@@ -1,13 +1,12 @@
 """Fit + validate the cost model against the measured rows on disk.
 
 The repo carries real measured step times: the per-round benchmark sweeps
-(SWEEP_r03–r05.jsonl), single-run BENCH_*.json rows, and — when a run has
+(SWEEP_r03–r04.jsonl), single-run BENCH_*.json rows, and — when a run has
 one — the per-phase timings in telemetry.jsonl. This module turns those
 into (config, measured tokens/s) pairs, fits the Calibration constants the
 rows can pin down (dense-matmul efficiency curve, attention efficiency,
 offload PCIe bandwidth — all the sweep rows are single-chip, so the ICI
-side stays analytic until TPU access returns; PERF.md documents that
-protocol), and scores rank agreement: the cost model's one job is ordering
+side stays analytic until a multi-chip cell is measured), and scores rank agreement: the cost model's one job is ordering
 layouts, so the metric is Spearman correlation between predicted and
 measured tokens/s within each sweep round.
 

@@ -48,7 +48,7 @@ from picotron_tpu.config import (
     Config, num_params, parse_tp_strategy, resolved_cp_flavor,
     resolved_cp_mesh, resolved_tp_mesh,
 )
-from picotron_tpu.utils import flops_per_token
+from picotron_tpu.utils import flops_per_token, tpu_generation
 
 # ---------------------------------------------------------------------------
 # TPU generations — ICI topology + link/HBM/peak constants.
@@ -96,20 +96,18 @@ GENERATIONS: dict[str, IciGeneration] = {
 
 def resolve_generation(name_or_kind: str) -> IciGeneration:
     """Generation from a config string ('v5e') or a jax device_kind
-    ('TPU v5 lite', 'TPU v5p'); unknown kinds (the CPU test platform)
-    default to v5e, matching utils.device_peak_flops."""
+    ('TPU v5 lite', 'TPU v5p'). Raises ValueError on a kind that names no
+    generation in the table (utils.tpu_generation) or one this model has
+    no ICI constants for — never a default."""
     k = name_or_kind.lower()
     if k in GENERATIONS:
         return GENERATIONS[k]
-    if "v6" in k or "trillium" in k:
-        return GENERATIONS["v6e"]
-    if "v5 lite" in k or "v5lite" in k or "v5e" in k:
-        return GENERATIONS["v5e"]
-    if "v5" in k:
-        return GENERATIONS["v5p"]
-    if "v4" in k:
-        return GENERATIONS["v4"]
-    return GENERATIONS["v5e"]
+    gen = tpu_generation(name_or_kind)
+    if gen not in GENERATIONS:
+        raise ValueError(
+            f"no ICI constants for TPU generation {gen!r} "
+            f"(device_kind {name_or_kind!r}); have {sorted(GENERATIONS)}")
+    return GENERATIONS[gen]
 
 
 # ---------------------------------------------------------------------------
@@ -225,8 +223,9 @@ def split_slice_link(link: AxisLink, n_slices: int,
 @dataclass(frozen=True)
 class Calibration:
     """Constants the measured rows on disk pin down (analysis/calibration.py
-    fits eff_max / h_half / eff_attn / pcie_bandwidth against the
-    SWEEP_r03–r05 + BENCH step times; the defaults below ARE that fit).
+    fits eff_max / h_half / eff_attn / pcie_bandwidth against SWEEP step
+    times; the defaults below are the fit to rounds 3–5, of which
+    SWEEP_r03–r04 remain on disk).
     The exposure fractions and link latency are analytic defaults awaiting
     on-TPU validation — PERF.md documents the protocol."""
 

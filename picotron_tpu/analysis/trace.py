@@ -59,12 +59,7 @@ def lower_train_step(cfg, menv=None) -> LoweredStep:
     batch = abstract_batch(cfg, menv)
     # one trace serves both consumers: the jaxpr (sharding-dataflow
     # provenance, analysis/dataflow.py) and the lowering (HLO-text checks)
-    jaxpr = None
-    if hasattr(step, "trace"):
-        traced = step.trace(state, batch)
-        jaxpr = traced.jaxpr
-        lowered = traced.lower()
-    else:  # older JAX: no Traced stage — lower directly, skip provenance
-        lowered = step.lower(state, batch)
+    traced = step.trace(state, batch)
+    lowered = traced.lower()
     return LoweredStep(step, lowered, lowered.as_text(), state, batch,
-                       jaxpr)
+                       traced.jaxpr)

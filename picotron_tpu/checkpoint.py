@@ -528,22 +528,12 @@ def restore_params_only(cfg: Config, ckpt_dir: str,
         item = {"params": abstract}
         rargs = {"params": restore_args}
         pick = lambda r: r["params"]  # noqa: E731
-    # partial_restore (skip tree branches absent from `item`) only exists
-    # on newer orbax; older releases spell the same thing as an empty
-    # `transforms` dict (the transforms machinery restores exactly the
-    # item's keys, each defaulting to its same-path checkpoint value)
-    import inspect
-
-    if "partial_restore" in inspect.signature(
-            ocp.args.PyTreeRestore).parameters:
-        restore_kwargs = {"partial_restore": True}
-    else:
-        restore_kwargs = {"transforms": {}}
+    # partial_restore: skip the tree branches absent from `item`
     with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ckptr:
         restored = ckptr.restore(
             os.path.join(mgr.directory, f"step_{step:08d}", "state"),
             args=ocp.args.PyTreeRestore(
-                item=item, restore_args=rargs, **restore_kwargs))
+                item=item, restore_args=rargs, partial_restore=True))
     return unpad_layers(pick(restored), nl, pp), step
 
 

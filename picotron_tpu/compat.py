@@ -1,31 +1,7 @@
-"""JAX version portability for the typed-shard_map API surface.
+"""The typed-shard_map names the parallel layer uses, in one place.
 
-The parallel layer is written against the varying-manual-axes ("vma")
-shard_map type system (`jax.shard_map`, `lax.pcast`, `jax.typeof(x).vma`).
-Older JAX releases (<= 0.4.x) ship shard_map under `jax.experimental` with
-the replication-rule checker instead of the vma types. Everything the
-composed step needs from the newer API has an exact old-API spelling:
-
-- `jax.shard_map(...)` -> `jax.experimental.shard_map.shard_map(...,
-  check_rep=False)`. With the checker off there is no replication typing to
-  satisfy. CAVEAT: differentiating THROUGH a `lax.psum` (a psum inside the
-  grad closure, e.g. a tp all-reduce in the forward) multiplies the
-  cotangent by the axis size on pre-vma JAX — measured 4x on a cp=4 mesh,
-  with check_rep=True no better. Grads of a LOCAL loss psummed AFTERWARDS
-  (the `_device_grads` pattern in parallel/api.py) are unaffected. The
-  parity tests that require grad-through-psum skip on `not HAS_VMA`.
-- `lax.pcast(x, axes, to="varying")` exists purely to satisfy the vma type
-  system (it is an identity on values); without that type system it IS the
-  identity.
-- `jax.typeof(x).vma` reads the axes a value varies over. The old API has
-  no such record; `vma()` returns the empty set, which is sound everywhere
-  the information is used to *add* varying axes (forgetting replication
-  knowledge), and the one site that needs the real answer
-  (parallel/pp.py sync_sp_partial_grads) guards on `HAS_VMA` explicitly.
-
-Keeping the adaptation in one module means the parallel layer reads as if
-the new API were always present, and deleting this file is the entire
-migration cost once the fleet's JAX floor catches up.
+Plain re-exports of the installed JAX's API (`jax.shard_map`, `lax.pcast`)
+plus `vma`, the one-line read of the mesh axes a value varies over.
 """
 
 from __future__ import annotations
@@ -33,63 +9,10 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-# The vma type system (pcast/pvary + typeof().vma) arrives together with
-# the public jax.shard_map; probe the one knob the code paths branch on.
-HAS_VMA = hasattr(lax, "pcast") and hasattr(jax, "typeof")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` on new JAX; the experimental spelling (checker off,
-    see module docstring) on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+shard_map = jax.shard_map
+pcast = lax.pcast
 
 
 def vma(x) -> frozenset:
-    """Mesh axes `x` varies over — empty on JAX without the vma types
-    (sound only where the caller ADDS varying axes; see module docstring)."""
-    if HAS_VMA:
-        return frozenset(jax.typeof(x).vma)
-    return frozenset()
-
-
-def pcast(x, axes, to="varying"):
-    """`lax.pcast` when the vma type system exists; identity otherwise
-    (pcast never changes values, only the varying-axes type)."""
-    if HAS_VMA:
-        return lax.pcast(x, axes, to=to)
-    return x
-
-
-def memory_space_puts():
-    """(to_device, to_host) callables for memory-SPACE-only transfers
-    inside jit (optimizer offload). New JAX spells this
-    `device_put(x, MemorySpace.Device/Host)`; 0.4.x spells it
-    `device_put(x, TransferToMemoryKind('device'/'pinned_host'))`."""
-    try:
-        from jax._src.core import MemorySpace
-
-        return (lambda x: jax.device_put(x, MemorySpace.Device),
-                lambda x: jax.device_put(x, MemorySpace.Host))
-    except ImportError:
-        from jax._src.sharding_impls import TransferToMemoryKind
-
-        return (lambda x: jax.device_put(x, TransferToMemoryKind("device")),
-                lambda x: jax.device_put(
-                    x, TransferToMemoryKind("pinned_host")))
-
-
-def require_vma(feature: str) -> None:
-    """Fail loudly where correctness (not just typing) depends on reading
-    real vma information — silently-wrong gradients are never acceptable."""
-    if not HAS_VMA:
-        raise RuntimeError(
-            f"{feature} requires the varying-manual-axes shard_map type "
-            f"system (jax.typeof(...).vma), which this JAX "
-            f"({jax.__version__}) predates; upgrade JAX or disable the "
-            f"feature")
+    """Mesh axes `x` varies over inside shard_map."""
+    return frozenset(jax.typeof(x).vma)

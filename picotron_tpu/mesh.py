@@ -165,9 +165,10 @@ def _topology_grid(shape: tuple, devices: list) -> np.ndarray:
 
     Non-TPU devices (the simulated CPU meshes tests use) reduce to the
     plain reshape inside mesh_utils, keeping single-host behavior and
-    device order unchanged. Any mesh_utils failure (e.g. a shape the torus
-    mapper cannot satisfy for a partial-host device subset) falls back to
-    the naive reshape with a warning rather than refusing to run.
+    device order unchanged. A mesh_utils failure (a shape the torus mapper
+    cannot satisfy for this device set) propagates: an enumeration-order
+    reshape in its place would put tp on arbitrary links and only show up
+    as slow collectives.
     """
     if len(devices) == 1:
         return np.array(devices).reshape(shape)
@@ -175,26 +176,13 @@ def _topology_grid(shape: tuple, devices: list) -> np.ndarray:
 
     slice_ids = {getattr(d, "slice_index", 0) for d in devices}
     if len(slice_ids) > 1:
-        # An unsatisfiable slice/axis split is a layout error the user must
-        # fix — raised OUTSIDE the try below, which only downgrades
-        # topology-*optimization* failures to a warning.
         dcn_shape, per_slice_shape = _split_axes_over_dcn(
             shape, len(slice_ids))
-    try:
-        if len(slice_ids) > 1:
-            return mesh_utils.create_hybrid_device_mesh(
-                per_slice_shape, dcn_shape, devices=devices,
-                allow_split_physical_axes=True)
-        return mesh_utils.create_device_mesh(
-            shape, devices=devices, allow_split_physical_axes=True)
-    except Exception as e:  # noqa: BLE001 — topology optimization only
-        import warnings
-
-        warnings.warn(
-            f"topology-aware mesh construction failed ({e}); falling back "
-            f"to enumeration-order reshape — collective performance may "
-            f"suffer on multi-chip hardware", stacklevel=2)
-        return np.array(devices).reshape(shape)
+        return mesh_utils.create_hybrid_device_mesh(
+            per_slice_shape, dcn_shape, devices=devices,
+            allow_split_physical_axes=True)
+    return mesh_utils.create_device_mesh(
+        shape, devices=devices, allow_split_physical_axes=True)
 
 
 def _split_axes_over_dcn(shape: tuple, n_slices: int) -> tuple[tuple, tuple]:

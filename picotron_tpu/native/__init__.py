@@ -11,6 +11,7 @@ pybind11 — plain C ABI + ctypes). If compilation is impossible the
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -19,27 +20,36 @@ import numpy as np
 
 _THIS_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_THIS_DIR, "packer.cpp")
-_LIB = os.path.join(_THIS_DIR, "libpacker.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
+def _lib_path() -> str:
+    """The built library is named after its source's content, so "is it
+    there" is also "is it current" — a checkout or a copy preserves no
+    mtimes, and the artifact is never in git."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_THIS_DIR, f"libpacker-{digest}.so")
+
+
 def _ensure_built() -> Optional[ctypes.CDLL]:
-    """Compile packer.cpp -> libpacker.so if missing or stale; load it."""
+    """Compile packer.cpp -> libpacker-<source hash>.so if absent; load it."""
     global _lib, _build_failed
     if _lib is not None:
         return _lib
     if _build_failed:
         return None
     try:
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            tmp = f"{lib_path}.{os.getpid()}.tmp"  # concurrent first users
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _LIB + ".tmp"],
+                ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", tmp],
                 check=True, capture_output=True, timeout=120)
-            os.replace(_LIB + ".tmp", _LIB)
-        lib = ctypes.CDLL(_LIB)
+            os.replace(tmp, lib_path)
+        lib = ctypes.CDLL(lib_path)
         lib.packer_new.restype = ctypes.c_void_p
         lib.packer_new.argtypes = [ctypes.c_int64]
         lib.packer_free.argtypes = [ctypes.c_void_p]

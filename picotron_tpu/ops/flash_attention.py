@@ -44,10 +44,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 # Swept on v5e at seq 2048 (B3 H32 D64): 1024x1024 runs 4x faster than
 # 256x256 — the kernel is VPU/overhead-bound, not MXU-bound, so fewer,
 # larger programs win. VMEM (fp32 [BQ, BK] score block) caps growth: 2048^2
@@ -55,6 +51,14 @@ _CompilerParams = getattr(pltpu, "CompilerParams", None) \
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 _NEG_INF = -1e30
+
+
+def compiled_kernels_available() -> bool:
+    """True where `flash_attention(interpret=None)` builds the compiled
+    Pallas kernels: on the TPU backend. Everywhere else the same call
+    builds the jnp reference math (see `flash_attention`'s dispatch note);
+    `parallel.api.attention_path` reports which one a config gets."""
+    return jax.default_backend() == "tpu"
 
 
 def _pick_block(s: int, preferred: int) -> int:
@@ -126,8 +130,6 @@ def _out_struct(shape, dtype, *operands):
     vma = frozenset()
     for x in operands:
         vma = vma | compat.vma(x)
-    if not compat.HAS_VMA:  # pre-vma ShapeDtypeStruct has no vma kwarg
-        return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -311,7 +313,7 @@ def _fwd(q4, k4, v4, qpos, kpos, rope, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((bq, d), jnp.float32),     # acc
         ] + ([pltpu.VMEM((bq, d), q4.dtype)]      # rotated q, reused per ki
              if rope is not None else []),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -570,7 +572,7 @@ def _bwd(q4, k4, v4, o4, lse, do4, dlse, qpos, kpos, rope, sm_scale, causal,
                               *rope_args),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]
         + ([pltpu.VMEM((bq, d), q4.dtype)] if rope is not None else []),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -631,7 +633,7 @@ def _bwd(q4, k4, v4, o4, lse, do4, dlse, qpos, kpos, rope, sm_scale, causal,
             pltpu.VMEM((bk, d), jnp.float32),
         ] + ([pltpu.VMEM((bk, d), k4.dtype)]  # rotated k, reused per t
              if rope is not None else []),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -723,7 +725,7 @@ def flash_attention(
     sk = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
-    if interpret is None and jax.default_backend() != "tpu":
+    if interpret is None and not compiled_kernels_available():
         from picotron_tpu.ops.attention import sdpa_attention
         from picotron_tpu.ops.rope import apply_rope
 
@@ -813,7 +815,7 @@ def flash_attention_bwd_from_saved(
     sk = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
-    if interpret is None and jax.default_backend() != "tpu":
+    if interpret is None and not compiled_kernels_available():
         from picotron_tpu.ops.attention import sdpa_attention_bwd_from_saved
         from picotron_tpu.ops.rope import apply_rope
 

@@ -116,7 +116,7 @@ def _lr_at(t: TrainingConfig, count):
     return lr(count) if callable(lr) else jnp.asarray(lr, jnp.float32)
 
 
-def _global_sq_norm(grads, clip_specs):
+def global_grad_norm(grads, clip_specs):
     """Global grad norm under shard_map: per-leaf local sum-of-squares,
     psum'd over the mesh axes the leaf is SHARDED over (its PartitionSpec
     axes — distinct shards sum to the global total; replicated leaves need
@@ -174,9 +174,6 @@ def offload_adam_update(grads, state: OffloadAdamState, t: TrainingConfig,
     `add_decayed_weights` + `scale_by_learning_rate` chain (and to
     optax.adamw for fp32 moments): offload changes WHERE state lives, not
     what the update computes."""
-    if transfer:
-        from picotron_tpu.compat import memory_space_puts
-
     b1, b2, eps = t.adam_beta1, t.adam_beta2, t.adam_eps
     wd = t.weight_decay
     mdt = jnp.bfloat16 if t.adam_moments_dtype == "bfloat16" else jnp.float32
@@ -196,12 +193,13 @@ def offload_adam_update(grads, state: OffloadAdamState, t: TrainingConfig,
     scale = (jnp.asarray(1.0, jnp.float32) if grad_scale is None
              else jnp.asarray(grad_scale, jnp.float32))
     if t.grad_clip_norm > 0:
-        gn = _global_sq_norm(grads, clip_specs) * scale
+        gn = global_grad_norm(grads, clip_specs) * scale
         scale = scale * jnp.where(gn < t.grad_clip_norm, 1.0,
                                   t.grad_clip_norm / gn)
 
     if transfer:
-        to_dev, to_host = memory_space_puts()
+        to_dev = lambda x: jax.device_put(x, jax.memory.Space.Device)  # noqa: E731
+        to_host = lambda x: jax.device_put(x, jax.memory.Space.Host)  # noqa: E731
     else:
         to_dev = to_host = lambda x: x
 

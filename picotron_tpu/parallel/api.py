@@ -43,7 +43,8 @@ from picotron_tpu.models.llama import (
     ParallelCtx, init_params, loss_sum_count, pad_layers_for_pp,
 )
 from picotron_tpu.optimizer import (
-    OffloadAdamState, make_optimizer, offload_adam_update,
+    OffloadAdamState, global_grad_norm, make_optimizer,
+    offload_adam_update,
 )
 from picotron_tpu.parallel.sharding import batch_spec, param_shardings, param_specs
 from picotron_tpu.parallel.tp import (
@@ -56,6 +57,30 @@ from picotron_tpu.parallel.tp import (
     vocab_parallel_embed,
 )
 from picotron_tpu.train_step import TrainState, guard_nonfinite
+
+
+def attention_path(cfg: Config) -> str:
+    """What this config's attention is built from on the current backend —
+    the fact the trainer's start-up line reports: 'pallas' (the compiled
+    Pallas flash kernels, TPU only) or 'jnp' (the reference math).
+    `attn_impl` 'auto' and the cp schedules take the kernels where they
+    exist and the reference elsewhere (the CPU test meshes);
+    'flash' names the kernels, so off-TPU it is an error rather than a
+    quiet substitution."""
+    from picotron_tpu.ops.flash_attention import compiled_kernels_available
+
+    impl = cfg.model.attn_impl
+    if impl == "reference":
+        return "jnp"
+    if compiled_kernels_available():
+        return "pallas"
+    if impl == "flash":
+        raise ValueError(
+            f"attn_impl='flash' asks for the compiled Pallas kernels, which "
+            f"need the TPU backend; this process runs on "
+            f"{jax.default_backend()!r}. Use attn_impl='auto' (kernels on "
+            f"TPU, reference math elsewhere) or 'reference'.")
+    return "jnp"
 
 
 def make_parallel_ctx(cfg: Config) -> ParallelCtx:
@@ -93,6 +118,7 @@ def make_parallel_ctx(cfg: Config) -> ParallelCtx:
             f"attn_impl={cfg.model.attn_impl!r} requires cp_size > 1 (it is "
             "a context-parallel schedule; ref: context_parallel.py:10-12)"
         )
+    attention_path(cfg)  # refuses attn_impl='flash' off-TPU
     use_flash = cfg.model.attn_impl in ("auto", "flash", "ring", "ulysses",
                                         "mesh")
     if use_flash:
@@ -287,8 +313,25 @@ def _device_grads(params, batch, cfg: Config):
     Returns (grads, loss, extras) — extras is a dict of normalized
     observability scalars ({"moe_drop_frac"} for MoE runs, {} otherwise)
     that the step surfaces in its metrics."""
+    from picotron_tpu.parallel.pp import _vary_over
+
     ctx = make_parallel_ctx(cfg)
     ids, tgt = batch  # [n_micro, mbs_local, s_local]
+    # Differentiate w.r.t. params that VARY over every axis whose reduction
+    # is written out below (the data axes in _data_axes_psum, 'pp' in
+    # sync_pp_replicated_grads). AD of an axis-INVARIANT param ends in the
+    # pvary-transpose psum, so each microbatch's grads would arrive already
+    # summed over those axes — an all-reduce per microbatch — and the
+    # explicit psum would then count them axis-size times (measured on JAX
+    # 0.9.0: every grad exactly dp x, cp x, and the pp-replicated leaves
+    # pp x, the single-device gradient; Adam's scale invariance hid it from
+    # the loss-trajectory parity tests). Varying params give per-device
+    # partials, reduced once per step. Values are unchanged — pcast only
+    # retypes.
+    reduced_axes = {"dp", "ep", "cp"}
+    if cfg.distributed.pp_size > 1:
+        reduced_axes.add("pp")
+    params = jax.tree.map(lambda p: _vary_over(p, reduced_axes), params)
 
     if cfg.distributed.pp_size > 1:
         # The pipeline scan subsumes the microbatch loop: grad accumulation
@@ -377,13 +420,9 @@ def _device_grads(params, batch, cfg: Config):
             grads = jax.tree.map(
                 lambda g: g.astype(jnp.float32), grads)
     else:
-        # The accumulators become dp/ep/cp-varying inside the scan (they
-        # depend on this device's batch shard), so the initial carry must
-        # carry the same varying type. Promote per leaf, skipping axes a
-        # leaf already varies over (expert banks arrive ep-varying from
-        # their sharding).
-        from picotron_tpu.parallel.pp import _vary_over
-
+        # The accumulators vary inside the scan exactly as the (varying)
+        # params they are grads of do, so the initial carry must carry the
+        # same varying type.
         # fp32 accumulation regardless of the param dtype: with
         # optimizer_offload the params (hence per-microbatch grads) are
         # bf16; summing grad-acc microbatches in bf16 would lose exactly
@@ -391,7 +430,7 @@ def _device_grads(params, batch, cfg: Config):
         # bf16 + fp32 -> fp32).
         zeros = jax.tree.map(
             lambda p: _vary_over(jnp.zeros(p.shape, jnp.float32),
-                                 {"dp", "ep", "cp"} | set(compat.vma(p))),
+                                 set(compat.vma(p))),
             params)
         init_carry = (zeros,) + compat.pcast(
             (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
@@ -503,6 +542,11 @@ def make_train_step(cfg: Config, menv: MeshEnv, inject_nan: bool = False):
             if inject_nan:
                 grads, loss = _poison(grads, loss)
             grad_scale = extras.pop("_grad_scale")
+            if guards_on:
+                # same observable the on-device path reports (optax
+                # global_norm below): the norm of the token-mean gradient
+                extras["grad_norm"] = (
+                    global_grad_norm(grads, pspecs) * grad_scale)
             new_params, new_opt = offload_adam_update(
                 grads, opt_state, cfg.training, cdt, transfer=transfer,
                 clip_specs=pspecs, grad_scale=grad_scale,
@@ -530,11 +574,9 @@ def make_train_step(cfg: Config, menv: MeshEnv, inject_nan: bool = False):
                     new_params, full_shardings)
             metrics = {"loss": loss, **extras}
             if guards_on:
-                # Offload guards key on the (already psum'd) loss only: a
-                # per-shard global grad norm would need the clip_specs
-                # psum machinery for no policy benefit — 'skip' is
-                # rejected for offload at config time, and rollback/abort
-                # both trigger off the loss.
+                # Offload guards key on the (already psum'd) loss only:
+                # 'skip' is rejected for offload at config time, and
+                # rollback/abort both trigger off the loss.
                 metrics["nonfinite"] = (
                     1.0 - jnp.isfinite(loss).astype(jnp.float32))
             return TrainState(new_params, new_opt, state.step + 1), metrics

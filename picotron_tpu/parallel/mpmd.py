@@ -421,6 +421,14 @@ def _chunk_param_specs(cfg: Config, j: int, V: int) -> dict:
     return specs
 
 
+def _vary_over_data(params):
+    """Params retyped to vary over the data axes, for differentiation: the
+    vjp then yields this device's partial grads and `_sub_data_psum` is the
+    one reduction. (AD of a data-invariant param ends in its own psum, and
+    the explicit one would count the grads dp x — api._device_grads.)"""
+    return jax.tree.map(lambda p: _vary_over(p, {"dp", "ep", "cp"}), params)
+
+
 def _sub_data_psum(grads, cfg: Config):
     """Per-microbatch grad reduction over the submesh's data axes. No
     per-leaf exceptions: MoE (the expert-bank case _data_axes_psum special-
@@ -520,7 +528,8 @@ class _StagePrograms:
 
             def bwd_body(params, ids, idx, g_in, acc):
                 mb = lax.dynamic_index_in_dim(ids, idx, 0, keepdims=False)
-                y, vjp_fn = jax.vjp(lambda p: embed_chunk(p, mb), params)
+                y, vjp_fn = jax.vjp(lambda p: embed_chunk(p, mb),
+                                    _vary_over_data(params))
                 (g_params,) = vjp_fn(_cast_varying_like(g_in, y))
                 return _accumulate(acc, _sub_data_psum(g_params, cfg))
 
@@ -552,7 +561,7 @@ class _StagePrograms:
                 def f(p, x):
                     total, _ = chunk_loss(p, x, mb_tgt)
                     return total
-                total, vjp_fn = jax.vjp(f, params, x_saved)
+                total, vjp_fn = jax.vjp(f, _vary_over_data(params), x_saved)
                 one = _vary_over(jnp.ones((), jnp.float32),
                                  set(compat.vma(total)))
                 g_params, g_x = vjp_fn(one)
@@ -573,7 +582,8 @@ class _StagePrograms:
                                   out_specs=xspec))
 
             def bwd_body(params, x_saved, g_in, acc):
-                y, vjp_fn = jax.vjp(run_chunk, params, x_saved)
+                y, vjp_fn = jax.vjp(run_chunk, _vary_over_data(params),
+                                    x_saved)
                 g_params, g_x = vjp_fn(_cast_varying_like(g_in, y))
                 return _accumulate(acc, _sub_data_psum(g_params, cfg)), g_x
 

@@ -304,11 +304,14 @@ class ServeEngine:
 
         self._owns_telemetry = telemetry is None
         self.telemetry = telemetry or Telemetry(sinks=[])
-        self._decode_jit, self._prefill_jit = _get_jits(
-            jax.default_backend() != "cpu")
+        # KV pool donation: on wherever the backend implements it (XLA:CPU
+        # ignores donation with a warning per call). Public so a caller
+        # checking the chip path can see which programs it got.
+        self.donate = jax.default_backend() != "cpu"
+        self._decode_jit, self._prefill_jit = _get_jits(self.donate)
         if self.speculate:
             from picotron_tpu.serve.spec_decode import get_spec_jit
-            self._decode_jit = get_spec_jit(jax.default_backend() != "cpu")
+            self._decode_jit = get_spec_jit(self.donate)
 
         self._t0 = time.perf_counter()  # trace clock zero (run() resets)
         self.engine_id = int(engine_id)  # fleet replica index (0 = solo)

@@ -41,7 +41,8 @@ from picotron_tpu.models.llama import pad_layers_for_pp
 from picotron_tpu.data import MicroBatchDataLoader
 from picotron_tpu.mesh import MeshEnv, multihost_initialize
 from picotron_tpu.parallel.api import (
-    init_sharded_state, install_params, make_train_step,
+    attention_path, init_sharded_state, install_params, make_train_step,
+    offload_memory_kind,
 )
 from picotron_tpu.resilience import (
     EXIT_DIVERGED, EXIT_PREEMPTED, DivergenceGuard, GuardAction,
@@ -51,7 +52,8 @@ from picotron_tpu.telemetry import Telemetry, bus as telemetry_bus
 from picotron_tpu.train_step import TrainState
 from picotron_tpu.utils import (
     StepTimer, device_memory_gb, device_peak_flops, human_format,
-    is_logging_host, log_print, mfu, training_log_line,
+    is_logging_host, log_print, mfu, require_platform,
+    setup_compile_cache, training_log_line,
 )
 
 
@@ -198,7 +200,14 @@ def main(argv=None) -> None:
         # would otherwise over-provision every process (code review r3)
         force_host_device_count(world // n_proc, exact=n_proc > 1)
         jax.config.update("jax_platforms", "cpu")
+    setup_compile_cache()
     multihost_initialize()
+    # The CPU is a fine place to train when somebody asked for it
+    # (`use_cpu: true`, or JAX_PLATFORMS / jax_platforms naming cpu — the
+    # tests' and tools' two spellings); JAX falling back to it because no
+    # accelerator came up is refused.
+    asked_cpu = "cpu" in (jax.config.jax_platforms or "").split(",")
+    dev0 = require_platform("train", allow_cpu=asked_cpu)
     menv = MeshEnv.from_config(cfg)
     t = cfg.training
 
@@ -253,10 +262,12 @@ def main(argv=None) -> None:
             est = preflight_save_dir(cfg)  # raises RuntimeError w/ story
             log_print(f"checkpoint preflight: ok ({cfg.checkpoint.save_dir}"
                       f", ~{est / 1e9:.2f} GB/checkpoint)")
-        if (cfg.distributed.world_size > 1
+        if (cfg.distributed.world_size > 1 and dev0.platform == "tpu"
                 and os.environ.get("PICOTRON_COST_PREFLIGHT", "1") != "0"):
             # Advisory layout check (analysis/cost_model + planner): pure
-            # arithmetic, milliseconds even at pod scale. Warn — never
+            # arithmetic, milliseconds even at pod scale; priced for the TPU
+            # generation this process runs on, so it has nothing to say on
+            # the CPU platform. Warn — never
             # fail — when the chosen layout is predicted >= 20% slower
             # than the planner's best at the same chip count, with the
             # overrides line that would close the gap. Threshold via
@@ -265,7 +276,7 @@ def main(argv=None) -> None:
             from picotron_tpu.analysis.cost_model import CostModel
             from picotron_tpu.analysis.planner import planner_gap
 
-            cm = CostModel(jax.devices()[0].device_kind)
+            cm = CostModel(dev0.device_kind)
             cur, best, gap = planner_gap(cfg, cm)
             gap_bar = float(os.environ.get("PICOTRON_COST_GAP", "0.2"))
             log_print(f"cost preflight [{cm.gen.name}]: predicted "
@@ -305,13 +316,19 @@ def main(argv=None) -> None:
 
     n_chips = menv.world_size
     n_params = num_params(cfg.model)
-    peak = device_peak_flops()
+    # MFU needs a peak, and only a TPU in the table has one: on the CPU
+    # platform the column is not computed (the frozen line prints 0.00%).
+    peak = device_peak_flops(dev0) if dev0.platform == "tpu" else None
+    offload = (offload_memory_kind(menv.mesh) or "unplaced"
+               if t.optimizer_offload else "off")
     log_print(
         f"model {cfg.model.name}: {human_format(n_params)} params | "
         f"mesh dp={menv.dp} pp={menv.pp} ep={menv.ep} cp={menv.cp} tp={menv.tp} "
-        f"({n_chips} chips, {jax.devices()[0].device_kind}) | "
+        f"({n_chips} chips, {dev0.device_kind}) | "
         f"global batch {cfg.global_batch_size} x seq {t.seq_length} = "
-        f"{human_format(cfg.tokens_per_step)} tokens/step"
+        f"{human_format(cfg.tokens_per_step)} tokens/step | "
+        f"platform={dev0.platform} attention={attention_path(cfg)} "
+        f"offload={offload}"
     )
 
     # Structured telemetry (picotron_tpu/telemetry; README
@@ -536,8 +553,8 @@ def main(argv=None) -> None:
                 steps_in_window = step - last_logged_step
                 last_logged_step = step
                 tokens_per_sec = cfg.tokens_per_step * steps_in_window / dt
-                mfu_frac = mfu(tokens_per_sec, cfg.model, t.seq_length,
-                               n_chips, peak)
+                mfu_frac = (mfu(tokens_per_sec, cfg.model, t.seq_length,
+                                n_chips, peak) if peak else 0.0)
                 mem_gb = device_memory_gb()
                 line = training_log_line(
                     step, loss, tokens_per_sec, tokens_per_sec / n_chips,

@@ -8,6 +8,7 @@ generation from the device kind, as SURVEY.md §5 prescribes.
 
 from __future__ import annotations
 
+import os
 import sys
 import threading
 import time
@@ -38,28 +39,89 @@ TPU_PEAK_FLOPS: dict[str, float] = {
 H100_BF16_PEAK = 989.5e12
 
 
-def device_peak_flops(device: Optional[jax.Device] = None) -> float:
-    """Per-chip bf16 peak FLOP/s for `device` (default: first local device).
+def tpu_generation(device_kind: str) -> str:
+    """TPU generation ('v5e', 'v5p', ...) from a jax `device_kind`.
 
     Real device_kind strings use the hardware naming, not the marketing one:
     a v5e reports "TPU v5 lite", a v6e/Trillium "TPU v6 lite", a v5p
-    "TPU v5p" (and "TPU v5" alone means v5p). Unknown kinds (e.g. the CPU
-    test platform) fall back to the v5e peak so derived MFU stays finite and
-    comparable.
-    """
-    if device is None:
-        device = jax.devices()[0]
-    kind = device.device_kind.lower()
+    "TPU v5p" (and "TPU v5" alone means v5p). A kind that names no known
+    generation (the CPU platform, a future chip) raises: a peak or a link
+    bandwidth borrowed from another device would make every derived
+    utilization wrong without saying so."""
+    kind = device_kind.lower()
     if "v6" in kind or "trillium" in kind:
-        return TPU_PEAK_FLOPS["v6e"]
+        return "v6e"
     if "v5 lite" in kind or "v5lite" in kind or "v5e" in kind:
-        return TPU_PEAK_FLOPS["v5e"]
+        return "v5e"
     if "v5" in kind:  # "TPU v5p" / bare "TPU v5"
-        return TPU_PEAK_FLOPS["v5p"]
+        return "v5p"
     for gen in ("v4", "v3", "v2"):
         if gen in kind:
-            return TPU_PEAK_FLOPS[gen]
-    return TPU_PEAK_FLOPS["v5e"]
+            return gen
+    raise ValueError(
+        f"device_kind {device_kind!r} is not a TPU generation this repo "
+        f"has constants for ({', '.join(TPU_PEAK_FLOPS)}); add it to the "
+        f"peak table with its source before computing utilization on it")
+
+
+def device_peak_flops(device: Optional[jax.Device] = None) -> float:
+    """Per-chip bf16 peak FLOP/s for `device` (default: first local device);
+    raises ValueError on a device_kind outside the peak table."""
+    if device is None:
+        device = jax.devices()[0]
+    return TPU_PEAK_FLOPS[tpu_generation(device.device_kind)]
+
+
+# ---------------------------------------------------------------------------
+# Process set-up: where the program runs, where compiled code is kept
+# ---------------------------------------------------------------------------
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def setup_compile_cache() -> Optional[str]:
+    """Point JAX's persistent compilation cache at a stable directory and
+    return it. Called by every entry point that compiles on the chip
+    (train, bench, chip_smoke, `pytest -m tpu`) before its first compile.
+
+    `JAX_COMPILATION_CACHE_DIR`, when set, is where the cache lives — JAX
+    reads the variable itself and this function sets nothing. Otherwise
+    the cache goes to `<checkout>/.jax_cache`: a cache that moves never
+    hits, so the path is fixed (never a temp name, a pid or a time), and
+    child processes resolve the same one. A process pinned to the CPU
+    platform (tests, tools) gets no cache and None: its programs are toys,
+    and a test run must not grow the checkout."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    if jax.config.jax_platforms == "cpu":
+        return None
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def require_platform(who: str, *, allow_cpu: bool) -> jax.Device:
+    """Initialize the backend and return its first device, refusing the one
+    outcome nobody asked for: JAX found no accelerator and quietly fell
+    back to the CPU. `allow_cpu` is the caller's explicit consent (a
+    `--cpu` flag, `use_cpu: true`, or — for the trainer — a
+    `JAX_PLATFORMS=cpu` the user set). One line, no traceback."""
+    try:
+        dev = jax.devices()[0]
+    except Exception as e:  # noqa: BLE001 — entry-point boundary: whatever
+        # the backend raised, the user gets the one-line story
+        why = (str(e).splitlines() or [""])[0][:200]
+        raise SystemExit(
+            f"{who}: no JAX backend came up "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '<unset>')}; "
+            f"{type(e).__name__}: {why})")
+    if dev.platform == "cpu" and not allow_cpu:
+        raise SystemExit(
+            f"{who}: no accelerator — JAX is running on the CPU "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '<unset>')}), "
+            f"which this run did not ask for")
+    return dev
 
 
 # ---------------------------------------------------------------------------

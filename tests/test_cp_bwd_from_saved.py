@@ -3,8 +3,7 @@
 ops/ulysses.py) pinned against AD of the dense forward.
 
 These are FORWARD-only programs (ppermutes/all_to_alls in the primal
-direction; no differentiation through collectives), so unlike the
-engine-level parity tests they run on pre-vma JAX too. The load-bearing
+direction; no differentiation through collectives). The load-bearing
 property: `sdpa_attention_bwd_from_saved` normalizes probabilities by the
 PASSED lse, so calling it per visiting block with the GLOBAL (out, lse)
 yields that block's additive contribution to the global grads — which is

@@ -128,7 +128,7 @@ def _two_device_mesh():
 
 
 def _shard_map_prog(mesh, in_spec):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def prog(tree):
         f = shard_map(lambda a: a * 2.0, mesh=mesh,

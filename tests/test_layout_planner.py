@@ -218,7 +218,7 @@ def test_cli_plan_chips8(capsys):
 
 def test_cli_validate_sweep_reproduces_measured_ranking(capsys):
     """Acceptance: the planner CLI reproduces the measured ranking of the
-    SWEEP_r03–r05 configs (per-round Spearman)."""
+    SWEEP_r03–r04 configs (per-round Spearman)."""
     lp = load_tool("layout_planner")
     rc = lp.main(["--validate-sweep", "--json"])
     assert rc == 0

@@ -146,7 +146,9 @@ def test_topology_grid_routes_multislice_to_hybrid(devices, monkeypatch):
     assert calls["per_slice"] == (1, 2, 1, 1, 2)
 
 
-def test_topology_grid_fallback_on_mesh_utils_failure(devices, monkeypatch):
+def test_topology_grid_mesh_utils_failure_propagates(devices, monkeypatch):
+    """No enumeration-order reshape behind a failed topology mapping: the
+    error reaches the caller."""
     from jax.experimental import mesh_utils
 
     from picotron_tpu import mesh as mesh_mod
@@ -155,10 +157,8 @@ def test_topology_grid_fallback_on_mesh_utils_failure(devices, monkeypatch):
         raise ValueError("unsatisfiable torus mapping")
 
     monkeypatch.setattr(mesh_utils, "create_device_mesh", boom)
-    with pytest.warns(UserWarning, match="topology-aware"):
-        grid = mesh_mod._topology_grid((2, 1, 1, 2, 2), list(devices[:8]))
-    ids = np.vectorize(lambda d: d.id)(grid)
-    assert (ids.ravel() == [d.id for d in devices[:8]]).all()
+    with pytest.raises(ValueError, match="unsatisfiable torus mapping"):
+        mesh_mod._topology_grid((2, 1, 1, 2, 2), list(devices[:8]))
 
 
 def test_multihost_initialize_singlehost_noop():

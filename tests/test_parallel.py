@@ -207,11 +207,6 @@ def test_vocab_parallel_ce_matches_dense():
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
 
 
-@pytest.mark.skipif(
-    not compat.HAS_VMA,
-    reason="differentiates THROUGH the tp psum in vocab_parallel_ce: "
-           "pre-vma shard_map inflates the cotangent by the tp size "
-           "(see compat.py)")
 def test_vocab_parallel_ce_grad_matches_dense():
     menv = MeshEnv.create(tp=8)
     h = jax.random.normal(jax.random.key(0), (2, 8, 16))

@@ -39,10 +39,6 @@ def test_ring_matches_dense_forward(cp, hq, hkv):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.skipif(
-    not compat.HAS_VMA,
-    reason="differentiates THROUGH lax.psum: pre-vma shard_map inflates "
-           "the cotangent by the cp size (see compat.py)")
 def test_ring_matches_dense_grads():
     menv = MeshEnv.create(cp=4)
     q, k, v = qkv()

@@ -484,13 +484,9 @@ def test_donation_aliased_into_two_outputs():
     assert rep_text.info["donation"] == rep.info["donation"]
 
 
-def test_donation_text_fallback_parity_under_compat_shim():
-    """This JAX (no varying-manual-axes) lowers the step through the
-    compat.py pre-vma shard_map shim; donation attributes must survive
-    that path identically in the Lowered.args_info view and the raw
-    StableHLO text view (the fallback older jax versions take)."""
-    from picotron_tpu.compat import HAS_VMA
-
+def test_donation_text_fallback_parity():
+    """Donation attributes must read identically from the
+    Lowered.args_info view and the raw StableHLO text view."""
     cfg = mkcfg(dist=dict(pp_size=2, dp_size=2), ga=2)
     low = lower_train_step(cfg)
     rep_info = check_donation(low.lowered, low.state, low.batch)
@@ -499,8 +495,6 @@ def test_donation_text_fallback_parity_under_compat_shim():
     assert rep_info.info["donation"] == rep_text.info["donation"]
     assert rep_info.info["donation"]["donated"] == \
         rep_info.info["donation"]["state_leaves"]
-    if not HAS_VMA:  # the shim path really was exercised
-        assert rep_text.ok(), rep_text.render(verbose=True)
 
 
 def test_donation_full_coverage_through_fused_bwd():
@@ -562,8 +556,7 @@ def test_shardflow_runs_gate():
     only: a NEW implicit reshard or predicted boundary reshard, a proven
     jit entry turning unproven, attribution decaying below the 90%
     acceptance bar, or a config newly failing to trace. Improvements
-    (e.g. a pre-vma-fatal config starting to trace on a newer jax) pass —
-    regenerate the baseline to lock them in."""
+    pass — regenerate the baseline to lock them in."""
     import subprocess
 
     root = os.path.join(os.path.dirname(__file__), "..")

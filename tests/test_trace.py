@@ -1,11 +1,9 @@
 """analysis/trace.py coverage: the abstract lowering that feeds every
 analyzer (collectives audit, hazards, cost pricing) must capture the
 lowered module text without materializing arrays, for single- and
-multi-axis layouts, and across the compat.py JAX-version shim (the
-`jax.shard_map` vs `jax.experimental.shard_map` spelling)."""
+multi-axis layouts."""
 
 import jax
-import pytest
 
 from picotron_tpu import compat
 from picotron_tpu.analysis.collectives import parse_collectives
@@ -66,50 +64,11 @@ def test_explicit_menv_is_honored():
     assert low.batch[0].sharding.mesh == menv.mesh
 
 
-def test_lowering_across_compat_shim(monkeypatch):
-    """compat.shard_map falls back to jax.experimental.shard_map when the
-    public spelling is absent (pre-vma JAX). On new JAX, hiding
-    jax.shard_map must yield the same lowered collective schedule; on
-    pre-vma JAX the experimental spelling IS the live path, and the shim
-    must report it consistently — either way the capture works and the
-    analyzers cannot tell the difference."""
-    cfg = mkcfg(dist=dict(dp_size=2), ga=2)
-    ref_ops = [(op.kind, op.group_size) for op in
-               parse_collectives(lower_train_step(cfg).text)
-               if op.effective]
-    assert ("all_reduce", 2) in ref_ops
+def test_compat_reexports():
+    """compat.py is plain re-exports of the installed JAX: shard_map and
+    pcast are the public functions, and vma reads a frozenset."""
+    from jax import lax
 
-    if hasattr(jax, "shard_map"):
-        pytest.importorskip("jax.experimental.shard_map")
-        monkeypatch.delattr(jax, "shard_map")
-        assert not hasattr(jax, "shard_map")  # compat takes the old path
-        shim_ops = [(op.kind, op.group_size) for op in
-                    parse_collectives(lower_train_step(cfg).text)
-                    if op.effective]
-        assert sorted(map(str, shim_ops)) == sorted(map(str, ref_ops))
-    else:
-        # pre-vma JAX: the lowering above already went through the
-        # experimental spelling; the shim must agree there is no vma
-        # type system to lean on
-        import importlib
-
-        importlib.import_module("jax.experimental.shard_map")  # must exist
-        assert not compat.HAS_VMA
-        assert compat.vma(jax.numpy.ones(())) == frozenset()
-
-
-def test_compat_pcast_vma_are_consistent():
-    """The shim helpers trace.py's lowering leans on: pcast is value-
-    identity, vma returns a set, and require_vma raises only without the
-    vma type system."""
-    import numpy as np
-
-    x = jax.numpy.ones((4,))
-    y = compat.pcast(x, ("dp",)) if compat.HAS_VMA else compat.pcast(x, ())
-    np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    assert isinstance(compat.vma(x), frozenset)
-    if compat.HAS_VMA:
-        compat.require_vma("test")  # must not raise
-    else:
-        with pytest.raises(RuntimeError):
-            compat.require_vma("test")
+    assert compat.shard_map is jax.shard_map
+    assert compat.pcast is lax.pcast
+    assert compat.vma(jax.numpy.ones((4,))) == frozenset()

@@ -864,7 +864,7 @@ def _run_bench_fleet(leg_dir: str, extra_args: list,
     cmd = [sys.executable,
            os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                         "bench.py"),
-           "--serve", "--model", "debug-tiny", "--prompt-len", "16",
+           "--serve", "--cpu", "--model", "debug-tiny", "--prompt-len", "16",
            "--max-new-tokens", "8", "--serve-slots", "3", "--block-size",
            "4", "--prefill-chunk", "4", "--serve-temperature", "0.7",
            "--serve-seed", "7"] + extra_args

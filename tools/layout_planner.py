@@ -17,7 +17,7 @@ measured SWEEP/BENCH rows on disk (validate with --validate-sweep).
       --trace 3 --verify-hbm            # re-cost top-3 from traced HLO,
                                         # memcheck-verify the winner
   python tools/layout_planner.py --validate-sweep   # rank agreement vs
-                                                    # SWEEP_r03–r05
+                                                    # SWEEP_r03–r04
 
 --trace and --verify-hbm lower/compile on simulated host devices (the
 memcheck recipe); expect minutes for multi-billion-parameter configs —
@@ -158,7 +158,7 @@ def main(argv=None) -> int:
                          "--tp-strategy-table (default 2 4 8 16)")
     ap.add_argument("--validate-sweep", action="store_true",
                     help="score the cost model's rank agreement against "
-                         "the measured SWEEP_r03-r05 rows instead of "
+                         "the measured SWEEP_r03-r04 rows instead of "
                          "planning")
     ap.add_argument("--fit", action="store_true",
                     help="with --validate-sweep: refit the calibration "

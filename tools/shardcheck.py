@@ -286,7 +286,7 @@ def main(argv=None) -> int:
             rep = run_shardcheck(cfg, checks=checks, budget_bytes=budget,
                                  cost_model=cost_model, slices=args.slices,
                                  dcn_axes=args.dcn_axes)
-        except Exception as e:  # layouts this JAX cannot trace (pre-vma)
+        except Exception as e:  # a layout that fails to trace is one bad row
             n_bad += 1
             if args.json:
                 print(json.dumps({
