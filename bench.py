@@ -224,7 +224,7 @@ def run_one(model: str, layers, seq: int, mbs: int, *, grad_acc: int = 1,
         dt_i = time.perf_counter() - t0
         hist.observe(dt_i)
         if tracer is not None:
-            tracer.complete("step", dur_s=dt_i, i=i)
+            tracer.complete("step", start_s=t0, dur_s=dt_i, i=i)
         if sink is not None:
             sink.emit({"ts": time.time(), "kind": "bench_step", "i": i,
                        "secs": round(dt_i, 6),
@@ -261,7 +261,7 @@ def run_one(model: str, layers, seq: int, mbs: int, *, grad_acc: int = 1,
         n_probe = 10_000
         t0 = time.perf_counter()
         for i in range(n_probe):
-            probe.complete("probe", dur_s=1e-6, i=i)
+            probe.complete("probe", start_s=t0, dur_s=1e-6, i=i)
         span_cost_us = (time.perf_counter() - t0) / n_probe * 1e6
         tracer.export(trace)
         row["trace"] = trace

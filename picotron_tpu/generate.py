@@ -52,6 +52,7 @@ from picotron_tpu.models.llama import (
     head_weight, model_rope_tables, qkv_proj, rms_norm,
 )
 from picotron_tpu.ops.rope import apply_rope
+from picotron_tpu.telemetry.scopes import scope
 
 
 class KVCache(NamedTuple):
@@ -164,8 +165,11 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
         q = _rope(q, cos, sin, q_pos)
         k = _rope(k, cos, sin, q_pos)
         cache = cache.write(li, k, v, q_pos)
-        ck_l, cv_l = cache.layer_view(li)
-        out = _cached_attention(q, ck_l, cv_l, q_pos)
+        # named for the serve programs, whose `layer_view` gathers every
+        # slot's blocks out of the pool (the contiguous cache's is a slice)
+        with scope("paged_attention"):
+            ck_l, cv_l = cache.layer_view(li)
+            out = _cached_attention(q, ck_l, cv_l, q_pos)
         out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
         x = x + out
         if cfg.num_experts:
@@ -186,6 +190,7 @@ def _logits_last(params, x, cfg: ModelConfig):
     return (hf @ head_weight(params).astype(hf.dtype))[:, 0].astype(jnp.float32)
 
 
+@scope("sample")
 def _sample(logits, temperature: float, top_k: int, key):
     if temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -42,6 +42,7 @@ from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.rope import apply_rope, rope_tables
+from picotron_tpu.telemetry.scopes import scope
 
 
 def model_rope_tables(cfg, max_len=None):
@@ -307,11 +308,12 @@ def embed(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
           ctx: ParallelCtx = DEFAULT_CTX) -> jnp.ndarray:
     """Token embedding -> [B, S, H] in compute dtype."""
     w = params["embedding"]
-    if ctx.embed_lookup is not None:
-        x = ctx.embed_lookup(w, input_ids)
-    else:
-        x = w[input_ids]
-    return x.astype(compute_dtype(cfg))
+    with scope("embed"):
+        if ctx.embed_lookup is not None:
+            x = ctx.embed_lookup(w, input_ids)
+        else:
+            x = w[input_ids]
+        return x.astype(compute_dtype(cfg))
 
 
 def qkv_proj(h, lp, d: int):
@@ -342,6 +344,7 @@ def qkv_proj(h, lp, d: int):
             v.reshape(b, s, -1, d))
 
 
+@scope("attention")
 def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     """RMSNorm -> qkv -> RoPE -> attention -> out_proj (ref: model.py:122-162)."""
     dt = x.dtype
@@ -385,6 +388,7 @@ def mlp_act(cfg: ModelConfig):
     return partial(jax.nn.gelu, approximate=cfg.hidden_act == "gelu_tanh")
 
 
+@scope("mlp")
 def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
     """RMSNorm -> gated MLP (ref: model.py:184-186)."""
     dt = x.dtype
@@ -398,6 +402,7 @@ def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
     return ctx.g(out)
 
 
+@scope("mlp")
 def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     """RMSNorm -> top-k routed expert SwiGLU bank (beyond the reference;
     ops/moe.py). Returns (out, aux [2])."""
@@ -582,12 +587,13 @@ def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
     x, aux = run_layers(params["layers"], x, cfg, ctx, cos, sin)
-    x = final_hidden(params, x, cfg)
-    if ctx.head_ce is not None:
-        total, count = ctx.head_ce(x, head_weight(params), targets)
-    else:
-        logits = x @ head_weight(params).astype(x.dtype)
-        total, count = cross_entropy_sum_count(logits, targets)
+    with scope("head_ce"):
+        x = final_hidden(params, x, cfg)
+        if ctx.head_ce is not None:
+            total, count = ctx.head_ce(x, head_weight(params), targets)
+        else:
+            logits = x @ head_weight(params).astype(x.dtype)
+            total, count = cross_entropy_sum_count(logits, targets)
     extras = {}
     if cfg.num_experts:
         total = total + aux[0] * count

@@ -21,6 +21,7 @@ import optax
 from jax import lax
 
 from picotron_tpu.config import TrainingConfig
+from picotron_tpu.telemetry.scopes import scope
 
 
 def scale_by_adam_low_moments(b1: float, b2: float, eps: float,
@@ -143,6 +144,7 @@ def global_grad_norm(grads, clip_specs):
     return jnp.sqrt(total)
 
 
+@scope("optimizer")
 def offload_adam_update(grads, state: OffloadAdamState, t: TrainingConfig,
                         compute_dtype, *, transfer: bool = True,
                         clip_specs=None, grad_scale=None, zero1_info=None):

@@ -51,11 +51,13 @@ from picotron_tpu.parallel.tp import (
     gather_logits,
     sp_gather_seq,
     sp_scatter_seq,
+    tp_psum,
     vocab_parallel_ce_local_stats,
     vocab_parallel_ce_merge,
     vocab_parallel_ce_sum_count,
     vocab_parallel_embed,
 )
+from picotron_tpu.telemetry.scopes import scope
 from picotron_tpu.train_step import TrainState, guard_nonfinite
 
 
@@ -204,7 +206,7 @@ def make_parallel_ctx(cfg: Config) -> ParallelCtx:
     ce_chunk = cfg.training.ce_chunk_size
     ce = partial(vocab_parallel_ce_sum_count, axis="tp", chunk_size=ce_chunk)
     hooks = dict(
-        g=lambda x: lax.psum(x, "tp"),
+        g=tp_psum,
         embed_lookup=partial(vocab_parallel_embed, axis="tp"),
         head_ce=ce,
         # the split form lets the PP engines run the head matmul only on
@@ -566,7 +568,7 @@ def make_train_step(cfg: Config, menv: MeshEnv, inject_nan: bool = False):
         full_shardings = param_shardings(cfg, mesh)
 
         @partial(jax.jit, donate_argnums=(0,))
-        def step(state: TrainState, batch):
+        def train_step(state: TrainState, batch):
             new_params, new_opt, loss, extras = fused(
                 state.params, batch, state.opt_state)
             if cfg.distributed.zero1:
@@ -581,12 +583,15 @@ def make_train_step(cfg: Config, menv: MeshEnv, inject_nan: bool = False):
                     1.0 - jnp.isfinite(loss).astype(jnp.float32))
             return TrainState(new_params, new_opt, state.step + 1), metrics
 
-        return step
+        return train_step
 
     opt = make_optimizer(cfg.training)
 
+    # The function's name is the program's module name (`jit_train_step`):
+    # a device trace lists each executed step under it on the `XLA
+    # Modules` line. Pinned by tests/test_scopes.py.
     @partial(jax.jit, donate_argnums=(0,))
-    def step(state: TrainState, batch):
+    def train_step(state: TrainState, batch):
         grads, loss, extras = grad_fn(state.params, batch)
         if inject_nan:
             grads, loss = _poison(grads, loss)
@@ -600,15 +605,17 @@ def make_train_step(cfg: Config, menv: MeshEnv, inject_nan: bool = False):
             ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
             extras = {**extras, "grad_norm": gnorm,
                       "nonfinite": 1.0 - ok.astype(jnp.float32)}
-        updates, opt_state = opt.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        if guards_on and guard_skip:
-            new_params = guard_nonfinite(ok, new_params, state.params)
-            opt_state = guard_nonfinite(ok, opt_state, state.opt_state)
+        with scope("optimizer"):
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            if guards_on and guard_skip:
+                new_params = guard_nonfinite(ok, new_params, state.params)
+                opt_state = guard_nonfinite(ok, opt_state, state.opt_state)
         metrics = {"loss": loss, **extras}
         return TrainState(new_params, opt_state, state.step + 1), metrics
 
-    return step
+    return train_step
 
 
 def make_eval_step(cfg: Config, menv: MeshEnv):

@@ -88,6 +88,7 @@ from picotron_tpu.ops.flash_attention import (
     flash_attention, flash_attention_bwd_from_saved,
 )
 from picotron_tpu.ops.rmsnorm import rms_norm
+from picotron_tpu.telemetry.scopes import scope
 
 
 def fused_bwd_supported(cfg: Config) -> bool:
@@ -308,19 +309,31 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
     moe_keys = (["router", "w_gate", "w_up", "w_down"] if moe
                 else ["gate", "up", "down"])
 
+    # Scopes (telemetry/scopes.py): the same names as the forward's in
+    # models/llama.py, entered here because this engine calls the
+    # building blocks below `_attention_block`. `attn_fwd` / `attn_bwd`
+    # run OUTSIDE every scope: the flash kernels' events are named after
+    # the innermost name-stack element at the call, and the benchmark's
+    # `flash_roofline.train` finds them under the name the layer scan's
+    # body gives them (tests/test_chip_compile.py).
+
     # ---------------- forward ----------------
-    x0, vjp_embed = jax.vjp(
-        lambda e: (ctx.embed_lookup(e, ids) if ctx.embed_lookup is not None
-                   else e[ids]).astype(compute_dtype(m)),
-        params["embedding"])
+    with scope("embed"):
+        x0, vjp_embed = jax.vjp(
+            lambda e: (ctx.embed_lookup(e, ids)
+                       if ctx.embed_lookup is not None
+                       else e[ids]).astype(compute_dtype(m)),
+            params["embedding"])
 
     def fwd_body(x, lp):
-        h1 = rms_norm(ctx.pre(x), lp["input_norm"], eps)
-        hf = ctx.f(h1)
-        q, k, v = (ctx.qkv_mm or qkv_proj)(hf, lp, hd)
+        with scope("attention"):
+            h1 = rms_norm(ctx.pre(x), lp["input_norm"], eps)
+            hf = ctx.f(h1)
+            q, k, v = (ctx.qkv_mm or qkv_proj)(hf, lp, hd)
         out, lse = attn_fwd(q, k, v)
-        outf = flat(out)
-        a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
+        with scope("attention"):
+            outf = flat(out)
+            a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
         if moe:
             mo, aux = _moe_block(a, lp, m, ctx)
             y = a + mo
@@ -335,6 +348,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
     # ---------------- head + CE ----------------
     nonlayer = {k: v for k, v in params.items() if k != "layers"}
 
+    @scope("head_ce")
     def head_fn(x, nl):
         xh = rms_norm(x, nl["final_norm"], eps)
         if ctx.head_ce is not None:
@@ -348,7 +362,8 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
 
     (total, vjp_head, count) = jax.vjp(head_fn, xL, nonlayer, has_aux=True)
     one = _vary_like(jnp.ones((), jnp.float32), total)
-    dxL, g_nonlayer = vjp_head(one)
+    with scope("head_ce"):
+        dxL, g_nonlayer = vjp_head(one)
     count_f = count.astype(jnp.float32)
     if moe:
         # the loss_sum_count fold: reported total = nll + (sum_l aux_l)*count
@@ -368,7 +383,8 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         # recompute set), derive the block's grads by segment VJP. For MoE
         # the routing recomputes deterministically and the aux-loss fold
         # (aux * count) rides the segment so balance/z grads flow.
-        a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
+        with scope("attention"):
+            a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
 
         if moe:
             def seg_mlp(a_, *ws):
@@ -391,14 +407,17 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
                 seg_mlp, a, lp["post_norm"], *[lp[k] for k in moe_keys])
             da, d_post, *d_ws = vjp_b(dy)
 
+        @scope("attention")
         def seg_o(x_, outf_, wo):
             return x_ + _o_exit(ctx, outf_, wo, x_.dtype)
 
         _, vjp_o = jax.vjp(seg_o, x, outf, lp["o"])
-        dx1, doutf, d_o = vjp_o(da)
+        with scope("attention"):
+            dx1, doutf, d_o = vjp_o(da)
 
         dqf, dkf, dvf = attn_bwd_flat(qf, kf, vf, outf, lse, doutf)
 
+        @scope("attention")
         def seg_qkv(x_, w_in, wq, wk, wv, *bs):
             lpq = dict(lp)
             lpq.update(input_norm=w_in, q=wq, k=wk, v=wv,
@@ -410,7 +429,8 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
 
         _, vjp_q = jax.vjp(seg_qkv, x, lp["input_norm"], lp["q"], lp["k"],
                            lp["v"], *[lp[k] for k in bias_keys])
-        dx2, d_in, d_q, d_k, d_v, *d_bs = vjp_q((dqf, dkf, dvf))
+        with scope("attention"):
+            dx2, d_in, d_q, d_k, d_v, *d_bs = vjp_q((dqf, dkf, dvf))
 
         gl = dict(input_norm=d_in, q=d_q, k=d_k, v=d_v, o=d_o,
                   post_norm=d_post,
@@ -423,7 +443,8 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
             return lax.dynamic_update_index_in_dim(
                 accl, cur + g.astype(accl.dtype), idx, 0)
 
-        gL = jax.tree.map(acc, gL, gl)
+        with scope("dw_accum"):
+            gL = jax.tree.map(acc, gL, gl)
         return (dx1 + dx2, gL), None
 
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
@@ -432,13 +453,15 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         (saved, params["layers"], jnp.arange(n_layers)), reverse=True)
 
     # ---------------- embedding + non-layer accumulate ----------------
-    (g_embed,) = vjp_embed(dx0)
+    with scope("embed"):
+        (g_embed,) = vjp_embed(dx0)
     new_acc = {"layers": g_layers}
-    for k in g_acc:
-        if k == "layers":
-            continue
-        g = g_nonlayer[k]
-        if k == "embedding":
-            g = g + g_embed if g is not None else g_embed
-        new_acc[k] = g_acc[k] + g.astype(g_acc[k].dtype)
+    with scope("head_ce"):  # with a tied head, the embedding's accumulation
+        for k in g_acc:
+            if k == "layers":
+                continue
+            g = g_nonlayer[k]
+            if k == "embedding":
+                g = g + g_embed if g is not None else g_embed
+            new_acc[k] = g_acc[k] + g.astype(g_acc[k].dtype)
     return new_acc, total, count, dropw

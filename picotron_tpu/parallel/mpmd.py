@@ -53,7 +53,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from functools import partial
 
 import jax
@@ -68,6 +67,7 @@ from picotron_tpu.config import Config
 from picotron_tpu.resilience import chaos, watchdog
 from picotron_tpu.telemetry import bus as telemetry_bus
 from picotron_tpu.telemetry.flightdeck.tracer import TID_PP_BASE
+from picotron_tpu.telemetry.spans import span
 from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.models.llama import (
     compute_dtype, embed, final_hidden, head_weight, model_rope_tables,
@@ -692,12 +692,13 @@ def _run_schedule(stages, table, chunk_params, accs, state_scalars,
     never a half-walked schedule's partial grads."""
     V = len(stages)
     nll_acc, cnt_acc = state_scalars
-    # flightdeck span tracer (telemetry/flightdeck): one fetch per walk,
-    # then a None check per op. When tracing, each op is synced like the
-    # sampled-timings path so span durations are real tick times (an
-    # opt-in perturbation, same as PICOTRON_PP_TICK_SAMPLE).
-    _tel = telemetry_bus.active()
-    tracer = getattr(_tel, "tracer", None) if _tel is not None else None
+    # Each op is one `pp.<stage>.<op>` span on its device group's lane
+    # (telemetry/spans.py). With a span tracer installed each op is also
+    # synced, like the sampled-timings path, so that span durations are
+    # real tick times (an opt-in perturbation, same as
+    # PICOTRON_PP_TICK_SAMPLE); without one the span times the dispatch.
+    synced = (timings is not None
+              or getattr(telemetry_bus.active(), "tracer", None) is not None)
     xbuf: dict = {}    # (vstage, mb) -> inbound activation
     xsave: dict = {}   # (vstage, mb) -> saved stage input for the backward
     gbuf: dict = {}    # (vstage, mb) -> inbound cotangent
@@ -712,59 +713,49 @@ def _run_schedule(stages, table, chunk_params, accs, state_scalars,
         if step is not None:
             chaos.fire("schedule_tick", step=step,
                        tick=op.tick, stage=j, op=op.op, mb=mb)
-        t0 = (time.perf_counter()
-              if (timings is not None or tracer is not None) else 0.0)
-        if op.op == "F":
-            if st.first:
-                y = st.fwd(chunk_params[j], ids_s, idx_first[mb])
-                xbuf[(j + 1, mb)] = jax.device_put(
-                    y, stages[j + 1].x_sharding)
-            elif st.last:
-                x_in = xbuf.pop((j, mb))
-                xsave[(j, mb)] = x_in
-                nll_mb, cnt_mb, nll_acc, cnt_acc = st.fwd(
-                    chunk_params[j], x_in, tgt_s, idx_last[mb],
-                    nll_acc, cnt_acc)
-                mb_nll[mb], mb_cnt[mb] = nll_mb, cnt_mb
-            else:
-                x_in = xbuf.pop((j, mb))
-                xsave[(j, mb)] = x_in
-                y = st.fwd(chunk_params[j], x_in)
-                xbuf[(j + 1, mb)] = jax.device_put(
-                    y, stages[j + 1].x_sharding)
-        elif op.op == "B":
-            if st.last:
-                accs[j], g_x = st.bwd(chunk_params[j], xsave.pop((j, mb)),
-                                      tgt_s, idx_last[mb], accs[j])
-                gbuf[(j - 1, mb)] = jax.device_put(
-                    g_x, stages[j - 1].x_sharding)
-            elif st.first:
-                accs[j] = st.bwd(chunk_params[j], ids_s, idx_first[mb],
-                                 gbuf.pop((j, mb)), accs[j])
-            else:
-                accs[j], g_x = st.bwd(chunk_params[j], xsave.pop((j, mb)),
-                                      gbuf.pop((j, mb)), accs[j])
-                gbuf[(j - 1, mb)] = jax.device_put(
-                    g_x, stages[j - 1].x_sharding)
-        else:  # pragma: no cover — zb tables are accounting-only
-            raise RuntimeError(
-                f"op {op.op!r} has no executable stage program")
-        if timings is not None or tracer is not None:
-            jax.block_until_ready(accs[j] if op.op == "B" else
-                                  (nll_acc if st.last else
-                                   xbuf.get((j + 1, mb))))
-            dt = time.perf_counter() - t0
-            if timings is not None:
-                timings.setdefault(op.group, []).append(dt)
-            if tracer is not None:
-                # One span per dispatched op on the owning device
-                # group's lane, named with the same stage/tick/op/mb
-                # coordinates the watchdog's last-touch string uses.
-                tracer.complete(
-                    f"stage{j}/tick{op.tick}/{op.op}/mb{mb}",
-                    tid=TID_PP_BASE + op.group, dur_s=dt,
-                    stage=j, tick=op.tick, op=op.op, mb=mb,
-                    step=step)
+        with span(f"pp.{j}.{op.op}", tid=TID_PP_BASE + op.group,
+                  tick=op.tick, mb=mb, step=step) as sp:
+            if op.op == "F":
+                if st.first:
+                    y = st.fwd(chunk_params[j], ids_s, idx_first[mb])
+                    xbuf[(j + 1, mb)] = jax.device_put(
+                        y, stages[j + 1].x_sharding)
+                elif st.last:
+                    x_in = xbuf.pop((j, mb))
+                    xsave[(j, mb)] = x_in
+                    nll_mb, cnt_mb, nll_acc, cnt_acc = st.fwd(
+                        chunk_params[j], x_in, tgt_s, idx_last[mb],
+                        nll_acc, cnt_acc)
+                    mb_nll[mb], mb_cnt[mb] = nll_mb, cnt_mb
+                else:
+                    x_in = xbuf.pop((j, mb))
+                    xsave[(j, mb)] = x_in
+                    y = st.fwd(chunk_params[j], x_in)
+                    xbuf[(j + 1, mb)] = jax.device_put(
+                        y, stages[j + 1].x_sharding)
+            elif op.op == "B":
+                if st.last:
+                    accs[j], g_x = st.bwd(chunk_params[j], xsave.pop((j, mb)),
+                                          tgt_s, idx_last[mb], accs[j])
+                    gbuf[(j - 1, mb)] = jax.device_put(
+                        g_x, stages[j - 1].x_sharding)
+                elif st.first:
+                    accs[j] = st.bwd(chunk_params[j], ids_s, idx_first[mb],
+                                     gbuf.pop((j, mb)), accs[j])
+                else:
+                    accs[j], g_x = st.bwd(chunk_params[j], xsave.pop((j, mb)),
+                                          gbuf.pop((j, mb)), accs[j])
+                    gbuf[(j - 1, mb)] = jax.device_put(
+                        g_x, stages[j - 1].x_sharding)
+            else:  # pragma: no cover — zb tables are accounting-only
+                raise RuntimeError(
+                    f"op {op.op!r} has no executable stage program")
+            if synced:
+                jax.block_until_ready(accs[j] if op.op == "B" else
+                                      (nll_acc if st.last else
+                                       xbuf.get((j + 1, mb))))
+        if timings is not None:
+            timings.setdefault(op.group, []).append(sp.secs)
     leftover = ([f"activation (vstage={j}, mb={m})" for j, m in sorted(xbuf)]
                 + [f"cotangent (vstage={j}, mb={m})" for j, m in sorted(gbuf)]
                 + [f"saved-input (vstage={j}, mb={m})"

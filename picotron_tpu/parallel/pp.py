@@ -78,6 +78,7 @@ from picotron_tpu.models.llama import (
     model_rope_tables, remat_policy_for, run_layers,
 )
 from picotron_tpu.ops.losses import IGNORE_INDEX, cross_entropy_sum_count
+from picotron_tpu.telemetry.scopes import scope
 
 
 def _vary_over(x, want):
@@ -173,27 +174,31 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
             return (y_sc.ravel()[0].astype(jnp.float32)
                     + params_sc[head_key].ravel()[0].astype(jnp.float32)) * 0.0
 
-        if gated:
-            # neutral branch merges to logz = log(tp_size) — finite garbage
-            # (never inf/nan: a nan would poison the masked accumulators'
-            # gradients through 0*nan), masked by the contrib select below
+        @scope("head_ce")
+        def score_microbatch():
+            if gated:
+                # neutral branch merges to logz = log(tp_size) — finite
+                # garbage (never inf/nan: a nan would poison the masked
+                # accumulators' gradients through 0*nan), masked by the
+                # contrib select below
 
-            def score(args):
-                y_sc, params_sc = args
-                hf = final_hidden(params_sc, y_sc, m)
-                return ctx.head_ce_local(hf, head_weight(params_sc), mb_tgt)
+                def score(args):
+                    y_sc, params_sc = args
+                    hf = final_hidden(params_sc, y_sc, m)
+                    return ctx.head_ce_local(hf, head_weight(params_sc),
+                                             mb_tgt)
 
-            def no_score(args):
-                a = _anchor(args)
-                zero = jnp.zeros(mb_tgt.shape, jnp.float32) + a
-                return (zero, zero + 1.0, zero)  # max=0, sumexp=1, label=0
+                def no_score(args):
+                    a = _anchor(args)
+                    zero = jnp.zeros(mb_tgt.shape, jnp.float32) + a
+                    return (zero, zero + 1.0, zero)  # max=0, sumexp=1, label=0
 
-            stats = lax.cond(s_idx == pp - 1, score, no_score, (y, params_v))
-            total = ctx.head_ce_merge(stats, mb_tgt)
-        elif ctx.head_ce is not None:
-            hf = final_hidden(params, y, m)
-            total, _ = ctx.head_ce(hf, head_weight(params), mb_tgt)
-        else:
+                stats = lax.cond(s_idx == pp - 1, score, no_score,
+                                 (y, params_v))
+                return ctx.head_ce_merge(stats, mb_tgt)
+            if ctx.head_ce is not None:
+                hf = final_hidden(params, y, m)
+                return ctx.head_ce(hf, head_weight(params), mb_tgt)[0]
             # no TP head hook (plain unsharded head): the whole scoring is
             # already collective-free, so the cond can return the total
 
@@ -201,11 +206,12 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
                 y_sc, params_sc = args
                 hf = final_hidden(params_sc, y_sc, m)
                 logits = hf @ head_weight(params_sc).astype(hf.dtype)
-                total, _ = cross_entropy_sum_count(logits, mb_tgt)
-                return total
+                return cross_entropy_sum_count(logits, mb_tgt)[0]
 
-            total = lax.cond(s_idx == pp - 1, score_full, _anchor,
-                             (y, params_v))
+            return lax.cond(s_idx == pp - 1, score_full, _anchor,
+                            (y, params_v))
+
+        total = score_microbatch()
         # `contrib` is stage-additive: the CE sum counts only on the last
         # stage (masked HERE, so the engines accumulate on every active
         # tick), while each stage contributes its own layers' (pre-weighted)
@@ -261,7 +267,8 @@ def pipeline_loss_sum_count(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         nll_acc = nll_acc + jnp.where(on, contrib, 0.0)
         cnt_acc = cnt_acc + jnp.where(on & (s_idx == pp - 1), cnt, 0)
         drop_acc = drop_acc + jnp.where(on, dropw, 0.0)
-        y_next = lax.ppermute(y * on.astype(y.dtype), "pp", fwd_perm)
+        with scope("pp_boundary"):
+            y_next = lax.ppermute(y * on.astype(y.dtype), "pp", fwd_perm)
         return (y_next, nll_acc, cnt_acc, drop_acc), None
 
     body = tick
@@ -386,7 +393,8 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         ring_new = lax.dynamic_update_index_in_dim(
             ring, x_buf, m_f % ring_slots, 0)
         ring = jnp.where(f_on, ring_new, ring)
-        y_send = lax.ppermute(y * f_on.astype(y.dtype), "pp", fwd_perm)
+        with scope("pp_boundary"):
+            y_send = lax.ppermute(y * f_on.astype(y.dtype), "pp", fwd_perm)
 
         # ---- backward unit: microbatch m_b retreats one stage ----
         # Last stage: b(m) == f(m), the input is this tick's live x_buf.
@@ -406,7 +414,8 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         g_params, g_x = vjp_fn((g_buf, g_nll))
         g_acc = jax.tree.map(
             lambda a, g: jnp.add(a, _cast_varying_like(g, a)), g_acc, g_params)
-        g_send = lax.ppermute(g_x, "pp", bwd_perm)
+        with scope("pp_boundary"):
+            g_send = lax.ppermute(g_x, "pp", bwd_perm)
 
         return (ring, y_send, g_send, g_acc, nll_acc, cnt_acc, drop_acc), None
 

@@ -31,6 +31,19 @@ import jax.numpy as jnp
 from jax import lax
 
 from picotron_tpu.ops.losses import IGNORE_INDEX
+from picotron_tpu.telemetry.scopes import scope
+
+# Every collective of the tensor-parallel hooks is entered under the
+# `tp_reduce` scope, so that a device trace can tell them from the
+# pipeline's sends (`pp_boundary`, parallel/pp.py) whatever the compiler
+# calls the instruction.
+
+
+@scope("tp_reduce")
+def tp_psum(x: jnp.ndarray, axis: str = "tp") -> jnp.ndarray:
+    """The row-parallel exit (`g`): psum over tp forward, identity
+    backward."""
+    return lax.psum(x, axis)
 
 
 def vocab_parallel_embed(w_shard: jnp.ndarray, ids: jnp.ndarray,
@@ -50,9 +63,10 @@ def vocab_parallel_embed(w_shard: jnp.ndarray, ids: jnp.ndarray,
     ok = (rel >= 0) & (rel < vshard)
     rel = jnp.clip(rel, 0, vshard - 1)
     x = w_shard[rel] * ok[..., None].astype(w_shard.dtype)
-    if scatter_seq:
-        return lax.psum_scatter(x, axis, scatter_dimension=1, tiled=True)
-    return lax.psum(x, axis)
+    with scope("tp_reduce"):
+        if scatter_seq:
+            return lax.psum_scatter(x, axis, scatter_dimension=1, tiled=True)
+        return lax.psum(x, axis)
 
 
 # -- sequence parallelism (SP) hooks ----------------------------------------
@@ -71,11 +85,13 @@ def vocab_parallel_embed(w_shard: jnp.ndarray, ids: jnp.ndarray,
 # engines.
 
 
+@scope("tp_reduce")
 def sp_gather_seq(x: jnp.ndarray, axis: str = "tp") -> jnp.ndarray:
     """[*, S/tp, H] -> [*, S, H]; the SP column-parallel entry (`f`)."""
     return lax.all_gather(x, axis, axis=1, tiled=True)
 
 
+@scope("tp_reduce")
 def sp_scatter_seq(x: jnp.ndarray, axis: str = "tp") -> jnp.ndarray:
     """partial [*, S, H] -> reduced [*, S/tp, H]; the SP row-parallel
     exit (`g`)."""
@@ -200,10 +216,11 @@ def vocab_parallel_ce_merge(stats, targets: jnp.ndarray, axis: str = "tp"):
     # pipeline's cond-anchored neutral stats, whose m_loc arrives with a
     # (zero-valued but non-symbolic) tangent that pmax cannot differentiate.
     m_loc = jax.lax.stop_gradient(m_loc)
-    m = lax.pmax(m_loc, axis)
-    sumexp = lax.psum(sumexp_loc * jnp.exp(m_loc - m), axis)
+    with scope("tp_reduce"):
+        m = lax.pmax(m_loc, axis)
+        sumexp = lax.psum(sumexp_loc * jnp.exp(m_loc - m), axis)
+        label = lax.psum(label_loc, axis)
     logz = m + jnp.log(sumexp)
-    label = lax.psum(label_loc, axis)
     valid = targets != IGNORE_INDEX
     nll = jnp.where(valid, logz - label, 0.0)
     return jnp.sum(nll)
@@ -216,6 +233,7 @@ def vocab_parallel_ce(hidden: jnp.ndarray, head_shard: jnp.ndarray,
     return total / jnp.maximum(count, 1)
 
 
+@scope("tp_reduce")
 def gather_logits(logits: jnp.ndarray, axis: str = "tp") -> jnp.ndarray:
     """all-gather vocab-sharded logits to full vocab on the last dim (the
     eval/debug path; ref: tp_communications.py:51-64 GatherFromModelParallel)."""

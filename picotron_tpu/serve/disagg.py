@@ -12,7 +12,7 @@ finished prefixes cross the boundary through an explicit
 discipline the pipeline executor uses for boundary activations.
 
 - **Prefill pool**: `prefill_slots` slots over `prefill_num_blocks`
-  blocks on `prefill_device`, running the SAME `_prefill_chunk_impl`
+  blocks on `prefill_device`, running the SAME `serve_prefill`
   program as the colocated engine (chunked, batched over mid-prefill
   slots). Admission is budgeted against THIS pool only.
 - **Decode pool**: `decode_slots` slots over `num_blocks` blocks on
@@ -59,6 +59,7 @@ from picotron_tpu.serve.engine import ServeEngine, _get_jits
 from picotron_tpu.serve.paged_cache import BlockPool, init_paged_cache
 from picotron_tpu.serve.scheduler import DisaggScheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
+from picotron_tpu.telemetry.spans import join_ids
 
 
 # ---------------------------------------------------------------------------
@@ -300,24 +301,23 @@ class DisaggServeEngine(ServeEngine):
 
     # -- one engine iteration ---------------------------------------------
 
-    def step(self, now: Optional[float] = None) -> bool:
+    def _step(self, now: float) -> bool:
         """Admit into the prefill pool; run ONE batched prefill chunk on
         the prefill placement; hand finished prefixes across the
         boundary; run ONE decode dispatch on the decode placement.
-        Returns whether any device work ran."""
-        if now is None:
-            now = time.perf_counter() - self._t0
+        Returns whether any device work ran. (`ServeEngine.step` wraps it
+        in the `serve.step` span; the leaf spans are the same, plus
+        `serve.handoff`.)"""
         reg = self.telemetry.registry
 
-        for pslot, st in self.sched.admit(now):
-            self._sync_ptable(pslot)
-            wait = max(now - st.req.arrival, 0.0)
-            self.telemetry.emit("phase", phase="queue_wait",
-                                category="queue_wait", secs=wait,
-                                id=st.req.id)
-            reg.histogram("serve/queue_wait").observe(wait)
-        for st in self.sched.drain_shed():
-            self._emit_shed(st, now)
+        with self._span("serve.admit") as sp:
+            admitted = self.sched.admit(now)
+            for pslot, st in admitted:
+                self._sync_ptable(pslot)
+                self._note_admitted(st, now, reg)
+            for st in self.sched.drain_shed():
+                self._emit_shed(st, now)
+            sp.set(admitted=len(admitted), queued=len(self.sched.queue))
 
         worked = False
 
@@ -325,41 +325,50 @@ class DisaggServeEngine(ServeEngine):
         pslots = self.sched.prefill_slots()
         if pslots:
             c = self.scfg.prefill_chunk
-            ids = np.zeros((self.num_pslots, c), np.int32)
-            start = np.zeros((self.num_pslots,), np.int32)
-            nval = np.zeros((self.num_pslots,), np.int32)
-            rids = np.zeros((self.num_pslots,), np.int32)
-            tidx = np.zeros((self.num_pslots,), np.int32)
-            finals = []
-            for s in pslots:
-                st = self.sched.pslots[s]
-                chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-                ids[s, :len(chunk)] = chunk
-                start[s] = st.n_prefilled
-                nval[s] = len(chunk)
-                rids[s] = st.req.id
-                tidx[s] = len(st.generated)
-                if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
-                    finals.append(s)
-            up = partial(jax.device_put, device=self._sh_p)
+            with self._span("serve.prefill.build"):
+                ids = np.zeros((self.num_pslots, c), np.int32)
+                start = np.zeros((self.num_pslots,), np.int32)
+                nval = np.zeros((self.num_pslots,), np.int32)
+                rids = np.zeros((self.num_pslots,), np.int32)
+                tidx = np.zeros((self.num_pslots,), np.int32)
+                finals = []
+                for s in pslots:
+                    st = self.sched.pslots[s]
+                    chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
+                    ids[s, :len(chunk)] = chunk
+                    start[s] = st.n_prefilled
+                    nval[s] = len(chunk)
+                    rids[s] = st.req.id
+                    tidx[s] = len(st.generated)
+                    if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
+                        finals.append(s)
+                up = partial(jax.device_put, device=self._sh_p)
+                feed = (up(self._tables_p), up(ids), up(start), up(nval),
+                        up(rids), up(tidx))
+            n_prefilled = int(nval.sum())
+            req_ids = [int(rids[s]) for s in pslots]
             self._drain_compile()
             if watchdog.active():
                 watchdog.touch(
                     f"serve engine={self.engine_id} dispatch=prefill")
             t0 = time.perf_counter()
-            self._k_p, self._v_p, toks_d = self._prefill_jit(
-                self.params_p, self._k_p, self._v_p, up(self._tables_p),
-                up(ids), up(start), up(nval), up(rids), up(tidx),
-                self.base_key_p, self.cos_p, self.sin_p, cfg=self.cfg,
-                temperature=self.temperature, top_k=self.top_k)
-            toks = np.asarray(toks_d) if finals else None
+            with self._span("serve.prefill.dispatch", slots=len(pslots),
+                            tokens=n_prefilled, capacity=self.num_pslots * c,
+                            ids=join_ids(req_ids)):
+                self._k_p, self._v_p, toks_d = self._prefill_jit(
+                    self.params_p, self._k_p, self._v_p, *feed,
+                    self.base_key_p, self.cos_p, self.sin_p, cfg=self.cfg,
+                    temperature=self.temperature, top_k=self.top_k)
+            toks = None
+            if finals:
+                with self._span("serve.prefill.wait", finals=len(finals)):
+                    toks = np.asarray(toks_d)
             dt = time.perf_counter() - t0
             dt -= min(self._drain_compile(), dt)
-            n_prefilled = int(nval.sum())
             self.telemetry.emit("phase", phase="prefill",
                                 category="prefill", secs=dt,
                                 tokens=n_prefilled, pool="prefill",
-                                ids=[int(rids[s]) for s in pslots])
+                                ids=req_ids, waited=bool(finals))
             for s in pslots:
                 self.sched.note_prefilled(s, int(nval[s]))
             self.stats["prefill_chunks"] += len(pslots)
@@ -392,7 +401,9 @@ class DisaggServeEngine(ServeEngine):
                 break  # youngest everywhere — wait for decode capacity
             dslot, src, dst, preempted = got
             t0 = time.perf_counter()
-            self._copy_blocks(src, dst)
+            with self._span("serve.handoff", blocks=len(src),
+                            id=self.sched.slots[dslot].req.id):
+                self._copy_blocks(src, dst)
             dt = time.perf_counter() - t0
             dt -= min(self._drain_compile(), dt)
             self._sync_ptable(pslot)

@@ -71,10 +71,17 @@ from picotron_tpu.serve.paged_cache import (
 )
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
+from picotron_tpu.telemetry.flightdeck.tracer import TID_SERVE
+from picotron_tpu.telemetry.scopes import scope
+from picotron_tpu.telemetry.spans import join_ids
 
 
 # ---------------------------------------------------------------------------
-# Device programs (module-level so every engine shares one jit cache)
+# Device programs (module-level so every engine shares one jit cache). The
+# functions' names are the programs' module names (`jit_serve_decode`,
+# `jit_serve_prefill`): a device trace lists each execution under them on
+# its `XLA Modules` line, which is where the benchmark finds the two
+# programs' times. Pinned by tests/test_scopes.py.
 # ---------------------------------------------------------------------------
 
 
@@ -87,10 +94,28 @@ def _fold_keys(base_key, rids, tidx):
     )(rids, tidx)
 
 
-def _decode_step_impl(params, k, v, tables, toks, positions, rids, tidx,
-                      base_key, cos, sin, cfg: ModelConfig,
-                      temperature: float, top_k: int, interval: int,
-                      eos_token_id):
+@scope("sample")
+def _sample_slots(logits, temperature: float, top_k: int, base_key, rids,
+                  tidx):
+    """Each slot's next token from its logits [S, V]: greedy, or a draw
+    under the slot's own (request id, token index) key — one sampling law
+    for the prefill and the decode program."""
+    if temperature == 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    lg = logits / temperature
+    if top_k > 0:
+        kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
+        lg = jnp.where(lg < kth, -jnp.inf, lg)
+    keys = _fold_keys(base_key, rids, tidx)
+    return jax.vmap(
+        lambda l, key: jax.random.categorical(key, l)
+    )(lg, keys).astype(jnp.int32)
+
+
+def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
+                 base_key, cos, sin, cfg: ModelConfig,
+                 temperature: float, top_k: int, interval: int,
+                 eos_token_id):
     """`interval` decode steps over all slots inside ONE dispatch (a
     lax.scan — amortizes per-dispatch host overhead over interval tokens
     per slot; the same reason offline generate scans its whole decode).
@@ -110,17 +135,7 @@ def _decode_step_impl(params, k, v, tables, toks, positions, rids, tidx,
         x, cache = _decode_layers(params, x, cache, positions[:, None],
                                   cfg, cos, sin)
         logits = _logits_last(params, x, cfg)  # [S, V] fp32
-        if temperature == 0.0:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            lg = logits / temperature
-            if top_k > 0:
-                kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-                lg = jnp.where(lg < kth, -jnp.inf, lg)
-            keys = _fold_keys(base_key, rids, tidx)
-            nxt = jax.vmap(
-                lambda l, key: jax.random.categorical(key, l)
-            )(lg, keys).astype(jnp.int32)
+        nxt = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
         if eos_token_id is not None:
             nxt = jnp.where(done, eos_token_id, nxt)
             done = done | (nxt == eos_token_id)
@@ -135,9 +150,9 @@ def _decode_step_impl(params, k, v, tables, toks, positions, rids, tidx,
     return toks_all.T, last, positions, tidx, cache.k, cache.v
 
 
-def _prefill_chunk_impl(params, k, v, table_rows, chunk_ids, start_pos,
-                        n_valid, rids, tidx, base_key, cos, sin,
-                        cfg: ModelConfig, temperature: float, top_k: int):
+def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
+                  n_valid, rids, tidx, base_key, cos, sin,
+                  cfg: ModelConfig, temperature: float, top_k: int):
     """Prefill the next chunk of EVERY mid-prefill slot in one dispatch:
     chunk_ids [S, C] (padded), start_pos/n_valid/rids/tidx [S],
     table_rows [S, max_blocks]. Rows with n_valid = 0 are idle slots
@@ -160,17 +175,7 @@ def _prefill_chunk_impl(params, k, v, table_rows, chunk_ids, start_pos,
     hf = final_hidden(params, h_last, cfg)
     logits = (hf @ head_weight(params).astype(hf.dtype))[:, 0]
     logits = logits.astype(jnp.float32)  # [S, V]
-    if temperature == 0.0:
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        lg = logits / temperature
-        if top_k > 0:
-            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        keys = _fold_keys(base_key, rids, tidx)
-        toks = jax.vmap(
-            lambda l, key: jax.random.categorical(key, l)
-        )(lg, keys).astype(jnp.int32)
+    toks = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
     return cache.k, cache.v, toks
 
 
@@ -185,10 +190,10 @@ def _get_jits(donate: bool):
     if donate not in _JITS:
         dargs = (1, 2) if donate else ()
         _JITS[donate] = (
-            jax.jit(_decode_step_impl, donate_argnums=dargs,
+            jax.jit(serve_decode, donate_argnums=dargs,
                     static_argnames=("cfg", "temperature", "top_k",
                                      "interval", "eos_token_id")),
-            jax.jit(_prefill_chunk_impl, donate_argnums=dargs,
+            jax.jit(serve_prefill, donate_argnums=dargs,
                     static_argnames=("cfg", "temperature", "top_k")),
         )
     return _JITS[donate]
@@ -379,6 +384,10 @@ class ServeEngine:
 
     # -- helpers -----------------------------------------------------------
 
+    def _span(self, name: str, **counts):
+        """A span on the serve lane (telemetry/spans.py)."""
+        return self.telemetry.span(name, tid=TID_SERVE, **counts)
+
     def _sync_table(self, slot: int) -> None:
         st = self.sched.slots[slot]
         row = np.full((self.max_blocks,), self.num_blocks, np.int32)
@@ -450,23 +459,28 @@ class ServeEngine:
     def step(self, now: Optional[float] = None) -> bool:
         """Admit; run ONE prefill chunk (if any prompt is mid-prefill);
         run ONE decode step over the slot batch; retire. Returns whether
-        any device work ran."""
+        any device work ran.
+
+        The step is one `serve.step` span whose leaf spans say what the
+        host was doing (`serve.admit`, `serve.prefill.build | dispatch |
+        wait`, `serve.decode.build | dispatch | wait | emit`), each with
+        its counts taken at the same boundary: telemetry/spans.py."""
         if now is None:
             now = time.perf_counter() - self._t0
+        with self._span("serve.step"):
+            return self._step(now)
+
+    def _step(self, now: float) -> bool:
         reg = self.telemetry.registry
 
-        for slot, st in self.sched.admit(now):
-            self._sync_table(slot)
-            wait = max(now - st.req.arrival, 0.0)
-            # "phase" events carry (category, secs) so a post-hoc sum of
-            # the JSONL reproduces the in-process ledger, exactly like the
-            # training stream's phase events
-            self.telemetry.emit("phase", phase="queue_wait",
-                                category="queue_wait", secs=wait,
-                                id=st.req.id)
-            reg.histogram("serve/queue_wait").observe(wait)
-        for st in self.sched.drain_shed():
-            self._emit_shed(st, now)
+        with self._span("serve.admit") as sp:
+            admitted = self.sched.admit(now)
+            for slot, st in admitted:
+                self._sync_table(slot)
+                self._note_admitted(st, now, reg)
+            for st in self.sched.drain_shed():
+                self._emit_shed(st, now)
+            sp.set(admitted=len(admitted), queued=len(self.sched.queue))
 
         worked = False
 
@@ -475,23 +489,28 @@ class ServeEngine:
         pslots = self.sched.prefill_slots()
         if pslots:
             c = self.scfg.prefill_chunk
-            ids = np.zeros((self.num_slots, c), np.int32)
-            start = np.zeros((self.num_slots,), np.int32)
-            nval = np.zeros((self.num_slots,), np.int32)
-            rids = np.zeros((self.num_slots,), np.int32)
-            tidx = np.zeros((self.num_slots,), np.int32)
-            finals = []
-            for s in pslots:
-                st = self.sched.slots[s]
-                chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-                ids[s, :len(chunk)] = chunk
-                start[s] = st.n_prefilled
-                nval[s] = len(chunk)
-                rids[s] = st.req.id
-                tidx[s] = len(st.generated)
-                if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
-                    finals.append(s)
-            up = partial(jax.device_put, device=self._rep_sh)
+            with self._span("serve.prefill.build"):
+                ids = np.zeros((self.num_slots, c), np.int32)
+                start = np.zeros((self.num_slots,), np.int32)
+                nval = np.zeros((self.num_slots,), np.int32)
+                rids = np.zeros((self.num_slots,), np.int32)
+                tidx = np.zeros((self.num_slots,), np.int32)
+                finals = []
+                for s in pslots:
+                    st = self.sched.slots[s]
+                    chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
+                    ids[s, :len(chunk)] = chunk
+                    start[s] = st.n_prefilled
+                    nval[s] = len(chunk)
+                    rids[s] = st.req.id
+                    tidx[s] = len(st.generated)
+                    if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
+                        finals.append(s)
+                up = partial(jax.device_put, device=self._rep_sh)
+                feed = (up(self._tables), up(ids), up(start), up(nval),
+                        up(rids), up(tidx))
+            n_prefilled = int(nval.sum())
+            req_ids = [int(rids[s]) for s in pslots]
             self._drain_compile()
             if watchdog.active():
                 # a hang inside this dispatch is reported as THIS
@@ -500,19 +519,28 @@ class ServeEngine:
                 watchdog.touch(
                     f"serve engine={self.engine_id} dispatch=prefill")
             t0 = time.perf_counter()
-            self._k, self._v, toks_d = self._prefill_jit(
-                self.params, self._k, self._v, up(self._tables), up(ids),
-                up(start), up(nval), up(rids), up(tidx), self.base_key,
-                self.cos, self.sin, cfg=self.cfg,
-                temperature=self.temperature, top_k=self.top_k)
-            toks = np.asarray(toks_d) if finals else None
+            # `capacity` is what the program computes whatever `slots` is
+            with self._span("serve.prefill.dispatch", slots=len(pslots),
+                            tokens=n_prefilled, capacity=self.num_slots * c,
+                            ids=join_ids(req_ids)):
+                self._k, self._v, toks_d = self._prefill_jit(
+                    self.params, self._k, self._v, *feed, self.base_key,
+                    self.cos, self.sin, cfg=self.cfg,
+                    temperature=self.temperature, top_k=self.top_k)
+            toks = None
+            if finals:
+                # the host needs a token only when a prompt ends in the
+                # chunk; otherwise the dispatch is left in flight
+                with self._span("serve.prefill.wait", finals=len(finals)):
+                    toks = np.asarray(toks_d)
             dt = time.perf_counter() - t0
             dt -= min(self._drain_compile(), dt)
-            n_prefilled = int(nval.sum())
+            # `waited`: whether `secs` is the device's time for the chunk
+            # or only the enqueue
             self.telemetry.emit("phase", phase="prefill",
                                 category="prefill", secs=dt,
-                                tokens=n_prefilled,
-                                ids=[int(rids[s]) for s in pslots])
+                                tokens=n_prefilled, ids=req_ids,
+                                waited=bool(finals))
             for s in pslots:
                 self.sched.note_prefilled(s, int(nval[s]))
             self.stats["prefill_chunks"] += len(pslots)
@@ -545,6 +573,18 @@ class ServeEngine:
                 self.stats["decode_stall_ticks_max"], self._stall_streak)
         return worked
 
+    def _note_admitted(self, st, now: float, reg) -> None:
+        """One request left the queue: its wait is a `phase` event (which
+        carries (category, secs) so a post-hoc sum of the JSONL reproduces
+        the in-process ledger, like the training stream's), a histogram
+        sample, and a `serve.queue_wait` span that ends now."""
+        wait = max(now - st.req.arrival, 0.0)
+        self.telemetry.emit("phase", phase="queue_wait",
+                            category="queue_wait", secs=wait, id=st.req.id)
+        self.telemetry.record_wait("serve.queue_wait", wait, tid=TID_SERVE,
+                                   id=st.req.id)
+        reg.histogram("serve/queue_wait").observe(wait)
+
     def _decode_tick(self, now: float, reg) -> bool:
         """One decode dispatch over every decode-ready slot. Operates
         purely through the scheduler's decode interface plus the
@@ -552,10 +592,12 @@ class ServeEngine:
         _rep_sh), so the disaggregated engine reuses it verbatim against
         its decode pool. Returns whether a dispatch ran."""
         ready = self.sched.decode_ready()
-        if ready:
+        if not ready:
+            return False
+        interval = self.scfg.decode_interval
+        with self._span("serve.decode.build") as sp:
             active = []
             dropped: set = set()
-            interval = self.scfg.decode_interval
             # a speculative iteration can advance a slot by up to
             # 1 + draft_len positions, so the write horizon (and the
             # block allocation backing it) scales with it
@@ -578,122 +620,130 @@ class ServeEngine:
             # a later ensure_block can preempt a slot already activated
             # (it was younger than the one needing the block)
             active = [s for s in active if s not in dropped]
-            if active:
-                ds = self._decode_state
-                if ds is None or ds["active"] != active:
-                    # slow path: roster changed — rebuild inputs on host,
-                    # uploaded with the shardings earlier calls produced
-                    # so the rebuild cannot mint a new jit variant
-                    toks = np.zeros((self.num_slots,), np.int32)
-                    positions = np.full((self.num_slots,), -1, np.int32)
-                    rids = np.zeros((self.num_slots,), np.int32)
-                    tidx = np.zeros((self.num_slots,), np.int32)
-                    for s in active:
-                        st = self.sched.slots[s]
-                        toks[s] = st.last_token
-                        positions[s] = st.write_pos
-                        rids[s] = st.req.id
-                        tidx[s] = len(st.generated)
-                    up = partial(jax.device_put, device=self._rep_sh)
-                    ds = {"active": list(active),
-                          "tables": up(self._tables),
-                          "toks": up(toks),
-                          "positions": up(positions),
-                          "rids": up(rids),
-                          "tidx": up(tidx)}
-                    if self.speculate:
-                        from picotron_tpu.serve.spec_decode import (
-                            context_rows,
-                        )
-                        ds["ctx"] = up(context_rows(
-                            self.sched.slots, active, self.num_slots))
-                self._drain_compile()
-                if watchdog.active():
-                    watchdog.touch(
-                        f"serve engine={self.engine_id} dispatch=decode")
-                t0 = time.perf_counter()
-                nval = None
-                if self.speculate:
-                    (toks_d, nval_d, last_d, pos_d, tidx_d, ctx_d,
-                     self._k, self._v) = self._decode_jit(
-                        self.params, self._k, self._v,
-                        ds["tables"], ds["toks"], ds["positions"],
-                        ds["rids"], ds["tidx"], ds["ctx"], self.base_key,
-                        self.cos, self.sin, cfg=self.cfg,
-                        temperature=self.temperature, top_k=self.top_k,
-                        interval=interval,
-                        eos_token_id=self.eos_token_id,
-                        draft_len=self.draft_len)
-                    nxt = np.asarray(toks_d)   # [S, interval, 1+d]
-                    nval = np.asarray(nval_d)  # [S, interval]
-                    state = dict(ds, toks=last_d, positions=pos_d,
-                                 tidx=tidx_d, ctx=ctx_d)
-                else:
-                    toks_d, last_d, pos_d, tidx_d, self._k, self._v = \
-                        self._decode_jit(
-                            self.params, self._k, self._v,
-                            ds["tables"], ds["toks"], ds["positions"],
-                            ds["rids"], ds["tidx"], self.base_key,
-                            self.cos, self.sin, cfg=self.cfg,
-                            temperature=self.temperature,
-                            top_k=self.top_k, interval=interval,
-                            eos_token_id=self.eos_token_id)
-                    nxt = np.asarray(toks_d)  # [S, interval]
-                    state = dict(ds, toks=last_d, positions=pos_d,
-                                 tidx=tidx_d)
-                # feed outputs forward; any roster/table change below
-                # nulls this via _sync_table
-                self._decode_state = state
-                dt = time.perf_counter() - t0
-                csecs = self._drain_compile()
-                if csecs:
-                    self.stats["decode_compiles"] += 1
-                dt -= min(csecs, dt)
-                # Request ids snapshotted before the retire loop below
-                # frees slots — tags the decode phase event (and its
-                # flightdeck span) with the requests it advanced.
-                dec_ids = [self.sched.slots[s].req.id for s in active]
-                n_tokens = 0
+            ds = self._decode_state
+            rebuilt = bool(active) and (ds is None or ds["active"] != active)
+            if rebuilt:
+                # slow path: roster changed — rebuild inputs on host,
+                # uploaded with the shardings earlier calls produced
+                # so the rebuild cannot mint a new jit variant
+                toks = np.zeros((self.num_slots,), np.int32)
+                positions = np.full((self.num_slots,), -1, np.int32)
+                rids = np.zeros((self.num_slots,), np.int32)
+                tidx = np.zeros((self.num_slots,), np.int32)
                 for s in active:
                     st = self.sched.slots[s]
-                    retired = False
-                    for t in range(interval):
-                        if retired:
+                    toks[s] = st.last_token
+                    positions[s] = st.write_pos
+                    rids[s] = st.req.id
+                    tidx[s] = len(st.generated)
+                up = partial(jax.device_put, device=self._rep_sh)
+                ds = {"active": list(active),
+                      "tables": up(self._tables),
+                      "toks": up(toks),
+                      "positions": up(positions),
+                      "rids": up(rids),
+                      "tidx": up(tidx)}
+                if self.speculate:
+                    from picotron_tpu.serve.spec_decode import (
+                        context_rows,
+                    )
+                    ds["ctx"] = up(context_rows(
+                        self.sched.slots, active, self.num_slots))
+            sp.set(rebuilt=int(rebuilt), preempted=len(dropped))
+        if not active:
+            return False
+        self._drain_compile()
+        if watchdog.active():
+            watchdog.touch(
+                f"serve engine={self.engine_id} dispatch=decode")
+        # Request ids snapshotted before the retire loop below frees
+        # slots — they tag the dispatch span and the decode phase event
+        # with the requests it advanced.
+        dec_ids = [self.sched.slots[s].req.id for s in active]
+        t0 = time.perf_counter()
+        nval = None
+        with self._span("serve.decode.dispatch", active=len(active),
+                        interval=interval, ids=join_ids(dec_ids)):
+            if self.speculate:
+                (toks_d, nval_d, last_d, pos_d, tidx_d, ctx_d,
+                 self._k, self._v) = self._decode_jit(
+                    self.params, self._k, self._v,
+                    ds["tables"], ds["toks"], ds["positions"],
+                    ds["rids"], ds["tidx"], ds["ctx"], self.base_key,
+                    self.cos, self.sin, cfg=self.cfg,
+                    temperature=self.temperature, top_k=self.top_k,
+                    interval=interval,
+                    eos_token_id=self.eos_token_id,
+                    draft_len=self.draft_len)
+                state = dict(ds, toks=last_d, positions=pos_d,
+                             tidx=tidx_d, ctx=ctx_d)
+            else:
+                toks_d, last_d, pos_d, tidx_d, self._k, self._v = \
+                    self._decode_jit(
+                        self.params, self._k, self._v,
+                        ds["tables"], ds["toks"], ds["positions"],
+                        ds["rids"], ds["tidx"], self.base_key,
+                        self.cos, self.sin, cfg=self.cfg,
+                        temperature=self.temperature,
+                        top_k=self.top_k, interval=interval,
+                        eos_token_id=self.eos_token_id)
+                state = dict(ds, toks=last_d, positions=pos_d,
+                             tidx=tidx_d)
+        with self._span("serve.decode.wait"):
+            nxt = np.asarray(toks_d)  # [S, interval] ([.., 1+d] speculative)
+            if self.speculate:
+                nval = np.asarray(nval_d)  # [S, interval]
+        # feed outputs forward; any roster/table change below
+        # nulls this via _sync_table
+        self._decode_state = state
+        dt = time.perf_counter() - t0
+        csecs = self._drain_compile()
+        if csecs:
+            self.stats["decode_compiles"] += 1
+        dt -= min(csecs, dt)
+        n_tokens = n_retired = 0
+        with self._span("serve.decode.emit") as sp:
+            for s in active:
+                st = self.sched.slots[s]
+                retired = False
+                for t in range(interval):
+                    if retired:
+                        break
+                    if self.speculate:
+                        emit = [int(x)
+                                for x in nxt[s, t, :int(nval[s, t])]]
+                        self.stats["draft_tokens"] += self.draft_len
+                        self.stats["accepted_draft_tokens"] += (
+                            len(emit) - 1)
+                    else:
+                        emit = [int(nxt[s, t])]
+                    for tok in emit:
+                        st.generated.append(tok)
+                        n_tokens += 1
+                        if self.sched.should_retire(
+                                s, self.eos_token_id):
+                            # tokens past EOS/budget are padding
+                            rst = self.sched.retire(s)
+                            self._sync_table(s)
+                            self._emit_retired(rst, now + dt)
+                            retired = True
+                            n_retired += 1
                             break
-                        if self.speculate:
-                            emit = [int(x)
-                                    for x in nxt[s, t, :int(nval[s, t])]]
-                            self.stats["draft_tokens"] += self.draft_len
-                            self.stats["accepted_draft_tokens"] += (
-                                len(emit) - 1)
-                        else:
-                            emit = [int(nxt[s, t])]
-                        for tok in emit:
-                            st.generated.append(tok)
-                            n_tokens += 1
-                            if self.sched.should_retire(
-                                    s, self.eos_token_id):
-                                # tokens past EOS/budget are padding
-                                rst = self.sched.retire(s)
-                                self._sync_table(s)
-                                self._emit_retired(rst, now + dt)
-                                retired = True
-                                break
-                self.telemetry.emit("phase", phase="decode",
-                                    category="decode", secs=dt,
-                                    tokens=n_tokens, ids=dec_ids)
-                reg.histogram("serve/token_latency").observe(
-                    dt / max(n_tokens if self.speculate
-                             else len(active) * interval, 1))
-                self.stats["decode_steps"] += 1
-                self.stats["occupancy_sum"] += len(active) / self.num_slots
-                self.stats["output_tokens"] += n_tokens
-                reg.gauge("serve/slot_occupancy").set(
-                    len(active) / self.num_slots)
-                reg.gauge("serve/pool_utilization").set(
-                    self.pool.in_use / self.num_blocks)
-                return True
-        return False
+            sp.set(tokens=n_tokens, retired=n_retired)
+        self.telemetry.emit("phase", phase="decode",
+                            category="decode", secs=dt,
+                            tokens=n_tokens, ids=dec_ids)
+        reg.histogram("serve/token_latency").observe(
+            dt / max(n_tokens if self.speculate
+                     else len(active) * interval, 1))
+        self.stats["decode_steps"] += 1
+        self.stats["occupancy_sum"] += len(active) / self.num_slots
+        self.stats["output_tokens"] += n_tokens
+        reg.gauge("serve/slot_occupancy").set(
+            len(active) / self.num_slots)
+        reg.gauge("serve/pool_utilization").set(
+            self.pool.in_use / self.num_blocks)
+        return True
 
     # -- trace driver ------------------------------------------------------
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from picotron_tpu.config import ModelConfig
 from picotron_tpu.models.llama import compute_dtype
+from picotron_tpu.telemetry.scopes import scope
 
 
 class PagedKVCache(NamedTuple):
@@ -62,6 +63,7 @@ class PagedKVCache(NamedTuple):
     def block_size(self) -> int:
         return self.k.shape[2]
 
+    @scope("kv_write")
     def write(self, li, k_new, v_new, q_pos) -> "PagedKVCache":
         """Scatter K/V [B, s, Hkv, D] into each token's (physical block,
         offset) slot of layer li. q_pos: [s] batch-shared or [B, s]

@@ -40,6 +40,7 @@ from picotron_tpu.generate import _decode_layers
 from picotron_tpu.models.llama import compute_dtype, final_hidden, head_weight
 from picotron_tpu.serve.engine import _fold_keys
 from picotron_tpu.serve.paged_cache import PagedKVCache
+from picotron_tpu.telemetry.scopes import scope
 
 # Drafter constants (static — baked into the compiled program).
 NGRAM_K = 2    # trailing gram length the drafter matches on
@@ -94,13 +95,13 @@ def _ngram_draft(ctx, last_tok, draft_len: int):
     return jnp.where(has[:, None], draft, last_tok[:, None])
 
 
-def _spec_decode_step_impl(params, k, v, tables, toks, positions, rids,
-                           tidx, ctx, base_key, cos, sin,
-                           cfg: ModelConfig, temperature: float,
-                           top_k: int, interval: int, eos_token_id,
-                           draft_len: int):
+def serve_decode_spec(params, k, v, tables, toks, positions, rids,
+                      tidx, ctx, base_key, cos, sin,
+                      cfg: ModelConfig, temperature: float,
+                      top_k: int, interval: int, eos_token_id,
+                      draft_len: int):
     """`interval` speculative decode iterations over all slots in ONE
-    dispatch. Shapes mirror engine._decode_step_impl with two additions:
+    dispatch. Shapes mirror engine.serve_decode with two additions:
     ctx [S, CTX_W] (drafter window) and the ragged outputs — each
     iteration emits between 1 and 1 + draft_len tokens per slot, so
     tokens come back as [S, interval, 1 + draft_len] plus a per-iteration
@@ -122,21 +123,22 @@ def _spec_decode_step_impl(params, k, v, tables, toks, positions, rids,
         hf = final_hidden(params, x, cfg)                    # [S, 1+d, H]
         logits = (hf @ head_weight(params).astype(hf.dtype)
                   ).astype(jnp.float32)                      # [S, 1+d, V]
-        if temperature == 0.0:
-            tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            lg = logits / temperature
-            if top_k > 0:
-                kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-                lg = jnp.where(lg < kth, -jnp.inf, lg)
-            # column j's token, if emitted, is output token tidx + j —
-            # key it exactly as the non-speculative step would
-            keys = jax.vmap(_fold_keys, in_axes=(None, None, 0),
-                            out_axes=1)(base_key, rids, (tidx[:, None]
-                                                         + offs).T)
-            tgt = jax.vmap(jax.vmap(
-                lambda l, key: jax.random.categorical(key, l)
-            ))(lg, keys).astype(jnp.int32)
+        with scope("sample"):
+            if temperature == 0.0:
+                tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                lg = logits / temperature
+                if top_k > 0:
+                    kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
+                    lg = jnp.where(lg < kth, -jnp.inf, lg)
+                # column j's token, if emitted, is output token tidx + j —
+                # key it exactly as the non-speculative step would
+                keys = jax.vmap(_fold_keys, in_axes=(None, None, 0),
+                                out_axes=1)(base_key, rids, (tidx[:, None]
+                                                             + offs).T)
+                tgt = jax.vmap(jax.vmap(
+                    lambda l, key: jax.random.categorical(key, l)
+                ))(lg, keys).astype(jnp.int32)
         if eos_token_id is not None:
             tgt = jnp.where(done[:, None], eos_token_id, tgt)
         # accept the longest draft prefix matching the targets: draft
@@ -184,7 +186,7 @@ def get_spec_jit(donate: bool):
     if donate not in _SPEC_JITS:
         dargs = (1, 2) if donate else ()
         _SPEC_JITS[donate] = jax.jit(
-            _spec_decode_step_impl, donate_argnums=dargs,
+            serve_decode_spec, donate_argnums=dargs,
             static_argnames=("cfg", "temperature", "top_k", "interval",
                              "eos_token_id", "draft_len"))
     return _SPEC_JITS[donate]

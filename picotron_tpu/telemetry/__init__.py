@@ -15,7 +15,13 @@ resilience machinery report through. One `Telemetry` facade owns:
   / data stall / ...), fed by the phases and by events the resilience
   modules emit through `telemetry.bus`,
 - a `CompileWatch` (jax.monitoring) that measures XLA compile time
-  exactly and flags unexpected re-jits of the step.
+  exactly and flags unexpected re-jits of the step,
+- `span(name, **counts)` (telemetry/spans.py), the program's one tracing
+  mechanism: a region of host code as a `jax.profiler.TraceAnnotation`
+  (in the profiler's trace, beside the device, whenever a profile is
+  open) and, with a flightdeck `SpanTracer` installed, a span recorded
+  where it starts and ends. `telemetry/scopes.py` holds the names of the
+  device programs' regions (`jax.named_scope`).
 
 Post-hoc: `tools/telemetry_report.py` summarizes a JSONL stream (goodput
 %, phase breakdown, event counts) for run triage; the per-phase category
@@ -24,6 +30,9 @@ mapping is shared so in-process and post-hoc accounting agree.
 JSONL schema (one object per line; `ts` = time.time()):
 
   {"ts", "kind": "phase", "phase", "step", "secs", "category"}
+      # serve: phase queue_wait | prefill | decode | handoff with id / ids,
+      # tokens; prefill carries `waited` (whether `secs` includes the
+      # host's wait for the device or only the enqueue)
   {"ts", "kind": "step",  "step", "loss", "tokens_per_sec",
    "tokens_per_sec_per_chip", "mfu", "trained_tokens", "memory_gb", ...}
   {"ts", "kind": "eval",  "step", "val_loss"}
@@ -38,7 +47,7 @@ import time
 from typing import Optional
 
 from picotron_tpu.telemetry import bus
-from picotron_tpu.telemetry.flightdeck.tracer import TID_SERVE, TID_TRAIN
+from picotron_tpu.telemetry.flightdeck.tracer import TID_TRAIN
 from picotron_tpu.telemetry.goodput import (
     CATEGORIES, GOODPUT_CATEGORIES, PHASE_CATEGORY, GoodputLedger,
 )
@@ -50,6 +59,7 @@ from picotron_tpu.telemetry.registry import (
 from picotron_tpu.telemetry.sinks import (
     JsonlSink, Sink, StdoutSink, WandbSink, telemetry_jsonl_path,
 )
+from picotron_tpu.telemetry.spans import Span, span
 
 __all__ = [
     "CATEGORIES",
@@ -64,16 +74,15 @@ __all__ = [
     "MetricsRegistry",
     "PhaseTimer",
     "Sink",
+    "Span",
     "StdoutSink",
     "Telemetry",
     "WandbSink",
     "bus",
+    "span",
     "telemetry_jsonl_path",
 ]
 
-# Serve-engine request-lifecycle phases: traced on the serve lane with
-# their request ids rather than the train lane.
-_SERVE_PHASES = frozenset(("queue_wait", "prefill", "decode", "handoff"))
 # Resilience/fault event kinds rendered as trace instants so a timeline
 # shows the fault next to the phase it interrupted.
 _INSTANT_KINDS = frozenset((
@@ -101,7 +110,8 @@ class Telemetry:
                               else CompileWatch().install())
         self.phases = PhaseTimer(self._phase_done, watchdog=watchdog,
                                  on_enter=self._phase_enter,
-                                 on_section=self._section_done)
+                                 on_section=self._section_done,
+                                 span=self.span)
         self._step_phases_done = 0
         # Analytic pipeline-bubble share of each step phase (from the
         # schedule table, parallel/mpmd.pipeline_bubble_fraction) —
@@ -194,24 +204,32 @@ class Telemetry:
                 and isinstance(secs, (int, float)):
             self.sentinel.observe_phase(fields.get("phase") or "", secs)
 
-    def _trace_event(self, kind: str, secs, fields: dict) -> None:
-        """Route one bus event onto the span timeline: phase events
-        become complete spans (serve request phases on the serve lane,
-        tagged with their request ids; everything else on the train
-        lane), resilience/fault kinds become instants."""
+    def span(self, name: str, tid: int = TID_TRAIN, **counts) -> Span:
+        """A region of host code as a `TraceAnnotation` and, with a tracer
+        installed, a span on lane `tid` (telemetry/spans.py)."""
+        return Span(name, self.tracer, tid, **counts)
+
+    def record_wait(self, name: str, wait_s: float, tid: int = TID_TRAIN,
+                    **counts) -> None:
+        """A wait that just ended (a request's time in the queue): not a
+        region of code, so it has no annotation; the tracer gets it with
+        its start (now less the wait) and its end (now)."""
         tr = self.tracer
-        if kind == "phase":
-            if not isinstance(secs, (int, float)):
-                return
-            phase = fields.get("phase") or "?"
-            args = {k: fields[k] for k in ("id", "ids", "tokens", "step")
-                    if fields.get(k) is not None}
-            tid = TID_SERVE if phase in _SERVE_PHASES else TID_TRAIN
-            tr.complete(phase, tid=tid, dur_s=secs, **args)
-        elif kind == "compile" and isinstance(secs, (int, float)):
+        if tr is not None:
+            tr.complete(name, tid=tid, start_s=tr.now() - wait_s,
+                        dur_s=wait_s, **counts)
+
+    def _trace_event(self, kind: str, secs, fields: dict) -> None:
+        """Route one bus event onto the span timeline as an instant:
+        resilience/fault kinds, and compiles (jax.monitoring reports a
+        compile's duration once it is over, not where it started, so it
+        is an instant carrying `secs`). Phase events are not spans: the
+        regions they time are recorded by `span` where they run."""
+        tr = self.tracer
+        if kind == "compile" and isinstance(secs, (int, float)):
             args = ({"step": fields["step"]}
                     if fields.get("step") is not None else {})
-            tr.complete("compile", tid=TID_TRAIN, dur_s=secs, **args)
+            tr.instant("compile", tid=TID_TRAIN, secs=round(secs, 6), **args)
         elif kind in _INSTANT_KINDS:
             args = {k: v for k, v in fields.items()
                     if isinstance(v, (int, float, str, bool))}

@@ -10,18 +10,20 @@ construction, so one export is one self-consistent timeline.
 Thread-lane (``tid``) convention, kept stable so traces from different
 runs line up:
 
-* 0            train-loop phases (data/step/sync/eval/save/...)
-* 1            serving request lifecycle (queue_wait/prefill/handoff/
-               decode spans, tagged with request ids)
+* 0            train-loop phases (``train.data`` / ``train.step`` / ...)
+* 1            the serve engine's step (``serve.step`` and its leaf spans,
+               tagged with request ids; ``serve.queue_wait`` per request)
 * 2            sentinel / flightdeck bookkeeping instants
 * 100 + stage  MPMD pipeline stage lanes (one per local stage), carrying
-               the per-op tick spans named ``stage/tick/op/mb`` — the
-               same coordinates the watchdog's last-touch string uses.
+               the per-op tick spans ``pp.<stage>.<op>`` with tick and
+               microbatch as arguments — the same coordinates the
+               watchdog's last-touch string uses.
 
-The tracer is deliberately dumb: no nesting model, no flow events. A
-span is one dict append under a lock; the disabled path (tracer absent)
-is a single ``is not None`` check at every call site and allocates
-nothing.
+The tracer is deliberately dumb: no flow events, and nesting is
+containment on one lane (a span that lies inside another on the same
+``tid`` is its child, which is how Perfetto draws them). Spans reach it
+through ``telemetry/spans.py``'s ``Span``, which records a region where it
+starts and ends; a span is one dict append under a lock.
 """
 
 from __future__ import annotations
@@ -68,18 +70,14 @@ class SpanTracer:
         """Current time on the tracer's clock (seconds)."""
         return self.clock()
 
-    def complete(self, name: str, tid: int = TID_TRAIN,
-                 start_s: float | None = None, dur_s: float = 0.0,
-                 **args) -> None:
-        """Record a complete span (``ph="X"``).
-
-        ``start_s`` is on the tracer's clock domain (``tracer.now()``);
-        when None the span is back-dated ``dur_s`` seconds from now —
-        the natural call shape for "phase just finished, took `secs`"
-        hooks that only learn the duration after the fact.
+    def complete(self, name: str, tid: int = TID_TRAIN, *,
+                 start_s: float, dur_s: float, **args) -> None:
+        """Record a complete span (``ph="X"``) that started at ``start_s``
+        on the tracer's clock domain (``tracer.now()``) and lasted
+        ``dur_s``. A span is recorded where it started and ended
+        (telemetry/spans.py reads the clock at both): there is no
+        back-dating from the moment of the call.
         """
-        if start_s is None:
-            start_s = self.clock() - dur_s
         ev = {"name": name, "ph": "X", "pid": self.pid, "tid": int(tid),
               "ts": (start_s - self._t0) * 1e6,
               "dur": max(dur_s, 0.0) * 1e6}
@@ -94,14 +92,6 @@ class SpanTracer:
         if args:
             ev["args"] = args
         self._push(tid, ev)
-
-    def counter(self, name: str, tid: int = TID_SENTINEL,
-                **series) -> None:
-        """Record a counter sample (``ph="C"``)."""
-        self._push(tid, {"name": name, "ph": "C", "pid": self.pid,
-                         "tid": int(tid),
-                         "ts": (self.clock() - self._t0) * 1e6,
-                         "args": dict(series)})
 
     def thread_name(self, tid: int, name: str) -> None:
         """Label a lane (metadata event, emitted first in the export)."""
@@ -150,7 +140,9 @@ class SpanTracer:
         humans prefer not to)."""
         with self._lock:
             meta = [self._meta[t] for t in sorted(self._meta)]
-            events = sorted(self._events, key=lambda e: e["ts"])
+            # a parent that starts with its first child comes first
+            events = sorted(self._events,
+                            key=lambda e: (e["ts"], -e.get("dur", 0.0)))
             dropped = self.dropped
         doc = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
         if dropped:

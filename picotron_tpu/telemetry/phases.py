@@ -6,15 +6,19 @@ beating but invisible to timing). The phase timer is the single source:
 entering a phase beats the watchdog with that phase name, leaving it hands
 the measured duration to a callback (the Telemetry facade books it into
 the histogram registry + goodput ledger and emits the JSONL phase event).
+The region itself is a `telemetry.spans.Span` named `train.<phase>`, so a
+`logging.profile_dir` capture shows data / step / sync / save beside the
+device, and the duration handed on is that span's own.
 A section that exists for the timer therefore cannot be missed by the
 watchdog, and vice versa.
 """
 
 from __future__ import annotations
 
-import time
 from contextlib import contextmanager
 from typing import Callable, Optional
+
+from picotron_tpu.telemetry.spans import span as bus_span
 
 
 class PhaseTimer:
@@ -23,10 +27,12 @@ class PhaseTimer:
                  on_enter: Optional[Callable[[str, Optional[int]], None]]
                  = None,
                  on_section: Optional[
-                     Callable[[str, float, Optional[int]], None]] = None):
+                     Callable[[str, float, Optional[int]], None]] = None,
+                 span: Callable = bus_span):
         self._on_phase = on_phase
         self._on_enter = on_enter
         self._on_section = on_section
+        self._span = span  # (name, **counts) -> telemetry.spans.Span
         self.watchdog = watchdog
 
     @contextmanager
@@ -42,11 +48,12 @@ class PhaseTimer:
             self.watchdog.beat(name, step)
         if self._on_enter is not None:
             self._on_enter(name, step)
-        t0 = time.perf_counter()
+        sp = self._span(f"train.{name}", step=step)
         try:
-            yield
+            with sp:
+                yield
         finally:
-            self._on_phase(name, time.perf_counter() - t0, step)
+            self._on_phase(name, sp.secs, step)
 
     @contextmanager
     def section(self, name: str, step: Optional[int] = None):
@@ -55,9 +62,10 @@ class PhaseTimer:
         no watchdog beat (the enclosing phase already armed it) and no
         ledger booking (their wall is part of the enclosing phase — a
         second booking would double-count the same seconds)."""
-        t0 = time.perf_counter()
+        sp = self._span(f"train.{name}", step=step)
         try:
-            yield
+            with sp:
+                yield
         finally:
             if self._on_section is not None:
-                self._on_section(name, time.perf_counter() - t0, step)
+                self._on_section(name, sp.secs, step)

@@ -1,0 +1,47 @@
+"""Named scopes: the regions of the device programs that a trace reduction
+can find by name after a refactor.
+
+`scope(name)` is `jax.named_scope(name)` for a name declared here. The name
+becomes an element of every enclosed operation's name stack, which the
+compiler keeps as the instruction's `op_name` (`compiled.as_text()`, and the
+`tf_op` stat of a device event in a profiler trace): under a transform it
+reads `jvp(mlp)` or `transpose(jvp(mlp))`, so a reduction looks for the name
+as a word of the path. It costs nothing at run time. The benchmark's metric
+files (`benchmark/layer_metrics/*.json`) spell the same strings.
+
+One rule, because this installation names a Pallas custom call after the
+innermost element of the name stack at the call, and an accepted benchmark
+metric (`flash_roofline.train`) finds the flash kernels of the fused grad
+engine by the name they have there (`closed_call.N`, after the layer
+scan's body): a scope is never entered directly around those kernel calls.
+`parallel/fused_bwd.py` leaves `attention` before each call and enters it
+again after, and tests/test_chip_compile.py holds the names. Collectives
+are named after their primitive, so `tp_reduce` and `pp_boundary` leave
+the events' names alone.
+"""
+
+from __future__ import annotations
+
+import jax
+
+SCOPES = (
+    "embed",            # token embedding lookup (and its gradient)
+    "attention",        # norm, q/k/v and o projections, RoPE; the AD engine's kernel calls
+    "mlp",              # norm, gated MLP or expert block
+    "head_ce",          # final norm, head matmul, cross entropy, their gradient
+    "dw_accum",         # the fused engine's in-scan dW accumulation into the f32 stacks
+    "optimizer",        # clip, Adam update, cast back
+    "pp_boundary",      # the pipeline schedule's ppermutes
+    "tp_reduce",        # tensor-parallel psum / psum_scatter / all_gather hooks
+    "kv_write",         # serve: the paged pool update
+    "paged_attention",  # serve: gather of the views, mask, softmax, PV
+    "sample",           # serve: next-token choice from the logits
+)
+
+
+def scope(name: str):
+    """`jax.named_scope(name)`, context manager or decorator, for a
+    declared name."""
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not declared in telemetry/scopes.py")
+    return jax.named_scope(name)

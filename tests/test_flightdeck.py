@@ -58,7 +58,7 @@ class Clock:
 # ---------------------------------------------------------------------------
 
 
-def test_tracer_span_ordering_nesting_and_backdating():
+def test_tracer_span_ordering_and_nesting():
     c = Clock()
     tr = SpanTracer(clock=c)
     outer_start = tr.now()
@@ -68,31 +68,31 @@ def test_tracer_span_ordering_nesting_and_backdating():
     tr.complete("inner", start_s=inner_start, dur_s=0.010, mb=1)
     c.t = 100.030
     tr.complete("outer", start_s=outer_start, dur_s=0.030)
-    # the phase-hook shape: duration learned only after the fact
-    c.t = 100.050
-    tr.complete("late", dur_s=0.010)
+    # a span is recorded with where it started: there is no back-dating
+    # from the moment of the call
+    with pytest.raises(TypeError):
+        tr.complete("late", dur_s=0.010)
     doc = tr.to_json()
     events = [e for e in doc["traceEvents"] if e["ph"] != "M"]
     # sorted by ts regardless of recording order: outer first
-    assert [e["name"] for e in events] == ["outer", "inner", "late"]
-    outer, inner, late = events
+    assert [e["name"] for e in events] == ["outer", "inner"]
+    outer, inner = events
     assert outer["ts"] == pytest.approx(0.0, abs=1e-6)
     assert outer["dur"] == pytest.approx(30_000.0)  # microseconds
     # nesting invariant: the inner span lies within the outer window
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
-    # back-dated span starts dur_s before now
-    assert late["ts"] == pytest.approx(40_000.0)
     assert inner["args"] == {"mb": 1}
     assert all(e["ph"] == "X" and e["tid"] == TID_TRAIN for e in events)
 
 
-def test_tracer_instants_counters_and_lane_labels():
+def test_tracer_instants_and_lane_labels():
     tr = SpanTracer(clock=Clock())
     tr.instant("rollback", step=4)
-    tr.counter("step_time_s", value=1.25)
-    tr.complete("stage1/tick3/F/mb0", tid=TID_PP_BASE + 1, dur_s=0.001)
-    tr.complete("prefill", tid=TID_SERVE, dur_s=0.001, ids=[0, 1])
+    tr.instant("sentinel_alert", tid=TID_SENTINEL)
+    tr.complete("pp.1.F", tid=TID_PP_BASE + 1, start_s=100.0, dur_s=0.001)
+    tr.complete("serve.prefill.dispatch", tid=TID_SERVE, start_s=100.0,
+                dur_s=0.001, ids="0 1")
     doc = tr.to_json()
     meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
     # lanes self-label: train, serve, flightdeck, pp_stage1
@@ -103,14 +103,12 @@ def test_tracer_instants_counters_and_lane_labels():
     assert names[TID_PP_BASE + 1] == "pp_stage1"
     inst = next(e for e in doc["traceEvents"] if e["ph"] == "i")
     assert inst["s"] == "p" and inst["args"] == {"step": 4}
-    cnt = next(e for e in doc["traceEvents"] if e["ph"] == "C")
-    assert cnt["tid"] == TID_SENTINEL and cnt["args"] == {"value": 1.25}
 
 
 def test_tracer_bounded_ring_counts_drops():
     tr = SpanTracer(clock=Clock(), max_events=5)
     for i in range(8):
-        tr.complete(f"s{i}", dur_s=0.001)
+        tr.complete(f"s{i}", start_s=100.0, dur_s=0.001)
     assert len(tr) == 5 and tr.dropped == 3
     doc = tr.to_json()
     assert doc["otherData"]["dropped_events"] == 3
@@ -120,9 +118,9 @@ def test_tracer_bounded_ring_counts_drops():
 
 def test_tracer_mark_since_and_atomic_export(tmp_path):
     tr = SpanTracer(clock=Clock())
-    tr.complete("before", dur_s=0.0)
+    tr.complete("before", start_s=100.0, dur_s=0.0)
     m = tr.mark()
-    tr.complete("after1", dur_s=0.0)
+    tr.complete("after1", start_s=100.0, dur_s=0.0)
     tr.instant("after2")
     assert [e["name"] for e in tr.since(m)] == ["after1", "after2"]
     assert tr.since(tr.mark()) == []
@@ -155,8 +153,9 @@ def test_dryrun_trace_has_all_span_families_and_validates(tmp_path):
     """The acceptance pin: a 2-step CPU dryrun (real MPMD pp2 executor +
     real disaggregated serve engine, driven through the facade) exports
     one Chrome-trace JSON carrying train phases, per-op stage-tick
-    spans, the serve request lifecycle (queue_wait -> prefill -> handoff
-    -> decode with request ids), and a resilience instant — and
+    spans, the serve request lifecycle (queue_wait -> the engine step's
+    prefill / handoff / decode spans with request ids), and a resilience
+    instant — and
     `tools/trace_export.py --validate` accepts it (subprocess, the same
     gate a CI smoke would run)."""
     from picotron_tpu.mesh import MeshEnv
@@ -216,20 +215,23 @@ def test_dryrun_trace_has_all_span_families_and_validates(tmp_path):
     for e in spans:
         names_by_lane.setdefault(e["tid"], set()).add(e["name"])
     # train phases
-    assert {"data", "step"} <= names_by_lane[TID_TRAIN]
-    # MPMD stage/tick/op/mb spans on both pp stage lanes
-    tick_re = re.compile(r"stage\d+/tick\d+/\w+/mb\d+")
+    assert {"train.data", "train.step"} <= names_by_lane[TID_TRAIN]
+    # MPMD per-op spans on both pp stage lanes, tick and microbatch attached
+    tick_re = re.compile(r"pp\.\d+\.[FB]")
     for stage in (0, 1):
         lane = names_by_lane.get(TID_PP_BASE + stage, set())
         assert any(tick_re.fullmatch(n) for n in lane), (stage, lane)
+    assert all({"tick", "mb", "step"} <= set(e["args"]) for e in spans
+               if e["tid"] >= TID_PP_BASE)
     # serve request lifecycle, ids attached
-    assert {"queue_wait", "prefill", "handoff",
-            "decode"} <= names_by_lane[TID_SERVE]
+    assert {"serve.queue_wait", "serve.step", "serve.prefill.dispatch",
+            "serve.handoff", "serve.decode.dispatch"} <= names_by_lane[TID_SERVE]
     serve = [e for e in spans if e["tid"] == TID_SERVE]
-    assert any("id" in e.get("args", {}) for e in serve
-               if e["name"] == "queue_wait")
-    assert any("ids" in e.get("args", {}) for e in serve
-               if e["name"] in ("prefill", "decode"))
+    assert all("id" in e.get("args", {}) for e in serve
+               if e["name"] in ("serve.queue_wait", "serve.handoff"))
+    assert all("ids" in e.get("args", {}) for e in serve
+               if e["name"] in ("serve.prefill.dispatch",
+                                "serve.decode.dispatch"))
     # resilience instant
     assert any(e["ph"] == "i" and e["name"] == "chaos" for e in events)
     # lanes are labeled
@@ -379,17 +381,15 @@ def test_flight_dump_never_raises_and_last_writer_wins(tmp_path):
 def test_flight_attributes_tracer_spans_per_step(tmp_path):
     c = Clock()
     tr = SpanTracer(clock=c)
-    tr.complete("preamble", dur_s=0.0)  # before the recorder attaches
+    tr.complete("preamble", start_s=0.0, dur_s=0.0)  # before the recorder attaches
     fr = FlightRecorder(str(tmp_path), max_steps=4, tracer=tr)
-    tr.complete("stage0/tick0/F/mb0", tid=TID_PP_BASE, dur_s=0.001)
+    tr.complete("pp.0.F", tid=TID_PP_BASE, start_s=0.0, dur_s=0.001)
     fr.on_step(1, {})
-    tr.complete("stage0/tick1/B/mb0", tid=TID_PP_BASE, dur_s=0.001)
+    tr.complete("pp.0.B", tid=TID_PP_BASE, start_s=0.0, dur_s=0.001)
     fr.on_step(2, {})
     doc = fr.snapshot("watchdog")
-    assert [s["name"] for s in doc["steps"][0]["spans"]] == \
-        ["stage0/tick0/F/mb0"]
-    assert [s["name"] for s in doc["steps"][1]["spans"]] == \
-        ["stage0/tick1/B/mb0"]
+    assert [s["name"] for s in doc["steps"][0]["spans"]] == ["pp.0.F"]
+    assert [s["name"] for s in doc["steps"][1]["spans"]] == ["pp.0.B"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,121 @@
+"""Names a trace reduction can find after a refactor: every scope declared
+in picotron_tpu/telemetry/scopes.py is on the name stack of some operation
+of the programs it belongs to, and the programs the benchmark's cells time
+carry pinned module names. Lowered here at tiny sizes on the CPU mesh; the
+described-chip twin, with the Pallas kernels, is tests/test_chip_compile.py."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from picotron_tpu.config import (
+    Config, DistributedConfig, ModelConfig, ServeConfig, TrainingConfig,
+    resolve_preset,
+)
+from picotron_tpu.mesh import MeshEnv
+from picotron_tpu.models.llama import init_params
+from picotron_tpu.parallel.api import init_sharded_state, make_train_step
+from picotron_tpu.serve import ServeEngine
+from picotron_tpu.serve.spec_decode import CTX_W, get_spec_jit
+from picotron_tpu.telemetry.scopes import SCOPES, scope
+
+TRAIN = {"embed", "attention", "mlp", "head_ce", "optimizer", "tp_reduce"}
+SERVE = {"kv_write", "paged_attention", "sample", "mlp"}
+seen: set = set()  # scopes found by the cases below, for the closing test
+
+
+def scopes_in(text: str) -> set:
+    """The declared scopes that are a word of some location's name stack
+    (`jit(train_step)/jvp(mlp)/dot_general`)."""
+    words = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        words.update(re.split(r"[/()]+", path))
+    return words & set(SCOPES)
+
+
+def module_name(text: str) -> str:
+    return re.search(r"module @(\S+)", text).group(1)
+
+
+def train_cfg(engine: str, pp: int) -> Config:
+    return Config(
+        distributed=DistributedConfig(tp_size=2, pp_size=pp, dp_size=1,
+                                      pp_engine="1f1b"),
+        model=ModelConfig(num_attention_heads=8, num_key_value_heads=4,
+                          num_hidden_layers=2, hidden_size=64,
+                          intermediate_size=96, vocab_size=256,
+                          max_position_embeddings=64, attention_bias=True),
+        training=TrainingConfig(grad_engine=engine, seq_length=32,
+                                micro_batch_size=1,
+                                gradient_accumulation_steps=2, remat=True,
+                                remat_policy="dots_attn"))
+
+
+@pytest.mark.parametrize("engine,pp,extra", [
+    ("fused", 1, {"dw_accum"}),
+    ("ad", 1, set()),
+    ("ad", 2, {"pp_boundary"}),
+])
+def test_train_step_scopes_and_module_name(engine, pp, extra):
+    cfg = train_cfg(engine, pp)
+    menv = MeshEnv.from_config(cfg)
+    state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
+    t = cfg.training
+    b = jax.ShapeDtypeStruct(
+        (t.gradient_accumulation_steps, t.micro_batch_size, t.seq_length),
+        jnp.int32, sharding=menv.batch_sharding())
+    text = make_train_step(cfg, menv).lower(state, (b, b)).as_text(debug_info=True)
+    assert module_name(text) == "jit_train_step"
+    found = scopes_in(text)
+    assert found == TRAIN | extra
+    seen.update(found)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    mcfg = ModelConfig(dtype="float32", **{
+        **resolve_preset("debug-tiny"), "max_position_embeddings": 64})
+    eng = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
+                      ServeConfig(decode_slots=2, block_size=4, num_blocks=16,
+                                  prefill_chunk=4, max_model_len=32,
+                                  decode_interval=2), temperature=0.7, top_k=4)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode",
+                                     "serve_decode_spec"])
+def test_serve_program_scopes_and_module_names(engine, program):
+    e = engine
+    s = e.num_slots
+    vec = jnp.zeros((s,), jnp.int32)
+    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k)
+    head = (e.params, e._k, e._v, jnp.asarray(e._tables))
+    tail = (e.base_key, e.cos, e.sin)
+    if program == "serve_prefill":
+        lowered = e._prefill_jit.lower(
+            *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
+            vec, vec, *tail, **common)
+    elif program == "serve_decode":
+        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
+                                      interval=2, eos_token_id=None, **common)
+    else:
+        lowered = get_spec_jit(False).lower(
+            *head, vec, vec, vec, vec, jnp.zeros((s, CTX_W), jnp.int32), *tail,
+            interval=2, eos_token_id=None, draft_len=2, **common)
+    text = lowered.as_text(debug_info=True)
+    assert module_name(text) == f"jit_{program}"
+    found = scopes_in(text)
+    assert found == SERVE
+    seen.update(found)
+
+
+def test_every_declared_scope_is_used_and_none_is_undeclared():
+    """Runs after the cases above (same file, same worker)."""
+    assert seen == set(SCOPES)
+    with pytest.raises(ValueError):
+        scope("atention")
+    assert np.all([re.fullmatch(r"[a-z_]+", s) for s in SCOPES])

@@ -291,38 +291,6 @@ def test_extract_metrics_extras_skip_stable_suffixed_fields(tmp_path):
     assert "mean_tokens" not in out and "mean_mem" not in out
 
 
-def test_trace_summary_tool(tmp_path, capsys):
-    """trace_summary aggregates device events from an xprof-style trace."""
-    import gzip
-    import json
-    import sys
-
-    sys.path.insert(0, "tools")
-    import trace_summary
-
-    events = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 2, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 3000,
-         "name": "fusion.1"},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 3000, "dur": 1000,
-         "name": "fusion.2"},
-        {"ph": "X", "pid": 2, "tid": 1, "ts": 0, "dur": 9999,
-         "name": "host_noise"},
-    ]
-    d = tmp_path / "plugins" / "profile" / "run1"
-    d.mkdir(parents=True)
-    with gzip.open(d / "vm.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-
-    totals, procs = trace_summary.summarize(
-        trace_summary.load_events(str(tmp_path)))
-    assert totals == {"fusion.1": 3000, "fusion.2": 1000}
-    assert list(procs.values()) == ["/device:TPU:0"]
-
-
 def test_telemetry_report_cli_markdown_smoke(tmp_path, capsys):
     """tools/telemetry_report.py end-to-end over a tiny synthetic stream:
     the CLI path resolution (run dir -> telemetry.jsonl), the summarize

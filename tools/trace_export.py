@@ -12,10 +12,13 @@ Two modes:
   preemption, watchdog, resize, recompile, sentinel alerts) become
   instants, so one timeline shows compute, comm phases, and faults
   together. Rotated streams (`telemetry.jsonl.1`, logging.telemetry_max_mb)
-  are read oldest-first. Note the in-process flightdeck tracer
-  (logging.trace_dir) exports richer traces — per-op MPMD tick spans
-  never hit the JSONL — this converter is the post-hoc fallback for
-  runs that only kept their telemetry stream.
+  are read oldest-first. A phase event is stamped when it is emitted, so
+  the span drawn from it is back-dated by its `secs` and is only as good
+  as that: the in-process flightdeck tracer (logging.trace_dir) records
+  each region where it starts and ends (telemetry/spans.py: the engine
+  step's leaf spans, per-op MPMD spans, none of which hit the JSONL) —
+  this converter is the post-hoc fallback for runs that only kept their
+  telemetry stream.
 
 * Validate (`--validate`): self-check a trace file — monotonic
   timestamps, balanced B/E begin/end events, pid/tid presence and
@@ -38,15 +41,15 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from picotron_tpu.telemetry import (  # noqa: E402
-    _INSTANT_KINDS, _SERVE_PHASES,
-)
+from picotron_tpu.telemetry import _INSTANT_KINDS  # noqa: E402
 from picotron_tpu.telemetry.flightdeck.tracer import (  # noqa: E402
     TID_SERVE, TID_TRAIN,
 )
 from picotron_tpu.telemetry.sinks import jsonl_segments  # noqa: E402
 
 _VALID_PH = frozenset("XBEiICMsnftPNODabevR")
+# The serve engine's `phase` events, drawn on the serve lane.
+_SERVE_PHASES = frozenset(("queue_wait", "prefill", "decode", "handoff"))
 
 
 def resolve_jsonl(path: str) -> str:
