@@ -461,7 +461,8 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
     # Index vectors are padded to the constant max_blocks width exactly
     # so a request's block COUNT stays data, not shape.
     buf = jax.ShapeDtypeStruct(
-        (pcache.k.shape[0], max_blocks) + tuple(pcache.k.shape[2:]),
+        tuple(pcache.k.shape[:2]) + (max_blocks,)
+        + tuple(pcache.k.shape[3:]),
         pcache.k.dtype)
     gather_args = {"k": sds(pcache.k), "v": sds(pcache.v),
                    "idx": i32(max_blocks)}

@@ -151,11 +151,15 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
     d = cfg.head_dim
 
     # The cache rides the scan CARRY with per-layer in-place writes of
-    # only the new token slots. Feeding it through as xs/ys instead (r4
-    # structure) made every decode step rewrite the full cache — the scan
-    # stacks fresh ys buffers — and the token-loop carry copy doubled it:
-    # profiled at 2x 2.75 ms of pure cache copies per token at
-    # SmolLM-1.7B batch 8 (~half the decode step; PERF.md r5).
+    # only the new token slots (as xs/ys the scan stacks fresh ys buffers
+    # and every step rewrites the whole cache). A carried buffer gets ONE
+    # layout for the whole loop, so the cache's `write` and `layer_view`
+    # must agree on it or the compiler copies the whole cache around one
+    # of them in every layer: the paged pool is [Hkv, L, blocks, block, D],
+    # scattered and gathered under a vmap over its heads, for that reason
+    # (the compiled serve programs carry both pools as
+    # {4,3,2,1,0:T(8,128)(2,1)} and hold no pool-sized copy;
+    # tests/test_chip_compile.py).
     def body(carry, inputs):
         x, cache = carry
         lp, li = inputs

@@ -69,12 +69,12 @@ from picotron_tpu.telemetry.spans import join_ids
 
 def _gather_blocks_impl(k, v, idx):
     """Pull the handed-off sequence's blocks out of the prefill pool
-    into a dense staging buffer: k/v [L, N_p, bs, Hkv, D], idx
+    into a dense staging buffer: k/v [Hkv, L, N_p, bs, D], idx
     [max_blocks] physical block ids (0-padded past the sequence's
     blocks — the padding rows carry garbage the scatter side drops).
     Runs ON the prefill placement; the returned buffer is what crosses
     the pool boundary via device_put."""
-    return k[:, idx], v[:, idx]
+    return k[:, :, idx], v[:, :, idx]
 
 
 def _scatter_blocks_impl(k, v, buf_k, buf_v, idx):
@@ -83,8 +83,8 @@ def _scatter_blocks_impl(k, v, buf_k, buf_v, idx):
     sequence's blocks so padding rows DROP — the same sentinel
     discipline as the paged cache's write path. Runs ON the decode
     placement."""
-    return (k.at[:, idx].set(buf_k, mode="drop"),
-            v.at[:, idx].set(buf_v, mode="drop"))
+    return (k.at[:, :, idx].set(buf_k, mode="drop"),
+            v.at[:, :, idx].set(buf_v, mode="drop"))
 
 
 _HANDOFF_JITS: dict = {}
@@ -169,7 +169,7 @@ class DisaggServeEngine(ServeEngine):
                 mesh_sh = NamedSharding(sh.mesh, PartitionSpec())
                 kv_sh = NamedSharding(
                     sh.mesh,
-                    PartitionSpec(None, None, None, "tp", None)
+                    PartitionSpec("tp")
                     if dict(zip(sh.mesh.axis_names,
                                 sh.mesh.devices.shape)).get("tp", 1) > 1
                     else PartitionSpec())

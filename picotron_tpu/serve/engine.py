@@ -272,7 +272,7 @@ class ServeEngine:
                 self._rep_sh = NamedSharding(mesh, PartitionSpec())
                 kv_sh = NamedSharding(
                     mesh,
-                    PartitionSpec(None, None, None, "tp", None)
+                    PartitionSpec("tp")
                     if dict(zip(mesh.axis_names,
                                 mesh.devices.shape)).get("tp", 1) > 1
                     else PartitionSpec())

@@ -8,21 +8,41 @@ and maps each decode slot's logical positions onto physical blocks
 through a per-slot block table (the vLLM arrangement, kept deliberately
 static-shaped for XLA):
 
-- ``k``/``v``: ``[L, num_blocks, block_size, Hkv, D]`` — the pool.
-  Persistent cache HBM scales with ``num_blocks`` actually provisioned,
-  not with ``slots x max_length`` (pinned by the pool-accounting test).
+- ``k``/``v``: ``[Hkv, L, num_blocks, block_size, D]`` — the pool, one
+  ``[L, num_blocks, block_size, D]`` pool a KV head. Persistent cache HBM
+  scales with ``num_blocks`` actually provisioned, not with
+  ``slots x max_length`` (pinned by the pool-accounting test).
 - ``tables``: ``[B, max_blocks]`` int32, logical block -> physical block.
   ``num_blocks`` itself is the UNMAPPED sentinel: scatter writes at the
   sentinel drop (``mode="drop"``), gathers clamp into the pool and the
   clamped garbage is masked by the causal mask before anything reads it.
 
+Why the KV heads lead: the pool rides the layer scan's carry, and the
+compiler gives a carried buffer ONE layout that `write`'s scatter and
+`layer_view`'s gather must both accept — where they disagree it
+re-lays-out the whole pool around the scatter in every layer. With the
+heads behind the block (``[L, blocks, block, Hkv, D]``) the prefill
+program carried V head-major for the P.V contraction and copied all of it
+twice a layer: 58 copies of 1.88 GB a dispatch at Qwen2-1.5B's chat
+settings, a third of the dispatch. Here `write` and `layer_view` are
+`vmap`s over the head axis of a scatter and a gather on one head's pool,
+so the head is a batching dimension of both (and the axis a tp mesh
+shards: each device scatters and gathers its own heads, no collective),
+the scatter's window is a row of ``D``, the gather's a whole
+``[block_size, D]`` block (16 x 128: one bf16 tile) addressed by
+(layer, block) straight out of the pool, and both serve programs compile
+for a v5e with the pool in the default layout
+``{4,3,2,1,0:T(8,128)(2,1)}`` from entry to exit: no pool-sized ``copy``
+anywhere, no layer sliced out before the gather, the scatters in place on
+the donated buffers. tests/test_chip_compile.py compiles both and counts.
+
 Writes use the same advanced-indexing scatter for decode (one token per
-slot, each at its own position) and chunked prefill (a contiguous span of
-one slot); positions < 0 (chunk padding) are routed to the sentinel. The
-attention view gathers a slot's blocks back into logical order, so
-`generate._cached_attention` runs on it unchanged — slot j of the
-gathered view holds the token at position j, exactly like the contiguous
-cache, which is what makes paged-vs-contiguous greedy parity a
+slot, each at its own position) and chunked prefill (a span of every
+mid-prefill slot); positions < 0 (chunk padding) are routed to the
+sentinel. The attention view gathers a slot's blocks back into logical
+order, so `generate._cached_attention` runs on it unchanged — slot j of
+the gathered view holds the token at position j, exactly like the
+contiguous cache, which is what makes paged-vs-contiguous greedy parity a
 structural property rather than a numerical accident.
 
 `BlockPool` is the host-side allocator: free-list alloc/free with
@@ -35,6 +55,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from picotron_tpu.config import ModelConfig
@@ -47,21 +68,21 @@ class PagedKVCache(NamedTuple):
     (num_layers / write / layer_view) so `generate._decode_layers` is
     cache-agnostic."""
 
-    k: jnp.ndarray       # [L, num_blocks, block_size, Hkv, D]
-    v: jnp.ndarray       # [L, num_blocks, block_size, Hkv, D]
+    k: jnp.ndarray       # [Hkv, L, num_blocks, block_size, D]
+    v: jnp.ndarray       # [Hkv, L, num_blocks, block_size, D]
     tables: jnp.ndarray  # [B, max_blocks] int32; num_blocks = unmapped
 
     @property
     def num_layers(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def num_blocks(self) -> int:
         return self.k.shape[1]
 
     @property
-    def block_size(self) -> int:
+    def num_blocks(self) -> int:
         return self.k.shape[2]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
 
     @scope("kv_write")
     def write(self, li, k_new, v_new, q_pos) -> "PagedKVCache":
@@ -80,9 +101,18 @@ class PagedKVCache(NamedTuple):
         ok = (q_pos >= 0) & (blk < self.tables.shape[1])
         phys = jnp.where(ok, phys, self.num_blocks)
         off = jnp.maximum(q_pos, 0) % bs
-        k = self.k.at[li, phys, off].set(k_new, mode="drop")
-        v = self.v.at[li, phys, off].set(v_new, mode="drop")
-        return self._replace(k=k, v=v)
+        # the indices are batched over the heads with the pool: vmapped
+        # over pool and rows alone, the head folds into the scatter's
+        # window and the compiler carries the pool heads-minor again
+        # (four pool copies a dispatch in both programs)
+        hkv = self.k.shape[0]
+        phys = jnp.broadcast_to(phys, (hkv,) + phys.shape)
+        off = jnp.broadcast_to(off, (hkv,) + off.shape)
+        put = jax.vmap(  # on one head's [L, num_blocks, block_size, D]
+            lambda pool, new, ph, of: pool.at[li, ph, of].set(new, mode="drop"),
+            in_axes=(0, 2, 0, 0))
+        return self._replace(k=put(self.k, k_new, phys, off),
+                             v=put(self.v, v_new, phys, off))
 
     def layer_view(self, li):
         """Gather layer li's blocks back into logical order:
@@ -93,12 +123,18 @@ class PagedKVCache(NamedTuple):
         beyond every live q position and is causally masked. This view is
         a per-layer TRANSIENT inside the layer scan (capacity-sized
         activation), not persistent cache memory."""
-        kl = self.k[li]  # [num_blocks, block_size, Hkv, D]
-        vl = self.v[li]
+        hkv = self.k.shape[0]
         b, mb = self.tables.shape
-        shape = (b, mb * self.block_size) + kl.shape[2:]
-        return (kl[self.tables].reshape(shape),
-                vl[self.tables].reshape(shape))
+        # tables batched over the heads for the same reason as in `write`
+        tables = jnp.broadcast_to(self.tables, (hkv, b, mb))
+        gather = jax.vmap(lambda pool, t: pool[li, t])
+
+        def view(pool):
+            g = gather(pool, tables)  # [Hkv, B, max_blocks, block_size, D]
+            return g.reshape(hkv, b, mb * self.block_size, -1).transpose(
+                1, 2, 0, 3)
+
+        return view(self.k), view(self.v)
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -106,8 +142,8 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     """Zeroed pool + all-unmapped tables. Pool memory is
     L * num_blocks * block_size * Hkv * D * 2 tensors — sized by the
     blocks provisioned, independent of num_slots * max_length."""
-    shape = (cfg.num_hidden_layers, num_blocks, block_size,
-             cfg.num_key_value_heads, cfg.head_dim)
+    shape = (cfg.num_key_value_heads, cfg.num_hidden_layers, num_blocks,
+             block_size, cfg.head_dim)
     dt = compute_dtype(cfg)
     tables = jnp.full((num_slots, max_blocks), num_blocks, jnp.int32)
     return PagedKVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt), tables)

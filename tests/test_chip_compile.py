@@ -1,6 +1,7 @@
-"""The benchmark's two training steps, compiled for a described TPU v5e (no
-chip attached): what the scopes of picotron_tpu/telemetry/scopes.py do to the
-names a device trace will show.
+"""The benchmark's two training steps and two serving programs, compiled for a
+described TPU v5e (no chip attached): what the scopes of
+picotron_tpu/telemetry/scopes.py do to the names a device trace will show, and
+whether the serving programs move a whole KV pool.
 
 On this installation a Pallas custom call is named after the innermost
 element of the name stack at the call. `benchmark/layer_metrics/
@@ -9,12 +10,20 @@ the name the layer scan's body gives them, and `collective_share.train.json`
 finds collectives by theirs, so a scope in the wrong place silences an
 accepted metric. This file holds the names; nothing here is a measurement.
 
+The serving programs carry the paged KV pool through the layer scan. Where the
+pool's scatter (`kv_write`) and gather (`paged_attention`) want different
+layouts of it, the compiler copies the whole pool around one of them in every
+layer: 58 copies of 1.88 GB a prefill dispatch, a third of its time on the
+chip, before serve/paged_cache.py took the order it has. The compiled text
+shows such a copy; the last test here counts them.
+
 One file, topology described inside a module-scoped fixture: only one process
 may load libtpu, and every xdist worker imports every test file.
 """
 
 import importlib
 import json
+import math
 import os
 import re
 
@@ -24,7 +33,11 @@ import pytest
 
 from picotron_tpu.config import config_from_dict
 from picotron_tpu.mesh import MeshEnv
+from picotron_tpu.models.llama import init_params, model_rope_tables
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
+from picotron_tpu.serve.engine import _get_jits
+from picotron_tpu.serve.paged_cache import init_paged_cache
+from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import SCOPES
 
 BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
@@ -120,3 +133,109 @@ def test_four_chip_step_keeps_its_collectives_names(topo, monkeypatch):
     found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
     assert found >= {"embed", "attention", "mlp", "head_ce", "optimizer",
                      "pp_boundary", "tp_reduce"}
+
+
+def compiled_serve(topo, program: str):
+    """(`compiled.as_text()`, the pool's shape, the pools' parameter numbers)
+    of the chat cell's `serve_prefill` or `serve_decode` at the cell's widths,
+    depth and serve settings on one described chip, pools donated: the
+    program `ServeEngine` dispatches there."""
+    c = load("configs", "qwen2-1.5b")
+    cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
+    m, sc = cfg.model, cfg.serve
+    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
+    slots = sc.decode_slots
+    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+    # bf16 weights, as the cell's runner serves them
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    cache = on_chip(jax.eval_shape(lambda: init_paged_cache(
+        m, sc.num_blocks or slots * max_blocks, sc.block_size, slots, max_blocks)))
+    cos, sin = on_chip(jax.eval_shape(
+        lambda: model_rope_tables(m, max_len=sc.max_model_len)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    decode, prefill = _get_jits(True)
+    if program == "serve_prefill":
+        low = prefill.lower(
+            params, cache.k, cache.v, i32(slots, max_blocks),
+            i32(slots, sc.prefill_chunk), i32(slots), i32(slots), i32(slots),
+            i32(slots), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
+    else:
+        low = decode.lower(
+            params, cache.k, cache.v, i32(slots, max_blocks), i32(slots),
+            i32(slots), i32(slots), i32(slots), key, cos, sin, cfg=m,
+            temperature=0.0, top_k=0, interval=sc.decode_interval,
+            eos_token_id=None)
+    n = len(jax.tree.leaves(params))
+    return low.compile().as_text(), cache.k.shape, {n, n + 1}
+
+
+def computations(text: str) -> dict:
+    """{computation's name: its lines} of a compiled module."""
+    out, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = out.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return out
+
+
+def whole_pool_copies(text: str, pool_shape) -> list:
+    """[(in a while body?, computation, the instruction up to its operands)]
+    for every copy or transpose whose result has as many elements as a KV
+    pool, fusions named after their copy included, with its layout."""
+    comps = computations(text)
+    calls = {name: set(re.findall(
+        r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", "\n".join(lines)))
+        for name, lines in comps.items()}
+    in_loop, todo = set(), list(set(re.findall(r"body=%?([\w.\-]+)", text)))
+    while todo:
+        name = todo.pop()
+        if name not in in_loop:
+            in_loop.add(name)
+            todo += calls.get(name, ())
+    n_pool = math.prod(pool_shape)
+    found = []
+    for name, lines in comps.items():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                         line)
+            if not m or not (m.group(3) in ("copy", "transpose")
+                             or "copy" in m.group(1)):
+                continue
+            if math.prod(int(d) for d in m.group(2).split(",") if d) == n_pool:
+                found.append((name in in_loop, name, line.strip().split("(%")[0]))
+    return found
+
+
+@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
+def test_serving_program_moves_no_whole_pool(topo, program):
+    text, pool_shape, pools = compiled_serve(topo, program)
+    # PR 25's accepted metrics find the programs and their scopes by name
+    assert text.startswith(f"HloModule jit_{program}")
+    found = set().union(*(words(op) for _, op, _ in instructions(text)))
+    assert found >= {"kv_write", "paged_attention"}
+    # none inside a loop and none outside one (PR 26; before it `serve_prefill`
+    # held 2 a layer inside the scan and 2 outside, all of the V pool: 58 a
+    # dispatch). One outside a loop costs 5 ms a dispatch, one inside 140.
+    copies = whole_pool_copies(text, pool_shape)
+    assert not copies, f"{program} copies a whole KV pool {pool_shape}:\n" + "\n".join(
+        f"{'in a loop' if loop else 'outside  '} {comp}: {ins}"
+        for loop, comp, ins in copies)
+    # the scatters write the donated pools in place: both are aliased to outputs
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+), ", alias)}
+    assert aliased >= pools, (alias, pools)

@@ -329,6 +329,46 @@ def test_disagg_single_decode_compile(tiny, requests5, offline_refs):
         assert by_id[i] == ref
 
 
+def test_handoff_blocks_read_back_equal_across_pools():
+    """A prefix written into the prefill pool, gathered by its block ids
+    and scattered into other blocks of a differently sized decode pool,
+    reads back through `layer_view` exactly as it did before the handoff;
+    the staging buffer's padding rows drop at the decode pool's sentinel."""
+    from picotron_tpu.serve.disagg import (
+        _gather_blocks_impl, _scatter_blocks_impl,
+    )
+    from picotron_tpu.serve.paged_cache import PagedKVCache
+
+    layers, hkv, d, bs, mb, n_tok = 2, 2, 8, 4, 4, 10
+    src, dst, n_p, n_d = [5, 1, 3], [7, 2, 8], 6, 9
+    kk, kv = jax.random.split(jax.random.key(5))
+    k_new = jax.random.normal(kk, (layers, 1, n_tok, hkv, d), jnp.float32)
+    v_new = jax.random.normal(kv, (layers, 1, n_tok, hkv, d), jnp.float32)
+    zeros_p = jnp.zeros((hkv, layers, n_p, bs, d), jnp.float32)
+    pre = PagedKVCache(zeros_p, zeros_p,
+                       jnp.asarray([src + [n_p]], jnp.int32))
+    for li in range(layers):
+        pre = pre.write(li, k_new[li], v_new[li], jnp.arange(n_tok))
+    # the engine's fixed-width index vectors: 0-padded source ids,
+    # sentinel-padded destination ids (DisaggServeEngine._copy_blocks)
+    buf_k, buf_v = _gather_blocks_impl(
+        pre.k, pre.v, jnp.asarray(src + [0], jnp.int32))
+    assert buf_k.shape == (hkv, layers, mb, bs, d)
+    ones_d = jnp.ones((hkv, layers, n_d, bs, d), jnp.float32)
+    k_d, v_d = _scatter_blocks_impl(ones_d, ones_d, buf_k, buf_v,
+                                    jnp.asarray(dst + [n_d], jnp.int32))
+    dec = PagedKVCache(k_d, v_d, jnp.asarray([dst + [n_d]], jnp.int32))
+    for li in range(layers):
+        for got, want in zip(dec.layer_view(li), pre.layer_view(li)):
+            np.testing.assert_array_equal(np.asarray(got)[:, :len(src) * bs],
+                                          np.asarray(want)[:, :len(src) * bs])
+        np.testing.assert_array_equal(
+            np.asarray(dec.layer_view(li)[0])[0, :n_tok], np.asarray(k_new[li, 0]))
+    # every block the handoff did not name is as it was
+    others = [b for b in range(n_d) if b not in dst]
+    assert bool(jnp.all(k_d[:, :, others] == 1) & jnp.all(v_d[:, :, others] == 1))
+
+
 def test_prove_disagg_programs_static():
     """The PR-9 variant prover proves all four disaggregated programs
     (prefill pool, decode pool, handoff gather/scatter) compile once,

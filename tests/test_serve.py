@@ -175,6 +175,71 @@ def test_preemption_single_request_pool_too_small_raises():
 
 
 # ---------------------------------------------------------------------------
+# paged cache: write -> layer_view against the contiguous cache
+# ---------------------------------------------------------------------------
+
+_UNMAPPED = 14  # = num_blocks of the pool below: the tables' sentinel
+# one slot of a [2, 6] prefill chunk each: its block table, the chunk's first
+# position, its valid tokens, and the positions whose K/V may reach the pool
+# (4 blocks of 4 positions a slot, so position 16 is past the table)
+DROP_CASES = {
+    "in_row_padding": ([0, 2, 5, 9], 2, 4, range(2, 6)),
+    "idle_row": ([_UNMAPPED] * 4, 0, 0, range(0)),
+    "unmapped_entry": ([3, 7, 1, _UNMAPPED], 8, 6, range(8, 12)),
+    "past_the_table": ([4, 6, 8, 10], 13, 6, range(13, 16)),
+}
+
+
+@pytest.mark.parametrize("case", DROP_CASES)
+def test_paged_write_then_view_matches_contiguous(case):
+    """What `serve_prefill` does to the pool in one layer: scatter a
+    [slots, chunk] span at per-slot positions, gather every slot's view.
+    Mapped positions read back exactly what the contiguous cache holds;
+    padding (q_pos = -1), an idle row, an unmapped table entry and a
+    position past the table all drop, leaving the rest of the pool alone."""
+    from picotron_tpu.generate import KVCache
+    from picotron_tpu.serve.paged_cache import PagedKVCache
+
+    layers, hkv, d, bs, mb, chunk, li = 2, 2, 8, 4, 4, 6, 1
+    table, start, n_valid, kept = DROP_CASES[case]
+    # slot 0 is an ordinary full chunk beside the slot under test
+    tables = jnp.asarray([[12, 13, _UNMAPPED, _UNMAPPED], table], jnp.int32)
+    starts, valid = np.array([1, start]), np.array([chunk, n_valid])
+    t = np.arange(chunk)[None, :]
+    q_pos = jnp.asarray(
+        np.where(t < valid[:, None], starts[:, None] + t, -1), jnp.int32)
+    kk, kv = jax.random.split(jax.random.key(3))
+    k_new = jax.random.normal(kk, (2, chunk, hkv, d), jnp.float32)
+    v_new = jax.random.normal(kv, (2, chunk, hkv, d), jnp.float32)
+    pool = jnp.zeros((hkv, layers, _UNMAPPED, bs, d), jnp.float32)
+    cache = PagedKVCache(pool, pool, tables).write(li, k_new, v_new, q_pos)
+    assert cache.num_blocks == _UNMAPPED and cache.block_size == bs
+    got_k, got_v = cache.layer_view(li)
+    assert got_k.shape == got_v.shape == (2, mb * bs, hkv, d)
+
+    kept_by_slot = [range(1, 1 + chunk), kept]
+    for b, span in enumerate(kept_by_slot):
+        empty = jnp.zeros((layers, 1, mb * bs, hkv, d))
+        ref = KVCache(empty, empty)
+        if len(span):
+            rows = slice(span[0] - int(starts[b]), span[-1] + 1 - int(starts[b]))
+            ref = ref.write(li, k_new[b:b + 1, rows], v_new[b:b + 1, rows],
+                            jnp.arange(span[0], span[-1] + 1))
+        ref_k, ref_v = ref.layer_view(li)
+        # an unmapped entry's view is clamped garbage behind the causal mask
+        mapped = np.repeat(np.asarray(tables[b]) != _UNMAPPED, bs)
+        np.testing.assert_array_equal(np.asarray(got_k[b])[mapped],
+                                      np.asarray(ref_k[0])[mapped])
+        np.testing.assert_array_equal(np.asarray(got_v[b])[mapped],
+                                      np.asarray(ref_v[0])[mapped])
+    # nothing else landed anywhere: the other layer and every other cell
+    n_kept = sum(len(span) for span in kept_by_slot)
+    for new in (cache.k, cache.v):
+        assert int(jnp.count_nonzero(new[:, li])) == n_kept * hkv * d
+        assert not bool(jnp.any(new[:, 1 - li]))
+
+
+# ---------------------------------------------------------------------------
 # engine: paged-vs-contiguous greedy parity
 # ---------------------------------------------------------------------------
 
@@ -304,9 +369,10 @@ def test_cache_memory_scales_with_blocks_not_batch_x_maxlen(tiny):
     sc = scfg(decode_slots=3, num_blocks=9)  # 36 token-slots
     eng = ServeEngine(params, cfg, sc)
     contiguous_equiv = sc.decode_slots * blocks_for(32, sc.block_size)
-    assert eng._k.shape[1] == 9 < contiguous_equiv
+    # the pool is [Hkv, L, num_blocks, block_size, D]
+    assert eng._k.shape[2] == 9 < contiguous_equiv
     # 3 slots x 32 max_model_len would be 96 token-slots; the pool holds 36
-    assert eng._k.shape[1] * eng._k.shape[2] == 36
+    assert eng._k.shape[2] * eng._k.shape[3] == 36
     eng.close()
 
 
