@@ -597,6 +597,13 @@ def load_hf_safetensors(path: str, cfg: ModelConfig,
                         dtype=jnp.float32) -> dict[str, Any]:
     """Materialize an HF Llama-family safetensors checkpoint as our stacked
     param pytree (fp32 master by default)."""
+    if cfg.qk_norm:
+        # OLMoE's tensor names (mlp.gate, mlp.experts.N.gate_proj,
+        # self_attn.q_norm) have no map here yet; loading the Mixtral
+        # names would leave the layer tree without its q/k norm weights
+        raise NotImplementedError(
+            "load_hf_safetensors has no tensor-name map for qk_norm "
+            "(OLMoE) checkpoints")
     raw = _read_safetensors_dir(path)
     nl = cfg.num_hidden_layers
     file_layers = {int(mm.group(1)) for k in raw
@@ -670,6 +677,10 @@ def save_hf_safetensors(params: dict[str, Any], path: str) -> None:
     if "lm_head" in params:  # tied models carry no separate head
         out["lm_head.weight"] = np.asarray(params["lm_head"]).T
     layers = params["layers"]
+    if "q_norm" in layers:
+        raise NotImplementedError(
+            "save_hf_safetensors has no tensor-name map for qk_norm "
+            "(OLMoE) checkpoints")
     nl = next(iter(layers.values())).shape[0]
     is_moe = "router" in layers
     lmap = dict(_ATTN_MAP if is_moe else _LAYER_MAP)

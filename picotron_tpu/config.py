@@ -144,6 +144,19 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         max_position_embeddings=65536, rope_theta=1e6, rms_norm_eps=1e-5,
         num_experts=8, num_experts_per_token=2,
     ),
+    # OLMoE (fine-grained MoE: 64 experts of width 1024, 8 a token whose
+    # gates are the raw softmax probabilities, QK-norm from model_type
+    # olmoe, plain multi-head attention, no shared expert; trained
+    # dropless). router_aux_coef is the source's router_aux_loss_coef;
+    # the z-loss coefficient is the OLMoE paper's (arXiv:2409.02060).
+    "allenai/OLMoE-1B-7B-0125-Instruct": dict(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_hidden_layers=16, num_attention_heads=16, num_key_value_heads=16,
+        max_position_embeddings=4096, rope_theta=10000.0, rms_norm_eps=1e-5,
+        num_experts=64, num_experts_per_token=8, moe_intermediate_size=1024,
+        norm_topk_prob=False, qk_norm=True,
+        router_aux_coef=0.01, router_z_coef=0.001,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -163,6 +176,16 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
         max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
         num_experts=8, num_experts_per_token=2,
+    ),
+    # Tiny OLMoE-shaped debug model (16 experts, top-4 un-renormalised
+    # gates, QK-norm, untied head)
+    "picotron-tpu/debug-tiny-olmoe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        num_experts=16, num_experts_per_token=4, moe_intermediate_size=32,
+        norm_topk_prob=False, qk_norm=True,
+        router_aux_coef=0.01, router_z_coef=0.001,
     ),
 }
 
@@ -190,6 +213,8 @@ _PRESET_ALIASES = {
     "debug-tiny": "picotron-tpu/debug-tiny",
     "debug-tiny-qwen": "picotron-tpu/debug-tiny-qwen",
     "debug-tiny-moe": "picotron-tpu/debug-tiny-moe",
+    "OLMoE-1B-7B": "allenai/OLMoE-1B-7B-0125-Instruct",
+    "debug-tiny-olmoe": "picotron-tpu/debug-tiny-olmoe",
 }
 
 
@@ -213,7 +238,7 @@ def resolve_hf_name(name: str) -> str:
 def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
-    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral-family model
+    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE-family model
     outside the preset registry resolves from its config file instead of
     hand-typed hyperparameters. Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -223,7 +248,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             hf = json.load(f)
 
     mtype = hf.get("model_type", "llama")
-    supported = ("llama", "mistral", "mixtral", "qwen2")
+    supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -262,9 +287,24 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             f"hidden_act {act!r} unsupported (silu/gelu gated MLPs only)")
     if hf.get("rope_scaling"):
         out["rope_scaling"] = dict(hf["rope_scaling"])
-    if hf.get("num_local_experts"):  # Mixtral-style MoE
-        out["num_experts"] = hf["num_local_experts"]
+    # Mixtral spells the expert count num_local_experts, OLMoE num_experts
+    n_experts = hf.get("num_local_experts") or hf.get("num_experts")
+    if n_experts:
+        out["num_experts"] = n_experts
         out["num_experts_per_token"] = hf.get("num_experts_per_tok", 2)
+        # Mixtral always renormalizes its k gates and has no key for it;
+        # OLMoE publishes the key (false)
+        out["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
+        if "router_aux_loss_coef" in hf:
+            out["router_aux_coef"] = float(hf["router_aux_loss_coef"])
+    if mtype == "olmoe":
+        # config.json has no key for either: OLMoE's intermediate_size IS
+        # the width of one expert, and its attention normalizes q and k
+        # (modeling_olmoe.py)
+        out["moe_intermediate_size"] = hf["intermediate_size"]
+        out["qk_norm"] = True
+        if hf.get("clip_qkv") is not None:
+            raise ValueError("olmoe with clip_qkv set is not supported")
     return out
 
 
@@ -605,12 +645,23 @@ class ModelConfig:
     # num_experts = 0 keeps the dense SwiGLU MLP; > 0 replaces every MLP with
     # a top-k-routed expert bank (Mixtral-style: softmax over the top-k
     # router logits) plus a load-balancing aux loss. Experts shard over the
-    # 'ep' mesh axis; dispatch is capacity-bounded (GShard-style) so shapes
-    # stay static for XLA.
+    # 'ep' mesh axis. With ep = 1 the dispatch is dropless (assignments
+    # permuted into expert order, grouped matmuls over the ragged group
+    # sizes: nothing padded, nothing dropped); with ep > 1 it is
+    # capacity-bounded (GShard-style, `capacity_factor`), because the
+    # all_to_all needs fixed shapes.
     num_experts: int = 0
     num_experts_per_token: int = 2
     moe_intermediate_size: Optional[int] = None  # default: intermediate_size
     capacity_factor: float = 1.25
+    # Renormalize the k chosen gates to sum to 1 (Mixtral's rule). False
+    # keeps the raw softmax probabilities (OLMoE's published key).
+    norm_topk_prob: bool = True
+    # Whole-vector RMSNorm on the q and k projections before the head
+    # split and RoPE (OLMoE; learned weights q_norm [n_q*d], k_norm
+    # [n_kv*d] per layer). The norm runs over channels tensor parallelism
+    # splits, so tp > 1 is refused (Config.validate).
+    qk_norm: bool = False
     router_aux_coef: float = 0.01
     # Router z-loss coefficient (ST-MoE eq. 5; 1e-3 there). 0 disables.
     router_z_coef: float = 0.0
@@ -1345,6 +1396,12 @@ class Config:
             if m.expert_ffn_size % d.tp_size != 0:
                 raise ValueError(
                     "expert ffn size must be divisible by tp_size")
+        if m.qk_norm and d.tp_size > 1:
+            raise ValueError(
+                "model.qk_norm normalizes q and k over the whole projected "
+                "vector, which tp_size > 1 splits across shards; a per-shard "
+                "norm would be a different model, so the combination is "
+                "refused (tp_size must be 1)")
         if t.remat_policy not in ("full", "dots", "dots_attn", "dots_lean",
                                   "dots_norms", "dots_offload"):
             raise ValueError(
@@ -1716,6 +1773,8 @@ def num_params(m: ModelConfig, active_only: bool = False,
     )
     if m.attention_bias:
         per_layer += h + 2 * kv  # q/k/v biases
+    if m.qk_norm:
+        per_layer += h + kv  # q_norm / k_norm weights
     head = (h * v if (not m.tie_word_embeddings or include_tied_head)
             else 0)
     return v * h + l * per_layer + h + head  # embed + layers + final_norm (+ head)

@@ -165,7 +165,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
         lp, li = inputs
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
         b, s, _ = h.shape
-        q, k, v = qkv_proj(h, lp, d)
+        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
         q = _rope(q, cos, sin, q_pos)
         k = _rope(k, cos, sin, q_pos)
         cache = cache.write(li, k, v, q_pos)

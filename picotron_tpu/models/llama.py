@@ -203,6 +203,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "b_k": jnp.zeros((nl, kv_out), jnp.float32),
             "b_v": jnp.zeros((nl, kv_out), jnp.float32),
         })
+    if cfg.qk_norm:
+        # OLMoE: RMSNorm weights over the whole q / k projection
+        layers.update({
+            "q_norm": jnp.ones((nl, q_out), jnp.float32),
+            "k_norm": jnp.ones((nl, kv_out), jnp.float32),
+        })
     if cfg.num_experts:
         e, f = cfg.num_experts, cfg.expert_ffn_size
         layers.update({
@@ -316,12 +322,16 @@ def embed(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
         return x.astype(compute_dtype(cfg))
 
 
-def qkv_proj(h, lp, d: int):
+def qkv_proj(h, lp, d: int, eps: float = 1e-5):
     """Shared q/k/v projection (+ optional Qwen2 bias, tp-sharded with its
-    output features) -> ([B,S,Hq,D], [B,S,Hkv,D], [B,S,Hkv,D]); local head
+    output features; + optional OLMoE QK-norm, an RMSNorm with `eps` over
+    the WHOLE projected q and k vectors before the head split and RoPE,
+    where the layer has `q_norm` / `k_norm` weights — tp = 1 only,
+    Config.validate) -> ([B,S,Hq,D], [B,S,Hkv,D], [B,S,Hkv,D]); local head
     counts come from the (possibly TP-sharded) weight shapes. One
-    implementation for the training block AND the KV-cache decode path
-    (generate.py) so attention-input changes cannot silently diverge."""
+    implementation for the training block, the fused grad engine's
+    segment VJP AND the KV-cache decode path (generate.py) so
+    attention-input changes cannot silently diverge."""
     dt = h.dtype
     b, s, _ = h.shape
     q = h @ lp["q"].astype(dt)
@@ -331,6 +341,9 @@ def qkv_proj(h, lp, d: int):
         q = q + lp["b_q"].astype(dt)
         k = k + lp["b_k"].astype(dt)
         v = v + lp["b_v"].astype(dt)
+    if "q_norm" in lp:
+        q = rms_norm(q, lp["q_norm"], eps)
+        k = rms_norm(k, lp["k_norm"], eps)
     # checkpoint-name the FLAT [B, S, H*D] projections, BEFORE the head
     # reshape: saved activations inherit the flat matmul layout, whose
     # (8, 128)-tiled minor dim is H*D. Naming the reshaped [B, S, H, 64]
@@ -359,7 +372,8 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     # inputs) while the MLP recomputes — the memory/flops midpoint between
     # "dots" and "full" (the MLP's gate/up activations are ~2/3 of a
     # layer's saved bytes but its matmuls only ~+7% of step flops)
-    q, k, v = (ctx.qkv_mm or qkv_proj)(h, lp, d)
+    q, k, v = (ctx.qkv_mm(h, lp, d) if ctx.qkv_mm is not None
+               else qkv_proj(h, lp, d, cfg.rms_norm_eps))
     n_q = q.shape[2]
 
     # K/V stay unexpanded (n_kv heads) — attention impls handle GQA so the
@@ -405,21 +419,28 @@ def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
 @scope("mlp")
 def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     """RMSNorm -> top-k routed expert SwiGLU bank (beyond the reference;
-    ops/moe.py). Returns (out, aux [2])."""
+    ops/moe.py). Returns (out, aux [3]): the pre-weighted router loss,
+    the capacity drop fraction and the busiest expert's load over the
+    mean."""
     from picotron_tpu.ops.moe import moe_mlp
 
     h = rms_norm(ctx.pre(x), lp["post_norm"], cfg.rms_norm_eps)
     h = ctx.f(h)
-    out, aux, drop = moe_mlp(
+    # every expert on this device (ep = 1): the dropless dispatch; across
+    # 'ep' the all_to_all needs the capacity path's fixed shapes
+    ep = (jax.lax.psum(1, ctx.moe_ep_axis)
+          if ctx.moe_ep_axis is not None else 1)
+    out, aux, drop, load = moe_mlp(
         h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
         num_experts=cfg.num_experts,
         top_k=cfg.num_experts_per_token,
-        capacity_factor=cfg.capacity_factor,
+        capacity_factor=cfg.capacity_factor if ep > 1 else None,
         act=mlp_act(cfg),
         ep_axis=ctx.moe_ep_axis,
         router_aux_coef=cfg.router_aux_coef,
         router_z_coef=cfg.router_z_coef,
         stat_axes=ctx.moe_stat_axes,
+        norm_topk_prob=cfg.norm_topk_prob,
     )
     # Zero-padded PP layer slots (pad_layers_for_pp) must not contribute
     # router statistics: their all-zero router yields uniform logits whose
@@ -427,21 +448,23 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     # would pollute the loss and the drop metric (code review r3). `is_real`
     # comes from the static placement (ctx.layer_is_real via run_layers),
     # not from the weights (ADVICE r3).
-    return ctx.g(out), ctx.moe_aux_sync(jnp.stack([aux, drop]) * is_real)
+    return ctx.g(out), ctx.moe_aux_sync(
+        jnp.stack([aux, drop, load]) * is_real)
 
 
 def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
                   is_real=1.0):
-    """Returns (x, aux [2]) — aux[0] is the pre-weighted router loss
+    """Returns (x, aux [3]) — aux[0] is the pre-weighted router loss
     (balance + z, 0 for dense models), aux[1] the capacity drop fraction
-    (observability; stop_gradient-free but weightless in the loss).
+    and aux[2] the busiest expert's load over the mean (observability;
+    stop_gradient-free but weightless in the loss).
     `is_real` masks the aux of zero-padded PP layer slots (see
     ParallelCtx.layer_is_real)."""
     x = x + _attention_block(x, lp, cfg, ctx, cos, sin)
     if cfg.num_experts:
         mlp_out, aux = _moe_block(x, lp, cfg, ctx, is_real)
     else:
-        mlp_out, aux = _mlp_block(x, lp, cfg, ctx), jnp.zeros(2, jnp.float32)
+        mlp_out, aux = _mlp_block(x, lp, cfg, ctx), jnp.zeros(3, jnp.float32)
     return x + mlp_out, aux
 
 
@@ -514,9 +537,9 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     """Scan a stacked layer pytree over x. Works on any contiguous stage
     slice, which is exactly what pipeline parallelism feeds it.
 
-    Returns (x, aux [2]) — aux[0] the summed pre-weighted MoE router loss
-    over the scanned layers, aux[1] the summed capacity drop fraction
-    (both 0 for dense models)."""
+    Returns (x, aux [3]) — aux[0] the summed pre-weighted MoE router loss
+    over the scanned layers, aux[1] the summed capacity drop fraction,
+    aux[2] the summed busiest-expert load ratio (all 0 for dense models)."""
     if cos is None:
         cos, sin = model_rope_tables(cfg)
 
@@ -532,7 +555,7 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
             else jnp.ones((n_slots,), jnp.float32))
     if ctx.remat:
         body = jax.checkpoint(body, policy=remat_policy_for(ctx.remat_policy))
-    x, aux_per_layer = jax.lax.scan(body, x, (layer_params, real))  # [L, 2]
+    x, aux_per_layer = jax.lax.scan(body, x, (layer_params, real))  # [L, 3]
     return x, jnp.sum(aux_per_layer, axis=0)
 
 
@@ -581,8 +604,9 @@ def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
     yields `ce_mean + aux` — the reported loss includes the router terms
     (Mixtral convention) and their gradient flows with no extra plumbing
     through the dp/cp/pp reductions. The third return is an extras dict of
-    token-weighted observability sums ({"moe_drop_weighted"} for MoE, {}
-    for dense) that ride the same psum path; the step normalizes them.
+    token-weighted observability sums ({"moe_obs_weighted"} [2] for MoE:
+    capacity drops and busiest-expert load; {} for dense) that ride the
+    same psum path; the step normalizes them.
     """
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
@@ -597,7 +621,7 @@ def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
     extras = {}
     if cfg.num_experts:
         total = total + aux[0] * count
-        extras["moe_drop_weighted"] = aux[1] * count
+        extras["moe_obs_weighted"] = aux[1:] * count
     return total, count, extras
 
 

@@ -3,14 +3,25 @@ expert parallelism over the 'ep' mesh axis.
 
 Beyond the reference (SURVEY §2.2 marks EP/MoE absent) — designed TPU-first:
 
-- **Static shapes** (GShard-style capacity): every expert processes exactly
+- **Static shapes** (GShard-style capacity; the path under ep > 1, whose
+  all_to_all needs them): every expert processes exactly
   `capacity` token slots per device; overflow tokens are dropped from the
   expert path (their residual stream passes through unchanged — top-k
   combine just contributes 0), underflow slots compute on zeros. XLA sees
   one fixed [E, C, H] einsum program, no data-dependent shapes.
-- **Routing** (Mixtral-style): softmax over the top-k router logits, so the
-  k gates sum to 1 per token. The load-balancing aux loss is the standard
+- **Routing**: softmax over all E router logits, the k largest chosen.
+  `norm_topk_prob` (the published key) renormalizes the k gates to sum to 1
+  per token (Mixtral's rule, the default); false keeps the raw
+  probabilities (OLMoE). The load-balancing aux loss is the standard
   Switch/Mixtral `E * sum_e(frac_tokens_e * mean_router_prob_e)`.
+- **Dropless dispatch** (`capacity_factor=None`; what the model layer asks
+  for whenever ep = 1): no capacity. Each
+  assignment's slot within its expert plus the exclusive prefix of the
+  group sizes is its row in an expert-sorted [N*k, H] buffer — one
+  permutation in, three grouped matmuls over the ragged group sizes
+  (`lax.ragged_dot`, which this chip's compiler lowers to its own grouped
+  matmul kernel that walks only the rows present), one permutation out.
+  No row of zeros is multiplied and no assignment can be dropped.
 - **Expert parallelism**: the expert bank [E, ...] is sharded over 'ep'
   (parallel/sharding.py). Dispatch builds per-device [E, C, H] slots, an
   `all_to_all` over 'ep' regroups them to [E/ep, ep*C, H] so each device
@@ -34,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from picotron_tpu.telemetry.scopes import scope
+
 
 class Routing(NamedTuple):
     """Per-token routing decisions (all leading dim N = flattened tokens)."""
@@ -44,10 +57,13 @@ class Routing(NamedTuple):
     #                           capacity buffer; >= capacity means dropped
     aux_loss: jnp.ndarray     # [] fp32 — load-balancing loss (unweighted)
     z_loss: jnp.ndarray       # [] fp32 — router z-loss (unweighted)
+    counts: jnp.ndarray       # [E] int32 — assignments per expert (the
+    #                           dropless dispatch's group sizes)
 
 
 def route_topk(logits: jnp.ndarray, k: int,
-               stat_axes: Optional[tuple] = None) -> Routing:
+               stat_axes: Optional[tuple] = None,
+               norm_topk_prob: bool = True) -> Routing:
     """Top-k routing with slots assigned in token order.
 
     logits: [N, E] fp32 router outputs. Slot assignment is deterministic in
@@ -68,8 +84,10 @@ def route_topk(logits: jnp.ndarray, k: int,
     logits = logits.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)                       # [N, E]
     top_p, top_i = lax.top_k(probs, k)                            # [N, k]
-    # Mixtral renormalizes the k selected probabilities to sum to 1.
-    gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    # Mixtral renormalizes the k selected probabilities to sum to 1;
+    # OLMoE (norm_topk_prob false) combines with the raw probabilities.
+    gate = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if norm_topk_prob else top_p)
 
     # slot_in_expert: for assignment (token t, choice j) -> how many earlier
     # assignments went to the same expert. Flatten [N, k] in token-major
@@ -95,7 +113,7 @@ def route_topk(logits: jnp.ndarray, k: int,
     z_loss = stat_mean(jnp.mean(z * z))
 
     return Routing(top_i.astype(jnp.int32), gate, slot.astype(jnp.int32),
-                   aux, z_loss)
+                   aux, z_loss, jnp.sum(onehot, axis=0))
 
 
 def _swiglu_experts(slots: jnp.ndarray, w_gate, w_up, w_down,
@@ -110,6 +128,80 @@ def _swiglu_experts(slots: jnp.ndarray, w_gate, w_up, w_down,
     return jnp.einsum("ecf,efh->ech", act(g) * u, w_down.astype(dt))
 
 
+# The dropless dispatch's two permutations. `row` [N, k] is each
+# assignment's row in the expert-sorted buffer and `inv` [N*k] its inverse
+# (row r holds assignment inv[r], of token inv[r] // k). Each direction is
+# written as a GATHER, and its transpose as the other one's gather instead
+# of the scatter-add AD would emit: on this chip a row gather costs about a
+# third of a row scatter (PERF.md, Findings PR 26).
+
+
+@jax.custom_vjp
+def _sorted_from_tokens(flat, row, inv):
+    """flat [N, H] -> expert-sorted [N*k, H]: row r is its token's copy."""
+    return flat[inv // row.shape[1]]
+
+
+def _sorted_from_tokens_fwd(flat, row, inv):
+    return flat[inv // row.shape[1]], row
+
+
+def _sorted_from_tokens_bwd(row, d_sorted):
+    with scope("moe_dispatch"):
+        d_flat = jnp.sum(d_sorted[row].astype(jnp.float32), axis=1)
+        return d_flat.astype(d_sorted.dtype), None, None
+
+
+_sorted_from_tokens.defvjp(_sorted_from_tokens_fwd, _sorted_from_tokens_bwd)
+
+
+@jax.custom_vjp
+def _assignments_from_sorted(out_sorted, row, inv):
+    """expert-sorted [N*k, H] -> [N, k, H]: each token's k expert outputs."""
+    return out_sorted[row]
+
+
+def _assignments_from_sorted_fwd(out_sorted, row, inv):
+    return out_sorted[row], inv
+
+
+def _assignments_from_sorted_bwd(inv, d_picked):
+    with scope("moe_dispatch"):
+        h = d_picked.shape[-1]
+        return d_picked.reshape(-1, h)[inv], None, None
+
+
+_assignments_from_sorted.defvjp(_assignments_from_sorted_fwd,
+                                _assignments_from_sorted_bwd)
+
+
+def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
+    """Every assignment through its expert, no capacity: flat [N, H] ->
+    [N, H] (gates applied, summed over k). Requires the whole bank
+    [E, H, F] / [E, F, H] on the device."""
+    n, h = flat.shape
+    k = r.expert_idx.shape[1]
+    dt = flat.dtype
+    with scope("moe_router"):
+        # an assignment's row: its expert's first row (the exclusive prefix
+        # of the group sizes) + its slot within the expert. Slots are in
+        # token order, so the rows are a permutation of 0..N*k-1: no sort.
+        offsets = jnp.cumsum(r.counts) - r.counts
+        row = offsets[r.expert_idx] + r.slot                      # [N, k]
+        inv = jnp.zeros((n * k,), jnp.int32).at[row.reshape(-1)].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+    with scope("moe_dispatch"):
+        xs = _sorted_from_tokens(flat, row, inv)                  # [N*k, H]
+    with scope("moe_experts"):
+        g = lax.ragged_dot(xs, w_gate.astype(dt), r.counts)
+        u = lax.ragged_dot(xs, w_up.astype(dt), r.counts)
+        ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), r.counts)
+    with scope("moe_dispatch"):
+        picked = _assignments_from_sorted(ys, row, inv)           # [N, k, H]
+        out = jnp.sum(picked.astype(jnp.float32) * r.gate[..., None], axis=1)
+    return out.astype(dt)
+
+
 def moe_mlp(
     x: jnp.ndarray,
     router_w: jnp.ndarray,
@@ -119,13 +211,14 @@ def moe_mlp(
     *,
     num_experts: int,
     top_k: int,
-    capacity_factor: float = 1.25,
+    capacity_factor: Optional[float] = 1.25,
     act=jax.nn.silu,
     ep_axis: Optional[str] = None,
     router_aux_coef: float = 0.0,
     router_z_coef: float = 0.0,
     stat_axes: Optional[tuple] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    norm_topk_prob: bool = True,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """MoE feed-forward. x: [B, S, H]; router_w: [H, E]; expert banks
     [E_local, H, F] / [E_local, F, H] (E_local = E/ep under expert
     parallelism — the bank arrives pre-sharded inside shard_map).
@@ -134,7 +227,14 @@ def moe_mlp(
     aux [] — the PRE-WEIGHTED router loss `aux_coef * balance +
     z_coef * z`, drop_frac [] — fraction of routing assignments dropped by
     the capacity bound, an observability scalar the train log reports;
-    capacity drops are otherwise silent). `ep_axis` names the mesh axis for
+    capacity drops are otherwise silent; without a capacity it is the
+    share of the expert-sorted rows that no group of the grouped matmuls
+    covers, 0 whenever the group sizes sum to N * k,
+    load [] — the busiest expert's assignments over the mean expert's, on
+    this device: 1 is a perfectly balanced router, E / k one that sends
+    every token to the same k). `capacity_factor=None` takes the dropless
+    grouped-matmul dispatch (`_dropless_experts`; ep = 1 only).
+    `ep_axis` names the mesh axis for
     the all_to_all pair; None = no expert parallelism (single device, or
     ep = 1). `stat_axes` makes the router statistics global (route_topk).
 
@@ -154,26 +254,40 @@ def moe_mlp(
     ep = lax.psum(1, ep_axis) if ep_axis is not None else 1
     e_local = w_gate.shape[0]
     assert e_local * ep == e, (e_local, ep, e)
+    flat = x.reshape(n, h)
+    with scope("moe_router"):
+        logits = (flat.astype(jnp.float32)
+                  @ router_w.astype(jnp.float32))                 # [N, E] fp32
+        r = route_topk(logits, top_k, stat_axes=stat_axes,
+                       norm_topk_prob=norm_topk_prob)
+        aux = router_aux_coef * r.aux_loss + router_z_coef * r.z_loss
+        load = (jnp.max(r.counts).astype(jnp.float32)
+                * (e / (n * top_k)))
+
+    if capacity_factor is None:
+        assert ep == 1 and e_local == e, "dropless dispatch needs ep = 1"
+        out = _dropless_experts(flat, r, w_gate, w_up, w_down, act)
+        # a grouped matmul leaves the rows past its last group zero, so an
+        # assignment is dropped exactly when the group sizes fall short
+        drop_frac = ((n * top_k - jnp.sum(r.counts)).astype(jnp.float32)
+                     / (n * top_k))
+        return out.reshape(b, s, h), aux, drop_frac, load
+
     # Per-device capacity per expert, padded to a lane-friendly multiple.
     cap = int(capacity_factor * top_k * n / e) + 1
     cap = -(-cap // 8) * 8
 
-    flat = x.reshape(n, h)
-    logits = (flat.astype(jnp.float32)
-              @ router_w.astype(jnp.float32))                     # [N, E] fp32
-    r = route_topk(logits, top_k, stat_axes=stat_axes)
-    aux = router_aux_coef * r.aux_loss + router_z_coef * r.z_loss
-
     # ---- dispatch: scatter assignments into [E, cap, H] slot buffers ----
-    keep = r.slot < cap                                           # [N, k]
-    drop_frac = 1.0 - jnp.mean(keep.astype(jnp.float32))
-    eidx = r.expert_idx.reshape(-1)                               # [N*k]
-    sidx = jnp.where(keep, r.slot, cap - 1).reshape(-1)
-    kflat = keep.reshape(-1)
-    tok = jnp.repeat(jnp.arange(n), top_k)                        # [N*k]
-    buf = jnp.zeros((e, cap, h), x.dtype)
-    buf = buf.at[eidx, sidx].add(
-        flat[tok] * kflat[:, None].astype(x.dtype), mode="drop")
+    with scope("moe_dispatch"):
+        keep = r.slot < cap                                       # [N, k]
+        drop_frac = 1.0 - jnp.mean(keep.astype(jnp.float32))
+        eidx = r.expert_idx.reshape(-1)                           # [N*k]
+        sidx = jnp.where(keep, r.slot, cap - 1).reshape(-1)
+        kflat = keep.reshape(-1)
+        tok = jnp.repeat(jnp.arange(n), top_k)                    # [N*k]
+        buf = jnp.zeros((e, cap, h), x.dtype)
+        buf = buf.at[eidx, sidx].add(
+            flat[tok] * kflat[:, None].astype(x.dtype), mode="drop")
 
     # ---- expert parallelism: regroup slots so each device runs only its
     # local experts over every ep-peer's slots ----
@@ -185,7 +299,8 @@ def moe_mlp(
                              tiled=False)                         # [ep, El, cap, H]
         buf = jnp.moveaxis(buf, 0, 1).reshape(e_local, ep * cap, h)
 
-    out_slots = _swiglu_experts(buf, w_gate, w_up, w_down, act=act)
+    with scope("moe_experts"):
+        out_slots = _swiglu_experts(buf, w_gate, w_up, w_down, act=act)
 
     if ep_axis is not None and ep > 1:
         out_slots = out_slots.reshape(e_local, ep, cap, h)
@@ -197,7 +312,8 @@ def moe_mlp(
     # ---- combine: gather each assignment's slot, weight by its gate.
     # tok is arange(n) repeated k times in order, so the "scatter-add back
     # to tokens" is just a dense sum over the k assignment column ----
-    picked = out_slots[eidx, sidx]                                # [N*k, H]
-    w = (r.gate.reshape(-1) * kflat).astype(x.dtype)[:, None]
-    out = (picked * w).reshape(n, top_k, h).sum(axis=1)
-    return out.reshape(b, s, h), aux, drop_frac
+    with scope("moe_dispatch"):
+        picked = out_slots[eidx, sidx]                            # [N*k, H]
+        w = (r.gate.reshape(-1) * kflat).astype(x.dtype)[:, None]
+        out = (picked * w).reshape(n, top_k, h).sum(axis=1)
+    return out.reshape(b, s, h), aux, drop_frac, load

@@ -295,14 +295,15 @@ def _data_axes_psum(grads, cfg: Config):
 
 
 def _normalize_extras(dropw, count, cfg: Config) -> dict:
-    """Turn the token-weighted capacity-drop sum into the global fraction:
-    dropw accumulates sum_micro(count_micro * sum_layers(drop_frac)), so
+    """Turn the token-weighted observability sums [2] into per-layer means:
+    dropw accumulates sum_micro(count_micro * sum_layers(stat)) for the
+    capacity drop fraction and the busiest expert's load over the mean, so
     dividing by count_total * L gives the token-weighted mean per-layer
-    drop fraction. Empty for dense models (no silent dict keys)."""
+    value of each. Empty for dense models (no silent dict keys)."""
     if not cfg.model.num_experts:
         return {}
-    return {"moe_drop_frac":
-            dropw / (count * cfg.model.num_hidden_layers)}
+    mean = dropw / (count * cfg.model.num_hidden_layers)
+    return {"moe_drop_frac": mean[0], "moe_load_max_over_mean": mean[1]}
 
 
 def _device_grads(params, batch, cfg: Config):
@@ -313,8 +314,8 @@ def _device_grads(params, batch, cfg: Config):
     pmean would mis-weight shards whose IGNORE_INDEX counts differ.
 
     Returns (grads, loss, extras) — extras is a dict of normalized
-    observability scalars ({"moe_drop_frac"} for MoE runs, {} otherwise)
-    that the step surfaces in its metrics."""
+    observability scalars ({"moe_drop_frac", "moe_load_max_over_mean"} for
+    MoE runs, {} otherwise) that the step surfaces in its metrics."""
     from picotron_tpu.parallel.pp import _vary_over
 
     ctx = make_parallel_ctx(cfg)
@@ -379,8 +380,8 @@ def _device_grads(params, batch, cfg: Config):
     def nll_sum(params, mb_ids, mb_tgt):
         total, count, extras = loss_sum_count(params, mb_ids, mb_tgt,
                                               cfg.model, ctx)
-        return total, (count, extras.get("moe_drop_weighted",
-                                         jnp.zeros((), jnp.float32)))
+        return total, (count, extras.get("moe_obs_weighted",
+                                         jnp.zeros((2,), jnp.float32)))
 
     def micro_step(carry, mb):
         g_acc, l_acc, c_acc, d_acc = carry
@@ -436,7 +437,7 @@ def _device_grads(params, batch, cfg: Config):
             params)
         init_carry = (zeros,) + compat.pcast(
             (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
-             jnp.zeros((), jnp.float32)),
+             jnp.zeros((2,), jnp.float32)),
             ("dp", "ep", "cp"), to="varying")
         (grads, nll_total, count, dropw), _ = lax.scan(
             micro_step, init_carry, (ids, tgt))

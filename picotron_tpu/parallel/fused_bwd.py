@@ -304,8 +304,16 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         dq, dk, dv = attn_bwd(r(qf), r(kf), r(vf), r(outf), lse, r(doutf))
         return flat(dq), flat(dk), flat(dv)
 
-    bias_keys = [k for k in ("b_q", "b_k", "b_v")
-                 if k in params["layers"]]
+    # optional leaves of the q/k/v segment: Qwen2's biases, OLMoE's QK-norm
+    # weights (qkv_proj branches on their presence)
+    qkv_opt_keys = [k for k in ("b_q", "b_k", "b_v", "q_norm", "k_norm")
+                    if k in params["layers"]]
+
+    def qkv(hf, lp):
+        if ctx.qkv_mm is not None:
+            return ctx.qkv_mm(hf, lp, hd)
+        return qkv_proj(hf, lp, hd, eps)
+
     moe_keys = (["router", "w_gate", "w_up", "w_down"] if moe
                 else ["gate", "up", "down"])
 
@@ -329,7 +337,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         with scope("attention"):
             h1 = rms_norm(ctx.pre(x), lp["input_norm"], eps)
             hf = ctx.f(h1)
-            q, k, v = (ctx.qkv_mm or qkv_proj)(hf, lp, hd)
+            q, k, v = qkv(hf, lp)
         out, lse = attn_fwd(q, k, v)
         with scope("attention"):
             outf = flat(out)
@@ -339,11 +347,11 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
             y = a + mo
         else:
             y = a + _mlp_block(a, lp, m, ctx)
-            aux = jnp.zeros(2, jnp.float32)
+            aux = jnp.zeros(3, jnp.float32)
         return y, ((x, flat(q), flat(k), flat(v), outf, lse), aux)
 
     xL, (saved, aux_layers) = lax.scan(fwd_body, x0, params["layers"])
-    aux_sum = jnp.sum(aux_layers, axis=0)  # [2]: (router loss, drop frac)
+    aux_sum = jnp.sum(aux_layers, axis=0)  # [3]: (router loss, drops, load)
 
     # ---------------- head + CE ----------------
     nonlayer = {k: v for k, v in params.items() if k != "layers"}
@@ -370,9 +378,9 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         # — the router-loss gradient flows per layer through the backward
         # scan's segment VJPs with cotangent 1.0 on the folded scalar.
         total = total + aux_sum[0] * count_f
-        dropw = aux_sum[1] * count_f
+        dropw = aux_sum[1:] * count_f
     else:
-        dropw = total * 0.0
+        dropw = total * jnp.zeros((2,), jnp.float32)
 
     # ---------------- backward layer scan ----------------
     def bwd_body(carry, xs):
@@ -421,21 +429,21 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         def seg_qkv(x_, w_in, wq, wk, wv, *bs):
             lpq = dict(lp)
             lpq.update(input_norm=w_in, q=wq, k=wk, v=wv,
-                       **dict(zip(bias_keys, bs)))
+                       **dict(zip(qkv_opt_keys, bs)))
             h1_ = rms_norm(ctx.pre(x_), w_in, eps)
             hf_ = ctx.f(h1_)
-            q_, k_, v_ = (ctx.qkv_mm or qkv_proj)(hf_, lpq, hd)
+            q_, k_, v_ = qkv(hf_, lpq)
             return flat(q_), flat(k_), flat(v_)
 
         _, vjp_q = jax.vjp(seg_qkv, x, lp["input_norm"], lp["q"], lp["k"],
-                           lp["v"], *[lp[k] for k in bias_keys])
+                           lp["v"], *[lp[k] for k in qkv_opt_keys])
         with scope("attention"):
             dx2, d_in, d_q, d_k, d_v, *d_bs = vjp_q((dqf, dkf, dvf))
 
         gl = dict(input_norm=d_in, q=d_q, k=d_k, v=d_v, o=d_o,
                   post_norm=d_post,
                   **dict(zip(moe_keys, d_ws)),
-                  **dict(zip(bias_keys, d_bs)))
+                  **dict(zip(qkv_opt_keys, d_bs)))
         assert set(gl) == set(lp), (sorted(gl), sorted(lp))
 
         def acc(accl, g):

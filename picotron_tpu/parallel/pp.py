@@ -217,12 +217,13 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
         # tick), while each stage contributes its own layers' (pre-weighted)
         # MoE router loss, scaled by the microbatch token count
         # (llama.loss_sum_count's folding rule) — psum over 'pp' then
-        # assembles the full total. dropw is the same-scaled capacity drop
-        # observability sum (aux[1] == 0 for dense models).
+        # assembles the full total. dropw [2] is the same-scaled pair of
+        # observability sums, capacity drops and busiest-expert load
+        # (aux[1:] == 0 for dense models).
         contrib = jnp.where(s_idx == pp - 1, total, 0.0)
         if m.num_experts:
             contrib = contrib + aux[0] * count
-        dropw = aux[1] * count
+        dropw = aux[1:] * count
         return (y, contrib), (count, dropw)
 
     return stage_fn
@@ -282,7 +283,7 @@ def pipeline_loss_sum_count(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         _boundary_axes(ctx), to="varying")
     init = (x0_buf,) + compat.pcast(
         (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
-         jnp.zeros((), jnp.float32)),
+         jnp.zeros((2,), jnp.float32)),
         ("dp", "ep", "cp", "pp"), to="varying")
     (x_last, nll_sum, cnt, dropw), _ = lax.scan(body, init,
                                                 jnp.arange(n_ticks))
@@ -425,7 +426,7 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         _boundary_axes(ctx), to="varying"
     ) + compat.pcast(
         (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
-         jnp.zeros((), jnp.float32)),
+         jnp.zeros((2,), jnp.float32)),
         ("dp", "ep", "cp", "pp"), to="varying")
     # Each grad-accumulator leaf varies over the data axes plus whatever its
     # param already varies over (tp/pp shardings) — matching what the VJP

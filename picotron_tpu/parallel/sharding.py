@@ -69,6 +69,12 @@ def param_specs(cfg: Config) -> dict[str, Any]:
             "b_k": P(pp, "tp"),
             "b_v": P(pp, "tp"),
         })
+    if cfg.model.qk_norm:
+        # whole-vector q/k norm weights: replicated (tp = 1 is validated)
+        layers.update({
+            "q_norm": P(pp, None),
+            "k_norm": P(pp, None),
+        })
     if cfg.model.num_experts:
         # expert banks [L, E, ...]: expert dim over 'ep', ffn dim over 'tp'
         # (column-parallel gate/up, row-parallel down — same as the dense

@@ -28,6 +28,9 @@ SCOPES = (
     "embed",            # token embedding lookup (and its gradient)
     "attention",        # norm, q/k/v and o projections, RoPE; the AD engine's kernel calls
     "mlp",              # norm, gated MLP or expert block
+    "moe_router",       # inside mlp: router matmul, softmax, top-k, slots, rows, aux terms
+    "moe_dispatch",     # inside mlp: the permutations into and out of expert order, gated sum
+    "moe_experts",      # inside mlp: the experts' three (grouped) matmuls and activation
     "head_ce",          # final norm, head matmul, cross entropy, their gradient
     "dw_accum",         # the fused engine's in-scan dW accumulation into the f32 stacks
     "optimizer",        # clip, Adam update, cast back

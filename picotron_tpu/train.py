@@ -321,6 +321,11 @@ def main(argv=None) -> None:
     peak = device_peak_flops(dev0) if dev0.platform == "tpu" else None
     offload = (offload_memory_kind(menv.mesh) or "unplaced"
                if t.optimizer_offload else "off")
+    # which expert dispatch was built: the capacity path and its option exist
+    # only across 'ep' (models/llama._moe_block selects on the mesh)
+    experts = "" if not cfg.model.num_experts else (
+        f" experts=capacity({cfg.model.capacity_factor})" if menv.ep > 1
+        else " experts=dropless (capacity_factor has no effect at ep=1)")
     log_print(
         f"model {cfg.model.name}: {human_format(n_params)} params | "
         f"mesh dp={menv.dp} pp={menv.pp} ep={menv.ep} cp={menv.cp} tp={menv.tp} "
@@ -328,7 +333,7 @@ def main(argv=None) -> None:
         f"global batch {cfg.global_batch_size} x seq {t.seq_length} = "
         f"{human_format(cfg.tokens_per_step)} tokens/step | "
         f"platform={dev0.platform} attention={attention_path(cfg)} "
-        f"offload={offload}"
+        f"offload={offload}{experts}"
     )
 
     # Structured telemetry (picotron_tpu/telemetry; README

@@ -65,13 +65,20 @@ def load(kind, name):
 def compiled_step(topo, monkeypatch, config: str) -> str:
     """`compiled.as_text()` of the cell's train step at the cell's widths
     and one layer a stage, for the described chips."""
+    return compile_step(topo, monkeypatch, config, layers=2).as_text()
+
+
+def compile_step(topo, monkeypatch, config: str, layers: int | None):
+    """The cell's train step at the cell's widths and `layers` layers (None:
+    the cell's own depth), compiled for the described chips."""
     # the program asks the backend whether the kernels exist; here the
     # backend is the CPU and the target is the described chip
     fa = importlib.import_module("picotron_tpu.ops.flash_attention")
     monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
     c = load("configs", config)
     d = c["distributed"]
-    c["model"]["num_hidden_layers"] = 2
+    if layers is not None:
+        c["model"]["num_hidden_layers"] = layers
     cfg = config_from_dict({k: c[k] for k in ("distributed", "model", "training")})
     n = d["dp_size"] * d["pp_size"] * d["cp_size"] * d["tp_size"]
     menv = MeshEnv.create(dp=d["dp_size"], pp=d["pp_size"], cp=d["cp_size"],
@@ -81,7 +88,7 @@ def compiled_step(topo, monkeypatch, config: str) -> str:
     b = jax.ShapeDtypeStruct(
         (t.gradient_accumulation_steps, t.micro_batch_size * d["dp_size"],
          t.seq_length), jnp.int32, sharding=menv.batch_sharding())
-    return make_train_step(cfg, menv).lower(state, (b, b)).compile().as_text()
+    return make_train_step(cfg, menv).lower(state, (b, b)).compile()
 
 
 def instructions(text: str):
@@ -133,6 +140,36 @@ def test_four_chip_step_keeps_its_collectives_names(topo, monkeypatch):
     found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
     assert found >= {"embed", "attention", "mlp", "head_ce", "optimizer",
                      "pp_boundary", "tp_reduce"}
+
+
+def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):
+    """The sparse-expert cell at its own depth: the fused engine's three
+    flash kernels keep the names `flash_roofline.train` finds, the expert
+    matmuls are the compiler's grouped-matmul kernels under the name
+    `moe_experts_ms.train` finds (they carry no name stack, so the name is all
+    there is), the three expert scopes are on the name stack, and the step
+    fits the chip."""
+    comp = compile_step(topo, monkeypatch, "olmoe-1b-7b-1l", layers=None)
+    ins = instructions(comp.as_text())
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    flash_pat = re.compile(load("layer_metrics", "flash_roofline.train")["params"]["pattern"])
+    flash = [(n, op) for n, op in kernels if flash_pat.search(n)]
+    assert len(flash) == 3, kernels
+    for name, op in flash:
+        assert op.endswith("/pallas_call") and not words(op) & set(SCOPES), op
+    grouped_pat = re.compile(load("layer_metrics", "moe_experts_ms.train")["params"]["ops"])
+    grouped = [n for n, _ in kernels if grouped_pat.search(n)]
+    assert len(flash) + len(grouped) == len(kernels), kernels
+    # gate, up, down forward; dX and dW of each (at one layer the fused
+    # engine's re-run of gate and up is the forward's, merged by the compiler)
+    assert sum(n.startswith("ragged-dot-none") for n in grouped) == 9, grouped
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"embed", "attention", "mlp", "moe_router", "moe_dispatch",
+                     "moe_experts", "head_ce", "dw_accum", "optimizer"}
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15.75 * 2**30, total / 2**30
 
 
 def compiled_serve(topo, program: str):

@@ -56,7 +56,7 @@ def test_moe_mlp_matches_dense_reference(top_k):
     key = jax.random.key(0)
     router_w, w_gate, w_up, w_down = moe_weights(key)
     x = jax.random.normal(jax.random.key(1), (2, 12, 16))  # [B, S, H]
-    out, aux, drop = moe_mlp(x, router_w, w_gate, w_up, w_down,
+    out, aux, drop, _ = moe_mlp(x, router_w, w_gate, w_up, w_down,
                              num_experts=4, top_k=top_k, capacity_factor=8.0,
                              router_aux_coef=0.01)  # no drops
     ref = dense_moe_reference(x.reshape(24, 16), router_w, w_gate, w_up,
@@ -73,7 +73,7 @@ def test_moe_mlp_grads_match_dense_reference():
     x = jax.random.normal(jax.random.key(1), (2, 12, 16))
 
     def loss_moe(x, *w):
-        out, _, _ = moe_mlp(x, *w, num_experts=4, top_k=2,
+        out, _, _, _ = moe_mlp(x, *w, num_experts=4, top_k=2,
                             capacity_factor=8.0)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
@@ -94,7 +94,7 @@ def test_moe_capacity_drops_tokens():
     key = jax.random.key(0)
     router_w, w_gate, w_up, w_down = moe_weights(key)
     x = jax.random.normal(jax.random.key(1), (1, 64, 16))
-    out, _, drop = moe_mlp(x, router_w, w_gate, w_up, w_down, num_experts=4,
+    out, _, drop, _ = moe_mlp(x, router_w, w_gate, w_up, w_down, num_experts=4,
                            top_k=2, capacity_factor=0.25)
     assert np.all(np.isfinite(np.asarray(out)))
     # the drop-fraction observability scalar reports the overflow
@@ -234,9 +234,9 @@ def test_route_topk_z_loss_and_z_coef_wiring():
     # the coefficient reaches the pre-weighted aux
     w = moe_weights(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (1, 8, 16))
-    _, aux0, _ = moe_mlp(x, *w, num_experts=4, top_k=2, capacity_factor=8.0,
+    _, aux0, _, _ = moe_mlp(x, *w, num_experts=4, top_k=2, capacity_factor=8.0,
                          router_aux_coef=0.01, router_z_coef=0.0)
-    _, aux1, _ = moe_mlp(x, *w, num_experts=4, top_k=2, capacity_factor=8.0,
+    _, aux1, _, _ = moe_mlp(x, *w, num_experts=4, top_k=2, capacity_factor=8.0,
                          router_aux_coef=0.01, router_z_coef=1.0)
     assert float(aux1) > float(aux0)
 

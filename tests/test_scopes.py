@@ -40,14 +40,22 @@ def module_name(text: str) -> str:
     return re.search(r"module @(\S+)", text).group(1)
 
 
-def train_cfg(engine: str, pp: int) -> Config:
+MOE = {"moe_router", "moe_dispatch", "moe_experts"}
+
+
+def train_cfg(engine: str, pp: int, moe: bool = False) -> Config:
+    if moe:  # OLMoE-shaped: QK-norm and the dropless dispatch need tp = 1
+        model = ModelConfig(**{**resolve_preset("debug-tiny-olmoe"),
+                               "max_position_embeddings": 64})
+    else:
+        model = ModelConfig(num_attention_heads=8, num_key_value_heads=4,
+                            num_hidden_layers=2, hidden_size=64,
+                            intermediate_size=96, vocab_size=256,
+                            max_position_embeddings=64, attention_bias=True)
     return Config(
-        distributed=DistributedConfig(tp_size=2, pp_size=pp, dp_size=1,
-                                      pp_engine="1f1b"),
-        model=ModelConfig(num_attention_heads=8, num_key_value_heads=4,
-                          num_hidden_layers=2, hidden_size=64,
-                          intermediate_size=96, vocab_size=256,
-                          max_position_embeddings=64, attention_bias=True),
+        distributed=DistributedConfig(tp_size=1 if moe else 2, pp_size=pp,
+                                      dp_size=1, pp_engine="1f1b"),
+        model=model,
         training=TrainingConfig(grad_engine=engine, seq_length=32,
                                 micro_batch_size=1,
                                 gradient_accumulation_steps=2, remat=True,
@@ -58,9 +66,11 @@ def train_cfg(engine: str, pp: int) -> Config:
     ("fused", 1, {"dw_accum"}),
     ("ad", 1, set()),
     ("ad", 2, {"pp_boundary"}),
-])
+    ("fused", 1, {"dw_accum"} | MOE),
+    ("ad", 1, MOE),
+], ids=["fused", "ad", "ad-pp2", "fused-moe", "ad-moe"])
 def test_train_step_scopes_and_module_name(engine, pp, extra):
-    cfg = train_cfg(engine, pp)
+    cfg = train_cfg(engine, pp, moe=extra >= MOE)
     menv = MeshEnv.from_config(cfg)
     state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
     t = cfg.training
