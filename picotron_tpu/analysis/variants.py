@@ -22,12 +22,15 @@ property *provable* on the host, before a chip is touched:
   for the whole run, fused-bwd and 1f1b interiors included (they live
   inside this jit; a custom-vjp path cannot mint an outer variant).
 - `prove_serve_programs(...)` / `check_engine_feed(engine)` certify the
-  decode + prefill programs: slot count is the only shape carrier (all
-  decode inputs are [S]/[S, C]-shaped, request identity is data), so the
-  signature space is closed iff every persistent input is committed and
-  every per-step upload goes through the engine's single replicated
-  sharding — the commit-everything discipline, now checked instead of
-  trusted.
+  decode + prefill programs: slot count is the decode program's only
+  shape carrier (its inputs are [S]-shaped, request identity is data),
+  and the prefill program's is the row count of its compacted batch,
+  which the engine draws from the fixed ladder `prefill_rungs(S)` and
+  nowhere else ([R, C]-shaped inputs, which slots prefill is data). So
+  the signature space is one decode signature and one prefill signature
+  per rung, closed iff every persistent input is committed and every
+  per-step upload goes through the engine's single replicated sharding
+  — the commit-everything discipline, now checked instead of trusted.
 
 What "proven" covers — and does not. The proof is over the abstract
 signature space: it shows no *input-side* variant can occur. It does not
@@ -290,8 +293,12 @@ def check_engine_feed(engine) -> Report:
     pool, rope tables, the sampling key — is committed, and (b) the
     per-step host uploads all route through the engine's single
     `_rep_sh` sharding (true by construction; recorded here). Slot count
-    is the only shape carrier, so with (a) and (b) the signature space is
-    exactly {one decode sig} x {one prefill sig}."""
+    is the decode program's only shape carrier, and the prefill program's
+    is the row count of its compacted batch, always a rung of the
+    engine's `prefill_rungs` (a function of the slot count; the
+    constructor compiles each). So with (a) and (b) the signature space
+    is exactly one decode signature and one prefill signature per rung:
+    `info["signatures"]` counts them."""
     from picotron_tpu.analysis.spec_lint import dict_by_path
 
     rep = Report()
@@ -313,9 +320,13 @@ def check_engine_feed(engine) -> Report:
                 "recompile — device_put it with an explicit sharding at "
                 "engine construction")
     proven = not uncommitted
+    rungs = tuple(getattr(engine, "prefill_rungs", ()))
     rep.info[CHECK] = {
         "entry": "serve_decode+prefill",
-        "signatures": 1 if proven else 2,
+        # one decode signature and one prefill signature a rung; an
+        # uncommitted input can mint each a second time
+        "signatures": (1 + len(rungs)) * (1 if proven else 2),
+        "prefill_rows": list(rungs),
         "proven": proven,
         "uncommitted": uncommitted,
         "upload_sharding": type(getattr(engine, "_rep_sh", None)).__name__,
@@ -323,9 +334,11 @@ def check_engine_feed(engine) -> Report:
     }
     if proven:
         rep.add(CHECK, INFO, "serve",
-                "compile-once proven for decode and prefill: every "
-                "persistent input committed; host uploads share one "
-                "replicated sharding; slot count is the only static shape")
+                "compile-once proven for decode, and for prefill at each "
+                f"row count of its ladder {list(rungs)}: every persistent "
+                "input committed; host uploads share one replicated "
+                "sharding; slot count and the rung are the only static "
+                "shapes")
     return rep
 
 
@@ -340,6 +353,7 @@ def prove_serve_programs(model_cfg, serve_cfg=None, *, params=None) -> \
     import jax.numpy as jnp
 
     from picotron_tpu.config import ServeConfig
+    from picotron_tpu.serve.engine import prefill_rungs
     from picotron_tpu.serve.paged_cache import init_paged_cache
     from picotron_tpu.serve.scheduler import blocks_for
 
@@ -350,6 +364,7 @@ def prove_serve_programs(model_cfg, serve_cfg=None, *, params=None) -> \
     max_blocks = blocks_for(max_len, scfg.block_size)
     num_blocks = scfg.num_blocks or scfg.decode_slots * max_blocks
     s = scfg.decode_slots
+    rungs = prefill_rungs(s)
 
     # abstract: the real pool for a 7B model is GBs of zeros — the proof
     # only needs the shapes ServeEngine would feed
@@ -362,18 +377,18 @@ def prove_serve_programs(model_cfg, serve_cfg=None, *, params=None) -> \
         "tables": i32(s, max_blocks), "toks": i32(s),
         "positions": i32(s), "rids": i32(s), "tidx": i32(s),
     }
-    prefill_args = {
-        "k": decode_args["k"], "v": decode_args["v"],
-        "tables": i32(s, max_blocks),
-        "chunk_ids": i32(s, scfg.prefill_chunk),
-        "start_pos": i32(s), "n_valid": i32(s), "rids": i32(s),
-        "tidx": i32(s),
-    }
-    # one signature per program: every shape above is a pure function of
-    # (model_cfg, serve_cfg) — request identity, positions, and block
-    # tables are DATA; nothing a request can do changes an abstract shape
+    # one signature for decode and one per rung for prefill: every shape
+    # is a pure function of (model_cfg, serve_cfg) — request identity,
+    # positions, block tables and WHICH slots prefill are DATA; how MANY
+    # prefill picks a rung of the fixed ladder, nothing else
     sig_d = signature_of(decode_args)
-    sig_p = signature_of(prefill_args)
+    sigs_p = {r: signature_of({
+        "k": decode_args["k"], "v": decode_args["v"],
+        "tables": i32(r, max_blocks),
+        "chunk_ids": i32(r, scfg.prefill_chunk),
+        "start_pos": i32(r), "n_valid": i32(r), "rids": i32(r),
+        "tidx": i32(r),
+    }) for r in rungs}
     uncommitted = []
     if params is not None:
         from picotron_tpu.analysis.spec_lint import dict_by_path
@@ -388,10 +403,11 @@ def prove_serve_programs(model_cfg, serve_cfg=None, *, params=None) -> \
     proven = not uncommitted
     rep.info[CHECK] = {
         "entry": "serve_decode+prefill",
-        "signatures": 1 if proven else 2,
+        "signatures": (1 + len(sigs_p)) * (1 if proven else 2),
+        "prefill_rows": list(rungs),
         "proven": proven,
         "decode_leaves": len(sig_d.leaves),
-        "prefill_leaves": len(sig_p.leaves),
+        "prefill_leaves": len(sigs_p[s].leaves),
         "uncommitted": uncommitted,
     }
     return rep
@@ -408,14 +424,17 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
     slot counts, and the fixed [max_blocks] handoff index width are
     config constants, while request identity, positions, block tables,
     and the handoff's actual block ids are DATA. One signature per
-    program => each pool compiles exactly once per engine lifetime, so
-    a prefill burst cannot trigger a decode-side recompile (nor vice
-    versa). With `speculator = "ngram"` the decode-pool program is the
+    program (the prefill-pool program: one per row count of
+    `prefill_rungs(prefill_slots)`, each compiled by the constructor)
+    => no pool compiles after construction, so a prefill burst cannot
+    trigger a decode-side recompile (nor vice versa). With
+    `speculator = "ngram"` the decode-pool program is the
     speculative scan; its ctx buffer is [S, CTX_W] with CTX_W constant,
     so the closure argument is unchanged."""
     import jax.numpy as jnp
 
     from picotron_tpu.config import ServeConfig
+    from picotron_tpu.serve.engine import prefill_rungs
     from picotron_tpu.serve.paged_cache import init_paged_cache
     from picotron_tpu.serve.scheduler import blocks_for
 
@@ -481,12 +500,16 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
         "signatures": {name: len(sig.leaves) for name, sig in sigs.items()},
         "proven": True,
         "prefill_slots": p, "decode_slots": s,
+        # the prefill-pool program's batch is compacted: its signature
+        # above is the top rung's, the others differ in the row count only
+        "prefill_rows": list(prefill_rungs(p)),
         "speculator": scfg.speculator,
     }
     rep.add(CHECK, INFO, "serve_disagg",
             f"compile-once proven for both pools + handoff: "
             f"{len(sigs)} programs, one closed abstract signature each "
-            f"(prefill [{p}, {scfg.prefill_chunk}], decode [{s}], "
+            f"(prefill [R, {scfg.prefill_chunk}] for R in "
+            f"{list(prefill_rungs(p))}, decode [{s}], "
             f"handoff idx [{max_blocks}])")
     return rep
 

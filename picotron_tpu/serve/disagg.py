@@ -54,12 +54,12 @@ import numpy as np
 
 from picotron_tpu.config import ModelConfig, ServeConfig
 from picotron_tpu.models.llama import model_rope_tables
-from picotron_tpu.resilience import watchdog
-from picotron_tpu.serve.engine import ServeEngine, _get_jits
+from picotron_tpu.serve.engine import (
+    ServeEngine, _get_jits, prefill_rungs,
+)
 from picotron_tpu.serve.paged_cache import BlockPool, init_paged_cache
 from picotron_tpu.serve.scheduler import DisaggScheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
-from picotron_tpu.telemetry.spans import join_ids
 
 
 # ---------------------------------------------------------------------------
@@ -141,6 +141,7 @@ class DisaggServeEngine(ServeEngine):
         self.num_blocks = (scfg.num_blocks
                            or scfg.decode_slots * self.max_blocks)
         self.num_pslots = scfg.prefill_slots or scfg.decode_slots
+        self.prefill_rungs = prefill_rungs(self.num_pslots)
         self.pnum_blocks = (scfg.prefill_num_blocks
                             or self.num_pslots * self.max_blocks)
 
@@ -250,7 +251,7 @@ class DisaggServeEngine(ServeEngine):
         self.results: list = []
         self.shed_results: list = []
         self.stats = {
-            "decode_steps": 0, "decode_compiles": 0,
+            "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
             "prefill_chunks": 0, "occupancy_sum": 0.0,
             "prefill_occupancy_sum": 0.0, "prefill_ticks": 0,
             "output_tokens": 0, "prefill_tokens": 0,
@@ -260,6 +261,7 @@ class DisaggServeEngine(ServeEngine):
         }
         self._stall_streak = 0
         self._next_auto_id = 0
+        self._warm_prefill()
 
         try:
             from picotron_tpu.analysis.variants import check_engine_feed
@@ -271,7 +273,8 @@ class DisaggServeEngine(ServeEngine):
         except Exception:  # analysis is best-effort at serve time
             self.variant_report = None
 
-    # -- prefill-pool table mirror ----------------------------------------
+    # -- the prefill pool: its table mirror, and what points the
+    # inherited `_prefill_tick` / `_warm_prefill` at it
 
     def _sync_ptable(self, pslot: int) -> None:
         st = self.sched.pslots[pslot]
@@ -279,6 +282,27 @@ class DisaggServeEngine(ServeEngine):
         if st is not None and st.blocks:
             row[:len(st.blocks)] = st.blocks
         self._tables_p[pslot] = row
+
+    _PREFILL_PHASE = {"pool": "prefill"}
+
+    def _prefill_pool(self):
+        return (self.sched.pslots, self._tables_p, self.pnum_blocks,
+                self._sh_p)
+
+    def _run_prefill(self, feed):
+        self._k_p, self._v_p, toks = self._prefill_jit(
+            self.params_p, self._k_p, self._v_p, *feed, self.base_key_p,
+            self.cos_p, self.sin_p, cfg=self.cfg,
+            temperature=self.temperature, top_k=self.top_k)
+        return toks
+
+    def _retire_prefilled(self, pslot: int, t: float) -> None:
+        # first token already finishes it: retire straight from the
+        # prefill pool, no handoff needed
+        if self.sched.should_retire(pslot, self.eos_token_id, pslot=True):
+            st = self.sched.retire_prefill(pslot)
+            self._sync_ptable(pslot)
+            self._emit_retired(st, t)
 
     # -- handoff -----------------------------------------------------------
 
@@ -319,76 +343,9 @@ class DisaggServeEngine(ServeEngine):
                 self._emit_shed(st, now)
             sp.set(admitted=len(admitted), queued=len(self.sched.queue))
 
-        worked = False
-
-        # ---- prefill chunks, batched over the PREFILL pool's slots
-        pslots = self.sched.prefill_slots()
-        if pslots:
-            c = self.scfg.prefill_chunk
-            with self._span("serve.prefill.build"):
-                ids = np.zeros((self.num_pslots, c), np.int32)
-                start = np.zeros((self.num_pslots,), np.int32)
-                nval = np.zeros((self.num_pslots,), np.int32)
-                rids = np.zeros((self.num_pslots,), np.int32)
-                tidx = np.zeros((self.num_pslots,), np.int32)
-                finals = []
-                for s in pslots:
-                    st = self.sched.pslots[s]
-                    chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-                    ids[s, :len(chunk)] = chunk
-                    start[s] = st.n_prefilled
-                    nval[s] = len(chunk)
-                    rids[s] = st.req.id
-                    tidx[s] = len(st.generated)
-                    if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
-                        finals.append(s)
-                up = partial(jax.device_put, device=self._sh_p)
-                feed = (up(self._tables_p), up(ids), up(start), up(nval),
-                        up(rids), up(tidx))
-            n_prefilled = int(nval.sum())
-            req_ids = [int(rids[s]) for s in pslots]
-            self._drain_compile()
-            if watchdog.active():
-                watchdog.touch(
-                    f"serve engine={self.engine_id} dispatch=prefill")
-            t0 = time.perf_counter()
-            with self._span("serve.prefill.dispatch", slots=len(pslots),
-                            tokens=n_prefilled, capacity=self.num_pslots * c,
-                            ids=join_ids(req_ids)):
-                self._k_p, self._v_p, toks_d = self._prefill_jit(
-                    self.params_p, self._k_p, self._v_p, *feed,
-                    self.base_key_p, self.cos_p, self.sin_p, cfg=self.cfg,
-                    temperature=self.temperature, top_k=self.top_k)
-            toks = None
-            if finals:
-                with self._span("serve.prefill.wait", finals=len(finals)):
-                    toks = np.asarray(toks_d)
-            dt = time.perf_counter() - t0
-            dt -= min(self._drain_compile(), dt)
-            self.telemetry.emit("phase", phase="prefill",
-                                category="prefill", secs=dt,
-                                tokens=n_prefilled, pool="prefill",
-                                ids=req_ids, waited=bool(finals))
-            for s in pslots:
-                self.sched.note_prefilled(s, int(nval[s]))
-            self.stats["prefill_chunks"] += len(pslots)
-            self.stats["prefill_tokens"] += n_prefilled
-            for s in finals:
-                st = self.sched.pslots[s]
-                st.generated.append(int(toks[s]))
-                self.stats["output_tokens"] += 1
-                if st.t_first_token is None:
-                    st.t_first_token = now + dt
-                    ttft = max(st.t_first_token - st.req.arrival, 0.0)
-                    reg.histogram("serve/ttft").observe(ttft)
-                if self.sched.should_retire(s, self.eos_token_id,
-                                            pslot=True):
-                    # first token already finishes it: retire straight
-                    # from the prefill pool, no handoff needed
-                    st = self.sched.retire_prefill(s)
-                    self._sync_ptable(s)
-                    self._emit_retired(st, now + dt)
-            worked = True
+        # ---- prefill chunks, compacted over the PREFILL pool's slots
+        # (inherited — runs against `_prefill_pool`, on its placement)
+        worked = self._prefill_tick(now, reg)
         self.stats["prefill_ticks"] += 1
         self.stats["prefill_occupancy_sum"] += (
             sum(s is not None for s in self.sched.pslots)

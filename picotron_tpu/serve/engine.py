@@ -3,7 +3,7 @@
 The host loop owns the scheduler (admission / chunked prefill /
 preemption / retirement, serve/scheduler.py) and drives exactly TWO
 jitted device programs, each compiled once for the whole serving
-lifetime:
+lifetime (the prefill program once per row count of a short ladder):
 
 - ``decode step``: a fixed batch of `decode_slots` slots, one token per
   slot per call. Slot count is the static shape; which request occupies
@@ -13,10 +13,16 @@ lifetime:
   across a multi-request trace). Idle/prefilling slots ride along at
   position -1: their q-rows compute masked garbage that is discarded and
   their K/V writes resolve to the sentinel block and drop.
-- ``prefill chunk``: `prefill_chunk` tokens of ONE slot's prompt,
-  interleaved one chunk per engine iteration so a long prompt never
-  stalls the in-flight decode batch. The final (padded) chunk returns
-  the last valid position's logits — the request's first token (TTFT).
+- ``prefill chunk``: `prefill_chunk` tokens of every mid-prefill slot's
+  prompt, one row a slot, interleaved one dispatch per engine iteration
+  so a long prompt never stalls the in-flight decode batch. The batch is
+  COMPACTED: it holds the slots that are mid-prefill and no others,
+  padded up to the next rung of `prefill_rungs(decode_slots)` (1, 4, 16,
+  ... , decode_slots), so the program computes the rows that prefill and
+  not `decode_slots` rows whatever prefills. One executable a rung, each
+  compiled (or loaded from the cache) by the constructor, none later. A
+  row whose prompt ends in the chunk returns its last valid position's
+  token — the request's first token (TTFT).
 
 Both run `generate._decode_layers` against `PagedKVCache` — the same
 layer math as the offline contiguous path, which is what makes greedy
@@ -153,17 +159,20 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
 def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
                   n_valid, rids, tidx, base_key, cos, sin,
                   cfg: ModelConfig, temperature: float, top_k: int):
-    """Prefill the next chunk of EVERY mid-prefill slot in one dispatch:
-    chunk_ids [S, C] (padded), start_pos/n_valid/rids/tidx [S],
-    table_rows [S, max_blocks]. Rows with n_valid = 0 are idle slots
-    riding along (all positions -1: writes sentinel-drop, outputs
-    discarded); padded positions inside a live row behave the same.
-    Batching matters: a per-slot prefill dispatch measured ~2x the
-    static sampler's batched prompt pass on the CPU bench — one [S, C]
-    program closes that. Samples each row's next token off its last
-    valid position's logits with the same (request id, token index) key
-    derivation as the decode step — one sampling law everywhere.
-    Returns (k, v, tokens [S])."""
+    """Prefill the next chunk of every mid-prefill slot in one dispatch:
+    chunk_ids [R, C] (padded), start_pos/n_valid/rids/tidx [R],
+    table_rows [R, max_blocks]. A row is a mid-prefill slot, not a slot
+    index: the host compacts the batch (`ServeEngine._prefill_feed`), so
+    R is a rung of `prefill_rungs`, and the row's table row says where
+    its K/V live. Rows with n_valid = 0 pad the batch up to the rung
+    (all positions -1 and an all-unmapped table row: writes
+    sentinel-drop, outputs discarded); padded positions inside a live
+    row behave the same. Batching matters: a per-slot prefill dispatch
+    measured ~2x the static sampler's batched prompt pass on the CPU
+    bench — one [R, C] program closes that. Samples each row's next
+    token off its last valid position's logits with the same (request
+    id, token index) key derivation as the decode step — one sampling
+    law everywhere. Returns (k, v, tokens [R])."""
     s, c = chunk_ids.shape
     t = jnp.arange(c)[None, :]
     pos = jnp.where(t < n_valid[:, None], start_pos[:, None] + t, -1)
@@ -197,6 +206,23 @@ def _get_jits(donate: bool):
                     static_argnames=("cfg", "temperature", "top_k")),
         )
     return _JITS[donate]
+
+
+def prefill_rungs(num_slots: int) -> tuple:
+    """The row counts the prefill program is compiled for, a function of
+    the pool's slot count and nothing else: the powers of 4 below it,
+    then the slot count itself (1, 4, 16, 32 at 32 slots). A dispatch
+    takes the smallest rung that holds its mid-prefill slots, so it pads
+    by under 4x and every row count up to `num_slots` has a rung. A
+    dispatch costs by its rows, so a finer ladder serves faster (powers
+    of 2 measured 13% under this one on the chat cell's TTFT p90), but a
+    rung costs start-up a trace and a cache load, and the set-up bound
+    had no room for six (PERF.md, PR 28)."""
+    rungs, r = [], 1
+    while r < num_slots:
+        rungs.append(r)
+        r *= 4
+    return (*rungs, num_slots)
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +261,7 @@ class ServeEngine:
         self.num_blocks = (scfg.num_blocks
                            or scfg.decode_slots * self.max_blocks)
         self.num_slots = scfg.decode_slots
+        self.prefill_rungs = prefill_rungs(self.num_slots)
 
         self.speculate = scfg.speculator == "ngram"
         self.draft_len = scfg.draft_len if self.speculate else 0
@@ -326,7 +353,7 @@ class ServeEngine:
         self.results: list = []
         self.shed_results: list = []
         self.stats = {
-            "decode_steps": 0, "decode_compiles": 0,
+            "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
             "prefill_chunks": 0, "occupancy_sum": 0.0,
             "output_tokens": 0, "prefill_tokens": 0,
             "draft_tokens": 0, "accepted_draft_tokens": 0,
@@ -334,6 +361,7 @@ class ServeEngine:
         }
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
         self._next_auto_id = 0
+        self._warm_prefill()
 
         # Static variant-prover check over the feed the engine just built
         # (analysis/variants.py): every persistent leaf must be committed,
@@ -395,6 +423,78 @@ class ServeEngine:
             row[:len(st.blocks)] = st.blocks
         self._tables[slot] = row
         self._decode_state = None  # roster/table changed: slow path next
+
+    # -- the prefill program's side of the engine: what DisaggServeEngine
+    # overrides to point the shared feed at its own prefill pool
+
+    def _prefill_pool(self):
+        """(slot states, host table mirror, pool size = the unmapped
+        sentinel, upload sharding) of the pool the prefill program
+        writes."""
+        return (self.sched.slots, self._tables, self.num_blocks,
+                self._rep_sh)
+
+    _PREFILL_PHASE: dict = {}  # further keys of the `phase=prefill` event
+
+    def _retire_prefilled(self, slot: int, t: float) -> None:
+        """A request whose first token already ends it (EOS, a budget of
+        one) leaves straight from the pool it was prefilled in."""
+        if self.sched.should_retire(slot, self.eos_token_id):
+            st = self.sched.retire(slot)
+            self._sync_table(slot)
+            self._emit_retired(st, t)
+
+    def _run_prefill(self, feed):
+        """One dispatch of the prefill program on `feed`; returns the
+        rows' tokens, still on the device."""
+        self._k, self._v, toks = self._prefill_jit(
+            self.params, self._k, self._v, *feed, self.base_key,
+            self.cos, self.sin, cfg=self.cfg,
+            temperature=self.temperature, top_k=self.top_k)
+        return toks
+
+    def _prefill_feed(self, pslots, rows: Optional[int] = None):
+        """The compacted prefill batch: row i carries the next chunk of
+        slot pslots[i] and that slot's table row; the batch is padded up
+        to the smallest rung that holds them (or to `rows`). A pad row
+        has n_valid 0 and an all-unmapped table row, so its writes drop
+        and its token is never read. Returns (device feed in
+        `serve_prefill`'s argument order, n_valid [R] on the host, the
+        rows whose prompt ends in this chunk)."""
+        states, tables, unmapped, sh = self._prefill_pool()
+        c = self.scfg.prefill_chunk
+        r = rows or next(x for x in self.prefill_rungs if x >= len(pslots))
+        trows = np.full((r, self.max_blocks), unmapped, np.int32)
+        ids = np.zeros((r, c), np.int32)
+        start, nval, rids, tidx = np.zeros((4, r), np.int32)
+        finals = []
+        for row, s in enumerate(pslots):
+            st = states[s]
+            chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
+            trows[row] = tables[s]
+            ids[row, :len(chunk)] = chunk
+            start[row] = st.n_prefilled
+            nval[row] = len(chunk)
+            rids[row] = st.req.id
+            tidx[row] = len(st.generated)
+            if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
+                finals.append(row)
+        feed = jax.device_put((trows, ids, start, nval, rids, tidx), sh)
+        return feed, nval, finals
+
+    def _warm_prefill(self) -> None:
+        """Compile (or load from the cache) the prefill program at every
+        rung, by dispatching each once with every row a pad row: the
+        pools come back as they went in. A serving window then meets no
+        shape the constructor has not held, whatever the traffic's
+        concurrency; `stats["prefill_compiles"]` counts the rungs that
+        compiled here, and stays there. Largest rung first and no wait:
+        the device runs a rung's idle batch while the host traces the
+        next, smaller one, so set-up pays the tracing alone."""
+        for r in reversed(self.prefill_rungs):
+            self._drain_compile()
+            self._run_prefill(self._prefill_feed([], rows=r)[0])
+            self.stats["prefill_compiles"] += bool(self._drain_compile())
 
     def _drain_compile(self) -> float:
         n, secs = self.telemetry.compile_watch.drain()
@@ -482,82 +582,9 @@ class ServeEngine:
                 self._emit_shed(st, now)
             sp.set(admitted=len(admitted), queued=len(self.sched.queue))
 
-        worked = False
-
         # ---- one prefill chunk per mid-prefill slot, batched into a
         # single dispatch and interleaved with the decode step
-        pslots = self.sched.prefill_slots()
-        if pslots:
-            c = self.scfg.prefill_chunk
-            with self._span("serve.prefill.build"):
-                ids = np.zeros((self.num_slots, c), np.int32)
-                start = np.zeros((self.num_slots,), np.int32)
-                nval = np.zeros((self.num_slots,), np.int32)
-                rids = np.zeros((self.num_slots,), np.int32)
-                tidx = np.zeros((self.num_slots,), np.int32)
-                finals = []
-                for s in pslots:
-                    st = self.sched.slots[s]
-                    chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-                    ids[s, :len(chunk)] = chunk
-                    start[s] = st.n_prefilled
-                    nval[s] = len(chunk)
-                    rids[s] = st.req.id
-                    tidx[s] = len(st.generated)
-                    if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
-                        finals.append(s)
-                up = partial(jax.device_put, device=self._rep_sh)
-                feed = (up(self._tables), up(ids), up(start), up(nval),
-                        up(rids), up(tidx))
-            n_prefilled = int(nval.sum())
-            req_ids = [int(rids[s]) for s in pslots]
-            self._drain_compile()
-            if watchdog.active():
-                # a hang inside this dispatch is reported as THIS
-                # dispatch, not a bare stack dump (satellite of the
-                # fleet's serve_hang detection; also arms bench --serve)
-                watchdog.touch(
-                    f"serve engine={self.engine_id} dispatch=prefill")
-            t0 = time.perf_counter()
-            # `capacity` is what the program computes whatever `slots` is
-            with self._span("serve.prefill.dispatch", slots=len(pslots),
-                            tokens=n_prefilled, capacity=self.num_slots * c,
-                            ids=join_ids(req_ids)):
-                self._k, self._v, toks_d = self._prefill_jit(
-                    self.params, self._k, self._v, *feed, self.base_key,
-                    self.cos, self.sin, cfg=self.cfg,
-                    temperature=self.temperature, top_k=self.top_k)
-            toks = None
-            if finals:
-                # the host needs a token only when a prompt ends in the
-                # chunk; otherwise the dispatch is left in flight
-                with self._span("serve.prefill.wait", finals=len(finals)):
-                    toks = np.asarray(toks_d)
-            dt = time.perf_counter() - t0
-            dt -= min(self._drain_compile(), dt)
-            # `waited`: whether `secs` is the device's time for the chunk
-            # or only the enqueue
-            self.telemetry.emit("phase", phase="prefill",
-                                category="prefill", secs=dt,
-                                tokens=n_prefilled, ids=req_ids,
-                                waited=bool(finals))
-            for s in pslots:
-                self.sched.note_prefilled(s, int(nval[s]))
-            self.stats["prefill_chunks"] += len(pslots)
-            self.stats["prefill_tokens"] += n_prefilled
-            for s in finals:
-                st = self.sched.slots[s]
-                st.generated.append(int(toks[s]))
-                self.stats["output_tokens"] += 1
-                if st.t_first_token is None:
-                    st.t_first_token = now + dt
-                    ttft = max(st.t_first_token - st.req.arrival, 0.0)
-                    reg.histogram("serve/ttft").observe(ttft)
-                if self.sched.should_retire(s, self.eos_token_id):
-                    st = self.sched.retire(s)
-                    self._sync_table(s)
-                    self._emit_retired(st, now + dt)
-            worked = True
+        worked = self._prefill_tick(now, reg)
 
         # ---- one decode step over every slot with a live sequence
         decode_ran = self._decode_tick(now, reg)
@@ -584,6 +611,68 @@ class ServeEngine:
         self.telemetry.record_wait("serve.queue_wait", wait, tid=TID_SERVE,
                                    id=st.req.id)
         reg.histogram("serve/queue_wait").observe(wait)
+
+    def _prefill_tick(self, now: float, reg) -> bool:
+        """One prefill dispatch: the next chunk of every mid-prefill
+        slot, compacted to a rung of rows (`_prefill_feed`). Works
+        through `_prefill_pool` / `_run_prefill` / `_retire_prefilled`
+        and the scheduler's prefill interface, so the disaggregated
+        engine runs it verbatim against its prefill pool. Returns
+        whether a dispatch ran."""
+        pslots = self.sched.prefill_slots()
+        if not pslots:
+            return False
+        states = self._prefill_pool()[0]
+        with self._span("serve.prefill.build"):
+            feed, nval, finals = self._prefill_feed(pslots)
+        n_prefilled = int(nval.sum())
+        req_ids = [states[s].req.id for s in pslots]
+        self._drain_compile()
+        if watchdog.active():
+            # a hang inside this dispatch is reported as THIS dispatch,
+            # not a bare stack dump (satellite of the fleet's serve_hang
+            # detection; also arms bench --serve)
+            watchdog.touch(
+                f"serve engine={self.engine_id} dispatch=prefill")
+        t0 = time.perf_counter()
+        # `capacity` is what the program computes: the rung's `rows`, of
+        # which `slots` carry a request
+        with self._span("serve.prefill.dispatch", slots=len(pslots),
+                        rows=len(nval), tokens=n_prefilled,
+                        capacity=len(nval) * self.scfg.prefill_chunk,
+                        ids=join_ids(req_ids)):
+            toks_d = self._run_prefill(feed)
+        toks = None
+        if finals:
+            # the host needs a token only when a prompt ends in the
+            # chunk; otherwise the dispatch is left in flight
+            with self._span("serve.prefill.wait", finals=len(finals)):
+                toks = np.asarray(toks_d)
+        dt = time.perf_counter() - t0
+        csecs = self._drain_compile()
+        # the constructor held every rung: a compile here is a shape or
+        # a sharding the feed should not have produced
+        self.stats["prefill_compiles"] += bool(csecs)
+        dt -= min(csecs, dt)
+        # `waited`: whether `secs` is the device's time for the chunk or
+        # only the enqueue
+        self.telemetry.emit("phase", phase="prefill", category="prefill",
+                            secs=dt, tokens=n_prefilled, ids=req_ids,
+                            waited=bool(finals), **self._PREFILL_PHASE)
+        for row, s in enumerate(pslots):
+            self.sched.note_prefilled(s, int(nval[row]))
+        self.stats["prefill_chunks"] += len(pslots)
+        self.stats["prefill_tokens"] += n_prefilled
+        for row in finals:
+            st = states[pslots[row]]
+            st.generated.append(int(toks[row]))
+            self.stats["output_tokens"] += 1
+            if st.t_first_token is None:
+                st.t_first_token = now + dt
+                ttft = max(st.t_first_token - st.req.arrival, 0.0)
+                reg.histogram("serve/ttft").observe(ttft)
+            self._retire_prefilled(pslots[row], now + dt)
+        return True
 
     def _decode_tick(self, now: float, reg) -> bool:
         """One decode dispatch over every decode-ready slot. Operates
@@ -815,6 +904,7 @@ class ServeEngine:
                 self.pool.peak_in_use / self.num_blocks, 4),
             "decode_steps": self.stats["decode_steps"],
             "decode_compiles": self.stats["decode_compiles"],
+            "prefill_compiles": self.stats["prefill_compiles"],
             "prefill_chunks": self.stats["prefill_chunks"],
             "decode_stall_ticks_max":
                 self.stats["decode_stall_ticks_max"],

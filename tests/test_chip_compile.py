@@ -35,7 +35,7 @@ from picotron_tpu.config import config_from_dict
 from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.models.llama import init_params, model_rope_tables
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
-from picotron_tpu.serve.engine import _get_jits
+from picotron_tpu.serve.engine import _get_jits, prefill_rungs
 from picotron_tpu.serve.paged_cache import init_paged_cache
 from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import SCOPES
@@ -172,11 +172,15 @@ def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):
     assert total < 15.75 * 2**30, total / 2**30
 
 
-def compiled_serve(topo, program: str):
+CHAT_SLOTS = load("configs", "qwen2-1.5b")["serve"]["decode_slots"]
+
+
+def compiled_serve(topo, program: str, rows=None):
     """(`compiled.as_text()`, the pool's shape, the pools' parameter numbers)
-    of the chat cell's `serve_prefill` or `serve_decode` at the cell's widths,
-    depth and serve settings on one described chip, pools donated: the
-    program `ServeEngine` dispatches there."""
+    of the chat cell's `serve_prefill` (at `rows` rows of the compacted
+    batch) or `serve_decode` at the cell's widths, depth and serve settings
+    on one described chip, pools donated: a program `ServeEngine` dispatches
+    there."""
     c = load("configs", "qwen2-1.5b")
     cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
     m, sc = cfg.model, cfg.serve
@@ -202,9 +206,9 @@ def compiled_serve(topo, program: str):
     decode, prefill = _get_jits(True)
     if program == "serve_prefill":
         low = prefill.lower(
-            params, cache.k, cache.v, i32(slots, max_blocks),
-            i32(slots, sc.prefill_chunk), i32(slots), i32(slots), i32(slots),
-            i32(slots), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
+            params, cache.k, cache.v, i32(rows, max_blocks),
+            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
+            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
     else:
         low = decode.lower(
             params, cache.k, cache.v, i32(slots, max_blocks), i32(slots),
@@ -257,9 +261,13 @@ def whole_pool_copies(text: str, pool_shape) -> list:
     return found
 
 
-@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
-def test_serving_program_moves_no_whole_pool(topo, program):
-    text, pool_shape, pools = compiled_serve(topo, program)
+# every shape the chat cell's engine dispatches: the prefill program at each
+# rung of its ladder of row counts (1, 4, 16, 32), and the decode program
+@pytest.mark.parametrize("program,rows", [
+    *(("serve_prefill", r) for r in prefill_rungs(CHAT_SLOTS)),
+    ("serve_decode", None)])
+def test_serving_program_moves_no_whole_pool(topo, program, rows):
+    text, pool_shape, pools = compiled_serve(topo, program, rows)
     # PR 25's accepted metrics find the programs and their scopes by name
     assert text.startswith(f"HloModule jit_{program}")
     found = set().union(*(words(op) for _, op, _ in instructions(text)))

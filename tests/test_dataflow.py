@@ -261,9 +261,12 @@ def test_serve_programs_prove_and_flag_uncommitted_params():
     from picotron_tpu.config import ModelConfig, resolve_preset
 
     mc = ModelConfig(**resolve_preset("debug-tiny"))
+    # one decode signature and one prefill signature per rung of the
+    # compacted batch's ladder (8 slots by default: 1, 4, 8 rows)
     rep = prove_serve_programs(mc)
     assert rep.ok() and rep.info["variants"]["proven"]
-    assert rep.info["variants"]["signatures"] == 1
+    assert rep.info["variants"]["prefill_rows"] == [1, 4, 8]
+    assert rep.info["variants"]["signatures"] == 1 + 3
 
     uncommitted = {"embedding": jnp.zeros((8, 4))}
     rep = prove_serve_programs(mc, params=uncommitted)
@@ -291,6 +294,10 @@ def test_engine_feed_check_proves_live_engine():
         assert rep.ok(), rep.render(verbose=True)
         info = rep.info["variants"]
         assert info["proven"] and info["uncommitted"] == []
+        # one decode signature, one prefill signature per rung (2 slots)
+        assert info["prefill_rows"] == list(eng.prefill_rungs) == [1, 2]
+        assert info["signatures"] == 1 + 2
+        assert eng.stats["prefill_compiles"] <= 2  # held by the constructor
         assert eng.variant_report is not None
         assert eng.variant_report.info["variants"]["proven"]
     finally:

@@ -409,6 +409,118 @@ def test_single_decode_compile_across_multi_request_trace(tiny, requests5,
         assert r["tokens"] == ref
 
 
+def prefill_dispatches(tel):
+    """The `serve.prefill.dispatch` spans' counts, in order."""
+    return [e["args"] for e in tel.tracer.to_json()["traceEvents"]
+            if e["ph"] == "X" and e["name"] == "serve.prefill.dispatch"]
+
+
+def traced_engine(tiny, **kw):
+    from picotron_tpu.telemetry import Telemetry
+    from picotron_tpu.telemetry.flightdeck import SpanTracer
+
+    cfg, params = tiny
+    tel = Telemetry(sinks=[])
+    tel.tracer = SpanTracer()
+    return ServeEngine(params, cfg, scfg(**kw), telemetry=tel), tel
+
+
+def test_prefill_rungs_are_a_function_of_the_slot_count():
+    from picotron_tpu.serve.engine import prefill_rungs
+
+    assert prefill_rungs(32) == (1, 4, 16, 32)  # the chat cell's ladder
+    assert [prefill_rungs(n) for n in (1, 2, 4, 5, 16, 17, 64)] == [
+        (1,), (1, 2), (1, 4), (1, 4, 5), (1, 4, 16), (1, 4, 16, 17),
+        (1, 4, 16, 64)]
+
+
+@pytest.mark.parametrize("n", [1, 2, 4, 5, 6])
+def test_compacted_prefill_parity_and_rung(tiny, requests5, offline_refs, n):
+    """Exactly n prompts prefill at once (n requests at t = 0, 6 slots):
+    the first dispatch takes the smallest rung of (1, 4, 6) that holds
+    them, every later one the smallest that holds what is still
+    mid-prefill, and the tokens are the offline sampler's whatever rung
+    a chunk rode."""
+    reqs = (requests5 + requests5[:1])[:n]
+    refs = (offline_refs + offline_refs[:1])[:n]
+    eng, tel = traced_engine(tiny, decode_slots=6)
+    assert eng.prefill_rungs == (1, 4, 6)
+    res = eng.run(reqs)
+    for r, ref in zip(res, refs):
+        assert r["tokens"] == ref
+    disp = prefill_dispatches(tel)
+    want = {1: 1, 2: 4, 4: 4, 5: 6, 6: 6}
+    assert disp[0]["slots"] == n and disp[0]["rows"] == want[n]
+    assert max(d["slots"] for d in disp) == n
+    for d in disp:
+        assert d["rows"] == min(r for r in eng.prefill_rungs
+                                if r >= d["slots"])
+        assert d["capacity"] == d["rows"] * eng.scfg.prefill_chunk
+    assert eng.pool.in_use == 0
+    eng.close()
+    tel.close()
+
+
+def test_prefill_compiles_once_per_rung_inside_the_constructor(
+        tiny, requests5, offline_refs):
+    """Every shape the engine can dispatch is held before the constructor
+    returns: one prefill compile a rung there, none in a trace whose
+    concurrency falls from five prompts to one and so rides every rung.
+    The pool of 25 blocks is unique to this test (the jit cache is shared,
+    and a rung below the top one has the same shapes at any slot count)."""
+    eng, tel = traced_engine(tiny, decode_slots=7, num_blocks=25)
+    assert eng.prefill_rungs == (1, 4, 7)
+    assert eng.stats["prefill_compiles"] == 3
+    for i, (p, n) in enumerate(requests5):
+        eng.submit(p, n, req_id=i)
+    while eng.sched.has_work():
+        eng.step(0.0)
+    eng.submit(*requests5[0], req_id=5)  # alone: the one-row rung
+    while eng.sched.has_work():
+        eng.step(1.0)
+    assert {d["rows"] for d in prefill_dispatches(tel)} == {1, 4, 7}
+    assert eng.stats["prefill_compiles"] == 3
+    assert eng.stats["decode_compiles"] == 1
+    res = sorted(eng.results, key=lambda r: r["id"])
+    for r, ref in zip(res, offline_refs + offline_refs[:1]):
+        assert r["tokens"] == ref
+    eng.close()
+    tel.close()
+
+
+def test_pad_rows_leak_nothing(tiny, requests5):
+    """A pad row of the compacted batch has no token and an all-unmapped
+    table row, so a dispatch of pad rows alone (the constructor's
+    warm-up) leaves both pools as they were, and a trace ends with no
+    block in use."""
+    cfg, params = tiny
+    eng = ServeEngine(params, cfg, scfg(decode_slots=6))
+    for i in (1, 3, 4):  # 9, 7 and 11 tokens: 2 or 3 chunks
+        eng.submit(*requests5[i])
+    eng.step(0.0)
+    pslots = eng.sched.prefill_slots()
+    assert len(pslots) == 3
+    feed, nval, finals = eng._prefill_feed(pslots)
+    trows = np.asarray(feed[0])
+    assert trows.shape == (4, eng.max_blocks) and finals == [1]
+    assert (trows[:3] == eng._tables[pslots]).all()
+    assert (trows[:3, 0] < eng.num_blocks).all()
+    assert (trows[3:] == eng.num_blocks).all()  # unmapped: writes drop
+    assert list(nval) == [4, 3, 4, 0]
+    k0, v0 = np.asarray(eng._k), np.asarray(eng._v)
+    assert k0.any()  # the first chunks are in the pool
+    for r in eng.prefill_rungs:
+        eng._run_prefill(eng._prefill_feed([], rows=r)[0])
+    assert (np.asarray(eng._k) == k0).all()
+    assert (np.asarray(eng._v) == v0).all()
+    while eng.sched.has_work():
+        eng.step(0.0)
+    assert eng.pool.in_use == 0
+    assert eng.pool.free_blocks == eng.pool.num_blocks
+    assert (eng._tables == eng.num_blocks).all()
+    eng.close()
+
+
 # ---------------------------------------------------------------------------
 # full loop smoke + telemetry report
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import pytest
 from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
 from picotron_tpu.models.llama import init_params
 from picotron_tpu.serve import DisaggServeEngine, ServeEngine
+from picotron_tpu.serve.engine import prefill_rungs
 from picotron_tpu.telemetry import PhaseTimer, Telemetry, bus
 from picotron_tpu.telemetry.flightdeck import (
     SpanTracer, TID_SENTINEL, TID_SERVE, TID_TRAIN,
@@ -143,9 +144,12 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
         assert hi <= lo
 
     # counts, at the boundary of the work they count
+    # (the prefill batch is compacted: `capacity` is the rung's rows, not SLOTS)
     disp = [a[3] for a in anns if a[0] == "serve.prefill.dispatch"]
-    assert disp and all(0 < d["tokens"] <= d["capacity"] == SLOTS * CHUNK
-                        and 1 <= d["slots"] <= SLOTS for d in disp)
+    assert disp and all(0 < d["tokens"] <= d["capacity"] == d["rows"] * CHUNK
+                        and 1 <= d["slots"] <= d["rows"]
+                        and d["rows"] in prefill_rungs(SLOTS) for d in disp)
+    assert [d["rows"] for d in disp] == ([2, 2] if ends_in_chunk else [1, 1])
     assert sum(d["tokens"] for d in disp) == (12 if ends_in_chunk else 8)
     adm = [a[3] for a in anns if a[0] == "serve.admit"]
     assert sum(d["admitted"] for d in adm) == len(prompts)
