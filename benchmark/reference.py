@@ -91,3 +91,32 @@ def logits_at(params, ids, rows, m: dict):
     """Logits [len(rows), V] at the given positions of `ids` [S]."""
     with jax.default_matmul_precision("highest"):
         return hidden_states(params, ids, m)[rows] @ _head(params)
+
+
+MATRICES = ("q", "k", "v", "o", "gate", "up", "down")
+
+
+def rounded_to(params, bits: int):
+    """The control of a served cell's `correct`: the same tree with every
+    matrix rounded to `bits`-bit integers and back, one scale an output
+    channel (symmetric, largest magnitude / (2^(bits-1) - 1)). 8 bits is the
+    nearest precision below the bfloat16 the configurations state. Norm
+    weights and biases stay as they are."""
+    top = 2.0 ** (bits - 1) - 1
+
+    def rnd(w, axis):
+        w32 = w.astype(F32)
+        scale = jnp.max(jnp.abs(w32), axis=axis, keepdims=True) / top
+        return (jnp.round(w32 / jnp.where(scale > 0, scale, 1.0)) * scale).astype(w.dtype)
+
+    out = dict(params, layers=dict(params["layers"]))
+    for k in MATRICES:  # [L, in, out]: a scale a layer and output column
+        out["layers"][k] = rnd(params["layers"][k], -2)
+    # [V, h]: a scale a token, which is the tied head's output channel
+    out["embedding"] = rnd(params["embedding"], -1)
+    if params.get("lm_head") is not None:  # [h, V]
+        out["lm_head"] = rnd(params["lm_head"], -2)
+    return out
+
+
+CONTROLS = {"int8": 8, "int4": 4}  # a cell file's `control` -> bits

@@ -181,7 +181,7 @@ def main(argv=None) -> int:
     ctx = Ctx(cell=cell, workload=workload, config=config, seed=args.seed,
               seconds=args.seconds, trace=bool(args.trace), trace_dir=trace_dir,
               devices=chip["devices"], peak=chip["peak"], meter=meter,
-              chips=cell["chips"], here=HERE, load_module=load_module)
+              chips=cell["chips"], here=HERE, out_dir=OUT_DIR, load_module=load_module)
     ctx.log(f"cell={cell['name']} device_kind={chip['devices'][0].device_kind} "
             f"chips={cell['chips']} jax={jax.__version__} cache={cache_dir}")
 
@@ -231,9 +231,17 @@ def main(argv=None) -> int:
     for note in facts.get("notes", []):
         ctx.log(note)
     correct = bool(facts["correct"]) and facts["compiles_in_window"] == 0
+    # `facts`: the runner's scalar facts, for the tools; the driver reads the other keys
+    scalars = {k: v for k, v in facts.items()
+               if isinstance(v, (int, float)) and not isinstance(v, bool)}
     print(json.dumps(dict(correct=correct, attempted=int(facts["attempted"]),
                           failed=int(facts["failed"]), metrics=metrics,
-                          device=device, **line)), flush=True)
+                          device=device, facts=scalars, **line)), flush=True)
+    # what `correct` compared, beside its limits, as the last lines of standard error too
+    for note in facts.get("notes", []):
+        print("[benchmark]", note, file=sys.stderr)
+    print(f"[benchmark] compiles_in_window={facts['compiles_in_window']} (limit 0) "
+          f"correct={correct}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -10,6 +10,7 @@ import importlib.util
 import io
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -19,6 +20,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4")
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -31,7 +33,7 @@ TRAIN = {"seq_length": 128, "micro_batch_size": 1, "gradient_accumulation_steps"
          "learning_rate": 3e-3, "adam_moments_dtype": "bfloat16", "remat": True,
          "remat_policy": "dots_attn", "grad_engine": "auto"}
 CONFIGS = {
-    "tiny-qwen": {"distributed": {}, "training": TRAIN,
+    "tiny-qwen": {"distributed": {}, "training": TRAIN, "initializer_range": 0.02,
                   "model": {"name": "debug-tiny-qwen", **TINY, "attention_bias": True,
                             "tie_word_embeddings": True, "dtype": "float32"},
                   "serve": {"decode_slots": 4, "block_size": 16, "prefill_chunk": 32,
@@ -44,7 +46,7 @@ TRAFFIC = {"generator": "chat_lognormal", "rate_per_s": 10.0, "shape_seed": 1,
            "prompt_tokens": {"median": 40, "sigma": 0.8, "min": 8, "max": 150},
            "output_tokens": {"median": 12, "sigma": 0.7, "min": 4, "max": 40}}
 TRAIN_E2E = {"train_tok_s_chip": "tokens_per_s_per_chip", "setup_s": "setup_s"}
-SERVE_E2E = {"ttft_p90_ms": "ttft_ms_p90", "setup_s": "setup_s"}
+SERVE_E2E = {"latency_per_token_p90_ms": "latency_per_token_ms_p90", "setup_s": "setup_s"}
 CELLS = {
     "tiny-qwen.train": dict(config="tiny-qwen", chips=1, runner="train_step", end_to_end=TRAIN_E2E),
     "tiny-tp2pp2.train": dict(config="tiny-tp2pp2", chips=4, runner="train_step",
@@ -52,6 +54,8 @@ CELLS = {
     "tiny-qwen.chat": dict(config="tiny-qwen", chips=1, runner="serve_open_loop",
                            end_to_end=SERVE_E2E, traffic=TRAFFIC, drain_limit_s=30),
 }
+# not in the parametrised lists: the chat cell with the control of `correct` switched on
+CONTROL = {"tiny-qwen.chat-control": dict(CELLS["tiny-qwen.chat"], control="int4")}
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +72,7 @@ def tree(tmp_path_factory):
         with open(root / "benchmark" / "configs" / f"{name}.json", "w") as f:
             json.dump(c, f)
         bench["configs"].append(dict(name=name, file=f"benchmark/configs/{name}.json"))
-    for name, w in CELLS.items():
+    for name, w in {**CELLS, **CONTROL}.items():
         with open(root / "benchmark" / "workloads" / f"{name}.json", "w") as f:
             json.dump(dict(name=name, **w), f)
         bench["workloads"].append(dict(name=name, config=w["config"], chips=w["chips"]))
@@ -80,7 +84,7 @@ def tree(tmp_path_factory):
         if "workloads" in m:  # the real cells' lists -> the tiny cells of the same runners
             runners = {runner_of[cell] for cell in m["workloads"]}
             end_to_end = m in bench["end_to_end"]
-            m["workloads"] = [n for n, w in CELLS.items() if w["runner"] in runners
+            m["workloads"] = [n for n, w in {**CELLS, **CONTROL}.items() if w["runner"] in runners
                               and (not end_to_end or m["name"] in w["end_to_end"])]
     with open(root / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
@@ -108,7 +112,7 @@ def cpu_device_ops(planes):
     return {0: ev}
 
 
-def run_cell(tree, cell, trace, monkeypatch, seed=2**31 + 5, seconds=1.5):
+def run_cell(tree, cell, trace, monkeypatch, seed=2**31 + 5, seconds=1.5, correct=True):
     mod = load_run(tree)
     monkeypatch.syspath_prepend(str(tree / "benchmark"))
     import trace_reduce
@@ -120,7 +124,7 @@ def run_cell(tree, cell, trace, monkeypatch, seed=2**31 + 5, seconds=1.5):
     assert rc == 0, out.getvalue()
     line = json.loads(out.getvalue().strip().splitlines()[-1])
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
-    assert line["correct"] is True, out.getvalue()
+    assert line["correct"] is correct, out.getvalue()
     assert line["failed"] == 0 and line["attempted"] > 0
     return line, out.getvalue()
 
@@ -149,6 +153,59 @@ def test_per_layer_metrics_and_breakdown(tree, cell, monkeypatch):
     else:
         assert {"decode_dispatch_ms.serve", "slot_occupancy.serve", "kv_pool_fill.serve",
                 "device_idle.serve"} <= set(line["metrics"])
+
+
+def test_chat_cell_writes_one_record_a_request(tree, monkeypatch):
+    line, text = run_cell(tree, "tiny-qwen.chat", 0, monkeypatch, seed=7)
+    with open(tree / ".bench_out" / "tiny-qwen.chat.requests.jsonl") as f:
+        rows = [json.loads(ln) for ln in f]
+    assert len(rows) == line["attempted"] and len({r["id"] for r in rows}) == len(rows)
+    for r in rows:
+        assert r["due_s"] <= r["submitted_s"] <= r["first_token_seen_s"] <= r["done_s"]
+        assert r["chunks"] == -(-r["prompt_tokens"] // 32) and r["output_tokens"] > 0
+    per_token = [(r["done_s"] - r["due_s"]) / r["output_tokens"] * 1e3 for r in rows]
+    ttft = [(r["first_token_seen_s"] - r["due_s"]) * 1e3 for r in rows]
+    assert line["metrics"]["latency_per_token_p90_ms"]["value"] == pytest.approx(
+        np.percentile(per_token, 90), rel=1e-9)
+    for key, label, values in (("latency_per_token_ms_p90", "latency a token", per_token),
+                               ("ttft_ms_p90", "ttft", ttft)):
+        p90 = line["facts"][key]
+        assert p90 == pytest.approx(np.percentile(values, 90), rel=1e-9)
+        starred = [float(x.split()[1]) for x in text.split(label + " p90 = rank")[1].splitlines()[0]
+                   .split(": ", 1)[1].split("; ") if x.startswith("*")]
+        assert min(starred) <= p90 <= max(starred)  # the printed neighbours bracket it
+    in_step, loop = (float(x) for x in re.search(
+        r"; ([\d.]+) s inside engine.step of ([\d.]+) s of loop", text).groups())
+    assert 0 < in_step <= loop
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(tree, monkeypatch):
+    """The rest of a run with the timed path broken underneath: the engine's
+    sampler puts the least likely token first."""
+    import jax
+    import jax.numpy as jnp
+
+    from picotron_tpu.serve import engine
+
+    monkeypatch.setattr(engine, "_sample_slots",
+                        lambda logits, *a, **k: jnp.argmin(logits, axis=-1).astype(jnp.int32))
+    jax.clear_caches()  # an earlier test's traced programs hold the sound sampler
+    try:
+        _, text = run_cell(tree, "tiny-qwen.chat", 0, monkeypatch, correct=False)
+    finally:
+        jax.clear_caches()
+    assert "correct=False" not in text  # that line goes to standard error
+    gap = float(text.split("worst gap to the top logit ")[1].split()[0])
+    assert gap > 1.0, text
+
+
+def test_the_control_is_not_correct(tree, monkeypatch):
+    """The reference with int8 weights in the program's place (the nearest
+    precision below bfloat16) is outside the tie band; the program is inside."""
+    _, text = run_cell(tree, "tiny-qwen.chat-control", 0, monkeypatch, correct=False)
+    program = float(text.split("worst gap to the top logit ")[1].split()[0])
+    control = float(text.split("in the program's place: worst gap to the top logit ")[1].split()[0])
+    assert program <= 1.0 < control, text
 
 
 def test_no_tpu_exits_nonzero_with_one_line():
