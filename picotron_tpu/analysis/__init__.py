@@ -9,19 +9,12 @@ them; `preflight` is train.py's fail-fast subset; tools/shardcheck.py is
 the CLI.
 """
 
-from picotron_tpu.analysis.boundary import (  # noqa: F401
-    ClassifiedOp, SliceTopology, audit_boundary, classify_ops,
-)
 from picotron_tpu.analysis.collectives import (  # noqa: F401
     CollectiveOp, audit_collectives, parse_collectives,
 )
 from picotron_tpu.analysis.cost_model import (  # noqa: F401
     Calibration, CostModel, GENERATIONS, StepCost, resolve_generation,
     spearman,
-)
-from picotron_tpu.analysis.dataflow import (  # noqa: F401
-    BoundaryReshard, CollectiveSite, attribute_collectives, audit_dataflow,
-    collect_sites, predict_boundary_reshards,
 )
 from picotron_tpu.analysis.hazards import (  # noqa: F401
     check_donation, check_state_stability, parse_arg_donation,

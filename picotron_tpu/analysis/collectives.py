@@ -52,10 +52,8 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8": 1,
                 "ui16": 2, "i8": 1, "ui8": 1, "i1": 1}
 
 # stablehlo: replica_groups = dense<[[0, 1], [2, 3]]> : tensor<GxSxi64>
-# (the member payload is kept so the slice-boundary auditor can map each
-# participant id to its slice — analysis/boundary.py)
 _RE_GROUPS = re.compile(
-    r"replica_groups = dense<([^>]*)> : tensor<(\d+)x(\d+)xi64>")
+    r"replica_groups = dense<[^>]*> : tensor<(\d+)x(\d+)xi64>")
 # stablehlo: source_target_pairs = dense<...> : tensor<Nx2xi64>
 _RE_PAIRS = re.compile(
     r"source_target_pairs = dense<([^>]*)> : tensor<(\d+)x2xi64>")
@@ -65,7 +63,7 @@ _RE_RESULT = re.compile(r"-> tensor<([0-9x]*)x?([a-z]+[0-9]+|i1)>")
 _RE_HLO_GROUPS = re.compile(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
 # compiled-HLO iota form: replica_groups=[2,4]<=[8] -> 2 groups of 4
 _RE_HLO_IOTA = re.compile(
-    r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
+    r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _RE_HLO_PAIRS = re.compile(r"source_target_pairs=\{([^}]*)\}")
 _RE_HLO_SHAPE = re.compile(r"=\s*([a-z]+[0-9]+|pred)\[([0-9,]*)\]")
 
@@ -95,11 +93,6 @@ class CollectiveOp:
     shape: Optional[tuple]
     dtype: Optional[str]
     line: int                       # 1-based line in the module text
-    # replica-group membership, when the dialect spells it out: one tuple
-    # of participant ids per group (for collective_permute, one (src, tgt)
-    # tuple per hop). None when only the G x S shape was recoverable —
-    # consumers (analysis/boundary.py) must treat None as unattributable.
-    members: Optional[tuple] = None
 
     @property
     def effective(self) -> bool:
@@ -107,57 +100,6 @@ class CollectiveOp:
         if self.kind == "collective_permute":
             return (self.n_groups or 0) > 0
         return (self.group_size or 0) > 1
-
-
-def _dense_members(payload: str, n_groups: int, group_size: int):
-    """Member tuples from a StableHLO dense<...> payload, or None.
-
-    Handles the explicit `[[0, 1], [2, 3]]` form and the splat form
-    (`dense<0>` for a 1x1 tensor). A payload whose integer count does not
-    match G x S (elided printing) yields None.
-    """
-    ids = [int(t) for t in re.findall(r"-?\d+", payload)]
-    if len(ids) == 1 and n_groups * group_size > 1:
-        ids = ids * (n_groups * group_size)  # splat
-    if len(ids) != n_groups * group_size:
-        return None
-    return tuple(tuple(ids[g * group_size:(g + 1) * group_size])
-                 for g in range(n_groups))
-
-
-def _iota_members(n_groups: int, group_size: int, dims_txt: str,
-                  perm_txt: Optional[str]):
-    """Member tuples from the compiled-HLO iota form
-    `replica_groups=[G,S]<=[d0,d1,...]` (optionally `T(p0,p1,...)`)."""
-    dims = [int(d) for d in dims_txt.split(",") if d]
-    n = math.prod(dims)
-    if n != n_groups * group_size:
-        return None
-    ids = list(range(n))
-    if perm_txt is not None:
-        perm = [int(p) for p in perm_txt.split(",") if p]
-        if sorted(perm) != list(range(len(dims))):
-            return None
-        # reshape iota to `dims`, transpose by `perm`, flatten (row-major)
-        strides = [0] * len(dims)
-        acc = 1
-        for ax in reversed(range(len(dims))):
-            strides[ax] = acc
-            acc *= dims[ax]
-        tdims = [dims[p] for p in perm]
-        tstrides = [strides[p] for p in perm]
-        out = []
-        idx = [0] * len(tdims)
-        for _ in range(n):
-            out.append(sum(i * s for i, s in zip(idx, tstrides)))
-            for ax in reversed(range(len(tdims))):
-                idx[ax] += 1
-                if idx[ax] < tdims[ax]:
-                    break
-                idx[ax] = 0
-        ids = out
-    return tuple(tuple(ids[g * group_size:(g + 1) * group_size])
-                 for g in range(n_groups))
 
 
 def _result_bytes(line: str):
@@ -194,48 +136,25 @@ def parse_collectives(text: str) -> list[CollectiveOp]:
                 break
         if kind is None:
             continue
-        group_size = n_groups = members = None
+        group_size = n_groups = None
         if kind == "collective_permute":
             m = _RE_PAIRS.search(line)
             if m:
                 n_groups = int(m.group(2))
-                members = _dense_members(m.group(1), n_groups, 2)
             else:
                 m = _RE_HLO_PAIRS.search(line)
                 if m:
-                    pairs = [p.strip("{}") for p in
-                             m.group(1).split("},{") if p]
-                    n_groups = len(pairs)
-                    try:
-                        members = tuple(
-                            tuple(int(x) for x in p.split(","))
-                            for p in pairs)
-                    except ValueError:
-                        members = None
+                    n_groups = len([p for p in m.group(1).split("},{") if p])
         else:
-            m = _RE_GROUPS.search(line)
+            m = _RE_GROUPS.search(line) or _RE_HLO_IOTA.search(line)
             if m:
-                n_groups, group_size = int(m.group(2)), int(m.group(3))
-                members = _dense_members(m.group(1), n_groups, group_size)
+                n_groups, group_size = int(m.group(1)), int(m.group(2))
             else:
-                m = _RE_HLO_IOTA.search(line)
+                m = _RE_HLO_GROUPS.search(line)
                 if m:
-                    n_groups, group_size = int(m.group(1)), int(m.group(2))
-                    members = _iota_members(n_groups, group_size,
-                                            m.group(3), m.group(4))
-                else:
-                    m = _RE_HLO_GROUPS.search(line)
-                    if m:
-                        groups = [g.strip("{}") for g in
-                                  m.group(1).split("},{")]
-                        n_groups = len(groups)
-                        group_size = len(groups[0].split(","))
-                        try:
-                            members = tuple(
-                                tuple(int(x) for x in g.split(","))
-                                for g in groups)
-                        except ValueError:
-                            members = None
+                    groups = m.group(1).split("},{")
+                    n_groups = len(groups)
+                    group_size = len(groups[0].split(","))
         # result type: same line for region-free ops, else the region's
         # closing `}) : (...) -> type` a few lines down
         nbytes = dims = dtype = None
@@ -254,7 +173,7 @@ def parse_collectives(text: str) -> list[CollectiveOp]:
                 dims = tuple(int(d) for d in m.group(2).split(",") if d)
                 nbytes = math.prod(dims) * _DTYPE_BYTES.get(dtype, 4)
         ops.append(CollectiveOp(kind, group_size, n_groups, nbytes, dims,
-                                dtype, i + 1, members))
+                                dtype, i + 1))
     return ops
 
 
@@ -362,84 +281,6 @@ def audit_collectives(cfg, *, text: str = None, state=None,
                     f"SP f/g pair present over tp ({len(sp_ag)} "
                     f"all-gather, {len(sp_rs)} reduce-scatter ops of "
                     f"group size {d.tp_size})")
-    # deferred activation sync (parallel/tp_strategies.py): the
-    # row-parallel exit psum is rescheduled as a reduce-scatter at the
-    # block exit whose gather half is hoisted into the NEXT block's entry
-    # — the signature is the same AG/RS pair over tp as Megatron-SP, but
-    # it must be present even WITHOUT sequence_parallel. One op of each
-    # kind per block boundary in the program text (run_layers rolls the
-    # layer loop into a lax.scan, so the per-layer count is structural:
-    # the scan body lowers each boundary collective once).
-    if d.tp_sync == "deferred" and d.tp_size > 1:
-        df_rs = [op for op in eff if op.kind == "reduce_scatter"
-                 and op.group_size == d.tp_size]
-        df_ag = [op for op in eff if op.kind == "all_gather"
-                 and op.group_size == d.tp_size]
-        if not df_rs:
-            rep.add(CHECK, ERROR, "reduce_scatter",
-                    f"tp_sync=deferred with tp_size={d.tp_size} but no "
-                    f"reduce-scatter over tp: the deferred schedule's "
-                    f"block-exit RS is missing — partial row-parallel "
-                    f"outputs are never reduced across tp shards")
-        if not df_ag:
-            rep.add(CHECK, ERROR, "all_gather",
-                    f"tp_sync=deferred with tp_size={d.tp_size} but no "
-                    f"all-gather over tp: the gather half hoisted into "
-                    f"the next block's entry is missing — the seq-sharded "
-                    f"residual stream never re-assembles the full "
-                    f"sequence")
-        if df_rs and df_ag:
-            rep.add(CHECK, INFO, "deferred_pair",
-                    f"deferred-sync RS/AG pair present over tp "
-                    f"({len(df_ag)} all-gather, {len(df_rs)} "
-                    f"reduce-scatter ops of group size {d.tp_size})")
-
-    # non-megatron TP strategies (parallel/tp_strategies.py)
-    if d.tp_size > 1 and d.tp_strategy != "megatron":
-        from picotron_tpu.config import (
-            resolved_tp_mesh, resolved_tp_strategy,
-        )
-
-        strat = resolved_tp_strategy(cfg)
-        if "2d" in strat.values():
-            # the 2d schedule's signature: subgroup collectives — an
-            # activation/weight all-gather whose group spans exactly the
-            # INNER tp_y factor and a partial-sum all_reduce over the
-            # OUTER tp_x factor. (A full-tp all_reduce still legitimately
-            # appears for the vocab-parallel CE merge, so only the
-            # positive subgroup presences are checkable here; the
-            # shardflow provenance rule owns implicit-widening detection.)
-            tp_x, tp_y = resolved_tp_mesh(cfg)
-            if tp_y > 1 and not any(
-                    op.kind == "all_gather" and op.group_size == tp_y
-                    for op in eff):
-                rep.add(CHECK, ERROR, "all_gather",
-                        f"2d tp strategy {tp_x}x{tp_y} but no all-gather "
-                        f"of group size {tp_y}: the inner-subgroup "
-                        f"activation/weight gather is missing")
-            if tp_x > 1 and tp_x != d.tp_size and not any(
-                    op.kind == "all_reduce" and op.group_size == tp_x
-                    for op in eff):
-                rep.add(CHECK, ERROR, "all_reduce",
-                        f"2d tp strategy {tp_x}x{tp_y} but no all-reduce "
-                        f"of group size {tp_x}: the row-matmul partial "
-                        f"sum over the outer subgroup is missing")
-        if "row" in (strat["qkv"], strat["up"]):
-            # row-first entry: a full-tp psum of the projections; its
-            # column-parallel exit re-assembles features via all-gather
-            if not any(op.kind == "all_reduce"
-                       and op.group_size == d.tp_size for op in eff):
-                rep.add(CHECK, ERROR, "all_reduce",
-                        f"row-first tp strategy but no all-reduce of "
-                        f"group size {d.tp_size}: the block-entry "
-                        f"projection psum is missing")
-            if not any(op.kind == "all_gather"
-                       and op.group_size == d.tp_size for op in eff):
-                rep.add(CHECK, ERROR, "all_gather",
-                        f"row-first tp strategy but no all-gather of "
-                        f"group size {d.tp_size}: the column-parallel "
-                        f"exit's feature gather is missing")
-
     if d.cp_size > 1:
         from picotron_tpu.config import resolved_cp_flavor, resolved_cp_mesh
 

@@ -45,8 +45,7 @@ from dataclasses import dataclass, replace
 from typing import Optional
 
 from picotron_tpu.config import (
-    Config, num_params, parse_tp_strategy, resolved_cp_flavor,
-    resolved_cp_mesh, resolved_tp_mesh,
+    Config, num_params, resolved_cp_flavor, resolved_cp_mesh,
 )
 from picotron_tpu.utils import flops_per_token, tpu_generation
 
@@ -72,25 +71,13 @@ class IciGeneration:
     peak_flops: float       # per-chip bf16 peak FLOP/s
     pcie_bandwidth: float   # host<->device streaming bw (offload); see
                             # Calibration — fitted, this is the fallback
-    # -- the dcn tier (multi-slice scale-out) -----------------------------
-    # Per-slice-exit DCN bandwidth per direction. Slices connect through
-    # the data-center network at per-host NIC rates aggregated across the
-    # slice boundary — order 50-100 Gb/s per host vs 360-800 Gb/s per
-    # chip of ICI. Analytic defaults (derated published figures) awaiting
-    # on-TPU multi-slice validation; PERF.md round 16 has the protocol.
-    dcn_bandwidth: float = 6.25e9   # bytes/s across the cut per direction
-    dcn_alpha_s: float = 2.0e-5     # per-transfer DCN latency (vs 1 µs ICI)
 
 
 GENERATIONS: dict[str, IciGeneration] = {
-    "v4": IciGeneration("v4", 3, 45e9, 4, 32.0, 275e12, 7e9,
-                        6.25e9, 2.0e-5),
-    "v5e": IciGeneration("v5e", 2, 45e9, 16, 16.0, 197e12, 7e9,
-                         6.25e9, 2.0e-5),
-    "v5p": IciGeneration("v5p", 3, 90e9, 4, 95.0, 459e12, 7e9,
-                         12.5e9, 2.0e-5),
-    "v6e": IciGeneration("v6e", 2, 100e9, 16, 32.0, 918e12, 7e9,
-                         12.5e9, 2.0e-5),
+    "v4": IciGeneration("v4", 3, 45e9, 4, 32.0, 275e12, 7e9),
+    "v5e": IciGeneration("v5e", 2, 45e9, 16, 16.0, 197e12, 7e9),
+    "v5p": IciGeneration("v5p", 3, 90e9, 4, 95.0, 459e12, 7e9),
+    "v6e": IciGeneration("v6e", 2, 100e9, 16, 32.0, 918e12, 7e9),
 }
 
 
@@ -196,25 +183,6 @@ def split_cp_link(link: AxisLink, cp_x: int, cp_y: int,
     return outer, inner
 
 
-def split_slice_link(link: AxisLink, n_slices: int,
-                     gen: IciGeneration) -> tuple[AxisLink, AxisLink]:
-    """Factor one placed DCN-crossing axis into its hierarchical tiers:
-    (intra-slice ICI sub-link of size n/slices, inter-slice DCN link of
-    size slices). The intra leg keeps the parent's bandwidth/stride and
-    re-derives its wrap rule from the shrunk size; the DCN leg is modeled
-    as a bidirectional ring of slices at the generation's dcn_bandwidth
-    (slice interconnects are switched, so a ring is the conservative
-    shape). Mirrors split_cp_link's role for the mesh cp flavor — the
-    slice-boundary analogue of the TASP follow-the-network split."""
-    m = max(link.size // max(n_slices, 1), 1)
-    intra = AxisLink(link.axis, m,
-                     "ring" if m >= gen.wrap_min else "line",
-                     link.bandwidth, link.stride)
-    dcn = AxisLink(f"{link.axis}@dcn", n_slices, "ring",
-                   gen.dcn_bandwidth, 1)
-    return intra, dcn
-
-
 # ---------------------------------------------------------------------------
 # Calibration constants
 # ---------------------------------------------------------------------------
@@ -252,12 +220,6 @@ class Calibration:
     # ~0.2 ms. Analytic default awaiting --pp-tick-sweep calibration.
     host_dispatch_s: float = 2.0e-4
     expose_layer: float = 1.0   # in-layer tp/sp/cp/ep collectives serialize
-    # deferred tp_sync (parallel/tp_strategies.py): the reduce-scatter at a
-    # block's exit still serializes, but its gather half is hoisted to the
-    # NEXT block's entry where it overlaps that block's norm + qkv/gate
-    # matmul issue window — only this fraction of the all-gather stays
-    # exposed. Analytic default awaiting on-TPU validation (PERF.md r15).
-    expose_deferred: float = 0.55
     # step-FLOPs multiplier per remat policy (recompute overhead), relative
     # to "dots" whose overhead the efficiency fit absorbs
     remat_flops: tuple = (("full", 1.30), ("dots", 1.0),
@@ -373,17 +335,15 @@ class CostModel:
     # -- per-collective ----------------------------------------------------
 
     def collective_secs(self, kind: str, nbytes: float,
-                        link: AxisLink, alpha: float = None) -> float:
+                        link: AxisLink) -> float:
         """Seconds for one collective of `kind` moving `nbytes` (the full
         logical tensor for group collectives; the per-device payload for a
-        ppermute shift) over one placed axis. `alpha` overrides the
-        per-hop latency (the dcn tier's is ~20x the ICI default)."""
+        ppermute shift) over one placed axis."""
         n, bw = link.size, link.bandwidth
         if n <= 1 or nbytes <= 0:
             return 0.0
         dirs = link.directions
-        if alpha is None:
-            alpha = self.calib.alpha_link_s
+        alpha = self.calib.alpha_link_s
         if kind == "all_gather" or kind == "reduce_scatter":
             return nbytes * (n - 1) / n / (dirs * bw) + alpha * (n - 1)
         if kind == "all_reduce":
@@ -404,71 +364,6 @@ class CostModel:
         return place_axes({"dp": d.dp_size, "pp": d.pp_size,
                            "ep": d.ep_size, "cp": d.cp_size,
                            "tp": d.tp_size}, self.gen)
-
-    # -- the dcn tier -----------------------------------------------------
-
-    def dcn_link(self, n_slices: int) -> AxisLink:
-        """The inter-slice DCN 'axis': a ring of slices at the
-        generation's dcn_bandwidth."""
-        return AxisLink("dcn", n_slices, "ring", self.gen.dcn_bandwidth, 1)
-
-    def dcn_secs(self, kind: str, nbytes: float, n_slices: int) -> float:
-        """Seconds for one collective leg crossing the slice cut — same
-        ring formulas as ICI, at the dcn tier's bandwidth and latency."""
-        return self.collective_secs(kind, nbytes, self.dcn_link(n_slices),
-                                    alpha=self.gen.dcn_alpha_s)
-
-    def slice_tiers(self, cfg: Config, n_slices: int, axis: str) -> dict:
-        """Price the predicted step comm under a slice cut on `axis`
-        (one of the DCN-tolerant axes, dp or pp): comm terms spanning the
-        axis are re-priced hierarchically — wide legs on the intra-slice
-        ICI sub-link, a shard-per-slice leg on the dcn tier — and
-        everything else stays on its placed ICI link. Returns the per-tier
-        split the planner renders: which axis should absorb the slice
-        granules falls out of comparing these rows."""
-        cost = self.predict(cfg)
-        links = self.axes_for(cfg)
-        d = cfg.distributed
-        axis_size = {"dp": d.dp_size, "pp": d.pp_size}.get(axis, 1)
-        ici_s = dcn_s = 0.0
-        dcn_bytes = 0.0
-        crossing = []
-        for t in cost.comm:
-            if axis not in t.axes or axis not in links:
-                ici_s += t.secs_total
-                continue
-            crossing.append(t.name)
-            intra, dcn = split_slice_link(links[axis], n_slices, self.gen)
-            other_s = sum(self.collective_secs(t.kind, t.bytes_each,
-                                               links[a])
-                          for a in t.axes if a != axis and a in links)
-            if t.kind == "collective_permute":
-                # the boundary pairs at the cut cross DCN point-to-point;
-                # in-slice pairs keep the ICI price
-                ici_s += t.count * (other_s + self.collective_secs(
-                    t.kind, t.bytes_each, intra))
-                dcn_leg = (t.bytes_each / self.gen.dcn_bandwidth
-                           + self.gen.dcn_alpha_s)
-                dcn_s += t.count * dcn_leg
-                dcn_bytes += t.count * t.bytes_each
-            else:
-                m = max(axis_size // n_slices, 1)
-                ici_s += t.count * (other_s + self.collective_secs(
-                    t.kind, t.bytes_each, intra))
-                shard = t.bytes_each / m
-                dcn_s += t.count * self.dcn_secs(t.kind, shard, n_slices)
-                dcn_bytes += t.count * shard * (
-                    2 if t.kind == "all_reduce" else 1) * (
-                    n_slices - 1) / n_slices
-        return {
-            "axis": axis, "slices": n_slices,
-            "generation": self.gen.name,
-            "crossing_terms": crossing,
-            "dcn_bytes": int(dcn_bytes),
-            "dcn_ms": round(dcn_s * 1e3, 4),
-            "ici_ms": round(ici_s * 1e3, 4),
-            "total_comm_ms": round((ici_s + dcn_s) * 1e3, 4),
-        }
 
     # -- traced-schedule pricing ------------------------------------------
 
@@ -505,21 +400,6 @@ class CostModel:
                            "bytes": nbytes, "axes": axes,
                            "secs": secs, "axis_guess": guess})
         return priced
-
-    def price_reshards(self, cfg: Config, reshards) -> tuple:
-        """(secs, bytes) for predicted boundary reshards
-        (analysis/dataflow.py BoundaryReshard). GSPMD materializes a spec
-        mismatch as an all-gather of the full logical tensor; the static
-        prediction cannot know which axis the partitioner routes it over,
-        so budget the slowest placed axis — the conservative bound the
-        planner should price unintended traffic at."""
-        links = [l for l in self.axes_for(cfg).values() if l.size > 1]
-        worst = min(links, key=lambda l: l.bandwidth, default=None)
-        if worst is None:
-            return 0.0, sum(r.nbytes for r in reshards)
-        secs = sum(self.collective_secs("all_gather", r.nbytes, worst)
-                   for r in reshards)
-        return secs, sum(r.nbytes for r in reshards)
 
     def price_kv_handoff(self, model_cfg, serve_cfg=None, *,
                          n_tokens: Optional[int] = None,
@@ -620,29 +500,6 @@ class CostModel:
                      * (f_dense_tok / eff_d + f_attn_tok / c.eff_attn)
                      / (world * self.gen.peak_flops))
 
-        # Non-megatron TP strategies (parallel/tp_strategies.py). The 2d
-        # row-side matmuls (o/down) contract a tp_y-times larger slab —
-        # weight rows are gathered within the inner subgroup so the
-        # contraction replicates tp_y-fold across it. Fold the extra FLOPs
-        # into compute_s so the bubble and overlap terms see the true
-        # critical path; the comm terms below price the collectives.
-        tp_strat = None
-        tp_x = tp_y = 1
-        if d.tp_size > 1:
-            from picotron_tpu.config import resolved_tp_strategy
-
-            tp_strat = resolved_tp_strategy(cfg, generation=self.gen.name)
-            if "2d" in tp_strat.values():
-                tp_x, tp_y = resolved_tp_mesh(cfg)
-                extra_tok = 0.0
-                if tp_strat["o"] == "2d":
-                    extra_tok += 2.0 * h * h
-                if tp_strat["down"] == "2d":
-                    extra_tok += 2.0 * h * m.intermediate_size
-                compute_s += (tokens * mult * m.num_hidden_layers
-                              * extra_tok * (tp_y - 1)
-                              / (eff_d * world * self.gen.peak_flops))
-
         # Pipeline bubble — executor-dependent (parallel/mpmd.py):
         # - spmd: the lockstep scan runs n + 2(pp-1) ticks and EVERY tick
         #   costs a full traced unit on every device (PERF.md r4: idle
@@ -704,72 +561,20 @@ class CostModel:
             add("zero1_gather", "all_gather", ("dp",), 1,
                 act_bytes * n_grad_local, c.expose_grad)
 
-        # TP: 2 fwd + 2 bwd boundary collectives per layer per microbatch
-        # on the megatron col/row pairing; Megatron-SP replaces each psum
-        # with an all-gather/reduce-scatter pair of the same volume, and
-        # tp_sync=deferred keeps the SP pair but hoists the gather into the
-        # next block's entry (only expose_deferred of it stays exposed).
-        # The row-first pairing moves the psum to the block ENTRY (over the
-        # full projection width — wider than hidden) and exits with a
-        # feature all-gather; the 2d pairing splits tp into tp_x x tp_y
-        # subgroups: an activation + weight-rows all-gather over the inner
-        # tp_y link and a psum shrunk to the outer tp_x link.
-        if d.tp_size > 1 and tp_strat is not None:
-            deferred = d.tp_sync == "deferred"
-            pair_kinds = (("attn", tp_strat["qkv"]), ("mlp", tp_strat["up"]))
-            n_pair = 2 * layers_stage * ga   # fwd + bwd, per pair per micro
-            n_boundary = sum(n_pair for _, k in pair_kinds if k == "col")
-            if n_boundary:
-                if deferred:
-                    add("tp_defer_gather", "all_gather", ("tp",),
-                        n_boundary, v_act, c.expose_deferred)
-                    add("tp_defer_scatter", "reduce_scatter", ("tp",),
-                        n_boundary, v_act, c.expose_layer)
-                elif d.sequence_parallel:
-                    add("sp_gather", "all_gather", ("tp",), n_boundary,
-                        v_act, c.expose_layer)
-                    add("sp_scatter", "reduce_scatter", ("tp",), n_boundary,
-                        v_act, c.expose_layer)
-                else:
-                    add("tp_psum", "all_reduce", ("tp",), n_boundary,
-                        v_act, c.expose_layer)
-            tok_mb = mbs * (s // d.cp_size)
-            p_bytes = _DTYPE_BYTES.get(m.dtype, 2)
-            attn_w = m.num_attention_heads * m.head_dim
-            proj = {"attn": attn_w + 2 * m.num_key_value_heads * m.head_dim,
-                    "mlp": 2 * m.intermediate_size}
-            gath = {"attn": proj["attn"], "mlp": m.intermediate_size}
-            wrows = {"attn": attn_w, "mlp": m.intermediate_size}
-            for pair, kind in pair_kinds:
-                if kind == "row":
-                    add(f"tp_row_psum_{pair}", "all_reduce", ("tp",),
-                        n_pair, tok_mb * proj[pair] * act_bytes,
-                        c.expose_layer)
-                    add(f"tp_row_gather_{pair}", "all_gather", ("tp",),
-                        n_pair, v_act, c.expose_layer)
-                elif kind == "2d" and "tp" in links:
-                    outer, inner = split_cp_link(links["tp"], tp_x, tp_y,
-                                                 self.gen)
-                    if tp_y > 1:
-                        v_g = tok_mb * gath[pair] // tp_x * act_bytes
-                        terms.append(CommTerm(
-                            f"tp2d_gather_{pair}", "all_gather", ("tp",),
-                            n_pair, v_g,
-                            self.collective_secs("all_gather", v_g, inner),
-                            c.expose_layer))
-                        v_w = wrows[pair] * h // tp_x * p_bytes
-                        terms.append(CommTerm(
-                            f"tp2d_wgather_{pair}", "all_gather", ("tp",),
-                            n_pair, v_w,
-                            self.collective_secs("all_gather", v_w, inner),
-                            c.expose_layer))
-                    if tp_x > 1:
-                        terms.append(CommTerm(
-                            f"tp2d_psum_{pair}", "all_reduce", ("tp",),
-                            n_pair, v_act,
-                            self.collective_secs("all_reduce", v_act,
-                                                 outer),
-                            c.expose_layer))
+        # TP: 2 fwd + 2 bwd boundary collectives per layer per microbatch,
+        # for each of the attention and MLP col/row pairs; Megatron-SP
+        # replaces each psum with an all-gather/reduce-scatter pair of the
+        # same volume.
+        if d.tp_size > 1:
+            n_boundary = 2 * 2 * layers_stage * ga
+            if d.sequence_parallel:
+                add("sp_gather", "all_gather", ("tp",), n_boundary,
+                    v_act, c.expose_layer)
+                add("sp_scatter", "reduce_scatter", ("tp",), n_boundary,
+                    v_act, c.expose_layer)
+            else:
+                add("tp_psum", "all_reduce", ("tp",), n_boundary,
+                    v_act, c.expose_layer)
 
         # CP: ring (K/V shift chain fwd, K/V + dK/dV bwd), the Ulysses
         # seq<->head all_to_all pair each way, or the mesh flavor's 2D
@@ -836,16 +641,6 @@ def layout_label(cfg: Config) -> str:
                                     if d.cp_flavor == "mesh" else ""))
     if d.sequence_parallel:
         flags.append("sp")
-    if d.tp_size > 1 and d.tp_strategy != "megatron":
-        if d.tp_strategy == "2d":
-            tp_x, tp_y = resolved_tp_mesh(cfg)
-            flags.append(f"tp2d-{tp_x}x{tp_y}")
-        elif d.tp_strategy in ("row", "adaptive"):
-            flags.append("tp" + d.tp_strategy)
-        else:
-            flags.append("tpmix")
-    if d.tp_sync == "deferred":
-        flags.append("deferred")
     if d.zero1:
         flags.append("zero1")
     if t.optimizer_offload:
@@ -944,116 +739,6 @@ def cp_crossover(model: CostModel, base: Config,
         if row["winner"] == "mesh":
             return row["cp"]
     return None
-
-
-# ---------------------------------------------------------------------------
-# TP-strategy pricing + adaptive selection
-# ---------------------------------------------------------------------------
-
-
-def feasible_tp_meshes(cfg: Config, tp: Optional[int] = None) -> list:
-    """True-2D (tp_x, tp_y) factorizations of the tp degree — both factors
-    > 1 (degenerates ARE megatron: tp_y=1 has no inner gather and tp_x=1
-    no outer psum shrink) and tp_x dividing the q AND kv head counts (the
-    2d attention runs heads/tp_x, tp_y-replicated)."""
-    m = cfg.model
-    tp = tp or cfg.distributed.tp_size
-    return [(tp // y, y) for y in range(2, tp)
-            if tp % y == 0 and tp // y > 1
-            and m.num_attention_heads % (tp // y) == 0
-            and m.num_key_value_heads % (tp // y) == 0]
-
-
-def price_tp_strategy(model: CostModel, cfg: Config, strategy: str,
-                      sync: str = "sync", tp_mesh: str = "") -> StepCost:
-    """Price `cfg` with its TP strategy/sync knobs forced — the one-call
-    query behind `choose_tp_strategy` and the `--tp-strategy-table` CLI.
-    No validation is re-run: this is a pricing probe, so the caller owns
-    eligibility (the planner only probes eligible configs)."""
-    return model.predict(replace(cfg, distributed=replace(
-        cfg.distributed, tp_strategy=strategy, tp_sync=sync,
-        tp_mesh=tp_mesh)))
-
-
-def _pair_spec(attn_kind: str, mlp_kind: str) -> str:
-    """Explicit per-class spec string for a (attn-pair, mlp-pair) choice,
-    respecting the legal (entry, exit) pairings config.parse_tp_strategy
-    enforces: col pairs with row, row with col, 2d with 2d."""
-    exit_of = {"col": "row", "row": "col", "2d": "2d"}
-    return (f"qkv={attn_kind},o={exit_of[attn_kind]},"
-            f"up={mlp_kind},down={exit_of[mlp_kind]},head=col")
-
-
-def choose_tp_strategy(cfg: Config, generation: str = "v5e") -> dict:
-    """Resolve tp_strategy='adaptive': per-class argmin over the legal
-    pair partitionings, priced on `generation`'s ICI descriptor (the ATP
-    selection loop, arxiv 2301.08658, collapsed to the three partitionings
-    this runtime implements). Deterministic: candidates are enumerated in
-    a fixed order with a strict < comparison, so megatron (first) wins
-    ties — tp degrees where no alternative strictly helps keep the
-    reference layout. Pure arithmetic; resolves in microseconds."""
-    model = CostModel(generation)
-    d = cfg.distributed
-    tp_x, tp_y = resolved_tp_mesh(cfg)
-    kinds = ["col", "row"] + (["2d"] if tp_x > 1 and tp_y > 1 else [])
-    best_s, best_spec = None, _pair_spec("col", "col")
-    for ak in kinds:
-        for mk in kinds:
-            spec = _pair_spec(ak, mk)
-            cost = price_tp_strategy(model, cfg, spec, sync=d.tp_sync,
-                                     tp_mesh=d.tp_mesh)
-            if best_s is None or cost.total_s < best_s:
-                best_s, best_spec = cost.total_s, spec
-    return parse_tp_strategy(best_spec)
-
-
-def tp_strategy_table(model: CostModel, base: Config,
-                      tp_degrees=(2, 4, 8, 16)) -> list[dict]:
-    """Sweep tp degree for `base`'s model/batch on `model`'s generation
-    and report, per degree, each strategy x sync-mode's predicted step
-    time and exposed-comm time, the best 2d factorization, the adaptive
-    resolution, and the winner — the table
-    `tools/layout_planner.py --tp-strategy-table` prints. Degrees the
-    model cannot shard (head/kv/vocab divisibility) are skipped."""
-    m = base.model
-    rows = []
-    for tp in tp_degrees:
-        if (tp < 2 or m.num_attention_heads % tp
-                or m.num_key_value_heads % tp or m.vocab_size % tp):
-            continue
-        cfg = replace(base, distributed=replace(
-            base.distributed, tp_size=tp, tp_strategy="megatron",
-            tp_sync="sync", tp_mesh=""))
-        variants: dict[str, StepCost] = {
-            "megatron": model.predict(cfg),
-            "deferred": price_tp_strategy(model, cfg, "megatron",
-                                          sync="deferred"),
-            "row": price_tp_strategy(model, cfg, "row"),
-        }
-        row = {"tp": tp, "generation": model.gen.name}
-        best2d = None
-        for tp_mx, tp_my in feasible_tp_meshes(cfg, tp):
-            cost = price_tp_strategy(model, cfg, "2d",
-                                     tp_mesh=f"{tp_mx}x{tp_my}")
-            if best2d is None or cost.total_s < best2d[0].total_s:
-                best2d = (cost, f"{tp_mx}x{tp_my}")
-        if best2d is not None:
-            variants["2d"] = best2d[0]
-            row["mesh_factorization"] = best2d[1]
-        base_exposed = variants["megatron"].exposed_comm_s
-        for name, cost in variants.items():
-            row[f"{name}_ms"] = round(cost.total_s * 1e3, 3)
-            row[f"{name}_exposed_ms"] = round(cost.exposed_comm_s * 1e3, 3)
-            row[f"{name}_exposed_delta_ms"] = round(
-                (cost.exposed_comm_s - base_exposed) * 1e3, 3)
-        adaptive = choose_tp_strategy(replace(cfg, distributed=replace(
-            cfg.distributed, tp_strategy="adaptive")),
-            generation=model.gen.name)
-        row["adaptive"] = ",".join(
-            f"{k}={adaptive[k]}" for k in ("qkv", "o", "up", "down"))
-        row["winner"] = min(variants, key=lambda k: variants[k].total_s)
-        rows.append(row)
-    return rows
 
 
 # ---------------------------------------------------------------------------

@@ -87,12 +87,6 @@ class PlanPoint:
             parts.append(f"model.attn_impl={self.cfg.model.attn_impl}")
         if d.cp_mesh:
             parts.append(f"distributed.cp_mesh={d.cp_mesh}")
-        if d.tp_strategy != "megatron":
-            parts.append(f"distributed.tp_strategy={d.tp_strategy}")
-        if d.tp_sync != "sync":
-            parts.append(f"distributed.tp_sync={d.tp_sync}")
-        if d.tp_mesh:
-            parts.append(f"distributed.tp_mesh={d.tp_mesh}")
         parts += [
                  f"distributed.sequence_parallel="
                  f"{str(d.sequence_parallel).lower()}",
@@ -231,29 +225,6 @@ def _cp_flavor_options(base: Config, cp: int, tp: int) -> list[tuple]:
     return opts
 
 
-def _tp_strategy_options(base: Config, tp: int) -> list[tuple]:
-    """(tp_strategy, tp_sync, tp_mesh) candidates for a tp-degree slice of
-    the layout space — strategy and sync mode are free planner axes, like
-    the cp flavor. Megatron-sync is always schedulable; deferred sync
-    needs tp > 1 (it reschedules the row-parallel exit psum as RS + a
-    hoisted AG); the 2d strategy enumerates every true-2D factorization
-    at tp >= 4. The row-first strategy is not enumerated: its entry psum
-    spans the full projection width (wider than hidden) plus an exit
-    gather, so it is dominated by megatron at every degree the cost model
-    prices — tools/layout_planner.py --tp-strategy-table still reports it
-    for inspection."""
-    opts = [("megatron", "sync", "")]
-    if tp <= 1:
-        return opts
-    opts.append(("megatron", "deferred", ""))
-    if tp >= 4:
-        from picotron_tpu.analysis.cost_model import feasible_tp_meshes
-
-        opts += [("2d", "sync", f"{x}x{y}")
-                 for x, y in feasible_tp_meshes(base, tp)]
-    return opts
-
-
 def candidate_configs(base: Config, chips: int,
                       *, flags: bool = True) -> list[Config]:
     """Every valid layout of `base` over `chips` devices. Flag knobs
@@ -277,49 +248,43 @@ def candidate_configs(base: Config, chips: int,
         cp_opts = _cp_flavor_options(base, cp, tp) if flags \
             else [(base.distributed.cp_flavor if cp > 1 else "",
                    base.distributed.cp_mesh if cp > 1 else "")]
-        tp_opts = _tp_strategy_options(base, tp) if flags \
-            else [(base.distributed.tp_strategy, base.distributed.tp_sync,
-                   base.distributed.tp_mesh)]
         for sp in sp_opts:
             for z1 in z_opts:
                 for off in o_opts:
                     for pl in pipe_opts:
                         for flavor, cp_mesh in cp_opts:
-                            for tp_strat, tp_sync, tp_mesh in tp_opts:
-                                model_cfg = base.model
-                                if (model_cfg.attn_impl in _CP_FLAVOR_IMPLS
-                                        and flavor
-                                        and model_cfg.attn_impl != flavor):
-                                    # a base pinned to one cp schedule by
-                                    # name would contradict the enumerated
-                                    # flavor; rename it (flash lowering is
-                                    # unchanged)
-                                    model_cfg = dataclasses.replace(
-                                        model_cfg, attn_impl=flavor)
-                                cfg = base.replace(
-                                    model=model_cfg,
-                                    distributed=dataclasses.replace(
-                                        base.distributed, dp_size=dp,
-                                        tp_size=tp, pp_size=pp, cp_size=cp,
-                                        ep_size=ep, cp_flavor=flavor,
-                                        cp_mesh=cp_mesh,
-                                        tp_strategy=tp_strat,
-                                        tp_sync=tp_sync, tp_mesh=tp_mesh,
-                                        sequence_parallel=sp, zero1=z1),
-                                    training=dataclasses.replace(
-                                        t, gradient_accumulation_steps=ga,
-                                        optimizer_offload=off,
-                                        # offload demands bf16 + 1f1b;
-                                        # grad_engine auto lets each layout
-                                        # pick its engine
-                                        grad_engine="auto"),
-                                    pipeline=pl,
-                                )
-                                try:
-                                    cfg.validate()
-                                except (ValueError, KeyError):
-                                    continue
-                                out.append(cfg)
+                            model_cfg = base.model
+                            if (model_cfg.attn_impl in _CP_FLAVOR_IMPLS
+                                    and flavor
+                                    and model_cfg.attn_impl != flavor):
+                                # a base pinned to one cp schedule by
+                                # name would contradict the enumerated
+                                # flavor; rename it (flash lowering is
+                                # unchanged)
+                                model_cfg = dataclasses.replace(
+                                    model_cfg, attn_impl=flavor)
+                            cfg = base.replace(
+                                model=model_cfg,
+                                distributed=dataclasses.replace(
+                                    base.distributed, dp_size=dp,
+                                    tp_size=tp, pp_size=pp, cp_size=cp,
+                                    ep_size=ep, cp_flavor=flavor,
+                                    cp_mesh=cp_mesh,
+                                    sequence_parallel=sp, zero1=z1),
+                                training=dataclasses.replace(
+                                    t, gradient_accumulation_steps=ga,
+                                    optimizer_offload=off,
+                                    # offload demands bf16 + 1f1b;
+                                    # grad_engine auto lets each layout
+                                    # pick its engine
+                                    grad_engine="auto"),
+                                pipeline=pl,
+                            )
+                            try:
+                                cfg.validate()
+                            except (ValueError, KeyError):
+                                continue
+                            out.append(cfg)
     return out
 
 
@@ -440,26 +405,3 @@ def planner_gap(cfg: Config, model: Optional[CostModel] = None,
     gap = ((cur.total_s / cur.tokens_per_step)
            / (best.cost.total_s / best.cost.tokens_per_step) - 1.0)
     return cur, best, gap
-
-
-def slice_plans(cfg: Config, model: Optional[CostModel] = None,
-                n_slices: Optional[int] = None) -> list[dict]:
-    """Enumerate which DCN-tolerant axis (dp or pp) can absorb the slice
-    granules for this layout and price both network tiers for each legal
-    split (CostModel.slice_tiers): the intra-slice ICI legs and the
-    shard-per-slice DCN leg of the hierarchical decomposition. Rows are
-    ranked by total comm — the top row is the boundary the layout should
-    declare in `distributed.dcn_axes`. Empty when no axis can absorb the
-    slice count (the same divisibility rule mesh._split_axes_over_dcn
-    enforces)."""
-    model = model or CostModel()
-    s = n_slices if n_slices is not None else cfg.distributed.slices
-    if s <= 1:
-        return []
-    d = cfg.distributed
-    rows = []
-    for axis, size in (("dp", d.dp_size), ("pp", d.pp_size)):
-        if size >= s and size % s == 0:
-            rows.append(model.slice_tiers(cfg, s, axis))
-    rows.sort(key=lambda r: r["total_comm_ms"])
-    return rows

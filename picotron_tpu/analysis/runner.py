@@ -13,15 +13,14 @@ import os
 
 from picotron_tpu.analysis.report import Report
 
-ALL_CHECKS = ("spec", "source", "collectives", "boundary", "provenance",
-              "variants", "donation", "stability")
-PREFLIGHT_CHECKS = ("spec", "donation", "stability", "provenance",
-                    "variants", "boundary")
+ALL_CHECKS = ("spec", "source", "collectives", "variants", "donation",
+              "stability")
+PREFLIGHT_CHECKS = ("spec", "donation", "stability", "variants")
 
 
 def run_shardcheck(cfg, *, menv=None, checks=ALL_CHECKS,
                    budget_bytes=None, source_roots=None,
-                   cost_model=None, slices=None, dcn_axes=None) -> Report:
+                   cost_model=None) -> Report:
     """Run the requested analyzers for `cfg`; returns the merged Report.
 
     Host-only: the trace-time checks lower the train step on an abstract
@@ -41,8 +40,8 @@ def run_shardcheck(cfg, *, menv=None, checks=ALL_CHECKS,
         from picotron_tpu.analysis.source_lint import lint_sources
 
         rep.extend(lint_sources(source_roots))
-    trace_checks = {"collectives", "boundary", "provenance", "variants",
-                    "donation", "stability"} & set(checks)
+    trace_checks = {"collectives", "variants", "donation",
+                    "stability"} & set(checks)
     if trace_checks:
         if not spec_ok:
             # a spec the lint rejects usually cannot trace either — stop at
@@ -59,16 +58,6 @@ def run_shardcheck(cfg, *, menv=None, checks=ALL_CHECKS,
                                          state=low.state,
                                          budget_bytes=budget_bytes,
                                          cost_model=cost_model))
-        if "boundary" in trace_checks:
-            from picotron_tpu.analysis.boundary import audit_boundary
-
-            rep.extend(audit_boundary(cfg, low=low,
-                                      n_slices=slices, dcn_axes=dcn_axes,
-                                      cost_model=cost_model))
-        if "provenance" in trace_checks:
-            from picotron_tpu.analysis.dataflow import audit_dataflow
-
-            rep.extend(audit_dataflow(cfg, low=low, cost_model=cost_model))
         if "variants" in trace_checks:
             from picotron_tpu.analysis.variants import audit_variants
 

@@ -21,8 +21,6 @@ class LoweredStep(NamedTuple):
     text: str            # StableHLO module text
     state: object        # abstract TrainState
     batch: tuple         # abstract (ids, targets)
-    jaxpr: object = None  # ClosedJaxpr pre-lowering (provenance analysis);
-    #                       None when this JAX lacks jit(...).trace
 
 
 def abstract_batch(cfg, menv):
@@ -49,7 +47,7 @@ def lower_train_step(cfg, menv=None) -> LoweredStep:
     if cfg.pipeline.executor == "mpmd":
         # The MPMD executor is a host-side schedule walker over per-stage
         # programs — there is no single jit to lower. Trace-level checks
-        # (collectives, provenance, donation, stability) run on its SPMD
+        # (collectives, donation, stability) run on its SPMD
         # twin: same math, one program. The per-stage compile-once claim
         # is proven separately by variants.prove_mpmd_stages.
         cfg = dataclasses.replace(cfg, pipeline=PipelineConfig())
@@ -57,9 +55,5 @@ def lower_train_step(cfg, menv=None) -> LoweredStep:
     state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
     step = make_train_step(cfg, menv)
     batch = abstract_batch(cfg, menv)
-    # one trace serves both consumers: the jaxpr (sharding-dataflow
-    # provenance, analysis/dataflow.py) and the lowering (HLO-text checks)
-    traced = step.trace(state, batch)
-    lowered = traced.lower()
-    return LoweredStep(step, lowered, lowered.as_text(), state, batch,
-                       traced.jaxpr)
+    lowered = step.lower(state, batch)
+    return LoweredStep(step, lowered, lowered.as_text(), state, batch)

@@ -38,7 +38,7 @@ model jit-cache eviction, explicitly different static arguments (a new
 `interval` is a new program — intended), or a JAX upgrade changing
 lowering itself. Output shardings are not observable without compiling;
 for the train step the stability check pins output avals == input avals,
-and the runtime CompileWatch twin tests (tests/test_dataflow.py) confirm
+and the runtime CompileWatch twin tests (tests/test_variants.py) confirm
 the end-to-end claim on the real cache.
 """
 

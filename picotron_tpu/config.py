@@ -365,52 +365,12 @@ class DistributedConfig:
     # moments, 20.25 -> 8.44 GiB/device total state (tools/memcheck.py
     # --override distributed.zero1=true; PERF.md round 4).
     zero1: bool = False
-    # Per-layer-class TP partitioning (ATP, arxiv 2301.08658). Presets:
-    # "megatron" — the fixed column/row pattern (qkv/up column-parallel,
-    # o/down row-parallel + exit psum; today's default); "row" — row-first
-    # (qkv/up input-sharded with a psum at the projection exit, o/down
-    # column-parallel with a feature all-gather exit; attention runs
-    # tp-replicated); "2d" — tp = tp_x x tp_y factorization (subgroup
-    # collectives over tp_mesh; see parallel/tp_strategies.py); "adaptive"
-    # — per-layer-class cost-model argmin (resolved_tp_strategy). An
-    # explicit per-class spec is also accepted:
-    # "qkv=col,o=row,up=col,down=row,head=col" (pairings must be legal —
-    # parse_tp_strategy).
-    tp_strategy: str = "megatron"
-    # Activation-sync mode for the TP block exit: "sync" keeps the
-    # row-parallel psum (or the SP reduce-scatter) on the critical path;
-    # "deferred" replaces it with a reduce-scatter over the sequence whose
-    # gather half is hoisted into the NEXT block's entry (ParallelCtx.pre),
-    # so the residual stream stays seq-sharded between blocks and XLA can
-    # hide the gather behind the block-entry compute (partially-
-    # synchronized-activation TP, arxiv 2506.19645). Numerics are exact
-    # (RMSNorm is per-token); parity is pinned against the sync path.
-    tp_sync: str = "sync"
-    # 2D strategy factorization "XxY" (tp_x x tp_y, tp_x * tp_y == tp_size);
-    # "" picks the most-square feasible factorization (resolved_tp_mesh).
-    tp_mesh: str = ""
     # Multi-slice topology: number of TPU slices joined over DCN (the
     # data-center network). 1 = single slice, everything on ICI. When > 1
     # the slice granules are absorbed into the DCN-tolerant axes (dp first,
-    # then pp — mesh._split_axes_over_dcn) and the static slice-boundary
-    # auditor (analysis/boundary.py) proves at preflight that only
-    # collectives over the declared dcn_axes cross the cut.
+    # then pp — mesh._split_axes_over_dcn), so only their collectives
+    # cross the cut.
     slices: int = 1
-    # Which mesh axes are DECLARED as allowed to cross the inter-slice DCN
-    # link, comma-separated subset of "dp,pp". The auditor classifies every
-    # traced replica group against this declaration: groups on a declared
-    # axis that straddle the cut are "boundary" (expected, priced at the
-    # dcn tier); groups on any other axis that straddle it are
-    # "violating" (a named preflight error).
-    dcn_axes: str = "dp,pp"
-    # Runtime hierarchical dp gradient reduction across the slice cut:
-    # reduce-scatter inside the slice + a shard-per-slice all-reduce over
-    # DCN + intra-slice all-gather (parallel/hier_reduce.py), replacing the
-    # flat dp all-reduce the single-slice step emits. "auto" turns it on
-    # exactly when slices > 1 and dp physically carries a slice granule;
-    # "on" requires such a layout (refuses to silently no-op); "off" keeps
-    # the flat all-reduce (the A/B twin the parity tests pin against).
-    hier_dp_reduce: str = "auto"
     # Accepted for reference-JSON compatibility; ignored (XLA picks transport).
     backend: str = "jax"
     use_cpu: bool = False
@@ -443,33 +403,11 @@ class DistributedConfig:
                 raise ValueError(
                     f"cp_mesh '{self.cp_mesh}' must factor the cp degree: "
                     f"{cp_x} * {cp_y} != cp_size ({self.cp_size})")
-        parse_tp_strategy(self.tp_strategy)  # raises with the field named
-        if self.tp_strategy != "megatron" and self.tp_size == 1:
-            raise ValueError(
-                f"tp_strategy={self.tp_strategy!r} requires tp_size > 1 "
-                "(it names a tensor-parallel partitioning)")
-        if self.tp_sync not in ("sync", "deferred"):
-            raise ValueError(
-                f"tp_sync must be 'sync' or 'deferred', got {self.tp_sync!r}")
-        if self.tp_sync == "deferred" and self.tp_size == 1:
-            raise ValueError(
-                "tp_sync='deferred' requires tp_size > 1 (it reschedules "
-                "the TP block-exit collective)")
-        if self.tp_mesh:
-            tp_x, tp_y = parse_cp_mesh(self.tp_mesh)
-            if tp_x * tp_y != self.tp_size:
-                raise ValueError(
-                    f"tp_mesh '{self.tp_mesh}' must factor the tp degree: "
-                    f"{tp_x} * {tp_y} != tp_size ({self.tp_size})")
         if self.slices < 1:
             raise ValueError(f"slices must be >= 1, got {self.slices}")
-        axes = parse_dcn_axes(self.dcn_axes)
         if self.slices > 1:
             # Mirror mesh._split_axes_over_dcn: the slice count must divide
-            # dp*pp so ep/cp/tp collectives stay on ICI. The declaration may
-            # be NARROWER than the house rule (that is how a mis-declared
-            # layout is caught by the boundary auditor), but it cannot name
-            # an ICI-only axis.
+            # dp*pp so ep/cp/tp collectives stay on ICI.
             if self.slices > self.dp_size * self.pp_size or (
                     self.dp_size * self.pp_size) % self.slices != 0:
                 raise ValueError(
@@ -478,46 +416,6 @@ class DistributedConfig:
                     f"{self.dp_size * self.pp_size}) — ep/cp/tp collectives "
                     "must stay on ICI. Rebalance the layout so dp*pp "
                     "absorbs the slice count.")
-            if not axes:
-                raise ValueError(
-                    "dcn_axes must declare at least one crossing axis "
-                    "when slices > 1 (subset of 'dp,pp')")
-        if self.hier_dp_reduce not in ("auto", "on", "off"):
-            raise ValueError(
-                f"hier_dp_reduce must be 'auto', 'on' or 'off', got "
-                f"{self.hier_dp_reduce!r}")
-        if self.hier_dp_reduce == "on":
-            import math
-
-            if (self.slices <= 1 or "dp" not in axes
-                    or math.gcd(self.dp_size, self.slices) <= 1):
-                raise ValueError(
-                    "hier_dp_reduce='on' requires a multi-slice layout "
-                    "whose dp axis physically carries a slice granule "
-                    "(slices > 1, 'dp' in dcn_axes, gcd(dp_size, slices) "
-                    "> 1); use 'auto' to enable it only when the layout "
-                    "calls for it")
-
-
-DCN_TOLERANT_AXES = ("dp", "pp")
-
-
-def parse_dcn_axes(spec: str) -> tuple[str, ...]:
-    """Parse a dcn_axes declaration into an ordered (dp-first) axis tuple.
-
-    Accepts a comma-separated subset of the DCN-tolerant axes ("dp", "pp");
-    the empty string means no axis is declared (only legal at slices == 1).
-    """
-    axes = tuple(a.strip() for a in spec.split(",") if a.strip())
-    bad = [a for a in axes if a not in DCN_TOLERANT_AXES]
-    if bad:
-        raise ValueError(
-            f"dcn_axes may only name the DCN-tolerant axes "
-            f"{DCN_TOLERANT_AXES} (got {bad} in {spec!r}) — ep/cp/tp "
-            "collectives must stay on ICI")
-    if len(set(axes)) != len(axes):
-        raise ValueError(f"dcn_axes has duplicate axes: {spec!r}")
-    return tuple(a for a in DCN_TOLERANT_AXES if a in axes)
 
 
 def _parse_mesh2(spec: str, field: str) -> tuple[int, int]:
@@ -537,68 +435,6 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
     """'XxY' -> (cp_x, cp_y), with a field-naming error (not a bare int
     crash) on malformed input."""
     return _parse_mesh2(spec, "cp_mesh")
-
-
-def parse_tp_mesh(spec: str) -> tuple[int, int]:
-    """'XxY' -> (tp_x, tp_y) for the 2D TP strategy factorization."""
-    return _parse_mesh2(spec, "tp_mesh")
-
-
-# Layer classes a TP strategy assigns a partitioning to, and the legal
-# (entry, exit) pairings: the qkv/o and up/down pairs bracket a block, so
-# the entry's output layout must be what the exit consumes.
-TP_STRATEGY_CLASSES = ("qkv", "o", "up", "down", "head")
-_TP_STRATEGY_PRESETS = {
-    "megatron": {"qkv": "col", "o": "row", "up": "col", "down": "row",
-                 "head": "col"},
-    "row": {"qkv": "row", "o": "col", "up": "row", "down": "col",
-            "head": "col"},
-    "2d": {"qkv": "2d", "o": "2d", "up": "2d", "down": "2d", "head": "col"},
-}
-_TP_LEGAL_PAIRS = {("col", "row"), ("row", "col"), ("2d", "2d")}
-
-
-def parse_tp_strategy(spec: str):
-    """Parse distributed.tp_strategy into a per-class dict
-    {qkv,o,up,down,head} -> {col,row,2d}, or None for "adaptive" (whose
-    resolution needs the cost model — resolved_tp_strategy). Presets
-    megatron/row/2d expand to full dicts; an explicit "k=v,..." spec may
-    name a subset of classes (the rest default to megatron) but must keep
-    the (qkv,o) and (up,down) pairings legal and head column-parallel."""
-    if spec == "adaptive":
-        return None
-    if spec in _TP_STRATEGY_PRESETS:
-        return dict(_TP_STRATEGY_PRESETS[spec])
-    out = dict(_TP_STRATEGY_PRESETS["megatron"])
-    for item in spec.split(","):
-        if "=" not in item:
-            raise ValueError(
-                f"tp_strategy must be a preset (megatron/row/2d/adaptive) "
-                f"or a 'class=value,...' spec over "
-                f"{'/'.join(TP_STRATEGY_CLASSES)}, got {spec!r}")
-        k, _, v = item.partition("=")
-        k, v = k.strip(), v.strip()
-        if k not in TP_STRATEGY_CLASSES:
-            raise ValueError(
-                f"tp_strategy names unknown layer class {k!r} (classes: "
-                f"{', '.join(TP_STRATEGY_CLASSES)})")
-        if v not in ("col", "row", "2d"):
-            raise ValueError(
-                f"tp_strategy value for {k!r} must be col/row/2d, got {v!r}")
-        out[k] = v
-    if out["head"] != "col":
-        raise ValueError(
-            "tp_strategy head must be 'col' (the vocab-parallel head/CE is "
-            "the only supported head partitioning; row is priced by the "
-            "cost model but has no runtime path)")
-    for entry, exit_ in (("qkv", "o"), ("up", "down")):
-        if (out[entry], out[exit_]) not in _TP_LEGAL_PAIRS:
-            raise ValueError(
-                f"tp_strategy pairing {entry}={out[entry]}/{exit_}="
-                f"{out[exit_]} is not a legal (entry, exit) pair — the "
-                f"entry's output layout must feed the exit (legal: "
-                f"col/row, row/col, 2d/2d)")
-    return out
 
 
 @dataclass(frozen=True)
@@ -1296,59 +1132,6 @@ class Config:
             raise ValueError("num_key_value_heads must be divisible by tp_size")
         if m.vocab_size % d.tp_size != 0:
             raise ValueError("vocab_size must be divisible by tp_size")
-        if d.tp_strategy != "megatron":
-            # Non-default strategies rewire the block matmuls through the
-            # strategy hooks (parallel/tp_strategies.py); the hook set is
-            # dense-model, single-stage, tp-only for now. "adaptive" may
-            # resolve to megatron, but its eligibility is the strict set
-            # (resolution depends on the ICI generation; gating on the
-            # resolved spec would make validity generation-dependent).
-            for bad, why in (
-                (d.pp_size > 1, "pp_size > 1 (the strategy hooks assume a "
-                 "single-stage residual stream)"),
-                (d.cp_size > 1, "cp_size > 1 (non-megatron head layouts "
-                 "change the tp-local head counts the cp schedules "
-                 "divide)"),
-                (d.ep_size > 1 or m.num_experts > 0, "MoE models (the "
-                 "expert bank keeps the megatron f/g pattern)"),
-                (d.sequence_parallel, "sequence_parallel (SP rebinds the "
-                 "f/g hooks the strategies replace; use tp_sync='deferred' "
-                 "for the seq-sharded residual instead)"),
-                (m.attention_bias, "attention_bias (row/2d qkv would need "
-                 "a post-collective bias add)"),
-            ):
-                if bad:
-                    raise ValueError(
-                        f"tp_strategy={d.tp_strategy!r} does not support "
-                        f"{why}")
-        if d.tp_sync == "deferred":
-            if d.tp_strategy != "megatron":
-                raise ValueError(
-                    "tp_sync='deferred' composes only with "
-                    "tp_strategy='megatron' (the deferred reduce-scatter/"
-                    "gather pair reschedules the megatron exit psum; 2d/"
-                    "row exits are subgroup collectives with their own "
-                    "schedule)")
-            if d.pp_size > 1:
-                raise ValueError(
-                    "tp_sync='deferred' requires pp_size=1 (the seq-sharded "
-                    "residual would change the pipeline boundary buffers)")
-            if m.num_experts > 0:
-                raise ValueError(
-                    "tp_sync='deferred' does not support MoE models yet "
-                    "(the expert dispatch assumes the sync f/g schedule)")
-            if t.seq_length % (d.cp_size * d.tp_size) != 0:
-                raise ValueError(
-                    "tp_sync='deferred' shards the cp-local sequence over "
-                    "tp: seq_length must be divisible by cp_size * tp_size "
-                    f"(= {d.cp_size * d.tp_size}), got {t.seq_length}")
-        if d.tp_mesh and "2d" not in (parse_tp_strategy(d.tp_strategy)
-                                      or {}).values() \
-                and d.tp_strategy != "adaptive":
-            raise ValueError(
-                f"tp_mesh={d.tp_mesh!r} only applies when tp_strategy "
-                f"uses the 2d partitioning (got tp_strategy="
-                f"{d.tp_strategy!r})")
         if (d.cp_flavor and m.attn_impl in ("ring", "ulysses", "mesh")
                 and m.attn_impl != d.cp_flavor):
             raise ValueError(
@@ -1635,45 +1418,6 @@ def resolved_cp_mesh(cfg: "Config") -> tuple[int, int]:
     return cp // cp_y, cp_y
 
 
-def resolved_tp_strategy(cfg: "Config", generation: str = "v5e"):
-    """The concrete per-layer-class TP partitioning this config runs:
-    a dict {qkv,o,up,down,head} -> {col,row,2d}. The single dispatch key
-    for parallel/tp_strategies.py, parallel/sharding.py, the collective
-    audit and the cost model. tp_size==1 always resolves to megatron (the
-    hooks compile away); "adaptive" resolves deterministically via the
-    cost-model per-class argmin against `generation`'s ICI descriptor
-    (choose_tp_strategy — pure analytic, no devices touched)."""
-    d = cfg.distributed
-    if d.tp_size <= 1:
-        return dict(_TP_STRATEGY_PRESETS["megatron"])
-    spec = parse_tp_strategy(d.tp_strategy)
-    if spec is not None:
-        return spec
-    from picotron_tpu.analysis.cost_model import choose_tp_strategy
-
-    return choose_tp_strategy(cfg, generation=generation)
-
-
-def resolved_tp_mesh(cfg: "Config") -> tuple[int, int]:
-    """(tp_x, tp_y) for the 2d TP partitioning. An explicit
-    distributed.tp_mesh wins; otherwise the most-square feasible
-    factorization (tp_x must divide the q and kv head counts — always true
-    when tp does — and both factors must divide tp), tie-broken toward the
-    SMALLER tp_y: tp_y is the replication factor of the row-side matmuls
-    and the attention, so among equally-square options less replicated
-    compute wins."""
-    d, m = cfg.distributed, cfg.model
-    tp = d.tp_size
-    if d.tp_mesh:
-        return parse_tp_mesh(d.tp_mesh)
-    feasible = [y for y in range(1, tp + 1)
-                if tp % y == 0
-                and m.num_attention_heads % (tp // y) == 0
-                and m.num_key_value_heads % (tp // y) == 0]
-    tp_y = min(feasible, key=lambda y: (abs(y - tp ** 0.5), y))
-    return tp // tp_y, tp_y
-
-
 # ---------------------------------------------------------------------------
 # Loading
 # ---------------------------------------------------------------------------
@@ -1684,8 +1428,32 @@ def _filter_kwargs(cls: type, raw: dict[str, Any]) -> dict[str, Any]:
     return {k: v for k, v in raw.items() if k in names}
 
 
+# Options of `distributed` that were removed together with the code they
+# selected, with their former defaults. Unknown keys are ignored on load, so
+# without this a config asking for a removed schedule would silently train
+# the one that remains. A key carrying its former default loads (dumped
+# configs of old runs do) and is dropped.
+_RETIRED_DISTRIBUTED = {
+    "tp_strategy": "megatron",
+    "tp_sync": "sync",
+    "tp_mesh": "",
+    "dcn_axes": "dp,pp",
+    "hier_dp_reduce": "auto",
+}
+
+
+def _reject_retired(dist_raw: dict[str, Any]) -> None:
+    for key, former in _RETIRED_DISTRIBUTED.items():
+        if key in dist_raw and dist_raw[key] != former:
+            raise ValueError(
+                f"distributed.{key}={dist_raw[key]!r}: this option was "
+                f"removed with the code it selected; only its former "
+                f"default {former!r} still loads. Delete the key.")
+
+
 def config_from_dict(raw: dict[str, Any]) -> Config:
     """Build a Config from a (reference-schema-compatible) dict."""
+    _reject_retired(raw.get("distributed", {}))
     model_raw = dict(raw.get("model", {}))
     name = model_raw.get("name")
     if name:

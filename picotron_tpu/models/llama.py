@@ -89,25 +89,6 @@ class ParallelCtx:
     # TP collectives
     f: Callable = _identity
     g: Callable = _identity
-    # block-entry hook, applied to the residual-stream input BEFORE the
-    # norm (identity everywhere except deferred TP sync, where it is the
-    # hoisted gather half of the previous block's exit reduce-scatter —
-    # parallel/tp_strategies.py)
-    pre: Callable = _identity
-    # per-layer-class TP strategy overrides (parallel/tp_strategies.py):
-    # qkv_mm replaces qkv_proj ((h, lp, head_dim) -> (q, k, v) reshaped to
-    # heads), o_mm replaces the o-projection + exit collective
-    # ((out_flat, w_o) -> block output), mlp_mm replaces the MLP matmuls +
-    # exit collective after the entry norm ((h, lp, cfg) -> block output).
-    # None = the megatron path as written in this file. The fused grad
-    # engine reaches all three through the same call sites / segment VJPs.
-    qkv_mm: Optional[Callable] = None
-    o_mm: Optional[Callable] = None
-    mlp_mm: Optional[Callable] = None
-    # head-entry hook for the logits path: deferred TP sync keeps f as the
-    # identity (the gather moved to `pre`) but the head still needs the
-    # full sequence — None falls back to f (SP and every sync path)
-    head_in: Optional[Callable] = None
     # embedding lookup (vocab-parallel TP overrides this)
     embed_lookup: Optional[Callable] = None
     # fused head+CE returning (nll_sum, valid_count) (vocab-parallel TP
@@ -363,7 +344,7 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     dt = x.dtype
     d = cfg.head_dim
 
-    h = rms_norm(ctx.pre(x), lp["input_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
     h = ctx.f(h)  # column-parallel entry: identity fwd / psum bwd; under
     # sequence parallelism an all_gather that restores the full sequence
     b, s, _ = h.shape
@@ -372,8 +353,7 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     # inputs) while the MLP recomputes — the memory/flops midpoint between
     # "dots" and "full" (the MLP's gate/up activations are ~2/3 of a
     # layer's saved bytes but its matmuls only ~+7% of step flops)
-    q, k, v = (ctx.qkv_mm(h, lp, d) if ctx.qkv_mm is not None
-               else qkv_proj(h, lp, d, cfg.rms_norm_eps))
+    q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
     n_q = q.shape[2]
 
     # K/V stay unexpanded (n_kv heads) — attention impls handle GQA so the
@@ -384,8 +364,6 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     # (flash VJP fwd rule / sdpa), so the "dots" remat policy saves the
     # kernel residuals exactly once and backward never re-runs the forward.
     out = out.reshape(b, s, n_q * d)
-    if ctx.o_mm is not None:
-        return ctx.o_mm(out, lp["o"])
     out = out @ lp["o"].astype(dt)
     out = checkpoint_name(out, "attn_proj_out")
     return ctx.g(out)  # row-parallel exit: psum-over-tp fwd / identity bwd
@@ -406,9 +384,7 @@ def mlp_act(cfg: ModelConfig):
 def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
     """RMSNorm -> gated MLP (ref: model.py:184-186)."""
     dt = x.dtype
-    h = rms_norm(ctx.pre(x), lp["post_norm"], cfg.rms_norm_eps)
-    if ctx.mlp_mm is not None:
-        return ctx.mlp_mm(h, lp, cfg)
+    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
     h = ctx.f(h)
     gate = checkpoint_name(h @ lp["gate"].astype(dt), "mlp_gate")
     up = checkpoint_name(h @ lp["up"].astype(dt), "mlp_up")
@@ -424,7 +400,7 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     mean."""
     from picotron_tpu.ops.moe import moe_mlp
 
-    h = rms_norm(ctx.pre(x), lp["post_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
     h = ctx.f(h)
     # every expert on this device (ep = 1): the dropless dispatch; across
     # 'ep' the all_to_all needs the capacity path's fixed shapes
@@ -567,9 +543,7 @@ def logits_from_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                        ctx: ParallelCtx = DEFAULT_CTX) -> jnp.ndarray:
     # Under sequence parallelism x arrives seq-sharded; the column-parallel
     # entry hook re-gathers the sequence before the vocab-sharded head.
-    # Deferred TP sync keeps f as the identity (the gather lives in `pre`)
-    # and supplies head_in instead (identity on every other path).
-    x = (ctx.head_in or ctx.f)(x)
+    x = ctx.f(x)
     logits = x @ head_weight(params).astype(x.dtype)
     return ctx.gather_logits(logits)
 

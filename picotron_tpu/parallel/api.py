@@ -233,13 +233,6 @@ def make_parallel_ctx(cfg: Config) -> ParallelCtx:
             moe_aux_sync=lambda a: lax.pmean(a, "tp"),
         )
 
-    # Non-megatron TP strategies and deferred activation sync install their
-    # hook overrides on top (parallel/tp_strategies.py); {} on the plain
-    # megatron/SP sync paths, so those stay byte-identical.
-    from picotron_tpu.parallel.tp_strategies import tp_strategy_hooks
-
-    hooks.update(tp_strategy_hooks(cfg, ce=ce))
-
     # Uneven-PP padding: mask the aux statistics of pad slots from the
     # STATIC placement rule (pp_layer_placement puts each stage's real
     # layers in its leading slots; remainder to early stages) rather than
@@ -274,21 +267,13 @@ def _data_axes_psum(grads, cfg: Config):
     would multiply them by ep_size.
 
     This is the one seam BOTH grad engines exit through (the AD and fused
-    paths below, and the pp scan path) — so it is also where the multi-slice
-    layouts swap the flat psum for the hierarchical DCN schedule
-    (parallel/hier_reduce.py): reduce-scatter inside the slice, a
-    shard-per-slice all-reduce across DCN, all-gather back."""
-    from picotron_tpu.parallel.hier_reduce import hier_axes_psum, use_hier_dp
-
+    paths below, and the pp scan path)."""
     specs = param_specs(cfg)
-    hier = use_hier_dp(cfg)
 
     def red(g, spec):
         flat = [a for part in spec if part is not None
                 for a in (part if isinstance(part, (tuple, list)) else (part,))]
         axes = ("dp", "cp") if "ep" in flat else ("dp", "ep", "cp")
-        if hier:
-            return hier_axes_psum(g, axes, cfg)
         return lax.psum(g, axes)
 
     return jax.tree.map(red, grads, specs, is_leaf=lambda x: isinstance(x, P))

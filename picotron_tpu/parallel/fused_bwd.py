@@ -43,15 +43,10 @@ Per-axis structure (the north-star layouts; VERDICT r5):
   directions around the flash backward kernel). RoPE for the ring is
   applied outside the ring exactly as in the forward wiring
   (parallel/api.py), with the rotation's transpose recovered by jax.vjp.
-- **Multi-slice / DCN**: the in-scan accumulator is a purely per-device
-  fp32 tree — no collective touches it until the engine seam
+- **Data axes**: the in-scan accumulator is a purely per-device fp32
+  tree — no collective touches it until the engine seam
   (api._data_axes_psum) reduces it ONCE over the data axes after the last
-  microbatch. That single exit point is exactly where multi-slice layouts
-  swap the flat dp all-reduce for the hierarchical DCN schedule
-  (parallel/hier_reduce.py: intra-slice reduce-scatter, shard-per-slice
-  all-reduce over DCN, intra-slice all-gather), so the fused engine emits
-  the same slice-boundary schedule as the AD engine by construction —
-  pinned by the `tiny-dp-cross-fused` shardcheck preset's boundary audit.
+  microbatch, the same exit as the AD engine's.
 - **MoE (Mixtral expert block)**: the expert MLP is recomputed in backward
   by a segment VJP over `_moe_block` — routing (router logits, top-k,
   slot cumsum) recomputes deterministically from the saved layer input,
@@ -109,18 +104,6 @@ def _vary_like(x, ref):
     from picotron_tpu.parallel.pp import _vary_over
 
     return _vary_over(x, set(compat.vma(ref)))
-
-
-def _o_exit(ctx: ParallelCtx, outf, w_o, dt):
-    """The o-projection + TP block exit, dispatched through the strategy
-    hook exactly as models/llama.py's _attention_block does — the single
-    definition both the forward scan and the backward segment VJPs close
-    over, so the fused engine emits whatever collectives the strategy
-    chose (megatron psum, SP/deferred reduce-scatter, 2d subgroup psum,
-    row-first feature gather)."""
-    if ctx.o_mm is not None:
-        return ctx.o_mm(outf, w_o)
-    return ctx.g(outf @ w_o.astype(dt))
 
 
 def _attn_paths(cfg: Config, ctx: ParallelCtx, cos, sin):
@@ -309,11 +292,6 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
     qkv_opt_keys = [k for k in ("b_q", "b_k", "b_v", "q_norm", "k_norm")
                     if k in params["layers"]]
 
-    def qkv(hf, lp):
-        if ctx.qkv_mm is not None:
-            return ctx.qkv_mm(hf, lp, hd)
-        return qkv_proj(hf, lp, hd, eps)
-
     moe_keys = (["router", "w_gate", "w_up", "w_down"] if moe
                 else ["gate", "up", "down"])
 
@@ -335,13 +313,13 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
 
     def fwd_body(x, lp):
         with scope("attention"):
-            h1 = rms_norm(ctx.pre(x), lp["input_norm"], eps)
+            h1 = rms_norm(x, lp["input_norm"], eps)
             hf = ctx.f(h1)
-            q, k, v = qkv(hf, lp)
+            q, k, v = qkv_proj(hf, lp, hd, eps)
         out, lse = attn_fwd(q, k, v)
         with scope("attention"):
             outf = flat(out)
-            a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
+            a = x + ctx.g(outf @ lp["o"].astype(x.dtype))
         if moe:
             mo, aux = _moe_block(a, lp, m, ctx)
             y = a + mo
@@ -392,7 +370,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         # the routing recomputes deterministically and the aux-loss fold
         # (aux * count) rides the segment so balance/z grads flow.
         with scope("attention"):
-            a = x + _o_exit(ctx, outf, lp["o"], x.dtype)
+            a = x + ctx.g(outf @ lp["o"].astype(x.dtype))
 
         if moe:
             def seg_mlp(a_, *ws):
@@ -417,7 +395,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
 
         @scope("attention")
         def seg_o(x_, outf_, wo):
-            return x_ + _o_exit(ctx, outf_, wo, x_.dtype)
+            return x_ + ctx.g(outf_ @ wo.astype(x_.dtype))
 
         _, vjp_o = jax.vjp(seg_o, x, outf, lp["o"])
         with scope("attention"):
@@ -430,9 +408,9 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
             lpq = dict(lp)
             lpq.update(input_norm=w_in, q=wq, k=wk, v=wv,
                        **dict(zip(qkv_opt_keys, bs)))
-            h1_ = rms_norm(ctx.pre(x_), w_in, eps)
+            h1_ = rms_norm(x_, w_in, eps)
             hf_ = ctx.f(h1_)
-            q_, k_, v_ = qkv(hf_, lpq)
+            q_, k_, v_ = qkv_proj(hf_, lpq, hd, eps)
             return flat(q_), flat(k_), flat(v_)
 
         _, vjp_q = jax.vjp(seg_qkv, x, lp["input_norm"], lp["q"], lp["k"],

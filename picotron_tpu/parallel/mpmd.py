@@ -432,16 +432,7 @@ def _vary_over_data(params):
 def _sub_data_psum(grads, cfg: Config):
     """Per-microbatch grad reduction over the submesh's data axes. No
     per-leaf exceptions: MoE (the expert-bank case _data_axes_psum special-
-    cases) is rejected for the MPMD executor at config time. When dp
-    carries a slice granule (dcn_axes includes dp at slices > 1) the flat
-    psum becomes the hierarchical DCN schedule — the submesh's dp axis
-    spans the same global dp coordinates as the SPMD mesh's, so the
-    intra/cross slice groups of parallel/hier_reduce.py apply unchanged."""
-    from picotron_tpu.parallel.hier_reduce import hier_axes_psum, use_hier_dp
-
-    if use_hier_dp(cfg):
-        return jax.tree.map(
-            lambda g: hier_axes_psum(g, ("dp", "ep", "cp"), cfg), grads)
+    cases) is rejected for the MPMD executor at config time."""
     return jax.tree.map(lambda g: lax.psum(g, ("dp", "ep", "cp")), grads)
 
 
@@ -647,11 +638,6 @@ def _make_slicer(cfg: Config, lo: int, hi: int, first: bool, last: bool,
 def _build_stages(cfg: Config, menv: MeshEnv):
     pp, v = cfg.distributed.pp_size, cfg.pipeline.interleave
     V = pp * v
-    if cfg.distributed.slices > 1:
-        # every stage program must live whole on one slice when pp alone
-        # carries the cut (see check_stage_slice_placement) — asserted at
-        # build time so a grid regression fails before a step runs
-        check_stage_slice_placement(cfg)
     blocks = _stage_blocks(cfg)
     meshes = _stage_meshes(menv)
     return [_StagePrograms(cfg, meshes[j % pp], j, V, blocks[j], menv.mesh)
@@ -1013,101 +999,3 @@ def mpmd_entry_feeds(cfg: Config, menv: MeshEnv) -> dict:
                 else:
                     feeds[bkey].append((p_abs, x_abs, x_abs, acc_abs))
     return feeds
-
-
-# ---------------------------------------------------------------------------
-# Multi-slice placement (the boundary auditor's runtime counterpart)
-# ---------------------------------------------------------------------------
-
-
-def stage_slice_placement(cfg: Config) -> list:
-    """Slice index each pp device group lives on — None for a group that
-    spans slices. Derived from the row-major (dp, pp, ep, cp, tp) grid the
-    Mesh contract fixes (analysis/boundary.py SliceTopology), so it needs
-    no live devices. When pp alone carries the slice granule, every group
-    is per-slice BY CONSTRUCTION (_stage_meshes re-meshes the full mesh's
-    pp=g column): the boundary device_put ring buffers become the only
-    DCN traffic (arxiv 2412.14374's placement), which `make_mpmd_train_step`
-    asserts and `boundary_dcn_traffic` prices."""
-    from picotron_tpu.analysis.boundary import SliceTopology
-
-    topo = SliceTopology.from_config(cfg)
-    d = cfg.distributed
-    grid = np.arange(topo.world).reshape(topo.grid)
-    out = []
-    for g in range(d.pp_size):
-        slices = {topo.slice_of(int(i)) for i in grid[:, g].ravel()}
-        out.append(slices.pop() if len(slices) == 1 else None)
-    return out
-
-
-def check_stage_slice_placement(cfg: Config) -> list:
-    """Raise unless every pp device group sits whole on one slice when pp
-    alone carries the slice cut — the invariant that makes the schedule
-    walk's device_put transfers the ONLY inter-slice traffic. A dp-cut
-    layout legitimately spans every group across slices (the hierarchical
-    dp reduction inside the stage programs handles that cut), so the check
-    applies only to the pure-pp cut. Returns the placement list."""
-    from picotron_tpu.analysis.boundary import SliceTopology
-
-    placement = stage_slice_placement(cfg)
-    topo = SliceTopology.from_config(cfg)
-    if topo.n_slices > 1 and topo.cut_axes == ("pp",):
-        bad = [g for g, s in enumerate(placement) if s is None]
-        if bad:
-            raise RuntimeError(
-                f"mpmd stage placement violates the slice cut: device "
-                f"group(s) {bad} span multiple slices although pp alone "
-                f"carries the {topo.n_slices}-slice granule — stage "
-                f"programs would run ICI collectives over DCN. The mesh "
-                f"grid no longer matches mesh._split_axes_over_dcn's "
-                f"house rule; this is a bug, not a layout choice.")
-    return placement
-
-
-def boundary_dcn_traffic(cfg: Config, cost_model=None) -> dict:
-    """Per-step DCN traffic of the schedule walk's boundary ring buffers:
-    which stage-to-stage device_put transfers cross the slice cut, their
-    bytes, and (with a cost model) seconds at the dcn tier — the
-    collective_permute pricing of CostModel.dcn_secs, since a boundary
-    transfer is a point-to-point neighbor shift, not a group collective."""
-    from picotron_tpu.analysis.boundary import SliceTopology
-
-    d = cfg.distributed
-    topo = SliceTopology.from_config(cfg)
-    placement = stage_slice_placement(cfg)
-    n_micro = cfg.training.gradient_accumulation_steps
-    pp, v = d.pp_size, cfg.pipeline.interleave
-    table = build_schedule(cfg.pipeline.schedule, n_micro, pp, v)
-    V = pp * v
-    m = cfg.model
-    itemsize = jnp.dtype(compute_dtype(m)).itemsize
-    per_transfer = (cfg.training.micro_batch_size * d.dp_size * d.ep_size
-                    * cfg.training.seq_length * m.hidden_size * itemsize)
-
-    def crosses(j_from: int, j_to: int) -> bool:
-        a, b = placement[j_from % pp], placement[j_to % pp]
-        return a is None or b is None or a != b
-
-    transfers = crossing = 0
-    for op in table:
-        j = op.vstage
-        if op.op == "F" and j < V - 1:
-            transfers += 1
-            crossing += crosses(j, j + 1)
-        elif op.op == "B" and j > 0:
-            transfers += 1
-            crossing += crosses(j, j - 1)
-    out = {
-        "slices": topo.n_slices,
-        "placement": placement,
-        "transfers": transfers,
-        "crossing": crossing,
-        "bytes_per_transfer": per_transfer,
-        "dcn_bytes": crossing * per_transfer,
-    }
-    if cost_model is not None and topo.n_slices > 1:
-        out["dcn_secs"] = crossing * cost_model.dcn_secs(
-            "collective_permute", per_transfer, topo.n_slices)
-        out["dcn_generation"] = cost_model.gen.name
-    return out

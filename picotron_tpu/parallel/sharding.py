@@ -29,37 +29,18 @@ from picotron_tpu.config import Config
 
 
 def param_specs(cfg: Config) -> dict[str, Any]:
-    """PartitionSpec pytree matching models.llama.init_params' structure.
-
-    Non-megatron TP strategies (config.resolved_tp_strategy) only re-point
-    which tensor dim carries 'tp': "row" flips a class to input-feature
-    (qkv/up) or output-feature (o/down) shards; "2d" keeps the megatron
-    1D shards — its tp_x x tp_y layout is expressed purely as subgroup
-    collectives over those shards (parallel/tp_strategies.py), so the
-    stored layout (and every checkpoint) is strategy-invariant except for
-    the explicit "row" flip."""
-    from picotron_tpu.config import resolved_tp_strategy
-
+    """PartitionSpec pytree matching models.llama.init_params' structure."""
     # layers % pp divisibility is enforced by Config.validate().
     pp = "pp" if cfg.distributed.pp_size > 1 else None
-    strat = resolved_tp_strategy(cfg)
-
-    def pair(cls):
-        # (entry, exit) specs for a col/row-paired class: megatron and 2d
-        # store column shards for the entry and row shards for the exit;
-        # "row" flips both.
-        if strat[cls] == "row":
-            return P(pp, "tp", None), P(pp, None, "tp")
-        return P(pp, None, "tp"), P(pp, "tp", None)
-
-    qkv_spec, o_spec = pair("qkv")
-    up_spec, down_spec = pair("up")
+    # Megatron's pairing: column shards for the entry matmul of a class
+    # (qkv, gate/up), row shards for its exit (o, down)
+    col, row = P(pp, None, "tp"), P(pp, "tp", None)
     layers = {
         "input_norm": P(pp, None),
-        "q": qkv_spec,
-        "k": qkv_spec,
-        "v": qkv_spec,
-        "o": o_spec,
+        "q": col,
+        "k": col,
+        "v": col,
+        "o": row,
         "post_norm": P(pp, None),
     }
     if cfg.model.attention_bias:
@@ -87,9 +68,9 @@ def param_specs(cfg: Config) -> dict[str, Any]:
         })
     else:
         layers.update({
-            "gate": up_spec,
-            "up": up_spec,
-            "down": down_spec,
+            "gate": col,
+            "up": col,
+            "down": row,
         })
     specs = {
         "embedding": P("tp", None),

@@ -224,34 +224,15 @@ def main(argv=None) -> None:
         pre = preflight(cfg, menv)  # raises ShardcheckError with the report
         log_print(f"shardcheck preflight: ok "
                   f"({len(pre.warnings())} warning(s))")
-        # Surface the sharding-dataflow audit verbatim: an implicit
-        # (GSPMD-minted) reshard or an unproven jit entry is a perf smell
-        # the operator should see at startup, each with the spec fix named
-        # (analysis/dataflow.py, analysis/variants.py).
+        # An unproven jit entry is a perf smell the operator should see at
+        # startup, with the fix named (analysis/variants.py).
         for f in pre.warnings():
-            if f.check in ("provenance", "variants"):
+            if f.check == "variants":
                 log_print(f"shardcheck preflight WARNING: {f.render()}")
-        prov = pre.info.get("provenance", {})
-        if prov.get("sites") is not None:
-            log_print(
-                f"shardflow: {prov['ops_attributed']}/"
-                f"{prov['ops_effective']} collective(s) attributed, "
-                f"{prov['implicit_ops']} implicit, "
-                f"{prov['boundary_reshards']} predicted reshard(s)")
         ts = pre.info.get("variants", {}).get("train_step", {})
         if ts.get("proven"):
             log_print("shardflow: train step proven compile-once "
                       f"({ts['leaves']} abstract leaves, 1 signature)")
-        bnd = pre.info.get("boundary", {})
-        if bnd.get("audited"):
-            # slicecheck (analysis/boundary.py): the preflight raised above
-            # on any ICI-only axis straddling the DCN cut, so reaching
-            # here means every crossing collective is a declared one
-            log_print(f"slicecheck: {bnd['slices']} slices, cut on "
-                      f"[{bnd['cut_axes']}] — {bnd['boundary']} declared "
-                      f"boundary op(s) over [{bnd['dcn_axes']}], "
-                      f"{bnd['intra']} intra-slice, 0 violating "
-                      f"({bnd['dcn_bytes']} B/step across DCN)")
         if cfg.checkpoint.save_frequency > 0:
             # Same fail-fast contract for the checkpoint store: an
             # unwritable save_dir or a disk without headroom for one

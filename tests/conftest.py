@@ -92,19 +92,3 @@ def devices():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected >=8 simulated devices, got {len(devs)}"
     return devs
-
-
-@pytest.fixture(scope="session")
-def slice_partition(devices):
-    """The 8 simulated host devices partitioned into 2 declared 'slices'.
-
-    Host CPUs carry no slice_index, so the partition is positional —
-    devices [0..3] are slice 0, [4..7] slice 1 — matching the house rule
-    (mesh._split_axes_over_dcn) that the slice granule is the OUTER
-    factor of the first DCN-tolerant axis: on the row-major
-    (dp, pp, ep, cp, tp) grid, the outer half of the leading cut axis is
-    exactly the first four flat device ids. The slice-boundary tests
-    (tests/test_boundary.py) audit traced replica groups against this
-    partition via analysis.boundary.SliceTopology."""
-    n = len(devices)
-    return {0: tuple(range(n // 2)), 1: tuple(range(n // 2, n))}

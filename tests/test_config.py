@@ -141,3 +141,62 @@ def test_serve_config_from_dict_round_trip():
     cfg2 = config_from_dict({"model": {"name": "debug-tiny"},
                              "serve": {"decode_slots": 2, "bogus": 1}})
     assert cfg2.serve.decode_slots == 2
+
+
+# ---------------------------------------------------------------------------
+# retired options fail loudly (unknown keys are otherwise ignored on load)
+# ---------------------------------------------------------------------------
+
+_RETIRED = {  # key: (former default, a value that used to select other code)
+    "tp_strategy": ("megatron", "2d"),
+    "tp_sync": ("sync", "deferred"),
+    "tp_mesh": ("", "2x2"),
+    "dcn_axes": ("dp,pp", "dp"),
+    "hier_dp_reduce": ("auto", "on"),
+}
+
+
+@pytest.mark.parametrize("how", ["former-default", "other-value"])
+@pytest.mark.parametrize("key", sorted(_RETIRED))
+def test_retired_option(key, how):
+    former, other = _RETIRED[key]
+    raw = {"distributed": {"tp_size": 2, "dp_size": 2,
+                           key: former if how == "former-default" else other}}
+    if how == "former-default":
+        # a dumped config of an old run: loads, and the key is dropped
+        cfg = config_from_dict(raw)
+        assert cfg.distributed.tp_size == 2
+        assert key not in cfg.to_json_dict()["distributed"]
+    else:
+        with pytest.raises(ValueError, match=f"distributed.{key}.*removed"):
+            config_from_dict(raw)
+
+
+@pytest.mark.parametrize("spelling", ['distributed.tp_sync="deferred"',
+                                      "distributed.tp_sync=deferred"])
+def test_retired_option_through_cli_override(tmp_path, monkeypatch, spelling):
+    """tools/memcheck.py --override is the CLI's way to set a knob: a
+    retired one must stop the run, not measure the schedule that remains.
+    JSON-quoted it reaches the loader, which names the removal; bare it is
+    refused earlier, as any string for a field that does not exist."""
+    from tests.test_tools import load_tool
+
+    path = tmp_path / "cfg.json"
+    path.write_text(json.dumps({"distributed": {"tp_size": 2}}))
+    monkeypatch.setattr("sys.argv", ["memcheck.py", "--config", str(path),
+                                     "--override", spelling])
+    with pytest.raises((ValueError, SystemExit), match="tp_sync") as exc:
+        load_tool("memcheck").main()
+    if '"' in spelling:
+        assert "removed" in str(exc.value)
+
+
+def test_slices_must_divide_dp_times_pp():
+    # mesh._split_axes_over_dcn's rule, refused at load: ep/cp/tp
+    # collectives never cross the slice cut
+    ok = config_from_dict({"distributed": {"dp_size": 2, "tp_size": 2,
+                                           "slices": 2}})
+    assert ok.distributed.slices == 2
+    with pytest.raises(ValueError, match="slices"):
+        config_from_dict({"distributed": {"dp_size": 2, "tp_size": 2,
+                                          "slices": 3}})

@@ -359,3 +359,27 @@ def test_audit_collectives_cost_info():
     assert pc["total_ms"] > 0
     assert math.isfinite(pc["total_ms"])
     assert pc["by_kind_ms"].get("all_reduce", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# the megatron schedule's price, pinned across the removal of the other
+# TP strategies' pricing (values printed by the tree at cecb420)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dist,total_s,exposed_comm_s,label", [
+    (dict(tp_size=2), 0.9557416907686728, 0.14354957653333333,
+     "dp1xtp2xpp1xcp1xep1"),
+    (dict(tp_size=4), 0.6219964219176697, 0.2159003648,
+     "dp1xtp4xpp1xcp1xep1"),
+    (dict(tp_size=4, sequence_parallel=True), 0.6231484219176697,
+     0.2170523648, "dp1xtp4xpp1xcp1xep1+sp"),
+    (dict(tp_size=2, pp_size=2), 0.6824141820320602, 0.07327009635555555,
+     "dp1xtp2xpp2xcp1xep1"),
+], ids=["tp2", "tp4", "tp4+sp", "tp2+pp2"])
+def test_megatron_prediction_unmoved(dist, total_s, exposed_comm_s, label):
+    cost = CostModel("v5e").predict(mkcfg(
+        model="HuggingFaceTB/SmolLM-1.7B", seq=2048, mbs=2, ga=4, dist=dist))
+    assert cost.total_s == pytest.approx(total_s, rel=1e-12)
+    assert cost.exposed_comm_s == pytest.approx(exposed_comm_s, rel=1e-12)
+    assert cost.config_label == label

@@ -94,8 +94,7 @@ def test_topology_records_and_compares_slices():
     compared with a default of 1 so pre-slices checkpoints read as
     single-slice — and it never multiplies into world_size."""
     multi = elastic.topology_from_distributed(
-        DistributedConfig(dp_size=2, tp_size=2, cp_size=2,
-                          slices=2, dcn_axes="dp"))
+        DistributedConfig(dp_size=2, tp_size=2, cp_size=2, slices=2))
     assert multi["slices"] == 2
     assert multi["world_size"] == 8  # slices partition the axes, not x2
     assert elastic.describe_topology(multi) == \
@@ -480,8 +479,7 @@ def test_elastic_resize_tool_restamps_slices(tmp_path):
     with the store untouched; --slices 1 re-stamps it single-slice as
     pure placement metadata — dp and the batch plan untouched — and the
     step re-verifies."""
-    cfg_a = make_cfg(tmp_path, dp_size=2, tp_size=2, mbs=2, ga=1,
-                     slices=2, dcn_axes="dp")
+    cfg_a = make_cfg(tmp_path, dp_size=2, tp_size=2, mbs=2, ga=1, slices=2)
     _save_step(cfg_a)
     save_dir = cfg_a.checkpoint.save_dir
     [step_dir] = [os.path.join(save_dir, d) for d in os.listdir(save_dir)
@@ -518,8 +516,7 @@ def test_elastic_resize_tool_restamps_slices(tmp_path):
 def test_ckpt_doctor_reports_source_topology(tmp_path, capsys):
     import importlib.util
 
-    cfg = make_cfg(tmp_path, dp_size=2, tp_size=2, slices=2,
-                   dcn_axes="dp")
+    cfg = make_cfg(tmp_path, dp_size=2, tp_size=2, slices=2)
     _save_step(cfg)
     spec = importlib.util.spec_from_file_location(
         "ckpt_doctor_topo", os.path.join(os.path.dirname(__file__), "..",

@@ -163,7 +163,18 @@ def test_parity_dense_dp():
     ("ad", {"pp_size": 2, "pp_engine": "1f1b"}),
     ("ad", {"pp_size": 2, "pp_engine": "afab"}),
     ("ad", {"dp_size": 2, "pp_size": 2, "pp_engine": "1f1b"}),
-], ids=["dp2-ad", "dp2-fused", "pp2-1f1b", "pp2-afab", "dp2pp2-1f1b"])
+    # the tp layouts the cells and the engines run: engine-vs-engine
+    # parity is blind to a fault in f/g that both engines share
+    ("ad", {"tp_size": 2}),
+    ("fused", {"tp_size": 2}),
+    ("ad", {"tp_size": 4}),
+    ("ad", {"tp_size": 2, "sequence_parallel": True}),
+    ("fused", {"tp_size": 2, "sequence_parallel": True}),
+    ("fused", {"dp_size": 2, "tp_size": 2}),
+    ("ad", {"tp_size": 2, "pp_size": 2, "pp_engine": "1f1b"}),
+], ids=["dp2-ad", "dp2-fused", "pp2-1f1b", "pp2-afab", "dp2pp2-1f1b",
+        "tp2-ad", "tp2-fused", "tp4-ad", "tp2sp-ad", "tp2sp-fused",
+        "dp2tp2-fused", "tp2pp2-1f1b"])
 def test_grads_equal_single_device(engine, dk):
     """The gradient a layout hands the optimizer IS the single-device
     gradient of the same global batch — not a multiple of it. Engine-vs-
@@ -185,14 +196,12 @@ def test_grads_equal_single_device(engine, dk):
             err_msg=jax.tree_util.keystr(path))
 
 
-@pytest.mark.slow
 def test_parity_tp_vocab_parallel():
     # tp=2 exercises the ctx.f/g hook transposes and the vocab-parallel CE
     # inside the segment VJPs
     assert_engines_match(dk={"dp_size": 2, "tp_size": 2})
 
 
-@pytest.mark.slow
 def test_parity_qwen_bias_tied():
     # qkv bias leaves + tied embeddings (head grads flow into the
     # embedding leaf through head_weight's transpose)
@@ -200,12 +209,10 @@ def test_parity_qwen_bias_tied():
                                  tie_word_embeddings=True))
 
 
-@pytest.mark.slow
 def test_parity_sdpa_path():
     assert_engines_match(mk=dict(attn_impl="reference"))
 
 
-@pytest.mark.slow
 def test_parity_without_offload():
     # the engine is independent of where the optimizer state lives
     assert_engines_match(optimizer_offload=False)
@@ -279,13 +286,11 @@ def test_grads_parity_moe_ep():
                        mk={"num_experts": 4, "num_experts_per_token": 2})
 
 
-@pytest.mark.slow
 def test_grads_parity_cp2_ring_contiguous():
     assert_grads_match(dk={"dp_size": 2, "cp_size": 2,
                            "cp_layout": "contiguous"})
 
 
-@pytest.mark.slow
 def test_grads_parity_moe_capacity_drops():
     # a tight capacity bound forces real drops: the drop statistic must
     # ride the fused path into extras identically, and dropped tokens'
@@ -301,7 +306,6 @@ def test_grads_parity_moe_capacity_drops():
     assert extras["moe_drop_frac"] > 0.0
 
 
-@pytest.mark.slow
 def test_grads_parity_sp_qwen_bias_tied():
     assert_grads_match(dk={"dp_size": 2, "tp_size": 2,
                            "sequence_parallel": True},
@@ -309,7 +313,6 @@ def test_grads_parity_sp_qwen_bias_tied():
                            "tie_word_embeddings": True})
 
 
-@pytest.mark.slow
 def test_parity_sequence_parallel_e2e():
     # full bf16 + offload steps through the optimizer (conventions of the
     # dense e2e tests above)
@@ -317,24 +320,20 @@ def test_parity_sequence_parallel_e2e():
                              "sequence_parallel": True})
 
 
-@pytest.mark.slow
 def test_parity_cp4_ring_e2e():
     assert_engines_match(dk={"dp_size": 2, "cp_size": 4})
 
 
-@pytest.mark.slow
 def test_parity_cp2_ulysses_e2e():
     assert_engines_match(dk={"dp_size": 2, "cp_size": 2},
                          mk={"attn_impl": "ulysses"})
 
 
-@pytest.mark.slow
 def test_parity_moe_ep_e2e():
     assert_engines_match(dk={"dp_size": 2, "ep_size": 2},
                          mk={"num_experts": 4, "num_experts_per_token": 2})
 
 
-@pytest.mark.slow
 def test_grad_clip_parity():
     # the global-norm clip consumes the accumulated grads — same totals,
     # same clip scale, regardless of engine

@@ -75,30 +75,10 @@ def test_invalid_layouts_are_skipped():
     assert all(c.distributed.pp_size <= 4 for c in cands)
 
 
-def test_plan_enumerates_mpmd_and_overrides_round_trip():
-    """Every pp>1 layout is priced under both executors (plus interleaved
-    variants where v divides the per-group layer slots), and an mpmd plan
-    point's --override line survives the tools/memcheck.py override
-    mechanism: dotted paths into the raw JSON, bare strings for
-    string-typed fields like pipeline.executor."""
-    from picotron_tpu.config import config_from_dict
-
-    pts = plan(tiny_base(), 8, CostModel("v5e"))
-    mpmd_pts = [p for p in pts if "mpmd" in p.label]
-    assert mpmd_pts, [p.label for p in pts]
-    assert any("interleaved" in p.label for p in mpmd_pts)
-    assert any("mpmd-1f1b" in p.label for p in mpmd_pts)
-
-    point = next(p for p in mpmd_pts if "interleaved" in p.label)
-    line = point.overrides_line()
-    assert "pipeline.executor=mpmd" in line
-    assert "pipeline.schedule=interleaved" in line
-
-    # the memcheck --override application: JSON values where they parse,
-    # bare strings otherwise (legitimate only for string-typed fields)
-    raw = {"model": {"name": "debug-tiny"},
-           "training": {"seq_length": 64, "micro_batch_size": 1,
-                        "gradient_accumulation_steps": 8}}
+def _apply_overrides(line, raw):
+    """The tools/memcheck.py --override application: dotted paths into the
+    raw JSON, JSON values where they parse, bare strings otherwise
+    (legitimate only for string-typed fields)."""
     for ov in line.split()[1:]:
         dotted, _, val = ov.partition("=")
         node = raw
@@ -109,11 +89,51 @@ def test_plan_enumerates_mpmd_and_overrides_round_trip():
             node[key] = json.loads(val)
         except ValueError:
             node[key] = val
-    cfg = config_from_dict(raw)  # validates
-    assert cfg.pipeline.executor == "mpmd"
-    assert cfg.pipeline.schedule == "interleaved"
-    assert cfg.pipeline.interleave >= 2
-    assert cfg.distributed.pp_size == point.cfg.distributed.pp_size
+    return raw
+
+
+def test_plan_enumerates_mpmd():
+    """Every pp>1 layout is priced under both executors (plus interleaved
+    variants where v divides the per-group layer slots)."""
+    pts = plan(tiny_base(), 8, CostModel("v5e"))
+    mpmd_pts = [p for p in pts if "mpmd" in p.label]
+    assert mpmd_pts, [p.label for p in pts]
+    assert any("interleaved" in p.label for p in mpmd_pts)
+    assert any("mpmd-1f1b" in p.label for p in mpmd_pts)
+
+
+@pytest.mark.parametrize("want", ["mpmd-interleaved", "+sp", "mesh-",
+                                  "ulysses"])
+def test_overrides_line_round_trips(want):
+    """A plan point's --override line, applied the way tools/memcheck.py
+    applies it, rebuilds the point's layout: every axis the planner
+    enumerates is on the line."""
+    from picotron_tpu.config import config_from_dict
+
+    point = next(p for p in plan(tiny_base(), 8, CostModel("v5e"))
+                 if want in p.label)
+    cfg = config_from_dict(_apply_overrides(
+        point.overrides_line(),
+        {"model": {"name": "debug-tiny"},
+         "training": {"seq_length": 64, "micro_batch_size": 1,
+                      "gradient_accumulation_steps": 8}}))  # validates
+    assert cfg.distributed == point.cfg.distributed
+    assert cfg.pipeline == point.cfg.pipeline
+    assert cfg.training.optimizer_offload == \
+        point.cfg.training.optimizer_offload
+
+
+@pytest.mark.parametrize("model,flags,n", [
+    ("debug-tiny", True, 111), ("debug-tiny", False, 15),
+    ("debug-tiny-moe", True, 142),
+])
+def test_candidate_count_8_chips(model, flags, n):
+    """The layout space for 8 chips: the five parallel axes, and with
+    flags the sp / zero1 / offload toggles, the pipeline executors and the
+    cp flavors — no other axis. (The parent enumerated the same points
+    beside its TP-strategy axes: 111 of its 131 for debug-tiny.)"""
+    assert len(candidate_configs(tiny_base(model=model), 8,
+                                 flags=flags)) == n
 
 
 def test_plan_ranks_and_is_deterministic():

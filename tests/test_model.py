@@ -1,3 +1,5 @@
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,6 +8,7 @@ import pytest
 from picotron_tpu.config import Config, ModelConfig, TrainingConfig
 from picotron_tpu.models.llama import (
     DEFAULT_CTX,
+    ParallelCtx,
     forward,
     init_params,
     loss_fn,
@@ -497,3 +500,59 @@ def test_gelu_hidden_act_changes_mlp_and_matches_reference():
     o_g = _swiglu_experts(slots, wg, wu, wd,
                           act=mlp_act(cfg_g))
     assert not np.allclose(np.asarray(o_s), np.asarray(o_g))
+
+
+# ---------------------------------------------------------------------------
+# every ParallelCtx hook is installed by a layout that runs
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def installed_ctx_fields():
+    """field name -> the layouts whose make_parallel_ctx sets it to
+    something other than the single-device default. The layouts are
+    tools/shardcheck.py's preset matrix plus the one hook no preset
+    reaches (uneven pipeline stages: 5 layers on pp 2)."""
+    from jax.sharding import PartitionSpec as P
+
+    from picotron_tpu import compat
+    from picotron_tpu.mesh import MeshEnv
+    from picotron_tpu.parallel.api import make_parallel_ctx
+    from tests.test_tools import load_tool
+
+    sc = load_tool("shardcheck")
+    layouts = {name: sc.preset_config(name) for name in sc.PRESETS}
+    uneven = sc.preset_config("tiny-dense-pp")
+    layouts["uneven-pp"] = dataclasses.replace(
+        uneven, model=dataclasses.replace(uneven.model, num_hidden_layers=5))
+    installed = {}
+    for name, cfg in layouts.items():
+        cfg.validate()
+        box = []
+
+        def body(cfg=cfg, box=box):
+            box.append(make_parallel_ctx(cfg))
+            return jnp.zeros(())
+
+        jax.eval_shape(compat.shard_map(
+            body, mesh=MeshEnv.from_config(cfg).mesh, in_specs=(),
+            out_specs=P()))
+        for f in dataclasses.fields(box[0]):
+            got, default = getattr(box[0], f.name), getattr(DEFAULT_CTX, f.name)
+            same = got is default if callable(default) or default is None \
+                else got == default
+            if not same:
+                installed.setdefault(f.name, []).append(name)
+    return installed
+
+
+@pytest.mark.parametrize(
+    "field", [f.name for f in dataclasses.fields(ParallelCtx)])
+def test_every_parallel_ctx_hook_is_installed_by_some_layout(
+        installed_ctx_fields, field):
+    """A field of ParallelCtx that no running layout sets is a branch in
+    the model's only block that nothing exercises: the guard against the
+    next hook that only a dead path installs."""
+    assert installed_ctx_fields.get(field), (
+        f"ParallelCtx.{field} keeps its single-device default under every "
+        f"layout: delete the hook or add the layout that installs it")

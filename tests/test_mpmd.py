@@ -437,3 +437,58 @@ def test_watchdog_beat_names_live_schedule_op():
         assert step == 3
     finally:
         w.stop()
+
+
+# ---------------------------------------------------------------------------
+# the static schedule-table lint
+# ---------------------------------------------------------------------------
+
+
+def test_lint_schedule_clean_tables():
+    from picotron_tpu.parallel.mpmd import SCHEDULES, build_schedule
+
+    # build_schedule lints at construction (raises ScheduleBufferError on
+    # failure) — a representative sweep must come back clean
+    for kind in SCHEDULES:
+        for pp in (2, 4, 8):
+            for n in (2, 8, 16):
+                for v in (1, 2) if kind == "interleaved" else (1,):
+                    build_schedule(kind, n, pp, v)
+
+
+def test_lint_schedule_catches_truncated_table():
+    from picotron_tpu.parallel.mpmd import build_schedule, lint_schedule
+
+    table = build_schedule("1f1b", 4, 4, 1)
+    truncated = [op for op in table if not (op.op == "B" and op.mb == 3)]
+    problems = lint_schedule(truncated, 4, 4, 1, kind="1f1b")
+    assert problems and any("never consumed" in p for p in problems)
+
+
+def test_lint_schedule_catches_missing_producer():
+    from picotron_tpu.parallel.mpmd import build_schedule, lint_schedule
+
+    table = build_schedule("1f1b", 4, 4, 1)
+    dropped = [op for op in table
+               if not (op.op == "F" and op.mb == 2 and op.vstage == 1)]
+    problems = lint_schedule(dropped, 4, 4, 1, kind="1f1b")
+    assert problems and any("never produced" in p for p in problems)
+
+
+def test_lint_schedule_catches_unbounded_live_set():
+    from picotron_tpu.parallel.mpmd import build_schedule, lint_schedule
+
+    # a gpipe table (save-everything) presented as 1f1b blows the
+    # in-flight budget: backwards deferred past the pipeline depth
+    table = build_schedule("gpipe", 16, 4, 1)
+    problems = lint_schedule(table, 16, 4, 1, kind="1f1b")
+    assert any("in-flight budget" in p for p in problems)
+
+
+def test_build_schedule_raises_on_linted_table(monkeypatch):
+    import picotron_tpu.parallel.mpmd as mpmd
+
+    monkeypatch.setattr(mpmd, "lint_schedule",
+                        lambda *a, **k: ["planted problem"])
+    with pytest.raises(mpmd.ScheduleBufferError, match="static lint"):
+        mpmd.build_schedule("1f1b", 4, 2, 1)

@@ -56,7 +56,6 @@ def run_engine(cfg, steps=3):
     dict(pp=2, gas=4, tp=2),
     dict(pp=2, gas=3, remat=True),  # odd n_micro + remat'd tick bodies
 ])
-@pytest.mark.slow
 def test_1f1b_matches_afab(layout):
     """The two engines compute the same gradients (same math, different
     schedule); only fp reduction order differs."""
@@ -81,7 +80,6 @@ def _compiled_temp_bytes(cfg):
     return stats.temp_size_in_bytes
 
 
-@pytest.mark.slow
 def test_1f1b_memory_bound():
     """1F1B's live activation set is <= pp microbatches (ring buffer);
     AFAB's grows with n_micro (per-tick scan residuals). With activations
@@ -126,7 +124,6 @@ def test_1f1b_tick_count_and_schedule_rate():
     assert old_ticks not in lengths, lengths
 
 
-@pytest.mark.slow
 def test_afab_remat_policy_reaches_pipeline_tick():
     """remat_policy must change what the AFAB tick scan saves (VERDICT r1:
     the pp path used to blanket-full-remat regardless of policy)."""

@@ -796,7 +796,7 @@ def test_chaos_dp_resize_scenario(tmp_path):
 @pytest.mark.slow
 def test_chaos_slice_lost_scenario(tmp_path):
     """Whole-slice loss, the full multi-process scenario: a 2-slice run
-    with the hierarchical dp reduction live is killed by slice_lost@3,
+    is killed by slice_lost@3,
     the store is re-stamped single-slice offline (--slices 1), and the
     surviving chips finish at dp=1 via checkpoint.elastic. run_slice_lost
     itself asserts the slice-naming log line, the manifest slice counts

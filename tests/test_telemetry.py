@@ -849,3 +849,29 @@ def test_extract_metrics_falls_back_to_log_when_jsonl_empty(tmp_path):
         "| MFU: 10.00% | tokens: 10K | mem: 1.0GB\n")
     stats = em.process_run(str(run), skip_steps=3)
     assert stats["final_loss"] == 4.5
+
+
+def test_cli_telemetry_comm_row(tmp_path, capsys):
+    """`--config` adds the cost model's exposed-comm prediction for the
+    run's layout beside the measured phases (tools/telemetry_report.py
+    comm_row), from a config tools/create_config.py wrote."""
+    from tests.test_tools import load_tool
+
+    cc = load_tool("create_config")
+    args = cc.build_parser().parse_args(
+        ["--exp-name", "t", "--out-dir", str(tmp_path), "--model",
+         "SmolLM-1.7B", "--dp", "2", "--tp", "4", "--seq-len", "2048",
+         "--use-cpu"])
+    cfg_path = cc.create_single_config(args)
+    capsys.readouterr()
+
+    tele = tmp_path / "telemetry.jsonl"
+    tele.write_text(json.dumps(
+        {"kind": "phase", "phase": "step", "step": 0,
+         "category": "compute", "secs": 0.5, "ts": 1.0}) + "\n")
+    assert load_report().main([str(tele), "--config", cfg_path,
+                               "--json"]) == 0
+    cm = json.loads(capsys.readouterr().out)["comm"]
+    assert cm["generation"] == "v5e"
+    assert 0 < cm["predicted_comm_ms"] < cm["predicted_step_ms"]
+    assert cm["measured_step_p50_ms"] == 500.0

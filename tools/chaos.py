@@ -35,8 +35,7 @@ Scenarios (the runtime-failure matrix README "Fault tolerance" documents):
                 mesh via checkpoint.elastic — same loss-parity /
                 resize-booking bar as dp_resize, plus the PR-9 prover
                 pins every rebuilt stage program compiles exactly once
-  slice_lost    whole-slice loss on a 2-slice job running the
-                hierarchical dp gradient reduction: slice_lost@3 kills
+  slice_lost    whole-slice loss on a 2-slice job: slice_lost@3 kills
                 the pod with the lost slice named in the log, the store
                 is re-stamped single-slice offline (tools/
                 elastic_resize.py --slices 1), and the surviving chips
@@ -670,10 +669,10 @@ def run_slice_lost(workdir: str, verbose: bool = False) -> bool:
     CLI step), registered next to SCENARIOS:
 
       baseline  dp=2 tp=2, single slice, fault-free, steps 1-6
-      leg 1     dp=2 tp=2 slices=2 dcn_axes=dp — the hierarchical dp
-                gradient reduction is live — slice_lost@3: SIGKILL with
-                the slice named in the log; the sync save @2 is durable
-                and records slices=2 in its manifest topology
+      leg 1     dp=2 tp=2 slices=2 (dp carries the slice granule) —
+                slice_lost@3: SIGKILL with the slice named in the log;
+                the sync save @2 is durable and records slices=2 in its
+                manifest topology
       re-stamp  tools/elastic_resize.py --slices 1 rewrites the store as
                 single-slice (placement metadata only; dp untouched)
       leg 2     dp=1 tp=2 (one surviving slice's worth of chips) with
@@ -682,10 +681,7 @@ def run_slice_lost(workdir: str, verbose: bool = False) -> bool:
                 to the `resize` goodput category, and trains to done
 
     Final step/tokens and the per-step loss trajectory must match the
-    fault-free baseline — fp32 reduction order is the only legitimate
-    difference (the hierarchical schedule reassociates the dp sum; the
-    documented ~1e-7 band of parallel/hier_reduce.py sits far inside the
-    rtol=1e-3 house tolerance)."""
+    fault-free baseline at the rtol=1e-3 house tolerance."""
     import numpy as np
 
     from picotron_tpu.resilience import elastic
@@ -700,8 +696,6 @@ def run_slice_lost(workdir: str, verbose: bool = False) -> bool:
                               {"checkpoint": {"async_save": False}})
         cfg["distributed"]["dp_size"] = dp
         cfg["distributed"]["slices"] = slices
-        if slices > 1:
-            cfg["distributed"]["dcn_axes"] = "dp"
         cfg["training"]["micro_batch_size"] = mbs
         cfg["training"]["gradient_accumulation_steps"] = ga
         cfg["checkpoint"]["save_dir"] = ckpt_dir
@@ -748,8 +742,8 @@ def run_slice_lost(workdir: str, verbose: bool = False) -> bool:
     os.makedirs(fault_dir, exist_ok=True)
     ckpt_dir = os.path.join(fault_dir, "ckpt")
 
-    # Leg 1: 2-slice run with the hierarchical dp reduction live, a
-    # whole slice lost at step-3 begin; the sync save @2 is durable.
+    # Leg 1: 2-slice run, a whole slice lost at step-3 begin; the sync
+    # save @2 is durable.
     rc = run_leg(leg_config(ckpt_dir, dp=2, mbs=2, ga=1, slices=2,
                             chaos_spec=f"slice_lost@{STEPS // 2}"),
                  "config_slices2.json", fault_dir)
@@ -1198,9 +1192,9 @@ CUSTOM_SCENARIOS: dict[str, tuple[Callable, str]] = {
                   "vs the pp=2 baseline, resize booked, rebuilt stage "
                   "programs proven compile-once"),
     "slice_lost": (run_slice_lost,
-                   "whole-slice loss on a 2-slice job (hierarchical dp "
-                   "grads live): slice_lost@3 SIGKILLs with the slice "
-                   "named, tools/elastic_resize.py --slices 1 re-stamps "
+                   "whole-slice loss on a 2-slice job: slice_lost@3 "
+                   "SIGKILLs with the slice named, "
+                   "tools/elastic_resize.py --slices 1 re-stamps "
                    "the store, the survivors finish at dp=1 via "
                    "checkpoint.elastic; loss parity vs the single-slice "
                    "baseline, resize booked"),

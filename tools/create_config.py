@@ -50,34 +50,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--sequence-parallel", action="store_true",
                    help="Megatron-SP over the tp axis (seq-sharded "
                         "residual stream between blocks)")
-    p.add_argument("--tp-strategy", default=None,
-                   choices=["megatron", "row", "2d", "adaptive"],
-                   help="per-layer TP partitioning (default: megatron "
-                        "column-first; 'adaptive' asks the cost model per "
-                        "layer class; per-class specs like "
-                        "'qkv=2d,up=col' go straight in config.json)")
-    p.add_argument("--tp-mesh", default=None, metavar="XxY",
-                   help="2D-strategy factorization tp = tp_x * tp_y, e.g. "
-                        "'2x2' (default: most-square feasible split; tp_x "
-                        "must divide the head counts)")
-    p.add_argument("--tp-sync", default=None,
-                   choices=["sync", "deferred"],
-                   help="TP activation sync schedule: 'deferred' replaces "
-                        "the row-parallel exit all_reduce with a "
-                        "reduce_scatter whose gather half is hoisted into "
-                        "the next block (megatron strategy, pp=1, dense)")
     p.add_argument("--zero1", action="store_true",
                    help="ZeRO-1: shard Adam moments over dp")
     p.add_argument("--slices", type=int, default=None,
                    help="multislice topology: the pod spans N TPU slices "
-                        "joined by DCN; train.py's slicecheck preflight "
-                        "then audits every collective against the cut "
-                        "(analysis/boundary.py)")
-    p.add_argument("--dcn-axes", default=None, metavar="AXES",
-                   help="comma-separated mesh axes allowed to cross the "
-                        "DCN boundary with --slices > 1 (subset of "
-                        "dp,pp; default dp,pp — pick with "
-                        "tools/layout_planner.py --slices)")
+                        "joined by DCN; dp then pp absorb the slice "
+                        "granules (mesh.py)")
     # model
     p.add_argument("--model", default="HuggingFaceTB/SmolLM-1.7B")
     p.add_argument("--from-hf-config", default=None, metavar="CONFIG_JSON",
@@ -228,11 +206,7 @@ def create_single_config(args) -> str:
             "use_cpu": args.use_cpu,
             **({"cp_flavor": args.cp_flavor} if args.cp_flavor else {}),
             **({"cp_mesh": args.cp_mesh} if args.cp_mesh else {}),
-            **({"tp_strategy": args.tp_strategy} if args.tp_strategy else {}),
-            **({"tp_mesh": args.tp_mesh} if args.tp_mesh else {}),
-            **({"tp_sync": args.tp_sync} if args.tp_sync else {}),
             **({"slices": args.slices} if args.slices else {}),
-            **({"dcn_axes": args.dcn_axes} if args.dcn_axes else {}),
         },
         "model": {
             "name": args.model, **preset, **model_overrides,

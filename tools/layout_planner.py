@@ -113,14 +113,6 @@ def main(argv=None) -> int:
                          "HBM capacity")
     ap.add_argument("--hbm-gib", type=float, default=None,
                     help="override the generation's per-chip HBM capacity")
-    ap.add_argument("--slices", type=int, default=None, metavar="N",
-                    help="multislice planning: after ranking, price the "
-                         "winner's layout split over N slices — one row "
-                         "per DCN-tolerant axis (dp/pp) that can absorb "
-                         "the slice count, with the intra-slice ICI and "
-                         "cross-slice DCN tiers of the hierarchical "
-                         "decomposition priced separately "
-                         "(analysis/planner.slice_plans)")
     ap.add_argument("--no-flags", action="store_true",
                     help="search only the 5 parallel axes (skip sp/zero1/"
                          "offload toggles)")
@@ -147,15 +139,6 @@ def main(argv=None) -> int:
     ap.add_argument("--cp-degrees", type=int, nargs="*", default=None,
                     metavar="CP", help="cp degrees to sweep with "
                          "--cp-crossover (default 2 4 8 16 32)")
-    ap.add_argument("--tp-strategy-table", action="store_true",
-                    help="instead of planning, sweep tp degree and print "
-                         "each TP strategy x sync-mode's predicted step "
-                         "and exposed-comm time per ICI generation, with "
-                         "the best 2D factorization and the adaptive "
-                         "resolution per degree")
-    ap.add_argument("--tp-degrees", type=int, nargs="*", default=None,
-                    metavar="TP", help="tp degrees to sweep with "
-                         "--tp-strategy-table (default 2 4 8 16)")
     ap.add_argument("--validate-sweep", action="store_true",
                     help="score the cost model's rank agreement against "
                          "the measured SWEEP_r03-r04 rows instead of "
@@ -230,36 +213,6 @@ def main(argv=None) -> int:
                      "never (within swept degrees)"))
         return 0
 
-    if args.tp_strategy_table:
-        from picotron_tpu.analysis.cost_model import (
-            GENERATIONS, tp_strategy_table,
-        )
-
-        base = build_base_config(args)
-        degrees = tuple(args.tp_degrees or (2, 4, 8, 16))
-        out = [(gen, tp_strategy_table(CostModel(gen), base, degrees))
-               for gen in GENERATIONS]
-        if args.json:
-            for gen, rows in out:
-                print(json.dumps({"generation": gen, "rows": rows}),
-                      flush=True)
-            return 0
-        print(f"TP strategy table: {base.model.name} seq "
-              f"{base.training.seq_length} ('-' = strategy infeasible at "
-              f"that degree; exposed_ms deltas vs megatron-sync)")
-        hdr = ("gen", "tp", "megatron_ms", "deferred_ms", "row_ms",
-               "2d_ms", "2d_mesh", "defer_dexp", "adaptive", "winner")
-        print("  " + "  ".join(h.rjust(11) for h in hdr))
-        for gen, rows in out:
-            for r in rows:
-                cells = (gen, r["tp"], r["megatron_ms"], r["deferred_ms"],
-                         r["row_ms"], r.get("2d_ms", "-"),
-                         r.get("mesh_factorization", "-"),
-                         r["deferred_exposed_delta_ms"],
-                         r["adaptive"], r["winner"])
-                print("  " + "  ".join(str(c).rjust(11) for c in cells))
-        return 0
-
     if not args.chips:
         ap.error("--chips is required (or use --validate-sweep)")
 
@@ -294,18 +247,9 @@ def main(argv=None) -> int:
               "or shrink the model/batch", file=sys.stderr)
         return 1
 
-    slice_rows = []
-    if args.slices and args.slices > 1:
-        from picotron_tpu.analysis.planner import slice_plans
-
-        slice_rows = slice_plans(winner.cfg, model, args.slices)
-
     if args.json:
         for p in points[:args.top]:
             print(json.dumps(p.as_dict()), flush=True)
-        if args.slices and args.slices > 1:
-            print(json.dumps({"slice_plans": slice_rows,
-                              "winner": winner.label}), flush=True)
     else:
         n_all = len(points)
         print(f"layout planner: {base.model.name} seq "
@@ -319,29 +263,6 @@ def main(argv=None) -> int:
               f"{winner.cost.as_dict()['tokens_per_sec_per_chip']} "
               f"tok/s/chip)")
         print(f"  run it: {winner.overrides_line()}")
-        if args.slices and args.slices > 1:
-            print()
-            if not slice_rows:
-                print(f"slice planning: no DCN-tolerant axis of "
-                      f"{winner.label} can absorb {args.slices} slices "
-                      f"(dp and pp must be divisible by the slice count)")
-            else:
-                print(f"slice planning: {winner.label} over "
-                      f"{args.slices} slices "
-                      f"[{slice_rows[0]['generation']}]:")
-                hdr = ("axis", "crossing_terms", "dcn_bytes", "dcn_ms",
-                       "ici_ms", "total_comm_ms")
-                print("  " + "  ".join(h.rjust(14) for h in hdr))
-                for r in slice_rows:
-                    cells = (r["axis"],
-                             ",".join(r["crossing_terms"]) or "-",
-                             r["dcn_bytes"], r["dcn_ms"], r["ici_ms"],
-                             r["total_comm_ms"])
-                    print("  " + "  ".join(str(c).rjust(14)
-                                           for c in cells))
-                best_ax = slice_rows[0]["axis"]
-                print(f"  declare it: --override distributed.slices="
-                      f"{args.slices} distributed.dcn_axes={best_ax}")
     return 0
 
 

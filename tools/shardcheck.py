@@ -12,19 +12,9 @@ configs by abstract evaluation on simulated host devices:
 - donation + recompilation hazards: every TrainState buffer donated; the
   step's output avals identical to its inputs (anything else recompiles
   every step)
-- sharding-dataflow audit (--provenance): attribute every lowered
-  collective to the source line + state/batch paths that minted it,
-  classify each as intended (schedule contract) or implicit
-  (GSPMD-minted reshard), and predict boundary reshards with the spec
-  fix named
 - jit-variant prover (--variants): statically enumerate the abstract
   signatures (shape/dtype/sharding/commitment) reaching each jit entry
   point — train step, serve prefill/decode — and prove compile-once
-- slice-boundary audit (--slices N, "slicecheck"): map every lowered
-  replica group onto the declared multislice partition and classify it
-  intra-slice / boundary / VIOLATING — an ICI-only axis (tp/cp/ep)
-  straddling the DCN cut is a named error, and the per-tier byte totals
-  are priced by the cost model's dcn tier under --cost
 - source lint: no semi-private jax.core, no host callbacks in library
   code, no uncommitted jax.device_put
 
@@ -33,8 +23,7 @@ Usage:
   python tools/shardcheck.py --config runs/smollm17-dp8/config.json
   python tools/shardcheck.py --preset tiny-dense --preset tiny-moe-ep
   python tools/shardcheck.py --all-presets --verbose
-  python tools/shardcheck.py --all-presets --provenance --variants --json
-  python tools/shardcheck.py --preset tiny-dense --slices 2 --dcn-axes dp
+  python tools/shardcheck.py --all-presets --variants --json
 
 --json emits one machine-readable line per config for every subcommand
 (findings + the per-check info dict); a config that cannot trace at all
@@ -108,56 +97,6 @@ PRESETS: dict[str, tuple] = {
                             dict(gradient_accumulation_steps=2,
                                  grad_engine="fused",
                                  remat_policy="dots_attn")),
-    # deferred activation sync (parallel/tp_strategies.py): the audit must
-    # see the block-exit reduce-scatter AND the gather hoisted into the
-    # next block's entry over tp — WITHOUT sequence_parallel set — on both
-    # grad engines (collectives.py deferred presence rule), and the
-    # provenance audit must attribute every tp collective (no implicit
-    # GSPMD reshard from the seq-sharded residual stream)
-    "tiny-tp-deferred": ("debug-tiny",
-                         dict(dp_size=2, tp_size=2, tp_sync="deferred"),
-                         dict(gradient_accumulation_steps=2)),
-    "tiny-tp-deferred-fused": ("debug-tiny",
-                               dict(dp_size=2, tp_size=2,
-                                    tp_sync="deferred"),
-                               dict(gradient_accumulation_steps=2,
-                                    grad_engine="fused",
-                                    remat_policy="dots_attn")),
-    # the 2d tp strategy's subgroup schedule (parallel/tp_strategies.py):
-    # inner tp_y activation/weight all-gathers + outer tp_x partial-sum
-    # all-reduces, audited against the collectives.py 2d presence rule
-    # (kv heads raised to 4 so tp=4 keeps GQA divisibility)
-    "tiny-tp2d": ("debug-tiny",
-                  dict(dp_size=2, tp_size=4, tp_strategy="2d",
-                       tp_mesh="2x2"),
-                  dict(gradient_accumulation_steps=2),
-                  {},
-                  dict(num_key_value_heads=4)),
-    # slice-boundary audit (analysis/boundary.py): the 8 simulated hosts
-    # split into 2 declared "slices"; with dp crossing the cut, every
-    # grad all-reduce must classify as a declared boundary crossing and
-    # every tp/cp collective must stay intra-slice — zero violations
-    "tiny-dense-dp-cross": ("debug-tiny",
-                            dict(dp_size=2, tp_size=2, cp_size=2,
-                                 slices=2, dcn_axes="dp"),
-                            dict(gradient_accumulation_steps=2)),
-    # the dp-cross audit again on the FUSED grad engine under remat: the
-    # runtime hierarchical dp reduction (parallel/hier_reduce.py) sits at
-    # the engine seam, so the in-scan accumulator must still reach the
-    # same explicit reduce-scatter / DCN all-reduce / all-gather schedule
-    "tiny-dp-cross-fused": ("debug-tiny",
-                            dict(dp_size=2, tp_size=2, cp_size=2,
-                                 slices=2, dcn_axes="dp"),
-                            dict(gradient_accumulation_steps=2,
-                                 grad_engine="fused", remat=True,
-                                 remat_policy="dots_attn")),
-    # same audit with the PIPELINE axis over DCN on the MPMD substrate:
-    # stage-boundary ppermutes are the only declared crossers
-    "tiny-pp-mpmd-cross": ("debug-tiny",
-                           dict(pp_size=2, tp_size=2,
-                                slices=2, dcn_axes="pp"),
-                           dict(gradient_accumulation_steps=2),
-                           dict(executor="mpmd")),
 }
 
 
@@ -169,11 +108,9 @@ def preset_config(name: str):
 
     model, dist_kw, train_kw, *rest = PRESETS[name]
     pipe_kw = rest[0] if rest else {}
-    model_kw = rest[1] if len(rest) > 1 else {}
     cfg = Config(
         distributed=DistributedConfig(**dist_kw),
-        model=ModelConfig(name=model,
-                          **{**resolve_preset(model), **model_kw}),
+        model=ModelConfig(name=model, **resolve_preset(model)),
         training=TrainingConfig(seq_length=64, micro_batch_size=1,
                                 **train_kw),
         pipeline=PipelineConfig(**pipe_kw),
@@ -195,26 +132,12 @@ def main(argv=None) -> int:
                          "ep>1, offload on/off)")
     ap.add_argument("--checks", default=None,
                     help="comma-separated subset of spec,source,"
-                         "collectives,boundary,provenance,variants,"
-                         "donation,stability (default: all)")
-    ap.add_argument("--provenance", action="store_true",
-                    help="focus on the sharding-dataflow audit: collective "
-                         "provenance, intended-vs-implicit classification, "
-                         "predicted boundary reshards (spec lint still "
-                         "runs first)")
+                         "collectives,variants,donation,stability "
+                         "(default: all)")
     ap.add_argument("--variants", action="store_true",
                     help="focus on the static jit-variant prover: abstract "
                          "signatures reaching each jit entry point, "
                          "compile-once proof (spec lint still runs first)")
-    ap.add_argument("--slices", type=int, default=None,
-                    help="audit the collective schedule against an "
-                         "N-slice multislice partition (overrides the "
-                         "config's distributed.slices); a config "
-                         "declaring slices > 1 is audited automatically")
-    ap.add_argument("--dcn-axes", default=None,
-                    help="comma-separated mesh axes allowed to cross the "
-                         "DCN cut (subset of dp,pp; overrides the "
-                         "config's distributed.dcn_axes)")
     ap.add_argument("--budget-mb", type=float, default=None,
                     help="all-gather replication budget in MiB (default: "
                          "the largest param leaf / activation block)")
@@ -244,15 +167,10 @@ def main(argv=None) -> int:
 
     if args.checks:
         checks = tuple(c.strip() for c in args.checks.split(","))
-    elif args.provenance or args.variants:
-        checks = ("spec",)
-        checks += ("provenance",) if args.provenance else ()
-        checks += ("variants",) if args.variants else ()
-        checks += ("boundary",) if args.slices else ()
+    elif args.variants:
+        checks = ("spec", "variants")
     else:
         checks = ALL_CHECKS
-    if args.slices and "boundary" not in checks:
-        checks += ("boundary",)
     unknown = set(checks) - set(ALL_CHECKS)
     if unknown:
         ap.error(f"unknown checks {sorted(unknown)}; valid: {ALL_CHECKS}")
@@ -284,8 +202,7 @@ def main(argv=None) -> int:
     for label, cfg in targets:
         try:
             rep = run_shardcheck(cfg, checks=checks, budget_bytes=budget,
-                                 cost_model=cost_model, slices=args.slices,
-                                 dcn_axes=args.dcn_axes)
+                                 cost_model=cost_model)
         except Exception as e:  # a layout that fails to trace is one bad row
             n_bad += 1
             if args.json:
@@ -326,38 +243,6 @@ def main(argv=None) -> int:
         else:
             print(f"== {label} ==")
             print(rep.render(verbose=args.verbose), flush=True)
-            prov = rep.info.get("provenance")
-            if prov and "sites" in prov:
-                print(f"provenance: {prov['sites']} site(s), "
-                      f"{prov['ops_attributed']}/{prov['ops_effective']} "
-                      f"lowered op(s) attributed "
-                      f"({prov['attribution_pct']:.1f}%), "
-                      f"{prov['implicit_ops']} implicit, "
-                      f"{prov['boundary_reshards']} predicted reshard(s)",
-                      flush=True)
-                if args.verbose:
-                    for src in sorted(prov.get("by_source", {})):
-                        row = prov["by_source"][src]
-                        roots = ", ".join(row["roots"][:3]) or "<constants>"
-                        print(f"  {src}: {row['ops']} "
-                              f"{'/'.join(row['kinds'])} <- {roots}",
-                              flush=True)
-            bnd = rep.info.get("boundary")
-            if bnd and bnd.get("audited"):
-                from picotron_tpu.analysis.boundary import render_table
-
-                line = (f"boundary: {bnd['slices']} slice(s), dcn axes "
-                        f"[{bnd.get('dcn_axes', '')}] — "
-                        f"{bnd.get('intra', 0)} intra / "
-                        f"{bnd.get('boundary', 0)} boundary / "
-                        f"{bnd.get('violating', 0)} violating")
-                if "dcn_ms" in bnd:
-                    line += (f"; dcn {bnd['dcn_ms']:.3f} ms, intra-slice "
-                             f"ici {bnd['ici_ms']:.3f} ms "
-                             f"[{bnd['dcn_generation']}]")
-                print(line, flush=True)
-                if args.verbose:
-                    print(render_table(bnd), flush=True)
             var = rep.info.get("variants")
             if var:
                 for entry in ("train_step", "mpmd_stages", "serve"):

@@ -324,20 +324,10 @@ def comm_row(events: list[dict], config_path: str,
     cfg = load_config(config_path)
     cost = CostModel(generation).predict(cfg)
     meas = measured_step_seconds(events) or {}
-    # TP-axis traffic split into its exposed vs overlapped halves: the
-    # deferred-sync schedule (distributed.tp_sync) only moves time from
-    # the first column into the second, so this pair is the row a
-    # strategy A/B actually compares.
-    tp_terms = [t for t in cost.comm if "tp" in t.axes]
-    tp_exposed = sum(t.secs_exposed for t in tp_terms)
-    tp_total = sum(t.secs_total for t in tp_terms)
     out = {
         "generation": cost.generation,
         "predicted_comm_ms": round(cost.exposed_comm_s * 1e3, 3),
         "predicted_step_ms": round(cost.total_s * 1e3, 3),
-        "predicted_tp_comm_exposed_ms": round(tp_exposed * 1e3, 3),
-        "predicted_tp_comm_overlapped_ms": round(
-            (tp_total - tp_exposed) * 1e3, 3),
         "measured_sync_p50_ms": (round(meas["sync_s"] * 1e3, 3)
                                  if meas.get("sync_s") is not None
                                  else None),
@@ -405,14 +395,6 @@ def render(s: dict, markdown: bool = False) -> str:
                f"measured sync p50 {sync_txt}"
                + (f" | drift {drift:+.1f}%" if drift is not None else ""))
         lines.append(f"**{msg}**" if markdown else msg)
-        if cm.get("predicted_tp_comm_exposed_ms") or \
-                cm.get("predicted_tp_comm_overlapped_ms"):
-            tp_msg = (f"  tp comm: {cm['predicted_tp_comm_exposed_ms']} "
-                      f"ms exposed + "
-                      f"{cm['predicted_tp_comm_overlapped_ms']} ms "
-                      f"overlapped (deferred sync moves exposed time "
-                      f"into the overlapped column)")
-            lines.append(tp_msg)
         lines.append("")
     pp = s.get("pipeline")
     if pp:
