@@ -61,8 +61,9 @@ class KVCache(NamedTuple):
     One of the two cache implementations `_decode_layers` runs against
     (the other is `serve.paged_cache.PagedKVCache`); both expose the same
     interface — `num_layers`, `write(li, k, v, q_pos)`,
-    `layer_view(li)` — so the layer loop is cache-agnostic and greedy
-    parity between the two is a test invariant, not an accident."""
+    `layer_view(li)`, `attend(li, q, q_pos)` — so the layer loop is
+    cache-agnostic and greedy parity between the two is a test invariant,
+    not an accident."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -88,6 +89,11 @@ class KVCache(NamedTuple):
         the token at position j."""
         return (lax.dynamic_index_in_dim(self.k, li, 0, keepdims=False),
                 lax.dynamic_index_in_dim(self.v, li, 0, keepdims=False))
+
+    def attend(self, li, q, q_pos):
+        """Attention of q [B, s, Hq, D] at positions q_pos over layer
+        li's cached positions: the layer's slice, attended whole."""
+        return _cached_attention(q, *self.layer_view(li), q_pos)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_length: int) -> KVCache:
@@ -145,7 +151,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
     """Run every layer over x [B, s, H] (prefill: s = prompt length,
     decode: s = 1), writing this segment's K/V into the cache at positions
     q_pos. Cache-agnostic: `cache` is any object with num_layers /
-    write / layer_view (contiguous KVCache here, PagedKVCache in
+    write / attend (contiguous KVCache here, PagedKVCache in
     picotron_tpu/serve). Returns (hidden, cache)."""
     dt = x.dtype
     d = cfg.head_dim
@@ -169,11 +175,12 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
         q = _rope(q, cos, sin, q_pos)
         k = _rope(k, cos, sin, q_pos)
         cache = cache.write(li, k, v, q_pos)
-        # named for the serve programs, whose `layer_view` gathers every
-        # slot's blocks out of the pool (the contiguous cache's is a slice)
+        # named for the serve programs: the decode step reads the blocks
+        # a slot holds in place (ops/paged_attention.py), prefill chunks
+        # gather their rows' views out of the pool (the contiguous cache's
+        # is a slice)
         with scope("paged_attention"):
-            ck_l, cv_l = cache.layer_view(li)
-            out = _cached_attention(q, ck_l, cv_l, q_pos)
+            out = cache.attend(li, q, q_pos)
         out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
         x = x + out
         if cfg.num_experts:

@@ -1,0 +1,215 @@
+"""Pallas paged attention for the serving decode step: one query position a
+slot against the blocks that slot holds, read in place through the block
+table.
+
+`PagedKVCache.layer_view` + `generate._cached_attention` gather every slot's
+whole `max_blocks * block_size` view out of the pool, live or idle, mapped or
+not, and then attend to it: at the chat cell's settings 2 x 67 MB a layer
+written and read again, 69% of the decode program's device time, of which the
+traffic holds a sixth at the fullest (PERF.md section 6, PR 32). This kernel
+reads the `ceil(length / block_size)` blocks of a slot and no other: the
+sequence loop is inside the kernel, so a block past a slot's length costs
+neither a grid step nor a DMA, and a slot of length 0 (idle, or mid-prefill)
+reads nothing.
+
+Design (after the kernel JAX ships,
+`jax.experimental.pallas.ops.tpu.paged_attention`, from which the online
+softmax over chunks of pages and the double buffer are taken):
+
+- **The pool stays where it is**: `[Hkv, L, num_blocks, block_size, D]` is
+  handed over whole, in HBM (`memory_space=ANY`), with the layer index as a
+  prefetched scalar; a page is `pool[:, li, page]`, ONE strided DMA for all
+  the KV heads of a block (`block_size x D` = one 4 KB bf16 tile a head).
+  No reshape of the pool, no layer sliced out, no offset added to the table.
+- **Grid (slots,)**, every KV head inside the program, grouped queries: a
+  block is fetched once for all the query heads that read it.
+- **Chunks of `pages_per_chunk` pages**, double-buffered in VMEM: while a
+  chunk is attended the next one's DMAs are in flight. Only the pages below
+  the slot's length are fetched (the DMA loops have dynamic trip counts), and
+  a table entry is clamped into the pool before it addresses a read: the
+  unmapped sentinel `num_blocks` is never a source.
+- **Mathematics of `_cached_attention`**, at no lower precision: K/V as
+  stored, scores `q k^T / sqrt(D)` accumulated and kept in float32, softmax
+  statistics in float32, P cast to the value dtype for the PV matmul with
+  float32 accumulation. Positions at or beyond the length are masked by
+  position, and V's rows there are zeroed, so whatever a partly filled block
+  or a stale buffer holds beyond the length (NaN included) cannot reach the
+  output. A slot of length 0 returns zeros.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+_NEG_INF = -1e30
+# Pages a chunk: one DMA a page and KV tensor, so a chunk of 64 pages of 16
+# positions is 1,024 positions and 2 x 64 DMAs in flight. Swept on the chip
+# at the chat cell's shapes over 4 .. 64 (PERF.md section 6, PR 32): the
+# kernel is bound by issuing the DMAs (50-65 ns each), and what a larger
+# chunk saves is the loop around them.
+DEFAULT_PAGES_PER_CHUNK = 64
+
+
+def compiled_kernels_available() -> bool:
+    """`ops.flash_attention`'s answer (a TPU backend), asked at each call:
+    the tests that compile for a described chip patch it there."""
+    from picotron_tpu.ops.flash_attention import compiled_kernels_available
+    return compiled_kernels_available()
+
+
+def decode_kernel_suits(q, k_pool) -> bool:
+    """Whether a step with queries q [B, s, Hq, D] against k_pool
+    [Hkv, L, num_blocks, block_size, D] is one the compiled kernel takes:
+    a decode step (one query position a slot), the head a whole number of
+    128-lane rows, the block a whole number of sublane tiles of the pool's
+    dtype (16 rows of bf16, 8 of float32), and a backend that compiles
+    Pallas kernels. Everything else keeps the gathered view."""
+    block_size, d = k_pool.shape[3], k_pool.shape[4]
+    sublanes = 8 * 4 // jnp.dtype(k_pool.dtype).itemsize
+    return (q.shape[1] == 1 and d % 128 == 0 and block_size % sublanes == 0
+            and compiled_kernels_available())
+
+
+def _kernel(lengths_ref, tables_ref, li_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, *, sm_scale: float, pages_per_chunk: int,
+            max_blocks: int):
+    b = pl.program_id(0)
+    hkv, _, num_blocks, bs, d = k_hbm.shape
+    chunk = pages_per_chunk * bs
+    li = li_ref[0]
+    length = lengths_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(length, bs), max_blocks)
+    n_chunks = pl.cdiv(n_pages, pages_per_chunk)
+
+    def copies(c, buf, j):
+        # the unmapped sentinel (num_blocks) never addresses a read; no page
+        # below a live slot's length is unmapped, so the clamp changes none
+        page = jnp.minimum(
+            tables_ref[b * max_blocks + c * pages_per_chunk + j],
+            num_blocks - 1)
+        return (pltpu.make_async_copy(k_hbm.at[:, li, page],
+                                      k_buf.at[buf, :, j], sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[:, li, page],
+                                      v_buf.at[buf, :, j], sems.at[1, buf]))
+
+    def pages_in(c):
+        return jnp.minimum(n_pages - c * pages_per_chunk, pages_per_chunk)
+
+    def each_copy(c, buf, act):
+        def one(j, _):
+            for cp in copies(c, buf, j):
+                act(cp)
+        lax.fori_loop(0, pages_in(c), one, None)
+
+    def start(c, buf):
+        each_copy(c, buf, lambda cp: cp.start())
+
+    def wait(c, buf):
+        each_copy(c, buf, lambda cp: cp.wait())
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        start(0, 0)
+
+    def body(c, carry):
+        buf = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            start(c + 1, 1 - buf)
+
+        wait(c, buf)
+        live = length - c * chunk  # positions of this chunk below the length
+        col = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < live
+        row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) < live
+        out = []
+        for h in range(hkv):
+            m_prev, l_prev, acc = carry[h]
+            k = k_buf[buf, h].reshape(chunk, d)
+            v = v_buf[buf, h].reshape(chunk, d)
+            v = jnp.where(row, v, jnp.zeros_like(v))
+            s = lax.dot_general(q_ref[h], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(col, s * sm_scale, _NEG_INF)       # [G, chunk] f32
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(col, jnp.exp(s - m_new), 0.0)
+            acc = acc * alpha + lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            out.append((m_new, l_new, acc))
+        return tuple(out)
+
+    g = q_ref.shape[1]
+    init = tuple((jnp.full((g, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((g, 1), jnp.float32),
+                  jnp.zeros((g, d), jnp.float32)) for _ in range(hkv))
+    final = lax.fori_loop(0, n_chunks, body, init)
+    for h, (_, l, acc) in enumerate(final):
+        # length 0: no chunk ran, acc = 0 and l = 0 -> zeros
+        o_ref[h] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
+                           pages_per_chunk: Optional[int] = None,
+                           interpret: Optional[bool] = None):
+    """Attention of one query position a slot over that slot's cached
+    positions, read from the paged pool in place.
+
+    q [B, Hq, D]; k_pool / v_pool [Hkv, L, num_blocks, block_size, D];
+    li: the layer (a scalar, traced or not); tables [B, max_blocks] int32,
+    logical block -> physical block, `num_blocks` = unmapped; lengths [B]
+    int32: slot b attends positions 0 .. lengths[b] - 1 (0: nothing is
+    read, the row is zeros). Returns [B, Hq, D] in q's dtype.
+
+    `interpret=None` compiles the kernel on a TPU backend and runs the
+    Pallas interpreter anywhere else (the CPU unit tests); the caller
+    decides whether the shapes suit the compiled kernel
+    (`decode_kernel_suits`)."""
+    if interpret is None:
+        interpret = not compiled_kernels_available()
+    b, hq, d = q.shape
+    hkv, _, _, bs, _ = k_pool.shape
+    max_blocks = tables.shape[1]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not group over {hkv} KV heads")
+    if k_pool.shape != v_pool.shape or k_pool.shape[4] != d:
+        raise ValueError(f"pools {k_pool.shape} / {v_pool.shape} do not "
+                         f"match q {q.shape}")
+    g = hq // hkv
+    ppc = min(pages_per_chunk or DEFAULT_PAGES_PER_CHUNK, max_blocks)
+    kernel = functools.partial(_kernel, sm_scale=1.0 / d ** 0.5,
+                               pages_per_chunk=ppc, max_blocks=max_blocks)
+    q_spec = pl.BlockSpec((None, hkv, g, d), lambda i, *_: (i, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # lengths, the tables (flat), the layer
+            grid=(b,),
+            in_specs=[q_spec,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, hkv, ppc, bs, d), k_pool.dtype),
+                pltpu.VMEM((2, hkv, ppc, bs, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(li, jnp.int32).reshape(1), q.reshape(b, hkv, g, d),
+      k_pool, v_pool)
+    return out.reshape(b, hq, d)

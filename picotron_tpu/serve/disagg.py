@@ -55,7 +55,7 @@ import numpy as np
 from picotron_tpu.config import ModelConfig, ServeConfig
 from picotron_tpu.models.llama import model_rope_tables
 from picotron_tpu.serve.engine import (
-    ServeEngine, _get_jits, prefill_rungs,
+    ServeEngine, _get_jits, _sharded, prefill_rungs,
 )
 from picotron_tpu.serve.paged_cache import BlockPool, init_paged_cache
 from picotron_tpu.serve.scheduler import DisaggScheduler, blocks_for
@@ -293,7 +293,8 @@ class DisaggServeEngine(ServeEngine):
         self._k_p, self._v_p, toks = self._prefill_jit(
             self.params_p, self._k_p, self._v_p, *feed, self.base_key_p,
             self.cos_p, self.sin_p, cfg=self.cfg,
-            temperature=self.temperature, top_k=self.top_k)
+            temperature=self.temperature, top_k=self.top_k,
+            pool_sharded=_sharded(self._k_p))
         return toks
 
     def _retire_prefilled(self, pslot: int, t: float) -> None:

@@ -12,7 +12,10 @@ lifetime (the prefill program once per row count of a short ladder):
   recompile (asserted via CompileWatch in tests — one decode compile
   across a multi-request trace). Idle/prefilling slots ride along at
   position -1: their q-rows compute masked garbage that is discarded and
-  their K/V writes resolve to the sentinel block and drop.
+  their K/V writes resolve to the sentinel block and drop. On a chip the
+  step attends in place: a kernel reads the blocks each slot holds
+  through the block table (ops/paged_attention.py), nothing for an idle
+  slot, and no view of the pool is gathered.
 - ``prefill chunk``: `prefill_chunk` tokens of every mid-prefill slot's
   prompt, one row a slot, interleaved one dispatch per engine iteration
   so a long prompt never stalls the in-flight decode batch. The batch is
@@ -73,7 +76,7 @@ from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, head_weight, model_rope_tables,
 )
 from picotron_tpu.serve.paged_cache import (
-    BlockPool, PagedKVCache, init_paged_cache,
+    BlockPool, PagedKVCache, ShardedPagedKVCache, init_paged_cache,
 )
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
@@ -118,10 +121,26 @@ def _sample_slots(logits, temperature: float, top_k: int, base_key, rids,
     )(lg, keys).astype(jnp.int32)
 
 
+def _paged_cache(k, v, tables, pool_sharded: bool) -> PagedKVCache:
+    """The cache a serve program runs its layers against. `pool_sharded`
+    (static; `_sharded` of the pool the engine feeds) says that a mesh
+    shards the pool over the KV heads (tp > 1): attention then keeps the
+    gathered view whatever the step, which the compiler partitions, and
+    never the in-place kernel, which it does not."""
+    return (ShardedPagedKVCache if pool_sharded else PagedKVCache)(
+        k, v, tables)
+
+
+def _sharded(pool) -> bool:
+    """Whether the sharding the engine's constructor gave this KV pool
+    splits it (over its KV heads, tp > 1)."""
+    return not pool.sharding.is_fully_replicated
+
+
 def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
                  base_key, cos, sin, cfg: ModelConfig,
                  temperature: float, top_k: int, interval: int,
-                 eos_token_id):
+                 eos_token_id, pool_sharded: bool = False):
     """`interval` decode steps over all slots inside ONE dispatch (a
     lax.scan — amortizes per-dispatch host overhead over interval tokens
     per slot; the same reason offline generate scans its whole decode).
@@ -132,7 +151,8 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     (tokens [S, interval], next positions, next tidx, k, v); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
-    (measured ~2x the whole dispatch on the CPU tiny-model bench)."""
+    (measured ~2x the whole dispatch on the CPU tiny-model bench).
+    `pool_sharded`: see `_paged_cache`."""
     live = positions >= 0
 
     def one(carry, _):
@@ -149,7 +169,7 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
         tidx = jnp.where(live, tidx + 1, tidx)
         return (nxt, positions, tidx, cache, done), nxt
 
-    cache = PagedKVCache(k, v, tables)
+    cache = _paged_cache(k, v, tables, pool_sharded)
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _), toks_all = jax.lax.scan(
         one, (toks, positions, tidx, cache, done), None, length=interval)
@@ -158,7 +178,8 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
 
 def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
                   n_valid, rids, tidx, base_key, cos, sin,
-                  cfg: ModelConfig, temperature: float, top_k: int):
+                  cfg: ModelConfig, temperature: float, top_k: int,
+                  pool_sharded: bool = False):
     """Prefill the next chunk of every mid-prefill slot in one dispatch:
     chunk_ids [R, C] (padded), start_pos/n_valid/rids/tidx [R],
     table_rows [R, max_blocks]. A row is a mid-prefill slot, not a slot
@@ -176,7 +197,7 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     s, c = chunk_ids.shape
     t = jnp.arange(c)[None, :]
     pos = jnp.where(t < n_valid[:, None], start_pos[:, None] + t, -1)
-    cache = PagedKVCache(k, v, table_rows)
+    cache = _paged_cache(k, v, table_rows, pool_sharded)
     x = params["embedding"][chunk_ids].astype(compute_dtype(cfg))
     x, cache = _decode_layers(params, x, cache, pos, cfg, cos, sin)
     last = jnp.maximum(n_valid - 1, 0)  # [S]
@@ -201,9 +222,11 @@ def _get_jits(donate: bool):
         _JITS[donate] = (
             jax.jit(serve_decode, donate_argnums=dargs,
                     static_argnames=("cfg", "temperature", "top_k",
-                                     "interval", "eos_token_id")),
+                                     "interval", "eos_token_id",
+                                     "pool_sharded")),
             jax.jit(serve_prefill, donate_argnums=dargs,
-                    static_argnames=("cfg", "temperature", "top_k")),
+                    static_argnames=("cfg", "temperature", "top_k",
+                                     "pool_sharded")),
         )
     return _JITS[donate]
 
@@ -450,7 +473,8 @@ class ServeEngine:
         self._k, self._v, toks = self._prefill_jit(
             self.params, self._k, self._v, *feed, self.base_key,
             self.cos, self.sin, cfg=self.cfg,
-            temperature=self.temperature, top_k=self.top_k)
+            temperature=self.temperature, top_k=self.top_k,
+            pool_sharded=_sharded(self._k))
         return toks
 
     def _prefill_feed(self, pslots, rows: Optional[int] = None):
@@ -751,8 +775,16 @@ class ServeEngine:
         dec_ids = [self.sched.slots[s].req.id for s in active]
         t0 = time.perf_counter()
         nval = None
+        # `kv_blocks`: the blocks the active slots' cached positions fill
+        # at the dispatch's first token, which is what a decode step that
+        # attends in place reads a layer; `view_blocks`: what the gathered
+        # view spans, whatever is live
+        kv_blocks = sum(blocks_for(self.sched.slots[s].write_pos + 1,
+                                   self.block_size) for s in active)
         with self._span("serve.decode.dispatch", active=len(active),
-                        interval=interval, ids=join_ids(dec_ids)):
+                        interval=interval, kv_blocks=kv_blocks,
+                        view_blocks=self.num_slots * self.max_blocks,
+                        ids=join_ids(dec_ids)):
             if self.speculate:
                 (toks_d, nval_d, last_d, pos_d, tidx_d, ctx_d,
                  self._k, self._v) = self._decode_jit(
@@ -775,7 +807,8 @@ class ServeEngine:
                         self.cos, self.sin, cfg=self.cfg,
                         temperature=self.temperature,
                         top_k=self.top_k, interval=interval,
-                        eos_token_id=self.eos_token_id)
+                        eos_token_id=self.eos_token_id,
+                        pool_sharded=_sharded(self._k))
                 state = dict(ds, toks=last_d, positions=pos_d,
                              tidx=tidx_d)
         with self._span("serve.decode.wait"):

@@ -15,7 +15,8 @@ static-shaped for XLA):
 - ``tables``: ``[B, max_blocks]`` int32, logical block -> physical block.
   ``num_blocks`` itself is the UNMAPPED sentinel: scatter writes at the
   sentinel drop (``mode="drop"``), gathers clamp into the pool and the
-  clamped garbage is masked by the causal mask before anything reads it.
+  clamped garbage is masked by the causal mask before anything reads it;
+  the decode kernel reads no entry past a slot's length at all.
 
 Why the KV heads lead: the pool rides the layer scan's carry, and the
 compiler gives a carried buffer ONE layout that `write`'s scatter and
@@ -45,6 +46,18 @@ the gathered view holds the token at position j, exactly like the
 contiguous cache, which is what makes paged-vs-contiguous greedy parity a
 structural property rather than a numerical accident.
 
+`attend` is what the layer loop calls. A decode step (one query position
+a slot) on a chip does not build that view: `ops/paged_attention.py`
+reads the ``ceil((pos + 1) / block_size)`` blocks each slot holds straight
+out of the pool through the table, the same mathematics with the scores
+kept in float32. The view spans ``slots x max_blocks`` blocks whatever is
+live (the whole pool's size where the pool is sized ``slots x
+max_model_len``), and gathering it was 69% of the decode program's device
+time at Qwen2-1.5B's chat settings, where the traffic holds a sixth of the
+pool at the fullest (PERF.md, PR 32). Prefill chunks, the speculative
+program (more than one query position a slot), shapes the kernel does not
+take and every CPU run keep the view.
+
 `BlockPool` is the host-side allocator: free-list alloc/free with
 all-or-nothing semantics and peak accounting, so the scheduler can make
 admission/preemption decisions and tests can assert no block leaks
@@ -59,14 +72,18 @@ import jax
 import jax.numpy as jnp
 
 from picotron_tpu.config import ModelConfig
+from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype
+from picotron_tpu.ops.paged_attention import (
+    decode_kernel_suits, paged_decode_attention,
+)
 from picotron_tpu.telemetry.scopes import scope
 
 
 class PagedKVCache(NamedTuple):
     """Pool-backed cache; same interface as `generate.KVCache`
-    (num_layers / write / layer_view) so `generate._decode_layers` is
-    cache-agnostic."""
+    (num_layers / write / layer_view / attend) so
+    `generate._decode_layers` is cache-agnostic."""
 
     k: jnp.ndarray       # [Hkv, L, num_blocks, block_size, D]
     v: jnp.ndarray       # [Hkv, L, num_blocks, block_size, D]
@@ -135,6 +152,31 @@ class PagedKVCache(NamedTuple):
                 1, 2, 0, 3)
 
         return view(self.k), view(self.v)
+
+    def attend(self, li, q, q_pos):
+        """Attention of q [B, s, Hq, D] at positions q_pos ([s] or
+        [B, s]) over layer li's cached positions. One algorithm, two
+        forms, chosen from what the step's shapes and the backend say
+        (`decode_kernel_suits`): a decode step reads each slot's own
+        blocks in place, everything else attends the gathered view.
+        Slots at positions < 0 have length 0: the kernel reads nothing
+        for them and returns zeros (discarded by the caller, finite for
+        the layers after)."""
+        if not decode_kernel_suits(q, self.k):
+            return _cached_attention(q, *self.layer_view(li), q_pos)
+        pos = jnp.broadcast_to(q_pos.reshape(-1), q.shape[:1])  # s == 1
+        out = paged_decode_attention(q[:, 0], self.k, self.v, li,
+                                     self.tables, jnp.maximum(pos + 1, 0))
+        return out[:, None]
+
+
+class ShardedPagedKVCache(PagedKVCache):
+    """The pool of an engine whose mesh shards it over the KV heads
+    (tp > 1 serving): the compiler does not partition a Pallas call, so
+    every step attends the gathered view, which it does partition."""
+
+    def attend(self, li, q, q_pos):
+        return _cached_attention(q, *self.layer_view(li), q_pos)
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,

@@ -37,7 +37,7 @@ SCOPES = (
     "pp_boundary",      # the pipeline schedule's ppermutes
     "tp_reduce",        # tensor-parallel psum / psum_scatter / all_gather hooks
     "kv_write",         # serve: the paged pool update
-    "paged_attention",  # serve: gather of the views, mask, softmax, PV
+    "paged_attention",  # serve: attention over the cache: the decode step's in-place kernel; prefill's gathered views, mask, softmax, PV
     "sample",           # serve: next-token choice from the logits
 )
 

@@ -1,0 +1,220 @@
+"""The decode step's in-place paged attention (ops/paged_attention.py), on the
+CPU through the Pallas interpreter: the kernel against the gathered view +
+`generate._cached_attention`, and one engine run with the kernel forced into
+`serve_decode`. The compiled kernel at the chat cell's shapes is held by
+tests/test_chip_compile.py; its times are chip runs (PERF.md)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
+from picotron_tpu.generate import (
+    _cached_attention, _decode_layers, init_cache,
+)
+from picotron_tpu.models.llama import (
+    final_hidden, head_weight, init_params, model_rope_tables,
+)
+from picotron_tpu.ops.paged_attention import (
+    decode_kernel_suits, paged_decode_attention,
+)
+from picotron_tpu.serve import ServeEngine, engine, paged_cache
+from picotron_tpu.serve.paged_cache import PagedKVCache
+
+BS, MB, NB, L = 16, 6, 40, 3   # block size, table width, pool blocks, layers
+FULL = BS * MB
+
+CASES = {
+    # lengths: 1, one block exactly, a block + 1, the full table, idle (0)
+    "gqa_12_2_ragged": dict(hq=12, hkv=2, lengths=[1, BS, BS + 1, FULL, 0], li=1),
+    "multi_head_4_4": dict(hq=4, hkv=4, lengths=[3, 0, FULL, 2 * BS], li=0),
+    "last_layer": dict(hq=12, hkv=2, lengths=[FULL, 5, BS + 1], li=L - 1),
+    "chunks_of_1_page": dict(hq=12, hkv=2, lengths=[1, BS, BS + 1, FULL, 0],
+                             li=1, ppc=1),
+    "chunks_of_4_pages": dict(hq=4, hkv=2, lengths=[4 * BS, 4 * BS + 1, FULL, 7],
+                              li=2, ppc=4),
+    "every_slot_idle": dict(hq=4, hkv=2, lengths=[0, 0, 0], li=L - 1),
+    "bf16_pool": dict(hq=12, hkv=2, lengths=[1, BS + 1, FULL, 0], li=L - 1,
+                      dtype=jnp.bfloat16, tol=2e-2),
+    "head_dim_32": dict(hq=4, hkv=2, lengths=[9, FULL, 0, BS], li=1, d=32),
+}
+
+
+def scattered_tables(rng, lengths):
+    """A table a slot whose held blocks are drawn without order from all
+    over the pool (non-monotone, interleaved between slots); entries past
+    the blocks held stay at the unmapped sentinel NB. The pool's last
+    block, which a clamped sentinel addresses, is never held."""
+    tables = np.full((len(lengths), MB), NB, np.int32)
+    free = list(rng.permutation(NB - 1))
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // BS)):
+            tables[b, j] = free.pop()
+    return tables
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_matches_the_gathered_view(name):
+    """The kernel on a pool poisoned everywhere a live slot must not read
+    equals the view path on the clean pool. NaN goes into every block no
+    live slot maps (the last block of the last layer, which a clamped
+    sentinel would address, among them), into the tail of every partly
+    filled block, and into all other layers: a read past a slot's length
+    or off the table's live entries would reach the output as NaN."""
+    c = CASES[name]
+    d, dtype, li = c.get("d", 128), c.get("dtype", jnp.float32), c["li"]
+    lengths = np.asarray(c["lengths"], np.int32)
+    rng = np.random.default_rng(sorted(CASES).index(name))
+    tables = scattered_tables(rng, lengths)
+    shape = (c["hkv"], L, NB, BS, d)
+    k = jnp.asarray(rng.standard_normal(shape), dtype)
+    v = jnp.asarray(rng.standard_normal(shape), dtype)
+    q = jnp.asarray(rng.standard_normal((len(lengths), c["hq"], d)), dtype)
+
+    # positions of the pool that hold a live slot's cached token at layer li
+    held = np.zeros((L, NB, BS), bool)
+    for b, n in enumerate(lengths):
+        for pos in range(n):
+            held[li, tables[b, pos // BS], pos % BS] = True
+    assert not held[:, NB - 1].any()  # what a clamped sentinel would read
+    poison = jnp.asarray(~held)[None, :, :, :, None]
+    got = paged_decode_attention(
+        q, jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v), li,
+        jnp.asarray(tables), jnp.asarray(lengths),
+        pages_per_chunk=c.get("ppc"), interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    assert (got[lengths == 0] == 0).all()
+
+    f32 = lambda a: a.astype(jnp.float32)
+    ck, cv = PagedKVCache(f32(k), f32(v), jnp.asarray(tables)).layer_view(li)
+    want = _cached_attention(f32(q)[:, None], ck, cv,
+                             jnp.asarray(lengths - 1)[:, None])[:, 0]
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               rtol=c.get("tol", 2e-5), atol=c.get("tol", 2e-5))
+
+
+def test_the_step_decides_the_path(monkeypatch):
+    """`PagedKVCache.attend` takes the kernel for a decode step whose shapes
+    suit it on a backend that compiles kernels, and the view otherwise:
+    from the shapes alone, no option."""
+    from picotron_tpu.ops import paged_attention as pa
+    pool = jnp.zeros((2, L, NB, 16, 128), jnp.bfloat16)
+    q1 = jnp.zeros((3, 1, 12, 128), jnp.bfloat16)
+    assert not decode_kernel_suits(q1, pool)  # the CPU compiles no kernel
+    monkeypatch.setattr(pa, "compiled_kernels_available", lambda: True)
+    assert decode_kernel_suits(q1, pool)
+    assert not decode_kernel_suits(jnp.zeros((3, 5, 12, 128)), pool)  # s > 1
+    assert not decode_kernel_suits(q1[..., :64], pool[..., :64])  # head 64
+    assert not decode_kernel_suits(q1, pool[:, :, :, :8])  # half a bf16 tile
+    assert decode_kernel_suits(q1, pool[:, :, :, :8].astype(jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = ModelConfig(dtype="float32", **{
+        **resolve_preset("debug-tiny"), "max_position_embeddings": 64})
+    return cfg, init_params(cfg, jax.random.key(0))
+
+
+def reference_logits(params, cfg, ids):
+    """[len(ids), V] logits of the offline contiguous-cache forward over
+    the whole sequence: row i predicts token i + 1."""
+    cos, sin = model_rope_tables(cfg, max_len=len(ids))
+    x = params["embedding"][jnp.asarray([ids])].astype(jnp.float32)
+    x, _ = _decode_layers(params, x, init_cache(cfg, 1, len(ids)),
+                          jnp.arange(len(ids)), cfg, cos, sin)
+    hf = final_hidden(params, x, cfg)
+    return np.asarray(hf @ head_weight(params).astype(hf.dtype))[0]
+
+
+@pytest.fixture
+def fresh_programs(monkeypatch):
+    """The engine's two programs under function objects of their own. JAX
+    keeps a traced program by the function it was traced from, whichever
+    `jax.jit` wraps it, so a patch that tracing consults (here: which form
+    of attention a step takes) needs functions that no other test of this
+    process has traced at the same shapes, and must leave none behind for
+    the bit-identical parity tests to pick up."""
+    import functools
+
+    def jits(donate):
+        decode = functools.wraps(engine.serve_decode)(
+            lambda *a, **k: engine.serve_decode(*a, **k))
+        prefill = functools.wraps(engine.serve_prefill)(
+            lambda *a, **k: engine.serve_prefill(*a, **k))
+        static = ("cfg", "temperature", "top_k", "pool_sharded")
+        return (jax.jit(decode, static_argnames=static + (
+                    "interval", "eos_token_id")),
+                jax.jit(prefill, static_argnames=static))
+
+    monkeypatch.setattr(engine, "_get_jits", jits)
+
+
+def test_sharded_pool_keeps_the_view(tiny, monkeypatch, fresh_programs):
+    """tp = 2 serving pins the pool over its KV heads, and the compiler does
+    not partition a Pallas call: with the kernel forced in wherever the
+    step's shape allows it, no program of such an engine asks for it (a
+    prefill chunk of ONE token is a decode-shaped step), and the tokens are
+    the single-device engine's."""
+    from picotron_tpu.generate import place_for_decode
+
+    cfg, params = tiny
+    asked = []
+    monkeypatch.setattr(paged_cache, "decode_kernel_suits",
+                        lambda q, k: asked.append(q.shape[1]) or True)
+    scfg = ServeConfig(decode_slots=2, block_size=4, num_blocks=16,
+                       prefill_chunk=1, max_model_len=32, decode_interval=2)
+    requests = [([3, 1, 4, 1, 5], 4), ([9, 2, 6], 5)]
+    tokens = {}
+    for tp in (2, 1):
+        del asked[:]
+        eng = ServeEngine(place_for_decode(params, cfg, tp=tp), cfg, scfg)
+        assert engine._sharded(eng._k) == (tp == 2)
+        tokens[tp] = [r["tokens"] for r in eng.run(requests)]
+        eng.close()
+        assert bool(asked) == (tp == 1)
+    assert tokens[2] == tokens[1]
+
+
+def test_engine_decodes_through_the_kernel(tiny, monkeypatch, fresh_programs):
+    """`serve_decode` with the kernel forced in (interpreted) serves the
+    offline reference's greedy tokens wherever the reference's two best
+    logits lie further apart than rounding moves them. The bit-identical
+    parity tests of test_serve.py keep running the view path."""
+    cfg, params = tiny
+    taken = []
+
+    def force(q, k_pool):
+        taken.append(q.shape[1])
+        return q.shape[1] == 1
+
+    monkeypatch.setattr(paged_cache, "decode_kernel_suits", force)
+    rng = np.random.default_rng(0)
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+                for n, m in ((5, 6), (9, 3), (3, 8), (7, 5), (11, 4))]
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=3, block_size=4, num_blocks=24, prefill_chunk=4,
+        max_model_len=32, decode_interval=3))
+    results = eng.run(requests)
+    eng.close()
+    assert 1 in taken and any(s > 1 for s in taken)  # decode: kernel; prefill: view
+    assert eng.pool.in_use == 0
+    tol, checked = 1e-3, 0
+    for (prompt, n), res in zip(requests, results):
+        toks = res["tokens"]
+        assert len(toks) == n
+        logits = reference_logits(params, cfg, prompt + toks)
+        for i, tok in enumerate(toks):
+            row = logits[len(prompt) - 1 + i]
+            best, second = np.sort(row)[[-1, -2]]
+            if tok != int(row.argmax()):
+                # a near-tie that rounding decided; what follows is another
+                # sequence than the reference's
+                assert best - second <= tol, (res["id"], i, best - second)
+                break
+            checked += 1
+    assert checked >= 20
