@@ -442,8 +442,8 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
     scfg.validate()
     if model_cfg.num_experts:
         raise ValueError(
-            "disaggregated serving rejects MoE models (chunked-prefill "
-            "expert routing is not parity-guaranteed)")
+            "disaggregated serving rejects MoE models (the block "
+            "handoff has never run or been tested with an expert block)")
     rep = Report()
     max_len = scfg.max_model_len or model_cfg.max_position_embeddings
     max_blocks = blocks_for(max_len, scfg.block_size)

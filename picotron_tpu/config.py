@@ -157,6 +157,31 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         norm_topk_prob=False, qk_norm=True,
         router_aux_coef=0.01, router_z_coef=0.001,
     ),
+    # Mellum 2 (JetBrains; model_type mellum): sparse experts in every
+    # layer (64 of width 896, 8 a token, renormalised gates, no shared
+    # expert), GQA 32:4 with a head_dim of its own (128, not hidden /
+    # heads = 72), three sliding-window layers (window 1024, plain RoPE)
+    # to one full layer (YaRN x16 over 8192 positions), untied head. The
+    # dense intermediate_size (7168) is unused: every mlp_layer_types
+    # entry is sparse.
+    "JetBrains/Mellum2-12B-A2.5B-Instruct": dict(
+        vocab_size=98304, hidden_size=2304, intermediate_size=7168,
+        num_hidden_layers=28, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, max_position_embeddings=131072, rope_theta=500000.0,
+        rms_norm_eps=1e-6,
+        num_experts=64, num_experts_per_token=8, moe_intermediate_size=896,
+        norm_topk_prob=True,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "sliding_attention", "full_attention") * 7,
+        sliding_window=1024,
+        rope_parameters=dict(
+            full_attention=dict(
+                rope_type="yarn", rope_theta=500000.0, factor=16.0,
+                original_max_position_embeddings=8192, beta_fast=32.0,
+                beta_slow=1.0, attention_factor=1.2772588722239782),
+            sliding_attention=dict(rope_type="default",
+                                   rope_theta=500000.0)),
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -187,6 +212,28 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         norm_topk_prob=False, qk_norm=True,
         router_aux_coef=0.01, router_z_coef=0.001,
     ),
+    # Tiny Mellum2-shaped debug model: two periods of (S, S, S, F),
+    # head_dim (32) unequal to hidden / heads (16), 8 experts 2 a token,
+    # window 8, YaRN over an original length (16) shorter than the tests'
+    # sequences. Served with block_size 4 so that rings wrap.
+    "picotron-tpu/debug-tiny-mellum2": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, max_position_embeddings=2048, rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        num_experts=8, num_experts_per_token=2, moe_intermediate_size=32,
+        norm_topk_prob=True,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "sliding_attention", "full_attention") * 2,
+        sliding_window=8,
+        rope_parameters=dict(
+            full_attention=dict(
+                rope_type="yarn", rope_theta=10000.0, factor=4.0,
+                original_max_position_embeddings=16, beta_fast=32.0,
+                beta_slow=1.0, attention_factor=1.1386294361119891),
+            sliding_attention=dict(rope_type="default",
+                                   rope_theta=10000.0)),
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -215,6 +262,8 @@ _PRESET_ALIASES = {
     "debug-tiny-moe": "picotron-tpu/debug-tiny-moe",
     "OLMoE-1B-7B": "allenai/OLMoE-1B-7B-0125-Instruct",
     "debug-tiny-olmoe": "picotron-tpu/debug-tiny-olmoe",
+    "Mellum2-12B-A2.5B": "JetBrains/Mellum2-12B-A2.5B-Instruct",
+    "debug-tiny-mellum2": "picotron-tpu/debug-tiny-mellum2",
 }
 
 
@@ -238,7 +287,7 @@ def resolve_hf_name(name: str) -> str:
 def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
-    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE-family model
+    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum-family model
     outside the preset registry resolves from its config file instead of
     hand-typed hyperparameters. Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -248,7 +297,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             hf = json.load(f)
 
     mtype = hf.get("model_type", "llama")
-    supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe")
+    supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -297,6 +346,35 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         out["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
         if "router_aux_loss_coef" in hf:
             out["router_aux_coef"] = float(hf["router_aux_loss_coef"])
+    if "head_dim" in hf:
+        out["head_dim"] = hf["head_dim"]
+    if hf.get("moe_intermediate_size"):
+        out["moe_intermediate_size"] = hf["moe_intermediate_size"]
+    if "dense" in (hf.get("mlp_layer_types") or ()):
+        # M3: the layer scan runs one kind of MLP in every layer
+        raise ValueError(
+            "mlp_layer_types holds a 'dense' entry: dense and sparse MLP "
+            "layers in one model are not supported (every layer of the "
+            "scan has the same parameters); only all-'sparse' loads")
+    if hf.get("layer_types"):
+        # layer_types decides which layers slide (use_sliding_window /
+        # max_window_layers are read as "layer_types decides")
+        out["layer_types"] = tuple(hf["layer_types"])
+        if "sliding_attention" in out["layer_types"]:
+            out["sliding_window"] = int(hf["sliding_window"])
+    if hf.get("rope_parameters"):
+        rp = hf["rope_parameters"]
+        if "rope_type" in rp or "rope_theta" in rp:
+            # one law for every layer, the newer spelling of
+            # rope_theta + rope_scaling
+            out["rope_theta"] = float(rp.get("rope_theta", out["rope_theta"]))
+            if rp.get("rope_type", "default") != "default":
+                out["rope_scaling"] = {k: v for k, v in rp.items()
+                                       if k != "rope_theta"}
+        else:
+            out["rope_parameters"] = rp
+            out["rope_theta"] = float(next(iter(rp.values())).get(
+                "rope_theta", out["rope_theta"]))
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -460,6 +538,22 @@ class ModelConfig:
     # frozen config stays hashable (generation jits with the config as a
     # static argument); pass a plain dict, __post_init__ normalizes.
     rope_scaling: Optional[Any] = None
+    # Size of one attention head. None = hidden_size // num_attention_heads
+    # (the Llama convention; resolved in __post_init__). A model that
+    # publishes the key (Mellum2: 128 at hidden 2304 / 32 heads) has q and
+    # o projections of hidden x (heads * head_dim).
+    head_dim: Optional[int] = None
+    # The published per-layer attention kinds, "full_attention" or
+    # "sliding_attention" a layer; None = every layer full. The layer scan
+    # runs over whole periods of the pattern (`layer_period`). A sliding
+    # layer's position i sees j with 0 <= i - j < sliding_window.
+    layer_types: Optional[tuple] = None
+    sliding_window: Optional[int] = None
+    # RoPE law a layer kind: {"full_attention": {rope_type, rope_theta,
+    # ...}, "sliding_attention": {...}} (the published key). None = one law
+    # for every layer, from rope_theta + rope_scaling. Stored like
+    # rope_scaling, as sorted tuples.
+    rope_parameters: Optional[Any] = None
     rms_norm_eps: float = 1e-5
     # Qwen2-style architecture variants: bias on the q/k/v projections, and
     # an LM head tied to the embedding matrix (logits = h @ embedding.T; no
@@ -521,14 +615,55 @@ class ModelConfig:
             # config stays hashable
             rs = tuple(sorted(tuple(pair) for pair in rs))
         object.__setattr__(self, "rope_scaling", rs or None)
+        if self.head_dim is None:
+            object.__setattr__(
+                self, "head_dim",
+                self.hidden_size // self.num_attention_heads)
+        if self.layer_types is not None:
+            lt = tuple(self.layer_types)
+            # every layer full is the model without the key
+            object.__setattr__(
+                self, "layer_types",
+                lt if "sliding_attention" in lt else None)
+        rp = self.rope_parameters
+        if rp:
+            # dict (or its JSON round trip as nested pair lists) -> sorted
+            # tuples of pairs, hashable
+            rp = tuple(sorted(
+                (kind, tuple(sorted(dict(law).items())))
+                for kind, law in dict(rp).items()))
+        object.__setattr__(self, "rope_parameters", rp or None)
 
     @property
     def rope_scaling_dict(self) -> Optional[dict]:
         return dict(self.rope_scaling) if self.rope_scaling else None
 
+    def rope_law(self, kind: str) -> tuple:
+        """(theta, HF-style scaling dict or None) of a layer kind: its
+        section of `rope_parameters`, else the model's one law."""
+        if self.rope_parameters:
+            law = dict(dict(self.rope_parameters)[kind])
+            theta = float(law.pop("rope_theta", self.rope_theta))
+            scaled = law.get("rope_type", "default") != "default"
+            return theta, (law if scaled else None)
+        return self.rope_theta, self.rope_scaling_dict
+
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def layer_kinds(self) -> tuple:
+        """The attention kind of each layer, as published."""
+        return self.layer_types or (
+            ("full_attention",) * self.num_hidden_layers)
+
+    @property
+    def layer_period(self) -> tuple:
+        """The shortest whole period of `layer_kinds`: what one iteration
+        of the layer scans runs. ("full_attention",) for a model of one
+        kind, whose scan is over single layers as it always was."""
+        kinds = self.layer_kinds
+        for p in range(1, len(kinds) + 1):
+            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
+                return kinds[:p]
+        return kinds
 
     @property
     def expert_ffn_size(self) -> int:
@@ -541,12 +676,34 @@ class ModelConfig:
                 f"attn_impl must be one of auto/flash/reference/ring/"
                 f"ulysses/mesh, got {self.attn_impl!r}"
             )
-        if self.hidden_size % self.num_attention_heads != 0:
+        if (self.head_dim == self.hidden_size // self.num_attention_heads
+                and self.hidden_size % self.num_attention_heads != 0):
+            # a head_dim of its own (published key) lifts the convention
             raise ValueError("hidden_size must be divisible by num_attention_heads")
         if self.num_attention_heads % self.num_key_value_heads != 0:
             raise ValueError("num_attention_heads must be divisible by num_key_value_heads")
         if self.head_dim % 2 != 0:
             raise ValueError("head_dim must be even for RoPE")
+        if self.layer_types is not None:
+            if len(self.layer_types) != self.num_hidden_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_hidden_layers is {self.num_hidden_layers}")
+            bad = set(self.layer_types) - {"full_attention",
+                                           "sliding_attention"}
+            if bad:
+                raise ValueError(
+                    f"layer_types entries must be 'full_attention' or "
+                    f"'sliding_attention', got {sorted(bad)}")
+            if not self.sliding_window or self.sliding_window < 1:
+                raise ValueError(
+                    "layer_types holds sliding_attention layers: "
+                    "sliding_window must be a positive number of positions")
+        if self.rope_parameters:
+            missing = set(self.layer_kinds) - set(dict(self.rope_parameters))
+            if missing:
+                raise ValueError(
+                    f"rope_parameters has no section for {sorted(missing)}")
         if self.hidden_act not in ("silu", "gelu", "gelu_tanh"):
             raise ValueError(
                 f"hidden_act must be 'silu', 'gelu', or 'gelu_tanh', got "
@@ -790,6 +947,14 @@ class ServeConfig:
     # case (same HBM as a contiguous cache at max length). Set it
     # explicitly to actually bank the paged-cache memory win.
     num_blocks: int = 0
+    # A model with sliding-window layers keeps a second pool for them:
+    # a slot holds a fixed RING of at most
+    # ceil((sliding_window + prefill_chunk) / block_size) + 1 blocks there
+    # (serve/paged_cache.py ring_blocks_for), given at admission, while
+    # its full-attention layers grow by the block in the pool above.
+    # 0 = auto: decode_slots rings, so the window pool never refuses an
+    # admission a free slot allows. Ignored by a model of full layers.
+    num_window_blocks: int = 0
     # Prompt tokens prefilled per engine iteration; one chunk interleaves
     # with each decode step so a long prompt cannot stall in-flight
     # decodes. Also the prefill program's static shape (prompts pad to a
@@ -863,10 +1028,11 @@ class ServeConfig:
             if getattr(self, name) < 1:
                 raise ValueError(
                     f"serve.{name} must be >= 1, got {getattr(self, name)}")
-        if self.num_blocks < 0:
-            raise ValueError(
-                f"serve.num_blocks must be >= 0 (0 = auto), got "
-                f"{self.num_blocks}")
+        for name in ("num_blocks", "num_window_blocks"):
+            if getattr(self, name) < 0:
+                raise ValueError(
+                    f"serve.{name} must be >= 0 (0 = auto), got "
+                    f"{getattr(self, name)}")
         if self.max_model_len < 0:
             raise ValueError(
                 f"serve.max_model_len must be >= 0 (0 = model limit), got "
@@ -1072,31 +1238,29 @@ class Config:
                 f"({self.model.max_position_embeddings})")
         if self.model.num_experts and (self.serve.disagg
                                        or self.serve.speculator != "off"):
-            # The serving engines chunk every prefill, and MoE routing is
-            # capacity-bounded PER CALL: the same prompt split into chunks
-            # routes (and drops) tokens differently than one batched pass,
-            # so chunked prefill is not parity-guaranteed for MoE (the
-            # PR-7 KNOWN issue, now a hard error instead of a footnote).
-            # The engines themselves reject MoE at construction; this
-            # catches the intent at config load.
+            # ServeEngine serves experts (dropless at ep = 1: a token's
+            # experts depend on that token alone, whatever the chunking);
+            # the disaggregated engine's block handoff and the speculative
+            # verify scan have never run an expert block, and nothing
+            # tests them with one. Their engines reject MoE at
+            # construction; this catches the intent at config load.
             raise ValueError(
                 "serve.disagg / serve.speculator do not support MoE "
-                "models (model.num_experts > 0): chunked prefill routes "
-                "tokens through per-call capacity-bounded expert dispatch, "
-                "which is not parity-guaranteed against the offline "
-                "sampler; serve dense models only")
+                "models (model.num_experts > 0): nobody has run or tested "
+                "the disaggregated handoff or the speculative verify scan "
+                "with an expert block; serve experts through the plain "
+                "ServeEngine")
         if self.serve.fleet_size > 1 and self.model.num_experts:
-            # Same root cause as the disagg guard above: every fleet
-            # replica chunk-prefills, and failover re-dispatch replays a
-            # request's prefix through a DIFFERENT chunking on the
-            # survivor — for MoE that changes routing, so the
-            # bit-identical-failover contract cannot hold.
+            # the fleet's bit-identical failover re-dispatch is pinned by
+            # test for dense models only
             raise ValueError(
                 "serve.fleet_size > 1 does not support MoE models "
-                "(model.num_experts > 0): failover re-dispatch replays "
-                "prefixes through per-call capacity-bounded expert "
-                "dispatch, which is not parity-guaranteed; serve dense "
-                "models only")
+                "(model.num_experts > 0): failover re-dispatch is pinned "
+                "bit-identical for dense models only and has never been "
+                "run or tested with an expert block; serve experts "
+                "through one ServeEngine")
+        if self.model.layer_types is not None:
+            self._refuse_window_layers()
         if self.serve.fleet_size > 1 and self.serve.speculator != "off":
             # The n-gram drafter's context is engine-local state that a
             # failover re-dispatch does not carry — tokens stay identical
@@ -1344,7 +1508,8 @@ class Config:
                 raise ValueError(
                     "pipeline.executor='mpmd' does not support MoE models "
                     "yet (per-stage submeshes drop the 'ep' axis from the "
-                    "stage programs); use the spmd executor")
+                    "stage programs, and nothing tests an expert block "
+                    "there); use the spmd executor")
             if d.sequence_parallel:
                 raise ValueError(
                     "pipeline.executor='mpmd' does not support "
@@ -1373,6 +1538,38 @@ class Config:
                 f"pipeline.interleave > 1 requires "
                 f"pipeline.schedule='interleaved', got "
                 f"schedule={pl.schedule!r} interleave={pl.interleave}")
+
+    def _refuse_window_layers(self) -> None:
+        """Sliding-window layers run on the plain attention of
+        `forward()`, on `generate()` and on `ServeEngine`. Every path
+        that has no band refuses the model by name (ROADMAP M4: the
+        banded flash kernel for training)."""
+        d, m, t, sv = (self.distributed, self.model, self.training,
+                       self.serve)
+
+        def refuse(what: str) -> None:
+            raise ValueError(
+                f"model.layer_types holds sliding_attention layers, which "
+                f"{what} does not implement (no band in its mask); they "
+                f"run on attn_impl='reference', generate() and "
+                f"ServeEngine only")
+
+        if m.attn_impl in ("flash", "ring", "ulysses", "mesh"):
+            refuse(f"attn_impl={m.attn_impl!r}")
+        if d.cp_size > 1:
+            refuse(f"context parallelism (cp_size={d.cp_size}: the "
+                   f"ring / ulysses / mesh schedules)")
+        if t.grad_engine == "fused":
+            refuse("grad_engine='fused'")
+        if d.pp_size > 1:
+            refuse(f"pipeline parallelism (pp_size={d.pp_size}: a stage "
+                   f"slice would have to carry its place in the pattern)")
+        if d.tp_size > 1:
+            refuse(f"tensor parallelism (tp_size={d.tp_size}: the two "
+                   f"pools of a mixed cache are not sharded)")
+        if sv.disagg or sv.speculator != "off" or sv.fleet_size > 1:
+            refuse("serve.disagg / serve.speculator / serve.fleet_size > 1 "
+                   "(one pool, one table a slot)")
 
     def to_json_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
@@ -1532,17 +1729,18 @@ def num_params(m: ModelConfig, active_only: bool = False,
         ffn = h * m.num_experts + n_ffn_experts * e_ffn  # router + experts
     else:
         ffn = 3 * h * i  # gate/up/down
+    q = m.num_attention_heads * m.head_dim
     per_layer = (
-        h * h  # q_proj
+        h * q  # q_proj
         + h * kv * 2  # k/v_proj
-        + h * h  # out_proj
+        + q * h  # out_proj
         + ffn
         + 2 * h  # two RMSNorm weights
     )
     if m.attention_bias:
-        per_layer += h + 2 * kv  # q/k/v biases
+        per_layer += q + 2 * kv  # q/k/v biases
     if m.qk_norm:
-        per_layer += h + kv  # q_norm / k_norm weights
+        per_layer += q + kv  # q_norm / k_norm weights
     head = (h * v if (not m.tie_word_embeddings or include_tied_head)
             else 0)
     return v * h + l * per_layer + h + head  # embed + layers + final_norm (+ head)

@@ -15,9 +15,16 @@ TPU-first decode design:
   unexpanded in the cache (Hkv heads) and queries are grouped at score
   time, so cache memory is Hkv/Hq of the naive layout.
 - **Weight-compatible with training**: same param pytree (train ->
-  generate without conversion), same RoPE/RMSNorm helpers, and the MLP /
-  MoE blocks are the training ones (a Mixtral checkpoint decodes through
-  the same capacity-bounded expert dispatch it trained with).
+  generate without conversion), same RoPE/RMSNorm helpers and dense MLP
+  block; a model with experts decodes through the dropless mathematics
+  it trains with at ep = 1 (`ops/moe.py moe_mlp_served`: few rows go
+  through every expert densely, many through the grouped matmuls; rows
+  without a token are routed nowhere).
+- **A layer pattern**: a model whose layers are of two kinds (sliding
+  window and full attention, each with its own RoPE law) is scanned a
+  whole period at a time, each layer of the body traced with its kind;
+  the cache is told the kind (`window`) and the layer's ordinal among
+  its kind (`ki`).
 
 Decode at target scale (VERDICT r3 weak #6 — a trained Llama-2-7B's fp32
 master cannot be sampled on one 16 GB chip):
@@ -48,9 +55,11 @@ from jax import lax
 
 from picotron_tpu.config import ModelConfig
 from picotron_tpu.models.llama import (
-    DEFAULT_CTX, _mlp_block, _moe_block, compute_dtype, final_hidden,
-    head_weight, model_rope_tables, qkv_proj, rms_norm,
+    DEFAULT_CTX, _mlp_block, by_period, compute_dtype, final_hidden,
+    head_weight, kind_tables, layer_window, mlp_act, model_rope_tables,
+    qkv_proj, rms_norm,
 )
+from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope
 from picotron_tpu.telemetry.scopes import scope
 
@@ -63,7 +72,12 @@ class KVCache(NamedTuple):
     interface — `num_layers`, `write(li, k, v, q_pos)`,
     `layer_view(li)`, `attend(li, q, q_pos)` — so the layer loop is
     cache-agnostic and greedy parity between the two is a test invariant,
-    not an accident."""
+    not an accident. A model with sliding-window layers passes two more
+    keywords, `window` (the layer's band, None on a full layer) and `ki`
+    (the layer's ordinal among the layers of its kind): this cache keeps
+    every position of every layer and only masks the band; the serving
+    cache for such a model (`serve.paged_cache.MixedPagedKVCache`) keeps a
+    ring of blocks for the sliding layers."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -72,7 +86,8 @@ class KVCache(NamedTuple):
     def num_layers(self) -> int:
         return self.k.shape[0]
 
-    def write(self, li, k_new, v_new, q_pos) -> "KVCache":
+    def write(self, li, k_new, v_new, q_pos, window=None,
+              ki=None) -> "KVCache":
         """Write this segment's K/V [B, s, Hkv, D] into slots
         q_pos[0]..q_pos[-1] of layer li. Contiguous slots only: needs the
         batch-shared [s] positions form (every sequence at the same
@@ -90,10 +105,11 @@ class KVCache(NamedTuple):
         return (lax.dynamic_index_in_dim(self.k, li, 0, keepdims=False),
                 lax.dynamic_index_in_dim(self.v, li, 0, keepdims=False))
 
-    def attend(self, li, q, q_pos):
+    def attend(self, li, q, q_pos, window=None, ki=None):
         """Attention of q [B, s, Hq, D] at positions q_pos over layer
-        li's cached positions: the layer's slice, attended whole."""
-        return _cached_attention(q, *self.layer_view(li), q_pos)
+        li's cached positions: the layer's slice, attended whole (a
+        sliding layer's band is a mask)."""
+        return _cached_attention(q, *self.layer_view(li), q_pos, window)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_length: int) -> KVCache:
@@ -119,13 +135,14 @@ def _rope(x, cos, sin, q_pos):
                            axis=-1).astype(x.dtype)
 
 
-def _cached_attention(q, ck, cv, q_pos):
+def _cached_attention(q, ck, cv, q_pos, window=None):
     """q: [B, s, Hq, D] at global positions q_pos ([s] batch-shared or
     [B, s] per-sequence); ck/cv: [B, S_max, Hkv, D] with slot j holding
     the token at position j (zeros/stale beyond the filled length —
     masked out by causality, since every filled slot index <= max(q_pos);
     exact zeros under softmax leave the valid rows bit-identical for any
-    S_max). Returns [B, s, Hq, D]."""
+    S_max). `window`: a sliding layer's band, position i sees j with
+    0 <= i - j < window. Returns [B, s, Hq, D]."""
     b, s, hq, d = q.shape
     s_max, hkv = ck.shape[1], ck.shape[2]
     group = hq // hkv
@@ -138,6 +155,8 @@ def _cached_attention(q, ck, cv, q_pos):
     # stream for positions whose output IS discarded, but which still
     # flows through later layers)
     mask = jnp.arange(s_max) <= jnp.maximum(q_pos, 0)[..., None]
+    if window is not None:
+        mask &= jnp.arange(s_max) > jnp.maximum(q_pos, 0)[..., None] - window
     if mask.ndim == 2:          # [s, S_max] batch-shared
         mask = mask[None]
     mask = mask[:, None, None]  # [B|1, 1, 1, s, S_max]
@@ -147,14 +166,25 @@ def _cached_attention(q, ck, cv, q_pos):
     return out.reshape(b, s, hq, d)
 
 
-def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
+def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
+                   with_touched: bool = False):
     """Run every layer over x [B, s, H] (prefill: s = prompt length,
     decode: s = 1), writing this segment's K/V into the cache at positions
     q_pos. Cache-agnostic: `cache` is any object with num_layers /
-    write / attend (contiguous KVCache here, PagedKVCache in
-    picotron_tpu/serve). Returns (hidden, cache)."""
+    write / attend (contiguous KVCache here, PagedKVCache or
+    MixedPagedKVCache in picotron_tpu/serve). Returns (hidden, cache), and
+    with `with_touched` a third value: the experts that at least one row
+    with a token (q_pos >= 0) was routed to, summed over the layers (0 for
+    a dense model): what decides the expert bytes a step streams."""
     dt = x.dtype
     d = cfg.head_dim
+    period = cfg.layer_period
+    # a model of full layers calls the cache as it always has; one with
+    # sliding layers says which kind each layer is
+    mixed = cfg.layer_types is not None
+    live = q_pos >= 0
+    if live.ndim == 1:
+        live = jnp.broadcast_to(live[None, :], x.shape[:2])
 
     # The cache rides the scan CARRY with per-layer in-place writes of
     # only the new token slots (as xs/ys the scan stacks fresh ys buffers
@@ -166,33 +196,80 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin):
     # (the compiled serve programs carry both pools as
     # {4,3,2,1,0:T(8,128)(2,1)} and hold no pool-sized copy;
     # tests/test_chip_compile.py).
-    def body(carry, inputs):
-        x, cache = carry
-        lp, li = inputs
+    def layer(x, cache, lp, li, kind, ki):
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
         b, s, _ = h.shape
         q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
-        q = _rope(q, cos, sin, q_pos)
-        k = _rope(k, cos, sin, q_pos)
-        cache = cache.write(li, k, v, q_pos)
+        c_k, s_k = kind_tables(cos, sin, kind)
+        q = _rope(q, c_k, s_k, q_pos)
+        k = _rope(k, c_k, s_k, q_pos)
+        how = (dict(window=layer_window(cfg, kind), ki=ki) if mixed else {})
+        cache = cache.write(li, k, v, q_pos, **how)
         # named for the serve programs: the decode step reads the blocks
         # a slot holds in place (ops/paged_attention.py), prefill chunks
         # gather their rows' views out of the pool (the contiguous cache's
         # is a slice)
         with scope("paged_attention"):
-            out = cache.attend(li, q, q_pos)
+            out = cache.attend(li, q, q_pos, **how)
         out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
         x = x + out
         if cfg.num_experts:
-            mlp_out, _ = _moe_block(x, lp, cfg, DEFAULT_CTX)
+            mlp_out, touched = _moe_served_block(x, lp, banks, li, cfg, live)
         else:
-            mlp_out = _mlp_block(x, lp, cfg, DEFAULT_CTX)
-        return (x + mlp_out, cache), None
+            mlp_out, touched = _mlp_block(x, lp, cfg, DEFAULT_CTX), None
+        return x + mlp_out, cache, touched
 
-    (x, cache), _ = lax.scan(
-        body, (x, cache),
-        (params["layers"], jnp.arange(cache.num_layers)))
+    # one scan iteration runs one whole period of the layer pattern
+    # (models/llama.py run_layers): a layer's kind is static in the body
+    n_before = [period[:j].count(kind) for j, kind in enumerate(period)]
+
+    def body(carry, inputs):
+        x, cache, touched = carry
+        lp, p = inputs
+        if len(period) == 1:
+            x, cache, t = layer(x, cache, lp, p, period[0], p)
+            return (x, cache, touched if t is None else touched + t), None
+        for j, kind in enumerate(period):
+            x, cache, t = layer(
+                x, cache, jax.tree.map(lambda w: w[j], lp),
+                p * len(period) + j, kind,
+                p * period.count(kind) + n_before[j])
+            touched = touched if t is None else touched + t
+        return (x, cache, touched), None
+
+    # the expert banks stay whole, outside the scanned inputs: a layer's
+    # grouped matmuls address its experts inside the stack (ops/moe.py
+    # _dropless_experts), so no bank is sliced out a layer
+    layers = {n: w for n, w in params["layers"].items() if n not in BANKS}
+    banks = {n: params["layers"].get(n) for n in BANKS}
+    if len(period) > 1:
+        layers = by_period(layers, len(period))
+    # a dense model carries no counter: its programs are what they were
+    touched0 = jnp.zeros((), jnp.int32) if cfg.num_experts else None
+    (x, cache, touched), _ = lax.scan(
+        body, (x, cache, touched0),
+        (layers, jnp.arange(cache.num_layers // len(period))))
+    if with_touched:
+        return x, cache, (touched if touched is not None
+                          else jnp.zeros((), jnp.int32))
     return x, cache
+
+
+BANKS = ("w_gate", "w_up", "w_down")  # the experts' stacks [L, E, ...]
+
+
+@scope("mlp")
+def _moe_served_block(x, lp, banks, li, cfg: ModelConfig, live):
+    """RMSNorm -> routed experts, dropless (every expert is on this
+    device: the decode paths run at ep = 1), as `models.llama._moe_block`
+    computes them; rows without a token (`live` false: idle slots,
+    chunk padding) are routed nowhere. `banks`: the model's whole expert
+    stacks, of which this is layer `li`. Returns (out, experts touched)."""
+    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+    return moe_mlp_served(
+        h, lp["router"], *(banks[n] for n in BANKS),
+        top_k=cfg.num_experts_per_token, act=mlp_act(cfg),
+        norm_topk_prob=cfg.norm_topk_prob, live=live, layer=li)
 
 
 def _logits_last(params, x, cfg: ModelConfig):

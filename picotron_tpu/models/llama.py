@@ -48,9 +48,47 @@ from picotron_tpu.telemetry.scopes import scope
 def model_rope_tables(cfg, max_len=None):
     """RoPE tables for a model config, honoring cfg.rope_scaling
     (Llama-3.1/3.2). All model-level paths must build tables through this
-    helper so scaling cannot be silently dropped on one path."""
-    return rope_tables(max_len or cfg.max_position_embeddings, cfg.head_dim,
-                       cfg.rope_theta, rope_scaling=cfg.rope_scaling_dict)
+    helper so scaling cannot be silently dropped on one path.
+
+    (cos, sin), each one table for a model whose layers all rotate by one
+    law, or a dict {layer kind: table} for one that publishes a law a kind
+    (`rope_parameters`: Mellum2's full layers rotate by YaRN, its sliding
+    layers unscaled). `kind_tables` picks a layer's pair from either."""
+    n = max_len or cfg.max_position_embeddings
+    if not cfg.rope_parameters:
+        return rope_tables(n, cfg.head_dim, cfg.rope_theta,
+                           rope_scaling=cfg.rope_scaling_dict)
+    pairs = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        theta, scaling = cfg.rope_law(kind)
+        pairs[kind] = rope_tables(n, cfg.head_dim, theta,
+                                  rope_scaling=scaling)
+    return ({k: p[0] for k, p in pairs.items()},
+            {k: p[1] for k, p in pairs.items()})
+
+
+def kind_tables(cos, sin, kind: str):
+    """The (cos, sin) a layer of `kind` rotates by, out of what
+    `model_rope_tables` returned."""
+    return (cos[kind], sin[kind]) if isinstance(cos, dict) else (cos, sin)
+
+
+def layer_window(cfg, kind: str):
+    """The band of a layer of `kind`: `sliding_window` positions on a
+    sliding layer, None (every earlier position) on a full one."""
+    return cfg.sliding_window if kind == "sliding_attention" else None
+
+
+def by_period(layer_tree, period: int):
+    """A [L, ...]-stacked layer tree as [L / period, period, ...]: what a
+    scan over whole periods of the layer pattern iterates over."""
+    def split(x):
+        if x.shape[0] % period:
+            raise ValueError(
+                f"{x.shape[0]} stacked layers are not whole periods of "
+                f"{period} layers")
+        return x.reshape(x.shape[0] // period, period, *x.shape[1:])
+    return jax.tree.map(split, layer_tree)
 
 Params = dict[str, Any]
 
@@ -64,13 +102,14 @@ def _identity(x):
     return x
 
 
-def _default_attn(q, k, v, positions, rope):
+def _default_attn(q, k, v, positions, rope, window=None):
     # q/k arrive unrotated: each attention impl owns RoPE so the flash path
     # can rotate inside its kernels (parallel/api.py) while reference paths
-    # use the jnp rotation.
+    # use the jnp rotation. `window`: a sliding layer's band; the only
+    # impl that has one (Config.validate refuses the others by name).
     q = apply_rope(q, *rope, positions)
     k = apply_rope(k, *rope, positions)
-    return sdpa_attention(q, k, v, causal=True,
+    return sdpa_attention(q, k, v, causal=True, window=window,
                           q_positions=positions, kv_positions=positions)
 
 
@@ -339,8 +378,11 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5):
 
 
 @scope("attention")
-def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
-    """RMSNorm -> qkv -> RoPE -> attention -> out_proj (ref: model.py:122-162)."""
+def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
+                     kind: str = "full_attention"):
+    """RMSNorm -> qkv -> RoPE -> attention -> out_proj (ref: model.py:122-162).
+    `kind`: the layer's attention kind, which picks its RoPE tables and
+    its band."""
     dt = x.dtype
     d = cfg.head_dim
 
@@ -359,7 +401,11 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     # K/V stay unexpanded (n_kv heads) — attention impls handle GQA so the
     # CP ring permutes and flash streams the small K/V. RoPE is applied by
     # the impl (in-kernel on the flash path), so q/k pass through raw.
-    out = ctx.attn(q, k, v, ctx.positions, (cos, sin))  # [B, S, n_q, D]
+    rope, window = kind_tables(cos, sin, kind), layer_window(cfg, kind)
+    # the band goes only to an impl that was asked for one: the others
+    # keep their signature, and never see a model with sliding layers
+    band = {} if window is None else {"window": window}
+    out = ctx.attn(q, k, v, ctx.positions, rope, **band)  # [B, S, n_q, D]
     # attn_out/attn_lse are checkpoint_name'd inside each attention impl
     # (flash VJP fwd rule / sdpa), so the "dots" remat policy saves the
     # kernel residuals exactly once and backward never re-runs the forward.
@@ -429,14 +475,14 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
 
 
 def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
-                  is_real=1.0):
+                  is_real=1.0, kind: str = "full_attention"):
     """Returns (x, aux [3]) — aux[0] is the pre-weighted router loss
     (balance + z, 0 for dense models), aux[1] the capacity drop fraction
     and aux[2] the busiest expert's load over the mean (observability;
     stop_gradient-free but weightless in the loss).
     `is_real` masks the aux of zero-padded PP layer slots (see
     ParallelCtx.layer_is_real)."""
-    x = x + _attention_block(x, lp, cfg, ctx, cos, sin)
+    x = x + _attention_block(x, lp, cfg, ctx, cos, sin, kind)
     if cfg.num_experts:
         mlp_out, aux = _moe_block(x, lp, cfg, ctx, is_real)
     else:
@@ -518,10 +564,20 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     aux[2] the summed busiest-expert load ratio (all 0 for dense models)."""
     if cos is None:
         cos, sin = model_rope_tables(cfg)
+    # one scan iteration runs one whole period of the layer pattern: one
+    # layer for a model of one kind, (S, S, S, F) for Mellum2, each layer
+    # of the body traced with its own kind (tables, band)
+    period = cfg.layer_period
 
     def body(h, xs):
         lp, real = xs
-        h, aux = decoder_layer(h, lp, cfg, ctx, cos, sin, real)
+        if len(period) == 1:
+            return decoder_layer(h, lp, cfg, ctx, cos, sin, real, period[0])
+        aux = jnp.zeros(3, jnp.float32)
+        for j, kind in enumerate(period):
+            h, a = decoder_layer(h, jax.tree.map(lambda w: w[j], lp), cfg,
+                                 ctx, cos, sin, real[j], kind)
+            aux = aux + a
         # aux rides the scan's stacked outputs (not the carry: its varying
         # mesh axes differ from x's, which would unstabilize the carry type)
         return h, aux
@@ -529,9 +585,12 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     n_slots = jax.tree.leaves(layer_params)[0].shape[0]
     real = (ctx.layer_is_real(n_slots) if ctx.layer_is_real is not None
             else jnp.ones((n_slots,), jnp.float32))
+    xs = (layer_params, real)
+    if len(period) > 1:
+        xs = by_period(xs, len(period))
     if ctx.remat:
         body = jax.checkpoint(body, policy=remat_policy_for(ctx.remat_policy))
-    x, aux_per_layer = jax.lax.scan(body, x, (layer_params, real))  # [L, 3]
+    x, aux_per_layer = jax.lax.scan(body, x, xs)  # [L / period, 3]
     return x, jnp.sum(aux_per_layer, axis=0)
 
 

@@ -111,6 +111,7 @@ def sdpa_attention(
     kv_positions: jnp.ndarray | None = None,
     return_lse: bool = False,
     sm_scale: float | None = None,
+    window: int | None = None,
 ):
     """Scaled dot-product attention.
 
@@ -122,6 +123,9 @@ def sdpa_attention(
     q_positions/kv_positions: optional global position vectors; the causal
         mask is `q_pos >= kv_pos`, which generalizes to context-parallel
         shards where local index != global position.
+    window: a sliding-window layer's band: position i sees j only where
+        `0 <= i - j < window` (itself and the window - 1 before it).
+        None = every j <= i.
 
     Returns out [batch, q_len, q_heads, head_dim] (and lse
     [batch, q_heads, q_len] fp32 if return_lse).
@@ -142,7 +146,11 @@ def sdpa_attention(
         qp = q_positions if q_positions is not None else jnp.arange(sq)
         kp = kv_positions if kv_positions is not None else jnp.arange(sk)
         mask = qp[:, None] >= kp[None, :]  # [Sq, Sk]
+        if window is not None:
+            mask &= qp[:, None] - kp[None, :] < window
         scores = jnp.where(mask[None, None, :, :], scores, -jnp.inf)
+    elif window is not None:
+        raise ValueError("a sliding window needs the causal mask")
 
     m = jnp.max(scores, axis=-1, keepdims=True)
     # Fully-masked rows (non-square blocks in the CP ring) have m = -inf and

@@ -61,10 +61,30 @@ class Routing(NamedTuple):
     #                           dropless dispatch's group sizes)
 
 
+def topk_gates(logits, k: int, norm_topk_prob: bool):
+    """(probs [N, E], chosen experts [N, k], gates [N, k]) of float32
+    router logits: softmax over all E, the k largest. Mixtral renormalizes
+    the k selected probabilities to sum to 1; OLMoE (norm_topk_prob false)
+    combines with the raw probabilities."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = lax.top_k(probs, k)
+    gate = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if norm_topk_prob else top_p)
+    return probs, top_i, gate
+
+
 def route_topk(logits: jnp.ndarray, k: int,
                stat_axes: Optional[tuple] = None,
-               norm_topk_prob: bool = True) -> Routing:
+               norm_topk_prob: bool = True,
+               live: Optional[jnp.ndarray] = None) -> Routing:
     """Top-k routing with slots assigned in token order.
+
+    `live` [N] bool (the serving programs: rows that carry a token): a row
+    that is not live is assigned to no expert. Its k assignments go to a
+    group of their own behind the last expert's (`counts` then has E + 1
+    entries, the last the dead assignments), so they appear in no
+    expert's group size, the grouped matmuls skip their rows, and no
+    expert's weights are read on their account.
 
     logits: [N, E] fp32 router outputs. Slot assignment is deterministic in
     token order (first-come priority); the CALLER drops assignments whose
@@ -82,18 +102,17 @@ def route_topk(logits: jnp.ndarray, k: int,
     """
     n, e = logits.shape
     logits = logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                       # [N, E]
-    top_p, top_i = lax.top_k(probs, k)                            # [N, k]
-    # Mixtral renormalizes the k selected probabilities to sum to 1;
-    # OLMoE (norm_topk_prob false) combines with the raw probabilities.
-    gate = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            if norm_topk_prob else top_p)
+    probs, top_i, gate = topk_gates(logits, k, norm_topk_prob)
 
     # slot_in_expert: for assignment (token t, choice j) -> how many earlier
     # assignments went to the same expert. Flatten [N, k] in token-major
     # order, one-hot over E, exclusive cumsum down the assignment axis.
     flat_e = top_i.reshape(-1)                                    # [N*k]
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)           # [N*k, E]
+    groups = e
+    if live is not None:
+        top_i = jnp.where(live[:, None], top_i, e)
+        flat_e, groups = top_i.reshape(-1), e + 1
+    onehot = jax.nn.one_hot(flat_e, groups, dtype=jnp.int32)      # [N*k, E]
     prior = jnp.cumsum(onehot, axis=0) - onehot                   # exclusive
     slot = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]
     slot = slot.reshape(n, k)
@@ -106,6 +125,8 @@ def route_topk(logits: jnp.ndarray, k: int,
     # Equal-sized token shards make pmean-of-means the exact global mean.
     f = stat_mean(
         jnp.mean(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=(0, 1)))
+    # (with `live`, f and the z-loss count dead rows too: they are training
+    # statistics, and the serving programs that pass `live` drop them)
     p = stat_mean(jnp.mean(probs, axis=0))
     aux = e * jnp.sum(f * p)
 
@@ -175,10 +196,20 @@ _assignments_from_sorted.defvjp(_assignments_from_sorted_fwd,
                                 _assignments_from_sorted_bwd)
 
 
-def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
+def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act,
+                      layer=None):
     """Every assignment through its expert, no capacity: flat [N, H] ->
     [N, H] (gates applied, summed over k). Requires the whole bank
-    [E, H, F] / [E, F, H] on the device."""
+    [E, H, F] / [E, F, H] on the device.
+
+    `layer` (the decode paths): the banks are the model's whole stacks
+    [L, E, H, F] / [L, E, F, H] and `layer` (traced or not) says whose
+    experts these are. The grouped matmuls then run over L * E groups of
+    which only this layer's E have rows: a group without rows costs no
+    read, and no layer's bank is sliced out of the stack first. (Sliced,
+    the compiler materialises each layer's three banks as the custom
+    call's operands: a second read and a write of every expert's weights
+    in a step whose time is reading them once.)"""
     n, h = flat.shape
     k = r.expert_idx.shape[1]
     dt = flat.dtype
@@ -193,13 +224,104 @@ def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
     with scope("moe_dispatch"):
         xs = _sorted_from_tokens(flat, row, inv)                  # [N*k, H]
     with scope("moe_experts"):
-        g = lax.ragged_dot(xs, w_gate.astype(dt), r.counts)
-        u = lax.ragged_dot(xs, w_up.astype(dt), r.counts)
-        ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), r.counts)
+        # the experts' group sizes: all of `counts`, less the dead rows'
+        # group where the router was told of them (route_topk `live`); a
+        # grouped matmul leaves the rows past its last group zero
+        e = w_gate.shape[-3]
+        sizes = r.counts if r.counts.shape[0] == e else r.counts[:e]
+        if layer is not None:
+            n_layers = w_gate.shape[0]
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros((n_layers * e,), sizes.dtype), sizes,
+                (jnp.asarray(layer, jnp.int32) * e,))
+            w_gate, w_up, w_down = (w.reshape(n_layers * e, *w.shape[2:])
+                                    for w in (w_gate, w_up, w_down))
+        g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
+        u = lax.ragged_dot(xs, w_up.astype(dt), sizes)
+        ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), sizes)
     with scope("moe_dispatch"):
         picked = _assignments_from_sorted(ys, row, inv)           # [N, k, H]
         out = jnp.sum(picked.astype(jnp.float32) * r.gate[..., None], axis=1)
     return out.astype(dt)
+
+
+# Tokens up to which the decode paths put every row through every expert
+# (`_every_expert`) instead of the grouped matmuls. On this chip the
+# compiler's grouped-matmul kernel costs about 35 us a group however few
+# rows the group has (PERF.md section 6, PR 33: 32 rows x 8 assignments over
+# 62 experts, 5.6 ms a layer where reading the three banks takes 1.0), while
+# the dense form streams the banks at 89% of the memory roofline; it does
+# E / k times the operations, which stops paying between 1,024 and 2,048
+# tokens (1,024: 4.3 against 7.0 ms a layer; 8,192 would be 33 against 9).
+EVERY_EXPERT_UP_TO = 1024
+
+
+def _every_expert(flat, top_i, gate, live, w_gate, w_up, w_down, act):
+    """Every row through EVERY expert, the gate as the weight (0 for an
+    expert the row did not choose, and for a row without a token): the
+    mathematics of the dropless dispatch as three dense matmuls over the
+    whole bank, gate folded into the activation so that the down
+    projection is one [N, E * F] x [E * F, H] product. flat [N, H];
+    top_i / gate [N, k]; banks [E, H, F] / [E, F, H]. For few rows: a
+    decode step reads (nearly) every expert's weights anyway, and reads
+    them here at the speed of a dense matmul."""
+    n, e = flat.shape[0], w_gate.shape[0]
+    dt = flat.dtype
+    with scope("moe_dispatch"):
+        dense = jnp.zeros((n, e), jnp.float32).at[
+            jnp.arange(n)[:, None], top_i].set(gate)
+        dense = jnp.where(live[:, None], dense, 0.0)
+    with scope("moe_experts"):
+        g = jnp.einsum("nh,ehf->nef", flat, w_gate.astype(dt))
+        u = jnp.einsum("nh,ehf->nef", flat, w_up.astype(dt))
+        a = ((act(g) * u).astype(jnp.float32) * dense[..., None]).astype(dt)
+        out = jnp.einsum("nef,efh->nh", a, w_down.astype(dt),
+                         preferred_element_type=jnp.float32)
+    return out.astype(dt), dense
+
+
+def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                   act, norm_topk_prob: bool, live, layer):
+    """The expert block of the decode paths (`generate`, the serve
+    programs): `moe_mlp`'s router and dropless mathematics, every expert
+    on this device, no loss terms, and `live` [B, S] saying which rows
+    carry a token (idle slots and chunk padding do not: they are routed
+    nowhere). The banks are the model's whole stacks [L, E, ...] and
+    `layer` this layer's index in them. A token's experts depend on that
+    token alone, so chunking a prompt differently changes nothing.
+
+    One mathematics, two forms, chosen from the number of rows alone: up
+    to EVERY_EXPERT_UP_TO tokens every row goes through every expert
+    densely (`_every_expert`), above it the rows are permuted into expert
+    order and go through the grouped matmuls (`_dropless_experts`).
+
+    Returns (out [B, S, H], touched []): the experts at least one live
+    row was routed to, which is what a decode step NEEDS of the expert
+    banks (the dense form reads all of them)."""
+    b, s, h = x.shape
+    n, e = b * s, router_w.shape[1]
+    flat = x.reshape(n, h)
+    live = live.reshape(-1)
+    with scope("moe_router"):
+        logits = (flat.astype(jnp.float32)
+                  @ router_w.astype(jnp.float32))                 # [N, E] fp32
+    if n > EVERY_EXPERT_UP_TO:
+        with scope("moe_router"):
+            r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
+                           live=live)
+            touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
+        out = _dropless_experts(flat, r, w_gate, w_up, w_down, act,
+                                layer=layer)
+        return out.reshape(b, s, h), touched
+    with scope("moe_router"):
+        _, top_i, gate = topk_gates(logits, top_k, norm_topk_prob)
+    out, dense = _every_expert(
+        flat, top_i, gate, live,
+        *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+          for w in (w_gate, w_up, w_down)), act)
+    with scope("moe_router"):
+        touched = jnp.sum(jnp.any(dense > 0.0, axis=0)).astype(jnp.int32)
+    return out.reshape(b, s, h), touched
 
 
 def moe_mlp(

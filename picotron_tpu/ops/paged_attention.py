@@ -35,6 +35,12 @@ softmax over chunks of pages and the double buffer are taken):
   position, and V's rows there are zeroed, so whatever a partly filled block
   or a stale buffer holds beyond the length (NaN included) cannot reach the
   output. A slot of length 0 returns zeros.
+- **A sliding-window layer** (`window`) reads from the block that holds
+  position `length - window` on, not from block 0, and its table is a RING:
+  logical block `b` lives at table entry `b % max_blocks`
+  (`serve/paged_cache.py`, the window pool). Positions before the band are
+  masked by position, as those at or beyond the length are. With
+  `window=None` the kernel is the one it was, instruction for instruction.
 """
 
 from __future__ import annotations
@@ -80,21 +86,30 @@ def decode_kernel_suits(q, k_pool) -> bool:
 
 def _kernel(lengths_ref, tables_ref, li_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, *, sm_scale: float, pages_per_chunk: int,
-            max_blocks: int):
+            max_blocks: int, window: Optional[int]):
     b = pl.program_id(0)
     hkv, _, num_blocks, bs, d = k_hbm.shape
     chunk = pages_per_chunk * bs
     li = li_ref[0]
     length = lengths_ref[b]
-    n_pages = jnp.minimum(pl.cdiv(length, bs), max_blocks)
+    if window is None:
+        n_pages = jnp.minimum(pl.cdiv(length, bs), max_blocks)
+    else:
+        # the band's first position and the logical block that holds it;
+        # the pages read are that block .. the block of position length - 1
+        lo = jnp.maximum(length - window, 0)
+        first = lo // bs
+        n_pages = jnp.minimum(pl.cdiv(length, bs) - first, max_blocks)
     n_chunks = pl.cdiv(n_pages, pages_per_chunk)
 
     def copies(c, buf, j):
         # the unmapped sentinel (num_blocks) never addresses a read; no page
         # below a live slot's length is unmapped, so the clamp changes none
-        page = jnp.minimum(
-            tables_ref[b * max_blocks + c * pages_per_chunk + j],
-            num_blocks - 1)
+        entry = c * pages_per_chunk + j
+        if window is not None:
+            entry = (first + entry) % max_blocks  # the ring
+        page = jnp.minimum(tables_ref[b * max_blocks + entry],
+                           num_blocks - 1)
         return (pltpu.make_async_copy(k_hbm.at[:, li, page],
                                       k_buf.at[buf, :, j], sems.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[:, li, page],
@@ -127,9 +142,18 @@ def _kernel(lengths_ref, tables_ref, li_ref, q_ref, k_hbm, v_hbm, o_ref,
             start(c + 1, 1 - buf)
 
         wait(c, buf)
-        live = length - c * chunk  # positions of this chunk below the length
-        col = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < live
-        row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) < live
+        if window is None:
+            live = length - c * chunk  # positions of this chunk below the length
+            col = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < live
+            row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) < live
+        else:
+            # the chunk's element i holds position at0 + i: inside the band
+            # where lo <= position < length
+            at0 = first * bs + c * chunk
+            col = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) + at0
+            col = (col >= lo) & (col < length)
+            row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) + at0
+            row = (row >= lo) & (row < length)
         out = []
         for h in range(hkv):
             m_prev, l_prev, acc = carry[h]
@@ -160,6 +184,7 @@ def _kernel(lengths_ref, tables_ref, li_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
+                           window: Optional[int] = None,
                            pages_per_chunk: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Attention of one query position a slot over that slot's cached
@@ -170,6 +195,11 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
     logical block -> physical block, `num_blocks` = unmapped; lengths [B]
     int32: slot b attends positions 0 .. lengths[b] - 1 (0: nothing is
     read, the row is zeros). Returns [B, Hq, D] in q's dtype.
+
+    `window`: a sliding layer. Slot b attends positions
+    max(lengths[b] - window, 0) .. lengths[b] - 1, and `tables` is its ring
+    in the window pool: logical block j at entry j % max_blocks. The ring
+    has to hold the band (max_blocks * block_size >= window + block_size).
 
     `interpret=None` compiles the kernel on a TPU backend and runs the
     Pallas interpreter anywhere else (the CPU unit tests); the caller
@@ -187,8 +217,12 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
                          f"match q {q.shape}")
     g = hq // hkv
     ppc = min(pages_per_chunk or DEFAULT_PAGES_PER_CHUNK, max_blocks)
+    if window is not None and max_blocks * bs < window + bs:
+        raise ValueError(f"a ring of {max_blocks} blocks of {bs} cannot "
+                         f"hold a band of {window} positions")
     kernel = functools.partial(_kernel, sm_scale=1.0 / d ** 0.5,
-                               pages_per_chunk=ppc, max_blocks=max_blocks)
+                               pages_per_chunk=ppc, max_blocks=max_blocks,
+                               window=window)
     q_spec = pl.BlockSpec((None, hkv, g, d), lambda i, *_: (i, 0, 0, 0))
     out = pl.pallas_call(
         kernel,

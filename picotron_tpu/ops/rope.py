@@ -3,8 +3,10 @@
 Matches the reference semantics (ref: picotron/model.py:12-31): non-interleaved
 "rotate-half" RoPE with HF-compatible frequencies, tables computed in fp32 and
 cast to the compute dtype at application time. One table pair serves all
-layers (the reference recomputes identical tables per layer,
-ref: model.py:199 — a pure waste we drop).
+layers of one kind (the reference recomputes identical tables per layer,
+ref: model.py:199 — a pure waste we drop); a model whose sliding-window
+and full layers rotate by different laws has a pair a kind
+(`models.llama.model_rope_tables`).
 
 For context parallelism each cp shard applies the table rows of its own
 contiguous sequence slice (ref: context_parallel.py:189-195); callers pass the
@@ -12,6 +14,8 @@ global positions of their local tokens instead of slicing tables by hand.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -41,16 +45,43 @@ def llama3_scale_freqs(inv_freq: jnp.ndarray, factor: float = 8.0,
     return scaled
 
 
+def yarn_scale_freqs(inv_freq: jnp.ndarray, head_dim: int, base: float,
+                     factor: float, original_max_position: int,
+                     beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> jnp.ndarray:
+    """YaRN (Peng et al. 2023; transformers' `_compute_yarn_parameters`,
+    `truncate` at its default): frequencies that turn more than
+    `beta_fast` times over the original context are kept (extrapolated),
+    those that turn fewer than `beta_slow` times are divided by `factor`
+    (interpolated), and a linear ramp over the dimension index blends the
+    two between. The attention factor multiplies cos and sin, not the
+    frequencies (`rope_tables`)."""
+    def dim_of(rotations: float) -> float:
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2.0 * math.pi))
+                / (2.0 * math.log(base)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    span = max(high - low, 0.001)  # transformers' guard for low == high
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / span, 0.0, 1.0)
+    return (1.0 - ramp) * inv_freq + ramp * inv_freq / factor
+
+
 def rope_tables(max_seq_len: int, head_dim: int, base: float = 10000.0,
                 rope_scaling: dict | None = None):
     """Precompute cos/sin tables, shape [max_seq_len, head_dim // 2], fp32.
 
     `rope_scaling`: optional HF-style dict; supported `rope_type`s:
-    "llama3" (Llama-3.1/3.2 frequency banding) and "linear" (positions
-    divided by `factor`)."""
+    "llama3" (Llama-3.1/3.2 frequency banding), "linear" (positions
+    divided by `factor`) and "yarn" (`yarn_scale_freqs`; cos and sin are
+    both multiplied by `attention_factor`, 0.1 ln(factor) + 1 where the
+    key is absent)."""
     assert head_dim % 2 == 0, "head_dim must be even for RoPE"
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     inv_freq = 1.0 / (base ** exponent)  # [head_dim/2]
+    amplitude = 1.0
     if rope_scaling:
         kind = rope_scaling.get("rope_type", rope_scaling.get("type"))
         if kind == "llama3":
@@ -63,12 +94,23 @@ def rope_tables(max_seq_len: int, head_dim: int, base: float = 10000.0,
                     "original_max_position_embeddings", 8192))
         elif kind == "linear":
             inv_freq = inv_freq / rope_scaling.get("factor", 1.0)
+        elif kind == "yarn":
+            factor = float(rope_scaling["factor"])
+            inv_freq = yarn_scale_freqs(
+                inv_freq, head_dim, base, factor,
+                int(rope_scaling["original_max_position_embeddings"]),
+                float(rope_scaling.get("beta_fast", 32.0)),
+                float(rope_scaling.get("beta_slow", 1.0)))
+            amplitude = float(rope_scaling.get("attention_factor")
+                              or 0.1 * math.log(factor) + 1.0)
         else:
             raise ValueError(
                 f"unsupported rope_scaling type {kind!r} (supported: "
-                f"'llama3', 'linear')")
+                f"'llama3', 'linear', 'yarn')")
     positions = jnp.arange(max_seq_len, dtype=jnp.float32)[:, None]  # [S, 1]
     angles = positions * inv_freq[None, :]  # [S, head_dim/2]
+    if amplitude != 1.0:
+        return jnp.cos(angles) * amplitude, jnp.sin(angles) * amplitude
     return jnp.cos(angles), jnp.sin(angles)
 
 

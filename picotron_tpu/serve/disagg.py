@@ -120,14 +120,14 @@ class DisaggServeEngine(ServeEngine):
                  engine_id: int = 0):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
-        if model_cfg.num_experts:
+        if model_cfg.num_experts or model_cfg.layer_types is not None:
             raise ValueError(
-                "serving does not support MoE models (num_experts > 0): "
-                "chunked prefill feeds each chunk through per-call "
-                "capacity-bounded expert dispatch, so routing — and "
-                "therefore tokens — depends on the chunking; parity with "
-                "the offline sampler cannot be guaranteed. Serve dense "
-                "models only.")
+                "disaggregated serving does not support MoE models "
+                "(num_experts > 0) or sliding-window layers: the block "
+                "handoff between the two pools has never run an expert "
+                "block or a second kind of cache state, and nothing "
+                "tests it with one. Serve them through ServeEngine.")
+        self.mixed, self.wpool = False, None  # one pool a side, full layers
         self.cfg = model_cfg
         self.scfg = scfg
         self.eos_token_id = eos_token_id
@@ -290,12 +290,12 @@ class DisaggServeEngine(ServeEngine):
                 self._sh_p)
 
     def _run_prefill(self, feed):
-        self._k_p, self._v_p, toks = self._prefill_jit(
+        self._k_p, self._v_p, toks, logits = self._prefill_jit(
             self.params_p, self._k_p, self._v_p, *feed, self.base_key_p,
             self.cos_p, self.sin_p, cfg=self.cfg,
             temperature=self.temperature, top_k=self.top_k,
             pool_sharded=_sharded(self._k_p))
-        return toks
+        return toks, logits
 
     def _retire_prefilled(self, pslot: int, t: float) -> None:
         # first token already finishes it: retire straight from the

@@ -45,11 +45,18 @@ iterations, but every iteration forwards 1 + draft_len candidate tokens
 and emits between 1 and 1 + draft_len of them. The same key fold keys
 every candidate position, so speculative output is bit-identical to the
 non-speculative stream at any temperature — acceptance only changes how
-fast the stream advances. MoE models are rejected at engine
-construction: chunked prefill routes tokens through per-call
-capacity-bounded expert dispatch, so routing depends on the chunking
-and parity with the offline sampler cannot be guaranteed (the PR-7
-KNOWN, now a hard error).
+fast the stream advances.
+
+Sparse experts are served through the dropless dispatch (`ops/moe.py`
+`moe_mlp_served`): every expert is on the device, a token's experts depend
+on that token alone, so a prompt prefilled in any chunking routes alike;
+rows without a token (idle slots, pad rows, chunk padding) are routed
+nowhere and touch no expert. The decode program returns how many experts
+its live rows touched, which decides the bytes a step streams. A model
+with sliding-window layers gets two pools and two tables a slot
+(`serve/paged_cache.py` MixedPagedKVCache): `_k`, `_v` and the tables fed
+to the programs are then pairs (full, window). The speculative program
+serves neither (it has run neither).
 
 Observability rides the existing telemetry machinery: the GoodputLedger
 books queue_wait / prefill / decode (compile time drained out exactly
@@ -76,7 +83,8 @@ from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, head_weight, model_rope_tables,
 )
 from picotron_tpu.serve.paged_cache import (
-    BlockPool, PagedKVCache, ShardedPagedKVCache, init_paged_cache,
+    BlockPool, MixedPagedKVCache, PagedKVCache, ShardedPagedKVCache,
+    init_mixed_cache, init_paged_cache, ring_blocks_for,
 )
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
@@ -121,20 +129,40 @@ def _sample_slots(logits, temperature: float, top_k: int, base_key, rids,
     )(lg, keys).astype(jnp.int32)
 
 
-def _paged_cache(k, v, tables, pool_sharded: bool) -> PagedKVCache:
+def _paged_cache(k, v, tables, pool_sharded: bool):
     """The cache a serve program runs its layers against. `pool_sharded`
     (static; `_sharded` of the pool the engine feeds) says that a mesh
     shards the pool over the KV heads (tp > 1): attention then keeps the
     gathered view whatever the step, which the compiler partitions, and
-    never the in-place kernel, which it does not."""
+    never the in-place kernel, which it does not. Pairs (full, window) of
+    pools and tables are a model with sliding layers'."""
+    if isinstance(k, (tuple, list)):
+        return MixedPagedKVCache(k[0], v[0], k[1], v[1], *tables)
     return (ShardedPagedKVCache if pool_sharded else PagedKVCache)(
         k, v, tables)
+
+
+def _pools(cache):
+    """(k, v) of a cache, in the form `_paged_cache` took them."""
+    if isinstance(cache, MixedPagedKVCache):
+        return (cache.k, cache.wk), (cache.v, cache.wv)
+    return cache.k, cache.v
 
 
 def _sharded(pool) -> bool:
     """Whether the sharding the engine's constructor gave this KV pool
     splits it (over its KV heads, tp > 1)."""
+    if isinstance(pool, (tuple, list)):
+        pool = pool[0]
     return not pool.sharding.is_fully_replicated
+
+
+def _logit_of(logits, toks):
+    """The float32 logit of each row's chosen token [S]: one number a
+    token out of the [S, V] row the program holds anyway, handed out so
+    that a served token can be held to a reference's logit, not only to
+    its argmax."""
+    return jnp.take_along_axis(logits, toks[:, None], axis=1)[:, 0]
 
 
 def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
@@ -148,18 +176,21 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     ignored, write dropped). Slots that emit EOS mid-interval are forced
     to keep emitting EOS — identical semantics to generate.py's scan —
     and the host truncates + retires them at dispatch end. Returns
-    (tokens [S, interval], next positions, next tidx, k, v); the
+    (tokens [S, interval], their logits [S, interval] float32, next
+    tokens, next positions, next tidx, experts touched, k, v); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
     (measured ~2x the whole dispatch on the CPU tiny-model bench).
-    `pool_sharded`: see `_paged_cache`."""
+    Experts touched: the experts at least one live slot was routed to,
+    summed over the layers and the interval's steps (0 for a dense
+    model). `pool_sharded`: see `_paged_cache`."""
     live = positions >= 0
 
     def one(carry, _):
-        toks, positions, tidx, cache, done = carry
+        toks, positions, tidx, cache, done, touched = carry
         x = params["embedding"][toks[:, None]].astype(compute_dtype(cfg))
-        x, cache = _decode_layers(params, x, cache, positions[:, None],
-                                  cfg, cos, sin)
+        x, cache, t = _decode_layers(params, x, cache, positions[:, None],
+                                     cfg, cos, sin, with_touched=True)
         logits = _logits_last(params, x, cfg)  # [S, V] fp32
         nxt = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
         if eos_token_id is not None:
@@ -167,13 +198,16 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
             done = done | (nxt == eos_token_id)
         positions = jnp.where(live, positions + 1, positions)
         tidx = jnp.where(live, tidx + 1, tidx)
-        return (nxt, positions, tidx, cache, done), nxt
+        return ((nxt, positions, tidx, cache, done, touched + t),
+                (nxt, _logit_of(logits, nxt)))
 
     cache = _paged_cache(k, v, tables, pool_sharded)
     done = jnp.zeros(toks.shape, bool)
-    (last, positions, tidx, cache, _), toks_all = jax.lax.scan(
-        one, (toks, positions, tidx, cache, done), None, length=interval)
-    return toks_all.T, last, positions, tidx, cache.k, cache.v
+    (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
+        jax.lax.scan(one, (toks, positions, tidx, cache, done,
+                           jnp.zeros((), jnp.int32)), None, length=interval)
+    return (toks_all.T, lg_all.T, last, positions, tidx, touched,
+            *_pools(cache))
 
 
 def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
@@ -193,7 +227,7 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     bench — one [R, C] program closes that. Samples each row's next
     token off its last valid position's logits with the same (request
     id, token index) key derivation as the decode step — one sampling
-    law everywhere. Returns (k, v, tokens [R])."""
+    law everywhere. Returns (k, v, tokens [R], their logits [R])."""
     s, c = chunk_ids.shape
     t = jnp.arange(c)[None, :]
     pos = jnp.where(t < n_valid[:, None], start_pos[:, None] + t, -1)
@@ -206,7 +240,7 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     logits = (hf @ head_weight(params).astype(hf.dtype))[:, 0]
     logits = logits.astype(jnp.float32)  # [S, V]
     toks = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
-    return cache.k, cache.v, toks
+    return (*_pools(cache), toks, _logit_of(logits, toks))
 
 
 _JITS: dict = {}
@@ -262,14 +296,14 @@ class ServeEngine:
                  device=None, engine_id: int = 0):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
-        if model_cfg.num_experts:
+        self.speculate = scfg.speculator == "ngram"
+        if self.speculate and (model_cfg.num_experts
+                               or model_cfg.layer_types is not None):
             raise ValueError(
-                "serving does not support MoE models (num_experts > 0): "
-                "chunked prefill feeds each chunk through per-call "
-                "capacity-bounded expert dispatch, so routing — and "
-                "therefore tokens — depends on the chunking; parity with "
-                "the offline sampler cannot be guaranteed. Serve dense "
-                "models only.")
+                "serve.speculator='ngram' serves dense models of full "
+                "layers only: the speculative verify scan has never run "
+                "an expert block or a sliding-window layer, and nothing "
+                "tests it with one")
         self.params = params
         self.cfg = model_cfg
         self.scfg = scfg
@@ -286,7 +320,6 @@ class ServeEngine:
         self.num_slots = scfg.decode_slots
         self.prefill_rungs = prefill_rungs(self.num_slots)
 
-        self.speculate = scfg.speculator == "ngram"
         self.draft_len = scfg.draft_len if self.speculate else 0
         if self.speculate:
             from picotron_tpu.serve import spec_decode
@@ -298,10 +331,24 @@ class ServeEngine:
 
         self.cos, self.sin = model_rope_tables(model_cfg,
                                                max_len=self.max_len)
-        cache = init_paged_cache(model_cfg, self.num_blocks,
-                                 self.block_size, self.num_slots,
-                                 self.max_blocks)
-        self._k, self._v = cache.k, cache.v
+        # a model with sliding-window layers: a second pool, a ring a slot
+        self.mixed = model_cfg.layer_types is not None
+        if self.mixed:
+            self.ring_blocks = min(self.max_blocks, ring_blocks_for(
+                model_cfg.sliding_window, scfg.prefill_chunk,
+                self.block_size))
+            self.num_window_blocks = (scfg.num_window_blocks
+                                      or self.num_slots * self.ring_blocks)
+            cache = init_mixed_cache(
+                model_cfg, self.num_blocks, self.num_window_blocks,
+                self.block_size, self.num_slots, self.max_blocks,
+                self.ring_blocks)
+            self._k, self._v = _pools(cache)
+        else:
+            cache = init_paged_cache(model_cfg, self.num_blocks,
+                                     self.block_size, self.num_slots,
+                                     self.max_blocks)
+            self._k, self._v = cache.k, cache.v
 
         # Sharding discipline: every decode/prefill input keeps ONE
         # explicit sharding for the engine's whole lifetime. Committed
@@ -354,8 +401,19 @@ class ServeEngine:
         self._tables = np.full((self.num_slots, self.max_blocks),
                                self.num_blocks, np.int32)
         self.pool = BlockPool(self.num_blocks)
-        self.sched = Scheduler(self.num_slots, self.pool, self.block_size,
-                               self.max_blocks)
+        self.wpool = None  # the sliding layers' pool and table mirror
+        if self.mixed:
+            if isinstance(kv_sh, NamedSharding) and _sharded(self._k):
+                raise ValueError(
+                    "a model with sliding-window layers is served from "
+                    "one device: the two pools are not sharded (tp = 1)")
+            self.wpool = BlockPool(self.num_window_blocks)
+            self._wtables = np.full((self.num_slots, self.ring_blocks),
+                                    self.num_window_blocks, np.int32)
+        self.sched = Scheduler(
+            self.num_slots, self.pool, self.block_size, self.max_blocks,
+            window_pool=self.wpool,
+            ring_blocks=self.ring_blocks if self.mixed else 0)
 
         self._owns_telemetry = telemetry is None
         self.telemetry = telemetry or Telemetry(sinks=[])
@@ -381,6 +439,9 @@ class ServeEngine:
             "output_tokens": 0, "prefill_tokens": 0,
             "draft_tokens": 0, "accepted_draft_tokens": 0,
             "decode_stall_ticks_max": 0, "cancelled": 0,
+            # experts the decode steps' live rows were routed to, out of
+            # layers x steps x experts (0 / 0 for a dense model)
+            "experts_touched": 0, "expert_slots": 0,
         }
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
         self._next_auto_id = 0
@@ -445,6 +506,10 @@ class ServeEngine:
         if st is not None and st.blocks:
             row[:len(st.blocks)] = st.blocks
         self._tables[slot] = row
+        if self.mixed:
+            self._wtables[slot] = self.num_window_blocks
+            if st is not None:
+                self._wtables[slot, :len(st.wblocks)] = st.wblocks
         self._decode_state = None  # roster/table changed: slow path next
 
     # -- the prefill program's side of the engine: what DisaggServeEngine
@@ -469,13 +534,13 @@ class ServeEngine:
 
     def _run_prefill(self, feed):
         """One dispatch of the prefill program on `feed`; returns the
-        rows' tokens, still on the device."""
-        self._k, self._v, toks = self._prefill_jit(
+        rows' tokens and their logits, still on the device."""
+        self._k, self._v, toks, logits = self._prefill_jit(
             self.params, self._k, self._v, *feed, self.base_key,
             self.cos, self.sin, cfg=self.cfg,
             temperature=self.temperature, top_k=self.top_k,
             pool_sharded=_sharded(self._k))
-        return toks
+        return toks, logits
 
     def _prefill_feed(self, pslots, rows: Optional[int] = None):
         """The compacted prefill batch: row i carries the next chunk of
@@ -489,6 +554,8 @@ class ServeEngine:
         c = self.scfg.prefill_chunk
         r = rows or next(x for x in self.prefill_rungs if x >= len(pslots))
         trows = np.full((r, self.max_blocks), unmapped, np.int32)
+        wrows = (np.full((r, self.ring_blocks), self.num_window_blocks,
+                         np.int32) if self.mixed else None)
         ids = np.zeros((r, c), np.int32)
         start, nval, rids, tidx = np.zeros((4, r), np.int32)
         finals = []
@@ -496,6 +563,8 @@ class ServeEngine:
             st = states[s]
             chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
             trows[row] = tables[s]
+            if self.mixed:
+                wrows[row] = self._wtables[s]
             ids[row, :len(chunk)] = chunk
             start[row] = st.n_prefilled
             nval[row] = len(chunk)
@@ -503,6 +572,8 @@ class ServeEngine:
             tidx[row] = len(st.generated)
             if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
                 finals.append(row)
+        if self.mixed:
+            trows = (trows, wrows)
         feed = jax.device_put((trows, ids, start, nval, rids, tidx), sh)
         return feed, nval, finals
 
@@ -542,6 +613,10 @@ class ServeEngine:
             "id": req.id,
             "prompt_len": len(req.prompt),
             "tokens": list(st.generated),
+            # the float32 logit each token was chosen at (None under the
+            # speculative program, which does not hand them out)
+            "logits": (list(st.logits)
+                       if len(st.logits) == len(st.generated) else None),
             "output_tokens": len(st.generated),
             "queue_wait_s": max((st.t_admit or 0.0) - req.arrival, 0.0),
             "ttft_s": ttft,
@@ -665,13 +740,13 @@ class ServeEngine:
                         rows=len(nval), tokens=n_prefilled,
                         capacity=len(nval) * self.scfg.prefill_chunk,
                         ids=join_ids(req_ids)):
-            toks_d = self._run_prefill(feed)
+            toks_d, logits_d = self._run_prefill(feed)
         toks = None
         if finals:
             # the host needs a token only when a prompt ends in the
             # chunk; otherwise the dispatch is left in flight
             with self._span("serve.prefill.wait", finals=len(finals)):
-                toks = np.asarray(toks_d)
+                toks, logits = jax.device_get((toks_d, logits_d))
         dt = time.perf_counter() - t0
         csecs = self._drain_compile()
         # the constructor held every rung: a compile here is a shape or
@@ -690,6 +765,7 @@ class ServeEngine:
         for row in finals:
             st = states[pslots[row]]
             st.generated.append(int(toks[row]))
+            st.logits.append(float(logits[row]))
             self.stats["output_tokens"] += 1
             if st.t_first_token is None:
                 st.t_first_token = now + dt
@@ -751,7 +827,8 @@ class ServeEngine:
                     tidx[s] = len(st.generated)
                 up = partial(jax.device_put, device=self._rep_sh)
                 ds = {"active": list(active),
-                      "tables": up(self._tables),
+                      "tables": up((self._tables, self._wtables)
+                                   if self.mixed else self._tables),
                       "toks": up(toks),
                       "positions": up(positions),
                       "rids": up(rids),
@@ -784,7 +861,8 @@ class ServeEngine:
         with self._span("serve.decode.dispatch", active=len(active),
                         interval=interval, kv_blocks=kv_blocks,
                         view_blocks=self.num_slots * self.max_blocks,
-                        ids=join_ids(dec_ids)):
+                        ids=join_ids(dec_ids),
+                        **self._kind_blocks(active, kv_blocks)):
             if self.speculate:
                 (toks_d, nval_d, last_d, pos_d, tidx_d, ctx_d,
                  self._k, self._v) = self._decode_jit(
@@ -799,8 +877,8 @@ class ServeEngine:
                 state = dict(ds, toks=last_d, positions=pos_d,
                              tidx=tidx_d, ctx=ctx_d)
             else:
-                toks_d, last_d, pos_d, tidx_d, self._k, self._v = \
-                    self._decode_jit(
+                (toks_d, lg_d, last_d, pos_d, tidx_d, touched_d, self._k,
+                 self._v) = self._decode_jit(
                         self.params, self._k, self._v,
                         ds["tables"], ds["toks"], ds["positions"],
                         ds["rids"], ds["tidx"], self.base_key,
@@ -811,10 +889,21 @@ class ServeEngine:
                         pool_sharded=_sharded(self._k))
                 state = dict(ds, toks=last_d, positions=pos_d,
                              tidx=tidx_d)
-        with self._span("serve.decode.wait"):
-            nxt = np.asarray(toks_d)  # [S, interval] ([.., 1+d] speculative)
+        with self._span("serve.decode.wait") as sp:
             if self.speculate:
+                nxt = np.asarray(toks_d)   # [S, interval, 1 + draft_len]
                 nval = np.asarray(nval_d)  # [S, interval]
+            else:
+                # tokens and their logits [S, interval], and the experts
+                # the steps touched: known once the dispatch has run, so
+                # the counts ride this span and not the dispatch's
+                nxt, lgs, touched = jax.device_get((toks_d, lg_d, touched_d))
+                if self.cfg.num_experts:
+                    slots = (self.cfg.num_hidden_layers * interval
+                             * self.cfg.num_experts)
+                    self.stats["experts_touched"] += int(touched)
+                    self.stats["expert_slots"] += slots
+                    sp.set(experts_touched=int(touched), expert_slots=slots)
         # feed outputs forward; any roster/table change below
         # nulls this via _sync_table
         self._decode_state = state
@@ -839,6 +928,7 @@ class ServeEngine:
                             len(emit) - 1)
                     else:
                         emit = [int(nxt[s, t])]
+                        st.logits.append(float(lgs[s, t]))
                     for tok in emit:
                         st.generated.append(tok)
                         n_tokens += 1
@@ -865,7 +955,33 @@ class ServeEngine:
             len(active) / self.num_slots)
         reg.gauge("serve/pool_utilization").set(
             self.pool.in_use / self.num_blocks)
+        if self.mixed:
+            reg.gauge("serve/window_pool_utilization").set(
+                self.wpool.in_use / self.num_window_blocks)
         return True
+
+    def _kind_blocks(self, active, kv_blocks: int) -> dict:
+        """Further counts of a decode dispatch's span for a model with
+        sliding layers, each summed over the layers of its kind, at the
+        dispatch's first token: `kv_blocks_full` (the full layers read
+        every block a slot's positions fill), `kv_blocks_window` (the
+        sliding layers read from the block of position length - window
+        on), `kv_blocks_banded` (their sum: what the step reads) and
+        `kv_blocks_unwindowed` (what it would read were every layer
+        full)."""
+        if not self.mixed:
+            return {}
+        n_full = self.cfg.layer_kinds.count("full_attention")
+        n_win = self.cfg.num_hidden_layers - n_full
+        band = 0
+        for s in active:
+            n = self.sched.slots[s].write_pos + 1
+            first = max(n - self.cfg.sliding_window, 0) // self.block_size
+            band += blocks_for(n, self.block_size) - first
+        return dict(kv_blocks_full=n_full * kv_blocks,
+                    kv_blocks_window=n_win * band,
+                    kv_blocks_banded=n_full * kv_blocks + n_win * band,
+                    kv_blocks_unwindowed=(n_full + n_win) * kv_blocks)
 
     # -- trace driver ------------------------------------------------------
 
@@ -935,6 +1051,11 @@ class ServeEngine:
             "slot_occupancy": round(self.stats["occupancy_sum"] / steps, 4),
             "pool_peak_utilization": round(
                 self.pool.peak_in_use / self.num_blocks, 4),
+            "window_pool_peak_utilization": (
+                round(self.wpool.peak_in_use / self.num_window_blocks, 4)
+                if self.wpool is not None else None),
+            "experts_touched": self.stats.get("experts_touched", 0),
+            "expert_slots": self.stats.get("expert_slots", 0),
             "decode_steps": self.stats["decode_steps"],
             "decode_compiles": self.stats["decode_compiles"],
             "prefill_compiles": self.stats["prefill_compiles"],

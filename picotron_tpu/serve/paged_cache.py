@@ -58,6 +58,22 @@ pool at the fullest (PERF.md, PR 32). Prefill chunks, the speculative
 program (more than one query position a slot), shapes the kernel does not
 take and every CPU run keep the view.
 
+A model with sliding-window layers has two kinds of state
+(`MixedPagedKVCache`): a full-attention layer needs every position of a
+sequence, a sliding layer the last `sliding_window` only. Its full layers
+keep the pool and tables above, with only those layers in the pool's layer
+axis; its sliding layers keep a second, smaller pool in which a slot holds
+a fixed RING of `ring_blocks_for(window, prefill_chunk, block_size)`
+blocks, given at admission and never grown: position `p` lives in ring
+entry `(p // block_size) % ring`, so a sequence overwrites what has left
+every later query's band. What a ring entry holds now is known from the
+last position written (`_ring_positions`), and a key is masked by that
+position, as a key past the length is. Neither kind of layer builds a
+whole view at prefill: `_tiled_attention` walks the keys in tiles of
+blocks under an online softmax (a full layer as far as the longest row of
+the batch reaches, a sliding layer over its ring), so a 16k-position row
+never has its `[chunk, 16384]` scores in memory at once.
+
 `BlockPool` is the host-side allocator: free-list alloc/free with
 all-or-nothing semantics and peak accounting, so the scheduler can make
 admission/preemption decisions and tests can assert no block leaks
@@ -102,17 +118,22 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[3]
 
     @scope("kv_write")
-    def write(self, li, k_new, v_new, q_pos) -> "PagedKVCache":
+    def write(self, li, k_new, v_new, q_pos,
+              ring: bool = False) -> "PagedKVCache":
         """Scatter K/V [B, s, Hkv, D] into each token's (physical block,
         offset) slot of layer li. q_pos: [s] batch-shared or [B, s]
         per-slot global positions; positions < 0, positions beyond the
         table's capacity, and unmapped table entries all resolve to the
-        out-of-bounds sentinel and are DROPPED by the scatter."""
+        out-of-bounds sentinel and are DROPPED by the scatter. `ring`: the
+        table is a ring (a sliding layer's), logical block j at entry
+        j % width, and no position is beyond it."""
         bs = self.block_size
         if q_pos.ndim == 1:
             q_pos = jnp.broadcast_to(q_pos[None, :],
                                      (k_new.shape[0], q_pos.shape[0]))
         blk = jnp.maximum(q_pos, 0) // bs                       # [B, s]
+        if ring:
+            blk = blk % self.tables.shape[1]
         idx = jnp.minimum(blk, self.tables.shape[1] - 1)
         phys = jnp.take_along_axis(self.tables, idx, axis=1)    # [B, s]
         ok = (q_pos >= 0) & (blk < self.tables.shape[1])
@@ -168,6 +189,179 @@ class PagedKVCache(NamedTuple):
         out = paged_decode_attention(q[:, 0], self.k, self.v, li,
                                      self.tables, jnp.maximum(pos + 1, 0))
         return out[:, None]
+
+
+# Blocks a tile of `_tiled_attention`: 32 blocks of 16 positions are 512
+# keys, [rows, heads, chunk, 512] float32 scores a tile (0.54 GB on the
+# 32-row rung at Mellum2's 32 heads and a 256-token chunk).
+TILE_BLOCKS = 32
+
+
+def ring_blocks_for(window: int, prefill_chunk: int, block_size: int) -> int:
+    """Blocks in a slot's ring for its sliding layers. A prefill chunk
+    writes its `prefill_chunk` positions before it attends, and its first
+    query still needs the `window - 1` positions before it: the ring holds
+    window + chunk positions, and one block more for the block edges."""
+    return -(-(window + prefill_chunk) // block_size) + 1
+
+
+def _ring_positions(last, n: int):
+    """The position each of a ring's `n` slots holds once positions
+    0 .. last have been written in order (last [B], -1: nothing yet):
+    the newest position <= last that is congruent to the slot mod n.
+    Negative: never written. [B, n]."""
+    j = jnp.arange(n)[None, :]
+    return last[:, None] - (last[:, None] - j) % n
+
+
+def _tiled_attention(q, q_pos, n_tiles, fetch, hkv: int, window=None):
+    """Causal attention of q [B, s, Hq, D] at positions q_pos [B, s] over
+    keys fetched a tile at a time, under an online softmax: the
+    mathematics of `generate._cached_attention` (scores and statistics in
+    float32, P in the value dtype for PV, float32 accumulation) without
+    the whole row of scores. `fetch(t)` -> (k [Hkv, B, T, D], v, kv_pos
+    [B, T]): tile t's keys and the position each holds; a key is seen
+    where 0 <= kv_pos <= q_pos (and q_pos - kv_pos < window on a sliding
+    layer). `n_tiles` may be traced. Rows at q_pos < 0 (padding) attend
+    as position 0 and are discarded by the caller; a row that sees no key
+    returns zeros."""
+    b, s, hq, d = q.shape
+
+    def body(t, carry):
+        m, l, acc = carry
+        k, v, kp = fetch(t)
+        qg = q.reshape(b, s, hkv, hq // hkv, d)
+        sc = jnp.einsum("bshgd,hbtd->bhgst", qg, k,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+        qp = jnp.maximum(q_pos, 0)[:, :, None]                 # [B, s, 1]
+        seen = (kp[:, None, :] >= 0) & (kp[:, None, :] <= qp)  # [B, s, T]
+        if window is not None:
+            seen &= qp - kp[:, None, :] < window
+        seen = seen[:, None, None]
+        sc = jnp.where(seen, sc, -1e30)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(sc - m_new[..., None]), 0.0)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhgst,hbtd->bhgsd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    g = hq // hkv
+    init = (jnp.full((b, hkv, g, s), -1e30, jnp.float32),
+            jnp.zeros((b, hkv, g, s), jnp.float32),
+            jnp.zeros((b, hkv, g, s, d), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d).astype(q.dtype)
+
+
+class MixedPagedKVCache(NamedTuple):
+    """The serving cache of a model with sliding-window and full layers
+    side by side. `generate._decode_layers` calls it with `window` (the
+    layer's band; None on a full layer) and `ki` (the layer's ordinal
+    among the layers of its kind, which is its index in its pool)."""
+
+    k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
+    v: jnp.ndarray
+    wk: jnp.ndarray       # [Hkv, L_window, num_window_blocks, block_size, D]
+    wv: jnp.ndarray
+    tables: jnp.ndarray   # [B, max_blocks]; num_blocks = unmapped
+    wtables: jnp.ndarray  # [B, ring]; num_window_blocks = unmapped
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[1] + self.wk.shape[1]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    def _of(self, window) -> PagedKVCache:
+        return (PagedKVCache(self.k, self.v, self.tables) if window is None
+                else PagedKVCache(self.wk, self.wv, self.wtables))
+
+    def write(self, li, k_new, v_new, q_pos, window=None,
+              ki=None) -> "MixedPagedKVCache":
+        c = self._of(window).write(ki, k_new, v_new, q_pos,
+                                   ring=window is not None)
+        return (self._replace(k=c.k, v=c.v) if window is None
+                else self._replace(wk=c.k, wv=c.v))
+
+    def attend(self, li, q, q_pos, window=None, ki=None):
+        """Attention of q [B, s, Hq, D] over layer `ki` of the pool of its
+        kind. A decode step on a chip reads the slot's blocks in place
+        (the kernel, from the band's first block on a sliding layer);
+        everything else walks the keys in tiles."""
+        c = self._of(window)
+        b, s = q.shape[:2]
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        with scope("attn_full" if window is None else "attn_window"):
+            if decode_kernel_suits(q, c.k):
+                out = paged_decode_attention(
+                    q[:, 0], c.k, c.v, ki, c.tables,
+                    jnp.maximum(q_pos[:, 0] + 1, 0), window=window)
+                return out[:, None]
+            return self._tiled(c, ki, q, q_pos, window)
+
+    @staticmethod
+    def _tiled(c: PagedKVCache, ki, q, q_pos, window):
+        bs, width = c.block_size, c.tables.shape[1]
+        tb = min(TILE_BLOCKS, width)
+        tiles = -(-width // tb)
+        hkv, b = c.k.shape[0], q.shape[0]
+        # whole tiles: entries past the table read the unmapped sentinel,
+        # which the gather clamps into the pool and the positions mask
+        tables = jnp.pad(c.tables, ((0, 0), (0, tiles * tb - width)),
+                         constant_values=c.num_blocks)
+        last = jnp.max(q_pos, axis=1)                            # [B]
+        if window is not None:
+            held = _ring_positions(last, width * bs)             # [B, n]
+            held = jnp.pad(held, ((0, 0), (0, (tiles * tb - width) * bs)),
+                           constant_values=-1)
+
+        def fetch(t):
+            tbl = jax.lax.dynamic_slice_in_dim(tables, t * tb, tb, axis=1)
+            tbl = jnp.broadcast_to(tbl, (hkv, b, tb))
+            gather = jax.vmap(lambda pool, rows: pool[ki, rows])
+            k = gather(c.k, tbl).reshape(hkv, b, tb * bs, -1)
+            v = gather(c.v, tbl).reshape(hkv, b, tb * bs, -1)
+            if window is None:
+                kp = jnp.broadcast_to(
+                    t * tb * bs + jnp.arange(tb * bs)[None, :], (b, tb * bs))
+            else:
+                kp = jax.lax.dynamic_slice_in_dim(held, t * tb * bs,
+                                                  tb * bs, axis=1)
+            return k, v, kp
+
+        # a full layer's keys end at the batch's last position; a ring is
+        # walked whole
+        n_tiles = (tiles if window is not None else
+                   jnp.clip(-(-(jnp.max(last) + 1) // (tb * bs)), 0, tiles))
+        return _tiled_attention(q, q_pos, n_tiles, fetch, hkv, window)
+
+
+def init_mixed_cache(cfg: ModelConfig, num_blocks: int,
+                     num_window_blocks: int, block_size: int,
+                     num_slots: int, max_blocks: int,
+                     ring_blocks: int) -> MixedPagedKVCache:
+    """Zeroed pools + all-unmapped tables for a model with sliding and
+    full layers: each pool's layer axis holds the layers of its kind."""
+    kinds = cfg.layer_kinds
+    dt = compute_dtype(cfg)
+
+    def pool(kind, n):
+        return jnp.zeros((cfg.num_key_value_heads, kinds.count(kind), n,
+                          block_size, cfg.head_dim), dt)
+
+    return MixedPagedKVCache(
+        pool("full_attention", num_blocks), pool("full_attention", num_blocks),
+        pool("sliding_attention", num_window_blocks),
+        pool("sliding_attention", num_window_blocks),
+        jnp.full((num_slots, max_blocks), num_blocks, jnp.int32),
+        jnp.full((num_slots, ring_blocks), num_window_blocks, jnp.int32))
 
 
 class ShardedPagedKVCache(PagedKVCache):

@@ -23,6 +23,13 @@ token-identical to an uninterrupted run). Preempting youngest-first
 means the oldest request always makes progress, so the system cannot
 livelock; a single request that cannot fit the pool alone is a
 configuration error and raises.
+
+A model with sliding-window layers has a second pool (`window_pool`): a
+request is given a fixed ring of blocks there at admission (`ring_blocks`,
+or fewer where the whole request is shorter than the ring), all or
+nothing with its full-layer blocks, keeps it through decode without
+growing it, and returns it wherever it returns the others: retirement,
+preemption, cancellation. A request shed from the queue holds neither.
 """
 
 from __future__ import annotations
@@ -68,9 +75,13 @@ class RequestState:
 
     req: Request
     generated: list = field(default_factory=list)
+    # the float32 logit each generated token was chosen at (the engine
+    # appends one a token; empty under the speculative program)
+    logits: list = field(default_factory=list)
     prefill_ids: tuple = ()   # snapshot at admission: prompt + generated
     n_prefilled: int = 0
     blocks: list = field(default_factory=list)
+    wblocks: list = field(default_factory=list)  # its ring, window pool
     admit_seq: int = -1
     t_admit: Optional[float] = None
     t_first_token: Optional[float] = None
@@ -97,11 +108,15 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
 
 class Scheduler:
     def __init__(self, num_slots: int, pool, block_size: int,
-                 max_blocks: int):
+                 max_blocks: int, window_pool=None, ring_blocks: int = 0):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        if window_pool is not None and ring_blocks < 1:
+            raise ValueError("a window pool needs ring_blocks >= 1")
         self.num_slots = num_slots
         self.pool = pool
+        self.window_pool = window_pool  # None: a model of full layers
+        self.ring_blocks = ring_blocks
         self.block_size = block_size
         self.max_blocks = max_blocks
         self.queue: deque = deque()
@@ -135,10 +150,33 @@ class Scheduler:
                 f"request {req.id}: needs {need} blocks but the whole "
                 f"pool holds {self.pool.num_blocks}; raise "
                 f"serve.num_blocks")
+        if (self.window_pool is not None
+                and self._ring_for(req) > self.window_pool.num_blocks):
+            raise ValueError(
+                f"request {req.id}: its sliding layers need a ring of "
+                f"{self._ring_for(req)} blocks but the whole window pool "
+                f"holds {self.window_pool.num_blocks}; raise "
+                f"serve.num_window_blocks")
         self.queue.append(RequestState(req))
 
     def has_work(self) -> bool:
         return bool(self.queue) or any(s is not None for s in self.slots)
+
+    def _ring_for(self, req: Request) -> int:
+        """Blocks of the window pool a request holds: the ring, or the
+        blocks of its whole length where that is shorter (it then never
+        wraps)."""
+        return min(self.ring_blocks,
+                   blocks_for(len(req.prompt) + req.max_new_tokens,
+                              self.block_size))
+
+    def _release(self, st: RequestState) -> None:
+        """A request's blocks of both pools back to their free lists."""
+        self.pool.free(st.blocks)
+        st.blocks = []
+        if st.wblocks:
+            self.window_pool.free(st.wblocks)
+            st.wblocks = []
 
     # -- admission ---------------------------------------------------------
 
@@ -182,6 +220,13 @@ class Scheduler:
                 blocks_for(len(st.prefill_ids), self.block_size))
             if blocks is None:
                 break
+            if self.window_pool is not None:
+                # both or neither: the ring for the sliding layers
+                ring = self.window_pool.alloc(self._ring_for(st.req))
+                if ring is None:
+                    self.pool.free(blocks)
+                    break
+                st.wblocks = ring
             self.queue.popleft()
             st.blocks = blocks
             st.n_prefilled = 0
@@ -255,8 +300,7 @@ class Scheduler:
 
     def _preempt(self, slot: int) -> None:
         st = self.slots[slot]
-        self.pool.free(st.blocks)
-        st.blocks = []
+        self._release(st)
         st.n_prefilled = 0
         st.prefill_ids = ()
         st.n_preempted += 1
@@ -275,17 +319,14 @@ class Scheduler:
         retired, shed, or never submitted)."""
         for i, s in enumerate(self.slots):
             if s is not None and s.req.id == request_id:
-                self.pool.free(s.blocks)
-                s.blocks = []
+                self._release(s)
                 self.slots[i] = None
                 self.n_cancelled += 1
                 return "slot", i, s
         for s in list(self.queue):
             if s.req.id == request_id:
                 self.queue.remove(s)
-                if s.blocks:  # queued states hold no blocks; defensive
-                    self.pool.free(s.blocks)
-                    s.blocks = []
+                self._release(s)  # queued states hold no blocks; defensive
                 self.n_cancelled += 1
                 return "queue", None, s
         return None
@@ -300,8 +341,7 @@ class Scheduler:
 
     def retire(self, slot: int) -> RequestState:
         st = self.slots[slot]
-        self.pool.free(st.blocks)
-        st.blocks = []
+        self._release(st)
         self.slots[slot] = None
         self.n_retired += 1
         return st
@@ -360,6 +400,9 @@ class DisaggScheduler:
     # of the disaggregation split — share the colocated implementation
     _shed_expired_head = Scheduler._shed_expired_head
     drain_shed = Scheduler.drain_shed
+    # what `Scheduler.cancel` frees a decode resident through: the decode
+    # pool's blocks (no model with sliding layers reaches this scheduler)
+    _release = Scheduler._release
 
     def cancel(self, request_id: int):
         """Scheduler.cancel plus the prefill side: a request caught

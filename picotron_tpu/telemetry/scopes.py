@@ -38,6 +38,8 @@ SCOPES = (
     "tp_reduce",        # tensor-parallel psum / psum_scatter / all_gather hooks
     "kv_write",         # serve: the paged pool update
     "paged_attention",  # serve: attention over the cache: the decode step's in-place kernel; prefill's gathered views, mask, softmax, PV
+    "attn_full",        # inside paged_attention, a model with sliding layers: a full layer's attention
+    "attn_window",      # inside paged_attention, the same model: a sliding-window layer's attention
     "sample",           # serve: next-token choice from the logits
 )
 

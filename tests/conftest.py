@@ -92,3 +92,29 @@ def devices():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected >=8 simulated devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture
+def fresh_programs(monkeypatch):
+    """The engine's two programs under function objects of their own. JAX
+    keeps a traced program by the function it was traced from, whichever
+    `jax.jit` wraps it, so a patch that tracing consults (here: which form
+    of attention a step takes, which form the expert block) needs
+    functions that no other test of this process has traced at the same
+    shapes, and must leave none behind for the bit-identical parity tests
+    to pick up."""
+    import functools
+
+    from picotron_tpu.serve import engine
+
+    def jits(donate):
+        decode = functools.wraps(engine.serve_decode)(
+            lambda *a, **k: engine.serve_decode(*a, **k))
+        prefill = functools.wraps(engine.serve_prefill)(
+            lambda *a, **k: engine.serve_prefill(*a, **k))
+        static = ("cfg", "temperature", "top_k", "pool_sharded")
+        return (jax.jit(decode, static_argnames=static + (
+                    "interval", "eos_token_id")),
+                jax.jit(prefill, static_argnames=static))
+
+    monkeypatch.setattr(engine, "_get_jits", jits)

@@ -347,3 +347,119 @@ def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
                  if view(rows) in result_sizes(line)
                  and "paged_attention" in words(op)]
         assert len(views) >= 2, (rows, views)
+
+
+# ---------------------------------------------------------------------------
+# mellum2-12b-a2.5b-8l: experts and two kinds of cache state in both programs
+# ---------------------------------------------------------------------------
+
+_COMPILED_MELLUM: dict = {}
+
+
+def compiled_mellum(topo, monkeypatch, program: str, rows=None):
+    """(compiled, the two pools' shapes, the pools' parameter numbers) of
+    `serve_decode` or `serve_prefill` (at `rows` rows) of the Mellum2
+    configuration at its widths, depth and serve settings on one described
+    chip, pools donated."""
+    from picotron_tpu.serve.engine import _pools
+    from picotron_tpu.serve.paged_cache import init_mixed_cache, ring_blocks_for
+
+    if (program, rows) in _COMPILED_MELLUM:
+        return _COMPILED_MELLUM[program, rows]
+    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
+    c = load("configs", "mellum2-12b-a2.5b-8l")
+    cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
+    m, sc = cfg.model, cfg.serve
+    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
+    ring = ring_blocks_for(m.sliding_window, sc.prefill_chunk, sc.block_size)
+    slots = sc.decode_slots
+    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    cache = on_chip(jax.eval_shape(lambda: init_mixed_cache(
+        m, slots * max_blocks, slots * ring, sc.block_size, slots, max_blocks, ring)))
+    k, v = _pools(cache)
+    cos, sin = on_chip(jax.eval_shape(
+        lambda: model_rope_tables(m, max_len=sc.max_model_len)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    decode, prefill = _get_jits(True)
+    if program == "serve_prefill":
+        low = prefill.lower(
+            params, k, v, (i32(rows, max_blocks), i32(rows, ring)),
+            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
+            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
+    else:
+        low = decode.lower(
+            params, k, v, (i32(slots, max_blocks), i32(slots, ring)),
+            i32(slots), i32(slots), i32(slots), i32(slots), key, cos, sin,
+            cfg=m, temperature=0.0, top_k=0, interval=sc.decode_interval,
+            eos_token_id=None)
+    n = len(jax.tree.leaves(params))
+    out = _COMPILED_MELLUM[program, rows] = (
+        low.compile(), (cache.k.shape, cache.wk.shape), set(range(n, n + 4)))
+    return out
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 32)])
+def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `mellum2-12b-a2.5b-8l` compile for a v5e and
+    fit it; no pool of either kind is copied whole and all four are written
+    in place; no layer's expert bank is sliced out of its stack (the
+    grouped matmuls address a layer's experts inside it); the names the
+    cell's metrics read are there: the decode kernel in both layer kinds'
+    scopes, the compiler's `ragged-dot-*` kernels, the expert scopes."""
+    comp, pool_shapes, pools = compiled_mellum(topo, monkeypatch, program, rows)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"kv_write", "paged_attention", "attn_full", "attn_window", "mlp",
+                     "moe_router", "moe_dispatch", "moe_experts", "sample"}
+    for shape in pool_shapes:
+        copies = whole_pool_copies(text, shape)
+        assert not copies, f"{program} copies a whole pool {shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    experts = re.compile(load("layer_metrics", "moe_experts_ms.serve")["params"]["ops"])
+    attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    grouped = [n for n, _ in kernels if experts.search(n)]
+    paged = [(n, op) for n, op in kernels if attn.search(n)]
+    assert len(grouped) + len(paged) == len(kernels), kernels
+    # up to EVERY_EXPERT_UP_TO tokens (a decode step's 32, a one-row chunk's
+    # 256) every row goes through every expert in dense matmuls under the
+    # moe_experts scope; above (32 rows x 256) through the grouped matmuls:
+    # gate, up and down of each of the period's four layers
+    from picotron_tpu.ops.moe import EVERY_EXPERT_UP_TO
+    tokens = 32 if program == "serve_decode" else rows * 256
+    want = 12 if tokens > EVERY_EXPERT_UP_TO else 0
+    assert sum(n.startswith("ragged-dot-none") for n in grouped) == want, grouped
+    dots = [n for n, op, line in ins if "moe_experts" in words(op)
+            and re.search(r" (convolution|dot)\(", line)]
+    assert want or len(dots) >= 12, dots
+    if program == "serve_decode":
+        assert len(paged) == 4  # three sliding layers and a full one a period
+        assert sum("attn_window" in words(op) for _, op in paged) == 3
+        assert sum("attn_full" in words(op) for _, op in paged) == 1
+    else:
+        assert not paged  # a chunk walks its keys in tiles, no kernel
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15.75 * 2**30, total / 2**30
+    if program == "serve_decode":
+        # no layer's expert banks are copied out of their stacks: scanned as
+        # a period's [4, 64, ...] slices they were 3 GiB of temporaries, and a
+        # second read and a write of every weight in a memory-bound step
+        assert ma.temp_size_in_bytes < 0.5 * 2**30, ma.temp_size_in_bytes / 2**30

@@ -425,10 +425,25 @@ def test_config_rejects_moe_disagg_and_speculator():
 
 
 def test_engines_reject_moe_at_construction():
+    """`ServeEngine` constructs and serves a model with experts (dropless
+    at ep 1: its tokens are `generate`'s, whatever the chunking); the
+    disaggregated engine still refuses one, since nothing has run its
+    handoff with an expert block."""
     moe = ModelConfig(dtype="float32",
                       **resolve_preset("debug-tiny-moe"))
-    with pytest.raises(ValueError, match="num_experts"):
-        ServeEngine({}, moe, scfg(disagg=False))
+    params = init_params(moe, jax.random.key(5))
+    prompt = list(range(3, 24))
+    want = np.asarray(generate(params, moe, jnp.asarray([prompt]), 6))[0, 21:]
+    for chunk in (4, 16):
+        eng = ServeEngine(params, moe, ServeConfig(
+            decode_slots=2, block_size=4, prefill_chunk=chunk,
+            max_model_len=64, decode_interval=2))
+        eng.submit(prompt, 6)
+        while eng.sched.has_work():
+            eng.step(0.0)
+        assert eng.results[0]["tokens"] == want.tolist()
+        assert 0 < eng.stats["experts_touched"] <= eng.stats["expert_slots"]
+        assert eng.pool.in_use == 0
     with pytest.raises(ValueError, match="num_experts"):
         DisaggServeEngine({}, moe, scfg())
 

@@ -410,7 +410,7 @@ def test_llama3_rope_scaling():
     assert not np.allclose(np.asarray(cos_s), np.asarray(cos_u))
 
     with pytest.raises(ValueError, match="rope_scaling"):
-        rope_tables(16, 8, rope_scaling={"rope_type": "yarn"})
+        rope_tables(16, 8, rope_scaling={"rope_type": "longrope"})
 
 
 def test_rope_scaled_model_trains_and_decodes():

@@ -131,29 +131,6 @@ def reference_logits(params, cfg, ids):
     return np.asarray(hf @ head_weight(params).astype(hf.dtype))[0]
 
 
-@pytest.fixture
-def fresh_programs(monkeypatch):
-    """The engine's two programs under function objects of their own. JAX
-    keeps a traced program by the function it was traced from, whichever
-    `jax.jit` wraps it, so a patch that tracing consults (here: which form
-    of attention a step takes) needs functions that no other test of this
-    process has traced at the same shapes, and must leave none behind for
-    the bit-identical parity tests to pick up."""
-    import functools
-
-    def jits(donate):
-        decode = functools.wraps(engine.serve_decode)(
-            lambda *a, **k: engine.serve_decode(*a, **k))
-        prefill = functools.wraps(engine.serve_prefill)(
-            lambda *a, **k: engine.serve_prefill(*a, **k))
-        static = ("cfg", "temperature", "top_k", "pool_sharded")
-        return (jax.jit(decode, static_argnames=static + (
-                    "interval", "eos_token_id")),
-                jax.jit(prefill, static_argnames=static))
-
-    monkeypatch.setattr(engine, "_get_jits", jits)
-
-
 def test_sharded_pool_keeps_the_view(tiny, monkeypatch, fresh_programs):
     """tp = 2 serving pins the pool over its KV heads, and the compiler does
     not partition a Pallas call: with the kernel forced in wherever the
@@ -218,3 +195,104 @@ def test_engine_decodes_through_the_kernel(tiny, monkeypatch, fresh_programs):
                 break
             checked += 1
     assert checked >= 20
+
+
+# ---------------------------------------------------------------------------
+# a sliding-window layer: the band's first block on, through a ring
+# ---------------------------------------------------------------------------
+
+RING = 4   # blocks in a slot's ring: 64 positions for a band of 40
+WINDOW = 40
+
+WINDOW_CASES = {
+    # lengths below the window, at it, a block past it, several turns of
+    # the ring, idle
+    "ragged": dict(hq=12, hkv=2, lengths=[1, WINDOW, WINDOW + BS + 1, 7 * BS + 3, 0]),
+    "block_edges": dict(hq=4, hkv=2, lengths=[BS, 4 * BS, 5 * BS, 9 * BS + 1]),
+    "chunks_of_1_page": dict(hq=4, hkv=4, lengths=[3, 6 * BS + 5, WINDOW + 1], ppc=1),
+    "bf16_pool": dict(hq=12, hkv=2, lengths=[5, 11 * BS + 9, 0], dtype=jnp.bfloat16,
+                      tol=2e-2),
+}
+
+
+@pytest.mark.parametrize("name", WINDOW_CASES)
+def test_windowed_kernel_matches_jnp(name):
+    """The kernel with `window` reads, through a ring of RING blocks, the
+    positions max(length - WINDOW, 0) .. length - 1 and nothing else: every
+    pool position outside a live slot's band is NaN (the ring's blocks that
+    hold positions before the band among them), and the result equals plain
+    softmax attention over the band's keys."""
+    c = WINDOW_CASES[name]
+    d, dtype, li = 128, c.get("dtype", jnp.float32), 1
+    lengths = np.asarray(c["lengths"], np.int32)
+    rng = np.random.default_rng(sorted(WINDOW_CASES).index(name))
+    free = list(rng.permutation(NB - 1))
+    tables = np.full((len(lengths), RING), NB, np.int32)
+    for b, n in enumerate(lengths):
+        for j in range(min(-(-n // BS), RING)):
+            tables[b, j] = free.pop()
+    shape = (c["hkv"], L, NB, BS, d)
+    k = np.full(shape, np.nan, np.float32)
+    v = np.full(shape, np.nan, np.float32)
+    q = rng.standard_normal((len(lengths), c["hq"], d)).astype(np.float32)
+    want = np.zeros_like(q)
+    g = c["hq"] // c["hkv"]
+    for b, n in enumerate(lengths):
+        band = np.arange(max(n - WINDOW, 0), n)
+        kb = rng.standard_normal((len(band), c["hkv"], d)).astype(np.float32)
+        vb = rng.standard_normal((len(band), c["hkv"], d)).astype(np.float32)
+        if dtype == jnp.bfloat16:
+            kb, vb = (np.asarray(jnp.asarray(a, dtype), np.float32) for a in (kb, vb))
+        for i, pos in enumerate(band):
+            blk = tables[b, (pos // BS) % RING]
+            k[:, li, blk, pos % BS], v[:, li, blk, pos % BS] = kb[i], vb[i]
+        for h in range(c["hq"]):
+            s = kb[:, h // g] @ q[b, h] / np.sqrt(d)
+            p = np.exp(s - s.max()) if len(band) else s
+            want[b, h] = (p / max(p.sum(), 1e-30)) @ vb[:, h // g] if len(band) else 0
+    got = paged_decode_attention(
+        jnp.asarray(q, dtype), jnp.asarray(k, dtype), jnp.asarray(v, dtype), li,
+        jnp.asarray(tables), jnp.asarray(lengths), window=WINDOW,
+        pages_per_chunk=c.get("ppc"), interpret=True)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all() and (got[lengths == 0] == 0).all()
+    tol = c.get("tol", 2e-5)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def test_a_ring_too_short_for_the_band_is_refused():
+    pool = jnp.zeros((2, L, NB, BS, 128))
+    with pytest.raises(ValueError, match="ring"):
+        paged_decode_attention(jnp.zeros((1, 4, 128)), pool, pool, 0,
+                               jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32),
+                               window=WINDOW, interpret=True)
+
+
+def test_mixed_engine_decodes_through_the_windowed_kernel(monkeypatch, fresh_programs):
+    """`serve_decode` of a model with sliding and full layers, the kernel
+    forced in (interpreted) for both kinds: the tokens and their logits are
+    those of the tiled jnp path, within rounding."""
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-mellum2"))
+    params = init_params(cfg, jax.random.key(2))
+    rng = np.random.default_rng(5)
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+                for n, m in ((37, 8), (6, 5), (21, 7))]
+    scfg = ServeConfig(decode_slots=2, block_size=4, prefill_chunk=8,
+                       max_model_len=64, decode_interval=2)
+
+    def run():
+        eng = ServeEngine(params, cfg, scfg)
+        out = eng.run(requests)
+        eng.close()
+        assert (eng.pool.in_use, eng.wpool.in_use) == (0, 0)
+        return out
+
+    plain = run()
+    taken = []
+    monkeypatch.setattr(paged_cache, "decode_kernel_suits",
+                        lambda q, k: taken.append(q.shape[1]) or q.shape[1] == 1)
+    kernel = run()
+    assert 1 in taken
+    for a, b in zip(plain, kernel):
+        assert a["tokens"] == b["tokens"]
+        np.testing.assert_allclose(a["logits"], b["logits"], atol=2e-4)

@@ -732,3 +732,82 @@ def test_engine_cancel_resident_and_queued_no_leak(tiny, requests5,
     assert sorted(e["id"] for e in cancels) == sorted([v_slot, v_queue])
     assert {e["where"] for e in cancels} == {"slot", "queue"}
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# two kinds of cache state (a model with sliding-window layers), and experts
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("window", [None, 10], ids=["full_layer", "sliding_layer"])
+@pytest.mark.parametrize("chunk", [1, 7], ids=["decode_step", "prefill_chunk"])
+def test_mixed_cache_write_then_attend_matches_plain_attention(window, chunk):
+    """`MixedPagedKVCache`: a sequence written chunk by chunk through scattered
+    blocks (a ring of 5 blocks of 4 for the sliding layer, which it turns
+    four times; a growing table for the full layer) and attended in tiles
+    equals plain softmax attention over the positions the layer sees, at
+    every chunk, for two rows at different depths, one of them padded."""
+    from picotron_tpu.serve.paged_cache import MixedPagedKVCache
+
+    hkv, g, d, bs, ring, n_tok = 2, 2, 8, 4, 5, 83
+    rng = np.random.default_rng(0 if window is None else 1)
+    k_all, v_all = rng.standard_normal((2, 2, n_tok, hkv, d)).astype(np.float32)
+    q_all = rng.standard_normal((2, n_tok, hkv * g, d)).astype(np.float32)
+    nb, nwb, mb = 64, 16, 24
+    perm, wperm = rng.permutation(nb - 1), rng.permutation(nwb - 1)
+    tables = np.stack([perm[:mb], perm[mb:2 * mb]]).astype(np.int32)
+    wtables = np.stack([wperm[:ring], wperm[ring:2 * ring]]).astype(np.int32)
+    zeros = lambda layers, blocks: jnp.zeros((hkv, layers, blocks, bs, d))  # noqa: E731
+    cache = MixedPagedKVCache(zeros(1, nb), zeros(1, nb), zeros(2, nwb), zeros(2, nwb),
+                              jnp.asarray(tables), jnp.asarray(wtables))
+    ki = 0 if window is None else 1
+    depth = np.array([0, 0])  # row 1 lags row 0 by being fed pad rows now and then
+    step = 0
+    while depth[0] < n_tok:
+        n = np.array([min(chunk, n_tok - depth[0]),
+                      0 if step % 3 == 2 else min(chunk, n_tok - depth[1])])
+        pos = np.where(np.arange(chunk)[None] < n[:, None],
+                       depth[:, None] + np.arange(chunk)[None], -1)
+        take = np.clip(pos, 0, n_tok - 1)
+        rows = np.arange(2)[:, None]
+        cache = cache.write(0, jnp.asarray(k_all[rows, take]), jnp.asarray(v_all[rows, take]),
+                            jnp.asarray(pos), window=window, ki=ki)
+        got = np.asarray(cache.attend(0, jnp.asarray(q_all[rows, take]), jnp.asarray(pos),
+                                      window=window, ki=ki))
+        for b in range(2):
+            for i in range(n[b]):
+                p = pos[b, i]
+                lo = 0 if window is None else max(p - window + 1, 0)
+                for h in range(hkv * g):
+                    s = k_all[b, lo:p + 1, h // g] @ q_all[b, p, h] / np.sqrt(d)
+                    w = np.exp(s - s.max())
+                    want = (w / w.sum()) @ v_all[b, lo:p + 1, h // g]
+                    np.testing.assert_allclose(got[b, i, h], want, rtol=2e-5, atol=2e-5)
+        depth += n
+        step += 1
+    assert np.isfinite(np.asarray(cache.wk)).all()
+
+
+def test_engine_serves_experts_with_generates_tokens():
+    """A model with experts through `ServeEngine` (the fence that refused it is
+    gone): several requests side by side, chunked prefill, preemption-free:
+    the tokens are `generate`'s for each prompt alone, and the decode
+    program's count of touched experts stays inside its bounds."""
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-moe"))
+    params = init_params(cfg, jax.random.key(11))
+    rng = np.random.default_rng(3)
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+                for n, m in ((19, 7), (5, 9), (33, 4), (12, 6))]
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=3, block_size=4, prefill_chunk=8, max_model_len=64, decode_interval=2))
+    results = eng.run(requests)
+    eng.close()
+    for (prompt, n), res in zip(requests, results):
+        want = np.asarray(generate(params, cfg, jnp.asarray([prompt]), n))[0, len(prompt):]
+        assert res["tokens"] == want.tolist()
+        assert len(res["logits"]) == n and np.isfinite(res["logits"]).all()
+    assert eng.pool.in_use == 0 and eng.wpool is None
+    steps = eng.stats["expert_slots"] // (cfg.num_hidden_layers * cfg.num_experts)
+    # a live row is routed to 2 experts a layer: between 2 and 2 x the slots
+    lo, hi = 2 * cfg.num_hidden_layers * steps, 2 * 3 * cfg.num_hidden_layers * steps
+    assert lo <= eng.stats["experts_touched"] <= hi
