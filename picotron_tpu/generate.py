@@ -17,9 +17,10 @@ TPU-first decode design:
 - **Weight-compatible with training**: same param pytree (train ->
   generate without conversion), same RoPE/RMSNorm helpers and dense MLP
   block; a model with experts decodes through the dropless mathematics
-  it trains with at ep = 1 (`ops/moe.py moe_mlp_served`: few rows go
-  through every expert densely, many through the grouped matmuls; rows
-  without a token are routed nowhere).
+  it trains with at ep = 1 (`ops/moe.py moe_mlp_served`: the rows that
+  carry a token, permuted into expert order, through one grouped kernel
+  that reads only the experts they chose; rows without a token are routed
+  nowhere).
 - **A layer pattern**: a model whose layers are of two kinds (sliding
   window and full attention, each with its own RoPE law) is scanned a
   whole period at a time, each layer of the body traced with its kind;
@@ -173,9 +174,11 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     q_pos. Cache-agnostic: `cache` is any object with num_layers /
     write / attend (contiguous KVCache here, PagedKVCache or
     MixedPagedKVCache in picotron_tpu/serve). Returns (hidden, cache), and
-    with `with_touched` a third value: the experts that at least one row
-    with a token (q_pos >= 0) was routed to, summed over the layers (0 for
-    a dense model): what decides the expert bytes a step streams."""
+    with `with_touched` a third value, [2] int32 summed over the layers
+    (zeros for a dense model): the experts that at least one row with a
+    token (q_pos >= 0) was routed to, which decides the expert bytes a step
+    needs, and the (row tile, expert) pairs the experts' kernel visited,
+    which is what it read (`ops/moe.py moe_mlp_served`)."""
     dt = x.dtype
     d = cfg.head_dim
     period = cfg.layer_period
@@ -238,20 +241,20 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         return (x, cache, touched), None
 
     # the expert banks stay whole, outside the scanned inputs: a layer's
-    # grouped matmuls address its experts inside the stack (ops/moe.py
-    # _dropless_experts), so no bank is sliced out a layer
+    # grouped kernel addresses its experts inside the stack
+    # (ops/grouped_experts.py), so no bank is sliced out a layer
     layers = {n: w for n, w in params["layers"].items() if n not in BANKS}
     banks = {n: params["layers"].get(n) for n in BANKS}
     if len(period) > 1:
         layers = by_period(layers, len(period))
     # a dense model carries no counter: its programs are what they were
-    touched0 = jnp.zeros((), jnp.int32) if cfg.num_experts else None
+    touched0 = jnp.zeros((2,), jnp.int32) if cfg.num_experts else None
     (x, cache, touched), _ = lax.scan(
         body, (x, cache, touched0),
         (layers, jnp.arange(cache.num_layers // len(period))))
     if with_touched:
         return x, cache, (touched if touched is not None
-                          else jnp.zeros((), jnp.int32))
+                          else jnp.zeros((2,), jnp.int32))
     return x, cache
 
 
@@ -264,7 +267,8 @@ def _moe_served_block(x, lp, banks, li, cfg: ModelConfig, live):
     device: the decode paths run at ep = 1), as `models.llama._moe_block`
     computes them; rows without a token (`live` false: idle slots,
     chunk padding) are routed nowhere. `banks`: the model's whole expert
-    stacks, of which this is layer `li`. Returns (out, experts touched)."""
+    stacks, of which this is layer `li`. Returns (out, [experts touched,
+    (row tile, expert) pairs visited])."""
     h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
     return moe_mlp_served(
         h, lp["router"], *(banks[n] for n in BANKS),

@@ -423,7 +423,13 @@ def mlp_act(cfg: ModelConfig):
     bank, and the decode path so they cannot diverge."""
     if cfg.hidden_act == "silu":
         return jax.nn.silu
-    return partial(jax.nn.gelu, approximate=cfg.hidden_act == "gelu_tanh")
+    return _GELU[cfg.hidden_act == "gelu_tanh"]
+
+
+# one object a variant: the activation is a static argument of the served
+# expert block's jit (ops/moe.py), which keys its traces by it
+_GELU = {approximate: partial(jax.nn.gelu, approximate=approximate)
+         for approximate in (False, True)}
 
 
 @scope("mlp")

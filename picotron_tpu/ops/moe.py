@@ -21,7 +21,11 @@ Beyond the reference (SURVEY §2.2 marks EP/MoE absent) — designed TPU-first:
   permutation in, three grouped matmuls over the ragged group sizes
   (`lax.ragged_dot`, which this chip's compiler lowers to its own grouped
   matmul kernel that walks only the rows present), one permutation out.
-  No row of zeros is multiplied and no assignment can be dropped.
+  No row of zeros is multiplied and no assignment can be dropped. The
+  decode paths (`moe_mlp_served`) run the same mathematics through one
+  Pallas kernel a layer (`ops/grouped_experts.py`): the compiler's kernel
+  costs about 35 us a group however few rows it holds, which a decode
+  step's 256 rows over 64 experts cannot pay.
 - **Expert parallelism**: the expert bank [E, ...] is sharded over 'ep'
   (parallel/sharding.py). Dispatch builds per-device [E, C, H] slots, an
   `all_to_all` over 'ep' regroups them to [E/ep, ep*C, H] so each device
@@ -39,12 +43,16 @@ the one-hot dispatch tensor is O(N*E*C) memory, which at train shapes
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from picotron_tpu.ops.grouped_experts import (
+    group_tiles, grouped_swiglu, max_tiles, row_tile,
+)
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -196,20 +204,10 @@ _assignments_from_sorted.defvjp(_assignments_from_sorted_fwd,
                                 _assignments_from_sorted_bwd)
 
 
-def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act,
-                      layer=None):
+def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
     """Every assignment through its expert, no capacity: flat [N, H] ->
     [N, H] (gates applied, summed over k). Requires the whole bank
-    [E, H, F] / [E, F, H] on the device.
-
-    `layer` (the decode paths): the banks are the model's whole stacks
-    [L, E, H, F] / [L, E, F, H] and `layer` (traced or not) says whose
-    experts these are. The grouped matmuls then run over L * E groups of
-    which only this layer's E have rows: a group without rows costs no
-    read, and no layer's bank is sliced out of the stack first. (Sliced,
-    the compiler materialises each layer's three banks as the custom
-    call's operands: a second read and a write of every expert's weights
-    in a step whose time is reading them once.)"""
+    [E, H, F] / [E, F, H] on the device."""
     n, h = flat.shape
     k = r.expert_idx.shape[1]
     dt = flat.dtype
@@ -227,15 +225,7 @@ def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act,
         # the experts' group sizes: all of `counts`, less the dead rows'
         # group where the router was told of them (route_topk `live`); a
         # grouped matmul leaves the rows past its last group zero
-        e = w_gate.shape[-3]
-        sizes = r.counts if r.counts.shape[0] == e else r.counts[:e]
-        if layer is not None:
-            n_layers = w_gate.shape[0]
-            sizes = lax.dynamic_update_slice(
-                jnp.zeros((n_layers * e,), sizes.dtype), sizes,
-                (jnp.asarray(layer, jnp.int32) * e,))
-            w_gate, w_up, w_down = (w.reshape(n_layers * e, *w.shape[2:])
-                                    for w in (w_gate, w_up, w_down))
+        sizes = r.counts[:w_gate.shape[0]]
         g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
         u = lax.ragged_dot(xs, w_up.astype(dt), sizes)
         ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), sizes)
@@ -245,59 +235,86 @@ def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act,
     return out.astype(dt)
 
 
-# Tokens up to which the decode paths put every row through every expert
-# (`_every_expert`) instead of the grouped matmuls. On this chip the
-# compiler's grouped-matmul kernel costs about 35 us a group however few
-# rows the group has (PERF.md section 6, PR 33: 32 rows x 8 assignments over
-# 62 experts, 5.6 ms a layer where reading the three banks takes 1.0), while
-# the dense form streams the banks at 89% of the memory roofline; it does
-# E / k times the operations, which stops paying between 1,024 and 2,048
-# tokens (1,024: 4.3 against 7.0 ms a layer; 8,192 would be 33 against 9).
-EVERY_EXPERT_UP_TO = 1024
+def _grouped_experts(flat, r: Routing, live, w_gate, w_up, w_down, act,
+                     layer):
+    """`_dropless_experts` for the decode paths, through the grouped
+    kernel (`ops/grouped_experts.py`): flat [N, H] -> ([N, H], the (row
+    tile, expert) pairs the kernel visits). `r` routes the `live` [N] rows
+    alone (`route_topk(live=...)`); the banks are the model's whole stacks
+    [L, E, H, F] / [L, E, F, H], of which `layer` (traced or not) is this
+    layer: the kernel addresses its experts inside them, so no layer's bank
+    is sliced out or copied, and an expert without rows is never read.
 
-
-def _every_expert(flat, top_i, gate, live, w_gate, w_up, w_down, act):
-    """Every row through EVERY expert, the gate as the weight (0 for an
-    expert the row did not choose, and for a row without a token): the
-    mathematics of the dropless dispatch as three dense matmuls over the
-    whole bank, gate folded into the activation so that the down
-    projection is one [N, E * F] x [E * F, H] product. flat [N, H];
-    top_i / gate [N, k]; banks [E, H, F] / [E, F, H]. For few rows: a
-    decode step reads (nearly) every expert's weights anyway, and reads
-    them here at the speed of a dense matmul."""
-    n, e = flat.shape[0], w_gate.shape[0]
+    The live assignments are permuted into expert order as above, except
+    that an expert's rows start on a row tile (the tile is chosen from the
+    static number of rows and experts, `row_tile`): a tile then belongs to
+    one expert. A row without a token has no place in the buffer and comes
+    out as zeros, selected, not multiplied: what the buffer holds outside
+    the live assignments' rows is never written and never read."""
+    n, h = flat.shape
+    k = r.expert_idx.shape[1]
+    e = w_gate.shape[1]
     dt = flat.dtype
+    tm = row_tile(n * k, e)
+    n_tiles = max_tiles(n * k, e, tm)
+    with scope("moe_router"):
+        first_row, tile_expert, visits = group_tiles(r.counts[:e], tm, n_tiles)
+        # a dead assignment (expert index E) reads row 0 and is selected
+        # away below; in the inverse it lands beyond the buffer and is dropped
+        row = jnp.where(live[:, None],
+                        first_row[jnp.minimum(r.expert_idx, e - 1)] + r.slot,
+                        0)                                         # [N, k]
+        at = jnp.arange(n * k, dtype=jnp.int32)
+        inv = jnp.zeros((n_tiles * tm,), jnp.int32).at[
+            jnp.where(jnp.repeat(live, k), row.reshape(-1), n_tiles * tm + at)
+        ].set(at, mode="drop", unique_indices=True)
     with scope("moe_dispatch"):
-        dense = jnp.zeros((n, e), jnp.float32).at[
-            jnp.arange(n)[:, None], top_i].set(gate)
-        dense = jnp.where(live[:, None], dense, 0.0)
+        xs = flat[inv // k]                                        # [T*tm, H]
     with scope("moe_experts"):
-        g = jnp.einsum("nh,ehf->nef", flat, w_gate.astype(dt))
-        u = jnp.einsum("nh,ehf->nef", flat, w_up.astype(dt))
-        a = ((act(g) * u).astype(jnp.float32) * dense[..., None]).astype(dt)
-        out = jnp.einsum("nef,efh->nh", a, w_down.astype(dt),
-                         preferred_element_type=jnp.float32)
-    return out.astype(dt), dense
+        ys = grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, visits,
+                            layer, tm=tm, act=act)
+    with scope("moe_dispatch"):
+        picked = ys[row].astype(jnp.float32) * r.gate[..., None]  # [N, k, H]
+        out = jnp.sum(jnp.where(live[:, None, None], picked, 0.0), axis=1)
+    return out.astype(dt), visits
 
 
+def _split_over_a_mesh(w) -> bool:
+    """Whether the array a jitted program was handed lives on a mesh of
+    more than one device (`generate.place_for_decode` with tp > 1 shards the
+    banks on F): the compiler partitions `lax.ragged_dot`, and not a Pallas
+    kernel."""
+    return jax.typeof(w).sharding.mesh.size > 1
+
+
+# jitted: the layers of a scanned period call it with the same shapes, so a
+# program traces and lowers the block (and its Mosaic kernel) once, not once
+# a layer: a second of every start of an engine with five programs
+@functools.partial(jax.jit,
+                   static_argnames=("top_k", "act", "norm_topk_prob"))
 def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                    act, norm_topk_prob: bool, live, layer):
     """The expert block of the decode paths (`generate`, the serve
     programs): `moe_mlp`'s router and dropless mathematics, every expert
     on this device, no loss terms, and `live` [B, S] saying which rows
     carry a token (idle slots and chunk padding do not: they are routed
-    nowhere). The banks are the model's whole stacks [L, E, ...] and
-    `layer` this layer's index in them. A token's experts depend on that
-    token alone, so chunking a prompt differently changes nothing.
+    nowhere and come out as zeros). The banks are the model's whole stacks
+    [L, E, ...] and `layer` this layer's index in them. A token's experts
+    depend on that token alone, so chunking a prompt differently changes
+    nothing.
 
-    One mathematics, two forms, chosen from the number of rows alone: up
-    to EVERY_EXPERT_UP_TO tokens every row goes through every expert
-    densely (`_every_expert`), above it the rows are permuted into expert
-    order and go through the grouped matmuls (`_dropless_experts`).
+    One form at every number of rows: the live rows' assignments permuted
+    into expert order and put through the grouped kernel
+    (`_grouped_experts`), which visits the (row tile, expert) pairs that
+    hold rows and reads no other expert. Only banks that a mesh splits
+    (tp > 1) keep the compiler's grouped matmul, on this layer's slice of
+    the stacks.
 
-    Returns (out [B, S, H], touched []): the experts at least one live
-    row was routed to, which is what a decode step NEEDS of the expert
-    banks (the dense form reads all of them)."""
+    Returns (out [B, S, H], counts [2] int32): the experts at least one
+    live row was routed to, which is what a step NEEDS of the expert
+    banks, and the (row tile, expert) pairs the kernel visited, which is
+    what it read of them: an expert whose rows span two tiles is two
+    visits."""
     b, s, h = x.shape
     n, e = b * s, router_w.shape[1]
     flat = x.reshape(n, h)
@@ -305,23 +322,18 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     with scope("moe_router"):
         logits = (flat.astype(jnp.float32)
                   @ router_w.astype(jnp.float32))                 # [N, E] fp32
-    if n > EVERY_EXPERT_UP_TO:
-        with scope("moe_router"):
-            r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
-                           live=live)
-            touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
-        out = _dropless_experts(flat, r, w_gate, w_up, w_down, act,
-                                layer=layer)
-        return out.reshape(b, s, h), touched
-    with scope("moe_router"):
-        _, top_i, gate = topk_gates(logits, top_k, norm_topk_prob)
-    out, dense = _every_expert(
-        flat, top_i, gate, live,
-        *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-          for w in (w_gate, w_up, w_down)), act)
-    with scope("moe_router"):
-        touched = jnp.sum(jnp.any(dense > 0.0, axis=0)).astype(jnp.int32)
-    return out.reshape(b, s, h), touched
+        r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
+                       live=live)
+        touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
+    if _split_over_a_mesh(w_gate):
+        out = _dropless_experts(
+            flat, r, *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                       for w in (w_gate, w_up, w_down)), act)
+        visits = touched  # the compiler's kernel walks a group once
+    else:
+        out, visits = _grouped_experts(flat, r, live, w_gate, w_up, w_down,
+                                       act, layer)
+    return out.reshape(b, s, h), jnp.stack([touched, visits])
 
 
 def moe_mlp(

@@ -51,8 +51,10 @@ Sparse experts are served through the dropless dispatch (`ops/moe.py`
 `moe_mlp_served`): every expert is on the device, a token's experts depend
 on that token alone, so a prompt prefilled in any chunking routes alike;
 rows without a token (idle slots, pad rows, chunk padding) are routed
-nowhere and touch no expert. The decode program returns how many experts
-its live rows touched, which decides the bytes a step streams. A model
+nowhere and touch no expert, and the grouped kernel reads no expert that
+no live row chose. The decode program returns how many experts its live
+rows touched, which decides the bytes a step needs, and how many (row
+tile, expert) pairs the kernel visited, which is what it read. A model
 with sliding-window layers gets two pools and two tables a slot
 (`serve/paged_cache.py` MixedPagedKVCache): `_k`, `_v` and the tables fed
 to the programs are then pairs (full, window). The speculative program
@@ -177,13 +179,14 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     to keep emitting EOS — identical semantics to generate.py's scan —
     and the host truncates + retires them at dispatch end. Returns
     (tokens [S, interval], their logits [S, interval] float32, next
-    tokens, next positions, next tidx, experts touched, k, v); the
+    tokens, next positions, next tidx, expert counts [2], k, v); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
     (measured ~2x the whole dispatch on the CPU tiny-model bench).
-    Experts touched: the experts at least one live slot was routed to,
-    summed over the layers and the interval's steps (0 for a dense
-    model). `pool_sharded`: see `_paged_cache`."""
+    Expert counts: the experts at least one live slot was routed to and
+    the (row tile, expert) pairs the experts' kernel visited, each summed
+    over the layers and the interval's steps (zeros for a dense model).
+    `pool_sharded`: see `_paged_cache`."""
     live = positions >= 0
 
     def one(carry, _):
@@ -205,7 +208,7 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
         jax.lax.scan(one, (toks, positions, tidx, cache, done,
-                           jnp.zeros((), jnp.int32)), None, length=interval)
+                           jnp.zeros((2,), jnp.int32)), None, length=interval)
     return (toks_all.T, lg_all.T, last, positions, tidx, touched,
             *_pools(cache))
 
@@ -439,9 +442,10 @@ class ServeEngine:
             "output_tokens": 0, "prefill_tokens": 0,
             "draft_tokens": 0, "accepted_draft_tokens": 0,
             "decode_stall_ticks_max": 0, "cancelled": 0,
-            # experts the decode steps' live rows were routed to, out of
-            # layers x steps x experts (0 / 0 for a dense model)
-            "experts_touched": 0, "expert_slots": 0,
+            # experts the decode steps' live rows were routed to and (row
+            # tile, expert) pairs their kernel visited, out of layers x
+            # steps x experts (all 0 for a dense model)
+            "experts_touched": 0, "expert_visits": 0, "expert_slots": 0,
         }
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
         self._next_auto_id = 0
@@ -895,15 +899,19 @@ class ServeEngine:
                 nval = np.asarray(nval_d)  # [S, interval]
             else:
                 # tokens and their logits [S, interval], and the experts
-                # the steps touched: known once the dispatch has run, so
-                # the counts ride this span and not the dispatch's
-                nxt, lgs, touched = jax.device_get((toks_d, lg_d, touched_d))
+                # the steps touched and visited: known once the dispatch
+                # has run, so the counts ride this span and not the
+                # dispatch's
+                nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
                 if self.cfg.num_experts:
+                    touched, visits = (int(c) for c in counts)
                     slots = (self.cfg.num_hidden_layers * interval
                              * self.cfg.num_experts)
-                    self.stats["experts_touched"] += int(touched)
+                    self.stats["experts_touched"] += touched
+                    self.stats["expert_visits"] += visits
                     self.stats["expert_slots"] += slots
-                    sp.set(experts_touched=int(touched), expert_slots=slots)
+                    sp.set(experts_touched=touched, expert_visits=visits,
+                           expert_slots=slots)
         # feed outputs forward; any roster/table change below
         # nulls this via _sync_table
         self._decode_state = state
@@ -1055,6 +1063,7 @@ class ServeEngine:
                 round(self.wpool.peak_in_use / self.num_window_blocks, 4)
                 if self.wpool is not None else None),
             "experts_touched": self.stats.get("experts_touched", 0),
+            "expert_visits": self.stats.get("expert_visits", 0),
             "expert_slots": self.stats.get("expert_slots", 0),
             "decode_steps": self.stats["decode_steps"],
             "decode_compiles": self.stats["decode_compiles"],

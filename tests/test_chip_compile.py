@@ -415,9 +415,9 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
     """Both serve programs of `mellum2-12b-a2.5b-8l` compile for a v5e and
     fit it; no pool of either kind is copied whole and all four are written
     in place; no layer's expert bank is sliced out of its stack (the
-    grouped matmuls address a layer's experts inside it); the names the
+    grouped kernel addresses a layer's experts inside it); the names the
     cell's metrics read are there: the decode kernel in both layer kinds'
-    scopes, the compiler's `ragged-dot-*` kernels, the expert scopes."""
+    scopes, the experts' kernel under `moe_experts`, the expert scopes."""
     comp, pool_shapes, pools = compiled_mellum(topo, monkeypatch, program, rows)
     text = comp.as_text()
     assert text.startswith(f"HloModule jit_{program}")
@@ -432,22 +432,25 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
     alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
     assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
     kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
-    experts = re.compile(load("layer_metrics", "moe_experts_ms.serve")["params"]["ops"])
     attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
-    grouped = [n for n, _ in kernels if experts.search(n)]
     paged = [(n, op) for n, op in kernels if attn.search(n)]
+    grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
     assert len(grouped) + len(paged) == len(kernels), kernels
-    # up to EVERY_EXPERT_UP_TO tokens (a decode step's 32, a one-row chunk's
-    # 256) every row goes through every expert in dense matmuls under the
-    # moe_experts scope; above (32 rows x 256) through the grouped matmuls:
-    # gate, up and down of each of the period's four layers
-    from picotron_tpu.ops.moe import EVERY_EXPERT_UP_TO
+    # the experts of each of the period's four layers are ONE kernel (gate,
+    # up, activation and down; ops/grouped_experts.py) at every number of
+    # rows, and its event carries the scope `moe_experts_ms.serve` and
+    # `moe_experts_roofline.serve` sum: they find it by that word alone
+    scopes = set(load("layer_metrics", "moe_experts_ms.serve")["params"]["scopes"])
+    assert len(grouped) == 4, kernels
+    assert all(scopes <= words(op) for _, op in grouped), grouped
+    # nothing of the two forms it replaced: no compiler's grouped matmul, no
+    # [tokens, 64, 896] product of every row with every expert
+    assert "ragged-dot" not in text
+    m = load("configs", "mellum2-12b-a2.5b-8l")["model"]
     tokens = 32 if program == "serve_decode" else rows * 256
-    want = 12 if tokens > EVERY_EXPERT_UP_TO else 0
-    assert sum(n.startswith("ragged-dot-none") for n in grouped) == want, grouped
-    dots = [n for n, op, line in ins if "moe_experts" in words(op)
-            and re.search(r" (convolution|dot)\(", line)]
-    assert want or len(dots) >= 12, dots
+    every = tokens * m["num_experts"] * m["moe_intermediate_size"]
+    dense = [line.strip()[:160] for _, _, line in ins if every in result_sizes(line)]
+    assert not dense, dense
     if program == "serve_decode":
         assert len(paged) == 4  # three sliding layers and a full one a period
         assert sum("attn_window" in words(op) for _, op in paged) == 3

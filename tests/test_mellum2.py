@@ -122,21 +122,28 @@ def serve(params, prompts, new, **scfg):
     return eng, sorted(eng.results, key=lambda r: r["id"])
 
 
-@pytest.mark.parametrize("chunk,every_expert_up_to", [(8, 1024), (16, 1024), (16, 0)])
-def test_engine_logits_match_reference(params, ids, chunk, every_expert_up_to,
-                                       monkeypatch, fresh_programs):
+@pytest.mark.parametrize("chunk,least_tile", [(8, 16), (16, 16), (16, 1)])
+def test_engine_logits_match_reference(params, ids, chunk, least_tile,
+                                       monkeypatch, fresh_programs, request):
     """Chunked prefill on the rungs + decode through both pools: the logit
     the engine hands out for every token is the reference's logit of that
     token at that position in ONE full forward; two requests side by side,
     one of which turns its ring several times. The same prompt under
     another `prefill_chunk` gives the same logits (a token's experts do
-    not depend on the chunking), and so does the expert block's other
-    form: few rows go through every expert densely, many through the
-    grouped matmuls (`ops/moe.py` EVERY_EXPERT_UP_TO; 0 forces the
-    grouped form, with idle rows in a group of their own)."""
-    from picotron_tpu.ops import moe
+    not depend on the chunking), and so does the experts' grouped kernel
+    under another tiling (`ops/grouped_experts.py row_tile`, from the
+    number of rows: at this size every program rides the least tile, 16,
+    where an expert is one visit; a least tile of 1 gives the decode step
+    tiles of one row, a one-row chunk 4 and a four-row chunk 16, and
+    experts whose rows fill several tiles)."""
+    from picotron_tpu.ops import grouped_experts
 
-    monkeypatch.setattr(moe, "EVERY_EXPERT_UP_TO", every_expert_up_to)
+    monkeypatch.setattr(grouped_experts, "MIN_ROW_TILE", least_tile)
+    if least_tile != 16:
+        # the expert block is jitted and keeps its traces by shape: none
+        # made under another tiling may serve this case, none of its may stay
+        jax.clear_caches()
+        request.addfinalizer(jax.clear_caches)
     prompts, new = [ids[:40], ids[5:28]], [14, 9]
     eng, res = serve(params, prompts, new, prefill_chunk=chunk)
     assert eng.ring_blocks == ring_blocks_for(8, chunk, 4) < (40 + 14) // 4
@@ -151,6 +158,10 @@ def test_engine_logits_match_reference(params, ids, chunk, every_expert_up_to,
     # the router's counter: live rows only, out of layers x steps x experts
     assert 0 < eng.stats["experts_touched"] <= eng.stats["expert_slots"]
     assert eng.stats["expert_slots"] % (8 * 2 * 8) == 0
+    # the kernel's counter: an expert is one visit while a tile holds the
+    # four slots, and two where both requests chose it and a tile holds one
+    visits, touched = eng.stats["expert_visits"], eng.stats["experts_touched"]
+    assert visits == touched if least_tile == 16 else visits > touched
 
 
 def test_idle_rows_touch_no_expert(params, ids):
@@ -159,6 +170,7 @@ def test_idle_rows_touch_no_expert(params, ids):
     eng, _ = serve(params, [ids[:9]], [5], prefill_chunk=16)
     steps = eng.stats["expert_slots"] // (8 * 8)  # decode steps dispatched
     assert eng.stats["experts_touched"] == steps * 8 * 2
+    assert eng.stats["expert_visits"] == eng.stats["experts_touched"]
 
 
 # ---------------------------------------------------------------------------
