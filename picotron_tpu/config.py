@@ -21,7 +21,7 @@ import dataclasses
 import json
 import math
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 
 # ---------------------------------------------------------------------------
@@ -182,6 +182,27 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
             sliding_attention=dict(rope_type="default",
                                    rope_theta=500000.0)),
     ),
+    # openPangu-Ultra-MoE-718B (model_type pangu_ultra_moe): latent
+    # attention (MLA: q through a rank-1536 bottleneck, K and V through one
+    # rank-512 latent + 64 shared RoPE dimensions), 3 leading dense layers
+    # (SwiGLU 18,432) before 58 expert layers (256 routed experts of width
+    # 2,048, 8 a token by sigmoid scores renormalised and scaled 2.5, + 1
+    # shared expert), four RMSNorms a layer (sandwich), untied head. The
+    # multi-token-prediction layer (num_nextn_predict_layers 1) drafts and
+    # is not built: a served token does not pass through it.
+    "FreedomIntelligence/openPangu-Ultra-MoE-718B": dict(
+        vocab_size=153600, hidden_size=7680, intermediate_size=18432,
+        num_hidden_layers=61, num_attention_heads=128,
+        num_key_value_heads=128, max_position_embeddings=131072,
+        rope_theta=25600000.0, rms_norm_eps=1e-5,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense_replace=3, num_experts=256, num_experts_per_token=8,
+        moe_intermediate_size=2048, n_shared_experts=1,
+        norm_topk_prob=True, moe_scoring="sigmoid",
+        routed_scaling_factor=2.5, sandwich_norm=True,
+        router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -234,6 +255,21 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
             sliding_attention=dict(rope_type="default",
                                    rope_theta=10000.0)),
     ),
+    # Tiny openPangu-Ultra-MoE-shaped debug model: 1 dense + 3 expert
+    # layers, MLA (nope 16 / rope 8 / v 16, q rank 24, latent 32), 16
+    # routed experts 2 a token + 1 shared, sigmoid scores scaled 2.5,
+    # sandwich norms. Served with block_size 4.
+    "picotron-tpu/debug-tiny-pangu-moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        first_k_dense_replace=1, num_experts=16, num_experts_per_token=2,
+        moe_intermediate_size=32, n_shared_experts=1, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=2.5,
+        sandwich_norm=True, router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -264,6 +300,8 @@ _PRESET_ALIASES = {
     "debug-tiny-olmoe": "picotron-tpu/debug-tiny-olmoe",
     "Mellum2-12B-A2.5B": "JetBrains/Mellum2-12B-A2.5B-Instruct",
     "debug-tiny-mellum2": "picotron-tpu/debug-tiny-mellum2",
+    "openPangu-Ultra-MoE-718B": "FreedomIntelligence/openPangu-Ultra-MoE-718B",
+    "debug-tiny-pangu-moe": "picotron-tpu/debug-tiny-pangu-moe",
 }
 
 
@@ -287,7 +325,7 @@ def resolve_hf_name(name: str) -> str:
 def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
-    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum-family model
+    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/Pangu-Ultra-MoE-family model
     outside the preset registry resolves from its config file instead of
     hand-typed hyperparameters. Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -297,7 +335,8 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             hf = json.load(f)
 
     mtype = hf.get("model_type", "llama")
-    supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum")
+    supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
+                 "pangu_ultra_moe")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -336,8 +375,10 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             f"hidden_act {act!r} unsupported (silu/gelu gated MLPs only)")
     if hf.get("rope_scaling"):
         out["rope_scaling"] = dict(hf["rope_scaling"])
-    # Mixtral spells the expert count num_local_experts, OLMoE num_experts
-    n_experts = hf.get("num_local_experts") or hf.get("num_experts")
+    # Mixtral spells the expert count num_local_experts, OLMoE num_experts,
+    # the DeepSeek-V3 lineage (Pangu Ultra MoE) n_routed_experts
+    n_experts = (hf.get("num_local_experts") or hf.get("num_experts")
+                 or hf.get("n_routed_experts"))
     if n_experts:
         out["num_experts"] = n_experts
         out["num_experts_per_token"] = hf.get("num_experts_per_tok", 2)
@@ -350,12 +391,36 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         out["head_dim"] = hf["head_dim"]
     if hf.get("moe_intermediate_size"):
         out["moe_intermediate_size"] = hf["moe_intermediate_size"]
-    if "dense" in (hf.get("mlp_layer_types") or ()):
-        # M3: the layer scan runs one kind of MLP in every layer
-        raise ValueError(
-            "mlp_layer_types holds a 'dense' entry: dense and sparse MLP "
-            "layers in one model are not supported (every layer of the "
-            "scan has the same parameters); only all-'sparse' loads")
+    mlp_kinds = tuple(hf.get("mlp_layer_types") or ())
+    if "dense" in mlp_kinds:
+        # the layer tree is two stacks, the leading dense layers and the
+        # expert layers after them (first_k_dense_replace); a dense layer
+        # anywhere else has no stack to live in
+        lead = mlp_kinds.index("sparse") if "sparse" in mlp_kinds else 0
+        if not lead or "dense" in mlp_kinds[lead:]:
+            raise ValueError(
+                "mlp_layer_types holds a 'dense' entry behind a 'sparse' "
+                "one (or no 'sparse' entry at all): dense MLP layers are "
+                "supported at the head of the stack only, before every "
+                "expert layer (first_k_dense_replace)")
+        out["first_k_dense_replace"] = lead
+    if mtype == "pangu_ultra_moe":
+        # MLA's widths, the leading dense layers, the shared expert and
+        # the router's law. config.json has routed_scaling_factor and
+        # norm_topk_prob and no scoring_func / n_group / topk_method key:
+        # sigmoid scores, no selection bias, no expert groups (the
+        # family's modelling code, the DeepSeek-V3 convention those two
+        # keys come from). sandwich_norm: four RMSNorms a layer.
+        for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                    "qk_rope_head_dim", "v_head_dim"):
+            out[key] = int(hf[key])
+        out["first_k_dense_replace"] = int(hf.get("first_k_dense_replace", 0))
+        out["n_shared_experts"] = int(hf.get("n_shared_experts", 0))
+        out["moe_scoring"] = "sigmoid"
+        out["routed_scaling_factor"] = float(
+            hf.get("routed_scaling_factor", 1.0))
+        out["sandwich_norm"] = bool(hf.get("sandwich_norm", False))
+        out["router_aux_coef"] = 0.0
     if hf.get("layer_types"):
         # layer_types decides which layers slide (use_sliding_window /
         # max_window_layers are read as "layer_types decides")
@@ -515,6 +580,16 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
     return _parse_mesh2(spec, "cp_mesh")
 
 
+class Block(NamedTuple):
+    """What one decoder block is made of: the one description that the
+    training forward (`models.llama.decoder_layer`) and the cached decode
+    forward (`generate._decode_layers`) both read."""
+
+    attn: str       # "gqa": q/k/v per head | "mla": latent attention
+    mlp: str        # "dense": gated MLP | "experts": routed (+ shared) experts
+    sandwich: bool  # RMSNorms on the attention's and the MLP's outputs too
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Llama-family architecture hyperparameters.
@@ -601,6 +676,40 @@ class ModelConfig:
     # statistics (cheaper by two [E]-sized pmeans per layer, differs across
     # layouts by O(shard variance)).
     router_aux_global: bool = True
+    # Latent attention (MLA; kv_lora_rank > 0): q through a low-rank
+    # bottleneck with its own RMSNorm (q_lora_rank), K and V of every head
+    # expanded from ONE normed latent of kv_lora_rank numbers a token, plus
+    # qk_rope_head_dim rotated dimensions shared by all heads; a head's
+    # query and key are qk_nope_head_dim + qk_rope_head_dim wide, its value
+    # v_head_dim. A cache holds the latent and the rotated dimensions and
+    # nothing per head (ops/mla.py). The published keys.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first k layers keep the dense gated MLP of `intermediate_size`;
+    # the layers after them have the experts (the published key). The layer
+    # tree is then two stacks, `dense_layers` and `layers` (`stacks`).
+    first_k_dense_replace: int = 0
+    # Experts every token passes through, beside the routed ones: one gated
+    # MLP of n_shared_experts x the expert width, gate 1.
+    n_shared_experts: int = 0
+    # The share of the experts this device holds (serving one chip of an
+    # expert-parallel group, with no exchange): the router scores
+    # `router_experts` experts (0 = num_experts: every expert is here) and
+    # the banks hold `num_experts` of them from index `expert_first` on. A
+    # pick that lands elsewhere adds nothing here.
+    router_experts: int = 0
+    expert_first: int = 0
+    # The router's scoring law: "softmax" over all router logits, or
+    # "sigmoid" of each (DeepSeek-V3 lineage); the k largest scores are
+    # renormalised (norm_topk_prob) and scaled by routed_scaling_factor.
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Four RMSNorms a layer: the attention's and the MLP's OUTPUTS are
+    # normed too before they join the residual stream (Pangu Ultra).
+    sandwich_norm: bool = False
     # Accepted for reference compat (ref uses them to pick CUDA kernels).
     use_flash_attention: bool = True
     use_fused_adam: bool = True
@@ -669,6 +778,38 @@ class ModelConfig:
     def expert_ffn_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """The rotated width of a head: all of it, or MLA's shared
+        qk_rope_head_dim."""
+        return self.qk_rope_head_dim if self.mla else self.head_dim
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def stacks(self) -> tuple:
+        """The layer tree's stacks in order, (params key, layers, Block)
+        each: what `models.llama.run_stacks` and
+        `generate._decode_layers` scan, one stack after the other. One
+        stack, `layers`, for a model of one kind of block; the leading
+        dense layers of a model with first_k_dense_replace are
+        `dense_layers`."""
+        attn = "mla" if self.mla else "gqa"
+        n, k = self.num_hidden_layers, self.first_k_dense_replace
+        if not self.num_experts:
+            return (("layers", n, Block(attn, "dense", self.sandwich_norm)),)
+        out = (("layers", n - k, Block(attn, "experts", self.sandwich_norm)),)
+        if k:
+            out = (("dense_layers", k,
+                    Block(attn, "dense", self.sandwich_norm)),) + out
+        return out
+
     def validate(self) -> None:
         if self.attn_impl not in ("auto", "flash", "reference", "ring",
                                   "ulysses", "mesh"):
@@ -708,6 +849,65 @@ class ModelConfig:
             raise ValueError(
                 f"hidden_act must be 'silu', 'gelu', or 'gelu_tanh', got "
                 f"{self.hidden_act!r}")
+        if self.mla:
+            for key in ("q_lora_rank", "qk_nope_head_dim",
+                        "qk_rope_head_dim", "v_head_dim"):
+                if getattr(self, key) < 1:
+                    raise ValueError(
+                        f"kv_lora_rank > 0 (latent attention) needs "
+                        f"{key} >= 1, got {getattr(self, key)}")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even for RoPE")
+            if (self.attention_bias or self.qk_norm
+                    or self.layer_types is not None or self.rope_parameters):
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) has no qkv bias, "
+                    "no whole-vector QK-norm, no sliding-window layers and "
+                    "one RoPE law: attention_bias / qk_norm / layer_types / "
+                    "rope_parameters must be unset")
+        elif (self.q_lora_rank or self.qk_nope_head_dim
+                or self.qk_rope_head_dim or self.v_head_dim):
+            raise ValueError(
+                "q_lora_rank / qk_nope_head_dim / qk_rope_head_dim / "
+                "v_head_dim are latent attention's (MLA) widths: set "
+                "kv_lora_rank > 0 with them, or none of them")
+        if self.first_k_dense_replace:
+            if not self.num_experts:
+                raise ValueError(
+                    "first_k_dense_replace > 0 needs num_experts > 0: a "
+                    "model without experts is dense in every layer")
+            if not 0 < self.first_k_dense_replace < self.num_hidden_layers:
+                raise ValueError(
+                    f"first_k_dense_replace ({self.first_k_dense_replace}) "
+                    f"must leave at least one expert layer of "
+                    f"num_hidden_layers ({self.num_hidden_layers})")
+            if self.layer_types is not None:
+                raise ValueError(
+                    "first_k_dense_replace > 0 with sliding-window "
+                    "layer_types: a stack's scan runs whole periods of the "
+                    "pattern, and nobody has cut a pattern over two stacks")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', got "
+                f"{self.moe_scoring!r}")
+        if (self.n_shared_experts or self.router_experts
+                or self.expert_first) and not self.num_experts:
+            raise ValueError(
+                "n_shared_experts / router_experts / expert_first describe "
+                "an expert layer: they need num_experts > 0")
+        if self.n_shared_experts < 0 or self.expert_first < 0:
+            raise ValueError(
+                "n_shared_experts and expert_first must be >= 0")
+        if self.num_experts and (
+                self.expert_first + self.num_experts > self.router_width):
+            raise ValueError(
+                f"the experts held here ({self.num_experts} from index "
+                f"{self.expert_first}) do not lie inside the router's "
+                f"{self.router_width} (router_experts)")
+        if self.num_experts and self.num_experts_per_token > self.router_width:
+            raise ValueError(
+                f"num_experts_per_token ({self.num_experts_per_token}) "
+                f"exceeds the router's width ({self.router_width})")
 
 
 @dataclass(frozen=True)
@@ -945,7 +1145,10 @@ class ServeConfig:
     # Physical blocks in the shared pool. 0 = auto: decode_slots *
     # ceil(max_model_len / block_size) — the no-oversubscription worst
     # case (same HBM as a contiguous cache at max length). Set it
-    # explicitly to actually bank the paged-cache memory win.
+    # explicitly to actually bank the paged-cache memory win. A model with
+    # latent attention (kv_lora_rank > 0) gets a latent pool of as many
+    # blocks: its bytes follow the latent's width, not the heads'
+    # (serve/paged_cache.py latent_row_width).
     num_blocks: int = 0
     # A model with sliding-window layers keeps a second pool for them:
     # a slot holds a fixed RING of at most
@@ -1261,6 +1464,7 @@ class Config:
                 "through one ServeEngine")
         if self.model.layer_types is not None:
             self._refuse_window_layers()
+        self._refuse_new_blocks()
         if self.serve.fleet_size > 1 and self.serve.speculator != "off":
             # The n-gram drafter's context is engine-local state that a
             # failover re-dispatch does not carry — tokens stay identical
@@ -1571,6 +1775,62 @@ class Config:
             refuse("serve.disagg / serve.speculator / serve.fleet_size > 1 "
                    "(one pool, one table a slot)")
 
+    def _refuse_new_blocks(self) -> None:
+        """Latent attention, sandwich norms, a shared expert, sigmoid
+        routing, a held share of the experts and leading dense layers run
+        on the plain attention of `forward()` (and its AD), on
+        `generate()` and on `ServeEngine`, on one device. Every path that
+        has its own copy of the block, one head width for q, k and v, or a
+        single `layers` stack refuses them by name (ROADMAP M5, M3, D6)."""
+        d, m, t, sv = (self.distributed, self.model, self.training,
+                       self.serve)
+        what = [name for name, on in (
+            ("latent attention (kv_lora_rank > 0)", m.mla),
+            ("first_k_dense_replace > 0", m.first_k_dense_replace > 0),
+            ("sandwich_norm", m.sandwich_norm),
+            ("n_shared_experts > 0", m.n_shared_experts > 0),
+            ("moe_scoring='sigmoid'", m.moe_scoring != "softmax"),
+            ("routed_scaling_factor != 1", m.routed_scaling_factor != 1.0),
+            ("a held share of the experts (router_experts)",
+             m.router_width != m.num_experts),
+        ) if on]
+        if not what:
+            return
+
+        def refuse(path: str, why: str) -> None:
+            raise ValueError(
+                f"model has {', '.join(what)}, which {path} does not "
+                f"implement ({why}); such a model runs on "
+                f"attn_impl='reference' with grad_engine='ad', on "
+                f"generate() and on ServeEngine, on one device")
+
+        if m.attn_impl in ("flash", "ring", "ulysses", "mesh"):
+            refuse(f"attn_impl={m.attn_impl!r}",
+                   "the flash kernels and the cp schedules have one head "
+                   "width for q, k and v and no latent form")
+        if d.cp_size > 1:
+            refuse(f"context parallelism (cp_size={d.cp_size})",
+                   "the ring / ulysses / mesh schedules move K and V per "
+                   "head")
+        if t.grad_engine == "fused":
+            refuse("grad_engine='fused'",
+                   "the fused grad engine carries its own copy of the "
+                   "block and one `layers` stack")
+        if d.tp_size > 1:
+            refuse(f"tensor parallelism (tp_size={d.tp_size})",
+                   "a latent cache has one head and is not split by "
+                   "heads; the new weights have no tp sharding rule")
+        if d.pp_size > 1:
+            refuse(f"pipeline parallelism (pp_size={d.pp_size})",
+                   "a stage slices one `layers` stack")
+        if d.ep_size > 1:
+            refuse(f"expert parallelism (ep_size={d.ep_size})",
+                   "the exchange across 'ep' routes by softmax gates over "
+                   "every expert; a held share is served without exchange")
+        if sv.disagg or sv.speculator != "off" or sv.fleet_size > 1:
+            refuse("serve.disagg / serve.speculator / serve.fleet_size > 1",
+                   "one K/V pool, never run with this block")
+
     def to_json_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
 
@@ -1722,25 +1982,36 @@ def num_params(m: ModelConfig, active_only: bool = False,
     understate MFU by the head's share."""
     h, i, v, l = m.hidden_size, m.intermediate_size, m.vocab_size, m.num_hidden_layers
     kv = m.num_key_value_heads * m.head_dim
+    dense_ffn = 3 * h * i  # gate/up/down
     if m.num_experts:
         e_ffn = 3 * h * m.expert_ffn_size  # gate/up/down per expert
         n_ffn_experts = (m.num_experts_per_token if active_only
                          else m.num_experts)
-        ffn = h * m.num_experts + n_ffn_experts * e_ffn  # router + experts
+        # router + routed experts (those held here) + shared experts
+        ffn = (h * m.router_width + n_ffn_experts * e_ffn
+               + m.n_shared_experts * e_ffn)
     else:
-        ffn = 3 * h * i  # gate/up/down
-    q = m.num_attention_heads * m.head_dim
-    per_layer = (
-        h * q  # q_proj
-        + h * kv * 2  # k/v_proj
-        + q * h  # out_proj
-        + ffn
-        + 2 * h  # two RMSNorm weights
-    )
-    if m.attention_bias:
-        per_layer += q + 2 * kv  # q/k/v biases
-    if m.qk_norm:
-        per_layer += q + kv  # q_norm / k_norm weights
+        ffn = dense_ffn
+    if m.mla:
+        heads = m.num_attention_heads
+        attn = (h * m.q_lora_rank + m.q_lora_rank  # q_a + its norm
+                + m.q_lora_rank * heads * (m.qk_nope_head_dim
+                                           + m.qk_rope_head_dim)
+                + h * (m.kv_lora_rank + m.qk_rope_head_dim)
+                + m.kv_lora_rank  # kv_a + its norm
+                + m.kv_lora_rank * heads * (m.qk_nope_head_dim
+                                            + m.v_head_dim)
+                + heads * m.v_head_dim * h)
+    else:
+        q = m.num_attention_heads * m.head_dim
+        attn = h * q + h * kv * 2 + q * h  # q, k/v, out projections
+        if m.attention_bias:
+            attn += q + 2 * kv  # q/k/v biases
+        if m.qk_norm:
+            attn += q + kv  # q_norm / k_norm weights
+    norms = (4 if m.sandwich_norm else 2) * h  # RMSNorm weights a layer
+    k = m.first_k_dense_replace
+    layers = (l - k) * (attn + ffn + norms) + k * (attn + dense_ffn + norms)
     head = (h * v if (not m.tie_word_embeddings or include_tied_head)
             else 0)
-    return v * h + l * per_layer + h + head  # embed + layers + final_norm (+ head)
+    return v * h + layers + h + head  # embed + layers + final_norm (+ head)

@@ -58,8 +58,9 @@ from picotron_tpu.config import ModelConfig
 from picotron_tpu.models.llama import (
     DEFAULT_CTX, _mlp_block, by_period, compute_dtype, final_hidden,
     head_weight, kind_tables, layer_window, mlp_act, model_rope_tables,
-    qkv_proj, rms_norm,
+    qkv_proj, rms_norm, shared_expert,
 )
+from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope
 from picotron_tpu.telemetry.scopes import scope
@@ -113,10 +114,54 @@ class KVCache(NamedTuple):
         return _cached_attention(q, *self.layer_view(li), q_pos, window)
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_length: int) -> KVCache:
+class LatentCache(NamedTuple):
+    """Per-layer contiguous latent cache of a model with latent attention
+    (MLA, ops/mla.py), [L, B, S_max, rank + rope]: `[c | k_r]` a position,
+    c after its norm and k_r after its rotation, nothing per head. The
+    offline twin of `serve.paged_cache.LatentPagedCache`; the layer loop
+    calls both alike: `write(li, ckr, q_pos)` and `attend(li, q_n, q_r,
+    q_pos, kv_b, cfg)`."""
+
+    ckr: jnp.ndarray
+
+    @property
+    def num_layers(self) -> int:
+        return self.ckr.shape[0]
+
+    def write(self, li, ckr_new, q_pos) -> "LatentCache":
+        """ckr_new [B, s, rank + rope] into slots q_pos[0] .. q_pos[-1] of
+        layer li (contiguous, batch-shared positions: the offline
+        arrangement)."""
+        return LatentCache(lax.dynamic_update_slice(
+            self.ckr, ckr_new[None], (li, 0, q_pos[0], 0)))
+
+    def attend(self, li, q_n, q_r, q_pos, kv_b, cfg):
+        b, s = q_n.shape[:2]
+        s_max = self.ckr.shape[2]
+        tile = min(TILE_KEYS, s_max)
+        tiles = -(-s_max // tile)
+        rows = jnp.pad(lax.dynamic_index_in_dim(self.ckr, li, 0, keepdims=False),
+                       ((0, 0), (0, tiles * tile - s_max), (0, 0)))
+        at = jnp.arange(tile)
+
+        def fetch(bi, t):
+            kp = t * tile + at
+            return (lax.dynamic_slice_in_dim(rows[bi], t * tile, tile, 0),
+                    jnp.where(kp < s_max, kp, -1))
+
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        return latent_attention(q_n, q_r, q_pos, fetch, tiles, tile, kv_b, cfg)
+
+
+def init_cache(cfg: ModelConfig, batch: int, max_length: int):
+    dt = compute_dtype(cfg)
+    if cfg.mla:
+        return LatentCache(jnp.zeros(
+            (cfg.num_hidden_layers, batch, max_length,
+             cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt))
     shape = (cfg.num_hidden_layers, batch, max_length,
              cfg.num_key_value_heads, cfg.head_dim)
-    dt = compute_dtype(cfg)
     return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
 
@@ -174,7 +219,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     q_pos. Cache-agnostic: `cache` is any object with num_layers /
     write / attend (contiguous KVCache here, PagedKVCache or
     MixedPagedKVCache in picotron_tpu/serve). Returns (hidden, cache), and
-    with `with_touched` a third value, [2] int32 summed over the layers
+    with `with_touched` a third value, [4] int32 summed over the layers
     (zeros for a dense model): the experts that at least one row with a
     token (q_pos >= 0) was routed to, which decides the expert bytes a step
     needs, and the (row tile, expert) pairs the experts' kernel visited,
@@ -189,18 +234,8 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     if live.ndim == 1:
         live = jnp.broadcast_to(live[None, :], x.shape[:2])
 
-    # The cache rides the scan CARRY with per-layer in-place writes of
-    # only the new token slots (as xs/ys the scan stacks fresh ys buffers
-    # and every step rewrites the whole cache). A carried buffer gets ONE
-    # layout for the whole loop, so the cache's `write` and `layer_view`
-    # must agree on it or the compiler copies the whole cache around one
-    # of them in every layer: the paged pool is [Hkv, L, blocks, block, D],
-    # scattered and gathered under a vmap over its heads, for that reason
-    # (the compiled serve programs carry both pools as
-    # {4,3,2,1,0:T(8,128)(2,1)} and hold no pool-sized copy;
-    # tests/test_chip_compile.py).
-    def layer(x, cache, lp, li, kind, ki):
-        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    def gqa(h, cache, lp, li, kind, ki):
+        """q/k/v a head, K and V written and attended per head."""
         b, s, _ = h.shape
         q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
         c_k, s_k = kind_tables(cos, sin, kind)
@@ -214,47 +249,96 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         # is a slice)
         with scope("paged_attention"):
             out = cache.attend(li, q, q_pos, **how)
-        out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
+        return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
+
+    def mla(h, cache, lp, li, kind, ki):
+        """Latent attention: `[c | k_r]` written, c normed and k_r
+        rotated, and attended absorbed or expanded (ops/mla.py)."""
+        b, s, _ = h.shape
+        q_n, q_r, c, k_r = mla_project(h, lp, cfg)
+        q_r = _rope(q_r, cos, sin, q_pos)
+        k_r = _rope(k_r[:, :, None, :], cos, sin, q_pos)[:, :, 0]
+        cache = cache.write(li, jnp.concatenate([c, k_r], axis=-1), q_pos)
+        with scope("paged_attention"):
+            out = cache.attend(li, q_n, q_r, q_pos, lp["kv_b"], cfg)
+        with scope("mla_o"):
+            return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
+
+    # The cache rides the scan CARRY with per-layer in-place writes of
+    # only the new token slots (as xs/ys the scan stacks fresh ys buffers
+    # and every step rewrites the whole cache). A carried buffer gets ONE
+    # layout for the whole loop, so the cache's `write` and `layer_view`
+    # must agree on it or the compiler copies the whole cache around one
+    # of them in every layer: the paged pool is [Hkv, L, blocks, block, D],
+    # scattered and gathered under a vmap over its heads, for that reason
+    # (the compiled serve programs carry both pools as
+    # {4,3,2,1,0:T(8,128)(2,1)} and hold no pool-sized copy;
+    # tests/test_chip_compile.py).
+    def layer(x, cache, lp, banks, block, li, bank_li, kind, ki):
+        """One block, as `models.llama.decoder_layer` describes it
+        (`block`), against the cache. `li`: the layer's index in the model
+        (and in the cache); `bank_li`: in its stack's expert banks."""
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        out, cache = (mla if block.attn == "mla" else gqa)(
+            h, cache, lp, li, kind, ki)
+        if block.sandwich:
+            out = rms_norm(out, lp["attn_out_norm"], cfg.rms_norm_eps)
         x = x + out
-        if cfg.num_experts:
-            mlp_out, touched = _moe_served_block(x, lp, banks, li, cfg, live)
+        if block.mlp == "experts":
+            mlp_out, touched = _moe_served_block(x, lp, banks, bank_li, cfg,
+                                                 live)
         else:
             mlp_out, touched = _mlp_block(x, lp, cfg, DEFAULT_CTX), None
+        if block.sandwich:
+            mlp_out = rms_norm(mlp_out, lp["mlp_out_norm"], cfg.rms_norm_eps)
         return x + mlp_out, cache, touched
 
     # one scan iteration runs one whole period of the layer pattern
     # (models/llama.py run_layers): a layer's kind is static in the body
     n_before = [period[:j].count(kind) for j, kind in enumerate(period)]
 
-    def body(carry, inputs):
-        x, cache, touched = carry
-        lp, p = inputs
-        if len(period) == 1:
-            x, cache, t = layer(x, cache, lp, p, period[0], p)
-            return (x, cache, touched if t is None else touched + t), None
-        for j, kind in enumerate(period):
-            x, cache, t = layer(
-                x, cache, jax.tree.map(lambda w: w[j], lp),
-                p * len(period) + j, kind,
-                p * period.count(kind) + n_before[j])
-            touched = touched if t is None else touched + t
-        return (x, cache, touched), None
+    def run_stack(x, cache, touched, stack, block, first: int):
+        """Scan one stack of the layer tree (`cfg.stacks`), whose first
+        layer is the model's layer `first`."""
 
-    # the expert banks stay whole, outside the scanned inputs: a layer's
-    # grouped kernel addresses its experts inside the stack
-    # (ops/grouped_experts.py), so no bank is sliced out a layer
-    layers = {n: w for n, w in params["layers"].items() if n not in BANKS}
-    banks = {n: params["layers"].get(n) for n in BANKS}
-    if len(period) > 1:
-        layers = by_period(layers, len(period))
+        def body(carry, inputs):
+            x, cache, touched = carry
+            lp, p = inputs
+            if len(period) == 1:
+                x, cache, t = layer(x, cache, lp, banks, block, first + p, p,
+                                    period[0], first + p)
+                return (x, cache, touched if t is None else touched + t), None
+            for j, kind in enumerate(period):
+                x, cache, t = layer(
+                    x, cache, jax.tree.map(lambda w: w[j], lp), banks, block,
+                    p * len(period) + j, p * len(period) + j, kind,
+                    p * period.count(kind) + n_before[j])
+                touched = touched if t is None else touched + t
+            return (x, cache, touched), None
+
+        # the expert banks stay whole, outside the scanned inputs: a
+        # layer's grouped kernel addresses its experts inside the stack
+        # (ops/grouped_experts.py), so no bank is sliced out a layer
+        layers = {n: w for n, w in stack.items() if n not in BANKS}
+        banks = {n: stack.get(n) for n in BANKS}
+        n_layers = jax.tree.leaves(layers)[0].shape[0]
+        if len(period) > 1:
+            layers = by_period(layers, len(period))
+        (x, cache, touched), _ = lax.scan(
+            body, (x, cache, touched),
+            (layers, jnp.arange(n_layers // len(period))))
+        return x, cache, touched
+
     # a dense model carries no counter: its programs are what they were
-    touched0 = jnp.zeros((2,), jnp.int32) if cfg.num_experts else None
-    (x, cache, touched), _ = lax.scan(
-        body, (x, cache, touched0),
-        (layers, jnp.arange(cache.num_layers // len(period))))
+    touched = jnp.zeros((4,), jnp.int32) if cfg.num_experts else None
+    first = 0
+    for name, n_layers, block in cfg.stacks:
+        x, cache, touched = run_stack(x, cache, touched, params[name], block,
+                                      first)
+        first += n_layers
     if with_touched:
         return x, cache, (touched if touched is not None
-                          else jnp.zeros((2,), jnp.int32))
+                          else jnp.zeros((4,), jnp.int32))
     return x, cache
 
 
@@ -263,17 +347,22 @@ BANKS = ("w_gate", "w_up", "w_down")  # the experts' stacks [L, E, ...]
 
 @scope("mlp")
 def _moe_served_block(x, lp, banks, li, cfg: ModelConfig, live):
-    """RMSNorm -> routed experts, dropless (every expert is on this
-    device: the decode paths run at ep = 1), as `models.llama._moe_block`
-    computes them; rows without a token (`live` false: idle slots,
-    chunk padding) are routed nowhere. `banks`: the model's whole expert
-    stacks, of which this is layer `li`. Returns (out, [experts touched,
-    (row tile, expert) pairs visited])."""
+    """RMSNorm -> routed experts, dropless, beside the shared expert
+    where the model has one, as `models.llama._moe_block` computes them;
+    rows without a token (`live` false: idle slots, chunk padding) are
+    routed nowhere. `banks`: the stack's whole banks of the experts held
+    on this device, of which this is layer `li`. Returns (out, [experts
+    touched, (row tile, expert) pairs visited, picks here, picks])."""
     h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
-    return moe_mlp_served(
+    out, counts = moe_mlp_served(
         h, lp["router"], *(banks[n] for n in BANKS),
         top_k=cfg.num_experts_per_token, act=mlp_act(cfg),
-        norm_topk_prob=cfg.norm_topk_prob, live=live, layer=li)
+        norm_topk_prob=cfg.norm_topk_prob, live=live, layer=li,
+        scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
+        expert_first=cfg.expert_first)
+    if "shared_gate" in lp:
+        out = out + shared_expert(h, lp, cfg)
+    return out, counts
 
 
 def _logits_last(params, x, cfg: ModelConfig):

@@ -37,9 +37,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from picotron_tpu.config import ModelConfig
+from picotron_tpu.config import Block, ModelConfig
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
+from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.rope import apply_rope, rope_tables
 from picotron_tpu.telemetry.scopes import scope
@@ -56,7 +57,9 @@ def model_rope_tables(cfg, max_len=None):
     layers unscaled). `kind_tables` picks a layer's pair from either."""
     n = max_len or cfg.max_position_embeddings
     if not cfg.rope_parameters:
-        return rope_tables(n, cfg.head_dim, cfg.rope_theta,
+        # rope_dim: the whole head, or latent attention's shared rotated
+        # dimensions
+        return rope_tables(n, cfg.rope_dim, cfg.rope_theta,
                            rope_scaling=cfg.rope_scaling_dict)
     pairs = {}
     for kind in sorted(set(cfg.layer_kinds)):
@@ -188,16 +191,13 @@ def _uniform_fan_in(key, fan_in: int, shape) -> jnp.ndarray:
     return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Full (unsharded) parameter pytree, fp32.
-
-    Layer weights are stacked on a leading layer axis. Matmul weights are
-    stored [in_features, out_features] (x @ w convention).
-    """
+def _init_stack(cfg: ModelConfig, block: Block, nl: int,
+                key: jax.Array) -> Params:
+    """One stack of `nl` layers of one kind of block (`cfg.stacks`), fp32,
+    stacked on a leading layer axis. The `layers` stack of a model of one
+    kind draws what it always drew from `key`."""
     h = cfg.hidden_size
     i = cfg.intermediate_size
-    v = cfg.vocab_size
-    nl = cfg.num_hidden_layers
     d = cfg.head_dim
     q_out = cfg.num_attention_heads * d
     kv_out = cfg.num_key_value_heads * d
@@ -210,12 +210,39 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     layers = {
         "input_norm": jnp.ones((nl, h), jnp.float32),
-        "q": stacked(keys[1], h, (h, q_out)),
-        "k": stacked(keys[2], h, (h, kv_out)),
-        "v": stacked(keys[3], h, (h, kv_out)),
-        "o": stacked(keys[4], q_out, (q_out, h)),
         "post_norm": jnp.ones((nl, h), jnp.float32),
     }
+    if block.sandwich:
+        # norms on the attention's and the MLP's outputs (`post_norm` is
+        # the MLP's input norm, as everywhere)
+        layers.update({
+            "attn_out_norm": jnp.ones((nl, h), jnp.float32),
+            "mlp_out_norm": jnp.ones((nl, h), jnp.float32),
+        })
+    if block.attn == "mla":
+        heads, rank, ql = (cfg.num_attention_heads, cfg.kv_lora_rank,
+                           cfg.q_lora_rank)
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        mk = jax.random.split(keys[1], 4)
+        layers.update({
+            "q_a": stacked(mk[0], h, (h, ql)),
+            "q_a_norm": jnp.ones((nl, ql), jnp.float32),
+            "q_b": stacked(mk[1], ql, (ql, heads * (dn + dr))),
+            # [c | k_r]: the latent and the shared rotated dimensions
+            "kv_a": stacked(mk[2], h, (h, rank + dr)),
+            "kv_a_norm": jnp.ones((nl, rank), jnp.float32),
+            # a head's [k_n | v] columns side by side (ops/mla.py)
+            "kv_b": stacked(mk[3], rank, (rank, heads * (dn + dv))),
+            "o": stacked(keys[4], heads * dv, (heads * dv, h)),
+        })
+    else:
+        layers.update({
+            "q": stacked(keys[1], h, (h, q_out)),
+            "k": stacked(keys[2], h, (h, kv_out)),
+            "v": stacked(keys[3], h, (h, kv_out)),
+            "o": stacked(keys[4], q_out, (q_out, h)),
+        })
     if cfg.attention_bias:
         # Qwen2-style qkv bias (zero-init, the HF convention)
         layers.update({
@@ -229,25 +256,53 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "q_norm": jnp.ones((nl, q_out), jnp.float32),
             "k_norm": jnp.ones((nl, kv_out), jnp.float32),
         })
-    if cfg.num_experts:
+    if block.mlp == "experts":
         e, f = cfg.num_experts, cfg.expert_ffn_size
         layers.update({
-            # router + per-layer expert banks [L, E, ...] (ops/moe.py)
-            "router": stacked(keys[9], h, (h, e)),
+            # router (over every expert of the model, held here or not) +
+            # per-layer banks of the experts held [L, E, ...] (ops/moe.py)
+            "router": stacked(keys[9], h, (h, cfg.router_width)),
             "w_gate": stacked(keys[5], h, (e, h, f)),
             "w_up": stacked(keys[6], h, (e, h, f)),
             "w_down": stacked(keys[7], f, (e, f, h)),
         })
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            layers.update({
+                "shared_gate": stacked(keys[10], h, (h, fs)),
+                "shared_up": stacked(keys[11], h, (h, fs)),
+                "shared_down": stacked(keys[12], fs, (fs, h)),
+            })
     else:
         layers.update({
             "gate": stacked(keys[5], h, (h, i)),
             "up": stacked(keys[6], h, (h, i)),
             "down": stacked(keys[7], i, (i, h)),
         })
+    return layers
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Full (unsharded) parameter pytree, fp32.
+
+    Layer weights are stacked on a leading layer axis, one stack a kind of
+    block (`cfg.stacks`: `layers`, and `dense_layers` before it in a model
+    with leading dense layers). Matmul weights are stored [in_features,
+    out_features] (x @ w convention).
+    """
+    h = cfg.hidden_size
+    v = cfg.vocab_size
+
+    keys = jax.random.split(key, 14)
+    # the last stack (`layers`) draws from `key` itself, as the one stack of
+    # a model of one kind of block always did; a stack before it from a fold
+    stacks = {name: _init_stack(cfg, block, nl,
+                                jax.random.fold_in(key, j) if j else key)
+              for j, (name, nl, block) in enumerate(reversed(cfg.stacks))}
 
     params = {
         "embedding": jax.random.normal(keys[0], (v, h), jnp.float32),
-        "layers": layers,
+        **stacks,
         "final_norm": jnp.ones((h,), jnp.float32),
     }
     if not cfg.tie_word_embeddings:
@@ -415,6 +470,33 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     return ctx.g(out)  # row-parallel exit: psum-over-tp fwd / identity bwd
 
 
+@scope("attention")
+def _mla_attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
+    """RMSNorm -> latent attention (ops/mla.py), expanded: every head's
+    keys and values built from the latents, plain causal attention over
+    heads of nope + rope wide keys and v wide values -> out_proj. The
+    one attention implementation of a model with a latent cache on the
+    training-shaped paths (Config.validate refuses the kernels and the cp
+    schedules for it: one head width for q, k and v there)."""
+    dt = x.dtype
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    b, s, _ = h.shape
+    q_n, q_r, c, k_r = mla_project(h, lp, cfg)
+    pos = ctx.positions
+    q_r = apply_rope(q_r, cos, sin, pos)
+    k_r = apply_rope(k_r[:, :, None, :], cos, sin, pos)      # one for all heads
+    w_uk, w_uv = up_weights(lp["kv_b"], cfg, dt)
+    k_n = jnp.einsum("bsr,rhd->bshd", c, w_uk)
+    v = jnp.einsum("bsr,rhd->bshd", c, w_uv)
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, q_r.shape)], axis=-1)
+    out = sdpa_attention(q, k, v, causal=True, q_positions=pos,
+                         kv_positions=pos)                   # [B, S, heads, v]
+    with scope("mla_o"):
+        out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
+    return checkpoint_name(out, "attn_proj_out")
+
+
 def mlp_act(cfg: ModelConfig):
     """Gated-MLP activation on the gate branch: SwiGLU (silu, the Llama
     lineage, ref: model.py:184-186), exact-erf GeGLU ("gelu" — what
@@ -444,12 +526,23 @@ def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
     return ctx.g(out)
 
 
+def shared_expert(h, lp, cfg: ModelConfig):
+    """The shared experts' gated MLP over the normed block input h: every
+    token passes through it with gate 1. One implementation for the
+    training block and the cached decode paths."""
+    dt = h.dtype
+    with scope("moe_shared"):
+        gate = h @ lp["shared_gate"].astype(dt)
+        up = h @ lp["shared_up"].astype(dt)
+        return (mlp_act(cfg)(gate) * up) @ lp["shared_down"].astype(dt)
+
+
 @scope("mlp")
 def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     """RMSNorm -> top-k routed expert SwiGLU bank (beyond the reference;
-    ops/moe.py). Returns (out, aux [3]): the pre-weighted router loss,
-    the capacity drop fraction and the busiest expert's load over the
-    mean."""
+    ops/moe.py), beside the shared expert where the model has one. Returns
+    (out, aux [3]): the pre-weighted router loss, the capacity drop
+    fraction and the busiest expert's load over the mean."""
     from picotron_tpu.ops.moe import moe_mlp
 
     h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
@@ -469,7 +562,11 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
         router_z_coef=cfg.router_z_coef,
         stat_axes=ctx.moe_stat_axes,
         norm_topk_prob=cfg.norm_topk_prob,
+        scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
+        expert_first=cfg.expert_first,
     )
+    if "shared_gate" in lp:
+        out = out + shared_expert(h, lp, cfg)
     # Zero-padded PP layer slots (pad_layers_for_pp) must not contribute
     # router statistics: their all-zero router yields uniform logits whose
     # z-loss (log(E)^2 per token) and tie-broken top-k capacity overflow
@@ -481,18 +578,30 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
 
 
 def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
-                  is_real=1.0, kind: str = "full_attention"):
+                  is_real=1.0, kind: str = "full_attention",
+                  block: Optional[Block] = None):
     """Returns (x, aux [3]) — aux[0] is the pre-weighted router loss
     (balance + z, 0 for dense models), aux[1] the capacity drop fraction
     and aux[2] the busiest expert's load over the mean (observability;
     stop_gradient-free but weightless in the loss).
     `is_real` masks the aux of zero-padded PP layer slots (see
-    ParallelCtx.layer_is_real)."""
-    x = x + _attention_block(x, lp, cfg, ctx, cos, sin, kind)
-    if cfg.num_experts:
+    ParallelCtx.layer_is_real). `block`: what the layer is made of
+    (`cfg.stacks`); None = the last stack's, which is every layer's in a
+    model of one kind of block."""
+    block = block or cfg.stacks[-1][2]
+    if block.attn == "mla":
+        attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
+    else:
+        attn_out = _attention_block(x, lp, cfg, ctx, cos, sin, kind)
+    if block.sandwich:
+        attn_out = rms_norm(attn_out, lp["attn_out_norm"], cfg.rms_norm_eps)
+    x = x + attn_out
+    if block.mlp == "experts":
         mlp_out, aux = _moe_block(x, lp, cfg, ctx, is_real)
     else:
         mlp_out, aux = _mlp_block(x, lp, cfg, ctx), jnp.zeros(3, jnp.float32)
+    if block.sandwich:
+        mlp_out = rms_norm(mlp_out, lp["mlp_out_norm"], cfg.rms_norm_eps)
     return x + mlp_out, aux
 
 
@@ -561,9 +670,11 @@ def remat_policy_for(name: str):
 def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
                ctx: ParallelCtx = DEFAULT_CTX,
                cos: jnp.ndarray | None = None,
-               sin: jnp.ndarray | None = None):
+               sin: jnp.ndarray | None = None,
+               block: Optional[Block] = None):
     """Scan a stacked layer pytree over x. Works on any contiguous stage
-    slice, which is exactly what pipeline parallelism feeds it.
+    slice, which is exactly what pipeline parallelism feeds it. `block`:
+    what the stack's layers are made of (see `decoder_layer`).
 
     Returns (x, aux [3]) — aux[0] the summed pre-weighted MoE router loss
     over the scanned layers, aux[1] the summed capacity drop fraction,
@@ -578,11 +689,12 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     def body(h, xs):
         lp, real = xs
         if len(period) == 1:
-            return decoder_layer(h, lp, cfg, ctx, cos, sin, real, period[0])
+            return decoder_layer(h, lp, cfg, ctx, cos, sin, real, period[0],
+                                 block)
         aux = jnp.zeros(3, jnp.float32)
         for j, kind in enumerate(period):
             h, a = decoder_layer(h, jax.tree.map(lambda w: w[j], lp), cfg,
-                                 ctx, cos, sin, real[j], kind)
+                                 ctx, cos, sin, real[j], kind, block)
             aux = aux + a
         # aux rides the scan's stacked outputs (not the carry: its varying
         # mesh axes differ from x's, which would unstabilize the carry type)
@@ -598,6 +710,19 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
         body = jax.checkpoint(body, policy=remat_policy_for(ctx.remat_policy))
     x, aux_per_layer = jax.lax.scan(body, x, xs)  # [L / period, 3]
     return x, jnp.sum(aux_per_layer, axis=0)
+
+
+def run_stacks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
+               ctx: ParallelCtx = DEFAULT_CTX, cos=None, sin=None):
+    """Every stack of the layer tree in order (`cfg.stacks`), each one
+    scan: the leading dense layers, then the expert layers; the one
+    `layers` stack of a model of one kind of block. Returns (x, aux [3])
+    as `run_layers`."""
+    aux = jnp.zeros(3, jnp.float32)
+    for name, _, block in cfg.stacks:
+        x, a = run_layers(params[name], x, cfg, ctx, cos, sin, block)
+        aux = aux + a
+    return x, aux
 
 
 def final_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -623,7 +748,7 @@ def forward(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
     """input_ids [B, S] -> logits [B, S, V] (full vocab; eval/debug path)."""
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
-    x, _ = run_layers(params["layers"], x, cfg, ctx, cos, sin)
+    x, _ = run_stacks(params, x, cfg, ctx, cos, sin)
     x = final_hidden(params, x, cfg)
     return logits_from_hidden(params, x, cfg, ctx)
 
@@ -649,7 +774,7 @@ def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
     """
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
-    x, aux = run_layers(params["layers"], x, cfg, ctx, cos, sin)
+    x, aux = run_stacks(params, x, cfg, ctx, cos, sin)
     with scope("head_ce"):
         x = final_hidden(params, x, cfg)
         if ctx.head_ce is not None:

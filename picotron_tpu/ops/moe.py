@@ -9,11 +9,19 @@ Beyond the reference (SURVEY §2.2 marks EP/MoE absent) — designed TPU-first:
   expert path (their residual stream passes through unchanged — top-k
   combine just contributes 0), underflow slots compute on zeros. XLA sees
   one fixed [E, C, H] einsum program, no data-dependent shapes.
-- **Routing**: softmax over all E router logits, the k largest chosen.
-  `norm_topk_prob` (the published key) renormalizes the k gates to sum to 1
-  per token (Mixtral's rule, the default); false keeps the raw
-  probabilities (OLMoE). The load-balancing aux loss is the standard
-  Switch/Mixtral `E * sum_e(frac_tokens_e * mean_router_prob_e)`.
+- **Routing**: scores over all router logits, softmax over them or the
+  sigmoid of each (`scoring`; the DeepSeek-V3 lineage), the k largest
+  chosen. `norm_topk_prob` (the published key) renormalizes the k gates to
+  sum to 1 per token (Mixtral's rule, the default); false keeps the raw
+  scores (OLMoE); `scale` (`routed_scaling_factor`) multiplies them. The
+  load-balancing aux loss is the standard Switch/Mixtral
+  `E * sum_e(frac_tokens_e * mean_router_prob_e)`.
+- **A held share** (`held`: first index, count): the router scores every
+  expert of the model, the banks hold `count` of them from `first` on (one
+  chip of an expert-parallel group, served without its exchange). A pick
+  that lands elsewhere keeps its gate's place in the renormalisation and
+  adds nothing here: it joins the dead assignments' group behind the last
+  held expert's, so no row is multiplied and no weight read on its account.
 - **Dropless dispatch** (`capacity_factor=None`; what the model layer asks
   for whenever ep = 1): no capacity. Each
   assignment's slot within its expert plus the exclusive prefix of the
@@ -56,6 +64,12 @@ from picotron_tpu.ops.grouped_experts import (
 from picotron_tpu.telemetry.scopes import scope
 
 
+# The most bytes of one expert-sorted buffer of the served experts (rows x
+# hidden): above it `moe_mlp_served` takes the tokens in blocks. Mellum2's
+# largest prefill batch (8,192 tokens, 0.38 GB) stays one block.
+MAX_SORTED_BYTES = 640 * 2**20
+
+
 class Routing(NamedTuple):
     """Per-token routing decisions (all leading dim N = flattened tokens)."""
 
@@ -69,23 +83,38 @@ class Routing(NamedTuple):
     #                           dropless dispatch's group sizes)
 
 
-def topk_gates(logits, k: int, norm_topk_prob: bool):
-    """(probs [N, E], chosen experts [N, k], gates [N, k]) of float32
-    router logits: softmax over all E, the k largest. Mixtral renormalizes
-    the k selected probabilities to sum to 1; OLMoE (norm_topk_prob false)
-    combines with the raw probabilities."""
-    probs = jax.nn.softmax(logits, axis=-1)
+def topk_gates(logits, k: int, norm_topk_prob: bool,
+               scoring: str = "softmax", scale: float = 1.0):
+    """(scores [N, E], chosen experts [N, k], gates [N, k]) of float32
+    router logits: softmax over all E or the sigmoid of each (`scoring`),
+    the k largest. Mixtral renormalizes the k selected scores to sum to 1;
+    OLMoE (norm_topk_prob false) combines with the raw ones; the sigmoid
+    law (DeepSeek-V3, Pangu Ultra MoE) renormalizes with 1e-20 under the
+    sum and multiplies by `scale` (routed_scaling_factor)."""
+    sigmoid = scoring == "sigmoid"
+    probs = (jax.nn.sigmoid(logits) if sigmoid
+             else jax.nn.softmax(logits, axis=-1))
     top_p, top_i = lax.top_k(probs, k)
-    gate = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            if norm_topk_prob else top_p)
-    return probs, top_i, gate
+    gate = top_p
+    if norm_topk_prob:
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        gate = top_p / (total + 1e-20 if sigmoid else total)
+    return probs, top_i, (gate * scale if scale != 1.0 else gate)
 
 
 def route_topk(logits: jnp.ndarray, k: int,
                stat_axes: Optional[tuple] = None,
                norm_topk_prob: bool = True,
-               live: Optional[jnp.ndarray] = None) -> Routing:
+               live: Optional[jnp.ndarray] = None,
+               scoring: str = "softmax", scale: float = 1.0,
+               held: Optional[tuple] = None) -> Routing:
     """Top-k routing with slots assigned in token order.
+
+    `scoring`, `scale`: the gates' law (`topk_gates`). `held` (first,
+    count): the banks hold experts first .. first + count - 1 of the
+    router's E. `expert_idx` is then an index into the banks, a pick that
+    lands on an expert held elsewhere is a dead assignment as a dead row's
+    are (below), and `counts` has count + 1 entries.
 
     `live` [N] bool (the serving programs: rows that carry a token): a row
     that is not live is assigned to no expert. Its k assignments go to a
@@ -110,16 +139,24 @@ def route_topk(logits: jnp.ndarray, k: int,
     """
     n, e = logits.shape
     logits = logits.astype(jnp.float32)
-    probs, top_i, gate = topk_gates(logits, k, norm_topk_prob)
+    probs, top_i, gate = topk_gates(logits, k, norm_topk_prob, scoring, scale)
+    picked = top_i  # in the router's numbering, for the balance statistic
 
     # slot_in_expert: for assignment (token t, choice j) -> how many earlier
     # assignments went to the same expert. Flatten [N, k] in token-major
     # order, one-hot over E, exclusive cumsum down the assignment axis.
-    flat_e = top_i.reshape(-1)                                    # [N*k]
     groups = e
+    if held is not None and held != (0, e):
+        first, groups = held
+        here = (top_i >= first) & (top_i < first + groups)
+        top_i = jnp.where(here, top_i - first, groups)
+        live = here if live is None else here & live[:, None]
+    elif live is not None:
+        live = live[:, None]
+    flat_e = top_i.reshape(-1)                                    # [N*k]
     if live is not None:
-        top_i = jnp.where(live[:, None], top_i, e)
-        flat_e, groups = top_i.reshape(-1), e + 1
+        top_i = jnp.where(live, top_i, groups)
+        flat_e, groups = top_i.reshape(-1), groups + 1
     onehot = jax.nn.one_hot(flat_e, groups, dtype=jnp.int32)      # [N*k, E]
     prior = jnp.cumsum(onehot, axis=0) - onehot                   # exclusive
     slot = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]
@@ -132,7 +169,7 @@ def route_topk(logits: jnp.ndarray, k: int,
     # f_e = fraction of assignments routed to e, P_e = mean router prob.
     # Equal-sized token shards make pmean-of-means the exact global mean.
     f = stat_mean(
-        jnp.mean(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=(0, 1)))
+        jnp.mean(jax.nn.one_hot(picked, e, dtype=jnp.float32), axis=(0, 1)))
     # (with `live`, f and the z-loss count dead rows too: they are training
     # statistics, and the serving programs that pass `live` drop them)
     p = stat_mean(jnp.mean(probs, axis=0))
@@ -239,8 +276,9 @@ def _grouped_experts(flat, r: Routing, live, w_gate, w_up, w_down, act,
                      layer):
     """`_dropless_experts` for the decode paths, through the grouped
     kernel (`ops/grouped_experts.py`): flat [N, H] -> ([N, H], the (row
-    tile, expert) pairs the kernel visits). `r` routes the `live` [N] rows
-    alone (`route_topk(live=...)`); the banks are the model's whole stacks
+    tile, expert) pairs the kernel visits). `r` routes the live rows' picks
+    of held experts alone (`route_topk(live=..., held=...)`: every other
+    assignment has expert index E); the banks are the stack's whole banks
     [L, E, H, F] / [L, E, F, H], of which `layer` (traced or not) is this
     layer: the kernel addresses its experts inside them, so no layer's bank
     is sliced out or copied, and an expert without rows is never read.
@@ -248,25 +286,26 @@ def _grouped_experts(flat, r: Routing, live, w_gate, w_up, w_down, act,
     The live assignments are permuted into expert order as above, except
     that an expert's rows start on a row tile (the tile is chosen from the
     static number of rows and experts, `row_tile`): a tile then belongs to
-    one expert. A row without a token has no place in the buffer and comes
-    out as zeros, selected, not multiplied: what the buffer holds outside
-    the live assignments' rows is never written and never read."""
+    one expert. A dead assignment has no place in the buffer and adds
+    zeros, selected, not multiplied: what the buffer holds outside the
+    live assignments' rows is never written and never read."""
     n, h = flat.shape
     k = r.expert_idx.shape[1]
     e = w_gate.shape[1]
     dt = flat.dtype
+    live = live[:, None] & (r.expert_idx < e)                      # [N, k]
     tm = row_tile(n * k, e)
     n_tiles = max_tiles(n * k, e, tm)
     with scope("moe_router"):
         first_row, tile_expert, visits = group_tiles(r.counts[:e], tm, n_tiles)
         # a dead assignment (expert index E) reads row 0 and is selected
         # away below; in the inverse it lands beyond the buffer and is dropped
-        row = jnp.where(live[:, None],
+        row = jnp.where(live,
                         first_row[jnp.minimum(r.expert_idx, e - 1)] + r.slot,
                         0)                                         # [N, k]
         at = jnp.arange(n * k, dtype=jnp.int32)
         inv = jnp.zeros((n_tiles * tm,), jnp.int32).at[
-            jnp.where(jnp.repeat(live, k), row.reshape(-1), n_tiles * tm + at)
+            jnp.where(live.reshape(-1), row.reshape(-1), n_tiles * tm + at)
         ].set(at, mode="drop", unique_indices=True)
     with scope("moe_dispatch"):
         xs = flat[inv // k]                                        # [T*tm, H]
@@ -275,7 +314,7 @@ def _grouped_experts(flat, r: Routing, live, w_gate, w_up, w_down, act,
                             layer, tm=tm, act=act)
     with scope("moe_dispatch"):
         picked = ys[row].astype(jnp.float32) * r.gate[..., None]  # [N, k, H]
-        out = jnp.sum(jnp.where(live[:, None, None], picked, 0.0), axis=1)
+        out = jnp.sum(jnp.where(live[..., None], picked, 0.0), axis=1)
     return out.astype(dt), visits
 
 
@@ -291,49 +330,81 @@ def _split_over_a_mesh(w) -> bool:
 # program traces and lowers the block (and its Mosaic kernel) once, not once
 # a layer: a second of every start of an engine with five programs
 @functools.partial(jax.jit,
-                   static_argnames=("top_k", "act", "norm_topk_prob"))
+                   static_argnames=("top_k", "act", "norm_topk_prob",
+                                    "scoring", "scale", "expert_first"))
 def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
-                   act, norm_topk_prob: bool, live, layer):
-    """The expert block of the decode paths (`generate`, the serve
-    programs): `moe_mlp`'s router and dropless mathematics, every expert
-    on this device, no loss terms, and `live` [B, S] saying which rows
-    carry a token (idle slots and chunk padding do not: they are routed
-    nowhere and come out as zeros). The banks are the model's whole stacks
-    [L, E, ...] and `layer` this layer's index in them. A token's experts
-    depend on that token alone, so chunking a prompt differently changes
-    nothing.
+                   act, norm_topk_prob: bool, live, layer,
+                   scoring: str = "softmax", scale: float = 1.0,
+                   expert_first: int = 0):
+    """The routed experts of the decode paths (`generate`, the serve
+    programs): `moe_mlp`'s router and dropless mathematics, no loss terms,
+    and `live` [B, S] saying which rows carry a token (idle slots and chunk
+    padding do not: they are routed nowhere and come out as zeros). The
+    router scores every expert of the model (`router_w` [H, R]); the banks
+    are the stack's whole banks [L, E, ...] of the E experts held on this
+    device, `expert_first` .. `expert_first + E - 1` of the R (all of them
+    where E = R), and `layer` this layer's index in them. A pick that lands
+    on an expert held elsewhere adds nothing here (a token whose picks are
+    all elsewhere comes out as zeros, and its block output is the shared
+    expert's alone), and nothing stands in for the absent devices. A
+    token's experts depend on that token alone, so chunking a prompt
+    differently changes nothing.
 
-    One form at every number of rows: the live rows' assignments permuted
+    One form at every number of rows (a batch whose expert-sorted buffer
+    would pass MAX_SORTED_BYTES goes through in equal blocks of tokens):
+    the live rows' assignments permuted
     into expert order and put through the grouped kernel
     (`_grouped_experts`), which visits the (row tile, expert) pairs that
     hold rows and reads no other expert. Only banks that a mesh splits
     (tp > 1) keep the compiler's grouped matmul, on this layer's slice of
     the stacks.
 
-    Returns (out [B, S, H], counts [2] int32): the experts at least one
-    live row was routed to, which is what a step NEEDS of the expert
-    banks, and the (row tile, expert) pairs the kernel visited, which is
-    what it read of them: an expert whose rows span two tiles is two
-    visits."""
+    Returns (out [B, S, H], counts [4] int32): the held experts at least
+    one live row was routed to, which is what a step NEEDS of the expert
+    banks; the (row tile, expert) pairs the kernel visited, which is what
+    it read of them (an expert whose rows span two tiles is two visits);
+    the live rows' picks that landed on held experts; and all their picks
+    (rows x k)."""
     b, s, h = x.shape
-    n, e = b * s, router_w.shape[1]
-    flat = x.reshape(n, h)
-    live = live.reshape(-1)
-    with scope("moe_router"):
-        logits = (flat.astype(jnp.float32)
-                  @ router_w.astype(jnp.float32))                 # [N, E] fp32
-        r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
-                       live=live)
-        touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
-    if _split_over_a_mesh(w_gate):
-        out = _dropless_experts(
-            flat, r, *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-                       for w in (w_gate, w_up, w_down)), act)
-        visits = touched  # the compiler's kernel walks a group once
-    else:
-        out, visits = _grouped_experts(flat, r, live, w_gate, w_up, w_down,
-                                       act, layer)
-    return out.reshape(b, s, h), jnp.stack([touched, visits])
+    n, e = b * s, w_gate.shape[1]
+
+    def block(args):
+        flat, live = args
+        with scope("moe_router"):
+            logits = (flat.astype(jnp.float32)
+                      @ router_w.astype(jnp.float32))             # [N, R] fp32
+            r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
+                           live=live, scoring=scoring, scale=scale,
+                           held=(expert_first, e))
+            touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
+            picks = jnp.stack([jnp.sum(r.counts[:e]),
+                               jnp.sum(live) * top_k]).astype(jnp.int32)
+        if _split_over_a_mesh(w_gate):
+            out = _dropless_experts(
+                flat, r, *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                           for w in (w_gate, w_up, w_down)), act)
+            visits = touched  # the compiler's kernel walks a group once
+        else:
+            out, visits = _grouped_experts(flat, r, live, w_gate, w_up,
+                                           w_down, act, layer)
+        return out, jnp.concatenate([jnp.stack([touched, visits]), picks])
+
+    # a prefill batch whose expert-sorted buffer would pass
+    # MAX_SORTED_BYTES goes through in equal blocks of tokens, one after
+    # the other (16 rows of 1,024 tokens at openPangu-Ultra's 7,680 wide
+    # rows are 2 x 1.9 GB otherwise); the counts are summed over the
+    # blocks, an expert two blocks touch counted twice (only a decode
+    # step's counts are read, and a decode step is one block)
+    blocks = 1
+    while (n * top_k * h * x.dtype.itemsize > blocks * MAX_SORTED_BYTES
+           and n % (2 * blocks) == 0):
+        blocks *= 2
+    # (unrolled, not a `lax.map`: loop-invariant banks that ride a while
+    # loop's operands were copied whole by the compiler, 3 x 1.9 GB)
+    flat, live = x.reshape(blocks, n // blocks, h), live.reshape(blocks, -1)
+    outs = [block((flat[j], live[j])) for j in range(blocks)]
+    return (jnp.concatenate([o for o, _ in outs]).reshape(b, s, h),
+            sum(c for _, c in outs))
 
 
 def moe_mlp(
@@ -352,6 +423,9 @@ def moe_mlp(
     router_z_coef: float = 0.0,
     stat_axes: Optional[tuple] = None,
     norm_topk_prob: bool = True,
+    scoring: str = "softmax",
+    scale: float = 1.0,
+    expert_first: int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """MoE feed-forward. x: [B, S, H]; router_w: [H, E]; expert banks
     [E_local, H, F] / [E_local, F, H] (E_local = E/ep under expert
@@ -371,6 +445,10 @@ def moe_mlp(
     `ep_axis` names the mesh axis for
     the all_to_all pair; None = no expert parallelism (single device, or
     ep = 1). `stat_axes` makes the router statistics global (route_topk).
+    `scoring`, `scale`: the gates' law (`topk_gates`). A router wider than
+    `num_experts` (router_w [H, R], R > E) is a held share: the banks are
+    experts `expert_first` .. `expert_first + E - 1` of the R, dropless
+    dispatch only, and a pick elsewhere adds nothing (`route_topk` held).
 
     Recompute contract: every op here is a deterministic function of
     (x, weights) — fp32 router logits, top_k, the slot cumsum, the
@@ -392,10 +470,13 @@ def moe_mlp(
     with scope("moe_router"):
         logits = (flat.astype(jnp.float32)
                   @ router_w.astype(jnp.float32))                 # [N, E] fp32
+        share = router_w.shape[1] != e
         r = route_topk(logits, top_k, stat_axes=stat_axes,
-                       norm_topk_prob=norm_topk_prob)
+                       norm_topk_prob=norm_topk_prob, scoring=scoring,
+                       scale=scale,
+                       held=(expert_first, e) if share else None)
         aux = router_aux_coef * r.aux_loss + router_z_coef * r.z_loss
-        load = (jnp.max(r.counts).astype(jnp.float32)
+        load = (jnp.max(r.counts[:e]).astype(jnp.float32)
                 * (e / (n * top_k)))
 
     if capacity_factor is None:
@@ -403,9 +484,11 @@ def moe_mlp(
         out = _dropless_experts(flat, r, w_gate, w_up, w_down, act)
         # a grouped matmul leaves the rows past its last group zero, so an
         # assignment is dropped exactly when the group sizes fall short
+        # (`counts` includes a held share's picks elsewhere: not drops)
         drop_frac = ((n * top_k - jnp.sum(r.counts)).astype(jnp.float32)
                      / (n * top_k))
         return out.reshape(b, s, h), aux, drop_frac, load
+    assert not share, "a held share of the experts is dropless (ep = 1)"
 
     # Per-device capacity per expert, padded to a lane-friendly multiple.
     cap = int(capacity_factor * top_k * n / e) + 1

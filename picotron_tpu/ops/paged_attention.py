@@ -77,11 +77,29 @@ def decode_kernel_suits(q, k_pool) -> bool:
     a decode step (one query position a slot), the head a whole number of
     128-lane rows, the block a whole number of sublane tiles of the pool's
     dtype (16 rows of bf16, 8 of float32), and a backend that compiles
-    Pallas kernels. Everything else keeps the gathered view."""
+    Pallas kernels. Everything else keeps the gathered view: prefill
+    chunks and the speculative program (more than one query a slot), a
+    pool that a tp mesh shards (`ShardedPagedKVCache` never asks), the tiny
+    test models' heads and blocks, and every CPU run. The two other cache
+    kinds answer for themselves: a model with sliding layers asks this for
+    each of its two pools and walks tiles where the answer is no
+    (`MixedPagedKVCache`); a latent cache asks `latent_kernel_suits` and
+    walks tiles likewise (`LatentPagedCache`)."""
     block_size, d = k_pool.shape[3], k_pool.shape[4]
     sublanes = 8 * 4 // jnp.dtype(k_pool.dtype).itemsize
     return (q.shape[1] == 1 and d % 128 == 0 and block_size % sublanes == 0
             and compiled_kernels_available())
+
+
+def latent_kernel_suits(s: int, pool, rank: int) -> bool:
+    """`decode_kernel_suits` for a latent pool [L, num_blocks, block_size,
+    W]: a decode step, the latent (the value: the first `rank` numbers of a
+    key) and the padded key both whole 128-lane rows, the block whole
+    sublane tiles, a backend that compiles Pallas kernels."""
+    block_size, w = pool.shape[2], pool.shape[3]
+    sublanes = 8 * 4 // jnp.dtype(pool.dtype).itemsize
+    return (s == 1 and rank % 128 == 0 and w % 128 == 0
+            and block_size % sublanes == 0 and compiled_kernels_available())
 
 
 def _kernel(lengths_ref, tables_ref, li_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -247,3 +265,134 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
       jnp.asarray(li, jnp.int32).reshape(1), q.reshape(b, hkv, g, d),
       k_pool, v_pool)
     return out.reshape(b, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# The latent cache's decode step (MLA absorbed, ops/mla.py): every query head
+# of a slot against ONE row a cached position, the value being the first
+# `rank` numbers of the key.
+# ---------------------------------------------------------------------------
+
+
+def _latent_kernel(lengths_ref, tables_ref, li_ref, q_ref, kv_hbm, o_ref,
+                   kv_buf, sems, m_ref, l_ref, acc_ref, *, sm_scale: float,
+                   pages_per_chunk: int, max_blocks: int, rank: int):
+    b = pl.program_id(0)
+    _, num_blocks, bs, w = kv_hbm.shape
+    chunk = pages_per_chunk * bs
+    li = li_ref[0]
+    length = lengths_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(length, bs), max_blocks)
+    n_chunks = pl.cdiv(n_pages, pages_per_chunk)
+
+    def copy(c, buf, j):
+        # ONE DMA a page: key and value are the same rows
+        page = jnp.minimum(tables_ref[b * max_blocks + c * pages_per_chunk + j],
+                           num_blocks - 1)
+        return pltpu.make_async_copy(kv_hbm.at[li, page], kv_buf.at[buf, j],
+                                     sems.at[buf])
+
+    def each_copy(c, buf, act):
+        n = jnp.minimum(n_pages - c * pages_per_chunk, pages_per_chunk)
+        lax.fori_loop(0, n, lambda j, _: act(copy(c, buf, j)), None)
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        each_copy(0, 0, lambda cp: cp.start())
+
+    def body(c, _):
+        buf = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            each_copy(c + 1, 1 - buf, lambda cp: cp.start())
+
+        each_copy(c, buf, lambda cp: cp.wait())
+        live = length - c * chunk  # positions of this chunk below the length
+        col = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < live
+        row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) < live
+        kv = kv_buf[buf].reshape(chunk, w)
+        s = lax.dot_general(q_ref[...], kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s = jnp.where(col, s * sm_scale, _NEG_INF)            # [heads, chunk]
+        v = kv[:, :rank]
+        v = jnp.where(row, v, jnp.zeros_like(v))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(col, jnp.exp(s - m_new), 0.0)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+
+    lax.fori_loop(0, n_chunks, body, None)
+    l = l_ref[...]
+    # length 0: no chunk ran, acc = 0 and l = 0 -> zeros
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, pool, li, tables, lengths, *, rank: int,
+                            sm_scale: float,
+                            pages_per_chunk: Optional[int] = None,
+                            interpret: Optional[bool] = None):
+    """Absorbed latent attention of one query position a slot over that
+    slot's cached positions, read from the latent pool in place.
+
+    q [B, heads, W]: a head's query carried into the latent space, `[q_n
+    Wuk_h^T | q_r | 0]` (ops/mla.py), as wide as a pool row; pool [L,
+    num_blocks, block_size, W]: `[c | k_r | 0]` a cached position, c after
+    its norm and k_r after its rotation; li: the layer; tables [B,
+    max_blocks] int32, `num_blocks` = unmapped; lengths [B] int32 (0:
+    nothing is read, the row is zeros). Scores `q . row * sm_scale` in
+    float32, softmax statistics in float32, P in the pool's dtype against
+    the row's first `rank` numbers (the value) with float32 accumulation:
+    the mathematics of `ops/mla.py latent_attention`, absorbed. A block is
+    read ONCE, for key and value and for all the heads. Returns P c
+    [B, heads, rank] in q's dtype.
+
+    Grid (slots,), chunks of `pages_per_chunk` pages double-buffered, only
+    the pages below a slot's length fetched, as `paged_decode_attention`.
+    `interpret=None` compiles the kernel on a TPU backend and runs the
+    Pallas interpreter anywhere else; the caller decides whether the shapes
+    suit the compiled kernel (`latent_kernel_suits`)."""
+    if interpret is None:
+        interpret = not compiled_kernels_available()
+    b, heads, w = q.shape
+    _, _, bs, wp = pool.shape
+    max_blocks = tables.shape[1]
+    if wp != w or rank > w:
+        raise ValueError(f"pool {pool.shape} does not match q {q.shape} "
+                         f"and rank {rank}")
+    ppc = min(pages_per_chunk or DEFAULT_PAGES_PER_CHUNK, max_blocks)
+    kernel = functools.partial(_latent_kernel, sm_scale=sm_scale,
+                               pages_per_chunk=ppc, max_blocks=max_blocks,
+                               rank=rank)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # lengths, the tables (flat), the layer
+            grid=(b,),
+            in_specs=[pl.BlockSpec((None, heads, w), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, heads, rank),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppc, bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, rank), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(li, jnp.int32).reshape(1), q, pool)

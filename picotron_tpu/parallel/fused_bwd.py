@@ -95,7 +95,16 @@ def fused_bwd_supported(cfg: Config) -> bool:
     keep the AD engine (their save sets differ from the manual backward's
     recompute plan)."""
     d, t = cfg.distributed, cfg.training
-    return (d.pp_size == 1
+    m = cfg.model
+    # one kind of block in one `layers` stack: latent attention, sandwich
+    # norms, a shared expert, sigmoid routing and leading dense layers keep
+    # the AD engine (Config.validate refuses an explicit 'fused' for them)
+    plain = (len(m.stacks) == 1 and not m.stacks[0][2].sandwich
+             and not m.mla and not m.n_shared_experts
+             and m.moe_scoring == "softmax"
+             and m.routed_scaling_factor == 1.0
+             and m.router_width == m.num_experts)
+    return (d.pp_size == 1 and plain
             and t.remat and t.remat_policy == "dots_attn")
 
 

@@ -35,46 +35,52 @@ def param_specs(cfg: Config) -> dict[str, Any]:
     # Megatron's pairing: column shards for the entry matmul of a class
     # (qkv, gate/up), row shards for its exit (o, down)
     col, row = P(pp, None, "tp"), P(pp, "tp", None)
-    layers = {
-        "input_norm": P(pp, None),
-        "q": col,
-        "k": col,
-        "v": col,
-        "o": row,
-        "post_norm": P(pp, None),
-    }
-    if cfg.model.attention_bias:
-        # qkv biases shard over tp with their output features
-        layers.update({
-            "b_q": P(pp, "tp"),
-            "b_k": P(pp, "tp"),
-            "b_v": P(pp, "tp"),
-        })
-    if cfg.model.qk_norm:
-        # whole-vector q/k norm weights: replicated (tp = 1 is validated)
-        layers.update({
-            "q_norm": P(pp, None),
-            "k_norm": P(pp, None),
-        })
-    if cfg.model.num_experts:
-        # expert banks [L, E, ...]: expert dim over 'ep', ffn dim over 'tp'
-        # (column-parallel gate/up, row-parallel down — same as the dense
-        # MLP); the router is small and replicated.
-        layers.update({
-            "router": P(pp, None, None),
-            "w_gate": P(pp, "ep", None, "tp"),
-            "w_up": P(pp, "ep", None, "tp"),
-            "w_down": P(pp, "ep", "tp", None),
-        })
-    else:
-        layers.update({
-            "gate": col,
-            "up": col,
-            "down": row,
-        })
+    m = cfg.model
+
+    def stack(block) -> dict[str, Any]:
+        """One stack's specs (`ModelConfig.stacks`), by what its block is
+        made of."""
+        layers = {"input_norm": P(pp, None), "o": row,
+                  "post_norm": P(pp, None)}
+        if block.sandwich:
+            layers.update({"attn_out_norm": P(pp, None),
+                           "mlp_out_norm": P(pp, None)})
+        if block.attn == "mla":
+            # latent attention runs on one device (Config.validate refuses
+            # tp / pp > 1): replicated, `o` included
+            layers.update({n: P(None, None, None)
+                           for n in ("q_a", "q_b", "kv_a", "kv_b", "o")})
+            layers.update({"q_a_norm": P(None, None),
+                           "kv_a_norm": P(None, None)})
+        else:
+            layers.update({"q": col, "k": col, "v": col})
+        if m.attention_bias:
+            # qkv biases shard over tp with their output features
+            layers.update({"b_q": P(pp, "tp"), "b_k": P(pp, "tp"),
+                           "b_v": P(pp, "tp")})
+        if m.qk_norm:
+            # whole-vector q/k norm weights: replicated (tp = 1 is validated)
+            layers.update({"q_norm": P(pp, None), "k_norm": P(pp, None)})
+        if block.mlp == "experts":
+            # expert banks [L, E, ...]: expert dim over 'ep', ffn dim over
+            # 'tp' (column-parallel gate/up, row-parallel down — same as the
+            # dense MLP); the router is small and replicated.
+            layers.update({
+                "router": P(pp, None, None),
+                "w_gate": P(pp, "ep", None, "tp"),
+                "w_up": P(pp, "ep", None, "tp"),
+                "w_down": P(pp, "ep", "tp", None),
+            })
+            if m.n_shared_experts:
+                layers.update({"shared_gate": col, "shared_up": col,
+                               "shared_down": row})
+        else:
+            layers.update({"gate": col, "up": col, "down": row})
+        return layers
+
     specs = {
         "embedding": P("tp", None),
-        "layers": layers,
+        **{name: stack(block) for name, _, block in m.stacks},
         "final_norm": P(),
     }
     if not cfg.model.tie_word_embeddings:

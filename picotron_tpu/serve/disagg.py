@@ -120,14 +120,17 @@ class DisaggServeEngine(ServeEngine):
                  engine_id: int = 0):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
-        if model_cfg.num_experts or model_cfg.layer_types is not None:
+        if (model_cfg.num_experts or model_cfg.layer_types is not None
+                or model_cfg.mla):
             raise ValueError(
                 "disaggregated serving does not support MoE models "
-                "(num_experts > 0) or sliding-window layers: the block "
-                "handoff between the two pools has never run an expert "
-                "block or a second kind of cache state, and nothing "
-                "tests it with one. Serve them through ServeEngine.")
+                "(num_experts > 0), sliding-window layers or latent "
+                "attention: the block handoff between the two pools has "
+                "never run an expert block or another kind of cache "
+                "state, and nothing tests it with one. Serve them through "
+                "ServeEngine.")
         self.mixed, self.wpool = False, None  # one pool a side, full layers
+        self.latent = False  # K and V per head (latent attention is refused)
         self.cfg = model_cfg
         self.scfg = scfg
         self.eos_token_id = eos_token_id

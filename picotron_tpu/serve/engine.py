@@ -85,8 +85,9 @@ from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, head_weight, model_rope_tables,
 )
 from picotron_tpu.serve.paged_cache import (
-    BlockPool, MixedPagedKVCache, PagedKVCache, ShardedPagedKVCache,
-    init_mixed_cache, init_paged_cache, ring_blocks_for,
+    BlockPool, LatentPagedCache, MixedPagedKVCache, PagedKVCache,
+    ShardedPagedKVCache, init_latent_cache, init_mixed_cache,
+    init_paged_cache, ring_blocks_for,
 )
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
@@ -137,7 +138,10 @@ def _paged_cache(k, v, tables, pool_sharded: bool):
     shards the pool over the KV heads (tp > 1): attention then keeps the
     gathered view whatever the step, which the compiler partitions, and
     never the in-place kernel, which it does not. Pairs (full, window) of
-    pools and tables are a model with sliding layers'."""
+    pools and tables are a model with sliding layers'; one pool and no
+    `v` is a latent cache (a model with latent attention)."""
+    if v is None:
+        return LatentPagedCache(k, tables)
     if isinstance(k, (tuple, list)):
         return MixedPagedKVCache(k[0], v[0], k[1], v[1], *tables)
     return (ShardedPagedKVCache if pool_sharded else PagedKVCache)(
@@ -148,6 +152,8 @@ def _pools(cache):
     """(k, v) of a cache, in the form `_paged_cache` took them."""
     if isinstance(cache, MixedPagedKVCache):
         return (cache.k, cache.wk), (cache.v, cache.wv)
+    if isinstance(cache, LatentPagedCache):
+        return cache.kv, None
     return cache.k, cache.v
 
 
@@ -179,13 +185,15 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     to keep emitting EOS — identical semantics to generate.py's scan —
     and the host truncates + retires them at dispatch end. Returns
     (tokens [S, interval], their logits [S, interval] float32, next
-    tokens, next positions, next tidx, expert counts [2], k, v); the
+    tokens, next positions, next tidx, expert counts [4], k, v); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
     (measured ~2x the whole dispatch on the CPU tiny-model bench).
-    Expert counts: the experts at least one live slot was routed to and
-    the (row tile, expert) pairs the experts' kernel visited, each summed
-    over the layers and the interval's steps (zeros for a dense model).
+    Expert counts: the experts at least one live slot was routed to, the
+    (row tile, expert) pairs the experts' kernel visited, the live slots'
+    picks that landed on experts held here and all their picks, each
+    summed over the layers and the interval's steps (zeros for a dense
+    model).
     `pool_sharded`: see `_paged_cache`."""
     live = positions >= 0
 
@@ -208,7 +216,7 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
         jax.lax.scan(one, (toks, positions, tidx, cache, done,
-                           jnp.zeros((2,), jnp.int32)), None, length=interval)
+                           jnp.zeros((4,), jnp.int32)), None, length=interval)
     return (toks_all.T, lg_all.T, last, positions, tidx, touched,
             *_pools(cache))
 
@@ -301,12 +309,13 @@ class ServeEngine:
         scfg.validate()
         self.speculate = scfg.speculator == "ngram"
         if self.speculate and (model_cfg.num_experts
-                               or model_cfg.layer_types is not None):
+                               or model_cfg.layer_types is not None
+                               or model_cfg.mla):
             raise ValueError(
                 "serve.speculator='ngram' serves dense models of full "
                 "layers only: the speculative verify scan has never run "
-                "an expert block or a sliding-window layer, and nothing "
-                "tests it with one")
+                "an expert block, a sliding-window layer or a latent "
+                "cache, and nothing tests it with one")
         self.params = params
         self.cfg = model_cfg
         self.scfg = scfg
@@ -336,7 +345,16 @@ class ServeEngine:
                                                max_len=self.max_len)
         # a model with sliding-window layers: a second pool, a ring a slot
         self.mixed = model_cfg.layer_types is not None
-        if self.mixed:
+        # a model with latent attention: one pool with no head axis and no
+        # `v` (serve/paged_cache.py LatentPagedCache), sized from the
+        # latent's width
+        self.latent = model_cfg.mla
+        if self.latent:
+            cache = init_latent_cache(model_cfg, self.num_blocks,
+                                      self.block_size, self.num_slots,
+                                      self.max_blocks)
+            self._k, self._v = cache.kv, None
+        elif self.mixed:
             self.ring_blocks = min(self.max_blocks, ring_blocks_for(
                 model_cfg.sliding_window, scfg.prefill_chunk,
                 self.block_size))
@@ -387,7 +405,7 @@ class ServeEngine:
             self._rep_sh = jax.sharding.SingleDeviceSharding(dev)
             kv_sh = self._rep_sh
         self._k = jax.device_put(self._k, kv_sh)
-        self._v = jax.device_put(self._v, kv_sh)
+        self._v = jax.device_put(self._v, kv_sh)  # None: a latent cache
         self.cos = jax.device_put(self.cos, self._rep_sh)
         self.sin = jax.device_put(self.sin, self._rep_sh)
         self.base_key = jax.device_put(self.base_key, self._rep_sh)
@@ -446,6 +464,10 @@ class ServeEngine:
             # tile, expert) pairs their kernel visited, out of layers x
             # steps x experts (all 0 for a dense model)
             "experts_touched": 0, "expert_visits": 0, "expert_slots": 0,
+            # the decode steps' picks (live slots x experts a token, summed
+            # over layers) and those that landed on experts held here:
+            # equal unless the device holds a share of the experts
+            "picks_here": 0, "picks_all": 0,
         }
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
         self._next_auto_id = 0
@@ -904,14 +926,17 @@ class ServeEngine:
                 # dispatch's
                 nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
                 if self.cfg.num_experts:
-                    touched, visits = (int(c) for c in counts)
-                    slots = (self.cfg.num_hidden_layers * interval
+                    touched, visits, here, picks = (int(c) for c in counts)
+                    slots = (self.cfg.stacks[-1][1] * interval
                              * self.cfg.num_experts)
                     self.stats["experts_touched"] += touched
                     self.stats["expert_visits"] += visits
                     self.stats["expert_slots"] += slots
+                    self.stats["picks_here"] += here
+                    self.stats["picks_all"] += picks
                     sp.set(experts_touched=touched, expert_visits=visits,
-                           expert_slots=slots)
+                           expert_slots=slots, picks_here=here,
+                           picks_all=picks)
         # feed outputs forward; any roster/table change below
         # nulls this via _sync_table
         self._decode_state = state
@@ -969,7 +994,8 @@ class ServeEngine:
         return True
 
     def _kind_blocks(self, active, kv_blocks: int) -> dict:
-        """Further counts of a decode dispatch's span for a model with
+        """Further counts of a decode dispatch's span. A model with a
+        latent cache: `latent_blocks`. A model with
         sliding layers, each summed over the layers of its kind, at the
         dispatch's first token: `kv_blocks_full` (the full layers read
         every block a slot's positions fill), `kv_blocks_window` (the
@@ -977,6 +1003,10 @@ class ServeEngine:
         on), `kv_blocks_banded` (their sum: what the step reads) and
         `kv_blocks_unwindowed` (what it would read were every layer
         full)."""
+        if self.latent:
+            # the blocks of the latent pool the step's slots hold, summed
+            # over the layers: what the latent kernel reads
+            return dict(latent_blocks=self.cfg.num_hidden_layers * kv_blocks)
         if not self.mixed:
             return {}
         n_full = self.cfg.layer_kinds.count("full_attention")

@@ -90,10 +90,33 @@ import jax.numpy as jnp
 from picotron_tpu.config import ModelConfig
 from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype
+from picotron_tpu.ops.mla import (
+    TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
+)
 from picotron_tpu.ops.paged_attention import (
-    decode_kernel_suits, paged_decode_attention,
+    decode_kernel_suits, latent_decode_attention, latent_kernel_suits,
+    paged_decode_attention,
 )
 from picotron_tpu.telemetry.scopes import scope
+
+
+def _slots_of(tables, q_pos, rows: int, block_size: int, num_blocks: int,
+              ring: bool = False):
+    """(physical block [B, s], offset in it [B, s]) of the positions q_pos
+    ([s] batch-shared or [B, s]) of `rows` table rows. Positions < 0,
+    positions beyond the table's capacity and unmapped entries all resolve
+    to the out-of-bounds sentinel `num_blocks`, which a scatter with
+    `mode="drop"` drops. `ring`: logical block j lives at entry j % width,
+    and no position is beyond the table."""
+    width = tables.shape[1]
+    if q_pos.ndim == 1:
+        q_pos = jnp.broadcast_to(q_pos[None, :], (rows, q_pos.shape[0]))
+    blk = jnp.maximum(q_pos, 0) // block_size                   # [B, s]
+    if ring:
+        blk = blk % width
+    phys = jnp.take_along_axis(tables, jnp.minimum(blk, width - 1), axis=1)
+    phys = jnp.where((q_pos >= 0) & (blk < width), phys, num_blocks)
+    return phys, jnp.maximum(q_pos, 0) % block_size
 
 
 class PagedKVCache(NamedTuple):
@@ -127,18 +150,8 @@ class PagedKVCache(NamedTuple):
         out-of-bounds sentinel and are DROPPED by the scatter. `ring`: the
         table is a ring (a sliding layer's), logical block j at entry
         j % width, and no position is beyond it."""
-        bs = self.block_size
-        if q_pos.ndim == 1:
-            q_pos = jnp.broadcast_to(q_pos[None, :],
-                                     (k_new.shape[0], q_pos.shape[0]))
-        blk = jnp.maximum(q_pos, 0) // bs                       # [B, s]
-        if ring:
-            blk = blk % self.tables.shape[1]
-        idx = jnp.minimum(blk, self.tables.shape[1] - 1)
-        phys = jnp.take_along_axis(self.tables, idx, axis=1)    # [B, s]
-        ok = (q_pos >= 0) & (blk < self.tables.shape[1])
-        phys = jnp.where(ok, phys, self.num_blocks)
-        off = jnp.maximum(q_pos, 0) % bs
+        phys, off = _slots_of(self.tables, q_pos, k_new.shape[0],
+                              self.block_size, self.num_blocks, ring)
         # the indices are batched over the heads with the pool: vmapped
         # over pool and rows alone, the head folds into the scatter's
         # window and the compiler carries the pool heads-minor again
@@ -362,6 +375,105 @@ def init_mixed_cache(cfg: ModelConfig, num_blocks: int,
         pool("sliding_attention", num_window_blocks),
         jnp.full((num_slots, max_blocks), num_blocks, jnp.int32),
         jnp.full((num_slots, ring_blocks), num_window_blocks, jnp.int32))
+
+
+def latent_row_width(cfg: ModelConfig) -> int:
+    """Numbers a cached position of a latent pool: `[c | k_r]`
+    (kv_lora_rank + qk_rope_head_dim) padded up to whole 128-lane rows
+    (576 -> 640 at openPangu-Ultra's widths, 11%: a block is then ONE
+    contiguous piece for the decode kernel's DMA and one matmul operand,
+    and a 576-wide array would be laid out 640 wide in HBM anyway)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+class LatentPagedCache(NamedTuple):
+    """The serving cache of a model with latent attention (MLA,
+    ops/mla.py): a third kind of state beside the K/V pool and the ring.
+    One pool [L, num_blocks, block_size, W] with NO head axis: a position
+    holds `[c | k_r | 0]`, c after its norm and k_r after its rotation,
+    W = `latent_row_width`. Tables, sentinel and `BlockPool` as
+    `PagedKVCache`'s. `generate._decode_layers` calls `write(li, ckr,
+    q_pos)` and `attend(li, q_n, q_r, q_pos, kv_b, cfg)`."""
+
+    kv: jnp.ndarray      # [L, num_blocks, block_size, W]
+    tables: jnp.ndarray  # [B, max_blocks] int32; num_blocks = unmapped
+
+    @property
+    def num_layers(self) -> int:
+        return self.kv.shape[0]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.kv.shape[1]
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[2]
+
+    @scope("kv_write")
+    def write(self, li, ckr_new, q_pos) -> "LatentPagedCache":
+        """Scatter `[c | k_r]` [B, s, rank + rope] into each token's
+        (physical block, offset) row of layer li, padded to the row's
+        width; dropped where `PagedKVCache.write` drops."""
+        phys, off = _slots_of(self.tables, q_pos, ckr_new.shape[0],
+                              self.block_size, self.num_blocks)
+        new = jnp.pad(ckr_new, ((0, 0), (0, 0),
+                                (0, self.kv.shape[3] - ckr_new.shape[2])))
+        return self._replace(
+            kv=self.kv.at[li, phys, off].set(new, mode="drop"))
+
+    def attend(self, li, q_n, q_r, q_pos, kv_b, cfg: ModelConfig):
+        """Attention of q_n [B, s, heads, nope] / q_r [B, s, heads, rope]
+        (rotated) over layer li's cached positions -> [B, s, heads, v].
+        A decode step on a chip runs absorbed through the latent kernel,
+        which reads the blocks a slot holds in place; everything else
+        walks each row's blocks in tiles (`ops/mla.py latent_attention`:
+        absorbed or expanded by the number of queries a row)."""
+        b, s = q_n.shape[:2]
+        rank = cfg.kv_lora_rank
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        with scope("attn_latent"):
+            if latent_kernel_suits(s, self.kv, rank):
+                q = jnp.concatenate(
+                    [absorb_queries(q_n[:, 0], kv_b, cfg), q_r[:, 0]], axis=-1)
+                q = jnp.pad(q, ((0, 0), (0, 0),
+                                (0, self.kv.shape[3] - q.shape[-1])))
+                o_lat = latent_decode_attention(
+                    q, self.kv, li, self.tables,
+                    jnp.maximum(q_pos[:, 0] + 1, 0), rank=rank,
+                    sm_scale=1.0 / (cfg.qk_nope_head_dim
+                                    + cfg.qk_rope_head_dim) ** 0.5)
+                return values_from_latent(o_lat, kv_b, cfg)[:, None]
+            return self._tiled(li, q_n, q_r, q_pos, kv_b, cfg)
+
+    def _tiled(self, li, q_n, q_r, q_pos, kv_b, cfg):
+        bs, width = self.block_size, self.tables.shape[1]
+        tb = min(max(TILE_KEYS // bs, 1), width)
+        tiles = -(-width // tb)
+        # whole tiles: entries past the table read the unmapped sentinel,
+        # which the gather clamps into the pool and the positions mask
+        tables = jnp.pad(self.tables, ((0, 0), (0, tiles * tb - width)),
+                         constant_values=self.num_blocks)
+        at = jnp.arange(tb * bs)
+
+        def fetch(bi, t):
+            tbl = jax.lax.dynamic_slice_in_dim(tables[bi], t * tb, tb)
+            return (self.kv[li, tbl].reshape(tb * bs, -1), t * tb * bs + at)
+
+        return latent_attention(q_n, q_r, q_pos, fetch, tiles, tb * bs,
+                                kv_b, cfg)
+
+
+def init_latent_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+                      num_slots: int, max_blocks: int) -> LatentPagedCache:
+    """Zeroed latent pool + all-unmapped tables: `latent_row_width` numbers
+    a position and layer, of which kv_lora_rank + qk_rope_head_dim are
+    the state."""
+    return LatentPagedCache(
+        jnp.zeros((cfg.num_hidden_layers, num_blocks, block_size,
+                   latent_row_width(cfg)), compute_dtype(cfg)),
+        jnp.full((num_slots, max_blocks), num_blocks, jnp.int32))
 
 
 class ShardedPagedKVCache(PagedKVCache):

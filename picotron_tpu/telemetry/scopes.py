@@ -41,6 +41,12 @@ SCOPES = (
     "attn_full",        # inside paged_attention, a model with sliding layers: a full layer's attention
     "attn_window",      # inside paged_attention, the same model: a sliding-window layer's attention
     "sample",           # serve: next-token choice from the logits
+    "mla_q",            # latent attention: the query's two projections and the norm between them
+    "mla_kv_latent",    # latent attention: the projection to [c | k_r] and c's norm
+    "mla_absorb",       # latent attention: the products with Wkvb's two halves (Wuk into the queries and Wuv out of P c when absorbed; a key tile's expansion otherwise)
+    "mla_o",            # latent attention: the output projection
+    "attn_latent",      # inside paged_attention, a model with a latent cache: the decode step's latent kernel; prefill's tiles, mask, softmax, PV
+    "moe_shared",       # inside mlp: the shared expert's gated MLP
 )
 
 

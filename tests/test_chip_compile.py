@@ -466,3 +466,105 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
         # a period's [4, 64, ...] slices they were 3 GiB of temporaries, and a
         # second read and a write of every weight in a memory-bound step
         assert ma.temp_size_in_bytes < 0.5 * 2**30, ma.temp_size_in_bytes / 2**30
+
+
+_COMPILED_PANGU: dict = {}
+
+
+def compiled_pangu(topo, monkeypatch, program: str, rows=None):
+    """(compiled, the latent pool's shape, the pool's parameter number) of
+    `serve_decode` or `serve_prefill` (at `rows` rows) of the openPangu-Ultra-MoE
+    configuration at its widths, depth and serve settings on one described
+    chip, the pool donated."""
+    from picotron_tpu.serve.paged_cache import init_latent_cache
+
+    if (program, rows) in _COMPILED_PANGU:
+        return _COMPILED_PANGU[program, rows]
+    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
+    c = load("configs", "openpangu-ultra-moe-5l-ep16")
+    cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
+    m, sc = cfg.model, cfg.serve
+    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
+    slots = sc.decode_slots
+    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    cache = on_chip(jax.eval_shape(lambda: init_latent_cache(
+        m, sc.num_blocks, sc.block_size, slots, max_blocks)))
+    cos, sin = on_chip(jax.eval_shape(
+        lambda: model_rope_tables(m, max_len=sc.max_model_len)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    decode, prefill = _get_jits(True)
+    if program == "serve_prefill":
+        low = prefill.lower(
+            params, cache.kv, None, i32(rows, max_blocks),
+            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
+            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
+    else:
+        low = decode.lower(
+            params, cache.kv, None, i32(slots, max_blocks),
+            i32(slots), i32(slots), i32(slots), i32(slots), key, cos, sin,
+            cfg=m, temperature=0.0, top_k=0, interval=sc.decode_interval,
+            eos_token_id=None)
+    out = _COMPILED_PANGU[program, rows] = (
+        low.compile(), cache.kv.shape, len(jax.tree.leaves(params)))
+    return out
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 4),
+    ("serve_prefill", 16)])
+def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `openpangu-ultra-moe-5l-ep16` compile for a v5e
+    and fit it beside the weights; the latent pool is never copied whole and
+    is written in place; the decode step attends through the latent kernel
+    (five layers, two stacks: one call a stack's scan body) under
+    `attn_latent`, a prefill chunk walks tiles and calls no attention
+    kernel; the experts of the expert stack are one grouped kernel; the
+    scopes the cell's metrics read are there."""
+    comp, pool_shape, pool = compiled_pangu(topo, monkeypatch, program, rows)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"kv_write", "paged_attention", "attn_latent", "mla_q",
+                     "mla_kv_latent", "mla_absorb", "mla_o", "mlp", "moe_router",
+                     "moe_dispatch", "moe_experts", "moe_shared", "sample"}
+    copies = whole_pool_copies(text, pool_shape)
+    assert not copies, f"{program} copies the latent pool {pool_shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert pool in {int(p) for p in re.findall(r"\}: \((\d+), ", alias)}, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "mla_attention_ms.serve")["params"]["ops"])
+    latent = [(n, op) for n, op in kernels if attn.search(n)]
+    grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
+    assert len(grouped) + len(latent) == len(kernels), kernels
+    # the expert stack's scan body calls the kernel once a block of tokens
+    # (`ops/moe.py MAX_SORTED_BYTES`: up to 4,096 tokens at these widths go
+    # in one; 16 rows of 1,024 would go in four)
+    chunk = load("configs", "openpangu-ultra-moe-5l-ep16")["serve"]["prefill_chunk"]
+    tokens = 16 if program == "serve_decode" else rows * chunk
+    assert len(grouped) == max(1, tokens // 4096), kernels
+    assert all("moe_experts" in words(op) for _, op in grouped)
+    assert "ragged-dot" not in text
+    if program == "serve_decode":
+        assert len(latent) == 2  # the dense stack's body and the expert stack's
+        assert all("attn_latent" in words(op) for _, op in latent)
+    else:
+        assert not latent
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15.75 * 2**30, total / 2**30
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)

@@ -310,8 +310,14 @@ def test_hf_reader_accepts_the_published_keys():
 
 
 def test_dense_mlp_layers_are_refused_by_name():
+    """A dense MLP layer loads at the head of the stack only (PR 35:
+    `first_k_dense_replace`); behind an expert layer it is refused by name."""
     with pytest.raises(ValueError, match="mlp_layer_types.*dense"):
-        model_config_from_hf_json(dict(HF, mlp_layer_types=["dense"] + ["sparse"] * 7))
+        model_config_from_hf_json(dict(HF, mlp_layer_types=["sparse", "dense"] + ["sparse"] * 6))
+    lead = model_config_from_hf_json(dict(HF, mlp_layer_types=["dense"] + ["sparse"] * 7))
+    assert lead["first_k_dense_replace"] == 1
+    with pytest.raises(ValueError, match="first_k_dense_replace > 0 with sliding-window"):
+        ModelConfig(**lead).validate()
 
 
 @pytest.mark.parametrize("what,kw", [

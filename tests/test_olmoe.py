@@ -12,16 +12,21 @@ gradient leaf's largest element.
 """
 
 import dataclasses
+import importlib.util
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
-import reference_moe  # noqa: E402
+# loaded by its path: `benchmark/` is not put on sys.path, where its own
+# `tests` package would shadow this one for every test file the worker runs after
+_spec = importlib.util.spec_from_file_location(
+    "reference_moe", os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                                  "reference_moe.py"))
+reference_moe = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(reference_moe)
 
 from picotron_tpu.config import (  # noqa: E402
     Config, DistributedConfig, ModelConfig, TrainingConfig,

@@ -296,3 +296,100 @@ def test_mixed_engine_decodes_through_the_windowed_kernel(monkeypatch, fresh_pro
     for a, b in zip(plain, kernel):
         assert a["tokens"] == b["tokens"]
         np.testing.assert_allclose(a["logits"], b["logits"], atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the latent cache's decode kernel (MLA absorbed)
+# ---------------------------------------------------------------------------
+
+LATENT_CASES = {
+    "ragged": dict(lengths=[1, BS, BS + 1, FULL, 0], li=1),
+    "last_layer_chunks_of_1_page": dict(lengths=[FULL, 5, BS + 1], li=L - 1, ppc=1),
+    "chunks_of_4_pages": dict(lengths=[4 * BS, 4 * BS + 1, FULL, 7], li=2, ppc=4),
+    "every_slot_idle": dict(lengths=[0, 0, 0], li=0),
+    "bf16_pool": dict(lengths=[1, BS + 1, FULL, 0], li=L - 1, dtype=jnp.bfloat16, tol=3e-2),
+}
+
+
+@pytest.mark.parametrize("name", LATENT_CASES)
+def test_latent_kernel_matches_the_tiled_walk(name):
+    """`latent_decode_attention` (interpreted) on a pool poisoned wherever a
+    live slot must not read, against `LatentPagedCache`'s tiled walk on the
+    clean pool: the same P c Wuv for every slot, zeros for an idle one."""
+    from picotron_tpu.ops import mla
+    from picotron_tpu.ops.paged_attention import latent_decode_attention
+    from picotron_tpu.serve.paged_cache import LatentPagedCache, latent_row_width
+
+    case = LATENT_CASES[name]
+    dt, tol = case.get("dtype", jnp.float32), case.get("tol", 2e-5)
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+    heads, dn, dr, rank, w = 4, 16, 8, 32, latent_row_width(cfg)
+    rng = np.random.default_rng(len(name))
+    lengths = np.asarray(case["lengths"], np.int32)
+    tables = scattered_tables(rng, lengths)
+    rows = rng.standard_normal((L, NB, BS, rank + dr)).astype(np.float32)
+    clean = np.zeros((L, NB, BS, w), np.float32)
+    clean[..., :rank + dr] = rows
+    poisoned = np.full((L, NB, BS, w), np.nan, np.float32)
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // BS)):
+            poisoned[:, tables[b, j]] = clean[:, tables[b, j]]
+    q_n = jnp.asarray(rng.standard_normal((len(lengths), 1, heads, dn)), dt)
+    q_r = jnp.asarray(rng.standard_normal((len(lengths), 1, heads, dr)), dt)
+    kv_b = jnp.asarray(rng.standard_normal((rank, heads * (dn + 16))) * 0.2, dt)
+    q_pos = jnp.asarray(lengths - 1)[:, None]
+    with jax.default_matmul_precision("highest"):
+        want = LatentPagedCache(jnp.asarray(clean, dt), jnp.asarray(tables))._tiled(
+            case["li"], q_n, q_r, q_pos, kv_b, cfg)
+        q = jnp.concatenate([mla.absorb_queries(q_n[:, 0], kv_b, cfg), q_r[:, 0]], -1)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, w - q.shape[-1])))
+        o_lat = latent_decode_attention(
+            q, jnp.asarray(poisoned, dt), case["li"], jnp.asarray(tables),
+            jnp.asarray(lengths), rank=rank, sm_scale=1 / 24 ** 0.5,
+            pages_per_chunk=case.get("ppc"), interpret=True)
+        got = mla.values_from_latent(o_lat, kv_b, cfg)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want[:, 0], np.float32), atol=tol)
+    assert not np.asarray(got, np.float32)[lengths == 0].any()
+
+
+def test_latent_step_decides_the_path(monkeypatch):
+    from picotron_tpu.ops import paged_attention as pa
+
+    pool = jnp.zeros((2, 8, 16, 640), jnp.bfloat16)
+    assert not pa.latent_kernel_suits(1, pool, 512)  # the CPU compiles no kernel
+    monkeypatch.setattr(pa, "compiled_kernels_available", lambda: True)
+    assert pa.latent_kernel_suits(1, pool, 512)
+    assert not pa.latent_kernel_suits(8, pool, 512)          # a prefill chunk
+    assert not pa.latent_kernel_suits(1, pool[..., :576], 512)  # rows of 4.5 x 128 lanes
+    assert not pa.latent_kernel_suits(1, jnp.zeros((2, 8, 4, 128)), 32)  # the tiny preset
+
+
+def test_latent_engine_decodes_through_the_kernel(monkeypatch, fresh_programs):
+    """`serve_decode` of a model with a latent cache, the kernel forced in
+    (interpreted): tokens and logits are the tiled walk's, within rounding."""
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+    params = init_params(cfg, jax.random.key(2))
+    rng = np.random.default_rng(5)
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+                for n, m in ((37, 8), (6, 5), (21, 7))]
+    scfg = ServeConfig(decode_slots=2, block_size=4, prefill_chunk=8,
+                       max_model_len=64, decode_interval=2)
+
+    def run():
+        eng = ServeEngine(params, cfg, scfg)
+        out = eng.run(requests)
+        eng.close()
+        assert eng.pool.in_use == 0
+        return out
+
+    plain = run()
+    taken = []
+    monkeypatch.setattr(paged_cache, "latent_kernel_suits",
+                        lambda s, pool, rank: taken.append(s) or s == 1)
+    kernel = run()
+    assert 1 in taken
+    for a, b in zip(plain, kernel):
+        assert a["tokens"] == b["tokens"]
+        np.testing.assert_allclose(a["logits"], b["logits"], atol=2e-4)

@@ -153,6 +153,39 @@ def test_mixed_model_serve_program_scopes(program):
     seen.update(found)
 
 
+MLA = {"mla_q", "mla_kv_latent", "mla_absorb", "mla_o", "attn_latent", "moe_shared"}
+
+
+@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
+def test_latent_model_serve_program_scopes(program):
+    """A model with latent attention, a shared expert and a dense layer before
+    its expert layers: both serve programs carry the latent scopes beside the
+    expert scopes (`benchmark/layer_metrics/mla_*.serve.json` and
+    `moe_shared_ms.serve.json` read them)."""
+    mcfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+    e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
+                    ServeConfig(decode_slots=2, block_size=4, prefill_chunk=4,
+                                max_model_len=32, decode_interval=2))
+    s = e.num_slots
+    vec = jnp.zeros((s,), jnp.int32)
+    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k)
+    head = (e.params, e._k, e._v, jnp.asarray(e._tables))
+    tail = (e.base_key, e.cos, e.sin)
+    if program == "serve_prefill":
+        lowered = e._prefill_jit.lower(
+            *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
+            vec, vec, *tail, **common)
+    else:
+        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
+                                      interval=2, eos_token_id=None, **common)
+    e.close()
+    text = lowered.as_text(debug_info=True)
+    assert module_name(text) == f"jit_{program}"
+    found = scopes_in(text)
+    assert found == SERVE | MOE | MLA
+    seen.update(found)
+
+
 def test_every_declared_scope_is_used_and_none_is_undeclared():
     """Runs after the cases above (same file, same worker)."""
     assert seen == set(SCOPES)

@@ -811,3 +811,55 @@ def test_engine_serves_experts_with_generates_tokens():
     # a live row is routed to 2 experts a layer: between 2 and 2 x the slots
     lo, hi = 2 * cfg.num_hidden_layers * steps, 2 * 3 * cfg.num_hidden_layers * steps
     assert lo <= eng.stats["experts_touched"] <= hi
+
+
+# ---------------------------------------------------------------------------
+# a third kind of cache state: the latent pool (a model with latent attention)
+# ---------------------------------------------------------------------------
+
+
+def _pangu(seed=3):
+    from picotron_tpu.config import resolve_preset
+
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+    p = init_params(cfg, jax.random.key(seed))
+    return cfg, dict(p, embedding=p["embedding"] * 0.1)
+
+
+def test_latent_engine_retire_cancel_shed_leak_no_block():
+    """The latent pool over a trace that retires, cancels (a resident and a
+    queued request) and sheds (a deadline passed in the queue): every block
+    is back, the survivors' tokens are `generate`'s, and a preempted request
+    (a pool too small for both residents' growth) finishes alike."""
+    cfg, params = _pangu()
+    rng = np.random.default_rng(2)
+    prompts = [list(map(int, rng.integers(0, cfg.vocab_size, size=n)))
+               for n in (23, 9, 17, 30, 12, 5)]
+    want = {i: list(map(int, np.asarray(generate(
+        params, cfg, jnp.asarray([p]), 6))[0, len(p):])) for i, p in enumerate(prompts)}
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=2, block_size=4, prefill_chunk=8, max_model_len=48,
+        decode_interval=2, num_blocks=14))
+    assert eng.latent and eng._v is None and eng._k.shape == (4, 14, 4, 128)
+    for i, p in enumerate(prompts):
+        eng.submit(p, 6, req_id=i, arrival=0.0,
+                   **({"deadline_ms": 10.0} if i == 5 else {}))
+    eng.step(0.0)
+    eng.step(0.0)
+    resident = [s.req.id for s in eng.sched.slots if s is not None]
+    queued = [s.req.id for s in eng.sched.queue if s.req.id != 5]
+    assert resident and queued
+    held = eng.pool.in_use
+    assert eng.cancel(resident[0]) and eng.pool.in_use < held
+    assert eng.cancel(queued[0])
+    now = 1.0
+    while eng.sched.has_work():
+        eng.step(now)
+        now += 0.1
+    assert eng.pool.in_use == 0
+    assert [r["id"] for r in eng.shed_results] == [5]
+    done = {r["id"]: r["tokens"] for r in eng.results}
+    assert set(done) == set(range(5)) - {resident[0], queued[0]}
+    for rid, toks in done.items():
+        assert toks == want[rid], rid
+    eng.close()
