@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from picotron_tpu.ops.losses import IGNORE_INDEX
+from picotron_tpu.ops.losses import IGNORE_INDEX, pick_label
 from picotron_tpu.telemetry.scopes import scope
 
 # Every collective of the tensor-parallel hooks is entered under the
@@ -148,11 +148,7 @@ def vocab_parallel_ce_local_stats(hidden: jnp.ndarray,
     logits = (hidden @ head_shard.astype(hidden.dtype)).astype(jnp.float32)
     m_loc = jax.lax.stop_gradient(jnp.max(logits, axis=-1))  # [B, S]
     sumexp_loc = jnp.sum(jnp.exp(logits - m_loc[..., None]), axis=-1)
-    ok = (rel >= 0) & (rel < vshard)
-    relc = jnp.clip(rel, 0, vshard - 1)
-    label_loc = (jnp.take_along_axis(logits, relc[..., None], axis=-1)
-                 .squeeze(-1) * ok.astype(jnp.float32))
-    return m_loc, sumexp_loc, label_loc
+    return m_loc, sumexp_loc, pick_label(logits, rel)
 
 
 def _chunked_local_stats(hidden, head_shard, rel, chunk_size: int):
@@ -177,12 +173,7 @@ def _chunked_local_stats(hidden, head_shard, rel, chunk_size: int):
         m_new = jnp.maximum(m_acc, m_c)
         se = (se_acc * jnp.exp(m_acc - m_new)
               + jnp.sum(jnp.exp(logits - m_new[..., None]), axis=-1))
-        rc = rel - off
-        ok = (rc >= 0) & (rc < chunk_size)
-        rcc = jnp.clip(rc, 0, chunk_size - 1)
-        lab = (jnp.take_along_axis(logits, rcc[..., None], axis=-1)
-               .squeeze(-1) * ok.astype(jnp.float32))
-        return (m_new, se, lab_acc + lab), None
+        return (m_new, se, lab_acc + pick_label(logits, rel - off)), None
 
     # The scan carry must already hold the varying type the body produces
     # (tp via head/rel, data axes via hidden). Anchored with zero-weighted

@@ -68,9 +68,14 @@ def compiled_step(topo, monkeypatch, config: str) -> str:
     return compile_step(topo, monkeypatch, config, layers=2).as_text()
 
 
+_COMPILED_STEP: dict = {}  # (config, layers) -> compile_step's result
+
+
 def compile_step(topo, monkeypatch, config: str, layers: int | None):
     """The cell's train step at the cell's widths and `layers` layers (None:
     the cell's own depth), compiled for the described chips."""
+    if (config, layers) in _COMPILED_STEP:
+        return _COMPILED_STEP[config, layers]
     # the program asks the backend whether the kernels exist; here the
     # backend is the CPU and the target is the described chip
     fa = importlib.import_module("picotron_tpu.ops.flash_attention")
@@ -88,7 +93,9 @@ def compile_step(topo, monkeypatch, config: str, layers: int | None):
     b = jax.ShapeDtypeStruct(
         (t.gradient_accumulation_steps, t.micro_batch_size * d["dp_size"],
          t.seq_length), jnp.int32, sharding=menv.batch_sharding())
-    return make_train_step(cfg, menv).lower(state, (b, b)).compile()
+    out = _COMPILED_STEP[config, layers] = make_train_step(
+        cfg, menv).lower(state, (b, b)).compile()
+    return out
 
 
 def instructions(text: str):
@@ -170,6 +177,53 @@ def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert total < 15.75 * 2**30, total / 2**30
+
+
+# temp_size_in_bytes of the same compile on the parent of PR 36 (commit
+# 2c20159, this installation), whose head backward scattered the label term
+# into a zero-filled copy of one microbatch's fp32 logits
+PARENT_TEMP_BYTES = {"qwen2-1.5b-12l": 13_961_579_008,
+                     "olmoe-1b-7b-1l": 9_322_046_464}
+
+
+@pytest.mark.parametrize("config", sorted(PARENT_TEMP_BYTES))
+def test_head_backward_takes_no_vocabulary_sized_detour(topo, monkeypatch, config):
+    """The only vocabulary-sized tensors of the head + CE backward are the
+    logits, dlogits and the fp32 accumulator. Before PR 36 the label pick's
+    transpose was a scatter of 4,096 values into a zero-filled [tokens, vocab]
+    fp32 tensor, which this compiler runs on a flat relayout of it
+    (`reshape f32[622329856]`: 2.5 GB read and written a microbatch, 3.8% of
+    the Qwen2 cell's step; a bf16 twin of it after the convert). The head's
+    dW and the embedding's rows, on the other hand, the compiler already lands
+    in the accumulator: held here so that a rewrite cannot lose it."""
+    comp = compile_step(topo, monkeypatch, config, layers=None)
+    c = load("configs", config)
+    tokens = c["training"]["micro_batch_size"] * c["training"]["seq_length"]
+    vocab, hidden = c["model"]["vocab_size"], c["model"]["hidden_size"]
+    text = comp.as_text()
+    ins = instructions(text)
+    # no scatter into, and no flat (rank-1) form of, a tokens x vocab tensor
+    flat = [line.strip()[:160] for _, _, line in ins
+            if re.search(rf"= \w+\[{tokens * vocab}\]", line)]
+    scatters = [line.strip()[:160] for _, _, line in ins
+                if " scatter(" in line and tokens * vocab in result_sizes(line)]
+    assert not flat and not scatters, (flat, scatters)
+    # what the microbatch loop does at the accumulator's size under `head_ce`
+    # and `embed`: the dW matmul with the fp32 add in its epilogue, and the
+    # embedding's rows scattered onto the accumulator in place; no pass of
+    # its own over a dense [V, H] gradient
+    comps = computations(text)
+    acc = [(n, line) for n, op, line in ins
+           if words(op) & {"head_ce", "embed"} and "fusion(" in line
+           and vocab * hidden in result_sizes(line) and "f32[" in line.split(" fusion(")[0]]
+    assert len(acc) == 2, [n for n, _ in acc]
+    bodies = ["\n".join(comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)])
+              for _, line in acc]
+    assert sum(" convolution(" in b for b in bodies) == 1
+    assert sum(" scatter(" in b for b in bodies) == 1
+    temp = comp.memory_analysis().temp_size_in_bytes
+    print(f"{config}: temp_size_in_bytes {temp:,} (parent {PARENT_TEMP_BYTES[config]:,})")
+    assert temp <= PARENT_TEMP_BYTES[config]
 
 
 CHAT_SLOTS = load("configs", "qwen2-1.5b")["serve"]["decode_slots"]

@@ -3,6 +3,8 @@ model as the single-device baseline (the reference's strongest implicit
 invariant, SURVEY.md §7 step 9; its TP test does the same against an
 unsharded nn.Linear, ref: tests/test_tensor_parallel.py)."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,9 +17,11 @@ from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.models.llama import (
     forward, init_params, pad_layers_for_pp, pp_layer_placement, unpad_layers,
 )
-from picotron_tpu.ops.losses import cross_entropy
+from picotron_tpu.ops.losses import IGNORE_INDEX, cross_entropy, pick_label
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
-from picotron_tpu.parallel.tp import vocab_parallel_ce, vocab_parallel_embed
+from picotron_tpu.parallel.tp import (
+    vocab_parallel_ce, vocab_parallel_ce_sum_count, vocab_parallel_embed,
+)
 from picotron_tpu.train_step import init_train_state, make_train_step as make_single_step
 
 
@@ -224,6 +228,85 @@ def test_vocab_parallel_ce_grad_matches_dense():
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(g_par[1]), np.asarray(g_ref[1]),
                                rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the label pick's hand-written backward (ops/losses.py pick_label): a
+# compare against an iota where the gather's own transpose scatters into a
+# zero-filled copy of the logits
+# ---------------------------------------------------------------------------
+
+
+def _gather_pick(logits, rel):
+    """The pick as it was before pick_label, with the gather's own
+    transpose: what dlogits must equal to the bit."""
+    v = logits.shape[-1]
+    ok = (rel >= 0) & (rel < v)
+    relc = jnp.clip(rel, 0, v - 1)
+    return (jnp.take_along_axis(logits, relc[..., None], axis=-1)
+            .squeeze(-1) * ok.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_label_pick_backward_matches_dense_grad(tied, tp, chunk):
+    """Hidden and head gradients of the vocab-parallel CE, through the
+    label pick's hand-written backward, against plain jax.grad of the dense
+    loss: tied (the [V, H] embedding, transposed) and untied head, one
+    shard and two (a label off this shard), the fused branch and the
+    chunked (8 divides both shards), rows with IGNORE_INDEX."""
+    menv = MeshEnv.create(tp=tp)
+    h = jax.random.normal(jax.random.key(0), (2, 8, 16))
+    w = jax.random.normal(jax.random.key(1), (64, 16) if tied else (16, 64))
+    tgt = jax.random.randint(jax.random.key(2), (2, 8), 0, 64)
+    tgt = tgt.at[0, :2].set(IGNORE_INDEX).at[1, 5].set(IGNORE_INDEX)
+    # labels on both shards, and the first and last column of each
+    tgt = tgt.at[1, :4].set(jnp.array([0, 31, 32, 63]))
+    head = (lambda p: p.T) if tied else (lambda p: p)
+
+    def sharded_loss(h, p):
+        total, count = vocab_parallel_ce_sum_count(h, head(p), tgt,
+                                                   chunk_size=chunk)
+        return total / jnp.maximum(count, 1)
+
+    w_spec = P("tp", None) if tied else P(None, "tp")
+    loss, g_par = jax.jit(compat.shard_map(
+        jax.value_and_grad(sharded_loss, argnums=(0, 1)), mesh=menv.mesh,
+        in_specs=(P(), w_spec), out_specs=(P(), (P(), w_spec)),
+    ))(h, w)
+    want, g_ref = jax.value_and_grad(
+        lambda h, p: cross_entropy(h @ head(p), tgt), argnums=(0, 1))(h, w)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    for got, ref in zip(g_par, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("lo,width", [(0, 64), (32, 32), (40, 8)],
+                         ids=["whole-vocab", "second-shard", "chunk"])
+def test_label_pick_dlogits_equal_the_gather_transpose_to_the_bit(lo, width):
+    """dlogits of one shard's (or chunk's) softmax statistics: the sum of
+    the same two terms, whichever way the label term is formed. Labels
+    below, inside and above the window, and both of its edges."""
+    logits = 4.0 * jax.random.normal(jax.random.key(3), (3, 16, width))
+    tgt = jax.random.randint(jax.random.key(4), (3, 16), 0, 64)
+    tgt = tgt.at[0, :4].set(jnp.array([lo, lo + width - 1, 0, 63]))
+    g_se = jax.random.normal(jax.random.key(5), (3, 16))
+    g_lab = jax.random.normal(jax.random.key(6), (3, 16))
+
+    def stats(pick, lg):
+        m = jax.lax.stop_gradient(jnp.max(lg, axis=-1))
+        se = jnp.sum(jnp.exp(lg - m[..., None]), axis=-1)
+        return jnp.sum(se * g_se) + jnp.sum(pick(lg, tgt - lo) * g_lab)
+
+    val, got = jax.jit(jax.value_and_grad(partial(stats, pick_label)))(logits)
+    val0, want = jax.jit(jax.value_and_grad(partial(stats, _gather_pick)))(logits)
+    assert float(val) == float(val0)
+    hit = np.asarray((tgt >= lo) & (tgt < lo + width))
+    assert hit.any() and (width == 64 or not hit.all())
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
 
 
 def test_zero1_moments_sharded_and_parity():

@@ -4,6 +4,8 @@ of the programs it belongs to, and the programs the benchmark's cells time
 carry pinned module names. Lowered here at tiny sizes on the CPU mesh; the
 described-chip twin, with the Pallas kernels, is tests/test_chip_compile.py."""
 
+import functools
+import math
 import re
 
 import jax
@@ -62,6 +64,19 @@ def train_cfg(engine: str, pp: int, moe: bool = False) -> Config:
                                 remat_policy="dots_attn"))
 
 
+@functools.lru_cache(maxsize=None)
+def lowered_train_step(engine: str, pp: int, moe: bool):
+    cfg = train_cfg(engine, pp, moe=moe)
+    menv = MeshEnv.from_config(cfg)
+    state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
+    t = cfg.training
+    b = jax.ShapeDtypeStruct(
+        (t.gradient_accumulation_steps, t.micro_batch_size, t.seq_length),
+        jnp.int32, sharding=menv.batch_sharding())
+    text = make_train_step(cfg, menv).lower(state, (b, b)).as_text(debug_info=True)
+    return cfg, text
+
+
 @pytest.mark.parametrize("engine,pp,extra", [
     ("fused", 1, {"dw_accum"}),
     ("ad", 1, set()),
@@ -70,18 +85,39 @@ def train_cfg(engine: str, pp: int, moe: bool = False) -> Config:
     ("ad", 1, MOE),
 ], ids=["fused", "ad", "ad-pp2", "fused-moe", "ad-moe"])
 def test_train_step_scopes_and_module_name(engine, pp, extra):
-    cfg = train_cfg(engine, pp, moe=extra >= MOE)
-    menv = MeshEnv.from_config(cfg)
-    state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
-    t = cfg.training
-    b = jax.ShapeDtypeStruct(
-        (t.gradient_accumulation_steps, t.micro_batch_size, t.seq_length),
-        jnp.int32, sharding=menv.batch_sharding())
-    text = make_train_step(cfg, menv).lower(state, (b, b)).as_text(debug_info=True)
+    _, text = lowered_train_step(engine, pp, extra >= MOE)
     assert module_name(text) == "jit_train_step"
     found = scopes_in(text)
     assert found == TRAIN | extra
     seen.update(found)
+
+
+@pytest.mark.parametrize("engine,pp,moe", [
+    ("fused", 1, False), ("ad", 2, False), ("fused", 1, True),
+], ids=["fused", "1f1b", "fused-moe"])
+def test_label_backward_is_under_head_ce(engine, pp, moe):
+    """The label pick's backward (ops/losses.py) is a custom_vjp rule, traced
+    where the engine applies the VJP and not where the forward's `head_ce`
+    decorator was: the rule enters the scope itself, so its one
+    vocabulary-sized operation, the compare against the iota, has `head_ce`
+    as the innermost element of its name stack. Without it
+    `head_ce_ms.train` would read a false gain."""
+    cfg, text = lowered_train_step(engine, pp, moe)
+    vshard = cfg.model.vocab_size // cfg.distributed.tp_size
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    compares = re.findall(
+        rf"stablehlo\.compare\s+EQ,.*x{vshard}xi1> loc\((#loc\d+)\)", text)
+    paths = [locs[c] for c in compares]
+    assert paths and all(p.endswith("head_ce/eq") for p in paths), paths
+    # and the step scatters into no copy of a microbatch's logits (the
+    # gather's own transpose did; the described-chip twin of this count is
+    # tests/test_chip_compile.py's)
+    t = cfg.training
+    logits = t.micro_batch_size * t.seq_length * vshard
+    operands = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<([\dx]+)x\w+>', text, re.S)
+    assert operands and all(
+        math.prod(map(int, o.split("x"))) != logits for o in operands), operands
 
 
 @pytest.fixture(scope="module")
