@@ -262,6 +262,7 @@ class DisaggServeEngine(ServeEngine):
             "decode_stall_ticks_max": 0, "cancelled": 0,
             "handoffs": 0, "handoff_s": 0.0, "handoff_blocks": 0,
         }
+        self._init_step_account()
         self._stall_streak = 0
         self._next_auto_id = 0
         self._warm_prefill()

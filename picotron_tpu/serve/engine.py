@@ -65,12 +65,20 @@ books queue_wait / prefill / decode (compile time drained out exactly
 via CompileWatch), per-request TTFT and per-token latency land in the
 registry histograms and as ``serve_request`` / ``serve_summary`` JSONL
 events, and tools/telemetry_report.py renders the serving view
-(p50/p95 TTFT, tok/s, slot occupancy, pool utilization).
+(p50/p95 TTFT, tok/s, slot occupancy, pool utilization). Every step
+with device work adds up its own leaf spans (`step_account`): the
+seconds with nothing enqueued on the device go onto the ``serve.step``
+span, into a ``phase=serve_host`` event and into `stats`, and a step far
+over the median says which leaf held it (``serve_slow_step``).
 """
 
 from __future__ import annotations
 
+import gc
+import logging
+import statistics
 import time
+from collections import deque
 from functools import partial
 from typing import Optional
 
@@ -94,6 +102,78 @@ from picotron_tpu.telemetry import Telemetry
 from picotron_tpu.telemetry.flightdeck.tracer import TID_SERVE
 from picotron_tpu.telemetry.scopes import scope
 from picotron_tpu.telemetry.spans import join_ids
+
+
+log = logging.getLogger("picotron_tpu.serve")
+
+# A slow step: one of its parts is over the larger of SLOW_STEP_S and
+# SLOW_STEP_X times the median of the last SLOW_STEP_WINDOW parts of its own
+# kind, once SLOW_STEP_AFTER of them have been seen. The parts are each
+# `*.wait` leaf, by the dispatch it waited for (the host runs ahead of a
+# long prompt's chunks, and the one wait at its end is as long as all of
+# them), and the rest of the wall (`host`): a prefill dispatch's own time is
+# held against other prefill dispatches and not against decode steps. The
+# first SLOW_STEP_LOGS are logged, the rest counted.
+SLOW_STEP_S, SLOW_STEP_X = 0.25, 8
+SLOW_STEP_WINDOW, SLOW_STEP_AFTER, SLOW_STEP_LOGS = 64, 16, 32
+
+
+# The leaves that enqueue work on the device, and those that wait for it.
+_ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch",
+                       "serve.handoff"))
+_WAITS = frozenset(("serve.prefill.wait", "serve.decode.wait"))
+
+
+def step_account(leaves, t0: float, wall: float, in_flight: int) -> dict:
+    """Where one engine step's wall went, from its own leaf spans.
+
+    `leaves` are the step's leaf spans in the order they ran, `(name,
+    start, secs)` on the clock of `t0`, the step's start; `wall` is the
+    step's seconds so far. The device has work from the start of a
+    `*.dispatch` (or `serve.handoff`) leaf to the end of the next `*.wait`
+    leaf (`_ENQUEUES`, `_WAITS`): a wait fetches the outputs of the
+    program enqueued last, behind whatever was enqueued before it and not
+    waited for, so it clears both. `in_flight` counts the dispatches that
+    the steps before enqueued and nobody waited for; `"in_flight"` of the
+    result is the same for the next, and `waits` lists each wait as `(name,
+    secs, dispatches it cleared)`.
+
+    `starved_s` is the wall outside those intervals: work pending and
+    nothing enqueued, so the device is idle whatever a profiler does to
+    the host. `starved_by` splits it by leaf (`unspanned`: the code
+    between spans), each leaf by the seconds of its own that were starved;
+    `unspanned_s` is the wall less the leaves, `leaves` each leaf's
+    seconds."""
+    fed = int(in_flight)  # dispatches enqueued and not yet waited for
+    at, spanned = t0, 0.0
+    secs_by: dict = {}
+    starved_by: dict = {}
+    waits = []
+    for name, start, secs in leaves:
+        if not fed and start > at:  # the code between two spans
+            starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
+                                       + start - at)
+        if name in _ENQUEUES:
+            fed += 1
+        secs_by[name] = secs_by.get(name, 0.0) + secs
+        spanned += secs
+        if not fed:
+            starved_by[name] = starved_by.get(name, 0.0) + secs
+        elif name in _WAITS:
+            waits.append((name, secs, fed))
+            fed = 0
+        at = start + secs
+    if not fed and t0 + wall > at:
+        starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
+                                   + t0 + wall - at)
+    return {"wall_s": wall, "starved_s": sum(starved_by.values()),
+            "unspanned_s": max(wall - spanned, 0.0), "leaves": secs_by,
+            "starved_by": starved_by, "waits": waits, "in_flight": fed}
+
+
+def _ms(secs_by: dict) -> dict:
+    """An account's seconds by leaf as an event's milliseconds."""
+    return {k: int(v * 1e6) / 1e3 for k, v in secs_by.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -469,6 +549,7 @@ class ServeEngine:
             # equal unless the device holds a share of the experts
             "picks_here": 0, "picks_all": 0,
         }
+        self._init_step_account()
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
         self._next_auto_id = 0
         self._warm_prefill()
@@ -523,8 +604,24 @@ class ServeEngine:
     # -- helpers -----------------------------------------------------------
 
     def _span(self, name: str, **counts):
-        """A span on the serve lane (telemetry/spans.py)."""
-        return self.telemetry.span(name, tid=TID_SERVE, **counts)
+        """A leaf span on the serve lane (telemetry/spans.py); it adds
+        itself to the step's list when it ends."""
+        return self.telemetry.span(name, tid=TID_SERVE, into=self._leaves,
+                                   **counts)
+
+    def _init_step_account(self) -> None:
+        """The state behind `step`'s account of its own leaves, and its
+        running totals in `stats` (over the steps that had device work)."""
+        self.stats.update(step_wall_s=0.0, starved_s=0.0,
+                          step_wall_max_s=0.0, slow_steps=0)
+        self._leaves: list = []  # this step's (name, start, secs)
+        self._in_flight = 0  # dispatches the last steps left un-waited
+        self._step_compile_s = 0.0  # compile seconds drained in this step
+        self._gc_count, self._gc_before = None, None  # see `step`
+        self._walls: deque = deque(maxlen=4096)  # for the summary's median
+        # the last seconds of each part a step is judged slow by
+        self._recent = {kind: deque(maxlen=SLOW_STEP_WINDOW)
+                        for kind in ("host", *_WAITS)}
 
     def _sync_table(self, slot: int) -> None:
         st = self.sched.slots[slot]
@@ -622,6 +719,7 @@ class ServeEngine:
         if n:
             self.telemetry.emit("compile", category="compile", secs=secs,
                                 compiles=n)
+            self._step_compile_s += secs
         return secs if n else 0.0
 
     def _emit_retired(self, st, now: float) -> dict:
@@ -689,11 +787,104 @@ class ServeEngine:
         The step is one `serve.step` span whose leaf spans say what the
         host was doing (`serve.admit`, `serve.prefill.build | dispatch |
         wait`, `serve.decode.build | dispatch | wait | emit`), each with
-        its counts taken at the same boundary: telemetry/spans.py."""
+        its counts taken at the same boundary: telemetry/spans.py. A step
+        with device work ends by adding those leaves up (`_account_step`)."""
         if now is None:
             now = time.perf_counter() - self._t0
-        with self._span("serve.step"):
-            return self._step(now)
+        self._leaves.clear()
+        self._step_compile_s = 0.0
+        # `gc.get_stats()` as the step starts, for a slow step's report:
+        # read anew only when the cheaper `get_count` says that a
+        # collection ran since the last look
+        count = gc.get_count()[1:]
+        if count != self._gc_count:
+            self._gc_count, self._gc_before = count, gc.get_stats()
+        with self.telemetry.span("serve.step", tid=TID_SERVE) as sp:
+            worked = self._step(now)
+            if worked:
+                self._account_step(sp)
+            else:
+                # nothing pending (an un-waited prefill's request was
+                # cancelled or shed): what is enqueued runs out unwatched,
+                # and the next step with work starts its account afresh
+                self._in_flight = 0
+        return worked
+
+    def _account_step(self, sp) -> None:
+        """The step's account (`step_account`), three ways: as counts on
+        its `serve.step` span, beside the device plane under a profile;
+        as one `phase=serve_host` event (category `serve_host`, `secs` the
+        starved seconds); and in `stats`. A slow step says so."""
+        t0, wall = sp.so_far()
+        acct = step_account(self._leaves, t0, wall, self._in_flight)
+        self._in_flight = acct["in_flight"]
+        starved = acct["starved_s"]
+        sp.set(wall_us=int(wall * 1e6), starved_us=int(starved * 1e6))
+        st = self.stats
+        st["step_wall_s"] += wall
+        st["starved_s"] += starved
+        if wall > st["step_wall_max_s"]:
+            st["step_wall_max_s"] = wall
+        self.telemetry.emit("phase", phase="serve_host",
+                            category="serve_host", secs=starved,
+                            engine=self.engine_id)
+        self._walls.append(wall)
+        waits = acct["waits"]
+        held = None  # the part furthest over its limit
+        for kind, secs, n in (
+                ("host", wall - sum(w[1] for w in waits), 1), *waits):
+            seen = self._recent[kind]
+            if secs > SLOW_STEP_S and len(seen) >= SLOW_STEP_AFTER:
+                limit = max(SLOW_STEP_S,
+                            SLOW_STEP_X * statistics.median(seen) * n)
+                if secs > limit and (held is None
+                                     or secs / limit > held[1] / held[2]):
+                    held = (kind, secs, limit, n)
+            seen.append(secs / n)
+        if held is not None:
+            self._slow_step(acct, *held)
+
+    def _slow_step(self, acct: dict, kind: str, secs: float, limit: float,
+                   cleared: int) -> None:
+        """One `serve_slow_step` event with the whole account, the part
+        that was over its limit (`held_by`: a wait, for the `held_for`
+        dispatches it cleared, or `host`, the wall less the waits) and
+        what else could hold a step (a compile, a collection, the load),
+        and one WARNING line: the leaf that held the seconds says where to
+        look (a wait: device or runtime; a build or emit: the host;
+        `unspanned`: the code between spans; none: the caller)."""
+        self.stats["slow_steps"] += 1
+        n = self.stats["slow_steps"]
+        collections = [[g["collections"] for g in stats]
+                       for stats in (self._gc_before, gc.get_stats())]
+        active = sum(s is not None for s in self.sched.slots)
+        self.telemetry.emit(
+            "serve_slow_step", held_by=kind, held_s=round(secs, 6),
+            limit_s=round(limit, 6), held_for=cleared,
+            wall_s=round(acct["wall_s"], 6),
+            starved_s=round(acct["starved_s"], 6),
+            unspanned_ms=round(acct["unspanned_s"] * 1e3, 3),
+            leaves_ms=_ms(acct["leaves"]),
+            starved_by_ms=_ms(acct["starved_by"]),
+            compile_s=round(self._step_compile_s, 6),
+            gc_before=collections[0], gc_after=collections[1],
+            active=active, queued=len(self.sched.queue),
+            engine=self.engine_id)
+        if n > SLOW_STEP_LOGS:
+            return
+        name, longest = max({**acct["leaves"],
+                             "unspanned": acct["unspanned_s"]}.items(),
+                            key=lambda kv: kv[1])
+        log.warning(
+            "serve engine=%d slow step: %s %.3f s (limit %.3f for %d "
+            "dispatched) of wall %.3f s, longest leaf %s %.3f s, starved "
+            "%.3f s, compile %.3f s, collections %s -> %s, active %d, "
+            "queued %d%s", self.engine_id,
+            kind, secs, limit, cleared, acct["wall_s"], name, longest,
+            acct["starved_s"], self._step_compile_s, *collections, active,
+            len(self.sched.queue),
+            "; further slow steps are counted, not logged"
+            if n == SLOW_STEP_LOGS else "")
 
     def _step(self, now: float) -> bool:
         reg = self.telemetry.registry
@@ -984,13 +1175,6 @@ class ServeEngine:
         self.stats["decode_steps"] += 1
         self.stats["occupancy_sum"] += len(active) / self.num_slots
         self.stats["output_tokens"] += n_tokens
-        reg.gauge("serve/slot_occupancy").set(
-            len(active) / self.num_slots)
-        reg.gauge("serve/pool_utilization").set(
-            self.pool.in_use / self.num_blocks)
-        if self.mixed:
-            reg.gauge("serve/window_pool_utilization").set(
-                self.wpool.in_use / self.num_window_blocks)
         return True
 
     def _kind_blocks(self, active, kv_blocks: int) -> dict:
@@ -1101,6 +1285,15 @@ class ServeEngine:
             "prefill_chunks": self.stats["prefill_chunks"],
             "decode_stall_ticks_max":
                 self.stats["decode_stall_ticks_max"],
+            # of the steps' wall, the share with nothing enqueued on the
+            # device (`step_account`)
+            "device_starved_share": (
+                round(self.stats["starved_s"] / self.stats["step_wall_s"], 4)
+                if self.stats["step_wall_s"] else None),
+            "step_wall_p50_s": (round(statistics.median(self._walls), 6)
+                                if self._walls else None),
+            "step_wall_max_s": round(self.stats["step_wall_max_s"], 6),
+            "slow_steps": self.stats["slow_steps"],
             "speculator": self.scfg.speculator,
             "draft_len": self.draft_len,
             "draft_tokens": drafted,

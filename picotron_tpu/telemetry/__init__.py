@@ -32,12 +32,20 @@ JSONL schema (one object per line; `ts` = time.time()):
   {"ts", "kind": "phase", "phase", "step", "secs", "category"}
       # serve: phase queue_wait | prefill | decode | handoff with id / ids,
       # tokens; prefill carries `waited` (whether `secs` includes the
-      # host's wait for the device or only the enqueue)
+      # host's wait for the device or only the enqueue); phase serve_host,
+      # one an engine step with device work: `secs` the step's seconds
+      # with nothing enqueued on the device (serve/engine.py step_account)
   {"ts", "kind": "step",  "step", "loss", "tokens_per_sec",
    "tokens_per_sec_per_chip", "mfu", "trained_tokens", "memory_gb", ...}
   {"ts", "kind": "eval",  "step", "val_loss"}
   {"ts", "kind": <event>, ...}        # retry / chaos / guard / preempt /
                                       # recompile / watchdog_timeout ...
+  {"ts", "kind": "serve_slow_step", "held_by", "held_s", "limit_s",
+   "held_for", "wall_s", "starved_s", "unspanned_ms", "leaves_ms", "starved_by_ms",
+   "compile_s", "gc_before", "gc_after", "active", "queued", "engine"}
+      # an engine step one of whose parts (a wait, by the `held_for`
+      # dispatches it cleared, or `host`: the rest of the wall) is far
+      # over the median of its own kind
   {"ts", "kind": "run_summary", "goodput": {...}, "metrics": {...}}
 """
 
@@ -204,10 +212,13 @@ class Telemetry:
                 and isinstance(secs, (int, float)):
             self.sentinel.observe_phase(fields.get("phase") or "", secs)
 
-    def span(self, name: str, tid: int = TID_TRAIN, **counts) -> Span:
+    def span(self, name: str, tid: int = TID_TRAIN, into=None,
+             **counts) -> Span:
         """A region of host code as a `TraceAnnotation` and, with a tracer
-        installed, a span on lane `tid` (telemetry/spans.py)."""
-        return Span(name, self.tracer, tid, **counts)
+        installed, a span on lane `tid`; `into` is the caller's own list,
+        which gets `(name, start, secs)` when the region ends
+        (telemetry/spans.py)."""
+        return Span(name, self.tracer, tid, into, **counts)
 
     def record_wait(self, name: str, wait_s: float, tid: int = TID_TRAIN,
                     **counts) -> None:

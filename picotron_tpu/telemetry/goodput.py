@@ -40,6 +40,15 @@ Every timed second of the run is booked to exactly one category:
                      boundary. Transport overhead, NOT goodput — the
                      number the cost model's price_kv_handoff predicts
                      and the decode pool must never wait on.
+- ``serve_host``   — serving only (serve/engine.py `step_account`): the
+                     seconds of an engine step with work pending and
+                     nothing enqueued on the device, between one
+                     dispatch's wait and the next dispatch: admission,
+                     input building, the emit loop, the code between
+                     spans. The device is idle in them by construction,
+                     so the ledger of a serving stream holds what the
+                     device was fed (``prefill`` + ``decode``) beside
+                     what it waited for. Badput.
 - ``shed``         — serving only (serve/fleet.py deadline admission):
                      queue seconds burned by requests REJECTED because
                      their wait already exceeded their deadline. Pure
@@ -92,8 +101,10 @@ CATEGORIES = (
     # serving (picotron_tpu/serve): device time in the two jitted
     # programs (goodput), the admission-latency badput, the
     # disaggregated engines' cross-pool KV transfer (badput: transport),
-    # and queue seconds thrown away by deadline load shedding (badput)
-    "prefill", "decode", "queue_wait", "handoff", "shed",
+    # queue seconds thrown away by deadline load shedding (badput), and
+    # an engine step's seconds with nothing enqueued on the device
+    # (badput: the host's share of the serving loop)
+    "prefill", "decode", "queue_wait", "handoff", "shed", "serve_host",
 )
 
 

@@ -863,3 +863,315 @@ def test_latent_engine_retire_cancel_shed_leak_no_block():
     for rid, toks in done.items():
         assert toks == want[rid], rid
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the step's own account (serve/engine.py step_account, PR 37)
+# ---------------------------------------------------------------------------
+
+MS = 1e-3
+
+
+def _leaves(t0, *spec):
+    """Leaves from (name, gap before it in ms, its length in ms): the
+    shape `Span(into=...)` appends."""
+    out, at = [], t0
+    for name, gap, ms in spec:
+        out.append((name, at + gap * MS, ms * MS))
+        at += (gap + ms) * MS
+    return out, at - t0
+
+
+ACCOUNT_CASES = {
+    # name: (in flight at the start, leaves, tail ms, starved ms by leaf,
+    #        ms with work enqueued: from the start of a dispatch or handoff
+    #        to the end of the next wait, added up by hand,
+    #        the dispatches each wait cleared, in flight at the end)
+    "decode_only": (
+        0,
+        [("serve.admit", 0.5, 1), ("serve.decode.build", 0, 2),
+         ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 20),
+         ("serve.decode.emit", 0, 4)],
+        1.5,
+        {"unspanned": 2.0, "serve.admit": 1, "serve.decode.build": 2,
+         "serve.decode.emit": 4}, 3 + 20, [1], 0),
+    # the decode's build runs behind a prefill nobody waited for: fed
+    "prefill_unwaited_then_decode": (
+        0,
+        [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.decode.build", 1, 2),
+         ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 30),
+         ("serve.decode.emit", 0.5, 4)],
+        0,
+        {"serve.admit": 1, "serve.prefill.build": 2, "unspanned": 0.5,
+         "serve.decode.emit": 4}, 3 + 1 + 2 + 3 + 30, [2], 0),
+    # the prefill was waited for: the device is idle under the build
+    "prefill_waited_then_decode": (
+        0,
+        [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 10),
+         ("serve.decode.build", 1, 2), ("serve.decode.dispatch", 0, 3),
+         ("serve.decode.wait", 0, 20), ("serve.decode.emit", 0, 4)],
+        0,
+        {"serve.admit": 1, "serve.prefill.build": 2, "unspanned": 1,
+         "serve.decode.build": 2, "serve.decode.emit": 4},
+        3 + 10 + 3 + 20, [1, 1], 0),
+    "ends_in_flight": (
+        0,
+        [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.decode.build", 0, 1)],
+        2,
+        {"serve.admit": 1, "serve.prefill.build": 2}, 3 + 1 + 2, [], 1),
+    # ... and the third step after it: fed until its first wait, which
+    # clears the three chunks before it and its own
+    "starts_in_flight": (
+        3,
+        [("serve.admit", 1, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 9),
+         ("serve.decode.build", 0, 2)],
+        1,
+        {"serve.decode.build": 2, "unspanned": 1}, 1 + 1 + 2 + 3 + 9, [4], 0),
+    "handoff": (
+        0,
+        [("serve.admit", 0, 1), ("serve.handoff", 0, 2),
+         ("serve.handoff", 0.5, 2), ("serve.decode.build", 0, 1),
+         ("serve.decode.dispatch", 0, 1), ("serve.decode.wait", 0, 8),
+         ("serve.decode.emit", 0, 2)],
+        0,
+        {"serve.admit": 1, "serve.decode.emit": 2},
+        2 + 0.5 + 2 + 1 + 1 + 8, [3], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ACCOUNT_CASES))
+def test_step_account_on_hand_made_leaves(case):
+    from picotron_tpu.serve.engine import step_account
+
+    in_flight, spec, tail, want, fed_ms, cleared, ends = ACCOUNT_CASES[case]
+    t0 = 1000.0
+    leaves, spanned_to = _leaves(t0, *spec)
+    wall = spanned_to + tail * MS
+    a = step_account(leaves, t0, wall, in_flight)
+    assert a["wall_s"] == wall and a["in_flight"] == ends
+    assert [n for _, _, n in a["waits"]] == cleared
+    assert all(secs == a["leaves"][name] for name, secs, _ in a["waits"])
+    assert {k: round(v / MS, 6) for k, v in a["starved_by"].items()} == {
+        k: float(v) for k, v in want.items()}
+    assert a["starved_s"] == pytest.approx(sum(want.values()) * MS, abs=1e-9)
+    # starved + in flight = wall, with no clamp to make it so
+    assert a["starved_s"] + fed_ms * MS == pytest.approx(wall, abs=1e-9)
+    # each leaf's seconds, and the wall less the leaves
+    assert sum(a["leaves"].values()) == pytest.approx(
+        sum(ms for _, _, ms in spec) * MS, abs=1e-9)
+    assert a["unspanned_s"] == pytest.approx(
+        (sum(gap for _, gap, _ in spec) + tail) * MS, abs=1e-9)
+    assert set(a["leaves"]) == {name for name, _, _ in spec}
+
+
+class _Events:
+    def __init__(self):
+        self.events = []
+
+    def emit(self, e):
+        self.events.append(e)
+
+    def close(self):
+        pass
+
+
+@pytest.mark.parametrize("disagg", [False, True])
+def test_serve_host_events_add_up_to_the_stats(tiny, requests5, disagg):
+    """Every step with device work emits one `phase=serve_host` event whose
+    `secs` are the step's starved seconds: they sum to `stats["starved_s"]`
+    and to the ledger's `serve_host`, and the summary carries the share."""
+    from picotron_tpu.serve import DisaggServeEngine
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    tel = Telemetry(sinks=[cap])
+    cls = DisaggServeEngine if disagg else ServeEngine
+    eng = cls(params, cfg, scfg(disagg=disagg), telemetry=tel)
+    res = eng.run(requests5)
+    assert len(res) == len(requests5)
+    host = [e for e in cap.events
+            if e["kind"] == "phase" and e.get("phase") == "serve_host"]
+    st = eng.stats
+    assert len(host) == len(eng._walls) > 0  # one a step with device work
+    assert all(e["category"] == "serve_host" and e["secs"] >= 0.0
+               and set(e) <= {"ts", "kind", "phase", "category", "secs", "engine"}
+               for e in host)
+    tol = 1e-6 * len(host)  # an event's `secs` are rounded to the microsecond
+    assert sum(e["secs"] for e in host) == pytest.approx(st["starved_s"], abs=tol)
+    assert tel.ledger.seconds["serve_host"] == pytest.approx(st["starved_s"])
+    assert 0.0 < st["starved_s"] <= st["step_wall_s"]
+    assert st["step_wall_max_s"] == max(eng._walls)
+    assert sum(eng._walls) == pytest.approx(st["step_wall_s"])
+    assert st["slow_steps"] == 0 and "steps" not in st
+    s = eng.summary
+    assert s["device_starved_share"] == pytest.approx(
+        st["starved_s"] / st["step_wall_s"], abs=1e-4)
+    assert 0.0 < s["step_wall_p50_s"] <= s["step_wall_max_s"]
+    if disagg:
+        assert st["handoffs"] > 0
+    # the gauges nothing read are gone; the summary's own numbers stay
+    assert not any(k.startswith("serve/") for k in
+                   tel.registry.snapshot()["gauges"])
+    assert 0 < s["slot_occupancy"] <= 1 and 0 < s["pool_peak_utilization"] <= 1
+    eng.close()
+    tel.close()
+
+
+def test_slow_step_makes_one_event_and_one_log_line(tiny, monkeypatch, caplog):
+    """A `device_get` that sleeps once: exactly one `serve_slow_step` with
+    the whole account, and one WARNING naming the leaf that held it."""
+    import time as _time
+
+    from picotron_tpu.serve import engine as engine_mod
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    eng = ServeEngine(params, cfg, scfg(decode_interval=1, max_model_len=64),
+                      telemetry=Telemetry(sinks=[cap]))
+    eng.submit([3, 1, 4, 1, 5], 40)
+    for _ in range(engine_mod.SLOW_STEP_AFTER + 4):
+        eng.step(0.0)
+    assert len(eng._recent["serve.decode.wait"]) >= engine_mod.SLOW_STEP_AFTER
+    assert eng.stats["slow_steps"] == 0 and eng.sched.decode_ready()
+    real, armed = engine_mod.jax.device_get, [True]
+
+    def sleepy(x):
+        if armed.pop() if armed else False:
+            _time.sleep(engine_mod.SLOW_STEP_S + 0.15)
+        return real(x)
+
+    monkeypatch.setattr(engine_mod.jax, "device_get", sleepy)
+    with caplog.at_level("WARNING", logger="picotron_tpu.serve"):
+        for _ in range(6):
+            eng.step(0.0)
+    slow = [e for e in cap.events if e["kind"] == "serve_slow_step"]
+    assert len(slow) == 1 and eng.stats["slow_steps"] == 1
+    (e,) = slow
+    assert e["held_by"] == "serve.decode.wait"
+    assert e["wall_s"] > e["held_s"] > e["limit_s"] >= engine_mod.SLOW_STEP_S
+    assert max(e["leaves_ms"], key=e["leaves_ms"].get) == "serve.decode.wait"
+    assert e["leaves_ms"]["serve.decode.wait"] >= engine_mod.SLOW_STEP_S * 1e3
+    # a wait is in flight, not starved
+    assert e["starved_s"] < 0.1 and "serve.decode.wait" not in e["starved_by_ms"]
+    assert e["compile_s"] == 0.0 and e["active"] == 1 and e["queued"] == 0
+    assert len(e["gc_before"]) == len(e["gc_after"]) == 3
+    assert all(b <= a for b, a in zip(e["gc_before"], e["gc_after"]))
+    records = [r for r in caplog.records if r.name == "picotron_tpu.serve"]
+    assert len(records) == 1 and records[0].levelname == "WARNING"
+    assert "longest leaf serve.decode.wait" in records[0].getMessage()
+    assert eng.stats["step_wall_max_s"] >= e["wall_s"] - 1e-6
+    eng.close()
+
+
+@pytest.mark.parametrize("usual_s, prompt_len, fires", [
+    (0.2, 3, False), (0.02, 3, True), (0.02, 11, False), (0.005, 11, True)])
+def test_a_wait_is_slow_among_its_own_kind(tiny, monkeypatch, caplog,
+                                           usual_s, prompt_len, fires):
+    """A prefill wait of 0.3 s is a stall where a prefill dispatch takes 20
+    ms, and the program's own time where it takes 0.2 s or where the wait
+    clears three chunks the host ran ahead of, whatever the decode steps
+    around it take: no event, no log line, nothing counted."""
+    import time as _time
+
+    from picotron_tpu.serve import engine as engine_mod
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    eng = ServeEngine(params, cfg, scfg(), telemetry=Telemetry(sinks=[cap]))
+    eng._recent["serve.prefill.wait"].extend(
+        [usual_s] * engine_mod.SLOW_STEP_AFTER)
+    real, armed = engine_mod.jax.device_get, [True]
+
+    def sleepy(x):
+        if armed.pop() if armed else False:
+            _time.sleep(engine_mod.SLOW_STEP_S + 0.05)
+        return real(x)
+
+    monkeypatch.setattr(engine_mod.jax, "device_get", sleepy)
+    # the host waits where the prompt ends: in its first chunk, or its third
+    eng.submit(list(range(1, prompt_len + 1)), 2)
+    with caplog.at_level("WARNING", logger="picotron_tpu.serve"):
+        while armed:
+            assert eng.step(0.0)
+    assert eng.stats["step_wall_max_s"] > engine_mod.SLOW_STEP_S
+    assert eng._recent["serve.prefill.wait"][-1] > (
+        engine_mod.SLOW_STEP_S / -(-prompt_len // 4))
+    slow = [e for e in cap.events if e["kind"] == "serve_slow_step"]
+    assert len(slow) == eng.stats["slow_steps"] == len(caplog.records) == fires
+    if fires:
+        assert slow[0]["held_by"] == "serve.prefill.wait"
+        assert slow[0]["limit_s"] == engine_mod.SLOW_STEP_S
+        assert slow[0]["held_for"] == -(-prompt_len // 4)
+        assert f"for {slow[0]['held_for']} dispatched" in caplog.messages[0]
+    eng.close()
+
+
+def test_a_slow_host_is_told_from_a_slow_wait(tiny, monkeypatch, caplog):
+    """The wall less the waits is judged among the same of other steps: an
+    admission that sleeps is `held_by` the host, under the leaf it ran in."""
+    import time as _time
+
+    from picotron_tpu.serve import engine as engine_mod
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    eng = ServeEngine(params, cfg, scfg(decode_interval=1, max_model_len=64),
+                      telemetry=Telemetry(sinks=[cap]))
+    eng.submit([3, 1, 4, 1, 5], 40)
+    for _ in range(engine_mod.SLOW_STEP_AFTER + 2):
+        eng.step(0.0)
+    real = eng.sched.admit
+
+    def sleepy(now):
+        _time.sleep(engine_mod.SLOW_STEP_S + 0.05)
+        monkeypatch.setattr(eng.sched, "admit", real)
+        return real(now)
+
+    monkeypatch.setattr(eng.sched, "admit", sleepy)
+    with caplog.at_level("WARNING", logger="picotron_tpu.serve"):
+        for _ in range(3):
+            eng.step(0.0)
+    (e,) = [e for e in cap.events if e["kind"] == "serve_slow_step"]
+    assert e["held_by"] == "host" and e["held_s"] > e["limit_s"]
+    assert e["starved_by_ms"]["serve.admit"] >= engine_mod.SLOW_STEP_S * 1e3
+    (record,) = caplog.records
+    assert "slow step: host" in record.getMessage()
+    assert "longest leaf serve.admit" in record.getMessage()
+    eng.close()
+
+
+def test_an_idle_poll_is_no_step_and_ends_what_was_in_flight(tiny):
+    """A prefill dispatch nobody waited for, then its request cancelled: the
+    polls that find no work are not accounted, and the next request's admit
+    and build are starved, not fed by a dispatch long since done."""
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    eng = ServeEngine(params, cfg, scfg(), telemetry=Telemetry(sinks=[cap]))
+    rid = eng.submit(list(range(1, 12)), 2)  # three chunks
+    assert eng.step(0.0) and eng._in_flight  # ends on the un-waited dispatch
+    assert eng.cancel(rid)
+
+    def host_events():
+        return [e for e in cap.events if e.get("phase") == "serve_host"]
+
+    n, walls = len(host_events()), eng.stats["step_wall_s"]
+    for _ in range(3):
+        assert not eng.step(0.0)
+    assert not eng._in_flight
+    assert len(host_events()) == n and eng.stats["step_wall_s"] == walls
+    eng.submit([3, 1, 4], 2)
+    assert eng.step(0.0)
+    (last,) = host_events()[n:]
+    assert last["secs"] > 0.0  # its admit and build were starved
+    assert eng.stats["starved_s"] <= eng.stats["step_wall_s"]
+    eng.close()

@@ -143,6 +143,17 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
     for (_, _, hi, _), (_, lo, _, _) in zip(leaves, leaves[1:]):
         assert hi <= lo
 
+    # a step with device work carries its own account (serve/engine.py
+    # `step_account`): what the wall was and what of it the device starved
+    for _, lo, hi, c in steps:
+        assert 0 <= c["starved_us"] <= c["wall_us"] <= (hi - lo) / 1e3 + 1
+        assert set(c) == {"wall_us", "starved_us"}
+    host = [e for e in events
+            if e.get("kind") == "phase" and e.get("phase") == "serve_host"]
+    assert len(host) == len(steps)
+    assert all(abs(e["secs"] * 1e6 - c["starved_us"]) <= 1
+               for e, (*_, c) in zip(host, steps))
+
     # counts, at the boundary of the work they count
     # (the prefill batch is compacted: `capacity` is the rung's rows, not SLOTS)
     disp = [a[3] for a in anns if a[0] == "serve.prefill.dispatch"]
@@ -215,6 +226,38 @@ def test_span_without_profile_or_tracer_records_nothing(model):
         pass
     assert sp2.secs >= 0.0
     tel.close()
+
+
+def test_span_into_appends_once_with_its_own_secs():
+    """`into` is the caller's list: one `(name, start, secs)` a span, when
+    it ends, from the clock reads the span takes anyway."""
+    c = Clock()
+    tr = SpanTracer(clock=c)
+    mine: list = []
+    with Span("serve.step", tr, TID_SERVE):  # no list: appends nowhere
+        c.t += 0.001
+        with Span("serve.admit", tr, TID_SERVE, mine, queued=2) as a:
+            c.t += 0.002
+            assert mine == []  # not before it ends
+        c.t += 0.003
+        with Span("serve.decode.wait", tr, TID_SERVE, into=mine) as w:
+            c.t += 0.004
+    assert mine == [("serve.admit", 10.001, a.secs),
+                    ("serve.decode.wait", 10.006, w.secs)]
+    assert (a.secs, w.secs) == (pytest.approx(0.002), pytest.approx(0.004))
+    assert a.counts == {"queued": 2}  # `into` is no count
+    # the tracer's copy has the same start and duration
+    spans = {e["name"]: e for e in tr.since(0)}
+    assert spans["serve.admit"]["dur"] == pytest.approx(a.secs * 1e6)
+    assert "into" not in spans["serve.admit"].get("args", {})
+    # while it runs a span says where it is, on the same clock
+    with Span("serve.step", tr, TID_SERVE) as sp:
+        c.t += 0.005
+        assert sp.so_far() == (pytest.approx(10.010), pytest.approx(0.005))
+    # without a tracer the clock is perf_counter, and the list still fills
+    with Span("x", into=mine) as x:
+        pass
+    assert mine[-1][0] == "x" and mine[-1][2] == x.secs and len(mine) == 3
 
 
 class Clock:

@@ -369,6 +369,60 @@ def test_facade_pp_bubble_jsonl_reproduces_ledger(tmp_path):
                 + tel.ledger.seconds["compute"]), rel=1e-6)
 
 
+def test_serve_host_category_reproduces_and_leaves_training_alone(tmp_path):
+    """`serve_host` (serve/engine.py: an engine step's seconds with nothing
+    enqueued on the device) is a ledger category of its own: badput beside
+    prefill + decode, the JSONL's (category, secs) pairs still reproduce the
+    ledger with it, the report shows the device-fed share and lists a slow
+    step, and a training stream's goodput % does not know it exists."""
+    report = load_report()
+    p = str(tmp_path / "serve.jsonl")
+    tel = Telemetry(sinks=[JsonlSink(p)])
+    tel.emit("phase", phase="queue_wait", category="queue_wait", secs=0.5, id=1)
+    tel.emit("phase", phase="prefill", category="prefill", secs=1.0)
+    tel.emit("phase", phase="decode", category="decode", secs=2.0)
+    for secs in (0.25, 0.75):
+        tel.emit("phase", phase="serve_host", category="serve_host",
+                 secs=secs, engine=0)
+    tel.emit("serve_slow_step", held_by="serve.decode.wait", held_s=2.4,
+             limit_s=0.25, wall_s=2.5, starved_s=0.01, unspanned_ms=1.0,
+             engine=0,
+             leaves_ms={"serve.decode.wait": 2400.0, "serve.decode.emit": 9.0})
+    tel.emit("serve_request", id=1, output_tokens=4, ttft_s=0.1,
+             queue_wait_s=0.5)
+    tel.close()
+    assert tel.ledger.seconds["serve_host"] == 1.0
+    assert tel.ledger.goodput_seconds == 3.0  # serve_host is badput
+    rows = [json.loads(ln) for ln in open(p)]
+    sums: dict = {}
+    for r in rows:
+        if "category" in r and "secs" in r:
+            sums[r["category"]] = sums.get(r["category"], 0.0) + r["secs"]
+    assert sums == pytest.approx(tel.ledger.seconds)
+    s = report.summarize(rows)
+    assert s["categories"]["serve_host"] == 1.0
+    assert s["goodput_pct"] == pytest.approx(100 * 3.0 / 4.5, abs=0.01)
+    sv = s["serving"]
+    assert sv["serve_host_s"] == 1.0 and sv["device_fed_share"] == 0.75
+    assert [(st["wall_s"], st["longest_leaf"]) for st in sv["slow_steps"]] == [
+        (2.5, ("serve.decode.wait", 2400.0))]
+    text = report.render(s)
+    assert "device fed share 0.75" in text
+    assert "serve.decode.wait 2.4 s (limit 0.25) of wall 2.5 s" in text
+    assert "longest leaf serve.decode.wait 2400.0 ms" in text
+
+    # a training stream: the same goodput % as before the category existed
+    tel = Telemetry(sinks=[])
+    tel.ledger.book_phase("step", 6.0, step=1)
+    tel.ledger.book_phase("data", 2.0, step=1)
+    assert tel.ledger.summary()["goodput_pct"] == 75.0
+    assert "serve_host" not in tel.ledger.seconds
+    tel.ledger.book("serve_host", 1.0)  # a known category, not `other`
+    assert tel.ledger.seconds == {"compute": 6.0, "data_wait": 2.0,
+                                  "serve_host": 1.0}
+    tel.close()
+
+
 def test_facade_observe_section_feeds_stage_histograms():
     tel = Telemetry(sinks=[])
     try:

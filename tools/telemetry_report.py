@@ -89,6 +89,7 @@ def summarize(events: list[dict]) -> dict:
     eval_rows: list[dict] = []
     serve_reqs: list[dict] = []
     serve_summary: dict | None = None
+    slow_steps: list[dict] = []
     run_summary: dict | None = None
     sentinel_alerts: list[dict] = []
     ts = [e["ts"] for e in events if isinstance(e.get("ts"), (int, float))]
@@ -120,6 +121,8 @@ def summarize(events: list[dict]) -> dict:
             serve_reqs.append(e)
         elif kind == "serve_summary":
             serve_summary = e  # last wins (one per engine run)
+        elif kind == "serve_slow_step":
+            slow_steps.append(e)
         elif kind == "run_summary":
             run_summary = e  # last wins (one per process lifetime)
         elif kind == "sentinel_alert":
@@ -173,6 +176,7 @@ def summarize(events: list[dict]) -> dict:
         out["training"]["final_val_loss"] = eval_rows[-1].get("val_loss")
     if serve_reqs or serve_summary:
         out["serving"] = serving_view(serve_reqs, serve_summary, counts)
+        out["serving"].update(host_view(categories, slow_steps))
     # Elastic-resize row: the resize category already sums into the table
     # above (the phase event carries its resolved category); this pairs
     # the seconds with the elastic_resize events so a shrink/grow saga is
@@ -309,6 +313,35 @@ def serving_view(reqs: list[dict], summary: dict | None,
     return view
 
 
+def host_view(categories: dict[str, float],
+              slow_steps: list[dict]) -> dict:
+    """The serving loop's own account (serve/engine.py `step_account`):
+    the steps' seconds with nothing enqueued on the device (category
+    `serve_host`), the share of the rest that the device was fed
+    (`prefill` + `decode` over those plus `serve_host`), and each
+    `serve_slow_step` with the part that was over its limit (`held_by`)
+    and the leaf that held most of it."""
+    view: dict = {}
+    host = categories.get("serve_host")
+    if host is not None:
+        fed = categories.get("prefill", 0.0) + categories.get("decode", 0.0)
+        view["serve_host_s"] = round(host, 4)
+        if fed + host > 0:
+            view["device_fed_share"] = round(fed / (fed + host), 4)
+    if slow_steps:
+        view["slow_steps"] = [
+            {"ts": e.get("ts"), "engine": e.get("engine"),
+             "wall_s": e.get("wall_s"), "starved_s": e.get("starved_s"),
+             "held_by": e.get("held_by"), "held_s": e.get("held_s"),
+             "limit_s": e.get("limit_s"),
+             "longest_leaf": max(
+                 {**(e.get("leaves_ms") or {}),
+                  "unspanned": e.get("unspanned_ms") or 0.0}.items(),
+                 key=lambda kv: kv[1])}
+            for e in slow_steps]
+    return view
+
+
 def comm_row(events: list[dict], config_path: str,
              generation: str) -> dict:
     """Predicted vs measured per-step communication time: the ICI cost
@@ -433,6 +466,18 @@ def render(s: dict, markdown: bool = False) -> str:
                 f"  TPOT p50 {pair('tpot_p50_ms')} ms p95 "
                 f"{pair('tpot_p95_ms')} ms | max decode stall "
                 f"{pair('decode_stall_ticks_max')} ticks")
+        if "serve_host_s" in sv:
+            lines.append(
+                f"  host: {pair('serve_host_s')} s of the steps with "
+                f"nothing enqueued on the device | device fed share "
+                f"{pair('device_fed_share')}")
+        for st in sv.get("slow_steps", []):
+            leaf, ms = st["longest_leaf"]
+            lines.append(
+                f"  slow step: engine {st['engine']} {st['held_by']} "
+                f"{st['held_s']} s (limit {st['limit_s']}) of wall "
+                f"{st['wall_s']} s (starved {st['starved_s']} s), longest "
+                f"leaf {leaf} {ms} ms")
         if "handoffs" in sv or "prefill_slot_occupancy" in sv:
             lines.append(
                 f"  disagg: prefill occupancy "
