@@ -16,9 +16,12 @@ pp_communications.py). The mapping:
 
 Both engines share one stage unit (`_make_stage_fn`): at a given tick, stage
 s applies its layer block to microbatch m, where stage 0 ingests `embed(m)`
-(masked-uniform) and the last stage scores m against the targets via a
-collective-free `lax.cond` branch (the head matmul runs ONLY on the last
-stage — see _make_stage_fn).
+(masked-uniform) and the last stage scores m against the targets in a
+`lax.cond` branch by stage (the head matmul runs ONLY on the last stage —
+see _make_stage_fn, which also states what such a branch may hold). AFAB
+differentiates through the unit, scoring and all; 1F1B differentiates it in
+its backward unit and runs it a second time, WITHOUT the scoring
+(`score=False`), only where a later stage needs the output.
 
 **"afab"** (all-forward-all-backward, ref: pipeline_parallel.py:77-118):
 one `lax.scan` over n_micro + pp - 1 ticks; at tick t stage s forwards
@@ -34,7 +37,9 @@ synchronous schedule-table scan with *manual* VJP — no AD through the scan.
 Microbatch m's forward runs at stage s on tick m + s; its backward at tick
 m + 2(pp-1) - s — each steady-state tick executes one active forward AND
 one active backward per stage, finishing in n_micro + 2(pp-1) ticks (see
-pipeline_1f1b_grads for the schedule/memory analysis). Activation
+pipeline_1f1b_grads for the schedule/memory analysis). On the last stage the
+two are the same microbatch and the forward has no consumer, so that stage's
+tick is the backward unit alone (its vjp runs the forward once). Activation
 cotangents ride a reverse ppermute; parameter gradients accumulate in the
 scan carry; live boundary inputs sit in a min(n_micro, 2(pp-1))-slot ring,
 *independent of n_micro* (AFAB's live set grows with n_micro). 1f1b is the
@@ -105,30 +110,49 @@ def _boundary_axes(ctx) -> tuple:
 def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
     """One stage-forward unit, shared by both engines.
 
-    Returns stage_fn(params, x_buf, m_idx, valid) ->
+    Returns stage_fn(params, x_buf, m_idx, valid, score=True) ->
     ((y, nll_sum), (count, dropw)): stage 0 consumes embed(ids[m_idx])
     (zero-masked when not `valid`), other stages consume the rotated-in
     activation `x_buf`; the last stage's nll_sum scores microbatch m_idx.
-    Differentiable in params and x_buf ((count, dropw) is aux).
+    Differentiable in params and x_buf ((count, dropw) is aux). With the
+    static `score=False` it is embed -> run_layers -> y and returns y alone:
+    1F1B's forward unit, which feeds the next stage and nothing else.
 
     The vocab-head scoring is gated with `lax.cond` on the stage index, not
     masked: a masked-uniform program would pay the full [B*S, H] x [H, V/tp]
     head matmul (and the fp32 exp over the logits) on EVERY stage every tick
     — at pp=4, tp=1 that is ~pp x redundant head FLOPs riding every tick
     (VERDICT r2 weak #2; the reference runs the head only on the last stage,
-    ref: pipeline_parallel.py:53-63). Constraint: the branches must contain
-    no cross-device collectives — a collective whose replica group spans
+    ref: pipeline_parallel.py:53-63).
+
+    The rule for a branch taken by stage: NO COLLECTIVE OVER 'pp' IN IT,
+    AND NO PPERMUTE OVER ANY AXIS. A collective whose replica group spans
     devices that take different branches leaves the in-branch members
     waiting on peers that never arrive (observed as a rendezvous deadlock
-    on the CPU backend). Hence the cond computes only this tp shard's local
-    softmax stats (vocab_parallel_ce_local_stats; zero FLOPs off the last
-    stage) and the [B, S]-sized pmax/psum merge runs uniformly on every
-    stage. Under sequence parallelism the scoring needs a seq
-    all_gather that cannot be split that way, so the engines fall back to
-    r2's uniform masked scoring there (no regression — SP already divides
-    the head by tp). The embed stays masked-uniform for the same reason
-    (its psum is the dominant cost and cannot leave a branch cheaply);
-    its gather FLOPs are negligible.
+    on the CPU backend). An all-reduce, all-gather, reduce-scatter or
+    all-to-all over another axis (tp, ep, cp) is allowed: every member of
+    its replica group sits on the same stage and takes the same branch. A
+    ppermute is not: the CPU backend's collective-permute is one
+    rendezvous of EVERY device of the run whatever its pairs (observed,
+    PR 38: dp 2 x pp 2 x cp 2 with ring attention's K/V ring in the 1F1B
+    forward unit's branch, "expected 8 threads to join, 4 arrived"; not
+    probed on the chip).
+    Probe, `tools/pp_branch_probe.py` (a scan whose body has a cond on
+    axis_index('pp') with a psum over 'tp' in one branch only and a
+    ppermute over 'pp' after it, on a (pp 2, tp 2) mesh): right values on
+    four CPU devices and on the four-chip v5e host (PR 38, JAX 0.9.0). The
+    scoring cond's BACKWARD has held such a collective all along: the
+    compiled four-chip step has a tp all-reduce in each branch of
+    `transpose(jvp(head_ce))/cond`. 1F1B's forward unit relies on the rule
+    for a whole layer block (pipeline_1f1b_grads).
+
+    The scoring branch computes this tp shard's local softmax stats
+    (vocab_parallel_ce_local_stats; zero FLOPs off the last stage) and the
+    [B, S]-sized pmax/psum merge runs uniformly on every stage. Under
+    sequence parallelism the engines keep r2's uniform masked scoring (SP
+    already divides the head by tp; the rule above would allow its seq
+    all_gather over tp in the branch — not done, no measured cell runs SP).
+    The embed stays masked-uniform (its gather FLOPs are negligible).
 
     The token count needs no head output (it is just the non-ignored-target
     count) and is computed outside the cond because the MoE aux-loss fold
@@ -137,19 +161,21 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
     dtype = compute_dtype(m)
     gated = ctx.head_ce_local is not None and ctx.seq_shard == 1
 
-    def stage_fn(params, x_buf, m_idx, valid):
+    def stage_fn(params, x_buf, m_idx, valid, score=True):
         mb_ids = lax.dynamic_index_in_dim(ids, m_idx, 0, keepdims=False)
-        mb_tgt = lax.dynamic_index_in_dim(tgt, m_idx, 0, keepdims=False)
         # Zero-mask invalid ingest so garbage never enters the pipe (all
         # bubble compute then runs on zeros, which every op here keeps
         # finite — no NaNs can poison the masked accumulators' grads).
         x0 = embed(params, mb_ids, m, ctx) * valid.astype(dtype)
         x_in = jnp.where(s_idx == 0, x0, x_buf)
         y, aux = run_layers(params["layers"], x_in, m, ctx, cos, sin)
+        if not score:
+            return y
+        mb_tgt = lax.dynamic_index_in_dim(tgt, m_idx, 0, keepdims=False)
         count = jnp.sum(mb_tgt != IGNORE_INDEX)
 
-        # Two rules keep the branches collective-free through the BACKWARD
-        # cond as well (verified against the optimized HLO — violations
+        # Two rules keep collectives over 'pp' out of the BACKWARD cond's
+        # branches as well (verified against the optimized HLO — violations
         # deadlock the CPU runtime's order-matched rendezvous):
         # 1. No lax.pcast inside a branch: pcast-to-varying transposes to a
         #    psum. The neutral branch instead anchors its constants on
@@ -350,6 +376,25 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
     (b == f) and the backward consumes the live x_buf directly, not the
     ring.
 
+    What a tick runs. The *backward unit* is `jax.vjp` of the whole stage
+    unit (layers, and on the last stage the scoring) at microbatch m_b: its
+    forward pass recomputes the stage from the saved boundary input, and its
+    primal outputs are where the engine reads the loss sum, the token count
+    and the MoE drop / load sums — every microbatch has exactly one backward
+    on every stage. The *forward unit* is the layer block alone at
+    microbatch m_f (`score=False`: no head, no merge collectives), and
+    exists to feed the next stage. On the last stage m_f == m_b and nothing
+    consumes y, so the unit sits in a `lax.cond` whose last-stage branch is
+    zeros: that stage computes each microbatch's forward once (a second
+    forward of layers and head there is a third of the tick of the stage
+    that sets the step). The forward unit's branch holds the layers' tp /
+    ep collectives and none over 'pp' — _make_stage_fn's branch rule — and
+    is not differentiated, so that docstring's two backward-branch rules do
+    not apply to it. With cp > 1
+    the layers may hold a ring of ppermutes, which the rule forbids: the
+    unit then runs masked-uniform on every stage (the last stage still
+    scores once).
+
     Grads of pp-replicated params (embedding / final norm / head) come out
     nonzero only on the stage that uses them — pass through
     sync_pp_replicated_grads like the AFAB path's.
@@ -379,16 +424,25 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
                                           keepdims=False)
 
         # ---- forward unit: microbatch m_f advances one stage ----
+        # Layers only, and nothing on the last stage, whose y has no
+        # consumer (docstring) — unless the layers hold a ring over cp,
+        # whose ppermutes may not sit in a branch by stage. Not
+        # differentiated, so the pcast in the zeros branch transposes to
+        # nothing.
         df = t - s_idx
         f_on = (df >= 0) & (df < n_micro)
         m_f = jnp.clip(df, 0, n_micro - 1)
-        (y, contrib), (cnt, dropw) = stage_fn(params, x_buf, m_f, f_on)
-        # contrib pre-masks the CE to the last stage (stage_fn); MoE aux
-        # contributions ride it on every stage, as does this stage's
-        # layers' capacity-drop observability sum.
-        nll_acc = nll_acc + jnp.where(f_on, contrib, 0.0)
-        cnt_acc = cnt_acc + jnp.where(f_on & (s_idx == pp - 1), cnt, 0)
-        drop_acc = drop_acc + jnp.where(f_on, dropw, 0.0)
+
+        def layers_fwd(p, xb):
+            return stage_fn(p, xb, m_f, f_on, score=False)
+
+        if cfg.distributed.cp_size == 1:
+            y = lax.cond(
+                s_idx == pp - 1,
+                lambda p, xb: _cast_varying_like(jnp.zeros_like(xb), xb),
+                layers_fwd, params, x_buf)
+        else:
+            y = layers_fwd(params, x_buf)
         # Save this stage's *input* for the backward recompute. Guard the
         # store: on non-forward ticks m_f aliases a possibly-live slot.
         ring_new = lax.dynamic_update_index_in_dim(
@@ -400,9 +454,17 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         # ---- backward unit: microbatch m_b retreats one stage ----
         # Last stage: b(m) == f(m), the input is this tick's live x_buf.
         x_saved = jnp.where(s_idx == pp - 1, x_buf, x_ring)
-        _, vjp_fn, _ = jax.vjp(
+        (_, contrib), vjp_fn, (cnt, dropw) = jax.vjp(
             lambda p, xb: stage_fn(p, xb, m_b, b_on), params, x_saved,
             has_aux=True)
+        # The loss is read where it is computed: the vjp's primal. Every
+        # microbatch has exactly one backward on every stage. contrib
+        # pre-masks the CE to the last stage (stage_fn); MoE aux
+        # contributions ride it on every stage, as does this stage's
+        # layers' capacity-drop observability sum.
+        nll_acc = nll_acc + jnp.where(b_on, contrib, 0.0)
+        cnt_acc = cnt_acc + jnp.where(b_on & (s_idx == pp - 1), cnt, 0)
+        drop_acc = drop_acc + jnp.where(b_on, dropw, 0.0)
         # Cotangents: g_buf arrived from stage s+1 (zeros at the last stage
         # by ppermute's edge semantics — its y has no downstream consumer);
         # the contrib cotangent is 1 on EVERY stage that ran m_b — contrib

@@ -41,10 +41,28 @@ from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import SCOPES
 
 BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-# matched by collective_share.train's pattern in the four-chip step at one
-# layer a stage, counted on the tree before any scope was added (34
-# psum_invariant, 8 all-reduce, 2 + 2 collective-permute start / done)
-N_COLLECTIVES = 46
+# Names matched by collective_share.train's pattern in the four-chip step at
+# one layer a stage. The pattern also matches the two parameters of a
+# reduction computation named after its psum, so one `psum_invariant.N`
+# all-reduce counts 3 and one that the compiler combined with a neighbour
+# into a tuple `all-reduce.N` counts 1 + 2. Until PR 38: 46 (34 psum_invariant,
+# 8 all-reduce, 2 + 2 collective-permute start / done), counted on the tree
+# before any scope was added. PR 38 took the scoring out of the 1F1B forward
+# unit and put what is left of the unit in a branch the last stage skips. The
+# forward unit's three [1,4096] merge collectives (pmax, two psums) left; they
+# had been combined with the backward unit's, so they leave no name behind,
+# but the backward unit's lone pmax is now `pmax.N`, which the pattern does
+# not match (-1). The embedding's psum and the layers' two (attention, mlp)
+# had each been one tuple all-reduce for the forward unit and the backward
+# unit's forward together; in a branch and outside it they are two
+# `psum_invariant.N` each (+3 names each): 46 - 1 + 9 = 54 (46
+# psum_invariant, 4 all-reduce, 2 + 2 collective-permute). As instructions:
+# 16 all-reduces before, 19 now, of which the last stage runs 16.
+N_COLLECTIVES = 54
+# temp_size_in_bytes of the four-chip step at one layer a stage on the parent
+# of PR 38 (commit 977113c, this installation), whose tick held the forward
+# unit's fp32 [4096,76032] logits (1.25 GB) beside the backward unit's
+PARENT_FOUR_CHIP_TEMP_BYTES = 11_745_135_104
 
 
 @pytest.fixture(scope="module")
@@ -140,13 +158,52 @@ def test_four_chip_step_keeps_its_collectives_names(topo, monkeypatch):
     assert sends and all("pp_boundary" in words(op) for op in sends)
     reduces = [op for n, op, _ in ins if n.startswith("psum_invariant")]
     assert any("tp_reduce" in words(op) for op in reduces)
-    # the AD engine's kernel calls sit inside `attention` (no accepted
-    # metric finds them by name in this cell)
+    # four kernel calls, all inside `attention` (no accepted metric finds
+    # them by name in this cell): the 1F1B forward unit's forward kernel, in
+    # the branch the last stage does not take; the backward unit's forward
+    # kernel (its `jax.vjp` runs the stage's forward: this one is NOT
+    # remat's); dq and dkv under the transpose. `dots_attn` saves the
+    # kernel's output, so remat adds no call of its own.
     kernels = [op for _, op, line in ins if "tpu_custom_call" in line]
     assert len(kernels) == 4 and all("attention" in words(op) for op in kernels)
     found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
     assert found >= {"embed", "attention", "mlp", "head_ce", "optimizer",
                      "pp_boundary", "tp_reduce"}
+
+
+def test_four_chip_last_stage_runs_each_forward_once(topo, monkeypatch):
+    """PR 38: the 1F1B tick's forward unit never scores, and its layer block
+    sits in a branch the last stage does not take (the backward unit's
+    `jax.vjp` runs that stage's forward of the same microbatch). Until then
+    the last stage, which sets the step, ran the layers' forward and the
+    head's forward twice a tick."""
+    comp = compile_step(topo, monkeypatch, "qwen2-7b-6l-tp2pp2", layers=2)
+    text = comp.as_text()
+    ins = instructions(text)
+    kernels = [op for _, op, line in ins if "tpu_custom_call" in line]
+    in_branch = [op for op in kernels if "branch_0_fun" in words(op)]
+    assert len(in_branch) == 1 and "jvp" not in in_branch[0], kernels
+    assert sum("transpose(jvp())" in op for op in kernels) == 2, kernels
+    # one forward matmul of the head a tick (`[4096, 3584] x [3584, 76032]`),
+    # under the backward unit's vjp; until PR 38 the forward unit had its own
+    logits = [op for _, op, line in ins if " convolution(" in line
+              and re.search(r"= bf16\[(1,)?4096,76032\]", line)]
+    assert len(logits) == 1 and "jvp(head_ce)" in logits[0], logits
+    # the forward unit's layer block sits in a branch by stage; it holds the
+    # embedding's and the layers' tensor-parallel all-reduces and nothing
+    # that crosses stages (devices 0, 1 are stage 0; 2, 3 the last)
+    conds = [(line, branch_collectives(text, line)) for _, op, line in ins
+             if " conditional(" in line and op.endswith("closed_call/cond")]
+    assert len(conds) == 1, [line[:200] for line, _ in conds]
+    layers_branch, skip_branch = conds[0][1]
+    assert not skip_branch and len(layers_branch) == 3, conds[0][1]
+    for line in layers_branch:
+        assert " all-reduce(" in line, line
+        assert "replica_groups={{0,1},{2,3}}" in line, line
+    temp = comp.memory_analysis().temp_size_in_bytes
+    print(f"four-chip step: temp_size_in_bytes {temp:,} "
+          f"(parent {PARENT_FOUR_CHIP_TEMP_BYTES:,})")
+    assert temp <= PARENT_FOUR_CHIP_TEMP_BYTES
 
 
 def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):
@@ -297,19 +354,45 @@ def computations(text: str) -> dict:
     return out
 
 
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)"
+                    r"|branch_computations=\{([^}]*)\}")
+
+
+def called(line_or_lines: str) -> set:
+    """The computations an instruction (or a computation's text) names:
+    fusions, reducers, loop bodies and conditions, a conditional's branches."""
+    return {x.strip().lstrip("%") for one, many in _CALLS.findall(line_or_lines)
+            for x in (one + many).split(",")}
+
+
+def reachable(comps: dict, roots) -> set:
+    """`roots` and every computation they call, directly or not."""
+    calls = {name: called("\n".join(lines)) for name, lines in comps.items()}
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += calls.get(name, ())
+    return seen
+
+
+def branch_collectives(text: str, conditional_line: str) -> list:
+    """For each branch of a `conditional` instruction, the collective
+    instructions in the branch's computation and whatever it calls."""
+    comps = computations(text)
+    branches = _CALLS.search(conditional_line).group(2).split(",")
+    return [[line.strip() for name in sorted(reachable(comps, [b.strip().lstrip("%")]))
+             for line in comps.get(name, ())
+             if re.search(r" (all-reduce|all-gather|reduce-scatter|all-to-all"
+                          r"|collective-permute)(-start)?\(", line)]
+            for b in branches]
+
+
 def loop_computations(text: str, comps: dict) -> set:
     """The names of the computations that run inside a while loop: the loops'
     bodies and whatever they call."""
-    calls = {name: set(re.findall(
-        r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", "\n".join(lines)))
-        for name, lines in comps.items()}
-    in_loop, todo = set(), list(set(re.findall(r"body=%?([\w.\-]+)", text)))
-    while todo:
-        name = todo.pop()
-        if name not in in_loop:
-            in_loop.add(name)
-            todo += calls.get(name, ())
-    return in_loop
+    return reachable(comps, set(re.findall(r"body=%?([\w.\-]+)", text)))
 
 
 def whole_pool_copies(text: str, pool_shape) -> list:

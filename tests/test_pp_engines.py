@@ -3,6 +3,12 @@ and remat-policy effect in the pipeline path (ref: the reference validates
 its schedules by loss parity between pipeline_parallel_1f1b and
 pipeline_parallel_afab, pipeline_parallel.py:122-215 vs 77-118)."""
 
+import faulthandler
+import functools
+import importlib.util
+import math
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -13,12 +19,25 @@ from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
 
 
+@pytest.fixture
+def rendezvous_timeout():
+    """A collective that waits for a peer which took the other branch of a
+    `lax.cond` never returns, and Python cannot interrupt the wait: after 300
+    s dump every thread's stack and end the process, so the hang is one
+    failed test (a lost worker) and not the suite's whole clock."""
+    faulthandler.dump_traceback_later(300, exit=True)
+    yield
+    faulthandler.cancel_dump_traceback_later()
+
+
 def pp_cfg(engine, pp=2, gas=4, tp=1, remat=False, remat_policy="dots",
-           seq=32, mbs=2, hidden=64):
+           seq=32, mbs=2, hidden=64, ep=1, sp=False, model=None):
     return Config(
-        distributed=DistributedConfig(pp_size=pp, tp_size=tp, pp_engine=engine),
+        distributed=DistributedConfig(pp_size=pp, tp_size=tp, ep_size=ep,
+                                      sequence_parallel=sp, pp_engine=engine),
         model=ModelConfig(dtype="float32", hidden_size=hidden,
-                          num_attention_heads=8, num_key_value_heads=4),
+                          num_attention_heads=8, num_key_value_heads=4,
+                          **(model or {})),
         training=TrainingConfig(seq_length=seq, micro_batch_size=mbs,
                                 gradient_accumulation_steps=gas,
                                 learning_rate=1e-3, remat=remat,
@@ -28,39 +47,60 @@ def pp_cfg(engine, pp=2, gas=4, tp=1, remat=False, remat_policy="dots",
 
 def batch_for(cfg, menv, key=0):
     t = cfg.training
-    b_global = t.micro_batch_size * cfg.distributed.dp_size
+    b_global = (t.micro_batch_size * cfg.distributed.dp_size
+                * cfg.distributed.ep_size)
     toks = jax.random.randint(
         jax.random.key(key),
         (t.gradient_accumulation_steps, b_global, t.seq_length + 1),
         0, cfg.model.vocab_size)
-    sh = NamedSharding(menv.mesh, P(None, "dp", "cp"))
+    sh = NamedSharding(menv.mesh, P(None, ("dp", "ep"), "cp"))
     return (jax.device_put(toks[..., :-1], sh),
             jax.device_put(toks[..., 1:], sh))
 
 
-def run_engine(cfg, steps=3):
+def build(cfg):
+    """(jitted train step, initial state, one batch) for cfg on its mesh."""
     menv = MeshEnv.from_config(cfg)
     state = init_sharded_state(cfg, menv, jax.random.key(0))
-    step = make_train_step(cfg, menv)
-    batch = batch_for(cfg, menv)
-    losses = []
+    return make_train_step(cfg, menv), state, batch_for(cfg, menv)
+
+
+def run_metrics(cfg, steps=3):
+    """([the step's metrics as floats, a step], final state)."""
+    step, state, batch = build(cfg)
+    history = []
     for _ in range(steps):
         state, metrics = step(state, batch)
-        losses.append(float(metrics["loss"]))
-    return losses, state
+        history.append({k: float(v) for k, v in metrics.items()})
+    return history, state
 
 
-@pytest.mark.parametrize("layout", [
+def run_engine(cfg, steps=3):
+    history, state = run_metrics(cfg, steps)
+    return [m["loss"] for m in history], state
+
+
+LAYOUTS = {
     # (pp2/gas4 pruned r5: strict subset of pp4/gas4 and pp2xtp2)
-    dict(pp=4, gas=4),
-    dict(pp=2, gas=4, tp=2),
-    dict(pp=2, gas=3, remat=True),  # odd n_micro + remat'd tick bodies
-])
-def test_1f1b_matches_afab(layout):
+    "pp4": dict(pp=4, gas=4),
+    "pp2tp2": dict(pp=2, gas=4, tp=2),
+    "pp2remat": dict(pp=2, gas=3, remat=True),  # odd n_micro + remat'd ticks
+}
+
+
+@functools.lru_cache(maxsize=None)
+def run_1f1b(name):
+    """(losses, final state) of three 1F1B steps on LAYOUTS[name]; the two
+    tests below read the same run."""
+    return run_engine(pp_cfg("1f1b", **LAYOUTS[name]))
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_1f1b_matches_afab(name):
     """The two engines compute the same gradients (same math, different
     schedule); only fp reduction order differs."""
-    l_1f1b, s_1f1b = run_engine(pp_cfg("1f1b", **layout))
-    l_afab, s_afab = run_engine(pp_cfg("afab", **layout))
+    l_1f1b, s_1f1b = run_1f1b(name)
+    l_afab, s_afab = run_engine(pp_cfg("afab", **LAYOUTS[name]))
     np.testing.assert_allclose(l_1f1b, l_afab, rtol=1e-5, atol=1e-6)
     for name in ("embedding", "lm_head"):
         np.testing.assert_allclose(
@@ -71,11 +111,139 @@ def test_1f1b_matches_afab(layout):
         np.asarray(s_afab.params["layers"]["q"]), rtol=2e-3, atol=1e-4)
 
 
+# Three steps of the 1F1B engine on the parent of PR 38 (commit 977113c, this
+# installation, float32 on 8 host devices), whose tick ran a forward unit
+# that scored, on every stage, beside the backward unit: the losses it
+# reported, and the float64 sum of |p| over the updated parameters. PR 38
+# reads the loss from the backward unit's primal and skips the last stage's
+# forward unit; the backward unit, which makes every gradient, is the same
+# code. Steps 2 and 3 are computed from updated parameters, so equal losses
+# hold the updates too. (On pp2tp2 and pp2remat the updated parameters are
+# bit-equal to the parent's; on pp4 the CPU compiler fuses the unchanged
+# backward differently around the new branch and single parameters move by
+# up to 8e-6 after one Adam step, which is why this holds sums and not a
+# digest.)
+PARENT_1F1B = {
+    "pp4": ([5.694900989532471, 5.573522567749023, 5.4533843994140625],
+            23417.8854430543),
+    "pp2tp2": ([5.694900989532471, 5.573522567749023, 5.4533843994140625],
+               23417.885443114465),
+    "pp2remat": ([5.687442779541016, 5.551187038421631, 5.4163079261779785],
+                 23418.75768325695),
+}
+
+
+def abs_sum(params) -> float:
+    return float(sum(np.abs(np.asarray(x, np.float64)).sum()
+                     for x in jax.tree.leaves(params)))
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_1f1b_loss_and_update_are_the_parents(name):
+    losses, state = run_1f1b(name)
+    want_losses, want_sum = PARENT_1F1B[name]
+    np.testing.assert_allclose(losses, want_losses, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(abs_sum(state.params), want_sum, rtol=1e-6)
+
+
+def head_forwards_a_tick(cfg) -> int:
+    """dot_generals whose result is one microbatch's logits ([tokens,
+    vocab / tp]) in the body of the traced step's 1F1B scan: the head's
+    forward matmuls a tick. (dx has the hidden size last, dW the hidden size
+    first.)"""
+    step, state, batch = build(cfg)
+    jaxpr = jax.make_jaxpr(lambda s, b: step(s, b))(state, batch).jaxpr
+    t, d = cfg.training, cfg.distributed
+    tokens = t.micro_batch_size * t.seq_length
+    vocab = cfg.model.vocab_size // d.tp_size
+    assert tokens != cfg.model.hidden_size  # or dW would read as logits
+    ticks = t.gradient_accumulation_steps + 2 * (d.pp_size - 1)
+
+    def sub_jaxprs(eqn):
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield x
+
+    def walk(jaxpr, in_scan):
+        n = 0
+        for eqn in jaxpr.eqns:
+            inside = in_scan or (eqn.primitive.name == "scan"
+                                 and eqn.params["length"] == ticks)
+            if in_scan and eqn.primitive.name == "dot_general":
+                shape = eqn.outvars[0].aval.shape
+                n += shape[-1] == vocab and math.prod(shape[:-1]) == tokens
+            n += sum(walk(j, inside) for j in sub_jaxprs(eqn))
+        return n
+
+    return walk(jaxpr, False)
+
+
+@pytest.mark.parametrize("layout", [
+    dict(pp=2, gas=4, tp=2),           # the head gated to the last stage
+    dict(pp=2, gas=4, tp=2, sp=True),  # masked-uniform on every stage
+    dict(pp=2, gas=4),                 # no tp hook: the cond returns the total
+], ids=["gated", "sequence_parallel", "unsharded"])
+def test_1f1b_tick_runs_one_head_forward(layout):
+    """The 1F1B tick computes a microbatch's logits once, under the backward
+    unit's `jax.vjp`. Until PR 38 the forward unit scored too (two a tick),
+    and kept only a scalar that the vjp's primal also holds."""
+    assert head_forwards_a_tick(pp_cfg("1f1b", seq=16, **layout)) == 1
+
+
+MOE = dict(name="debug-tiny-moe", num_hidden_layers=2, num_experts=8,
+           num_experts_per_token=2,
+           # tight capacity: drops happen, so the drop sum has a value to lose
+           capacity_factor=0.5, router_z_coef=1e-3)
+
+
+@pytest.mark.parametrize("layout", [
+    dict(pp=2, ep=2, gas=3, model=MOE),
+    dict(pp=2, gas=3, model=dict(tie_word_embeddings=True)),
+    dict(pp=2, tp=2, gas=3, sp=True),
+], ids=["moe_ep2", "tied_embedding", "sequence_parallel"])
+def test_1f1b_backward_units_sums_match_afab(layout, rendezvous_timeout):
+    """What PR 38 moved from the forward unit's outputs to the backward
+    unit's primal: the CE sum and count (the head is the embedding when
+    tied; masked-uniform on every stage under sequence parallelism), each
+    stage's own router term, and the drop / load observability sums. AFAB
+    still scores in its differentiated forward, so it is the reference."""
+    m_1f1b, s_1f1b = run_metrics(pp_cfg("1f1b", **layout), steps=2)
+    m_afab, s_afab = run_metrics(pp_cfg("afab", **layout), steps=2)
+    # (grad_norm under sequence parallelism reads 1% apart between the two
+    # engines on the parent too, 0.67146 against 0.66442, with equal losses
+    # and updates: PERF.md section 7)
+    skip = {"grad_norm"} if layout.get("sp") else set()
+    for a, b in zip(m_1f1b, m_afab):
+        assert set(a) == set(b)
+        for k in set(a) - skip:
+            np.testing.assert_allclose(
+                a[k], b[k], rtol=1e-5 if k == "loss" else 1e-4, atol=1e-6,
+                err_msg=k)
+    if "num_experts" in layout.get("model", {}):
+        assert 0.0 < m_1f1b[0]["moe_drop_frac"] < 1.0
+        assert m_1f1b[0]["moe_load_max_over_mean"] > 1.0
+    np.testing.assert_allclose(
+        np.asarray(s_1f1b.params["embedding"]),
+        np.asarray(s_afab.params["embedding"]), rtol=2e-3, atol=1e-4)
+
+
+def test_branch_by_stage_may_hold_a_tp_collective(rendezvous_timeout):
+    """parallel/pp.py's branch rule, probed: a cond on the stage index with a
+    psum over 'tp' in one branch only, a ppermute over 'pp' after it, in a
+    scan (tools/pp_branch_probe.py; the same script ran on the four chips)."""
+    spec = importlib.util.spec_from_file_location(
+        "pp_branch_probe", os.path.join(os.path.dirname(__file__), "..",
+                                        "tools", "pp_branch_probe.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    ok, got = mod.probe(jax.devices())
+    assert ok, got
+
+
 def _compiled_temp_bytes(cfg):
-    menv = MeshEnv.from_config(cfg)
-    state = init_sharded_state(cfg, menv, jax.random.key(0))
-    step = make_train_step(cfg, menv)
-    batch = batch_for(cfg, menv)
+    step, state, batch = build(cfg)
     stats = step.lower(state, batch).compile().memory_analysis()
     return stats.temp_size_in_bytes
 
@@ -112,11 +280,7 @@ def test_1f1b_tick_count_and_schedule_rate():
     assert pp_1f1b_ring_slots(4, 1) == 1
 
     pp_size, gas = 4, 8
-    cfg = pp_cfg("1f1b", pp=pp_size, gas=gas)
-    menv = MeshEnv.from_config(cfg)
-    state = init_sharded_state(cfg, menv, jax.random.key(0))
-    step = make_train_step(cfg, menv)
-    batch = batch_for(cfg, menv)
+    step, state, batch = build(pp_cfg("1f1b", pp=pp_size, gas=gas))
     jaxpr = str(jax.make_jaxpr(lambda s, b: step(s, b))(state, batch))
     lengths = {int(x) for x in re.findall(r"length=(\d+)", jaxpr)}
     assert pp_1f1b_ticks(gas, pp_size) in lengths, lengths
@@ -130,11 +294,8 @@ def test_afab_remat_policy_reaches_pipeline_tick():
     jaxprs = {}
     losses = {}
     for policy in ("full", "dots", "dots_attn", "dots_norms"):
-        cfg = pp_cfg("afab", pp=2, gas=2, remat=True, remat_policy=policy)
-        menv = MeshEnv.from_config(cfg)
-        state = init_sharded_state(cfg, menv, jax.random.key(0))
-        step = make_train_step(cfg, menv)
-        batch = batch_for(cfg, menv)
+        step, state, batch = build(
+            pp_cfg("afab", pp=2, gas=2, remat=True, remat_policy=policy))
         jaxprs[policy] = str(jax.make_jaxpr(lambda s, b: step(s, b))(state, batch))
         _, metrics = step(state, batch)
         losses[policy] = float(metrics["loss"])
@@ -155,11 +316,9 @@ def test_dots_offload_policy_compiles_and_matches():
     numerics; the on-chip economics are recorded in PERF.md r4)."""
     losses = {}
     for policy in ("dots", "dots_offload"):
-        cfg = pp_cfg("afab", pp=2, gas=2, remat=True, remat_policy=policy)
-        menv = MeshEnv.from_config(cfg)
-        state = init_sharded_state(cfg, menv, jax.random.key(0))
-        step = make_train_step(cfg, menv)
-        _, metrics = step(state, batch_for(cfg, menv))
+        step, state, batch = build(
+            pp_cfg("afab", pp=2, gas=2, remat=True, remat_policy=policy))
+        _, metrics = step(state, batch)
         losses[policy] = float(metrics["loss"])
     np.testing.assert_allclose(losses["dots"], losses["dots_offload"],
                                rtol=1e-6)
