@@ -203,6 +203,38 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         routed_scaling_factor=2.5, sandwich_norm=True,
         router_aux_coef=0.0,
     ),
+    # K-EXAONE-236B-A23B (LG AI Research; model_type exaone_moe): 48 layers
+    # in the pattern (sliding, sliding, sliding, full) x 12 at window 128,
+    # GQA 64:8 at head_dim 128, layer 0 dense (SwiGLU 18,432) and 47 expert
+    # layers (128 routed experts of width 2,048, 8 a token by sigmoid scores
+    # renormalised and scaled 2.5, no groups, + 1 shared expert), untied
+    # 153,600-row head. The pattern is cut where the stacks are: the dense
+    # stack is (S,), the expert stack (S, S, F, S) x 11 + (S, S, F), scanned
+    # over its own whole periods with the three left over run after the scan
+    # (`pattern_of`). Built: all of the above through forward(), generate()
+    # and ServeEngine. Assumed, with no key in config.json (the family's
+    # modelling code): RMSNorm over each head of q and k (qk_norm 'head'),
+    # no rotation on full layers (rope_type 'none'; the one published law,
+    # theta 1e6 unscaled, is the sliding layers'), two RMSNorms a layer, no
+    # selection bias in the router. Not built: the multi-token-prediction
+    # layer (num_nextn_predict_layers 1), which drafts; a served token does
+    # not pass through it.
+    "LGAI-EXAONE/K-EXAONE-236B-A23B": dict(
+        vocab_size=153600, hidden_size=6144, intermediate_size=18432,
+        num_hidden_layers=48, num_attention_heads=64, num_key_value_heads=8,
+        head_dim=128, max_position_embeddings=262144, rope_theta=1e6,
+        rms_norm_eps=1e-5,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "sliding_attention", "full_attention") * 12,
+        sliding_window=128, qk_norm="head",
+        rope_parameters=dict(
+            sliding_attention=dict(rope_type="default", rope_theta=1e6),
+            full_attention=dict(rope_type="none")),
+        first_k_dense_replace=1, num_experts=128, num_experts_per_token=8,
+        moe_intermediate_size=2048, n_shared_experts=1,
+        norm_topk_prob=True, moe_scoring="sigmoid",
+        routed_scaling_factor=2.5, router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -270,6 +302,30 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         moe_scoring="sigmoid", routed_scaling_factor=2.5,
         sandwich_norm=True, router_aux_coef=0.0,
     ),
+    # Tiny K-EXAONE-shaped debug model: a dense sliding layer, then
+    # S, S, F, S expert layers (to `pattern_of` a period (S, S, F) of the
+    # expert stack's own slice and one layer left over), window 8,
+    # head_dim (32) unequal to hidden / heads, per-head QK-norm, unrotated
+    # full layer, 16 routed experts 2 a token + 1 shared, sigmoid scores
+    # scaled 2.5. Served with block_size 4 and a prefill chunk longer than
+    # the window.
+    "picotron-tpu/debug-tiny-exaone-moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, max_position_embeddings=2048, rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "sliding_attention", "full_attention",
+                     "sliding_attention"),
+        sliding_window=8, qk_norm="head",
+        rope_parameters=dict(
+            sliding_attention=dict(rope_type="default", rope_theta=10000.0),
+            full_attention=dict(rope_type="none")),
+        first_k_dense_replace=1, num_experts=16, num_experts_per_token=2,
+        moe_intermediate_size=32, n_shared_experts=1, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=2.5,
+        router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -302,6 +358,8 @@ _PRESET_ALIASES = {
     "debug-tiny-mellum2": "picotron-tpu/debug-tiny-mellum2",
     "openPangu-Ultra-MoE-718B": "FreedomIntelligence/openPangu-Ultra-MoE-718B",
     "debug-tiny-pangu-moe": "picotron-tpu/debug-tiny-pangu-moe",
+    "K-EXAONE-236B-A23B": "LGAI-EXAONE/K-EXAONE-236B-A23B",
+    "debug-tiny-exaone-moe": "picotron-tpu/debug-tiny-exaone-moe",
 }
 
 
@@ -325,9 +383,10 @@ def resolve_hf_name(name: str) -> str:
 def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
-    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/Pangu-Ultra-MoE-family model
-    outside the preset registry resolves from its config file instead of
-    hand-typed hyperparameters. Pass a path or an already-parsed dict."""
+    (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
+    Pangu-Ultra-MoE/EXAONE-MoE-family model outside the preset registry
+    resolves from its config file instead of hand-typed hyperparameters.
+    Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
         hf = path_or_dict
     else:
@@ -336,7 +395,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
 
     mtype = hf.get("model_type", "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
-                 "pangu_ultra_moe")
+                 "pangu_ultra_moe", "exaone_moe")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -440,6 +499,47 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
             out["rope_parameters"] = rp
             out["rope_theta"] = float(next(iter(rp.values())).get(
                 "rope_theta", out["rope_theta"]))
+    if mtype == "exaone_moe":
+        # K-EXAONE: a dense layer before the expert layers
+        # (first_k_dense_replace, or the leading 'dense' entries of
+        # mlp_layer_types above), a shared expert (num_shared_experts), the
+        # router's law by its own keys (scoring_func, routed_scaling_factor;
+        # n_group / topk_group 1: no expert groups, and groups are not
+        # built), sliding_windows a per-layer restatement of layer_types +
+        # sliding_window. config.json has no key for two things the
+        # family's modelling code does, both `assumed` where a benchmark
+        # configuration states them: RMSNorm over each head of q and k
+        # (qk_norm 'head'), and no rotation on the full-attention layers
+        # (the one published law is the sliding layers').
+        if int(hf.get("n_group", 1)) != 1 or int(hf.get("topk_group", 1)) != 1:
+            raise ValueError(
+                "exaone_moe with n_group / topk_group != 1: routing within "
+                "expert groups is not built (the router picks the k largest "
+                "of all its scores)")
+        if "first_k_dense_replace" in hf:
+            out["first_k_dense_replace"] = int(hf["first_k_dense_replace"])
+        out["n_shared_experts"] = int(hf.get("num_shared_experts", 0))
+        out["moe_scoring"] = hf.get("scoring_func", "softmax")
+        out["routed_scaling_factor"] = float(
+            hf.get("routed_scaling_factor", 1.0))
+        out["router_aux_coef"] = 0.0
+        out["qk_norm"] = "head"
+        kinds = out.get("layer_types")
+        if kinds:
+            windows = hf.get("sliding_windows")
+            if windows is not None and [int(w) for w in windows] != [
+                    out["sliding_window"] if k == "sliding_attention" else 0
+                    for k in kinds]:
+                raise ValueError(
+                    "sliding_windows disagrees with layer_types / "
+                    "sliding_window: one window for every sliding layer "
+                    "and 0 on a full layer is what is built")
+            theta = out["rope_theta"]
+            out.pop("rope_scaling", None)
+            out["rope_parameters"] = {
+                "sliding_attention": {"rope_type": "default",
+                                      "rope_theta": theta},
+                "full_attention": {"rope_type": "none"}}
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -590,6 +690,32 @@ class Block(NamedTuple):
     sandwich: bool  # RMSNorms on the attention's and the MLP's outputs too
 
 
+def pattern_of(kinds: tuple) -> tuple:
+    """(period, whole, rest) of a run of layer kinds: the shortest `period`
+    with kinds[i] == period[i % len(period)] for every i, how many `whole`
+    periods the run holds, and the `rest` after them (a head of the period,
+    shorter than it). A layer scan runs the whole periods, one an
+    iteration with each layer's kind static in the body, and the rest is
+    run after it, outside the scan."""
+    for p in range(1, len(kinds) + 1):
+        if all(kinds[i] == kinds[i % p] for i in range(p, len(kinds))):
+            return kinds[:p], len(kinds) // p, kinds[len(kinds) // p * p:]
+    return (), 0, ()
+
+
+class Stack(NamedTuple):
+    """One stack of the layer tree (`ModelConfig.stacks`): layers of one
+    kind of block, stacked on a leading axis under one params key. It knows
+    its own slice of the model's layer pattern: the dense stack of a model
+    whose pattern is (S, S, S, F) x n is (S,), and its expert stack starts
+    one layer into the pattern."""
+
+    name: str     # params key: "layers" | "dense_layers"
+    layers: int
+    block: Block
+    kinds: tuple  # the attention kind of each layer: its slice of layer_kinds
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Llama-family architecture hyperparameters.
@@ -626,8 +752,10 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     # RoPE law a layer kind: {"full_attention": {rope_type, rope_theta,
     # ...}, "sliding_attention": {...}} (the published key). None = one law
-    # for every layer, from rope_theta + rope_scaling. Stored like
-    # rope_scaling, as sorted tuples.
+    # for every layer, from rope_theta + rope_scaling. A kind whose
+    # rope_type is "none" is not rotated at all (the EXAONE hybrids' full
+    # layers): its tables are cos 1, sin 0. Stored like rope_scaling, as
+    # sorted tuples.
     rope_parameters: Optional[Any] = None
     rms_norm_eps: float = 1e-5
     # Qwen2-style architecture variants: bias on the q/k/v projections, and
@@ -662,11 +790,14 @@ class ModelConfig:
     # Renormalize the k chosen gates to sum to 1 (Mixtral's rule). False
     # keeps the raw softmax probabilities (OLMoE's published key).
     norm_topk_prob: bool = True
-    # Whole-vector RMSNorm on the q and k projections before the head
-    # split and RoPE (OLMoE; learned weights q_norm [n_q*d], k_norm
-    # [n_kv*d] per layer). The norm runs over channels tensor parallelism
-    # splits, so tp > 1 is refused (Config.validate).
-    qk_norm: bool = False
+    # RMSNorm on the q and k projections before RoPE, in one of two forms.
+    # True: over the WHOLE projected vector before the head split (OLMoE;
+    # learned weights q_norm [n_q*d], k_norm [n_kv*d] per layer); the norm
+    # runs over channels tensor parallelism splits, so tp > 1 is refused
+    # (Config.validate). "head": over each head's head_dim numbers, one
+    # weight vector [d] for all the heads of q and one for k (the EXAONE
+    # family's and Qwen3's form).
+    qk_norm: Any = False
     router_aux_coef: float = 0.01
     # Router z-loss coefficient (ST-MoE eq. 5; 1e-3 there). 0 disables.
     router_z_coef: float = 0.0
@@ -765,14 +896,10 @@ class ModelConfig:
 
     @property
     def layer_period(self) -> tuple:
-        """The shortest whole period of `layer_kinds`: what one iteration
-        of the layer scans runs. ("full_attention",) for a model of one
-        kind, whose scan is over single layers as it always was."""
-        kinds = self.layer_kinds
-        for p in range(1, len(kinds) + 1):
-            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
-                return kinds[:p]
-        return kinds
+        """The shortest period of `layer_kinds` (`pattern_of`).
+        ("full_attention",) for a model of one kind. A stack scans the
+        periods of its own slice (`Stack.kinds`), not this."""
+        return pattern_of(self.layer_kinds)[0]
 
     @property
     def expert_ffn_size(self) -> int:
@@ -794,20 +921,25 @@ class ModelConfig:
 
     @property
     def stacks(self) -> tuple:
-        """The layer tree's stacks in order, (params key, layers, Block)
-        each: what `models.llama.run_stacks` and
+        """The layer tree's stacks in order, a `Stack` (params key, layers,
+        Block, the layers' kinds) each: what `models.llama.run_stacks` and
         `generate._decode_layers` scan, one stack after the other. One
         stack, `layers`, for a model of one kind of block; the leading
         dense layers of a model with first_k_dense_replace are
-        `dense_layers`."""
+        `dense_layers`. The pattern of `layer_types` is cut where the
+        stacks are: each stack carries its own slice."""
         attn = "mla" if self.mla else "gqa"
         n, k = self.num_hidden_layers, self.first_k_dense_replace
+        kinds = self.layer_kinds
         if not self.num_experts:
-            return (("layers", n, Block(attn, "dense", self.sandwich_norm)),)
-        out = (("layers", n - k, Block(attn, "experts", self.sandwich_norm)),)
+            return (Stack("layers", n,
+                          Block(attn, "dense", self.sandwich_norm), kinds),)
+        out = (Stack("layers", n - k,
+                     Block(attn, "experts", self.sandwich_norm), kinds[k:]),)
         if k:
-            out = (("dense_layers", k,
-                    Block(attn, "dense", self.sandwich_norm)),) + out
+            out = (Stack("dense_layers", k,
+                         Block(attn, "dense", self.sandwich_norm),
+                         kinds[:k]),) + out
         return out
 
     def validate(self) -> None:
@@ -840,6 +972,10 @@ class ModelConfig:
                 raise ValueError(
                     "layer_types holds sliding_attention layers: "
                     "sliding_window must be a positive number of positions")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"qk_norm must be false, true (over the whole projected "
+                f"vector) or 'head' (over each head), got {self.qk_norm!r}")
         if self.rope_parameters:
             missing = set(self.layer_kinds) - set(dict(self.rope_parameters))
             if missing:
@@ -881,11 +1017,6 @@ class ModelConfig:
                     f"first_k_dense_replace ({self.first_k_dense_replace}) "
                     f"must leave at least one expert layer of "
                     f"num_hidden_layers ({self.num_hidden_layers})")
-            if self.layer_types is not None:
-                raise ValueError(
-                    "first_k_dense_replace > 0 with sliding-window "
-                    "layer_types: a stack's scan runs whole periods of the "
-                    "pattern, and nobody has cut a pattern over two stacks")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_scoring must be 'softmax' or 'sigmoid', got "
@@ -1547,7 +1678,7 @@ class Config:
             if m.expert_ffn_size % d.tp_size != 0:
                 raise ValueError(
                     "expert ffn size must be divisible by tp_size")
-        if m.qk_norm and d.tp_size > 1:
+        if m.qk_norm is True and d.tp_size > 1:
             raise ValueError(
                 "model.qk_norm normalizes q and k over the whole projected "
                 "vector, which tp_size > 1 splits across shards; a per-shard "
@@ -1767,7 +1898,8 @@ class Config:
             refuse("grad_engine='fused'")
         if d.pp_size > 1:
             refuse(f"pipeline parallelism (pp_size={d.pp_size}: a stage "
-                   f"slice would have to carry its place in the pattern)")
+                   f"slice would have to carry its own slice of the "
+                   f"pattern, as a stack does)")
         if d.tp_size > 1:
             refuse(f"tensor parallelism (tp_size={d.tp_size}: the two "
                    f"pools of a mixed cache are not sharded)")
@@ -1777,14 +1909,20 @@ class Config:
 
     def _refuse_new_blocks(self) -> None:
         """Latent attention, sandwich norms, a shared expert, sigmoid
-        routing, a held share of the experts and leading dense layers run
-        on the plain attention of `forward()` (and its AD), on
-        `generate()` and on `ServeEngine`, on one device. Every path that
-        has its own copy of the block, one head width for q, k and v, or a
-        single `layers` stack refuses them by name (ROADMAP M5, M3, D6)."""
+        routing, a held share of the experts, leading dense layers (with
+        or without a layer pattern cut over the two stacks), per-head
+        QK-norm and an unrotated layer kind run on the plain attention of
+        `forward()` (and its AD), on `generate()` and on `ServeEngine`, on
+        one device. Every path that has its own copy of the block, one
+        head width for q, k and v, or a single `layers` stack refuses them
+        by name (ROADMAP M5, M3, D6)."""
         d, m, t, sv = (self.distributed, self.model, self.training,
                        self.serve)
         what = [name for name, on in (
+            ("per-head QK-norm (qk_norm='head')", m.qk_norm == "head"),
+            ("a layer kind that is not rotated (rope_type 'none')",
+             any(dict(law).get("rope_type") == "none"
+                 for _, law in m.rope_parameters or ())),
             ("latent attention (kv_lora_rank > 0)", m.mla),
             ("first_k_dense_replace > 0", m.first_k_dense_replace > 0),
             ("sandwich_norm", m.sandwich_norm),
@@ -2007,7 +2145,9 @@ def num_params(m: ModelConfig, active_only: bool = False,
         attn = h * q + h * kv * 2 + q * h  # q, k/v, out projections
         if m.attention_bias:
             attn += q + 2 * kv  # q/k/v biases
-        if m.qk_norm:
+        if m.qk_norm == "head":
+            attn += 2 * m.head_dim  # q_norm / k_norm, one vector for all heads
+        elif m.qk_norm:
             attn += q + kv  # q_norm / k_norm weights
     norms = (4 if m.sandwich_norm else 2) * h  # RMSNorm weights a layer
     k = m.first_k_dense_replace

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from picotron_tpu.config import Block, ModelConfig
+from picotron_tpu.config import Block, ModelConfig, pattern_of
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
@@ -54,7 +54,8 @@ def model_rope_tables(cfg, max_len=None):
     (cos, sin), each one table for a model whose layers all rotate by one
     law, or a dict {layer kind: table} for one that publishes a law a kind
     (`rope_parameters`: Mellum2's full layers rotate by YaRN, its sliding
-    layers unscaled). `kind_tables` picks a layer's pair from either."""
+    layers unscaled; K-EXAONE's full layers do not rotate, which is the
+    identity's tables). `kind_tables` picks a layer's pair from either."""
     n = max_len or cfg.max_position_embeddings
     if not cfg.rope_parameters:
         # rope_dim: the whole head, or latent attention's shared rotated
@@ -83,14 +84,15 @@ def layer_window(cfg, kind: str):
 
 
 def by_period(layer_tree, period: int):
-    """A [L, ...]-stacked layer tree as [L / period, period, ...]: what a
-    scan over whole periods of the layer pattern iterates over."""
+    """The whole periods of a [L, ...]-stacked layer tree as
+    [L // period, period, ...]: what a scan over whole periods of the layer
+    pattern iterates over. The L % period layers after them are the
+    caller's to run (`pattern_of`)."""
     def split(x):
+        whole = x.shape[0] // period
         if x.shape[0] % period:
-            raise ValueError(
-                f"{x.shape[0]} stacked layers are not whole periods of "
-                f"{period} layers")
-        return x.reshape(x.shape[0] // period, period, *x.shape[1:])
+            x = x[:whole * period]
+        return x.reshape(whole, period, *x.shape[1:])
     return jax.tree.map(split, layer_tree)
 
 Params = dict[str, Any]
@@ -250,7 +252,13 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "b_k": jnp.zeros((nl, kv_out), jnp.float32),
             "b_v": jnp.zeros((nl, kv_out), jnp.float32),
         })
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        # K-EXAONE: RMSNorm weights over one head, shared by the heads
+        layers.update({
+            "q_norm": jnp.ones((nl, d), jnp.float32),
+            "k_norm": jnp.ones((nl, d), jnp.float32),
+        })
+    elif cfg.qk_norm:
         # OLMoE: RMSNorm weights over the whole q / k projection
         layers.update({
             "q_norm": jnp.ones((nl, q_out), jnp.float32),
@@ -296,9 +304,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     keys = jax.random.split(key, 14)
     # the last stack (`layers`) draws from `key` itself, as the one stack of
     # a model of one kind of block always did; a stack before it from a fold
-    stacks = {name: _init_stack(cfg, block, nl,
-                                jax.random.fold_in(key, j) if j else key)
-              for j, (name, nl, block) in enumerate(reversed(cfg.stacks))}
+    stacks = {st.name: _init_stack(cfg, st.block, st.layers,
+                                   jax.random.fold_in(key, j) if j else key)
+              for j, st in enumerate(reversed(cfg.stacks))}
 
     params = {
         "embedding": jax.random.normal(keys[0], (v, h), jnp.float32),
@@ -399,10 +407,13 @@ def embed(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
 
 def qkv_proj(h, lp, d: int, eps: float = 1e-5):
     """Shared q/k/v projection (+ optional Qwen2 bias, tp-sharded with its
-    output features; + optional OLMoE QK-norm, an RMSNorm with `eps` over
-    the WHOLE projected q and k vectors before the head split and RoPE,
-    where the layer has `q_norm` / `k_norm` weights — tp = 1 only,
-    Config.validate) -> ([B,S,Hq,D], [B,S,Hkv,D], [B,S,Hkv,D]); local head
+    output features; + optional QK-norm where the layer has `q_norm` /
+    `k_norm` weights, an RMSNorm with `eps` before RoPE: over the WHOLE
+    projected q and k vectors before the head split where the weights are
+    that wide (OLMoE), over each head's `d` numbers where they are `d`
+    wide (K-EXAONE: one weight vector for all heads; with one head the two
+    forms are one) — tp = 1 only, Config.validate)
+    -> ([B,S,Hq,D], [B,S,Hkv,D], [B,S,Hkv,D]); local head
     counts come from the (possibly TP-sharded) weight shapes. One
     implementation for the training block, the fused grad engine's
     segment VJP AND the KV-cache decode path (generate.py) so
@@ -416,7 +427,8 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5):
         q = q + lp["b_q"].astype(dt)
         k = k + lp["b_k"].astype(dt)
         v = v + lp["b_v"].astype(dt)
-    if "q_norm" in lp:
+    per_head = "q_norm" in lp and lp["q_norm"].shape[-1] == d
+    if "q_norm" in lp and not per_head:
         q = rms_norm(q, lp["q_norm"], eps)
         k = rms_norm(k, lp["k_norm"], eps)
     # checkpoint-name the FLAT [B, S, H*D] projections, BEFORE the head
@@ -428,8 +440,12 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5):
     q = checkpoint_name(q, "qkv_out")
     k = checkpoint_name(k, "qkv_out")
     v = checkpoint_name(v, "qkv_out")
-    return (q.reshape(b, s, -1, d), k.reshape(b, s, -1, d),
-            v.reshape(b, s, -1, d))
+    q, k, v = (q.reshape(b, s, -1, d), k.reshape(b, s, -1, d),
+               v.reshape(b, s, -1, d))
+    if per_head:
+        q = rms_norm(q, lp["q_norm"], eps)
+        k = rms_norm(k, lp["k_norm"], eps)
+    return q, k, v
 
 
 @scope("attention")
@@ -588,7 +604,7 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     ParallelCtx.layer_is_real). `block`: what the layer is made of
     (`cfg.stacks`); None = the last stack's, which is every layer's in a
     model of one kind of block."""
-    block = block or cfg.stacks[-1][2]
+    block = block or cfg.stacks[-1].block
     if block.attn == "mla":
         attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
     else:
@@ -671,56 +687,77 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
                ctx: ParallelCtx = DEFAULT_CTX,
                cos: jnp.ndarray | None = None,
                sin: jnp.ndarray | None = None,
-               block: Optional[Block] = None):
+               block: Optional[Block] = None,
+               kinds: Optional[tuple] = None):
     """Scan a stacked layer pytree over x. Works on any contiguous stage
     slice, which is exactly what pipeline parallelism feeds it. `block`:
-    what the stack's layers are made of (see `decoder_layer`).
+    what the stack's layers are made of (see `decoder_layer`). `kinds`:
+    the attention kind of each layer of the slice (`Stack.kinds`); None =
+    every layer of the model's one kind (a stage slice of any length).
 
     Returns (x, aux [3]) — aux[0] the summed pre-weighted MoE router loss
     over the scanned layers, aux[1] the summed capacity drop fraction,
     aux[2] the summed busiest-expert load ratio (all 0 for dense models)."""
     if cos is None:
         cos, sin = model_rope_tables(cfg)
-    # one scan iteration runs one whole period of the layer pattern: one
-    # layer for a model of one kind, (S, S, S, F) for Mellum2, each layer
-    # of the body traced with its own kind (tables, band)
-    period = cfg.layer_period
+    n_slots = jax.tree.leaves(layer_params)[0].shape[0]
+    if kinds is None:
+        if len(cfg.layer_period) > 1:
+            raise ValueError(
+                "run_layers needs the `kinds` of its slice for a model "
+                "with a layer pattern (`Stack.kinds`)")
+        kinds = cfg.layer_period * n_slots
+    # one scan iteration runs one whole period of the slice's own pattern:
+    # one layer for a model of one kind, (S, S, S, F) for Mellum2, each
+    # layer of the body traced with its own kind (tables, band); the
+    # layers left over after the whole periods run after the scan
+    period, whole, rest = pattern_of(tuple(kinds))
+
+    def one(h, lp, real, kind):
+        return decoder_layer(h, lp, cfg, ctx, cos, sin, real, kind, block)
 
     def body(h, xs):
         lp, real = xs
         if len(period) == 1:
-            return decoder_layer(h, lp, cfg, ctx, cos, sin, real, period[0],
-                                 block)
+            return one(h, lp, real, period[0])
         aux = jnp.zeros(3, jnp.float32)
         for j, kind in enumerate(period):
-            h, a = decoder_layer(h, jax.tree.map(lambda w: w[j], lp), cfg,
-                                 ctx, cos, sin, real[j], kind, block)
+            h, a = one(h, jax.tree.map(lambda w: w[j], lp), real[j], kind)
             aux = aux + a
         # aux rides the scan's stacked outputs (not the carry: its varying
         # mesh axes differ from x's, which would unstabilize the carry type)
         return h, aux
 
-    n_slots = jax.tree.leaves(layer_params)[0].shape[0]
     real = (ctx.layer_is_real(n_slots) if ctx.layer_is_real is not None
             else jnp.ones((n_slots,), jnp.float32))
     xs = (layer_params, real)
     if len(period) > 1:
         xs = by_period(xs, len(period))
+    left_over = one
     if ctx.remat:
-        body = jax.checkpoint(body, policy=remat_policy_for(ctx.remat_policy))
-    x, aux_per_layer = jax.lax.scan(body, x, xs)  # [L / period, 3]
-    return x, jnp.sum(aux_per_layer, axis=0)
+        policy = remat_policy_for(ctx.remat_policy)
+        body = jax.checkpoint(body, policy=policy)
+        left_over = jax.checkpoint(one, policy=policy, static_argnums=(3,))
+    x, aux_per_layer = jax.lax.scan(body, x, xs)  # [L // period, 3]
+    aux = jnp.sum(aux_per_layer, axis=0)
+    for j, kind in enumerate(rest, whole * len(period)):
+        x, a = left_over(x, jax.tree.map(lambda w: w[j], layer_params),
+                         real[j], kind)
+        aux = aux + a
+    return x, aux
 
 
 def run_stacks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                ctx: ParallelCtx = DEFAULT_CTX, cos=None, sin=None):
     """Every stack of the layer tree in order (`cfg.stacks`), each one
-    scan: the leading dense layers, then the expert layers; the one
-    `layers` stack of a model of one kind of block. Returns (x, aux [3])
-    as `run_layers`."""
+    scan over the whole periods of its own slice of the layer pattern (and
+    what is left of it after them): the leading dense layers, then the
+    expert layers; the one `layers` stack of a model of one kind of block.
+    Returns (x, aux [3]) as `run_layers`."""
     aux = jnp.zeros(3, jnp.float32)
-    for name, _, block in cfg.stacks:
-        x, a = run_layers(params[name], x, cfg, ctx, cos, sin, block)
+    for st in cfg.stacks:
+        x, a = run_layers(params[st.name], x, cfg, ctx, cos, sin, st.block,
+                          st.kinds)
         aux = aux + a
     return x, aux
 

@@ -235,9 +235,14 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
                          f"match q {q.shape}")
     g = hq // hkv
     ppc = min(pages_per_chunk or DEFAULT_PAGES_PER_CHUNK, max_blocks)
-    if window is not None and max_blocks * bs < window + bs:
-        raise ValueError(f"a ring of {max_blocks} blocks of {bs} cannot "
-                         f"hold a band of {window} positions")
+    if window is not None:
+        if max_blocks * bs < window + bs:
+            raise ValueError(f"a ring of {max_blocks} blocks of {bs} cannot "
+                             f"hold a band of {window} positions")
+        # a band lies in at most this many blocks, whatever the ring holds
+        # beside it for prefill (window 128 in a ring of 25 blocks of 16:
+        # 9 pages a chunk, not 25 buffered and multiplied)
+        ppc = min(ppc, -(-window // bs) + 1)
     kernel = functools.partial(_kernel, sm_scale=1.0 / d ** 0.5,
                                pages_per_chunk=ppc, max_blocks=max_blocks,
                                window=window)

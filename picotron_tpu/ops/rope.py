@@ -77,13 +77,18 @@ def rope_tables(max_seq_len: int, head_dim: int, base: float = 10000.0,
     "llama3" (Llama-3.1/3.2 frequency banding), "linear" (positions
     divided by `factor`) and "yarn" (`yarn_scale_freqs`; cos and sin are
     both multiplied by `attention_factor`, 0.1 ln(factor) + 1 where the
-    key is absent)."""
+    key is absent) and "none" (no rotation: cos 1, sin 0)."""
     assert head_dim % 2 == 0, "head_dim must be even for RoPE"
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     inv_freq = 1.0 / (base ** exponent)  # [head_dim/2]
     amplitude = 1.0
     if rope_scaling:
         kind = rope_scaling.get("rope_type", rope_scaling.get("type"))
+        if kind == "none":
+            # a layer kind that is not rotated (`ModelConfig.rope_parameters`):
+            # the identity's tables, so that no caller branches
+            shape = (max_seq_len, head_dim // 2)
+            return jnp.ones(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
         if kind == "llama3":
             inv_freq = llama3_scale_freqs(
                 inv_freq,
@@ -106,7 +111,7 @@ def rope_tables(max_seq_len: int, head_dim: int, base: float = 10000.0,
         else:
             raise ValueError(
                 f"unsupported rope_scaling type {kind!r} (supported: "
-                f"'llama3', 'linear', 'yarn')")
+                f"'llama3', 'linear', 'yarn', 'none')")
     positions = jnp.arange(max_seq_len, dtype=jnp.float32)[:, None]  # [S, 1]
     angles = positions * inv_freq[None, :]  # [S, head_dim/2]
     if amplitude != 1.0:

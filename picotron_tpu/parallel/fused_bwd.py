@@ -99,8 +99,9 @@ def fused_bwd_supported(cfg: Config) -> bool:
     # one kind of block in one `layers` stack: latent attention, sandwich
     # norms, a shared expert, sigmoid routing and leading dense layers keep
     # the AD engine (Config.validate refuses an explicit 'fused' for them)
-    plain = (len(m.stacks) == 1 and not m.stacks[0][2].sandwich
+    plain = (len(m.stacks) == 1 and not m.stacks[0].block.sandwich
              and not m.mla and not m.n_shared_experts
+             and m.qk_norm != "head"
              and m.moe_scoring == "softmax"
              and m.routed_scaling_factor == 1.0
              and m.router_width == m.num_experts)

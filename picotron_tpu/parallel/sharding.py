@@ -59,7 +59,8 @@ def param_specs(cfg: Config) -> dict[str, Any]:
             layers.update({"b_q": P(pp, "tp"), "b_k": P(pp, "tp"),
                            "b_v": P(pp, "tp")})
         if m.qk_norm:
-            # whole-vector q/k norm weights: replicated (tp = 1 is validated)
+            # q/k norm weights, whole-vector or per-head: replicated (tp = 1
+            # is validated for both forms)
             layers.update({"q_norm": P(pp, None), "k_norm": P(pp, None)})
         if block.mlp == "experts":
             # expert banks [L, E, ...]: expert dim over 'ep', ffn dim over
@@ -80,7 +81,7 @@ def param_specs(cfg: Config) -> dict[str, Any]:
 
     specs = {
         "embedding": P("tp", None),
-        **{name: stack(block) for name, _, block in m.stacks},
+        **{st.name: stack(st.block) for st in m.stacks},
         "final_norm": P(),
     }
     if not cfg.model.tie_word_embeddings:

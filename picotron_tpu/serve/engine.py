@@ -1118,7 +1118,7 @@ class ServeEngine:
                 nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
                 if self.cfg.num_experts:
                     touched, visits, here, picks = (int(c) for c in counts)
-                    slots = (self.cfg.stacks[-1][1] * interval
+                    slots = (self.cfg.stacks[-1].layers * interval
                              * self.cfg.num_experts)
                     self.stats["experts_touched"] += touched
                     self.stats["expert_visits"] += visits

@@ -66,13 +66,17 @@ axis; its sliding layers keep a second, smaller pool in which a slot holds
 a fixed RING of `ring_blocks_for(window, prefill_chunk, block_size)`
 blocks, given at admission and never grown: position `p` lives in ring
 entry `(p // block_size) % ring`, so a sequence overwrites what has left
-every later query's band. What a ring entry holds now is known from the
-last position written (`_ring_positions`), and a key is masked by that
-position, as a key past the length is. Neither kind of layer builds a
-whole view at prefill: `_tiled_attention` walks the keys in tiles of
-blocks under an online softmax (a full layer as far as the longest row of
-the batch reaches, a sliding layer over its ring), so a 16k-position row
-never has its `[chunk, 16384]` scores in memory at once.
+every later query's band. The ring is sized by what prefill needs
+(window + chunk), whichever is the larger: K-EXAONE's window of 128 under
+a chunk of 256 is a ring of 25 blocks of which a decode step's band lies
+in 9, and the decode kernel buffers those 9, not the ring. What a ring
+entry holds now is known from the last position written
+(`_ring_positions`), and a key is masked by that position, as a key past
+the length is. Neither kind of layer builds a whole view at prefill:
+`_tiled_attention` walks the keys in tiles of blocks under an online
+softmax (a full layer as far as the longest row of the batch reaches, a
+sliding layer over its ring), so a 16k-position row never has its
+`[chunk, 16384]` scores in memory at once.
 
 `BlockPool` is the host-side allocator: free-list alloc/free with
 all-or-nothing semantics and peak accounting, so the scheduler can make
@@ -274,7 +278,9 @@ class MixedPagedKVCache(NamedTuple):
     """The serving cache of a model with sliding-window and full layers
     side by side. `generate._decode_layers` calls it with `window` (the
     layer's band; None on a full layer) and `ki` (the layer's ordinal
-    among the layers of its kind, which is its index in its pool)."""
+    among the model's layers of its kind, which is its index in its pool:
+    a pool holds the layers of its kind of every stack of the layer tree,
+    a leading dense layer's beside the expert layers', in model order)."""
 
     k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
     v: jnp.ndarray
@@ -361,7 +367,8 @@ def init_mixed_cache(cfg: ModelConfig, num_blocks: int,
                      num_slots: int, max_blocks: int,
                      ring_blocks: int) -> MixedPagedKVCache:
     """Zeroed pools + all-unmapped tables for a model with sliding and
-    full layers: each pool's layer axis holds the layers of its kind."""
+    full layers: each pool's layer axis holds the layers of its kind, of
+    every stack (`cfg.layer_kinds` is the whole model's)."""
     kinds = cfg.layer_kinds
     dt = compute_dtype(cfg)
 

@@ -493,19 +493,20 @@ def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
 _COMPILED_MELLUM: dict = {}
 
 
-def compiled_mellum(topo, monkeypatch, program: str, rows=None):
+def compiled_mellum(topo, monkeypatch, program: str, rows=None,
+                    config: str = "mellum2-12b-a2.5b-8l"):
     """(compiled, the two pools' shapes, the pools' parameter numbers) of
-    `serve_decode` or `serve_prefill` (at `rows` rows) of the Mellum2
-    configuration at its widths, depth and serve settings on one described
-    chip, pools donated."""
+    `serve_decode` or `serve_prefill` (at `rows` rows) of a configuration
+    with sliding and full layers (Mellum2's, K-EXAONE's) at its widths,
+    depth and serve settings on one described chip, pools donated."""
     from picotron_tpu.serve.engine import _pools
     from picotron_tpu.serve.paged_cache import init_mixed_cache, ring_blocks_for
 
-    if (program, rows) in _COMPILED_MELLUM:
-        return _COMPILED_MELLUM[program, rows]
+    if (config, program, rows) in _COMPILED_MELLUM:
+        return _COMPILED_MELLUM[config, program, rows]
     fa = importlib.import_module("picotron_tpu.ops.flash_attention")
     monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
-    c = load("configs", "mellum2-12b-a2.5b-8l")
+    c = load("configs", config)
     cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
     m, sc = cfg.model, cfg.serve
     max_blocks = blocks_for(sc.max_model_len, sc.block_size)
@@ -541,7 +542,7 @@ def compiled_mellum(topo, monkeypatch, program: str, rows=None):
             cfg=m, temperature=0.0, top_k=0, interval=sc.decode_interval,
             eos_token_id=None)
     n = len(jax.tree.leaves(params))
-    out = _COMPILED_MELLUM[program, rows] = (
+    out = _COMPILED_MELLUM[config, program, rows] = (
         low.compile(), (cache.k.shape, cache.wk.shape), set(range(n, n + 4)))
     return out
 
@@ -603,6 +604,62 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
         # a period's [4, 64, ...] slices they were 3 GiB of temporaries, and a
         # second read and a write of every weight in a memory-bound step
         assert ma.temp_size_in_bytes < 0.5 * 2**30, ma.temp_size_in_bytes / 2**30
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 48)])
+def test_k_exaone_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `k-exaone-236b-a23b-5l-ep8` compile for a v5e
+    and fit it beside each other's pools; no pool of either kind is copied
+    whole and all four are written in place through BOTH stacks; the decode
+    step attends through the kernel in every layer: the dense stack's one
+    sliding layer, the expert stack's whole period (S, S, F) in its scan
+    body and the sliding layer left over after it; the band's kernel holds 9
+    pages a chunk (window 128 / 16 + 1), not the ring's 25; the experts of
+    each expert layer are one grouped kernel; the scopes the cell's metrics
+    read are there, the expert ones and the window ones in one program."""
+    name = "k-exaone-236b-a23b-5l-ep8"
+    comp, pool_shapes, pools = compiled_mellum(topo, monkeypatch, program, rows, name)
+    c = load("configs", name)
+    assert pool_shapes == ((8, 1, 48 * 1024, 16, 128), (8, 4, 48 * 25, 16, 128))
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"kv_write", "paged_attention", "attn_full", "attn_window", "mlp",
+                     "moe_router", "moe_dispatch", "moe_experts", "moe_shared", "sample"}
+    for shape in pool_shapes:
+        copies = whole_pool_copies(text, shape)
+        assert not copies, f"{program} copies a whole pool {shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    paged = [(n, op) for n, op in kernels if attn.search(n)]
+    grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
+    assert len(grouped) + len(paged) == len(kernels), kernels
+    scopes = set(load("layer_metrics", "moe_experts_ms.serve")["params"]["scopes"])
+    assert all(scopes <= words(op) for _, op in grouped), grouped
+    assert "ragged-dot" not in text
+    # the scan body's three expert layers and the one left over, each one kernel a
+    # block of tokens (`ops/moe.py MAX_SORTED_BYTES`)
+    assert len(grouped) % 4 == 0 and len(grouped) >= 4, kernels
+    if program == "serve_decode":
+        assert len(paged) == 5  # (S) | (S, S, F) in the scan body + (S) after it
+        assert sum("attn_window" in words(op) for _, op in paged) == 4
+        assert sum("attn_full" in words(op) for _, op in paged) == 1
+        # (that the windowed calls buffer the band's 9 pages and the full one 64
+        # is inside the kernels: tests/test_paged_attention.py
+        # test_a_chunk_is_sized_by_the_band)
+    else:
+        assert not paged  # a chunk walks its keys in tiles, no kernel
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)
+    assert total < 15.75 * 2**30, total / 2**30
 
 
 _COMPILED_PANGU: dict = {}

@@ -316,8 +316,11 @@ def test_dense_mlp_layers_are_refused_by_name():
         model_config_from_hf_json(dict(HF, mlp_layer_types=["sparse", "dense"] + ["sparse"] * 6))
     lead = model_config_from_hf_json(dict(HF, mlp_layer_types=["dense"] + ["sparse"] * 7))
     assert lead["first_k_dense_replace"] == 1
-    with pytest.raises(ValueError, match="first_k_dense_replace > 0 with sliding-window"):
-        ModelConfig(**lead).validate()
+    # ... and a leading dense layer under a layer pattern validates: the pattern is
+    # cut where the stacks are, each stack carrying its own slice
+    cfg = ModelConfig(**lead)
+    cfg.validate()
+    assert [st.kinds for st in cfg.stacks] == [cfg.layer_kinds[:1], cfg.layer_kinds[1:]]
 
 
 @pytest.mark.parametrize("what,kw", [

@@ -212,24 +212,31 @@ WINDOW_CASES = {
     "chunks_of_1_page": dict(hq=4, hkv=4, lengths=[3, 6 * BS + 5, WINDOW + 1], ppc=1),
     "bf16_pool": dict(hq=12, hkv=2, lengths=[5, 11 * BS + 9, 0], dtype=jnp.bfloat16,
                       tol=2e-2),
+    # K-EXAONE's shape of the matter: a band far shorter than the ring that prefill
+    # needs (window + chunk), 8 query heads a KV head; the chunk is sized by the band
+    "band_shorter_than_ring": dict(hq=16, hkv=2, ring=7, window=2 * BS,
+                                   lengths=[1, 2 * BS, 2 * BS + 1, 9 * BS + 5, 0]),
+    "band_off_block_edges": dict(hq=8, hkv=1, ring=6, window=BS + 3,
+                                 lengths=[BS + 3, 3 * BS, 6 * BS + 1, 13 * BS - 1]),
 }
 
 
 @pytest.mark.parametrize("name", WINDOW_CASES)
 def test_windowed_kernel_matches_jnp(name):
-    """The kernel with `window` reads, through a ring of RING blocks, the
-    positions max(length - WINDOW, 0) .. length - 1 and nothing else: every
+    """The kernel with `window` reads, through a ring of `ring` blocks, the
+    positions max(length - window, 0) .. length - 1 and nothing else: every
     pool position outside a live slot's band is NaN (the ring's blocks that
     hold positions before the band among them), and the result equals plain
     softmax attention over the band's keys."""
     c = WINDOW_CASES[name]
     d, dtype, li = 128, c.get("dtype", jnp.float32), 1
+    ring, window = c.get("ring", RING), c.get("window", WINDOW)
     lengths = np.asarray(c["lengths"], np.int32)
     rng = np.random.default_rng(sorted(WINDOW_CASES).index(name))
     free = list(rng.permutation(NB - 1))
-    tables = np.full((len(lengths), RING), NB, np.int32)
+    tables = np.full((len(lengths), ring), NB, np.int32)
     for b, n in enumerate(lengths):
-        for j in range(min(-(-n // BS), RING)):
+        for j in range(min(-(-n // BS), ring)):
             tables[b, j] = free.pop()
     shape = (c["hkv"], L, NB, BS, d)
     k = np.full(shape, np.nan, np.float32)
@@ -238,13 +245,13 @@ def test_windowed_kernel_matches_jnp(name):
     want = np.zeros_like(q)
     g = c["hq"] // c["hkv"]
     for b, n in enumerate(lengths):
-        band = np.arange(max(n - WINDOW, 0), n)
+        band = np.arange(max(n - window, 0), n)
         kb = rng.standard_normal((len(band), c["hkv"], d)).astype(np.float32)
         vb = rng.standard_normal((len(band), c["hkv"], d)).astype(np.float32)
         if dtype == jnp.bfloat16:
             kb, vb = (np.asarray(jnp.asarray(a, dtype), np.float32) for a in (kb, vb))
         for i, pos in enumerate(band):
-            blk = tables[b, (pos // BS) % RING]
+            blk = tables[b, (pos // BS) % ring]
             k[:, li, blk, pos % BS], v[:, li, blk, pos % BS] = kb[i], vb[i]
         for h in range(c["hq"]):
             s = kb[:, h // g] @ q[b, h] / np.sqrt(d)
@@ -252,12 +259,35 @@ def test_windowed_kernel_matches_jnp(name):
             want[b, h] = (p / max(p.sum(), 1e-30)) @ vb[:, h // g] if len(band) else 0
     got = paged_decode_attention(
         jnp.asarray(q, dtype), jnp.asarray(k, dtype), jnp.asarray(v, dtype), li,
-        jnp.asarray(tables), jnp.asarray(lengths), window=WINDOW,
+        jnp.asarray(tables), jnp.asarray(lengths), window=window,
         pages_per_chunk=c.get("ppc"), interpret=True)
     got = np.asarray(got, np.float32)
     assert np.isfinite(got).all() and (got[lengths == 0] == 0).all()
     tol = c.get("tol", 2e-5)
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("window,ring,pages", [
+    (128, 25, 9),     # K-EXAONE: the band's blocks + 1, not the ring that prefill needs
+    (1024, 82, 64),   # Mellum2: the band is longer than a chunk, which stays the default
+    (None, 1024, 64),  # a full layer
+], ids=["k_exaone", "mellum2", "full"])
+def test_a_chunk_is_sized_by_the_band(window, ring, pages):
+    """The pages a chunk the kernel double-buffers in VMEM: at most what a
+    band can lie in, whatever the ring holds beside it for prefill."""
+    import functools
+
+    hkv, d, slots = 8, 128, 4
+    pool = jax.ShapeDtypeStruct((hkv, 2, 64, BS, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        functools.partial(paged_decode_attention, window=window, interpret=True),
+        static_argnums=(3,))(
+        jax.ShapeDtypeStruct((slots, 64, d), jnp.bfloat16), pool, pool, 1,
+        jax.ShapeDtypeStruct((slots, ring), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    bufs = [a.shape for a in call.params["grid_mapping"].scratch_avals][:2]
+    assert bufs == [(2, hkv, pages, BS, d)] * 2
 
 
 def test_a_ring_too_short_for_the_band_is_refused():

@@ -94,7 +94,7 @@ def test_forward_matches_the_reference(share):
 def test_built_tree_has_two_stacks_and_the_counted_parameters():
     cfg = tiny()
     params = init_params(cfg, jax.random.key(0))
-    assert [name for name, _, _ in cfg.stacks] == ["dense_layers", "layers"]
+    assert [st.name for st in cfg.stacks] == ["dense_layers", "layers"]
     assert params["dense_layers"]["gate"].shape == (1, 64, 128)
     assert "w_gate" not in params["dense_layers"] and "gate" not in params["layers"]
     assert params["layers"]["w_gate"].shape == (3, 16, 64, 32)
@@ -107,7 +107,7 @@ def test_built_tree_has_two_stacks_and_the_counted_parameters():
     assert param_count(init_params(share, jax.random.key(0))) == num_params(share)
     # a model of one kind of block is the one `layers` stack it always was
     dense = ModelConfig(**resolve_preset("debug-tiny"))
-    assert [n for n, _, _ in dense.stacks] == ["layers"]
+    assert [st.name for st in dense.stacks] == ["layers"]
     assert set(init_params(dense, jax.random.key(0))) == {
         "embedding", "layers", "final_norm", "lm_head"}
 
