@@ -301,13 +301,17 @@ class DisaggServeEngine(ServeEngine):
             pool_sharded=_sharded(self._k_p))
         return toks, logits
 
-    def _retire_prefilled(self, pslot: int, t: float) -> None:
+    def _retire_prefilled(self, pslot: int, t: float) -> int:
         # first token already finishes it: retire straight from the
         # prefill pool, no handoff needed
-        if self.sched.should_retire(pslot, self.eos_token_id, pslot=True):
-            st = self.sched.retire_prefill(pslot)
-            self._sync_ptable(pslot)
-            self._emit_retired(st, t)
+        if not self.sched.should_retire(pslot, self.eos_token_id,
+                                        pslot=True):
+            return 0
+        freed = self.sched.pslots[pslot].held_blocks
+        st = self.sched.retire_prefill(pslot)
+        self._sync_ptable(pslot)
+        self._emit_retired(st, t)
+        return freed
 
     # -- handoff -----------------------------------------------------------
 

@@ -378,6 +378,13 @@ def prefill_rungs(num_slots: int) -> tuple:
 # ---------------------------------------------------------------------------
 
 
+# New host code goes below this line, not above the device programs: the
+# decode kernel's Mosaic body carries the file and line of its callers
+# (`serve_decode` among them) where the compile cache's key still sees them,
+# so a line added above the programs costs every checkout at the same path a
+# recompile of the decode program.
+
+
 class ServeEngine:
     def __init__(self, params, model_cfg: ModelConfig,
                  serve_cfg: Optional[ServeConfig] = None, *,
@@ -617,6 +624,7 @@ class ServeEngine:
         self._leaves: list = []  # this step's (name, start, secs)
         self._in_flight = 0  # dispatches the last steps left un-waited
         self._step_compile_s = 0.0  # compile seconds drained in this step
+        self._step_blocks_freed = 0  # blocks its retirements gave back
         self._gc_count, self._gc_before = None, None  # see `step`
         self._walls: deque = deque(maxlen=4096)  # for the summary's median
         # the last seconds of each part a step is judged slow by
@@ -647,13 +655,17 @@ class ServeEngine:
 
     _PREFILL_PHASE: dict = {}  # further keys of the `phase=prefill` event
 
-    def _retire_prefilled(self, slot: int, t: float) -> None:
+    def _retire_prefilled(self, slot: int, t: float) -> int:
         """A request whose first token already ends it (EOS, a budget of
-        one) leaves straight from the pool it was prefilled in."""
-        if self.sched.should_retire(slot, self.eos_token_id):
-            st = self.sched.retire(slot)
-            self._sync_table(slot)
-            self._emit_retired(st, t)
+        one) leaves straight from the pool it was prefilled in. Returns
+        the blocks it gave back (0: it stays)."""
+        if not self.sched.should_retire(slot, self.eos_token_id):
+            return 0
+        freed = self.sched.slots[slot].held_blocks
+        st = self.sched.retire(slot)
+        self._sync_table(slot)
+        self._emit_retired(st, t)
+        return freed
 
     def _run_prefill(self, feed):
         """One dispatch of the prefill program on `feed`; returns the
@@ -786,13 +798,14 @@ class ServeEngine:
 
         The step is one `serve.step` span whose leaf spans say what the
         host was doing (`serve.admit`, `serve.prefill.build | dispatch |
-        wait`, `serve.decode.build | dispatch | wait | emit`), each with
+        wait | emit`, `serve.decode.build | dispatch | wait | emit`), each with
         its counts taken at the same boundary: telemetry/spans.py. A step
         with device work ends by adding those leaves up (`_account_step`)."""
         if now is None:
             now = time.perf_counter() - self._t0
         self._leaves.clear()
         self._step_compile_s = 0.0
+        self._step_blocks_freed = 0
         # `gc.get_stats()` as the step starts, for a slow step's report:
         # read anew only when the cheaper `get_count` says that a
         # collection ran since the last look
@@ -867,6 +880,7 @@ class ServeEngine:
             leaves_ms=_ms(acct["leaves"]),
             starved_by_ms=_ms(acct["starved_by"]),
             compile_s=round(self._step_compile_s, 6),
+            blocks_freed=self._step_blocks_freed,
             gc_before=collections[0], gc_after=collections[1],
             active=active, queued=len(self.sched.queue),
             engine=self.engine_id)
@@ -878,10 +892,11 @@ class ServeEngine:
         log.warning(
             "serve engine=%d slow step: %s %.3f s (limit %.3f for %d "
             "dispatched) of wall %.3f s, longest leaf %s %.3f s, starved "
-            "%.3f s, compile %.3f s, collections %s -> %s, active %d, "
-            "queued %d%s", self.engine_id,
+            "%.3f s, compile %.3f s, blocks freed %d, collections %s -> "
+            "%s, active %d, queued %d%s", self.engine_id,
             kind, secs, limit, cleared, acct["wall_s"], name, longest,
-            acct["starved_s"], self._step_compile_s, *collections, active,
+            acct["starved_s"], self._step_compile_s,
+            self._step_blocks_freed, *collections, active,
             len(self.sched.queue),
             "; further slow steps are counted, not logged"
             if n == SLOW_STEP_LOGS else "")
@@ -979,16 +994,25 @@ class ServeEngine:
             self.sched.note_prefilled(s, int(nval[row]))
         self.stats["prefill_chunks"] += len(pslots)
         self.stats["prefill_tokens"] += n_prefilled
-        for row in finals:
-            st = states[pslots[row]]
-            st.generated.append(int(toks[row]))
-            st.logits.append(float(logits[row]))
-            self.stats["output_tokens"] += 1
-            if st.t_first_token is None:
-                st.t_first_token = now + dt
-                ttft = max(st.t_first_token - st.req.arrival, 0.0)
-                reg.histogram("serve/ttft").observe(ttft)
-            self._retire_prefilled(pslots[row], now + dt)
+        if not finals:
+            return True
+        n_retired = n_freed = 0
+        with self._span("serve.prefill.emit") as sp:
+            for row in finals:
+                st = states[pslots[row]]
+                st.generated.append(int(toks[row]))
+                st.logits.append(float(logits[row]))
+                self.stats["output_tokens"] += 1
+                if st.t_first_token is None:
+                    st.t_first_token = now + dt
+                    ttft = max(st.t_first_token - st.req.arrival, 0.0)
+                    reg.histogram("serve/ttft").observe(ttft)
+                freed = self._retire_prefilled(pslots[row], now + dt)
+                n_retired += bool(freed)
+                n_freed += freed
+            sp.set(tokens=len(finals), retired=n_retired,
+                   blocks_freed=n_freed)
+        self._step_blocks_freed += n_freed
         return True
 
     def _decode_tick(self, now: float, reg) -> bool:
@@ -1136,7 +1160,7 @@ class ServeEngine:
         if csecs:
             self.stats["decode_compiles"] += 1
         dt -= min(csecs, dt)
-        n_tokens = n_retired = 0
+        n_tokens = n_retired = n_freed = 0
         with self._span("serve.decode.emit") as sp:
             for s in active:
                 st = self.sched.slots[s]
@@ -1159,13 +1183,17 @@ class ServeEngine:
                         if self.sched.should_retire(
                                 s, self.eos_token_id):
                             # tokens past EOS/budget are padding
+                            n_freed += st.held_blocks
                             rst = self.sched.retire(s)
                             self._sync_table(s)
                             self._emit_retired(rst, now + dt)
                             retired = True
                             n_retired += 1
                             break
-            sp.set(tokens=n_tokens, retired=n_retired)
+            # `blocks_freed`: what the retirements gave back to the pools,
+            # the size of the one thing here that grows with a request
+            sp.set(tokens=n_tokens, retired=n_retired, blocks_freed=n_freed)
+        self._step_blocks_freed += n_freed
         self.telemetry.emit("phase", phase="decode",
                             category="decode", secs=dt,
                             tokens=n_tokens, ids=dec_ids)

@@ -510,13 +510,18 @@ class BlockPool:
     All-or-nothing `alloc(n)` (a partially-allocated sequence could never
     run and would strand blocks), LIFO reuse (freshly-freed blocks are the
     ones whose stale contents the causal mask already screens), and peak
-    accounting for the pool-utilization telemetry."""
+    accounting for the pool-utilization telemetry.
+
+    Beside the list a byte a block says whether the block is free, so that
+    `free` tells a double free without looking through the list: giving
+    blocks back costs their number, whatever the pool holds."""
 
     def __init__(self, num_blocks: int):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         self.num_blocks = num_blocks
         self._free = list(range(num_blocks - 1, -1, -1))
+        self._is_free = bytearray(b"\x01") * num_blocks
         self.peak_in_use = 0
 
     @property
@@ -535,13 +540,23 @@ class BlockPool:
         if n > len(self._free):
             return None
         out = [self._free.pop() for _ in range(n)]
+        for b in out:
+            self._is_free[b] = 0
         self.peak_in_use = max(self.peak_in_use, self.in_use)
         return out
 
     def free(self, blocks) -> None:
-        for b in blocks:
-            if not 0 <= b < self.num_blocks:
-                raise ValueError(f"freeing unknown block {b}")
-            if b in self._free:
-                raise ValueError(f"double free of block {b}")
-            self._free.append(b)
+        """Give `blocks` back, in the order given. An unknown block or one
+        that is already free (from an earlier call or earlier in this
+        one) raises ValueError and leaves the pool as it was."""
+        blocks = list(blocks)
+        is_free = self._is_free
+        for i, b in enumerate(blocks):
+            known = 0 <= b < self.num_blocks
+            if not known or is_free[b]:
+                for marked in blocks[:i]:
+                    is_free[marked] = 0
+                raise ValueError(f"double free of block {b}" if known
+                                 else f"freeing unknown block {b}")
+            is_free[b] = 1
+        self._free.extend(blocks)

@@ -88,6 +88,11 @@ class RequestState:
     n_preempted: int = 0
 
     @property
+    def held_blocks(self) -> int:
+        """The blocks its retirement gives back, over both pools."""
+        return len(self.blocks) + len(self.wblocks)
+
+    @property
     def prefilling(self) -> bool:
         return self.n_prefilled < len(self.prefill_ids)
 

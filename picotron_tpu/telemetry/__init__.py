@@ -42,7 +42,8 @@ JSONL schema (one object per line; `ts` = time.time()):
                                       # recompile / watchdog_timeout ...
   {"ts", "kind": "serve_slow_step", "held_by", "held_s", "limit_s",
    "held_for", "wall_s", "starved_s", "unspanned_ms", "leaves_ms", "starved_by_ms",
-   "compile_s", "gc_before", "gc_after", "active", "queued", "engine"}
+   "compile_s", "blocks_freed", "gc_before", "gc_after", "active", "queued",
+   "engine"}
       # an engine step one of whose parts (a wait, by the `held_for`
       # dispatches it cleared, or `host`: the rest of the wall) is far
       # over the median of its own kind

@@ -87,6 +87,146 @@ def test_block_pool_accounting():
     assert pool.in_use == 0 and pool.peak_in_use == 6
 
 
+class ListPool:
+    """The plain reference: the free list alone, a double free found by
+    looking through it (what `BlockPool` was until it kept a byte a
+    block), and a call refused as a whole."""
+
+    def __init__(self, num_blocks):
+        self.num_blocks = num_blocks
+        self.free_list = list(range(num_blocks - 1, -1, -1))
+        self.peak_in_use = 0
+
+    @property
+    def free_blocks(self):
+        return len(self.free_list)
+
+    @property
+    def in_use(self):
+        return self.num_blocks - len(self.free_list)
+
+    def alloc(self, n):
+        if n > len(self.free_list):
+            return None
+        out = [self.free_list.pop() for _ in range(n)]
+        self.peak_in_use = max(self.peak_in_use, self.in_use)
+        return out
+
+    def free(self, blocks):
+        for i, b in enumerate(blocks):
+            if not 0 <= b < self.num_blocks:
+                raise ValueError(f"freeing unknown block {b}")
+            if b in self.free_list or b in blocks[:i]:
+                raise ValueError(f"double free of block {b}")
+        self.free_list.extend(blocks)
+
+
+@pytest.mark.parametrize("num_blocks", [1, 7, 64, 1000])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_block_pool_matches_a_plain_list_pool(num_blocks, seed):
+    """Random alloc / free sequences, some of them faulty: the same ids in
+    the same order from every alloc, the same counts and the same refusals
+    as a pool that is nothing but its list."""
+    rng = np.random.default_rng(1000 * num_blocks + seed)
+    pool, ref = BlockPool(num_blocks), ListPool(num_blocks)
+    held = []  # the live allocations, as the callers hold them
+    n_refused = 0
+    for _ in range(400):
+        kind = rng.integers(0, 10)
+        if kind < 5:
+            n = int(rng.integers(0, max(2, num_blocks // 3)))
+            got, want = pool.alloc(n), ref.alloc(n)
+            assert got == want
+            if got:
+                held.append(got)
+        elif kind < 8 and held:
+            blocks = held.pop(int(rng.integers(0, len(held))))
+            # part of an allocation, in an order of the caller's own
+            cut = int(rng.integers(0, len(blocks) + 1))
+            give, keep = blocks[:cut][::-1], blocks[cut:]
+            if keep:
+                held.append(keep)
+            pool.free(give)
+            ref.free(give)
+        else:
+            # a faulty call: a block that is unknown, free already, or given
+            # twice, behind some that would have been in order
+            good = list(held[-1]) if held else []
+            bad = [int(rng.choice([-1, num_blocks, num_blocks + 2]))]
+            if kind == 8 and ref.free_list:
+                bad = [int(rng.choice(ref.free_list))]
+            elif good:
+                bad = good[-1:]
+            errs = []
+            for p in (pool, ref):
+                with pytest.raises(ValueError) as e:
+                    p.free(good + bad)
+                errs.append(str(e.value))
+            assert errs[0] == errs[1]
+            n_refused += 1
+        assert (pool.free_blocks, pool.in_use, pool.peak_in_use) == (
+            ref.free_blocks, ref.in_use, ref.peak_in_use)
+    assert n_refused > 10
+    for blocks in held:
+        pool.free(blocks)
+        ref.free(blocks)
+    # the whole list, in its order
+    assert pool.in_use == 0 and pool.alloc(num_blocks) == ref.alloc(num_blocks)
+
+
+@pytest.mark.parametrize("case", ["unknown", "negative", "across_calls",
+                                  "within_one_call", "never_allocated"])
+def test_block_pool_refuses_and_stays_as_it_was(case):
+    """Each refusal by itself, behind blocks that were in order: the call
+    raises, and the pool hands out next what it would have without it."""
+    pool, twin = BlockPool(12), BlockPool(12)
+    a, b = pool.alloc(5), pool.alloc(3)
+    twin.alloc(5), twin.alloc(3)
+    pool.free(b[:1])
+    twin.free(b[:1])
+    call, word = {
+        "unknown": (a + [12], "unknown block 12"),
+        "negative": (a + [-1], "unknown block -1"),
+        "across_calls": (a + b[:1], f"double free of block {b[0]}"),
+        "within_one_call": (a + a[1:2], f"double free of block {a[1]}"),
+        "never_allocated": (a + [11], "double free of block 11"),
+    }[case]
+    with pytest.raises(ValueError, match=word):
+        pool.free(call)
+    assert pool.free_blocks == twin.free_blocks == 5
+    assert pool.in_use == 7 and pool.peak_in_use == 8
+    # the refused call marked nothing: the same blocks can still be freed,
+    # and come back in the order a pool that never saw the call gives them
+    pool.free(a)
+    twin.free(a)
+    assert pool.alloc(10) == twin.alloc(10)
+    assert pool.alloc(1) is None and pool.in_use == 12
+
+
+def test_block_pool_free_does_not_grow_with_the_pool():
+    """A long request's retirement costs its blocks: 1,920 blocks back to a
+    pool of 32,768 at 6% fill (the longdoc cell's longest request) takes
+    about what the same free takes in a pool of 2,048."""
+    import time
+
+    def best_of_3(num_blocks, others, n=1920):
+        best = float("inf")
+        for _ in range(3):
+            pool = BlockPool(num_blocks)
+            pool.alloc(others)
+            mine = pool.alloc(n)
+            t0 = time.perf_counter()
+            pool.free(mine)
+            best = min(best, time.perf_counter() - t0)
+            assert pool.in_use == others
+        return best
+
+    small = best_of_3(2048, 0)
+    large = best_of_3(32768, int(0.06 * 32768) - 1920)
+    assert large < 0.025, large
+    assert large < 5 * small + 1e-3, (small, large)
+
+
 # ---------------------------------------------------------------------------
 # scheduler (pure host logic)
 # ---------------------------------------------------------------------------
@@ -388,6 +528,41 @@ def test_pool_accounting_over_full_trace(tiny, requests5):
     # nonzero because sequences really allocated
     worst = sum(blocks_for(len(p) + n, 4) for p, n in requests5)
     assert 0 < eng.pool.peak_in_use <= worst
+
+
+def test_emit_spans_count_the_blocks_a_run_gave_back(tiny, requests5):
+    """`blocks_freed` on the emit spans, summed over a drained run, is what
+    the run allocated: every block leaves through a retirement here (the
+    pool is wide enough that nothing is preempted), the request whose first
+    token ends it through `serve.prefill.emit`."""
+    eng, tel = traced_engine(tiny)
+    allocated = []
+    alloc = eng.pool.alloc
+
+    def counting_alloc(n):
+        got = alloc(n)
+        allocated.extend(got or [])
+        return got
+
+    eng.pool.alloc = counting_alloc
+    reqs = requests5 + [(requests5[0][0], 1)]
+    eng.run(reqs)
+    emits = {name: [e["args"] for e in tel.tracer.to_json()["traceEvents"]
+                    if e["ph"] == "X" and e["name"] == name]
+             for name in ("serve.decode.emit", "serve.prefill.emit")}
+    eng.close()
+    assert eng.summary["preemptions"] == 0 and eng.pool.in_use == 0
+    freed = {name: sum(a["blocks_freed"] for a in spans)
+             for name, spans in emits.items()}
+    assert sum(freed.values()) == len(allocated) > 0
+    # the budget of one holds its prompt's blocks and never decodes
+    assert freed["serve.prefill.emit"] == blocks_for(len(reqs[-1][0]), 4)
+    for spans in emits.values():
+        assert sum(a["retired"] for a in spans) > 0
+        assert all((a["blocks_freed"] > 0) == (a["retired"] > 0)
+                   for a in spans)
+    assert sum(a["retired"] for spans in emits.values()
+               for a in spans) == len(reqs)
 
 
 # ---------------------------------------------------------------------------
@@ -1066,6 +1241,43 @@ def test_slow_step_makes_one_event_and_one_log_line(tiny, monkeypatch, caplog):
     assert len(records) == 1 and records[0].levelname == "WARNING"
     assert "longest leaf serve.decode.wait" in records[0].getMessage()
     assert eng.stats["step_wall_max_s"] >= e["wall_s"] - 1e-6
+    eng.close()
+
+
+def test_a_slow_retirement_says_what_it_gave_back(tiny, monkeypatch, caplog):
+    """A step the host holds under the emit (a pool that takes its time
+    over a retirement, as the free list's scan did): the event is held by
+    `host`, names the emit, and carries the blocks the step gave back."""
+    import time as _time
+
+    from picotron_tpu.serve import engine as engine_mod
+    from picotron_tpu.telemetry import Telemetry
+
+    cfg, params = tiny
+    cap = _Events()
+    eng = ServeEngine(params, cfg, scfg(decode_interval=1, max_model_len=64),
+                      telemetry=Telemetry(sinks=[cap]))
+    eng.submit([3, 1, 4, 1, 5], 40)
+    eng.submit([2, 7, 1, 8, 2, 8], engine_mod.SLOW_STEP_AFTER + 4)
+    free = eng.pool.free
+
+    def slow_free(blocks):
+        _time.sleep(engine_mod.SLOW_STEP_S + 0.15)
+        free(blocks)
+
+    monkeypatch.setattr(eng.pool, "free", slow_free)
+    with caplog.at_level("WARNING", logger="picotron_tpu.serve"):
+        while eng.sched.n_retired == 0:
+            eng.step(0.0)
+    (e,) = [e for e in cap.events if e["kind"] == "serve_slow_step"]
+    assert e["held_by"] == "host" and e["held_for"] == 1
+    assert max(e["leaves_ms"], key=e["leaves_ms"].get) == "serve.decode.emit"
+    assert e["starved_by_ms"]["serve.decode.emit"] >= engine_mod.SLOW_STEP_S * 1e3
+    # 6 prompt + 20 new tokens, the last never written, in blocks of 4
+    assert e["blocks_freed"] == blocks_for(6 + 20 - 1, 4) == 7
+    (record,) = [r for r in caplog.records if r.name == "picotron_tpu.serve"]
+    assert "longest leaf serve.decode.emit" in record.getMessage()
+    assert "blocks freed 7" in record.getMessage()
     eng.close()
 
 

@@ -24,8 +24,8 @@ from picotron_tpu.telemetry.flightdeck import (
 from picotron_tpu.telemetry.spans import Span, join_ids, span
 
 LEAVES = ("serve.admit", "serve.prefill.build", "serve.prefill.dispatch",
-          "serve.prefill.wait", "serve.decode.build", "serve.decode.dispatch",
-          "serve.decode.wait", "serve.decode.emit")
+          "serve.prefill.wait", "serve.prefill.emit", "serve.decode.build",
+          "serve.decode.dispatch", "serve.decode.wait", "serve.decode.emit")
 CHUNK, SLOTS = 4, 2
 
 
@@ -168,12 +168,21 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
     if ends_in_chunk:
         waits = [a[3] for a in anns if a[0] == "serve.prefill.wait"]
         assert sum(d["finals"] for d in waits) == 2
+        # the first tokens are appended under a span of their own; no
+        # request ends at its first token here
+        first = [a[3] for a in anns if a[0] == "serve.prefill.emit"]
+        assert sum(d["tokens"] for d in first) == 2
+        assert all(d["retired"] == d["blocks_freed"] == 0 for d in first)
         dec = [a[3] for a in anns if a[0] == "serve.decode.dispatch"]
         assert all(d["interval"] == 2 and 1 <= d["active"] <= SLOTS for d in dec)
         emit = [a[3] for a in anns if a[0] == "serve.decode.emit"]
         # each request's first token comes from its prefill chunk
         assert sum(d["tokens"] for d in emit) == 2 * (4 - 1)
         assert sum(d["retired"] for d in emit) == 2
+        # a retirement gives back the blocks its cached positions filled:
+        # prompt + 4 tokens less the last, which is never written
+        assert sum(d["blocks_freed"] for d in emit) == 2 + 3
+        assert all((d["blocks_freed"] > 0) == (d["retired"] > 0) for d in emit)
         build = [a[3] for a in anns if a[0] == "serve.decode.build"]
         assert build[0]["rebuilt"] == 1 and all(d["preempted"] == 0 for d in build)
         # one request's spans share its id

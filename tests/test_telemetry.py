@@ -386,7 +386,7 @@ def test_serve_host_category_reproduces_and_leaves_training_alone(tmp_path):
                  secs=secs, engine=0)
     tel.emit("serve_slow_step", held_by="serve.decode.wait", held_s=2.4,
              limit_s=0.25, wall_s=2.5, starved_s=0.01, unspanned_ms=1.0,
-             engine=0,
+             engine=0, blocks_freed=900,
              leaves_ms={"serve.decode.wait": 2400.0, "serve.decode.emit": 9.0})
     tel.emit("serve_request", id=1, output_tokens=4, ttft_s=0.1,
              queue_wait_s=0.5)
@@ -409,7 +409,7 @@ def test_serve_host_category_reproduces_and_leaves_training_alone(tmp_path):
     text = report.render(s)
     assert "device fed share 0.75" in text
     assert "serve.decode.wait 2.4 s (limit 0.25) of wall 2.5 s" in text
-    assert "longest leaf serve.decode.wait 2400.0 ms" in text
+    assert "longest leaf serve.decode.wait 2400.0 ms, blocks freed 900" in text
 
     # a training stream: the same goodput % as before the category existed
     tel = Telemetry(sinks=[])

@@ -320,7 +320,8 @@ def host_view(categories: dict[str, float],
     `serve_host`), the share of the rest that the device was fed
     (`prefill` + `decode` over those plus `serve_host`), and each
     `serve_slow_step` with the part that was over its limit (`held_by`)
-    and the leaf that held most of it."""
+    and the leaf that held most of it, beside the blocks its retirements
+    gave back."""
     view: dict = {}
     host = categories.get("serve_host")
     if host is not None:
@@ -334,6 +335,7 @@ def host_view(categories: dict[str, float],
              "wall_s": e.get("wall_s"), "starved_s": e.get("starved_s"),
              "held_by": e.get("held_by"), "held_s": e.get("held_s"),
              "limit_s": e.get("limit_s"),
+             "blocks_freed": e.get("blocks_freed"),
              "longest_leaf": max(
                  {**(e.get("leaves_ms") or {}),
                   "unspanned": e.get("unspanned_ms") or 0.0}.items(),
@@ -477,7 +479,7 @@ def render(s: dict, markdown: bool = False) -> str:
                 f"  slow step: engine {st['engine']} {st['held_by']} "
                 f"{st['held_s']} s (limit {st['limit_s']}) of wall "
                 f"{st['wall_s']} s (starved {st['starved_s']} s), longest "
-                f"leaf {leaf} {ms} ms")
+                f"leaf {leaf} {ms} ms, blocks freed {st['blocks_freed']}")
         if "handoffs" in sv or "prefill_slot_occupancy" in sv:
             lines.append(
                 f"  disagg: prefill occupancy "
