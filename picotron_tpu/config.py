@@ -235,6 +235,41 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         norm_topk_prob=True, moe_scoring="sigmoid",
         routed_scaling_factor=2.5, router_aux_coef=0.0,
     ),
+    # EvaByte (6.5B, byte-level; model_type evabyte, attention_class eva):
+    # 32 layers, h 4096, 32:32 heads x 128, SwiGLU 11,008, no bias, a
+    # vocabulary of 320 rows (bytes and a few specials), an untied head of
+    # num_pred_heads 8 x 320 rows (head j at position t scores byte
+    # t + 1 + j), RMSNorm with 1 + w (norm_add_unit_offset), residual adds
+    # and logits in float32, RoPE theta 1e5 unscaled over 32,768 positions.
+    # Attention is EVA (ops/eva.py): positions fall into windows of
+    # window_size 2,048 and chunks of chunk_size 16; a query sees the keys
+    # of its own window one by one and every CLOSED window through one
+    # summary (a pooled key, a pooled value) a chunk, under one softmax.
+    # Built: forward() (plain attention, AD, one device), generate() and
+    # ServeEngine, whose paged cache holds window blocks (recycled at every
+    # window boundary) and summary blocks in one table a slot
+    # (serve/paged_cache.py EvaPagedCache); the served byte is head 0's.
+    # Assumed, with no key in config.json (benchmark/reference_evabyte.py
+    # repeats the list): (a) a chunk's pooled key is sum_j softmax_j(s k_j .
+    # mu) k_j and its pooled value sum_j softmax_j(s k_j . phi) v_j, s =
+    # head_dim^-1/2, one learned vector mu and one phi a head and layer
+    # (eva_mu, eva_phi); (b) keys are pooled after rotation; (c) a window's
+    # summaries are seen from the next window on; (d) the one scale s on
+    # both kinds of score, no count term on a summary's; (e) mu, phi drawn
+    # unit normal clamped to [-1, 1]; (f) pre-norm, two norms a layer; (g)
+    # head j predicts byte t + 1 + j. Not built: training (no loss over the
+    # 8 heads, no banded kernel with summary keys: ROADMAP M9),
+    # self-speculative decoding from heads 1-7 (ROADMAP M8), tp / pp / cp /
+    # ep, the disaggregated engine, the fleet, the n-gram speculator.
+    "EvaByte/EvaByte": dict(
+        vocab_size=320, hidden_size=4096, intermediate_size=11008,
+        num_hidden_layers=32, num_attention_heads=32,
+        num_key_value_heads=32, head_dim=128,
+        max_position_embeddings=32768, rope_theta=100000.0,
+        rms_norm_eps=1e-5, attention_class="eva", window_size=2048,
+        chunk_size=16, num_pred_heads=8, norm_add_unit_offset=True,
+        fp32_skip_add=True,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -326,6 +361,18 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         moe_scoring="sigmoid", routed_scaling_factor=2.5,
         router_aux_coef=0.0,
     ),
+    # Tiny EvaByte-shaped debug model: EVA attention at window 32 and chunk
+    # 4 (8 summaries a window: two blocks of 4), 2 heads of 16, 3 prediction
+    # heads over the 320-row vocabulary, 1 + w norms, float32 residual
+    # stream. Served with block_size 4 and a prefill chunk of 8 or 16.
+    "picotron-tpu/debug-tiny-evabyte": dict(
+        vocab_size=320, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=16, max_position_embeddings=256, rope_theta=100000.0,
+        rms_norm_eps=1e-5, attention_class="eva", window_size=32,
+        chunk_size=4, num_pred_heads=3, norm_add_unit_offset=True,
+        fp32_skip_add=True,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -360,6 +407,8 @@ _PRESET_ALIASES = {
     "debug-tiny-pangu-moe": "picotron-tpu/debug-tiny-pangu-moe",
     "K-EXAONE-236B-A23B": "LGAI-EXAONE/K-EXAONE-236B-A23B",
     "debug-tiny-exaone-moe": "picotron-tpu/debug-tiny-exaone-moe",
+    "EvaByte": "EvaByte/EvaByte",
+    "debug-tiny-evabyte": "picotron-tpu/debug-tiny-evabyte",
 }
 
 
@@ -384,7 +433,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -395,7 +444,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
 
     mtype = hf.get("model_type", "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
-                 "pangu_ultra_moe", "exaone_moe")
+                 "pangu_ultra_moe", "exaone_moe", "evabyte")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -540,6 +589,23 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
                 "sliding_attention": {"rope_type": "default",
                                       "rope_theta": theta},
                 "full_attention": {"rope_type": "none"}}
+    if mtype == "evabyte":
+        # EvaByte: EVA attention by its own keys (attention_class eva,
+        # window_size, chunk_size), the head of num_pred_heads x vocab_size
+        # rows, RMSNorm with 1 + w, float32 residual adds. What config.json
+        # has no key for (the pooling law, when a window's summaries are
+        # seen) is `assumed` where a benchmark configuration states it.
+        if hf.get("attention_class", "eva") != "eva":
+            raise ValueError(
+                f"evabyte with attention_class "
+                f"{hf.get('attention_class')!r}: only 'eva' is built")
+        out["attention_class"] = "eva"
+        out["window_size"] = int(hf["window_size"])
+        out["chunk_size"] = int(hf["chunk_size"])
+        out["num_pred_heads"] = int(hf.get("num_pred_heads", 1))
+        out["norm_add_unit_offset"] = bool(
+            hf.get("norm_add_unit_offset", False))
+        out["fp32_skip_add"] = bool(hf.get("fp32_skip_add", False))
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -685,7 +751,9 @@ class Block(NamedTuple):
     training forward (`models.llama.decoder_layer`) and the cached decode
     forward (`generate._decode_layers`) both read."""
 
-    attn: str       # "gqa": q/k/v per head | "mla": latent attention
+    # "gqa": q/k/v per head | "mla": latent attention | "eva": q/k/v per
+    # head over the open window's keys and a summary a chunk of the rest
+    attn: str
     mlp: str        # "dense": gated MLP | "experts": routed (+ shared) experts
     sandwich: bool  # RMSNorms on the attention's and the MLP's outputs too
 
@@ -841,6 +909,28 @@ class ModelConfig:
     # Four RMSNorms a layer: the attention's and the MLP's OUTPUTS are
     # normed too before they join the residual stream (Pangu Ultra).
     sandwich_norm: bool = False
+    # The attention's law: "softmax" over every (or a band of) earlier
+    # key, or "eva" (the published key `attention_class`; ops/eva.py):
+    # positions fall into windows of `window_size` and chunks of
+    # `chunk_size`, a query sees the keys of its own window and one learned
+    # summary (eva_mu, eva_phi: a pooled key and value) a chunk of every
+    # closed window, under one softmax. Not `layer_types` /
+    # `sliding_window`: this window is block-aligned (it resets, it does
+    # not slide) and the same in every layer.
+    attention_class: str = "softmax"
+    window_size: int = 0
+    chunk_size: int = 0
+    # RMSNorm scales by 1 + w (the published key; w is drawn 0).
+    norm_add_unit_offset: bool = False
+    # The residual stream is float32: the blocks' outputs are added to it
+    # in float32 and the final norm and the head read it so (the published
+    # keys fp32_skip_add / fp32_logits; the program's logits are float32
+    # for every model).
+    fp32_skip_add: bool = False
+    # The head holds num_pred_heads x vocab_size rows: head j at position t
+    # scores token t + 1 + j. forward() returns all of them; a served token
+    # is head 0's (heads 1.. draft, which is not built: ROADMAP M8).
+    num_pred_heads: int = 1
     # Accepted for reference compat (ref uses them to pick CUDA kernels).
     use_flash_attention: bool = True
     use_fused_adam: bool = True
@@ -910,6 +1000,10 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def eva(self) -> bool:
+        return self.attention_class == "eva"
+
+    @property
     def rope_dim(self) -> int:
         """The rotated width of a head: all of it, or MLA's shared
         qk_rope_head_dim."""
@@ -928,7 +1022,7 @@ class ModelConfig:
         dense layers of a model with first_k_dense_replace are
         `dense_layers`. The pattern of `layer_types` is cut where the
         stacks are: each stack carries its own slice."""
-        attn = "mla" if self.mla else "gqa"
+        attn = ("mla" if self.mla else "eva" if self.eva else "gqa")
         n, k = self.num_hidden_layers, self.first_k_dense_replace
         kinds = self.layer_kinds
         if not self.num_experts:
@@ -1007,6 +1101,44 @@ class ModelConfig:
                 "q_lora_rank / qk_nope_head_dim / qk_rope_head_dim / "
                 "v_head_dim are latent attention's (MLA) widths: set "
                 "kv_lora_rank > 0 with them, or none of them")
+        if self.attention_class not in ("softmax", "eva"):
+            raise ValueError(
+                f"attention_class must be 'softmax' or 'eva', got "
+                f"{self.attention_class!r}")
+        if self.eva:
+            w, c = self.window_size, self.chunk_size
+            if c < 1 or w < c or w % c:
+                raise ValueError(
+                    f"attention_class 'eva' needs chunk_size >= 1 and a "
+                    f"window_size that is a whole number of chunks, got "
+                    f"window_size {w}, chunk_size {c}")
+            if (self.mla or self.layer_types is not None or self.num_experts
+                    or self.attention_bias or self.qk_norm
+                    or self.sandwich_norm or self.rope_parameters):
+                raise ValueError(
+                    "attention_class 'eva' is built with plain q/k/v "
+                    "heads, one RoPE law and a dense MLP: latent attention, "
+                    "layer_types, experts, attention_bias, qk_norm, "
+                    "sandwich_norm and rope_parameters must be unset")
+        elif self.window_size or self.chunk_size:
+            raise ValueError(
+                "window_size / chunk_size are attention_class 'eva''s: set "
+                "it with them, or neither")
+        if (self.norm_add_unit_offset or self.fp32_skip_add) and (
+                self.mla or self.num_experts or self.sandwich_norm
+                or self.qk_norm):
+            raise ValueError(
+                "norm_add_unit_offset / fp32_skip_add are built for a block "
+                "of q/k/v heads and a dense MLP with two norms a layer: "
+                "latent attention, experts, sandwich_norm and qk_norm must "
+                "be unset")
+        if self.num_pred_heads < 1:
+            raise ValueError(
+                f"num_pred_heads must be >= 1, got {self.num_pred_heads}")
+        if self.num_pred_heads > 1 and self.tie_word_embeddings:
+            raise ValueError(
+                "num_pred_heads > 1 needs an untied head (the head holds "
+                "num_pred_heads x vocab_size rows)")
         if self.first_k_dense_replace:
             if not self.num_experts:
                 raise ValueError(
@@ -1911,9 +2043,12 @@ class Config:
         """Latent attention, sandwich norms, a shared expert, sigmoid
         routing, a held share of the experts, leading dense layers (with
         or without a layer pattern cut over the two stacks), per-head
-        QK-norm and an unrotated layer kind run on the plain attention of
-        `forward()` (and its AD), on `generate()` and on `ServeEngine`, on
-        one device. Every path that has its own copy of the block, one
+        QK-norm, an unrotated layer kind, EVA attention, a head of several
+        prediction heads, 1 + w norms and a float32 residual stream run on
+        the plain attention of `forward()` (and its AD), on `generate()`
+        and on `ServeEngine`, on one device (EVA and several prediction
+        heads have no training loss at all: `refuse_training`). Every
+        path that has its own copy of the block, one
         head width for q, k and v, or a single `layers` stack refuses them
         by name (ROADMAP M5, M3, D6)."""
         d, m, t, sv = (self.distributed, self.model, self.training,
@@ -1931,6 +2066,10 @@ class Config:
             ("routed_scaling_factor != 1", m.routed_scaling_factor != 1.0),
             ("a held share of the experts (router_experts)",
              m.router_width != m.num_experts),
+            ("attention_class 'eva' (window_size / chunk_size)", m.eva),
+            ("num_pred_heads > 1", m.num_pred_heads > 1),
+            ("norm_add_unit_offset", m.norm_add_unit_offset),
+            ("fp32_skip_add", m.fp32_skip_add),
         ) if on]
         if not what:
             return
@@ -1974,6 +2113,43 @@ class Config:
 
     def replace(self, **sections: Any) -> "Config":
         return dataclasses.replace(self, **sections)
+
+
+def refuse_training(m: ModelConfig) -> None:
+    """The training entry points (`models.llama.loss_sum_count`, which every
+    train step differentiates, and `train.main`) refuse by name a model
+    that has no training loss: EVA attention trains through a banded kernel
+    with summary keys and its backward, which is not built (ROADMAP M9),
+    and a head of several prediction heads has no loss over them."""
+    what = [name for name, on in (
+        ("attention_class 'eva'", m.eva),
+        ("num_pred_heads > 1", m.num_pred_heads > 1)) if on]
+    if what:
+        raise ValueError(
+            f"model has {', '.join(what)}, which training does not "
+            f"implement (no loss over several prediction heads, no banded "
+            f"attention kernel with summary keys and its backward); such a "
+            f"model runs on forward(), generate() and ServeEngine")
+
+
+def check_eva_serving(m: ModelConfig, sv: ServeConfig) -> None:
+    """What `ServeEngine` needs of the serve settings to hold a model with
+    attention_class 'eva' in its paged cache (serve/paged_cache.py
+    EvaPagedCache): a prefill chunk never straddles a window and holds
+    whole chunks, a window, a window's summaries and a prefill chunk are
+    whole blocks."""
+    w, c, bs, pc = m.window_size, m.chunk_size, sv.block_size, sv.prefill_chunk
+    if w % pc or pc % c:
+        raise ValueError(
+            f"attention_class 'eva': serve.prefill_chunk ({pc}) must divide "
+            f"window_size ({w}) and be a whole number of chunks of "
+            f"chunk_size ({c}): a prefill chunk never straddles a window "
+            f"and summarises whole chunks")
+    if w % bs or (w // c) % bs or pc % bs:
+        raise ValueError(
+            f"attention_class 'eva': a window ({w} positions), a window's "
+            f"summaries ({w // c}) and a prefill chunk ({pc}) must each be "
+            f"a whole number of blocks of serve.block_size ({bs})")
 
 
 def resolved_cp_flavor(cfg: "Config") -> str:
@@ -2149,9 +2325,11 @@ def num_params(m: ModelConfig, active_only: bool = False,
             attn += 2 * m.head_dim  # q_norm / k_norm, one vector for all heads
         elif m.qk_norm:
             attn += q + kv  # q_norm / k_norm weights
+        if m.eva:
+            attn += 2 * kv  # eva_mu / eva_phi: a pooling vector a KV head
     norms = (4 if m.sandwich_norm else 2) * h  # RMSNorm weights a layer
     k = m.first_k_dense_replace
     layers = (l - k) * (attn + ffn + norms) + k * (attn + dense_ffn + norms)
-    head = (h * v if (not m.tie_word_embeddings or include_tied_head)
-            else 0)
+    head = (h * v * m.num_pred_heads
+            if (not m.tie_word_embeddings or include_tied_head) else 0)
     return v * h + layers + h + head  # embed + layers + final_norm (+ head)

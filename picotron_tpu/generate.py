@@ -59,8 +59,11 @@ from jax import lax
 from picotron_tpu.config import ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
     DEFAULT_CTX, _mlp_block, by_period, compute_dtype, final_hidden,
-    head_weight, kind_tables, layer_window, mlp_act, model_rope_tables,
-    qkv_proj, rms_norm, shared_expert,
+    kind_tables, layer_window, mlp_act, model_rope_tables, norm_weight,
+    qkv_proj, residual_stream, rms_norm, served_head, shared_expert,
+)
+from picotron_tpu.ops.eva import (
+    chunk_summaries, eva_attention, eva_summarise,
 )
 from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
@@ -156,8 +159,71 @@ class LatentCache(NamedTuple):
         return latent_attention(q_n, q_r, q_pos, fetch, tiles, tile, kv_b, cfg)
 
 
+class EvaCache(NamedTuple):
+    """Per-layer contiguous cache of a model with EVA attention
+    (ops/eva.py): every position's K/V row, [L, B, S_max, Hkv, D], and one
+    summary row a chunk, [L, B, ceil(S_max / chunk), Hkv, D]. The offline
+    twin of `serve.paged_cache.EvaPagedCache`, which keeps the open window's
+    rows only; this one keeps them all and masks, as `KVCache` does with a
+    sliding layer's band. The layer loop calls both alike: `write(li, k, v,
+    q_pos, mu, phi, cfg)` and `attend(li, q, q_pos, cfg)`."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    sk: jnp.ndarray
+    sv: jnp.ndarray
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[0]
+
+    def write(self, li, k_new, v_new, q_pos, mu, phi, cfg) -> "EvaCache":
+        """K/V [B, s, Hkv, D] into slots q_pos[0] .. q_pos[-1] of layer li
+        (contiguous, batch-shared positions), and the summary of every
+        chunk the segment completes: a prefill (s > 1, from a chunk
+        boundary on) summarises its own whole chunks, a decode step the
+        chunk its position ends, from the rows the cache holds."""
+        c, start = cfg.chunk_size, q_pos[0]
+        at = start // c  # the first summary row the segment may write
+        ck = lax.dynamic_update_slice(self.k, k_new[None],
+                                      (li, 0, start, 0, 0))
+        cv = lax.dynamic_update_slice(self.v, v_new[None],
+                                      (li, 0, start, 0, 0))
+        if k_new.shape[1] > 1:
+            ks, vs = chunk_summaries(k_new, v_new, mu, phi, c)
+        else:
+            first = jnp.maximum(start - (c - 1), 0)
+            ks, vs = eva_summarise(
+                lax.dynamic_slice_in_dim(ck[li], first, c, 1)[:, None],
+                lax.dynamic_slice_in_dim(cv[li], first, c, 1)[:, None],
+                mu, phi)
+            # a step that ends no chunk writes back what is there
+            ends = (start + 1) % c == 0
+            old = (lax.dynamic_slice_in_dim(self.sk[li], at, 1, 1),
+                   lax.dynamic_slice_in_dim(self.sv[li], at, 1, 1))
+            ks, vs = jnp.where(ends, ks, old[0]), jnp.where(ends, vs, old[1])
+        return EvaCache(
+            ck, cv,
+            lax.dynamic_update_slice(self.sk, ks[None], (li, 0, at, 0, 0)),
+            lax.dynamic_update_slice(self.sv, vs[None], (li, 0, at, 0, 0)))
+
+    def attend(self, li, q, q_pos, cfg):
+        def of(x):
+            return lax.dynamic_index_in_dim(x, li, 0, keepdims=False)
+
+        return eva_attention(q, of(self.k), of(self.v), of(self.sk),
+                             of(self.sv), q_pos, cfg.window_size,
+                             cfg.chunk_size)
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_length: int):
     dt = compute_dtype(cfg)
+    if cfg.eva:
+        shape = (cfg.num_hidden_layers, batch, max_length,
+                 cfg.num_key_value_heads, cfg.head_dim)
+        chunks = shape[:2] + (-(-max_length // cfg.chunk_size),) + shape[3:]
+        return EvaCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+                        jnp.zeros(chunks, dt), jnp.zeros(chunks, dt))
     if cfg.mla:
         return LatentCache(jnp.zeros(
             (cfg.num_hidden_layers, batch, max_length,
@@ -227,6 +293,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     needs, and the (row tile, expert) pairs the experts' kernel visited,
     which is what it read (`ops/moe.py moe_mlp_served`)."""
     dt = x.dtype
+    x = residual_stream(x, cfg)
     d = cfg.head_dim
     # a model of full layers calls the cache as it always has; one with
     # sliding layers says which kind each layer is
@@ -250,6 +317,20 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         # is a slice)
         with scope("paged_attention"):
             out = cache.attend(li, q, q_pos, **how)
+        return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
+
+    def eva(h, cache, lp, li, kind, ki):
+        """EVA attention (ops/eva.py): K and V written a head as `gqa`
+        writes them, and with them the summary of every chunk the segment
+        completes; the cache knows which rows a query may see."""
+        b, s, _ = h.shape
+        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
+        q = _rope(q, cos, sin, q_pos)
+        k = _rope(k, cos, sin, q_pos)
+        cache = cache.write(li, k, v, q_pos, lp["eva_mu"], lp["eva_phi"],
+                            cfg)
+        with scope("paged_attention"):
+            out = cache.attend(li, q, q_pos, cfg)
         return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
 
     def mla(h, cache, lp, li, kind, ki):
@@ -279,8 +360,9 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """One block, as `models.llama.decoder_layer` describes it
         (`block`), against the cache. `li`: the layer's index in the model
         (and in the cache); `bank_li`: in its stack's expert banks."""
-        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-        out, cache = (mla if block.attn == "mla" else gqa)(
+        h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
+                     cfg.rms_norm_eps).astype(dt)
+        out, cache = {"gqa": gqa, "mla": mla, "eva": eva}[block.attn](
             h, cache, lp, li, kind, ki)
         if block.sandwich:
             out = rms_norm(out, lp["attn_out_norm"], cfg.rms_norm_eps)
@@ -378,9 +460,11 @@ def _moe_served_block(x, lp, banks, li, cfg: ModelConfig, live):
 
 
 def _logits_last(params, x, cfg: ModelConfig):
-    """Logits of the LAST position only: [B, V] fp32."""
+    """Logits of the LAST position only: [B, V] fp32 (head 0's, of a head
+    of several prediction heads)."""
     hf = final_hidden(params, x[:, -1:], cfg)
-    return (hf @ head_weight(params).astype(hf.dtype))[:, 0].astype(jnp.float32)
+    return (hf @ served_head(params, cfg).astype(hf.dtype))[:, 0].astype(
+        jnp.float32)
 
 
 @scope("sample")

@@ -37,8 +37,11 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from picotron_tpu.config import Block, ModelConfig, pattern_of
+from picotron_tpu.config import (
+    Block, ModelConfig, pattern_of, refuse_training,
+)
 from picotron_tpu.ops.attention import sdpa_attention
+from picotron_tpu.ops.eva import chunk_summaries, eva_attention
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -193,6 +196,13 @@ def _uniform_fan_in(key, fan_in: int, shape) -> jnp.ndarray:
     return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
 
 
+def _norm_init(cfg: ModelConfig, shape) -> jnp.ndarray:
+    """A block norm's weight at its start: the scale is 1 either way
+    (`norm_weight`)."""
+    return (jnp.zeros if cfg.norm_add_unit_offset else jnp.ones)(
+        shape, jnp.float32)
+
+
 def _init_stack(cfg: ModelConfig, block: Block, nl: int,
                 key: jax.Array) -> Params:
     """One stack of `nl` layers of one kind of block (`cfg.stacks`), fp32,
@@ -211,8 +221,8 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
         return jnp.stack([_uniform_fan_in(ks[j], fan_in, shape) for j in range(nl)])
 
     layers = {
-        "input_norm": jnp.ones((nl, h), jnp.float32),
-        "post_norm": jnp.ones((nl, h), jnp.float32),
+        "input_norm": _norm_init(cfg, (nl, h)),
+        "post_norm": _norm_init(cfg, (nl, h)),
     }
     if block.sandwich:
         # norms on the attention's and the MLP's outputs (`post_norm` is
@@ -245,6 +255,15 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "v": stacked(keys[3], h, (h, kv_out)),
             "o": stacked(keys[4], q_out, (q_out, h)),
         })
+    if block.attn == "eva":
+        # EVA's pooling vectors, one a KV head: unit normal clamped to
+        # [-1, 1] an element, so that a chunk's summary is far from its
+        # mean (`assumed`: config.py, the EvaByte preset's comment)
+        ek = jax.random.split(keys[13], 2)
+        layers.update({
+            name: jnp.clip(jax.random.normal(
+                k, (nl, cfg.num_key_value_heads, d), jnp.float32), -1.0, 1.0)
+            for name, k in zip(("eva_mu", "eva_phi"), ek)})
     if cfg.attention_bias:
         # Qwen2-style qkv bias (zero-init, the HF convention)
         layers.update({
@@ -311,10 +330,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     params = {
         "embedding": jax.random.normal(keys[0], (v, h), jnp.float32),
         **stacks,
-        "final_norm": jnp.ones((h,), jnp.float32),
+        "final_norm": _norm_init(cfg, (h,)),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = _uniform_fan_in(keys[8], h, (h, v))
+        # num_pred_heads heads side by side: head j in columns j v .. (j+1) v
+        params["lm_head"] = _uniform_fan_in(
+            keys[8], h, (h, v * cfg.num_pred_heads))
     return params
 
 
@@ -326,6 +347,22 @@ def head_weight(params: Params) -> jnp.ndarray:
     # the head's [H, V/tp] layout).
     w = params.get("lm_head")
     return w if w is not None else params["embedding"].T
+
+
+def served_head(params: Params, cfg: ModelConfig) -> jnp.ndarray:
+    """The head a served token is sampled from: head 0 of a head of several
+    prediction heads (its first vocab_size columns; the others draft, which
+    is not built), else the whole head. The serve programs multiply these
+    columns and no others."""
+    w = head_weight(params)
+    return w[:, :cfg.vocab_size] if cfg.num_pred_heads > 1 else w
+
+
+def norm_weight(w, cfg: ModelConfig):
+    """The scale of a block norm (input, post, final): `1 + w` where the
+    config says so (norm_add_unit_offset). A transform of the weight, not a
+    second norm: every site calls `rms_norm` with it."""
+    return 1.0 + w if cfg.norm_add_unit_offset else w
 
 
 def param_count(params: Params) -> int:
@@ -402,7 +439,14 @@ def embed(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
             x = ctx.embed_lookup(w, input_ids)
         else:
             x = w[input_ids]
-        return x.astype(compute_dtype(cfg))
+        return residual_stream(x.astype(compute_dtype(cfg)), cfg)
+
+
+def residual_stream(x, cfg: ModelConfig):
+    """The residual stream the blocks add to: float32 where the config says
+    so (fp32_skip_add), else as it is. A block's norm hands its matmuls the
+    compute dtype either way."""
+    return x.astype(jnp.float32) if cfg.fp32_skip_add else x
 
 
 def qkv_proj(h, lp, d: int, eps: float = 1e-5):
@@ -457,7 +501,7 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     dt = x.dtype
     d = cfg.head_dim
 
-    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
     h = ctx.f(h)  # column-parallel entry: identity fwd / psum bwd; under
     # sequence parallelism an all_gather that restores the full sequence
     b, s, _ = h.shape
@@ -484,6 +528,28 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     out = out @ lp["o"].astype(dt)
     out = checkpoint_name(out, "attn_proj_out")
     return ctx.g(out)  # row-parallel exit: psum-over-tp fwd / identity bwd
+
+
+@scope("attention")
+def _eva_attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
+    """RMSNorm -> qkv -> RoPE -> EVA attention (ops/eva.py), dense: a mask
+    over the sequence's keys and the summaries of its whole chunks ->
+    out_proj. The one attention implementation of such a model on the
+    training-shaped path (Config.validate refuses the kernels, the cp
+    schedules and every sharded layout for it)."""
+    dt = compute_dtype(cfg)
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
+                 cfg.rms_norm_eps).astype(dt)
+    b, s, _ = h.shape
+    q, k, v = qkv_proj(h, lp, cfg.head_dim, cfg.rms_norm_eps)
+    pos = ctx.positions if ctx.positions is not None else jnp.arange(s)
+    q, k = apply_rope(q, cos, sin, pos), apply_rope(k, cos, sin, pos)
+    ks, vs = chunk_summaries(k, v, lp["eva_mu"], lp["eva_phi"],
+                             cfg.chunk_size)
+    out = eva_attention(q, k, v, ks, vs, pos, cfg.window_size,
+                        cfg.chunk_size)
+    out = out.reshape(b, s, -1) @ lp["o"].astype(dt)
+    return checkpoint_name(out, "attn_proj_out")
 
 
 @scope("attention")
@@ -533,8 +599,10 @@ _GELU = {approximate: partial(jax.nn.gelu, approximate=approximate)
 @scope("mlp")
 def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
     """RMSNorm -> gated MLP (ref: model.py:184-186)."""
-    dt = x.dtype
-    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(lp["post_norm"], cfg), cfg.rms_norm_eps)
+    if cfg.fp32_skip_add:  # the stream is float32, the matmuls are not
+        h = h.astype(compute_dtype(cfg))
+    dt = h.dtype
     h = ctx.f(h)
     gate = checkpoint_name(h @ lp["gate"].astype(dt), "mlp_gate")
     up = checkpoint_name(h @ lp["up"].astype(dt), "mlp_up")
@@ -607,6 +675,8 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     block = block or cfg.stacks[-1].block
     if block.attn == "mla":
         attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
+    elif block.attn == "eva":
+        attn_out = _eva_attention_block(x, lp, cfg, ctx, cos, sin)
     else:
         attn_out = _attention_block(x, lp, cfg, ctx, cos, sin, kind)
     if block.sandwich:
@@ -763,7 +833,8 @@ def run_stacks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 
 def final_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return rms_norm(x, norm_weight(params["final_norm"], cfg),
+                    cfg.rms_norm_eps)
 
 
 def logits_from_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig,
@@ -782,12 +853,16 @@ def logits_from_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 def forward(params: Params, input_ids: jnp.ndarray, cfg: ModelConfig,
             ctx: ParallelCtx = DEFAULT_CTX) -> jnp.ndarray:
-    """input_ids [B, S] -> logits [B, S, V] (full vocab; eval/debug path)."""
+    """input_ids [B, S] -> logits [B, S, V] (full vocab; eval/debug path);
+    [B, S, num_pred_heads, V] for a head of several prediction heads."""
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
     x, _ = run_stacks(params, x, cfg, ctx, cos, sin)
     x = final_hidden(params, x, cfg)
-    return logits_from_hidden(params, x, cfg, ctx)
+    logits = logits_from_hidden(params, x, cfg, ctx)
+    if cfg.num_pred_heads > 1:
+        logits = logits.reshape(*logits.shape[:2], cfg.num_pred_heads, -1)
+    return logits
 
 
 def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
@@ -809,6 +884,7 @@ def loss_sum_count(params: Params, input_ids: jnp.ndarray, targets: jnp.ndarray,
     capacity drops and busiest-expert load; {} for dense) that ride the
     same psum path; the step normalizes them.
     """
+    refuse_training(cfg)
     cos, sin = model_rope_tables(cfg)
     x = embed(params, input_ids, cfg, ctx)
     x, aux = run_stacks(params, x, cfg, ctx, cos, sin)

@@ -96,11 +96,14 @@ def fused_bwd_supported(cfg: Config) -> bool:
     recompute plan)."""
     d, t = cfg.distributed, cfg.training
     m = cfg.model
-    # one kind of block in one `layers` stack: latent attention, sandwich
-    # norms, a shared expert, sigmoid routing and leading dense layers keep
-    # the AD engine (Config.validate refuses an explicit 'fused' for them)
+    # one kind of block in one `layers` stack: latent and EVA attention,
+    # sandwich and 1 + w norms, a float32 residual stream, a shared expert,
+    # sigmoid routing and leading dense layers keep the AD engine
+    # (Config.validate refuses an explicit 'fused' for them)
     plain = (len(m.stacks) == 1 and not m.stacks[0].block.sandwich
-             and not m.mla and not m.n_shared_experts
+             and m.stacks[0].block.attn == "gqa"
+             and not m.norm_add_unit_offset and not m.fp32_skip_add
+             and not m.n_shared_experts
              and m.qk_norm != "head"
              and m.moe_scoring == "softmax"
              and m.routed_scaling_factor == 1.0

@@ -54,6 +54,10 @@ def param_specs(cfg: Config) -> dict[str, Any]:
                            "kv_a_norm": P(None, None)})
         else:
             layers.update({"q": col, "k": col, "v": col})
+        if block.attn == "eva":
+            # EVA's pooling vectors [L, Hkv, D]: one device only
+            layers.update({"eva_mu": P(None, None, None),
+                           "eva_phi": P(None, None, None)})
         if m.attention_bias:
             # qkv biases shard over tp with their output features
             layers.update({"b_q": P(pp, "tp"), "b_k": P(pp, "tp"),

@@ -121,16 +121,17 @@ class DisaggServeEngine(ServeEngine):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
         if (model_cfg.num_experts or model_cfg.layer_types is not None
-                or model_cfg.mla):
+                or model_cfg.mla or model_cfg.eva):
             raise ValueError(
                 "disaggregated serving does not support MoE models "
-                "(num_experts > 0), sliding-window layers or latent "
-                "attention: the block handoff between the two pools has "
-                "never run an expert block or another kind of cache "
-                "state, and nothing tests it with one. Serve them through "
-                "ServeEngine.")
+                "(num_experts > 0), sliding-window layers, latent "
+                "attention or attention_class 'eva': the block handoff "
+                "between the two pools has never run an expert block or "
+                "another kind of cache state, and nothing tests it with "
+                "one. Serve them through ServeEngine.")
         self.mixed, self.wpool = False, None  # one pool a side, full layers
         self.latent = False  # K and V per head (latent attention is refused)
+        self.eva = False  # a position a row (EVA attention is refused)
         self.cfg = model_cfg
         self.scfg = scfg
         self.eos_token_id = eos_token_id
@@ -140,6 +141,7 @@ class DisaggServeEngine(ServeEngine):
         self.max_len = scfg.max_model_len or model_cfg.max_position_embeddings
         self.block_size = scfg.block_size
         self.max_blocks = blocks_for(self.max_len, self.block_size)
+        self.table_width = self.max_blocks
         self.num_slots = scfg.decode_slots
         self.num_blocks = (scfg.num_blocks
                            or scfg.decode_slots * self.max_blocks)

@@ -86,16 +86,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from picotron_tpu.config import ModelConfig, ServeConfig
+from picotron_tpu.config import ModelConfig, ServeConfig, check_eva_serving
 from picotron_tpu.generate import _decode_layers, _logits_last
 from picotron_tpu.resilience import watchdog
 from picotron_tpu.models.llama import (
-    compute_dtype, final_hidden, head_weight, model_rope_tables,
+    compute_dtype, final_hidden, model_rope_tables, served_head,
 )
 from picotron_tpu.serve.paged_cache import (
-    BlockPool, LatentPagedCache, MixedPagedKVCache, PagedKVCache,
-    ShardedPagedKVCache, init_latent_cache, init_mixed_cache,
-    init_paged_cache, ring_blocks_for,
+    BlockPool, EvaPagedCache, LatentPagedCache, MixedPagedKVCache,
+    PagedKVCache, ShardedPagedKVCache, init_eva_cache, init_latent_cache,
+    init_mixed_cache, init_paged_cache, ring_blocks_for,
 )
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
@@ -212,18 +212,21 @@ def _sample_slots(logits, temperature: float, top_k: int, base_key, rids,
     )(lg, keys).astype(jnp.int32)
 
 
-def _paged_cache(k, v, tables, pool_sharded: bool):
+def _paged_cache(k, v, tables, pool_sharded: bool, cfg: ModelConfig):
     """The cache a serve program runs its layers against. `pool_sharded`
     (static; `_sharded` of the pool the engine feeds) says that a mesh
     shards the pool over the KV heads (tp > 1): attention then keeps the
     gathered view whatever the step, which the compiler partitions, and
     never the in-place kernel, which it does not. Pairs (full, window) of
     pools and tables are a model with sliding layers'; one pool and no
-    `v` is a latent cache (a model with latent attention)."""
+    `v` is a latent cache (a model with latent attention); a model with
+    EVA attention keeps window and summary blocks in the one pool."""
     if v is None:
         return LatentPagedCache(k, tables)
     if isinstance(k, (tuple, list)):
         return MixedPagedKVCache(k[0], v[0], k[1], v[1], *tables)
+    if cfg.eva:
+        return EvaPagedCache(k, v, tables)
     return (ShardedPagedKVCache if pool_sharded else PagedKVCache)(
         k, v, tables)
 
@@ -292,7 +295,7 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
         return ((nxt, positions, tidx, cache, done, touched + t),
                 (nxt, _logit_of(logits, nxt)))
 
-    cache = _paged_cache(k, v, tables, pool_sharded)
+    cache = _paged_cache(k, v, tables, pool_sharded, cfg)
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
         jax.lax.scan(one, (toks, positions, tidx, cache, done,
@@ -322,13 +325,13 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     s, c = chunk_ids.shape
     t = jnp.arange(c)[None, :]
     pos = jnp.where(t < n_valid[:, None], start_pos[:, None] + t, -1)
-    cache = _paged_cache(k, v, table_rows, pool_sharded)
+    cache = _paged_cache(k, v, table_rows, pool_sharded, cfg)
     x = params["embedding"][chunk_ids].astype(compute_dtype(cfg))
     x, cache = _decode_layers(params, x, cache, pos, cfg, cos, sin)
     last = jnp.maximum(n_valid - 1, 0)  # [S]
     h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [S,1,H]
     hf = final_hidden(params, h_last, cfg)
-    logits = (hf @ head_weight(params).astype(hf.dtype))[:, 0]
+    logits = (hf @ served_head(params, cfg).astype(hf.dtype))[:, 0]
     logits = logits.astype(jnp.float32)  # [S, V]
     toks = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
     return (*_pools(cache), toks, _logit_of(logits, toks))
@@ -397,12 +400,12 @@ class ServeEngine:
         self.speculate = scfg.speculator == "ngram"
         if self.speculate and (model_cfg.num_experts
                                or model_cfg.layer_types is not None
-                               or model_cfg.mla):
+                               or model_cfg.mla or model_cfg.eva):
             raise ValueError(
                 "serve.speculator='ngram' serves dense models of full "
                 "layers only: the speculative verify scan has never run "
-                "an expert block, a sliding-window layer or a latent "
-                "cache, and nothing tests it with one")
+                "an expert block, a sliding-window layer, a latent cache "
+                "or attention_class 'eva', and nothing tests it with one")
         self.params = params
         self.cfg = model_cfg
         self.scfg = scfg
@@ -436,7 +439,19 @@ class ServeEngine:
         # `v` (serve/paged_cache.py LatentPagedCache), sized from the
         # latent's width
         self.latent = model_cfg.mla
-        if self.latent:
+        # a model with EVA attention: one pool, a table row of two regions
+        # (serve/paged_cache.py EvaPagedCache); `table_width` is a row's
+        # entries, `max_blocks` stays the positions a slot may reach
+        self.eva = model_cfg.eva
+        self.table_width = self.max_blocks
+        if self.eva:
+            check_eva_serving(model_cfg, scfg)
+            cache = init_eva_cache(model_cfg, self.num_blocks,
+                                   self.block_size, self.num_slots,
+                                   self.max_len)
+            self._k, self._v = cache.k, cache.v
+            self.table_width = cache.tables.shape[1]
+        elif self.latent:
             cache = init_latent_cache(model_cfg, self.num_blocks,
                                       self.block_size, self.num_slots,
                                       self.max_blocks)
@@ -506,7 +521,7 @@ class ServeEngine:
             lambda x: x if getattr(x, "committed", True)
             else jax.device_put(x, self._rep_sh), self.params)
         # host mirror of the device block tables; sentinel = num_blocks
-        self._tables = np.full((self.num_slots, self.max_blocks),
+        self._tables = np.full((self.num_slots, self.table_width),
                                self.num_blocks, np.int32)
         self.pool = BlockPool(self.num_blocks)
         self.wpool = None  # the sliding layers' pool and table mirror
@@ -521,7 +536,9 @@ class ServeEngine:
         self.sched = Scheduler(
             self.num_slots, self.pool, self.block_size, self.max_blocks,
             window_pool=self.wpool,
-            ring_blocks=self.ring_blocks if self.mixed else 0)
+            ring_blocks=self.ring_blocks if self.mixed else 0,
+            summary=((model_cfg.window_size, model_cfg.chunk_size)
+                     if self.eva else None))
 
         self._owns_telemetry = telemetry is None
         self.telemetry = telemetry or Telemetry(sinks=[])
@@ -633,9 +650,15 @@ class ServeEngine:
 
     def _sync_table(self, slot: int) -> None:
         st = self.sched.slots[slot]
-        row = np.full((self.max_blocks,), self.num_blocks, np.int32)
+        row = np.full((self.table_width,), self.num_blocks, np.int32)
         if st is not None and st.blocks:
-            row[:len(st.blocks)] = st.blocks
+            # a model with EVA attention: the summary blocks first, the
+            # open window's blocks after the summary region
+            first = (self.table_width
+                     - self.cfg.window_size // self.block_size
+                     if self.eva else 0)
+            row[:len(st.sblocks)] = st.sblocks
+            row[first:first + len(st.blocks)] = st.blocks
         self._tables[slot] = row
         if self.mixed:
             self._wtables[slot] = self.num_window_blocks
@@ -688,7 +711,7 @@ class ServeEngine:
         states, tables, unmapped, sh = self._prefill_pool()
         c = self.scfg.prefill_chunk
         r = rows or next(x for x in self.prefill_rungs if x >= len(pslots))
-        trows = np.full((r, self.max_blocks), unmapped, np.int32)
+        trows = np.full((r, self.table_width), unmapped, np.int32)
         wrows = (np.full((r, self.ring_blocks), self.num_window_blocks,
                          np.int32) if self.mixed else None)
         ids = np.zeros((r, c), np.int32)
@@ -971,7 +994,11 @@ class ServeEngine:
         with self._span("serve.prefill.dispatch", slots=len(pslots),
                         rows=len(nval), tokens=n_prefilled,
                         capacity=len(nval) * self.scfg.prefill_chunk,
-                        ids=join_ids(req_ids)):
+                        ids=join_ids(req_ids),
+                        **(self._eva_counts(
+                            [(states[s].n_prefilled, int(nval[row]))
+                             for row, s in enumerate(pslots)])
+                           if self.eva else {})):
             toks_d, logits_d = self._run_prefill(feed)
         toks = None
         if finals:
@@ -1038,13 +1065,13 @@ class ServeEngine:
                 st = self.sched.slots[s]
                 horizon = min(span,
                               st.req.max_new_tokens - len(st.generated))
-                n_before = len(st.blocks)
+                n_before = st.held_blocks
                 ok, preempted = self.sched.ensure_block(s, horizon)
                 dropped.update(preempted)
                 for p in preempted:
                     self._sync_table(p)
                 if ok:
-                    if len(self.sched.slots[s].blocks) != n_before:
+                    if st.held_blocks != n_before:
                         self._sync_table(s)
                     active.append(s)
             # a later ensure_block can preempt a slot already activated
@@ -1097,8 +1124,8 @@ class ServeEngine:
         # at the dispatch's first token, which is what a decode step that
         # attends in place reads a layer; `view_blocks`: what the gathered
         # view spans, whatever is live
-        kv_blocks = sum(blocks_for(self.sched.slots[s].write_pos + 1,
-                                   self.block_size) for s in active)
+        kv_blocks = sum(self._blocks_read(self.sched.slots[s].write_pos + 1)
+                        for s in active)
         with self._span("serve.decode.dispatch", active=len(active),
                         interval=interval, kv_blocks=kv_blocks,
                         view_blocks=self.num_slots * self.max_blocks,
@@ -1219,6 +1246,13 @@ class ServeEngine:
             # the blocks of the latent pool the step's slots hold, summed
             # over the layers: what the latent kernel reads
             return dict(latent_blocks=self.cfg.num_hidden_layers * kv_blocks)
+        if self.eva:
+            return self._eva_counts(
+                [(self.sched.slots[s].write_pos,
+                  min(self.scfg.decode_interval,
+                      self.sched.slots[s].req.max_new_tokens
+                      - len(self.sched.slots[s].generated)))
+                 for s in active], reads=True)
         if not self.mixed:
             return {}
         n_full = self.cfg.layer_kinds.count("full_attention")
@@ -1232,6 +1266,45 @@ class ServeEngine:
                     kv_blocks_window=n_win * band,
                     kv_blocks_banded=n_full * kv_blocks + n_win * band,
                     kv_blocks_unwindowed=(n_full + n_win) * kv_blocks)
+
+    def _blocks_read(self, n: int) -> int:
+        """Blocks a layer's attention reads for a query at position n - 1:
+        every block its n positions fill; with EVA attention the closed
+        windows' summary blocks and what the open window fills."""
+        if not self.eva:
+            return blocks_for(n, self.block_size)
+        w, c = self.cfg.window_size, self.cfg.chunk_size
+        closed = (n - 1) // w
+        return (blocks_for(closed * (w // c), self.block_size)
+                + blocks_for(n - closed * w, self.block_size))
+
+    def _eva_counts(self, spans, reads: bool = False) -> dict:
+        """Counts of a dispatch of a model with EVA attention, for its
+        span. `spans`: (first position written, positions written) a slot
+        or row. `eva_summaries_written`: chunks those positions complete,
+        a summary row a layer each; `eva_windows_closed`: windows they
+        complete. `reads` (a decode dispatch, at its first token, over
+        slots and layers): `eva_summary_blocks` + `eva_window_blocks` =
+        `eva_blocks_read`, what the step's attention reads, and
+        `eva_blocks_full_attention`, what full attention over the same
+        lengths would."""
+        w, c, bs = (self.cfg.window_size, self.cfg.chunk_size,
+                    self.block_size)
+        layers = self.cfg.num_hidden_layers
+        out = dict(
+            eva_summaries_written=layers * sum(
+                (p + n) // c - p // c for p, n in spans),
+            eva_windows_closed=sum((p + n) // w - p // w for p, n in spans))
+        if reads:
+            summary = sum(blocks_for(p // w * (w // c), bs) for p, _ in spans)
+            both = sum(self._blocks_read(p + 1) for p, _ in spans)
+            out.update(
+                eva_summary_blocks=layers * summary,
+                eva_window_blocks=layers * (both - summary),
+                eva_blocks_read=layers * both,
+                eva_blocks_full_attention=layers * sum(
+                    blocks_for(p + 1, bs) for p, _ in spans))
+        return out
 
     # -- trace driver ------------------------------------------------------
 

@@ -94,6 +94,7 @@ import jax.numpy as jnp
 from picotron_tpu.config import ModelConfig
 from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype
+from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
 )
@@ -502,6 +503,165 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     dt = compute_dtype(cfg)
     tables = jnp.full((num_slots, max_blocks), num_blocks, jnp.int32)
     return PagedKVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt), tables)
+
+
+# The decode kernel double-buffers a chunk of pages of K and of V for every
+# KV head of a slot in VMEM: `pages_per_chunk` for a cache whose model has
+# many KV heads is what fits this many bytes (64 pages, the kernel's own
+# default, at up to 8 heads of 128 in bfloat16; 16 at EvaByte's 32).
+KERNEL_VMEM_BYTES = 8 << 20
+
+
+class EvaPagedCache(PagedKVCache):
+    """The serving cache of a model with EVA attention (ops/eva.py): the
+    pool and the tables of `PagedKVCache`, and a LAW for what a slot's
+    table row holds. A summary row `(k~, v~)` has the shape of a K/V row,
+    so both kinds live in the one pool, and a slot's table is two regions:
+
+    - entries `[0, n_sum)`: summary blocks. The summary of chunk C (global
+      index, `position // chunk_size`) is row `C`: entry `C // block_size`,
+      offset `C % block_size`. A block is mapped once the chunk that
+      starts it is complete, and stays.
+    - entries `[n_sum, n_sum + window_size / block_size)`: the open
+      window's blocks. Position `i` is row `i % window_size` of the
+      region: a new window overwrites the rows of the one before it in
+      place (the blocks are recycled, not returned), and a slot never
+      holds more than one window of positions.
+
+    `n_sum` is what the summaries of `max_model_len` positions fill (the
+    width of the table less the window's entries). A chunk is summarised
+    when its last position is written (`write`): by a prefill chunk from
+    its own rows, by a decode step from the chunk's rows in the pool; a
+    partial chunk is in no summary. So closing a window takes no work at
+    all, on the host or on the device: its summaries are there, and what
+    changes is what the next query may see. That is the rows below ONE
+    length of a table whose entries are `[the closed windows' summary
+    blocks | the open window's blocks]` (a window's summaries are whole
+    blocks: `config.check_eva_serving`), which `attend` builds from the
+    slot's row and hands, with that length, to the programs every other
+    model's cache uses: the decode kernel on a chip (the entries below
+    each slot's length, read in place), the tiled walk everywhere else.
+    """
+
+    def _summary_entries(self, cfg: ModelConfig) -> int:
+        return self.tables.shape[1] - cfg.window_size // self.block_size
+
+    def _window_rows(self, pos, cfg: ModelConfig):
+        """The table-wide row of each position's K/V (-1 stays -1)."""
+        first = self._summary_entries(cfg) * self.block_size
+        return jnp.where(pos >= 0, first + pos % cfg.window_size, -1)
+
+    def write(self, li, k_new, v_new, q_pos, mu, phi,
+              cfg: ModelConfig) -> "EvaPagedCache":
+        """K/V [B, s, Hkv, D] of positions q_pos into the open window's
+        rows of layer li, then the summary of every chunk those positions
+        complete into its summary row. s > 1 (a prefill chunk, which
+        starts on a chunk boundary): the chunks of the segment itself,
+        whole where their last position is a real one. s == 1 (a decode
+        step): the chunk the position ends, from the rows the pool holds
+        now. Dropped where `PagedKVCache.write` drops."""
+        b, s = k_new.shape[:2]
+        c = cfg.chunk_size
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        if s > 1:
+            cache = self._write_blocks(li, k_new, v_new, q_pos, cfg)
+            ks, vs = chunk_summaries(k_new, v_new, mu, phi, c)
+            last = q_pos[:, c - 1::c]                           # [B, s // c]
+        else:
+            cache = PagedKVCache.write(self, li, k_new, v_new,
+                                       self._window_rows(q_pos, cfg))
+            last = jnp.where((q_pos + 1) % c == 0, q_pos, -1)   # [B, 1]
+            at = jnp.maximum(q_pos, c - 1) - (c - 1) + jnp.arange(c)[None, :]
+            phys, off = _slots_of(cache.tables, self._window_rows(at, cfg),
+                                  b, self.block_size, self.num_blocks)
+            hkv = self.k.shape[0]
+            # clamped into the pool: an unmapped row's summary is dropped
+            phys = jnp.broadcast_to(jnp.minimum(phys, self.num_blocks - 1),
+                                    (hkv,) + phys.shape)
+            off = jnp.broadcast_to(off, (hkv,) + off.shape)
+            rows = jax.vmap(lambda pool, ph, of: pool[li, ph, of])
+
+            def chunk_of(pool):  # [Hkv, B, c, D] -> [B, 1, c, Hkv, D]
+                return rows(pool, phys, off).transpose(1, 2, 0, 3)[:, None]
+
+            ks, vs = eva_summarise(chunk_of(cache.k), chunk_of(cache.v),
+                                   mu, phi)
+        return PagedKVCache.write(cache, li, ks, vs,
+                                  jnp.where(last >= 0, last // c, -1))
+
+    @scope("kv_write")
+    def _write_blocks(self, li, k_new, v_new, q_pos,
+                      cfg: ModelConfig) -> "EvaPagedCache":
+        """A prefill chunk's K/V [B, s, Hkv, D] a BLOCK at a time: the
+        chunk starts on a block boundary and is whole blocks long
+        (`config.check_eva_serving`), so the scatter's window is a block
+        of `[block_size, D]` and not a row of `D`. The scatter costs by its
+        windows (70 ns each on a v5e), and with a KV head a query head a
+        16-row chunk of 256 positions is 131,072 rows a tensor and layer,
+        19 ms of a layer's 42; a block at a time it is 8,192. A block is
+        written where its first position is a real one; what its rows past
+        the chunk's last real position then hold (padding's K/V) lies
+        beyond every query's length until the position that owns the row
+        writes it."""
+        b, s, hkv, d = k_new.shape
+        bs = self.block_size
+        rows = self._window_rows(q_pos[:, ::bs], cfg)            # [B, s / bs]
+        phys, _ = _slots_of(self.tables, rows, b, bs, self.num_blocks)
+        phys = jnp.broadcast_to(phys, (hkv,) + phys.shape)
+        put = jax.vmap(  # on one head's [L, num_blocks, block_size, D]
+            lambda pool, new, ph: pool.at[li, ph].set(
+                new.reshape(b, s // bs, bs, d), mode="drop"),
+            in_axes=(0, 2, 0))
+        return self._replace(k=put(self.k, k_new, phys),
+                             v=put(self.v, v_new, phys))
+
+    def attend(self, li, q, q_pos, cfg: ModelConfig):
+        """Attention of q [B, s, Hq, D] at positions q_pos (one window a
+        row: a prefill chunk never straddles one) over what each may see
+        of layer li."""
+        b, s = q.shape[:2]
+        w, bs, width = cfg.window_size, self.block_size, self.tables.shape[1]
+        per_window = w // cfg.chunk_size           # summaries a window
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        window = jnp.max(jnp.maximum(q_pos, 0), axis=1) // w        # [B]
+        closed = (window * (per_window // bs))[:, None]   # summary blocks seen
+        # the table whose rows below one length are what a query sees: the
+        # closed windows' summary blocks, then the open window's blocks
+        e = jnp.arange(width)[None, :]
+        src = jnp.where(e < closed, e, self._summary_entries(cfg) + e - closed)
+        seen = jnp.where(
+            src < width,
+            jnp.take_along_axis(self.tables, jnp.minimum(src, width - 1), 1),
+            self.num_blocks)
+        at = jnp.where(q_pos >= 0,
+                       window[:, None] * per_window + q_pos % w, -1)
+        if decode_kernel_suits(q, self.k):
+            hkv, _, _, _, d = self.k.shape
+            page = hkv * bs * d * self.k.dtype.itemsize   # a block's K
+            out = paged_decode_attention(
+                q[:, 0], self.k, self.v, li, seen,
+                jnp.maximum(at[:, 0] + 1, 0),
+                pages_per_chunk=max(1, KERNEL_VMEM_BYTES // (4 * page)))
+            return out[:, None]
+        return MixedPagedKVCache._tiled(
+            PagedKVCache(self.k, self.v, seen), li, q, at, None)
+
+
+def eva_table_width(cfg: ModelConfig, max_len: int, block_size: int) -> int:
+    """Entries of an `EvaPagedCache` table row: the summary blocks of
+    max_len positions, then one window's blocks."""
+    return (-(-(max_len // cfg.chunk_size) // block_size)
+            + cfg.window_size // block_size)
+
+
+def init_eva_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+                   num_slots: int, max_len: int) -> EvaPagedCache:
+    """Zeroed pool + all-unmapped tables of `eva_table_width` entries."""
+    return EvaPagedCache(*init_paged_cache(
+        cfg, num_blocks, block_size, num_slots,
+        eva_table_width(cfg, max_len, block_size)))
 
 
 class BlockPool:

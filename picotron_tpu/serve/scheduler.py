@@ -30,6 +30,15 @@ or fewer where the whole request is shorter than the ring), all or
 nothing with its full-layer blocks, keeps it through decode without
 growing it, and returns it wherever it returns the others: retirement,
 preemption, cancellation. A request shed from the queue holds neither.
+
+A model with chunk-summarised attention (`summary`: its window and chunk;
+serve/paged_cache.py EvaPagedCache) holds blocks of two kinds from the ONE
+pool: `blocks`, the open window's, which grow by the block up to one
+window's worth and are then overwritten in place, and `sblocks`, one row a
+complete chunk, which grow for as long as the request does
+(`Scheduler.blocks_at`). Both are given at admission for the whole
+prefix, all or nothing, grown together before a decode dispatch, and
+returned together.
 """
 
 from __future__ import annotations
@@ -82,6 +91,7 @@ class RequestState:
     n_prefilled: int = 0
     blocks: list = field(default_factory=list)
     wblocks: list = field(default_factory=list)  # its ring, window pool
+    sblocks: list = field(default_factory=list)  # its summary blocks
     admit_seq: int = -1
     t_admit: Optional[float] = None
     t_first_token: Optional[float] = None
@@ -90,7 +100,7 @@ class RequestState:
     @property
     def held_blocks(self) -> int:
         """The blocks its retirement gives back, over both pools."""
-        return len(self.blocks) + len(self.wblocks)
+        return len(self.blocks) + len(self.wblocks) + len(self.sblocks)
 
     @property
     def prefilling(self) -> bool:
@@ -113,7 +123,8 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
 
 class Scheduler:
     def __init__(self, num_slots: int, pool, block_size: int,
-                 max_blocks: int, window_pool=None, ring_blocks: int = 0):
+                 max_blocks: int, window_pool=None, ring_blocks: int = 0,
+                 summary: Optional[tuple] = None):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if window_pool is not None and ring_blocks < 1:
@@ -122,6 +133,8 @@ class Scheduler:
         self.pool = pool
         self.window_pool = window_pool  # None: a model of full layers
         self.ring_blocks = ring_blocks
+        # (window, chunk) of a model with chunk-summarised attention
+        self.summary = summary
         self.block_size = block_size
         self.max_blocks = max_blocks
         self.queue: deque = deque()
@@ -150,6 +163,7 @@ class Scheduler:
                 f"{req.max_new_tokens} new tokens needs {need} blocks, "
                 f"over the per-slot table capacity ({self.max_blocks}); "
                 f"raise serve.max_model_len")
+        need = sum(self.blocks_at(len(req.prompt) + req.max_new_tokens))
         if need > self.pool.num_blocks:
             raise ValueError(
                 f"request {req.id}: needs {need} blocks but the whole "
@@ -167,6 +181,18 @@ class Scheduler:
     def has_work(self) -> bool:
         return bool(self.queue) or any(s is not None for s in self.slots)
 
+    def blocks_at(self, n_tokens: int) -> tuple:
+        """(position blocks, summary blocks) of the pool a request holds
+        once `n_tokens` positions are written. Every position's block and
+        no summary, unless the model's attention summarises chunks
+        (`summary`): then the open window's blocks, one window's worth at
+        the most, and a row a complete chunk."""
+        if self.summary is None:
+            return blocks_for(n_tokens, self.block_size), 0
+        window, chunk = self.summary
+        return (blocks_for(min(n_tokens, window), self.block_size),
+                blocks_for(n_tokens // chunk, self.block_size))
+
     def _ring_for(self, req: Request) -> int:
         """Blocks of the window pool a request holds: the ring, or the
         blocks of its whole length where that is shorter (it then never
@@ -177,8 +203,8 @@ class Scheduler:
 
     def _release(self, st: RequestState) -> None:
         """A request's blocks of both pools back to their free lists."""
-        self.pool.free(st.blocks)
-        st.blocks = []
+        self.pool.free(st.blocks + st.sblocks)
+        st.blocks, st.sblocks = [], []
         if st.wblocks:
             self.window_pool.free(st.wblocks)
             st.wblocks = []
@@ -221,8 +247,8 @@ class Scheduler:
                 break
             st = self.queue[0]
             st.prefill_ids = st.req.prompt + tuple(st.generated)
-            blocks = self.pool.alloc(
-                blocks_for(len(st.prefill_ids), self.block_size))
+            n_pos, n_sum = self.blocks_at(len(st.prefill_ids))
+            blocks = self.pool.alloc(n_pos + n_sum)
             if blocks is None:
                 break
             if self.window_pool is not None:
@@ -233,7 +259,7 @@ class Scheduler:
                     break
                 st.wblocks = ring
             self.queue.popleft()
-            st.blocks = blocks
+            st.blocks, st.sblocks = blocks[:n_pos], blocks[n_pos:]
             st.n_prefilled = 0
             st.admit_seq = self._admit_seq
             st.t_admit = now
@@ -281,12 +307,13 @@ class Scheduler:
         # clamp to table capacity: interval padding past a request's
         # budget may point beyond max_model_len — those writes sentinel-
         # drop in the cache, and must not demand unallocatable blocks
-        need_upto = min(blocks_for(st.write_pos + horizon, self.block_size),
-                        self.max_blocks)
-        while len(st.blocks) < need_upto:
+        want = self.blocks_at(min(st.write_pos + horizon,
+                                  self.max_blocks * self.block_size))
+        while len(st.blocks) < want[0] or len(st.sblocks) < want[1]:
+            short = st.blocks if len(st.blocks) < want[0] else st.sblocks
             got = self.pool.alloc(1)
             if got is not None:
-                st.blocks.extend(got)
+                short.extend(got)
                 continue
             live = [(s.admit_seq, i) for i, s in enumerate(self.slots)
                     if s is not None]

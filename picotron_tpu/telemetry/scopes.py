@@ -47,6 +47,7 @@ SCOPES = (
     "mla_o",            # latent attention: the output projection
     "attn_latent",      # inside paged_attention, a model with a latent cache: the decode step's latent kernel; prefill's tiles, mask, softmax, PV
     "moe_shared",       # inside mlp: the shared expert's gated MLP
+    "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 
 

@@ -36,7 +36,9 @@ import time
 import jax
 
 from picotron_tpu.checkpoint import CheckpointManager, load_hf_safetensors
-from picotron_tpu.config import Config, load_config, num_params
+from picotron_tpu.config import (
+    Config, load_config, num_params, refuse_training,
+)
 from picotron_tpu.models.llama import pad_layers_for_pp
 from picotron_tpu.data import MicroBatchDataLoader
 from picotron_tpu.mesh import MeshEnv, multihost_initialize
@@ -178,6 +180,7 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     cfg = load_config(args.config)
+    refuse_training(cfg.model)  # a model with no training loss, by name
     if cfg.distributed.use_cpu:
         # The reference's --use_cpu path (gloo + FLASH_ATTEN=0, ref:
         # create_config.py:64-66): run the full parallel layout on simulated

@@ -287,20 +287,25 @@ CHAT_SLOTS = load("configs", "qwen2-1.5b")["serve"]["decode_slots"]
 _COMPILED_SERVE: dict = {}  # (program, rows) -> compiled_serve's result
 
 
-def compiled_serve(topo, monkeypatch, program: str, rows=None):
-    """(`compiled.as_text()`, the pool's shape, the pools' parameter numbers)
-    of the chat cell's `serve_prefill` (at `rows` rows of the compacted
-    batch) or `serve_decode` at the cell's widths, depth and serve settings
-    on one described chip, pools donated: a program `ServeEngine` dispatches
-    there."""
-    if (program, rows) in _COMPILED_SERVE:
-        return _COMPILED_SERVE[program, rows]
+def compiled_serve(topo, monkeypatch, program: str, rows=None,
+                   config: str = "qwen2-1.5b", text: bool = True):
+    """(`compiled.as_text()` or with `text` false the compiled program, the
+    pool's shape, the pools' parameter numbers) of the chat cell's
+    `serve_prefill` (at `rows` rows of the compacted batch) or `serve_decode`
+    at the cell's widths, depth and serve settings on one described chip,
+    pools donated: a program `ServeEngine` dispatches there. `config`:
+    another configuration with one K/V pool and one table a slot (EvaByte's,
+    whose table row is as wide as its law says)."""
+    from picotron_tpu.serve.paged_cache import init_eva_cache
+
+    if (config, program, rows) in _COMPILED_SERVE:
+        return _COMPILED_SERVE[config, program, rows]
     # the decode step asks the backend whether kernels compile; here the
     # backend is the CPU and the target is the described chip. Every serve
     # program of this file is traced under the patch: the jits are shared
     fa = importlib.import_module("picotron_tpu.ops.flash_attention")
     monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
-    c = load("configs", "qwen2-1.5b")
+    c = load("configs", config)
     cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
     m, sc = cfg.model, cfg.serve
     max_blocks = blocks_for(sc.max_model_len, sc.block_size)
@@ -317,26 +322,32 @@ def compiled_serve(topo, monkeypatch, program: str, rows=None):
     # bf16 weights, as the cell's runner serves them
     params = on_chip(jax.eval_shape(lambda: jax.tree.map(
         lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
-    cache = on_chip(jax.eval_shape(lambda: init_paged_cache(
-        m, sc.num_blocks or slots * max_blocks, sc.block_size, slots, max_blocks)))
+    num_blocks = sc.num_blocks or slots * max_blocks
+    cache = on_chip(jax.eval_shape(
+        (lambda: init_eva_cache(m, num_blocks, sc.block_size, slots,
+                                sc.max_model_len)) if m.eva else
+        (lambda: init_paged_cache(m, num_blocks, sc.block_size, slots,
+                                  max_blocks))))
+    width = cache.tables.shape[1]
     cos, sin = on_chip(jax.eval_shape(
         lambda: model_rope_tables(m, max_len=sc.max_model_len)))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     decode, prefill = _get_jits(True)
     if program == "serve_prefill":
         low = prefill.lower(
-            params, cache.k, cache.v, i32(rows, max_blocks),
+            params, cache.k, cache.v, i32(rows, width),
             i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
             i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
     else:
         low = decode.lower(
-            params, cache.k, cache.v, i32(slots, max_blocks), i32(slots),
+            params, cache.k, cache.v, i32(slots, width), i32(slots),
             i32(slots), i32(slots), i32(slots), key, cos, sin, cfg=m,
             temperature=0.0, top_k=0, interval=sc.decode_interval,
             eos_token_id=None)
     n = len(jax.tree.leaves(params))
-    out = _COMPILED_SERVE[program, rows] = (
-        low.compile().as_text(), cache.k.shape, {n, n + 1})
+    comp = low.compile()
+    out = _COMPILED_SERVE[config, program, rows] = (
+        comp.as_text() if text else comp, cache.k.shape, {n, n + 1})
     return out
 
 
@@ -654,6 +665,45 @@ def test_k_exaone_serving_programs(topo, monkeypatch, program, rows):
         # test_a_chunk_is_sized_by_the_band)
     else:
         assert not paged  # a chunk walks its keys in tiles, no kernel
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)
+    assert total < 15.75 * 2**30, total / 2**30
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 16)])
+def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `evabyte-6.5b-8l` compile for a v5e and fit it
+    beside the pool; the one pool is not copied whole and is written in place
+    (the positions' rows and the summaries' rows: two scatters a layer); the
+    decode step attends through THE decode kernel under its own name, one call
+    in the layer scan's body, with a chunk of pages that fits its 32 KV heads
+    into fast memory; a prefill chunk walks tiles; the pooling's scope is in
+    both."""
+    name = "evabyte-6.5b-8l"
+    comp, pool_shape, pools = compiled_serve(topo, monkeypatch, program, rows, name,
+                                             text=False)
+    assert pool_shape == (32, 8, load("configs", name)["serve"]["num_blocks"], 16, 128)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"kv_write", "paged_attention", "eva_summarise", "mlp", "sample"}
+    copies = whole_pool_copies(text, pool_shape)
+    assert not copies, f"{program} copies the whole pool {pool_shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    if program == "serve_decode":
+        assert len(kernels) == 1 and attn.search(kernels[0][0]), kernels
+        assert "paged_attention" in words(kernels[0][1])
+    else:
+        assert not kernels  # a chunk walks its keys in tiles, no kernel
     ma = comp.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)

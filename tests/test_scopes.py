@@ -192,15 +192,27 @@ def test_mixed_model_serve_program_scopes(program):
 MLA = {"mla_q", "mla_kv_latent", "mla_absorb", "mla_o", "attn_latent", "moe_shared"}
 
 
+# a model with one pool and one table a slot that is not the plain one: its
+# preset, the scopes its serve programs carry, a prefill chunk it takes
+ONE_TABLE = {
+    "latent": ("debug-tiny-pangu-moe", SERVE | MOE | MLA, 4),
+    "eva": ("debug-tiny-evabyte", SERVE | {"eva_summarise"}, 8),
+}
+
+
 @pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
-def test_latent_model_serve_program_scopes(program):
+@pytest.mark.parametrize("model", ONE_TABLE)
+def test_latent_and_eva_model_serve_program_scopes(model, program):
     """A model with latent attention, a shared expert and a dense layer before
     its expert layers: both serve programs carry the latent scopes beside the
     expert scopes (`benchmark/layer_metrics/mla_*.serve.json` and
-    `moe_shared_ms.serve.json` read them)."""
-    mcfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+    `moe_shared_ms.serve.json` read them). A model with EVA attention: both
+    carry `eva_summarise` (`eva_summarise_ms.serve.json` reads it), and its
+    attention is under `paged_attention` like any other's."""
+    preset, want, chunk = ONE_TABLE[model]
+    mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
-                    ServeConfig(decode_slots=2, block_size=4, prefill_chunk=4,
+                    ServeConfig(decode_slots=2, block_size=4, prefill_chunk=chunk,
                                 max_model_len=32, decode_interval=2))
     s = e.num_slots
     vec = jnp.zeros((s,), jnp.int32)
@@ -218,7 +230,7 @@ def test_latent_model_serve_program_scopes(program):
     text = lowered.as_text(debug_info=True)
     assert module_name(text) == f"jit_{program}"
     found = scopes_in(text)
-    assert found == SERVE | MOE | MLA
+    assert found == want
     seen.update(found)
 
 
