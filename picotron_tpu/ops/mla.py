@@ -9,7 +9,9 @@ scores and outputs:
 - **expanded**: keys and values of every head built from the latents
   (`c @ kv_b`), attention per head as usual. What `forward()` runs, and a
   prefill chunk with enough queries a row to pay for expanding each key tile
-  once.
+  once (on the chip, over the serving pool, in one kernel that keeps the
+  expanded tile and its scores in VMEM: `ops/paged_attention.py
+  latent_prefill_attention`).
 - **absorbed**: `q_n . (c Wuk_h) = (q_n Wuk_h^T) . c` and
   `P (c Wuv_h) = (P c) Wuv_h`: the queries are carried into the latent space
   and every head attends the SAME `[T, rank + rope]` rows, the value being the
@@ -21,6 +23,11 @@ Which form a cached step of `s` queries a row takes is arithmetic on the
 shapes (`absorbed_suits`): per cached key the expanded form costs the
 expansion, `2 rank heads (nope + v)`, plus `2 s heads (nope + rope + v)`; the
 absorbed form `2 s heads (2 rank + rope)`.
+
+`latent_attention` below is the plain form of both, in `jax.numpy`: what
+every CPU run, `generate()`'s dense cache, the tiny test models and any
+shape the two kernels do not take run (`serve/paged_cache.py
+LatentPagedCache.attend` decides), and what the kernels are tested against.
 """
 
 from __future__ import annotations

@@ -401,3 +401,287 @@ def latent_decode_attention(q, pool, li, tables, lengths, *, rank: int,
         name="latent_decode_attention",
     )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
       jnp.asarray(li, jnp.int32).reshape(1), q, pool)
+
+
+# ---------------------------------------------------------------------------
+# The latent cache's prefill chunk (MLA expanded, ops/mla.py): `s` queries a
+# row against the blocks that row holds, read in place, a tile of keys at a
+# time; a head's keys and values are built from the tile's latents in VMEM
+# and its scores never leave the chip. At the END of this file: a Pallas
+# program's compile-cache key holds the file and line of every frame above
+# its call, so a line added above the two decode kernels would cost every
+# serving cell a cold compile (PERF.md section 7, "Open since PR 42" (a)).
+# ---------------------------------------------------------------------------
+
+# Keys a tile, as `ops/mla.py TILE_KEYS`: the online softmax then rounds P
+# at the same tile edges as the plain form the kernel is tested against.
+PREFILL_TILE_KEYS = 512
+# Heads a grid step: their `kv_b` columns ([g, rank, nope + v], 8 MB at
+# g = 32), queries and float32 accumulators stay in VMEM while the row's
+# tiles stream past, and a tile is fetched once a group. Swept on the chip
+# at openPangu-Ultra's widths (PERF.md section 6, PR 44; us a tile of 256
+# queries over 16,384 keys / over four rows of unequal length): 8 heads
+# 216 / 270, 16 heads 206 / 250, 32 heads 199 / 242: a grid step costs
+# some 20 us beside its tiles, so fewer and larger steps win.
+PREFILL_HEAD_GROUP = 32
+# Heads a trip of the loop inside a tile, unrolled: the scheduler overlaps
+# one head's softmax with the next one's matmuls only inside a trip (16
+# heads a step: 218 us a tile at 1, 209 at 2, 206 at 4, 204 at 8), and a
+# trip's body is what the kernel's code and its lowering time grow with.
+PREFILL_HEAD_UNROLL = 4
+# Scoped VMEM the kernel may use (the default is 16 MiB of the chip's 128):
+# at 32 heads the blocks, double-buffered, are 2 x (8 + 2 + 2 + 2) MB, the
+# statistics and the accumulator 12 MB, the two tiles 1.3 MB, and the
+# compiler's temporaries (a head's scores are 0.5 MB) ride on top.
+PREFILL_VMEM_BYTES = 64 << 20
+_LANES = 128
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of n that is at most `most`."""
+    return next(d for d in range(min(n, most), 0, -1) if n % d == 0)
+
+
+def latent_prefill_tile(block_size: int, max_blocks: int) -> int:
+    """Keys a tile of a prefill chunk's walk over a latent pool whose rows
+    hold `max_blocks` blocks: whole blocks, `PREFILL_TILE_KEYS` at most
+    (the kernel's tile and `LatentPagedCache._tiled`'s)."""
+    return min(max(PREFILL_TILE_KEYS // block_size, 1), max_blocks) * block_size
+
+
+def latent_prefill_suits(q_n, q_r, pool, kv_b, max_blocks: int) -> bool:
+    """Whether a step with queries q_n [B, s, heads, nope] / q_r [B, s,
+    heads, rope] over a latent pool [L, num_blocks, block_size, W] with
+    `kv_b` [rank, heads * (nope + v)] and tables of `max_blocks` entries a
+    row is one `latent_prefill_attention` takes compiled: more than one
+    query a row and a whole number of sublane tiles of them, the latent, a
+    head's key, a head's value and a tile's keys whole 128-lane rows, the
+    rotated part inside the row's last lanes, the block whole sublane
+    tiles, a backend that compiles Pallas kernels. Everything
+    else walks tiles in `ops/mla.py latent_attention`: every CPU run, the
+    tiny test models, `generate()`'s dense cache (which has no block
+    table and never asks)."""
+    s, heads, dn = q_n.shape[1:]
+    block_size, w = pool.shape[2], pool.shape[3]
+    rank, dv = kv_b.shape[0], kv_b.shape[1] // heads - dn
+    sublanes = 8 * 4 // jnp.dtype(pool.dtype).itemsize
+    return (s > 1 and s % sublanes == 0 and block_size % sublanes == 0
+            and rank % _LANES == 0 and w % _LANES == 0 and dn % _LANES == 0
+            and dv > 0 and dv % _LANES == 0 and rank + q_r.shape[3] <= w
+            and latent_prefill_tile(block_size, max_blocks) % _LANES == 0
+            and q_n.dtype == pool.dtype and compiled_kernels_available())
+
+
+def _latent_prefill_kernel(lengths_ref, tables_ref, li_ref, qn_ref, qr_ref,
+                           qpos_ref, w_ref, kv_hbm, o_ref, kv_buf, sems,
+                           m_ref, l_ref, acc_ref, *, sm_scale: float,
+                           pages_per_tile: int, max_blocks: int, rank: int,
+                           dn: int):
+    b = pl.program_id(1)
+    group, _, dv = o_ref.shape
+    unroll = _divisor(group, PREFILL_HEAD_UNROLL)
+    _, num_blocks, bs, w = kv_hbm.shape
+    tile = pages_per_tile * bs
+    li = li_ref[0]
+    length = lengths_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(length, bs), max_blocks)
+    n_tiles = pl.cdiv(n_pages, pages_per_tile)
+
+    def copy(t, buf, j):
+        # ONE DMA a page, straight out of the pool: [c | k_r | 0] of 16 keys
+        page = jnp.minimum(tables_ref[b * max_blocks + t * pages_per_tile + j],
+                           num_blocks - 1)
+        return pltpu.make_async_copy(kv_hbm.at[li, page], kv_buf.at[buf, j],
+                                     sems.at[buf])
+
+    def each_copy(t, buf, act):
+        n = jnp.minimum(n_pages - t * pages_per_tile, pages_per_tile)
+        lax.fori_loop(0, n, lambda j, _: act(copy(t, buf, j)), None)
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        each_copy(0, 0, lambda cp: cp.start())
+
+    # a padding query (position < 0) attends as position 0, as the plain form
+    qp0 = jnp.maximum(qpos_ref[...], 0)                            # [s, 1]
+
+    def body(t, _):
+        buf = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _next():
+            each_copy(t + 1, 1 - buf, lambda cp: cp.start())
+
+        each_copy(t, buf, lambda cp: cp.wait())
+        ckr = kv_buf[buf].reshape(tile, w)
+        # rows at or beyond the length hold whatever the buffer or a partly
+        # filled block held (NaN included): no key there is seen, and a
+        # zeroed latent expands to a zero value, so 0 x it stays 0
+        live = lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < length - t * tile
+        ckr = jnp.where(live, ckr, jnp.zeros_like(ckr))
+        c, kr = ckr[:, :rank], ckr[:, rank:]
+        kpos = t * tile + lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        seen = kpos <= qp0                                         # [s, tile]
+
+        def head(h, _):
+            # head h's keys and values of this tile, rounded to the compute
+            # dtype as `latent_attention` rounds them
+            kv = lax.dot_general(
+                c, w_ref[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(c.dtype)
+            sc = lax.dot_general(qn_ref[h], kv[:, :dn], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            sc = (sc + lax.dot_general(
+                qr_ref[h], kr, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)) * sm_scale
+            sc = jnp.where(seen, sc, _NEG_INF)
+            # m and l ride 128 lanes wide: m the same in every lane, l a
+            # partial sum a lane (key k in lane k % 128), summed once when
+            # the row's tiles are done, so a tile costs ONE cross-lane
+            # reduction a query (the max) and no lane broadcast
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # every query sees key 0, so from tile 0 on m is a real score
+            # and an unseen key's exp(-1e30 - m) is exactly 0
+            p = jnp.exp(sc - pltpu.repeat(m_new, tile // _LANES, axis=1))
+            l_ref[h] = l_ref[h] * alpha + sum(
+                p[:, j:j + _LANES] for j in range(0, tile, _LANES))
+            acc_ref[h] = (acc_ref[h] * pltpu.repeat(alpha, dv // _LANES, axis=1)
+                          + lax.dot_general(
+                              p.astype(c.dtype), kv[:, dn:],
+                              (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
+            m_ref[h] = m_new
+
+        def trip(i, _):
+            for j in range(unroll):
+                head(i * unroll + j, None)
+
+        lax.fori_loop(0, group // unroll, trip, None)
+
+    lax.fori_loop(0, n_tiles, body, None)
+
+    def finish(h, _):
+        l = jnp.sum(l_ref[h], axis=-1, keepdims=True)
+        # length 0 (a padding row): no tile ran, acc = 0 and l = 0 -> zeros
+        o_ref[h] = (acc_ref[h] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    lax.fori_loop(0, group, finish, None)
+
+
+def latent_prefill_attention(q_n, q_r, q_pos, pool, li, tables, kv_b, *,
+                             sm_scale: Optional[float] = None,
+                             pages_per_tile: Optional[int] = None,
+                             interpret: Optional[bool] = None):
+    """Expanded latent attention of `s` query positions a row over that
+    row's cached positions, read from the latent pool in place: the
+    mathematics of `ops/mla.py latent_attention(absorbed=False)` over
+    `LatentPagedCache`'s tiles, to the rounding.
+
+    q_n [B, s, heads, nope], q_r [B, s, heads, rope] (rotated), q_pos
+    [B, s] int32 (< 0: padding); pool [L, num_blocks, block_size, W],
+    `[c | k_r | 0]` a cached position; li: the layer; tables [B,
+    max_blocks] int32, `num_blocks` = unmapped; kv_b [rank, heads * (nope +
+    v)]. A key at position k is seen by a query at position p where
+    k <= max(p, 0). Row b walks the tiles of `pages_per_tile` pages up to
+    its own last position (`max(q_pos[b]) + 1` keys) and no further, and
+    fetches only the pages below it: an unmapped entry beyond a row's
+    length is never read, and a row whose positions are all negative reads
+    nothing and returns zeros.
+
+    Grid (head groups, rows), the rows innermost so a group's `kv_b`
+    columns are fetched once; inside a step the row's tiles, double
+    buffered, one DMA a page, and inside a tile a loop over the group's
+    heads. A tile's keys and values are expanded a head at a time in VMEM
+    (`c @ kv_b_h`, rounded to the compute dtype), scores
+    `(q_n . k + q_r . k_r) * sm_scale` and the softmax's statistics are
+    float32, P is rounded to the compute dtype for PV, accumulation is
+    float32: nothing a head wide or a score wide reaches HBM. Queries,
+    weights and outputs go in and out head-major (one transpose each
+    outside the kernel), so a head is a leading index inside it.
+    `interpret=None` compiles on a TPU backend and runs the Pallas
+    interpreter anywhere else; the caller decides whether the shapes suit
+    the compiled kernel (`latent_prefill_suits`). Returns [B, s, heads, v]
+    in q_n's dtype."""
+    if interpret is None:
+        interpret = not compiled_kernels_available()
+    return _latent_prefill_call(q_n, q_r, q_pos, pool, li, tables, kv_b,
+                                sm_scale=sm_scale,
+                                pages_per_tile=pages_per_tile,
+                                interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "pages_per_tile", "interpret"))
+def _latent_prefill_call(q_n, q_r, q_pos, pool, li, tables, kv_b, *,
+                         sm_scale, pages_per_tile, interpret: bool):
+    """`latent_prefill_attention`, jitted: a model's stacks call it with
+    the same shapes, and a jitted function is traced and lowered once a
+    program however many call it (on a TPU host Mosaic's layout passes run
+    while the kernel is lowered, before the compile cache is asked: about
+    a second a kernel at openPangu-Ultra's widths, every start)."""
+    b, s, heads, dn = q_n.shape
+    dr = q_r.shape[3]
+    _, _, bs, w = pool.shape
+    rank = kv_b.shape[0]
+    dv = kv_b.shape[1] // heads - dn
+    max_blocks = tables.shape[1]
+    if rank + dr > w or kv_b.shape[1] != heads * (dn + dv) or dv <= 0:
+        raise ValueError(f"pool {pool.shape} / kv_b {kv_b.shape} do not "
+                         f"match q_n {q_n.shape} / q_r {q_r.shape}")
+    if sm_scale is None:
+        sm_scale = 1.0 / (dn + dr) ** 0.5
+    g = _divisor(heads, PREFILL_HEAD_GROUP)
+    ppt = min(pages_per_tile or max_blocks,
+              latent_prefill_tile(bs, max_blocks) // bs)
+    if ppt * bs % _LANES or dv % _LANES:
+        raise ValueError(f"tiles of {ppt * bs} keys / values of {dv} are not "
+                         f"whole rows of {_LANES} lanes")
+    wr = w - rank  # [k_r | 0]: the lanes of a pool row behind the latent
+    lengths = jnp.clip(jnp.max(q_pos, axis=1) + 1, 0, max_blocks * bs)
+    kernel = functools.partial(
+        _latent_prefill_kernel, sm_scale=sm_scale, pages_per_tile=ppt,
+        max_blocks=max_blocks, rank=rank, dn=dn)
+
+    def per_row(width):
+        return pl.BlockSpec((None, g, s, width),
+                            lambda hg, i, *_: (i, hg, 0, 0))
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # lengths, the tables (flat), the layer
+            grid=(heads // g, b),
+            in_specs=[per_row(dn), per_row(wr),
+                      pl.BlockSpec((None, s, 1), lambda hg, i, *_: (i, 0, 0)),
+                      pl.BlockSpec((g, rank, dn + dv),
+                                   lambda hg, i, *_: (hg, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=per_row(dv),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppt, bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((g, s, _LANES), jnp.float32),
+                pltpu.VMEM((g, s, _LANES), jnp.float32),
+                pltpu.VMEM((g, s, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, s, dv), q_n.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="latent_prefill_attention",
+    )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(li, jnp.int32).reshape(1),
+      q_n.transpose(0, 2, 1, 3),
+      jnp.pad(q_r, ((0, 0), (0, 0), (0, 0), (0, wr - dr))).transpose(
+          0, 2, 1, 3),
+      q_pos.astype(jnp.int32)[..., None],
+      kv_b.astype(q_n.dtype).reshape(rank, heads, dn + dv).transpose(1, 0, 2),
+      pool)
+    return out.transpose(0, 2, 1, 3)

@@ -995,10 +995,9 @@ class ServeEngine:
                         rows=len(nval), tokens=n_prefilled,
                         capacity=len(nval) * self.scfg.prefill_chunk,
                         ids=join_ids(req_ids),
-                        **(self._eva_counts(
+                        **self._kind_keys(
                             [(states[s].n_prefilled, int(nval[row]))
-                             for row, s in enumerate(pslots)])
-                           if self.eva else {})):
+                             for row, s in enumerate(pslots)])):
             toks_d, logits_d = self._run_prefill(feed)
         toks = None
         if finals:
@@ -1231,6 +1230,25 @@ class ServeEngine:
         self.stats["occupancy_sum"] += len(active) / self.num_slots
         self.stats["output_tokens"] += n_tokens
         return True
+
+    def _kind_keys(self, spans) -> dict:
+        """Further counts of a prefill dispatch's span, the twin of
+        `_kind_blocks`. `spans`: (positions already cached, tokens of this
+        chunk) a row. A model with a latent cache: `latent_keys`, the key
+        positions the rows' chunks may see (each row's cached positions and
+        its chunk, rounded up to the attention's tile), summed over the
+        layers: with the seconds of `latent_prefill_attention`'s events it
+        gives the kernel's share of the matmul peak, at
+        `2 keys rank heads (nope + v) + 2 s keys heads (nope + rope + v)`
+        operations a row. A model with EVA attention: `_eva_counts`."""
+        if self.latent:
+            # imported here: a line added above the device programs would
+            # change their compile-cache keys (the comment below them)
+            from picotron_tpu.ops.paged_attention import latent_prefill_tile
+            tile = latent_prefill_tile(self.block_size, self.max_blocks)
+            return dict(latent_keys=self.cfg.num_hidden_layers * sum(
+                -(-(p + n) // tile) * tile for p, n in spans))
+        return self._eva_counts(spans) if self.eva else {}
 
     def _kind_blocks(self, active, kv_blocks: int) -> dict:
         """Further counts of a decode dispatch's span. A model with a

@@ -100,7 +100,7 @@ from picotron_tpu.ops.mla import (
 )
 from picotron_tpu.ops.paged_attention import (
     decode_kernel_suits, latent_decode_attention, latent_kernel_suits,
-    paged_decode_attention,
+    latent_prefill_attention, latent_prefill_suits, paged_decode_attention,
 )
 from picotron_tpu.telemetry.scopes import scope
 
@@ -430,30 +430,30 @@ class LatentPagedCache(NamedTuple):
         return self._replace(
             kv=self.kv.at[li, phys, off].set(new, mode="drop"))
 
+    @scope("attn_latent")
     def attend(self, li, q_n, q_r, q_pos, kv_b, cfg: ModelConfig):
         """Attention of q_n [B, s, heads, nope] / q_r [B, s, heads, rope]
-        (rotated) over layer li's cached positions -> [B, s, heads, v].
-        A decode step on a chip runs absorbed through the latent kernel,
-        which reads the blocks a slot holds in place; everything else
-        walks each row's blocks in tiles (`ops/mla.py latent_attention`:
-        absorbed or expanded by the number of queries a row)."""
+        (rotated) over layer li's cached positions -> [B, s, heads, v]. On
+        a chip a step reads the blocks a row holds in place: a prefill chunk
+        expanded (`latent_prefill_attention`), a decode step absorbed
+        (`latent_decode_attention`). Shapes neither kernel takes and every
+        CPU run walk tiles (`ops/mla.py latent_attention`, either form)."""
         b, s = q_n.shape[:2]
-        rank = cfg.kv_lora_rank
         if q_pos.ndim == 1:
             q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
-        with scope("attn_latent"):
-            if latent_kernel_suits(s, self.kv, rank):
-                q = jnp.concatenate(
-                    [absorb_queries(q_n[:, 0], kv_b, cfg), q_r[:, 0]], axis=-1)
-                q = jnp.pad(q, ((0, 0), (0, 0),
-                                (0, self.kv.shape[3] - q.shape[-1])))
-                o_lat = latent_decode_attention(
-                    q, self.kv, li, self.tables,
-                    jnp.maximum(q_pos[:, 0] + 1, 0), rank=rank,
-                    sm_scale=1.0 / (cfg.qk_nope_head_dim
-                                    + cfg.qk_rope_head_dim) ** 0.5)
-                return values_from_latent(o_lat, kv_b, cfg)[:, None]
-            return self._tiled(li, q_n, q_r, q_pos, kv_b, cfg)
+        if latent_prefill_suits(q_n, q_r, self.kv, kv_b, self.tables.shape[1]):
+            return latent_prefill_attention(q_n, q_r, q_pos, self.kv, li,
+                                            self.tables, kv_b)
+        if latent_kernel_suits(s, self.kv, cfg.kv_lora_rank):
+            q = jnp.concatenate(
+                [absorb_queries(q_n[:, 0], kv_b, cfg), q_r[:, 0]], axis=-1)
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, self.kv.shape[3] - q.shape[-1])))
+            o_lat = latent_decode_attention(
+                q, self.kv, li, self.tables, jnp.maximum(q_pos[:, 0] + 1, 0),
+                rank=cfg.kv_lora_rank, sm_scale=1.0 / (
+                    cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5)
+            return values_from_latent(o_lat, kv_b, cfg)[:, None]
+        return self._tiled(li, q_n, q_r, q_pos, kv_b, cfg)
 
     def _tiled(self, li, q_n, q_r, q_pos, kv_b, cfg):
         bs, width = self.block_size, self.tables.shape[1]

@@ -772,17 +772,22 @@ def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
     and fit it beside the weights; the latent pool is never copied whole and
     is written in place; the decode step attends through the latent kernel
     (five layers, two stacks: one call a stack's scan body) under
-    `attn_latent`, a prefill chunk walks tiles and calls no attention
-    kernel; the experts of the expert stack are one grouped kernel; the
+    `attn_latent`, a prefill chunk through the prefill kernel, likewise one
+    call a stack, under a name the decode kernel's metrics do not match,
+    and no score-shaped array (float32 [heads, chunk, tile]) is left in the
+    program; the experts of the expert stack are one grouped kernel; the
     scopes the cell's metrics read are there."""
     comp, pool_shape, pool = compiled_pangu(topo, monkeypatch, program, rows)
     text = comp.as_text()
     assert text.startswith(f"HloModule jit_{program}")
     ins = instructions(text)
     found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    # `mla_absorb` (queries into the latent space, values out of it) is the
+    # decode step's: a prefill chunk expands inside its kernel
     assert found >= {"kv_write", "paged_attention", "attn_latent", "mla_q",
-                     "mla_kv_latent", "mla_absorb", "mla_o", "mlp", "moe_router",
+                     "mla_kv_latent", "mla_o", "mlp", "moe_router",
                      "moe_dispatch", "moe_experts", "moe_shared", "sample"}
+    assert ("mla_absorb" in found) == (program == "serve_decode")
     copies = whole_pool_copies(text, pool_shape)
     assert not copies, f"{program} copies the latent pool {pool_shape}: {copies}"
     head = text.splitlines()[0]
@@ -792,7 +797,9 @@ def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
     attn = re.compile(load("layer_metrics", "mla_attention_ms.serve")["params"]["ops"])
     latent = [(n, op) for n, op in kernels if attn.search(n)]
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
-    assert len(grouped) + len(latent) == len(kernels), kernels
+    chunked = [(n, op) for n, op in kernels
+               if n.startswith("latent_prefill_attention")]
+    assert len(grouped) + len(latent) + len(chunked) == len(kernels), kernels
     # the expert stack's scan body calls the kernel once a block of tokens
     # (`ops/moe.py MAX_SORTED_BYTES`: up to 4,096 tokens at these widths go
     # in one; 16 rows of 1,024 would go in four)
@@ -804,8 +811,15 @@ def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
     if program == "serve_decode":
         assert len(latent) == 2  # the dense stack's body and the expert stack's
         assert all("attn_latent" in words(op) for _, op in latent)
+        assert not chunked
     else:
-        assert not latent
+        assert not latent  # `mla_attention_ms.serve` divides by decode steps
+        assert len(chunked) == 2
+        assert all({"paged_attention", "attn_latent"} <= words(op)
+                   for _, op in chunked)
+        heads = load("configs", "openpangu-ultra-moe-5l-ep16")["model"][
+            "num_attention_heads"]
+        assert f"f32[{heads},{chunk},512]" not in text
     ma = comp.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
