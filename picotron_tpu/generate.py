@@ -58,7 +58,7 @@ from jax import lax
 
 from picotron_tpu.config import ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
-    DEFAULT_CTX, _mlp_block, by_period, compute_dtype, final_hidden,
+    DEFAULT_CTX, _mlp_block, compute_dtype, final_hidden,
     kind_tables, layer_window, mlp_act, model_rope_tables, norm_weight,
     qkv_proj, residual_stream, rms_norm, served_head, shared_expert,
 )
@@ -305,7 +305,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     def gqa(h, cache, lp, li, kind, ki):
         """q/k/v a head, K and V written and attended per head."""
         b, s, _ = h.shape
-        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
+        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps, keep_flat=True)
         c_k, s_k = kind_tables(cos, sin, kind)
         q = _rope(q, c_k, s_k, q_pos)
         k = _rope(k, c_k, s_k, q_pos)
@@ -324,7 +324,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         writes them, and with them the summary of every chunk the segment
         completes; the cache knows which rows a query may see."""
         b, s, _ = h.shape
-        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
+        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps, keep_flat=True)
         q = _rope(q, cos, sin, q_pos)
         k = _rope(k, cos, sin, q_pos)
         cache = cache.write(li, k, v, q_pos, lp["eva_mu"], lp["eva_phi"],
@@ -337,7 +337,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """Latent attention: `[c | k_r]` written, c normed and k_r
         rotated, and attended absorbed or expanded (ops/mla.py)."""
         b, s, _ = h.shape
-        q_n, q_r, c, k_r = mla_project(h, lp, cfg)
+        q_n, q_r, c, k_r = mla_project(h, lp, cfg, keep_flat=True)
         q_r = _rope(q_r, cos, sin, q_pos)
         k_r = _rope(k_r[:, :, None, :], cos, sin, q_pos)[:, :, 0]
         cache = cache.write(li, jnp.concatenate([c, k_r], axis=-1), q_pos)
@@ -389,38 +389,38 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         plen = len(period)
         before = {k: cfg.layer_kinds[:first].count(k) for k in set(st.kinds)}
 
-        def one(carry, lp, i, kind, ki):
-            # layer i of the stack (its place in the banks), first + i of
-            # the model
+        # every leaf of the stack stays whole, outside the scanned inputs,
+        # and a layer reads its own matrices by its index in the stack, as
+        # its grouped kernel reads its experts inside the banks
+        # (ops/grouped_experts.py): the slice then fuses into the matmul that
+        # consumes it. A period's slice of a scanned stack is one value that
+        # its layers share, which the compiler writes out to HBM every
+        # iteration of every step (tests/test_chip_compile.py weights_written)
+        layers = {n: w for n, w in stack.items() if n not in BANKS}
+        banks = {n: stack.get(n) for n in BANKS}
+
+        def one(carry, i, kind, ki):
+            # layer i of the stack (its place in the stack's leaves and
+            # banks), first + i of the model
             x, cache, touched = carry
+            lp = jax.tree.map(
+                lambda w: lax.dynamic_index_in_dim(w, i, 0, keepdims=False),
+                layers)
             x, cache, t = layer(x, cache, lp, banks, block, first + i, i,
                                 kind, ki)
             return x, cache, touched if t is None else touched + t
 
-        def body(carry, inputs):
-            lp, p = inputs
-            if plen == 1:
-                return one(carry, lp, p, period[0],
-                           before[period[0]] + p), None
+        def body(carry, p):
             for j, kind in enumerate(period):
-                carry = one(carry, jax.tree.map(lambda w: w[j], lp),
-                            p * plen + j, kind,
+                carry = one(carry, p * plen + j, kind,
                             before[kind] + p * period.count(kind)
                             + period[:j].count(kind))
             return carry, None
 
-        # the expert banks stay whole, outside the scanned inputs: a
-        # layer's grouped kernel addresses its experts inside the stack
-        # (ops/grouped_experts.py), so no bank is sliced out a layer
-        layers = {n: w for n, w in stack.items() if n not in BANKS}
-        banks = {n: stack.get(n) for n in BANKS}
-        carry, _ = lax.scan(
-            body, (x, cache, touched),
-            (by_period(layers, plen) if plen > 1 else layers,
-             jnp.arange(whole)))
+        carry, _ = lax.scan(body, (x, cache, touched), jnp.arange(whole))
         for i, kind in enumerate(rest, whole * plen):
-            carry = one(carry, jax.tree.map(lambda w: w[i], layers), i,
-                        kind, before[kind] + st.kinds[:i].count(kind))
+            carry = one(carry, i, kind,
+                        before[kind] + st.kinds[:i].count(kind))
         return carry
 
     # a dense model carries no counter: its programs are what they were

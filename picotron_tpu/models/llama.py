@@ -449,7 +449,7 @@ def residual_stream(x, cfg: ModelConfig):
     return x.astype(jnp.float32) if cfg.fp32_skip_add else x
 
 
-def qkv_proj(h, lp, d: int, eps: float = 1e-5):
+def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False):
     """Shared q/k/v projection (+ optional Qwen2 bias, tp-sharded with its
     output features; + optional QK-norm where the layer has `q_norm` /
     `k_norm` weights, an RMSNorm with `eps` before RoPE: over the WHOLE
@@ -461,7 +461,9 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5):
     counts come from the (possibly TP-sharded) weight shapes. One
     implementation for the training block, the fused grad engine's
     segment VJP AND the KV-cache decode path (generate.py) so
-    attention-input changes cannot silently diverge."""
+    attention-input changes cannot silently diverge. `keep_flat` (the
+    decode path's): the flat projections stay values of their own, see
+    below."""
     dt = h.dtype
     b, s, _ = h.shape
     q = h @ lp["q"].astype(dt)
@@ -484,6 +486,15 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5):
     q = checkpoint_name(q, "qkv_out")
     k = checkpoint_name(k, "qkv_out")
     v = checkpoint_name(v, "qkv_out")
+    if keep_flat:
+        # Folded into the matmul, the head split gives it a head-major
+        # result ([rows, heads, d]{2,0,1}), and for that form the chip's
+        # compiler wants the weight with the contracted dimension minor: a
+        # serve program then re-lays the stacks' q, k and v once a dispatch
+        # and writes each layer's slice of the copy out every step. A plain
+        # [rows, in] x [in, out] matmul reads the layer's matrix where it
+        # lies in the stack (tests/test_chip_compile.py weights_written)
+        q, k, v = (jax.lax.optimization_barrier(x) for x in (q, k, v))
     q, k, v = (q.reshape(b, s, -1, d), k.reshape(b, s, -1, d),
                v.reshape(b, s, -1, d))
     if per_head:

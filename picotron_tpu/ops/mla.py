@@ -44,18 +44,23 @@ TILE_KEYS = 512
 _NEG = -1e30
 
 
-def mla_project(h, lp, cfg):
+def mla_project(h, lp, cfg, keep_flat: bool = False):
     """The normed block input h [B, s, H] -> (q_n [B, s, heads, nope],
     q_r [B, s, heads, rope] unrotated, c [B, s, rank] normed, k_r
     [B, s, rope] unrotated). One implementation for `forward()` and the
-    cached decode paths."""
+    cached decode paths, which keep the flat q a value of its own
+    (`keep_flat`) so that `q_b` is read where it lies, as
+    `models.llama.qkv_proj` says."""
     dt = h.dtype
     b, s, _ = h.shape
     dn, dr, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     with scope("mla_q"):
         cq = rms_norm(h @ lp["q_a"].astype(dt), lp["q_a_norm"],
                       cfg.rms_norm_eps)
-        q = (cq @ lp["q_b"].astype(dt)).reshape(b, s, -1, dn + dr)
+        q = cq @ lp["q_b"].astype(dt)
+        if keep_flat:
+            q = jax.lax.optimization_barrier(q)
+        q = q.reshape(b, s, -1, dn + dr)
     with scope("mla_kv_latent"):
         ckr = h @ lp["kv_a"].astype(dt)                    # [B, s, rank + rope]
         c = rms_norm(ckr[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps)

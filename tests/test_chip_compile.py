@@ -283,6 +283,19 @@ def test_head_backward_takes_no_vocabulary_sized_detour(topo, monkeypatch, confi
     assert temp <= PARENT_TEMP_BYTES[config]
 
 
+_ABSTRACT_PARAMS: dict = {}
+
+
+def abstract_params(config: str):
+    """The shapes of the configuration's parameter tree: bf16 weights, as the
+    cell's runner serves them."""
+    if config not in _ABSTRACT_PARAMS:
+        m = config_from_dict({"model": load("configs", config)["model"]}).model
+        _ABSTRACT_PARAMS[config] = jax.eval_shape(lambda: jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0))))
+    return _ABSTRACT_PARAMS[config]
+
+
 CHAT_SLOTS = load("configs", "qwen2-1.5b")["serve"]["decode_slots"]
 _COMPILED_SERVE: dict = {}  # (program, rows) -> compiled_serve's result
 
@@ -319,9 +332,7 @@ def compiled_serve(topo, monkeypatch, program: str, rows=None,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
-    # bf16 weights, as the cell's runner serves them
-    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    params = on_chip(abstract_params(config))
     num_blocks = sc.num_blocks or slots * max_blocks
     cache = on_chip(jax.eval_shape(
         (lambda: init_eva_cache(m, num_blocks, sc.block_size, slots,
@@ -426,6 +437,58 @@ def whole_pool_copies(text: str, pool_shape) -> list:
     return found
 
 
+# instructions whose result is another's buffer, or none
+_NO_WRITE = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "conditional", "call", "optimization-barrier", "copy-start",
+             "slice-start", "dynamic-slice-start"}
+
+
+def weights_written(text: str, params, at_least: int = 8 * 2**20) -> list:
+    """[(in a while body?, the instruction's name without its number, a result
+    of it)] for every result of at least `at_least` bytes that a top-level
+    instruction (not one inside a fused computation) of a while body or of the
+    entry computation leaves in HBM (no `S(1)`) and that is shaped like one or
+    more layers of a stack leaf [L, ...] of `params`, the experts' banks apart
+    (they are addressed inside their stacks by the grouped kernel): a layer's
+    matrix sliced out of its stack, or a stack's layers laid out anew, and
+    written where the matmul that wants it could have read it in place."""
+    from picotron_tpu.generate import BANKS
+
+    tails = {(a.dtype.name.replace("bfloat", "bf").replace("float", "f"),
+              a.shape[0], tuple(a.shape[1:]))
+             for path, a in jax.tree_util.tree_leaves_with_path(params)
+             if a.ndim >= 3 and not {getattr(k, "key", None) for k in path} & set(BANKS)}
+    comps = computations(text)
+    fused = {c for lines in comps.values() for line in lines
+             for c in re.findall(r" fusion\(.*calls=%?([\w.\-]+)", line)}
+    in_loop = loop_computations(text, comps) - fused
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    found = []
+    for comp in sorted(in_loop | {entry}):
+        for line in comps[comp]:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+?)(?:\.\d+)? = (.*?) ([\w\-]+)\(", line)
+            if not m or m.group(3) in _NO_WRITE:
+                continue
+            for dt, dims, layout in re.findall(r"(\w+)\[([\d,]*)\](\{[^}]*\})?", m.group(2)):
+                d = tuple(int(x) for x in dims.split(",") if x)
+                like = any(dt == t and (d == tail or (d[1:] == tail and d[0] <= n))
+                           for t, n, tail in tails)
+                if (like and "S(" not in layout
+                        and math.prod(d) * int(dt.lstrip("bf")) // 8 >= at_least):
+                    found.append((comp in in_loop, m.group(1), f"{dt}[{dims}]"))
+    return found
+
+
+def assert_weights_read_in_place(text: str, config: str, left=()):
+    """No weight is written in a loop or in the entry computation
+    (`weights_written`) but those of `left`, which are there, each as often
+    as it is listed."""
+    found = weights_written(text, abstract_params(config))
+    assert sorted(found) == sorted(left), "\n".join(
+        f"{'in a loop' if loop else 'at entry '} {name} {shape}"
+        for loop, name, shape in found)
+
+
 # every shape the chat cell's engine dispatches: the prefill program at each
 # rung of its ladder of row counts (1, 4, 16, 32), and the decode program
 @pytest.mark.parametrize("program,rows", [
@@ -449,6 +512,10 @@ def test_serving_program_moves_no_whole_pool(topo, monkeypatch, program, rows):
     alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
     aliased = {int(p) for p in re.findall(r"\}: \((\d+), ", alias)}
     assert aliased >= pools, (alias, pools)
+    # no layer's matrix is written out of its stack, and no stack laid out
+    # anew: until PR 45 `serve_decode` copied q's stack once a dispatch
+    # (`copy.19 bf16[28,1536,1536]{1,2,0}`)
+    assert_weights_read_in_place(text, "qwen2-1.5b")
 
 
 def result_sizes(line: str) -> list:
@@ -532,8 +599,7 @@ def compiled_mellum(topo, monkeypatch, program: str, rows=None,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
-    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    params = on_chip(abstract_params(config))
     cache = on_chip(jax.eval_shape(lambda: init_mixed_cache(
         m, slots * max_blocks, slots * ring, sc.block_size, slots, max_blocks, ring)))
     k, v = _pools(cache)
@@ -615,6 +681,13 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
         # a period's [4, 64, ...] slices they were 3 GiB of temporaries, and a
         # second read and a write of every weight in a memory-bound step
         assert ma.temp_size_in_bytes < 0.5 * 2**30, ma.temp_size_in_bytes / 2**30
+    # nor any other leaf of the stack (PR 45). Scanned as a period's [4, ...]
+    # slices, q and o were `dynamic-slice_bitcast_fusion.27/.28` an iteration
+    # and o's three later layers a `copy-done bf16[4,4096,2304]` back to HBM,
+    # and q, wanted with the contracted dimension minor by a matmul that had
+    # the head split folded in, was re-laid whole once a dispatch (`copy.165
+    # bf16[8,2304,4096]{1,2,0}`) and a layer a `fusion` in the loop
+    assert_weights_read_in_place(text, "mellum2-12b-a2.5b-8l")
 
 
 @pytest.mark.parametrize("program,rows", [
@@ -671,6 +744,16 @@ def test_k_exaone_serving_programs(topo, monkeypatch, program, rows):
     print(program, rows, "total GiB", total / 2**30, "temp GiB",
           ma.temp_size_in_bytes / 2**30)
     assert total < 15.75 * 2**30, total / 2**30
+    # every projection is read where it lies (PR 45). Until then the decode
+    # step's loop wrote the expert stack's four q projections out of a copy of
+    # the stack every step (`fusion.870`: four results `bf16[1,6144,8192]
+    # {1,2,0}`, three of them in HBM; k and v likewise, `fusion.899/.904`) and
+    # evicted and fetched the dense stack's (`copy-done bf16[1,6144,8192]`);
+    # its entry computation re-laid q, k and v of both stacks once a dispatch
+    # (`copy.122 bf16[4,6144,8192]{1,2,0}`, `copy.119`, `copy.121/.123`); the
+    # prefill program's copied each layer's o (`copy bf16[8192,6144]` x 4 and
+    # three `slice_bitcast_fusion`s at one row)
+    assert_weights_read_in_place(text, name)
 
 
 @pytest.mark.parametrize("program,rows", [
@@ -710,9 +793,30 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     print(program, rows, "total GiB", total / 2**30, "temp GiB",
           ma.temp_size_in_bytes / 2**30)
     assert total < 15.75 * 2**30, total / 2**30
+    # until PR 45 `serve_decode` re-laid q, k and v once a dispatch (three
+    # `copy bf16[8,4096,4096]{1,2,0}`)
+    assert_weights_read_in_place(text, name)
 
 
 _COMPILED_PANGU: dict = {}
+# What PR 45 left of `weights_written` (it took q_b's: `copy.50 bf16[4,1536,
+# 24576]{1,2,0}` and `copy.47` at the decode program's entry, a `constant_
+# dynamic-slice_fusion` or a `copy` of a layer's in the prefill program's scan).
+# `kv_b` is no [rows, in] x [in, out] matmul: the absorbed decode step contracts
+# it a head, over `nope` on the way in and over `rank` on the way out (ops/mla.py
+# up_weights), and a batched matmul wants its batch, the heads, major. So the
+# decode program re-lays the expert stack's four once a dispatch (`copy.35`,
+# 134 MB) and keeps the dense stack's, re-laid into fast memory at entry, by
+# evicting and fetching it every step (`copy-done.3`, 34 MB each way); the
+# prefill program at 16 rows re-lays the dense stack's (`copy.111`). `kv_a`
+# [4,7680,576] arrives as {1,2,0} (the runtime's own layout for a minor
+# dimension of 576) and is wanted {2,1,0}: `copy.34`, 35 MB a dispatch.
+PANGU_WEIGHTS_WRITTEN = {
+    ("serve_decode", None): [(False, "copy", "bf16[4,512,32768]"),
+                             (False, "copy", "bf16[4,7680,576]"),
+                             (True, "copy-done", "bf16[1,512,32768]")],
+    ("serve_prefill", 16): [(False, "copy", "bf16[1,512,32768]")],
+}
 
 
 def compiled_pangu(topo, monkeypatch, program: str, rows=None):
@@ -740,8 +844,7 @@ def compiled_pangu(topo, monkeypatch, program: str, rows=None):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
-    params = on_chip(jax.eval_shape(lambda: jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0)))))
+    params = on_chip(abstract_params("openpangu-ultra-moe-5l-ep16"))
     cache = on_chip(jax.eval_shape(lambda: init_latent_cache(
         m, sc.num_blocks, sc.block_size, slots, max_blocks)))
     cos, sin = on_chip(jax.eval_shape(
@@ -826,3 +929,5 @@ def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
     assert total < 15.75 * 2**30, total / 2**30
     print(program, rows, "total GiB", total / 2**30, "temp GiB",
           ma.temp_size_in_bytes / 2**30)
+    assert_weights_read_in_place(text, "openpangu-ultra-moe-5l-ep16",
+                                 PANGU_WEIGHTS_WRITTEN.get((program, rows), ()))

@@ -86,6 +86,52 @@ def test_greedy_generate_matches_full_recompute(tiny):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ids))
 
 
+S, F = "sliding_attention", "full_attention"
+# stacks whose layer pattern has a period: the scan over its whole periods, the
+# layers left over after them, and each layer's place in its stack's leaves
+PATTERNED = {
+    # a dense layer's stack, then one period and a layer left over
+    "S|(S,S,F)+S": ("debug-tiny-exaone-moe", {}),
+    # two periods and a layer left over
+    "S|(S,S,F)x2+S": ("debug-tiny-exaone-moe", dict(
+        num_hidden_layers=8, layer_types=(S,) + (S, S, F) * 2 + (S,))),
+    # a period of four and three layers left over
+    "S|(S,S,F,S)+S,S,F": ("debug-tiny-exaone-moe", dict(
+        num_hidden_layers=8, layer_types=(S, S, S, F) * 2)),
+    # two whole periods, nothing left over
+    "(S,S,S,F)x2": ("debug-tiny-mellum2", {}),
+}
+
+
+@pytest.mark.parametrize("pattern", PATTERNED)
+def test_patterned_stacks_decode_what_forward_scores(pattern):
+    """Every layer of a stack with a period reads ITS OWN weights out of the
+    stack's whole leaves (`_decode_layers.run_stack`: layer `p * plen + j` of
+    the scan, `whole * plen + i` after it): the cached path's logits are the
+    full forward's at every position, and a greedy `generate` is token for
+    token the argmax of `forward()` over what it produced."""
+    preset, over = PATTERNED[pattern]
+    cfg = ModelConfig(dtype="float32", **{**resolve_preset(preset), **over})
+    cfg.validate()
+    params = init_params(cfg, jax.random.key(5))
+    # a trained model's embedding scale, so that the layers show in the logits
+    params = dict(params, embedding=params["embedding"] * 0.1)
+    ids = jax.random.randint(jax.random.key(1), (2, 14), 0, cfg.vocab_size)
+    want = forward(params, ids, cfg).astype(jnp.float32)
+    got = teacher_forced_cache_logits(params, cfg, ids)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    # a layer that read its neighbour's weights moves the logits by far more
+    swapped = dict(params, **{st.name: jax.tree.map(lambda w: w[::-1], params[st.name])
+                              for st in cfg.stacks if st.layers > 1})
+    moved = np.abs(np.asarray(forward(swapped, ids, cfg)) - np.asarray(want)).max()
+    assert moved > 1e-2, moved
+    out = generate(params, cfg, ids, max_new_tokens=6)
+    scored = forward(params, out[:, :-1], cfg)[:, -6:].astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out[:, -6:]),
+                                  np.asarray(jnp.argmax(scored, axis=-1)))
+
+
 def test_sampling_shapes_and_determinism(tiny):
     cfg, params = tiny
     prompt = jnp.zeros((3, 4), jnp.int32)
