@@ -692,11 +692,10 @@ def run_serve_disagg(model: str, layers, *, slots: int, block_size: int,
                      num_blocks: int, prefill_chunk: int, prompt_len: int,
                      max_new: int, n_requests: int, rate: float,
                      decode_interval: int = 4, seed: int = 0,
-                     draft_lens=(1, 2, 3),
                      telemetry: str | None = None) -> dict:
     """Disaggregated vs colocated serving (picotron_tpu/serve/disagg) on
-    the deterministic long-prefill burst trace, plus two sweep
-    artifacts. One JSON line:
+    the deterministic long-prefill burst trace, plus a sweep
+    artifact. One JSON line:
 
     - headline: the drop in max consecutive decode-dispatch stall ticks
       (colocated minus disagg) on the burst trace — the number
@@ -705,13 +704,8 @@ def run_serve_disagg(model: str, layers, *, slots: int, block_size: int,
       saturation point only), TTFT/TPOT/queue-wait percentiles for both
       engines on the SAME Poisson trace — the
       disaggregated-vs-colocated SLO comparison.
-    - `acceptance_sweep`: the n-gram speculator over `draft_lens` on the
-      mixed saturation trace: acceptance rate, decode dispatches, and
-      draft accounting per draft length (speculative decode is
-      token-identical to non-speculative by construction, so this is
-      pure work-per-dispatch accounting, not a quality trade).
 
-    Stall ticks, slot-steps, handoffs, and acceptance are structural —
+    Stall ticks, slot-steps and handoffs are structural —
     identical on any host; only the secondary wall fields are timing
     (see `wall_note`)."""
     from picotron_tpu.analysis.cost_model import CostModel
@@ -788,23 +782,6 @@ def run_serve_disagg(model: str, layers, *, slots: int, block_size: int,
             }
         slo_curve.append(point)
 
-    # --- acceptance-rate sweep: n-gram speculator per draft length ---
-    sweep_trace = make_serve_trace(n_requests, 0.0, prompt_len, max_new,
-                                   mcfg.vocab_size, seed)
-    acceptance_sweep = []
-    for dl in draft_lens:
-        s, _ = run(DisaggServeEngine,
-                   scfg(disagg=True, speculator="ngram", draft_len=dl),
-                   sweep_trace)
-        acceptance_sweep.append({
-            "draft_len": dl,
-            "acceptance_rate": s["acceptance_rate"],
-            "draft_tokens": s["draft_tokens"],
-            "accepted_draft_tokens": s["accepted_draft_tokens"],
-            "decode_steps": s["decode_steps"],
-            "output_tokens": s["output_tokens"],
-        })
-
     handoff_s, handoff_bytes = CostModel("v5e").price_kv_handoff(
         mcfg, scfg(disagg=True))
     print(f"# {_WALL_NOTE}", file=sys.stderr)
@@ -834,7 +811,6 @@ def run_serve_disagg(model: str, layers, *, slots: int, block_size: int,
         "disagg_wall_s": round(dis_wall, 4),
         "wall_note": _WALL_NOTE,
         "slo_curve": slo_curve,
-        "acceptance_sweep": acceptance_sweep,
         "device_kind": jax.devices()[0].device_kind,
     }
 
@@ -1359,11 +1335,8 @@ def main(argv=None) -> None:
                     help="--serve: disaggregated vs colocated engines "
                          "(picotron_tpu/serve/disagg) — deterministic "
                          "decode-stall drop on a long-prefill burst "
-                         "trace, a --rate SLO curve for both engines, "
-                         "and an n-gram speculator acceptance sweep")
-    ap.add_argument("--draft-lens", type=int, nargs="*", default=[1, 2, 3],
-                    help="--serve --disagg: speculator draft lengths "
-                         "for the acceptance sweep")
+                         "trace and a --rate SLO curve for both "
+                         "engines")
     ap.add_argument("--fleet", type=int, default=0,
                     help="--serve: run N engine replicas behind one "
                          "queue (picotron_tpu/serve/fleet) instead of "
@@ -1510,7 +1483,6 @@ def main(argv=None) -> None:
                 prompt_len=args.prompt_len,
                 max_new=args.max_new_tokens, n_requests=args.requests,
                 rate=args.rate, decode_interval=args.decode_interval,
-                draft_lens=tuple(args.draft_lens),
                 telemetry=args.telemetry)))
             return
         print(json.dumps(run_serve(

@@ -281,7 +281,7 @@ def prove_mpmd_stages(cfg, menv=None) -> Report:
 # Serve programs
 # ---------------------------------------------------------------------------
 
-_PERSISTENT = ("params", "_k", "_v", "cos", "sin", "base_key")
+_PERSISTENT = ("params", "_kv", "cos", "sin", "base_key")
 _UPLOADED = ("tables", "toks", "positions", "rids", "tidx")
 
 
@@ -427,10 +427,7 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
     program (the prefill-pool program: one per row count of
     `prefill_rungs(prefill_slots)`, each compiled by the constructor)
     => no pool compiles after construction, so a prefill burst cannot
-    trigger a decode-side recompile (nor vice versa). With
-    `speculator = "ngram"` the decode-pool program is the
-    speculative scan; its ctx buffer is [S, CTX_W] with CTX_W constant,
-    so the closure argument is unchanged."""
+    trigger a decode-side recompile (nor vice versa)."""
     import jax.numpy as jnp
 
     from picotron_tpu.config import ServeConfig
@@ -464,10 +461,6 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
         "tables": i32(s, max_blocks), "toks": i32(s),
         "positions": i32(s), "rids": i32(s), "tidx": i32(s),
     }
-    if scfg.speculator == "ngram":
-        from picotron_tpu.serve.spec_decode import CTX_W
-
-        decode_args["ctx"] = i32(s, CTX_W)
     prefill_args = {
         "k": sds(pcache.k), "v": sds(pcache.v),
         "tables": i32(p, max_blocks),
@@ -503,7 +496,6 @@ def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
         # the prefill-pool program's batch is compacted: its signature
         # above is the top rung's, the others differ in the row count only
         "prefill_rows": list(prefill_rungs(p)),
-        "speculator": scfg.speculator,
     }
     rep.add(CHECK, INFO, "serve_disagg",
             f"compile-once proven for both pools + handoff: "

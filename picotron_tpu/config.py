@@ -260,7 +260,7 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
     # head j predicts byte t + 1 + j. Not built: training (no loss over the
     # 8 heads, no banded kernel with summary keys: ROADMAP M9),
     # self-speculative decoding from heads 1-7 (ROADMAP M8), tp / pp / cp /
-    # ep, the disaggregated engine, the fleet, the n-gram speculator.
+    # ep, the disaggregated engine, the fleet.
     "EvaByte/EvaByte": dict(
         vocab_size=320, hidden_size=4096, intermediate_size=11008,
         num_hidden_layers=32, num_attention_heads=32,
@@ -1476,18 +1476,6 @@ class ServeConfig:
     # anyway.
     drain_grace_s: float = 5.0
 
-    # --- speculative decode (serve/spec_decode.py): multi-token decode
-    # inside the decode_interval scan, verify-and-accept in one dispatch,
-    # sampling keys still derived from (request id, token index) so
-    # accept/reject cannot perturb tokens ---
-    # 'off' (one token per slot per step) or 'ngram' (self-drafting
-    # n-gram speculator over each slot's recent context — no draft
-    # model, the prompt-lookup arrangement).
-    speculator: str = "off"
-    # Tokens drafted (and verified) per decode step when the speculator
-    # is on; each step emits 1..draft_len+1 tokens per slot.
-    draft_len: int = 3
-
     def validate(self) -> None:
         for name in ("decode_slots", "block_size", "prefill_chunk",
                      "decode_interval"):
@@ -1524,17 +1512,6 @@ class ServeConfig:
             raise ValueError(
                 f"serve.drain_grace_s must be >= 0, got "
                 f"{self.drain_grace_s}")
-        if self.speculator not in ("off", "ngram"):
-            raise ValueError(
-                f"serve.speculator must be 'off' or 'ngram', got "
-                f"{self.speculator!r}")
-        if self.speculator != "off" and self.draft_len < 1:
-            raise ValueError(
-                f"serve.draft_len must be >= 1 when a speculator is on, "
-                f"got {self.draft_len}")
-        if self.draft_len < 0:
-            raise ValueError(
-                f"serve.draft_len must be >= 0, got {self.draft_len}")
 
 
 @dataclass(frozen=True)
@@ -1702,20 +1679,18 @@ class Config:
                 f"serve.max_model_len ({self.serve.max_model_len}) exceeds "
                 f"max_position_embeddings "
                 f"({self.model.max_position_embeddings})")
-        if self.model.num_experts and (self.serve.disagg
-                                       or self.serve.speculator != "off"):
+        if self.model.num_experts and self.serve.disagg:
             # ServeEngine serves experts (dropless at ep = 1: a token's
             # experts depend on that token alone, whatever the chunking);
-            # the disaggregated engine's block handoff and the speculative
-            # verify scan have never run an expert block, and nothing
-            # tests them with one. Their engines reject MoE at
-            # construction; this catches the intent at config load.
+            # the disaggregated engine's block handoff has never run an
+            # expert block, and nothing tests it with one. Its engine
+            # rejects MoE at construction; this catches the intent at
+            # config load.
             raise ValueError(
-                "serve.disagg / serve.speculator do not support MoE "
-                "models (model.num_experts > 0): nobody has run or tested "
-                "the disaggregated handoff or the speculative verify scan "
-                "with an expert block; serve experts through the plain "
-                "ServeEngine")
+                "serve.disagg does not support MoE models "
+                "(model.num_experts > 0): nobody has run or tested the "
+                "disaggregated handoff with an expert block; serve experts "
+                "through the plain ServeEngine")
         if self.serve.fleet_size > 1 and self.model.num_experts:
             # the fleet's bit-identical failover re-dispatch is pinned by
             # test for dense models only
@@ -1728,19 +1703,6 @@ class Config:
         if self.model.layer_types is not None:
             self._refuse_window_layers()
         self._refuse_new_blocks()
-        if self.serve.fleet_size > 1 and self.serve.speculator != "off":
-            # The n-gram drafter's context is engine-local state that a
-            # failover re-dispatch does not carry — tokens stay identical
-            # (verify-and-accept guarantees that) but the fleet's
-            # redispatch-latency and acceptance accounting would be
-            # engine-dependent; keep the combination a hard error until
-            # it is pinned.
-            raise ValueError(
-                "serve.fleet_size > 1 does not support speculative decode "
-                "(serve.speculator != 'off'): the drafter's context is "
-                "engine-local and is not carried across failover "
-                "re-dispatch; set serve.speculator='off' or "
-                "serve.fleet_size=1")
         d, m, t = self.distributed, self.model, self.training
         ck = self.checkpoint
         if ck.keep_last < 0 or ck.keep_every < 0:
@@ -2035,8 +1997,8 @@ class Config:
         if d.tp_size > 1:
             refuse(f"tensor parallelism (tp_size={d.tp_size}: the two "
                    f"pools of a mixed cache are not sharded)")
-        if sv.disagg or sv.speculator != "off" or sv.fleet_size > 1:
-            refuse("serve.disagg / serve.speculator / serve.fleet_size > 1 "
+        if sv.disagg or sv.fleet_size > 1:
+            refuse("serve.disagg / serve.fleet_size > 1 "
                    "(one pool, one table a slot)")
 
     def _refuse_new_blocks(self) -> None:
@@ -2104,8 +2066,8 @@ class Config:
             refuse(f"expert parallelism (ep_size={d.ep_size})",
                    "the exchange across 'ep' routes by softmax gates over "
                    "every expert; a held share is served without exchange")
-        if sv.disagg or sv.speculator != "off" or sv.fleet_size > 1:
-            refuse("serve.disagg / serve.speculator / serve.fleet_size > 1",
+        if sv.disagg or sv.fleet_size > 1:
+            refuse("serve.disagg / serve.fleet_size > 1",
                    "one K/V pool, never run with this block")
 
     def to_json_dict(self) -> dict[str, Any]:
@@ -2199,32 +2161,41 @@ def _filter_kwargs(cls: type, raw: dict[str, Any]) -> dict[str, Any]:
     return {k: v for k, v in raw.items() if k in names}
 
 
-# Options of `distributed` that were removed together with the code they
-# selected, with their former defaults. Unknown keys are ignored on load, so
+# Options that were removed together with the code they selected, by
+# section, with their former defaults. Unknown keys are ignored on load, so
 # without this a config asking for a removed schedule would silently train
-# the one that remains. A key carrying its former default loads (dumped
-# configs of old runs do) and is dropped.
-_RETIRED_DISTRIBUTED = {
-    "tp_strategy": "megatron",
-    "tp_sync": "sync",
-    "tp_mesh": "",
-    "dcn_axes": "dp,pp",
-    "hier_dp_reduce": "auto",
+# the one that remains (or serve one token a step where it asked for
+# drafts). A key carrying its former default loads (dumped configs of old
+# runs do) and is dropped.
+_RETIRED = {
+    "distributed": {
+        "tp_strategy": "megatron",
+        "tp_sync": "sync",
+        "tp_mesh": "",
+        "dcn_axes": "dp,pp",
+        "hier_dp_reduce": "auto",
+    },
+    "serve": {
+        "speculator": "off",
+        "draft_len": 3,
+    },
 }
 
 
-def _reject_retired(dist_raw: dict[str, Any]) -> None:
-    for key, former in _RETIRED_DISTRIBUTED.items():
-        if key in dist_raw and dist_raw[key] != former:
-            raise ValueError(
-                f"distributed.{key}={dist_raw[key]!r}: this option was "
-                f"removed with the code it selected; only its former "
-                f"default {former!r} still loads. Delete the key.")
+def _reject_retired(raw: dict[str, Any]) -> None:
+    for section, retired in _RETIRED.items():
+        given = raw.get(section) or {}
+        for key, former in retired.items():
+            if key in given and given[key] != former:
+                raise ValueError(
+                    f"{section}.{key}={given[key]!r}: this option was "
+                    f"removed with the code it selected; only its former "
+                    f"default {former!r} still loads. Delete the key.")
 
 
 def config_from_dict(raw: dict[str, Any]) -> Config:
     """Build a Config from a (reference-schema-compatible) dict."""
-    _reject_retired(raw.get("distributed", {}))
+    _reject_retired(raw)
     model_raw = dict(raw.get("model", {}))
     name = model_raw.get("name")
     if name:

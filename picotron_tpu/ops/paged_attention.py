@@ -78,9 +78,9 @@ def decode_kernel_suits(q, k_pool) -> bool:
     128-lane rows, the block a whole number of sublane tiles of the pool's
     dtype (16 rows of bf16, 8 of float32), and a backend that compiles
     Pallas kernels. Everything else keeps the gathered view: prefill
-    chunks and the speculative program (more than one query a slot), a
-    pool that a tp mesh shards (`ShardedPagedKVCache` never asks), the tiny
-    test models' heads and blocks, and every CPU run. The two other cache
+    chunks (more than one query a slot), a pool that a tp mesh shards
+    (`ShardedPagedKVCache` never asks), the tiny test models' heads and
+    blocks, and every CPU run. The two other cache
     kinds answer for themselves: a model with sliding layers asks this for
     each of its two pools and walks tiles where the answer is no
     (`MixedPagedKVCache`); a latent cache asks `latent_kernel_suits` and

@@ -3,7 +3,9 @@ path — the "millions of users, heavy traffic" half of the north star.
 
 - `paged_cache`: block-pool KV cache (fixed-size blocks, per-slot block
   tables, memory ~ blocks allocated, not batch x max_length) behind the
-  same interface as the offline contiguous `generate.KVCache`.
+  same interface as the offline contiguous `generate.KVCache`; which
+  kind of cache a model is served from (`init_serve_cache`) and every
+  fact of its format.
 - `scheduler`: FIFO admission into a fixed decode-slot batch, chunked
   prefill, youngest-first preemption with recompute, retirement — pure
   host logic. `DisaggScheduler` splits the slot set in two with a
@@ -17,9 +19,6 @@ path — the "millions of users, heavy traffic" half of the north star.
   PLACED pools over their own block pools, paged-KV block handoff via
   explicit `device_put` (the MPMD ring-buffer discipline), so prefill
   bursts cannot stall decode dispatches.
-- `spec_decode`: speculative multi-token decode (self-drafting n-gram
-  speculator, verify-and-accept in one dispatch) for either engine;
-  token-identical to non-speculative decode by construction.
 - `fleet`: `FleetSupervisor` — N engine replicas behind one queue, with
   failover re-dispatch (bit-identical continuations), deadline load
   shedding, hang detection, and graceful drain.

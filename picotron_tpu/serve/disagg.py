@@ -16,8 +16,8 @@ discipline the pipeline executor uses for boundary activations.
   program as the colocated engine (chunked, batched over mid-prefill
   slots). Admission is budgeted against THIS pool only.
 - **Decode pool**: `decode_slots` slots over `num_blocks` blocks on
-  `decode_device`, running the same decode program (speculative or not)
-  via the `ServeEngine._decode_tick` it inherits. Long-prompt bursts
+  `decode_device`, running the same decode program via the
+  `ServeEngine._decode_tick` it inherits. Long-prompt bursts
   cannot touch it: `bench.py --serve --disagg` measures the max
   consecutive decode-stall ticks collapsing vs colocated.
 - **Handoff**: a jitted block gather on the prefill device ->
@@ -55,9 +55,9 @@ import numpy as np
 from picotron_tpu.config import ModelConfig, ServeConfig
 from picotron_tpu.models.llama import model_rope_tables
 from picotron_tpu.serve.engine import (
-    ServeEngine, _get_jits, _sharded, prefill_rungs,
+    ServeEngine, _get_jits, mesh_shardings, new_cache, prefill_rungs,
 )
-from picotron_tpu.serve.paged_cache import BlockPool, init_paged_cache
+from picotron_tpu.serve.paged_cache import BlockPool
 from picotron_tpu.serve.scheduler import DisaggScheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
 
@@ -129,9 +129,7 @@ class DisaggServeEngine(ServeEngine):
                 "between the two pools has never run an expert block or "
                 "another kind of cache state, and nothing tests it with "
                 "one. Serve them through ServeEngine.")
-        self.mixed, self.wpool = False, None  # one pool a side, full layers
-        self.latent = False  # K and V per head (latent attention is refused)
-        self.eva = False  # a position a row (EVA attention is refused)
+        self.wpool = None  # one pool a side, full layers
         self.cfg = model_cfg
         self.scfg = scfg
         self.eos_token_id = eos_token_id
@@ -141,7 +139,6 @@ class DisaggServeEngine(ServeEngine):
         self.max_len = scfg.max_model_len or model_cfg.max_position_embeddings
         self.block_size = scfg.block_size
         self.max_blocks = blocks_for(self.max_len, self.block_size)
-        self.table_width = self.max_blocks
         self.num_slots = scfg.decode_slots
         self.num_blocks = (scfg.num_blocks
                            or scfg.decode_slots * self.max_blocks)
@@ -150,39 +147,17 @@ class DisaggServeEngine(ServeEngine):
         self.pnum_blocks = (scfg.prefill_num_blocks
                             or self.num_pslots * self.max_blocks)
 
-        self.speculate = scfg.speculator == "ngram"
-        self.draft_len = scfg.draft_len if self.speculate else 0
-        if self.speculate:
-            from picotron_tpu.serve import spec_decode
-            if self.draft_len > spec_decode.max_draft_len():
-                raise ValueError(
-                    f"serve.draft_len ({self.draft_len}) exceeds the "
-                    f"drafter's context window: max "
-                    f"{spec_decode.max_draft_len()}")
-
         # ---- placement: one sharding per pool, everything committed up
         # front (the colocated engine's variant discipline, doubled).
         # tp-sharded params pin both pools to the mesh; otherwise each
         # pool gets its own device, defaulting to distinct devices when
         # the backend has more than one.
-        from jax.sharding import (
-            NamedSharding, PartitionSpec, SingleDeviceSharding,
-        )
-        mesh_sh = None
-        for leaf in jax.tree.leaves(params):
-            sh = getattr(leaf, "sharding", None)
-            if isinstance(sh, NamedSharding):
-                mesh_sh = NamedSharding(sh.mesh, PartitionSpec())
-                kv_sh = NamedSharding(
-                    sh.mesh,
-                    PartitionSpec("tp")
-                    if dict(zip(sh.mesh.axis_names,
-                                sh.mesh.devices.shape)).get("tp", 1) > 1
-                    else PartitionSpec())
-                break
-        if mesh_sh is not None:
+        from jax.sharding import SingleDeviceSharding
+        on_mesh = mesh_shardings(params)
+        if on_mesh is not None:
+            mesh_sh, kv_sh_d = on_mesh
             self._sh_p = self._sh_d = self._rep_sh = mesh_sh
-            kv_sh_p = kv_sh_d = kv_sh
+            kv_sh_p = kv_sh_d
         else:
             devices = jax.devices()
             d_idx = scfg.decode_device if scfg.decode_device >= 0 else 0
@@ -204,7 +179,7 @@ class DisaggServeEngine(ServeEngine):
         # uncommitted leaves get committed, tp shardings stay untouched)
         put_p = partial(jax.device_put, device=self._sh_p)
         put_d = partial(jax.device_put, device=self._sh_d)
-        if mesh_sh is not None:
+        if on_mesh is not None:
             self.params_p = self.params = jax.tree.map(
                 lambda x: x if getattr(x, "committed", True)
                 else jax.device_put(x, mesh_sh), params)
@@ -219,22 +194,14 @@ class DisaggServeEngine(ServeEngine):
         self.base_key = put_d(jax.random.key(seed))
         self.base_key_p = put_p(jax.random.key(seed))
 
-        dcache = init_paged_cache(model_cfg, self.num_blocks,
-                                  self.block_size, self.num_slots,
-                                  self.max_blocks)
-        self._k = jax.device_put(dcache.k, kv_sh_d)
-        self._v = jax.device_put(dcache.v, kv_sh_d)
-        pcache = init_paged_cache(model_cfg, self.pnum_blocks,
-                                  self.block_size, self.num_pslots,
-                                  self.max_blocks)
-        self._k_p = jax.device_put(pcache.k, kv_sh_p)
-        self._v_p = jax.device_put(pcache.v, kv_sh_p)
-
-        # host table mirrors, one per pool; sentinel = each pool's size
-        self._tables = np.full((self.num_slots, self.max_blocks),
-                               self.num_blocks, np.int32)
-        self._tables_p = np.full((self.num_pslots, self.max_blocks),
-                                 self.pnum_blocks, np.int32)
+        # a cache a pool, kept as the colocated engine keeps its one:
+        # `cache` / `_kv` / `_tables` are the decode pool's
+        self.cache, self._kv, self._tables = new_cache(
+            model_cfg, scfg, self.num_slots, self.num_blocks, self.max_len,
+            kv_sh_d)
+        self.cache_p, self._kv_p, self._tables_p = new_cache(
+            model_cfg, scfg, self.num_pslots, self.pnum_blocks,
+            self.max_len, kv_sh_p)
         self.pool = BlockPool(self.num_blocks)
         self.pool_p = BlockPool(self.pnum_blocks)
         self.sched = DisaggScheduler(self.num_pslots, self.num_slots,
@@ -245,9 +212,6 @@ class DisaggServeEngine(ServeEngine):
         self.telemetry = telemetry or Telemetry(sinks=[])
         donate = jax.default_backend() != "cpu"
         self._decode_jit, self._prefill_jit = _get_jits(donate)
-        if self.speculate:
-            from picotron_tpu.serve.spec_decode import get_spec_jit
-            self._decode_jit = get_spec_jit(donate)
         self._gather_jit, self._scatter_jit = _get_handoff_jits(donate)
 
         self._t0 = time.perf_counter()
@@ -260,7 +224,6 @@ class DisaggServeEngine(ServeEngine):
             "prefill_chunks": 0, "occupancy_sum": 0.0,
             "prefill_occupancy_sum": 0.0, "prefill_ticks": 0,
             "output_tokens": 0, "prefill_tokens": 0,
-            "draft_tokens": 0, "accepted_draft_tokens": 0,
             "decode_stall_ticks_max": 0, "cancelled": 0,
             "handoffs": 0, "handoff_s": 0.0, "handoff_blocks": 0,
         }
@@ -283,24 +246,21 @@ class DisaggServeEngine(ServeEngine):
     # inherited `_prefill_tick` / `_warm_prefill` at it
 
     def _sync_ptable(self, pslot: int) -> None:
-        st = self.sched.pslots[pslot]
-        row = np.full((self.max_blocks,), self.pnum_blocks, np.int32)
-        if st is not None and st.blocks:
-            row[:len(st.blocks)] = st.blocks
-        self._tables_p[pslot] = row
+        self._write_rows(self.cache_p, self._tables_p, pslot,
+                         self.sched.pslots[pslot])
 
     _PREFILL_PHASE = {"pool": "prefill"}
 
     def _prefill_pool(self):
-        return (self.sched.pslots, self._tables_p, self.pnum_blocks,
+        return (self.sched.pslots, self.cache_p, self._tables_p,
                 self._sh_p)
 
     def _run_prefill(self, feed):
-        self._k_p, self._v_p, toks, logits = self._prefill_jit(
-            self.params_p, self._k_p, self._v_p, *feed, self.base_key_p,
+        self._kv_p, toks, logits = self._prefill_jit(
+            self.params_p, self._kv_p, *feed, self.base_key_p,
             self.cos_p, self.sin_p, cfg=self.cfg,
             temperature=self.temperature, top_k=self.top_k,
-            pool_sharded=_sharded(self._k_p))
+            cache_cls=type(self.cache_p))
         return toks, logits
 
     def _retire_prefilled(self, pslot: int, t: float) -> int:
@@ -327,12 +287,10 @@ class DisaggServeEngine(ServeEngine):
         idx_dst = np.full((self.max_blocks,), self.num_blocks, np.int32)
         idx_dst[:len(dst)] = dst
         buf_k, buf_v = self._gather_jit(
-            self._k_p, self._v_p,
-            jax.device_put(idx_src, self._sh_p))
+            *self._kv_p, jax.device_put(idx_src, self._sh_p))
         buf_k, buf_v = jax.device_put((buf_k, buf_v), self._sh_d)
-        self._k, self._v = self._scatter_jit(
-            self._k, self._v, buf_k, buf_v,
-            jax.device_put(idx_dst, self._sh_d))
+        self._kv = self._scatter_jit(
+            *self._kv, buf_k, buf_v, jax.device_put(idx_dst, self._sh_d))
 
     # -- one engine iteration ---------------------------------------------
 

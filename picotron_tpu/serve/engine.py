@@ -27,25 +27,21 @@ lifetime (the prefill program once per row count of a short ladder):
   row whose prompt ends in the chunk returns its last valid position's
   token — the request's first token (TTFT).
 
-Both run `generate._decode_layers` against `PagedKVCache` — the same
+Both run `generate._decode_layers` against a paged cache — the same
 layer math as the offline contiguous path, which is what makes greedy
 token parity between the two cache implementations a pinned test
-invariant. tp-sharded params from `generate.place_for_decode` work
-unchanged: the programs are pure GSPMD, XLA propagates the shardings
+invariant. Which kind of paged cache a model has, and everything about
+its format (its pools, the rows of its tables, what a step reads of it),
+is decided and known in serve/paged_cache.py (`init_serve_cache` and the
+class it returns): the engine holds that cache's shapes (`cache`), its
+pools on the device (`_kv`) and the host mirrors of its tables
+(`_tables`), and asks the cache whatever depends on its kind. tp-sharded
+params from `generate.place_for_decode` work unchanged: the programs are pure GSPMD, XLA propagates the shardings
 through the block pool and inserts the collectives.
 
 Sampling keys derive from (request id, token index), so tokens are
 independent of slot assignment, arrival interleaving, and preemption —
 the ragged-batch-invariance property the tests pin.
-
-With ``serve.speculator = "ngram"`` the decode program is swapped for
-the speculative verify-and-accept scan (serve/spec_decode.py): each
-dispatch still compiles once and still covers `decode_interval`
-iterations, but every iteration forwards 1 + draft_len candidate tokens
-and emits between 1 and 1 + draft_len of them. The same key fold keys
-every candidate position, so speculative output is bit-identical to the
-non-speculative stream at any temperature — acceptance only changes how
-fast the stream advances.
 
 Sparse experts are served through the dropless dispatch (`ops/moe.py`
 `moe_mlp_served`): every expert is on the device, a token's experts depend
@@ -54,11 +50,7 @@ rows without a token (idle slots, pad rows, chunk padding) are routed
 nowhere and touch no expert, and the grouped kernel reads no expert that
 no live row chose. The decode program returns how many experts its live
 rows touched, which decides the bytes a step needs, and how many (row
-tile, expert) pairs the kernel visited, which is what it read. A model
-with sliding-window layers gets two pools and two tables a slot
-(`serve/paged_cache.py` MixedPagedKVCache): `_k`, `_v` and the tables fed
-to the programs are then pairs (full, window). The speculative program
-serves neither (it has run neither).
+tile, expert) pairs the kernel visited, which is what it read.
 
 Observability rides the existing telemetry machinery: the GoodputLedger
 books queue_wait / prefill / decode (compile time drained out exactly
@@ -86,17 +78,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from picotron_tpu.config import ModelConfig, ServeConfig, check_eva_serving
+from picotron_tpu.config import ModelConfig, ServeConfig
 from picotron_tpu.generate import _decode_layers, _logits_last
 from picotron_tpu.resilience import watchdog
 from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, model_rope_tables, served_head,
 )
-from picotron_tpu.serve.paged_cache import (
-    BlockPool, EvaPagedCache, LatentPagedCache, MixedPagedKVCache,
-    PagedKVCache, ShardedPagedKVCache, init_eva_cache, init_latent_cache,
-    init_mixed_cache, init_paged_cache, ring_blocks_for,
-)
+from picotron_tpu.serve.paged_cache import BlockPool, init_serve_cache
 from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 from picotron_tpu.telemetry import Telemetry
 from picotron_tpu.telemetry.flightdeck.tracer import TID_SERVE
@@ -212,42 +200,6 @@ def _sample_slots(logits, temperature: float, top_k: int, base_key, rids,
     )(lg, keys).astype(jnp.int32)
 
 
-def _paged_cache(k, v, tables, pool_sharded: bool, cfg: ModelConfig):
-    """The cache a serve program runs its layers against. `pool_sharded`
-    (static; `_sharded` of the pool the engine feeds) says that a mesh
-    shards the pool over the KV heads (tp > 1): attention then keeps the
-    gathered view whatever the step, which the compiler partitions, and
-    never the in-place kernel, which it does not. Pairs (full, window) of
-    pools and tables are a model with sliding layers'; one pool and no
-    `v` is a latent cache (a model with latent attention); a model with
-    EVA attention keeps window and summary blocks in the one pool."""
-    if v is None:
-        return LatentPagedCache(k, tables)
-    if isinstance(k, (tuple, list)):
-        return MixedPagedKVCache(k[0], v[0], k[1], v[1], *tables)
-    if cfg.eva:
-        return EvaPagedCache(k, v, tables)
-    return (ShardedPagedKVCache if pool_sharded else PagedKVCache)(
-        k, v, tables)
-
-
-def _pools(cache):
-    """(k, v) of a cache, in the form `_paged_cache` took them."""
-    if isinstance(cache, MixedPagedKVCache):
-        return (cache.k, cache.wk), (cache.v, cache.wv)
-    if isinstance(cache, LatentPagedCache):
-        return cache.kv, None
-    return cache.k, cache.v
-
-
-def _sharded(pool) -> bool:
-    """Whether the sharding the engine's constructor gave this KV pool
-    splits it (over its KV heads, tp > 1)."""
-    if isinstance(pool, (tuple, list)):
-        pool = pool[0]
-    return not pool.sharding.is_fully_replicated
-
-
 def _logit_of(logits, toks):
     """The float32 logit of each row's chosen token [S]: one number a
     token out of the [S, V] row the program holds anyway, handed out so
@@ -256,10 +208,10 @@ def _logit_of(logits, toks):
     return jnp.take_along_axis(logits, toks[:, None], axis=1)[:, 0]
 
 
-def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
+def serve_decode(params, pools, tables, toks, positions, rids, tidx,
                  base_key, cos, sin, cfg: ModelConfig,
                  temperature: float, top_k: int, interval: int,
-                 eos_token_id, pool_sharded: bool = False):
+                 eos_token_id, cache_cls: type):
     """`interval` decode steps over all slots inside ONE dispatch (a
     lax.scan — amortizes per-dispatch host overhead over interval tokens
     per slot; the same reason offline generate scans its whole decode).
@@ -268,7 +220,7 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     to keep emitting EOS — identical semantics to generate.py's scan —
     and the host truncates + retires them at dispatch end. Returns
     (tokens [S, interval], their logits [S, interval] float32, next
-    tokens, next positions, next tidx, expert counts [4], k, v); the
+    tokens, next positions, next tidx, expert counts [4], pools); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
     (measured ~2x the whole dispatch on the CPU tiny-model bench).
@@ -277,7 +229,10 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
     picks that landed on experts held here and all their picks, each
     summed over the layers and the interval's steps (zeros for a dense
     model).
-    `pool_sharded`: see `_paged_cache`."""
+    `pools`, `tables`, `cache_cls`: the model's serving cache
+    (serve/paged_cache.py `init_serve_cache`) as its class, the tuple of
+    its pools (donated, handed back as they are after the writes) and the
+    tuple of its tables."""
     live = positions >= 0
 
     def one(carry, _):
@@ -295,22 +250,23 @@ def serve_decode(params, k, v, tables, toks, positions, rids, tidx,
         return ((nxt, positions, tidx, cache, done, touched + t),
                 (nxt, _logit_of(logits, nxt)))
 
-    cache = _paged_cache(k, v, tables, pool_sharded, cfg)
+    cache = cache_cls.of(pools, tables)
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
         jax.lax.scan(one, (toks, positions, tidx, cache, done,
                            jnp.zeros((4,), jnp.int32)), None, length=interval)
     return (toks_all.T, lg_all.T, last, positions, tidx, touched,
-            *_pools(cache))
+            cache.pools)
 
 
-def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
+def serve_prefill(params, pools, table_rows, chunk_ids, start_pos,
                   n_valid, rids, tidx, base_key, cos, sin,
                   cfg: ModelConfig, temperature: float, top_k: int,
-                  pool_sharded: bool = False):
+                  cache_cls: type):
     """Prefill the next chunk of every mid-prefill slot in one dispatch:
     chunk_ids [R, C] (padded), start_pos/n_valid/rids/tidx [R],
-    table_rows [R, max_blocks]. A row is a mid-prefill slot, not a slot
+    table_rows a tuple of [R, width] (a table of the cache each). A row
+    is a mid-prefill slot, not a slot
     index: the host compacts the batch (`ServeEngine._prefill_feed`), so
     R is a rung of `prefill_rungs`, and the row's table row says where
     its K/V live. Rows with n_valid = 0 pad the batch up to the rung
@@ -321,11 +277,12 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     bench — one [R, C] program closes that. Samples each row's next
     token off its last valid position's logits with the same (request
     id, token index) key derivation as the decode step — one sampling
-    law everywhere. Returns (k, v, tokens [R], their logits [R])."""
+    law everywhere. `pools`, `cache_cls`: as `serve_decode`'s. Returns
+    (pools, tokens [R], their logits [R])."""
     s, c = chunk_ids.shape
     t = jnp.arange(c)[None, :]
     pos = jnp.where(t < n_valid[:, None], start_pos[:, None] + t, -1)
-    cache = _paged_cache(k, v, table_rows, pool_sharded, cfg)
+    cache = cache_cls.of(pools, table_rows)
     x = params["embedding"][chunk_ids].astype(compute_dtype(cfg))
     x, cache = _decode_layers(params, x, cache, pos, cfg, cos, sin)
     last = jnp.maximum(n_valid - 1, 0)  # [S]
@@ -334,7 +291,7 @@ def serve_prefill(params, k, v, table_rows, chunk_ids, start_pos,
     logits = (hf @ served_head(params, cfg).astype(hf.dtype))[:, 0]
     logits = logits.astype(jnp.float32)  # [S, V]
     toks = _sample_slots(logits, temperature, top_k, base_key, rids, tidx)
-    return (*_pools(cache), toks, _logit_of(logits, toks))
+    return cache.pools, toks, _logit_of(logits, toks)
 
 
 _JITS: dict = {}
@@ -346,15 +303,15 @@ def _get_jits(donate: bool):
     reuses the compile cache. Cache donation is only requested off-CPU —
     the CPU backend ignores donation with a warning per call site."""
     if donate not in _JITS:
-        dargs = (1, 2) if donate else ()
+        dargs = (1,) if donate else ()  # the pools
         _JITS[donate] = (
             jax.jit(serve_decode, donate_argnums=dargs,
                     static_argnames=("cfg", "temperature", "top_k",
                                      "interval", "eos_token_id",
-                                     "pool_sharded")),
+                                     "cache_cls")),
             jax.jit(serve_prefill, donate_argnums=dargs,
                     static_argnames=("cfg", "temperature", "top_k",
-                                     "pool_sharded")),
+                                     "cache_cls")),
         )
     return _JITS[donate]
 
@@ -381,11 +338,41 @@ def prefill_rungs(num_slots: int) -> tuple:
 # ---------------------------------------------------------------------------
 
 
-# New host code goes below this line, not above the device programs: the
-# decode kernel's Mosaic body carries the file and line of its callers
-# (`serve_decode` among them) where the compile cache's key still sees them,
-# so a line added above the programs costs every checkout at the same path a
-# recompile of the decode program.
+# The decode kernel's Mosaic body carries the file and line of its callers
+# (`serve_decode` among them) where the compile cache's key still sees them:
+# a change that moves the lines of the device programs above costs every
+# checkout at the same path one recompile of the serve programs.
+
+
+def mesh_shardings(params):
+    """(replicated, KV pool) shardings on the mesh `params` are sharded
+    over, None where no leaf is. With tp > 1 the KV pool is pinned over
+    the kv-head axis — the layout GSPMD picks for TP attention."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    for leaf in jax.tree.leaves(params):
+        mesh = getattr(getattr(leaf, "sharding", None), "mesh", None)
+        if mesh is not None:  # a NamedSharding
+            tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("tp", 1)
+            return (NamedSharding(mesh, PartitionSpec()),
+                    NamedSharding(mesh, PartitionSpec("tp") if tp > 1
+                                  else PartitionSpec()))
+    return None
+
+
+def new_cache(model_cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
+              num_blocks: int, max_len: int, kv_sh):
+    """What an engine keeps of the model's serving cache
+    (`init_serve_cache`), new: its shapes, which is all the host asks the
+    cache about; its pools on the device under `kv_sh`, which live with the
+    engine between dispatches (a program is handed them, donated, and hands
+    them back); and the host mirrors of its tables, all unmapped."""
+    cache = init_serve_cache(model_cfg, scfg, num_slots, num_blocks, max_len,
+                             sharded=not kv_sh.is_fully_replicated)
+    return (jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                         cache),
+            jax.device_put(cache.pools, kv_sh),
+            tuple(np.full((num_slots, width), unmapped, np.int32)
+                  for width, unmapped in cache.table_specs))
 
 
 class ServeEngine:
@@ -397,15 +384,6 @@ class ServeEngine:
                  device=None, engine_id: int = 0):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
-        self.speculate = scfg.speculator == "ngram"
-        if self.speculate and (model_cfg.num_experts
-                               or model_cfg.layer_types is not None
-                               or model_cfg.mla or model_cfg.eva):
-            raise ValueError(
-                "serve.speculator='ngram' serves dense models of full "
-                "layers only: the speculative verify scan has never run "
-                "an expert block, a sliding-window layer, a latent cache "
-                "or attention_class 'eva', and nothing tests it with one")
         self.params = params
         self.cfg = model_cfg
         self.scfg = scfg
@@ -422,56 +400,8 @@ class ServeEngine:
         self.num_slots = scfg.decode_slots
         self.prefill_rungs = prefill_rungs(self.num_slots)
 
-        self.draft_len = scfg.draft_len if self.speculate else 0
-        if self.speculate:
-            from picotron_tpu.serve import spec_decode
-            if self.draft_len > spec_decode.max_draft_len():
-                raise ValueError(
-                    f"serve.draft_len ({self.draft_len}) exceeds the "
-                    f"drafter's context window: max "
-                    f"{spec_decode.max_draft_len()}")
-
         self.cos, self.sin = model_rope_tables(model_cfg,
                                                max_len=self.max_len)
-        # a model with sliding-window layers: a second pool, a ring a slot
-        self.mixed = model_cfg.layer_types is not None
-        # a model with latent attention: one pool with no head axis and no
-        # `v` (serve/paged_cache.py LatentPagedCache), sized from the
-        # latent's width
-        self.latent = model_cfg.mla
-        # a model with EVA attention: one pool, a table row of two regions
-        # (serve/paged_cache.py EvaPagedCache); `table_width` is a row's
-        # entries, `max_blocks` stays the positions a slot may reach
-        self.eva = model_cfg.eva
-        self.table_width = self.max_blocks
-        if self.eva:
-            check_eva_serving(model_cfg, scfg)
-            cache = init_eva_cache(model_cfg, self.num_blocks,
-                                   self.block_size, self.num_slots,
-                                   self.max_len)
-            self._k, self._v = cache.k, cache.v
-            self.table_width = cache.tables.shape[1]
-        elif self.latent:
-            cache = init_latent_cache(model_cfg, self.num_blocks,
-                                      self.block_size, self.num_slots,
-                                      self.max_blocks)
-            self._k, self._v = cache.kv, None
-        elif self.mixed:
-            self.ring_blocks = min(self.max_blocks, ring_blocks_for(
-                model_cfg.sliding_window, scfg.prefill_chunk,
-                self.block_size))
-            self.num_window_blocks = (scfg.num_window_blocks
-                                      or self.num_slots * self.ring_blocks)
-            cache = init_mixed_cache(
-                model_cfg, self.num_blocks, self.num_window_blocks,
-                self.block_size, self.num_slots, self.max_blocks,
-                self.ring_blocks)
-            self._k, self._v = _pools(cache)
-        else:
-            cache = init_paged_cache(model_cfg, self.num_blocks,
-                                     self.block_size, self.num_slots,
-                                     self.max_blocks)
-            self._k, self._v = cache.k, cache.v
 
         # Sharding discipline: every decode/prefill input keeps ONE
         # explicit sharding for the engine's whole lifetime. Committed
@@ -480,24 +410,11 @@ class ServeEngine:
         # (e.g. place_for_decode'd params) cascades into k/v and then
         # every upload, minting fresh 0.6 s recompiles mid-trace (caught
         # on the CPU bench). Committing everything up front collapses the
-        # variant space to exactly one per program. With tp > 1 the KV
-        # pool is pinned over the kv-head axis — the layout GSPMD picks
-        # for TP attention.
-        from jax.sharding import NamedSharding, PartitionSpec
-        self._rep_sh = None
-        for leaf in jax.tree.leaves(params):
-            sh = getattr(leaf, "sharding", None)
-            if isinstance(sh, NamedSharding):
-                mesh = sh.mesh
-                self._rep_sh = NamedSharding(mesh, PartitionSpec())
-                kv_sh = NamedSharding(
-                    mesh,
-                    PartitionSpec("tp")
-                    if dict(zip(mesh.axis_names,
-                                mesh.devices.shape)).get("tp", 1) > 1
-                    else PartitionSpec())
-                break
-        if self._rep_sh is None:
+        # variant space to exactly one per program.
+        on_mesh = mesh_shardings(params)
+        if on_mesh is not None:
+            self._rep_sh, kv_sh = on_mesh
+        else:
             # `device` pins the whole engine (params, KV pool, rope
             # tables, key) to ONE device — the fleet's per-replica
             # placement: N engines on N distinct (simulated) devices,
@@ -506,8 +423,9 @@ class ServeEngine:
             dev = device if device is not None else jax.devices()[0]
             self._rep_sh = jax.sharding.SingleDeviceSharding(dev)
             kv_sh = self._rep_sh
-        self._k = jax.device_put(self._k, kv_sh)
-        self._v = jax.device_put(self._v, kv_sh)  # None: a latent cache
+        self.cache, self._kv, self._tables = new_cache(
+            model_cfg, scfg, self.num_slots, self.num_blocks, self.max_len,
+            kv_sh)
         self.cos = jax.device_put(self.cos, self._rep_sh)
         self.sin = jax.device_put(self.sin, self._rep_sh)
         self.base_key = jax.device_put(self.base_key, self._rep_sh)
@@ -520,25 +438,12 @@ class ServeEngine:
         self.params = jax.tree.map(
             lambda x: x if getattr(x, "committed", True)
             else jax.device_put(x, self._rep_sh), self.params)
-        # host mirror of the device block tables; sentinel = num_blocks
-        self._tables = np.full((self.num_slots, self.table_width),
-                               self.num_blocks, np.int32)
         self.pool = BlockPool(self.num_blocks)
-        self.wpool = None  # the sliding layers' pool and table mirror
-        if self.mixed:
-            if isinstance(kv_sh, NamedSharding) and _sharded(self._k):
-                raise ValueError(
-                    "a model with sliding-window layers is served from "
-                    "one device: the two pools are not sharded (tp = 1)")
-            self.wpool = BlockPool(self.num_window_blocks)
-            self._wtables = np.full((self.num_slots, self.ring_blocks),
-                                    self.num_window_blocks, np.int32)
-        self.sched = Scheduler(
-            self.num_slots, self.pool, self.block_size, self.max_blocks,
-            window_pool=self.wpool,
-            ring_blocks=self.ring_blocks if self.mixed else 0,
-            summary=((model_cfg.window_size, model_cfg.chunk_size)
-                     if self.eva else None))
+        sched_args = self.cache.scheduler_args(model_cfg)
+        # the sliding layers' pool (None: the cache has no second pool)
+        self.wpool = sched_args.get("window_pool")
+        self.sched = Scheduler(self.num_slots, self.pool, self.block_size,
+                               self.max_blocks, **sched_args)
 
         self._owns_telemetry = telemetry is None
         self.telemetry = telemetry or Telemetry(sinks=[])
@@ -547,9 +452,6 @@ class ServeEngine:
         # checking the chip path can see which programs it got.
         self.donate = jax.default_backend() != "cpu"
         self._decode_jit, self._prefill_jit = _get_jits(self.donate)
-        if self.speculate:
-            from picotron_tpu.serve.spec_decode import get_spec_jit
-            self._decode_jit = get_spec_jit(self.donate)
 
         self._t0 = time.perf_counter()  # trace clock zero (run() resets)
         self.engine_id = int(engine_id)  # fleet replica index (0 = solo)
@@ -562,7 +464,6 @@ class ServeEngine:
             "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
             "prefill_chunks": 0, "occupancy_sum": 0.0,
             "output_tokens": 0, "prefill_tokens": 0,
-            "draft_tokens": 0, "accepted_draft_tokens": 0,
             "decode_stall_ticks_max": 0, "cancelled": 0,
             # experts the decode steps' live rows were routed to and (row
             # tile, expert) pairs their kernel visited, out of layers x
@@ -648,33 +549,24 @@ class ServeEngine:
         self._recent = {kind: deque(maxlen=SLOW_STEP_WINDOW)
                         for kind in ("host", *_WAITS)}
 
+    def _write_rows(self, cache, tables, slot: int, st) -> None:
+        """Slot `slot`'s row of each host table mirror, from the blocks its
+        request `st` holds (None: a free slot, all unmapped)."""
+        for table, row in zip(tables, cache.slot_rows(st, self.cfg)):
+            table[slot] = row
+
     def _sync_table(self, slot: int) -> None:
-        st = self.sched.slots[slot]
-        row = np.full((self.table_width,), self.num_blocks, np.int32)
-        if st is not None and st.blocks:
-            # a model with EVA attention: the summary blocks first, the
-            # open window's blocks after the summary region
-            first = (self.table_width
-                     - self.cfg.window_size // self.block_size
-                     if self.eva else 0)
-            row[:len(st.sblocks)] = st.sblocks
-            row[first:first + len(st.blocks)] = st.blocks
-        self._tables[slot] = row
-        if self.mixed:
-            self._wtables[slot] = self.num_window_blocks
-            if st is not None:
-                self._wtables[slot, :len(st.wblocks)] = st.wblocks
+        self._write_rows(self.cache, self._tables, slot,
+                         self.sched.slots[slot])
         self._decode_state = None  # roster/table changed: slow path next
 
     # -- the prefill program's side of the engine: what DisaggServeEngine
     # overrides to point the shared feed at its own prefill pool
 
     def _prefill_pool(self):
-        """(slot states, host table mirror, pool size = the unmapped
-        sentinel, upload sharding) of the pool the prefill program
-        writes."""
-        return (self.sched.slots, self._tables, self.num_blocks,
-                self._rep_sh)
+        """(slot states, the cache, its host table mirrors, upload
+        sharding) of the pool the prefill program writes."""
+        return self.sched.slots, self.cache, self._tables, self._rep_sh
 
     _PREFILL_PHASE: dict = {}  # further keys of the `phase=prefill` event
 
@@ -693,11 +585,11 @@ class ServeEngine:
     def _run_prefill(self, feed):
         """One dispatch of the prefill program on `feed`; returns the
         rows' tokens and their logits, still on the device."""
-        self._k, self._v, toks, logits = self._prefill_jit(
-            self.params, self._k, self._v, *feed, self.base_key,
+        self._kv, toks, logits = self._prefill_jit(
+            self.params, self._kv, *feed, self.base_key,
             self.cos, self.sin, cfg=self.cfg,
             temperature=self.temperature, top_k=self.top_k,
-            pool_sharded=_sharded(self._k))
+            cache_cls=type(self.cache))
         return toks, logits
 
     def _prefill_feed(self, pslots, rows: Optional[int] = None):
@@ -708,21 +600,19 @@ class ServeEngine:
         and its token is never read. Returns (device feed in
         `serve_prefill`'s argument order, n_valid [R] on the host, the
         rows whose prompt ends in this chunk)."""
-        states, tables, unmapped, sh = self._prefill_pool()
+        states, cache, tables, sh = self._prefill_pool()
         c = self.scfg.prefill_chunk
         r = rows or next(x for x in self.prefill_rungs if x >= len(pslots))
-        trows = np.full((r, self.table_width), unmapped, np.int32)
-        wrows = (np.full((r, self.ring_blocks), self.num_window_blocks,
-                         np.int32) if self.mixed else None)
+        trows = tuple(np.full((r, width), unmapped, np.int32)
+                      for width, unmapped in cache.table_specs)
         ids = np.zeros((r, c), np.int32)
         start, nval, rids, tidx = np.zeros((4, r), np.int32)
         finals = []
         for row, s in enumerate(pslots):
             st = states[s]
             chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-            trows[row] = tables[s]
-            if self.mixed:
-                wrows[row] = self._wtables[s]
+            for rows_of, table in zip(trows, tables):
+                rows_of[row] = table[s]
             ids[row, :len(chunk)] = chunk
             start[row] = st.n_prefilled
             nval[row] = len(chunk)
@@ -730,8 +620,6 @@ class ServeEngine:
             tidx[row] = len(st.generated)
             if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
                 finals.append(row)
-        if self.mixed:
-            trows = (trows, wrows)
         feed = jax.device_put((trows, ids, start, nval, rids, tidx), sh)
         return feed, nval, finals
 
@@ -772,10 +660,8 @@ class ServeEngine:
             "id": req.id,
             "prompt_len": len(req.prompt),
             "tokens": list(st.generated),
-            # the float32 logit each token was chosen at (None under the
-            # speculative program, which does not hand them out)
-            "logits": (list(st.logits)
-                       if len(st.logits) == len(st.generated) else None),
+            # the float32 logit each token was chosen at
+            "logits": list(st.logits),
             "output_tokens": len(st.generated),
             "queue_wait_s": max((st.t_admit or 0.0) - req.arrival, 0.0),
             "ttft_s": ttft,
@@ -976,7 +862,7 @@ class ServeEngine:
         pslots = self.sched.prefill_slots()
         if not pslots:
             return False
-        states = self._prefill_pool()[0]
+        states, cache = self._prefill_pool()[:2]
         with self._span("serve.prefill.build"):
             feed, nval, finals = self._prefill_feed(pslots)
         n_prefilled = int(nval.sum())
@@ -995,9 +881,9 @@ class ServeEngine:
                         rows=len(nval), tokens=n_prefilled,
                         capacity=len(nval) * self.scfg.prefill_chunk,
                         ids=join_ids(req_ids),
-                        **self._kind_keys(
+                        **cache.prefill_counts(
                             [(states[s].n_prefilled, int(nval[row]))
-                             for row, s in enumerate(pslots)])):
+                             for row, s in enumerate(pslots)], self.cfg)):
             toks_d, logits_d = self._run_prefill(feed)
         toks = None
         if finals:
@@ -1044,7 +930,7 @@ class ServeEngine:
     def _decode_tick(self, now: float, reg) -> bool:
         """One decode dispatch over every decode-ready slot. Operates
         purely through the scheduler's decode interface plus the
-        decode-side device context (self.params/_k/_v/cos/sin/base_key/
+        decode-side device context (self.params/_kv/cos/sin/base_key/
         _rep_sh), so the disaggregated engine reuses it verbatim against
         its decode pool. Returns whether a dispatch ran."""
         ready = self.sched.decode_ready()
@@ -1054,15 +940,11 @@ class ServeEngine:
         with self._span("serve.decode.build") as sp:
             active = []
             dropped: set = set()
-            # a speculative iteration can advance a slot by up to
-            # 1 + draft_len positions, so the write horizon (and the
-            # block allocation backing it) scales with it
-            span = interval * (1 + self.draft_len)
             for s in ready:
                 if s in dropped:
                     continue
                 st = self.sched.slots[s]
-                horizon = min(span,
+                horizon = min(interval,
                               st.req.max_new_tokens - len(st.generated))
                 n_before = st.held_blocks
                 ok, preempted = self.sched.ensure_block(s, horizon)
@@ -1094,18 +976,11 @@ class ServeEngine:
                     tidx[s] = len(st.generated)
                 up = partial(jax.device_put, device=self._rep_sh)
                 ds = {"active": list(active),
-                      "tables": up((self._tables, self._wtables)
-                                   if self.mixed else self._tables),
+                      "tables": up(self._tables),
                       "toks": up(toks),
                       "positions": up(positions),
                       "rids": up(rids),
                       "tidx": up(tidx)}
-                if self.speculate:
-                    from picotron_tpu.serve.spec_decode import (
-                        context_rows,
-                    )
-                    ds["ctx"] = up(context_rows(
-                        self.sched.slots, active, self.num_slots))
             sp.set(rebuilt=int(rebuilt), preempted=len(dropped))
         if not active:
             return False
@@ -1118,66 +993,44 @@ class ServeEngine:
         # with the requests it advanced.
         dec_ids = [self.sched.slots[s].req.id for s in active]
         t0 = time.perf_counter()
-        nval = None
-        # `kv_blocks`: the blocks the active slots' cached positions fill
-        # at the dispatch's first token, which is what a decode step that
-        # attends in place reads a layer; `view_blocks`: what the gathered
-        # view spans, whatever is live
-        kv_blocks = sum(self._blocks_read(self.sched.slots[s].write_pos + 1)
-                        for s in active)
+        # what the step reads of the cache at the dispatch's first token
+        # (`kv_blocks` and the counts of the cache's kind), and
+        # `view_blocks`: what the gathered view spans, whatever is live
+        live = [self.sched.slots[s] for s in active]
+        read = self.cache.decode_counts(
+            [(st.write_pos,
+              min(interval, st.req.max_new_tokens - len(st.generated)))
+             for st in live], self.cfg)
         with self._span("serve.decode.dispatch", active=len(active),
-                        interval=interval, kv_blocks=kv_blocks,
+                        interval=interval,
                         view_blocks=self.num_slots * self.max_blocks,
-                        ids=join_ids(dec_ids),
-                        **self._kind_blocks(active, kv_blocks)):
-            if self.speculate:
-                (toks_d, nval_d, last_d, pos_d, tidx_d, ctx_d,
-                 self._k, self._v) = self._decode_jit(
-                    self.params, self._k, self._v,
-                    ds["tables"], ds["toks"], ds["positions"],
-                    ds["rids"], ds["tidx"], ds["ctx"], self.base_key,
-                    self.cos, self.sin, cfg=self.cfg,
-                    temperature=self.temperature, top_k=self.top_k,
-                    interval=interval,
-                    eos_token_id=self.eos_token_id,
-                    draft_len=self.draft_len)
-                state = dict(ds, toks=last_d, positions=pos_d,
-                             tidx=tidx_d, ctx=ctx_d)
-            else:
-                (toks_d, lg_d, last_d, pos_d, tidx_d, touched_d, self._k,
-                 self._v) = self._decode_jit(
-                        self.params, self._k, self._v,
-                        ds["tables"], ds["toks"], ds["positions"],
-                        ds["rids"], ds["tidx"], self.base_key,
-                        self.cos, self.sin, cfg=self.cfg,
-                        temperature=self.temperature,
-                        top_k=self.top_k, interval=interval,
-                        eos_token_id=self.eos_token_id,
-                        pool_sharded=_sharded(self._k))
-                state = dict(ds, toks=last_d, positions=pos_d,
-                             tidx=tidx_d)
+                        ids=join_ids(dec_ids), **read):
+            (toks_d, lg_d, last_d, pos_d, tidx_d, touched_d,
+             self._kv) = self._decode_jit(
+                self.params, self._kv, ds["tables"], ds["toks"],
+                ds["positions"], ds["rids"], ds["tidx"], self.base_key,
+                self.cos, self.sin, cfg=self.cfg,
+                temperature=self.temperature, top_k=self.top_k,
+                interval=interval, eos_token_id=self.eos_token_id,
+                cache_cls=type(self.cache))
+            state = dict(ds, toks=last_d, positions=pos_d, tidx=tidx_d)
         with self._span("serve.decode.wait") as sp:
-            if self.speculate:
-                nxt = np.asarray(toks_d)   # [S, interval, 1 + draft_len]
-                nval = np.asarray(nval_d)  # [S, interval]
-            else:
-                # tokens and their logits [S, interval], and the experts
-                # the steps touched and visited: known once the dispatch
-                # has run, so the counts ride this span and not the
-                # dispatch's
-                nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
-                if self.cfg.num_experts:
-                    touched, visits, here, picks = (int(c) for c in counts)
-                    slots = (self.cfg.stacks[-1].layers * interval
-                             * self.cfg.num_experts)
-                    self.stats["experts_touched"] += touched
-                    self.stats["expert_visits"] += visits
-                    self.stats["expert_slots"] += slots
-                    self.stats["picks_here"] += here
-                    self.stats["picks_all"] += picks
-                    sp.set(experts_touched=touched, expert_visits=visits,
-                           expert_slots=slots, picks_here=here,
-                           picks_all=picks)
+            # tokens and their logits [S, interval], and the experts the
+            # steps touched and visited: known once the dispatch has run,
+            # so the counts ride this span and not the dispatch's
+            nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
+            if self.cfg.num_experts:
+                touched, visits, here, picks = (int(c) for c in counts)
+                slots = (self.cfg.stacks[-1].layers * interval
+                         * self.cfg.num_experts)
+                self.stats["experts_touched"] += touched
+                self.stats["expert_visits"] += visits
+                self.stats["expert_slots"] += slots
+                self.stats["picks_here"] += here
+                self.stats["picks_all"] += picks
+                sp.set(experts_touched=touched, expert_visits=visits,
+                       expert_slots=slots, picks_here=here,
+                       picks_all=picks)
         # feed outputs forward; any roster/table change below
         # nulls this via _sync_table
         self._decode_state = state
@@ -1190,32 +1043,18 @@ class ServeEngine:
         with self._span("serve.decode.emit") as sp:
             for s in active:
                 st = self.sched.slots[s]
-                retired = False
                 for t in range(interval):
-                    if retired:
+                    st.generated.append(int(nxt[s, t]))
+                    st.logits.append(float(lgs[s, t]))
+                    n_tokens += 1
+                    if self.sched.should_retire(s, self.eos_token_id):
+                        # tokens past EOS/budget are padding
+                        n_freed += st.held_blocks
+                        rst = self.sched.retire(s)
+                        self._sync_table(s)
+                        self._emit_retired(rst, now + dt)
+                        n_retired += 1
                         break
-                    if self.speculate:
-                        emit = [int(x)
-                                for x in nxt[s, t, :int(nval[s, t])]]
-                        self.stats["draft_tokens"] += self.draft_len
-                        self.stats["accepted_draft_tokens"] += (
-                            len(emit) - 1)
-                    else:
-                        emit = [int(nxt[s, t])]
-                        st.logits.append(float(lgs[s, t]))
-                    for tok in emit:
-                        st.generated.append(tok)
-                        n_tokens += 1
-                        if self.sched.should_retire(
-                                s, self.eos_token_id):
-                            # tokens past EOS/budget are padding
-                            n_freed += st.held_blocks
-                            rst = self.sched.retire(s)
-                            self._sync_table(s)
-                            self._emit_retired(rst, now + dt)
-                            retired = True
-                            n_retired += 1
-                            break
             # `blocks_freed`: what the retirements gave back to the pools,
             # the size of the one thing here that grows with a request
             sp.set(tokens=n_tokens, retired=n_retired, blocks_freed=n_freed)
@@ -1224,105 +1063,11 @@ class ServeEngine:
                             category="decode", secs=dt,
                             tokens=n_tokens, ids=dec_ids)
         reg.histogram("serve/token_latency").observe(
-            dt / max(n_tokens if self.speculate
-                     else len(active) * interval, 1))
+            dt / max(len(active) * interval, 1))
         self.stats["decode_steps"] += 1
         self.stats["occupancy_sum"] += len(active) / self.num_slots
         self.stats["output_tokens"] += n_tokens
         return True
-
-    def _kind_keys(self, spans) -> dict:
-        """Further counts of a prefill dispatch's span, the twin of
-        `_kind_blocks`. `spans`: (positions already cached, tokens of this
-        chunk) a row. A model with a latent cache: `latent_keys`, the key
-        positions the rows' chunks may see (each row's cached positions and
-        its chunk, rounded up to the attention's tile), summed over the
-        layers: with the seconds of `latent_prefill_attention`'s events it
-        gives the kernel's share of the matmul peak, at
-        `2 keys rank heads (nope + v) + 2 s keys heads (nope + rope + v)`
-        operations a row. A model with EVA attention: `_eva_counts`."""
-        if self.latent:
-            # imported here: a line added above the device programs would
-            # change their compile-cache keys (the comment below them)
-            from picotron_tpu.ops.paged_attention import latent_prefill_tile
-            tile = latent_prefill_tile(self.block_size, self.max_blocks)
-            return dict(latent_keys=self.cfg.num_hidden_layers * sum(
-                -(-(p + n) // tile) * tile for p, n in spans))
-        return self._eva_counts(spans) if self.eva else {}
-
-    def _kind_blocks(self, active, kv_blocks: int) -> dict:
-        """Further counts of a decode dispatch's span. A model with a
-        latent cache: `latent_blocks`. A model with
-        sliding layers, each summed over the layers of its kind, at the
-        dispatch's first token: `kv_blocks_full` (the full layers read
-        every block a slot's positions fill), `kv_blocks_window` (the
-        sliding layers read from the block of position length - window
-        on), `kv_blocks_banded` (their sum: what the step reads) and
-        `kv_blocks_unwindowed` (what it would read were every layer
-        full)."""
-        if self.latent:
-            # the blocks of the latent pool the step's slots hold, summed
-            # over the layers: what the latent kernel reads
-            return dict(latent_blocks=self.cfg.num_hidden_layers * kv_blocks)
-        if self.eva:
-            return self._eva_counts(
-                [(self.sched.slots[s].write_pos,
-                  min(self.scfg.decode_interval,
-                      self.sched.slots[s].req.max_new_tokens
-                      - len(self.sched.slots[s].generated)))
-                 for s in active], reads=True)
-        if not self.mixed:
-            return {}
-        n_full = self.cfg.layer_kinds.count("full_attention")
-        n_win = self.cfg.num_hidden_layers - n_full
-        band = 0
-        for s in active:
-            n = self.sched.slots[s].write_pos + 1
-            first = max(n - self.cfg.sliding_window, 0) // self.block_size
-            band += blocks_for(n, self.block_size) - first
-        return dict(kv_blocks_full=n_full * kv_blocks,
-                    kv_blocks_window=n_win * band,
-                    kv_blocks_banded=n_full * kv_blocks + n_win * band,
-                    kv_blocks_unwindowed=(n_full + n_win) * kv_blocks)
-
-    def _blocks_read(self, n: int) -> int:
-        """Blocks a layer's attention reads for a query at position n - 1:
-        every block its n positions fill; with EVA attention the closed
-        windows' summary blocks and what the open window fills."""
-        if not self.eva:
-            return blocks_for(n, self.block_size)
-        w, c = self.cfg.window_size, self.cfg.chunk_size
-        closed = (n - 1) // w
-        return (blocks_for(closed * (w // c), self.block_size)
-                + blocks_for(n - closed * w, self.block_size))
-
-    def _eva_counts(self, spans, reads: bool = False) -> dict:
-        """Counts of a dispatch of a model with EVA attention, for its
-        span. `spans`: (first position written, positions written) a slot
-        or row. `eva_summaries_written`: chunks those positions complete,
-        a summary row a layer each; `eva_windows_closed`: windows they
-        complete. `reads` (a decode dispatch, at its first token, over
-        slots and layers): `eva_summary_blocks` + `eva_window_blocks` =
-        `eva_blocks_read`, what the step's attention reads, and
-        `eva_blocks_full_attention`, what full attention over the same
-        lengths would."""
-        w, c, bs = (self.cfg.window_size, self.cfg.chunk_size,
-                    self.block_size)
-        layers = self.cfg.num_hidden_layers
-        out = dict(
-            eva_summaries_written=layers * sum(
-                (p + n) // c - p // c for p, n in spans),
-            eva_windows_closed=sum((p + n) // w - p // w for p, n in spans))
-        if reads:
-            summary = sum(blocks_for(p // w * (w // c), bs) for p, _ in spans)
-            both = sum(self._blocks_read(p + 1) for p, _ in spans)
-            out.update(
-                eva_summary_blocks=layers * summary,
-                eva_window_blocks=layers * (both - summary),
-                eva_blocks_read=layers * both,
-                eva_blocks_full_attention=layers * sum(
-                    blocks_for(p + 1, bs) for p, _ in spans))
-        return out
 
     # -- trace driver ------------------------------------------------------
 
@@ -1377,7 +1122,6 @@ class ServeEngine:
         qw = reg.histogram("serve/queue_wait")
         tpot = reg.histogram("serve/tpot")
         steps = max(self.stats["decode_steps"], 1)
-        drafted = self.stats["draft_tokens"]
         return {
             "requests": len(self.results),
             "output_tokens": sum(r["output_tokens"] for r in self.results),
@@ -1393,7 +1137,7 @@ class ServeEngine:
             "pool_peak_utilization": round(
                 self.pool.peak_in_use / self.num_blocks, 4),
             "window_pool_peak_utilization": (
-                round(self.wpool.peak_in_use / self.num_window_blocks, 4)
+                round(self.wpool.peak_in_use / self.wpool.num_blocks, 4)
                 if self.wpool is not None else None),
             "experts_touched": self.stats.get("experts_touched", 0),
             "expert_visits": self.stats.get("expert_visits", 0),
@@ -1413,13 +1157,6 @@ class ServeEngine:
                                 if self._walls else None),
             "step_wall_max_s": round(self.stats["step_wall_max_s"], 6),
             "slow_steps": self.stats["slow_steps"],
-            "speculator": self.scfg.speculator,
-            "draft_len": self.draft_len,
-            "draft_tokens": drafted,
-            "accepted_draft_tokens": self.stats["accepted_draft_tokens"],
-            "acceptance_rate": (
-                round(self.stats["accepted_draft_tokens"] / drafted, 4)
-                if drafted else None),
             "preemptions": self.sched.n_preempted,
             "shed": self.sched.n_shed,
             "cancelled": self.stats["cancelled"],

@@ -82,13 +82,6 @@ class FleetSupervisor:
                  watchdog_on_timeout=None):
         scfg = serve_cfg or ServeConfig()
         scfg.validate()
-        if scfg.speculator != "off":
-            raise ValueError(
-                "serve.fleet_size > 1 does not support speculative decode "
-                "(serve.speculator != 'off'): the drafter's context is "
-                "engine-local and is not carried across failover "
-                "re-dispatch; set serve.speculator='off' or "
-                "serve.fleet_size=1")
         self.scfg = scfg
         self.n = max(int(scfg.fleet_size), 1)
         self.tick_s = float(tick_s)

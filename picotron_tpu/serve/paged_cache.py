@@ -54,9 +54,9 @@ kept in float32. The view spans ``slots x max_blocks`` blocks whatever is
 live (the whole pool's size where the pool is sized ``slots x
 max_model_len``), and gathering it was 69% of the decode program's device
 time at Qwen2-1.5B's chat settings, where the traffic holds a sixth of the
-pool at the fullest (PERF.md, PR 32). Prefill chunks, the speculative
-program (more than one query position a slot), shapes the kernel does not
-take and every CPU run keep the view.
+pool at the fullest (PERF.md, PR 32). Prefill chunks (more than one query
+position a slot), shapes the kernel does not take and every CPU run keep
+the view.
 
 A model with sliding-window layers has two kinds of state
 (`MixedPagedKVCache`): a full-attention layer needs every position of a
@@ -82,6 +82,18 @@ sliding layer over its ring), so a 16k-position row never has its
 all-or-nothing semantics and peak accounting, so the scheduler can make
 admission/preemption decisions and tests can assert no block leaks
 across a full trace.
+
+Which of these caches a model is served from is decided in ONE place,
+`init_serve_cache`, and a cache's format is known here and nowhere else.
+The serving engine (serve/engine.py) holds whatever that constructor gave
+it and asks it: `pools` (what a serve program is handed, donated, and
+hands back) and `of` (the cache inside the program, from those and the
+dispatch's table rows), `table_specs` (each table's row width and unmapped
+sentinel), `slot_rows` (a slot's host table rows from the blocks the
+scheduler gave it), `scheduler_args` (what the scheduler must know of the
+format) and `prefill_counts` / `decode_counts` (what a dispatch's span
+says it writes and reads). A new kind of cache is a class with those
+answers and an arm of `init_serve_cache`; the engine does not change.
 """
 
 from __future__ import annotations
@@ -90,8 +102,9 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from picotron_tpu.config import ModelConfig
+from picotron_tpu.config import ModelConfig, ServeConfig, check_eva_serving
 from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
@@ -100,8 +113,10 @@ from picotron_tpu.ops.mla import (
 )
 from picotron_tpu.ops.paged_attention import (
     decode_kernel_suits, latent_decode_attention, latent_kernel_suits,
-    latent_prefill_attention, latent_prefill_suits, paged_decode_attention,
+    latent_prefill_attention, latent_prefill_suits, latent_prefill_tile,
+    paged_decode_attention,
 )
+from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -122,6 +137,15 @@ def _slots_of(tables, q_pos, rows: int, block_size: int, num_blocks: int,
     phys = jnp.take_along_axis(tables, jnp.minimum(blk, width - 1), axis=1)
     phys = jnp.where((q_pos >= 0) & (blk < width), phys, num_blocks)
     return phys, jnp.maximum(q_pos, 0) % block_size
+
+
+def _table_row(spec, blocks=(), first: int = 0):
+    """A slot's host row of a table of `spec` (`table_specs`): `blocks`
+    from entry `first` on, unmapped elsewhere."""
+    width, unmapped = spec
+    row = np.full((width,), unmapped, np.int32)
+    row[first:first + len(blocks)] = blocks
+    return row
 
 
 class PagedKVCache(NamedTuple):
@@ -207,6 +231,53 @@ class PagedKVCache(NamedTuple):
         out = paged_decode_attention(q[:, 0], self.k, self.v, li,
                                      self.tables, jnp.maximum(pos + 1, 0))
         return out[:, None]
+
+    # -- what the serving engine asks of its cache (the module docstring's
+    # last paragraph). Everything below `pools` runs on the host and reads
+    # shapes alone: the engine asks a cache of shapes.
+
+    @classmethod
+    def of(cls, pools, tables):
+        """The cache inside a serve program: the pools it was handed and
+        the table rows uploaded for this dispatch (a tuple each)."""
+        return cls(*pools, *tables)
+
+    @property
+    def pools(self) -> tuple:
+        return self.k, self.v
+
+    @property
+    def table_specs(self) -> tuple:
+        """(entries of a row, the unmapped sentinel) of each table."""
+        return ((self.tables.shape[1], self.num_blocks),)
+
+    def scheduler_args(self, cfg: ModelConfig) -> dict:
+        """`Scheduler`'s arguments beyond the pool of `num_blocks` blocks."""
+        return {}
+
+    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
+        """A slot's row of each table from the blocks its request holds
+        (`st`: its `RequestState`, None for a free slot)."""
+        return (_table_row(self.table_specs[0], st.blocks if st else ()),)
+
+    def blocks_read(self, n: int, cfg: ModelConfig) -> int:
+        """Blocks a layer's attention reads for a query at position n - 1:
+        every block its n positions fill."""
+        return blocks_for(n, self.block_size)
+
+    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+        """Further counts of a prefill dispatch's span. `spans`: (positions
+        already cached, tokens of this chunk) a row."""
+        return {}
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """Counts of a decode dispatch's span. `spans`: (the position its
+        first step writes, the tokens it has yet to emit in this dispatch)
+        a slot. `kv_blocks`: the blocks the slots' cached positions fill at
+        the dispatch's first token, which is what a decode step that
+        attends in place reads a layer."""
+        return dict(kv_blocks=sum(self.blocks_read(p + 1, cfg)
+                                  for p, _ in spans))
 
 
 # Blocks a tile of `_tiled_attention`: 32 blocks of 16 positions are 512
@@ -362,6 +433,58 @@ class MixedPagedKVCache(NamedTuple):
                    jnp.clip(-(-(jnp.max(last) + 1) // (tb * bs)), 0, tiles))
         return _tiled_attention(q, q_pos, n_tiles, fetch, hkv, window)
 
+    # -- what the serving engine asks (see `PagedKVCache`)
+
+    @classmethod
+    def of(cls, pools, tables):
+        k, wk, v, wv = pools
+        return cls(k, v, wk, wv, *tables)
+
+    @property
+    def pools(self) -> tuple:
+        """Both pools' K, then both pools' V: the order the serve programs
+        have taken them in since there were two. A compiled program is not
+        indifferent to the order of its parameters (K-EXAONE's one-row
+        prefill schedules 8 prefetches fewer under another; PR 46)."""
+        return self.k, self.wk, self.v, self.wv
+
+    @property
+    def table_specs(self) -> tuple:
+        return ((self.tables.shape[1], self.k.shape[2]),
+                (self.wtables.shape[1], self.wk.shape[2]))
+
+    def scheduler_args(self, cfg: ModelConfig) -> dict:
+        ring, window_blocks = self.table_specs[1]
+        return dict(window_pool=BlockPool(window_blocks), ring_blocks=ring)
+
+    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
+        full, ring = self.table_specs
+        return (_table_row(full, st.blocks if st else ()),
+                _table_row(ring, st.wblocks if st else ()))
+
+    prefill_counts = PagedKVCache.prefill_counts
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`kv_blocks`, and each summed over the layers of its kind, at
+        the dispatch's first token: `kv_blocks_full` (the full layers read
+        every block a slot's positions fill), `kv_blocks_window` (the
+        sliding layers read from the block of position length - window
+        on), `kv_blocks_banded` (their sum: what the step reads) and
+        `kv_blocks_unwindowed` (what it would read were every layer
+        full)."""
+        bs = self.block_size
+        n_full, n_win = self.k.shape[1], self.wk.shape[1]
+        kv_blocks = band = 0
+        for p, _ in spans:
+            kv_blocks += blocks_for(p + 1, bs)
+            band += (blocks_for(p + 1, bs)
+                     - max(p + 1 - cfg.sliding_window, 0) // bs)
+        return dict(kv_blocks=kv_blocks,
+                    kv_blocks_full=n_full * kv_blocks,
+                    kv_blocks_window=n_win * band,
+                    kv_blocks_banded=n_full * kv_blocks + n_win * band,
+                    kv_blocks_unwindowed=(n_full + n_win) * kv_blocks)
+
 
 def init_mixed_cache(cfg: ModelConfig, num_blocks: int,
                      num_window_blocks: int, block_size: int,
@@ -471,6 +594,37 @@ class LatentPagedCache(NamedTuple):
 
         return latent_attention(q_n, q_r, q_pos, fetch, tiles, tb * bs,
                                 kv_b, cfg)
+
+    # -- what the serving engine asks (see `PagedKVCache`, whose answers
+    # hold wherever one table maps one pool)
+
+    @property
+    def pools(self) -> tuple:
+        return (self.kv,)
+
+    of = classmethod(PagedKVCache.of.__func__)
+    table_specs = PagedKVCache.table_specs
+    scheduler_args = PagedKVCache.scheduler_args
+    slot_rows = PagedKVCache.slot_rows
+
+    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`latent_keys`: the key positions the rows' chunks may see (each
+        row's cached positions and its chunk, rounded up to the
+        attention's tile), summed over the layers: with the seconds of
+        `latent_prefill_attention`'s events it gives the kernel's share of
+        the matmul peak, at `2 keys rank heads (nope + v) + 2 s keys heads
+        (nope + rope + v)` operations a row."""
+        tile = latent_prefill_tile(self.block_size, self.tables.shape[1])
+        return dict(latent_keys=self.num_layers * sum(
+            -(-(p + n) // tile) * tile for p, n in spans))
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`kv_blocks`, and `latent_blocks`: the blocks of the latent pool
+        the step's slots hold, summed over the layers, which is what the
+        latent kernel reads."""
+        kv_blocks = sum(blocks_for(p + 1, self.block_size) for p, _ in spans)
+        return dict(kv_blocks=kv_blocks,
+                    latent_blocks=self.num_layers * kv_blocks)
 
 
 def init_latent_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -648,6 +802,56 @@ class EvaPagedCache(PagedKVCache):
         return MixedPagedKVCache._tiled(
             PagedKVCache(self.k, self.v, seen), li, q, at, None)
 
+    # -- what the serving engine asks (see `PagedKVCache`)
+
+    def scheduler_args(self, cfg: ModelConfig) -> dict:
+        return dict(summary=(cfg.window_size, cfg.chunk_size))
+
+    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
+        """The summary blocks first, the open window's blocks after the
+        summary region."""
+        row = _table_row(self.table_specs[0], st.blocks if st else (),
+                         first=self._summary_entries(cfg))
+        if st:
+            row[:len(st.sblocks)] = st.sblocks
+        return (row,)
+
+    def blocks_read(self, n: int, cfg: ModelConfig) -> int:
+        """The closed windows' summary blocks and what the open window
+        fills."""
+        w, c = cfg.window_size, cfg.chunk_size
+        closed = (n - 1) // w
+        return (blocks_for(closed * (w // c), self.block_size)
+                + blocks_for(n - closed * w, self.block_size))
+
+    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`eva_summaries_written`: chunks the rows' positions complete, a
+        summary row a layer each; `eva_windows_closed`: windows they
+        complete."""
+        w, c = cfg.window_size, cfg.chunk_size
+        return dict(
+            eva_summaries_written=self.num_layers * sum(
+                (p + n) // c - p // c for p, n in spans),
+            eva_windows_closed=sum((p + n) // w - p // w for p, n in spans))
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`kv_blocks`, what the dispatch's positions complete
+        (`prefill_counts`), and at its first token, over slots and layers:
+        `eva_summary_blocks` + `eva_window_blocks` = `eva_blocks_read`,
+        what the step's attention reads, and `eva_blocks_full_attention`,
+        what full attention over the same lengths would."""
+        w, c, bs = cfg.window_size, cfg.chunk_size, self.block_size
+        layers = self.num_layers
+        summary = sum(blocks_for(p // w * (w // c), bs) for p, _ in spans)
+        both = sum(self.blocks_read(p + 1, cfg) for p, _ in spans)
+        return dict(
+            kv_blocks=both, **self.prefill_counts(spans, cfg),
+            eva_summary_blocks=layers * summary,
+            eva_window_blocks=layers * (both - summary),
+            eva_blocks_read=layers * both,
+            eva_blocks_full_attention=layers * sum(
+                blocks_for(p + 1, bs) for p, _ in spans))
+
 
 def eva_table_width(cfg: ModelConfig, max_len: int, block_size: int) -> int:
     """Entries of an `EvaPagedCache` table row: the summary blocks of
@@ -662,6 +866,36 @@ def init_eva_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return EvaPagedCache(*init_paged_cache(
         cfg, num_blocks, block_size, num_slots,
         eva_table_width(cfg, max_len, block_size)))
+
+
+def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
+                     num_blocks: int, max_len: int, sharded: bool = False):
+    """The cache a model is served from, zeroed and all-unmapped: `num_slots`
+    slots of up to `max_len` positions over a pool of `num_blocks` blocks
+    of `scfg.block_size`. The one place that reads a model's configuration
+    for the kind of its serving cache. `sharded`: a mesh shards the pool
+    over its KV heads (tp > 1): attention then keeps the gathered view
+    whatever the step, which the compiler partitions, and never the
+    in-place kernel, which it does not."""
+    bs = scfg.block_size
+    max_blocks = blocks_for(max_len, bs)
+    if cfg.eva:  # one pool, a table row of two regions
+        check_eva_serving(cfg, scfg)
+        return init_eva_cache(cfg, num_blocks, bs, num_slots, max_len)
+    if cfg.mla:  # one pool with no head axis, sized from the latent's width
+        return init_latent_cache(cfg, num_blocks, bs, num_slots, max_blocks)
+    if cfg.layer_types is not None:  # a second pool, a ring a slot
+        if sharded:
+            raise ValueError(
+                "a model with sliding-window layers is served from "
+                "one device: the two pools are not sharded (tp = 1)")
+        ring = min(max_blocks, ring_blocks_for(
+            cfg.sliding_window, scfg.prefill_chunk, bs))
+        return init_mixed_cache(
+            cfg, num_blocks, scfg.num_window_blocks or num_slots * ring, bs,
+            num_slots, max_blocks, ring)
+    cache = init_paged_cache(cfg, num_blocks, bs, num_slots, max_blocks)
+    return ShardedPagedKVCache(*cache) if sharded else cache
 
 
 class BlockPool:

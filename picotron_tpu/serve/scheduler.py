@@ -85,7 +85,7 @@ class RequestState:
     req: Request
     generated: list = field(default_factory=list)
     # the float32 logit each generated token was chosen at (the engine
-    # appends one a token; empty under the speculative program)
+    # appends one a token)
     logits: list = field(default_factory=list)
     prefill_ids: tuple = ()   # snapshot at admission: prompt + generated
     n_prefilled: int = 0

@@ -112,7 +112,7 @@ def fresh_programs(monkeypatch):
             lambda *a, **k: engine.serve_decode(*a, **k))
         prefill = functools.wraps(engine.serve_prefill)(
             lambda *a, **k: engine.serve_prefill(*a, **k))
-        static = ("cfg", "temperature", "top_k", "pool_sharded")
+        static = ("cfg", "temperature", "top_k", "cache_cls")
         return (jax.jit(decode, static_argnames=static + (
                     "interval", "eos_token_id")),
                 jax.jit(prefill, static_argnames=static))

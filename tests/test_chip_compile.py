@@ -36,7 +36,7 @@ from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.models.llama import init_params, model_rope_tables
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
 from picotron_tpu.serve.engine import _get_jits, prefill_rungs
-from picotron_tpu.serve.paged_cache import init_paged_cache
+from picotron_tpu.serve.paged_cache import init_serve_cache
 from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import SCOPES
 
@@ -297,22 +297,18 @@ def abstract_params(config: str):
 
 
 CHAT_SLOTS = load("configs", "qwen2-1.5b")["serve"]["decode_slots"]
-_COMPILED_SERVE: dict = {}  # (program, rows) -> compiled_serve's result
+_LOWERED_SERVE: dict = {}  # (config, program, rows) -> lower_serve's result
 
 
-def compiled_serve(topo, monkeypatch, program: str, rows=None,
-                   config: str = "qwen2-1.5b", text: bool = True):
-    """(`compiled.as_text()` or with `text` false the compiled program, the
-    pool's shape, the pools' parameter numbers) of the chat cell's
-    `serve_prefill` (at `rows` rows of the compacted batch) or `serve_decode`
-    at the cell's widths, depth and serve settings on one described chip,
-    pools donated: a program `ServeEngine` dispatches there. `config`:
-    another configuration with one K/V pool and one table a slot (EvaByte's,
-    whose table row is as wide as its law says)."""
-    from picotron_tpu.serve.paged_cache import init_eva_cache
-
-    if (config, program, rows) in _COMPILED_SERVE:
-        return _COMPILED_SERVE[config, program, rows]
+def lower_serve(topo, monkeypatch, config: str, program: str, rows=None):
+    """(the compiled program, the shapes of the configuration's serving
+    cache, the pools' parameter numbers) of `serve_prefill` (at `rows` rows of
+    the compacted batch) or `serve_decode` of a benchmark configuration at its
+    widths, depth and serve settings on one described chip, pools donated: a
+    program `ServeEngine` dispatches there, on the cache `init_serve_cache`
+    gives the model, whatever its kind."""
+    if (config, program, rows) in _LOWERED_SERVE:
+        return _LOWERED_SERVE[config, program, rows]
     # the decode step asks the backend whether kernels compile; here the
     # backend is the CPU and the target is the described chip. Every serve
     # program of this file is traced under the patch: the jits are shared
@@ -321,7 +317,6 @@ def compiled_serve(topo, monkeypatch, program: str, rows=None,
     c = load("configs", config)
     cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
     m, sc = cfg.model, cfg.serve
-    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
     slots = sc.decode_slots
     sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
 
@@ -333,33 +328,41 @@ def compiled_serve(topo, monkeypatch, program: str, rows=None,
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
     params = on_chip(abstract_params(config))
-    num_blocks = sc.num_blocks or slots * max_blocks
-    cache = on_chip(jax.eval_shape(
-        (lambda: init_eva_cache(m, num_blocks, sc.block_size, slots,
-                                sc.max_model_len)) if m.eva else
-        (lambda: init_paged_cache(m, num_blocks, sc.block_size, slots,
-                                  max_blocks))))
-    width = cache.tables.shape[1]
+    cache = on_chip(jax.eval_shape(lambda: init_serve_cache(
+        m, sc, slots,
+        sc.num_blocks or slots * blocks_for(sc.max_model_len, sc.block_size),
+        sc.max_model_len)))
     cos, sin = on_chip(jax.eval_shape(
         lambda: model_rope_tables(m, max_len=sc.max_model_len)))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     decode, prefill = _get_jits(True)
+    static = dict(cfg=m, temperature=0.0, top_k=0, cache_cls=type(cache))
+    r = rows if program == "serve_prefill" else slots
+    head = (params, cache.pools,
+            tuple(i32(r, width) for width, _ in cache.table_specs))
     if program == "serve_prefill":
         low = prefill.lower(
-            params, cache.k, cache.v, i32(rows, width),
-            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
-            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
+            *head, i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
+            i32(rows), key, cos, sin, **static)
     else:
         low = decode.lower(
-            params, cache.k, cache.v, i32(slots, width), i32(slots),
-            i32(slots), i32(slots), i32(slots), key, cos, sin, cfg=m,
-            temperature=0.0, top_k=0, interval=sc.decode_interval,
-            eos_token_id=None)
+            *head, i32(slots), i32(slots), i32(slots), i32(slots), key, cos,
+            sin, interval=sc.decode_interval, eos_token_id=None, **static)
     n = len(jax.tree.leaves(params))
-    comp = low.compile()
-    out = _COMPILED_SERVE[config, program, rows] = (
-        comp.as_text() if text else comp, cache.k.shape, {n, n + 1})
+    out = _LOWERED_SERVE[config, program, rows] = (
+        low.compile(), cache, set(range(n, n + len(cache.pools))))
     return out
+
+
+def compiled_serve(topo, monkeypatch, program: str, rows=None,
+                   config: str = "qwen2-1.5b", text: bool = True):
+    """(`compiled.as_text()` or with `text` false the compiled program, the
+    pool's shape, the pools' parameter numbers) of the chat cell's
+    `serve_prefill` or `serve_decode`. `config`: another configuration with
+    one K/V pool and one table a slot (EvaByte's, whose table row is as wide
+    as its law says)."""
+    comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
+    return comp.as_text() if text else comp, cache.k.shape, pools
 
 
 def computations(text: str) -> dict:
@@ -568,60 +571,13 @@ def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
 # mellum2-12b-a2.5b-8l: experts and two kinds of cache state in both programs
 # ---------------------------------------------------------------------------
 
-_COMPILED_MELLUM: dict = {}
-
-
 def compiled_mellum(topo, monkeypatch, program: str, rows=None,
                     config: str = "mellum2-12b-a2.5b-8l"):
     """(compiled, the two pools' shapes, the pools' parameter numbers) of
     `serve_decode` or `serve_prefill` (at `rows` rows) of a configuration
-    with sliding and full layers (Mellum2's, K-EXAONE's) at its widths,
-    depth and serve settings on one described chip, pools donated."""
-    from picotron_tpu.serve.engine import _pools
-    from picotron_tpu.serve.paged_cache import init_mixed_cache, ring_blocks_for
-
-    if (config, program, rows) in _COMPILED_MELLUM:
-        return _COMPILED_MELLUM[config, program, rows]
-    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
-    c = load("configs", config)
-    cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
-    m, sc = cfg.model, cfg.serve
-    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
-    ring = ring_blocks_for(m.sliding_window, sc.prefill_chunk, sc.block_size)
-    slots = sc.decode_slots
-    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
-
-    params = on_chip(abstract_params(config))
-    cache = on_chip(jax.eval_shape(lambda: init_mixed_cache(
-        m, slots * max_blocks, slots * ring, sc.block_size, slots, max_blocks, ring)))
-    k, v = _pools(cache)
-    cos, sin = on_chip(jax.eval_shape(
-        lambda: model_rope_tables(m, max_len=sc.max_model_len)))
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    decode, prefill = _get_jits(True)
-    if program == "serve_prefill":
-        low = prefill.lower(
-            params, k, v, (i32(rows, max_blocks), i32(rows, ring)),
-            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
-            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
-    else:
-        low = decode.lower(
-            params, k, v, (i32(slots, max_blocks), i32(slots, ring)),
-            i32(slots), i32(slots), i32(slots), i32(slots), key, cos, sin,
-            cfg=m, temperature=0.0, top_k=0, interval=sc.decode_interval,
-            eos_token_id=None)
-    n = len(jax.tree.leaves(params))
-    out = _COMPILED_MELLUM[config, program, rows] = (
-        low.compile(), (cache.k.shape, cache.wk.shape), set(range(n, n + 4)))
-    return out
+    with sliding and full layers (Mellum2's, K-EXAONE's)."""
+    comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
+    return comp, (cache.k.shape, cache.wk.shape), pools
 
 
 @pytest.mark.parametrize("program,rows", [
@@ -798,7 +754,6 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     assert_weights_read_in_place(text, name)
 
 
-_COMPILED_PANGU: dict = {}
 # What PR 45 left of `weights_written` (it took q_b's: `copy.50 bf16[4,1536,
 # 24576]{1,2,0}` and `copy.47` at the decode program's entry, a `constant_
 # dynamic-slice_fusion` or a `copy` of a layer's in the prefill program's scan).
@@ -821,50 +776,11 @@ PANGU_WEIGHTS_WRITTEN = {
 
 def compiled_pangu(topo, monkeypatch, program: str, rows=None):
     """(compiled, the latent pool's shape, the pool's parameter number) of
-    `serve_decode` or `serve_prefill` (at `rows` rows) of the openPangu-Ultra-MoE
-    configuration at its widths, depth and serve settings on one described
-    chip, the pool donated."""
-    from picotron_tpu.serve.paged_cache import init_latent_cache
-
-    if (program, rows) in _COMPILED_PANGU:
-        return _COMPILED_PANGU[program, rows]
-    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
-    c = load("configs", "openpangu-ultra-moe-5l-ep16")
-    cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
-    m, sc = cfg.model, cfg.serve
-    max_blocks = blocks_for(sc.max_model_len, sc.block_size)
-    slots = sc.decode_slots
-    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
-
-    params = on_chip(abstract_params("openpangu-ultra-moe-5l-ep16"))
-    cache = on_chip(jax.eval_shape(lambda: init_latent_cache(
-        m, sc.num_blocks, sc.block_size, slots, max_blocks)))
-    cos, sin = on_chip(jax.eval_shape(
-        lambda: model_rope_tables(m, max_len=sc.max_model_len)))
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    decode, prefill = _get_jits(True)
-    if program == "serve_prefill":
-        low = prefill.lower(
-            params, cache.kv, None, i32(rows, max_blocks),
-            i32(rows, sc.prefill_chunk), i32(rows), i32(rows), i32(rows),
-            i32(rows), key, cos, sin, cfg=m, temperature=0.0, top_k=0)
-    else:
-        low = decode.lower(
-            params, cache.kv, None, i32(slots, max_blocks),
-            i32(slots), i32(slots), i32(slots), i32(slots), key, cos, sin,
-            cfg=m, temperature=0.0, top_k=0, interval=sc.decode_interval,
-            eos_token_id=None)
-    out = _COMPILED_PANGU[program, rows] = (
-        low.compile(), cache.kv.shape, len(jax.tree.leaves(params)))
-    return out
+    `serve_decode` or `serve_prefill` (at `rows` rows) of the
+    openPangu-Ultra-MoE configuration."""
+    comp, cache, (pool,) = lower_serve(
+        topo, monkeypatch, "openpangu-ultra-moe-5l-ep16", program, rows)
+    return comp, cache.kv.shape, pool
 
 
 @pytest.mark.parametrize("program,rows", [

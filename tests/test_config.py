@@ -147,28 +147,34 @@ def test_serve_config_from_dict_round_trip():
 # retired options fail loudly (unknown keys are otherwise ignored on load)
 # ---------------------------------------------------------------------------
 
-_RETIRED = {  # key: (former default, a value that used to select other code)
-    "tp_strategy": ("megatron", "2d"),
-    "tp_sync": ("sync", "deferred"),
-    "tp_mesh": ("", "2x2"),
-    "dcn_axes": ("dp,pp", "dp"),
-    "hier_dp_reduce": ("auto", "on"),
+# section.key: (former default, a value that used to select other code); one
+# table and one check in config.py for both sections
+_RETIRED = {
+    "distributed.tp_strategy": ("megatron", "2d"),
+    "distributed.tp_sync": ("sync", "deferred"),
+    "distributed.tp_mesh": ("", "2x2"),
+    "distributed.dcn_axes": ("dp,pp", "dp"),
+    "distributed.hier_dp_reduce": ("auto", "on"),
+    "serve.speculator": ("off", "ngram"),
+    "serve.draft_len": (3, 2),
 }
 
 
 @pytest.mark.parametrize("how", ["former-default", "other-value"])
-@pytest.mark.parametrize("key", sorted(_RETIRED))
-def test_retired_option(key, how):
-    former, other = _RETIRED[key]
-    raw = {"distributed": {"tp_size": 2, "dp_size": 2,
-                           key: former if how == "former-default" else other}}
+@pytest.mark.parametrize("name", sorted(_RETIRED))
+def test_retired_option(name, how):
+    former, other = _RETIRED[name]
+    section, key = name.split(".")
+    raw = {"distributed": {"tp_size": 2, "dp_size": 2},
+           "serve": {"decode_slots": 2}}
+    raw[section][key] = former if how == "former-default" else other
     if how == "former-default":
         # a dumped config of an old run: loads, and the key is dropped
         cfg = config_from_dict(raw)
-        assert cfg.distributed.tp_size == 2
-        assert key not in cfg.to_json_dict()["distributed"]
+        assert (cfg.distributed.tp_size, cfg.serve.decode_slots) == (2, 2)
+        assert key not in cfg.to_json_dict()[section]
     else:
-        with pytest.raises(ValueError, match=f"distributed.{key}.*removed"):
+        with pytest.raises(ValueError, match=f"{section}.{key}.*removed"):
             config_from_dict(raw)
 
 

@@ -1,5 +1,5 @@
-"""Disaggregated serving + speculative decode tests
-(picotron_tpu/serve/disagg, serve/spec_decode): greedy/sampled token
+"""Disaggregated serving tests
+(picotron_tpu/serve/disagg): greedy/sampled token
 parity vs the offline oracle and the colocated engine (including under
 preemption and across the handoff boundary), both-pools exhaustion
 without leak or deadlock, youngest-first preemption across the
@@ -109,8 +109,8 @@ def test_disagg_pools_are_separately_placed(tiny, requests5,
     cross-device transfer — parity must survive it."""
     cfg, params = tiny
     eng = DisaggServeEngine(params, cfg, scfg())
-    p_dev = next(iter(eng._k_p.sharding.device_set))
-    d_dev = next(iter(eng._k.sharding.device_set))
+    p_dev = next(iter(eng._kv_p[0].sharding.device_set))
+    d_dev = next(iter(eng._kv[0].sharding.device_set))
     assert p_dev != d_dev, "prefill and decode pools share a device"
     res = eng.run(requests5)
     eng.close()
@@ -219,97 +219,6 @@ def test_handoff_preempts_only_strictly_younger():
 
 
 # ---------------------------------------------------------------------------
-# speculative decode: parity + acceptance accounting
-# ---------------------------------------------------------------------------
-
-
-def test_spec_greedy_parity_matches_offline(tiny, requests5,
-                                            offline_refs):
-    """The n-gram speculator's verify-and-accept emits target-sampled
-    tokens only, so greedy output is token-identical to non-speculative
-    greedy — acceptance decides how MANY tokens emit per dispatch, never
-    WHICH."""
-    cfg, params = tiny
-    eng = ServeEngine(params, cfg,
-                      scfg(disagg=False, speculator="ngram", draft_len=2))
-    res = eng.run(requests5)
-    eng.close()
-    by_id = tokens_by_id(res)
-    for i, ref in enumerate(offline_refs):
-        assert by_id[i] == ref
-    s = eng.summary
-    assert s["speculator"] == "ngram" and s["draft_len"] == 2
-    assert s["draft_tokens"] > 0
-    assert s["acceptance_rate"] is not None
-    assert 0.0 <= s["acceptance_rate"] <= 1.0
-
-
-def test_spec_sampled_parity_under_accept_reject(tiny, requests5):
-    """Sampling-key discipline under speculative accept/reject: at
-    temperature > 0 every emitted token is sampled with the key folded
-    from (request id, token index), so a rejected draft cannot shift any
-    later token — spec and non-spec streams must be bit-identical."""
-    cfg, params = tiny
-    kw = dict(temperature=0.8, top_k=5, seed=2)
-    plain = ServeEngine(params, cfg, scfg(disagg=False), **kw)
-    res_p = plain.run(requests5)
-    plain.close()
-    spec = ServeEngine(
-        params, cfg, scfg(disagg=False, speculator="ngram", draft_len=3),
-        **kw)
-    res_s = spec.run(requests5)
-    spec.close()
-    assert tokens_by_id(res_p) == tokens_by_id(res_s)
-
-
-def test_spec_on_disagg_parity_with_preemption(tiny, requests5,
-                                               offline_refs):
-    """The full stack at once: speculative decode on the disaggregated
-    engine with a decode pool tight enough to preempt — still greedy
-    bit-parity with the offline oracle."""
-    cfg, params = tiny
-    eng, res = run_disagg(
-        params, cfg,
-        scfg(num_blocks=6, speculator="ngram", draft_len=2), requests5)
-    assert eng.sched.n_preempted > 0
-    by_id = tokens_by_id(res)
-    for i, ref in enumerate(offline_refs):
-        assert by_id[i] == ref
-
-
-def test_spec_acceptance_nonzero_on_looping_generation(tiny):
-    """A long greedy generation from a tiny model falls into repetition;
-    the self-drafting n-gram speculator must catch some of it —
-    accepted_draft_tokens > 0 and decode dispatches strictly fewer than
-    the non-speculative engine needs for the same tokens."""
-    cfg, params = tiny
-    req = [([5, 9, 5, 9], 28)]
-    sc = dict(decode_slots=1, block_size=4, num_blocks=8,
-              prefill_chunk=4, max_model_len=32, decode_interval=2,
-              disagg=False)
-    plain = ServeEngine(params, cfg, ServeConfig(**sc))
-    res_p = plain.run(req)
-    plain.close()
-    spec = ServeEngine(params, cfg, ServeConfig(
-        **sc, speculator="ngram", draft_len=3))
-    res_s = spec.run(req)
-    spec.close()
-    assert res_p[0]["tokens"] == res_s[0]["tokens"]
-    assert spec.summary["accepted_draft_tokens"] > 0
-    assert spec.summary["decode_steps"] < plain.summary["decode_steps"]
-
-
-def test_spec_draft_len_over_context_window_rejected(tiny):
-    from picotron_tpu.serve import spec_decode
-
-    cfg, params = tiny
-    with pytest.raises(ValueError, match="context window"):
-        ServeEngine(params, cfg, scfg(
-            disagg=False, speculator="ngram",
-            draft_len=spec_decode.max_draft_len() + 1))
-
-
-# ---------------------------------------------------------------------------
 # compile discipline: each pool program compiles exactly once
 # ---------------------------------------------------------------------------
 
@@ -384,11 +293,6 @@ def test_prove_disagg_programs_static():
     assert set(info["signatures"]) == {
         "prefill_pool", "decode_pool", "handoff_gather",
         "handoff_scatter"}
-    # the speculator adds the rolling context to the decode signature
-    spec = prove_disagg_programs(
-        mcfg, scfg(speculator="ngram", draft_len=2)).info[CHECK]
-    assert spec["proven"] is True
-    assert spec["signatures"] != info["signatures"]
     with pytest.raises(ValueError, match="MoE"):
         prove_disagg_programs(
             ModelConfig(**resolve_preset("debug-tiny-moe")), scfg())
@@ -414,14 +318,13 @@ def test_config_rejects_moe_disagg_and_speculator():
     moe = ModelConfig(**resolve_preset("debug-tiny-moe"))
     with pytest.raises(ValueError, match="MoE"):
         Config(model=moe, serve=ServeConfig(disagg=True)).validate()
-    with pytest.raises(ValueError, match="MoE"):
-        Config(model=moe,
-               serve=ServeConfig(speculator="ngram")).validate()
     # dense passes; MoE without serving features passes
     Config(model=ModelConfig(**resolve_preset("debug-tiny")),
-           serve=ServeConfig(disagg=True,
-                             speculator="ngram")).validate()
+           serve=ServeConfig(disagg=True)).validate()
     Config(model=moe).validate()
+    # the speculator went with its options: no such field
+    with pytest.raises(TypeError, match="speculator"):
+        ServeConfig(speculator="ngram")
 
 
 def test_engines_reject_moe_at_construction():
@@ -449,10 +352,8 @@ def test_engines_reject_moe_at_construction():
 
 
 def test_serve_config_validates_disagg_fields():
-    with pytest.raises(ValueError, match="speculator"):
-        ServeConfig(speculator="medusa").validate()
-    with pytest.raises(ValueError, match="draft_len"):
-        ServeConfig(speculator="ngram", draft_len=0).validate()
+    with pytest.raises(ValueError, match="prefill_slots"):
+        ServeConfig(prefill_slots=-1).validate()
     with pytest.raises(ValueError, match="prefill_device"):
         ServeConfig(prefill_device=-2).validate()
 
@@ -465,8 +366,8 @@ def test_serve_config_validates_disagg_fields():
 def test_disagg_telemetry_handoff_and_report(tiny, requests5, tmp_path):
     """The disaggregated stream books the handoff transport as its own
     (non-goodput) ledger category, the serve_summary carries the
-    per-pool and acceptance aggregates, and tools/telemetry_report.py
-    renders the disagg + speculative rows from the stream alone."""
+    per-pool aggregates, and tools/telemetry_report.py renders the
+    disagg row from the stream alone."""
     from picotron_tpu.telemetry import JsonlSink, Telemetry
     from picotron_tpu.telemetry.goodput import (
         CATEGORIES, GOODPUT_CATEGORIES,
@@ -477,9 +378,7 @@ def test_disagg_telemetry_handoff_and_report(tiny, requests5, tmp_path):
     cfg, params = tiny
     path = str(tmp_path / "telemetry.jsonl")
     tel = Telemetry(sinks=[JsonlSink(path)])
-    eng = DisaggServeEngine(params, cfg,
-                            scfg(speculator="ngram", draft_len=2),
-                            telemetry=tel)
+    eng = DisaggServeEngine(params, cfg, scfg(), telemetry=tel)
     eng.run(requests5)
     tel.close()
 
@@ -489,7 +388,6 @@ def test_disagg_telemetry_handoff_and_report(tiny, requests5, tmp_path):
     summ = next(e for e in events if e["kind"] == "serve_summary")
     assert summ["disagg"] is True and summ["handoffs"] > 0
     assert summ["prefill_slot_occupancy"] > 0
-    assert summ["acceptance_rate"] is not None
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                     "tools"))
@@ -499,16 +397,15 @@ def test_disagg_telemetry_handoff_and_report(tiny, requests5, tmp_path):
     sv = s["serving"]
     assert sv["handoffs"] == summ["handoffs"]
     assert sv["prefill_slot_occupancy"] == summ["prefill_slot_occupancy"]
-    assert sv["acceptance_rate"] == summ["acceptance_rate"]
     assert "handoff" in s["categories"]
     text = telemetry_report.render(s)
-    assert "disagg:" in text and "speculative:" in text
+    assert "disagg:" in text
 
 
 def test_extract_metrics_serve_columns(tiny, requests5, tmp_path):
     """A serving-only telemetry stream (no train steps) must still yield
-    a harvest row: serve_* TTFT/TPOT/acceptance columns from the
-    serve_summary event."""
+    a harvest row: serve_* TTFT/TPOT columns from the serve_summary
+    event."""
     from picotron_tpu.telemetry import JsonlSink, Telemetry
 
     cfg, params = tiny
@@ -516,9 +413,7 @@ def test_extract_metrics_serve_columns(tiny, requests5, tmp_path):
     run_dir.mkdir()
     path = str(run_dir / "telemetry.jsonl")
     tel = Telemetry(sinks=[JsonlSink(path)])
-    eng = DisaggServeEngine(params, cfg,
-                            scfg(speculator="ngram", draft_len=2),
-                            telemetry=tel)
+    eng = DisaggServeEngine(params, cfg, scfg(), telemetry=tel)
     eng.run(requests5)
     tel.close()
 
@@ -531,7 +426,6 @@ def test_extract_metrics_serve_columns(tiny, requests5, tmp_path):
     assert stats["serve_requests"] == len(requests5)
     assert stats["serve_ttft_p50_ms"] >= 0
     assert stats["serve_tpot_p50_ms"] >= 0
-    assert "serve_acceptance_rate" in stats
     assert stats["serve_handoffs"] > 0
 
 
@@ -569,13 +463,13 @@ def test_bench_disagg_stall_drop_on_burst_trace(tiny):
     slot-coupled admission serializes the long prefills behind the
     shorts and stalls decode for the whole grind; the disaggregated
     engine overlaps them — max consecutive decode-dispatch stall ticks
-    must DROP. Plus the SLO-curve and acceptance-sweep artifacts."""
+    must DROP. Plus the SLO-curve artifact."""
     import bench
 
     row = bench.run_serve_disagg(
         "debug-tiny", 2, slots=2, block_size=4, num_blocks=0,
         prefill_chunk=4, prompt_len=24, max_new=16, n_requests=4,
-        rate=0.0, decode_interval=2, draft_lens=(2,))
+        rate=0.0, decode_interval=2)
     assert row["unit"] == "decode_stall_ticks_drop"
     assert row["value"] > 0, (
         f"disagg did not reduce decode stalls: colocated "
@@ -589,9 +483,6 @@ def test_bench_disagg_stall_drop_on_burst_trace(tiny):
     assert len(row["slo_curve"]) == 1  # rate=0: saturation point only
     for tag in ("colocated", "disagg"):
         assert row["slo_curve"][0][tag]["ttft_p50_ms"] is not None
-    sweep = row["acceptance_sweep"]
-    assert [p["draft_len"] for p in sweep] == [2]
-    assert sweep[0]["draft_tokens"] > 0
     assert "wall_note" in row
 
 

@@ -325,7 +325,7 @@ def test_engine_holds_what_the_law_says_and_recycles_the_window_in_place():
     params = weights(cfg)
     eng = ServeEngine(params, cfg, ServeConfig(**SERVE))
     width = eva_table_width(cfg, 160, BS)
-    assert width == 10 + 8 and eng._tables.shape == (2, width)  # 40 summary rows, a window
+    assert width == 10 + 8 and eng._tables[0].shape == (2, width)  # 40 summary rows, a window
     eng.submit(list(range(1, W + 7)), 2 * W + 10)
     seen = {}
     while eng.sched.has_work():
@@ -337,7 +337,7 @@ def test_engine_holds_what_the_law_says_and_recycles_the_window_in_place():
             # the window's blocks never change once the window is full: recycled
             seen.setdefault("window", list(st.blocks))
             assert st.blocks[:len(seen["window"])] == seen["window"] and len(st.blocks) == 8
-            row = eng._tables[0]
+            row = eng._tables[0][0]
             assert list(row[:len(st.sblocks)]) == st.sblocks
             assert list(row[10:18]) == st.blocks
             assert (row[len(st.sblocks):10] == eng.num_blocks).all()  # unmapped
@@ -385,8 +385,8 @@ def test_a_recycled_windows_rows_are_never_read_again():
         first = (st.write_pos % W + 4) // BS + 1
         if st.write_pos >= W and first < W // BS:
             blocks = jnp.asarray(st.blocks[first:])
-            eng._k = eng._k.at[:, :, blocks].set(1e4)
-            eng._v = eng._v.at[:, :, blocks].set(-1e4)
+            k, v = eng._kv
+            eng._kv = (k.at[:, :, blocks].set(1e4), v.at[:, :, blocks].set(-1e4))
             poisoned += 1
     assert poisoned >= 3
     assert eng.results[0]["tokens"] == clean[0]["tokens"]
@@ -398,16 +398,16 @@ def test_engine_counts_both_kinds_of_block_on_its_spans():
     eng = ServeEngine(weights(cfg), cfg, ServeConfig(**SERVE))
     # a query at position 70 (71 positions): windows 0 and 1 closed, 16 summary rows =
     # 4 blocks, and 7 positions of window 2 = 2 blocks, where full attention reads 18
-    got = eng._eva_counts([(70, 4)], reads=True)
+    got = eng.cache.decode_counts([(70, 4)], cfg)
     layers = cfg.num_hidden_layers
     assert got == dict(
-        eva_summaries_written=layers * 1, eva_windows_closed=0,
+        kv_blocks=6, eva_summaries_written=layers * 1, eva_windows_closed=0,
         eva_summary_blocks=layers * 4, eva_window_blocks=layers * 2,
         eva_blocks_read=layers * 6, eva_blocks_full_attention=layers * 18)
-    assert eng._blocks_read(71) == 6
+    assert eng.cache.blocks_read(71, cfg) == 6
     # a prefill chunk that ends window 0: 8 positions, 2 chunks, 1 window closed
-    assert eng._eva_counts([(24, 8)]) == dict(eva_summaries_written=layers * 2,
-                                              eva_windows_closed=1)
+    assert eng.cache.prefill_counts([(24, 8)], cfg) == dict(
+        eva_summaries_written=layers * 2, eva_windows_closed=1)
     eng.close()
 
 
@@ -424,7 +424,6 @@ REFUSED = {
     "tp": dict(distributed=dict(tp_size=2)),
     "ep": dict(distributed=dict(ep_size=2)),
     "disagg": dict(serve=dict(disagg=True)),
-    "speculator": dict(serve=dict(speculator="ngram")),
     "fleet": dict(serve=dict(fleet_size=2)),
 }
 
@@ -456,15 +455,11 @@ def test_each_new_feature_is_fenced_by_its_own_name(feature, over):
     Config(model=model, training=TrainingConfig(seq_length=64)).validate()
 
 
-@pytest.mark.parametrize("engine", ["speculator", "disagg"])
-def test_the_other_engines_refuse_the_model_at_construction(engine):
+def test_the_disaggregated_engine_refuses_the_model_at_construction():
     from picotron_tpu.serve.disagg import DisaggServeEngine
 
     cfg = tiny()
     params = init_params(cfg, jax.random.key(0))
     scfg = dict(decode_slots=2, block_size=BS, prefill_chunk=8, max_model_len=64)
     with pytest.raises(ValueError, match="attention_class 'eva'"):
-        if engine == "speculator":
-            ServeEngine(params, cfg, ServeConfig(speculator="ngram", **scfg))
-        else:
-            DisaggServeEngine(params, cfg, ServeConfig(disagg=True, **scfg))
+        DisaggServeEngine(params, cfg, ServeConfig(disagg=True, **scfg))

@@ -381,15 +381,7 @@ def test_config_rejects_fleet_moe_and_speculator():
     moe = ModelConfig(**resolve_preset("debug-tiny-moe"))
     with pytest.raises(ValueError, match="fleet_size"):
         Config(model=moe, serve=ServeConfig(fleet_size=2)).validate()
-    with pytest.raises(ValueError, match="speculator"):
-        Config(model=ModelConfig(**resolve_preset("debug-tiny")),
-               serve=ServeConfig(fleet_size=2,
-                                 speculator="ngram")).validate()
-    # fleet of 1 with a speculator is the existing single-engine path;
     # a dense fleet of 2 is the supported configuration
-    Config(model=ModelConfig(**resolve_preset("debug-tiny")),
-           serve=ServeConfig(fleet_size=1,
-                             speculator="ngram")).validate()
     Config(model=ModelConfig(**resolve_preset("debug-tiny")),
            serve=ServeConfig(fleet_size=2)).validate()
 
@@ -401,11 +393,3 @@ def test_serve_config_validates_fleet_fields():
         ServeConfig(deadline_ms=-1.0).validate()
     with pytest.raises(ValueError, match="drain_grace_s"):
         ServeConfig(drain_grace_s=-0.1).validate()
-
-
-def test_supervisor_rejects_speculator(tiny):
-    cfg, params = tiny
-    with pytest.raises(ValueError, match="speculator"):
-        FleetSupervisor(params, cfg,
-                        scfg(fleet_size=2, speculator="ngram",
-                             draft_len=2))

@@ -288,7 +288,7 @@ def test_engine_matches_the_reference(share, depth, chunk):
                 for n, m in ((37, 8), (6, 5), (21, 7), (45, 4))]
     eng, out = run_engine(params, cfg, requests, prefill_chunk=chunk)
     assert len(out) == 4 and eng.stats["decode_compiles"] <= 1  # the one decode program
-    assert eng.ring_blocks == -(-(8 + chunk) // 4) + 1
+    assert eng.sched.ring_blocks == -(-(8 + chunk) // 4) + 1
     for (prompt, _), res in zip(requests, sorted(out, key=lambda r: r["id"])):
         toks = res["tokens"]
         want = ref_logits(params, cfg, prompt + toks,
@@ -306,7 +306,8 @@ def test_engine_matches_the_reference(share, depth, chunk):
     assert 0 < eng.stats["experts_touched"] <= eng.stats["expert_slots"]
     # the pools hold the layers of their kind, of both stacks
     n_full = cfg.layer_kinds.count(F)
-    assert eng._k[0].shape[1] == n_full and eng._k[1].shape[1] == cfg.num_hidden_layers - n_full
+    k, wk, _, _ = eng._kv
+    assert k.shape[1] == n_full and wk.shape[1] == cfg.num_hidden_layers - n_full
 
 
 def test_engine_counts_banded_reads_over_both_stacks():
@@ -316,7 +317,8 @@ def test_engine_counts_banded_reads_over_both_stacks():
     eng.submit(list(range(1, 30)), 4)
     while eng.sched.slots[0] is None or not eng.sched.slots[0].generated:
         eng.step(0.0)
-    counts = eng._kind_blocks([0], 8)
+    counts = eng.cache.decode_counts([(eng.sched.slots[0].write_pos, 2)], cfg)
+    assert counts["kv_blocks"] == 8
     # 1 full layer reads every block, the 4 sliding ones (the dense layer among
     # them) the band's
     assert counts["kv_blocks_full"] == 8 and counts["kv_blocks_unwindowed"] == 5 * 8
@@ -415,7 +417,6 @@ REFUSED = {
     "tp": dict(distributed=dict(tp_size=2)),
     "ep": dict(distributed=dict(ep_size=2)),
     "disagg": dict(serve=dict(disagg=True)),
-    "speculator": dict(serve=dict(speculator="ngram")),
     "fleet": dict(serve=dict(fleet_size=2)),
 }
 
@@ -453,11 +454,3 @@ def test_each_new_feature_is_fenced_by_its_own_name(feature, over):
         with pytest.raises(ValueError, match=feature):
             cfg.validate()
     Config(model=model, training=TrainingConfig(seq_length=64)).validate()
-
-
-def test_speculative_engine_refuses_the_model_at_construction():
-    cfg = tiny()
-    with pytest.raises(ValueError, match="speculator"):
-        ServeEngine(init_params(cfg, jax.random.key(0)), cfg,
-                    ServeConfig(speculator="ngram", decode_slots=2, block_size=4,
-                                max_model_len=64))

@@ -146,7 +146,7 @@ def test_engine_logits_match_reference(params, ids, chunk, least_tile,
         request.addfinalizer(jax.clear_caches)
     prompts, new = [ids[:40], ids[5:28]], [14, 9]
     eng, res = serve(params, prompts, new, prefill_chunk=chunk)
-    assert eng.ring_blocks == ring_blocks_for(8, chunk, 4) < (40 + 14) // 4
+    assert eng.sched.ring_blocks == ring_blocks_for(8, chunk, 4) < (40 + 14) // 4
     for p, r in zip(prompts, res):
         seq = np.concatenate([p, r["tokens"]])
         rows = np.arange(len(p) - 1, len(seq) - 1)
@@ -282,7 +282,7 @@ def test_engine_leaks_nothing_on_cancel(params, ids):
     while eng.sched.has_work():
         eng.step(0.0)
     assert (eng.pool.in_use, eng.wpool.in_use) == (0, 0)
-    assert (eng._wtables == eng.num_window_blocks).all()
+    assert (eng._tables[1] == eng.wpool.num_blocks).all()
 
 
 # ---------------------------------------------------------------------------

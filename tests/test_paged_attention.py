@@ -150,7 +150,7 @@ def test_sharded_pool_keeps_the_view(tiny, monkeypatch, fresh_programs):
     for tp in (2, 1):
         del asked[:]
         eng = ServeEngine(place_for_decode(params, cfg, tp=tp), cfg, scfg)
-        assert engine._sharded(eng._k) == (tp == 2)
+        assert (type(eng.cache) is paged_cache.ShardedPagedKVCache) == (tp == 2)
         tokens[tp] = [r["tokens"] for r in eng.run(requests)]
         eng.close()
         assert bool(asked) == (tp == 1)

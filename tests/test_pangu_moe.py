@@ -371,7 +371,6 @@ REFUSALS = [
     (dict(distributed=dict(ep_size=2)), "expert parallelism"),
     (dict(serve=dict(disagg=True)), "serve.disagg"),
     (dict(serve=dict(fleet_size=2)), "fleet_size"),
-    (dict(serve=dict(speculator="ngram")), "speculator"),
 ]
 
 

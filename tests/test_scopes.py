@@ -21,7 +21,6 @@ from picotron_tpu.mesh import MeshEnv
 from picotron_tpu.models.llama import init_params
 from picotron_tpu.parallel.api import init_sharded_state, make_train_step
 from picotron_tpu.serve import ServeEngine
-from picotron_tpu.serve.spec_decode import CTX_W, get_spec_jit
 from picotron_tpu.telemetry.scopes import SCOPES, scope
 
 TRAIN = {"embed", "attention", "mlp", "head_ce", "optimizer", "tp_reduce"}
@@ -132,28 +131,30 @@ def engine():
     eng.close()
 
 
-@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode",
-                                     "serve_decode_spec"])
-def test_serve_program_scopes_and_module_names(engine, program):
-    e = engine
+def lowered_serve(e, program: str) -> str:
+    """The text of one of an engine's two programs, lowered on what the
+    engine feeds it: the same call whatever the kind of its cache."""
     s = e.num_slots
     vec = jnp.zeros((s,), jnp.int32)
-    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k)
-    head = (e.params, e._k, e._v, jnp.asarray(e._tables))
+    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k,
+                  cache_cls=type(e.cache))
+    head = (e.params, e._kv, tuple(jnp.asarray(t) for t in e._tables))
     tail = (e.base_key, e.cos, e.sin)
     if program == "serve_prefill":
         lowered = e._prefill_jit.lower(
             *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
             vec, vec, *tail, **common)
-    elif program == "serve_decode":
+    else:
         lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
                                       interval=2, eos_token_id=None, **common)
-    else:
-        lowered = get_spec_jit(False).lower(
-            *head, vec, vec, vec, vec, jnp.zeros((s, CTX_W), jnp.int32), *tail,
-            interval=2, eos_token_id=None, draft_len=2, **common)
     text = lowered.as_text(debug_info=True)
     assert module_name(text) == f"jit_{program}"
+    return text
+
+
+@pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
+def test_serve_program_scopes_and_module_names(engine, program):
+    text = lowered_serve(engine, program)
     found = scopes_in(text)
     assert found == SERVE
     seen.update(found)
@@ -168,22 +169,8 @@ def test_mixed_model_serve_program_scopes(program):
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
                     ServeConfig(decode_slots=2, block_size=4, prefill_chunk=4,
                                 max_model_len=32, decode_interval=2))
-    s = e.num_slots
-    vec = jnp.zeros((s,), jnp.int32)
-    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k)
-    head = (e.params, e._k, e._v,
-            (jnp.asarray(e._tables), jnp.asarray(e._wtables)))
-    tail = (e.base_key, e.cos, e.sin)
-    if program == "serve_prefill":
-        lowered = e._prefill_jit.lower(
-            *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
-            vec, vec, *tail, **common)
-    else:
-        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
-                                      interval=2, eos_token_id=None, **common)
+    text = lowered_serve(e, program)
     e.close()
-    text = lowered.as_text(debug_info=True)
-    assert module_name(text) == f"jit_{program}"
     found = scopes_in(text)
     assert found == SERVE | MOE | {"attn_full", "attn_window"}
     seen.update(found)
@@ -214,21 +201,8 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
                     ServeConfig(decode_slots=2, block_size=4, prefill_chunk=chunk,
                                 max_model_len=32, decode_interval=2))
-    s = e.num_slots
-    vec = jnp.zeros((s,), jnp.int32)
-    common = dict(cfg=e.cfg, temperature=e.temperature, top_k=e.top_k)
-    head = (e.params, e._k, e._v, jnp.asarray(e._tables))
-    tail = (e.base_key, e.cos, e.sin)
-    if program == "serve_prefill":
-        lowered = e._prefill_jit.lower(
-            *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
-            vec, vec, *tail, **common)
-    else:
-        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
-                                      interval=2, eos_token_id=None, **common)
+    text = lowered_serve(e, program)
     e.close()
-    text = lowered.as_text(debug_info=True)
-    assert module_name(text) == f"jit_{program}"
     found = scopes_in(text)
     assert found == want
     seen.update(found)

@@ -510,9 +510,10 @@ def test_cache_memory_scales_with_blocks_not_batch_x_maxlen(tiny):
     eng = ServeEngine(params, cfg, sc)
     contiguous_equiv = sc.decode_slots * blocks_for(32, sc.block_size)
     # the pool is [Hkv, L, num_blocks, block_size, D]
-    assert eng._k.shape[2] == 9 < contiguous_equiv
+    k = eng._kv[0]
+    assert k.shape[2] == 9 < contiguous_equiv
     # 3 slots x 32 max_model_len would be 96 token-slots; the pool holds 36
-    assert eng._k.shape[2] * eng._k.shape[3] == 36
+    assert k.shape[2] * k.shape[3] == 36
     eng.close()
 
 
@@ -676,23 +677,23 @@ def test_pad_rows_leak_nothing(tiny, requests5):
     pslots = eng.sched.prefill_slots()
     assert len(pslots) == 3
     feed, nval, finals = eng._prefill_feed(pslots)
-    trows = np.asarray(feed[0])
+    trows = np.asarray(feed[0][0])
     assert trows.shape == (4, eng.max_blocks) and finals == [1]
-    assert (trows[:3] == eng._tables[pslots]).all()
+    assert (trows[:3] == eng._tables[0][pslots]).all()
     assert (trows[:3, 0] < eng.num_blocks).all()
     assert (trows[3:] == eng.num_blocks).all()  # unmapped: writes drop
     assert list(nval) == [4, 3, 4, 0]
-    k0, v0 = np.asarray(eng._k), np.asarray(eng._v)
+    k0, v0 = (np.asarray(pool) for pool in eng._kv)
     assert k0.any()  # the first chunks are in the pool
     for r in eng.prefill_rungs:
         eng._run_prefill(eng._prefill_feed([], rows=r)[0])
-    assert (np.asarray(eng._k) == k0).all()
-    assert (np.asarray(eng._v) == v0).all()
+    assert (np.asarray(eng._kv[0]) == k0).all()
+    assert (np.asarray(eng._kv[1]) == v0).all()
     while eng.sched.has_work():
         eng.step(0.0)
     assert eng.pool.in_use == 0
     assert eng.pool.free_blocks == eng.pool.num_blocks
-    assert (eng._tables == eng.num_blocks).all()
+    assert (eng._tables[0] == eng.num_blocks).all()
     eng.close()
 
 
@@ -1015,7 +1016,7 @@ def test_latent_engine_retire_cancel_shed_leak_no_block():
     eng = ServeEngine(params, cfg, ServeConfig(
         decode_slots=2, block_size=4, prefill_chunk=8, max_model_len=48,
         decode_interval=2, num_blocks=14))
-    assert eng.latent and eng._v is None and eng._k.shape == (4, 14, 4, 128)
+    assert [pool.shape for pool in eng._kv] == [(4, 14, 4, 128)]
     for i, p in enumerate(prompts):
         eng.submit(p, 6, req_id=i, arrival=0.0,
                    **({"deadline_ms": 10.0} if i == 5 else {}))

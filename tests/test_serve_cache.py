@@ -1,0 +1,104 @@
+"""The serving cache behind its one constructor (serve/paged_cache.py
+`init_serve_cache`): for each of the four kinds of cache, what the engine
+asks of it and nothing of how it answers. The numbers are what `ServeEngine`
+built and counted for the same model and settings while it still chose the
+cache itself, by flag (PR 45's tree), so a layout moved here is the layout
+the serving cells ran on."""
+
+import jax
+import numpy as np
+import pytest
+
+from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
+from picotron_tpu.serve.paged_cache import init_serve_cache
+from picotron_tpu.serve.scheduler import Request, RequestState, Scheduler
+
+SLOTS, BLOCKS, MAX_LEN = 3, 40, 96
+ROW = [5, 2, 7] + [40] * 21  # 24 entries: 96 positions in blocks of 4
+
+# kind: (preset, prefill chunk, what the engine built and counted at PR 45)
+KINDS = {
+    "plain": ("debug-tiny", 4, dict(
+        cls="PagedKVCache", pools=[(2, 4, 40, 4, 16)] * 2, specs=((24, 40),),
+        rows=[ROW], prefill={}, decode=dict(kv_blocks=29), sched={})),
+    "sliding+full": ("debug-tiny-mellum2", 4, dict(
+        cls="MixedPagedKVCache",
+        pools=[(2, 2, 40, 4, 32), (2, 6, 12, 4, 32)] * 2,  # K of both, V of both
+        specs=((24, 40), (4, 12)),
+        rows=[ROW, [3, 1, 12, 12]],  # a ring's tail stays unmapped
+        prefill={},
+        decode=dict(kv_blocks=29, kv_blocks_full=58, kv_blocks_window=48,
+                    kv_blocks_banded=106, kv_blocks_unwindowed=232),
+        sched=dict(ring_blocks=4, window_blocks=12))),
+    "latent": ("debug-tiny-pangu-moe", 4, dict(
+        cls="LatentPagedCache", pools=[(4, 40, 4, 128)], specs=((24, 40),),
+        rows=[ROW], prefill=dict(latent_keys=1152),
+        decode=dict(kv_blocks=29, latent_blocks=116), sched={})),
+    "eva": ("debug-tiny-evabyte", 8, dict(
+        cls="EvaPagedCache", pools=[(2, 2, 40, 4, 16)] * 2, specs=((14, 40),),
+        # 6 summary entries first (96 / 4 rows in blocks of 4), then a window
+        rows=[[9, 4, 40, 40, 40, 40, 5, 2, 7, 40, 40, 40, 40, 40]],
+        prefill=dict(eva_summaries_written=10, eva_windows_closed=1),
+        decode=dict(kv_blocks=11, eva_summaries_written=4,
+                    eva_windows_closed=0, eva_summary_blocks=12,
+                    eva_window_blocks=10, eva_blocks_read=22,
+                    eva_blocks_full_attention=58),
+        sched=dict(summary=(32, 4)))),
+}
+# (positions already cached, tokens of this chunk) a row of a prefill
+# dispatch; (position written first, tokens to emit) a slot of a decode one
+PREFILL_SPANS = [(0, 8), (24, 8), (40, 5)]
+DECODE_SPANS = [(70, 2), (7, 1), (33, 2)]
+
+
+def made(kind):
+    preset, chunk, want = KINDS[kind]
+    cfg = ModelConfig(dtype="float32", **{**resolve_preset(preset),
+                                          "max_position_embeddings": 128})
+    scfg = ServeConfig(decode_slots=SLOTS, block_size=4, prefill_chunk=chunk,
+                       max_model_len=MAX_LEN, decode_interval=2,
+                       num_blocks=BLOCKS)
+    cache = jax.eval_shape(
+        lambda: init_serve_cache(cfg, scfg, SLOTS, BLOCKS, MAX_LEN))
+    return cfg, cache, want
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_constructor_gives_the_class_pools_and_tables(kind):
+    cfg, cache, want = made(kind)
+    assert type(cache).__name__ == want["cls"]
+    assert [p.shape for p in cache.pools] == want["pools"]
+    assert cache.table_specs == want["specs"]
+    # a program rebuilds the cache from its pools and the dispatch's tables
+    tables = cache[len(cache.pools):]
+    assert [t.shape for t in tables] == [(SLOTS, w) for w, _ in want["specs"]]
+    assert type(cache).of(cache.pools, tables) == cache
+    # what the scheduler is told of the format, and a second pool where it has one
+    args = cache.scheduler_args(cfg)
+    pool = args.pop("window_pool", None)
+    assert dict(args, **({"window_blocks": pool.num_blocks} if pool else {})) \
+        == want["sched"]
+    Scheduler(SLOTS, None, 4, 24, window_pool=pool, **args)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_slots_table_rows_from_its_block_lists(kind):
+    cfg, cache, want = made(kind)
+    st = RequestState(Request(0, tuple(range(1, 12)), 5))
+    st.blocks = [5, 2, 7]
+    st.wblocks = [3, 1] if len(want["specs"]) > 1 else []
+    st.sblocks = [9, 4] if kind == "eva" else []
+    rows = cache.slot_rows(st, cfg)
+    assert [r.tolist() for r in rows] == want["rows"]
+    assert all(r.dtype == np.int32 for r in rows)
+    # a free slot: every entry of every table unmapped
+    assert [r.tolist() for r in cache.slot_rows(None, cfg)] == [
+        [unmapped] * width for width, unmapped in want["specs"]]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_counts_a_dispatchs_span_carries(kind):
+    cfg, cache, want = made(kind)
+    assert cache.prefill_counts(PREFILL_SPANS, cfg) == want["prefill"]
+    assert cache.decode_counts(DECODE_SPANS, cfg) == want["decode"]
+    assert cache.decode_counts([], cfg)["kv_blocks"] == 0

@@ -147,14 +147,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--serve-decode-device", type=int, default=None,
                    help="device index for the decode pool (-1 = auto: "
                         "device 0)")
-    p.add_argument("--serve-speculator", default=None,
-                   choices=["off", "ngram"],
-                   help="speculative decode drafter ('ngram' = "
-                        "self-drafting n-gram; token-identical to "
-                        "non-speculative decode)")
-    p.add_argument("--serve-draft-len", type=int, default=None,
-                   help="draft tokens proposed per decode step when the "
-                        "speculator is on")
     # checkpoint / logging
     p.add_argument("--save-frequency", type=int, default=0)
     p.add_argument("--auto-resume", action="store_true",
@@ -256,8 +248,6 @@ def create_single_config(args) -> str:
         prefill_num_blocks=args.serve_prefill_num_blocks,
         prefill_device=args.serve_prefill_device,
         decode_device=args.serve_decode_device,
-        speculator=args.serve_speculator,
-        draft_len=args.serve_draft_len,
     ).items() if v is not None}
     if serve:
         raw["serve"] = serve

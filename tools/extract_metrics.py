@@ -109,7 +109,6 @@ _SERVE_FIELDS = (
     ("ttft_p95_s", "serve_ttft_p95_ms", 1e3),
     ("tpot_p50_s", "serve_tpot_p50_ms", 1e3),
     ("tpot_p95_s", "serve_tpot_p95_ms", 1e3),
-    ("acceptance_rate", "serve_acceptance_rate", 1),
     ("decode_stall_ticks_max", "serve_decode_stall_ticks_max", 1),
     ("handoffs", "serve_handoffs", 1),
     # fleet serving (serve/fleet.py): overload + failover counters
@@ -128,7 +127,7 @@ def process_telemetry(path: str, skip_steps: int = 3) -> dict | None:
     restart) keep only their LAST record — the one whose update survived
     into the final weights. Serving streams (no step rows, but a
     serve_summary event) yield serve_* columns instead, so a serving
-    sweep harvests TTFT/TPOT/acceptance with the same tool."""
+    sweep harvests TTFT/TPOT with the same tool."""
     rows_by_step: dict[int, dict] = {}
     val_losses: list[float] = []
     categories: dict[str, float] = {}
@@ -288,8 +287,7 @@ def main() -> None:
                   f"loss {r['final_loss']:.3f}")
         else:  # serving-only run (serve_summary, no train steps)
             print(f"  {r['run']}: {r.get('serve_tokens_per_sec', 0)} tok/s, "
-                  f"TTFT p50 {r.get('serve_ttft_p50_ms', 'n/a')} ms, "
-                  f"acceptance {r.get('serve_acceptance_rate', 'n/a')}")
+                  f"TTFT p50 {r.get('serve_ttft_p50_ms', 'n/a')} ms")
 
 
 if __name__ == "__main__":

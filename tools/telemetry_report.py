@@ -282,10 +282,6 @@ def serving_view(reqs: list[dict], summary: dict | None,
                 ("handoffs", "handoffs", 1),
                 ("handoff_s", "handoff_s", 1),
                 ("handoff_blocks", "handoff_blocks", 1),
-                # speculative decode (serve/spec_decode.py)
-                ("acceptance_rate", "acceptance_rate", 1),
-                ("draft_tokens", "draft_tokens", 1),
-                ("accepted_draft_tokens", "accepted_draft_tokens", 1),
                 # fleet serving (serve/fleet.py)
                 ("fleet_size", "fleet_size", 1),
                 ("shed", "shed", 1),
@@ -487,11 +483,6 @@ def render(s: dict, markdown: bool = False) -> str:
                 f"{pair('prefill_pool_peak_utilization')}) | handoffs "
                 f"{pair('handoffs')} ({pair('handoff_blocks')} blocks, "
                 f"{pair('handoff_s')} s)")
-        if "acceptance_rate" in sv or "draft_tokens" in sv:
-            lines.append(
-                f"  speculative: acceptance {pair('acceptance_rate')} "
-                f"({pair('accepted_draft_tokens')}/{pair('draft_tokens')} "
-                f"draft tokens accepted)")
         if any(k in sv for k in ("fleet_size", "shed", "redispatched",
                                  "engines_dead", "drains")):
             lines.append(
