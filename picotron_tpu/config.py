@@ -270,6 +270,36 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         chunk_size=16, num_pred_heads=8, norm_add_unit_offset=True,
         fp32_skip_add=True,
     ),
+    # LongCat-Flash-Omni's language model (Meituan; 560B-A27B): 28 layers,
+    # each TWO (latent attention, dense SwiGLU 12,288) pairs and an expert
+    # branch that starts after the first attention and lands after the
+    # second MLP (`shortcut_moe`; the block is written out at
+    # models/llama.py `_shortcut_layer`): MLA at openPangu's ranks with 64
+    # heads, each attention with its own weights and its own cache row
+    # (`attention_sublayers`), the query and key/value latents scaled after
+    # their norms (mla_scale_q_lora / mla_scale_kv_lora); a softmax router
+    # 768 wide, 512 routed experts of width 2,048 and 256 zero-compute
+    # experts (`zero_experts`: a pick returns the token itself times its
+    # gate), 12 a token chosen by score + a selection bias
+    # (`moe_selection_bias`) and weighed by the score x 6, not renormalised;
+    # no shared expert, untied 131,072-row head. Built: forward(), generate()
+    # and ServeEngine, on one device; training is refused by name. Assumed,
+    # with no key in config.json (the released modelling code;
+    # benchmark/reference_longcat.py repeats the list): the two scales are
+    # sqrt(hidden / rank) and act after the latents' norms, c cached after
+    # its scale; norm_topk_prob false; rotate-half RoPE. Not built: the
+    # audio and vision encoders and the codec decoder.
+    "meituan-longcat/LongCat-Flash-Omni": dict(
+        vocab_size=131072, hidden_size=6144, intermediate_size=12288,
+        num_hidden_layers=28, num_attention_heads=64, num_key_value_heads=64,
+        max_position_embeddings=131072, rope_theta=1e7, rms_norm_eps=1e-5,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True, shortcut_moe=True,
+        num_experts=512, num_experts_per_token=12, moe_intermediate_size=2048,
+        zero_experts=256, moe_selection_bias=True, norm_topk_prob=False,
+        routed_scaling_factor=6.0, router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -373,6 +403,21 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         chunk_size=4, num_pred_heads=3, norm_add_unit_offset=True,
         fp32_skip_add=True,
     ),
+    # Tiny LongCat-shaped debug model: 3 shortcut layers (6 attention
+    # sublayers), MLA as the tiny Pangu model's with both scales, a router
+    # 24 wide (16 routed + 8 zero-compute experts) 4 a token by score +
+    # bias, gates x 6 un-renormalised. Served with block_size 4.
+    "picotron-tpu/debug-tiny-longcat": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True, shortcut_moe=True,
+        num_experts=16, num_experts_per_token=4, moe_intermediate_size=32,
+        zero_experts=8, moe_selection_bias=True, norm_topk_prob=False,
+        routed_scaling_factor=6.0, router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -409,6 +454,8 @@ _PRESET_ALIASES = {
     "debug-tiny-exaone-moe": "picotron-tpu/debug-tiny-exaone-moe",
     "EvaByte": "EvaByte/EvaByte",
     "debug-tiny-evabyte": "picotron-tpu/debug-tiny-evabyte",
+    "LongCat-Flash-Omni": "meituan-longcat/LongCat-Flash-Omni",
+    "debug-tiny-longcat": "picotron-tpu/debug-tiny-longcat",
 }
 
 
@@ -433,7 +480,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -442,15 +489,24 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         with open(path_or_dict) as f:
             hf = json.load(f)
 
-    mtype = hf.get("model_type", "llama")
+    # LongCat-Flash's config.json is told by its own keys where a copy
+    # carries no model_type (the catalog's rows do not)
+    mtype = hf.get("model_type") or (
+        "longcat_flash" if "zero_expert_num" in hf else "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
-                 "pangu_ultra_moe", "exaone_moe", "evabyte")
+                 "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
             f"({supported}); the model layer (models/llama.py) implements "
             "the Llama lineage")
 
+    if mtype == "longcat_flash":
+        # its names for the depth, the two widths and the experts a token
+        hf = {**hf, "num_hidden_layers": hf["num_layers"],
+              "intermediate_size": hf["ffn_hidden_size"],
+              "moe_intermediate_size": hf["expert_ffn_hidden_size"],
+              "num_experts_per_tok": hf["moe_topk"]}
     heads = hf["num_attention_heads"]
     out: dict[str, Any] = {
         "vocab_size": hf["vocab_size"],
@@ -606,6 +662,34 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         out["norm_add_unit_offset"] = bool(
             hf.get("norm_add_unit_offset", False))
         out["fp32_skip_add"] = bool(hf.get("fp32_skip_add", False))
+    if mtype == "longcat_flash":
+        # LongCat-Flash: MLA's widths and its two latent scales (booleans;
+        # the factors are the modelling code's, `assumed` where a benchmark
+        # configuration states them), the double layer with its shortcut
+        # expert branch (every layer; no key), the zero-compute experts
+        # beside the routed ones in one softmax router, a selection bias
+        # (e_score_correction_bias, a buffer of the checkpoint), gates
+        # scaled and not renormalised (no norm_topk_prob key: false)
+        if hf.get("attention_method", "MLA") != "MLA":
+            raise ValueError(
+                f"longcat_flash with attention_method "
+                f"{hf.get('attention_method')!r}: only 'MLA' is built")
+        if hf.get("zero_expert_type", "identity") != "identity":
+            raise ValueError(
+                f"longcat_flash with zero_expert_type "
+                f"{hf.get('zero_expert_type')!r}: only 'identity' is built")
+        for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                    "qk_rope_head_dim", "v_head_dim"):
+            out[key] = int(hf[key])
+        out["mla_scale_q_lora"] = bool(hf.get("mla_scale_q_lora", False))
+        out["mla_scale_kv_lora"] = bool(hf.get("mla_scale_kv_lora", False))
+        out["shortcut_moe"] = True
+        out["zero_experts"] = int(hf.get("zero_expert_num", 0))
+        out["moe_selection_bias"] = True
+        out["norm_topk_prob"] = bool(hf.get("norm_topk_prob", False))
+        out["routed_scaling_factor"] = float(
+            hf.get("routed_scaling_factor", 1.0))
+        out["router_aux_coef"] = 0.0
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -754,8 +838,18 @@ class Block(NamedTuple):
     # "gqa": q/k/v per head | "mla": latent attention | "eva": q/k/v per
     # head over the open window's keys and a summary a chunk of the rest
     attn: str
-    mlp: str        # "dense": gated MLP | "experts": routed (+ shared) experts
+    # "dense": gated MLP | "experts": routed (+ shared) experts |
+    # "shortcut": the layer is TWO (attention, dense gated MLP) pairs, and
+    # routed experts read the first pair's normed MLP input and land on the
+    # residual stream after the second pair's MLP (every per-pair leaf of
+    # the stack has a sublayer axis behind the layer axis)
+    mlp: str
     sandwich: bool  # RMSNorms on the attention's and the MLP's outputs too
+
+    @property
+    def attentions(self) -> int:
+        """Attention sublayers a layer, each with its own cache row."""
+        return 2 if self.mlp == "shortcut" else 1
 
 
 def pattern_of(kinds: tuple) -> tuple:
@@ -909,6 +1003,23 @@ class ModelConfig:
     # Four RMSNorms a layer: the attention's and the MLP's OUTPUTS are
     # normed too before they join the residual stream (Pangu Ultra).
     sandwich_norm: bool = False
+    # Latent attention's two latents are scaled after their norms, the
+    # query's by sqrt(hidden_size / q_lora_rank) and the key/value's by
+    # sqrt(hidden_size / kv_lora_rank) (the published keys, booleans; the
+    # cache holds c after its scale).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # A layer is two (attention, dense MLP of `intermediate_size`) pairs and
+    # a shortcut-connected expert branch (`Block.mlp == "shortcut"`).
+    shortcut_moe: bool = False
+    # Zero-compute experts: the router's LAST `zero_experts` columns, behind
+    # those of the routed experts; a pick of one returns the token itself
+    # times its gate (the published zero_expert_type 'identity'), is always
+    # on the token's own device, and reads no bank.
+    zero_experts: int = 0
+    # The router chooses its k experts by score + a bias a column
+    # (`router_bias`, zeros at init) and weighs them by the score alone.
+    moe_selection_bias: bool = False
     # The attention's law: "softmax" over every (or a band of) earlier
     # key, or "eva" (the published key `attention_class`; ops/eva.py):
     # positions fall into windows of `window_size` and chunks of
@@ -1011,7 +1122,24 @@ class ModelConfig:
 
     @property
     def router_width(self) -> int:
-        return self.router_experts or self.num_experts
+        """The router's columns: every routed expert of the model, held
+        here or not, then the zero-compute experts."""
+        return (self.router_experts or self.num_experts) + self.zero_experts
+
+    @property
+    def mla_scales(self) -> tuple:
+        """(query latent's, key/value latent's) scale after its norm."""
+        return (
+            (self.hidden_size / self.q_lora_rank) ** 0.5
+            if self.mla_scale_q_lora else 1.0,
+            (self.hidden_size / self.kv_lora_rank) ** 0.5
+            if self.mla_scale_kv_lora else 1.0)
+
+    @property
+    def attention_sublayers(self) -> int:
+        """Attention sublayers of the model, one cache row each: the
+        leading axis of a latent cache."""
+        return sum(st.layers * st.block.attentions for st in self.stacks)
 
     @property
     def stacks(self) -> tuple:
@@ -1028,6 +1156,8 @@ class ModelConfig:
         if not self.num_experts:
             return (Stack("layers", n,
                           Block(attn, "dense", self.sandwich_norm), kinds),)
+        if self.shortcut_moe:
+            return (Stack("layers", n, Block(attn, "shortcut", False), kinds),)
         out = (Stack("layers", n - k,
                      Block(attn, "experts", self.sandwich_norm), kinds[k:]),)
         if k:
@@ -1162,11 +1292,32 @@ class ModelConfig:
             raise ValueError(
                 "n_shared_experts and expert_first must be >= 0")
         if self.num_experts and (
-                self.expert_first + self.num_experts > self.router_width):
+                self.expert_first + self.num_experts
+                > self.router_width - self.zero_experts):
             raise ValueError(
                 f"the experts held here ({self.num_experts} from index "
                 f"{self.expert_first}) do not lie inside the router's "
-                f"{self.router_width} (router_experts)")
+                f"{self.router_width - self.zero_experts} routed experts "
+                f"(router_experts)")
+        if (self.zero_experts or self.moe_selection_bias
+                or self.shortcut_moe) and not self.num_experts:
+            raise ValueError(
+                "zero_experts / moe_selection_bias / shortcut_moe describe "
+                "an expert branch: they need num_experts > 0")
+        if self.zero_experts < 0:
+            raise ValueError("zero_experts must be >= 0")
+        if self.shortcut_moe and (
+                not self.mla or self.first_k_dense_replace
+                or self.sandwich_norm or self.n_shared_experts):
+            raise ValueError(
+                "shortcut_moe is built as two (latent attention, dense MLP) "
+                "pairs a layer with two norms a pair and no shared expert: "
+                "it needs kv_lora_rank > 0, and first_k_dense_replace, "
+                "sandwich_norm and n_shared_experts unset")
+        if (self.mla_scale_q_lora or self.mla_scale_kv_lora) and not self.mla:
+            raise ValueError(
+                "mla_scale_q_lora / mla_scale_kv_lora are latent "
+                "attention's: they need kv_lora_rank > 0")
         if self.num_experts and self.num_experts_per_token > self.router_width:
             raise ValueError(
                 f"num_experts_per_token ({self.num_experts_per_token}) "
@@ -2002,8 +2153,10 @@ class Config:
                    "(one pool, one table a slot)")
 
     def _refuse_new_blocks(self) -> None:
-        """Latent attention, sandwich norms, a shared expert, sigmoid
-        routing, a held share of the experts, leading dense layers (with
+        """Latent attention (scaled or not), sandwich norms, a shared
+        expert, sigmoid routing, a held share of the experts, a layer of two
+        attentions with a shortcut-connected expert branch, zero-compute
+        experts, a router selection bias, leading dense layers (with
         or without a layer pattern cut over the two stacks), per-head
         QK-norm, an unrotated layer kind, EVA attention, a head of several
         prediction heads, 1 + w norms and a float32 residual stream run on
@@ -2027,7 +2180,14 @@ class Config:
             ("moe_scoring='sigmoid'", m.moe_scoring != "softmax"),
             ("routed_scaling_factor != 1", m.routed_scaling_factor != 1.0),
             ("a held share of the experts (router_experts)",
-             m.router_width != m.num_experts),
+             m.router_width - m.zero_experts != m.num_experts),
+            ("a shortcut-connected expert branch beside two attentions a "
+             "layer (shortcut_moe)", m.shortcut_moe),
+            ("zero-compute experts (zero_experts)", m.zero_experts > 0),
+            ("a router selection bias (moe_selection_bias)",
+             m.moe_selection_bias),
+            ("scaled latents (mla_scale_q_lora / mla_scale_kv_lora)",
+             m.mla_scale_q_lora or m.mla_scale_kv_lora),
             ("attention_class 'eva' (window_size / chunk_size)", m.eva),
             ("num_pred_heads > 1", m.num_pred_heads > 1),
             ("norm_add_unit_offset", m.norm_add_unit_offset),
@@ -2082,16 +2242,26 @@ def refuse_training(m: ModelConfig) -> None:
     train step differentiates, and `train.main`) refuse by name a model
     that has no training loss: EVA attention trains through a banded kernel
     with summary keys and its backward, which is not built (ROADMAP M9),
-    and a head of several prediction heads has no loss over them."""
+    a head of several prediction heads has no loss over them, and a router
+    with zero-compute experts or a selection bias has no balance term (the
+    bias is moved by the experts' load outside the loss, and a share of the
+    load is meant to fall on the zero-compute experts), nor has the layer
+    of two attentions a fused or pipelined form."""
     what = [name for name, on in (
         ("attention_class 'eva'", m.eva),
-        ("num_pred_heads > 1", m.num_pred_heads > 1)) if on]
+        ("num_pred_heads > 1", m.num_pred_heads > 1),
+        ("a shortcut-connected expert branch (shortcut_moe)", m.shortcut_moe),
+        ("zero-compute experts (zero_experts)", m.zero_experts > 0),
+        ("a router selection bias (moe_selection_bias)",
+         m.moe_selection_bias)) if on]
     if what:
         raise ValueError(
             f"model has {', '.join(what)}, which training does not "
             f"implement (no loss over several prediction heads, no banded "
-            f"attention kernel with summary keys and its backward); such a "
-            f"model runs on forward(), generate() and ServeEngine")
+            f"attention kernel with summary keys and its backward, no router "
+            f"loss over zero-compute experts and no update of a selection "
+            f"bias); such a model runs on forward(), generate() and "
+            f"ServeEngine")
 
 
 def check_eva_serving(m: ModelConfig, sv: ServeConfig) -> None:
@@ -2272,9 +2442,11 @@ def num_params(m: ModelConfig, active_only: bool = False,
         e_ffn = 3 * h * m.expert_ffn_size  # gate/up/down per expert
         n_ffn_experts = (m.num_experts_per_token if active_only
                          else m.num_experts)
-        # router + routed experts (those held here) + shared experts
+        # router (+ its selection bias) + routed experts (those held here)
+        # + shared experts
         ffn = (h * m.router_width + n_ffn_experts * e_ffn
-               + m.n_shared_experts * e_ffn)
+               + m.n_shared_experts * e_ffn
+               + (m.router_width if m.moe_selection_bias else 0))
     else:
         ffn = dense_ffn
     if m.mla:
@@ -2299,6 +2471,9 @@ def num_params(m: ModelConfig, active_only: bool = False,
         if m.eva:
             attn += 2 * kv  # eva_mu / eva_phi: a pooling vector a KV head
     norms = (4 if m.sandwich_norm else 2) * h  # RMSNorm weights a layer
+    if m.shortcut_moe:
+        # two (attention, dense MLP, two norms) pairs beside the experts
+        attn, ffn, norms = 2 * attn, ffn + 2 * dense_ffn, 2 * norms
     k = m.first_k_dense_replace
     layers = (l - k) * (attn + ffn + norms) + k * (attn + dense_ffn + norms)
     head = (h * v * m.num_pred_heads

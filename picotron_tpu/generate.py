@@ -58,7 +58,7 @@ from jax import lax
 
 from picotron_tpu.config import ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
-    DEFAULT_CTX, _mlp_block, compute_dtype, final_hidden,
+    BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, final_hidden,
     kind_tables, layer_window, mlp_act, model_rope_tables, norm_weight,
     qkv_proj, residual_stream, rms_norm, served_head, shared_expert,
 )
@@ -120,9 +120,11 @@ class KVCache(NamedTuple):
 
 
 class LatentCache(NamedTuple):
-    """Per-layer contiguous latent cache of a model with latent attention
-    (MLA, ops/mla.py), [L, B, S_max, rank + rope]: `[c | k_r]` a position,
-    c after its norm and k_r after its rotation, nothing per head. The
+    """Contiguous latent cache of a model with latent attention (MLA,
+    ops/mla.py), a row an attention sublayer (a layer, but for a model whose
+    layers hold two attentions: `cfg.attention_sublayers`),
+    [L, B, S_max, rank + rope]: `[c | k_r]` a position,
+    c after its norm (and scale) and k_r after its rotation, nothing per head. The
     offline twin of `serve.paged_cache.LatentPagedCache`; the layer loop
     calls both alike: `write(li, ckr, q_pos)` and `attend(li, q_n, q_r,
     q_pos, kv_b, cfg)`."""
@@ -226,7 +228,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_length: int):
                         jnp.zeros(chunks, dt), jnp.zeros(chunks, dt))
     if cfg.mla:
         return LatentCache(jnp.zeros(
-            (cfg.num_hidden_layers, batch, max_length,
+            (cfg.attention_sublayers, batch, max_length,
              cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt))
     shape = (cfg.num_hidden_layers, batch, max_length,
              cfg.num_key_value_heads, cfg.head_dim)
@@ -356,14 +358,36 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     # (the compiled serve programs carry both pools as
     # {4,3,2,1,0:T(8,128)(2,1)} and hold no pool-sized copy;
     # tests/test_chip_compile.py).
-    def layer(x, cache, lp, banks, block, li, bank_li, kind, ki):
-        """One block, as `models.llama.decoder_layer` describes it
-        (`block`), against the cache. `li`: the layer's index in the model
-        (and in the cache); `bank_li`: in its stack's expert banks."""
+    def attend(x, cache, lp, block, li, kind, ki):
+        """Norm -> one attention against cache row `li` -> its output."""
         h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
                      cfg.rms_norm_eps).astype(dt)
-        out, cache = {"gqa": gqa, "mla": mla, "eva": eva}[block.attn](
+        return {"gqa": gqa, "mla": mla, "eva": eva}[block.attn](
             h, cache, lp, li, kind, ki)
+
+    def shortcut_layer(x, cache, lp, banks, block, li, bank_li, kind, ki):
+        """`models.llama._shortcut_layer` against the cache: the layer's
+        two attentions write and read cache rows `li` and `li + 1`. `lp`:
+        the layer as each of its two pairs sees it."""
+        p0, p1 = lp
+        out, cache = attend(x, cache, p0, block, li, kind, ki)
+        a1 = x + out
+        with scope("scmoe_branch"):
+            s, touched = _served_experts(a1, p0, banks, bank_li, cfg, live)
+        m1 = a1 + _mlp_block(a1, p0, cfg, DEFAULT_CTX)
+        out, cache = attend(m1, cache, p1, block, li + 1, kind, ki)
+        a2 = m1 + out
+        return a2 + _mlp_block(a2, p1, cfg, DEFAULT_CTX) + s, cache, touched
+
+    def layer(x, cache, lp, banks, block, li, bank_li, kind, ki):
+        """One block, as `models.llama.decoder_layer` describes it
+        (`block`), against the cache. `li`: the layer's first row in the
+        cache (its index in the model, where a layer is one attention);
+        `bank_li`: its index in its stack's expert banks."""
+        if block.mlp == "shortcut":
+            return shortcut_layer(x, cache, lp, banks, block, li, bank_li,
+                                  kind, ki)
+        out, cache = attend(x, cache, lp, block, li, kind, ki)
         if block.sandwich:
             out = rms_norm(out, lp["attn_out_norm"], cfg.rms_norm_eps)
         x = x + out
@@ -376,15 +400,17 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             mlp_out = rms_norm(mlp_out, lp["mlp_out_norm"], cfg.rms_norm_eps)
         return x + mlp_out, cache, touched
 
-    def run_stack(x, cache, touched, stack, st, first: int):
+    def run_stack(x, cache, touched, stack, st, first: int, row: int):
         """One stack of the layer tree (`cfg.stacks`), whose first layer
-        is the model's layer `first`: a scan over the whole periods of its
-        own slice of the layer pattern (models/llama.py run_layers: a
-        layer's kind is static in the body), then the layers left over,
-        outside the scan. A layer's place in the cache: `first + i`, and
-        for a cache with a pool a kind (`ki`) the layers of its kind before
-        it in the model, this stack's own among them."""
+        is the model's layer `first` and whose first cache row is `row`: a
+        scan over the whole periods of its own slice of the layer pattern
+        (models/llama.py run_layers: a layer's kind is static in the body),
+        then the layers left over, outside the scan. A layer's place in
+        the cache: `row + i` (`row + 2 i` where a layer holds two
+        attentions), and for a cache with a pool a kind (`ki`) the layers
+        of its kind before it in the model, this stack's own among them."""
         block = st.block
+        rows = block.attentions
         period, whole, rest = pattern_of(st.kinds)
         plen = len(period)
         before = {k: cfg.layer_kinds[:first].count(k) for k in set(st.kinds)}
@@ -403,10 +429,24 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             # layer i of the stack (its place in the stack's leaves and
             # banks), first + i of the model
             x, cache, touched = carry
-            lp = jax.tree.map(
-                lambda w: lax.dynamic_index_in_dim(w, i, 0, keepdims=False),
-                layers)
-            x, cache, t = layer(x, cache, lp, banks, block, first + i, i,
+
+            def take(w, at):
+                return lax.dynamic_index_in_dim(w, at, 0, keepdims=False)
+
+            if rows == 1:
+                at = i
+                lp = jax.tree.map(lambda w: take(w, i), layers)
+            else:
+                # each (attention, dense MLP) pair's own view of the layer
+                # (`models.llama.sublayer`), a pair's leaf [L, 2, ...] read
+                # as [2 L, ...] at 2 i + j: ONE index into the stack, as
+                # above. Layer i's [2, ...] slice is a value both pairs
+                # share, and the compiler writes it out every iteration
+                at = rows * i
+                lp = tuple({n: (take(w, i) if n in BRANCH else
+                                take(w.reshape(-1, *w.shape[2:]), at + j))
+                            for n, w in layers.items()} for j in range(rows))
+            x, cache, t = layer(x, cache, lp, banks, block, row + at, i,
                                 kind, ki)
             return x, cache, touched if t is None else touched + t
 
@@ -424,39 +464,53 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         return carry
 
     # a dense model carries no counter: its programs are what they were
-    touched = jnp.zeros((4,), jnp.int32) if cfg.num_experts else None
-    first = 0
+    n_counts = len(expert_counts(cfg))
+    touched = jnp.zeros((n_counts,), jnp.int32) if cfg.num_experts else None
+    first = row = 0
     for st in cfg.stacks:
         x, cache, touched = run_stack(x, cache, touched, params[st.name], st,
-                                      first)
-        first += st.layers
+                                      first, row)
+        first, row = first + st.layers, row + st.layers * st.block.attentions
     if with_touched:
         return x, cache, (touched if touched is not None
-                          else jnp.zeros((4,), jnp.int32))
+                          else jnp.zeros((n_counts,), jnp.int32))
     return x, cache
 
 
 BANKS = ("w_gate", "w_up", "w_down")  # the experts' stacks [L, E, ...]
 
 
-@scope("mlp")
-def _moe_served_block(x, lp, banks, li, cfg: ModelConfig, live):
+def expert_counts(cfg: ModelConfig) -> tuple:
+    """The names of the counts a decode step returns of its expert blocks
+    (`ops/moe.py moe_mlp_served`), summed over the layers, in their order;
+    the serving engine keeps a running sum of each under the same name.
+    The last two only where the router has zero-compute experts."""
+    return ("experts_touched", "expert_visits", "picks_here", "picks_all") + (
+        ("picks_zero", "rows_all_zero_or_away") if cfg.zero_experts else ())
+
+
+def _served_experts(x, lp, banks, li, cfg: ModelConfig, live):
     """RMSNorm -> routed experts, dropless, beside the shared expert
-    where the model has one, as `models.llama._moe_block` computes them;
+    where the model has one and the zero-compute experts' term where the
+    router has some, as `models.llama._experts` computes them;
     rows without a token (`live` false: idle slots, chunk padding) are
     routed nowhere. `banks`: the stack's whole banks of the experts held
-    on this device, of which this is layer `li`. Returns (out, [experts
-    touched, (row tile, expert) pairs visited, picks here, picks])."""
+    on this device, of which this is layer `li`. Returns (out, the counts
+    `expert_counts` names)."""
     h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
     out, counts = moe_mlp_served(
         h, lp["router"], *(banks[n] for n in BANKS),
         top_k=cfg.num_experts_per_token, act=mlp_act(cfg),
         norm_topk_prob=cfg.norm_topk_prob, live=live, layer=li,
         scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
-        expert_first=cfg.expert_first)
+        expert_first=cfg.expert_first, bias=lp.get("router_bias"),
+        zero=cfg.zero_experts)
     if "shared_gate" in lp:
         out = out + shared_expert(h, lp, cfg)
     return out, counts
+
+
+_moe_served_block = scope("mlp")(_served_experts)  # a layer's MLP
 
 
 def _logits_last(params, x, cfg: ModelConfig):

@@ -215,14 +215,20 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     kv_out = cfg.num_key_value_heads * d
 
     keys = jax.random.split(key, 14)
+    # a layer of two (attention, dense MLP) pairs: every leaf of a pair has
+    # a sublayer axis behind the layer axis, [nl, 2, ...]
+    pair = (block.attentions,) if block.mlp == "shortcut" else ()
 
     def stacked(k, fan_in, shape):
         ks = jax.random.split(k, nl)
         return jnp.stack([_uniform_fan_in(ks[j], fan_in, shape) for j in range(nl)])
 
+    def paired(k, fan_in, shape):
+        return stacked(k, fan_in, pair + shape)
+
     layers = {
-        "input_norm": _norm_init(cfg, (nl, h)),
-        "post_norm": _norm_init(cfg, (nl, h)),
+        "input_norm": _norm_init(cfg, (nl,) + pair + (h,)),
+        "post_norm": _norm_init(cfg, (nl,) + pair + (h,)),
     }
     if block.sandwich:
         # norms on the attention's and the MLP's outputs (`post_norm` is
@@ -238,15 +244,15 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
                       cfg.v_head_dim)
         mk = jax.random.split(keys[1], 4)
         layers.update({
-            "q_a": stacked(mk[0], h, (h, ql)),
-            "q_a_norm": jnp.ones((nl, ql), jnp.float32),
-            "q_b": stacked(mk[1], ql, (ql, heads * (dn + dr))),
+            "q_a": paired(mk[0], h, (h, ql)),
+            "q_a_norm": jnp.ones((nl,) + pair + (ql,), jnp.float32),
+            "q_b": paired(mk[1], ql, (ql, heads * (dn + dr))),
             # [c | k_r]: the latent and the shared rotated dimensions
-            "kv_a": stacked(mk[2], h, (h, rank + dr)),
-            "kv_a_norm": jnp.ones((nl, rank), jnp.float32),
+            "kv_a": paired(mk[2], h, (h, rank + dr)),
+            "kv_a_norm": jnp.ones((nl,) + pair + (rank,), jnp.float32),
             # a head's [k_n | v] columns side by side (ops/mla.py)
-            "kv_b": stacked(mk[3], rank, (rank, heads * (dn + dv))),
-            "o": stacked(keys[4], heads * dv, (heads * dv, h)),
+            "kv_b": paired(mk[3], rank, (rank, heads * (dn + dv))),
+            "o": paired(keys[4], heads * dv, (heads * dv, h)),
         })
     else:
         layers.update({
@@ -283,7 +289,7 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "q_norm": jnp.ones((nl, q_out), jnp.float32),
             "k_norm": jnp.ones((nl, kv_out), jnp.float32),
         })
-    if block.mlp == "experts":
+    if block.mlp in ("experts", "shortcut"):
         e, f = cfg.num_experts, cfg.expert_ffn_size
         layers.update({
             # router (over every expert of the model, held here or not) +
@@ -300,13 +306,30 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
                 "shared_up": stacked(keys[11], h, (h, fs)),
                 "shared_down": stacked(keys[12], fs, (fs, h)),
             })
-    else:
+        if cfg.moe_selection_bias:
+            # zeros, as a released checkpoint's buffer starts
+            layers["router_bias"] = jnp.zeros((nl, cfg.router_width),
+                                              jnp.float32)
+    if block.mlp != "experts":
+        dk = jax.random.split(keys[10], 3) if pair else keys[5:8]
         layers.update({
-            "gate": stacked(keys[5], h, (h, i)),
-            "up": stacked(keys[6], h, (h, i)),
-            "down": stacked(keys[7], i, (i, h)),
+            "gate": paired(dk[0], h, (h, i)),
+            "up": paired(dk[1], h, (h, i)),
+            "down": paired(dk[2], i, (i, h)),
         })
     return layers
+
+
+# the leaves of a "shortcut" block's expert branch: one a layer, where every
+# other leaf of the stack is one a sublayer
+BRANCH = ("router", "router_bias", "w_gate", "w_up", "w_down")
+
+
+def sublayer(lp, j: int):
+    """A layer of two (attention, dense MLP) pairs as pair `j` sees it: the
+    pair's own leaves (index j of their sublayer axis) under the names they
+    have in a layer of one pair, and the expert branch's as they are."""
+    return {n: (w if n in BRANCH else w[j]) for n, w in lp.items()}
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -632,10 +655,10 @@ def shared_expert(h, lp, cfg: ModelConfig):
         return (mlp_act(cfg)(gate) * up) @ lp["shared_down"].astype(dt)
 
 
-@scope("mlp")
-def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
+def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     """RMSNorm -> top-k routed expert SwiGLU bank (beyond the reference;
-    ops/moe.py), beside the shared expert where the model has one. Returns
+    ops/moe.py), beside the shared expert where the model has one and the
+    zero-compute experts' term where the router has some. Returns
     (out, aux [3]): the pre-weighted router loss, the capacity drop
     fraction and the busiest expert's load over the mean."""
     from picotron_tpu.ops.moe import moe_mlp
@@ -659,6 +682,7 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
         norm_topk_prob=cfg.norm_topk_prob,
         scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
         expert_first=cfg.expert_first,
+        bias=lp.get("router_bias"), zero=cfg.zero_experts,
     )
     if "shared_gate" in lp:
         out = out + shared_expert(h, lp, cfg)
@@ -670,6 +694,32 @@ def _moe_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     # not from the weights (ADVICE r3).
     return ctx.g(out), ctx.moe_aux_sync(
         jnp.stack([aux, drop, load]) * is_real)
+
+
+_moe_block = scope("mlp")(_experts)  # a layer's MLP that is an expert block
+
+
+def _shortcut_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
+                    is_real=1.0):
+    """A layer of two (latent attention, dense MLP) pairs and a
+    shortcut-connected expert branch (LongCat-Flash; `Block.mlp ==
+    "shortcut"`), N an RMSNorm:
+
+        a1 = x  + Attn_0(N_in0(x))
+        s  = Experts(N_post0(a1))          the branch starts here ...
+        m1 = a1 + MLP_0(N_post0(a1))
+        a2 = m1 + Attn_1(N_in1(m1))
+        y  = a2 + MLP_1(N_post1(a2)) + s   ... and lands here
+
+    The branch is computed where its input exists and added where the
+    model adds it, in this order of the sums."""
+    p0, p1 = sublayer(lp, 0), sublayer(lp, 1)
+    a1 = x + _mla_attention_block(x, p0, cfg, ctx, cos, sin)
+    with scope("scmoe_branch"):
+        s, aux = _experts(a1, p0, cfg, ctx, is_real)
+    m1 = a1 + _mlp_block(a1, p0, cfg, ctx)
+    a2 = m1 + _mla_attention_block(m1, p1, cfg, ctx, cos, sin)
+    return a2 + _mlp_block(a2, p1, cfg, ctx) + s, aux
 
 
 def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
@@ -684,6 +734,8 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     (`cfg.stacks`); None = the last stack's, which is every layer's in a
     model of one kind of block."""
     block = block or cfg.stacks[-1].block
+    if block.mlp == "shortcut":
+        return _shortcut_layer(x, lp, cfg, ctx, cos, sin, is_real)
     if block.attn == "mla":
         attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
     elif block.attn == "eva":

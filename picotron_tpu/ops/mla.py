@@ -50,12 +50,20 @@ def mla_project(h, lp, cfg, keep_flat: bool = False):
     [B, s, rope] unrotated). One implementation for `forward()` and the
     cached decode paths, which keep the flat q a value of its own
     (`keep_flat`) so that `q_b` is read where it lies, as
-    `models.llama.qkv_proj` says."""
+    `models.llama.qkv_proj` says. Where the model scales its latents
+    (`cfg.mla_scales`, static; 1.0 and nothing computed otherwise), the scale
+    rides the norm's weight in float32, so a latent is rounded once: `c`
+    is what a cache holds, after its scale."""
     dt = h.dtype
     b, s, _ = h.shape
     dn, dr, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    q_scale, kv_scale = cfg.mla_scales
+
+    def weight(w, scale):
+        return w if scale == 1.0 else w.astype(jnp.float32) * scale
+
     with scope("mla_q"):
-        cq = rms_norm(h @ lp["q_a"].astype(dt), lp["q_a_norm"],
+        cq = rms_norm(h @ lp["q_a"].astype(dt), weight(lp["q_a_norm"], q_scale),
                       cfg.rms_norm_eps)
         q = cq @ lp["q_b"].astype(dt)
         if keep_flat:
@@ -63,7 +71,8 @@ def mla_project(h, lp, cfg, keep_flat: bool = False):
         q = q.reshape(b, s, -1, dn + dr)
     with scope("mla_kv_latent"):
         ckr = h @ lp["kv_a"].astype(dt)                    # [B, s, rank + rope]
-        c = rms_norm(ckr[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps)
+        c = rms_norm(ckr[..., :rank], weight(lp["kv_a_norm"], kv_scale),
+                     cfg.rms_norm_eps)
     return q[..., :dn], q[..., dn:], c, ckr[..., rank:]
 
 

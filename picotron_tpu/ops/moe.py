@@ -81,20 +81,29 @@ class Routing(NamedTuple):
     z_loss: jnp.ndarray       # [] fp32 — router z-loss (unweighted)
     counts: jnp.ndarray       # [E] int32 — assignments per expert (the
     #                           dropless dispatch's group sizes)
+    zero_pick: Optional[jnp.ndarray] = None  # [N, k] bool — the pick fell
+    #                           on a zero-compute expert (a router with some)
 
 
 def topk_gates(logits, k: int, norm_topk_prob: bool,
-               scoring: str = "softmax", scale: float = 1.0):
+               scoring: str = "softmax", scale: float = 1.0, bias=None):
     """(scores [N, E], chosen experts [N, k], gates [N, k]) of float32
     router logits: softmax over all E or the sigmoid of each (`scoring`),
     the k largest. Mixtral renormalizes the k selected scores to sum to 1;
     OLMoE (norm_topk_prob false) combines with the raw ones; the sigmoid
     law (DeepSeek-V3, Pangu Ultra MoE) renormalizes with 1e-20 under the
-    sum and multiplies by `scale` (routed_scaling_factor)."""
+    sum and multiplies by `scale` (routed_scaling_factor). `bias` [E] (a
+    selection bias: DeepSeek-V3's e_score_correction_bias, LongCat-Flash's):
+    the k are the largest of score + bias, and their gates are made of the
+    scores alone."""
     sigmoid = scoring == "sigmoid"
     probs = (jax.nn.sigmoid(logits) if sigmoid
              else jax.nn.softmax(logits, axis=-1))
-    top_p, top_i = lax.top_k(probs, k)
+    if bias is None:
+        top_p, top_i = lax.top_k(probs, k)
+    else:
+        _, top_i = lax.top_k(probs + bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(probs, top_i, axis=-1)
     gate = top_p
     if norm_topk_prob:
         total = jnp.sum(top_p, axis=-1, keepdims=True)
@@ -107,14 +116,20 @@ def route_topk(logits: jnp.ndarray, k: int,
                norm_topk_prob: bool = True,
                live: Optional[jnp.ndarray] = None,
                scoring: str = "softmax", scale: float = 1.0,
-               held: Optional[tuple] = None) -> Routing:
+               held: Optional[tuple] = None, bias=None,
+               zero: int = 0) -> Routing:
     """Top-k routing with slots assigned in token order.
 
-    `scoring`, `scale`: the gates' law (`topk_gates`). `held` (first,
-    count): the banks hold experts first .. first + count - 1 of the
+    `scoring`, `scale`, `bias`: the gates' law (`topk_gates`). `held`
+    (first, count): the banks hold experts first .. first + count - 1 of the
     router's E. `expert_idx` is then an index into the banks, a pick that
     lands on an expert held elsewhere is a dead assignment as a dead row's
-    are (below), and `counts` has count + 1 entries.
+    are (below), and `counts` has count + 1 entries. `zero`: the router's
+    last `zero` columns are zero-compute experts, behind the routed ones
+    that `held` counts within. A pick of one is a dead assignment too (it
+    lies in no held range, so it is in no group size and reads no bank);
+    `zero_pick` says which picks they were, and the caller adds their
+    gates times the token.
 
     `live` [N] bool (the serving programs: rows that carry a token): a row
     that is not live is assigned to no expert. Its k assignments go to a
@@ -139,7 +154,8 @@ def route_topk(logits: jnp.ndarray, k: int,
     """
     n, e = logits.shape
     logits = logits.astype(jnp.float32)
-    probs, top_i, gate = topk_gates(logits, k, norm_topk_prob, scoring, scale)
+    probs, top_i, gate = topk_gates(logits, k, norm_topk_prob, scoring, scale,
+                                    bias)
     picked = top_i  # in the router's numbering, for the balance statistic
 
     # slot_in_expert: for assignment (token t, choice j) -> how many earlier
@@ -179,7 +195,21 @@ def route_topk(logits: jnp.ndarray, k: int,
     z_loss = stat_mean(jnp.mean(z * z))
 
     return Routing(top_i.astype(jnp.int32), gate, slot.astype(jnp.int32),
-                   aux, z_loss, jnp.sum(onehot, axis=0))
+                   aux, z_loss, jnp.sum(onehot, axis=0),
+                   picked >= e - zero if zero else None)
+
+
+def add_zero_experts(out, flat, r: Routing, live=None):
+    """`out` [N, H], the routed experts' part of the tokens' outputs, plus
+    the zero-compute experts' part: the token itself times the summed gates
+    of its picks of them (zeros, selected and not multiplied, for a row that
+    is not `live`). Added in float32 and rounded once."""
+    with scope("moe_zero"):
+        gate = jnp.sum(jnp.where(r.zero_pick, r.gate, 0.0), axis=-1)
+        term = gate[:, None] * flat.astype(jnp.float32)
+        if live is not None:
+            term = jnp.where(live[:, None], term, 0.0)
+        return (out.astype(jnp.float32) + term).astype(out.dtype)
 
 
 def _swiglu_experts(slots: jnp.ndarray, w_gate, w_up, w_down,
@@ -331,11 +361,12 @@ def _split_over_a_mesh(w) -> bool:
 # a layer: a second of every start of an engine with five programs
 @functools.partial(jax.jit,
                    static_argnames=("top_k", "act", "norm_topk_prob",
-                                    "scoring", "scale", "expert_first"))
+                                    "scoring", "scale", "expert_first",
+                                    "zero"))
 def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                    act, norm_topk_prob: bool, live, layer,
                    scoring: str = "softmax", scale: float = 1.0,
-                   expert_first: int = 0):
+                   expert_first: int = 0, bias=None, zero: int = 0):
     """The routed experts of the decode paths (`generate`, the serve
     programs): `moe_mlp`'s router and dropless mathematics, no loss terms,
     and `live` [B, S] saying which rows carry a token (idle slots and chunk
@@ -343,7 +374,11 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     router scores every expert of the model (`router_w` [H, R]); the banks
     are the stack's whole banks [L, E, ...] of the E experts held on this
     device, `expert_first` .. `expert_first + E - 1` of the R (all of them
-    where E = R), and `layer` this layer's index in them. A pick that lands
+    where E = R), and `layer` this layer's index in them. `bias` [R]: the
+    router's selection bias (`topk_gates`). `zero`: the router's last
+    `zero` columns are zero-compute experts (the routed ones are the R -
+    zero before them): a pick of one adds its gate times the token, here,
+    whatever this device holds, and is in no group of the kernel. A pick that lands
     on an expert held elsewhere adds nothing here (a token whose picks are
     all elsewhere comes out as zeros, and its block output is the shared
     expert's alone), and nothing stands in for the absent devices. A
@@ -364,7 +399,9 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     banks; the (row tile, expert) pairs the kernel visited, which is what
     it read of them (an expert whose rows span two tiles is two visits);
     the live rows' picks that landed on held experts; and all their picks
-    (rows x k)."""
+    (rows x k). With `zero`, counts [6]: then the live rows' picks of
+    zero-compute experts, and the live rows none of whose picks read a
+    held bank (all of them zero-compute or held elsewhere)."""
     b, s, h = x.shape
     n, e = b * s, w_gate.shape[1]
 
@@ -375,10 +412,13 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                       @ router_w.astype(jnp.float32))             # [N, R] fp32
             r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
                            live=live, scoring=scoring, scale=scale,
-                           held=(expert_first, e))
+                           held=(expert_first, e), bias=bias, zero=zero)
             touched = jnp.sum(r.counts[:e] > 0).astype(jnp.int32)
-            picks = jnp.stack([jnp.sum(r.counts[:e]),
-                               jnp.sum(live) * top_k]).astype(jnp.int32)
+            picks = [jnp.sum(r.counts[:e]), jnp.sum(live) * top_k]
+            if zero:
+                picks += [jnp.sum(r.zero_pick & live[:, None]),
+                          jnp.sum(live & jnp.all(r.expert_idx >= e, axis=-1))]
+            picks = jnp.stack(picks).astype(jnp.int32)
         if _split_over_a_mesh(w_gate):
             out = _dropless_experts(
                 flat, r, *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
@@ -387,6 +427,8 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         else:
             out, visits = _grouped_experts(flat, r, live, w_gate, w_up,
                                            w_down, act, layer)
+        if zero:
+            out = add_zero_experts(out, flat, r, live)
         return out, jnp.concatenate([jnp.stack([touched, visits]), picks])
 
     # a prefill batch whose expert-sorted buffer would pass
@@ -426,6 +468,8 @@ def moe_mlp(
     scoring: str = "softmax",
     scale: float = 1.0,
     expert_first: int = 0,
+    bias=None,
+    zero: int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """MoE feed-forward. x: [B, S, H]; router_w: [H, E]; expert banks
     [E_local, H, F] / [E_local, F, H] (E_local = E/ep under expert
@@ -449,6 +493,9 @@ def moe_mlp(
     `num_experts` (router_w [H, R], R > E) is a held share: the banks are
     experts `expert_first` .. `expert_first + E - 1` of the R, dropless
     dispatch only, and a pick elsewhere adds nothing (`route_topk` held).
+    `bias`, `zero`: a selection bias and zero-compute experts, as
+    `moe_mlp_served` describes them (a held share in this sense: the
+    router is wider than the banks).
 
     Recompute contract: every op here is a deterministic function of
     (x, weights) — fp32 router logits, top_k, the slot cumsum, the
@@ -474,7 +521,8 @@ def moe_mlp(
         r = route_topk(logits, top_k, stat_axes=stat_axes,
                        norm_topk_prob=norm_topk_prob, scoring=scoring,
                        scale=scale,
-                       held=(expert_first, e) if share else None)
+                       held=(expert_first, e) if share else None,
+                       bias=bias, zero=zero)
         aux = router_aux_coef * r.aux_loss + router_z_coef * r.z_loss
         load = (jnp.max(r.counts[:e]).astype(jnp.float32)
                 * (e / (n * top_k)))
@@ -482,6 +530,8 @@ def moe_mlp(
     if capacity_factor is None:
         assert ep == 1 and e_local == e, "dropless dispatch needs ep = 1"
         out = _dropless_experts(flat, r, w_gate, w_up, w_down, act)
+        if zero:
+            out = add_zero_experts(out, flat, r)
         # a grouped matmul leaves the rows past its last group zero, so an
         # assignment is dropped exactly when the group sizes fall short
         # (`counts` includes a held share's picks elsewhere: not drops)

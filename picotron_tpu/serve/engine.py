@@ -79,7 +79,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from picotron_tpu.config import ModelConfig, ServeConfig
-from picotron_tpu.generate import _decode_layers, _logits_last
+from picotron_tpu.generate import (
+    _decode_layers, _logits_last, expert_counts,
+)
 from picotron_tpu.resilience import watchdog
 from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, model_rope_tables, served_head,
@@ -220,7 +222,7 @@ def serve_decode(params, pools, tables, toks, positions, rids, tidx,
     to keep emitting EOS — identical semantics to generate.py's scan —
     and the host truncates + retires them at dispatch end. Returns
     (tokens [S, interval], their logits [S, interval] float32, next
-    tokens, next positions, next tidx, expert counts [4], pools); the
+    tokens, next positions, next tidx, expert counts, pools); the
     position/index outputs feed the steady-state fast path straight back
     in, so an unchanged slot roster costs zero host->device uploads
     (measured ~2x the whole dispatch on the CPU tiny-model bench).
@@ -228,7 +230,8 @@ def serve_decode(params, pools, tables, toks, positions, rids, tidx,
     (row tile, expert) pairs the experts' kernel visited, the live slots'
     picks that landed on experts held here and all their picks, each
     summed over the layers and the interval's steps (zeros for a dense
-    model).
+    model); two more where the router has zero-compute experts
+    (`generate.expert_counts` names them all).
     `pools`, `tables`, `cache_cls`: the model's serving cache
     (serve/paged_cache.py `init_serve_cache`) as its class, the tuple of
     its pools (donated, handed back as they are after the writes) and the
@@ -254,7 +257,8 @@ def serve_decode(params, pools, tables, toks, positions, rids, tidx,
     done = jnp.zeros(toks.shape, bool)
     (last, positions, tidx, cache, _, touched), (toks_all, lg_all) = \
         jax.lax.scan(one, (toks, positions, tidx, cache, done,
-                           jnp.zeros((4,), jnp.int32)), None, length=interval)
+                           jnp.zeros((len(expert_counts(cfg)),), jnp.int32)),
+                     None, length=interval)
     return (toks_all.T, lg_all.T, last, positions, tidx, touched,
             cache.pools)
 
@@ -473,6 +477,10 @@ class ServeEngine:
             # over layers) and those that landed on experts held here:
             # equal unless the device holds a share of the experts
             "picks_here": 0, "picks_all": 0,
+            # a router with zero-compute experts: the picks that fell on
+            # them, and the live rows for which no held bank was read
+            # (every pick zero-compute or held elsewhere)
+            "picks_zero": 0, "rows_all_zero_or_away": 0,
         }
         self._init_step_account()
         self._stall_streak = 0  # consecutive ticks: work queued, no decode
@@ -1020,17 +1028,12 @@ class ServeEngine:
             # so the counts ride this span and not the dispatch's
             nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
             if self.cfg.num_experts:
-                touched, visits, here, picks = (int(c) for c in counts)
-                slots = (self.cfg.stacks[-1].layers * interval
-                         * self.cfg.num_experts)
-                self.stats["experts_touched"] += touched
-                self.stats["expert_visits"] += visits
-                self.stats["expert_slots"] += slots
-                self.stats["picks_here"] += here
-                self.stats["picks_all"] += picks
-                sp.set(experts_touched=touched, expert_visits=visits,
-                       expert_slots=slots, picks_here=here,
-                       picks_all=picks)
+                step = dict(zip(expert_counts(self.cfg), map(int, counts)),
+                            expert_slots=(self.cfg.stacks[-1].layers
+                                          * interval * self.cfg.num_experts))
+                for name, n in step.items():
+                    self.stats[name] += n
+                sp.set(**step)
         # feed outputs forward; any roster/table change below
         # nulls this via _sync_table
         self._decode_state = state

@@ -521,10 +521,14 @@ class LatentPagedCache(NamedTuple):
     """The serving cache of a model with latent attention (MLA,
     ops/mla.py): a third kind of state beside the K/V pool and the ring.
     One pool [L, num_blocks, block_size, W] with NO head axis: a position
-    holds `[c | k_r | 0]`, c after its norm and k_r after its rotation,
-    W = `latent_row_width`. Tables, sentinel and `BlockPool` as
-    `PagedKVCache`'s. `generate._decode_layers` calls `write(li, ckr,
-    q_pos)` and `attend(li, q_n, q_r, q_pos, kv_b, cfg)`."""
+    holds `[c | k_r | 0]`, c after its norm (and its scale, where the model
+    has one) and k_r after its rotation, W = `latent_row_width`. L counts
+    attention SUBLAYERS (`cfg.attention_sublayers`): the layers, but two a
+    layer for a model whose layers hold two attentions, each with its own
+    row of the pool. A position costs one block entry whatever L is:
+    tables, sentinel and `BlockPool` as `PagedKVCache`'s.
+    `generate._decode_layers` calls `write(li, ckr, q_pos)` and
+    `attend(li, q_n, q_r, q_pos, kv_b, cfg)`, li the sublayer."""
 
     kv: jnp.ndarray      # [L, num_blocks, block_size, W]
     tables: jnp.ndarray  # [B, max_blocks] int32; num_blocks = unmapped
@@ -610,30 +614,39 @@ class LatentPagedCache(NamedTuple):
     def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
         """`latent_keys`: the key positions the rows' chunks may see (each
         row's cached positions and its chunk, rounded up to the
-        attention's tile), summed over the layers: with the seconds of
+        attention's tile), summed over the pool's rows (`attn_sublayers`: the
+        attention sublayers, each of which walks them): with the seconds of
         `latent_prefill_attention`'s events it gives the kernel's share of
         the matmul peak, at `2 keys rank heads (nope + v) + 2 s keys heads
         (nope + rope + v)` operations a row."""
         tile = latent_prefill_tile(self.block_size, self.tables.shape[1])
-        return dict(latent_keys=self.num_layers * sum(
+        return dict(self._sublayers(cfg), latent_keys=self.num_layers * sum(
             -(-(p + n) // tile) * tile for p, n in spans))
 
     def decode_counts(self, spans, cfg: ModelConfig) -> dict:
         """`kv_blocks`, and `latent_blocks`: the blocks of the latent pool
-        the step's slots hold, summed over the layers, which is what the
-        latent kernel reads."""
+        the step's slots hold, summed over the pool's rows
+        (`attn_sublayers`: one call of the latent kernel each), which is
+        what the kernel reads."""
         kv_blocks = sum(blocks_for(p + 1, self.block_size) for p, _ in spans)
-        return dict(kv_blocks=kv_blocks,
+        return dict(self._sublayers(cfg), kv_blocks=kv_blocks,
                     latent_blocks=self.num_layers * kv_blocks)
+
+    def _sublayers(self, cfg: ModelConfig) -> dict:
+        """`attn_sublayers` on a dispatch's span, where the pool's rows are
+        not the model's layers (two attentions a layer): what the counts
+        beside it were summed over."""
+        return ({} if self.num_layers == cfg.num_hidden_layers
+                else dict(attn_sublayers=self.num_layers))
 
 
 def init_latent_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                       num_slots: int, max_blocks: int) -> LatentPagedCache:
     """Zeroed latent pool + all-unmapped tables: `latent_row_width` numbers
-    a position and layer, of which kv_lora_rank + qk_rope_head_dim are
-    the state."""
+    a position and attention sublayer, of which kv_lora_rank +
+    qk_rope_head_dim are the state."""
     return LatentPagedCache(
-        jnp.zeros((cfg.num_hidden_layers, num_blocks, block_size,
+        jnp.zeros((cfg.attention_sublayers, num_blocks, block_size,
                    latent_row_width(cfg)), compute_dtype(cfg)),
         jnp.full((num_slots, max_blocks), num_blocks, jnp.int32))
 

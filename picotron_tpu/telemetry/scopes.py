@@ -47,6 +47,8 @@ SCOPES = (
     "mla_o",            # latent attention: the output projection
     "attn_latent",      # inside paged_attention, a model with a latent cache: the decode step's latent kernel; prefill's tiles, mask, softmax, PV
     "moe_shared",       # inside mlp: the shared expert's gated MLP
+    "scmoe_branch",     # a layer of two attentions: its shortcut-connected expert branch as a whole (router, routed experts, zero-compute term); NOT under mlp, which is that layer's two dense MLPs
+    "moe_zero",         # inside the expert block: the zero-compute experts' term, the token times the summed gates of its picks of them
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 

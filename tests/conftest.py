@@ -118,3 +118,16 @@ def fresh_programs(monkeypatch):
                 jax.jit(prefill, static_argnames=static))
 
     monkeypatch.setattr(engine, "_get_jits", jits)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs_between_files():
+    """A worker runs whole files one after the other (`--dist loadfile`) and
+    keeps every program it compiled. `tests/test_k_exaone.py` followed by
+    `tests/test_generate.py` in ONE process ends in a segmentation fault
+    inside the CPU backend's compile of a trivial program (the parent of PR
+    47 does the same: which files share a worker is xdist's draw, and a new
+    test file moves it). Dropping the compiled programs when a file ends
+    keeps a worker's process as small as a fresh one's."""
+    yield
+    jax.clear_caches()

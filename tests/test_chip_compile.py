@@ -847,3 +847,85 @@ def test_openpangu_serving_programs(topo, monkeypatch, program, rows):
           ma.temp_size_in_bytes / 2**30)
     assert_weights_read_in_place(text, "openpangu-ultra-moe-5l-ep16",
                                  PANGU_WEIGHTS_WRITTEN.get((program, rows), ()))
+
+
+# What the LongCat programs write that is shaped like a stack's weights
+# (`weights_written`; PANGU_WEIGHTS_WRITTEN's twin). A layer holds two of every
+# attention projection and two dense MLPs on a sublayer axis behind the layer
+# axis, and both sublayers read q_a, q_b, o, gate, up and down where they lie
+# (each by ONE index into the stack read as [2 L, ...]: a layer's [2, ...]
+# slice, shared by its two pairs, was written out every iteration, four
+# `dynamic-slice_bitcast_fusion`s of 100-150 MB). What is left is what openPangu
+# leaves: `kv_b` re-laid once a dispatch with the heads major for the absorbed
+# step's batched matmuls (134 MB), and `kv_a`'s stack (57 MB; the runtime's
+# layout for a minor dimension of 576 is not the matmul's) kept in fast memory
+# by the compiler, which evicts it and fetches a layer's slice inside the loop.
+LONGCAT_WEIGHTS_WRITTEN = {
+    ("serve_decode", None): [(False, "copy", "bf16[4,2,512,16384]"),
+                             (True, "copy-done", "bf16[4,2,6144,576]")],
+    # the prefill kernel's expansion wants kv_b's stack laid out anew as well,
+    # once a dispatch (0.16 ms of a chunk's 35)
+    ("serve_prefill", 1): [(False, "copy", "bf16[4,2,512,16384]")],
+}
+
+
+@pytest.mark.parametrize("program,rows", [("serve_decode", None), ("serve_prefill", 1)])
+def test_longcat_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `longcat-flash-omni-4l-ep32` compile for a v5e
+    and fit it beside the weights; the latent pool has a row an attention
+    sublayer (8 for 4 layers), is never copied whole and is written in place;
+    the one stack's scan body holds both attentions of a layer, so the latent
+    kernel is called twice; no projection or dense MLP of either sublayer is
+    sliced out of its stack or laid out anew; the experts are one grouped
+    kernel a layer; the scopes the cell's metrics read are there, the dense
+    MLPs under `mlp` and the expert branch under `scmoe_branch` and NOT under
+    `mlp`."""
+    config = "longcat-flash-omni-4l-ep32"
+    comp, cache, (pool,) = lower_serve(topo, monkeypatch, config, program, rows)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    assert cache.kv.shape == (8, 16384, 16, 640)
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"kv_write", "paged_attention", "attn_latent", "mla_q",
+                     "mla_kv_latent", "mla_o", "mlp", "scmoe_branch", "moe_zero",
+                     "moe_router", "moe_dispatch", "moe_experts", "sample"}
+    assert "moe_shared" not in found
+    assert ("mla_absorb" in found) == (program == "serve_decode")
+    # the branch is its own region: nothing of it under `mlp`, which is the
+    # two dense MLPs (dense_mlp_ms.serve reads `mlp`). The jitted expert block
+    # (ops/moe.py moe_mlp_served) is lowered once: some of its operations carry
+    # its call site's names and others only those entered inside it, so
+    # scmoe_branch_ms.serve reads the union of the five names
+    branch = set(load("layer_metrics", "scmoe_branch_ms.serve")["params"]["any_scope"])
+    assert branch == {"scmoe_branch", "moe_router", "moe_dispatch", "moe_experts",
+                      "moe_zero"}
+    assert not [op for _, op, _ in ins if "mlp" in words(op) and words(op) & branch]
+    copies = whole_pool_copies(text, cache.kv.shape)
+    assert not copies, f"{program} copies the latent pool {cache.kv.shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert pool in {int(p) for p in re.findall(r"\}: \((\d+), ", alias)}, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "mla_attention_ms.serve")["params"]["ops"])
+    latent = [(n, op) for n, op in kernels if attn.search(n)]
+    grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
+    chunked = [(n, op) for n, op in kernels
+               if n.startswith("latent_prefill_attention")]
+    assert len(grouped) + len(latent) + len(chunked) == len(kernels), kernels
+    assert len(grouped) == 1 and "ragged-dot" not in text
+    assert all({"moe_experts", "scmoe_branch"} <= words(op) for _, op in grouped)
+    if program == "serve_decode":
+        assert len(latent) == 2 and not chunked  # one scan body, two attentions
+        assert all("attn_latent" in words(op) for _, op in latent)
+    else:
+        assert len(chunked) == 2 and not latent
+        assert all({"paged_attention", "attn_latent"} <= words(op) for _, op in chunked)
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15.75 * 2**30, total / 2**30
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)
+    assert_weights_read_in_place(text, config,
+                                 LONGCAT_WEIGHTS_WRITTEN.get((program, rows), ()))

@@ -184,6 +184,10 @@ MLA = {"mla_q", "mla_kv_latent", "mla_absorb", "mla_o", "attn_latent", "moe_shar
 ONE_TABLE = {
     "latent": ("debug-tiny-pangu-moe", SERVE | MOE | MLA, 4),
     "eva": ("debug-tiny-evabyte", SERVE | {"eva_summarise"}, 8),
+    # a layer of two latent attentions with a shortcut-connected expert branch
+    # and zero-compute experts: the branch's own scopes, and no shared expert
+    "shortcut": ("debug-tiny-longcat",
+                 SERVE | MOE | (MLA - {"moe_shared"}) | {"scmoe_branch", "moe_zero"}, 4),
 }
 
 
@@ -195,7 +199,9 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     expert scopes (`benchmark/layer_metrics/mla_*.serve.json` and
     `moe_shared_ms.serve.json` read them). A model with EVA attention: both
     carry `eva_summarise` (`eva_summarise_ms.serve.json` reads it), and its
-    attention is under `paged_attention` like any other's."""
+    attention is under `paged_attention` like any other's. A model whose
+    layers hold two latent attentions and a shortcut-connected expert branch:
+    `scmoe_branch` and `moe_zero` beside them (`scmoe_branch_ms.serve.json`)."""
     preset, want, chunk = ONE_TABLE[model]
     mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
