@@ -17,7 +17,9 @@ discipline the pipeline executor uses for boundary activations.
   slots). Admission is budgeted against THIS pool only.
 - **Decode pool**: `decode_slots` slots over `num_blocks` blocks on
   `decode_device`, running the same decode program via the
-  `ServeEngine._decode_tick` it inherits. Long-prompt bursts
+  `ServeEngine._decode_tick` it inherits, one dispatch ahead as there:
+  a step's handoffs are enqueued behind the decode dispatch in flight
+  and in front of the one the step builds. Long-prompt bursts
   cannot touch it: `bench.py --serve --disagg` measures the max
   consecutive decode-stall ticks collapsing vs colocated.
 - **Handoff**: a jitted block gather on the prefill device ->
@@ -297,7 +299,8 @@ class DisaggServeEngine(ServeEngine):
     def _step(self, now: float) -> bool:
         """Admit into the prefill pool; run ONE batched prefill chunk on
         the prefill placement; hand finished prefixes across the
-        boundary; run ONE decode dispatch on the decode placement.
+        boundary; enqueue ONE decode dispatch on the decode placement,
+        then wait for the one before it (the inherited `_decode_tick`).
         Returns whether any device work ran. (`ServeEngine.step` wraps it
         in the `serve.step` span; the leaf spans are the same, plus
         `serve.handoff`.)"""

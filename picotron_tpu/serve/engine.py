@@ -52,6 +52,15 @@ no live row chose. The decode program returns how many experts its live
 rows touched, which decides the bytes a step needs, and how many (row
 tile, expert) pairs the kernel visited, which is what it read.
 
+The step loop runs ONE DECODE DISPATCH AHEAD (`ServeEngine._decode_tick`):
+a step enqueues decode dispatch n + 1 before it waits for the tokens of
+dispatch n. Each slot's position, token index and blocks after dispatch n
+are arithmetic the host can do without its tokens, and the token itself
+stays on the device (`serve_decode`'s `last`), so the device has its next
+program queued while the host copies tokens back, emits them, admits and
+builds. What only the device knew (an EOS) or what happened meanwhile (a
+cancel, a preemption) costs the rows of one dispatch, dropped at its emit.
+
 Observability rides the existing telemetry machinery: the GoodputLedger
 books queue_wait / prefill / decode (compile time drained out exactly
 via CompileWatch), per-request TTFT and per-token latency land in the
@@ -71,7 +80,6 @@ import logging
 import statistics
 import time
 from collections import deque
-from functools import partial
 from typing import Optional
 
 import jax
@@ -87,7 +95,9 @@ from picotron_tpu.models.llama import (
     compute_dtype, final_hidden, model_rope_tables, served_head,
 )
 from picotron_tpu.serve.paged_cache import BlockPool, init_serve_cache
-from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
+from picotron_tpu.serve.scheduler import (
+    Request, Scheduler, blocks_for, ended,
+)
 from picotron_tpu.telemetry import Telemetry
 from picotron_tpu.telemetry.flightdeck.tracer import TID_SERVE
 from picotron_tpu.telemetry.scopes import scope
@@ -111,22 +121,29 @@ SLOW_STEP_WINDOW, SLOW_STEP_AFTER, SLOW_STEP_LOGS = 64, 16, 32
 # The leaves that enqueue work on the device, and those that wait for it.
 _ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch",
                        "serve.handoff"))
-_WAITS = frozenset(("serve.prefill.wait", "serve.decode.wait"))
+_DECODE_DISPATCH, _DECODE_WAIT = "serve.decode.dispatch", "serve.decode.wait"
+_WAITS = frozenset(("serve.prefill.wait", _DECODE_WAIT))
 
 
-def step_account(leaves, t0: float, wall: float, in_flight: int) -> dict:
+def step_account(leaves, t0: float, wall: float, in_flight=()) -> dict:
     """Where one engine step's wall went, from its own leaf spans.
 
     `leaves` are the step's leaf spans in the order they ran, `(name,
     start, secs)` on the clock of `t0`, the step's start; `wall` is the
     step's seconds so far. The device has work from the start of a
-    `*.dispatch` (or `serve.handoff`) leaf to the end of the next `*.wait`
-    leaf (`_ENQUEUES`, `_WAITS`): a wait fetches the outputs of the
-    program enqueued last, behind whatever was enqueued before it and not
-    waited for, so it clears both. `in_flight` counts the dispatches that
-    the steps before enqueued and nobody waited for; `"in_flight"` of the
-    result is the same for the next, and `waits` lists each wait as `(name,
-    secs, dispatches it cleared)`.
+    `*.dispatch` (or `serve.handoff`) leaf (`_ENQUEUES`) until a `*.wait`
+    leaf (`_WAITS`) has fetched its outputs, or those of something
+    enqueued behind it. A `serve.prefill.wait` fetches the dispatch
+    enqueued last, so it clears all that is in flight. A
+    `serve.decode.wait` fetches the OLDEST decode dispatch nobody has
+    fetched: the engine runs one decode dispatch ahead, so a newer one is
+    usually enqueued behind it and stays in flight, and the emit, the next
+    admit and the next build are fed by it. Where a prefill wait has
+    cleared that dispatch already, the decode wait clears nothing and is a
+    leaf like any other. `in_flight` names the leaves that enqueued what
+    the steps before left in flight, oldest first; `"in_flight"` of the
+    result is the same for the next step, and `waits` lists each wait that
+    cleared something as `(name, secs, dispatches it cleared)`.
 
     `starved_s` is the wall outside those intervals: work pending and
     nothing enqueued, so the device is idle whatever a profiler does to
@@ -134,7 +151,8 @@ def step_account(leaves, t0: float, wall: float, in_flight: int) -> dict:
     between spans), each leaf by the seconds of its own that were starved;
     `unspanned_s` is the wall less the leaves, `leaves` each leaf's
     seconds."""
-    fed = int(in_flight)  # dispatches enqueued and not yet waited for
+    fed = list(in_flight)  # the enqueues nobody waited for, oldest first
+    unfetched = 0  # decode dispatches a prefill wait cleared
     at, spanned = t0, 0.0
     secs_by: dict = {}
     starved_by: dict = {}
@@ -144,21 +162,33 @@ def step_account(leaves, t0: float, wall: float, in_flight: int) -> dict:
             starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
                                        + start - at)
         if name in _ENQUEUES:
-            fed += 1
+            fed.append(name)
         secs_by[name] = secs_by.get(name, 0.0) + secs
         spanned += secs
+        n = 0  # the enqueues this leaf clears, oldest first
+        if name == _DECODE_WAIT:
+            if unfetched:
+                unfetched -= 1
+            elif _DECODE_DISPATCH in fed:
+                n = fed.index(_DECODE_DISPATCH) + 1
+            else:
+                n = len(fed)
+        elif name in _WAITS:
+            n = len(fed)
+            unfetched += fed.count(_DECODE_DISPATCH)
         if not fed:
             starved_by[name] = starved_by.get(name, 0.0) + secs
-        elif name in _WAITS:
-            waits.append((name, secs, fed))
-            fed = 0
+        elif n:
+            waits.append((name, secs, n))
+            del fed[:n]
         at = start + secs
     if not fed and t0 + wall > at:
         starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
                                    + t0 + wall - at)
     return {"wall_s": wall, "starved_s": sum(starved_by.values()),
             "unspanned_s": max(wall - spanned, 0.0), "leaves": secs_by,
-            "starved_by": starved_by, "waits": waits, "in_flight": fed}
+            "starved_by": starved_by, "waits": waits,
+            "in_flight": tuple(fed)}
 
 
 def _ms(secs_by: dict) -> dict:
@@ -210,17 +240,23 @@ def _logit_of(logits, toks):
     return jnp.take_along_axis(logits, toks[:, None], axis=1)[:, 0]
 
 
-def serve_decode(params, pools, tables, toks, positions, rids, tidx,
+def serve_decode(params, pools, tables, toks, last, positions, rids, tidx,
                  base_key, cos, sin, cfg: ModelConfig,
                  temperature: float, top_k: int, interval: int,
                  eos_token_id, cache_cls: type):
     """`interval` decode steps over all slots inside ONE dispatch (a
     lax.scan — amortizes per-dispatch host overhead over interval tokens
     per slot; the same reason offline generate scans its whole decode).
-    toks/positions/rids/tidx: [S]; positions < 0 = idle slot (output
-    ignored, write dropped). Slots that emit EOS mid-interval are forced
+    toks/last/positions/rids/tidx: [S]; positions < 0 = idle slot
+    (output ignored, write dropped). The host enqueues this dispatch
+    before it has read the tokens of the one before, so a slot that
+    continues (`toks` < 0) takes its input token from `last`, that
+    dispatch's `next tokens` output, still on the device; only a slot
+    that joins takes the uploaded `toks`. Slots that emit EOS mid-interval are forced
     to keep emitting EOS — identical semantics to generate.py's scan —
-    and the host truncates + retires them at dispatch end. Returns
+    and the host truncates + retires them when it emits the dispatch,
+    by which time the next one is in flight with that slot still in it:
+    padding, whose rows the host drops. Returns
     (tokens [S, interval], their logits [S, interval] float32, next
     tokens, next positions, next tidx, expert counts, pools); the
     position/index outputs feed the steady-state fast path straight back
@@ -237,6 +273,7 @@ def serve_decode(params, pools, tables, toks, positions, rids, tidx,
     its pools (donated, handed back as they are after the writes) and the
     tuple of its tables."""
     live = positions >= 0
+    toks = jnp.where(toks < 0, last, toks)
 
     def one(carry, _):
         toks, positions, tidx, cache, done, touched = carry
@@ -546,9 +583,23 @@ class ServeEngine:
         """The state behind `step`'s account of its own leaves, and its
         running totals in `stats` (over the steps that had device work)."""
         self.stats.update(step_wall_s=0.0, starved_s=0.0,
-                          step_wall_max_s=0.0, slow_steps=0)
+                          step_wall_max_s=0.0, slow_steps=0,
+                          # of `decode_steps`, those enqueued while the
+                          # dispatch before was in flight (`_decode_tick`)
+                          decode_ahead=0)
         self._leaves: list = []  # this step's (name, start, secs)
-        self._in_flight = 0  # dispatches the last steps left un-waited
+        # the leaves that enqueued what the last steps left un-waited
+        self._in_flight: tuple = ()
+        # the decode dispatch in flight (`_enqueue_decode`'s record), the
+        # number of the next one, and when the last wait for either
+        # program ended
+        self._flying: Optional[dict] = None
+        self._decode_seq = 0
+        self._waited_at = 0.0
+        # `toks` of a dispatch that uploads none: every slot continues
+        self._no_toks = jax.device_put(
+            np.full((self.num_slots,), -1, np.int32), self._rep_sh)
+        self._step_t0 = time.perf_counter()  # when this step started
         self._step_compile_s = 0.0  # compile seconds drained in this step
         self._step_blocks_freed = 0  # blocks its retirements gave back
         self._gc_count, self._gc_before = None, None  # see `step`
@@ -709,17 +760,26 @@ class ServeEngine:
     # -- one engine iteration ---------------------------------------------
 
     def step(self, now: Optional[float] = None) -> bool:
-        """Admit; run ONE prefill chunk (if any prompt is mid-prefill);
-        run ONE decode step over the slot batch; retire. Returns whether
-        any device work ran.
+        """Admit; enqueue ONE prefill chunk (if any prompt is mid-prefill)
+        and wait for it where a prompt ends in it; enqueue ONE decode
+        dispatch over the slot batch; THEN wait for the decode dispatch
+        the step before enqueued, emit its tokens and retire. The engine
+        runs one decode dispatch ahead (`_decode_tick`): the tokens a step
+        returns with are those of the dispatch before the one it enqueued,
+        and the first step after an empty system returns with none. A
+        slot whose request's budget ends in the dispatch in flight is free
+        before the step admits (`_settle_flying`), so its successor rides
+        the dispatch this step enqueues.
+        Returns whether any device work ran (an enqueue or a wait).
 
         The step is one `serve.step` span whose leaf spans say what the
         host was doing (`serve.admit`, `serve.prefill.build | dispatch |
         wait | emit`, `serve.decode.build | dispatch | wait | emit`), each with
         its counts taken at the same boundary: telemetry/spans.py. A step
         with device work ends by adding those leaves up (`_account_step`)."""
+        self._step_t0 = time.perf_counter()
         if now is None:
-            now = time.perf_counter() - self._t0
+            now = self._step_t0 - self._t0
         self._leaves.clear()
         self._step_compile_s = 0.0
         self._step_blocks_freed = 0
@@ -730,6 +790,7 @@ class ServeEngine:
         if count != self._gc_count:
             self._gc_count, self._gc_before = count, gc.get_stats()
         with self.telemetry.span("serve.step", tid=TID_SERVE) as sp:
+            self._settle_flying()
             worked = self._step(now)
             if worked:
                 self._account_step(sp)
@@ -737,7 +798,7 @@ class ServeEngine:
                 # nothing pending (an un-waited prefill's request was
                 # cancelled or shed): what is enqueued runs out unwatched,
                 # and the next step with work starts its account afresh
-                self._in_flight = 0
+                self._in_flight = ()
         return worked
 
     def _account_step(self, sp) -> None:
@@ -899,6 +960,7 @@ class ServeEngine:
             # chunk; otherwise the dispatch is left in flight
             with self._span("serve.prefill.wait", finals=len(finals)):
                 toks, logits = jax.device_get((toks_d, logits_d))
+            self._waited_at = time.perf_counter()
         dt = time.perf_counter() - t0
         csecs = self._drain_compile()
         # the constructor held every rung: a compile here is a shape or
@@ -936,26 +998,107 @@ class ServeEngine:
         return True
 
     def _decode_tick(self, now: float, reg) -> bool:
-        """One decode dispatch over every decode-ready slot. Operates
-        purely through the scheduler's decode interface plus the
-        decode-side device context (self.params/_kv/cos/sin/base_key/
-        _rep_sh), so the disaggregated engine reuses it verbatim against
-        its decode pool. Returns whether a dispatch ran."""
+        """The decode side of one step, ONE DISPATCH AHEAD: enqueue decode
+        dispatch n + 1 over every decode-ready slot, from the host's
+        projection of where dispatch n leaves each (`_enqueue_decode`),
+        and only then wait for dispatch n's tokens and emit them
+        (`_collect_decode`). The device always has a program queued behind
+        the one it runs, so the copy back, the wake, the emit, the caller's
+        reading of the tokens, the next admit and the next build happen
+        while it computes. With nothing in flight (the first dispatch
+        after an empty system) the step enqueues and returns, and the next
+        step is ahead. Operates purely through the scheduler's decode
+        interface plus the decode-side device context (self.params/_kv/
+        cos/sin/base_key/_rep_sh), so the disaggregated engine reuses it
+        verbatim against its decode pool. Returns whether a dispatch was
+        enqueued or waited for."""
+        flying = self._flying
+        self._flying = self._enqueue_decode(flying)
+        if flying is not None:
+            self._collect_decode(flying, now, reg)
+        return flying is not None or self._flying is not None
+
+    def _rows_in_flight(self, flying) -> dict:
+        """{slot: its request} for the rows of the dispatch in flight that
+        still stand: the slot holds the request the dispatch was built
+        for, where it was built. A request that was cancelled, shed,
+        preempted, displaced or retired since (an EOS inside the dispatch
+        before, which only the device knew) has no row: what the dispatch
+        computes for it is padding. Nor has one that `_settle_flying`
+        released: its tokens are kept, and its slot is another's."""
+        if flying is None:
+            return {}
+        return {s: st for s, st, n in flying["rows"]
+                if self.sched.slots[s] is st and len(st.generated) == n}
+
+    def _settle_flying(self) -> None:
+        """What a step knows of the dispatch in flight before it admits.
+        A row whose budget ends inside it needs its slot and its blocks
+        for nothing more, whatever its tokens are: both are given back
+        now (`released`), so that this step's admission can hand them on
+        and the successor rides the dispatch this step enqueues, as it
+        would behind a wait; whatever writes those blocks next is enqueued
+        behind the dispatch in flight, on the same stream. The request's
+        tokens are emitted at this step's end (`_collect_decode`), so it
+        is out of the scheduler for no longer than the step. A dispatch of
+        which no row stands (its requests were cancelled, or retired on an
+        EOS inside the dispatch before) is forgotten: nobody waits for it,
+        and the next one is not ahead of anything."""
+        flying = self._flying
+        if flying is None:
+            return
+        rows = self._rows_in_flight(flying)
+        if not rows:
+            self._flying = None
+            self._in_flight = tuple(x for x in self._in_flight
+                                    if x != _DECODE_DISPATCH)
+            return
+        ending = [s for s, st in rows.items()
+                  if st.req.max_new_tokens - len(st.generated)
+                  <= self.scfg.decode_interval]
+        if not ending:
+            return
+        # the half of the emit that needs no token, under the emit's name:
+        # its counts add up with those of the span behind the wait
+        with self._span("serve.decode.emit") as sp:
+            n_freed = sum(rows[s].held_blocks for s in ending)
+            for s in ending:
+                flying["released"][s] = self.sched.retire(s)
+                self._sync_table(s)
+            sp.set(tokens=0, retired=len(ending), blocks_freed=n_freed,
+                   dropped=0)
+        self._step_blocks_freed += n_freed
+
+    def _enqueue_decode(self, flying) -> Optional[dict]:
+        """Build and enqueue one decode dispatch behind `flying`, the one
+        in flight (None: none is), without its tokens. What the host can
+        project it does: a slot with a row in flight stands `interval`
+        tokens further (its position, its token index, the blocks it
+        needs, what the step reads of the cache); one whose budget ends
+        inside the dispatch in flight has left its slot already
+        (`_settle_flying`). What it cannot project is the token, which
+        the program takes from the device (`serve_decode`: `last`).
+        Returns the record `_collect_decode` reads, None where nothing
+        was enqueued."""
         ready = self.sched.decode_ready()
         if not ready:
-            return False
+            return None
         interval = self.scfg.decode_interval
+        rows = self._rows_in_flight(flying)
         with self._span("serve.decode.build") as sp:
-            active = []
+            active, ahead_of, left_of = [], {}, {}
             dropped: set = set()
             for s in ready:
                 if s in dropped:
                     continue
                 st = self.sched.slots[s]
-                horizon = min(interval,
-                              st.req.max_new_tokens - len(st.generated))
+                # the tokens in flight for it, and its budget after them
+                # (some: `_settle_flying`)
+                ahead = interval if s in rows else 0
+                left = st.req.max_new_tokens - len(st.generated) - ahead
                 n_before = st.held_blocks
-                ok, preempted = self.sched.ensure_block(s, horizon)
+                ok, preempted = self.sched.ensure_block(
+                    s, ahead + min(interval, left))
                 dropped.update(preempted)
                 for p in preempted:
                     self._sync_table(p)
@@ -963,11 +1106,13 @@ class ServeEngine:
                     if st.held_blocks != n_before:
                         self._sync_table(s)
                     active.append(s)
+                    ahead_of[s], left_of[s] = ahead, min(interval, left)
             # a later ensure_block can preempt a slot already activated
             # (it was younger than the one needing the block)
             active = [s for s in active if s not in dropped]
             ds = self._decode_state
-            rebuilt = bool(active) and (ds is None or ds["active"] != active)
+            rebuilt = bool(active) and (flying is None or ds is None
+                                        or ds["active"] != active)
             if rebuilt:
                 # slow path: roster changed — rebuild inputs on host,
                 # uploaded with the shardings earlier calls produced
@@ -978,55 +1123,85 @@ class ServeEngine:
                 tidx = np.zeros((self.num_slots,), np.int32)
                 for s in active:
                     st = self.sched.slots[s]
-                    toks[s] = st.last_token
-                    positions[s] = st.write_pos
+                    positions[s] = st.write_pos + ahead_of[s]
                     rids[s] = st.req.id
-                    tidx[s] = len(st.generated)
-                up = partial(jax.device_put, device=self._rep_sh)
-                ds = {"active": list(active),
-                      "tables": up(self._tables),
-                      "toks": up(toks),
-                      "positions": up(positions),
-                      "rids": up(rids),
-                      "tidx": up(tidx)}
+                    tidx[s] = len(st.generated) + ahead_of[s]
+                    # it joins, its token is here; or it continues (-1)
+                    toks[s] = -1 if ahead_of[s] else st.last_token
+                # the tables as they stand, in a copy: the mirrors change
+                # (`_sync_table`) while this dispatch is in flight, and an
+                # upload may read its source until the program has run
+                ds = dict(zip(
+                    ("tables", "toks", "positions", "rids", "tidx"),
+                    jax.device_put((tuple(t.copy() for t in self._tables),
+                                    toks, positions, rids, tidx),
+                                   self._rep_sh)),
+                    active=list(active))
             sp.set(rebuilt=int(rebuilt), preempted=len(dropped))
         if not active:
-            return False
+            return None
         self._drain_compile()
         if watchdog.active():
             watchdog.touch(
                 f"serve engine={self.engine_id} dispatch=decode")
-        # Request ids snapshotted before the retire loop below frees
-        # slots — they tag the dispatch span and the decode phase event
-        # with the requests it advanced.
-        dec_ids = [self.sched.slots[s].req.id for s in active]
-        t0 = time.perf_counter()
-        # what the step reads of the cache at the dispatch's first token
+        # the requests the dispatch advances, where it finds each: they
+        # tag the dispatch span and the decode phase event, and say at the
+        # emit which rows still stand
+        live = [self.sched.slots[s] for s in active]
+        dec_ids = [st.req.id for st in live]
+        # what the step reads of the cache at THIS dispatch's first token
         # (`kv_blocks` and the counts of the cache's kind), and
         # `view_blocks`: what the gathered view spans, whatever is live
-        live = [self.sched.slots[s] for s in active]
         read = self.cache.decode_counts(
-            [(st.write_pos,
-              min(interval, st.req.max_new_tokens - len(st.generated)))
-             for st in live], self.cfg)
+            [(st.write_pos + ahead_of[s], left_of[s])
+             for s, st in zip(active, live)], self.cfg)
+        seq = self._decode_seq
+        self._decode_seq += 1
+        t0 = time.perf_counter()
         with self._span("serve.decode.dispatch", active=len(active),
                         interval=interval,
                         view_blocks=self.num_slots * self.max_blocks,
-                        ids=join_ids(dec_ids), **read):
+                        ids=join_ids(dec_ids), seq=seq,
+                        ahead=int(flying is not None), dispatched=1, **read):
             (toks_d, lg_d, last_d, pos_d, tidx_d, touched_d,
              self._kv) = self._decode_jit(
                 self.params, self._kv, ds["tables"], ds["toks"],
+                # with nothing in flight no slot continues: read for none
+                ds["toks"] if flying is None else flying["last"],
                 ds["positions"], ds["rids"], ds["tidx"], self.base_key,
                 self.cos, self.sin, cfg=self.cfg,
                 temperature=self.temperature, top_k=self.top_k,
                 interval=interval, eos_token_id=self.eos_token_id,
                 cache_cls=type(self.cache))
-            state = dict(ds, toks=last_d, positions=pos_d, tidx=tidx_d)
-        with self._span("serve.decode.wait") as sp:
+        csecs = self._drain_compile()
+        if csecs:
+            self.stats["decode_compiles"] += 1
+        # feed outputs forward: the next dispatch's inputs while the
+        # roster stands; any roster/table change nulls this via _sync_table
+        self._decode_state = dict(ds, toks=self._no_toks, positions=pos_d,
+                                  tidx=tidx_d)
+        return {"seq": seq, "ahead": flying is not None, "ids": dec_ids,
+                "rows": [(s, st, len(st.generated) + ahead_of[s])
+                         for s, st in zip(active, live)],
+                "out": (toks_d, lg_d, touched_d), "last": last_d,
+                "t0": t0, "compile_s": csecs,
+                # {slot: the request `_settle_flying` released}
+                "released": {}}
+
+    def _collect_decode(self, flying, now: float, reg) -> None:
+        """Wait for the tokens of `flying`, the oldest decode dispatch in
+        flight, and emit them: each row that still stands
+        (`_rows_in_flight`) or was released (`_settle_flying`) gets its
+        tokens up to its EOS or its budget and retires there; the others'
+        are dropped, and regenerated alike under the (request id, token
+        index) key where the request lives on. A retirement is stamped
+        when the host has the token."""
+        interval = self.scfg.decode_interval
+        with self._span("serve.decode.wait", seq=flying["seq"]) as sp:
             # tokens and their logits [S, interval], and the experts the
             # steps touched and visited: known once the dispatch has run,
             # so the counts ride this span and not the dispatch's
-            nxt, lgs, counts = jax.device_get((toks_d, lg_d, touched_d))
+            nxt, lgs, counts = jax.device_get(flying["out"])
             if self.cfg.num_experts:
                 step = dict(zip(expert_counts(self.cfg), map(int, counts)),
                             expert_slots=(self.cfg.stacks[-1].layers
@@ -1034,43 +1209,55 @@ class ServeEngine:
                 for name, n in step.items():
                     self.stats[name] += n
                 sp.set(**step)
-        # feed outputs forward; any roster/table change below
-        # nulls this via _sync_table
-        self._decode_state = state
-        dt = time.perf_counter() - t0
-        csecs = self._drain_compile()
-        if csecs:
-            self.stats["decode_compiles"] += 1
-        dt -= min(csecs, dt)
-        n_tokens = n_retired = n_freed = 0
+        t_end = time.perf_counter()
+        # the dispatch's seconds as the host saw them pass: to the end of
+        # its wait from its enqueue, or, where it was enqueued ahead of
+        # that, from the end of the wait before (the decode dispatch's
+        # before it, or a prompt's last chunk's, whose seconds are the
+        # prefill's): a steady state reads the step's period, and no second
+        # is booked to two phases
+        dt = t_end - max(flying["t0"], self._waited_at)
+        dt -= min(flying["compile_s"], dt)
+        self._waited_at = t_end
+        t_done = now + t_end - self._step_t0
+        rows, released = self._rows_in_flight(flying), flying["released"]
+        n_tokens = n_retired = n_freed = n_dropped = 0
         with self._span("serve.decode.emit") as sp:
-            for s in active:
-                st = self.sched.slots[s]
+            for s, st, _ in flying["rows"]:
+                if s not in rows and s not in released:
+                    n_dropped += 1
+                    continue
                 for t in range(interval):
                     st.generated.append(int(nxt[s, t]))
                     st.logits.append(float(lgs[s, t]))
                     n_tokens += 1
-                    if self.sched.should_retire(s, self.eos_token_id):
+                    if ended(st, self.eos_token_id):
                         # tokens past EOS/budget are padding
-                        n_freed += st.held_blocks
-                        rst = self.sched.retire(s)
-                        self._sync_table(s)
-                        self._emit_retired(rst, now + dt)
-                        n_retired += 1
+                        if s in rows:  # an EOS: nobody knew
+                            n_freed += st.held_blocks
+                            self.sched.retire(s)
+                            self._sync_table(s)
+                            n_retired += 1
+                        self._emit_retired(st, t_done)
                         break
-            # `blocks_freed`: what the retirements gave back to the pools,
-            # the size of the one thing here that grows with a request
-            sp.set(tokens=n_tokens, retired=n_retired, blocks_freed=n_freed)
+            # `retired`, `blocks_freed`: the retirements here and what they
+            # gave back to the pools, the size of the one thing here that
+            # grows with a request (a budget's end gave its own back at
+            # the step's start, `_settle_flying`); `dropped`: rows whose
+            # request left while they were in flight
+            sp.set(tokens=n_tokens, retired=n_retired, blocks_freed=n_freed,
+                   dropped=n_dropped)
         self._step_blocks_freed += n_freed
         self.telemetry.emit("phase", phase="decode",
                             category="decode", secs=dt,
-                            tokens=n_tokens, ids=dec_ids)
+                            tokens=n_tokens, ids=flying["ids"])
+        n_rows = len(flying["rows"])
         reg.histogram("serve/token_latency").observe(
-            dt / max(len(active) * interval, 1))
+            dt / max(n_rows * interval, 1))
         self.stats["decode_steps"] += 1
-        self.stats["occupancy_sum"] += len(active) / self.num_slots
+        self.stats["decode_ahead"] += flying["ahead"]
+        self.stats["occupancy_sum"] += n_rows / self.num_slots
         self.stats["output_tokens"] += n_tokens
-        return True
 
     # -- trace driver ------------------------------------------------------
 
@@ -1146,6 +1333,10 @@ class ServeEngine:
             "expert_visits": self.stats.get("expert_visits", 0),
             "expert_slots": self.stats.get("expert_slots", 0),
             "decode_steps": self.stats["decode_steps"],
+            # of them, the share enqueued while the dispatch before was in
+            # flight: how often the device had its next program queued
+            "decode_ahead_share": round(
+                self.stats["decode_ahead"] / steps, 4),
             "decode_compiles": self.stats["decode_compiles"],
             "prefill_compiles": self.stats["prefill_compiles"],
             "prefill_chunks": self.stats["prefill_chunks"],
@@ -1169,5 +1360,6 @@ class ServeEngine:
         }
 
     def close(self) -> None:
+        self._flying = None  # a dispatch of padding nobody waited for
         if self._owns_telemetry:
             self.telemetry.close()

@@ -221,6 +221,8 @@ class FleetSupervisor:
             st.blocks = []
             st.n_prefilled = 0
             st.prefill_ids = ()
+        # the decode dispatch in flight, if any, finds none of its
+        # requests in their slots when it is emitted: its rows are dropped
         eng._decode_state = None
         return sts
 

@@ -121,6 +121,14 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
     return -(-n_tokens // block_size)
 
 
+def ended(st: RequestState, eos_token_id: Optional[int]) -> bool:
+    """Whether a request's newest token is its last: its budget is spent,
+    or the token is the EOS."""
+    return (len(st.generated) >= st.req.max_new_tokens
+            or (eos_token_id is not None
+                and st.last_token == eos_token_id))
+
+
 class Scheduler:
     def __init__(self, num_slots: int, pool, block_size: int,
                  max_blocks: int, window_pool=None, ring_blocks: int = 0,
@@ -366,10 +374,7 @@ class Scheduler:
     # -- retirement --------------------------------------------------------
 
     def should_retire(self, slot: int, eos_token_id: Optional[int]) -> bool:
-        st = self.slots[slot]
-        return (len(st.generated) >= st.req.max_new_tokens
-                or (eos_token_id is not None
-                    and st.last_token == eos_token_id))
+        return ended(self.slots[slot], eos_token_id)
 
     def retire(self, slot: int) -> RequestState:
         st = self.slots[slot]
@@ -643,10 +648,8 @@ class DisaggScheduler:
 
     def should_retire(self, slot: int, eos_token_id: Optional[int],
                       pslot: bool = False) -> bool:
-        st = (self.pslots if pslot else self.slots)[slot]
-        return (len(st.generated) >= st.req.max_new_tokens
-                or (eos_token_id is not None
-                    and st.last_token == eos_token_id))
+        return ended((self.pslots if pslot else self.slots)[slot],
+                     eos_token_id)
 
     def retire(self, slot: int) -> RequestState:
         st = self.slots[slot]

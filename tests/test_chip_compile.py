@@ -346,8 +346,9 @@ def lower_serve(topo, monkeypatch, config: str, program: str, rows=None):
             i32(rows), key, cos, sin, **static)
     else:
         low = decode.lower(
-            *head, i32(slots), i32(slots), i32(slots), i32(slots), key, cos,
-            sin, interval=sc.decode_interval, eos_token_id=None, **static)
+            *head, i32(slots), i32(slots), i32(slots), i32(slots), i32(slots),
+            key, cos, sin, interval=sc.decode_interval, eos_token_id=None,
+            **static)
     n = len(jax.tree.leaves(params))
     out = _LOWERED_SERVE[config, program, rows] = (
         low.compile(), cache, set(range(n, n + len(cache.pools))))

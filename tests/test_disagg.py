@@ -218,6 +218,53 @@ def test_handoff_preempts_only_strictly_younger():
     assert sched.n_preempted == 1
 
 
+def test_handoff_behind_a_decode_dispatch_in_flight_keeps_parity(
+        tiny, requests5, offline_refs):
+    """The inherited decode tick runs one dispatch ahead: a handoff's copy
+    is enqueued while decode dispatch n is in flight and nobody has waited
+    for it, dispatch n + 1 is enqueued behind the copy with the request
+    that crossed in it, and only then does the host wait for dispatch n.
+    Tokens are the offline sampler's, with and without decode-pool
+    preemption (a handoff may take the blocks of a resident in flight)."""
+    from picotron_tpu.telemetry import Telemetry
+    from picotron_tpu.telemetry.flightdeck import SpanTracer
+
+    cfg, params = tiny
+    for num_blocks in (24, 7):
+        tel = Telemetry(sinks=[])
+        tel.tracer = SpanTracer()
+        eng, res = run_disagg(params, cfg, scfg(num_blocks=num_blocks),
+                              requests5, telemetry=tel)
+        by_id = tokens_by_id(res)
+        for i, ref in enumerate(offline_refs):
+            assert by_id[i] == ref, (num_blocks, i)
+        names = ("serve.handoff", "serve.decode.dispatch", "serve.decode.wait")
+        spans = sorted((e for e in tel.tracer.to_json()["traceEvents"]
+                        if e["ph"] == "X" and e["name"] in names),
+                       key=lambda e: e["ts"])
+        in_flight, crossed_behind = [], 0
+        for i, e in enumerate(spans):
+            a = e["args"]
+            if e["name"] == "serve.decode.wait":
+                assert in_flight.pop(0) == a["seq"]
+            elif e["name"] == "serve.decode.dispatch":
+                in_flight.append(a["seq"])
+            elif in_flight:
+                # a copy behind dispatch n: the next decode leaf is
+                # dispatch n + 1, with the request in it, before wait n
+                nxt = next(x for x in spans[i + 1:]
+                           if x["name"] != "serve.handoff")
+                assert nxt["name"] == "serve.decode.dispatch"
+                # (where blocks are short the build may preempt it again)
+                assert (str(a["id"]) in nxt["args"]["ids"].split()
+                        or num_blocks == 7)
+                crossed_behind += 1
+        assert crossed_behind > 0 and not in_flight
+        assert eng.stats["decode_ahead"] > 0
+        assert eng.pool.in_use == 0 and eng.pool_p.in_use == 0
+        assert (eng.sched.n_preempted > 0) == (num_blocks == 7)
+
+
 # ---------------------------------------------------------------------------
 # compile discipline: each pool program compiles exactly once
 # ---------------------------------------------------------------------------

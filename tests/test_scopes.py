@@ -145,7 +145,7 @@ def lowered_serve(e, program: str) -> str:
             *head, jnp.zeros((s, e.scfg.prefill_chunk), jnp.int32), vec, vec,
             vec, vec, *tail, **common)
     else:
-        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, *tail,
+        lowered = e._decode_jit.lower(*head, vec, vec, vec, vec, vec, *tail,
                                       interval=2, eos_token_id=None, **common)
     text = lowered.as_text(debug_info=True)
     assert module_name(text) == f"jit_{program}"

@@ -1058,32 +1058,35 @@ def _leaves(t0, *spec):
     return out, at - t0
 
 
+PD, DD = "serve.prefill.dispatch", "serve.decode.dispatch"
+
 ACCOUNT_CASES = {
-    # name: (in flight at the start, leaves, tail ms, starved ms by leaf,
+    # name: (the enqueues in flight at the start, oldest first, leaves,
+    #        tail ms, starved ms by leaf,
     #        ms with work enqueued: from the start of a dispatch or handoff
-    #        to the end of the next wait, added up by hand,
+    #        to the end of the wait that clears it, added up by hand,
     #        the dispatches each wait cleared, in flight at the end)
     "decode_only": (
-        0,
+        (),
         [("serve.admit", 0.5, 1), ("serve.decode.build", 0, 2),
          ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 20),
          ("serve.decode.emit", 0, 4)],
         1.5,
         {"unspanned": 2.0, "serve.admit": 1, "serve.decode.build": 2,
-         "serve.decode.emit": 4}, 3 + 20, [1], 0),
+         "serve.decode.emit": 4}, 3 + 20, [1], ()),
     # the decode's build runs behind a prefill nobody waited for: fed
     "prefill_unwaited_then_decode": (
-        0,
+        (),
         [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
          ("serve.prefill.dispatch", 0, 3), ("serve.decode.build", 1, 2),
          ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 30),
          ("serve.decode.emit", 0.5, 4)],
         0,
         {"serve.admit": 1, "serve.prefill.build": 2, "unspanned": 0.5,
-         "serve.decode.emit": 4}, 3 + 1 + 2 + 3 + 30, [2], 0),
+         "serve.decode.emit": 4}, 3 + 1 + 2 + 3 + 30, [2], ()),
     # the prefill was waited for: the device is idle under the build
     "prefill_waited_then_decode": (
-        0,
+        (),
         [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
          ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 10),
          ("serve.decode.build", 1, 2), ("serve.decode.dispatch", 0, 3),
@@ -1091,31 +1094,80 @@ ACCOUNT_CASES = {
         0,
         {"serve.admit": 1, "serve.prefill.build": 2, "unspanned": 1,
          "serve.decode.build": 2, "serve.decode.emit": 4},
-        3 + 10 + 3 + 20, [1, 1], 0),
+        3 + 10 + 3 + 20, [1, 1], ()),
     "ends_in_flight": (
-        0,
+        (),
         [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
          ("serve.prefill.dispatch", 0, 3), ("serve.decode.build", 0, 1)],
         2,
-        {"serve.admit": 1, "serve.prefill.build": 2}, 3 + 1 + 2, [], 1),
+        {"serve.admit": 1, "serve.prefill.build": 2}, 3 + 1 + 2, [], (PD,)),
     # ... and the third step after it: fed until its first wait, which
     # clears the three chunks before it and its own
     "starts_in_flight": (
-        3,
+        (PD,) * 3,
         [("serve.admit", 1, 1), ("serve.prefill.build", 0, 2),
          ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 9),
          ("serve.decode.build", 0, 2)],
         1,
-        {"serve.decode.build": 2, "unspanned": 1}, 1 + 1 + 2 + 3 + 9, [4], 0),
+        {"serve.decode.build": 2, "unspanned": 1}, 1 + 1 + 2 + 3 + 9, [4],
+        ()),
     "handoff": (
-        0,
+        (),
         [("serve.admit", 0, 1), ("serve.handoff", 0, 2),
          ("serve.handoff", 0.5, 2), ("serve.decode.build", 0, 1),
          ("serve.decode.dispatch", 0, 1), ("serve.decode.wait", 0, 8),
          ("serve.decode.emit", 0, 2)],
         0,
         {"serve.admit": 1, "serve.decode.emit": 2},
-        2 + 0.5 + 2 + 1 + 1 + 8, [3], 0),
+        2 + 0.5 + 2 + 1 + 1 + 8, [3], ()),
+    # ---- one decode dispatch ahead (PR 48): dispatch n + 1, then wait n
+    # the first dispatch after an empty system: enqueued, not waited for
+    "ahead_first": (
+        (),
+        [("serve.admit", 0, 1), ("serve.decode.build", 0.5, 2),
+         ("serve.decode.dispatch", 0, 3)],
+        1,
+        {"serve.admit": 1, "unspanned": 0.5, "serve.decode.build": 2},
+        3 + 1, [], (DD,)),
+    # the steady state: the wait clears the older of two dispatches, the
+    # newer one stays in flight, and nothing after the wait is starved
+    "ahead_steady": (
+        (DD,),
+        [("serve.admit", 0.5, 1), ("serve.decode.build", 0, 2),
+         ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 20),
+         ("serve.decode.emit", 0.5, 4)],
+        1.5, {}, 0.5 + 1 + 2 + 3 + 20 + 0.5 + 4 + 1.5, [1], (DD,)),
+    # ... behind a prefill chunk nobody waited for: the wait clears the
+    # chunk of the step before and the dispatch behind it, not this step's
+    "ahead_behind_unwaited_prefill": (
+        (PD, DD),
+        [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.decode.build", 0, 2),
+         ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 20),
+         ("serve.decode.emit", 0, 4)],
+        1, {}, 1 + 2 + 3 + 2 + 3 + 20 + 4 + 1, [2], (PD, DD)),
+    # a prompt ends in the step: its wait clears the decode dispatch in
+    # flight too, the device is idle under the prefill's emit and the
+    # build, and the decode wait, which finds its tokens there, clears
+    # nothing: the newer dispatch stays in flight under it and the emit
+    "ahead_prefill_waited": (
+        (DD,),
+        [("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+         ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 10),
+         ("serve.prefill.emit", 0, 1), ("serve.decode.build", 0.5, 2),
+         ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 0.5),
+         ("serve.decode.emit", 0, 4)],
+        0,
+        {"serve.prefill.emit": 1, "unspanned": 0.5, "serve.decode.build": 2},
+        1 + 2 + 3 + 10 + 3 + 0.5 + 4, [2], (DD,)),
+    # the last dispatch of a run: nothing is enqueued behind it, and the
+    # emit after its wait is starved as it always was
+    "ahead_last": (
+        (DD,),
+        [("serve.admit", 0, 1), ("serve.decode.build", 0, 2),
+         ("serve.decode.wait", 0, 20), ("serve.decode.emit", 0, 4)],
+        1,
+        {"serve.decode.emit": 4, "unspanned": 1}, 1 + 2 + 20, [1], ()),
 }
 
 
@@ -1129,6 +1181,7 @@ def test_step_account_on_hand_made_leaves(case):
     wall = spanned_to + tail * MS
     a = step_account(leaves, t0, wall, in_flight)
     assert a["wall_s"] == wall and a["in_flight"] == ends
+    assert isinstance(a["in_flight"], tuple)
     assert [n for _, _, n in a["waits"]] == cleared
     assert all(secs == a["leaves"][name] for name, secs, _ in a["waits"])
     assert {k: round(v / MS, 6) for k, v in a["starved_by"].items()} == {
@@ -1248,7 +1301,9 @@ def test_slow_step_makes_one_event_and_one_log_line(tiny, monkeypatch, caplog):
 def test_a_slow_retirement_says_what_it_gave_back(tiny, monkeypatch, caplog):
     """A step the host holds under the emit (a pool that takes its time
     over a retirement, as the free list's scan did): the event is held by
-    `host`, names the emit, and carries the blocks the step gave back."""
+    `host`, names the emit, and carries the blocks the step gave back. The
+    other request's next dispatch was enqueued before the emit, so none of
+    its seconds are starved."""
     import time as _time
 
     from picotron_tpu.serve import engine as engine_mod
@@ -1273,7 +1328,9 @@ def test_a_slow_retirement_says_what_it_gave_back(tiny, monkeypatch, caplog):
     (e,) = [e for e in cap.events if e["kind"] == "serve_slow_step"]
     assert e["held_by"] == "host" and e["held_for"] == 1
     assert max(e["leaves_ms"], key=e["leaves_ms"].get) == "serve.decode.emit"
-    assert e["starved_by_ms"]["serve.decode.emit"] >= engine_mod.SLOW_STEP_S * 1e3
+    assert e["leaves_ms"]["serve.decode.emit"] >= engine_mod.SLOW_STEP_S * 1e3
+    assert "serve.decode.emit" not in e["starved_by_ms"]
+    assert e["starved_s"] < 0.1 and e["active"] == 1
     # 6 prompt + 20 new tokens, the last never written, in blocks of 4
     assert e["blocks_freed"] == blocks_for(6 + 20 - 1, 4) == 7
     (record,) = [r for r in caplog.records if r.name == "picotron_tpu.serve"]
@@ -1328,7 +1385,8 @@ def test_a_wait_is_slow_among_its_own_kind(tiny, monkeypatch, caplog,
 
 def test_a_slow_host_is_told_from_a_slow_wait(tiny, monkeypatch, caplog):
     """The wall less the waits is judged among the same of other steps: an
-    admission that sleeps is `held_by` the host, under the leaf it ran in."""
+    admission that sleeps is `held_by` the host, under the leaf it ran in,
+    and fed by the decode dispatch in flight, not starved."""
     import time as _time
 
     from picotron_tpu.serve import engine as engine_mod
@@ -1354,7 +1412,8 @@ def test_a_slow_host_is_told_from_a_slow_wait(tiny, monkeypatch, caplog):
             eng.step(0.0)
     (e,) = [e for e in cap.events if e["kind"] == "serve_slow_step"]
     assert e["held_by"] == "host" and e["held_s"] > e["limit_s"]
-    assert e["starved_by_ms"]["serve.admit"] >= engine_mod.SLOW_STEP_S * 1e3
+    assert e["leaves_ms"]["serve.admit"] >= engine_mod.SLOW_STEP_S * 1e3
+    assert "serve.admit" not in e["starved_by_ms"] and e["starved_s"] < 0.1
     (record,) = caplog.records
     assert "slow step: host" in record.getMessage()
     assert "longest leaf serve.admit" in record.getMessage()
@@ -1388,3 +1447,207 @@ def test_an_idle_poll_is_no_step_and_ends_what_was_in_flight(tiny):
     assert last["secs"] > 0.0  # its admit and build were starved
     assert eng.stats["starved_s"] <= eng.stats["step_wall_s"]
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# one decode dispatch ahead (PR 48): dispatch n + 1 is enqueued before the
+# host waits for dispatch n's tokens
+# ---------------------------------------------------------------------------
+
+
+def decode_leaves(tel):
+    """The decode dispatch, wait and emit spans in the order they began, as
+    (the leaf's last name, its counts)."""
+    names = {f"serve.decode.{n}": n for n in ("dispatch", "wait", "emit")}
+    spans = sorted((e for e in tel.tracer.to_json()["traceEvents"]
+                    if e["ph"] == "X" and e["name"] in names),
+                   key=lambda e: e["ts"])
+    return [(names[e["name"]], e["args"]) for e in spans]
+
+
+@pytest.mark.parametrize("num_blocks", [24, 8], ids=["roomy", "preempting"])
+def test_every_decode_dispatch_but_a_restart_is_enqueued_ahead(
+        tiny, requests5, offline_refs, num_blocks):
+    """The leaf spans of a multi-request trace: dispatches are numbered in
+    order and waited for in order, each once; every dispatch is enqueued
+    before the wait for the one before it began, but the first after the
+    engine held none in flight (`ahead=0`: a restart); the stats count the
+    same, and the tokens are the offline sampler's."""
+    eng, tel = traced_engine(tiny, num_blocks=num_blocks)
+    res = eng.run(requests5)
+    eng.close()
+    for r, ref in zip(res, offline_refs):
+        assert r["tokens"] == ref
+    leaves = decode_leaves(tel)
+    n = eng.stats["decode_steps"]
+    for kind in ("dispatch", "wait"):
+        assert [a["seq"] for k, a in leaves if k == kind] == list(range(n))
+    in_flight, restarts = [], 0
+    for kind, a in leaves:
+        if kind == "wait":
+            assert in_flight.pop(0) == a["seq"]  # the oldest, never the newest
+        elif kind == "dispatch":
+            assert a["ahead"] == bool(in_flight) and a["dispatched"] == 1
+            restarts += not in_flight
+            in_flight.append(a["seq"])
+            assert len(in_flight) <= 2
+    assert not in_flight and 1 <= restarts < n
+    assert eng.stats["decode_ahead"] == n - restarts
+    assert eng.summary["decode_ahead_share"] == round((n - restarts) / n, 4)
+    # no row was dropped unless a request left while it was in flight
+    dropped = sum(a["dropped"] for kind, a in leaves if kind == "emit")
+    assert (dropped > 0) == (eng.sched.n_preempted > 0) == (num_blocks == 8)
+    assert eng.pool.in_use == 0
+
+
+@pytest.mark.parametrize("leaves_by",
+                         ["cancel", "cancel_alone", "preempt", "eos"])
+def test_a_request_that_leaves_while_its_next_dispatch_is_in_flight(
+        tiny, requests5, offline_refs, leaves_by):
+    """A request cancelled, preempted or ended by an EOS while a dispatch
+    built for it is in flight: no token of that dispatch reaches its
+    `generated`, the emit counts the row as dropped, no block leaks, and
+    the neighbours' tokens are what they are alone. A dispatch whose only
+    row was cancelled (`cancel_alone`) is forgotten: nobody waits for it,
+    it is no decode step, and the next dispatch is ahead of nothing."""
+    cfg, params = tiny
+    eos, refs = None, list(offline_refs)
+    if leaves_by == "eos":
+        # request 2's third token ends it inside its first dispatch, before
+        # the host has seen it: the second is built with it still in
+        eos = refs[2][2]
+        assert eos not in refs[0] and eos not in refs[2][:2]
+        refs = [r[:r.index(eos) + 1] if eos in r else r for r in refs]
+    eng, tel = traced_engine(
+        tiny, **({"num_blocks": 8} if leaves_by == "preempt" else {}))
+    eng.eos_token_id = eos  # read at each dispatch
+    for i, (p, n) in enumerate(requests5):
+        eng.submit(p, n, req_id=i)
+    victim = None  # (its state, its tokens as it left)
+    seen = {}  # request id -> (state, tokens) while a row of its is in flight
+    while eng.sched.has_work():
+        eng.step(0.0)
+        rows = eng._rows_in_flight(eng._flying)
+        if (victim is None and len(rows)
+                == {"cancel": 2, "cancel_alone": 1}.get(leaves_by)):
+            st = next(iter(rows.values()))
+            victim = (st, list(st.generated))
+            assert eng.cancel(st.req.id)
+        seen.update({st.req.id: (st, list(st.generated))
+                     for st in rows.values()})
+    eng.close()
+    leaves = decode_leaves(tel)
+    dropped = sum(a["dropped"] for kind, a in leaves if kind == "emit")
+    assert (dropped >= 1) == (leaves_by != "cancel_alone")
+    assert eng.pool.in_use == 0 and eng._flying is None
+    done = {r["id"]: r["tokens"] for r in eng.results}
+    if leaves_by.startswith("cancel"):
+        st, toks = victim
+        assert st.generated == toks and st.req.id not in done
+        assert eng.stats["cancelled"] == 1
+        seqs = {kind: [a["seq"] for k, a in leaves if k == kind]
+                for kind in ("dispatch", "wait")}
+        if leaves_by == "cancel_alone":
+            # dispatch 0 was the victim's alone: no wait, and 1 restarts
+            assert seqs["wait"] == seqs["dispatch"][1:]
+            assert [a["ahead"] for k, a in leaves if k == "dispatch"][:2] == [0, 0]
+            assert eng.stats["decode_steps"] == len(seqs["wait"])
+        else:
+            assert seqs["wait"] == seqs["dispatch"]
+    elif leaves_by == "preempt":
+        assert eng.sched.n_preempted > 0
+    else:
+        assert done[2] == refs[2] and done[2][-1] == eos
+        assert len(done[2]) < requests5[2][1]
+    for rid, toks in done.items():
+        assert toks == refs[rid], rid
+    assert len(done) == len(requests5) - leaves_by.startswith("cancel")
+    # a row in flight never grew a request's tokens before its own emit:
+    # what a state held while a dispatch was in flight for it is a prefix of
+    # what it ended with
+    for rid, (st, toks) in seen.items():
+        assert st.generated[:len(toks)] == toks
+
+
+def test_a_budgets_end_hands_its_slot_on_before_the_next_dispatch(tiny):
+    """A saturated engine loses no slot-step to running ahead: a request
+    whose budget ends in the dispatch in flight leaves its slot before the
+    step admits, so its successor (a one-chunk prompt) rides the very next
+    dispatch. While requests queue, every dispatch carries every slot, all
+    but the first are enqueued ahead, and the tokens are the offline
+    sampler's."""
+    cfg, params = tiny
+    eng, tel = traced_engine(tiny)
+    rng = np.random.default_rng(7)
+    reqs = [(list(map(int, rng.integers(0, cfg.vocab_size, size=3))), n)
+            for n in (4, 7, 10, 5, 6, 8, 4, 9, 7)]
+    for i, (p, n) in enumerate(reqs):
+        eng.submit(p, n, req_id=i)
+    full = 0
+    while eng.sched.has_work():
+        eng.step(0.0)
+        if eng.sched.queue:
+            assert len(eng._flying["rows"]) == eng.num_slots
+            full += 1
+    eng.close()
+    assert full >= 4
+    disp = [a for kind, a in decode_leaves(tel) if kind == "dispatch"]
+    assert [a["ahead"] for a in disp] == [0] + [1] * (len(disp) - 1)
+    assert eng.stats["decode_ahead"] == eng.stats["decode_steps"] - 1
+    # the released rows' tokens were kept, none was dropped
+    emits = [a for kind, a in decode_leaves(tel) if kind == "emit"]
+    assert sum(a["dropped"] for a in emits) == 0
+    assert sum(a["retired"] for a in emits) == len(reqs)
+    assert eng.pool.in_use == 0
+    for r, (p, n) in zip(sorted(eng.results, key=lambda r: r["id"]), reqs):
+        assert r["tokens"] == np.asarray(generate(
+            params, cfg, jnp.asarray([p], jnp.int32), n))[0, len(p):].tolist()
+
+
+def _mellum2_tiny():
+    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-mellum2"))
+    return cfg, init_params(cfg, jax.random.key(5))
+
+
+@pytest.mark.parametrize("kind", ["paged", "windowed", "latent"])
+def test_dispatch_span_counts_are_its_own_dispatchs(tiny, kind):
+    """The counts on a `serve.decode.dispatch` span (`kv_blocks` and what
+    the cache's kind adds) are `cache.decode_counts` at the positions the
+    PROGRAM received, one dispatch ahead of the host's own, for a paged, a
+    windowed and a latent cache: the roofline readers divide by them."""
+    from picotron_tpu.telemetry import Telemetry
+    from picotron_tpu.telemetry.flightdeck import SpanTracer
+
+    cfg, params = {"paged": lambda: tiny, "windowed": _mellum2_tiny,
+                   "latent": _pangu}[kind]()
+    tel = Telemetry(sinks=[])
+    tel.tracer = SpanTracer()
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=3, block_size=4, prefill_chunk=8, max_model_len=48,
+        decode_interval=3), telemetry=tel)
+    rng = np.random.default_rng(4)
+    budget = {i: n for i, n in enumerate((11, 7, 14, 5))}
+    fed, jit = [], eng._decode_jit
+
+    def recording(params, pools, tables, toks, last, positions, rids, tidx,
+                  *a, **k):
+        fed.append((positions, rids, tidx))
+        return jit(params, pools, tables, toks, last, positions, rids, tidx,
+                   *a, **k)
+
+    eng._decode_jit = recording
+    for i, n in budget.items():
+        eng.submit(list(map(int, rng.integers(0, cfg.vocab_size, size=9 + 6 * i))),
+                   n, req_id=i)
+    while eng.sched.has_work():
+        eng.step(0.0)
+    eng.close()
+    spans = [a for k, a in decode_leaves(tel) if k == "dispatch"]
+    assert len(spans) == len(fed) > 4 and any(a["ahead"] for a in spans)
+    for a, (positions, rids, tidx) in zip(spans, jax.device_get(fed)):
+        live = positions >= 0
+        assert a["active"] == live.sum()
+        want = eng.cache.decode_counts(
+            [(int(p), min(3, budget[int(r)] - int(t)))
+             for p, r, t in zip(positions[live], rids[live], tidx[live])], cfg)
+        assert want and {k: a[k] for k in want} == want
