@@ -698,7 +698,9 @@ class EvaPagedCache(PagedKVCache):
     `n_sum` is what the summaries of `max_model_len` positions fill (the
     width of the table less the window's entries). A chunk is summarised
     when its last position is written (`write`): by a prefill chunk from
-    its own rows, by a decode step from the chunk's rows in the pool; a
+    its own rows, by a decode step from the chunk's rows in the pool (a
+    decode step whose positions end no chunk, in any slot, reads no chunk
+    and pools nothing: the step's positions decide, `write`); a
     partial chunk is in no summary. So closing a window takes no work at
     all, on the host or on the device: its summaries are there, and what
     changes is what the next query may see. That is the rows below ONE
@@ -726,7 +728,10 @@ class EvaPagedCache(PagedKVCache):
         starts on a chunk boundary): the chunks of the segment itself,
         whole where their last position is a real one. s == 1 (a decode
         step): the chunk the position ends, from the rows the pool holds
-        now. Dropped where `PagedKVCache.write` drops."""
+        now, read and pooled only in a step where some slot's position
+        ends a chunk (one test of the step's positions; every other step
+        writes the row and nothing else). Dropped where
+        `PagedKVCache.write` drops."""
         b, s = k_new.shape[:2]
         c = cfg.chunk_size
         if q_pos.ndim == 1:
@@ -739,21 +744,39 @@ class EvaPagedCache(PagedKVCache):
             cache = PagedKVCache.write(self, li, k_new, v_new,
                                        self._window_rows(q_pos, cfg))
             last = jnp.where((q_pos + 1) % c == 0, q_pos, -1)   # [B, 1]
+            # where the chunk's rows lie is worked out here and held here:
+            # left to itself the compiler sinks the table's gather into the
+            # branch below, the conditional becomes a reader of the table
+            # that nothing hoists, and the table's other gathers (the rows
+            # a query may see, `attend`: the same for every layer) then
+            # stay in the layer loop, one a layer for one a step
             at = jnp.maximum(q_pos, c - 1) - (c - 1) + jnp.arange(c)[None, :]
-            phys, off = _slots_of(cache.tables, self._window_rows(at, cfg),
-                                  b, self.block_size, self.num_blocks)
-            hkv = self.k.shape[0]
-            # clamped into the pool: an unmapped row's summary is dropped
-            phys = jnp.broadcast_to(jnp.minimum(phys, self.num_blocks - 1),
-                                    (hkv,) + phys.shape)
-            off = jnp.broadcast_to(off, (hkv,) + off.shape)
-            rows = jax.vmap(lambda pool, ph, of: pool[li, ph, of])
+            phys, off = jax.lax.optimization_barrier(_slots_of(
+                cache.tables, self._window_rows(at, cfg),
+                b, self.block_size, self.num_blocks))
 
-            def chunk_of(pool):  # [Hkv, B, c, D] -> [B, 1, c, Hkv, D]
-                return rows(pool, phys, off).transpose(1, 2, 0, 3)[:, None]
+            def summaries():
+                hkv = self.k.shape[0]
+                # clamped into the pool: an unmapped row's summary is dropped
+                ph = jnp.broadcast_to(jnp.minimum(phys, self.num_blocks - 1),
+                                      (hkv,) + phys.shape)
+                of = jnp.broadcast_to(off, (hkv,) + off.shape)
+                rows = jax.vmap(lambda pool, p, o: pool[li, p, o])
 
-            ks, vs = eva_summarise(chunk_of(cache.k), chunk_of(cache.v),
-                                   mu, phi)
+                def chunk_of(pool):  # [Hkv, B, c, D] -> [B, 1, c, Hkv, D]
+                    return rows(pool, ph, of).transpose(1, 2, 0, 3)[:, None]
+
+                return eva_summarise(chunk_of(cache.k), chunk_of(cache.v),
+                                     mu, phi)
+
+            # the pools are read inside the conditional and never written
+            # or returned by it: what comes out is the two summary rows
+            # (zeros from a step that closes nothing, which the write below
+            # drops with every `last` at -1)
+            ks, vs = jax.lax.cond(
+                jnp.any(last >= 0), summaries,
+                lambda: (jnp.zeros(k_new.shape, cache.k.dtype),
+                         jnp.zeros(v_new.shape, cache.v.dtype)))
         return PagedKVCache.write(cache, li, ks, vs,
                                   jnp.where(last >= 0, last // c, -1))
 
@@ -852,13 +875,23 @@ class EvaPagedCache(PagedKVCache):
         (`prefill_counts`), and at its first token, over slots and layers:
         `eva_summary_blocks` + `eva_window_blocks` = `eva_blocks_read`,
         what the step's attention reads, and `eva_blocks_full_attention`,
-        what full attention over the same lengths would."""
+        what full attention over the same lengths would. `eva_steps`: the
+        dispatch's steps in which some slot still has a token to emit;
+        `eva_steps_summarising`: those of them in which the program reads
+        chunks and pools them (`write`), because some slot's position ends
+        a chunk (the program advances every live slot a position a step,
+        whatever the slot has left to emit)."""
         w, c, bs = cfg.window_size, cfg.chunk_size, self.block_size
         layers = self.num_layers
         summary = sum(blocks_for(p // w * (w // c), bs) for p, _ in spans)
         both = sum(self.blocks_read(p + 1, cfg) for p, _ in spans)
+        steps = max((n for _, n in spans), default=0)
         return dict(
             kv_blocks=both, **self.prefill_counts(spans, cfg),
+            eva_steps=steps,
+            eva_steps_summarising=sum(
+                any((p + j + 1) % c == 0 for p, _ in spans)
+                for j in range(steps)),
             eva_summary_blocks=layers * summary,
             eva_window_blocks=layers * (both - summary),
             eva_blocks_read=layers * both,

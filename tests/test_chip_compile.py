@@ -713,6 +713,12 @@ def test_k_exaone_serving_programs(topo, monkeypatch, program, rows):
     assert_weights_read_in_place(text, name)
 
 
+# arguments + outputs - aliased + temporaries of `serve_decode` of
+# `evabyte-6.5b-8l` on the parent of PR 49 (commit cc3a921, this installation),
+# which gathered and pooled every slot's chunk in every step
+PARENT_EVA_DECODE_BYTES = 9_755_770_368
+
+
 @pytest.mark.parametrize("program,rows", [
     ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 16)])
 def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
@@ -722,7 +728,9 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     decode step attends through THE decode kernel under its own name, one call
     in the layer scan's body, with a chunk of pages that fits its 32 KV heads
     into fast memory; a prefill chunk walks tiles; the pooling's scope is in
-    both."""
+    both. The decode step reads a chunk's rows out of the pool and pools them
+    inside ONE conditional on the step's positions (PR 49), which returns the
+    two summaries and no pool, at the parent's memory."""
     name = "evabyte-6.5b-8l"
     comp, pool_shape, pools = compiled_serve(topo, monkeypatch, program, rows, name,
                                              text=False)
@@ -753,6 +761,51 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     # until PR 45 `serve_decode` re-laid q, k and v once a dispatch (three
     # `copy bf16[8,4096,4096]{1,2,0}`)
     assert_weights_read_in_place(text, name)
+    if program != "serve_decode":
+        return
+    # the decode step's two scatters a pool and layer (a position's row, a
+    # summary's row), each the loop's own instruction with the whole pool as its
+    # result; until PR 49 the loop held a fifth, K's rows scattered a second time
+    # (rematerialised) for the chunk's gather
+    comps = computations(text)
+    in_loop = loop_computations(text, comps)
+    scatters = [n for n, op, line in ins if " fusion(" in line and "scatter" in words(op)
+                and result_sizes(line) == [math.prod(pool_shape)]]
+    assert len(scatters) == 2 * len(pools), scatters
+    # PR 49: the chunk's rows are read and pooled only in a step where some slot's
+    # position ends a chunk. ONE conditional, in the layer loop's body (a while
+    # body that the step loop's body reaches), which the compiler has not turned
+    # into a select over both branches; it READS the pools and returns the two
+    # summaries, [slots, 1, Hkv, D] each, and no pool
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    conds = [(c, line) for c in sorted(in_loop) for line in comps[c]
+             if " conditional(" in line]
+    assert len(conds) == 1, conds
+    where, cond = conds[0]
+    assert where in bodies and any(
+        where in reachable(comps, [b]) for b in bodies - {where}), where
+    slots, hkv, d = load("configs", name)["serve"]["decode_slots"], pool_shape[0], pool_shape[-1]
+    assert result_sizes(cond) == [slots * hkv * d] * 2, cond
+    # every gather of 16 slots x 32 heads x 16 rows x 128 is in a computation the
+    # conditional's branches reach: two (K and V), none in the loop body itself
+    branches = reachable(comps, called(cond))
+    chunk = slots * hkv * load("configs", name)["model"]["chunk_size"] * d
+    placed = [(c, words(op), line) for c, lines in comps.items()
+              for _, op, line in instructions("\n".join(lines))]
+    gathers = [(c, line) for c, w, line in placed
+               if "gather" in w and chunk in result_sizes(line)]
+    assert sum(" fusion(" in line for _, line in gathers) == 2, gathers
+    assert {c for c, _ in gathers} <= branches and where not in branches, (
+        {c for c, _ in gathers} - branches)
+    # where the chunk's rows lie is worked out outside the conditional, so that
+    # the conditional does not read the table: what a query may see (the table's
+    # gather under `paged_attention`, the same for every layer) is then hoisted
+    # out of the layer loop as it was, one a step and not one a layer
+    seen = {c for c, w, line in placed
+            if {"paged_attention", "gather"} <= w and " fusion(" in line}
+    assert seen and where not in seen, seen
+    # and it costs the two summaries' room, no copy of anything it reads
+    assert total <= PARENT_EVA_DECODE_BYTES + 2**20, total - PARENT_EVA_DECODE_BYTES
 
 
 # What PR 45 left of `weights_written` (it took q_b's: `copy.50 bf16[4,1536,

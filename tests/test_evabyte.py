@@ -23,9 +23,9 @@ from picotron_tpu.config import (
 )
 from picotron_tpu.generate import generate
 from picotron_tpu.models.llama import forward, init_params, loss_fn, param_count
-from picotron_tpu.ops.eva import chunk_summaries
+from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.serve import ServeEngine
-from picotron_tpu.serve.paged_cache import eva_table_width
+from picotron_tpu.serve.paged_cache import PagedKVCache, eva_table_width, init_eva_cache
 from picotron_tpu.serve.scheduler import Scheduler
 
 # loaded by its path: `benchmark/` is not put on sys.path, where its own
@@ -403,12 +403,197 @@ def test_engine_counts_both_kinds_of_block_on_its_spans():
     assert got == dict(
         kv_blocks=6, eva_summaries_written=layers * 1, eva_windows_closed=0,
         eva_summary_blocks=layers * 4, eva_window_blocks=layers * 2,
-        eva_blocks_read=layers * 6, eva_blocks_full_attention=layers * 18)
+        eva_blocks_read=layers * 6, eva_blocks_full_attention=layers * 18,
+        # positions 70..73: the step at 71 ends chunk 17, the other three end none
+        eva_steps=4, eva_steps_summarising=1)
     assert eng.cache.blocks_read(71, cfg) == 6
     # a prefill chunk that ends window 0: 8 positions, 2 chunks, 1 window closed
     assert eng.cache.prefill_counts([(24, 8)], cfg) == dict(
         eva_summaries_written=layers * 2, eva_windows_closed=1)
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# a decode step reads and pools a chunk only where some slot's position ends one
+# ---------------------------------------------------------------------------
+
+
+def filled_cache(cfg, slots=4):
+    """An `EvaPagedCache` whose every row holds noise and whose every slot has
+    the blocks of 160 positions mapped: 10 summary blocks and a window's 8 a
+    slot, all distinct."""
+    width = eva_table_width(cfg, 160, BS)
+    cache = init_eva_cache(cfg, slots * width + 3, BS, slots, 160)
+    kk, kv = jax.random.split(jax.random.key(3))
+    return cache._replace(
+        k=jax.random.normal(kk, cache.k.shape, cache.k.dtype),
+        v=jax.random.normal(kv, cache.v.shape, cache.v.dtype),
+        tables=jnp.arange(slots * width, dtype=jnp.int32).reshape(slots, width))
+
+
+def parents_decode_write(cache, li, k_new, v_new, q_pos, mu, phi, cfg):
+    """`EvaPagedCache.write` of a decode step as it stood before PR 49, written
+    out: the position's row, then EVERY slot's chunk gathered out of the pool and
+    pooled, whatever the positions, and the summary's row index -1 wherever the
+    position ends no chunk."""
+    b, c = k_new.shape[0], cfg.chunk_size
+    cache = PagedKVCache.write(cache, li, k_new, v_new, cache._window_rows(q_pos, cfg))
+    first = cache._summary_entries(cfg) * cache.block_size
+    at = jnp.maximum(q_pos, c - 1) - (c - 1) + jnp.arange(c)[None, :]   # [B, c]
+    rows = first + at % cfg.window_size
+    blk = jnp.take_along_axis(cache.tables, rows // cache.block_size, axis=1)
+    blk = jnp.minimum(blk, cache.num_blocks - 1)
+
+    def chunk_of(pool):  # [Hkv, L, blocks, bs, D] -> [B, 1, c, Hkv, D]
+        return pool[:, li][:, blk, rows % cache.block_size].transpose(1, 2, 0, 3)[:, None]
+
+    ks, vs = eva_summarise(chunk_of(cache.k), chunk_of(cache.v), mu, phi)
+    return PagedKVCache.write(
+        cache, li, ks, vs, jnp.where((q_pos >= 0) & ((q_pos + 1) % c == 0), q_pos // c, -1))
+
+
+@pytest.mark.parametrize("positions", [
+    (5, 34, 70, 0),        # no slot ends a chunk: nothing is read or pooled
+    (5, 35, 70, 0),        # one does (35 ends chunk 8)
+    (3, 35, 71, 159),      # every slot does, the last one at the table's end
+    (31, -1, 6, -1),       # the last position of a window beside idle rows
+    (-1, -1, -1, -1),      # an empty step
+    (63, 64, 65, 66),      # a window's last position, and rows inside the next
+], ids=["none", "one", "all", "mixed-with-idle", "all-idle", "window-end"])
+def test_a_decode_steps_write_leaves_what_the_unconditional_form_leaves(positions):
+    """The pools after `EvaPagedCache.write` of one decode step EQUAL, bit for
+    bit, what the form without the conditional leaves (PR 49: the chunk's gather
+    and the pooling sit behind one test of the step's positions; in a step where
+    some slot ends a chunk every slot's summary is computed as before, and its
+    row index drops those that end none)."""
+    cfg = tiny()
+    cache = filled_cache(cfg)
+    lp = {k: v[1] for k, v in weights(cfg)["layers"].items()}
+    hkv, d = cfg.num_key_value_heads, cfg.head_dim
+    k_new = jax.random.normal(jax.random.key(7), (4, 1, hkv, d))
+    v_new = jax.random.normal(jax.random.key(8), (4, 1, hkv, d))
+    q_pos = jnp.asarray(positions, jnp.int32)[:, None]
+    args = (jnp.int32(1), k_new, v_new, q_pos, lp["eva_mu"], lp["eva_phi"])
+    got = jax.jit(lambda ch, *a: ch.write(*a, cfg))(cache, *args)
+    want = jax.jit(lambda ch, *a: parents_decode_write(ch, *a, cfg))(cache, *args)
+    assert type(got) is type(cache)
+    assert np.array_equal(got.k, want.k) and np.array_equal(got.v, want.v)
+    # and what changed is the positions' rows and the summaries of those that end
+    # a chunk, in layer 1 alone
+    ends = [p for p in positions if p >= 0 and (p + 1) % C == 0]
+    live = sum(p >= 0 for p in positions)
+    changed = np.asarray((got.k != cache.k).any(axis=(0, -1)))   # [L, blocks, bs]
+    assert changed.sum() == live + len(ends) and not changed[0].any()
+
+
+def test_a_windows_last_chunk_is_summarised_before_the_next_step_attends():
+    """A prompt two bytes short of a window, then ONE decode dispatch of four
+    steps over positions W - 2 .. W + 1: the step at W - 1 ends the window's last
+    chunk, and the steps at W and W + 1, in the same dispatch, see window 0
+    through its summaries, that chunk's among them. Their logits match the
+    reference; and they are read from that summary's row: a twin that serves a
+    step a dispatch, with that one row overwritten between the step at W - 1 and
+    the step at W, serves other logits from there on."""
+    cfg = tiny()
+    params = weights(cfg)
+    prompt = list(map(int, np.random.default_rng(13).integers(0, 320, size=W - 2)))
+    eng, out = run_engine(params, cfg, [(prompt, 5)])
+    assert eng.stats["decode_steps"] == 1            # one dispatch: four steps
+    toks, logits = out[0]["tokens"], np.asarray(out[0]["logits"])
+    want = ref_logits(params, cfg, prompt + toks, rows=range(W - 3, W + 2))
+    assert (want.argmax(-1) == np.asarray(toks)).all()
+    np.testing.assert_allclose(logits, want[np.arange(5), toks], atol=2e-4)
+    # the summaries are in play at the last two: without them the reference moves
+    bare = ref_logits(params, cfg, prompt + toks, rows=range(W - 3, W + 2),
+                      no_summaries=True)
+    assert np.abs(bare - want)[3:].max() > 1e-3 > np.abs(bare - want)[:3].max()
+
+    twin = ServeEngine(params, cfg, ServeConfig(**{**SERVE, "decode_interval": 1}))
+    jit, poisoned = twin._decode_jit, []
+
+    def poisoning(params, pools, tables, toks, last, positions, *a, **k):
+        if int(positions[0]) == W:  # the row of chunk W / C - 1: entry, offset
+            blk, off = int(tables[0][0, (W // C - 1) // BS]), (W // C - 1) % BS
+            pools = tuple(x.at[:, :, blk, off].set(3.0) for x in pools)
+            poisoned.append(blk)
+        return jit(params, pools, tables, toks, last, positions, *a, **k)
+
+    twin._decode_jit = poisoning
+    moved = twin.run([(prompt, 5)])[0]
+    twin.close()
+    assert len(poisoned) == 1 and moved["tokens"][:3] == toks[:3]
+    np.testing.assert_allclose(moved["logits"][:3], logits[:3], atol=2e-4)
+    assert np.abs(np.asarray(moved["logits"][3]) - logits[3]) > 1e-3
+
+
+def steps_by_hand(spans):
+    """(steps with a row that has a byte to emit, those of them in which some
+    row's position ends a chunk), a step at a time."""
+    steps = closing = 0
+    for j in range(8):
+        if any(j < n for _, n in spans):
+            steps += 1
+            closing += any((p + j) % C == C - 1 for p, _ in spans)
+    return steps, closing
+
+
+@pytest.mark.parametrize("spans,want", [
+    ([], (0, 0)),                                   # nothing dispatched
+    ([(72, 3)], (3, 0)),                            # 72..74: inside chunk 18
+    ([(70, 4)], (4, 1)),                            # 71 ends chunk 17
+    ([(70, 4), (9, 2)], (4, 2)),                    # and 11 ends chunk 2, at a step of its own
+    ([(3, 1), (7, 1), (12, 1)], (1, 1)),            # two rows end a chunk in ONE step
+    ([(0, 4), (1, 4), (2, 4), (3, 4)], (4, 4)),     # a row a phase: every step
+    ([(W - 2, 4)], (4, 1)),                         # across a window's end
+    ([(4, 2), (8, 1)], (2, 0)),                     # would end one at a third step only
+], ids=["empty", "inside", "straddles", "two-rows", "same-step", "every-step",
+        "window-end", "short"])
+def test_decode_counts_say_in_which_steps_the_program_summarises(spans, want):
+    """`eva_steps` and `eva_steps_summarising` of `decode_counts`: what
+    `eva_summarise_steps.serve` divides."""
+    cfg = tiny()
+    cache = init_eva_cache(cfg, 8, BS, 4, 160)
+    got = cache.decode_counts(spans, cfg)
+    assert (got["eva_steps"], got["eva_steps_summarising"]) == want == steps_by_hand(spans)
+
+
+def test_the_dispatch_spans_count_the_steps_the_program_summarised_in():
+    """The two counts on a `serve.decode.dispatch` span are those of the
+    positions the PROGRAM received (one dispatch ahead of the host's own), and
+    `eva_steps_summarising` is the number of the dispatch's steps in which the
+    program's own test (`EvaPagedCache.write`: some live position ends a chunk)
+    holds, wherever every row has a byte to emit at every step."""
+    from picotron_tpu.telemetry import Telemetry
+    from picotron_tpu.telemetry.flightdeck import SpanTracer
+
+    cfg = tiny()
+    tel = Telemetry(sinks=[])
+    tel.tracer = SpanTracer()
+    eng = ServeEngine(weights(cfg), cfg, ServeConfig(**SERVE), telemetry=tel)
+    fed, jit = [], eng._decode_jit
+
+    def recording(params, pools, tables, toks, last, positions, *a, **k):
+        fed.append(positions)
+        return jit(params, pools, tables, toks, last, positions, *a, **k)
+
+    eng._decode_jit = recording
+    rng = np.random.default_rng(2)
+    eng.run([(list(map(int, rng.integers(0, 320, size=n))), m)
+             for n, m in ((W + 3, 18), (9, 14), (21, 7))])
+    eng.close()
+    spans = sorted((e for e in tel.tracer.to_json()["traceEvents"]
+                    if e["ph"] == "X" and e["name"] == "serve.decode.dispatch"),
+                   key=lambda e: e["ts"])
+    assert len(spans) == len(fed) > 6
+    full = 0
+    for e, positions in zip(spans, jax.device_get(fed)):
+        a, live = e["args"], positions[positions >= 0]
+        assert 1 <= a["eva_steps"] <= 4 and a["eva_steps_summarising"] <= a["eva_steps"]
+        if a["eva_steps"] == 4:
+            full += 1
+            assert a["eva_steps_summarising"] == sum(
+                bool(((live + j + 1) % C == 0).any()) for j in range(4))
+    assert full > 4
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,9 @@ KINDS = {
         decode=dict(kv_blocks=11, eva_summaries_written=4,
                     eva_windows_closed=0, eva_summary_blocks=12,
                     eva_window_blocks=10, eva_blocks_read=22,
-                    eva_blocks_full_attention=58),
+                    eva_blocks_full_attention=58,
+                    # 7 ends a chunk at the first step, 71 at the second
+                    eva_steps=2, eva_steps_summarising=2),
         sched=dict(summary=(32, 4)))),
 }
 # (positions already cached, tokens of this chunk) a row of a prefill
