@@ -300,6 +300,41 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         zero_experts=256, moe_selection_bias=True, norm_topk_prob=False,
         routed_scaling_factor=6.0, router_aux_coef=0.0,
     ),
+    # Qwen3-Next-80B-A3B-Instruct
+    # (https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json).
+    # 48 layers in periods of (L, L, L, F): three Gated DeltaNet mixers
+    # (ops/gated_delta.py: 16 key heads and 32 value heads of 128, a causal
+    # depthwise convolution of 4 over q, k and v, a float32 state of
+    # 128 x 128 a value head carried by the gated delta rule, an RMSNorm of
+    # each head's output gated by silu(z)) to one gated softmax attention
+    # (16 heads over 2 KV heads of 256, per-head QK-norm, RoPE on the first
+    # 64 of a head's 256 dimensions, the output times sigmoid of a gate that
+    # comes out of q's projection); in every layer top-10 of 512 experts of
+    # width 512 (softmax, renormalised) beside one shared expert whose
+    # output is scaled by sigmoid of a 1-wide projection of the token; 1 + w
+    # norms; untied 151,936-row head. Built: forward(), generate() and
+    # ServeEngine, on one device. Assumed, with no key in config.json (the
+    # released modelling code; benchmark/reference_qwen3_next.py repeats the
+    # list): the attention's output gate and where it comes from, the order
+    # of [q | k | v | z] and [b | a] inside their projections, the plain
+    # weight of the mixer's output norm, A_log = log U(0, 16) and dt_bias
+    # the inverse softplus of a step log-uniform in [0.001, 0.1] at init.
+    # Not built: the multi-token-prediction module.
+    "Qwen/Qwen3-Next-80B-A3B-Instruct": dict(
+        vocab_size=151936, hidden_size=2048, intermediate_size=5120,
+        num_hidden_layers=48, num_attention_heads=16, num_key_value_heads=2,
+        head_dim=256, max_position_embeddings=262144, rope_theta=1e7,
+        rms_norm_eps=1e-6, partial_rotary_factor=0.25,
+        layer_types=("linear_attention", "linear_attention",
+                     "linear_attention", "full_attention") * 12,
+        linear_conv_kernel_dim=4, linear_key_head_dim=128,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_value_head_dim=128, qk_norm="head", attn_output_gate=True,
+        norm_add_unit_offset=True,
+        num_experts=512, num_experts_per_token=10, moe_intermediate_size=512,
+        n_shared_experts=1, shared_expert_gate=True, norm_topk_prob=True,
+        router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -418,6 +453,25 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         zero_experts=8, moe_selection_bias=True, norm_topk_prob=False,
         routed_scaling_factor=6.0, router_aux_coef=0.0,
     ),
+    # Tiny Qwen3-Next-shaped debug model: two periods of (L, L, L, F), the
+    # mixer at 2 key heads and 4 value heads of 8, the gated attention at 4
+    # heads over 2 KV heads of 16 with 4 rotated dimensions, 16 experts 2 a
+    # token + a gated shared expert. Served with block_size 4.
+    "picotron-tpu/debug-tiny-qwen3-next": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, max_position_embeddings=2048, rope_theta=10000.0,
+        rms_norm_eps=1e-6, partial_rotary_factor=0.25,
+        layer_types=("linear_attention", "linear_attention",
+                     "linear_attention", "full_attention") * 2,
+        linear_conv_kernel_dim=4, linear_key_head_dim=8,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_value_head_dim=8, qk_norm="head", attn_output_gate=True,
+        norm_add_unit_offset=True,
+        num_experts=16, num_experts_per_token=2, moe_intermediate_size=32,
+        n_shared_experts=1, shared_expert_gate=True, norm_topk_prob=True,
+        router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -456,6 +510,8 @@ _PRESET_ALIASES = {
     "debug-tiny-evabyte": "picotron-tpu/debug-tiny-evabyte",
     "LongCat-Flash-Omni": "meituan-longcat/LongCat-Flash-Omni",
     "debug-tiny-longcat": "picotron-tpu/debug-tiny-longcat",
+    "Qwen3-Next-80B-A3B-Instruct": "Qwen/Qwen3-Next-80B-A3B-Instruct",
+    "debug-tiny-qwen3-next": "picotron-tpu/debug-tiny-qwen3-next",
 }
 
 
@@ -480,7 +536,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -494,7 +550,8 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     mtype = hf.get("model_type") or (
         "longcat_flash" if "zero_expert_num" in hf else "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
-                 "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash")
+                 "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash",
+                 "qwen3_next")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -690,6 +747,45 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         out["routed_scaling_factor"] = float(
             hf.get("routed_scaling_factor", 1.0))
         out["router_aux_coef"] = 0.0
+    if mtype == "qwen3_next":
+        # Qwen3-Next: the pattern by full_attention_interval (layer i is
+        # full attention when (i + 1) % interval == 0, a Gated DeltaNet
+        # mixer otherwise), the mixer's sizes by its own keys, the rotated
+        # share of a head, experts in every layer (decoder_sparse_step 1,
+        # mlp_only_layers empty: anything else is not built), the shared
+        # expert by its width. config.json has no key for four things the
+        # family's modelling code does, each `assumed` where a benchmark
+        # configuration states it: per-head QK-norm, 1 + w norms, the
+        # attention's output gate, the shared expert's sigmoid gate.
+        if int(hf.get("decoder_sparse_step", 1)) != 1 or hf.get(
+                "mlp_only_layers"):
+            raise ValueError(
+                "qwen3_next with decoder_sparse_step != 1 or mlp_only_layers"
+                ": dense MLP layers among the expert layers are not built "
+                "(every layer holds the experts)")
+        every = int(hf["full_attention_interval"])
+        out["layer_types"] = tuple(
+            "full_attention" if (i + 1) % every == 0 else "linear_attention"
+            for i in range(out["num_hidden_layers"]))
+        for key in ("linear_conv_kernel_dim", "linear_key_head_dim",
+                    "linear_num_key_heads", "linear_num_value_heads",
+                    "linear_value_head_dim"):
+            out[key] = int(hf[key])
+        out["partial_rotary_factor"] = float(
+            hf.get("partial_rotary_factor", 1.0))
+        shared, width = (int(hf.get("shared_expert_intermediate_size", 0)),
+                         int(hf["moe_intermediate_size"]))
+        if shared % width:
+            raise ValueError(
+                f"qwen3_next: shared_expert_intermediate_size ({shared}) is "
+                f"not a whole number of experts of moe_intermediate_size "
+                f"({width})")
+        out["n_shared_experts"] = shared // width
+        out["shared_expert_gate"] = shared > 0
+        out["qk_norm"] = "head"
+        out["attn_output_gate"] = True
+        out["norm_add_unit_offset"] = True
+        out["router_aux_coef"] = 0.0
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -830,13 +926,19 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
     return _parse_mesh2(spec, "cp_mesh")
 
 
+GDN = "linear_attention"  # the kind of a layer that is a Gated DeltaNet mixer
+
+
 class Block(NamedTuple):
     """What one decoder block is made of: the one description that the
     training forward (`models.llama.decoder_layer`) and the cached decode
     forward (`generate._decode_layers`) both read."""
 
     # "gqa": q/k/v per head | "mla": latent attention | "eva": q/k/v per
-    # head over the open window's keys and a summary a chunk of the rest
+    # head over the open window's keys and a summary a chunk of the rest.
+    # A layer whose kind is "linear_attention" (`Stack.kinds`) runs a Gated
+    # DeltaNet mixer in this attention's place (ops/gated_delta.py), over a
+    # recurrent state and not over cached positions
     attn: str
     # "dense": gated MLP | "experts": routed (+ shared) experts |
     # "shortcut": the layer is TWO (attention, dense gated MLP) pairs, and
@@ -907,9 +1009,10 @@ class ModelConfig:
     # o projections of hidden x (heads * head_dim).
     head_dim: Optional[int] = None
     # The published per-layer attention kinds, "full_attention" or
-    # "sliding_attention" a layer; None = every layer full. The layer scan
-    # runs over whole periods of the pattern (`layer_period`). A sliding
-    # layer's position i sees j with 0 <= i - j < sliding_window.
+    # "sliding_attention" a layer, or "linear_attention" (a Gated DeltaNet
+    # mixer in the attention's place); None = every layer full. The layer
+    # scan runs over whole periods of the pattern (`layer_period`). A
+    # sliding layer's position i sees j with 0 <= i - j < sliding_window.
     layer_types: Optional[tuple] = None
     sliding_window: Optional[int] = None
     # RoPE law a layer kind: {"full_attention": {rope_type, rope_theta,
@@ -1042,6 +1145,27 @@ class ModelConfig:
     # scores token t + 1 + j. forward() returns all of them; a served token
     # is head 0's (heads 1.. draft, which is not built: ROADMAP M8).
     num_pred_heads: int = 1
+    # The share of a head's dimensions RoPE rotates, the leading ones (the
+    # published key; 1.0: all of them). `rope_dim`.
+    partial_rotary_factor: float = 1.0
+    # The softmax attention's output is scaled by sigmoid of a gate a
+    # dimension before the output projection; the gate comes out of q's
+    # projection, which is twice as wide: each head's 2 x head_dim outputs
+    # are its query, then its gate (Qwen3-Next).
+    attn_output_gate: bool = False
+    # The Gated DeltaNet mixer of a "linear_attention" layer
+    # (ops/gated_delta.py), the published keys: the kernel of the causal
+    # depthwise convolution over [q | k | v], the key heads and their
+    # width (q's too), the value heads and theirs; value heads are a whole
+    # multiple of key heads, each key head serving that many.
+    linear_conv_kernel_dim: int = 0
+    linear_key_head_dim: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_value_head_dim: int = 0
+    # The shared expert's output is scaled by sigmoid of a 1-wide
+    # projection of the token (`shared_out_gate`); false: gate 1.
+    shared_expert_gate: bool = False
     # Accepted for reference compat (ref uses them to pick CUDA kernels).
     use_flash_attention: bool = True
     use_fused_adam: bool = True
@@ -1065,7 +1189,7 @@ class ModelConfig:
             # every layer full is the model without the key
             object.__setattr__(
                 self, "layer_types",
-                lt if "sliding_attention" in lt else None)
+                lt if set(lt) - {"full_attention"} else None)
         rp = self.rope_parameters
         if rp:
             # dict (or its JSON round trip as nested pair lists) -> sorted
@@ -1116,9 +1240,22 @@ class ModelConfig:
 
     @property
     def rope_dim(self) -> int:
-        """The rotated width of a head: all of it, or MLA's shared
-        qk_rope_head_dim."""
-        return self.qk_rope_head_dim if self.mla else self.head_dim
+        """The rotated width of a head: all of it, its leading
+        partial_rotary_factor share, or MLA's shared qk_rope_head_dim."""
+        if self.mla:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def gdn(self) -> bool:
+        """Whether some layer is a Gated DeltaNet mixer."""
+        return GDN in self.layer_kinds
+
+    @property
+    def gdn_channels(self) -> int:
+        """The channels the mixer's convolution runs over: [q | k | v]."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def router_width(self) -> int:
@@ -1187,15 +1324,73 @@ class ModelConfig:
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"num_hidden_layers is {self.num_hidden_layers}")
             bad = set(self.layer_types) - {"full_attention",
-                                           "sliding_attention"}
+                                           "sliding_attention", GDN}
             if bad:
                 raise ValueError(
-                    f"layer_types entries must be 'full_attention' or "
-                    f"'sliding_attention', got {sorted(bad)}")
-            if not self.sliding_window or self.sliding_window < 1:
+                    f"layer_types entries must be 'full_attention', "
+                    f"'sliding_attention' or 'linear_attention', got "
+                    f"{sorted(bad)}")
+            if "sliding_attention" in self.layer_types and (
+                    not self.sliding_window or self.sliding_window < 1):
                 raise ValueError(
                     "layer_types holds sliding_attention layers: "
                     "sliding_window must be a positive number of positions")
+        sizes = (self.linear_conv_kernel_dim, self.linear_key_head_dim,
+                 self.linear_num_key_heads, self.linear_num_value_heads,
+                 self.linear_value_head_dim)
+        if self.gdn:
+            if min(sizes) < 1 or (self.linear_num_value_heads
+                                  % self.linear_num_key_heads):
+                raise ValueError(
+                    f"layer_types holds linear_attention layers: "
+                    f"linear_conv_kernel_dim, linear_key_head_dim, "
+                    f"linear_num_key_heads, linear_num_value_heads and "
+                    f"linear_value_head_dim must be >= 1 and the value "
+                    f"heads a whole multiple of the key heads, got {sizes}")
+            if ("sliding_attention" in self.layer_types or self.mla
+                    or self.eva or self.first_k_dense_replace
+                    or self.shortcut_moe or self.sandwich_norm
+                    or self.attention_bias or self.rope_parameters):
+                raise ValueError(
+                    "linear_attention layers are built beside full "
+                    "attention layers of q/k/v heads in one stack with two "
+                    "norms a layer and one RoPE law: sliding_attention "
+                    "layers, latent attention, attention_class 'eva', "
+                    "first_k_dense_replace, shortcut_moe, sandwich_norm, "
+                    "attention_bias and rope_parameters must be unset")
+        elif any(sizes):
+            raise ValueError(
+                "linear_conv_kernel_dim / linear_key_head_dim / "
+                "linear_num_key_heads / linear_num_value_heads / "
+                "linear_value_head_dim are a linear_attention layer's: set "
+                "layer_types with them, or none of them")
+        if not 0.0 < self.partial_rotary_factor <= 1.0 or (
+                self.rope_dim % 2):
+            raise ValueError(
+                f"partial_rotary_factor ({self.partial_rotary_factor}) must "
+                f"lie in (0, 1] and leave an even number of rotated "
+                f"dimensions of head_dim ({self.head_dim})")
+        if (self.partial_rotary_factor != 1.0 or self.attn_output_gate) and (
+                self.mla or self.eva or "sliding_attention" in self.layer_kinds
+                or self.rope_parameters):
+            raise ValueError(
+                "partial_rotary_factor < 1 and attn_output_gate are built "
+                "for full attention layers of q/k/v heads under one RoPE "
+                "law: latent attention, attention_class 'eva', "
+                "sliding_attention layers and rope_parameters must be unset")
+        if self.attn_output_gate and self.qk_norm is True:
+            raise ValueError(
+                "attn_output_gate splits q's projection a head: qk_norm "
+                "must be 'head' or false, not true (the whole vector)")
+        if (self.norm_add_unit_offset and self.qk_norm == "head"
+                and not self.attn_output_gate):
+            raise ValueError(
+                "norm_add_unit_offset with qk_norm 'head' is built in the "
+                "gated attention only (attn_output_gate): the head norms "
+                "scale by 1 + w there")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError(
+                "shared_expert_gate needs n_shared_experts > 0")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(
                 f"qk_norm must be false, true (over the whole projected "
@@ -1255,13 +1450,17 @@ class ModelConfig:
                 "window_size / chunk_size are attention_class 'eva''s: set "
                 "it with them, or neither")
         if (self.norm_add_unit_offset or self.fp32_skip_add) and (
-                self.mla or self.num_experts or self.sandwich_norm
-                or self.qk_norm):
+                self.mla or self.sandwich_norm or self.shortcut_moe
+                or self.first_k_dense_replace or self.qk_norm is True
+                or (self.fp32_skip_add and (self.num_experts
+                                            or self.qk_norm))):
             raise ValueError(
                 "norm_add_unit_offset / fp32_skip_add are built for a block "
-                "of q/k/v heads and a dense MLP with two norms a layer: "
-                "latent attention, experts, sandwich_norm and qk_norm must "
-                "be unset")
+                "of q/k/v heads with two norms a layer, the float32 stream "
+                "with a dense MLP and no QK-norm: latent attention, "
+                "sandwich_norm, shortcut_moe, first_k_dense_replace and "
+                "whole-vector qk_norm must be unset, and with fp32_skip_add "
+                "experts and qk_norm too")
         if self.num_pred_heads < 1:
             raise ValueError(
                 f"num_pred_heads must be >= 1, got {self.num_pred_heads}")
@@ -2120,17 +2319,22 @@ class Config:
                 f"schedule={pl.schedule!r} interleave={pl.interleave}")
 
     def _refuse_window_layers(self) -> None:
-        """Sliding-window layers run on the plain attention of
+        """Sliding-window layers and Gated DeltaNet mixers
+        (linear_attention layers) run on the plain attention of
         `forward()`, on `generate()` and on `ServeEngine`. Every path
-        that has no band refuses the model by name (ROADMAP M4: the
-        banded flash kernel for training)."""
+        that has no band, or that slices, shards or copies a stack whose
+        layers are all alike, refuses the model by name (ROADMAP M4: the
+        banded flash kernel for training; M9: the mixer's sharded
+        layouts)."""
         d, m, t, sv = (self.distributed, self.model, self.training,
                        self.serve)
+        kinds = " and ".join(sorted(set(m.layer_types) - {"full_attention"}))
 
         def refuse(what: str) -> None:
             raise ValueError(
-                f"model.layer_types holds sliding_attention layers, which "
-                f"{what} does not implement (no band in its mask); they "
+                f"model.layer_types holds {kinds} layers, which "
+                f"{what} does not implement (no band in its mask, no "
+                f"recurrent state, one kind of layer a stack); they "
                 f"run on attn_impl='reference', generate() and "
                 f"ServeEngine only")
 
@@ -2147,10 +2351,14 @@ class Config:
                    f"pattern, as a stack does)")
         if d.tp_size > 1:
             refuse(f"tensor parallelism (tp_size={d.tp_size}: the two "
-                   f"pools of a mixed cache are not sharded)")
+                   f"pools of a mixed cache, and a state pool, are not "
+                   f"sharded)")
+        if m.gdn and d.ep_size > 1:
+            refuse(f"expert parallelism (ep_size={d.ep_size}: the mixer's "
+                   f"leaves have no sharding rule)")
         if sv.disagg or sv.fleet_size > 1:
             refuse("serve.disagg / serve.fleet_size > 1 "
-                   "(one pool, one table a slot)")
+                   "(one pool, one table a slot, no hand-over of a state)")
 
     def _refuse_new_blocks(self) -> None:
         """Latent attention (scaled or not), sandwich norms, a shared
@@ -2159,7 +2367,9 @@ class Config:
         experts, a router selection bias, leading dense layers (with
         or without a layer pattern cut over the two stacks), per-head
         QK-norm, an unrotated layer kind, EVA attention, a head of several
-        prediction heads, 1 + w norms and a float32 residual stream run on
+        prediction heads, 1 + w norms, a float32 residual stream, a
+        partly rotated head, a gated attention output and a gated shared
+        expert run on
         the plain attention of `forward()` (and its AD), on `generate()`
         and on `ServeEngine`, on one device (EVA and several prediction
         heads have no training loss at all: `refuse_training`). Every
@@ -2192,6 +2402,11 @@ class Config:
             ("num_pred_heads > 1", m.num_pred_heads > 1),
             ("norm_add_unit_offset", m.norm_add_unit_offset),
             ("fp32_skip_add", m.fp32_skip_add),
+            ("partial_rotary_factor < 1", m.partial_rotary_factor != 1.0),
+            ("a gated attention output (attn_output_gate)",
+             m.attn_output_gate),
+            ("a gated shared expert (shared_expert_gate)",
+             m.shared_expert_gate),
         ) if on]
         if not what:
             return
@@ -2446,7 +2661,8 @@ def num_params(m: ModelConfig, active_only: bool = False,
         # + shared experts
         ffn = (h * m.router_width + n_ffn_experts * e_ffn
                + m.n_shared_experts * e_ffn
-               + (m.router_width if m.moe_selection_bias else 0))
+               + (m.router_width if m.moe_selection_bias else 0)
+               + (h if m.shared_expert_gate else 0))
     else:
         ffn = dense_ffn
     if m.mla:
@@ -2462,6 +2678,8 @@ def num_params(m: ModelConfig, active_only: bool = False,
     else:
         q = m.num_attention_heads * m.head_dim
         attn = h * q + h * kv * 2 + q * h  # q, k/v, out projections
+        if m.attn_output_gate:
+            attn += h * q  # the gate's half of q's projection
         if m.attention_bias:
             attn += q + 2 * kv  # q/k/v biases
         if m.qk_norm == "head":
@@ -2476,6 +2694,15 @@ def num_params(m: ModelConfig, active_only: bool = False,
         attn, ffn, norms = 2 * attn, ffn + 2 * dense_ffn, 2 * norms
     k = m.first_k_dense_replace
     layers = (l - k) * (attn + ffn + norms) + k * (attn + dense_ffn + norms)
+    if m.gdn:
+        # a Gated DeltaNet mixer in the attention's place: [q | k | v | z]
+        # and [b | a], the convolution, A_log and dt_bias, the output norm
+        # and the output projection
+        hv, dv = m.linear_num_value_heads, m.linear_value_head_dim
+        mixer = (h * (m.gdn_channels + hv * dv) + h * 2 * hv
+                 + m.gdn_channels * m.linear_conv_kernel_dim + 2 * hv + dv
+                 + hv * dv * h)
+        layers += m.layer_kinds.count(GDN) * (mixer - attn)
     head = (h * v * m.num_pred_heads
             if (not m.tie_word_embeddings or include_tied_head) else 0)
     return v * h + layers + h + head  # embed + layers + final_norm (+ head)

@@ -56,18 +56,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from picotron_tpu.config import ModelConfig, pattern_of
+from picotron_tpu.config import GDN, ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
     BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, final_hidden,
-    kind_tables, layer_window, mlp_act, model_rope_tables, norm_weight,
-    qkv_proj, residual_stream, rms_norm, served_head, shared_expert,
+    gate_attention, gated_qkv_proj, gdn_mixer, gdn_start, holds,
+    kind_tables, layer_window, mlp_act, model_rope_tables,
+    norm_weight, own_leaf, qkv_proj, residual_stream, rms_norm, served_head,
+    shared_expert,
 )
 from picotron_tpu.ops.eva import (
     chunk_summaries, eva_attention, eva_summarise,
 )
 from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
-from picotron_tpu.ops.rope import apply_rope
+from picotron_tpu.ops.rope import apply_rope, rotate_half
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -218,8 +220,64 @@ class EvaCache(NamedTuple):
                              cfg.chunk_size)
 
 
+class HybridCache(NamedTuple):
+    """Contiguous cache of a model whose layers are Gated DeltaNet mixers
+    and full attentions side by side (Qwen3-Next): the full layers' K/V as
+    `KVCache` holds them, a row a position, with only those layers in the
+    layer axis (a layer's row is `ki`, its ordinal among its kind), and
+    beside them what a mixer carries from token to token, a row a SEQUENCE:
+    `state` [L_gdn, B, Hv, d_k, d_v] float32 and `tail` [L_gdn, B,
+    (kernel - 1) x channels], the convolution's last inputs. The offline twin
+    of `serve.paged_cache.HybridPagedCache`; the layer loop calls both
+    alike: `write` / `attend` with `ki` on a full layer, `state_of(gi,
+    q_pos)` / `put_state(gi, state, tail, q_pos)` on a mixer, `gi` its
+    ordinal among the mixers. A sequence's state before position 0 is
+    zeros, whatever the cache holds: `state_of` says so and nobody resets
+    a row."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    state: jnp.ndarray
+    tail: jnp.ndarray
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[0] + self.state.shape[0]
+
+    def write(self, li, k_new, v_new, q_pos, window=None,
+              ki=None) -> "HybridCache":
+        kv = KVCache(self.k, self.v).write(ki, k_new, v_new, q_pos)
+        return self._replace(k=kv.k, v=kv.v)
+
+    def attend(self, li, q, q_pos, window=None, ki=None):
+        return KVCache(self.k, self.v).attend(ki, q, q_pos)
+
+    def state_of(self, gi, q_pos):
+        """(state [B, Hv, d_k, d_v], tail) the rows carry into positions
+        q_pos ([s], batch-shared): mixer gi's rows, zeros at position 0."""
+        fresh = q_pos[0] == 0
+        return tuple(
+            jnp.where(fresh, 0, lax.dynamic_index_in_dim(x, gi, 0, False))
+            for x in (self.state, self.tail))
+
+    def put_state(self, gi, state, tail, q_pos) -> "HybridCache":
+        return self._replace(
+            state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0),
+            tail=lax.dynamic_update_index_in_dim(
+                self.tail, tail.astype(self.tail.dtype), gi, 0))
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_length: int):
     dt = compute_dtype(cfg)
+    if cfg.gdn:
+        n_gdn = cfg.layer_kinds.count(GDN)
+        shape = (cfg.num_hidden_layers - n_gdn, batch, max_length,
+                 cfg.num_key_value_heads, cfg.head_dim)
+        state, tail = gdn_start(cfg, batch)
+        return HybridCache(
+            jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+            jnp.zeros((n_gdn,) + state.shape, state.dtype),
+            jnp.zeros((n_gdn,) + tail.shape, tail.dtype))
     if cfg.eva:
         shape = (cfg.num_hidden_layers, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
@@ -245,10 +303,7 @@ def _rope(x, cos, sin, q_pos):
         return apply_rope(x, cos, sin, jnp.maximum(q_pos, 0))
     c = cos[jnp.maximum(q_pos, 0)][:, :, None, :]  # [B, s, 1, D/2]
     s_ = sin[jnp.maximum(q_pos, 0)][:, :, None, :]
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * c - x2 * s_, x2 * c + x1 * s_],
-                           axis=-1).astype(x.dtype)
+    return rotate_half(x, c, s_)
 
 
 def _cached_attention(q, ck, cv, q_pos, window=None):
@@ -307,7 +362,11 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
     def gqa(h, cache, lp, li, kind, ki):
         """q/k/v a head, K and V written and attended per head."""
         b, s, _ = h.shape
-        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps, keep_flat=True)
+        gate = None
+        if cfg.attn_output_gate:
+            q, k, v, gate = gated_qkv_proj(h, lp, cfg, keep_flat=True)
+        else:
+            q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps, keep_flat=True)
         c_k, s_k = kind_tables(cos, sin, kind)
         q = _rope(q, c_k, s_k, q_pos)
         k = _rope(k, c_k, s_k, q_pos)
@@ -319,7 +378,22 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         # is a slice)
         with scope("paged_attention"):
             out = cache.attend(li, q, q_pos, **how)
+        if gate is not None:
+            out = gate_attention(out, gate)
         return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
+
+    def gdn(h, cache, lp, li, kind, ki):
+        """A Gated DeltaNet mixer: what the rows carry is read from the
+        cache's row `ki` of the mixers' state (zeros at a sequence's
+        start), and what they carry on is put back. Both under `gdn_state`
+        with the recurrence itself: the scope holds every byte of state the
+        step moves, whatever moves it."""
+        with scope("gdn"):
+            with scope("gdn_state"):
+                carried = cache.state_of(ki, q_pos)
+            out, state, tail = gdn_mixer(h, lp, cfg, *carried, live)
+            with scope("gdn_state"):
+                return out, cache.put_state(ki, state, tail, q_pos)
 
     def eva(h, cache, lp, li, kind, ki):
         """EVA attention (ops/eva.py): K and V written a head as `gqa`
@@ -362,8 +436,9 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """Norm -> one attention against cache row `li` -> its output."""
         h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
                      cfg.rms_norm_eps).astype(dt)
-        return {"gqa": gqa, "mla": mla, "eva": eva}[block.attn](
-            h, cache, lp, li, kind, ki)
+        mixer = gdn if kind == GDN else {
+            "gqa": gqa, "mla": mla, "eva": eva}[block.attn]
+        return mixer(h, cache, lp, li, kind, ki)
 
     def shortcut_layer(x, cache, lp, banks, block, li, bank_li, kind, ki):
         """`models.llama._shortcut_layer` against the cache: the layer's
@@ -433,9 +508,17 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             def take(w, at):
                 return lax.dynamic_index_in_dim(w, at, 0, keepdims=False)
 
-            if rows == 1:
+            if rows == 1 and not cfg.gdn:
                 at = i
                 lp = jax.tree.map(lambda w: take(w, i), layers)
+            elif rows == 1:
+                # a mixer's leaves are stacked over the layers of its kind
+                # alone (`models.llama.holds`): the layer's ordinal among
+                # them in this stack; every other leaf is at `i`
+                at = i
+                own = ki - before[kind]
+                lp = {n: take(w, own if own_leaf(n) else i)
+                      for n, w in layers.items() if holds(n, kind)}
             else:
                 # each (attention, dense MLP) pair's own view of the layer
                 # (`models.llama.sublayer`), a pair's leaf [L, 2, ...] read
@@ -497,7 +580,7 @@ def _served_experts(x, lp, banks, li, cfg: ModelConfig, live):
     routed nowhere. `banks`: the stack's whole banks of the experts held
     on this device, of which this is layer `li`. Returns (out, the counts
     `expert_counts` names)."""
-    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(lp["post_norm"], cfg), cfg.rms_norm_eps)
     out, counts = moe_mlp_served(
         h, lp["router"], *(banks[n] for n in BANKS),
         top_k=cfg.num_experts_per_token, act=mlp_act(cfg),

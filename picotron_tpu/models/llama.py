@@ -29,6 +29,7 @@ TPU-first design decisions (vs the reference's nn.Module tree):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Optional
@@ -38,10 +39,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from picotron_tpu.config import (
-    Block, ModelConfig, pattern_of, refuse_training,
+    GDN, Block, ModelConfig, pattern_of, refuse_training,
 )
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.eva import chunk_summaries, eva_attention
+from picotron_tpu.ops.gated_delta import (
+    causal_conv, gated_delta_chunked, gated_delta_step, l2_normalise,
+)
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -97,6 +101,43 @@ def by_period(layer_tree, period: int):
             x = x[:whole * period]
         return x.reshape(whole, period, *x.shape[1:])
     return jax.tree.map(split, layer_tree)
+
+
+# A stack whose layers are of two kinds of mixer (softmax attention and
+# Gated DeltaNet) holds each mixer's leaves stacked over the layers of ITS
+# kind alone, in their order, beside the leaves every layer has (the norms,
+# the MLP or the experts), stacked over all of them: no layer carries the
+# other kind's matrices. These are the softmax attention's leaves; the
+# mixer's are named `gdn_...`.
+ATTENTION_LEAVES = ("q", "k", "v", "o", "q_norm", "k_norm", "b_q", "b_k",
+                    "b_v")
+
+
+def own_leaf(name: str) -> bool:
+    """Whether `name` is a leaf of one kind of mixer: the softmax
+    attention's or the Gated DeltaNet mixer's."""
+    return name.startswith("gdn_") or name in ATTENTION_LEAVES
+
+
+def holds(name: str, kind: str) -> bool:
+    """Whether a layer of `kind` holds the stack's leaf `name`. Every layer
+    holds every leaf of a stack without `linear_attention` layers."""
+    if name.startswith("gdn_"):
+        return kind == GDN
+    return kind != GDN or name not in ATTENTION_LEAVES
+
+
+def leaf_row(name: str, kinds: tuple, i: int) -> int:
+    """The row of leaf `name` that layer `i` of a run of layers of `kinds`
+    reads: the layers before it that hold the leaf (i, where all do)."""
+    return sum(holds(name, k) for k in kinds[:i])
+
+
+def layer_leaves(stack, kinds: tuple, i: int):
+    """Layer i's leaves out of a stack (or a period of one) of layers of
+    `kinds`: each leaf it holds, at the leaf's row for it."""
+    return {n: w[leaf_row(n, kinds, i)] for n, w in stack.items()
+            if holds(n, kinds[i])}
 
 Params = dict[str, Any]
 
@@ -204,24 +245,29 @@ def _norm_init(cfg: ModelConfig, shape) -> jnp.ndarray:
 
 
 def _init_stack(cfg: ModelConfig, block: Block, nl: int,
-                key: jax.Array) -> Params:
+                key: jax.Array, kinds: tuple = ()) -> Params:
     """One stack of `nl` layers of one kind of block (`cfg.stacks`), fp32,
     stacked on a leading layer axis. The `layers` stack of a model of one
-    kind draws what it always drew from `key`."""
+    kind draws what it always drew from `key`. `kinds`: the layers' kinds
+    (`Stack.kinds`): the softmax attention's leaves are stacked over the
+    layers that are not Gated DeltaNet mixers, the mixers' over those that
+    are (`holds`)."""
     h = cfg.hidden_size
     i = cfg.intermediate_size
     d = cfg.head_dim
     q_out = cfg.num_attention_heads * d
     kv_out = cfg.num_key_value_heads * d
+    n_gdn = tuple(kinds).count(GDN)
+    na = nl - n_gdn  # layers with a softmax attention
 
     keys = jax.random.split(key, 14)
     # a layer of two (attention, dense MLP) pairs: every leaf of a pair has
     # a sublayer axis behind the layer axis, [nl, 2, ...]
     pair = (block.attentions,) if block.mlp == "shortcut" else ()
 
-    def stacked(k, fan_in, shape):
-        ks = jax.random.split(k, nl)
-        return jnp.stack([_uniform_fan_in(ks[j], fan_in, shape) for j in range(nl)])
+    def stacked(k, fan_in, shape, n=nl):
+        ks = jax.random.split(k, n)
+        return jnp.stack([_uniform_fan_in(ks[j], fan_in, shape) for j in range(n)])
 
     def paired(k, fan_in, shape):
         return stacked(k, fan_in, pair + shape)
@@ -255,11 +301,39 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "o": paired(keys[4], heads * dv, (heads * dv, h)),
         })
     else:
+        # a gated attention's q holds each head's query, then its gate
+        gated = 2 if cfg.attn_output_gate else 1
         layers.update({
-            "q": stacked(keys[1], h, (h, q_out)),
-            "k": stacked(keys[2], h, (h, kv_out)),
-            "v": stacked(keys[3], h, (h, kv_out)),
-            "o": stacked(keys[4], q_out, (q_out, h)),
+            "q": stacked(keys[1], h, (h, gated * q_out), na),
+            "k": stacked(keys[2], h, (h, kv_out), na),
+            "v": stacked(keys[3], h, (h, kv_out), na),
+            "o": stacked(keys[4], q_out, (q_out, h), na),
+        })
+    if n_gdn:
+        hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+        c, kern = cfg.gdn_channels, cfg.linear_conv_kernel_dim
+        gk = jax.random.split(keys[13], 6)
+        # a step's dt = softplus(dt_bias) log-uniform in [0.001, 0.1], as the
+        # gated delta rule's authors start it (dt_bias its inverse softplus):
+        # with A = U(0, 16) a step keeps exp(-A dt) of the state, 0.49 to
+        # 0.996 over the middle nine tenths of the heads, so a state carries
+        # tens to thousands of positions. (dt_bias = 1, the released
+        # modelling code's placeholder, keeps under half in 31 heads of 32:
+        # a mixer without a memory.)
+        step = jnp.exp(jax.random.uniform(gk[5], (n_gdn, hv), jnp.float32,
+                                          math.log(1e-3), math.log(1e-1)))
+        layers.update({
+            # [q | k | v | z]: the convolved channels, then the output gate
+            "gdn_qkvz": stacked(gk[0], h, (h, c + hv * dv), n_gdn),
+            "gdn_ba": stacked(gk[1], h, (h, 2 * hv), n_gdn),  # [b | a]
+            "gdn_conv": stacked(gk[2], kern, (c, kern), n_gdn),
+            # decays where a trained model's lie, neither all 1 nor all 0
+            # (`assumed`: A = U(0, 16), the released code's draw)
+            "gdn_A_log": jnp.log(jax.random.uniform(
+                gk[3], (n_gdn, hv), jnp.float32, 1e-3, 16.0)),
+            "gdn_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "gdn_norm": jnp.ones((n_gdn, dv), jnp.float32),  # a plain weight
+            "gdn_out": stacked(gk[4], hv * dv, (hv * dv, h), n_gdn),
         })
     if block.attn == "eva":
         # EVA's pooling vectors, one a KV head: unit normal clamped to
@@ -280,8 +354,8 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     if cfg.qk_norm == "head":
         # K-EXAONE: RMSNorm weights over one head, shared by the heads
         layers.update({
-            "q_norm": jnp.ones((nl, d), jnp.float32),
-            "k_norm": jnp.ones((nl, d), jnp.float32),
+            "q_norm": _norm_init(cfg, (na, d)),
+            "k_norm": _norm_init(cfg, (na, d)),
         })
     elif cfg.qk_norm:
         # OLMoE: RMSNorm weights over the whole q / k projection
@@ -306,6 +380,9 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
                 "shared_up": stacked(keys[11], h, (h, fs)),
                 "shared_down": stacked(keys[12], fs, (fs, h)),
             })
+            if cfg.shared_expert_gate:
+                layers["shared_out_gate"] = stacked(
+                    jax.random.fold_in(keys[12], 1), h, (h,))
         if cfg.moe_selection_bias:
             # zeros, as a released checkpoint's buffer starts
             layers["router_bias"] = jnp.zeros((nl, cfg.router_width),
@@ -347,7 +424,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     # the last stack (`layers`) draws from `key` itself, as the one stack of
     # a model of one kind of block always did; a stack before it from a fold
     stacks = {st.name: _init_stack(cfg, st.block, st.layers,
-                                   jax.random.fold_in(key, j) if j else key)
+                                   jax.random.fold_in(key, j) if j else key,
+                                   st.kinds)
               for j, st in enumerate(reversed(cfg.stacks))}
 
     params = {
@@ -384,8 +462,9 @@ def served_head(params: Params, cfg: ModelConfig) -> jnp.ndarray:
 def norm_weight(w, cfg: ModelConfig):
     """The scale of a block norm (input, post, final): `1 + w` where the
     config says so (norm_add_unit_offset). A transform of the weight, not a
-    second norm: every site calls `rms_norm` with it."""
-    return 1.0 + w if cfg.norm_add_unit_offset else w
+    second norm: every site calls `rms_norm` with it. In float32, as the
+    norm takes it: 1 + w in bfloat16 keeps 8 bits of w."""
+    return 1.0 + w.astype(jnp.float32) if cfg.norm_add_unit_offset else w
 
 
 def param_count(params: Params) -> int:
@@ -472,7 +551,8 @@ def residual_stream(x, cfg: ModelConfig):
     return x.astype(jnp.float32) if cfg.fp32_skip_add else x
 
 
-def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False):
+def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False,
+             qk_norm: bool = True):
     """Shared q/k/v projection (+ optional Qwen2 bias, tp-sharded with its
     output features; + optional QK-norm where the layer has `q_norm` /
     `k_norm` weights, an RMSNorm with `eps` before RoPE: over the WHOLE
@@ -486,7 +566,8 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False):
     segment VJP AND the KV-cache decode path (generate.py) so
     attention-input changes cannot silently diverge. `keep_flat` (the
     decode path's): the flat projections stay values of their own, see
-    below."""
+    below. `qk_norm` false: the caller norms q and k itself (a gated
+    attention, whose q holds more than the query)."""
     dt = h.dtype
     b, s, _ = h.shape
     q = h @ lp["q"].astype(dt)
@@ -496,8 +577,9 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False):
         q = q + lp["b_q"].astype(dt)
         k = k + lp["b_k"].astype(dt)
         v = v + lp["b_v"].astype(dt)
-    per_head = "q_norm" in lp and lp["q_norm"].shape[-1] == d
-    if "q_norm" in lp and not per_head:
+    normed = qk_norm and "q_norm" in lp
+    per_head = normed and lp["q_norm"].shape[-1] == d
+    if normed and not per_head:
         q = rms_norm(q, lp["q_norm"], eps)
         k = rms_norm(k, lp["k_norm"], eps)
     # checkpoint-name the FLAT [B, S, H*D] projections, BEFORE the head
@@ -526,6 +608,99 @@ def qkv_proj(h, lp, d: int, eps: float = 1e-5, keep_flat: bool = False):
     return q, k, v
 
 
+def gated_qkv_proj(h, lp, cfg: ModelConfig, keep_flat: bool = False):
+    """q/k/v of a gated attention (`attn_output_gate`) and its gate
+    [B, S, Hq, D]: q's projection is twice as wide, each head's 2 D
+    outputs its query, then its gate. The per-head QK-norm (with the
+    block norms' scale, `norm_weight`) is the query's and the key's, not
+    the gate's."""
+    d, eps = cfg.head_dim, cfg.rms_norm_eps
+    q, k, v = qkv_proj(h, lp, d, eps, keep_flat, qk_norm=False)
+    q = q.reshape(*q.shape[:2], -1, 2, d)
+    q, gate = q[..., 0, :], q[..., 1, :]
+    if "q_norm" in lp:
+        q = rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
+        k = rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
+    return q, k, v, gate
+
+
+def gate_attention(out, gate):
+    """The attention's output [B, S, Hq, D] times sigmoid of its gate."""
+    with scope("attn_gate"):
+        return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+
+
+def gdn_mixer(h, lp, cfg: ModelConfig, state, tail, live):
+    """A Gated DeltaNet mixer (ops/gated_delta.py) over a segment of every
+    row. h [B, s, hidden]: the normed block input; state [B, Hv, d_k, d_v]
+    float32 and tail [B, (kernel - 1) x channels] (the convolution's last
+    inputs, position-major, as one row: a cache's pool then has no axis of
+    3 next to its last one, which a compiled program would carry in tiles
+    of 4 and re-lay at its entry and exit): what the rows carry into the
+    segment (zeros at a sequence's start); live [B, s]: the positions
+    that hold a token, a prefix of each row. Returns (out [B, s, hidden],
+    state', tail'): the carried values after each row's last live position
+    (as they were for a row with none). One implementation for `forward()`
+    and the cached paths; one token a row (s == 1, a decode step) takes
+    the rule as written, a longer segment its chunked form."""
+    dt = h.dtype
+    b, s, _ = h.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    nq, c = hk * dk, cfg.gdn_channels
+    f32 = jnp.float32
+    # the projections' outputs and the convolution's stay float32 up to the
+    # recurrence, which is float32: a mixer answers an input's rounding with
+    # twice its size, nine of them one after the other with eight times, so
+    # the roundings inside it are not spent where they cost no time
+    qkvz = jnp.matmul(h, lp["gdn_qkvz"].astype(dt), preferred_element_type=f32)
+    ba = jnp.matmul(h, lp["gdn_ba"].astype(dt), preferred_element_type=f32)
+    with scope("gdn_conv"):
+        mixed, tail = causal_conv(qkvz[..., :c], tail.reshape(b, -1, c),
+                                  lp["gdn_conv"], jnp.sum(live, axis=1))
+        tail = tail.reshape(b, -1)
+    q = l2_normalise(mixed[..., :nq].reshape(b, s, hk, dk)) * dk ** -0.5
+    k = l2_normalise(mixed[..., nq:2 * nq].reshape(b, s, hk, dk))
+    # each key head serves hv / hk value heads, side by side
+    q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+    v = mixed[..., 2 * nq:].reshape(b, s, hv, dv).astype(f32)
+    # a position without a token neither decays nor writes
+    beta = jnp.where(live[..., None], jax.nn.sigmoid(ba[..., :hv]), 0.0)
+    g = jnp.where(live[..., None], -jnp.exp(lp["gdn_A_log"].astype(f32))
+                  * jax.nn.softplus(ba[..., hv:]
+                                    + lp["gdn_dt_bias"].astype(f32)), 0.0)
+    with scope("gdn_state"):
+        if s == 1:
+            o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                        beta[:, 0], state)
+            o = o[:, None]
+        else:
+            o, state = gated_delta_chunked(q, k, v, g, beta, state)
+    z = qkvz[..., c:].reshape(b, s, hv, dv)
+    o = rms_norm(o, lp["gdn_norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
+    return o.astype(dt).reshape(b, s, -1) @ lp["gdn_out"].astype(dt), state, tail
+
+
+def gdn_start(cfg: ModelConfig, rows: int):
+    """(state, tail) of `rows` sequences before their first position, both
+    float32: the tail holds the projections' float32 outputs."""
+    return (jnp.zeros((rows, cfg.linear_num_value_heads,
+                       cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                      jnp.float32),
+            jnp.zeros((rows, (cfg.linear_conv_kernel_dim - 1)
+                       * cfg.gdn_channels), jnp.float32))
+
+
+@scope("gdn")
+def _gdn_block(x, lp, cfg: ModelConfig):
+    """RMSNorm -> Gated DeltaNet mixer over whole sequences from a zero
+    state (the chunked form, which AD differentiates)."""
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
+    out, _, _ = gdn_mixer(h, lp, cfg, *gdn_start(cfg, h.shape[0]),
+                          jnp.ones(h.shape[:2], bool))
+    return out
+
+
 @scope("attention")
 def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
                      kind: str = "full_attention"):
@@ -544,7 +719,11 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     # inputs) while the MLP recomputes — the memory/flops midpoint between
     # "dots" and "full" (the MLP's gate/up activations are ~2/3 of a
     # layer's saved bytes but its matmuls only ~+7% of step flops)
-    q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
+    gate = None
+    if cfg.attn_output_gate:
+        q, k, v, gate = gated_qkv_proj(h, lp, cfg)
+    else:
+        q, k, v = qkv_proj(h, lp, d, cfg.rms_norm_eps)
     n_q = q.shape[2]
 
     # K/V stay unexpanded (n_kv heads) — attention impls handle GQA so the
@@ -555,6 +734,8 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     # keep their signature, and never see a model with sliding layers
     band = {} if window is None else {"window": window}
     out = ctx.attn(q, k, v, ctx.positions, rope, **band)  # [B, S, n_q, D]
+    if gate is not None:
+        out = gate_attention(out, gate)
     # attn_out/attn_lse are checkpoint_name'd inside each attention impl
     # (flash VJP fwd rule / sdpa), so the "dots" remat policy saves the
     # kernel residuals exactly once and backward never re-runs the forward.
@@ -646,13 +827,20 @@ def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
 
 def shared_expert(h, lp, cfg: ModelConfig):
     """The shared experts' gated MLP over the normed block input h: every
-    token passes through it with gate 1. One implementation for the
-    training block and the cached decode paths."""
+    token passes through it, with gate 1 or, where the layer has
+    `shared_out_gate`, sigmoid of that 1-wide projection of the token. One
+    implementation for the training block and the cached decode paths."""
     dt = h.dtype
     with scope("moe_shared"):
         gate = h @ lp["shared_gate"].astype(dt)
         up = h @ lp["shared_up"].astype(dt)
-        return (mlp_act(cfg)(gate) * up) @ lp["shared_down"].astype(dt)
+        out = (mlp_act(cfg)(gate) * up) @ lp["shared_down"].astype(dt)
+        if "shared_out_gate" in lp:
+            with scope("moe_shared_gate"):
+                out = out * jax.nn.sigmoid(
+                    (h @ lp["shared_out_gate"].astype(dt)).astype(
+                        jnp.float32))[..., None].astype(dt)
+        return out
 
 
 def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
@@ -663,7 +851,7 @@ def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     fraction and the busiest expert's load over the mean."""
     from picotron_tpu.ops.moe import moe_mlp
 
-    h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(lp["post_norm"], cfg), cfg.rms_norm_eps)
     h = ctx.f(h)
     # every expert on this device (ep = 1): the dropless dispatch; across
     # 'ep' the all_to_all needs the capacity path's fixed shapes
@@ -736,7 +924,9 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     block = block or cfg.stacks[-1].block
     if block.mlp == "shortcut":
         return _shortcut_layer(x, lp, cfg, ctx, cos, sin, is_real)
-    if block.attn == "mla":
+    if kind == GDN:
+        attn_out = _gdn_block(x, lp, cfg)
+    elif block.attn == "mla":
         attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
     elif block.attn == "eva":
         attn_out = _eva_attention_block(x, lp, cfg, ctx, cos, sin)
@@ -833,7 +1023,7 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     aux[2] the summed busiest-expert load ratio (all 0 for dense models)."""
     if cos is None:
         cos, sin = model_rope_tables(cfg)
-    n_slots = jax.tree.leaves(layer_params)[0].shape[0]
+    n_slots = layer_params["input_norm"].shape[0]
     if kinds is None:
         if len(cfg.layer_period) > 1:
             raise ValueError(
@@ -855,7 +1045,7 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
             return one(h, lp, real, period[0])
         aux = jnp.zeros(3, jnp.float32)
         for j, kind in enumerate(period):
-            h, a = one(h, jax.tree.map(lambda w: w[j], lp), real[j], kind)
+            h, a = one(h, layer_leaves(lp, period, j), real[j], kind)
             aux = aux + a
         # aux rides the scan's stacked outputs (not the carry: its varying
         # mesh axes differ from x's, which would unstabilize the carry type)
@@ -865,7 +1055,10 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
             else jnp.ones((n_slots,), jnp.float32))
     xs = (layer_params, real)
     if len(period) > 1:
-        xs = by_period(xs, len(period))
+        # a leaf is split by the layers of a period that hold it
+        xs = ({n: by_period(w, leaf_row(n, period, len(period)))
+               for n, w in layer_params.items()},
+              by_period(real, len(period)))
     left_over = one
     if ctx.remat:
         policy = remat_policy_for(ctx.remat_policy)
@@ -874,7 +1067,7 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     x, aux_per_layer = jax.lax.scan(body, x, xs)  # [L // period, 3]
     aux = jnp.sum(aux_per_layer, axis=0)
     for j, kind in enumerate(rest, whole * len(period)):
-        x, a = left_over(x, jax.tree.map(lambda w: w[j], layer_params),
+        x, a = left_over(x, layer_leaves(layer_params, tuple(kinds), j),
                          real[j], kind)
         aux = aux + a
     return x, aux

@@ -290,14 +290,21 @@ def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
         xs = _sorted_from_tokens(flat, row, inv)                  # [N*k, H]
     with scope("moe_experts"):
         # the experts' group sizes: all of `counts`, less the dead rows'
-        # group where the router was told of them (route_topk `live`); a
-        # grouped matmul leaves the rows past its last group zero
+        # group where the router was told of them (route_topk `live`): the
+        # rows past the last group are selected away below
         sizes = r.counts[:w_gate.shape[0]]
         g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
         u = lax.ragged_dot(xs, w_up.astype(dt), sizes)
         ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), sizes)
     with scope("moe_dispatch"):
         picked = _assignments_from_sorted(ys, row, inv)           # [N, k, H]
+        if r.counts.shape[0] > w_gate.shape[0]:
+            # dead assignments (picks of experts held elsewhere, rows
+            # without a token) lie past the last group, where the chip's
+            # grouped matmul leaves whatever was there, not zeros (54 x the
+            # block's output at 64 of 512 held: PERF.md section 6, PR 51)
+            picked = jnp.where(
+                (r.expert_idx < w_gate.shape[0])[..., None], picked, 0)
         out = jnp.sum(picked.astype(jnp.float32) * r.gate[..., None], axis=1)
     return out.astype(dt)
 

@@ -124,7 +124,9 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     """Apply rotate-half RoPE.
 
     x:    [batch, seq, heads, head_dim]
-    cos/sin: [max_seq, head_dim/2] tables from `rope_tables`
+    cos/sin: [max_seq, head_dim/2] tables from `rope_tables`; tables of
+        fewer columns rotate the head's leading 2 x columns dimensions and
+        leave the rest as they are (a partly rotated head)
     positions: optional [seq] global positions of the local tokens (for CP
         shards); defaults to 0..seq-1.
 
@@ -163,10 +165,18 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
         s = sin[positions]
     c = c[None, :, None, :]  # [1, S, 1, D/2]
     s = s[None, :, None, :]
-    half = x.shape[-1] // 2
+    return rotate_half(x, c, s)
+
+
+def rotate_half(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """x's leading 2 x c.shape[-1] dimensions rotated by (c, s), which
+    broadcast against x's leading axes; the dimensions behind them pass
+    through (all of a wholly rotated head is rotated)."""
+    half = c.shape[-1]
     x1 = x[..., :half]
-    x2 = x[..., half:]
+    x2 = x[..., half:2 * half]
     # (x1, x2) * repeat(cos,2) + (-x2, x1) * repeat(sin,2)
     out1 = x1 * c - x2 * s
     out2 = x2 * c + x1 * s
-    return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+    rest = [x[..., 2 * half:]] if x.shape[-1] > 2 * half else []
+    return jnp.concatenate([out1, out2, *rest], axis=-1).astype(x.dtype)

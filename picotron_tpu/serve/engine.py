@@ -611,7 +611,7 @@ class ServeEngine:
     def _write_rows(self, cache, tables, slot: int, st) -> None:
         """Slot `slot`'s row of each host table mirror, from the blocks its
         request `st` holds (None: a free slot, all unmapped)."""
-        for table, row in zip(tables, cache.slot_rows(st, self.cfg)):
+        for table, row in zip(tables, cache.slot_rows(st, self.cfg, slot)):
             table[slot] = row
 
     def _sync_table(self, slot: int) -> None:

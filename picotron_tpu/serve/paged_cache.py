@@ -90,7 +90,7 @@ it and asks it: `pools` (what a serve program is handed, donated, and
 hands back) and `of` (the cache inside the program, from those and the
 dispatch's table rows), `table_specs` (each table's row width and unmapped
 sentinel), `slot_rows` (a slot's host table rows from the blocks the
-scheduler gave it), `scheduler_args` (what the scheduler must know of the
+scheduler gave it and the slot's own index), `scheduler_args` (what the scheduler must know of the
 format) and `prefill_counts` / `decode_counts` (what a dispatch's span
 says it writes and reads). A new kind of cache is a class with those
 answers and an arm of `init_serve_cache`; the engine does not change.
@@ -104,9 +104,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from picotron_tpu.config import ModelConfig, ServeConfig, check_eva_serving
+from picotron_tpu.config import (
+    GDN, ModelConfig, ServeConfig, check_eva_serving,
+)
 from picotron_tpu.generate import _cached_attention
-from picotron_tpu.models.llama import compute_dtype
+from picotron_tpu.models.llama import compute_dtype, gdn_start
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
@@ -255,9 +257,9 @@ class PagedKVCache(NamedTuple):
         """`Scheduler`'s arguments beyond the pool of `num_blocks` blocks."""
         return {}
 
-    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
-        """A slot's row of each table from the blocks its request holds
-        (`st`: its `RequestState`, None for a free slot)."""
+    def slot_rows(self, st, cfg: ModelConfig, slot: int) -> tuple:
+        """Slot `slot`'s row of each table from the blocks its request
+        holds (`st`: its `RequestState`, None for a free slot)."""
         return (_table_row(self.table_specs[0], st.blocks if st else ()),)
 
     def blocks_read(self, n: int, cfg: ModelConfig) -> int:
@@ -457,7 +459,7 @@ class MixedPagedKVCache(NamedTuple):
         ring, window_blocks = self.table_specs[1]
         return dict(window_pool=BlockPool(window_blocks), ring_blocks=ring)
 
-    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
+    def slot_rows(self, st, cfg: ModelConfig, slot: int) -> tuple:
         full, ring = self.table_specs
         return (_table_row(full, st.blocks if st else ()),
                 _table_row(ring, st.wblocks if st else ()))
@@ -843,7 +845,7 @@ class EvaPagedCache(PagedKVCache):
     def scheduler_args(self, cfg: ModelConfig) -> dict:
         return dict(summary=(cfg.window_size, cfg.chunk_size))
 
-    def slot_rows(self, st, cfg: ModelConfig) -> tuple:
+    def slot_rows(self, st, cfg: ModelConfig, slot: int) -> tuple:
         """The summary blocks first, the open window's blocks after the
         summary region."""
         row = _table_row(self.table_specs[0], st.blocks if st else (),
@@ -914,6 +916,165 @@ def init_eva_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
         eva_table_width(cfg, max_len, block_size)))
 
 
+class HybridPagedCache(NamedTuple):
+    """The serving cache of a model whose layers are Gated DeltaNet mixers
+    and full attentions side by side (Qwen3-Next): two kinds of state, one
+    of them not addressed by position. The full layers keep the pool and
+    tables of `PagedKVCache`, with only those layers in the pool's layer
+    axis (the arrangement of `MixedPagedKVCache`'s full half; a layer's
+    row is `ki`). The mixers keep a row a SLOT: `state` [L_gdn, slots, Hv,
+    d_k, d_v] float32, the matrix the gated delta rule carries from token
+    to token, and `tail` [L_gdn, slots, (kernel - 1) x channels], the
+    convolution's last inputs as one row (`models.llama.gdn_mixer`);
+    `stables` [B, 1] maps a row of the dispatch
+    to its slot's row of both (`slots` itself: unmapped, the write drops).
+    A state row is the slot's for good, so admission allocates nothing for
+    it, and nothing on the host ever resets one: what a sequence carries
+    into position 0 is zeros whatever the row holds (`state_of`), so a
+    slot's next request, a request resumed after a preemption (its prefill
+    starts again at 0) and a decode dispatch still in flight for a request
+    that has left cannot leak into the sequence that follows. A row that
+    holds no real position in a dispatch (a padding row, an idle slot)
+    writes nothing (`put_state`). `generate._decode_layers` calls `write` /
+    `attend` with `ki` on a full layer and `state_of(gi, q_pos)` /
+    `put_state(gi, state, tail, q_pos)` on a mixer."""
+
+    k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
+    v: jnp.ndarray
+    state: jnp.ndarray    # [L_gdn, slots, Hv, d_k, d_v] float32
+    tail: jnp.ndarray     # [L_gdn, slots, (kernel - 1) x channels] float32
+    tables: jnp.ndarray   # [B, max_blocks]; num_blocks = unmapped
+    stables: jnp.ndarray  # [B, 1]; slots = unmapped
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[1] + self.state.shape[0]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def _kv(self) -> PagedKVCache:
+        return PagedKVCache(self.k, self.v, self.tables)
+
+    def write(self, li, k_new, v_new, q_pos, window=None,
+              ki=None) -> "HybridPagedCache":
+        c = self._kv.write(ki, k_new, v_new, q_pos)
+        return self._replace(k=c.k, v=c.v)
+
+    def attend(self, li, q, q_pos, window=None, ki=None):
+        """Attention of q [B, s, Hq, D] over full layer `ki`: a decode step
+        on a chip reads the slot's blocks in place, everything else walks
+        the keys in tiles as far as the batch's longest row reaches (a
+        row's whole view would be `max_model_len` positions wide)."""
+        c = self._kv
+        b, s = q.shape[:2]
+        if q_pos.ndim == 1:
+            q_pos = jnp.broadcast_to(q_pos[None, :], (b, s))
+        with scope("attn_full"):
+            if decode_kernel_suits(q, c.k):
+                out = paged_decode_attention(
+                    q[:, 0], c.k, c.v, ki, c.tables,
+                    jnp.maximum(q_pos[:, 0] + 1, 0))
+                return out[:, None]
+            return MixedPagedKVCache._tiled(c, ki, q, q_pos, None)
+
+    def state_of(self, gi, q_pos):
+        """(state [B, Hv, d_k, d_v], tail [B, (kernel - 1) x channels]) the
+        dispatch's rows carry into positions q_pos [B, s]: mixer gi's rows
+        of their slots, zeros where a row starts at position 0."""
+        slots = self.state.shape[1]
+        rows = jnp.minimum(self.stables[:, 0], slots - 1)  # unmapped: discarded
+        fresh = q_pos[:, 0] == 0
+
+        def of(pool):
+            x = pool[gi, rows]
+            return jnp.where(fresh.reshape((-1,) + (1,) * (x.ndim - 1)), 0, x)
+
+        return of(self.state), of(self.tail)
+
+    def put_state(self, gi, state, tail, q_pos) -> "HybridPagedCache":
+        """What the rows carry on, into mixer gi's rows of their slots;
+        dropped for a row without a real position and for an unmapped
+        one."""
+        slots = self.state.shape[1]
+        rows = jnp.where(jnp.any(q_pos >= 0, axis=1), self.stables[:, 0],
+                         slots)
+        return self._replace(
+            state=self.state.at[gi, rows].set(state, mode="drop"),
+            tail=self.tail.at[gi, rows].set(tail.astype(self.tail.dtype),
+                                            mode="drop"))
+
+    # -- what the serving engine asks (see `PagedKVCache`)
+
+    @classmethod
+    def of(cls, pools, tables):
+        return cls(*pools, *tables)
+
+    @property
+    def pools(self) -> tuple:
+        return self.k, self.v, self.state, self.tail
+
+    @property
+    def table_specs(self) -> tuple:
+        return ((self.tables.shape[1], self.k.shape[2]),
+                (1, self.state.shape[1]))
+
+    scheduler_args = PagedKVCache.scheduler_args  # a state row is the slot's
+
+    def slot_rows(self, st, cfg: ModelConfig, slot: int) -> tuple:
+        full, state = self.table_specs
+        return (_table_row(full, st.blocks if st else ()),
+                _table_row(state, (slot,) if st else ()))
+
+    blocks_read = PagedKVCache.blocks_read
+
+    def state_row_bytes(self) -> int:
+        """Bytes of one slot's state and tail of one mixer."""
+        return sum(int(np.prod(x.shape[2:])) * x.dtype.itemsize
+                   for x in (self.state, self.tail))
+
+    def _state_counts(self, rows: int, resets: int) -> dict:
+        """`state_rows`: (slot, mixer) pairs whose state and tail a dispatch
+        reads and writes (every step of a decode dispatch again);
+        `state_bytes`: their bytes, both ways; `state_resets`: those of
+        them that start from zeros."""
+        n = self.state.shape[0]
+        return dict(state_rows=n * rows,
+                    state_bytes=2 * n * rows * self.state_row_bytes(),
+                    state_resets=n * resets)
+
+    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+        return self._state_counts(len(spans), sum(p == 0 for p, _ in spans))
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`kv_blocks` (what one full layer's kernel reads),
+        `kv_blocks_banded` (summed over the full layers, the only ones that
+        read any: the name `MixedPagedKVCache` gives the same sum), and the
+        state's counts a step of the dispatch."""
+        kv = PagedKVCache.decode_counts(self, spans, cfg)["kv_blocks"]
+        return dict(kv_blocks=kv, kv_blocks_banded=self.k.shape[1] * kv,
+                    **self._state_counts(len(spans), 0))
+
+
+def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+                      num_slots: int, max_blocks: int) -> HybridPagedCache:
+    """Zeroed pools + all-unmapped tables: the K/V pool over the full
+    layers alone, a state row and a tail row a slot and mixer."""
+    n_gdn = cfg.layer_kinds.count(GDN)
+    dt = compute_dtype(cfg)
+    shape = (cfg.num_key_value_heads, cfg.num_hidden_layers - n_gdn,
+             num_blocks, block_size, cfg.head_dim)
+    state, tail = gdn_start(cfg, num_slots)
+    return HybridPagedCache(
+        jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+        jnp.zeros((n_gdn,) + state.shape, state.dtype),
+        jnp.zeros((n_gdn,) + tail.shape, tail.dtype),
+        jnp.full((num_slots, max_blocks), num_blocks, jnp.int32),
+        jnp.full((num_slots, 1), num_slots, jnp.int32))
+
+
 def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
                      num_blocks: int, max_len: int, sharded: bool = False):
     """The cache a model is served from, zeroed and all-unmapped: `num_slots`
@@ -930,6 +1091,12 @@ def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
         return init_eva_cache(cfg, num_blocks, bs, num_slots, max_len)
     if cfg.mla:  # one pool with no head axis, sized from the latent's width
         return init_latent_cache(cfg, num_blocks, bs, num_slots, max_blocks)
+    if cfg.gdn:  # a state row a slot beside the full layers' pool
+        if sharded:
+            raise ValueError(
+                "a model with linear_attention layers is served from one "
+                "device: the state pool is not sharded (tp = 1)")
+        return init_hybrid_cache(cfg, num_blocks, bs, num_slots, max_blocks)
     if cfg.layer_types is not None:  # a second pool, a ring a slot
         if sharded:
             raise ValueError(

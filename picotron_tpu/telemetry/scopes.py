@@ -49,6 +49,11 @@ SCOPES = (
     "moe_shared",       # inside mlp: the shared expert's gated MLP
     "scmoe_branch",     # a layer of two attentions: its shortcut-connected expert branch as a whole (router, routed experts, zero-compute term); NOT under mlp, which is that layer's two dense MLPs
     "moe_zero",         # inside the expert block: the zero-compute experts' term, the token times the summed gates of its picks of them
+    "gdn",              # a Gated DeltaNet mixer as a whole: its projections, convolution, recurrence, gated norm and output projection
+    "gdn_conv",         # inside gdn: the causal depthwise convolution over [q | k | v] with its carried tail, and the SiLU
+    "gdn_state",        # inside gdn: the gated delta rule alone: one step a row in the decode program, the chunked form in the prefill program
+    "attn_gate",        # a gated attention: its output times sigmoid of the gate that came out of q's projection
+    "moe_shared_gate",  # inside moe_shared: the shared expert's output times sigmoid of a 1-wide projection of the token
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 

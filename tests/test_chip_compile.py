@@ -983,3 +983,63 @@ def test_longcat_serving_programs(topo, monkeypatch, program, rows):
           ma.temp_size_in_bytes / 2**30)
     assert_weights_read_in_place(text, config,
                                  LONGCAT_WEIGHTS_WRITTEN.get((program, rows), ()))
+
+
+# ---------------------------------------------------------------------------
+# qwen3-next-80b-a3b-12l-ep8: a recurrent-state pool beside the K/V pool
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 16)])
+def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `qwen3-next-80b-a3b-12l-ep8` compile for a v5e
+    and fit it beside the weights; the K/V pool holds the three full layers
+    alone and the state pool a row a slot and mixer; neither, nor the tail
+    pool, is copied whole, and all four ride their program in place (the rule
+    the K/V pool is held to: the state pool is carried through the layer scan
+    and the decode program's step scan, gathered and scattered a mixer at a
+    time); the scan body is one period (L, L, L, F), so the decode step calls
+    the decode kernel once a body and the experts' grouped kernel four times;
+    the scopes the cell's metrics read are there."""
+    config = "qwen3-next-80b-a3b-12l-ep8"
+    comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    assert cache.k.shape == (2, 3, 49152, 16, 256)
+    assert cache.state.shape == (9, 16, 32, 128, 128) and cache.state.dtype == jnp.float32
+    assert cache.tail.shape == (9, 16, 24576) and cache.tail.dtype == jnp.float32
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    assert found >= {"gdn", "gdn_conv", "gdn_state", "attn_gate", "kv_write",
+                     "paged_attention", "attn_full", "mlp", "moe_router", "moe_dispatch",
+                     "moe_experts", "moe_shared", "moe_shared_gate", "sample"}
+    for shape in (cache.k.shape, cache.state.shape, cache.tail.shape):
+        copies = whole_pool_copies(text, shape)
+        if shape == cache.tail.shape:
+            # the tail pool is 14 MB: the compiler may keep it in VMEM while a
+            # step's mixers gather and scatter their rows (`copy-start` /
+            # `copy-done` to `S(1)` and back, 17 us each way), which is a move
+            # it chose, not a layout two of the pool's users disagree on
+            copies = [c for c in copies if "copy-done" not in c[2]]
+        assert not copies, f"{program} copies a whole pool {shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    paged = [(n, op) for n, op in kernels if attn.search(n)]
+    grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
+    assert len(grouped) + len(paged) == len(kernels), kernels
+    assert len(grouped) % 4 == 0 and len(grouped) >= 4 and "ragged-dot" not in text
+    assert all("moe_experts" in words(op) for _, op in grouped), grouped
+    if program == "serve_decode":
+        assert len(paged) == 1 and "attn_full" in words(paged[0][1])
+    else:
+        assert not paged  # a chunk walks its keys in tiles, no kernel
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)
+    assert total < 15.75 * 2**30, total / 2**30
+    assert_weights_read_in_place(text, config)

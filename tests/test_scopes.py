@@ -188,6 +188,11 @@ ONE_TABLE = {
     # and zero-compute experts: the branch's own scopes, and no shared expert
     "shortcut": ("debug-tiny-longcat",
                  SERVE | MOE | (MLA - {"moe_shared"}) | {"scmoe_branch", "moe_zero"}, 4),
+    # Gated DeltaNet mixers over a state pool beside gated full attentions
+    # over the K/V pool (a second table, of one column), a gated shared expert
+    "hybrid": ("debug-tiny-qwen3-next",
+               SERVE | MOE | {"gdn", "gdn_conv", "gdn_state", "attn_gate", "attn_full",
+                              "moe_shared", "moe_shared_gate"}, 8),
 }
 
 
@@ -201,7 +206,10 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     carry `eva_summarise` (`eva_summarise_ms.serve.json` reads it), and its
     attention is under `paged_attention` like any other's. A model whose
     layers hold two latent attentions and a shortcut-connected expert branch:
-    `scmoe_branch` and `moe_zero` beside them (`scmoe_branch_ms.serve.json`)."""
+    `scmoe_branch` and `moe_zero` beside them (`scmoe_branch_ms.serve.json`).
+    A model of Gated DeltaNet mixers and gated attentions: `gdn` with
+    `gdn_conv` and `gdn_state` inside it (`gdn_*.serve.json`), `attn_gate`,
+    `moe_shared_gate`."""
     preset, want, chunk = ONE_TABLE[model]
     mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,

@@ -1,5 +1,5 @@
 """The serving cache behind its one constructor (serve/paged_cache.py
-`init_serve_cache`): for each of the four kinds of cache, what the engine
+`init_serve_cache`): for each of the five kinds of cache, what the engine
 asks of it and nothing of how it answers. The numbers are what `ServeEngine`
 built and counted for the same model and settings while it still chose the
 cache itself, by flag (PR 45's tree), so a layout moved here is the layout
@@ -46,6 +46,18 @@ KINDS = {
                     # 7 ends a chunk at the first step, 71 at the second
                     eva_steps=2, eva_steps_summarising=2),
         sched=dict(summary=(32, 4)))),
+    # (new with the cache itself, PR 51: no engine ever chose it by flag)
+    "state+full": ("debug-tiny-qwen3-next", 8, dict(
+        cls="HybridPagedCache",
+        # K and V of the 2 full layers, then a state and a tail a slot and mixer
+        pools=[(2, 2, 40, 4, 16)] * 2 + [(6, 3, 4, 8, 8), (6, 3, 192)],
+        specs=((24, 40), (1, 3)),
+        rows=[ROW, [0]],  # the state row is the slot's own index
+        # 6 mixers x 3 rows, 1,792 B a row both ways; one row starts at 0
+        prefill=dict(state_rows=18, state_bytes=2 * 18 * 1792, state_resets=6),
+        decode=dict(kv_blocks=29, kv_blocks_banded=58,
+                    state_rows=18, state_bytes=2 * 18 * 1792, state_resets=0),
+        sched={})),
 }
 # (positions already cached, tokens of this chunk) a row of a prefill
 # dispatch; (position written first, tokens to emit) a slot of a decode one
@@ -90,11 +102,11 @@ def test_a_slots_table_rows_from_its_block_lists(kind):
     st.blocks = [5, 2, 7]
     st.wblocks = [3, 1] if len(want["specs"]) > 1 else []
     st.sblocks = [9, 4] if kind == "eva" else []
-    rows = cache.slot_rows(st, cfg)
+    rows = cache.slot_rows(st, cfg, 0)
     assert [r.tolist() for r in rows] == want["rows"]
     assert all(r.dtype == np.int32 for r in rows)
     # a free slot: every entry of every table unmapped
-    assert [r.tolist() for r in cache.slot_rows(None, cfg)] == [
+    assert [r.tolist() for r in cache.slot_rows(None, cfg, 0)] == [
         [unmapped] * width for width, unmapped in want["specs"]]
 
 
