@@ -67,6 +67,7 @@ from picotron_tpu.models.llama import (
 from picotron_tpu.ops.eva import (
     chunk_summaries, eva_attention, eva_summarise,
 )
+from picotron_tpu.ops.gated_delta import gated_delta
 from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope, rotate_half
@@ -229,11 +230,14 @@ class HybridCache(NamedTuple):
     `state` [L_gdn, B, Hv, d_k, d_v] float32 and `tail` [L_gdn, B,
     (kernel - 1) x channels], the convolution's last inputs. The offline twin
     of `serve.paged_cache.HybridPagedCache`; the layer loop calls both
-    alike: `write` / `attend` with `ki` on a full layer, `state_of(gi,
-    q_pos)` / `put_state(gi, state, tail, q_pos)` on a mixer, `gi` its
-    ordinal among the mixers. A sequence's state before position 0 is
-    zeros, whatever the cache holds: `state_of` says so and nobody resets
-    a row."""
+    alike: `write` / `attend` with `ki` on a full layer; on a mixer, `gi` its
+    ordinal among the mixers, `tail_of(gi, q_pos)` before the convolution,
+    `recur(gi, q, k, v, g, beta, q_pos)` for the recurrence (the cache runs
+    it on the state it holds and keeps what comes out) and `put_tail(gi,
+    tail, q_pos)` after. A sequence's state before position 0 is zeros,
+    whatever the cache holds: `tail_of` and `recur` say so and nobody resets
+    a row. Every row is live here, so the recurrence is the plain one
+    (`ops.gated_delta.gated_delta`) on the mixer's whole row of the state."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -252,19 +256,28 @@ class HybridCache(NamedTuple):
     def attend(self, li, q, q_pos, window=None, ki=None):
         return KVCache(self.k, self.v).attend(ki, q, q_pos)
 
-    def state_of(self, gi, q_pos):
-        """(state [B, Hv, d_k, d_v], tail) the rows carry into positions
-        q_pos ([s], batch-shared): mixer gi's rows, zeros at position 0."""
-        fresh = q_pos[0] == 0
-        return tuple(
-            jnp.where(fresh, 0, lax.dynamic_index_in_dim(x, gi, 0, False))
-            for x in (self.state, self.tail))
+    def _carried(self, x, gi, q_pos):
+        """Mixer gi's row of x, zeros where the rows start at position 0
+        (q_pos [s], batch-shared)."""
+        return jnp.where(q_pos[0] == 0, 0,
+                         lax.dynamic_index_in_dim(x, gi, 0, False))
 
-    def put_state(self, gi, state, tail, q_pos) -> "HybridCache":
-        return self._replace(
-            state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0),
-            tail=lax.dynamic_update_index_in_dim(
-                self.tail, tail.astype(self.tail.dtype), gi, 0))
+    def tail_of(self, gi, q_pos):
+        """The tail [B, (kernel - 1) x channels] the rows carry into q_pos."""
+        return self._carried(self.tail, gi, q_pos)
+
+    def put_tail(self, gi, tail, q_pos) -> "HybridCache":
+        return self._replace(tail=lax.dynamic_update_index_in_dim(
+            self.tail, tail.astype(self.tail.dtype), gi, 0))
+
+    def recur(self, gi, q, k, v, g, beta, q_pos):
+        """The gated delta rule over the segment from mixer gi's state
+        (zeros at position 0) -> (o [B, s, Hv, d_v], the cache with the
+        state after it)."""
+        o, state = gated_delta(q, k, v, g, beta,
+                               self._carried(self.state, gi, q_pos))
+        return o, self._replace(
+            state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_length: int):
@@ -383,17 +396,21 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
 
     def gdn(h, cache, lp, li, kind, ki):
-        """A Gated DeltaNet mixer: what the rows carry is read from the
-        cache's row `ki` of the mixers' state (zeros at a sequence's
-        start), and what they carry on is put back. Both under `gdn_state`
-        with the recurrence itself: the scope holds every byte of state the
-        step moves, whatever moves it."""
+        """A Gated DeltaNet mixer: the tail the rows carry is read from the
+        cache's row `ki` of the mixers (zeros at a sequence's start) and the
+        tail they carry on is put back; the recurrence is asked of the cache,
+        which runs it on the state it holds and keeps what comes out (a
+        serving decode step: one kernel over the state pool in place, the
+        live rows' matrices alone). All under `gdn_state`: the scope holds
+        every byte of state the step moves, whatever moves it."""
         with scope("gdn"):
             with scope("gdn_state"):
-                carried = cache.state_of(ki, q_pos)
-            out, state, tail = gdn_mixer(h, lp, cfg, *carried, live)
+                tail = cache.tail_of(ki, q_pos)
+            out, cache, tail = gdn_mixer(
+                h, lp, cfg, partial(cache.recur, ki, q_pos=q_pos),
+                tail, live)
             with scope("gdn_state"):
-                return out, cache.put_state(ki, state, tail, q_pos)
+                return out, cache.put_tail(ki, tail, q_pos)
 
     def eva(h, cache, lp, li, kind, ki):
         """EVA attention (ops/eva.py): K and V written a head as `gqa`

@@ -43,9 +43,7 @@ from picotron_tpu.config import (
 )
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.eva import chunk_summaries, eva_attention
-from picotron_tpu.ops.gated_delta import (
-    causal_conv, gated_delta_chunked, gated_delta_step, l2_normalise,
-)
+from picotron_tpu.ops.gated_delta import causal_conv, gated_delta, l2_normalise
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -630,19 +628,26 @@ def gate_attention(out, gate):
         return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
 
 
-def gdn_mixer(h, lp, cfg: ModelConfig, state, tail, live):
+def gdn_mixer(h, lp, cfg: ModelConfig, recur, tail, live):
     """A Gated DeltaNet mixer (ops/gated_delta.py) over a segment of every
-    row. h [B, s, hidden]: the normed block input; state [B, Hv, d_k, d_v]
-    float32 and tail [B, (kernel - 1) x channels] (the convolution's last
-    inputs, position-major, as one row: a cache's pool then has no axis of
-    3 next to its last one, which a compiled program would carry in tiles
-    of 4 and re-lay at its entry and exit): what the rows carry into the
-    segment (zeros at a sequence's start); live [B, s]: the positions
-    that hold a token, a prefix of each row. Returns (out [B, s, hidden],
-    state', tail'): the carried values after each row's last live position
-    (as they were for a row with none). One implementation for `forward()`
-    and the cached paths; one token a row (s == 1, a decode step) takes
-    the rule as written, a longer segment its chunked form."""
+    row. h [B, s, hidden]: the normed block input; tail [B, (kernel - 1) x
+    channels] (the convolution's last inputs, position-major, as one row: a
+    cache's pool then has no axis of 3 next to its last one, which a
+    compiled program would carry in tiles of 4 and re-lay at its entry and
+    exit): what the rows carry into the segment (zeros at a sequence's
+    start); live [B, s]: the positions that hold a token, a prefix of each
+    row. The recurrent state is the caller's, and so is the recurrence:
+    `recur(q, k, v, g, beta)` (q, k [B, s, Hv, d_k]; v [B, s, Hv, d_v]; g,
+    beta [B, s, Hv], float32, a position without a token inert) runs the
+    gated delta rule over the segment from the state the caller's rows
+    carry and returns (o [B, s, Hv, d_v], whatever the caller carries on):
+    `ops.gated_delta.gated_delta` over a state [B, Hv, d_k, d_v] where the
+    caller holds one (`_gdn_block`, `generate.HybridCache`), the cache's own
+    answer where the state lives in a pool that a decode step updates in
+    place (`serve.paged_cache.HybridPagedCache.recur`). Returns (out [B, s,
+    hidden], what `recur` handed back, tail'): the tail after each row's
+    last live position (as it was for a row with none). One body for
+    `forward()`, prefill chunks and decode steps."""
     dt = h.dtype
     b, s, _ = h.shape
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
@@ -670,15 +675,11 @@ def gdn_mixer(h, lp, cfg: ModelConfig, state, tail, live):
                   * jax.nn.softplus(ba[..., hv:]
                                     + lp["gdn_dt_bias"].astype(f32)), 0.0)
     with scope("gdn_state"):
-        if s == 1:
-            o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                        beta[:, 0], state)
-            o = o[:, None]
-        else:
-            o, state = gated_delta_chunked(q, k, v, g, beta, state)
+        o, carried = recur(q, k, v, g, beta)
     z = qkvz[..., c:].reshape(b, s, hv, dv)
     o = rms_norm(o, lp["gdn_norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
-    return o.astype(dt).reshape(b, s, -1) @ lp["gdn_out"].astype(dt), state, tail
+    return (o.astype(dt).reshape(b, s, -1) @ lp["gdn_out"].astype(dt), carried,
+            tail)
 
 
 def gdn_start(cfg: ModelConfig, rows: int):
@@ -696,7 +697,8 @@ def _gdn_block(x, lp, cfg: ModelConfig):
     """RMSNorm -> Gated DeltaNet mixer over whole sequences from a zero
     state (the chunked form, which AD differentiates)."""
     h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
-    out, _, _ = gdn_mixer(h, lp, cfg, *gdn_start(cfg, h.shape[0]),
+    state, tail = gdn_start(cfg, h.shape[0])
+    out, _, _ = gdn_mixer(h, lp, cfg, partial(gated_delta, state=state), tail,
                           jnp.ones(h.shape[:2], bool))
     return out
 

@@ -6,7 +6,7 @@ d_k^-0.5), value v, decay g <= 0 and write strength beta in (0, 1):
 
     S' = exp(g) S;   r = S'^T k;   S = S' + k (beta (v - r))^T;   o = S^T q
 
-Three things live here, each a function of arrays alone:
+Four things live here, each a function of arrays alone:
 
 - `causal_conv`: the depthwise convolution over the sequence that q, k and v
   pass through before the recurrence, with the `kernel - 1` positions before
@@ -23,6 +23,16 @@ Three things live here, each a function of arrays alone:
   triangular solve of 64 x 64 a head, the rest matrix products), and only
   the sub-chunks run one after the other. Same mathematics as the step:
   tests/test_qwen3_next.py holds them together from a non-zero start state.
+- `gated_delta_step_pooled`: `gated_delta_step` as ONE Pallas kernel over a
+  serving cache's state pool [L_gdn, slots, H, d_k, d_v], in place: the pool
+  stays in HBM and is the kernel's output too, and of each row of the batch
+  that holds a token the kernel brings the slot's matrices into VMEM once,
+  a block of heads at a time, updates them and writes them back where they
+  were. A row without a token moves no byte either way (at the Qwen3-Next
+  cell's 2.4 live rows of 16, gathering every row, the rule and the scatter
+  back made 2.7 passes over ALL rows: PERF.md section 6, PR 52).
+  `gated_delta_kernel_suits` says which steps take it; `gated_delta` is the
+  plain forms' one entry (the step for one position, else the chunks).
 
 A position that carries no token (chunk padding, an idle slot) is made inert
 by its caller: g = 0 and beta = 0 leave the state as it was. Everything is
@@ -33,11 +43,24 @@ and the state lives for tens of thousands of tokens.
 
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from picotron_tpu.ops.paged_attention import (
+    _LANES, _divisor, compiled_kernels_available,
+)
 
 F32 = jnp.float32
+# Value heads a DMA: 8 matrices of 128 x 128 float32 are 512 KiB, in and out
+# and double-buffered 2 MiB of VMEM, and the 8 heads' rows of v and o are one
+# float32 sublane tile.
+STEP_HEAD_BLOCK = 8
 
 
 def causal_conv(x, tail, w, n_valid):
@@ -137,3 +160,207 @@ def gated_delta_chunked(q, k, v, g, beta, state, sub: int = 64):
         state, o = lax.scan(one, state.astype(F32), (q, k, u, w, qk, gc))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)       # [B, N, c, H, d_v]
     return o.reshape(b, n * c, h, dv)[:, :s], state
+
+
+def gated_delta(q, k, v, g, beta, state):
+    """A segment from `state` in the plain form that suits its length: the
+    rule itself for one position a row, else the chunked form. Shapes as
+    `gated_delta_scan`'s."""
+    if q.shape[1] == 1:
+        o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], state)
+        return o[:, None], state
+    return gated_delta_chunked(q, k, v, g, beta, state)
+
+
+# ---------------------------------------------------------------------------
+# The decode step over a serving cache's state pool, in place.
+# ---------------------------------------------------------------------------
+
+
+def gated_delta_kernel_suits(s: int, pool) -> bool:
+    """Whether a segment of `s` positions a row over a state pool [L_gdn,
+    slots, H, d_k, d_v] is one `gated_delta_step_pooled` takes compiled: a
+    decode step (one position a row), a float32 state whose d_k and d_v are
+    whole rows of 128 lanes and whose heads are whole blocks of
+    `STEP_HEAD_BLOCK`, a backend that compiles Pallas kernels.
+    Everything else gathers its rows and takes the plain forms: prefill
+    chunks, the tiny test models' heads, every CPU run
+    (`ops.paged_attention.decode_kernel_suits` is the K/V pool's answer to
+    the same question)."""
+    return (s == 1 and pool.dtype == F32 and pool.shape[2] % STEP_HEAD_BLOCK == 0
+            and pool.shape[3] % _LANES == 0 and pool.shape[4] % _LANES == 0
+            and compiled_kernels_available())
+
+
+def _step_kernel(gi_ref, slot_ref, fresh_ref, decay_ref, beta_ref, qt_ref,
+                 kt_ref, v_ref, pool_in, pool_out, o_ref, order, s_in, s_out,
+                 sems, *, heads: int):
+    rows, hv, dv = v_ref.shape
+    blocks = hv // heads
+    gi = gi_ref[0]
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    # the rows with work, compacted: order[0 .. n) in the batch's order
+    def note(b, n):
+        @pl.when(slot_ref[b] >= 0)
+        def _():
+            order[n] = b
+        return n + (slot_ref[b] >= 0).astype(jnp.int32)
+
+    items = blocks * lax.fori_loop(0, rows, note, 0)
+
+    # item t: block t % blocks of the heads of the (t // blocks)-th such row,
+    # through buffer t % 2; its matrices are pool[gi, slot, the block's heads]
+    def place(t):
+        return (gi, slot_ref[order[t // blocks]],
+                pl.ds((t % blocks) * heads, heads))
+
+    def fetch(t):
+        return pltpu.make_async_copy(pool_in.at[place(t)], s_in.at[t % 2],
+                                     sems.at[0, t % 2])
+
+    def store(t):
+        return pltpu.make_async_copy(s_out.at[t % 2], pool_out.at[place(t)],
+                                     sems.at[1, t % 2])
+
+    @pl.when(items > 0)
+    def _first():
+        fetch(0).start()
+
+    lane = lax.broadcasted_iota(jnp.int32, kt_ref.shape[1:], 1)
+    sub = lax.broadcasted_iota(jnp.int32, (heads, dv), 0)
+
+    def item(t, _):
+        b = order[t // blocks]
+        h0 = pl.multiple_of((t % blocks) * heads, heads)
+        buf = t % 2
+
+        @pl.when(t + 1 < items)
+        def _next():
+            fetch(t + 1).start()
+
+        fetch(t).wait()
+
+        @pl.when(fresh_ref[b] != 0)
+        def _start():  # position 0: whatever the row holds, zeros
+            s_in[buf] = jnp.zeros(s_in.shape[1:], F32)
+
+        @pl.when(t >= 2)
+        def _free():
+            # item t - 2's matrices are on their way out of this buffer
+            store(t - 2).wait()
+
+        kt, qt = kt_ref[b], qt_ref[b]                      # [d_k, H]
+        v = v_ref[b, pl.ds(h0, heads), :]                  # [heads, d_v]
+
+        def head(j, o):
+            h = h0 + j
+            # head h's key and query as columns, d_k down the sublanes as
+            # the state's rows are: one lane of the tile, the others zeros
+            kc = jnp.sum(jnp.where(lane == h, kt, 0.0), axis=1, keepdims=True)
+            qc = jnp.sum(jnp.where(lane == h, qt, 0.0), axis=1, keepdims=True)
+            vr = jnp.sum(jnp.where(sub == j, v, 0.0), axis=0, keepdims=True)
+            # gated_delta_step, expression for expression
+            s = s_in[buf, j] * decay_ref[b, h]
+            r = jnp.sum(s * kc, axis=0, keepdims=True)
+            s = s + kc * (beta_ref[b, h] * (vr - r))
+            s_out[buf, j] = s
+            return jnp.where(sub == j, jnp.sum(s * qc, axis=0, keepdims=True), o)
+
+        o = lax.fori_loop(0, heads, head, jnp.zeros((heads, dv), F32))
+        o_ref[b, pl.ds(h0, heads), :] = o
+        store(t).start()
+
+    lax.fori_loop(0, items, item, None)
+    for back in (1, 2):  # the two stores still in flight, one a buffer
+        @pl.when(items >= back)
+        def _drain():
+            store(items - back).wait()
+
+
+def gated_delta_step_pooled(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
+                            interpret: Optional[bool] = None):
+    """`gated_delta_step` for the batch's rows that hold a token, on mixer
+    `gi`'s rows of a state pool, in place.
+
+    q, k [B, H, d_k]; v [B, H, d_v]; g, beta [B, H]; pool [L_gdn, slots, H,
+    d_k, d_v], all float32; gi: the mixer (a scalar, traced or not); rows [B]
+    int32: row b's slot, `slots` or more = unmapped; live [B] bool: the row
+    holds a token; fresh [B] bool: it starts its sequence (the state it
+    carries in is zeros, whatever the pool holds). No two rows with work
+    share a slot. Returns (o [B, H, d_v], pool'): for a live, mapped row
+    `gated_delta_step`'s o and its state' at pool'[gi, rows[b]]; any other
+    row's o is zeros, and every bit of the pool outside the worked rows'
+    matrices of mixer gi is as it was: nothing there is read or written.
+
+    One grid step; the pool is handed over whole in HBM and aliased to the
+    output; the rows with work are walked in blocks of `STEP_HEAD_BLOCK`
+    heads (the largest divisor of H up to it), one DMA in and one out a
+    block, double-buffered both ways, so a matrix crosses the memory bus
+    once each way; the loops over (row, block) and over a block's heads are
+    the kernel's own, so its body is one head's update whatever H is (the
+    heads of a row unrolled read the same device time at 1-3 live rows, 8%
+    less at 16, and cost every start 1.5 s a mixer of Mosaic's passes
+    where this costs 0.6 s in all: PERF.md section 6, PR 52). Inside:
+    float32 on the vector unit alone, `gated_delta_step`'s expressions in
+    its order; q and k are handed over [d_k, H], and a head's key is that
+    tile's lane h as a column (d_k down the sublanes, as the state's rows
+    are). The two sums over d_k are
+    the only place where the order of additions may differ from the plain
+    form's (on a v5e they come out bit-equal: PERF.md section 6, PR 52).
+    `interpret=None` compiles on a TPU backend and runs the Pallas
+    interpreter anywhere else; the caller decides whether the shapes suit
+    the compiled kernel (`gated_delta_kernel_suits`)."""
+    if interpret is None:
+        interpret = not compiled_kernels_available()
+    if pool.shape[2:] != q.shape[1:] + v.shape[2:] or pool.dtype != F32:
+        raise ValueError(f"pool {pool.shape} {pool.dtype} does not match "
+                         f"q {q.shape} / v {v.shape} in float32")
+    return _step_pooled_call(
+        q, k, v, g, beta, pool, gi, rows, live, fresh,
+        heads=_divisor(q.shape[1], STEP_HEAD_BLOCK), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _step_pooled_call(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
+                      heads: int, interpret: bool):
+    """`gated_delta_step_pooled`, jitted: a period's mixers call it with the
+    same shapes, and a jitted function is traced and lowered once a program
+    however many call it (Mosaic's passes run while the kernel is lowered,
+    before the compile cache is asked: every start pays them)."""
+    b, _, dk = q.shape
+    dv = v.shape[-1]
+    slot = jnp.where(live & (rows < pool.shape[1]), rows, -1)
+
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda *_: (0,) * x.ndim,
+                            memory_space=pltpu.VMEM)
+
+    qt, kt = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+    pool, o = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # the mixer, the rows' slots, their starts
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pltpu.SMEM),
+                      whole(qt), whole(kt), whole(v),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY), whole(v)],
+            scratch_shapes=[
+                pltpu.SMEM((b,), jnp.int32),
+                pltpu.VMEM((2, heads, dk, dv), F32),
+                pltpu.VMEM((2, heads, dk, dv), F32),
+                pltpu.SemaphoreType.DMA((2, 2)),  # (in | out, buffer)
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct(v.shape, F32)],
+        input_output_aliases={8: 0},  # the pool, counted with the scalars
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="gated_delta_step_pooled",
+    )(jnp.asarray(gi, jnp.int32).reshape(1), slot.astype(jnp.int32),
+      fresh.astype(jnp.int32), jnp.exp(g), beta, qt, kt, v, pool)
+    return o, pool

@@ -110,6 +110,9 @@ from picotron_tpu.config import (
 from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype, gdn_start
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
+from picotron_tpu.ops.gated_delta import (
+    gated_delta, gated_delta_kernel_suits, gated_delta_step_pooled,
+)
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
 )
@@ -930,14 +933,23 @@ class HybridPagedCache(NamedTuple):
     to its slot's row of both (`slots` itself: unmapped, the write drops).
     A state row is the slot's for good, so admission allocates nothing for
     it, and nothing on the host ever resets one: what a sequence carries
-    into position 0 is zeros whatever the row holds (`state_of`), so a
-    slot's next request, a request resumed after a preemption (its prefill
-    starts again at 0) and a decode dispatch still in flight for a request
-    that has left cannot leak into the sequence that follows. A row that
-    holds no real position in a dispatch (a padding row, an idle slot)
-    writes nothing (`put_state`). `generate._decode_layers` calls `write` /
-    `attend` with `ki` on a full layer and `state_of(gi, q_pos)` /
-    `put_state(gi, state, tail, q_pos)` on a mixer."""
+    into position 0 is zeros whatever the row holds (`state_of`, `tail_of`,
+    the kernel's `fresh`), so a slot's next request, a request resumed after
+    a preemption (its prefill starts again at 0) and a decode dispatch still
+    in flight for a request that has left cannot leak into the sequence
+    that follows. A row that holds no real position in a dispatch (a
+    padding row, an idle slot) writes nothing (`put_state`, `put_tail`, the
+    kernel's `live`). `generate._decode_layers` calls `write` / `attend` with
+    `ki` on a full layer; on a mixer `tail_of(gi, q_pos)` before the
+    convolution, `recur(gi, q, k, v, g, beta, q_pos)` for the recurrence and
+    `put_tail(gi, tail, q_pos)` after. `recur` answers for the state: a
+    decode step on a chip is ONE kernel over the state pool in place
+    (`ops.gated_delta.gated_delta_step_pooled`: the live rows' matrices are
+    read once and written once where they lie, an idle row costs nothing);
+    a prefill chunk, the tiny test models and every CPU run gather the
+    rows, run the plain rule and scatter them back (`state_of` ->
+    `ops.gated_delta.gated_delta` -> `put_state`). The tail (96 KiB a row)
+    is gathered and scattered in every case."""
 
     k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
     v: jnp.ndarray
@@ -980,31 +992,51 @@ class HybridPagedCache(NamedTuple):
                 return out[:, None]
             return MixedPagedKVCache._tiled(c, ki, q, q_pos, None)
 
-    def state_of(self, gi, q_pos):
-        """(state [B, Hv, d_k, d_v], tail [B, (kernel - 1) x channels]) the
-        dispatch's rows carry into positions q_pos [B, s]: mixer gi's rows
-        of their slots, zeros where a row starts at position 0."""
-        slots = self.state.shape[1]
-        rows = jnp.minimum(self.stables[:, 0], slots - 1)  # unmapped: discarded
+    def _carried(self, pool, gi, q_pos):
+        """Mixer gi's rows of `pool` that the dispatch's rows carry into
+        positions q_pos [B, s]: their slots', zeros where a row starts at
+        position 0."""
+        rows = jnp.minimum(self.stables[:, 0], pool.shape[1] - 1)  # unmapped: discarded
         fresh = q_pos[:, 0] == 0
+        x = pool[gi, rows]
+        return jnp.where(fresh.reshape((-1,) + (1,) * (x.ndim - 1)), 0, x)
 
-        def of(pool):
-            x = pool[gi, rows]
-            return jnp.where(fresh.reshape((-1,) + (1,) * (x.ndim - 1)), 0, x)
-
-        return of(self.state), of(self.tail)
-
-    def put_state(self, gi, state, tail, q_pos) -> "HybridPagedCache":
-        """What the rows carry on, into mixer gi's rows of their slots;
-        dropped for a row without a real position and for an unmapped
-        one."""
-        slots = self.state.shape[1]
+    def _carry_on(self, pool, gi, x, q_pos):
+        """`pool` with what the rows carry on in mixer gi's rows of their
+        slots; dropped for a row without a real position and for an
+        unmapped one."""
         rows = jnp.where(jnp.any(q_pos >= 0, axis=1), self.stables[:, 0],
-                         slots)
-        return self._replace(
-            state=self.state.at[gi, rows].set(state, mode="drop"),
-            tail=self.tail.at[gi, rows].set(tail.astype(self.tail.dtype),
-                                            mode="drop"))
+                         pool.shape[1])
+        return pool.at[gi, rows].set(x.astype(pool.dtype), mode="drop")
+
+    def state_of(self, gi, q_pos):
+        """The state [B, Hv, d_k, d_v] the rows carry into q_pos [B, s]."""
+        return self._carried(self.state, gi, q_pos)
+
+    def put_state(self, gi, state, q_pos) -> "HybridPagedCache":
+        return self._replace(state=self._carry_on(self.state, gi, state, q_pos))
+
+    def tail_of(self, gi, q_pos):
+        """The tail [B, (kernel - 1) x channels] the rows carry into q_pos."""
+        return self._carried(self.tail, gi, q_pos)
+
+    def put_tail(self, gi, tail, q_pos) -> "HybridPagedCache":
+        return self._replace(tail=self._carry_on(self.tail, gi, tail, q_pos))
+
+    def recur(self, gi, q, k, v, g, beta, q_pos):
+        """The gated delta rule over the segment (q, k [B, s, Hv, d_k]; v
+        [B, s, Hv, d_v]; g, beta [B, s, Hv]; q_pos [B, s]) from mixer gi's
+        state of the rows' slots -> (o [B, s, Hv, d_v], the cache with the
+        state after it). A decode step the kernel suits updates the pool in
+        place, the rows with a real position and a mapped slot alone;
+        everything else gathers, runs the plain rule and scatters."""
+        if gated_delta_kernel_suits(q.shape[1], self.state):
+            o, state = gated_delta_step_pooled(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], self.state,
+                gi, self.stables[:, 0], q_pos[:, 0] >= 0, q_pos[:, 0] == 0)
+            return o[:, None], self._replace(state=state)
+        o, state = gated_delta(q, k, v, g, beta, self.state_of(gi, q_pos))
+        return o, self.put_state(gi, state, q_pos)
 
     # -- what the serving engine asks (see `PagedKVCache`)
 
@@ -1051,11 +1083,17 @@ class HybridPagedCache(NamedTuple):
     def decode_counts(self, spans, cfg: ModelConfig) -> dict:
         """`kv_blocks` (what one full layer's kernel reads),
         `kv_blocks_banded` (summed over the full layers, the only ones that
-        read any: the name `MixedPagedKVCache` gives the same sum), and the
-        state's counts a step of the dispatch."""
+        read any: the name `MixedPagedKVCache` gives the same sum), the
+        state's counts a step of the dispatch, and beside `state_rows` what
+        the decode program's batch holds: `state_rows_batch` ((row, mixer)
+        pairs, a row a slot, live or idle) and `state_rows_idle` (those of
+        them without a token, whose state a step leaves where it lies)."""
         kv = PagedKVCache.decode_counts(self, spans, cfg)["kv_blocks"]
+        counts = self._state_counts(len(spans), 0)
+        batch = self.state.shape[0] * self.stables.shape[0]
         return dict(kv_blocks=kv, kv_blocks_banded=self.k.shape[1] * kv,
-                    **self._state_counts(len(spans), 0))
+                    **counts, state_rows_batch=batch,
+                    state_rows_idle=batch - counts["state_rows"])
 
 
 def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,

@@ -997,10 +997,12 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
     alone and the state pool a row a slot and mixer; neither, nor the tail
     pool, is copied whole, and all four ride their program in place (the rule
     the K/V pool is held to: the state pool is carried through the layer scan
-    and the decode program's step scan, gathered and scattered a mixer at a
-    time); the scan body is one period (L, L, L, F), so the decode step calls
-    the decode kernel once a body and the experts' grouped kernel four times;
-    the scopes the cell's metrics read are there."""
+    and the decode program's step scan; a prefill chunk gathers and scatters
+    its rows a mixer at a time, a decode step hands the pool whole to a
+    kernel that is aliased to it); the scan body is one period (L, L, L, F),
+    so the decode step calls the attention's kernel once a body, the state's
+    three times and the experts' grouped kernel four times; the scopes the
+    cell's metrics read are there."""
     config = "qwen3-next-80b-a3b-12l-ep8"
     comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
     text = comp.as_text()
@@ -1029,17 +1031,32 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
     attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
     paged = [(n, op) for n, op in kernels if attn.search(n)]
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
-    assert len(grouped) + len(paged) == len(kernels), kernels
+    state = [(n, op) for n, op in kernels if n.startswith("gated_delta_step_pooled")]
+    assert len(grouped) + len(paged) + len(state) == len(kernels), kernels
     assert len(grouped) % 4 == 0 and len(grouped) >= 4 and "ragged-dot" not in text
     assert all("moe_experts" in words(op) for _, op in grouped), grouped
+    # a batch's rows of one mixer's state, gathered or to be scattered
+    rows_of_state = f"f32[{rows or cache.state.shape[1]},32,128,128]"
+    # the chunked form's triangular solve, 64 x 64 a head and sub-chunk
+    solves = [n for n, _, line in ins if "InvertDiagBlocksLowerTriangular" in line]
     if program == "serve_decode":
         assert len(paged) == 1 and "attn_full" in words(paged[0][1])
+        # PR 52: a step's three mixers of the scan body update the state pool
+        # in place, one kernel each, under the scope the cell's metrics read;
+        # no row of state is gathered out of the pool or scattered back
+        assert len(state) == 3 and all({"gdn", "gdn_state"} <= words(op) for _, op in state)
+        assert rows_of_state not in text and not solves
     else:
         assert not paged  # a chunk walks its keys in tiles, no kernel
+        # ... and keeps the chunked recurrence: rows gathered, solved, scattered
+        assert not state and rows_of_state in text and len(solves) == 3
     ma = comp.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     print(program, rows, "total GiB", total / 2**30, "temp GiB",
           ma.temp_size_in_bytes / 2**30)
     assert total < 15.75 * 2**30, total / 2**30
+    if program == "serve_decode":
+        # what the program held before PR 52, with its 32 MiB gathered copies
+        assert total <= 10.37 * 2**30, total / 2**30
     assert_weights_read_in_place(text, config)

@@ -328,10 +328,10 @@ def test_the_cache_drops_what_it_must_and_resets_at_position_zero():
     # dispatch rows: slot 2 mid-sequence, slot 0 at its start, a padding row
     rows = cache._replace(stables=jnp.asarray([[2], [0], [3]], jnp.int32))
     pos = jnp.asarray([[8, 9, -1], [0, 1, 2], [-1, -1, -1]])
-    state, tail = rows.state_of(4, pos)
+    state, tail = rows.state_of(4, pos), rows.tail_of(4, pos)
     assert float(state[0].min()) == 2.0 and not np.asarray(state[1]).any()
     assert float(tail[0].min()) == 3.0 and not np.asarray(tail[1]).any()
-    wrote = rows.put_state(4, state + 1.0, tail + 1.0, pos)
+    wrote = rows.put_state(4, state + 1.0, pos).put_tail(4, tail + 1.0, pos)
     changed = np.asarray(jnp.any(wrote.state != cache.state, axis=(2, 3, 4)))
     assert changed.tolist() == [[g == 4 and s in (0, 2) for s in range(3)] for g in range(6)]
     assert np.asarray(jnp.any(wrote.tail != cache.tail, axis=2)).tolist() == changed.tolist()
@@ -343,7 +343,70 @@ def test_the_cache_drops_what_it_must_and_resets_at_position_zero():
     assert cache.prefill_counts([(0, 8), (8, 5)], cfg) == dict(
         state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=6)
     assert cache.decode_counts([(5, 2), (9, 2)], cfg) == dict(
-        kv_blocks=5, kv_blocks_banded=10, state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=0)
+        kv_blocks=5, kv_blocks_banded=10, state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=0,
+        state_rows_batch=18, state_rows_idle=6)
+
+
+def test_a_decode_step_through_the_kernel_serves_what_the_plain_path_serves(monkeypatch):
+    """A two-slot engine at widths the decode kernel takes (one period, 2 value
+    heads of 128 x 128 over one key head), driven through admission, a slot's
+    second and third request, a preemption and with it a decode dispatch in
+    flight for a request that has left: once as every CPU run serves it
+    (gather, `gated_delta_step`, scatter) and once with the decode steps
+    through `gated_delta_step_pooled` (the Pallas interpreter). The same
+    tokens; after every engine step the same state pool to float32 rounding,
+    and a row the plain path left alone in that step (idle, padding, a
+    prefill-only step) is left alone by the kernel too, to the bit."""
+    from picotron_tpu.serve import paged_cache
+
+    cfg = tiny(num_hidden_layers=4, layer_types=(GDN, GDN, GDN, F), linear_key_head_dim=128,
+               linear_value_head_dim=128, linear_num_key_heads=1, linear_num_value_heads=2)
+    params = weights(cfg)
+    requests = some_requests(cfg, ((14, 9), (11, 8), (9, 7), (5, 4)), seed=3)
+    calls = []
+
+    def served(kernel: bool):
+        jax.clear_caches()  # the engines of one process share their compiled programs
+        if kernel:
+            sound = paged_cache.gated_delta_step_pooled
+            monkeypatch.setattr(paged_cache, "gated_delta_kernel_suits", lambda s, pool: s == 1)
+            monkeypatch.setattr(paged_cache, "gated_delta_step_pooled",
+                                lambda *a, **k: calls.append(a[5].shape) or sound(*a, **k))
+        eng = ServeEngine(params, cfg, ServeConfig(
+            decode_slots=2, block_size=4, prefill_chunk=8, max_model_len=32, decode_interval=2,
+            num_blocks=9))
+        # state in every row, as earlier requests would have left it
+        eng._kv = jax.device_put(tuple(jnp.full(x.shape, 0.25 + i, x.dtype)
+                                       for i, x in enumerate(eng._kv)))
+        for i, (prompt, n) in enumerate(requests):
+            eng.submit(prompt, n, req_id=i)
+        pools = [np.asarray(eng._kv[2])]
+        while eng.sched.has_work():
+            eng.step(0.0)
+            pools.append(np.asarray(eng._kv[2]))
+        eng.close()
+        assert eng.pool.in_use == 0 and eng.sched.n_preempted > 0
+        assert eng.stats["decode_ahead"] > 0
+        return sorted(eng.results, key=lambda r: r["id"]), pools
+
+    try:
+        plain_out, plain_pools = served(False)
+        assert not calls
+        kernel_out, kernel_pools = served(True)
+    finally:
+        jax.clear_caches()  # no later engine may meet the programs traced here
+    # traced once a mixer of the one period, in the decode program alone
+    assert calls == [(3, 2, 2, 128, 128)] * 3
+    assert [r["tokens"] for r in kernel_out] == [r["tokens"] for r in plain_out]
+    assert len(plain_out) == 4 and len(kernel_pools) == len(plain_pools) > 8
+    for before, after, got_before, got in zip(plain_pools, plain_pools[1:], kernel_pools,
+                                              kernel_pools[1:]):
+        np.testing.assert_allclose(got, after, rtol=0, atol=1e-5)
+        left = ~np.any(after != before, axis=(2, 3, 4))  # [mixer, slot]
+        assert left.all(axis=0).tolist() == left.any(axis=0).tolist()  # a slot's mixers together
+        np.testing.assert_array_equal(got[left], got_before[left])
+    assert any(np.any(a != b) for a, b in zip(kernel_pools, kernel_pools[1:]))
+    held_to_the_reference(params, cfg, requests, kernel_out)
 
 
 def test_the_seeded_decays_are_a_trained_models_not_the_placeholders():
@@ -399,8 +462,8 @@ def test_the_benchmarks_reuse_phase_reads_the_state_the_slots_hold(wrong, monkey
         sound_put = paged_cache.HybridPagedCache.put_state
         monkeypatch.setattr(
             paged_cache.HybridPagedCache, "put_state",
-            lambda self, gi, state, tail, q_pos: sound_put(
-                self, gi, state.astype(jnp.bfloat16).astype(jnp.float32), tail, q_pos))
+            lambda self, gi, state, q_pos: sound_put(
+                self, gi, state.astype(jnp.bfloat16).astype(jnp.float32), q_pos))
     try:
         eng = ServeEngine(params, cfg, ServeConfig(decode_slots=3, block_size=4, prefill_chunk=8,
                                                    max_model_len=64, decode_interval=2))

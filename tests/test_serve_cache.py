@@ -55,8 +55,10 @@ KINDS = {
         rows=[ROW, [0]],  # the state row is the slot's own index
         # 6 mixers x 3 rows, 1,792 B a row both ways; one row starts at 0
         prefill=dict(state_rows=18, state_bytes=2 * 18 * 1792, state_resets=6),
+        # ... and of the decode batch's 6 x 3 (row, mixer) pairs none is idle
         decode=dict(kv_blocks=29, kv_blocks_banded=58,
-                    state_rows=18, state_bytes=2 * 18 * 1792, state_resets=0),
+                    state_rows=18, state_bytes=2 * 18 * 1792, state_resets=0,
+                    state_rows_batch=18, state_rows_idle=0),
         sched={})),
 }
 # (positions already cached, tokens of this chunk) a row of a prefill
@@ -116,3 +118,17 @@ def test_the_counts_a_dispatchs_span_carries(kind):
     assert cache.prefill_counts(PREFILL_SPANS, cfg) == want["prefill"]
     assert cache.decode_counts(DECODE_SPANS, cfg) == want["decode"]
     assert cache.decode_counts([], cfg)["kv_blocks"] == 0
+
+
+@pytest.mark.parametrize("live", range(SLOTS + 1))
+def test_the_state_rows_a_decode_batch_holds_and_those_it_leaves(live):
+    """`state_rows_batch`: mixers x the decode program's rows, a row a slot,
+    whatever is live; `state_rows_idle`: that less `state_rows`, the pairs
+    whose state a step leaves where it lies (`gdn_rows_skipped.serve` reads
+    the two)."""
+    cfg, cache, _ = made("state+full")
+    got = cache.decode_counts(DECODE_SPANS[:live], cfg)
+    mixers = cache.state.shape[0]
+    assert got["state_rows_batch"] == mixers * SLOTS == 18
+    assert got["state_rows"] == mixers * live
+    assert got["state_rows_idle"] == got["state_rows_batch"] - got["state_rows"]
