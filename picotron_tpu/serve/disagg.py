@@ -225,8 +225,7 @@ class DisaggServeEngine(ServeEngine):
             "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
             "prefill_chunks": 0, "occupancy_sum": 0.0,
             "prefill_occupancy_sum": 0.0, "prefill_ticks": 0,
-            "output_tokens": 0, "prefill_tokens": 0,
-            "decode_stall_ticks_max": 0, "cancelled": 0,
+            "output_tokens": 0, "decode_stall_ticks_max": 0, "cancelled": 0,
             "handoffs": 0, "handoff_s": 0.0, "handoff_blocks": 0,
         }
         self._init_step_account()
@@ -252,6 +251,12 @@ class DisaggServeEngine(ServeEngine):
                          self.sched.pslots[pslot])
 
     _PREFILL_PHASE = {"pool": "prefill"}
+
+    # two pools, each maybe on a device of its own with a stream of its
+    # own: the newest output says nothing of the other pool's work, so the
+    # device is not probed, the account's dry seconds stay 0 (what is in
+    # flight counts as fed) and a decode wait's `next_ready` reads -1
+    _PROBED = False
 
     def _prefill_pool(self):
         return (self.sched.pslots, self.cache_p, self._tables_p,

@@ -67,10 +67,23 @@ via CompileWatch), per-request TTFT and per-token latency land in the
 registry histograms and as ``serve_request`` / ``serve_summary`` JSONL
 events, and tools/telemetry_report.py renders the serving view
 (p50/p95 TTFT, tok/s, slot occupancy, pool utilization). Every step
-with device work adds up its own leaf spans (`step_account`): the
-seconds with nothing enqueued on the device go onto the ``serve.step``
-span, into a ``phase=serve_host`` event and into `stats`, and a step far
-over the median says which leaf held it (``serve_slow_step``).
+with device work adds up its own leaf spans (`step_account`), and gives
+every second of its PERIOD, from the end of the step with device work
+before it to its own end, one name, the first of these that fits:
+``empty`` (no request in the system: from the end of a step that left
+nothing pending to the next `submit`), ``starved`` (work pending and
+nothing enqueued on the device, inside the step; ``caller_starved`` the
+same between steps), ``dry`` (something enqueued that a probe has seen
+finished: between the leaf spans the engine asks the newest output it
+enqueued `is_ready()`, which does not block, so "in flight" does not
+pass for "fed"; a lower bound, with the slack up to the last probe that
+read running beside it), and ``fed``, the rest. The parts go onto the
+``serve.step`` span, into a ``phase=serve_host`` (starved) and a
+``phase=serve_dry`` event and into `stats`, with the profiler on or off.
+Every wait says whether the dispatch it waited for had finished when it
+began and whether the one behind it had when it ended (``ready``,
+``next_ready``), and a step far over the median says which leaf held it
+and, of a wait, both (``serve_slow_step``).
 """
 
 from __future__ import annotations
@@ -105,96 +118,6 @@ from picotron_tpu.telemetry.spans import join_ids
 
 
 log = logging.getLogger("picotron_tpu.serve")
-
-# A slow step: one of its parts is over the larger of SLOW_STEP_S and
-# SLOW_STEP_X times the median of the last SLOW_STEP_WINDOW parts of its own
-# kind, once SLOW_STEP_AFTER of them have been seen. The parts are each
-# `*.wait` leaf, by the dispatch it waited for (the host runs ahead of a
-# long prompt's chunks, and the one wait at its end is as long as all of
-# them), and the rest of the wall (`host`): a prefill dispatch's own time is
-# held against other prefill dispatches and not against decode steps. The
-# first SLOW_STEP_LOGS are logged, the rest counted.
-SLOW_STEP_S, SLOW_STEP_X = 0.25, 8
-SLOW_STEP_WINDOW, SLOW_STEP_AFTER, SLOW_STEP_LOGS = 64, 16, 32
-
-
-# The leaves that enqueue work on the device, and those that wait for it.
-_ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch",
-                       "serve.handoff"))
-_DECODE_DISPATCH, _DECODE_WAIT = "serve.decode.dispatch", "serve.decode.wait"
-_WAITS = frozenset(("serve.prefill.wait", _DECODE_WAIT))
-
-
-def step_account(leaves, t0: float, wall: float, in_flight=()) -> dict:
-    """Where one engine step's wall went, from its own leaf spans.
-
-    `leaves` are the step's leaf spans in the order they ran, `(name,
-    start, secs)` on the clock of `t0`, the step's start; `wall` is the
-    step's seconds so far. The device has work from the start of a
-    `*.dispatch` (or `serve.handoff`) leaf (`_ENQUEUES`) until a `*.wait`
-    leaf (`_WAITS`) has fetched its outputs, or those of something
-    enqueued behind it. A `serve.prefill.wait` fetches the dispatch
-    enqueued last, so it clears all that is in flight. A
-    `serve.decode.wait` fetches the OLDEST decode dispatch nobody has
-    fetched: the engine runs one decode dispatch ahead, so a newer one is
-    usually enqueued behind it and stays in flight, and the emit, the next
-    admit and the next build are fed by it. Where a prefill wait has
-    cleared that dispatch already, the decode wait clears nothing and is a
-    leaf like any other. `in_flight` names the leaves that enqueued what
-    the steps before left in flight, oldest first; `"in_flight"` of the
-    result is the same for the next step, and `waits` lists each wait that
-    cleared something as `(name, secs, dispatches it cleared)`.
-
-    `starved_s` is the wall outside those intervals: work pending and
-    nothing enqueued, so the device is idle whatever a profiler does to
-    the host. `starved_by` splits it by leaf (`unspanned`: the code
-    between spans), each leaf by the seconds of its own that were starved;
-    `unspanned_s` is the wall less the leaves, `leaves` each leaf's
-    seconds."""
-    fed = list(in_flight)  # the enqueues nobody waited for, oldest first
-    unfetched = 0  # decode dispatches a prefill wait cleared
-    at, spanned = t0, 0.0
-    secs_by: dict = {}
-    starved_by: dict = {}
-    waits = []
-    for name, start, secs in leaves:
-        if not fed and start > at:  # the code between two spans
-            starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
-                                       + start - at)
-        if name in _ENQUEUES:
-            fed.append(name)
-        secs_by[name] = secs_by.get(name, 0.0) + secs
-        spanned += secs
-        n = 0  # the enqueues this leaf clears, oldest first
-        if name == _DECODE_WAIT:
-            if unfetched:
-                unfetched -= 1
-            elif _DECODE_DISPATCH in fed:
-                n = fed.index(_DECODE_DISPATCH) + 1
-            else:
-                n = len(fed)
-        elif name in _WAITS:
-            n = len(fed)
-            unfetched += fed.count(_DECODE_DISPATCH)
-        if not fed:
-            starved_by[name] = starved_by.get(name, 0.0) + secs
-        elif n:
-            waits.append((name, secs, n))
-            del fed[:n]
-        at = start + secs
-    if not fed and t0 + wall > at:
-        starved_by["unspanned"] = (starved_by.get("unspanned", 0.0)
-                                   + t0 + wall - at)
-    return {"wall_s": wall, "starved_s": sum(starved_by.values()),
-            "unspanned_s": max(wall - spanned, 0.0), "leaves": secs_by,
-            "starved_by": starved_by, "waits": waits,
-            "in_flight": tuple(fed)}
-
-
-def _ms(secs_by: dict) -> dict:
-    """An account's seconds by leaf as an event's milliseconds."""
-    return {k: int(v * 1e6) / 1e3 for k, v in secs_by.items()}
-
 
 # ---------------------------------------------------------------------------
 # Device programs (module-level so every engine shares one jit cache). The
@@ -382,7 +305,202 @@ def prefill_rungs(num_slots: int) -> tuple:
 # The decode kernel's Mosaic body carries the file and line of its callers
 # (`serve_decode` among them) where the compile cache's key still sees them:
 # a change that moves the lines of the device programs above costs every
-# checkout at the same path one recompile of the serve programs.
+# checkout at the same path one recompile of the serve programs. So the
+# host's code stands below them, the step's account (PR 53 moved it here)
+# among it.
+
+
+# ---------------------------------------------------------------------------
+# The step's own account
+# ---------------------------------------------------------------------------
+
+# A slow step: one of its parts is over the larger of SLOW_STEP_S and
+# SLOW_STEP_X times the median of the last SLOW_STEP_WINDOW parts of its own
+# kind, once SLOW_STEP_AFTER of them have been seen. The parts are each
+# `*.wait` leaf, by the dispatch it waited for (the host runs ahead of a
+# long prompt's chunks, and the one wait at its end is as long as all of
+# them), and the rest of the wall (`host`): a prefill dispatch's own time is
+# held against other prefill dispatches and not against decode steps. The
+# first SLOW_STEP_LOGS are logged, the rest counted.
+SLOW_STEP_S, SLOW_STEP_X = 0.25, 8
+SLOW_STEP_WINDOW, SLOW_STEP_AFTER, SLOW_STEP_LOGS = 64, 16, 32
+
+
+# The leaves that enqueue work on the device, and those that wait for it.
+_ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch",
+                       "serve.handoff"))
+_DECODE_DISPATCH, _DECODE_WAIT = "serve.decode.dispatch", "serve.decode.wait"
+_WAITS = frozenset(("serve.prefill.wait", _DECODE_WAIT))
+# `dry_by`'s name for the seconds between two steps (the caller's), as
+# `unspanned` is its name for the code between two spans inside a step
+BETWEEN_STEPS = "between_steps"
+
+
+def _inside(a: float, b: float, intervals) -> float:
+    """The seconds of [a, b) inside `intervals` (disjoint, in order)."""
+    return sum(min(b, hi) - max(a, lo) for lo, hi in intervals
+               if lo < b and hi > a)
+
+
+def step_account(leaves, t0: float, wall: float, in_flight=(), probes=(),
+                 since: Optional[float] = None, empties=(),
+                 dry: bool = False) -> dict:
+    """Where one engine step's PERIOD went, from its own leaf spans, its
+    probes of the device and the stamps between steps. The period runs
+    from `since`, when the step with device work before this one ended
+    (None: from `t0`), to the end of this step's wall. Each second of it
+    has one name, the first of these that fits:
+
+    - ``empty``: no request in the system. `empties` are those intervals,
+      `(from, to)` in order: from the end of a step that left nothing
+      pending (what is in flight then is padding nobody waits for) to the
+      first `submit` after it. They lie between steps.
+    - ``starved``: work pending, nothing enqueued, so the device is idle
+      whatever a profiler does to the host. `starved_s` counts it inside
+      the step, by leaf in `starved_by` (`unspanned`: the code between
+      spans); `caller_starved_s` is the same between steps (the caller's
+      reading of the tokens, its submits, its sleep's granularity).
+    - ``dry``: something enqueued that a probe has seen finished: the
+      device ran out of work under a name the account calls in flight.
+    - ``fed``: the rest.
+
+    `leaves` are the step's leaf spans in the order they ran, `(name,
+    start, secs)` on the clock of `t0`, the step's start; `wall` is the
+    step's seconds so far. The device has work from the start of a
+    `*.dispatch` (or `serve.handoff`) leaf (`_ENQUEUES`) until a `*.wait`
+    leaf (`_WAITS`) has fetched its outputs, or those of something
+    enqueued behind it. A `serve.prefill.wait` fetches the dispatch
+    enqueued last, so it clears all that is in flight. A
+    `serve.decode.wait` fetches the OLDEST decode dispatch nobody has
+    fetched: the engine runs one decode dispatch ahead, so a newer one is
+    usually enqueued behind it and stays in flight, and the emit, the next
+    admit and the next build are fed by it. Where a prefill wait has
+    cleared that dispatch already, the decode wait clears nothing and is a
+    leaf like any other. `in_flight` names the leaves that enqueued what
+    the steps before left in flight, oldest first (it holds from `since`
+    on); `"in_flight"` of the result is the same for the next step, and
+    `waits` lists each wait that cleared something as `(name, secs,
+    dispatches it cleared)`.
+
+    `probes` are `(stamp, ready)` in order: whether the NEWEST output
+    enqueued had finished at `stamp` (one stream runs in order, so the
+    newest ready means all ready). From the first probe that reads ready
+    the device is dry, until the end of the next enqueuing leaf or of a
+    wait that leaves nothing in flight (`dry`: the period before ended
+    so). `dry_s` counts those seconds where something is in flight, by
+    leaf in `dry_by` (`between_steps`, `unspanned`): a lower bound, since
+    the device ran dry somewhere between the last probe that read running
+    (or the enqueue) and that first one; `dry_slack_s` are the in-flight
+    seconds between the two, so `dry_s + dry_slack_s` is the upper bound.
+    `"dry"` of the result is `dry` for the next step.
+
+    `unspanned_s` is the wall less the leaves, `leaves` each leaf's
+    seconds, `period_s` the period, `end` its last instant; `empty_s +
+    starved_s + caller_starved_s + dry_s + fed_s` is `period_s`, every
+    part counted and none the remainder of the others."""
+    fed = list(in_flight)  # the enqueues nobody waited for, oldest first
+    unfetched = 0  # decode dispatches a prefill wait cleared
+    begin, end = (t0 if since is None else since), t0 + wall
+    at = begin
+    # the period in pieces, each under one key and either in flight or not
+    pieces = []
+    empty_s = 0.0
+    for lo, hi in empties:  # between the steps, by how they are stamped
+        lo, hi = max(lo, at), min(hi, t0)
+        if hi > lo:
+            if lo > at:
+                pieces.append((BETWEEN_STEPS, at, lo, bool(fed)))
+            empty_s += hi - lo
+            at = hi
+    if t0 > at:
+        pieces.append((BETWEEN_STEPS, at, t0, bool(fed)))
+    # when what the probes watched was added to or had all been fetched:
+    # the end of an enqueuing leaf (and its start, with nothing in flight
+    # before it), the end of a wait that left nothing in flight
+    resets = []
+    at, spanned = t0, 0.0
+    secs_by: dict = {}
+    waits = []
+    for name, start, secs in leaves:
+        if start > at:  # the code between two spans
+            pieces.append(("unspanned", at, start, bool(fed)))
+        if name in _ENQUEUES:
+            if not fed:  # nothing in flight: nothing to have run dry
+                resets.append(start)
+            fed.append(name)
+            resets.append(start + secs)
+        secs_by[name] = secs_by.get(name, 0.0) + secs
+        spanned += secs
+        n = 0  # the enqueues this leaf clears, oldest first
+        if name == _DECODE_WAIT:
+            if unfetched:
+                unfetched -= 1
+            elif _DECODE_DISPATCH in fed:
+                n = fed.index(_DECODE_DISPATCH) + 1
+            else:
+                n = len(fed)
+        elif name in _WAITS:
+            n = len(fed)
+            unfetched += fed.count(_DECODE_DISPATCH)
+        pieces.append((name, start, start + secs, bool(fed)))
+        if fed and n:
+            waits.append((name, secs, n))
+            del fed[:n]
+            if not fed:
+                resets.append(start + secs)
+        at = start + secs
+    if end > at:
+        pieces.append(("unspanned", at, end, bool(fed)))
+
+    # the intervals the probes call dry, and the slack before each
+    dry_in, slack_in = [], []
+    dry_from = begin if dry else None
+    if probes or dry:
+        running_at = begin
+        # a reset before a probe with the same stamp: the sort is stable
+        marks = sorted([*((r, False) for r in resets), *probes],
+                       key=lambda m: m[0])
+        for stamp, ready in marks:
+            if not ready:  # a reset, or the newest output still running
+                if dry_from is not None:
+                    dry_in.append((dry_from, stamp))
+                    dry_from = None
+                running_at = stamp
+            elif dry_from is None:
+                dry_from = stamp
+                slack_in.append((running_at, stamp))
+        if dry_from is not None:
+            dry_in.append((dry_from, end))
+
+    starved_by: dict = {}
+    dry_by: dict = {}
+    caller_starved = fed_s = slack = 0.0
+    for key, lo, hi, flying in pieces:
+        if flying:
+            d = _inside(lo, hi, dry_in) if dry_in else 0.0
+            if d:
+                dry_by[key] = dry_by.get(key, 0.0) + d
+            fed_s += hi - lo - d
+            if slack_in:
+                slack += _inside(lo, hi, slack_in)
+        elif key is BETWEEN_STEPS:
+            caller_starved += hi - lo
+        else:
+            starved_by[key] = starved_by.get(key, 0.0) + hi - lo
+    return {"wall_s": wall, "starved_s": sum(starved_by.values()),
+            "unspanned_s": max(wall - spanned, 0.0), "leaves": secs_by,
+            "starved_by": starved_by, "waits": waits,
+            "in_flight": tuple(fed),
+            "period_s": wall + (t0 - begin), "end": end, "empty_s": empty_s,
+            "caller_starved_s": caller_starved,
+            "dry_s": sum(dry_by.values()), "dry_slack_s": slack,
+            "dry_by": dry_by, "fed_s": fed_s,
+            "dry": dry_from is not None and bool(fed)}
+
+
+def _ms(secs_by: dict) -> dict:
+    """An account's seconds by leaf as an event's milliseconds."""
+    return {k: int(v * 1e6) / 1e3 for k, v in secs_by.items()}
 
 
 def mesh_shardings(params):
@@ -414,6 +532,25 @@ def new_cache(model_cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
             jax.device_put(cache.pools, kv_sh),
             tuple(np.full((num_slots, width), unmapped, np.int32)
                   for width, unmapped in cache.table_specs))
+
+
+class _Probed:
+    """A leaf span of an engine with a probe of its device on either side
+    (`ServeEngine._probe`): the span itself knows nothing of devices."""
+
+    __slots__ = ("_engine", "_span")
+
+    def __init__(self, engine, span):
+        self._engine, self._span = engine, span
+
+    def __enter__(self):
+        self._engine._probe()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._engine._probe()
+        return False
 
 
 class ServeEngine:
@@ -503,8 +640,7 @@ class ServeEngine:
         self.shed_results: list = []
         self.stats = {
             "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
-            "prefill_chunks": 0, "occupancy_sum": 0.0,
-            "output_tokens": 0, "prefill_tokens": 0,
+            "prefill_chunks": 0, "occupancy_sum": 0.0, "output_tokens": 0,
             "decode_stall_ticks_max": 0, "cancelled": 0,
             # experts the decode steps' live rows were routed to and (row
             # tile, expert) pairs their kernel visited, out of layers x
@@ -550,6 +686,7 @@ class ServeEngine:
         self._next_auto_id = max(self._next_auto_id, req_id + 1)
         self.sched.submit(Request(req_id, tuple(prompt), max_new_tokens,
                                   arrival, deadline_ms))
+        self._woke(self._now())
         return req_id
 
     def cancel(self, request_id: int) -> bool:
@@ -574,22 +711,88 @@ class ServeEngine:
     # -- helpers -----------------------------------------------------------
 
     def _span(self, name: str, **counts):
-        """A leaf span on the serve lane (telemetry/spans.py); it adds
-        itself to the step's list when it ends."""
-        return self.telemetry.span(name, tid=TID_SERVE, into=self._leaves,
-                                   **counts)
+        """A leaf span on the serve lane (telemetry/spans.py) between two
+        probes of the device (`_probe`); it adds itself to the step's list
+        when it ends."""
+        return _Probed(self, self.telemetry.span(
+            name, tid=TID_SERVE, into=self._leaves, **counts))
+
+    def _now(self) -> float:
+        """Now, on the clock the spans are stamped on."""
+        tracer = self.telemetry.tracer
+        return tracer.clock() if tracer is not None else time.perf_counter()
+
+    # whether the device is probed: one stream runs in order, so the newest
+    # output enqueued says whether all of it has run (serve/disagg.py: two
+    # pools, maybe on two devices, and no dry counts)
+    _PROBED = True
+
+    def _enqueued(self, out) -> None:
+        """`out` is the newest output enqueued on the device: what the
+        probes ask until one reads ready."""
+        if self._PROBED:
+            self._newest, self._newest_ready = out, False
+
+    def _fetched(self, out) -> None:
+        """The host has `out`: if nothing was enqueued behind it, nothing is
+        left to probe."""
+        if self._newest is out:
+            self._newest = None
+
+    def _probe(self) -> int:
+        """Has the device run everything it was given? 1: the newest output
+        enqueued is ready; 0: it is not; -1: the host has fetched it, or
+        this engine is not probed. One non-blocking `is_ready()`, stamped
+        for `step_account`, and none once a probe has read ready."""
+        if self._newest is None:
+            return -1
+        if not self._newest_ready:
+            self._newest_ready = self._newest.is_ready()
+            self._probes.append((self._now(), self._newest_ready))
+        return int(self._newest_ready)
+
+    def _woke(self, stamp: float) -> None:
+        """A request is in the system at `stamp`: the empty interval that
+        the last step opened ends there (none open: nothing to do); the
+        first request of all starts the account."""
+        if self._since is None:
+            self._since = stamp
+        elif self._empty_from is not None:
+            self._empties.append((self._empty_from, stamp))
+        self._empty_from = None
 
     def _init_step_account(self) -> None:
         """The state behind `step`'s account of its own leaves, and its
         running totals in `stats` (over the steps that had device work)."""
         self.stats.update(step_wall_s=0.0, starved_s=0.0,
                           step_wall_max_s=0.0, slow_steps=0,
+                          # the steps' periods and their other parts
+                          # (`step_account`; fed is the rest)
+                          period_s=0.0, empty_s=0.0, caller_starved_s=0.0,
+                          dry_s=0.0, dry_slack_s=0.0,
+                          # `is_ready()` calls made for them (`_probe`)
+                          probes=0,
                           # of `decode_steps`, those enqueued while the
                           # dispatch before was in flight (`_decode_tick`)
                           decode_ahead=0)
         self._leaves: list = []  # this step's (name, start, secs)
         # the leaves that enqueued what the last steps left un-waited
         self._in_flight: tuple = ()
+        # the newest output enqueued on the device (None: fetched), whether
+        # a probe has read it ready, the period's (stamp, ready) probes,
+        # and whether the period before ended dry
+        self._newest, self._newest_ready = None, False
+        self._probes: list = []
+        self._dry = False
+        # when the last step with device work ended (None: no request
+        # yet), since when the system has been empty (None: it is not),
+        # and the period's closed (from, to) empty intervals
+        self._since: Optional[float] = None
+        self._empty_from: Optional[float] = None
+        self._empties: list = []
+        # (ready, next_ready) of the step's last wait of each name
+        self._wait_probes: dict = {}
+        self._prefill_seq = 0  # the number of the next prefill dispatch
         # the decode dispatch in flight (`_enqueue_decode`'s record), the
         # number of the next one, and when the last wait for either
         # program ended
@@ -789,35 +992,65 @@ class ServeEngine:
         count = gc.get_count()[1:]
         if count != self._gc_count:
             self._gc_count, self._gc_before = count, gc.get_stats()
+        self._wait_probes.clear()
         with self.telemetry.span("serve.step", tid=TID_SERVE) as sp:
+            if ((self._empty_from is not None or self._since is None)
+                    and self.sched.has_work()):
+                self._woke(sp.so_far()[0])  # a request came past `submit`
+            self._probe()
             self._settle_flying()
             worked = self._step(now)
+            self._probe()
             if worked:
                 self._account_step(sp)
+                end = self._since
             else:
                 # nothing pending (an un-waited prefill's request was
                 # cancelled or shed): what is enqueued runs out unwatched,
-                # and the next step with work starts its account afresh
+                # and the next step with work starts its account afresh;
+                # this step's seconds are part of that step's period
                 self._in_flight = ()
+                end = self._now()
+            if self._empty_from is None and not self.sched.has_work():
+                self._empty_from = end  # until the next `submit`
         return worked
 
     def _account_step(self, sp) -> None:
         """The step's account (`step_account`), three ways: as counts on
         its `serve.step` span, beside the device plane under a profile;
         as one `phase=serve_host` event (category `serve_host`, `secs` the
-        starved seconds); and in `stats`. A slow step says so."""
+        starved seconds) and one `phase=serve_dry` event (`secs` the dry
+        seconds; no category, since they lie inside the seconds the
+        `prefill` and `decode` phases book); and in `stats`. A slow step
+        says so. `_since` is then when the step's period ended."""
         t0, wall = sp.so_far()
-        acct = step_account(self._leaves, t0, wall, self._in_flight)
-        self._in_flight = acct["in_flight"]
-        starved = acct["starved_s"]
-        sp.set(wall_us=int(wall * 1e6), starved_us=int(starved * 1e6))
+        acct = step_account(self._leaves, t0, wall, self._in_flight,
+                            self._probes, self._since, self._empties,
+                            self._dry)
+        self._in_flight, self._dry = acct["in_flight"], acct["dry"]
+        self._since = acct["end"]
         st = self.stats
+        st["probes"] += len(self._probes)
+        self._probes.clear()
+        self._empties.clear()
+        starved, dry = acct["starved_s"], acct["dry_s"]
+        sp.set(wall_us=int(wall * 1e6), starved_us=int(starved * 1e6),
+               period_us=int(acct["period_s"] * 1e6),
+               empty_us=int(acct["empty_s"] * 1e6),
+               caller_starved_us=int(acct["caller_starved_s"] * 1e6),
+               dry_us=int(dry * 1e6),
+               dry_slack_us=int(acct["dry_slack_s"] * 1e6))
         st["step_wall_s"] += wall
         st["starved_s"] += starved
+        for part in ("period_s", "empty_s", "caller_starved_s", "dry_s",
+                     "dry_slack_s"):
+            st[part] += acct[part]
         if wall > st["step_wall_max_s"]:
             st["step_wall_max_s"] = wall
         self.telemetry.emit("phase", phase="serve_host",
                             category="serve_host", secs=starved,
+                            engine=self.engine_id)
+        self.telemetry.emit("phase", phase="serve_dry", secs=dry,
                             engine=self.engine_id)
         self._walls.append(wall)
         waits = acct["waits"]
@@ -843,20 +1076,29 @@ class ServeEngine:
         what else could hold a step (a compile, a collection, the load),
         and one WARNING line: the leaf that held the seconds says where to
         look (a wait: device or runtime; a build or emit: the host;
-        `unspanned`: the code between spans; none: the caller)."""
+        `unspanned`: the code between spans; none: the caller). A wait
+        says which of the two: `ready`, whether the dispatch it waited for
+        had finished when it began, and `next_ready`, whether the one
+        enqueued behind that had when it ended (-1: none was). A long wait
+        that ends with a 20 ms dispatch behind it finished means the
+        device kept running and the runtime held the host; one that ends
+        with it still running means the device or its queue was late."""
         self.stats["slow_steps"] += 1
         n = self.stats["slow_steps"]
         collections = [[g["collections"] for g in stats]
                        for stats in (self._gc_before, gc.get_stats())]
         active = sum(s is not None for s in self.sched.slots)
+        ready, next_ready = self._wait_probes.get(kind, (None, None))
         self.telemetry.emit(
             "serve_slow_step", held_by=kind, held_s=round(secs, 6),
             limit_s=round(limit, 6), held_for=cleared,
+            ready=ready, next_ready=next_ready,
             wall_s=round(acct["wall_s"], 6),
             starved_s=round(acct["starved_s"], 6),
             unspanned_ms=round(acct["unspanned_s"] * 1e3, 3),
             leaves_ms=_ms(acct["leaves"]),
             starved_by_ms=_ms(acct["starved_by"]),
+            dry_by_ms=_ms(acct["dry_by"]),
             compile_s=round(self._step_compile_s, 6),
             blocks_freed=self._step_blocks_freed,
             gc_before=collections[0], gc_after=collections[1],
@@ -869,11 +1111,14 @@ class ServeEngine:
                             key=lambda kv: kv[1])
         log.warning(
             "serve engine=%d slow step: %s %.3f s (limit %.3f for %d "
-            "dispatched) of wall %.3f s, longest leaf %s %.3f s, starved "
-            "%.3f s, compile %.3f s, blocks freed %d, collections %s -> "
-            "%s, active %d, queued %d%s", self.engine_id,
-            kind, secs, limit, cleared, acct["wall_s"], name, longest,
-            acct["starved_s"], self._step_compile_s,
+            "dispatched%s) of wall %.3f s, longest leaf %s %.3f s, starved "
+            "%.3f s, dry %.3f s, compile %.3f s, blocks freed %d, "
+            "collections %s -> %s, active %d, queued %d%s", self.engine_id,
+            kind, secs, limit, cleared,
+            "" if ready is None else
+            f"; ready={ready} as the wait began, next_ready={next_ready} "
+            "as it ended", acct["wall_s"], name, longest,
+            acct["starved_s"], acct["dry_s"], self._step_compile_s,
             self._step_blocks_freed, *collections, active,
             len(self.sched.queue),
             "; further slow steps are counted, not logged"
@@ -943,23 +1188,34 @@ class ServeEngine:
             # detection; also arms bench --serve)
             watchdog.touch(
                 f"serve engine={self.engine_id} dispatch=prefill")
+        seq = self._prefill_seq
+        self._prefill_seq += 1
         t0 = time.perf_counter()
         # `capacity` is what the program computes: the rung's `rows`, of
-        # which `slots` carry a request
+        # which `slots` carry a request; `seq` numbers the dispatch, and
+        # its wait carries the same: a chunk nobody waits for stays in
+        # flight across steps, as a decode dispatch does
         with self._span("serve.prefill.dispatch", slots=len(pslots),
                         rows=len(nval), tokens=n_prefilled,
                         capacity=len(nval) * self.scfg.prefill_chunk,
-                        ids=join_ids(req_ids),
+                        ids=join_ids(req_ids), seq=seq,
                         **cache.prefill_counts(
                             [(states[s].n_prefilled, int(nval[row]))
                              for row, s in enumerate(pslots)], self.cfg)):
             toks_d, logits_d = self._run_prefill(feed)
+            self._enqueued(toks_d)
         toks = None
         if finals:
             # the host needs a token only when a prompt ends in the
-            # chunk; otherwise the dispatch is left in flight
-            with self._span("serve.prefill.wait", finals=len(finals)):
+            # chunk; otherwise the dispatch is left in flight. `ready`:
+            # whether it had run when the wait began; nothing is enqueued
+            # behind the newest dispatch (`next_ready`)
+            probes = (int(toks_d.is_ready()), -1)
+            with self._span("serve.prefill.wait", finals=len(finals),
+                            seq=seq, ready=probes[0], next_ready=probes[1]):
                 toks, logits = jax.device_get((toks_d, logits_d))
+                self._fetched(toks_d)
+            self._wait_probes["serve.prefill.wait"] = probes
             self._waited_at = time.perf_counter()
         dt = time.perf_counter() - t0
         csecs = self._drain_compile()
@@ -975,7 +1231,6 @@ class ServeEngine:
         for row, s in enumerate(pslots):
             self.sched.note_prefilled(s, int(nval[row]))
         self.stats["prefill_chunks"] += len(pslots)
-        self.stats["prefill_tokens"] += n_prefilled
         if not finals:
             return True
         n_retired = n_freed = 0
@@ -1173,6 +1428,7 @@ class ServeEngine:
                 temperature=self.temperature, top_k=self.top_k,
                 interval=interval, eos_token_id=self.eos_token_id,
                 cache_cls=type(self.cache))
+            self._enqueued(toks_d)
         csecs = self._drain_compile()
         if csecs:
             self.stats["decode_compiles"] += 1
@@ -1197,11 +1453,19 @@ class ServeEngine:
         index) key where the request lives on. A retirement is stamped
         when the host has the token."""
         interval = self.scfg.decode_interval
-        with self._span("serve.decode.wait", seq=flying["seq"]) as sp:
+        # `ready`: whether the dispatch had run when the wait began;
+        # `next_ready`: whether what was enqueued behind it (the dispatch
+        # this step enqueued, as a rule) had when the wait ended
+        ready = int(flying["out"][0].is_ready())
+        with self._span("serve.decode.wait", seq=flying["seq"],
+                        ready=ready) as sp:
             # tokens and their logits [S, interval], and the experts the
             # steps touched and visited: known once the dispatch has run,
             # so the counts ride this span and not the dispatch's
             nxt, lgs, counts = jax.device_get(flying["out"])
+            self._fetched(flying["out"][0])
+            probes = self._wait_probes[_DECODE_WAIT] = (ready, self._probe())
+            sp.set(next_ready=probes[1])
             if self.cfg.num_experts:
                 step = dict(zip(expert_counts(self.cfg), map(int, counts)),
                             expert_slots=(self.cfg.stacks[-1].layers
@@ -1347,6 +1611,16 @@ class ServeEngine:
             "device_starved_share": (
                 round(self.stats["starved_s"] / self.stats["step_wall_s"], 4)
                 if self.stats["step_wall_s"] else None),
+            # the steps' periods (each from the end of the step with
+            # device work before it) and their parts, one name a second:
+            # empty, starved (`device_starved_share`'s, inside the steps),
+            # caller-starved, dry (a lower bound; with the slack, the
+            # upper), and the rest fed
+            **{k: round(self.stats[k], 6)
+               for k in ("period_s", "empty_s", "starved_s",
+                         "caller_starved_s", "dry_s", "dry_slack_s")},
+            "system_empty_share": self._share("empty_s"),
+            "device_dry_share": self._share("dry_s"),
             "step_wall_p50_s": (round(statistics.median(self._walls), 6)
                                 if self._walls else None),
             "step_wall_max_s": round(self.stats["step_wall_max_s"], 6),
@@ -1358,6 +1632,11 @@ class ServeEngine:
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
         }
+
+    def _share(self, part: str) -> Optional[float]:
+        """One part of the steps' periods as a share of them."""
+        period = self.stats["period_s"]
+        return round(self.stats[part] / period, 4) if period else None
 
     def close(self) -> None:
         self._flying = None  # a dispatch of padding nobody waited for

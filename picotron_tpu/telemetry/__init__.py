@@ -34,19 +34,24 @@ JSONL schema (one object per line; `ts` = time.time()):
       # tokens; prefill carries `waited` (whether `secs` includes the
       # host's wait for the device or only the enqueue); phase serve_host,
       # one an engine step with device work: `secs` the step's seconds
-      # with nothing enqueued on the device (serve/engine.py step_account)
+      # with nothing enqueued on the device (serve/engine.py step_account);
+      # phase serve_dry beside it, with NO category (its seconds lie inside
+      # the prefill and decode phases'): the step's period's seconds in
+      # which a probe had seen everything enqueued finished
   {"ts", "kind": "step",  "step", "loss", "tokens_per_sec",
    "tokens_per_sec_per_chip", "mfu", "trained_tokens", "memory_gb", ...}
   {"ts", "kind": "eval",  "step", "val_loss"}
   {"ts", "kind": <event>, ...}        # retry / chaos / guard / preempt /
                                       # recompile / watchdog_timeout ...
   {"ts", "kind": "serve_slow_step", "held_by", "held_s", "limit_s",
-   "held_for", "wall_s", "starved_s", "unspanned_ms", "leaves_ms", "starved_by_ms",
+   "held_for", "ready", "next_ready", "wall_s", "starved_s", "unspanned_ms",
+   "leaves_ms", "starved_by_ms", "dry_by_ms",
    "compile_s", "blocks_freed", "gc_before", "gc_after", "active", "queued",
    "engine"}
       # an engine step one of whose parts (a wait, by the `held_for`
       # dispatches it cleared, or `host`: the rest of the wall) is far
-      # over the median of its own kind
+      # over the median of its own kind; of a wait, whether its dispatch
+      # had finished when it began and the one behind it when it ended
   {"ts", "kind": "run_summary", "goodput": {...}, "metrics": {...}}
 """
 

@@ -48,7 +48,10 @@ Every timed second of the run is booked to exactly one category:
                      spans. The device is idle in them by construction,
                      so the ledger of a serving stream holds what the
                      device was fed (``prefill`` + ``decode``) beside
-                     what it waited for. Badput.
+                     what it waited for. Badput. (The engine's
+                     ``phase=serve_dry`` events carry NO category: dry
+                     seconds lie inside ``prefill`` / ``decode`` seconds,
+                     and the categories stay a partition of the wall.)
 - ``shed``         — serving only (serve/fleet.py deadline admission):
                      queue seconds burned by requests REJECTED because
                      their wait already exceeded their deadline. Pure

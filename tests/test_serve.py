@@ -1171,30 +1171,181 @@ ACCOUNT_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(ACCOUNT_CASES))
+# ---- the step's period and the probes (PR 53). Times in ms from the
+# period's start; a probe is (ms, whether the newest output was ready)
+PERIOD_CASES = {
+    # name: dict(in_flight, gap: ms from the end of the step before to this
+    #   step's start, empties: [(from, to)], dry: the period before ended
+    #   dry, spec: the leaves, tail, probes,
+    #   want: ms of (empty, starved, caller_starved, dry, slack),
+    #   dry_by, ends: (in flight, dry) at the end)
+    # a decode dispatch is "in flight" and runs out in the middle of a
+    # prefill build: dry from the first probe that says so (the build's
+    # end) to the end of the next enqueue, the build itself the slack
+    "dry_in_the_middle_of_a_build": dict(
+        in_flight=(DD,), gap=1,
+        spec=[("serve.admit", 0.5, 1), ("serve.prefill.build", 0, 8),
+              ("serve.prefill.dispatch", 0, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 1), ("serve.decode.wait", 0, 5),
+              ("serve.decode.emit", 0, 2)],
+        tail=0.5,
+        probes=[(1, False), (1.5, False), (2.5, False), (10.5, True),
+                (11.5, False), (13.5, False), (14.5, False), (19.5, False),
+                (21.5, False), (22, False)],
+        want=(0, 0, 0, 1, 8), dry_by={"serve.prefill.dispatch": 1},
+        ends=((PD, DD), False)),
+    # the steady state, probed round every leaf: never dry
+    "never_dry": dict(
+        in_flight=(DD,), gap=2,
+        spec=[("serve.admit", 0.5, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 20),
+              ("serve.decode.emit", 0.5, 4)],
+        tail=1.5,
+        probes=[(2, False), (2.5, False), (3.5, False), (5.5, False),
+                (8.5, False), (28.5, False), (29, False), (33, False),
+                (34.5, False)],
+        want=(0, 0, 0, 0, 0), dry_by={}, ends=((DD,), False)),
+    # the dispatch in flight ended while the caller held the loop: the
+    # step's first probe says so, the seconds between the steps are the
+    # slack, and the device is dry until the next dispatch is enqueued
+    "dry_between_two_steps": dict(
+        in_flight=(DD,), gap=6,
+        spec=[("serve.admit", 0, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 1), ("serve.decode.wait", 0, 0.5),
+              ("serve.decode.emit", 0, 2)],
+        tail=0.5,
+        probes=[(6, True), (10, False), (10.5, False), (12.5, False),
+                (13, False)],
+        want=(0, 0, 0, 4, 6),
+        dry_by={"serve.admit": 1, "serve.decode.build": 2,
+                "serve.decode.dispatch": 1},
+        ends=((DD,), False)),
+    # ... and the step after a step that ended dry, with nothing to
+    # enqueue: dry from the period's first second to its last
+    "dry_carried_over": dict(
+        in_flight=(PD,), gap=3, dry=True,
+        spec=[("serve.admit", 0, 1)], tail=1, probes=[],
+        want=(0, 0, 0, 5, 0),
+        dry_by={"between_steps": 3, "serve.admit": 1, "unspanned": 1},
+        ends=((PD,), True)),
+    # a long prompt's chunk left in flight, every probe running: fed
+    "a_probe_that_never_reads_ready": dict(
+        in_flight=(PD,), gap=1,
+        spec=[("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+              ("serve.prefill.dispatch", 0, 3)],
+        tail=1,
+        probes=[(1, False), (2, False), (4, False), (7, False), (8, False)],
+        want=(0, 0, 0, 0, 0), dry_by={}, ends=((PD, PD), False)),
+    # the last request ended on an EOS with a dispatch of padding behind
+    # it, which a probe saw finished: empty until the submit, whatever is
+    # in flight, and dry only from there
+    "empty_over_dry": dict(
+        in_flight=(DD,), gap=10, empties=[(0, 7)], dry=True,
+        spec=[("serve.admit", 0, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 1)],
+        tail=1, probes=[(14, False), (15, False)],
+        want=(7, 0, 0, 7, 0),
+        dry_by={"between_steps": 3, "serve.admit": 1,
+                "serve.decode.build": 2, "serve.decode.dispatch": 1},
+        ends=((DD, DD), False)),
+    # ... and the same once the engine has forgotten that dispatch: with
+    # nothing in flight the seconds are the caller's and the host's,
+    # starved, and a probe's word does not make them dry
+    "empty_over_starved_over_dry": dict(
+        in_flight=(), gap=10, empties=[(0, 7)], dry=True,
+        spec=[("serve.admit", 0, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 1)],
+        tail=1, probes=[(10, True), (14, False), (15, False)],
+        want=(7, 3, 3, 0, 0), dry_by={}, ends=((DD,), False)),
+    # the chunk had run before its wait began, which fetched everything:
+    # dry up to the wait's end and no further (the emit and the build are
+    # starved, and the next dispatch starts afresh)
+    "a_wait_that_leaves_nothing_ends_the_dry": dict(
+        in_flight=(DD,), gap=0,
+        spec=[("serve.admit", 0, 1), ("serve.prefill.build", 0, 2),
+              ("serve.prefill.dispatch", 0, 3), ("serve.prefill.wait", 0, 10),
+              ("serve.prefill.emit", 0, 1), ("serve.decode.build", 0, 2),
+              ("serve.decode.dispatch", 0, 3), ("serve.decode.wait", 0, 0.5)],
+        tail=0,
+        probes=[(0, False), (1, True), (6, True)],
+        want=(0, 3, 0, 15, 1),
+        dry_by={"serve.prefill.build": 2, "serve.prefill.dispatch": 3,
+                "serve.prefill.wait": 10},
+        ends=((DD,), False)),
+}
+
+
+def _account_case(case):
+    """A case of either table as `step_account`'s arguments and what it
+    should return, the period's parts in ms."""
+    t0 = 1000.0
+    if case in ACCOUNT_CASES:  # a step alone: no probe, no gap
+        in_flight, spec, tail, starved_by, fed_ms, cleared, ends = \
+            ACCOUNT_CASES[case]
+        c = dict(in_flight=in_flight, spec=spec, tail=tail,
+                 want=(0, sum(starved_by.values()), 0, 0, 0),
+                 ends=(ends, False))
+    else:
+        c = PERIOD_CASES[case]
+    gap = c.get("gap")
+    since = None if gap is None else t0 - gap * MS
+    begin = t0 if since is None else since
+    leaves, spanned_to = _leaves(t0, *c["spec"])
+    kw = dict(
+        in_flight=c["in_flight"], since=since, dry=c.get("dry", False),
+        probes=[(begin + ms * MS, ready) for ms, ready in c.get("probes", ())],
+        empties=[(begin + a * MS, begin + b * MS)
+                 for a, b in c.get("empties", ())])
+    return c, leaves, t0, spanned_to + c["tail"] * MS, kw
+
+
+@pytest.mark.parametrize("case", sorted({**ACCOUNT_CASES, **PERIOD_CASES}))
 def test_step_account_on_hand_made_leaves(case):
     from picotron_tpu.serve.engine import step_account
 
-    in_flight, spec, tail, want, fed_ms, cleared, ends = ACCOUNT_CASES[case]
-    t0 = 1000.0
-    leaves, spanned_to = _leaves(t0, *spec)
-    wall = spanned_to + tail * MS
-    a = step_account(leaves, t0, wall, in_flight)
-    assert a["wall_s"] == wall and a["in_flight"] == ends
+    c, leaves, t0, wall, kw = _account_case(case)
+    a = step_account(leaves, t0, wall, **kw)
+    spec, tail = c["spec"], c["tail"]
+    assert a["wall_s"] == wall and a["end"] == t0 + wall
+    assert (a["in_flight"], a["dry"]) == c["ends"]
     assert isinstance(a["in_flight"], tuple)
-    assert [n for _, _, n in a["waits"]] == cleared
     assert all(secs == a["leaves"][name] for name, secs, _ in a["waits"])
-    assert {k: round(v / MS, 6) for k, v in a["starved_by"].items()} == {
-        k: float(v) for k, v in want.items()}
-    assert a["starved_s"] == pytest.approx(sum(want.values()) * MS, abs=1e-9)
-    # starved + in flight = wall, with no clamp to make it so
-    assert a["starved_s"] + fed_ms * MS == pytest.approx(wall, abs=1e-9)
     # each leaf's seconds, and the wall less the leaves
     assert sum(a["leaves"].values()) == pytest.approx(
         sum(ms for _, _, ms in spec) * MS, abs=1e-9)
     assert a["unspanned_s"] == pytest.approx(
         (sum(gap for _, gap, _ in spec) + tail) * MS, abs=1e-9)
     assert set(a["leaves"]) == {name for name, _, _ in spec}
+    # every second of the period has one name: the five parts, each
+    # counted from its own pieces, add up to it with no clamp and no
+    # remainder, and each is what was added up by hand
+    period = wall + (c.get("gap") or 0) * MS
+    assert a["period_s"] == pytest.approx(period, abs=1e-9)
+    parts = [a[k] for k in ("empty_s", "starved_s", "caller_starved_s",
+                            "dry_s", "fed_s")]
+    assert all(x >= 0.0 for x in parts)
+    assert sum(parts) == pytest.approx(period, abs=1e-9)
+    got = [a[k] for k in ("empty_s", "starved_s", "caller_starved_s",
+                          "dry_s", "dry_slack_s")]
+    assert [round(x / MS, 6) for x in got] == [float(x) for x in c["want"]]
+    # the slack is in-flight time the probes could not name: part of fed
+    assert a["dry_slack_s"] <= a["fed_s"] + 1e-12
+    assert a["starved_s"] == pytest.approx(sum(a["starved_by"].values()))
+    assert a["dry_s"] == pytest.approx(sum(a["dry_by"].values()))
+    if case in PERIOD_CASES:
+        assert {k: round(v / MS, 6) for k, v in a["dry_by"].items()} == {
+            k: float(v) for k, v in c["dry_by"].items()}
+        return
+    # ---- a step alone (PR 37, PR 48): the starved seconds by leaf, the
+    # waits, and nothing of what came with the period
+    _, _, _, want, fed_ms, cleared, _ = ACCOUNT_CASES[case]
+    assert [n for _, _, n in a["waits"]] == cleared
+    assert {k: round(v / MS, 6) for k, v in a["starved_by"].items()} == {
+        k: float(v) for k, v in want.items()}
+    # starved + in flight = wall, with no clamp to make it so
+    assert a["starved_s"] + fed_ms * MS == pytest.approx(wall, abs=1e-9)
+    assert a["fed_s"] == pytest.approx(fed_ms * MS, abs=1e-9)
+    assert a["period_s"] == wall and a["dry_by"] == {}
 
 
 class _Events:
@@ -1234,6 +1385,24 @@ def test_serve_host_events_add_up_to_the_stats(tiny, requests5, disagg):
     assert sum(e["secs"] for e in host) == pytest.approx(st["starved_s"], abs=tol)
     assert tel.ledger.seconds["serve_host"] == pytest.approx(st["starved_s"])
     assert 0.0 < st["starved_s"] <= st["step_wall_s"]
+    # ... and one `phase=serve_dry` event beside it, whose seconds lie
+    # inside those the decode and prefill phases book: no category, so
+    # neither the ledger nor the report's sums take them for more wall
+    dry = [e for e in cap.events
+           if e["kind"] == "phase" and e.get("phase") == "serve_dry"]
+    assert len(dry) == len(host)
+    assert all(set(e) == {"ts", "kind", "phase", "secs", "engine"}
+               for e in dry)
+    assert sum(e["secs"] for e in dry) == pytest.approx(st["dry_s"], abs=tol)
+    assert "serve_dry" not in tel.ledger.seconds
+    assert st["period_s"] >= st["step_wall_s"]
+    assert (st["empty_s"] + st["starved_s"] + st["caller_starved_s"]
+            + st["dry_s"]) <= st["period_s"]
+    if disagg:  # its two pools are not probed (serve/disagg.py)
+        assert st["probes"] == 0 and st["dry_s"] == st["dry_slack_s"] == 0.0
+        assert eng.summary["device_dry_share"] == 0.0
+    else:
+        assert st["probes"] > 0
     assert st["step_wall_max_s"] == max(eng._walls)
     assert sum(eng._walls) == pytest.approx(st["step_wall_s"])
     assert st["slow_steps"] == 0 and "steps" not in st
@@ -1283,6 +1452,11 @@ def test_slow_step_makes_one_event_and_one_log_line(tiny, monkeypatch, caplog):
     assert len(slow) == 1 and eng.stats["slow_steps"] == 1
     (e,) = slow
     assert e["held_by"] == "serve.decode.wait"
+    # the dispatch behind the one awaited ran out under the sleeping wait:
+    # the device kept running, and it was not the device that was late
+    assert e["ready"] in (0, 1) and e["next_ready"] == 1
+    assert set(e["dry_by_ms"]) <= set(e["leaves_ms"]) | {"unspanned",
+                                                          "between_steps"}
     assert e["wall_s"] > e["held_s"] > e["limit_s"] >= engine_mod.SLOW_STEP_S
     assert max(e["leaves_ms"], key=e["leaves_ms"].get) == "serve.decode.wait"
     assert e["leaves_ms"]["serve.decode.wait"] >= engine_mod.SLOW_STEP_S * 1e3
@@ -1294,6 +1468,8 @@ def test_slow_step_makes_one_event_and_one_log_line(tiny, monkeypatch, caplog):
     records = [r for r in caplog.records if r.name == "picotron_tpu.serve"]
     assert len(records) == 1 and records[0].levelname == "WARNING"
     assert "longest leaf serve.decode.wait" in records[0].getMessage()
+    assert (f"ready={e['ready']} as the wait began, next_ready=1 as it ended"
+            in records[0].getMessage())
     assert eng.stats["step_wall_max_s"] >= e["wall_s"] - 1e-6
     eng.close()
 
@@ -1379,6 +1555,8 @@ def test_a_wait_is_slow_among_its_own_kind(tiny, monkeypatch, caplog,
         assert slow[0]["held_by"] == "serve.prefill.wait"
         assert slow[0]["limit_s"] == engine_mod.SLOW_STEP_S
         assert slow[0]["held_for"] == -(-prompt_len // 4)
+        # nothing is enqueued behind the chunk a prefill wait waits for
+        assert slow[0]["ready"] in (0, 1) and slow[0]["next_ready"] == -1
         assert f"for {slow[0]['held_for']} dispatched" in caplog.messages[0]
     eng.close()
 
@@ -1412,7 +1590,12 @@ def test_a_slow_host_is_told_from_a_slow_wait(tiny, monkeypatch, caplog):
             eng.step(0.0)
     (e,) = [e for e in cap.events if e["kind"] == "serve_slow_step"]
     assert e["held_by"] == "host" and e["held_s"] > e["limit_s"]
+    assert e["ready"] is None and e["next_ready"] is None  # no wait held it
     assert e["leaves_ms"]["serve.admit"] >= engine_mod.SLOW_STEP_S * 1e3
+    # ... by the account; the probes say the dispatch ran out before or
+    # under the sleeping admit (its seconds dry, or the slack): dry at the
+    # latest from the admit's end to the end of the next dispatch
+    assert e["dry_by_ms"]
     assert "serve.admit" not in e["starved_by_ms"] and e["starved_s"] < 0.1
     (record,) = caplog.records
     assert "slow step: host" in record.getMessage()
@@ -1447,6 +1630,159 @@ def test_an_idle_poll_is_no_step_and_ends_what_was_in_flight(tiny):
     assert last["secs"] > 0.0  # its admit and build were starved
     assert eng.stats["starved_s"] <= eng.stats["step_wall_s"]
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the step's period (PR 53): empty, starved, caller-starved, dry, fed
+# ---------------------------------------------------------------------------
+
+
+def _starved_as_pr48(leaves, t0, wall, in_flight):
+    """`starved_s` as the function of PR 48 added it up (a copy of its
+    loop), for the scenarios in which nothing else may have changed."""
+    from picotron_tpu.serve.engine import (
+        _DECODE_DISPATCH as DDN, _DECODE_WAIT as DWN, _ENQUEUES, _WAITS,
+    )
+
+    fed, unfetched, at, starved = list(in_flight), 0, t0, 0.0
+    for name, start, secs in leaves:
+        if not fed and start > at:
+            starved += start - at
+        if name in _ENQUEUES:
+            fed.append(name)
+        n = 0
+        if name == DWN:
+            if unfetched:
+                unfetched -= 1
+            elif DDN in fed:
+                n = fed.index(DDN) + 1
+            else:
+                n = len(fed)
+        elif name in _WAITS:
+            n = len(fed)
+            unfetched += fed.count(DDN)
+        if not fed:
+            starved += secs
+        elif n:
+            del fed[:n]
+        at = start + secs
+    if not fed and t0 + wall > at:
+        starved += t0 + wall - at
+    return starved
+
+
+def _recording_accounts(monkeypatch):
+    """Every `step_account` call of the engines built after this, as
+    (its arguments, its result)."""
+    from picotron_tpu.serve import engine as engine_mod
+
+    calls, real = [], engine_mod.step_account
+
+    def recording(leaves, t0, wall, in_flight, probes, since, empties, dry):
+        a = real(leaves, t0, wall, in_flight, probes, since, empties, dry)
+        calls.append((dict(leaves=list(leaves), t0=t0, wall=wall,
+                           in_flight=in_flight, probes=list(probes),
+                           since=since, empties=list(empties), dry=dry), a))
+        return a
+
+    monkeypatch.setattr(engine_mod, "step_account", recording)
+    return calls
+
+
+def test_an_idle_second_before_a_submit_is_empty_not_starved(
+        tiny, requests5, monkeypatch):
+    """Two requests, an idle second, three more: the second is `empty_s`
+    and none of it starved; every step's five parts add up to its period
+    to the microsecond, the periods tile the run from the first submit to
+    the last step's end, the span carries the same counts, and the starved
+    seconds are what PR 48's function made of the same leaves."""
+    import time as _time
+
+    eng, tel = traced_engine(tiny)
+    calls = _recording_accounts(monkeypatch)
+    t_first = eng._now()
+    for p, n in requests5[:2]:
+        eng.submit(p, n)
+    while eng.sched.has_work():
+        eng.step(0.0)
+    before = dict(eng.stats)
+    assert before["empty_s"] == 0.0 and eng._empty_from is not None
+    _time.sleep(1.0)
+    for p, n in requests5[2:]:
+        eng.submit(p, n)
+    assert eng._empty_from is None and len(eng._empties) == 1
+    while eng.sched.has_work():
+        eng.step(0.0)
+    st = eng.stats
+    assert 1.0 <= st["empty_s"] < 2.0
+    # none of the idle second is starved, by either name
+    assert st["starved_s"] + st["caller_starved_s"] < 0.9
+    (woke,) = [a for kw, a in calls if a["empty_s"] > 0.0]
+    assert woke["empty_s"] == st["empty_s"]
+    assert woke["period_s"] > woke["wall_s"] + 1.0
+    parts = ("empty_s", "starved_s", "caller_starved_s", "dry_s", "fed_s")
+    for kw, a in calls:
+        assert sum(a[k] for k in parts) == pytest.approx(a["period_s"],
+                                                         abs=1e-6)
+        assert all(a[k] >= 0.0 for k in parts)
+        assert a["dry_slack_s"] <= a["fed_s"] + 1e-9
+        assert a["starved_s"] == pytest.approx(_starved_as_pr48(
+            kw["leaves"], kw["t0"], kw["wall"], kw["in_flight"]), abs=1e-9)
+    # the periods tile the time since the first submit
+    assert calls[0][0]["since"] == pytest.approx(t_first, abs=0.5)
+    for (_, a), (kw, _) in zip(calls, calls[1:]):
+        assert kw["since"] == a["end"]
+    assert st["period_s"] == pytest.approx(
+        calls[-1][1]["end"] - calls[0][0]["since"], abs=1e-6)
+    for k in ("period_s", "empty_s", "caller_starved_s", "dry_s",
+              "dry_slack_s", "starved_s"):
+        assert st[k] == pytest.approx(sum(a[k] for _, a in calls))
+    assert st["probes"] == sum(len(kw["probes"]) for kw, _ in calls) > 0
+    # the `serve.step` spans carry each account, in whole microseconds
+    steps = [e["args"] for e in tel.tracer.to_json()["traceEvents"]
+             if e["ph"] == "X" and e["name"] == "serve.step" and e.get("args")]
+    assert len(steps) == len(calls)
+    for c, (_, a) in zip(steps, calls):
+        assert set(c) == {"wall_us", "starved_us", "period_us", "empty_us",
+                          "caller_starved_us", "dry_us", "dry_slack_us"}
+        for k in ("period", "empty", "caller_starved", "dry", "dry_slack",
+                  "starved", "wall"):
+            assert c[f"{k}_us"] == int(a[f"{k}_s"] * 1e6)
+        assert (c["empty_us"] + c["starved_us"] + c["caller_starved_us"]
+                + c["dry_us"]) <= c["period_us"]
+    s = eng._summary_dict(1.0)
+    assert s["system_empty_share"] == round(st["empty_s"] / st["period_s"], 4)
+    assert s["device_dry_share"] == round(st["dry_s"] / st["period_s"], 4)
+    assert 0.05 < s["system_empty_share"] < 1.0
+    assert s["empty_s"] == round(st["empty_s"], 6)
+    eng.close()
+
+
+def test_prefill_dispatches_are_numbered_and_waits_say_what_they_found(tiny):
+    """`serve.prefill.dispatch` and `.wait` carry `seq` as the decode pair
+    does: a chunk nobody waited for stays in flight, and the wait at the
+    prompt's end carries its own chunk's number. Every wait says whether
+    its dispatch had run when it began (`ready`) and whether what was
+    enqueued behind it had when it ended (`next_ready`; -1: nothing)."""
+    eng, tel = traced_engine(tiny)
+    eng.submit(list(range(1, 12)), 5)  # three chunks, two decode dispatches
+    while eng.sched.has_work():
+        eng.step(0.0)
+    eng.close()
+    spans = sorted((e for e in tel.tracer.to_json()["traceEvents"]
+                    if e["ph"] == "X"), key=lambda e: e["ts"])
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e.get("args", {}))
+    assert [a["seq"] for a in by["serve.prefill.dispatch"]] == [0, 1, 2]
+    (pw,) = by["serve.prefill.wait"]
+    assert pw["seq"] == 2 and pw["ready"] in (0, 1) and pw["next_ready"] == -1
+    waits = by["serve.decode.wait"]
+    assert [a["seq"] for a in waits] == [0, 1]
+    assert all(a["ready"] in (0, 1) for a in waits)
+    # dispatch 1 was enqueued behind dispatch 0; nothing behind dispatch 1
+    assert waits[0]["next_ready"] in (0, 1) and waits[1]["next_ready"] == -1
+    assert eng._newest is None  # all fetched: nothing left to probe
 
 
 # ---------------------------------------------------------------------------

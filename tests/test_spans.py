@@ -147,7 +147,12 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
     # `step_account`): what the wall was and what of it the device starved
     for _, lo, hi, c in steps:
         assert 0 <= c["starved_us"] <= c["wall_us"] <= (hi - lo) / 1e3 + 1
-        assert set(c) == {"wall_us", "starved_us"}
+        # ... and, since PR 53, of its whole period: one name a second
+        assert set(c) == {"wall_us", "starved_us", "period_us", "empty_us",
+                          "caller_starved_us", "dry_us", "dry_slack_us"}
+        assert c["wall_us"] <= c["period_us"] + 1
+        assert (c["empty_us"] + c["starved_us"] + c["caller_starved_us"]
+                + c["dry_us"]) <= c["period_us"]
     host = [e for e in events
             if e.get("kind") == "phase" and e.get("phase") == "serve_host"]
     assert len(host) == len(steps)

@@ -423,6 +423,63 @@ def test_serve_host_category_reproduces_and_leaves_training_alone(tmp_path):
     tel.close()
 
 
+def test_report_prints_the_period_and_what_a_slow_wait_found(tmp_path):
+    """The serving view prints the partition of the steps' periods from the
+    serve_summary (empty / starved / caller-starved / dry + slack / fed, in
+    seconds and %), a slow step's `ready` / `next_ready` in its line, and
+    takes the `phase=serve_dry` events for no category: their seconds lie
+    inside `decode` and `prefill`, and the sums stay a partition."""
+    report = load_report()
+    p = str(tmp_path / "serve.jsonl")
+    tel = Telemetry(sinks=[JsonlSink(p)])
+    tel.emit("phase", phase="decode", category="decode", secs=3.0)
+    tel.emit("phase", phase="serve_host", category="serve_host", secs=1.0,
+             engine=0)
+    for secs in (0.5, 0.0):
+        tel.emit("phase", phase="serve_dry", secs=secs, engine=0)
+    tel.emit("serve_slow_step", held_by="serve.decode.wait", held_s=2.7,
+             limit_s=0.25, wall_s=2.7, starved_s=0.0, unspanned_ms=0.1,
+             engine=0, blocks_freed=0, ready=0, next_ready=1,
+             leaves_ms={"serve.decode.wait": 2700.0}, dry_by_ms={})
+    tel.emit("serve_slow_step", held_by="host", held_s=0.4, limit_s=0.25,
+             wall_s=0.4, starved_s=0.0, unspanned_ms=0.1, engine=0,
+             blocks_freed=7, ready=None, next_ready=None,
+             leaves_ms={"serve.decode.emit": 400.0}, dry_by_ms={})
+    tel.emit("serve_request", id=1, output_tokens=4, ttft_s=0.1,
+             queue_wait_s=0.0)
+    tel.emit("serve_summary", requests=1, output_tokens=4, wall_s=10.0,
+             period_s=10.0, empty_s=5.0, starved_s=1.0, caller_starved_s=0.5,
+             dry_s=0.5, dry_slack_s=0.25, system_empty_share=0.5,
+             device_dry_share=0.05)
+    tel.close()
+    assert "serve_dry" not in tel.ledger.seconds
+    rows = [json.loads(ln) for ln in open(p)]
+    s = report.summarize(rows)
+    assert set(s["categories"]) == {"decode", "serve_host"}  # a partition
+    assert s["phases"]["serve_dry"]["count"] == 2
+    assert s["phases"]["serve_dry"]["total_s"] == 0.5
+    sv = s["serving"]
+    assert sv["period_s"] == 10.0
+    assert sv["period"] == {
+        "empty": (5.0, 0.5), "starved": (1.0, 0.1),
+        "caller_starved": (0.5, 0.05), "dry": (0.5, 0.05), "fed": (3.0, 0.3),
+        "dry_slack": (0.25, 0.025)}
+    assert sum(secs for k, (secs, _) in sv["period"].items()
+               if k != "dry_slack") == sv["period_s"]
+    assert [(st["ready"], st["next_ready"]) for st in sv["slow_steps"]] == [
+        (0, 1), (None, None)]
+    text = report.render(s)
+    assert ("period 10.0 s: empty 5.0 s 50.0% | starved 1.0 s 10.0% | "
+            "caller-starved 0.5 s 5.0% | dry 0.5 s 5.0% (+ slack 0.25 s) | "
+            "fed 3.0 s 30.0%") in text
+    assert "serve.decode.wait ready=0 next_ready=1 2.7 s (limit 0.25)" in text
+    assert "slow step: engine 0 host 0.4 s (limit 0.25)" in text
+    # a stream of an engine before the count: no period, nothing printed
+    old = [r for r in rows if r["kind"] != "serve_summary"]
+    assert "period" not in report.summarize(old)["serving"]
+    assert "period " not in report.render(report.summarize(old))
+
+
 def test_facade_observe_section_feeds_stage_histograms():
     tel = Telemetry(sinks=[])
     try:

@@ -176,7 +176,8 @@ def summarize(events: list[dict]) -> dict:
         out["training"]["final_val_loss"] = eval_rows[-1].get("val_loss")
     if serve_reqs or serve_summary:
         out["serving"] = serving_view(serve_reqs, serve_summary, counts)
-        out["serving"].update(host_view(categories, slow_steps))
+        out["serving"].update(host_view(categories, slow_steps,
+                                        serve_summary))
     # Elastic-resize row: the resize category already sums into the table
     # above (the phase event carries its resolved category); this pairs
     # the seconds with the elastic_resize events so a shrink/grow saga is
@@ -309,16 +310,39 @@ def serving_view(reqs: list[dict], summary: dict | None,
     return view
 
 
-def host_view(categories: dict[str, float],
-              slow_steps: list[dict]) -> dict:
+def period_view(summary: dict | None) -> dict:
+    """The partition of the engine steps' periods from the serve_summary
+    (serve/engine.py `step_account`): every second between the first
+    request and the last step's end under one name, as (seconds, share):
+    empty (no request in the system), starved (work pending, nothing
+    enqueued, inside a step), caller_starved (the same between steps),
+    dry (enqueued work a probe saw finished: a lower bound, `dry_slack`
+    what the bound leaves open) and fed, the rest. Empty for a stream
+    whose summary has no period (an engine before the count)."""
+    period = (summary or {}).get("period_s")
+    if not isinstance(period, (int, float)) or period <= 0:
+        return {}
+    parts = {k: float(summary.get(f"{k}_s") or 0.0)
+             for k in ("empty", "starved", "caller_starved", "dry")}
+    parts["fed"] = max(period - sum(parts.values()), 0.0)
+    parts["dry_slack"] = float(summary.get("dry_slack_s") or 0.0)
+    return {"period_s": round(period, 4),
+            "period": {k: (round(v, 4), round(v / period, 4))
+                       for k, v in parts.items()}}
+
+
+def host_view(categories: dict[str, float], slow_steps: list[dict],
+              summary: dict | None = None) -> dict:
     """The serving loop's own account (serve/engine.py `step_account`):
     the steps' seconds with nothing enqueued on the device (category
     `serve_host`), the share of the rest that the device was fed
-    (`prefill` + `decode` over those plus `serve_host`), and each
-    `serve_slow_step` with the part that was over its limit (`held_by`)
-    and the leaf that held most of it, beside the blocks its retirements
-    gave back."""
-    view: dict = {}
+    (`prefill` + `decode` over those plus `serve_host`), the partition of
+    the steps' periods (`period_view`), and each `serve_slow_step` with
+    the part that was over its limit (`held_by`), what a wait found
+    (`ready`, `next_ready`) and the leaf that held most of it, beside the
+    blocks its retirements gave back. The `phase=serve_dry` events carry
+    no category: their seconds lie inside `prefill` and `decode`."""
+    view: dict = period_view(summary)
     host = categories.get("serve_host")
     if host is not None:
         fed = categories.get("prefill", 0.0) + categories.get("decode", 0.0)
@@ -331,6 +355,7 @@ def host_view(categories: dict[str, float],
              "wall_s": e.get("wall_s"), "starved_s": e.get("starved_s"),
              "held_by": e.get("held_by"), "held_s": e.get("held_s"),
              "limit_s": e.get("limit_s"),
+             "ready": e.get("ready"), "next_ready": e.get("next_ready"),
              "blocks_freed": e.get("blocks_freed"),
              "longest_leaf": max(
                  {**(e.get("leaves_ms") or {}),
@@ -469,10 +494,21 @@ def render(s: dict, markdown: bool = False) -> str:
                 f"  host: {pair('serve_host_s')} s of the steps with "
                 f"nothing enqueued on the device | device fed share "
                 f"{pair('device_fed_share')}")
+        if "period" in sv:
+            lines.append(
+                f"  period {sv['period_s']} s: " + " | ".join(
+                    f"{name.replace('_', '-')} {secs} s "
+                    f"{100.0 * share:.1f}%" + (
+                        f" (+ slack {sv['period']['dry_slack'][0]} s)"
+                        if name == "dry" else "")
+                    for name, (secs, share) in sv["period"].items()
+                    if name != "dry_slack"))
         for st in sv.get("slow_steps", []):
             leaf, ms = st["longest_leaf"]
+            found = ("" if st.get("ready") is None else
+                     f" ready={st['ready']} next_ready={st['next_ready']}")
             lines.append(
-                f"  slow step: engine {st['engine']} {st['held_by']} "
+                f"  slow step: engine {st['engine']} {st['held_by']}{found} "
                 f"{st['held_s']} s (limit {st['limit_s']}) of wall "
                 f"{st['wall_s']} s (starved {st['starved_s']} s), longest "
                 f"leaf {leaf} {ms} ms, blocks freed {st['blocks_freed']}")
