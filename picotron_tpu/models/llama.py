@@ -637,7 +637,8 @@ def gdn_mixer(h, lp, cfg: ModelConfig, recur, tail, live):
     exit): what the rows carry into the segment (zeros at a sequence's
     start); live [B, s]: the positions that hold a token, a prefix of each
     row. The recurrent state is the caller's, and so is the recurrence:
-    `recur(q, k, v, g, beta)` (q, k [B, s, Hv, d_k]; v [B, s, Hv, d_v]; g,
+    `recur(q, k, v, g, beta)` (q, k [B, s, Hk, d_k], a row a KEY head, each
+    of which serves Hv / Hk value heads side by side; v [B, s, Hv, d_v]; g,
     beta [B, s, Hv], float32, a position without a token inert) runs the
     gated delta rule over the segment from the state the caller's rows
     carry and returns (o [B, s, Hv, d_v], whatever the caller carries on):
@@ -666,8 +667,6 @@ def gdn_mixer(h, lp, cfg: ModelConfig, recur, tail, live):
         tail = tail.reshape(b, -1)
     q = l2_normalise(mixed[..., :nq].reshape(b, s, hk, dk)) * dk ** -0.5
     k = l2_normalise(mixed[..., nq:2 * nq].reshape(b, s, hk, dk))
-    # each key head serves hv / hk value heads, side by side
-    q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
     v = mixed[..., 2 * nq:].reshape(b, s, hv, dv).astype(f32)
     # a position without a token neither decays nor writes
     beta = jnp.where(live[..., None], jax.nn.sigmoid(ba[..., :hv]), 0.0)

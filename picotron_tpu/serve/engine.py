@@ -1201,7 +1201,8 @@ class ServeEngine:
                         ids=join_ids(req_ids), seq=seq,
                         **cache.prefill_counts(
                             [(states[s].n_prefilled, int(nval[row]))
-                             for row, s in enumerate(pslots)], self.cfg)):
+                             for row, s in enumerate(pslots)], self.cfg,
+                            rows=len(nval))):
             toks_d, logits_d = self._run_prefill(feed)
             self._enqueued(toks_d)
         toks = None

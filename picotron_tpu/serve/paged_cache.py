@@ -111,7 +111,8 @@ from picotron_tpu.generate import _cached_attention
 from picotron_tpu.models.llama import compute_dtype, gdn_start
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.ops.gated_delta import (
-    gated_delta, gated_delta_kernel_suits, gated_delta_step_pooled,
+    gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
+    gated_delta_kernel_suits, gated_delta_step_pooled, per_value_head,
 )
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
@@ -270,9 +271,11 @@ class PagedKVCache(NamedTuple):
         every block its n positions fill."""
         return blocks_for(n, self.block_size)
 
-    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+    def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
         """Further counts of a prefill dispatch's span. `spans`: (positions
-        already cached, tokens of this chunk) a row."""
+        already cached, tokens of this chunk) a row with a request; `rows`:
+        the rows of the rung that ran, those and its pad rows (None: no
+        pad row)."""
         return {}
 
     def decode_counts(self, spans, cfg: ModelConfig) -> dict:
@@ -616,7 +619,7 @@ class LatentPagedCache(NamedTuple):
     scheduler_args = PagedKVCache.scheduler_args
     slot_rows = PagedKVCache.slot_rows
 
-    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+    def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
         """`latent_keys`: the key positions the rows' chunks may see (each
         row's cached positions and its chunk, rounded up to the
         attention's tile), summed over the pool's rows (`attn_sublayers`: the
@@ -865,7 +868,7 @@ class EvaPagedCache(PagedKVCache):
         return (blocks_for(closed * (w // c), self.block_size)
                 + blocks_for(n - closed * w, self.block_size))
 
-    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
+    def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
         """`eva_summaries_written`: chunks the rows' positions complete, a
         summary row a layer each; `eva_windows_closed`: windows they
         complete."""
@@ -942,14 +945,16 @@ class HybridPagedCache(NamedTuple):
     kernel's `live`). `generate._decode_layers` calls `write` / `attend` with
     `ki` on a full layer; on a mixer `tail_of(gi, q_pos)` before the
     convolution, `recur(gi, q, k, v, g, beta, q_pos)` for the recurrence and
-    `put_tail(gi, tail, q_pos)` after. `recur` answers for the state: a
-    decode step on a chip is ONE kernel over the state pool in place
-    (`ops.gated_delta.gated_delta_step_pooled`: the live rows' matrices are
-    read once and written once where they lie, an idle row costs nothing);
-    a prefill chunk, the tiny test models and every CPU run gather the
-    rows, run the plain rule and scatter them back (`state_of` ->
-    `ops.gated_delta.gated_delta` -> `put_state`). The tail (96 KiB a row)
-    is gathered and scattered in every case."""
+    `put_tail(gi, tail, q_pos)` after. `recur` answers for the state, and on
+    a chip the pool never leaves its place: a decode step is ONE kernel over
+    it (`ops.gated_delta.gated_delta_step_pooled`: the live rows' matrices
+    are read once and written once where they lie, an idle row costs
+    nothing), and so is a prefill chunk (`gated_delta_chunk_pooled`: a row's
+    state comes into VMEM once, stays there across the chunk's sub-chunks
+    and goes back once; a rung's pad rows are skipped). The tiny test
+    models and every CPU run gather the rows, run the plain rule and scatter
+    them back (`state_of` -> `ops.gated_delta.gated_delta` -> `put_state`).
+    The tail (96 KiB a row) is gathered and scattered in every case."""
 
     k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
     v: jnp.ndarray
@@ -1024,17 +1029,23 @@ class HybridPagedCache(NamedTuple):
         return self._replace(tail=self._carry_on(self.tail, gi, tail, q_pos))
 
     def recur(self, gi, q, k, v, g, beta, q_pos):
-        """The gated delta rule over the segment (q, k [B, s, Hv, d_k]; v
-        [B, s, Hv, d_v]; g, beta [B, s, Hv]; q_pos [B, s]) from mixer gi's
-        state of the rows' slots -> (o [B, s, Hv, d_v], the cache with the
-        state after it). A decode step the kernel suits updates the pool in
-        place, the rows with a real position and a mapped slot alone;
-        everything else gathers, runs the plain rule and scatters."""
+        """The gated delta rule over the segment (q, k [B, s, Hk, d_k], a
+        row a KEY head; v [B, s, Hv, d_v]; g, beta [B, s, Hv]; q_pos [B, s])
+        from mixer gi's state of the rows' slots -> (o [B, s, Hv, d_v], the
+        cache with the state after it). A decode step and a
+        prefill chunk that their kernels suit update the pool in place, the
+        rows with a real position and a mapped slot alone; everything else
+        gathers, runs the plain rule and scatters."""
+        where = (self.state, gi, self.stables[:, 0],
+                 jnp.any(q_pos >= 0, axis=1), q_pos[:, 0] == 0)
         if gated_delta_kernel_suits(q.shape[1], self.state):
+            q, k = (per_value_head(x[:, 0], v.shape[2]) for x in (q, k))
             o, state = gated_delta_step_pooled(
-                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], self.state,
-                gi, self.stables[:, 0], q_pos[:, 0] >= 0, q_pos[:, 0] == 0)
+                q, k, v[:, 0], g[:, 0], beta[:, 0], *where)
             return o[:, None], self._replace(state=state)
+        if gated_delta_chunk_suits(q.shape[1], q.shape[2], self.state):
+            o, state = gated_delta_chunk_pooled(q, k, v, g, beta, *where)
+            return o, self._replace(state=state)
         o, state = gated_delta(q, k, v, g, beta, self.state_of(gi, q_pos))
         return o, self.put_state(gi, state, q_pos)
 
@@ -1077,8 +1088,16 @@ class HybridPagedCache(NamedTuple):
                     state_bytes=2 * n * rows * self.state_row_bytes(),
                     state_resets=n * resets)
 
-    def prefill_counts(self, spans, cfg: ModelConfig) -> dict:
-        return self._state_counts(len(spans), sum(p == 0 for p, _ in spans))
+    def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
+        """The state's counts of the dispatch, and what the prefill program's
+        rung holds: `chunk_rows_batch` ((row, mixer) pairs, a row with a
+        request or a pad row) and `chunk_rows_idle` (those of them without
+        a real position, which the chunk's kernel skips)."""
+        batch = self.state.shape[0] * (len(spans) if rows is None else rows)
+        real = self.state.shape[0] * sum(n > 0 for _, n in spans)
+        return dict(
+            **self._state_counts(len(spans), sum(p == 0 for p, _ in spans)),
+            chunk_rows_batch=batch, chunk_rows_idle=batch - real)
 
     def decode_counts(self, spans, cfg: ModelConfig) -> dict:
         """`kv_blocks` (what one full layer's kernel reads),

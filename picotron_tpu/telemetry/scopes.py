@@ -51,7 +51,7 @@ SCOPES = (
     "moe_zero",         # inside the expert block: the zero-compute experts' term, the token times the summed gates of its picks of them
     "gdn",              # a Gated DeltaNet mixer as a whole: its projections, convolution, recurrence, gated norm and output projection
     "gdn_conv",         # inside gdn: the causal depthwise convolution over [q | k | v] with its carried tail, and the SiLU
-    "gdn_state",        # inside gdn: every byte of recurrent state a dispatch moves: in the decode program the gated delta rule as one kernel over the state pool in place (the live rows alone) and the tail's gather and scatter; in the prefill program the rows' gather, the chunked form and the scatter
+    "gdn_state",        # inside gdn: every byte of recurrent state a dispatch moves: in the decode program the gated delta rule as one kernel over the state pool in place (the live rows alone) and the tail's gather and scatter; in the prefill program the chunked rule as one kernel over the state pool in place (the rows with a real position alone; off a chip the rows' gather, the chunked form and the scatter) and the tail's gather and scatter
     "attn_gate",        # a gated attention: its output times sigmoid of the gate that came out of q's projection
     "moe_shared_gate",  # inside moe_shared: the shared expert's output times sigmoid of a 1-wide projection of the token
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention

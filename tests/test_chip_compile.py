@@ -997,12 +997,13 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
     alone and the state pool a row a slot and mixer; neither, nor the tail
     pool, is copied whole, and all four ride their program in place (the rule
     the K/V pool is held to: the state pool is carried through the layer scan
-    and the decode program's step scan; a prefill chunk gathers and scatters
-    its rows a mixer at a time, a decode step hands the pool whole to a
-    kernel that is aliased to it); the scan body is one period (L, L, L, F),
-    so the decode step calls the attention's kernel once a body, the state's
-    three times and the experts' grouped kernel four times; the scopes the
-    cell's metrics read are there."""
+    and the decode program's step scan; a decode step and a prefill chunk
+    each hand the pool whole to a kernel that is aliased to it); the scan
+    body is one period (L, L, L, F), so the decode step calls the attention's
+    kernel once a body, the state's three times and the experts' grouped
+    kernel four times, and a prefill chunk the chunk's kernel three times in
+    the place of the chunked form's triangular solves; the scopes the cell's
+    metrics read are there."""
     config = "qwen3-next-80b-a3b-12l-ep8"
     comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
     text = comp.as_text()
@@ -1032,7 +1033,8 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
     paged = [(n, op) for n, op in kernels if attn.search(n)]
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
     state = [(n, op) for n, op in kernels if n.startswith("gated_delta_step_pooled")]
-    assert len(grouped) + len(paged) + len(state) == len(kernels), kernels
+    chunk = [(n, op) for n, op in kernels if n.startswith("gated_delta_chunk_pooled")]
+    assert len(grouped) + len(paged) + len(state) + len(chunk) == len(kernels), kernels
     assert len(grouped) % 4 == 0 and len(grouped) >= 4 and "ragged-dot" not in text
     assert all("moe_experts" in words(op) for _, op in grouped), grouped
     # a batch's rows of one mixer's state, gathered or to be scattered
@@ -1045,11 +1047,22 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
         # in place, one kernel each, under the scope the cell's metrics read;
         # no row of state is gathered out of the pool or scattered back
         assert len(state) == 3 and all({"gdn", "gdn_state"} <= words(op) for _, op in state)
-        assert rows_of_state not in text and not solves
+        assert rows_of_state not in text and not solves and not chunk
     else:
         assert not paged  # a chunk walks its keys in tiles, no kernel
-        # ... and keeps the chunked recurrence: rows gathered, solved, scattered
-        assert not state and rows_of_state in text and len(solves) == 3
+        # PR 54: a chunk's three mixers of the scan body run the rule as one
+        # kernel each over the state pool in place, under the scope the
+        # cell's metrics read: no row of state is gathered or scattered, and
+        # the chunked form's triangular solve (64 sequential rows) is gone
+        assert len(chunk) == 3 and all({"gdn", "gdn_state"} <= words(op) for _, op in chunk)
+        assert not state and rows_of_state not in text and not solves
+        assert "triangular" not in text.lower()
+        # q and k reach the kernel as the mixer's fusions wrote them, a row a
+        # key head: nothing re-lays them on the way (a reshape to [B, s, Hk x
+        # d_k] is a pass over both that a compiled program pays every mixer)
+        relaid = [n for n, op, line in ins if "gdn_state" in words(op)
+                  and re.search(rf" f32\[{rows},256,2048\]", line)]
+        assert not relaid, relaid
     ma = comp.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
@@ -1060,3 +1073,29 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
         # what the program held before PR 52, with its 32 MiB gathered copies
         assert total <= 10.37 * 2**30, total / 2**30
     assert_weights_read_in_place(text, config)
+
+
+def test_qwen3_next_under_ad_keeps_the_chunked_form(topo, monkeypatch):
+    """A Gated DeltaNet block of the cell's widths under `jax.grad`, compiled
+    for a v5e with kernels available: the recurrence is `gated_delta_chunked`
+    (its triangular solve is in the program), and the
+    prefill chunk's kernel, which has no VJP, is not reached: `_gdn_block`
+    holds its own state and never asks a serving cache."""
+    from picotron_tpu.models.llama import _gdn_block
+
+    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
+    config = "qwen3-next-80b-a3b-12l-ep8"
+    m = config_from_dict({"model": load("configs", config)["model"]}).model
+    sh = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    lp = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=sh),
+                      {k: v for k, v in abstract_params(config)["layers"].items()
+                       if k.startswith("gdn_") or k == "input_norm"})
+    x = jax.ShapeDtypeStruct((1, 256, m.hidden_size), jnp.bfloat16, sharding=sh)
+
+    def loss(x, lp):
+        return jnp.sum(_gdn_block(x, lp, m).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
+    assert "gated_delta_chunk_pooled" not in text and "gated_delta_step_pooled" not in text
+    assert any("InvertDiagBlocksLowerTriangular" in line for _, _, line in instructions(text))

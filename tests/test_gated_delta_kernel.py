@@ -1,10 +1,11 @@
-"""`ops/gated_delta.py gated_delta_step_pooled`, the decode step's kernel over a
-serving cache's state pool, through the Pallas interpreter on the CPU at the
-kernel's own widths (d_k = d_v = 128) and a few heads: held to
-`gated_delta_step`, the one written form of the rule, on the rows that hold a
-token, and to the pool's own bits everywhere else. The compiled kernel inside
-the decode program is held by tests/test_chip_compile.py, a serving engine
-that runs it by tests/test_qwen3_next.py."""
+"""`ops/gated_delta.py gated_delta_step_pooled` and `gated_delta_chunk_pooled`,
+the decode step's and the prefill chunk's kernels over a serving cache's state
+pool, through the Pallas interpreter on the CPU at the kernels' own widths
+(d_k = d_v = 128) and a few heads: held to `gated_delta_step`, the one written
+form of the rule (a chunk: token by token, `gated_delta_scan`), on the rows
+that hold a token, and to the pool's own bits everywhere else. The compiled
+kernels inside the serve programs are held by tests/test_chip_compile.py, a
+serving engine that runs them by tests/test_qwen3_next.py."""
 
 import importlib
 
@@ -15,8 +16,9 @@ import pytest
 
 from picotron_tpu.ops import gated_delta as gd
 from picotron_tpu.ops.gated_delta import (
-    gated_delta, gated_delta_kernel_suits, gated_delta_step,
-    gated_delta_step_pooled, l2_normalise,
+    CHUNK_SUB, gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
+    gated_delta_kernel_suits, gated_delta_scan, gated_delta_step,
+    gated_delta_step_pooled, l2_normalise, per_value_head,
 )
 from picotron_tpu.serve.paged_cache import HybridPagedCache
 
@@ -145,3 +147,126 @@ def test_which_steps_take_the_kernel_and_what_the_others_do(monkeypatch):
         assert moved.tolist() == [[g == 1 and slot in (0, 3) for slot in range(SLOTS)]
                                   for g in range(MIXERS)]
         assert after.tail is cache.tail and after.k is cache.k
+
+
+# ---------------------------------------------------------------------------
+# the prefill chunk's kernel
+# ---------------------------------------------------------------------------
+
+
+def chunk_inputs(key_heads: int, heads: int, s: int, real, alike: bool, seed: int = 0):
+    """What a mixer hands the rule for a chunk of `s` positions a row, `real[b]`
+    of them real (the others inert: g = 0, beta = 0). `alike`: the keys of a
+    sub-chunk nearly one direction and beta near 1, where the 64 x 64 inverse
+    as a series of powers overflows float32."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    k = jax.random.normal(ks[1], (ROWS, s, key_heads, D))
+    if alike:
+        one = jax.random.normal(ks[5], (ROWS, s // CHUNK_SUB, 1, key_heads, D))
+        k = 0.02 * k + jnp.repeat(one, CHUNK_SUB, axis=2).reshape(k.shape)
+    held = (jnp.arange(s)[None, :] < jnp.asarray(real)[:, None])[..., None]
+    beta = jax.random.uniform(ks[4], (ROWS, s, heads), minval=0.97 if alike else 0.0)
+    return (l2_normalise(jax.random.normal(ks[0], (ROWS, s, key_heads, D))) * D ** -0.5,
+            l2_normalise(k), jax.random.normal(ks[2], (ROWS, s, heads, D)),
+            jnp.where(held, -0.2 * jax.random.uniform(ks[3], (ROWS, s, heads)), 0.0),
+            jnp.where(held, beta, 0.0))
+
+
+CHUNK_CASES = {
+    # name: (key heads, value heads, positions, mixer, rows' slots, real
+    #        positions a row, fresh, the pool's scale, alike keys)
+    "all_rows_live": (1, 2, 128, 1, [0, 1, 2, 3], [128] * 4, [F, F, F, F], 1.0, F),
+    "pad_rows_in_a_rung": (1, 2, 128, 1, [2, 5, 5, 5], [128, 0, 0, 0], [F, F, F, F], 1.0, F),
+    "pad_rows_still_mapped": (1, 2, 64, 1, [0, 1, 2, 3], [0, 64, 0, 0], [F, T, T, F], 1.0, F),
+    "no_row_live": (1, 2, 64, 1, [0, 1, 2, 3], [0] * 4, [F, F, F, F], 1.0, F),
+    "an_unmapped_row": (1, 2, 64, 1, [2, 5, 7, 0], [64] * 4, [F, F, F, F], 1.0, F),
+    "position_0_over_a_nonzero_row": (1, 2, 128, 1, [4, 1, 5, 5], [128, 128, 0, 0],
+                                      [T, F, F, F], 1.0, F),
+    "last_sub_chunk_part_padding": (1, 2, 192, 1, [3, 0, 4, 5], [150, 192, 65, 0],
+                                    [F, T, F, F], 1.0, F),
+    "a_large_start_state": (1, 2, 128, 1, [4, 0, 3, 1], [128, 100, 128, 128],
+                            [F, F, T, F], 10.0, F),
+    "alike_keys_beta_near_1": (1, 2, 128, 1, [1, 5, 3, 5], [128, 0, 128, 0],
+                               [F, F, T, F], 1.0, T),
+    "first_mixer": (1, 2, 64, 0, [1, 5, 3, 5], [64, 0, 40, 0], [F, F, F, F], 1.0, F),
+    "last_mixer": (1, 2, 64, MIXERS - 1, [1, 5, 3, 5], [64, 0, 40, 0], [F, F, F, F], 1.0, F),
+    "two_key_heads_four_pairs": (2, 8, 128, 2, [3, 5, 0, 2], [128, 0, 77, 128],
+                                 [F, F, T, F], 1.0, F),
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_the_chunk_kernel_is_the_rule_on_the_live_rows_and_nothing_elsewhere(case):
+    """o and the worked rows' state against the rule token by token
+    (`gated_delta_scan`) from a non-zero pool, and the chunked `jax.numpy`
+    form no further from it; a row without a real position and an unmapped
+    one get zeros for o; every other bit of the pool (pad and unmapped rows,
+    the other mixers) as it was. Where the keys of a sub-chunk are alike the
+    kernel's blocked inverse stays as close as the triangular solve does."""
+    hk, heads, s, gi, rows, real, fresh, scale, alike = CHUNK_CASES[case]
+    rows, fresh = jnp.asarray(rows), jnp.asarray(fresh)
+    live = jnp.asarray(real) > 0
+    work = np.asarray(live) & (np.asarray(rows) < SLOTS)
+    x = chunk_inputs(hk, heads, s, real, alike)
+    pool0 = scale * jax.random.normal(jax.random.key(9), (MIXERS, SLOTS, heads, D, D))
+    o, pool = jax.jit(gated_delta_chunk_pooled)(*x, pool0, jnp.asarray(gi), rows, live, fresh)
+    start = jnp.where(fresh[:, None, None, None], 0.0, pool0[gi, jnp.minimum(rows, SLOTS - 1)])
+    q, k = (per_value_head(a, heads) for a in x[:2])
+    with jax.default_matmul_precision("highest"):
+        want_o, want = jax.jit(gated_delta_scan)(q, k, *x[2:], start)
+        plain_o, plain = jax.jit(gated_delta)(*x, start)
+    want_o = np.where(work[:, None, None, None], np.asarray(want_o), 0.0)
+    o_scale, s_scale = np.abs(want_o).max() or 1.0, float(jnp.abs(want).max())
+    err_o = np.abs(np.asarray(o) - want_o).max() / o_scale
+    assert err_o <= 2e-5, err_o
+    assert not np.asarray(o)[~work].any()
+    got, pool0 = np.asarray(pool), np.asarray(pool0)
+    for b in np.flatnonzero(work):
+        err = np.abs(got[gi, int(rows[b])] - np.asarray(want[b])).max() / s_scale
+        ref = np.abs(np.asarray(plain[b]) - np.asarray(want[b])).max() / s_scale
+        assert err <= max(2e-6, 2 * ref), (b, err, ref)
+    assert work.any() == (np.abs(want_o).max() > 0.01)
+    # the chunk moved the worked rows and nothing else, not by a bit
+    moved = np.any(got != pool0, axis=(2, 3, 4))
+    worked = {int(rows[b]) for b in np.flatnonzero(work)}
+    assert moved.tolist() == [[g == gi and slot in worked for slot in range(SLOTS)]
+                              for g in range(MIXERS)]
+
+
+def test_which_chunks_take_the_kernel_and_what_the_others_do(monkeypatch):
+    """`gated_delta_chunk_suits`: whole sub-chunks of 64 positions over 128-lane
+    float32 heads that come in pairs a key head, on a backend that compiles
+    kernels, nothing else (never a decode step); and where it says no,
+    `HybridPagedCache.recur` is gather -> the chunked form -> scatter and never
+    calls the kernel."""
+    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
+    wide = jnp.zeros((MIXERS, SLOTS, 8, D, D))
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
+    assert gated_delta_chunk_suits(64, 4, wide) and gated_delta_chunk_suits(256, 1, wide)
+    assert not gated_delta_chunk_suits(1, 4, wide) and not gated_delta_chunk_suits(96, 4, wide)
+    assert not gated_delta_chunk_suits(64, 8, wide)  # a value head a key head: no pairs
+    assert not gated_delta_chunk_suits(64, 1, jnp.zeros((MIXERS, SLOTS, 2, 64, D)))
+    assert not gated_delta_chunk_suits(64, 1, jnp.zeros((MIXERS, SLOTS, 2, D, 8)))
+    assert not gated_delta_chunk_suits(64, 4, wide.astype(jnp.bfloat16))
+    assert not gated_delta_chunk_suits(16384, 4, wide)  # a row's q, k, v, o outgrow VMEM
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: False)  # the CPU's own
+    assert not gated_delta_chunk_suits(64, 4, wide)
+
+    def refuse(*a, **k):
+        raise AssertionError("the kernel was called")
+
+    monkeypatch.setattr("picotron_tpu.serve.paged_cache.gated_delta_chunk_pooled", refuse)
+    pool = jax.random.normal(jax.random.key(1), (MIXERS, SLOTS, 2, D, D))
+    cache = HybridPagedCache(
+        jnp.zeros((1, 1, 4, 4, 8)), jnp.zeros((1, 1, 4, 4, 8)), pool,
+        jnp.ones((MIXERS, SLOTS, 6)), jnp.full((ROWS, 2), 4, jnp.int32),
+        jnp.asarray([[3], [SLOTS], [0], [1]], jnp.int32))
+    real = [64, 0, 20, 0]
+    pos = jnp.where(jnp.arange(64)[None, :] < jnp.asarray(real)[:, None],
+                    jnp.asarray([[7], [0], [0], [0]]) + jnp.arange(64)[None, :], -1)
+    x = chunk_inputs(1, 2, 64, real, False)
+    o, after = cache.recur(1, *x, pos)
+    want_o, state = gated_delta(*x, cache.state_of(1, pos))
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
+    np.testing.assert_array_equal(np.asarray(after.state),
+                                  np.asarray(cache.put_state(1, state, pos).state))

@@ -341,7 +341,12 @@ def test_the_cache_drops_what_it_must_and_resets_at_position_zero():
     row_bytes = 4 * 8 * 8 * 4 + 3 * 64 * 4
     assert cache.state_row_bytes() == row_bytes
     assert cache.prefill_counts([(0, 8), (8, 5)], cfg) == dict(
-        state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=6)
+        state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=6,
+        chunk_rows_batch=12, chunk_rows_idle=0)
+    # a rung of 4 rows, two of them pads: the chunk's kernel skips those
+    assert cache.prefill_counts([(0, 8), (8, 5)], cfg, rows=4) == dict(
+        state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=6,
+        chunk_rows_batch=24, chunk_rows_idle=12)
     assert cache.decode_counts([(5, 2), (9, 2)], cfg) == dict(
         kv_blocks=5, kv_blocks_banded=10, state_rows=12, state_bytes=2 * 12 * row_bytes, state_resets=0,
         state_rows_batch=18, state_rows_idle=6)
@@ -406,6 +411,65 @@ def test_a_decode_step_through_the_kernel_serves_what_the_plain_path_serves(monk
         assert left.all(axis=0).tolist() == left.any(axis=0).tolist()  # a slot's mixers together
         np.testing.assert_array_equal(got[left], got_before[left])
     assert any(np.any(a != b) for a, b in zip(kernel_pools, kernel_pools[1:]))
+    held_to_the_reference(params, cfg, requests, kernel_out)
+
+
+def test_a_prefill_chunk_through_the_kernel_serves_what_the_plain_path_serves(monkeypatch):
+    """A two-slot engine at widths the chunk kernel takes (one period, 2 value
+    heads of 128 x 128 over one key head, chunks of 64 positions), prompts of
+    one to three chunks whose last is part padding, two of them side by side
+    in a rung and one alone in a rung of two (a pad row): once as every CPU
+    run serves it (gather, `gated_delta_chunked`, scatter) and once with the
+    prefill chunks through `gated_delta_chunk_pooled` (the Pallas
+    interpreter). The same tokens; after every engine step the same state
+    pool to float32 rounding, and a row the plain path left alone in that
+    step is left alone by the kernel too, to the bit."""
+    from picotron_tpu.serve import paged_cache
+
+    cfg = tiny(num_hidden_layers=4, layer_types=(GDN, GDN, GDN, F), linear_key_head_dim=128,
+               linear_value_head_dim=128, linear_num_key_heads=1, linear_num_value_heads=2)
+    params = weights(cfg)
+    requests = some_requests(cfg, ((150, 3), (70, 4), (40, 3)), seed=3)
+    calls = []
+
+    def served(kernel: bool):
+        jax.clear_caches()  # the engines of one process share their compiled programs
+        if kernel:
+            sound = paged_cache.gated_delta_chunk_pooled
+            monkeypatch.setattr(paged_cache, "gated_delta_chunk_suits",
+                                lambda s, hk, pool: s == 64)
+            monkeypatch.setattr(paged_cache, "gated_delta_chunk_pooled",
+                                lambda *a, **k: calls.append(a[0].shape) or sound(*a, **k))
+        eng = ServeEngine(params, cfg, ServeConfig(
+            decode_slots=2, block_size=16, prefill_chunk=64, max_model_len=192,
+            decode_interval=2))
+        # state in every row, as earlier requests would have left it
+        eng._kv = jax.device_put(tuple(jnp.full(x.shape, 0.25 + i, x.dtype)
+                                       for i, x in enumerate(eng._kv)))
+        for i, (prompt, n) in enumerate(requests):
+            eng.submit(prompt, n, req_id=i)
+        pools = [np.asarray(eng._kv[2])]
+        while eng.sched.has_work():
+            eng.step(0.0)
+            pools.append(np.asarray(eng._kv[2]))
+        eng.close()
+        return sorted(eng.results, key=lambda r: r["id"]), pools
+
+    try:
+        plain_out, plain_pools = served(False)
+        assert not calls
+        kernel_out, kernel_pools = served(True)
+    finally:
+        jax.clear_caches()  # no later engine may meet the programs traced here
+    # traced once a mixer of the one period and rung, in the prefill program alone
+    assert sorted(set(calls)) == [(1, 64, 1, 128), (2, 64, 1, 128)] and len(calls) == 6
+    assert [r["tokens"] for r in kernel_out] == [r["tokens"] for r in plain_out]
+    assert len(plain_out) == 3 and len(kernel_pools) == len(plain_pools) > 4
+    for before, after, got_before, got in zip(plain_pools, plain_pools[1:], kernel_pools,
+                                              kernel_pools[1:]):
+        np.testing.assert_allclose(got, after, rtol=0, atol=1e-5)
+        left = ~np.any(after != before, axis=(2, 3, 4))  # [mixer, slot]
+        np.testing.assert_array_equal(got[left], got_before[left])
     held_to_the_reference(params, cfg, requests, kernel_out)
 
 

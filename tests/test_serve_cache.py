@@ -54,7 +54,9 @@ KINDS = {
         specs=((24, 40), (1, 3)),
         rows=[ROW, [0]],  # the state row is the slot's own index
         # 6 mixers x 3 rows, 1,792 B a row both ways; one row starts at 0
-        prefill=dict(state_rows=18, state_bytes=2 * 18 * 1792, state_resets=6),
+        # (no pad row named: the rung is the 3 rows, and the chunk's kernel skips none)
+        prefill=dict(state_rows=18, state_bytes=2 * 18 * 1792, state_resets=6,
+                     chunk_rows_batch=18, chunk_rows_idle=0),
         # ... and of the decode batch's 6 x 3 (row, mixer) pairs none is idle
         decode=dict(kv_blocks=29, kv_blocks_banded=58,
                     state_rows=18, state_bytes=2 * 18 * 1792, state_resets=0,
