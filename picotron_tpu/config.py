@@ -335,6 +335,35 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         n_shared_experts=1, shared_expert_gate=True, norm_topk_prob=True,
         router_aux_coef=0.0,
     ),
+    # AI21-Jamba2-3B
+    # (https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json).
+    # 28 layers in periods of 14: layer i is a softmax attention where
+    # i % attn_layer_period == attn_layer_offset (layers 7 and 21: 20 heads
+    # of 128 over ONE K/V head, no rotation and no position term of any
+    # kind), every other layer a Mamba-1 mixer (ops/selective_scan.py:
+    # d_inner = 2 x 2560, a causal depthwise convolution of 4 with bias, an
+    # input-dependent step dt through a 160-wide bottleneck, B and C of 16,
+    # each of the three through an RMSNorm of its own, a float32 state of
+    # [5120, 16] a sequence carried by the diagonal selective scan, D u
+    # beside it, the output times silu(z)); a dense gated MLP of 8192 in
+    # every layer (num_experts 1); tied 65,536-row head. Built: forward(),
+    # generate() and ServeEngine, on one device. Assumed, with no key in
+    # config.json (the released Jamba modelling code;
+    # benchmark/reference_jamba.py repeats the list): the layer order from
+    # period and offset, head_dim = hidden / heads, A_log = log(1..16) a
+    # channel and D = 1 at init, the step's bias the inverse softplus of a
+    # step log-uniform in [0.001, 0.1] (Gu and Dao's Mamba initialiser).
+    "ai21labs/AI21-Jamba2-3B": dict(
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+        num_hidden_layers=28, num_attention_heads=20, num_key_value_heads=1,
+        max_position_embeddings=262144, rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        layer_types=(("mamba",) * 7 + ("full_attention",)
+                     + ("mamba",) * 6) * 2,
+        rope_parameters=dict(full_attention=dict(rope_type="none")),
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
+        mamba_conv_bias=True, mamba_proj_bias=False,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -472,6 +501,21 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         n_shared_experts=1, shared_expert_gate=True, norm_topk_prob=True,
         router_aux_coef=0.0,
     ),
+    # Tiny Jamba-shaped debug model: two periods of (3 mixers, 1 attention,
+    # 2 mixers), d_inner 128 over a state of 4 and a step rank of 3, the
+    # attention at 4 heads over ONE K/V head of 16, unrotated; dense MLPs,
+    # tied head. Served with block_size 4.
+    "picotron-tpu/debug-tiny-jamba": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=12, num_attention_heads=4, num_key_value_heads=1,
+        max_position_embeddings=2048, rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        layer_types=(("mamba",) * 3 + ("full_attention",)
+                     + ("mamba",) * 2) * 2,
+        rope_parameters=dict(full_attention=dict(rope_type="none")),
+        mamba_d_state=4, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=3,
+        mamba_conv_bias=True, mamba_proj_bias=False,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -512,6 +556,8 @@ _PRESET_ALIASES = {
     "debug-tiny-longcat": "picotron-tpu/debug-tiny-longcat",
     "Qwen3-Next-80B-A3B-Instruct": "Qwen/Qwen3-Next-80B-A3B-Instruct",
     "debug-tiny-qwen3-next": "picotron-tpu/debug-tiny-qwen3-next",
+    "AI21-Jamba2-3B": "ai21labs/AI21-Jamba2-3B",
+    "debug-tiny-jamba": "picotron-tpu/debug-tiny-jamba",
 }
 
 
@@ -536,7 +582,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next/Jamba-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -551,13 +597,26 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         "longcat_flash" if "zero_expert_num" in hf else "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
                  "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash",
-                 "qwen3_next")
+                 "qwen3_next", "jamba")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
             f"({supported}); the model layer (models/llama.py) implements "
             "the Llama lineage")
 
+    if mtype == "jamba":
+        # the dense member of the family (num_experts 1: every feed-forward
+        # the gated MLP, whatever expert_layer_period says); the MoE siblings
+        # put experts in every expert_layer_period-th layer beside dense
+        # MLPs in the others, which no stack of this tree holds
+        if int(hf.get("num_experts", 1)) > 1:
+            raise ValueError(
+                f"jamba with num_experts = {hf['num_experts']}: experts in "
+                f"every expert_layer_period-th layer beside dense MLPs in "
+                f"the others are not built (num_experts must be 1: the "
+                f"dense Jamba)")
+        hf = {k: v for k, v in hf.items()
+              if k not in ("num_experts", "num_experts_per_tok")}
     if mtype == "longcat_flash":
         # its names for the depth, the two widths and the experts a token
         hf = {**hf, "num_hidden_layers": hf["num_layers"],
@@ -786,6 +845,24 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         out["attn_output_gate"] = True
         out["norm_add_unit_offset"] = True
         out["router_aux_coef"] = 0.0
+    if mtype == "jamba":
+        # Jamba: layer i is a softmax attention where i % attn_layer_period
+        # == attn_layer_offset, a Mamba mixer otherwise (the released
+        # modelling code's rule; config.json has the two keys and no list),
+        # the mixer's sizes by its own keys; the attentions carry no
+        # position term of any kind (the mixers order the sequence)
+        every, at = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+        out["layer_types"] = tuple(
+            "full_attention" if i % every == at else SSM
+            for i in range(out["num_hidden_layers"]))
+        out["rope_parameters"] = {"full_attention": {"rope_type": "none"}}
+        for key in ("mamba_d_state", "mamba_d_conv", "mamba_expand"):
+            out[key] = int(hf[key])
+        rank = hf.get("mamba_dt_rank", "auto")
+        out["mamba_dt_rank"] = (-(-out["hidden_size"] // 16) if rank == "auto"
+                                else int(rank))
+        out["mamba_conv_bias"] = bool(hf.get("mamba_conv_bias", True))
+        out["mamba_proj_bias"] = bool(hf.get("mamba_proj_bias", False))
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -927,6 +1004,9 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
 
 
 GDN = "linear_attention"  # the kind of a layer that is a Gated DeltaNet mixer
+SSM = "mamba"  # the kind of a layer that is a Mamba-1 selective-scan mixer
+# the kinds whose mixer carries a state a SEQUENCE, not a row a position
+RECURRENT = (GDN, SSM)
 
 
 class Block(NamedTuple):
@@ -938,7 +1018,8 @@ class Block(NamedTuple):
     # head over the open window's keys and a summary a chunk of the rest.
     # A layer whose kind is "linear_attention" (`Stack.kinds`) runs a Gated
     # DeltaNet mixer in this attention's place (ops/gated_delta.py), over a
-    # recurrent state and not over cached positions
+    # recurrent state and not over cached positions; one whose kind is
+    # "mamba" a Mamba-1 mixer (ops/selective_scan.py), likewise
     attn: str
     # "dense": gated MLP | "experts": routed (+ shared) experts |
     # "shortcut": the layer is TWO (attention, dense gated MLP) pairs, and
@@ -1010,9 +1091,10 @@ class ModelConfig:
     head_dim: Optional[int] = None
     # The published per-layer attention kinds, "full_attention" or
     # "sliding_attention" a layer, or "linear_attention" (a Gated DeltaNet
-    # mixer in the attention's place); None = every layer full. The layer
-    # scan runs over whole periods of the pattern (`layer_period`). A
-    # sliding layer's position i sees j with 0 <= i - j < sliding_window.
+    # mixer in the attention's place) or "mamba" (a Mamba-1 selective-scan
+    # mixer there); None = every layer full. The layer scan runs over
+    # whole periods of the pattern (`layer_period`). A sliding layer's
+    # position i sees j with 0 <= i - j < sliding_window.
     layer_types: Optional[tuple] = None
     sliding_window: Optional[int] = None
     # RoPE law a layer kind: {"full_attention": {rope_type, rope_theta,
@@ -1166,6 +1248,17 @@ class ModelConfig:
     # The shared expert's output is scaled by sigmoid of a 1-wide
     # projection of the token (`shared_out_gate`); false: gate 1.
     shared_expert_gate: bool = False
+    # The Mamba-1 mixer of a "mamba" layer (ops/selective_scan.py), the
+    # published keys: the state's width a channel, the kernel of the causal
+    # depthwise convolution, d_inner over hidden_size (`ssm_inner`), the
+    # width of the step's bottleneck, whether the convolution has a bias
+    # and whether the in and out projections have one (not built).
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
     # Accepted for reference compat (ref uses them to pick CUDA kernels).
     use_flash_attention: bool = True
     use_fused_adam: bool = True
@@ -1252,6 +1345,28 @@ class ModelConfig:
         return GDN in self.layer_kinds
 
     @property
+    def ssm(self) -> bool:
+        """Whether some layer is a Mamba-1 selective-scan mixer."""
+        return SSM in self.layer_kinds
+
+    @property
+    def ssm_inner(self) -> int:
+        """d_inner: the channels of a Mamba mixer, each with a state of
+        mamba_d_state."""
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def recurrent_layers(self) -> int:
+        """The layers whose mixer carries a state a sequence (a Gated
+        DeltaNet or a Mamba mixer): a model with some is cached in a state
+        pool beside the attentions' K/V."""
+        return sum(k in RECURRENT for k in self.layer_kinds)
+
+    @property
+    def recurrent(self) -> bool:
+        return self.recurrent_layers > 0
+
+    @property
     def gdn_channels(self) -> int:
         """The channels the mixer's convolution runs over: [q | k | v]."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
@@ -1324,12 +1439,12 @@ class ModelConfig:
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"num_hidden_layers is {self.num_hidden_layers}")
             bad = set(self.layer_types) - {"full_attention",
-                                           "sliding_attention", GDN}
+                                           "sliding_attention", GDN, SSM}
             if bad:
                 raise ValueError(
                     f"layer_types entries must be 'full_attention', "
-                    f"'sliding_attention' or 'linear_attention', got "
-                    f"{sorted(bad)}")
+                    f"'sliding_attention', 'linear_attention' or 'mamba', "
+                    f"got {sorted(bad)}")
             if "sliding_attention" in self.layer_types and (
                     not self.sliding_window or self.sliding_window < 1):
                 raise ValueError(
@@ -1364,6 +1479,45 @@ class ModelConfig:
                 "linear_num_key_heads / linear_num_value_heads / "
                 "linear_value_head_dim are a linear_attention layer's: set "
                 "layer_types with them, or none of them")
+        sizes = (self.mamba_d_state, self.mamba_d_conv, self.mamba_expand,
+                 self.mamba_dt_rank)
+        if self.ssm:
+            if min(sizes) < 1 or self.mamba_d_conv < 2:
+                raise ValueError(
+                    f"layer_types holds mamba layers: mamba_d_state, "
+                    f"mamba_expand and mamba_dt_rank must be >= 1 and "
+                    f"mamba_d_conv >= 2, got {sizes}")
+            if self.ssm_inner % 128:
+                raise ValueError(
+                    f"layer_types holds mamba layers: d_inner = mamba_expand "
+                    f"x hidden_size ({self.ssm_inner}) must be a whole number "
+                    f"of 128-lane rows (a sequence's convolution tail is "
+                    f"held in rows of 128)")
+            if self.mamba_proj_bias:
+                raise ValueError(
+                    "mamba_proj_bias: a bias on the mixer's in and out "
+                    "projections is not built (must be false)")
+            if ("full_attention" not in self.layer_types
+                    or set(self.layer_types) - {"full_attention", SSM}
+                    or self.mla or self.eva or self.num_experts
+                    or self.sandwich_norm or self.attention_bias
+                    or self.qk_norm or self.attn_output_gate
+                    or self.norm_add_unit_offset or self.fp32_skip_add
+                    or self.num_pred_heads > 1):
+                raise ValueError(
+                    "mamba layers are built beside full attention layers "
+                    "of plain q/k/v heads (one at least) in one stack of "
+                    "dense MLPs with two norms a layer: sliding_attention "
+                    "and linear_attention layers, latent attention, "
+                    "attention_class 'eva', experts, sandwich_norm, "
+                    "attention_bias, qk_norm, attn_output_gate, "
+                    "norm_add_unit_offset, fp32_skip_add and num_pred_heads "
+                    "> 1 must be unset")
+        elif any(sizes):
+            raise ValueError(
+                "mamba_d_state / mamba_d_conv / mamba_expand / mamba_dt_rank "
+                "are a mamba layer's: set layer_types with them, or none of "
+                "them")
         if not 0.0 < self.partial_rotary_factor <= 1.0 or (
                 self.rope_dim % 2):
             raise ValueError(
@@ -1396,7 +1550,9 @@ class ModelConfig:
                 f"qk_norm must be false, true (over the whole projected "
                 f"vector) or 'head' (over each head), got {self.qk_norm!r}")
         if self.rope_parameters:
-            missing = set(self.layer_kinds) - set(dict(self.rope_parameters))
+            # a recurrent mixer has no positions to rotate: no section
+            missing = (set(self.layer_kinds) - set(RECURRENT)
+                       - set(dict(self.rope_parameters)))
             if missing:
                 raise ValueError(
                     f"rope_parameters has no section for {sorted(missing)}")
@@ -2319,8 +2475,8 @@ class Config:
                 f"schedule={pl.schedule!r} interleave={pl.interleave}")
 
     def _refuse_window_layers(self) -> None:
-        """Sliding-window layers and Gated DeltaNet mixers
-        (linear_attention layers) run on the plain attention of
+        """Sliding-window layers, Gated DeltaNet mixers (linear_attention
+        layers) and Mamba mixers (mamba layers) run on the plain attention of
         `forward()`, on `generate()` and on `ServeEngine`. Every path
         that has no band, or that slices, shards or copies a stack whose
         layers are all alike, refuses the model by name (ROADMAP M4: the
@@ -2353,7 +2509,7 @@ class Config:
             refuse(f"tensor parallelism (tp_size={d.tp_size}: the two "
                    f"pools of a mixed cache, and a state pool, are not "
                    f"sharded)")
-        if m.gdn and d.ep_size > 1:
+        if m.recurrent and d.ep_size > 1:
             refuse(f"expert parallelism (ep_size={d.ep_size}: the mixer's "
                    f"leaves have no sharding rule)")
         if sv.disagg or sv.fleet_size > 1:
@@ -2461,8 +2617,11 @@ def refuse_training(m: ModelConfig) -> None:
     with zero-compute experts or a selection bias has no balance term (the
     bias is moved by the experts' load outside the loss, and a share of the
     load is meant to fall on the zero-compute experts), nor has the layer
-    of two attentions a fused or pipelined form."""
+    of two attentions a fused or pipelined form; a Mamba mixer's scan has
+    no backward at training shapes (a [sequence, d_inner, d_state] float32
+    history a layer, kept or recomputed: ROADMAP M9)."""
     what = [name for name, on in (
+        ("mamba layers (a selective scan)", m.ssm),
         ("attention_class 'eva'", m.eva),
         ("num_pred_heads > 1", m.num_pred_heads > 1),
         ("a shortcut-connected expert branch (shortcut_moe)", m.shortcut_moe),
@@ -2472,7 +2631,8 @@ def refuse_training(m: ModelConfig) -> None:
     if what:
         raise ValueError(
             f"model has {', '.join(what)}, which training does not "
-            f"implement (no loss over several prediction heads, no banded "
+            f"implement (no backward of the selective scan at training "
+            f"shapes, no loss over several prediction heads, no banded "
             f"attention kernel with summary keys and its backward, no router "
             f"loss over zero-compute experts and no update of a selection "
             f"bias); such a model runs on forward(), generate() and "
@@ -2703,6 +2863,15 @@ def num_params(m: ModelConfig, active_only: bool = False,
                  + m.gdn_channels * m.linear_conv_kernel_dim + 2 * hv + dv
                  + hv * dv * h)
         layers += m.layer_kinds.count(GDN) * (mixer - attn)
+    if m.ssm:
+        # a Mamba mixer in the attention's place: [u | z], the convolution
+        # and its bias, [r | B | C] and their three norms, the step's
+        # projection and bias, A_log, D and the output projection
+        di, n, r = m.ssm_inner, m.mamba_d_state, m.mamba_dt_rank
+        mixer = (h * 2 * di + di * m.mamba_d_conv
+                 + (di if m.mamba_conv_bias else 0) + di * (r + 2 * n)
+                 + (r + 2 * n) + r * di + di + di * n + di + di * h)
+        layers += m.layer_kinds.count(SSM) * (mixer - attn)
     head = (h * v * m.num_pred_heads
             if (not m.tie_word_embeddings or include_tied_head) else 0)
     return v * h + layers + h + head  # embed + layers + final_norm (+ head)

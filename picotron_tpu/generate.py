@@ -56,13 +56,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from picotron_tpu.config import GDN, ModelConfig, pattern_of
+from picotron_tpu.config import GDN, SSM, ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
-    BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, final_hidden,
-    gate_attention, gated_qkv_proj, gdn_mixer, gdn_start, holds,
-    kind_tables, layer_window, mlp_act, model_rope_tables,
-    norm_weight, own_leaf, qkv_proj, residual_stream, rms_norm, served_head,
-    shared_expert,
+    BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, conv_from_tail,
+    final_hidden,
+    gate_attention, gated_qkv_proj, gdn_mixer, holds, kind_tables,
+    layer_window, mamba_mixer, mlp_act, model_rope_tables, norm_weight,
+    own_leaf, qkv_proj, recurrent_start, residual_stream, rms_norm,
+    served_head, shared_expert,
 )
 from picotron_tpu.ops.eva import (
     chunk_summaries, eva_attention, eva_summarise,
@@ -71,6 +72,7 @@ from picotron_tpu.ops.gated_delta import gated_delta
 from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope, rotate_half
+from picotron_tpu.ops.selective_scan import scan_segment
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -222,8 +224,10 @@ class EvaCache(NamedTuple):
 
 
 class HybridCache(NamedTuple):
-    """Contiguous cache of a model whose layers are Gated DeltaNet mixers
-    and full attentions side by side (Qwen3-Next): the full layers' K/V as
+    """Contiguous cache of a model whose layers are recurrent mixers and full
+    attentions side by side (Gated DeltaNet: Qwen3-Next, described here;
+    Mamba: Jamba, whose `state` is [L_ssm, B, d_state, d_inner] and whose
+    recurrence is `scan` where the other's is `recur`): the full layers' K/V as
     `KVCache` holds them, a row a position, with only those layers in the
     layer axis (a layer's row is `ki`, its ordinal among its kind), and
     beside them what a mixer carries from token to token, a row a SEQUENCE:
@@ -279,18 +283,44 @@ class HybridCache(NamedTuple):
         return o, self._replace(
             state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
 
+    def conv(self, gi, x, w, bias, n_valid, moves, q_pos):
+        """`models.llama.mamba_mixer`'s convolution from Mamba mixer gi's
+        tail -> (u [B, s, d_inner], the cache with the tail after it)."""
+        return conv_through(self, gi, x, w, bias, n_valid, moves, q_pos)
+
+    def scan(self, gi, u, dt, b, c, a, q_pos):
+        """The selective scan over the segment from Mamba mixer gi's state
+        (zeros at position 0) -> (y [B, s, d_inner], the cache with the
+        state after it)."""
+        y, state = scan_segment(u, dt, b, c, a,
+                                self._carried(self.state, gi, q_pos))
+        return y, self._replace(
+            state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
+
+
+def conv_through(cache, gi, x, w, bias, n_valid, moves, q_pos):
+    """A Mamba mixer's convolution through a cache's `tail_of` / `put_tail`
+    (either hybrid cache): the rows' tails read (zeros at a sequence's
+    start), `causal_conv` with the bias, the tails put back. The tail's
+    moves stand under the recurrence's own scope `moves`."""
+    with scope(moves):
+        tail = cache.tail_of(gi, q_pos)
+    u, tail = conv_from_tail(x, tail, w, bias, n_valid)
+    with scope(moves):
+        return u, cache.put_tail(gi, tail, q_pos)
+
 
 def init_cache(cfg: ModelConfig, batch: int, max_length: int):
     dt = compute_dtype(cfg)
-    if cfg.gdn:
-        n_gdn = cfg.layer_kinds.count(GDN)
-        shape = (cfg.num_hidden_layers - n_gdn, batch, max_length,
+    if cfg.recurrent:
+        n_rec = cfg.recurrent_layers
+        shape = (cfg.num_hidden_layers - n_rec, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
-        state, tail = gdn_start(cfg, batch)
+        state, tail = recurrent_start(cfg, batch)
         return HybridCache(
             jnp.zeros(shape, dt), jnp.zeros(shape, dt),
-            jnp.zeros((n_gdn,) + state.shape, state.dtype),
-            jnp.zeros((n_gdn,) + tail.shape, tail.dtype))
+            jnp.zeros((n_rec,) + state.shape, state.dtype),
+            jnp.zeros((n_rec,) + tail.shape, tail.dtype))
     if cfg.eva:
         shape = (cfg.num_hidden_layers, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
@@ -412,6 +442,17 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             with scope("gdn_state"):
                 return out, cache.put_tail(ki, tail, q_pos)
 
+    def mamba(h, cache, lp, li, kind, ki):
+        """A Mamba mixer: the convolution and the recurrence are asked of the
+        cache, which runs each on what it holds for mixer `ki` (the tail, the
+        state) and keeps what comes out (a serving decode step: one kernel
+        each over its pool in place, the live rows alone)."""
+        with scope("ssm_mixer"):
+            return mamba_mixer(
+                h, lp, cfg,
+                lambda c, *xs: c.conv(ki, *xs, q_pos=q_pos),
+                lambda c, *xs: c.scan(ki, *xs, q_pos=q_pos), cache, live)
+
     def eva(h, cache, lp, li, kind, ki):
         """EVA attention (ops/eva.py): K and V written a head as `gqa`
         writes them, and with them the summary of every chunk the segment
@@ -453,7 +494,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """Norm -> one attention against cache row `li` -> its output."""
         h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
                      cfg.rms_norm_eps).astype(dt)
-        mixer = gdn if kind == GDN else {
+        mixer = {GDN: gdn, SSM: mamba}.get(kind) or {
             "gqa": gqa, "mla": mla, "eva": eva}[block.attn]
         return mixer(h, cache, lp, li, kind, ki)
 
@@ -525,7 +566,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             def take(w, at):
                 return lax.dynamic_index_in_dim(w, at, 0, keepdims=False)
 
-            if rows == 1 and not cfg.gdn:
+            if rows == 1 and not cfg.recurrent:
                 at = i
                 lp = jax.tree.map(lambda w: take(w, i), layers)
             elif rows == 1:

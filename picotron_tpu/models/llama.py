@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from picotron_tpu.config import (
-    GDN, Block, ModelConfig, pattern_of, refuse_training,
+    GDN, RECURRENT, SSM, Block, ModelConfig, pattern_of, refuse_training,
 )
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.eva import chunk_summaries, eva_attention
@@ -48,6 +48,7 @@ from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.rope import apply_rope, rope_tables
+from picotron_tpu.ops.selective_scan import scan_segment, tail_shape
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -68,7 +69,7 @@ def model_rope_tables(cfg, max_len=None):
         return rope_tables(n, cfg.rope_dim, cfg.rope_theta,
                            rope_scaling=cfg.rope_scaling_dict)
     pairs = {}
-    for kind in sorted(set(cfg.layer_kinds)):
+    for kind in sorted(set(cfg.layer_kinds) - set(RECURRENT)):
         theta, scaling = cfg.rope_law(kind)
         pairs[kind] = rope_tables(n, cfg.head_dim, theta,
                                   rope_scaling=scaling)
@@ -101,28 +102,32 @@ def by_period(layer_tree, period: int):
     return jax.tree.map(split, layer_tree)
 
 
-# A stack whose layers are of two kinds of mixer (softmax attention and
-# Gated DeltaNet) holds each mixer's leaves stacked over the layers of ITS
-# kind alone, in their order, beside the leaves every layer has (the norms,
-# the MLP or the experts), stacked over all of them: no layer carries the
-# other kind's matrices. These are the softmax attention's leaves; the
-# mixer's are named `gdn_...`.
+# A stack whose layers are of two kinds of mixer (softmax attention and a
+# recurrent one: Gated DeltaNet or Mamba) holds each mixer's leaves stacked
+# over the layers of ITS kind alone, in their order, beside the leaves every
+# layer has (the norms, the MLP or the experts), stacked over all of them:
+# no layer carries the other kind's matrices. These are the softmax
+# attention's leaves; a Gated DeltaNet mixer's are named `gdn_...`, a Mamba
+# mixer's `ssm_...`.
+OWN_PREFIX = {GDN: "gdn_", SSM: "ssm_"}
 ATTENTION_LEAVES = ("q", "k", "v", "o", "q_norm", "k_norm", "b_q", "b_k",
                     "b_v")
 
 
 def own_leaf(name: str) -> bool:
     """Whether `name` is a leaf of one kind of mixer: the softmax
-    attention's or the Gated DeltaNet mixer's."""
-    return name.startswith("gdn_") or name in ATTENTION_LEAVES
+    attention's or a recurrent mixer's."""
+    return (name.startswith(tuple(OWN_PREFIX.values()))
+            or name in ATTENTION_LEAVES)
 
 
 def holds(name: str, kind: str) -> bool:
     """Whether a layer of `kind` holds the stack's leaf `name`. Every layer
-    holds every leaf of a stack without `linear_attention` layers."""
-    if name.startswith("gdn_"):
-        return kind == GDN
-    return kind != GDN or name not in ATTENTION_LEAVES
+    holds every leaf of a stack without recurrent mixers."""
+    for own, prefix in OWN_PREFIX.items():
+        if name.startswith(prefix):
+            return kind == own
+    return kind not in RECURRENT or name not in ATTENTION_LEAVES
 
 
 def leaf_row(name: str, kinds: tuple, i: int) -> int:
@@ -256,7 +261,8 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     q_out = cfg.num_attention_heads * d
     kv_out = cfg.num_key_value_heads * d
     n_gdn = tuple(kinds).count(GDN)
-    na = nl - n_gdn  # layers with a softmax attention
+    n_ssm = tuple(kinds).count(SSM)
+    na = nl - n_gdn - n_ssm  # layers with a softmax attention
 
     keys = jax.random.split(key, 14)
     # a layer of two (attention, dense MLP) pairs: every leaf of a pair has
@@ -333,6 +339,36 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "gdn_norm": jnp.ones((n_gdn, dv), jnp.float32),  # a plain weight
             "gdn_out": stacked(gk[4], hv * dv, (hv * dv, h), n_gdn),
         })
+    if n_ssm:
+        di, n, r = cfg.ssm_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+        sk = jax.random.split(keys[13], 7)
+        # a step's dt = softplus(b_dt + W_dt r) around a draw log-uniform in
+        # [0.001, 0.1] (Gu and Dao's Mamba initialiser; b_dt its inverse
+        # softplus), A = -(1 .. d_state) a channel and D = 1 (the released
+        # initialiser's): a step keeps exp(dt A) of a (channel, state) pair,
+        # 0.43 to 0.998 over the middle nine tenths of the pairs, so a state
+        # carries tens to thousands of positions
+        step = jnp.exp(jax.random.uniform(sk[6], (n_ssm, di), jnp.float32,
+                                          math.log(1e-3), math.log(1e-1)))
+        layers.update({
+            "ssm_in": stacked(sk[0], h, (h, 2 * di), n_ssm),      # [u | z]
+            "ssm_conv": stacked(sk[1], cfg.mamba_d_conv,
+                                (di, cfg.mamba_d_conv), n_ssm),
+            "ssm_x": stacked(sk[3], di, (di, r + 2 * n), n_ssm),  # [r | B | C]
+            "ssm_dt_norm": jnp.ones((n_ssm, r), jnp.float32),
+            "ssm_b_norm": jnp.ones((n_ssm, n), jnp.float32),
+            "ssm_c_norm": jnp.ones((n_ssm, n), jnp.float32),
+            "ssm_dt": stacked(sk[4], r, (r, di), n_ssm),
+            "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "ssm_A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+                (n_ssm, di, n)),
+            "ssm_D": jnp.ones((n_ssm, di), jnp.float32),
+            "ssm_out": stacked(sk[5], di, (di, h), n_ssm),
+        })
+        if cfg.mamba_conv_bias:
+            layers["ssm_conv_bias"] = stacked(
+                sk[2], cfg.mamba_d_conv, (di,), n_ssm)
     if block.attn == "eva":
         # EVA's pooling vectors, one a KV head: unit normal clamped to
         # [-1, 1] an element, so that a chunk's summary is far from its
@@ -702,6 +738,118 @@ def _gdn_block(x, lp, cfg: ModelConfig):
     return out
 
 
+def mamba_mixer(h, lp, cfg: ModelConfig, conv, scan, carry, live):
+    """A Mamba-1 mixer (ops/selective_scan.py) over a segment of every row.
+    h [B, s, hidden]: the normed block input; live [B, s]: the positions that
+    hold a token, a prefix of each row. What a sequence carries from segment
+    to segment (the recurrent state and the convolution's tail: the last
+    kernel - 1 inputs) is the caller's, `carry`, and so is every step that
+    touches it:
+
+    - `conv(carry, x, w, bias, n_valid)` (x [B, s, d_inner] float32; w
+      [d_inner, kernel]; bias [d_inner] or None; n_valid [B]: each row's real
+      positions) runs the causal convolution and the SiLU over the segment
+      from the tail the rows carry (zeros at a sequence's start) and returns
+      (u [B, s, d_inner], carry with the tail after each row's last real
+      position);
+    - `scan(carry, u, dt, b, c, a)` (u, dt [B, s, d_inner]; b, c [B, s,
+      d_state]; a [d_state, d_inner] = -exp(A_log) transposed, as the state
+      lies; float32, dt = 0 at a position without a token, which leaves the
+      state as it was) runs the selective scan over the segment from the
+      state the rows carry and returns (y [B, s, d_inner] without the D u
+      term, carry with the state after it).
+
+    `held_conv` / `held_scan` over a (state, tail) pair where the caller
+    holds one (`_mamba_block`); a cache's own `conv` / `scan` where both live
+    in pools that a decode step updates in place (`generate.HybridCache`,
+    `serve.paged_cache.HybridPagedCache`). Returns (out [B, s, hidden], the
+    carry after the segment). One body for `forward()`, prefill chunks and
+    decode steps.
+
+    Where it rounds: the matrix products take their inputs in the compute
+    dtype (h, u before x_proj, the normed r before dt_proj, y silu(z) before
+    out_proj: bfloat16 as served) and give float32; the convolution, the
+    three inner norms, softplus, exp, the recurrence, D u and the gate are
+    float32, and so are the state and the tail a sequence carries."""
+    dt_ = h.dtype
+    s = h.shape[1]
+    di, n, r = cfg.ssm_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    f32, eps = jnp.float32, cfg.rms_norm_eps
+    uz = jnp.matmul(h, lp["ssm_in"].astype(dt_), preferred_element_type=f32)
+    # the recurrence's own scope holds every byte of state the segment moves
+    # (the state's and, inside ssm_conv, the tail's) and the rule itself: a
+    # decode step's `ssm_step`, a longer segment's `ssm_scan`
+    moves = "ssm_step" if s == 1 else "ssm_scan"
+    with scope("ssm_conv"):
+        u, carry = conv(carry, uz[..., :di], lp["ssm_conv"],
+                        lp.get("ssm_conv_bias"), jnp.sum(live, axis=1), moves)
+    x = jnp.matmul(u.astype(dt_), lp["ssm_x"].astype(dt_),
+                   preferred_element_type=f32)
+    rr = rms_norm(x[..., :r], lp["ssm_dt_norm"], eps)
+    bb = rms_norm(x[..., r:r + n], lp["ssm_b_norm"], eps).astype(f32)
+    cc = rms_norm(x[..., r + n:], lp["ssm_c_norm"], eps).astype(f32)
+    step = jax.nn.softplus(
+        jnp.matmul(rr.astype(dt_), lp["ssm_dt"].astype(dt_),
+                   preferred_element_type=f32)
+        + lp["ssm_dt_bias"].astype(f32))
+    # a position without a token neither decays nor writes
+    step = jnp.where(live[..., None], step, 0.0)
+    a = -jnp.exp(lp["ssm_A_log"].astype(f32)).T
+    with scope(moves):
+        y, carry = scan(carry, u, step, bb, cc, a)
+    y = (y + lp["ssm_D"].astype(f32) * u) * jax.nn.silu(uz[..., di:])
+    return y.astype(dt_) @ lp["ssm_out"].astype(dt_), carry
+
+
+def conv_from_tail(x, tail, w, bias, n_valid):
+    """`causal_conv` of x [B, s, d_inner] from a tail held in rows of 128
+    lanes (`ops.selective_scan.tail_shape`, position-major) -> (u, the tail
+    after each row's last real position, in the same shape)."""
+    u, new = causal_conv(x, tail.reshape(x.shape[0], -1, x.shape[2]), w,
+                         n_valid, bias)
+    return u, new.reshape(tail.shape)
+
+
+def held_conv(carry, x, w, bias, n_valid, moves=None):
+    """`mamba_mixer`'s convolution over a (state, tail) pair the caller
+    holds."""
+    u, tail = conv_from_tail(x, carry[1], w, bias, n_valid)
+    return u, (carry[0], tail)
+
+
+def held_scan(carry, u, dt, b, c, a):
+    """`mamba_mixer`'s recurrence over a (state, tail) pair the caller
+    holds: state [B, d_state, d_inner]."""
+    y, state = scan_segment(u, dt, b, c, a, carry[0])
+    return y, (state, carry[1])
+
+
+def mamba_start(cfg: ModelConfig, rows: int):
+    """(state, tail) of `rows` sequences before their first position, both
+    float32; the state transposed, [rows, d_state, d_inner], the tail in rows
+    of 128 lanes (ops/selective_scan.py, `tail_shape`)."""
+    return (jnp.zeros((rows, cfg.mamba_d_state, cfg.ssm_inner), jnp.float32),
+            jnp.zeros((rows,) + tail_shape(cfg.ssm_inner, cfg.mamba_d_conv),
+                      jnp.float32))
+
+
+def recurrent_start(cfg: ModelConfig, rows: int):
+    """(state, tail) of `rows` sequences before their first position, for
+    the model's kind of recurrent mixer: what a cache's pools are shaped
+    from."""
+    return (mamba_start if cfg.ssm else gdn_start)(cfg, rows)
+
+
+@scope("ssm_mixer")
+def _mamba_block(x, lp, cfg: ModelConfig):
+    """RMSNorm -> Mamba mixer over whole sequences from a zero state."""
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
+    out, _ = mamba_mixer(h, lp, cfg, held_conv, held_scan,
+                         mamba_start(cfg, h.shape[0]),
+                         jnp.ones(h.shape[:2], bool))
+    return out
+
+
 @scope("attention")
 def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
                      kind: str = "full_attention"):
@@ -927,6 +1075,8 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
         return _shortcut_layer(x, lp, cfg, ctx, cos, sin, is_real)
     if kind == GDN:
         attn_out = _gdn_block(x, lp, cfg)
+    elif kind == SSM:
+        attn_out = _mamba_block(x, lp, cfg)
     elif block.attn == "mla":
         attn_out = _mla_attention_block(x, lp, cfg, ctx, cos, sin)
     elif block.attn == "eva":

@@ -77,13 +77,14 @@ F32 = jnp.float32
 STEP_HEAD_BLOCK = 8
 
 
-def causal_conv(x, tail, w, n_valid):
+def causal_conv(x, tail, w, n_valid, bias=None):
     """Depthwise causal convolution over the sequence, then SiLU.
 
     x [B, s, C]: the segment's channels; tail [B, K - 1, C]: the channels of
     the K - 1 positions before it; w [C, K], w[:, K - 1] the current
     position's tap; n_valid [B]: the segment's real positions, a prefix of
-    it. Returns (y [B, s, C] in x's dtype, the tail after the last real
+    it; bias [C]: added before the SiLU (a Mamba mixer's; the Gated DeltaNet
+    mixer's convolution has none). Returns (y [B, s, C] in x's dtype, the tail after the last real
     position [B, K - 1, C] in the tail's dtype: the old tail where
     n_valid is 0)."""
     k = w.shape[-1]
@@ -91,6 +92,8 @@ def causal_conv(x, tail, w, n_valid):
     full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     wf = w.astype(F32)
     y = sum(full[:, j:j + s].astype(F32) * wf[:, j] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(F32)
     new_tail = jax.vmap(
         lambda f, n: lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(
             full, n_valid)
@@ -572,6 +575,20 @@ def _chunk_kernel(gi_ref, slot_ref, fresh_ref, order_ref, n_ref, q_ref, k_ref,
         lax.fori_loop(0, hv // 2 // together, some_pairs, None)
 
 
+def work_first(work):
+    """(order [B] int32, count): the rows of a batch with `work` [B] bool
+    first, in the batch's order, then the others (a stable argsort of `not
+    work`, without the sort), and how many have work. What a chunk kernel's
+    grid walks."""
+    b = work.shape[0]
+    n = jnp.sum(work, dtype=jnp.int32)
+    at = jnp.where(work, jnp.cumsum(work) - 1, n + jnp.cumsum(~work) - 1)
+    ids = jnp.arange(b, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(at[None, :] == ids[:, None], ids[None, :], 0),
+                    axis=1, dtype=jnp.int32)
+    return order, n
+
+
 def gated_delta_chunk_pooled(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
                              interpret: Optional[bool] = None):
     """`gated_delta_chunked` for the batch's rows that hold a real position,
@@ -605,13 +622,7 @@ def _chunk_pooled_call(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
     hv, dv = v.shape[2:]
     c, pairs = CHUNK_SUB, hv // 2
     work = live & (rows < pool.shape[1])
-    # the rows with work first, in the batch's order, then the others (a
-    # stable argsort of `not work`, without the sort)
-    n = jnp.sum(work, dtype=jnp.int32)
-    at = jnp.where(work, jnp.cumsum(work) - 1, n + jnp.cumsum(~work) - 1)
-    ids = jnp.arange(b, dtype=jnp.int32)
-    order = jnp.sum(jnp.where(at[None, :] == ids[:, None], ids[None, :], 0),
-                    axis=1, dtype=jnp.int32)
+    order, n = work_first(work)
     # G, the running sum of g inside a sub-chunk: a column a head for what
     # scales a position's row, and a pair of heads' 2 x 64 side by side as
     # one row of lanes for the decay matrix's columns

@@ -105,10 +105,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from picotron_tpu.config import (
-    GDN, ModelConfig, ServeConfig, check_eva_serving,
+    ModelConfig, ServeConfig, check_eva_serving,
 )
-from picotron_tpu.generate import _cached_attention
-from picotron_tpu.models.llama import compute_dtype, gdn_start
+from picotron_tpu.generate import _cached_attention, conv_through
+from picotron_tpu.models.llama import compute_dtype, recurrent_start
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.ops.gated_delta import (
     gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
@@ -121,6 +121,11 @@ from picotron_tpu.ops.paged_attention import (
     decode_kernel_suits, latent_decode_attention, latent_kernel_suits,
     latent_prefill_attention, latent_prefill_suits, latent_prefill_tile,
     paged_decode_attention,
+)
+from picotron_tpu.ops.selective_scan import (
+    conv_kernel_suits, conv_step_pooled, scan_segment,
+    selective_scan_chunk_pooled, selective_scan_step_pooled, ssm_chunk_suits,
+    ssm_kernel_suits,
 )
 from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import scope
@@ -923,9 +928,22 @@ def init_eva_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 
 
 class HybridPagedCache(NamedTuple):
-    """The serving cache of a model whose layers are Gated DeltaNet mixers
-    and full attentions side by side (Qwen3-Next): two kinds of state, one
-    of them not addressed by position. The full layers keep the pool and
+    """The serving cache of a model whose layers are recurrent mixers and
+    full attentions side by side: two kinds of state, one of them not
+    addressed by position. Described for Gated DeltaNet mixers (Qwen3-Next);
+    a model of Mamba mixers (Jamba) is held the same way, with `state`
+    [L_ssm, slots, d_state, d_inner] float32 (a channel's states down the
+    sublanes: ops/selective_scan.py), the same one-column table, the same
+    rule of a start from zeros at position 0 and of rows that write nothing,
+    and `scan` where the other's recurrence is `recur`: a decode step on a
+    chip ONE kernel over the pool in place, the live rows' states alone
+    (`ops.selective_scan.selective_scan_step_pooled`; the convolution before
+    it likewise over the tail pool, `conv_step_pooled`), and so is a prefill
+    chunk (`selective_scan_chunk_pooled`: a row's state comes into VMEM a
+    block of channels at a time, is carried in registers across the chunk's
+    positions and goes back once; a rung's pad rows are skipped); the tiny
+    test models and every CPU run gather the rows, scan token by token and
+    scatter them back. The full layers keep the pool and
     tables of `PagedKVCache`, with only those layers in the pool's layer
     axis (the arrangement of `MixedPagedKVCache`'s full half; a layer's
     row is `ki`). The mixers keep a row a SLOT: `state` [L_gdn, slots, Hv,
@@ -1049,6 +1067,45 @@ class HybridPagedCache(NamedTuple):
         o, state = gated_delta(q, k, v, g, beta, self.state_of(gi, q_pos))
         return o, self.put_state(gi, state, q_pos)
 
+    def conv(self, gi, x, w, bias, n_valid, moves, q_pos):
+        """`models.llama.mamba_mixer`'s convolution (x [B, s, d_inner]; w
+        [d_inner, kernel]; q_pos [B, s]) from Mamba mixer gi's tail of the
+        rows' slots -> (u [B, s, d_inner], the cache with the tail after
+        it). A decode step that the kernel suits updates the tail pool in
+        place, the rows with a token and a mapped slot alone; everything else
+        gathers the rows' tails, convolves and scatters (`conv_through`)."""
+        if conv_kernel_suits(x.shape[1], self.tail):
+            with scope(moves):
+                u, tail = conv_step_pooled(
+                    x[:, 0], w, bias, self.tail, gi, self.stables[:, 0],
+                    q_pos[:, 0] >= 0, q_pos[:, 0] == 0)
+            return u[:, None], self._replace(tail=tail)
+        return conv_through(self, gi, x, w, bias, n_valid, moves, q_pos)
+
+    def scan(self, gi, u, dt, b, c, a, q_pos):
+        """The selective scan over the segment (u, dt [B, s, d_inner]; b, c
+        [B, s, d_state]; a [d_state, d_inner]; q_pos [B, s]) from Mamba mixer
+        gi's state of the rows' slots -> (y [B, s, d_inner], the cache with
+        the state after it). A decode step and a prefill chunk that their
+        kernels suit update the pool in place, the rows with a real position
+        and a mapped slot alone; everything else gathers, runs the plain
+        rule and scatters."""
+        real = q_pos >= 0
+        if ssm_kernel_suits(u.shape[1], self.state):
+            y, state = selective_scan_step_pooled(
+                u[:, 0], dt[:, 0], b[:, 0], c[:, 0], a, self.state, gi,
+                self.stables[:, 0], real[:, 0], q_pos[:, 0] == 0)
+            return y[:, None], self._replace(state=state)
+        if ssm_chunk_suits(u.shape[1], self.state):
+            y, state = selective_scan_chunk_pooled(
+                u, dt, b, c, a, self.state, gi, self.stables[:, 0],
+                jnp.sum(real, axis=1, dtype=jnp.int32), q_pos[:, 0] == 0)
+            # the kernel writes y where a real position is, and nowhere else
+            return (jnp.where(real[..., None], y, 0.0),
+                    self._replace(state=state))
+        y, state = scan_segment(u, dt, b, c, a, self.state_of(gi, q_pos))
+        return y, self.put_state(gi, state, q_pos)
+
     # -- what the serving engine asks (see `PagedKVCache`)
 
     @classmethod
@@ -1092,12 +1149,19 @@ class HybridPagedCache(NamedTuple):
         """The state's counts of the dispatch, and what the prefill program's
         rung holds: `chunk_rows_batch` ((row, mixer) pairs, a row with a
         request or a pad row) and `chunk_rows_idle` (those of them without
-        a real position, which the chunk's kernel skips)."""
+        a real position, which the chunk's kernel skips); for a model of
+        Mamba mixers `scan_tokens` too."""
         batch = self.state.shape[0] * (len(spans) if rows is None else rows)
         real = self.state.shape[0] * sum(n > 0 for _, n in spans)
-        return dict(
+        counts = dict(
             **self._state_counts(len(spans), sum(p == 0 for p, _ in spans)),
             chunk_rows_batch=batch, chunk_rows_idle=batch - real)
+        if cfg.ssm:
+            # (position, mixer) pairs with a token: what a selective scan
+            # runs over, one after the other, whatever the rung pads
+            counts["scan_tokens"] = self.state.shape[0] * sum(
+                n for _, n in spans)
+        return counts
 
     def decode_counts(self, spans, cfg: ModelConfig) -> dict:
         """`kv_blocks` (what one full layer's kernel reads),
@@ -1118,16 +1182,17 @@ class HybridPagedCache(NamedTuple):
 def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                       num_slots: int, max_blocks: int) -> HybridPagedCache:
     """Zeroed pools + all-unmapped tables: the K/V pool over the full
-    layers alone, a state row and a tail row a slot and mixer."""
-    n_gdn = cfg.layer_kinds.count(GDN)
+    layers alone, a state row and a tail row a slot and mixer, shaped from
+    the model's own start state (`models.llama.recurrent_start`)."""
+    n_rec = cfg.recurrent_layers
     dt = compute_dtype(cfg)
-    shape = (cfg.num_key_value_heads, cfg.num_hidden_layers - n_gdn,
+    shape = (cfg.num_key_value_heads, cfg.num_hidden_layers - n_rec,
              num_blocks, block_size, cfg.head_dim)
-    state, tail = gdn_start(cfg, num_slots)
+    state, tail = recurrent_start(cfg, num_slots)
     return HybridPagedCache(
         jnp.zeros(shape, dt), jnp.zeros(shape, dt),
-        jnp.zeros((n_gdn,) + state.shape, state.dtype),
-        jnp.zeros((n_gdn,) + tail.shape, tail.dtype),
+        jnp.zeros((n_rec,) + state.shape, state.dtype),
+        jnp.zeros((n_rec,) + tail.shape, tail.dtype),
         jnp.full((num_slots, max_blocks), num_blocks, jnp.int32),
         jnp.full((num_slots, 1), num_slots, jnp.int32))
 
@@ -1148,11 +1213,11 @@ def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
         return init_eva_cache(cfg, num_blocks, bs, num_slots, max_len)
     if cfg.mla:  # one pool with no head axis, sized from the latent's width
         return init_latent_cache(cfg, num_blocks, bs, num_slots, max_blocks)
-    if cfg.gdn:  # a state row a slot beside the full layers' pool
+    if cfg.recurrent:  # a state row a slot beside the full layers' pool
         if sharded:
             raise ValueError(
-                "a model with linear_attention layers is served from one "
-                "device: the state pool is not sharded (tp = 1)")
+                "a model with linear_attention or mamba layers is served "
+                "from one device: the state pool is not sharded (tp = 1)")
         return init_hybrid_cache(cfg, num_blocks, bs, num_slots, max_blocks)
     if cfg.layer_types is not None:  # a second pool, a ring a slot
         if sharded:

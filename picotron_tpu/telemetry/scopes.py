@@ -54,6 +54,10 @@ SCOPES = (
     "gdn_state",        # inside gdn: every byte of recurrent state a dispatch moves: in the decode program the gated delta rule as one kernel over the state pool in place (the live rows alone) and the tail's gather and scatter; in the prefill program the chunked rule as one kernel over the state pool in place (the rows with a real position alone; off a chip the rows' gather, the chunked form and the scatter) and the tail's gather and scatter
     "attn_gate",        # a gated attention: its output times sigmoid of the gate that came out of q's projection
     "moe_shared_gate",  # inside moe_shared: the shared expert's output times sigmoid of a 1-wide projection of the token
+    "ssm_mixer",        # a Mamba-1 mixer as a whole: its projections, convolution, the three inner norms, recurrence, D u, gate and output projection
+    "ssm_conv",         # inside ssm_mixer: the causal depthwise convolution with bias over the d_inner channels, and the SiLU
+    "ssm_scan",         # inside ssm_mixer: every byte of recurrent state a longer segment moves (a prefill chunk's, forward()'s) and the rule itself: the rows' states and tails out of the pools, the selective scan token by token, the states and tails back
+    "ssm_step",         # inside ssm_mixer: every byte of recurrent state a decode step moves and the rule itself: on a chip one kernel over the state pool in place (the live rows' states alone), else the rows' gather, the rule and the scatter; the tail's gather and scatter in every case
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 

@@ -284,13 +284,29 @@ def test_head_backward_takes_no_vocabulary_sized_detour(topo, monkeypatch, confi
 
 
 _ABSTRACT_PARAMS: dict = {}
+# serve programs of a tiny preset, under the name of a configuration: the
+# blocks `lower_serve` reads of a configuration's file
+TINY_SERVE = {
+    # the Jamba-shaped preset with a state and a tail of whole (8, 128) tiles
+    # (d_state 8; d_inner 1024, so a slot's tail is 24 rows of 128), which
+    # the decode step's kernels take
+    "debug-tiny-jamba": dict(
+        model=dict(name="debug-tiny-jamba", hidden_size=512, mamba_d_state=8,
+                   dtype="bfloat16"),
+        serve=dict(decode_slots=4, block_size=4, num_blocks=32, prefill_chunk=8,
+                   max_model_len=32, decode_interval=2)),
+}
+
+
+def blocks_of(config: str) -> dict:
+    return TINY_SERVE.get(config) or load("configs", config)
 
 
 def abstract_params(config: str):
     """The shapes of the configuration's parameter tree: bf16 weights, as the
     cell's runner serves them."""
     if config not in _ABSTRACT_PARAMS:
-        m = config_from_dict({"model": load("configs", config)["model"]}).model
+        m = config_from_dict({"model": blocks_of(config)["model"]}).model
         _ABSTRACT_PARAMS[config] = jax.eval_shape(lambda: jax.tree.map(
             lambda x: x.astype(jnp.bfloat16), init_params(m, jax.random.key(0))))
     return _ABSTRACT_PARAMS[config]
@@ -314,7 +330,7 @@ def lower_serve(topo, monkeypatch, config: str, program: str, rows=None):
     # program of this file is traced under the patch: the jits are shared
     fa = importlib.import_module("picotron_tpu.ops.flash_attention")
     monkeypatch.setattr(fa, "compiled_kernels_available", lambda: True)
-    c = load("configs", config)
+    c = blocks_of(config)
     cfg = config_from_dict({k: c[k] for k in ("model", "serve")})
     m, sc = cfg.model, cfg.serve
     slots = sc.decode_slots
@@ -1099,3 +1115,113 @@ def test_qwen3_next_under_ad_keeps_the_chunked_form(topo, monkeypatch):
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
     assert "gated_delta_chunk_pooled" not in text and "gated_delta_step_pooled" not in text
     assert any("InvertDiagBlocksLowerTriangular" in line for _, _, line in instructions(text))
+
+
+# ---------------------------------------------------------------------------
+# jamba2-3b: Mamba mixers' state pool beside the two attentions' K/V pool
+# ---------------------------------------------------------------------------
+
+def _jamba_pools_ride_in_place(text, cache, pools, program, kv: bool = True):
+    """Neither the K/V pool, nor the state pool, nor the tail pool is copied
+    whole (each is carried through the layer scan, and the decode program's
+    step scan, in ONE layout), and all four are aliased to their outputs.
+    `kv` false: the state and tail pools alone (a tiny preset's 8 KB K/V pool
+    of heads of 16 the compiler re-lays in VMEM, a shape no chip run has)."""
+    for shape in ((cache.k.shape,) if kv else ()) + (cache.state.shape, cache.tail.shape):
+        copies = whole_pool_copies(text, shape)
+        if shape == cache.tail.shape or not kv:
+            # a small pool the compiler may park in VMEM (`copy-start` /
+            # `copy-done` to `S(1)` and back, in the layout it has): a move it
+            # chose, not a disagreement about layout
+            parked = [c for c in copies if "copy-done" in c[2]]
+            assert len({re.sub(r"S\(1\)", "", c[2].split("{")[1].split("}")[0])
+                        for c in parked}) <= 1, parked
+            copies = [c for c in copies if c not in parked]
+        assert not copies, f"{program} copies a whole pool {shape}: {copies}"
+    head = text.splitlines()[0]
+    alias = head[head.index("input_output_alias={"):head.index("entry_computation_layout")]
+    assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
+
+
+JAMBA_SERVE = load("configs", "jamba2-3b")["serve"]
+JAMBA_SLOTS = JAMBA_SERVE["decode_slots"]
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("serve_decode", None), ("serve_prefill", 1), ("serve_prefill", 16),
+    ("serve_prefill", JAMBA_SLOTS)])
+def test_jamba_serving_programs(topo, monkeypatch, program, rows):
+    """Both serve programs of `jamba2-3b` compile for a v5e and fit it beside
+    the weights, the largest prefill rung included; the K/V pool holds the two
+    attention layers alone and the state pool a row a slot and mixer, float32,
+    a channel's 16 states down the sublanes; no pool is copied whole and all
+    four ride their program in place; the scan body is one period of 14 (7
+    mixers, an attention, 6 mixers), so a decode step calls the state's kernel
+    13 times a body and the attention's once, and a prefill chunk the chunk's
+    kernel 13 times (its attention walks tiles, no kernel); neither gathers a
+    row of state; the scopes the cell's metrics read are there."""
+    config = "jamba2-3b"
+    comp, cache, pools = lower_serve(topo, monkeypatch, config, program, rows)
+    text = comp.as_text()
+    assert text.startswith(f"HloModule jit_{program}")
+    slots = JAMBA_SLOTS
+    assert cache.k.shape == (1, 2, JAMBA_SERVE["num_blocks"], JAMBA_SERVE["block_size"], 128)
+    # the decode kernel takes the slots' tables whole into SMEM (1 MiB)
+    assert slots * cache.tables.shape[1] * 4 <= 2**19
+    assert cache.state.shape == (26, slots, 16, 5120) and cache.state.dtype == jnp.float32
+    assert cache.tail.shape == (26, slots, 120, 128) and cache.tail.dtype == jnp.float32
+    ins = instructions(text)
+    found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
+    recurrence = "ssm_step" if program == "serve_decode" else "ssm_scan"
+    assert found >= {"ssm_mixer", "ssm_conv", recurrence, "kv_write", "paged_attention",
+                     "attn_full", "mlp", "sample"}
+    assert not found & {"ssm_step", "ssm_scan"} - {recurrence}
+    _jamba_pools_ride_in_place(text, cache, pools, program)
+    kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
+    attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    paged = [(n, op) for n, op in kernels if attn.search(n)]
+    step = [(n, op) for n, op in kernels if n.startswith("selective_scan_step_pooled")]
+    chunk = [(n, op) for n, op in kernels if n.startswith("selective_scan_chunk_pooled")]
+    conv = [(n, op) for n, op in kernels if n.startswith("ssm_conv_step_pooled")]
+    assert len(paged) + len(step) + len(chunk) + len(conv) == len(kernels), kernels
+    # the live rows' states alone: no batch of states is gathered or scattered
+    assert f"f32[{rows or slots},16,5120]" not in text
+    if program == "serve_decode":
+        # 20 query heads over one K/V head of 128 through the decode kernel
+        assert len(paged) == 1 and "attn_full" in words(paged[0][1]) and not chunk
+        assert len(step) == 13 and all({"ssm_mixer", "ssm_step"} <= words(op) for _, op in step)
+        # ... and their tails alone: the convolution's kernel, under both scopes
+        assert len(conv) == 13 and all({"ssm_mixer", "ssm_conv", "ssm_step"} <= words(op)
+                                       for _, op in conv)
+        assert f"f32[{slots},120,128]" not in text
+    else:
+        assert not paged and not step and not conv
+        assert len(chunk) == 13 and all({"ssm_mixer", "ssm_scan"} <= words(op)
+                                        for _, op in chunk)
+    ma = comp.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    print(program, rows, "total GiB", total / 2**30, "temp GiB",
+          ma.temp_size_in_bytes / 2**30)
+    assert total < 15.75 * 2**30, total / 2**30
+    assert_weights_read_in_place(text, config)
+
+
+@pytest.mark.parametrize("program,rows", [("serve_decode", None), ("serve_prefill", 4)])
+def test_tiny_jamba_serving_programs(topo, monkeypatch, program, rows):
+    """The tiny Jamba preset's two serve programs (at a state of 8 x 1024 and
+    a tail of 24 x 128, which the decode step's kernels take) compile for a v5e with the state pool in
+    one layout: no pool-sized copy, every pool aliased; the decode step's
+    five mixers a scan body go through the step's kernel, a prefill chunk's
+    through the chunk's."""
+    comp, cache, pools = lower_serve(topo, monkeypatch, "debug-tiny-jamba", program, rows)
+    text = comp.as_text()
+    assert cache.state.shape == (10, 4, 8, 1024) and cache.tail.shape == (10, 4, 24, 128)
+    assert cache.k.shape == (1, 2, 32, 4, 128)
+    _jamba_pools_ride_in_place(text, cache, pools, program, kv=False)
+    kernel = "selective_scan_" + ("step" if program == "serve_decode" else "chunk") + "_pooled"
+    calls = [n for n, op, line in instructions(text) if "tpu_custom_call" in line]
+    ssm = [n for n in calls if n.startswith("selective_scan_")]
+    assert len(ssm) == 5 and all(n.startswith(kernel) for n in ssm), ssm
+    assert len([n for n in calls if n.startswith("ssm_conv_step_pooled")]) == (
+        5 if program == "serve_decode" else 0)

@@ -193,6 +193,12 @@ ONE_TABLE = {
     "hybrid": ("debug-tiny-qwen3-next",
                SERVE | MOE | {"gdn", "gdn_conv", "gdn_state", "attn_gate", "attn_full",
                               "moe_shared", "moe_shared_gate"}, 8),
+    # Mamba mixers over a state pool beside unrotated multi-query attentions
+    # over the K/V pool: a prefill chunk's recurrence under `ssm_scan`, a
+    # decode step's under `ssm_step`
+    "mamba": ("debug-tiny-jamba",
+              {"serve_prefill": SERVE | {"ssm_mixer", "ssm_conv", "ssm_scan", "attn_full"},
+               "serve_decode": SERVE | {"ssm_mixer", "ssm_conv", "ssm_step", "attn_full"}}, 8),
 }
 
 
@@ -209,8 +215,10 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     `scmoe_branch` and `moe_zero` beside them (`scmoe_branch_ms.serve.json`).
     A model of Gated DeltaNet mixers and gated attentions: `gdn` with
     `gdn_conv` and `gdn_state` inside it (`gdn_*.serve.json`), `attn_gate`,
-    `moe_shared_gate`."""
+    `moe_shared_gate`. A model of Mamba mixers: `ssm_mixer` with `ssm_conv`
+    and the recurrence's scope of that program inside it (`ssm_*.serve.json`)."""
     preset, want, chunk = ONE_TABLE[model]
+    want = want[program] if isinstance(want, dict) else want
     mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))
     e = ServeEngine(init_params(mcfg, jax.random.key(0)), mcfg,
                     ServeConfig(decode_slots=2, block_size=4, prefill_chunk=chunk,
