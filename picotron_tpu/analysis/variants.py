@@ -26,7 +26,9 @@ property *provable* on the host, before a chip is touched:
   shape carrier (its inputs are [S]-shaped, request identity is data),
   and the prefill program's is the row count of its compacted batch,
   which the engine draws from the fixed ladder `prefill_rungs(S)` and
-  nowhere else ([R, C]-shaped inputs, which slots prefill is data). So
+  nowhere else ([R, C]-shaped inputs, which slots prefill is data; a
+  tick may lay its rows onto several rungs, `prefill_cover`, each a
+  dispatch at one of the ladder's signatures). So
   the signature space is one decode signature and one prefill signature
   per rung, closed iff every persistent input is committed and every
   per-step upload goes through the engine's single replicated sharding
@@ -296,7 +298,8 @@ def check_engine_feed(engine) -> Report:
     is the decode program's only shape carrier, and the prefill program's
     is the row count of its compacted batch, always a rung of the
     engine's `prefill_rungs` (a function of the slot count; the
-    constructor compiles each). So with (a) and (b) the signature space
+    constructor compiles each, and `prefill_cover` picks a tick's pieces
+    from them alone). So with (a) and (b) the signature space
     is exactly one decode signature and one prefill signature per rung:
     `info["signatures"]` counts them."""
     from picotron_tpu.analysis.spec_lint import dict_by_path

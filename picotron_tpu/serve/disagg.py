@@ -224,7 +224,7 @@ class DisaggServeEngine(ServeEngine):
         self.stats = {
             "decode_steps": 0, "decode_compiles": 0, "prefill_compiles": 0,
             "prefill_chunks": 0, "occupancy_sum": 0.0,
-            "prefill_occupancy_sum": 0.0, "prefill_ticks": 0,
+            "prefill_occupancy_sum": 0.0, "prefill_occupancy_steps": 0,
             "output_tokens": 0, "decode_stall_ticks_max": 0, "cancelled": 0,
             "handoffs": 0, "handoff_s": 0.0, "handoff_blocks": 0,
         }
@@ -323,7 +323,9 @@ class DisaggServeEngine(ServeEngine):
         # ---- prefill chunks, compacted over the PREFILL pool's slots
         # (inherited — runs against `_prefill_pool`, on its placement)
         worked = self._prefill_tick(now, reg)
-        self.stats["prefill_ticks"] += 1
+        # every step, with a dispatch or without (`prefill_ticks`, the
+        # inherited count, has those with one)
+        self.stats["prefill_occupancy_steps"] += 1
         self.stats["prefill_occupancy_sum"] += (
             sum(s is not None for s in self.sched.pslots)
             / self.num_pslots)
@@ -368,7 +370,7 @@ class DisaggServeEngine(ServeEngine):
     # -- summary -----------------------------------------------------------
 
     def _summary_dict(self, wall: float) -> dict:
-        pticks = max(self.stats["prefill_ticks"], 1)
+        pticks = max(self.stats["prefill_occupancy_steps"], 1)
         return dict(
             super()._summary_dict(wall),
             disagg=True,

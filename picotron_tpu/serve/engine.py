@@ -227,16 +227,16 @@ def serve_prefill(params, pools, table_rows, chunk_ids, start_pos,
                   n_valid, rids, tidx, base_key, cos, sin,
                   cfg: ModelConfig, temperature: float, top_k: int,
                   cache_cls: type):
-    """Prefill the next chunk of every mid-prefill slot in one dispatch:
+    """Prefill the next chunk of R mid-prefill slots in one dispatch:
     chunk_ids [R, C] (padded), start_pos/n_valid/rids/tidx [R],
     table_rows a tuple of [R, width] (a table of the cache each). A row
-    is a mid-prefill slot, not a slot
-    index: the host compacts the batch (`ServeEngine._prefill_feed`), so
-    R is a rung of `prefill_rungs`, and the row's table row says where
-    its K/V live. Rows with n_valid = 0 pad the batch up to the rung
-    (all positions -1 and an all-unmapped table row: writes
-    sentinel-drop, outputs discarded); padded positions inside a live
-    row behave the same. Batching matters: a per-slot prefill dispatch
+    is a mid-prefill slot, not a slot index: the host compacts the batch
+    (`ServeEngine._prefill_feed`), so R is a rung of `prefill_rungs` (a
+    tick's slots ride one rung or several, `prefill_cover`), and the
+    row's table row says where its K/V live. Rows with n_valid = 0 pad
+    the batch up to the rung (all positions -1 and an all-unmapped table
+    row: writes sentinel-drop, outputs discarded); padded positions in a
+    live row behave the same. Batching matters: a per-slot prefill dispatch
     measured ~2x the static sampler's batched prompt pass on the CPU
     bench — one [R, C] program closes that. Samples each row's next
     token off its last valid position's logits with the same (request
@@ -283,13 +283,13 @@ def _get_jits(donate: bool):
 def prefill_rungs(num_slots: int) -> tuple:
     """The row counts the prefill program is compiled for, a function of
     the pool's slot count and nothing else: the powers of 4 below it,
-    then the slot count itself (1, 4, 16, 32 at 32 slots). A dispatch
-    takes the smallest rung that holds its mid-prefill slots, so it pads
-    by under 4x and every row count up to `num_slots` has a rung. A
-    dispatch costs by its rows, so a finer ladder serves faster (powers
-    of 2 measured 13% under this one on the chat cell's TTFT p90), but a
-    rung costs start-up a trace and a cache load, and the set-up bound
-    had no room for six (PERF.md, PR 28)."""
+    then the slot count itself (1, 4, 16, 32 at 32 slots). A tick's
+    mid-prefill slots ride one rung or several (`prefill_cover`, below
+    the engine): never padded beyond 4/3, and every row count up to
+    `num_slots` has a rung. A dispatch costs by its rows, so a finer
+    ladder serves faster (powers of 2 measured 13% under this one on the
+    chat cell's TTFT p90), but a rung costs start-up a trace and a cache
+    load, and the set-up bound had no room for six (PERF.md, PR 28)."""
     rungs, r = [], 1
     while r < num_slots:
         rungs.append(r)
@@ -774,7 +774,7 @@ class ServeEngine:
                           probes=0,
                           # of `decode_steps`, those enqueued while the
                           # dispatch before was in flight (`_decode_tick`)
-                          decode_ahead=0)
+                          decode_ahead=0, **dict.fromkeys(PREFILL_COUNTS, 0))
         self._leaves: list = []  # this step's (name, start, secs)
         # the leaves that enqueued what the last steps left un-waited
         self._in_flight: tuple = ()
@@ -857,9 +857,9 @@ class ServeEngine:
     def _prefill_feed(self, pslots, rows: Optional[int] = None):
         """The compacted prefill batch: row i carries the next chunk of
         slot pslots[i] and that slot's table row; the batch is padded up
-        to the smallest rung that holds them (or to `rows`). A pad row
-        has n_valid 0 and an all-unmapped table row, so its writes drop
-        and its token is never read. Returns (device feed in
+        to `rows`, the rung `prefill_cover` gave these slots (None: the
+        smallest that holds them). A pad row has n_valid 0 and an
+        all-unmapped table row: its writes drop. Returns (device feed in
         `serve_prefill`'s argument order, n_valid [R] on the host, the
         rows whose prompt ends in this chunk)."""
         states, cache, tables, sh = self._prefill_pool()
@@ -963,8 +963,8 @@ class ServeEngine:
     # -- one engine iteration ---------------------------------------------
 
     def step(self, now: Optional[float] = None) -> bool:
-        """Admit; enqueue ONE prefill chunk (if any prompt is mid-prefill)
-        and wait for it where a prompt ends in it; enqueue ONE decode
+        """Admit; enqueue ONE prefill chunk a mid-prefill slot (a tick: one
+        dispatch or several) and wait where a prompt ends; enqueue ONE decode
         dispatch over the slot batch; THEN wait for the decode dispatch
         the step before enqueued, emit its tokens and retire. The engine
         runs one decode dispatch ahead (`_decode_tick`): the tokens a step
@@ -1167,55 +1167,81 @@ class ServeEngine:
         reg.histogram("serve/queue_wait").observe(wait)
 
     def _prefill_tick(self, now: float, reg) -> bool:
-        """One prefill dispatch: the next chunk of every mid-prefill
-        slot, compacted to a rung of rows (`_prefill_feed`). Works
-        through `_prefill_pool` / `_run_prefill` / `_retire_prefilled`
-        and the scheduler's prefill interface, so the disaggregated
-        engine runs it verbatim against its prefill pool. Returns
-        whether a dispatch ran."""
+        """One prefill tick: the next chunk of every mid-prefill slot,
+        laid onto the rungs the constructor compiled (`prefill_cover`:
+        seventeen rows ride the 16-row and the one-row rung, not the
+        64-row one). A piece is a dispatch of its own (`_prefill_feed` at
+        its rung) with a span, a `seq` and counts of its own, `piece` of
+        `pieces`; all are enqueued before any is waited for, the pools
+        chained through the donated argument, so the device runs them back
+        to back. Works through `_prefill_pool` / `_run_prefill` /
+        `_retire_prefilled` and the scheduler's prefill interface, so the
+        disaggregated engine runs it verbatim against its prefill pool.
+        Every slot advances one chunk a tick whatever piece carries it,
+        and samples under its own (request id, token index) key, so the
+        served tokens are those of one dispatch over all the rows.
+        Returns whether a dispatch ran.
+
+        It keeps the lines it had before PR 56 (what it grew by is
+        `_emit_prefilled`, below `_collect_decode`): a decode kernel's
+        cache key may carry the lines of `_enqueue_decode`, which stands
+        below this (the comment above `step_account`)."""
         pslots = self.sched.prefill_slots()
         if not pslots:
             return False
         states, cache = self._prefill_pool()[:2]
+        chunk = self.scfg.prefill_chunk
         with self._span("serve.prefill.build"):
-            feed, nval, finals = self._prefill_feed(pslots)
-        n_prefilled = int(nval.sum())
-        req_ids = [states[s].req.id for s in pslots]
+            pieces, at = [], 0
+            for rung, n in prefill_cover(len(pslots), self.prefill_rungs):
+                mine = pslots[at:at + n]  # oldest admitted first
+                feed, nval, finals = self._prefill_feed(mine, rows=rung)
+                pieces.append({"slots": mine, "nval": nval, "feed": feed,
+                               "finals": finals})
+                at += n
         self._drain_compile()
-        if watchdog.active():
-            # a hang inside this dispatch is reported as THIS dispatch,
-            # not a bare stack dump (satellite of the fleet's serve_hang
-            # detection; also arms bench --serve)
-            watchdog.touch(
-                f"serve engine={self.engine_id} dispatch=prefill")
-        seq = self._prefill_seq
-        self._prefill_seq += 1
         t0 = time.perf_counter()
-        # `capacity` is what the program computes: the rung's `rows`, of
-        # which `slots` carry a request; `seq` numbers the dispatch, and
-        # its wait carries the same: a chunk nobody waits for stays in
-        # flight across steps, as a decode dispatch does
-        with self._span("serve.prefill.dispatch", slots=len(pslots),
-                        rows=len(nval), tokens=n_prefilled,
-                        capacity=len(nval) * self.scfg.prefill_chunk,
-                        ids=join_ids(req_ids), seq=seq,
-                        **cache.prefill_counts(
-                            [(states[s].n_prefilled, int(nval[row]))
-                             for row, s in enumerate(pslots)], self.cfg,
-                            rows=len(nval))):
-            toks_d, logits_d = self._run_prefill(feed)
-            self._enqueued(toks_d)
-        toks = None
-        if finals:
-            # the host needs a token only when a prompt ends in the
-            # chunk; otherwise the dispatch is left in flight. `ready`:
-            # whether it had run when the wait began; nothing is enqueued
-            # behind the newest dispatch (`next_ready`)
-            probes = (int(toks_d.is_ready()), -1)
-            with self._span("serve.prefill.wait", finals=len(finals),
-                            seq=seq, ready=probes[0], next_ready=probes[1]):
-                toks, logits = jax.device_get((toks_d, logits_d))
-                self._fetched(toks_d)
+        # every piece enqueued before any is waited for
+        for i, p in enumerate(pieces):
+            mine, nval = p["slots"], p["nval"]
+            if watchdog.active():
+                # a hang inside a dispatch is reported as THAT dispatch, not
+                # a bare stack dump (the fleet's serve_hang detection)
+                watchdog.touch(
+                    f"serve engine={self.engine_id} dispatch=prefill")
+            p["seq"] = self._prefill_seq
+            self._prefill_seq += 1
+            # `capacity` is what the program computes: the rung's `rows`, of
+            # which `slots` carry a request; `seq` numbers the dispatch
+            with self._span("serve.prefill.dispatch", slots=len(mine),
+                            rows=len(nval), tokens=int(nval.sum()),
+                            capacity=len(nval) * chunk,
+                            ids=join_ids(states[s].req.id for s in mine),
+                            seq=p["seq"], piece=i, pieces=len(pieces),
+                            **cache.prefill_counts(
+                                [(states[s].n_prefilled, int(nval[row]))
+                                 for row, s in enumerate(mine)], self.cfg,
+                                rows=len(nval))):
+                p["toks"], p["logits"] = self._run_prefill(p["feed"])
+                self._enqueued(p["toks"])
+        n_finals = sum(len(p["finals"]) for p in pieces)
+        newest = pieces[-1]
+        if n_finals:
+            # the host needs a token only when a prompt ends in the tick's
+            # chunks; otherwise its pieces are left in flight across steps,
+            # as a decode dispatch is. The ONE wait fetches the newest piece
+            # too, so it clears all that is in flight (`step_account`) and
+            # carries that piece's `seq`. `ready`: whether the newest had
+            # run when the wait began; nothing is enqueued behind it
+            probes = (int(newest["toks"].is_ready()), -1)
+            with self._span("serve.prefill.wait", finals=n_finals,
+                            seq=newest["seq"], ready=probes[0],
+                            next_ready=probes[1]):
+                want = [p for p in pieces if p["finals"] or p is newest]
+                for p, out in zip(want, jax.device_get(
+                        [(p["toks"], p["logits"]) for p in want])):
+                    p["out"] = out  # the rows' tokens and their logits
+                self._fetched(newest["toks"])
             self._wait_probes["serve.prefill.wait"] = probes
             self._waited_at = time.perf_counter()
         dt = time.perf_counter() - t0
@@ -1224,33 +1250,7 @@ class ServeEngine:
         # a sharding the feed should not have produced
         self.stats["prefill_compiles"] += bool(csecs)
         dt -= min(csecs, dt)
-        # `waited`: whether `secs` is the device's time for the chunk or
-        # only the enqueue
-        self.telemetry.emit("phase", phase="prefill", category="prefill",
-                            secs=dt, tokens=n_prefilled, ids=req_ids,
-                            waited=bool(finals), **self._PREFILL_PHASE)
-        for row, s in enumerate(pslots):
-            self.sched.note_prefilled(s, int(nval[row]))
-        self.stats["prefill_chunks"] += len(pslots)
-        if not finals:
-            return True
-        n_retired = n_freed = 0
-        with self._span("serve.prefill.emit") as sp:
-            for row in finals:
-                st = states[pslots[row]]
-                st.generated.append(int(toks[row]))
-                st.logits.append(float(logits[row]))
-                self.stats["output_tokens"] += 1
-                if st.t_first_token is None:
-                    st.t_first_token = now + dt
-                    ttft = max(st.t_first_token - st.req.arrival, 0.0)
-                    reg.histogram("serve/ttft").observe(ttft)
-                freed = self._retire_prefilled(pslots[row], now + dt)
-                n_retired += bool(freed)
-                n_freed += freed
-            sp.set(tokens=len(finals), retired=n_retired,
-                   blocks_freed=n_freed)
-        self._step_blocks_freed += n_freed
+        self._emit_prefilled(pieces, n_finals, now, dt, reg)
         return True
 
     def _decode_tick(self, now: float, reg) -> bool:
@@ -1524,6 +1524,61 @@ class ServeEngine:
         self.stats["occupancy_sum"] += n_rows / self.num_slots
         self.stats["output_tokens"] += n_tokens
 
+    def _emit_prefilled(self, pieces, n_finals: int, now: float, dt: float,
+                        reg) -> None:
+        """What a prefill tick books once its pieces are enqueued and, where
+        a prompt ended in one, fetched (`_prefill_tick`): ONE `phase=prefill`
+        event for the tick (its `secs` lie on one clock: a piece has no
+        seconds of its own on the host; `dispatches` says how many it
+        covers), every slot's chunk noted, the tick's counts in `stats`
+        (`prefill_ticks`, `prefill_dispatches`, and the rows the rungs
+        computed as `prefill_rows_real` + `prefill_rows_padded`), and the
+        first token of each prompt that ended, under `serve.prefill.emit`.
+        `dt`: the seconds since the first piece's enqueue, less compiles."""
+        states = self._prefill_pool()[0]
+        pslots = [s for p in pieces for s in p["slots"]]
+        # `waited`: whether `secs` is the device's time for the chunks or
+        # only the enqueues
+        self.telemetry.emit("phase", phase="prefill", category="prefill",
+                            secs=dt,
+                            tokens=sum(int(p["nval"].sum()) for p in pieces),
+                            ids=[states[s].req.id for s in pslots],
+                            waited=bool(n_finals), dispatches=len(pieces),
+                            **self._PREFILL_PHASE)
+        for p in pieces:
+            for row, s in enumerate(p["slots"]):
+                self.sched.note_prefilled(s, int(p["nval"][row]))
+        stats = self.stats
+        stats["prefill_chunks"] += len(pslots)
+        stats["prefill_ticks"] += 1
+        stats["prefill_dispatches"] += len(pieces)
+        stats["prefill_rows_real"] += len(pslots)
+        stats["prefill_rows_padded"] += (sum(len(p["nval"]) for p in pieces)
+                                         - len(pslots))
+        if not n_finals:
+            return
+        n_retired = n_freed = 0
+        with self._span("serve.prefill.emit") as sp:
+            for p in pieces:
+                if not p["finals"]:
+                    continue
+                toks, logits = p["out"]
+                for row in p["finals"]:
+                    slot = p["slots"][row]
+                    st = states[slot]
+                    st.generated.append(int(toks[row]))
+                    st.logits.append(float(logits[row]))
+                    stats["output_tokens"] += 1
+                    if st.t_first_token is None:
+                        st.t_first_token = now + dt
+                        ttft = max(st.t_first_token - st.req.arrival, 0.0)
+                        reg.histogram("serve/ttft").observe(ttft)
+                    freed = self._retire_prefilled(slot, now + dt)
+                    n_retired += bool(freed)
+                    n_freed += freed
+            sp.set(tokens=n_finals, retired=n_retired, blocks_freed=n_freed)
+        self._step_blocks_freed += n_freed
+
     # -- trace driver ------------------------------------------------------
 
     def run(self, requests=(), watchdog_timeout: float = 0.0) -> list:
@@ -1605,6 +1660,10 @@ class ServeEngine:
             "decode_compiles": self.stats["decode_compiles"],
             "prefill_compiles": self.stats["prefill_compiles"],
             "prefill_chunks": self.stats["prefill_chunks"],
+            # the ticks that carried them, the dispatches those were laid
+            # onto (`prefill_cover`), and the rows the rungs computed: with
+            # a request, and pad
+            **{k: self.stats[k] for k in PREFILL_COUNTS},
             "decode_stall_ticks_max":
                 self.stats["decode_stall_ticks_max"],
             # of the steps' wall, the share with nothing enqueued on the
@@ -1643,3 +1702,51 @@ class ServeEngine:
         self._flying = None  # a dispatch of padding nobody waited for
         if self._owns_telemetry:
             self.telemetry.close()
+
+
+# ---------------------------------------------------------------------------
+# A prefill tick's rows on the compiled rungs (host code: below the device
+# programs and their callers, see above `step_account`)
+# ---------------------------------------------------------------------------
+
+# `engine.stats` of the prefill ticks (`ServeEngine._emit_prefilled` counts
+# them; `_init_step_account`, which both engines pass, zeroes them)
+PREFILL_COUNTS = ("prefill_ticks", "prefill_dispatches", "prefill_rows_real",
+                  "prefill_rows_padded")
+
+# The least share of the smallest rung that holds a tick's rows at which the
+# rung takes them all, padded. A measurement, not a setting (PERF.md section
+# 6, PR 56: every rung of all eight serving cells timed on the chip, and ticks
+# of 2-65 rows each way). A pad row costs what a real row costs (84-100% of
+# it) and a dispatch's fixed part is less than one row's arithmetic in every
+# cell (0.3-0.9 of it, the models with expert banks highest), so two rows run
+# faster as 1 + 1 than padded to four everywhere (by 20-68%) and nine as
+# 4 + 4 + 1 than padded to sixteen; three rows as 1 + 1 + 1 lose to the
+# four-row rung in the three cells that stream expert banks (by 8-10%), which
+# is what holds the fraction at three quarters and not above.
+PAD_UP_FROM = 0.75
+
+
+def prefill_cover(n: int, rungs) -> tuple:
+    """The pieces that carry a tick's `n` mid-prefill rows, `((rung, rows),
+    ...)`, largest first: each `rung` one of `rungs` (`prefill_rungs`:
+    ascending, the last holds every slot), `rows` the real rows it carries,
+    summing to `n`. Walking down from `n`: where the rows left fill at least
+    `PAD_UP_FROM` of the smallest rung that holds them, that rung takes them
+    all, padded; otherwise the largest rung they fill takes that many, full,
+    and the walk goes on with the rest. So every piece but the last is full
+    and a tick computes at most `n / PAD_UP_FROM` rows: 17 rows of (1, 4, 16,
+    64, 128) ride (16, 16) + (1, 1) and not 64, 5 ride (4, 4) + (1, 1), 2
+    ride (1, 1) + (1, 1), 40 ride 16 + 16 + 4 + 4, 31 ride (16, 16) + (16,
+    15), and 1, 3, 4 or 13 ride one rung as they always did. It reads the
+    count and the engine's own ladder, nothing else."""
+    pieces = []
+    while n > 0:
+        up = next(r for r in rungs if r >= n)
+        if n >= PAD_UP_FROM * up:
+            pieces.append((up, n))
+            break
+        full = max(r for r in rungs if r <= n)
+        pieces.append((full, full))
+        n -= full
+    return tuple(pieces)

@@ -591,14 +591,14 @@ def prefill_dispatches(tel):
             if e["ph"] == "X" and e["name"] == "serve.prefill.dispatch"]
 
 
-def traced_engine(tiny, **kw):
+def traced_engine(tiny, cls=ServeEngine, **kw):
     from picotron_tpu.telemetry import Telemetry
     from picotron_tpu.telemetry.flightdeck import SpanTracer
 
     cfg, params = tiny
     tel = Telemetry(sinks=[])
     tel.tracer = SpanTracer()
-    return ServeEngine(params, cfg, scfg(**kw), telemetry=tel), tel
+    return cls(params, cfg, scfg(**kw), telemetry=tel), tel
 
 
 def test_prefill_rungs_are_a_function_of_the_slot_count():
@@ -610,28 +610,200 @@ def test_prefill_rungs_are_a_function_of_the_slot_count():
         (1, 4, 16, 64)]
 
 
-@pytest.mark.parametrize("n", [1, 2, 4, 5, 6])
-def test_compacted_prefill_parity_and_rung(tiny, requests5, offline_refs, n):
-    """Exactly n prompts prefill at once (n requests at t = 0, 6 slots):
-    the first dispatch takes the smallest rung of (1, 4, 6) that holds
-    them, every later one the smallest that holds what is still
-    mid-prefill, and the tokens are the offline sampler's whatever rung
-    a chunk rode."""
-    reqs = (requests5 + requests5[:1])[:n]
-    refs = (offline_refs + offline_refs[:1])[:n]
-    eng, tel = traced_engine(tiny, decode_slots=6)
-    assert eng.prefill_rungs == (1, 4, 6)
+# the ladders the benchmark's serving cells compile: 16 slots (EvaByte,
+# Qwen3-Next, openPangu, LongCat), 32 (chat, Mellum2), 48 (K-EXAONE), 128
+# (Jamba)
+COVER_LADDERS = [(1, 4, 16), (1, 4, 16, 32), (1, 4, 16, 48),
+                 (1, 4, 16, 64, 128)]
+
+
+@pytest.mark.parametrize("rungs", COVER_LADDERS, ids=lambda r: f"slots{r[-1]}")
+def test_prefill_cover_over_the_cells_ladders(rungs):
+    """Every row count up to the slot count: the pieces are rungs, their
+    rows are the tick's, at most one piece (the last) is not full, the
+    tick computes no more than four thirds of its rows (so under twice)
+    and no more than the one rung that holds them all would, and a rung
+    that the rows fill by three quarters or more takes them as it always
+    did."""
+    from picotron_tpu.serve.engine import prefill_cover, prefill_rungs
+
+    assert prefill_rungs(rungs[-1]) == rungs
+    assert prefill_cover(0, rungs) == ()
+    for n in range(1, rungs[-1] + 1):
+        cover = prefill_cover(n, rungs)
+        up = min(r for r in rungs if r >= n)
+        assert all(rung in rungs and 1 <= rows <= rung
+                   for rung, rows in cover), (n, cover)
+        assert sum(rows for _, rows in cover) == n
+        assert all(rung == rows for rung, rows in cover[:-1]), (n, cover)
+        assert [rung for rung, _ in cover] == sorted(
+            (rung for rung, _ in cover), reverse=True)
+        computed = sum(rung for rung, _ in cover)
+        assert 3 * computed <= 4 * n and computed <= up, (n, cover)
+        assert (cover == ((up, n),)) == (4 * n >= 3 * up), (n, cover)
+
+
+COVER_EXAMPLES = [
+    ((1, 4, 16, 64, 128), 17, ((16, 16), (1, 1))),  # a burst: not 64 rows
+    ((1, 4, 16, 64, 128), 5, ((4, 4), (1, 1))),
+    ((1, 4, 16, 64, 128), 40, ((16, 16), (16, 16), (4, 4), (4, 4))),
+    ((1, 4, 16, 64, 128), 31, ((16, 16), (16, 15))),
+    ((1, 4, 16, 64, 128), 2, ((1, 1), (1, 1))),  # faster than (4, 2) in
+    ((1, 4, 16, 64, 128), 3, ((4, 3),)),  # every cell; 1 + 1 + 1 is not
+    ((1, 4, 16, 64, 128), 1, ((1, 1),)),
+    ((1, 4, 16, 64, 128), 48, ((64, 48),)),
+    ((1, 4, 16, 64, 128), 87, ((64, 64), (16, 16), (4, 4), (4, 3))),
+    ((1, 4, 16, 64, 128), 24, ((16, 16), (4, 4), (4, 4))),
+    ((1, 4, 16), 2, ((1, 1), (1, 1))),  # EvaByte's two documents
+    ((1, 4, 16), 7, ((4, 4), (4, 3))),
+    ((1, 4, 16), 12, ((16, 12),)),
+    ((1, 4, 16, 32), 20, ((16, 16), (4, 4))),
+    ((1, 4, 16, 48), 23, ((16, 16), (4, 4), (4, 3))),
+    ((1,), 1, ((1, 1),)),
+    ((1, 2), 2, ((2, 2),)),
+]
+
+
+@pytest.mark.parametrize(
+    "rungs, n, want", COVER_EXAMPLES,
+    ids=[f"slots{r[-1]}-rows{n}" for r, n, _ in COVER_EXAMPLES])
+def test_prefill_cover_examples(rungs, n, want):
+    from picotron_tpu.serve.engine import prefill_cover
+
+    assert prefill_cover(n, rungs) == want
+
+
+def cover_of(eng, slots):
+    """The (rung, rows) pieces a tick of `slots` mid-prefill rows rides."""
+    from picotron_tpu.serve.engine import prefill_cover
+
+    return prefill_cover(slots, eng.prefill_rungs)
+
+
+def ticks_of(dispatches):
+    """The dispatch spans grouped by tick: `piece` counts up from 0."""
+    ticks = []
+    for d in dispatches:
+        if d["piece"] == 0:
+            ticks.append([])
+        ticks[-1].append(d)
+    return ticks
+
+
+@pytest.mark.parametrize("n, slots", [(1, 6), (2, 6), (4, 6), (5, 6), (6, 6),
+                                      (5, 16), (7, 16)])
+def test_compacted_prefill_parity_and_rung(tiny, requests5, offline_refs, n,
+                                           slots):
+    """Exactly n prompts prefill at once (n requests at t = 0): every
+    tick's dispatches are the cover of its mid-prefill rows
+    (`prefill_cover`) on the engine's ladder, (1, 4, 6) or (1, 4, 16): one
+    rung where the rows fill three quarters of the smallest that holds
+    them (the 6-slot cases but n = 2, as before PR 56), otherwise full
+    rungs and a rest (two rows ride 1 + 1, five of 16 slots 4 + 1, seven
+    4 + 3 of 4), and the tokens are the offline sampler's whatever rung a
+    chunk rode."""
+    reqs = (requests5 + requests5[:2])[:n]
+    refs = (offline_refs + offline_refs[:2])[:n]
+    eng, tel = traced_engine(tiny, decode_slots=slots, num_blocks=8 * slots)
+    assert eng.prefill_rungs == {6: (1, 4, 6), 16: (1, 4, 16)}[slots]
     res = eng.run(reqs)
     for r, ref in zip(res, refs):
         assert r["tokens"] == ref
-    disp = prefill_dispatches(tel)
-    want = {1: 1, 2: 4, 4: 4, 5: 6, 6: 6}
-    assert disp[0]["slots"] == n and disp[0]["rows"] == want[n]
-    assert max(d["slots"] for d in disp) == n
-    for d in disp:
-        assert d["rows"] == min(r for r in eng.prefill_rungs
-                                if r >= d["slots"])
-        assert d["capacity"] == d["rows"] * eng.scfg.prefill_chunk
+    ticks = ticks_of(prefill_dispatches(tel))
+    want = {(1, 6): [1], (2, 6): [1, 1], (4, 6): [4], (5, 6): [6], (6, 6): [6],
+            (5, 16): [4, 1], (7, 16): [4, 4]}
+    assert [d["rows"] for d in ticks[0]] == want[n, slots]
+    assert sum(d["slots"] for d in ticks[0]) == n
+    assert max(sum(d["slots"] for d in t) for t in ticks) == n
+    for t in ticks:
+        assert ([(d["rows"], d["slots"]) for d in t]
+                == list(cover_of(eng, sum(d["slots"] for d in t))))
+        for d in t:
+            assert d["rows"] in eng.prefill_rungs
+            assert d["capacity"] == d["rows"] * eng.scfg.prefill_chunk
+    assert eng.pool.in_use == 0
+    eng.close()
+    tel.close()
+
+
+@pytest.mark.parametrize("n", [5, 7])
+@pytest.mark.parametrize("disagg", [False, True], ids=["colocated", "disagg"])
+def test_a_tick_of_several_dispatches(tiny, requests5, offline_refs, n,
+                                      disagg):
+    """Five and seven prompts mid-prefill at once on the ladder (1, 4, 16),
+    driven by hand: a tick's spans are its cover, numbered `piece` of
+    `pieces` with consecutive `seq`, all enqueued before the tick's one
+    wait (which carries the newest `seq`); every slot advances exactly one
+    chunk a tick; the stats count ticks, dispatches and rows; nothing
+    compiles past the constructor; the tokens are the offline sampler's.
+    The disaggregated engine runs the same tick against its prefill pool."""
+    from picotron_tpu.serve.disagg import DisaggServeEngine
+
+    reqs = (requests5 + requests5[:2])[:n]
+    refs = (offline_refs + offline_refs[:2])[:n]
+    kw = dict(decode_slots=16, num_blocks=128)
+    if disagg:
+        eng, tel = traced_engine(tiny, DisaggServeEngine, disagg=True,
+                                 prefill_slots=16, prefill_num_blocks=128,
+                                 **kw)
+        states = eng.sched.pslots
+    else:
+        eng, tel = traced_engine(tiny, **kw)
+        states = eng.sched.slots
+    assert eng.prefill_rungs == (1, 4, 16)
+    compiles = (eng.stats["prefill_compiles"], eng.stats["decode_compiles"])
+    for i, (p, m) in enumerate(reqs):
+        eng.submit(p, m, req_id=i)
+    chunk = eng.scfg.prefill_chunk
+    while eng.sched.has_work():
+        # (positions prefilled, the prompt's) of what this step's tick will
+        # carry: the slots mid-prefill and, admitted first, the queue
+        before = {st.req.id: (st.n_prefilled, len(st.prefill_ids))
+                  for st in (*states, *eng.sched.queue)
+                  if st is not None and st.prefilling}
+        eng.step(0.0)
+        after = {st.req.id: st.n_prefilled
+                 for st in (*states, *eng.sched.slots) if st is not None}
+        for rid, (done, total) in before.items():
+            if rid in after:  # not retired at its first token
+                assert after[rid] == min(done + chunk, total), rid
+    events = [e for e in tel.tracer.to_json()["traceEvents"] if e["ph"] == "X"]
+    ticks = ticks_of(prefill_dispatches(tel))
+    assert [(d["rows"], d["slots"]) for d in ticks[0]] == {
+        5: [(4, 4), (1, 1)], 7: [(4, 4), (4, 3)]}[n]
+    seqs = [d["seq"] for t in ticks for d in t]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    for t in ticks:
+        assert [d["piece"] for d in t] == list(range(len(t)))
+        assert all(d["pieces"] == len(t) for d in t)
+        assert ([(d["rows"], d["slots"]) for d in t]
+                == list(cover_of(eng, sum(d["slots"] for d in t))))
+    # one wait a tick at most, behind the tick's last dispatch, with its seq
+    order = [e for e in events if e["name"] in ("serve.prefill.dispatch",
+                                                "serve.prefill.wait")]
+    order.sort(key=lambda e: e["ts"])
+    for a, b in zip(order, order[1:]):
+        if b["name"] == "serve.prefill.wait":
+            assert a["name"] == "serve.prefill.dispatch"
+            assert a["args"]["piece"] == a["args"]["pieces"] - 1
+            assert b["args"]["seq"] == a["args"]["seq"]
+    waits = [e["args"] for e in order if e["name"] == "serve.prefill.wait"]
+    assert sum(w["finals"] for w in waits) == n
+    stats = eng.stats
+    assert stats["prefill_ticks"] == len(ticks)
+    assert stats["prefill_dispatches"] == len(seqs) > len(ticks)
+    assert stats["prefill_rows_real"] == stats["prefill_chunks"] == sum(
+        -(-len(p) // chunk) for p, _ in reqs)
+    assert stats["prefill_rows_padded"] == sum(
+        d["rows"] - d["slots"] for t in ticks for d in t)
+    # the one rung that holds a tick's rows would have computed more
+    assert stats["prefill_rows_real"] + stats["prefill_rows_padded"] < sum(
+        min(r for r in eng.prefill_rungs if r >= sum(d["slots"] for d in t))
+        for t in ticks)
+    assert stats["prefill_compiles"] == compiles[0]  # the constructor's
+    assert stats["decode_compiles"] <= compiles[1] + 1
+    res = sorted(eng.results, key=lambda r: r["id"])
+    assert [r["tokens"] for r in res] == refs
     assert eng.pool.in_use == 0
     eng.close()
     tel.close()
@@ -641,24 +813,25 @@ def test_prefill_compiles_once_per_rung_inside_the_constructor(
         tiny, requests5, offline_refs):
     """Every shape the engine can dispatch is held before the constructor
     returns: one prefill compile a rung there, none in a trace whose
-    concurrency falls from five prompts to one and so rides every rung.
-    The pool of 25 blocks is unique to this test (the jit cache is shared,
+    concurrency falls from seven prompts to one and so rides every rung
+    (seven fill the top rung; six ride 4 + 1 + 1).
+    The pool of 29 blocks is unique to this test (the jit cache is shared,
     and a rung below the top one has the same shapes at any slot count)."""
-    eng, tel = traced_engine(tiny, decode_slots=7, num_blocks=25)
+    eng, tel = traced_engine(tiny, decode_slots=7, num_blocks=29)
     assert eng.prefill_rungs == (1, 4, 7)
     assert eng.stats["prefill_compiles"] == 3
-    for i, (p, n) in enumerate(requests5):
+    for i, (p, n) in enumerate(requests5 + requests5[:2]):
         eng.submit(p, n, req_id=i)
     while eng.sched.has_work():
         eng.step(0.0)
-    eng.submit(*requests5[0], req_id=5)  # alone: the one-row rung
+    eng.submit(*requests5[0], req_id=7)  # alone: the one-row rung
     while eng.sched.has_work():
         eng.step(1.0)
     assert {d["rows"] for d in prefill_dispatches(tel)} == {1, 4, 7}
     assert eng.stats["prefill_compiles"] == 3
     assert eng.stats["decode_compiles"] == 1
     res = sorted(eng.results, key=lambda r: r["id"])
-    for r, ref in zip(res, offline_refs + offline_refs[:1]):
+    for r, ref in zip(res, offline_refs + offline_refs[:2] + offline_refs[:1]):
         assert r["tokens"] == ref
     eng.close()
     tel.close()
