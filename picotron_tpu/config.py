@@ -364,6 +364,46 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
         mamba_conv_bias=True, mamba_proj_bias=False,
     ),
+    # Kimi-Linear-48B-A3B-Instruct
+    # (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json,
+    # model_type kimi_linear). 27 layers in periods of (K, K, K, F), the last
+    # period short: a Kimi Delta Attention mixer (ops/kda.py: 32 heads of
+    # 128 x 128, three causal depthwise convolutions of 4 over q, k and v,
+    # the delta rule with a decay a CHANNEL of the key, from a low-rank
+    # projection 2304 -> 128 -> 4096 with a bias a channel, a write strength
+    # a head, a float32 state of 128 x 128 a head, an RMSNorm of each head's
+    # output times a low-rank SIGMOID gate) three times to one latent
+    # attention (MLA: no query bottleneck, a latent of 512 and 64 shared
+    # dimensions that are NOT rotated, mla_use_nope: no position term of any
+    # kind, the mixers order the sequence); layer 1 a dense gated MLP of
+    # 9216, the other 26 top-8 of 256 experts of width 1024 (sigmoid scores
+    # chosen with a bias a column, renormalised, scaled by 2.446) beside one
+    # shared expert; untied 163,840-row head. Built: forward(), generate()
+    # and ServeEngine, on one device. Assumed, with no key in config.json
+    # (the released modelling code; benchmark/reference_kimi_linear.py
+    # repeats the list): no bias in any projection or convolution, SiLU
+    # behind the convolutions, l2-normalised q and k (eps 1e-6, q scaled by
+    # d_k^-0.5), the width of the two bottlenecks (the head's 128), A_log =
+    # log U(1, 16) a head and dt_bias the inverse softplus of a step
+    # log-uniform in [0.001, 0.1] a channel at init, head_dim 72 used by
+    # neither mixer.
+    "moonshotai/Kimi-Linear-48B-A3B-Instruct": dict(
+        vocab_size=163840, hidden_size=2304, intermediate_size=9216,
+        num_hidden_layers=27, num_attention_heads=32, num_key_value_heads=32,
+        max_position_embeddings=1048576, rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        layer_types=("kda", "kda", "kda", "full_attention") * 6
+        + ("kda", "kda", "full_attention"),
+        linear_conv_kernel_dim=4, linear_key_head_dim=128,
+        linear_num_key_heads=32, linear_num_value_heads=32,
+        linear_value_head_dim=128,
+        q_lora_rank=0, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, mla_use_nope=True,
+        first_k_dense_replace=1, num_experts=256, num_experts_per_token=8,
+        moe_intermediate_size=1024, n_shared_experts=1, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=2.446,
+        moe_selection_bias=True, router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -516,6 +556,27 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         mamba_d_state=4, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=3,
         mamba_conv_bias=True, mamba_proj_bias=False,
     ),
+    # Tiny Kimi-Linear-shaped debug model: two periods of (K, K, K, F), the
+    # first layer dense (the expert stack's own slice is then a period (K, K,
+    # F, K) and three layers left over, as the benchmark's 12-layer cut), the
+    # mixer at 4 heads of 8 x 8, the latent attention at 4 heads without a
+    # query bottleneck and without rotation, 16 experts 2 a token + 1 shared,
+    # sigmoid scores with a selection bias. Served with block_size 4.
+    "picotron-tpu/debug-tiny-kimi-linear": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        layer_types=("kda", "kda", "kda", "full_attention") * 2,
+        linear_conv_kernel_dim=4, linear_key_head_dim=8,
+        linear_num_key_heads=4, linear_num_value_heads=4,
+        linear_value_head_dim=8,
+        q_lora_rank=0, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, mla_use_nope=True,
+        first_k_dense_replace=1, num_experts=16, num_experts_per_token=2,
+        moe_intermediate_size=32, n_shared_experts=1, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=2.446,
+        moe_selection_bias=True, router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -558,6 +619,8 @@ _PRESET_ALIASES = {
     "debug-tiny-qwen3-next": "picotron-tpu/debug-tiny-qwen3-next",
     "AI21-Jamba2-3B": "ai21labs/AI21-Jamba2-3B",
     "debug-tiny-jamba": "picotron-tpu/debug-tiny-jamba",
+    "Kimi-Linear-48B-A3B-Instruct": "moonshotai/Kimi-Linear-48B-A3B-Instruct",
+    "debug-tiny-kimi-linear": "picotron-tpu/debug-tiny-kimi-linear",
 }
 
 
@@ -582,7 +645,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next/Jamba-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next/Jamba/Kimi-Linear-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -597,7 +660,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         "longcat_flash" if "zero_expert_num" in hf else "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
                  "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash",
-                 "qwen3_next", "jamba")
+                 "qwen3_next", "jamba", "kimi_linear")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -631,7 +694,9 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         "num_hidden_layers": hf["num_hidden_layers"],
         "num_attention_heads": heads,
         "num_key_value_heads": hf.get("num_key_value_heads", heads),
-        "max_position_embeddings": hf.get("max_position_embeddings", 2048),
+        # (Kimi-Linear publishes its length as model_max_length)
+        "max_position_embeddings": hf.get(
+            "max_position_embeddings", hf.get("model_max_length", 2048)),
         "rope_theta": float(hf.get("rope_theta", 10000.0)),
         "rms_norm_eps": float(hf.get("rms_norm_eps", 1e-5)),
         "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
@@ -661,7 +726,8 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
                  or hf.get("n_routed_experts"))
     if n_experts:
         out["num_experts"] = n_experts
-        out["num_experts_per_token"] = hf.get("num_experts_per_tok", 2)
+        out["num_experts_per_token"] = hf.get(
+            "num_experts_per_tok", hf.get("num_experts_per_token", 2))
         # Mixtral always renormalizes its k gates and has no key for it;
         # OLMoE publishes the key (false)
         out["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
@@ -863,6 +929,56 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
                                 else int(rank))
         out["mamba_conv_bias"] = bool(hf.get("mamba_conv_bias", True))
         out["mamba_proj_bias"] = bool(hf.get("mamba_proj_bias", False))
+    if mtype == "kimi_linear":
+        # Kimi-Linear: the layer order from linear_attn_config's two
+        # 1-indexed lists (an entry beyond num_hidden_layers names no layer
+        # of a model cut in depth), the mixer's heads and convolution by the
+        # same group (one head count and one width for q, k and v), MLA's
+        # widths with q_lora_rank null (no query bottleneck) and
+        # mla_use_nope (neither q_pe nor k_pe is rotated), the leading dense
+        # layer, the router's law by its own keys (use_grouped_topk with ONE
+        # group is no grouping; more are not built) and a selection bias
+        # (e_score_correction_bias, a buffer of the checkpoint)
+        lin = hf["linear_attn_config"]
+        n = out["num_hidden_layers"]
+        kda_at, full_at = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+        if kda_at & full_at or not set(range(1, n + 1)) <= kda_at | full_at:
+            raise ValueError(
+                f"kimi_linear: linear_attn_config's kda_layers and "
+                f"full_attn_layers must name each of the layers 1..{n} once")
+        out["layer_types"] = tuple(
+            KDA if i in kda_at else "full_attention" for i in range(1, n + 1))
+        out["linear_conv_kernel_dim"] = int(lin["short_conv_kernel_size"])
+        out["linear_num_key_heads"] = int(lin["num_heads"])
+        out["linear_num_value_heads"] = int(lin["num_heads"])
+        out["linear_key_head_dim"] = int(lin["head_dim"])
+        out["linear_value_head_dim"] = int(lin["head_dim"])
+        if (int(hf.get("num_expert_group", 1)) != 1
+                or int(hf.get("topk_group", 1)) != 1):
+            raise ValueError(
+                "kimi_linear with num_expert_group / topk_group != 1: "
+                "routing within expert groups is not built (the router picks "
+                "the k largest of all its scores)")
+        if int(hf.get("moe_layer_freq", 1)) != 1:
+            raise ValueError(
+                "kimi_linear with moe_layer_freq != 1: dense MLP layers "
+                "among the expert layers are not built (every layer behind "
+                "first_k_dense_replace holds the experts)")
+        for key in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                    "v_head_dim"):
+            out[key] = int(hf[key])
+        out["q_lora_rank"] = int(hf.get("q_lora_rank") or 0)
+        out["mla_use_nope"] = bool(hf.get("mla_use_nope", False))
+        out["first_k_dense_replace"] = int(hf.get("first_k_dense_replace", 0))
+        out["n_shared_experts"] = int(hf.get("num_shared_experts", 0))
+        out["moe_scoring"] = hf.get("moe_router_activation_func", "sigmoid")
+        out["norm_topk_prob"] = bool(hf.get("moe_renormalize", True))
+        out["routed_scaling_factor"] = float(
+            hf.get("routed_scaling_factor", 1.0))
+        out["moe_selection_bias"] = True
+        out["router_aux_coef"] = 0.0
+        # published, and used by neither mixer (the default hidden / heads)
+        out.pop("head_dim", None)
     if mtype == "olmoe":
         # config.json has no key for either: OLMoE's intermediate_size IS
         # the width of one expert, and its attention normalizes q and k
@@ -1005,8 +1121,9 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
 
 GDN = "linear_attention"  # the kind of a layer that is a Gated DeltaNet mixer
 SSM = "mamba"  # the kind of a layer that is a Mamba-1 selective-scan mixer
+KDA = "kda"  # the kind of a layer that is a Kimi Delta Attention mixer
 # the kinds whose mixer carries a state a SEQUENCE, not a row a position
-RECURRENT = (GDN, SSM)
+RECURRENT = (GDN, SSM, KDA)
 
 
 class Block(NamedTuple):
@@ -1019,7 +1136,8 @@ class Block(NamedTuple):
     # A layer whose kind is "linear_attention" (`Stack.kinds`) runs a Gated
     # DeltaNet mixer in this attention's place (ops/gated_delta.py), over a
     # recurrent state and not over cached positions; one whose kind is
-    # "mamba" a Mamba-1 mixer (ops/selective_scan.py), likewise
+    # "mamba" a Mamba-1 mixer (ops/selective_scan.py), one whose kind is
+    # "kda" a Kimi Delta Attention mixer (ops/kda.py), likewise
     attn: str
     # "dense": gated MLP | "experts": routed (+ shared) experts |
     # "shortcut": the layer is TWO (attention, dense gated MLP) pairs, and
@@ -1092,8 +1210,9 @@ class ModelConfig:
     # The published per-layer attention kinds, "full_attention" or
     # "sliding_attention" a layer, or "linear_attention" (a Gated DeltaNet
     # mixer in the attention's place) or "mamba" (a Mamba-1 selective-scan
-    # mixer there); None = every layer full. The layer scan runs over
-    # whole periods of the pattern (`layer_period`). A sliding layer's
+    # mixer there) or "kda" (a Kimi Delta Attention mixer there, beside
+    # LATENT full attentions); None = every layer full. The layer scan runs
+    # over whole periods of the pattern (`layer_period`). A sliding layer's
     # position i sees j with 0 <= i - j < sliding_window.
     layer_types: Optional[tuple] = None
     sliding_window: Optional[int] = None
@@ -1161,11 +1280,17 @@ class ModelConfig:
     # query and key are qk_nope_head_dim + qk_rope_head_dim wide, its value
     # v_head_dim. A cache holds the latent and the rotated dimensions and
     # nothing per head (ops/mla.py). The published keys.
+    # q_lora_rank 0 beside kv_lora_rank > 0: no query bottleneck, q comes
+    # out of one projection (`q_b` [hidden, heads x (nope + rope)]).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Neither the queries' nor the keys' qk_rope_head_dim shared dimensions
+    # are rotated and no RoPE table is built (the published key; the model
+    # orders its sequence by recurrent mixers).
+    mla_use_nope: bool = False
     # The first k layers keep the dense gated MLP of `intermediate_size`;
     # the layers after them have the experts (the published key). The layer
     # tree is then two stacks, `dense_layers` and `layers` (`stacks`).
@@ -1239,7 +1364,10 @@ class ModelConfig:
     # (ops/gated_delta.py), the published keys: the kernel of the causal
     # depthwise convolution over [q | k | v], the key heads and their
     # width (q's too), the value heads and theirs; value heads are a whole
-    # multiple of key heads, each key head serving that many.
+    # multiple of key heads, each key head serving that many. A "kda"
+    # layer's Kimi Delta Attention mixer (ops/kda.py) reads the same five
+    # (key heads = value heads; its two low-rank projections, the decay's
+    # and the output gate's, pass through linear_value_head_dim numbers).
     linear_conv_kernel_dim: int = 0
     linear_key_head_dim: int = 0
     linear_num_key_heads: int = 0
@@ -1350,6 +1478,11 @@ class ModelConfig:
         return SSM in self.layer_kinds
 
     @property
+    def kda(self) -> bool:
+        """Whether some layer is a Kimi Delta Attention mixer."""
+        return KDA in self.layer_kinds
+
+    @property
     def ssm_inner(self) -> int:
         """d_inner: the channels of a Mamba mixer, each with a state of
         mamba_d_state."""
@@ -1358,8 +1491,9 @@ class ModelConfig:
     @property
     def recurrent_layers(self) -> int:
         """The layers whose mixer carries a state a sequence (a Gated
-        DeltaNet or a Mamba mixer): a model with some is cached in a state
-        pool beside the attentions' K/V."""
+        DeltaNet, a Mamba or a Kimi Delta Attention mixer): a model with
+        some is cached in a state pool beside the attentions' K/V (or their
+        latents)."""
         return sum(k in RECURRENT for k in self.layer_kinds)
 
     @property
@@ -1390,8 +1524,9 @@ class ModelConfig:
     @property
     def attention_sublayers(self) -> int:
         """Attention sublayers of the model, one cache row each: the
-        leading axis of a latent cache."""
-        return sum(st.layers * st.block.attentions for st in self.stacks)
+        leading axis of a latent cache (a recurrent mixer has none)."""
+        return sum(st.layers * st.block.attentions
+                   for st in self.stacks) - self.recurrent_layers
 
     @property
     def stacks(self) -> tuple:
@@ -1439,12 +1574,12 @@ class ModelConfig:
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"num_hidden_layers is {self.num_hidden_layers}")
             bad = set(self.layer_types) - {"full_attention",
-                                           "sliding_attention", GDN, SSM}
+                                           "sliding_attention", *RECURRENT}
             if bad:
                 raise ValueError(
                     f"layer_types entries must be 'full_attention', "
-                    f"'sliding_attention', 'linear_attention' or 'mamba', "
-                    f"got {sorted(bad)}")
+                    f"'sliding_attention', 'linear_attention', 'mamba' or "
+                    f"'kda', got {sorted(bad)}")
             if "sliding_attention" in self.layer_types and (
                     not self.sliding_window or self.sliding_window < 1):
                 raise ValueError(
@@ -1473,12 +1608,33 @@ class ModelConfig:
                     "layers, latent attention, attention_class 'eva', "
                     "first_k_dense_replace, shortcut_moe, sandwich_norm, "
                     "attention_bias and rope_parameters must be unset")
+        elif self.kda:
+            if min(sizes) < 1 or (self.linear_num_value_heads
+                                  != self.linear_num_key_heads):
+                raise ValueError(
+                    f"layer_types holds kda layers: linear_conv_kernel_dim, "
+                    f"linear_key_head_dim, linear_num_key_heads, "
+                    f"linear_num_value_heads and linear_value_head_dim must "
+                    f"be >= 1 and the value heads as many as the key heads, "
+                    f"got {sizes}")
+            if (set(self.layer_types) - {"full_attention", KDA}
+                    or "full_attention" not in self.layer_types
+                    or not self.mla or self.eva or self.shortcut_moe
+                    or self.sandwich_norm or self.rope_parameters
+                    or self.mla_scale_q_lora or self.mla_scale_kv_lora):
+                raise ValueError(
+                    "kda layers are built beside full layers of latent "
+                    "attention (kv_lora_rank > 0, one at least) with two "
+                    "norms a layer: sliding_attention, linear_attention and "
+                    "mamba layers, attention_class 'eva', shortcut_moe, "
+                    "sandwich_norm, rope_parameters and mla_scale_q_lora / "
+                    "mla_scale_kv_lora must be unset")
         elif any(sizes):
             raise ValueError(
                 "linear_conv_kernel_dim / linear_key_head_dim / "
                 "linear_num_key_heads / linear_num_value_heads / "
-                "linear_value_head_dim are a linear_attention layer's: set "
-                "layer_types with them, or none of them")
+                "linear_value_head_dim are a linear_attention layer's (or a "
+                "kda layer's): set layer_types with them, or none of them")
         sizes = (self.mamba_d_state, self.mamba_d_conv, self.mamba_expand,
                  self.mamba_dt_rank)
         if self.ssm:
@@ -1561,26 +1717,31 @@ class ModelConfig:
                 f"hidden_act must be 'silu', 'gelu', or 'gelu_tanh', got "
                 f"{self.hidden_act!r}")
         if self.mla:
-            for key in ("q_lora_rank", "qk_nope_head_dim",
-                        "qk_rope_head_dim", "v_head_dim"):
+            for key in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"):
                 if getattr(self, key) < 1:
                     raise ValueError(
                         f"kv_lora_rank > 0 (latent attention) needs "
                         f"{key} >= 1, got {getattr(self, key)}")
-            if self.qk_rope_head_dim % 2:
+            if self.q_lora_rank < 0:
+                raise ValueError(
+                    f"q_lora_rank must be >= 1, or 0 for no query "
+                    f"bottleneck, got {self.q_lora_rank}")
+            if self.qk_rope_head_dim % 2 and not self.mla_use_nope:
                 raise ValueError("qk_rope_head_dim must be even for RoPE")
-            if (self.attention_bias or self.qk_norm
-                    or self.layer_types is not None or self.rope_parameters):
+            if (self.attention_bias or self.qk_norm or self.rope_parameters
+                    or (self.layer_types is not None and not self.kda)):
                 raise ValueError(
                     "latent attention (kv_lora_rank > 0) has no qkv bias, "
                     "no whole-vector QK-norm, no sliding-window layers and "
-                    "one RoPE law: attention_bias / qk_norm / layer_types / "
-                    "rope_parameters must be unset")
+                    "one RoPE law: attention_bias / qk_norm / rope_parameters "
+                    "must be unset, and layer_types too unless its other "
+                    "layers are kda mixers")
         elif (self.q_lora_rank or self.qk_nope_head_dim
-                or self.qk_rope_head_dim or self.v_head_dim):
+                or self.qk_rope_head_dim or self.v_head_dim
+                or self.mla_use_nope):
             raise ValueError(
                 "q_lora_rank / qk_nope_head_dim / qk_rope_head_dim / "
-                "v_head_dim are latent attention's (MLA) widths: set "
+                "v_head_dim / mla_use_nope are latent attention's (MLA): set "
                 "kv_lora_rank > 0 with them, or none of them")
         if self.attention_class not in ("softmax", "eva"):
             raise ValueError(
@@ -2540,6 +2701,9 @@ class Config:
              any(dict(law).get("rope_type") == "none"
                  for _, law in m.rope_parameters or ())),
             ("latent attention (kv_lora_rank > 0)", m.mla),
+            ("latent attention without a query bottleneck (q_lora_rank 0)",
+             m.mla and not m.q_lora_rank),
+            ("an unrotated latent head (mla_use_nope)", m.mla_use_nope),
             ("first_k_dense_replace > 0", m.first_k_dense_replace > 0),
             ("sandwich_norm", m.sandwich_norm),
             ("n_shared_experts > 0", m.n_shared_experts > 0),
@@ -2619,8 +2783,12 @@ def refuse_training(m: ModelConfig) -> None:
     load is meant to fall on the zero-compute experts), nor has the layer
     of two attentions a fused or pipelined form; a Mamba mixer's scan has
     no backward at training shapes (a [sequence, d_inner, d_state] float32
-    history a layer, kept or recomputed: ROADMAP M9)."""
+    history a layer, kept or recomputed: ROADMAP M9), nor has a Kimi Delta
+    Attention mixer's chunked rule (its within-chunk decays are built block
+    by block with a loop over the columns of each diagonal block, a form
+    written for the served prefill: ROADMAP M9 (a))."""
     what = [name for name, on in (
+        ("kda layers (a delta rule with a decay a channel)", m.kda),
         ("mamba layers (a selective scan)", m.ssm),
         ("attention_class 'eva'", m.eva),
         ("num_pred_heads > 1", m.num_pred_heads > 1),
@@ -2631,8 +2799,9 @@ def refuse_training(m: ModelConfig) -> None:
     if what:
         raise ValueError(
             f"model has {', '.join(what)}, which training does not "
-            f"implement (no backward of the selective scan at training "
-            f"shapes, no loss over several prediction heads, no banded "
+            f"implement (no backward of the selective scan or of the "
+            f"per-channel delta rule at training shapes, no loss over "
+            f"several prediction heads, no banded "
             f"attention kernel with summary keys and its backward, no router "
             f"loss over zero-compute experts and no update of a selection "
             f"bias); such a model runs on forward(), generate() and "
@@ -2827,9 +2996,10 @@ def num_params(m: ModelConfig, active_only: bool = False,
         ffn = dense_ffn
     if m.mla:
         heads = m.num_attention_heads
-        attn = (h * m.q_lora_rank + m.q_lora_rank  # q_a + its norm
-                + m.q_lora_rank * heads * (m.qk_nope_head_dim
-                                           + m.qk_rope_head_dim)
+        # q_a + its norm, then q_b; q_b alone (from h) without a bottleneck
+        attn = (h * m.q_lora_rank + m.q_lora_rank
+                + (m.q_lora_rank or h) * heads * (m.qk_nope_head_dim
+                                                  + m.qk_rope_head_dim)
                 + h * (m.kv_lora_rank + m.qk_rope_head_dim)
                 + m.kv_lora_rank  # kv_a + its norm
                 + m.kv_lora_rank * heads * (m.qk_nope_head_dim
@@ -2863,6 +3033,17 @@ def num_params(m: ModelConfig, active_only: bool = False,
                  + m.gdn_channels * m.linear_conv_kernel_dim + 2 * hv + dv
                  + hv * dv * h)
         layers += m.layer_kinds.count(GDN) * (mixer - attn)
+    if m.kda:
+        # a Kimi Delta Attention mixer in the attention's place: [q | k | v],
+        # the three convolutions, the decay's two projections with dt_bias
+        # and A_log, beta's, the output gate's two, the output norm and the
+        # output projection
+        hv, dv = m.linear_num_value_heads, m.linear_value_head_dim
+        c, key = m.gdn_channels, hv * m.linear_key_head_dim
+        mixer = (h * c + c * m.linear_conv_kernel_dim
+                 + h * dv + dv * key + key + hv
+                 + h * hv + h * dv + dv * hv * dv + dv + hv * dv * h)
+        layers += m.layer_kinds.count(KDA) * (mixer - attn)
     if m.ssm:
         # a Mamba mixer in the attention's place: [u | z], the convolution
         # and its bias, [r | B | C] and their three norms, the step's

@@ -56,11 +56,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from picotron_tpu.config import GDN, SSM, ModelConfig, pattern_of
+from picotron_tpu.config import GDN, KDA, SSM, ModelConfig, pattern_of
 from picotron_tpu.models.llama import (
     BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, conv_from_tail,
     final_hidden,
-    gate_attention, gated_qkv_proj, gdn_mixer, holds, kind_tables,
+    gate_attention, gated_qkv_proj, gdn_mixer, holds, kda_mixer, kind_tables,
     layer_window, mamba_mixer, mlp_act, model_rope_tables, norm_weight,
     own_leaf, qkv_proj, recurrent_start, residual_stream, rms_norm,
     served_head, shared_expert,
@@ -68,7 +68,7 @@ from picotron_tpu.models.llama import (
 from picotron_tpu.ops.eva import (
     chunk_summaries, eva_attention, eva_summarise,
 )
-from picotron_tpu.ops.gated_delta import gated_delta
+from picotron_tpu.ops.kda import delta_rule
 from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope, rotate_half
@@ -241,7 +241,9 @@ class HybridCache(NamedTuple):
     tail, q_pos)` after. A sequence's state before position 0 is zeros,
     whatever the cache holds: `tail_of` and `recur` say so and nobody resets
     a row. Every row is live here, so the recurrence is the plain one
-    (`ops.gated_delta.gated_delta`) on the mixer's whole row of the state."""
+    (`ops.kda.delta_rule`: `ops.gated_delta.gated_delta`, or `ops.kda.kda`
+    where the decay comes a channel) on the mixer's whole row of the
+    state."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -278,8 +280,8 @@ class HybridCache(NamedTuple):
         """The gated delta rule over the segment from mixer gi's state
         (zeros at position 0) -> (o [B, s, Hv, d_v], the cache with the
         state after it)."""
-        o, state = gated_delta(q, k, v, g, beta,
-                               self._carried(self.state, gi, q_pos))
+        o, state = delta_rule(q, k, v, g, beta,
+                              self._carried(self.state, gi, q_pos))
         return o, self._replace(
             state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
 
@@ -298,6 +300,35 @@ class HybridCache(NamedTuple):
             state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
 
 
+class HybridLatentCache(NamedTuple):
+    """`HybridCache` for a model whose full layers are LATENT attentions
+    (Kimi-Linear: Kimi Delta Attention mixers beside MLA): `ckr` as
+    `LatentCache` holds it, [L_full, B, S_max, rank + rope], a layer's row
+    `ki`, beside the mixers' `state` and `tail`, which are held, carried and
+    started from zeros as `HybridCache`'s are (its methods, as they are).
+    The offline twin of `serve.paged_cache.HybridLatentPagedCache`."""
+
+    ckr: jnp.ndarray
+    state: jnp.ndarray
+    tail: jnp.ndarray
+
+    @property
+    def num_layers(self) -> int:
+        return self.ckr.shape[0] + self.state.shape[0]
+
+    def write(self, li, ckr_new, q_pos, ki=None) -> "HybridLatentCache":
+        return self._replace(
+            ckr=LatentCache(self.ckr).write(ki, ckr_new, q_pos).ckr)
+
+    def attend(self, li, q_n, q_r, q_pos, kv_b, cfg, ki=None):
+        return LatentCache(self.ckr).attend(ki, q_n, q_r, q_pos, kv_b, cfg)
+
+    _carried = HybridCache._carried
+    tail_of = HybridCache.tail_of
+    put_tail = HybridCache.put_tail
+    recur = HybridCache.recur
+
+
 def conv_through(cache, gi, x, w, bias, n_valid, moves, q_pos):
     """A Mamba mixer's convolution through a cache's `tail_of` / `put_tail`
     (either hybrid cache): the rows' tails read (zeros at a sequence's
@@ -314,13 +345,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_length: int):
     dt = compute_dtype(cfg)
     if cfg.recurrent:
         n_rec = cfg.recurrent_layers
+        state, tail = recurrent_start(cfg, batch)
+        carried = (jnp.zeros((n_rec,) + state.shape, state.dtype),
+                   jnp.zeros((n_rec,) + tail.shape, tail.dtype))
+        if cfg.mla:
+            return HybridLatentCache(jnp.zeros(
+                (cfg.attention_sublayers, batch, max_length,
+                 cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt), *carried)
         shape = (cfg.num_hidden_layers - n_rec, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
-        state, tail = recurrent_start(cfg, batch)
-        return HybridCache(
-            jnp.zeros(shape, dt), jnp.zeros(shape, dt),
-            jnp.zeros((n_rec,) + state.shape, state.dtype),
-            jnp.zeros((n_rec,) + tail.shape, tail.dtype))
+        return HybridCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+                           *carried)
     if cfg.eva:
         shape = (cfg.num_hidden_layers, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
@@ -442,6 +477,21 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             with scope("gdn_state"):
                 return out, cache.put_tail(ki, tail, q_pos)
 
+    def kda(h, cache, lp, li, kind, ki):
+        """A Kimi Delta Attention mixer, against the cache as `gdn` is: the
+        tail read and put back, the recurrence asked of the cache; the
+        tail's moves stand under the recurrence's own scope, `kda_state` in
+        a decode step and `kda_chunk` in a longer segment."""
+        moves = "kda_state" if h.shape[1] == 1 else "kda_chunk"
+        with scope("kda"):
+            with scope(moves):
+                tail = cache.tail_of(ki, q_pos)
+            out, cache, tail = kda_mixer(
+                h, lp, cfg, partial(cache.recur, ki, q_pos=q_pos),
+                tail, live)
+            with scope(moves):
+                return out, cache.put_tail(ki, tail, q_pos)
+
     def mamba(h, cache, lp, li, kind, ki):
         """A Mamba mixer: the convolution and the recurrence are asked of the
         cache, which runs each on what it holds for mixer `ki` (the tail, the
@@ -469,14 +519,20 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
 
     def mla(h, cache, lp, li, kind, ki):
         """Latent attention: `[c | k_r]` written, c normed and k_r
-        rotated, and attended absorbed or expanded (ops/mla.py)."""
+        rotated (unless the model rotates nothing: `mla_use_nope`), and
+        attended absorbed or expanded (ops/mla.py). A cache beside
+        recurrent mixers is told the layer's ordinal among the full layers
+        (`ki`)."""
         b, s, _ = h.shape
         q_n, q_r, c, k_r = mla_project(h, lp, cfg, keep_flat=True)
-        q_r = _rope(q_r, cos, sin, q_pos)
-        k_r = _rope(k_r[:, :, None, :], cos, sin, q_pos)[:, :, 0]
-        cache = cache.write(li, jnp.concatenate([c, k_r], axis=-1), q_pos)
+        if not cfg.mla_use_nope:
+            q_r = _rope(q_r, cos, sin, q_pos)
+            k_r = _rope(k_r[:, :, None, :], cos, sin, q_pos)[:, :, 0]
+        how = dict(ki=ki) if mixed else {}
+        cache = cache.write(li, jnp.concatenate([c, k_r], axis=-1), q_pos,
+                            **how)
         with scope("paged_attention"):
-            out = cache.attend(li, q_n, q_r, q_pos, lp["kv_b"], cfg)
+            out = cache.attend(li, q_n, q_r, q_pos, lp["kv_b"], cfg, **how)
         with scope("mla_o"):
             return out.reshape(b, s, -1) @ lp["o"].astype(dt), cache
 
@@ -494,7 +550,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """Norm -> one attention against cache row `li` -> its output."""
         h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
                      cfg.rms_norm_eps).astype(dt)
-        mixer = {GDN: gdn, SSM: mamba}.get(kind) or {
+        mixer = {GDN: gdn, SSM: mamba, KDA: kda}.get(kind) or {
             "gqa": gqa, "mla": mla, "eva": eva}[block.attn]
         return mixer(h, cache, lp, li, kind, ki)
 

@@ -39,11 +39,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from picotron_tpu.config import (
-    GDN, RECURRENT, SSM, Block, ModelConfig, pattern_of, refuse_training,
+    GDN, KDA, RECURRENT, SSM, Block, ModelConfig, pattern_of, refuse_training,
 )
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.eva import chunk_summaries, eva_attention
 from picotron_tpu.ops.gated_delta import causal_conv, gated_delta, l2_normalise
+from picotron_tpu.ops.kda import kda
 from picotron_tpu.ops.losses import cross_entropy, cross_entropy_sum_count
 from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -61,7 +62,11 @@ def model_rope_tables(cfg, max_len=None):
     law, or a dict {layer kind: table} for one that publishes a law a kind
     (`rope_parameters`: Mellum2's full layers rotate by YaRN, its sliding
     layers unscaled; K-EXAONE's full layers do not rotate, which is the
-    identity's tables). `kind_tables` picks a layer's pair from either."""
+    identity's tables). `kind_tables` picks a layer's pair from either.
+    (None, None) for a model whose latent attention does not rotate
+    (`mla_use_nope`): nothing reads a table there, and none is built."""
+    if cfg.mla_use_nope:
+        return None, None
     n = max_len or cfg.max_position_embeddings
     if not cfg.rope_parameters:
         # rope_dim: the whole head, or latent attention's shared rotated
@@ -89,17 +94,15 @@ def layer_window(cfg, kind: str):
     return cfg.sliding_window if kind == "sliding_attention" else None
 
 
-def by_period(layer_tree, period: int):
-    """The whole periods of a [L, ...]-stacked layer tree as
-    [L // period, period, ...]: what a scan over whole periods of the layer
-    pattern iterates over. The L % period layers after them are the
-    caller's to run (`pattern_of`)."""
-    def split(x):
-        whole = x.shape[0] // period
-        if x.shape[0] % period:
-            x = x[:whole * period]
-        return x.reshape(whole, period, *x.shape[1:])
-    return jax.tree.map(split, layer_tree)
+def by_period(layer_tree, period: int, whole: int):
+    """The `whole` whole periods of a [L, ...]-stacked layer tree as
+    [whole, period, ...]: what a scan over whole periods of the layer
+    pattern iterates over. The rows after them (the layers left over, or
+    those of them that hold the leaf) are the caller's to run
+    (`pattern_of`)."""
+    return jax.tree.map(
+        lambda x: x[:whole * period].reshape(whole, period, *x.shape[1:]),
+        layer_tree)
 
 
 # A stack whose layers are of two kinds of mixer (softmax attention and a
@@ -107,11 +110,13 @@ def by_period(layer_tree, period: int):
 # over the layers of ITS kind alone, in their order, beside the leaves every
 # layer has (the norms, the MLP or the experts), stacked over all of them:
 # no layer carries the other kind's matrices. These are the softmax
-# attention's leaves; a Gated DeltaNet mixer's are named `gdn_...`, a Mamba
-# mixer's `ssm_...`.
-OWN_PREFIX = {GDN: "gdn_", SSM: "ssm_"}
+# attention's leaves (a latent attention's among them); a Gated DeltaNet
+# mixer's are named `gdn_...`, a Mamba mixer's `ssm_...`, a Kimi Delta
+# Attention mixer's `kda_...`.
+OWN_PREFIX = {GDN: "gdn_", SSM: "ssm_", KDA: "kda_"}
 ATTENTION_LEAVES = ("q", "k", "v", "o", "q_norm", "k_norm", "b_q", "b_k",
-                    "b_v")
+                    "b_v", "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm",
+                    "kv_b")
 
 
 def own_leaf(name: str) -> bool:
@@ -262,7 +267,8 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     kv_out = cfg.num_key_value_heads * d
     n_gdn = tuple(kinds).count(GDN)
     n_ssm = tuple(kinds).count(SSM)
-    na = nl - n_gdn - n_ssm  # layers with a softmax attention
+    n_kda = tuple(kinds).count(KDA)
+    na = nl - n_gdn - n_ssm - n_kda  # layers with a softmax attention
 
     keys = jax.random.split(key, 14)
     # a layer of two (attention, dense MLP) pairs: every leaf of a pair has
@@ -273,8 +279,8 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
         ks = jax.random.split(k, n)
         return jnp.stack([_uniform_fan_in(ks[j], fan_in, shape) for j in range(n)])
 
-    def paired(k, fan_in, shape):
-        return stacked(k, fan_in, pair + shape)
+    def paired(k, fan_in, shape, n=nl):
+        return stacked(k, fan_in, pair + shape, n)
 
     layers = {
         "input_norm": _norm_init(cfg, (nl,) + pair + (h,)),
@@ -287,24 +293,31 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "attn_out_norm": jnp.ones((nl, h), jnp.float32),
             "mlp_out_norm": jnp.ones((nl, h), jnp.float32),
         })
-    if block.attn == "mla":
+    # (a stack of recurrent mixers alone, na = 0, holds no attention's leaf)
+    if block.attn == "mla" and na:
         heads, rank, ql = (cfg.num_attention_heads, cfg.kv_lora_rank,
                            cfg.q_lora_rank)
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
         mk = jax.random.split(keys[1], 4)
+        # (over the layers that hold an attention: `na`, all of them but
+        # beside recurrent mixers)
+        if ql:
+            layers.update({
+                "q_a": paired(mk[0], h, (h, ql), na),
+                "q_a_norm": jnp.ones((na,) + pair + (ql,), jnp.float32),
+            })
         layers.update({
-            "q_a": paired(mk[0], h, (h, ql)),
-            "q_a_norm": jnp.ones((nl,) + pair + (ql,), jnp.float32),
-            "q_b": paired(mk[1], ql, (ql, heads * (dn + dr))),
+            # without a bottleneck (q_lora_rank 0) q comes out of q_b alone
+            "q_b": paired(mk[1], ql or h, (ql or h, heads * (dn + dr)), na),
             # [c | k_r]: the latent and the shared rotated dimensions
-            "kv_a": paired(mk[2], h, (h, rank + dr)),
-            "kv_a_norm": jnp.ones((nl,) + pair + (rank,), jnp.float32),
+            "kv_a": paired(mk[2], h, (h, rank + dr), na),
+            "kv_a_norm": jnp.ones((na,) + pair + (rank,), jnp.float32),
             # a head's [k_n | v] columns side by side (ops/mla.py)
-            "kv_b": paired(mk[3], rank, (rank, heads * (dn + dv))),
-            "o": paired(keys[4], heads * dv, (heads * dv, h)),
+            "kv_b": paired(mk[3], rank, (rank, heads * (dn + dv)), na),
+            "o": paired(keys[4], heads * dv, (heads * dv, h), na),
         })
-    else:
+    elif block.attn != "mla":
         # a gated attention's q holds each head's query, then its gate
         gated = 2 if cfg.attn_output_gate else 1
         layers.update({
@@ -338,6 +351,36 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "gdn_dt_bias": step + jnp.log(-jnp.expm1(-step)),
             "gdn_norm": jnp.ones((n_gdn, dv), jnp.float32),  # a plain weight
             "gdn_out": stacked(gk[4], hv * dv, (hv * dv, h), n_gdn),
+        })
+    if n_kda:
+        hv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+        c, kern = cfg.gdn_channels, cfg.linear_conv_kernel_dim
+        kk = jax.random.split(keys[13], 10)
+        # a step's dt = softplus(dt_bias + ...) around a draw log-uniform in
+        # [0.001, 0.1] a CHANNEL of the key (dt_bias its inverse softplus)
+        # and A = U(1, 16) a head (the released initialiser's): a step keeps
+        # exp(-A dt) of a state's row, 0.37 to 0.999 over the middle nine
+        # tenths of the channels, so a state carries a few to thousands of
+        # positions, its channels side by side (a placeholder dt_bias makes
+        # a mixer without a memory: PERF.md section 6, PR 51)
+        step = jnp.exp(jax.random.uniform(kk[9], (n_kda, hv * dk), jnp.float32,
+                                          math.log(1e-3), math.log(1e-1)))
+        layers.update({
+            "kda_qkv": stacked(kk[0], h, (h, c), n_kda),     # [q | k | v]
+            "kda_conv": stacked(kk[1], kern, (c, kern), n_kda),
+            # the decay's low-rank projection, hidden -> d_v -> heads x d_k
+            "kda_f_a": stacked(kk[2], h, (h, dv), n_kda),
+            "kda_f_b": stacked(kk[3], dv, (dv, hv * dk), n_kda),
+            "kda_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "kda_A_log": jnp.log(jax.random.uniform(
+                kk[4], (n_kda, hv), jnp.float32, 1.0, 16.0)),
+            "kda_beta": stacked(kk[5], h, (h, hv), n_kda),
+            # the output gate's, hidden -> d_v -> heads x d_v
+            "kda_g_a": stacked(kk[6], h, (h, dv), n_kda),
+            "kda_g_b": stacked(kk[7], dv, (dv, hv * dv), n_kda),
+            "kda_norm": jnp.ones((n_kda, dv), jnp.float32),  # a plain weight
+            "kda_out": stacked(kk[8], hv * dv, (hv * dv, h), n_kda),
         })
     if n_ssm:
         di, n, r = cfg.ssm_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
@@ -719,7 +762,8 @@ def gdn_mixer(h, lp, cfg: ModelConfig, recur, tail, live):
 
 def gdn_start(cfg: ModelConfig, rows: int):
     """(state, tail) of `rows` sequences before their first position, both
-    float32: the tail holds the projections' float32 outputs."""
+    float32: the tail holds the projections' float32 outputs. A Kimi Delta
+    Attention mixer's are shaped alike, from the same keys."""
     return (jnp.zeros((rows, cfg.linear_num_value_heads,
                        cfg.linear_key_head_dim, cfg.linear_value_head_dim),
                       jnp.float32),
@@ -734,6 +778,67 @@ def _gdn_block(x, lp, cfg: ModelConfig):
     h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
     state, tail = gdn_start(cfg, h.shape[0])
     out, _, _ = gdn_mixer(h, lp, cfg, partial(gated_delta, state=state), tail,
+                          jnp.ones(h.shape[:2], bool))
+    return out
+
+
+def kda_mixer(h, lp, cfg: ModelConfig, recur, tail, live):
+    """A Kimi Delta Attention mixer (ops/kda.py) over a segment of every
+    row: `gdn_mixer`'s arrangement (h, tail, live, `recur` and what comes
+    back are as described there, every head a key head and a value head),
+    with what the mixer itself changes: q, k and v come out of one projection
+    [q | k | v] and through three depthwise convolutions (one over all
+    their channels); the decay is a CHANNEL of the key's, g [B, s, H, d_k] =
+    -exp(A_log) softplus(W_f2 (W_f1 h) + dt_bias), through a bottleneck of
+    d_v numbers; beta a head from its own projection; the output is each
+    head's RMSNorm (a plain weight) times SIGMOID of a second low-rank
+    projection of h. The recurrence stands under `kda_state` for a decode
+    step and `kda_chunk` for a longer segment, the two low-rank projections
+    and the softplus under `kda_gate`."""
+    dt = h.dtype
+    b, s, _ = h.shape
+    hv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                  cfg.linear_value_head_dim)
+    nq, c = hv * dk, cfg.gdn_channels
+    f32 = jnp.float32
+
+    def low_rank(a, b_):  # h -> d_v numbers -> the heads' channels, float32
+        mid = jnp.matmul(h, lp[a].astype(dt), preferred_element_type=f32)
+        return jnp.matmul(mid.astype(dt), lp[b_].astype(dt),
+                          preferred_element_type=f32)
+
+    # float32 up to the recurrence, as `gdn_mixer` keeps it and for its reason
+    qkv = jnp.matmul(h, lp["kda_qkv"].astype(dt), preferred_element_type=f32)
+    with scope("kda_conv"):
+        mixed, tail = causal_conv(qkv, tail.reshape(b, -1, c), lp["kda_conv"],
+                                  jnp.sum(live, axis=1))
+        tail = tail.reshape(b, -1)
+    q = l2_normalise(mixed[..., :nq].reshape(b, s, hv, dk)) * dk ** -0.5
+    k = l2_normalise(mixed[..., nq:2 * nq].reshape(b, s, hv, dk))
+    v = mixed[..., 2 * nq:].reshape(b, s, hv, dv).astype(f32)
+    with scope("kda_gate"):
+        g = -jnp.exp(lp["kda_A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            low_rank("kda_f_a", "kda_f_b").reshape(b, s, hv, dk)
+            + lp["kda_dt_bias"].astype(f32).reshape(hv, dk))
+        gate = low_rank("kda_g_a", "kda_g_b").reshape(b, s, hv, dv)
+    # a position without a token neither decays nor writes
+    g = jnp.where(live[..., None, None], g, 0.0)
+    beta = jnp.where(live[..., None], jax.nn.sigmoid(jnp.matmul(
+        h, lp["kda_beta"].astype(dt), preferred_element_type=f32)), 0.0)
+    with scope("kda_state" if s == 1 else "kda_chunk"):
+        o, carried = recur(q, k, v, g, beta)
+    o = rms_norm(o, lp["kda_norm"], cfg.rms_norm_eps) * jax.nn.sigmoid(gate)
+    return (o.astype(dt).reshape(b, s, -1) @ lp["kda_out"].astype(dt), carried,
+            tail)
+
+
+@scope("kda")
+def _kda_block(x, lp, cfg: ModelConfig):
+    """RMSNorm -> Kimi Delta Attention mixer over whole sequences from a
+    zero state."""
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
+    state, tail = gdn_start(cfg, h.shape[0])
+    out, _, _ = kda_mixer(h, lp, cfg, partial(kda, state=state), tail,
                           jnp.ones(h.shape[:2], bool))
     return out
 
@@ -929,8 +1034,10 @@ def _mla_attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin):
     b, s, _ = h.shape
     q_n, q_r, c, k_r = mla_project(h, lp, cfg)
     pos = ctx.positions
-    q_r = apply_rope(q_r, cos, sin, pos)
-    k_r = apply_rope(k_r[:, :, None, :], cos, sin, pos)      # one for all heads
+    k_r = k_r[:, :, None, :]                                 # one for all heads
+    if not cfg.mla_use_nope:
+        q_r = apply_rope(q_r, cos, sin, pos)
+        k_r = apply_rope(k_r, cos, sin, pos)
     w_uk, w_uv = up_weights(lp["kv_b"], cfg, dt)
     k_n = jnp.einsum("bsr,rhd->bshd", c, w_uk)
     v = jnp.einsum("bsr,rhd->bshd", c, w_uv)
@@ -1075,6 +1182,8 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
         return _shortcut_layer(x, lp, cfg, ctx, cos, sin, is_real)
     if kind == GDN:
         attn_out = _gdn_block(x, lp, cfg)
+    elif kind == KDA:
+        attn_out = _kda_block(x, lp, cfg)
     elif kind == SSM:
         attn_out = _mamba_block(x, lp, cfg)
     elif block.attn == "mla":
@@ -1207,9 +1316,9 @@ def run_layers(layer_params: Params, x: jnp.ndarray, cfg: ModelConfig,
     xs = (layer_params, real)
     if len(period) > 1:
         # a leaf is split by the layers of a period that hold it
-        xs = ({n: by_period(w, leaf_row(n, period, len(period)))
+        xs = ({n: by_period(w, leaf_row(n, period, len(period)), whole)
                for n, w in layer_params.items()},
-              by_period(real, len(period)))
+              by_period(real, len(period), whole))
     left_over = one
     if ctx.remat:
         policy = remat_policy_for(ctx.remat_policy)

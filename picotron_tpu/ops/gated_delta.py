@@ -108,8 +108,11 @@ def l2_normalise(x, eps: float = 1e-6):
 
 def gated_delta_step(q, k, v, g, beta, state):
     """One token a row. q, k [B, H, d_k]; v [B, H, d_v]; g, beta [B, H];
-    state [B, H, d_k, d_v], all float32 -> (o [B, H, d_v], state')."""
-    s = state * jnp.exp(g)[..., None, None]
+    state [B, H, d_k, d_v], all float32 -> (o [B, H, d_v], state'). g may
+    also come a CHANNEL of the key, [B, H, d_k] (Kimi Delta Attention,
+    ops/kda.py): the state's row d then decays by exp(g[d])."""
+    s = state * (jnp.exp(g)[..., None] if g.ndim == k.ndim
+                 else jnp.exp(g)[..., None, None])
     r = jnp.sum(s * k[..., :, None], axis=-2)
     s = s + k[..., :, None] * (beta[..., None] * (v - r))[..., None, :]
     return jnp.sum(s * q[..., :, None], axis=-2), s
@@ -117,8 +120,8 @@ def gated_delta_step(q, k, v, g, beta, state):
 
 def gated_delta_scan(q, k, v, g, beta, state):
     """A segment token by token. q, k [B, s, H, d_k]; v [B, s, H, d_v];
-    g, beta [B, s, H]; state [B, H, d_k, d_v] -> (o [B, s, H, d_v],
-    state')."""
+    g, beta [B, s, H] (g [B, s, H, d_k] where the decay is a channel's);
+    state [B, H, d_k, d_v] -> (o [B, s, H, d_v], state')."""
     def one(s, xs):
         o, s = gated_delta_step(*xs, s)
         return s, o
@@ -220,7 +223,7 @@ def gated_delta_kernel_suits(s: int, pool) -> bool:
 
 def _step_kernel(gi_ref, slot_ref, fresh_ref, decay_ref, beta_ref, qt_ref,
                  kt_ref, v_ref, pool_in, pool_out, o_ref, order, s_in, s_out,
-                 sems, *, heads: int):
+                 sems, *, heads: int, per_channel: bool = False):
     rows, hv, dv = v_ref.shape
     blocks = hv // heads
     gi = gi_ref[0]
@@ -286,8 +289,11 @@ def _step_kernel(gi_ref, slot_ref, fresh_ref, decay_ref, beta_ref, qt_ref,
             kc = jnp.sum(jnp.where(lane == h, kt, 0.0), axis=1, keepdims=True)
             qc = jnp.sum(jnp.where(lane == h, qt, 0.0), axis=1, keepdims=True)
             vr = jnp.sum(jnp.where(sub == j, v, 0.0), axis=0, keepdims=True)
-            # gated_delta_step, expression for expression
-            s = s_in[buf, j] * decay_ref[b, h]
+            # gated_delta_step, expression for expression; a decay a
+            # channel is handed over as the key is, [d_k, H] a row
+            s = s_in[buf, j] * (
+                jnp.sum(jnp.where(lane == h, decay_ref[b], 0.0), axis=1,
+                        keepdims=True) if per_channel else decay_ref[b, h])
             r = jnp.sum(s * kc, axis=0, keepdims=True)
             s = s + kc * (beta_ref[b, h] * (vr - r))
             s_out[buf, j] = s
@@ -309,7 +315,10 @@ def gated_delta_step_pooled(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
     """`gated_delta_step` for the batch's rows that hold a token, on mixer
     `gi`'s rows of a state pool, in place.
 
-    q, k [B, H, d_k]; v [B, H, d_v]; g, beta [B, H]; pool [L_gdn, slots, H,
+    q, k [B, H, d_k]; v [B, H, d_v]; g, beta [B, H] (g [B, H, d_k] where the
+    decay is a channel's: the same body, the decay a tile [d_k, H] a row in
+    VMEM where the scalar one is a word in SMEM, and the kernel's name
+    `kda_step_pooled`); pool [L_gdn, slots, H,
     d_k, d_v], all float32; gi: the mixer (a scalar, traced or not); rows [B]
     int32: row b's slot, `slots` or more = unmapped; live [B] bool: the row
     holds a token; fresh [B] bool: it starts its sequence (the state it
@@ -363,12 +372,15 @@ def _step_pooled_call(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
                             memory_space=pltpu.VMEM)
 
     qt, kt = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+    per_channel = g.ndim == 3
+    decay = jnp.swapaxes(jnp.exp(g), 1, 2) if per_channel else jnp.exp(g)
     pool, o = pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads),
+        functools.partial(_step_kernel, heads=heads, per_channel=per_channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # the mixer, the rows' slots, their starts
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+            in_specs=[whole(decay) if per_channel
+                      else pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=pltpu.SMEM),
                       whole(qt), whole(kt), whole(v),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -385,9 +397,9 @@ def _step_pooled_call(q, k, v, g, beta, pool, gi, rows, live, fresh, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="gated_delta_step_pooled",
+        name="kda_step_pooled" if per_channel else "gated_delta_step_pooled",
     )(jnp.asarray(gi, jnp.int32).reshape(1), slot.astype(jnp.int32),
-      fresh.astype(jnp.int32), jnp.exp(g), beta, qt, kt, v, pool)
+      fresh.astype(jnp.int32), decay, beta, qt, kt, v, pool)
     return o, pool
 
 

@@ -47,7 +47,8 @@ _NEG = -1e30
 def mla_project(h, lp, cfg, keep_flat: bool = False):
     """The normed block input h [B, s, H] -> (q_n [B, s, heads, nope],
     q_r [B, s, heads, rope] unrotated, c [B, s, rank] normed, k_r
-    [B, s, rope] unrotated). One implementation for `forward()` and the
+    [B, s, rope] unrotated; whether the two are rotated at all is the
+    caller's, `cfg.mla_use_nope`). One implementation for `forward()` and the
     cached decode paths, which keep the flat q a value of its own
     (`keep_flat`) so that `q_b` is read where it lies, as
     `models.llama.qkv_proj` says. Where the model scales its latents
@@ -63,8 +64,9 @@ def mla_project(h, lp, cfg, keep_flat: bool = False):
         return w if scale == 1.0 else w.astype(jnp.float32) * scale
 
     with scope("mla_q"):
+        # through the bottleneck and its norm, or (q_lora_rank 0) straight
         cq = rms_norm(h @ lp["q_a"].astype(dt), weight(lp["q_a_norm"], q_scale),
-                      cfg.rms_norm_eps)
+                      cfg.rms_norm_eps) if cfg.q_lora_rank else h
         q = cq @ lp["q_b"].astype(dt)
         if keep_flat:
             q = jax.lax.optimization_barrier(q)

@@ -111,9 +111,10 @@ from picotron_tpu.generate import _cached_attention, conv_through
 from picotron_tpu.models.llama import compute_dtype, recurrent_start
 from picotron_tpu.ops.eva import chunk_summaries, eva_summarise
 from picotron_tpu.ops.gated_delta import (
-    gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
+    gated_delta_chunk_pooled, gated_delta_chunk_suits,
     gated_delta_kernel_suits, gated_delta_step_pooled, per_value_head,
 )
+from picotron_tpu.ops.kda import delta_rule
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
 )
@@ -927,6 +928,11 @@ def init_eva_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
         eva_table_width(cfg, max_len, block_size)))
 
 
+def _finite(x):
+    """x with zeros where it is not finite."""
+    return jnp.where(jnp.isfinite(x), x, 0)
+
+
 class HybridPagedCache(NamedTuple):
     """The serving cache of a model whose layers are recurrent mixers and
     full attentions side by side: two kinds of state, one of them not
@@ -972,7 +978,14 @@ class HybridPagedCache(NamedTuple):
     and goes back once; a rung's pad rows are skipped). The tiny test
     models and every CPU run gather the rows, run the plain rule and scatter
     them back (`state_of` -> `ops.gated_delta.gated_delta` -> `put_state`).
-    The tail (96 KiB a row) is gathered and scattered in every case."""
+    The tail (96 KiB a row) moves through one product a mixer each way
+    with the rows' one-hot map (`tail_of` / `put_tail`), whatever the rows;
+    a Mamba mixer's, kept in its kernel's rows of lanes, by index.
+    A hybrid pairs its state rows with ONE of two kinds of pool for its full
+    layers: this K/V pool, or a latent pool (`HybridLatentPagedCache`, the
+    sibling below, which takes this class's state half as it is and is where
+    a Kimi Delta Attention mixer's rows live: `recur` takes a decay a channel
+    of the key as it takes a decay a head)."""
 
     k: jnp.ndarray        # [Hkv, L_full, num_blocks, block_size, D]
     v: jnp.ndarray
@@ -1039,12 +1052,48 @@ class HybridPagedCache(NamedTuple):
     def put_state(self, gi, state, q_pos) -> "HybridPagedCache":
         return self._replace(state=self._carry_on(self.state, gi, state, q_pos))
 
+    # The tails' moves. Gathered and scattered by index the compiler walks a
+    # mixer's rows one after the other (a dynamic-update-slice and a select
+    # a slot, whatever is live: at 64 slots of 144 KiB 4.5 of a decode
+    # step's 18.1 ms, twice the recurrence's own kernel; PERF.md section 6,
+    # PR 57). So a mixer's whole plane [slots, W] goes through ONE product
+    # with the rows' one-hot map [B, slots] each way (float32 at the highest
+    # precision: a one times a value and zeros, which is the value to the
+    # bit) and one select, whatever the rows. A product sums over every
+    # slot, and 0 x nan is nan: what is not finite is zeroed on its way in,
+    # so that one request's fault stays in its own row (its state keeps it).
+    # A tail kept in a kernel's rows of 128 lanes (a Mamba mixer's, [slots,
+    # rows, 128]: its decode step is `conv_step_pooled` in place, and only a
+    # prefill chunk's few rows come here) is no matrix without a re-laying
+    # of the whole pool (`tests/test_chip_compile.py` finds that copy), and
+    # goes by index.
+    def _hot(self, keep):
+        """[B, slots] bool: row b's slot, for the rows of `keep` [B] that
+        are mapped."""
+        return (self.stables[:, :1] == jnp.arange(self.tail.shape[1])) & keep[:, None]
+
     def tail_of(self, gi, q_pos):
-        """The tail [B, (kernel - 1) x channels] the rows carry into q_pos."""
-        return self._carried(self.tail, gi, q_pos)
+        """The tail [B, ...] the rows carry into q_pos: their slots', zeros
+        where a row starts at position 0."""
+        if self.tail.ndim != 3:
+            return self._carried(self.tail, gi, q_pos)
+        plane = jax.lax.dynamic_index_in_dim(self.tail, gi, 0, keepdims=False)
+        return jnp.matmul(self._hot(q_pos[:, 0] != 0).astype(plane.dtype),
+                          _finite(plane), precision=jax.lax.Precision.HIGHEST)
 
     def put_tail(self, gi, tail, q_pos) -> "HybridPagedCache":
-        return self._replace(tail=self._carry_on(self.tail, gi, tail, q_pos))
+        """The tail pool with what the rows carry on in mixer gi's rows of
+        their slots; a row without a real position, or an unmapped one,
+        writes nothing."""
+        if self.tail.ndim != 3:
+            return self._replace(tail=self._carry_on(self.tail, gi, tail, q_pos))
+        hot = self._hot(jnp.any(q_pos >= 0, axis=1))
+        plane = jax.lax.dynamic_index_in_dim(self.tail, gi, 0, keepdims=False)
+        new = jnp.matmul(hot.T.astype(plane.dtype), _finite(tail.astype(plane.dtype)),
+                         precision=jax.lax.Precision.HIGHEST)
+        plane = jnp.where(jnp.any(hot, axis=0)[:, None], new, plane)
+        return self._replace(tail=jax.lax.dynamic_update_index_in_dim(
+            self.tail, plane, gi, 0))
 
     def recur(self, gi, q, k, v, g, beta, q_pos):
         """The gated delta rule over the segment (q, k [B, s, Hk, d_k], a
@@ -1053,7 +1102,10 @@ class HybridPagedCache(NamedTuple):
         cache with the state after it). A decode step and a
         prefill chunk that their kernels suit update the pool in place, the
         rows with a real position and a mapped slot alone; everything else
-        gathers, runs the plain rule and scatters."""
+        gathers, runs the plain rule and scatters. g [B, s, Hv, d_k], a
+        decay a CHANNEL (a Kimi Delta Attention mixer's): the decode step's
+        kernel takes it as it takes the other; a prefill chunk has no kernel
+        yet and gathers, runs `ops.kda.kda_chunked` and scatters."""
         where = (self.state, gi, self.stables[:, 0],
                  jnp.any(q_pos >= 0, axis=1), q_pos[:, 0] == 0)
         if gated_delta_kernel_suits(q.shape[1], self.state):
@@ -1061,10 +1113,11 @@ class HybridPagedCache(NamedTuple):
             o, state = gated_delta_step_pooled(
                 q, k, v[:, 0], g[:, 0], beta[:, 0], *where)
             return o[:, None], self._replace(state=state)
-        if gated_delta_chunk_suits(q.shape[1], q.shape[2], self.state):
+        if g.ndim == beta.ndim and gated_delta_chunk_suits(
+                q.shape[1], q.shape[2], self.state):
             o, state = gated_delta_chunk_pooled(q, k, v, g, beta, *where)
             return o, self._replace(state=state)
-        o, state = gated_delta(q, k, v, g, beta, self.state_of(gi, q_pos))
+        o, state = delta_rule(q, k, v, g, beta, self.state_of(gi, q_pos))
         return o, self.put_state(gi, state, q_pos)
 
     def conv(self, gi, x, w, bias, n_valid, moves, q_pos):
@@ -1145,17 +1198,30 @@ class HybridPagedCache(NamedTuple):
                     state_bytes=2 * n * rows * self.state_row_bytes(),
                     state_resets=n * resets)
 
+    def _chunk_counts(self, spans, rows) -> dict:
+        """The state's counts of a prefill dispatch and its rung's
+        `chunk_rows_batch` / `chunk_rows_idle` (`prefill_counts`)."""
+        batch = self.state.shape[0] * (len(spans) if rows is None else rows)
+        real = self.state.shape[0] * sum(n > 0 for _, n in spans)
+        return dict(
+            **self._state_counts(len(spans), sum(p == 0 for p, _ in spans)),
+            chunk_rows_batch=batch, chunk_rows_idle=batch - real)
+
+    def _step_counts(self, spans) -> dict:
+        """The state's counts a step of a decode dispatch and its batch's
+        `state_rows_batch` / `state_rows_idle` (`decode_counts`)."""
+        counts = self._state_counts(len(spans), 0)
+        batch = self.state.shape[0] * self.stables.shape[0]
+        return dict(**counts, state_rows_batch=batch,
+                    state_rows_idle=batch - counts["state_rows"])
+
     def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
         """The state's counts of the dispatch, and what the prefill program's
         rung holds: `chunk_rows_batch` ((row, mixer) pairs, a row with a
         request or a pad row) and `chunk_rows_idle` (those of them without
         a real position, which the chunk's kernel skips); for a model of
         Mamba mixers `scan_tokens` too."""
-        batch = self.state.shape[0] * (len(spans) if rows is None else rows)
-        real = self.state.shape[0] * sum(n > 0 for _, n in spans)
-        counts = dict(
-            **self._state_counts(len(spans), sum(p == 0 for p, _ in spans)),
-            chunk_rows_batch=batch, chunk_rows_idle=batch - real)
+        counts = self._chunk_counts(spans, rows)
         if cfg.ssm:
             # (position, mixer) pairs with a token: what a selective scan
             # runs over, one after the other, whatever the rung pads
@@ -1172,11 +1238,8 @@ class HybridPagedCache(NamedTuple):
         pairs, a row a slot, live or idle) and `state_rows_idle` (those of
         them without a token, whose state a step leaves where it lies)."""
         kv = PagedKVCache.decode_counts(self, spans, cfg)["kv_blocks"]
-        counts = self._state_counts(len(spans), 0)
-        batch = self.state.shape[0] * self.stables.shape[0]
         return dict(kv_blocks=kv, kv_blocks_banded=self.k.shape[1] * kv,
-                    **counts, state_rows_batch=batch,
-                    state_rows_idle=batch - counts["state_rows"])
+                    **self._step_counts(spans))
 
 
 def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -1197,12 +1260,122 @@ def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
         jnp.full((num_slots, 1), num_slots, jnp.int32))
 
 
+class HybridLatentPagedCache(NamedTuple):
+    """`HybridPagedCache` for a model whose full layers are LATENT
+    attentions (Kimi-Linear: Kimi Delta Attention mixers beside MLA): the
+    two kinds of pool a hybrid may pair its state rows with are a K/V pool
+    (`HybridPagedCache`) and, here, a latent pool. The attention half is
+    `LatentPagedCache`'s pool and table, `kv` [L_full, num_blocks,
+    block_size, W] with only the full layers in its leading axis (a layer's
+    row is `ki`), written and attended by that class's own methods (the
+    decode step's `latent_decode_attention`, a prefill chunk's
+    `latent_prefill_attention`, tiles off a chip). The state half is
+    `HybridPagedCache`'s, field for field and method for method (`state`
+    [L_kda, slots, H, d_k, d_v] float32, `tail` [L_kda, slots, (kernel - 1)
+    x channels] float32, `stables`; a start from zeros at position 0, rows
+    without a real position write nothing, `recur` answers for the state:
+    a decode step on a chip is ONE kernel over the pool in place,
+    `kda_step_pooled` in a trace). `generate._decode_layers` calls `write(li,
+    ckr, q_pos, ki=)` / `attend(li, q_n, q_r, q_pos, kv_b, cfg, ki=)` on a
+    full layer and `tail_of` / `recur` / `put_tail` on a mixer. A dispatch's
+    span carries both halves' counts: `latent_blocks` / `latent_keys` over
+    the full layers alone, `state_rows` / `state_bytes` / `state_resets`
+    and the rung's `chunk_rows_*` / `state_rows_*` over the mixers."""
+
+    kv: jnp.ndarray       # [L_full, num_blocks, block_size, W]
+    state: jnp.ndarray    # [L_kda, slots, H, d_k, d_v] float32
+    tail: jnp.ndarray     # [L_kda, slots, (kernel - 1) x channels] float32
+    tables: jnp.ndarray   # [B, max_blocks]; num_blocks = unmapped
+    stables: jnp.ndarray  # [B, 1]; slots = unmapped
+
+    @property
+    def num_layers(self) -> int:
+        return self.kv.shape[0] + self.state.shape[0]
+
+    @property
+    def block_size(self) -> int:
+        return self.kv.shape[2]
+
+    @property
+    def _latent(self) -> LatentPagedCache:
+        return LatentPagedCache(self.kv, self.tables)
+
+    def write(self, li, ckr_new, q_pos, ki=None) -> "HybridLatentPagedCache":
+        return self._replace(kv=self._latent.write(ki, ckr_new, q_pos).kv)
+
+    def attend(self, li, q_n, q_r, q_pos, kv_b, cfg: ModelConfig, ki=None):
+        return self._latent.attend(ki, q_n, q_r, q_pos, kv_b, cfg)
+
+    # the state half, as `HybridPagedCache` has it
+    _carried = HybridPagedCache._carried
+    _carry_on = HybridPagedCache._carry_on
+    state_of = HybridPagedCache.state_of
+    put_state = HybridPagedCache.put_state
+    recur = HybridPagedCache.recur
+    _hot = HybridPagedCache._hot
+    tail_of = HybridPagedCache.tail_of
+    put_tail = HybridPagedCache.put_tail
+    state_row_bytes = HybridPagedCache.state_row_bytes
+    _state_counts = HybridPagedCache._state_counts
+    _chunk_counts = HybridPagedCache._chunk_counts
+    _step_counts = HybridPagedCache._step_counts
+
+    # -- what the serving engine asks (see `PagedKVCache`)
+
+    of = classmethod(HybridPagedCache.of.__func__)
+
+    @property
+    def pools(self) -> tuple:
+        return self.kv, self.state, self.tail
+
+    @property
+    def table_specs(self) -> tuple:
+        return ((self.tables.shape[1], self.kv.shape[1]),
+                (1, self.state.shape[1]))
+
+    scheduler_args = PagedKVCache.scheduler_args
+    slot_rows = HybridPagedCache.slot_rows
+
+    def prefill_counts(self, spans, cfg: ModelConfig, rows=None) -> dict:
+        """`LatentPagedCache`'s `latent_keys` over the full layers
+        (`attn_sublayers` says how many) beside `HybridPagedCache`'s state
+        and rung counts over the mixers."""
+        return dict(self._latent.prefill_counts(spans, cfg),
+                    **self._chunk_counts(spans, rows))
+
+    def decode_counts(self, spans, cfg: ModelConfig) -> dict:
+        """`LatentPagedCache`'s `kv_blocks` and `latent_blocks` (summed
+        over the full layers, each one call of the latent kernel) beside
+        `HybridPagedCache`'s state counts a step of the dispatch."""
+        return dict(self._latent.decode_counts(spans, cfg),
+                    **self._step_counts(spans))
+
+
+def init_hybrid_latent_cache(cfg: ModelConfig, num_blocks: int,
+                             block_size: int, num_slots: int,
+                             max_blocks: int) -> HybridLatentPagedCache:
+    """Zeroed pools + all-unmapped tables: the latent pool over the full
+    layers alone (`cfg.attention_sublayers`), a state row and a tail row a
+    slot and mixer."""
+    latent = init_latent_cache(cfg, num_blocks, block_size, num_slots,
+                               max_blocks)
+    state, tail = recurrent_start(cfg, num_slots)
+    n_rec = cfg.recurrent_layers
+    return HybridLatentPagedCache(
+        latent.kv, jnp.zeros((n_rec,) + state.shape, state.dtype),
+        jnp.zeros((n_rec,) + tail.shape, tail.dtype), latent.tables,
+        jnp.full((num_slots, 1), num_slots, jnp.int32))
+
+
 def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
                      num_blocks: int, max_len: int, sharded: bool = False):
     """The cache a model is served from, zeroed and all-unmapped: `num_slots`
     slots of up to `max_len` positions over a pool of `num_blocks` blocks
     of `scfg.block_size`. The one place that reads a model's configuration
-    for the kind of its serving cache. `sharded`: a mesh shards the pool
+    for the kind of its serving cache: EVA's pool, a hybrid (recurrent
+    mixers: state rows a slot beside a K/V pool, or beside a latent pool
+    where the full layers are latent attentions), a latent pool, a K/V pool
+    with a ring pool for sliding layers, or the plain K/V pool. `sharded`: a mesh shards the pool
     over its KV heads (tp > 1): attention then keeps the gathered view
     whatever the step, which the compiler partitions, and never the
     in-place kernel, which it does not."""
@@ -1211,14 +1384,17 @@ def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
     if cfg.eva:  # one pool, a table row of two regions
         check_eva_serving(cfg, scfg)
         return init_eva_cache(cfg, num_blocks, bs, num_slots, max_len)
-    if cfg.mla:  # one pool with no head axis, sized from the latent's width
-        return init_latent_cache(cfg, num_blocks, bs, num_slots, max_blocks)
-    if cfg.recurrent:  # a state row a slot beside the full layers' pool
+    if cfg.recurrent:  # a state row a slot beside the full layers' pool,
+        # which is a K/V pool or (latent attention) a latent pool
         if sharded:
             raise ValueError(
-                "a model with linear_attention or mamba layers is served "
-                "from one device: the state pool is not sharded (tp = 1)")
-        return init_hybrid_cache(cfg, num_blocks, bs, num_slots, max_blocks)
+                "a model with linear_attention, kda or mamba layers is "
+                "served from one device: the state pool is not sharded "
+                "(tp = 1)")
+        init = init_hybrid_latent_cache if cfg.mla else init_hybrid_cache
+        return init(cfg, num_blocks, bs, num_slots, max_blocks)
+    if cfg.mla:  # one pool with no head axis, sized from the latent's width
+        return init_latent_cache(cfg, num_blocks, bs, num_slots, max_blocks)
     if cfg.layer_types is not None:  # a second pool, a ring a slot
         if sharded:
             raise ValueError(

@@ -58,6 +58,11 @@ SCOPES = (
     "ssm_conv",         # inside ssm_mixer: the causal depthwise convolution with bias over the d_inner channels, and the SiLU
     "ssm_scan",         # inside ssm_mixer: every byte of recurrent state a longer segment moves (a prefill chunk's, forward()'s) and the rule itself: the rows' states and tails out of the pools, the selective scan token by token, the states and tails back
     "ssm_step",         # inside ssm_mixer: every byte of recurrent state a decode step moves and the rule itself: on a chip one kernel over the state pool in place (the live rows' states alone), else the rows' gather, the rule and the scatter; the tail's gather and scatter in every case
+    "kda",              # a Kimi Delta Attention mixer as a whole: its projections, convolutions, decay and gate projections, recurrence, gated norm and output projection
+    "kda_conv",         # inside kda: the three causal depthwise convolutions over [q | k | v] (one over all their channels) with the carried tail, and the SiLU
+    "kda_gate",         # inside kda: the two low-rank projections (the decay's, the output gate's) and the decay's softplus
+    "kda_state",        # inside kda: every byte of recurrent state a DECODE step moves and the rule itself: on a chip one kernel over the state pool in place (the live rows' matrices alone), else the rows' gather, the rule and the scatter; the tail's gather and scatter in every case
+    "kda_chunk",        # inside kda: every byte of recurrent state a longer segment moves (a prefill chunk's, forward()'s) and the chunked per-channel rule: the rows' states and tails out of the pools, the sub-chunks one after the other, the states and tails back
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 

@@ -199,6 +199,12 @@ ONE_TABLE = {
     "mamba": ("debug-tiny-jamba",
               {"serve_prefill": SERVE | {"ssm_mixer", "ssm_conv", "ssm_scan", "attn_full"},
                "serve_decode": SERVE | {"ssm_mixer", "ssm_conv", "ssm_step", "attn_full"}}, 8),
+    # Kimi Delta Attention mixers over a state pool beside unrotated latent
+    # attentions over a LATENT pool: a prefill chunk's recurrence under
+    # `kda_chunk`, a decode step's under `kda_state`
+    "kda": ("debug-tiny-kimi-linear",
+            {"serve_prefill": SERVE | MOE | MLA | {"kda", "kda_conv", "kda_gate", "kda_chunk"},
+             "serve_decode": SERVE | MOE | MLA | {"kda", "kda_conv", "kda_gate", "kda_state"}}, 8),
 }
 
 
@@ -216,7 +222,10 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     A model of Gated DeltaNet mixers and gated attentions: `gdn` with
     `gdn_conv` and `gdn_state` inside it (`gdn_*.serve.json`), `attn_gate`,
     `moe_shared_gate`. A model of Mamba mixers: `ssm_mixer` with `ssm_conv`
-    and the recurrence's scope of that program inside it (`ssm_*.serve.json`)."""
+    and the recurrence's scope of that program inside it (`ssm_*.serve.json`).
+    A model of Kimi Delta Attention mixers and latent attentions: `kda` with
+    `kda_conv`, `kda_gate` and the recurrence's scope of that program inside it
+    (`kda_*.serve.json`) beside the latent scopes."""
     preset, want, chunk = ONE_TABLE[model]
     want = want[program] if isinstance(want, dict) else want
     mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))

@@ -62,6 +62,22 @@ KINDS = {
                     state_rows=18, state_bytes=2 * 18 * 1792, state_resets=0,
                     state_rows_batch=18, state_rows_idle=0),
         sched={})),
+    # (PR 57: the same state half beside a LATENT pool of the full layers alone)
+    "state+latent": ("debug-tiny-kimi-linear", 8, dict(
+        cls="HybridLatentPagedCache",
+        # [c | k_r] of the 2 full layers, then a state and a tail a slot and mixer
+        pools=[(2, 40, 4, 128), (6, 3, 4, 8, 8), (6, 3, 288)],
+        specs=((24, 40), (1, 3)),
+        rows=[ROW, [0]],
+        # the latent pool's counts over its 2 rows (`attn_sublayers`), the state's
+        # over the 6 mixers: 2,176 B a row both ways
+        prefill=dict(attn_sublayers=2, latent_keys=576, state_rows=18,
+                     state_bytes=2 * 18 * 2176, state_resets=6, chunk_rows_batch=18,
+                     chunk_rows_idle=0),
+        decode=dict(attn_sublayers=2, kv_blocks=29, latent_blocks=58, state_rows=18,
+                    state_bytes=2 * 18 * 2176, state_resets=0, state_rows_batch=18,
+                    state_rows_idle=0),
+        sched={})),
 }
 # (positions already cached, tokens of this chunk) a row of a prefill
 # dispatch; (position written first, tokens to emit) a slot of a decode one
@@ -134,3 +150,34 @@ def test_the_state_rows_a_decode_batch_holds_and_those_it_leaves(live):
     assert got["state_rows_batch"] == mixers * SLOTS == 18
     assert got["state_rows"] == mixers * live
     assert got["state_rows_idle"] == got["state_rows_batch"] - got["state_rows"]
+
+
+@pytest.mark.parametrize("preset", ["debug-tiny-qwen3-next", "debug-tiny-kimi-linear"])
+def test_a_tails_moves_are_the_indexed_moves_and_keep_a_fault_to_its_row(preset):
+    """`tail_of` / `put_tail` move a mixer's plane through one product with
+    the rows' one-hot map: to the bit what a gather and a scatter by index
+    move, and a slot whose tail is not finite leaves every other row's as it
+    was (a product sums over every slot, and 0 x nan is nan). (A Mamba
+    mixer's tail, in its kernel's rows of lanes, goes by index as it did.)"""
+    cfg = ModelConfig(dtype="float32", **{**resolve_preset(preset),
+                                          "max_position_embeddings": 128})
+    scfg = ServeConfig(decode_slots=4, block_size=4, prefill_chunk=8,
+                       max_model_len=MAX_LEN, decode_interval=2, num_blocks=BLOCKS)
+    cache = init_serve_cache(cfg, scfg, 4, BLOCKS, MAX_LEN)
+    pool = jax.random.normal(jax.random.PRNGKey(3), cache.tail.shape) * 1e3
+    # rows: slot 2 mid-sequence, slot 0 at its start, a pad row, an unmapped row
+    rows = cache._replace(tail=pool, stables=np.asarray([[2], [0], [1], [4]], np.int32))
+    pos = np.asarray([[8, 9], [0, 1], [-1, -1], [5, 6]])
+    got = rows.tail_of(1, pos)
+    # (an unmapped row's is discarded: by index it reads the last slot's)
+    np.testing.assert_array_equal(got[:3], rows._carried(pool, 1, pos)[:3])
+    assert not np.asarray(got[1]).any() and not np.asarray(got[3]).any()
+    new = jax.random.normal(jax.random.PRNGKey(4), got.shape) * 1e3
+    np.testing.assert_array_equal(rows.put_tail(1, new, pos).tail,
+                                  rows._carry_on(pool, 1, new, pos))
+    # slot 3, which no row holds, and pad row 2's outcome are not finite
+    bad = rows._replace(tail=pool.at[1, 3].set(np.nan))
+    np.testing.assert_array_equal(bad.tail_of(1, pos), got)
+    wrote = bad.put_tail(1, new.at[2].set(np.inf), pos).tail
+    np.testing.assert_array_equal(wrote[:, :3], rows.put_tail(1, new, pos).tail[:, :3])
+    assert np.isnan(np.asarray(wrote[1, 3])).all()
