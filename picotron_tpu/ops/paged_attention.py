@@ -685,3 +685,167 @@ def _latent_prefill_call(q_n, q_r, q_pos, pool, li, tables, kv_b, *,
       kv_b.astype(q_n.dtype).reshape(rank, heads, dn + dv).transpose(1, 0, 2),
       pool)
     return out.transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# The decode step's K/V write: one position a row into the row's own block of
+# both pools, in place. At the END of this file for the reason given above
+# the prefill kernel (the compile cache's keys hold the lines above a call).
+# ---------------------------------------------------------------------------
+
+# VMEM the write may hold: every row's tile of K and of V at once (EvaByte's
+# 16 rows x 32 heads x 4 KB x 2 is 4 MB, K-EXAONE's 48 x 8 x 4 KB x 2 is 3).
+KV_WRITE_VMEM_BYTES = 32 << 20
+
+
+def _sublanes(dtype) -> int:
+    """Rows of a sublane tile of `dtype`: 8 of float32, 16 of bfloat16."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def kv_write_suits(k_new, k_pool) -> bool:
+    """Whether writing k_new [B, s, Hkv, D] into k_pool [Hkv, L, num_blocks,
+    block_size, D] is a step `paged_kv_write` takes compiled: the shapes
+    `decode_kernel_suits` asks for (one position a row, the head whole
+    128-lane rows, the block whole sublane tiles of the pool's dtype, a
+    backend that compiles Pallas kernels), and every row's tile of both
+    pools in VMEM at once. Everything else keeps the scatter: prefill
+    chunks, the tiny test models' heads and blocks, every CPU run, and a
+    pool that a tp mesh shards (`ShardedPagedKVCache` never asks)."""
+    b, _, hkv, d = k_new.shape
+    tile = hkv * _sublanes(k_pool.dtype) * d * jnp.dtype(k_pool.dtype).itemsize
+    return (decode_kernel_suits(k_new, k_pool)
+            and 2 * b * tile <= KV_WRITE_VMEM_BYTES)
+
+
+def _kv_write_kernel(li_ref, page_ref, off_ref, k_new, v_new, k_in, v_in,
+                     k_out, v_out, order, k_buf, v_buf, sems):
+    rows, hkv, sub, d = k_buf.shape
+    li = li_ref[0]
+
+    def note(r, n):  # the rows that are written, compacted into `order`
+        @pl.when(page_ref[r] >= 0)
+        def _():
+            order[n] = r
+        return n + (page_ref[r] >= 0).astype(jnp.int32)
+
+    live = lax.fori_loop(0, rows, note, 0)
+
+    def copies(r, back: bool):
+        # the aligned sublane tile that holds the row, of every KV head: the
+        # strided read the attention kernel makes of a page, `sub` rows of it
+        at = pl.ds(pl.multiple_of(off_ref[r] // sub * sub, sub), sub)
+        for i, (src, dst, buf) in enumerate(((k_in, k_out, k_buf),
+                                             (v_in, v_out, v_buf))):
+            if back:
+                yield pltpu.make_async_copy(
+                    buf.at[r], dst.at[:, li, page_ref[r], at], sems.at[1, i])
+            else:
+                yield pltpu.make_async_copy(
+                    src.at[:, li, page_ref[r], at], buf.at[r], sems.at[0, i])
+
+    def each_live(act):
+        lax.fori_loop(0, live, lambda t, _: act(order[t]), None)
+
+    def start(r, back):
+        for cp in copies(r, back):
+            cp.start()
+
+    def wait(r, back):
+        for cp in copies(r, back):
+            cp.wait()
+
+    def put(r):
+        # every head at once: the new rows ride [B, Hkv, 1, D], a head a
+        # leading index as in the tiles, so a row costs two selects, not two
+        # a head (the unrolled form took 3 s to lower at EvaByte's 32 heads)
+        here = lax.broadcasted_iota(jnp.int32, (hkv, sub, d), 1) == off_ref[r] % sub
+        for new, buf in ((k_new, k_buf), (v_new, v_buf)):
+            buf[r] = jnp.where(here, jnp.broadcast_to(new[r], (hkv, sub, d)), buf[r])
+        start(r, True)
+
+    # every read in flight before the first is waited for, every write-back
+    # before the first of those: a row costs no DMA's latency of its own
+    each_live(lambda r: start(r, False))
+    each_live(lambda r: wait(r, False))
+    each_live(put)
+    each_live(lambda r: wait(r, True))
+
+
+def paged_kv_write(k_pool, v_pool, li, k_new, v_new, phys, off, *,
+                   interpret: Optional[bool] = None):
+    """One new position a row into both pools, in place: what
+    `pool.at[li, phys, off].set(new, mode="drop")` over the KV heads leaves,
+    bit for bit (no arithmetic: the same rows in the same places).
+
+    k_pool / v_pool [Hkv, L, num_blocks, block_size, D]; li: the layer (a
+    scalar, traced or not); k_new / v_new [B, Hkv, D], cast to the pools'
+    dtype as the scatter casts them; phys [B] int32: the physical block of
+    each row's position, `num_blocks` (or anything outside the pool) =
+    dropped; off [B] int32: the offset in it. NO TWO ROWS THAT ARE WRITTEN
+    SHARE A BLOCK (a slot writes into its own last block; the scatter has
+    the same premise for a (block, offset)): the unit moved is the aligned
+    sublane tile that holds the row (a bfloat16 row is half a packed
+    sublane: 16 rows, the whole block at `block_size` 16), read into VMEM,
+    the row put at its offset by a select, and written back, the tile's
+    other rows as they were read.
+
+    One grid step; both pools handed over whole in HBM and aliased to the
+    outputs; a row's tile is `pool[:, li, page, rows]`, one strided DMA for
+    all its KV heads each way; all rows' reads are in flight together and
+    all their write-backs. A dropped row issues no DMA: a call whose rows
+    are all dropped (EvaByte's summary write in a step that closes no
+    chunk) is a launch and nothing else. `interpret=None` compiles on a
+    TPU backend and runs the Pallas interpreter anywhere else; the caller
+    decides whether the shapes suit the compiled kernel (`kv_write_suits`).
+    Returns (k_pool', v_pool')."""
+    if interpret is None:
+        interpret = not compiled_kernels_available()
+    if (k_pool.shape != v_pool.shape or k_new.shape != v_new.shape
+            or k_new.shape[1:] != (k_pool.shape[0], k_pool.shape[4])):
+        raise ValueError(f"pools {k_pool.shape} / {v_pool.shape} do not match "
+                         f"rows {k_new.shape} / {v_new.shape}")
+    return _kv_write_call(k_pool, v_pool, li, k_new, v_new, phys, off,
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_write_call(k_pool, v_pool, li, k_new, v_new, phys, off, *,
+                   interpret: bool):
+    """`paged_kv_write`, jitted: a layer's K/V rows and EvaByte's summary
+    rows call it with the same shapes, and a jitted function is traced and
+    lowered once a program however many call it."""
+    b, hkv, d = k_new.shape
+    sub = _sublanes(k_pool.dtype)
+    k_new = k_new.astype(k_pool.dtype)[:, :, None]
+    v_new = v_new.astype(v_pool.dtype)[:, :, None]
+    page = jnp.where((phys >= 0) & (phys < k_pool.shape[2]), phys, -1)
+
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda *_: (0,) * x.ndim,
+                            memory_space=pltpu.VMEM)
+
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # the layer, the rows' blocks, their offsets
+            grid=(1,),
+            in_specs=[whole(k_new), whole(v_new), pool, pool],
+            out_specs=[pool, pool],
+            scratch_shapes=[
+                pltpu.SMEM((b,), jnp.int32),
+                pltpu.VMEM((b, hkv, sub, d), k_pool.dtype),
+                pltpu.VMEM((b, hkv, sub, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # (in | out, K | V)
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={5: 0, 6: 1},  # the pools, counted with the scalars
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=KV_WRITE_VMEM_BYTES + (16 << 20)),
+        interpret=interpret,
+        name="paged_kv_write",
+    )(jnp.asarray(li, jnp.int32).reshape(1), page.astype(jnp.int32),
+      off.astype(jnp.int32), k_new, v_new, k_pool, v_pool)

@@ -37,10 +37,10 @@ for a v5e with the pool in the default layout
 anywhere, no layer sliced out before the gather, the scatters in place on
 the donated buffers. tests/test_chip_compile.py compiles both and counts.
 
-Writes use the same advanced-indexing scatter for decode (one token per
+Writes address a token's row the same way for decode (one token per
 slot, each at its own position) and chunked prefill (a span of every
-mid-prefill slot); positions < 0 (chunk padding) are routed to the
-sentinel. The attention view gathers a slot's blocks back into logical
+mid-prefill slot): `_slots_of`, with positions < 0 (chunk padding) routed
+to the sentinel. The attention view gathers a slot's blocks back into logical
 order, so `generate._cached_attention` runs on it unchanged — slot j of
 the gathered view holds the token at position j, exactly like the
 contiguous cache, which is what makes paged-vs-contiguous greedy parity a
@@ -56,7 +56,16 @@ max_model_len``), and gathering it was 69% of the decode program's device
 time at Qwen2-1.5B's chat settings, where the traffic holds a sixth of the
 pool at the fullest (PERF.md, PR 32). Prefill chunks (more than one query
 position a slot), shapes the kernel does not take and every CPU run keep
-the view.
+the view. Such a step WRITES in place too (`write`, PR 59): one kernel a
+layer over both pools where they lie (`ops/paged_attention.py
+paged_kv_write`), which reads each live slot's block into VMEM by the
+strided DMA the attention kernel reads it with, puts the new row in, and
+sends the block back, K and V and all the KV heads of a row in one round
+of DMAs each way; an idle slot moves nothing. The scatter it stands in
+for costs by its windows, one a (row, KV head), 92 ns each on a v5e
+whatever they hold (47 us a call at EvaByte's 32 heads x 16 slots, four
+calls a layer: a fifth of that cell's device time, PERF.md, PR 59), and
+still writes the prefill chunks, every CPU run and a tp-sharded pool.
 
 A model with sliding-window layers has two kinds of state
 (`MixedPagedKVCache`): a full-attention layer needs every position of a
@@ -120,8 +129,8 @@ from picotron_tpu.ops.mla import (
 )
 from picotron_tpu.ops.paged_attention import (
     decode_kernel_suits, latent_decode_attention, latent_kernel_suits,
-    latent_prefill_attention, latent_prefill_suits, latent_prefill_tile,
-    paged_decode_attention,
+    kv_write_suits, latent_prefill_attention, latent_prefill_suits,
+    latent_prefill_tile, paged_decode_attention, paged_kv_write,
 )
 from picotron_tpu.ops.selective_scan import (
     conv_kernel_suits, conv_step_pooled, scan_segment,
@@ -184,15 +193,30 @@ class PagedKVCache(NamedTuple):
     @scope("kv_write")
     def write(self, li, k_new, v_new, q_pos,
               ring: bool = False) -> "PagedKVCache":
-        """Scatter K/V [B, s, Hkv, D] into each token's (physical block,
-        offset) slot of layer li. q_pos: [s] batch-shared or [B, s]
-        per-slot global positions; positions < 0, positions beyond the
-        table's capacity, and unmapped table entries all resolve to the
-        out-of-bounds sentinel and are DROPPED by the scatter. `ring`: the
-        table is a ring (a sliding layer's), logical block j at entry
-        j % width, and no position is beyond it."""
+        """K/V [B, s, Hkv, D] into each token's (physical block, offset)
+        row of layer li. q_pos: [s] batch-shared or [B, s] per-slot global
+        positions; positions < 0, positions beyond the table's capacity,
+        and unmapped table entries all resolve to the out-of-bounds
+        sentinel and are DROPPED. `ring`: the table is a ring (a sliding
+        layer's), logical block j at entry j % width, and no position is
+        beyond it. One result, two forms, chosen from what the step's
+        shapes and the backend say (`kv_write_suits`): a decode step on a
+        chip is ONE kernel over both pools in place (`paged_kv_write`: a
+        row's block comes into VMEM, takes the row, and goes back, one DMA
+        each way for all its KV heads; a dropped row moves nothing);
+        everything else scatters. No two rows of a decode step share a
+        block: a slot writes into its own last block."""
         phys, off = _slots_of(self.tables, q_pos, k_new.shape[0],
                               self.block_size, self.num_blocks, ring)
+        if kv_write_suits(k_new, self.k):
+            k, v = paged_kv_write(self.k, self.v, li, k_new[:, 0], v_new[:, 0],
+                                  phys[:, 0], off[:, 0])
+            return self._replace(k=k, v=v)
+        return self._scatter(li, k_new, v_new, phys, off)
+
+    def _scatter(self, li, k_new, v_new, phys, off) -> "PagedKVCache":
+        """K/V [B, s, Hkv, D] into rows `off` [B, s] of blocks `phys` [B, s]
+        of layer li; a block outside the pool is dropped."""
         # the indices are batched over the heads with the pool: vmapped
         # over pool and rows alone, the head folds into the scatter's
         # window and the compiler carries the pool heads-minor again
@@ -668,10 +692,17 @@ def init_latent_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 class ShardedPagedKVCache(PagedKVCache):
     """The pool of an engine whose mesh shards it over the KV heads
     (tp > 1 serving): the compiler does not partition a Pallas call, so
-    every step attends the gathered view, which it does partition."""
+    every step attends the gathered view and writes by the scatter, both
+    of which it does partition."""
 
     def attend(self, li, q, q_pos):
         return _cached_attention(q, *self.layer_view(li), q_pos)
+
+    @scope("kv_write")
+    def write(self, li, k_new, v_new, q_pos, ring: bool = False):
+        return self._scatter(li, k_new, v_new, *_slots_of(
+            self.tables, q_pos, k_new.shape[0], self.block_size,
+            self.num_blocks, ring))
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,

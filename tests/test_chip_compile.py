@@ -545,12 +545,36 @@ def result_sizes(line: str) -> list:
             for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))] if m else []
 
 
+def kv_write_kernels(ins, pool_shapes, program: str, calls: int) -> list:
+    """The K/V write kernels [(name, op_name)] of a compiled serve program,
+    found as `kv_write_ms.serve` finds their events. Since PR 59
+    `serve_decode` holds `calls` of them, under the scope `kv_write`, and no
+    instruction under a scatter's name, fused or not, whose result has a
+    pool's shape: a step's rows go into both pools through `paged_kv_write`,
+    in place. A prefill program is the parent's: such scatters, no kernel."""
+    named = re.compile(load("layer_metrics", "kv_write_ms.serve")["params"]["ops"])
+    sizes = [[math.prod(shape)] for shape in pool_shapes]
+    written = [(n, op) for n, op, line in ins
+               if "tpu_custom_call" in line and named.search(n)]
+    scatters = [n for n, op, line in ins if "tpu_custom_call" not in line
+                and "scatter" in words(op) and result_sizes(line) in sizes]
+    if program == "serve_decode":
+        assert len(written) == calls and not scatters, (written, scatters)
+        assert all("kv_write" in words(op) for _, op in written), written
+    else:
+        assert not written and scatters, (written, scatters)
+    return written
+
+
 def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
     """The decode program reads K/V through the block table inside one Mosaic
     kernel a layer and builds no view of the pool: before PR 32 it held two
     gathers `bf16[16384,16,128]` a layer (2 KV heads x 32 slots x 256 table
-    entries of 16 x 128), 69% of its device time. The prefill program is
-    what it was: its rows' views gathered, no kernel."""
+    entries of 16 x 128), 69% of its device time. It writes the step's rows
+    by one kernel a layer too, and holds no scatter shaped like a pool: before
+    PR 59 two a layer (`fusion.171/.173 bf16[2,28,8192,16,128]`), 7% of the
+    cell's busy time. The prefill program is what it was: its rows' views
+    gathered, its rows scattered, no kernel."""
     c = load("configs", "qwen2-1.5b")
     m, sc = c["model"], c["serve"]
     max_blocks = blocks_for(sc["max_model_len"], sc["block_size"])
@@ -560,7 +584,7 @@ def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
                 * (m["hidden_size"] // m["num_attention_heads"]))
 
     assert view(CHAT_SLOTS) == 2 * 32 * 256 * 16 * 128
-    text, _, _ = compiled_serve(topo, monkeypatch, "serve_decode")
+    text, pool_shape, _ = compiled_serve(topo, monkeypatch, "serve_decode")
     comps = computations(text)
     big = [line.strip()[:160] for lines in comps.values() for line in lines
            if view(CHAT_SLOTS) in result_sizes(line)]
@@ -569,15 +593,19 @@ def test_decode_attends_in_place_and_prefill_keeps_its_views(topo, monkeypatch):
     kernels = [(comp, n, op) for comp, lines in comps.items()
                for n, op, line in instructions("\n".join(lines))
                if "tpu_custom_call" in line]
-    assert len(kernels) == 1, kernels
-    comp, name, op = kernels[0]
-    assert comp in in_loop  # inside the layer scan: once a layer
+    assert len(kernels) == 2, kernels
+    (comp, name, op), (wcomp, wname, _) = sorted(kernels, key=lambda k: k[1])
+    assert comp in in_loop and wcomp in in_loop  # inside the layer scan: once a layer
     assert name.startswith("paged_decode_attention")  # what a trace shows
     assert "paged_attention" in words(op)
-    # the prefill program: the view of its rows is there, K and V; no kernel
+    written = kv_write_kernels(instructions(text), [pool_shape], "serve_decode", 1)
+    assert [n for n, _ in written] == [wname], kernels
+    # the prefill program: the view of its rows is there, K and V; no kernel;
+    # its chunks' rows are scattered, K and V
     for rows in prefill_rungs(CHAT_SLOTS)[:2]:
         ptext, _, _ = compiled_serve(topo, monkeypatch, "serve_prefill", rows)
         assert "tpu_custom_call" not in ptext
+        kv_write_kernels(instructions(ptext), [pool_shape], "serve_prefill", 0)
         views = [n for n, op, line in instructions(ptext)
                  if view(rows) in result_sizes(line)
                  and "paged_attention" in words(op)]
@@ -623,7 +651,9 @@ def test_mellum2_serving_programs(topo, monkeypatch, program, rows):
     attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
     paged = [(n, op) for n, op in kernels if attn.search(n)]
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
-    assert len(grouped) + len(paged) == len(kernels), kernels
+    # a write kernel a layer of a decode step, into the pools of its kind (PR 59)
+    written = kv_write_kernels(ins, pool_shapes, program, 4)
+    assert len(grouped) + len(paged) + len(written) == len(kernels), kernels
     # the experts of each of the period's four layers are ONE kernel (gate,
     # up, activation and down; ops/grouped_experts.py) at every number of
     # rows, and its event carries the scope `moe_experts_ms.serve` and
@@ -695,7 +725,9 @@ def test_k_exaone_serving_programs(topo, monkeypatch, program, rows):
     attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
     paged = [(n, op) for n, op in kernels if attn.search(n)]
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
-    assert len(grouped) + len(paged) == len(kernels), kernels
+    # a write kernel beside each decode attention, into the pools of its kind (PR 59)
+    written = kv_write_kernels(ins, pool_shapes, program, 5)
+    assert len(grouped) + len(paged) + len(written) == len(kernels), kernels
     scopes = set(load("layer_metrics", "moe_experts_ms.serve")["params"]["scopes"])
     assert all(scopes <= words(op) for _, op in grouped), grouped
     assert "ragged-dot" not in text
@@ -740,7 +772,10 @@ PARENT_EVA_DECODE_BYTES = 9_755_770_368
 def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     """Both serve programs of `evabyte-6.5b-8l` compile for a v5e and fit it
     beside the pool; the one pool is not copied whole and is written in place
-    (the positions' rows and the summaries' rows: two scatters a layer); the
+    (the positions' rows and the summaries' rows: two calls of the write
+    kernel a layer in the decode step since PR 59 and no scatter shaped like
+    the pool, where there were four, a fifth of the cell's busy time; two
+    scatters a tensor and layer in a prefill chunk, as before); the
     decode step attends through THE decode kernel under its own name, one call
     in the layer scan's body, with a chunk of pages that fits its 32 KV heads
     into fast memory; a prefill chunk walks tiles; the pooling's scope is in
@@ -763,9 +798,14 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     assert {int(p) for p in re.findall(r"\}: \((\d+), ", alias)} >= pools, alias
     kernels = [(n, op) for n, op, line in ins if "tpu_custom_call" in line]
     attn = re.compile(load("layer_metrics", "paged_attention_ms.serve")["params"]["ops"])
+    # a decode step: the positions' rows, then the summaries' rows through the
+    # same call (ONE lowering: `_kv_write_call` is jitted), each the loop's own
+    # instruction with both pools aliased through it; a prefill chunk scatters
+    # its blocks and its summaries' rows
+    written = kv_write_kernels(ins, [pool_shape], program, 2)
     if program == "serve_decode":
-        assert len(kernels) == 1 and attn.search(kernels[0][0]), kernels
-        assert "paged_attention" in words(kernels[0][1])
+        (paged,) = [k for k in kernels if k not in written]
+        assert attn.search(paged[0]) and "paged_attention" in words(paged[1]), kernels
     else:
         assert not kernels  # a chunk walks its keys in tiles, no kernel
     ma = comp.memory_analysis()
@@ -779,15 +819,8 @@ def test_evabyte_serving_programs(topo, monkeypatch, program, rows):
     assert_weights_read_in_place(text, name)
     if program != "serve_decode":
         return
-    # the decode step's two scatters a pool and layer (a position's row, a
-    # summary's row), each the loop's own instruction with the whole pool as its
-    # result; until PR 49 the loop held a fifth, K's rows scattered a second time
-    # (rematerialised) for the chunk's gather
     comps = computations(text)
     in_loop = loop_computations(text, comps)
-    scatters = [n for n, op, line in ins if " fusion(" in line and "scatter" in words(op)
-                and result_sizes(line) == [math.prod(pool_shape)]]
-    assert len(scatters) == 2 * len(pools), scatters
     # PR 49: the chunk's rows are read and pooled only in a step where some slot's
     # position ends a chunk. ONE conditional, in the layer loop's body (a while
     # body that the step loop's body reaches), which the compiler has not turned
@@ -1050,7 +1083,11 @@ def test_qwen3_next_serving_programs(topo, monkeypatch, program, rows):
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
     state = [(n, op) for n, op in kernels if n.startswith("gated_delta_step_pooled")]
     chunk = [(n, op) for n, op in kernels if n.startswith("gated_delta_chunk_pooled")]
-    assert len(grouped) + len(paged) + len(state) + len(chunk) == len(kernels), kernels
+    # the period's one full layer writes its rows by the kernel in a decode step
+    # (PR 59), by the scatter in a prefill chunk
+    written = kv_write_kernels(ins, [cache.k.shape], program, 1)
+    assert (len(grouped) + len(paged) + len(state) + len(chunk) + len(written)
+            == len(kernels)), kernels
     assert len(grouped) % 4 == 0 and len(grouped) >= 4 and "ragged-dot" not in text
     assert all("moe_experts" in words(op) for _, op in grouped), grouped
     # a batch's rows of one mixer's state, gathered or to be scattered
@@ -1183,7 +1220,11 @@ def test_jamba_serving_programs(topo, monkeypatch, program, rows):
     step = [(n, op) for n, op in kernels if n.startswith("selective_scan_step_pooled")]
     chunk = [(n, op) for n, op in kernels if n.startswith("selective_scan_chunk_pooled")]
     conv = [(n, op) for n, op in kernels if n.startswith("ssm_conv_step_pooled")]
-    assert len(paged) + len(step) + len(chunk) + len(conv) == len(kernels), kernels
+    # the period's one attention layer writes its rows by the kernel in a decode
+    # step (PR 59), by the scatter in a prefill chunk
+    written = kv_write_kernels(ins, [cache.k.shape], program, 1)
+    assert (len(paged) + len(step) + len(chunk) + len(conv) + len(written)
+            == len(kernels)), kernels
     # the live rows' states alone: no batch of states is gathered or scattered
     assert f"f32[{rows or slots},16,5120]" not in text
     if program == "serve_decode":

@@ -4,6 +4,8 @@ CPU through the Pallas interpreter: the kernel against the gathered view +
 `serve_decode`. The compiled kernel at the chat cell's shapes is held by
 tests/test_chip_compile.py; its times are chip runs (PERF.md)."""
 
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +19,7 @@ from picotron_tpu.models.llama import (
     final_hidden, head_weight, init_params, model_rope_tables,
 )
 from picotron_tpu.ops.paged_attention import (
-    decode_kernel_suits, paged_decode_attention,
+    decode_kernel_suits, paged_decode_attention, paged_kv_write,
 )
 from picotron_tpu.serve import ServeEngine, engine, paged_cache
 from picotron_tpu.serve.paged_cache import PagedKVCache
@@ -135,25 +137,28 @@ def test_sharded_pool_keeps_the_view(tiny, monkeypatch, fresh_programs):
     """tp = 2 serving pins the pool over its KV heads, and the compiler does
     not partition a Pallas call: with the kernel forced in wherever the
     step's shape allows it, no program of such an engine asks for it (a
-    prefill chunk of ONE token is a decode-shaped step), and the tokens are
-    the single-device engine's."""
+    prefill chunk of ONE token is a decode-shaped step), nor for the write's
+    kernel, and the tokens are the single-device engine's."""
     from picotron_tpu.generate import place_for_decode
 
     cfg, params = tiny
-    asked = []
+    asked, wrote = [], []
     monkeypatch.setattr(paged_cache, "decode_kernel_suits",
                         lambda q, k: asked.append(q.shape[1]) or True)
+    # (asked and refused: the tiny model's blocks are no sublane tile)
+    monkeypatch.setattr(paged_cache, "kv_write_suits",
+                        lambda new, k: wrote.append(new.shape[1]) or False)
     scfg = ServeConfig(decode_slots=2, block_size=4, num_blocks=16,
                        prefill_chunk=1, max_model_len=32, decode_interval=2)
     requests = [([3, 1, 4, 1, 5], 4), ([9, 2, 6], 5)]
     tokens = {}
     for tp in (2, 1):
-        del asked[:]
+        del asked[:], wrote[:]
         eng = ServeEngine(place_for_decode(params, cfg, tp=tp), cfg, scfg)
         assert (type(eng.cache) is paged_cache.ShardedPagedKVCache) == (tp == 2)
         tokens[tp] = [r["tokens"] for r in eng.run(requests)]
         eng.close()
-        assert bool(asked) == (tp == 1)
+        assert bool(asked) == (tp == 1) and bool(wrote) == (tp == 1)
     assert tokens[2] == tokens[1]
 
 
@@ -423,3 +428,146 @@ def test_latent_engine_decodes_through_the_kernel(monkeypatch, fresh_programs):
     for a, b in zip(plain, kernel):
         assert a["tokens"] == b["tokens"]
         np.testing.assert_allclose(a["logits"], b["logits"], atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the decode step's K/V write: one kernel over both pools in place
+# ---------------------------------------------------------------------------
+
+EVA = SimpleNamespace(window_size=64, chunk_size=4)  # what `EvaPagedCache.write` reads of a config
+
+KV_WRITE_CASES = {
+    # positions a row (one a slot; -1: an idle slot); `held`: the blocks of that
+    # many positions are mapped in a slot's table, the rest of its row unmapped
+    "bf16_block_16": dict(bs=16, hkv=2, pos=[3, 16, 40, 95]),
+    "bf16_block_32": dict(bs=32, hkv=2, pos=[3, 16, 33, 100]),   # either half of a block
+    "f32_block_8": dict(bs=8, hkv=2, pos=[3, 8, 20, 47], dtype=jnp.float32),
+    "one_kv_head": dict(bs=16, hkv=1, pos=[0, 17, 34, 51]),
+    "two_kv_heads_last_layer": dict(bs=16, hkv=2, pos=[5, 21, 37, 53], li=L - 1),
+    "a_kv_head_a_query_head_32": dict(bs=16, hkv=32, pos=[9, 30]),
+    "positions_below_zero": dict(bs=16, hkv=2, pos=[-1, 18, -1, 4]),
+    "unmapped_entries": dict(bs=16, hkv=2, pos=[40, 18, 95, 50], held=[33, 16, 96, 48]),
+    "beyond_the_table": dict(bs=16, hkv=2, pos=[96, 4, 1000], held=[96, 96, 96]),
+    "ring_table": dict(bs=16, hkv=2, pos=[3, 96, 200, 1001], ring=True),
+    "every_row_dropped": dict(bs=16, hkv=2, pos=[-1, -1, 40], held=[96, 96, 16]),
+    "a_blocks_first_and_last_offset": dict(bs=16, hkv=2, pos=[0, 15, 16, 31]),
+    # EvaByte's decode write: the window rows, then the summary rows through the
+    # same call; 35 ends chunk 8 (the summary write moves one row), no position
+    # of the other step ends a chunk (it moves none)
+    "eva_step_that_closes_a_chunk": dict(bs=16, hkv=2, pos=[5, 35, 70, -1], eva=True),
+    "eva_step_that_closes_none": dict(bs=16, hkv=2, pos=[5, 34, 70, 0], eva=True),
+}
+
+
+def bits(x):
+    return np.asarray(x).view(np.uint8)
+
+
+@pytest.mark.parametrize("name", KV_WRITE_CASES)
+def test_kv_write_kernel_leaves_what_the_scatter_leaves(name, monkeypatch):
+    """`PagedKVCache.write` of a decode step through `paged_kv_write`
+    (interpreted) leaves both pools EQUAL, bit for bit, to what its scatter
+    leaves: the same rows in the same places, every other row of a moved tile
+    as it was (NaN among them), the rows the scatter drops dropped."""
+    c = KV_WRITE_CASES[name]
+    bs, hkv, dtype = c["bs"], c["hkv"], c.get("dtype", jnp.bfloat16)
+    pos = np.asarray(c["pos"], np.int32)
+    rng = np.random.default_rng(sorted(KV_WRITE_CASES).index(name))
+    held = c.get("held", [MB * bs] * len(pos))
+    if c.get("eva"):
+        width = paged_cache.eva_table_width(EVA, MB * bs, bs)
+        held = [width * bs] * len(pos)
+    else:
+        width = MB
+    # every slot's blocks its own, drawn from all over the pool without order
+    nb = len(pos) * width + 3
+    free = list(rng.permutation(nb))
+    tables = np.full((len(pos), width), nb, np.int32)
+    for b, n in enumerate(held):
+        for j in range(-(-n // bs)):
+            tables[b, j] = free.pop()
+    shape = (hkv, L, nb, bs, 128)
+    k = rng.standard_normal(shape).astype(np.float32)
+    k[:, :, ::3, 1::4] = np.nan   # rows a moved tile carries back as they were
+    k, v = jnp.asarray(k, dtype), jnp.asarray(rng.standard_normal(shape), dtype)
+    k_new = jnp.asarray(rng.standard_normal((len(pos), 1, hkv, 128)), dtype)
+    v_new = jnp.asarray(rng.standard_normal((len(pos), 1, hkv, 128)), dtype)
+    li, q_pos = jnp.int32(c.get("li", 1)), jnp.asarray(pos)[:, None]
+
+    if c.get("eva"):
+        cache = paged_cache.EvaPagedCache(k, v, jnp.asarray(tables))
+        mu = jnp.asarray(rng.standard_normal((hkv, 128)), dtype)
+        phi = jnp.asarray(rng.standard_normal((hkv, 128)), dtype)
+
+        def write(ch):
+            return ch.write(li, k_new, v_new, q_pos, mu, phi, EVA)
+    else:
+        cache = PagedKVCache(k, v, jnp.asarray(tables))
+
+        def write(ch):
+            return ch.write(li, k_new, v_new, q_pos, ring=c.get("ring", False))
+
+    want = jax.jit(write)(cache)
+    calls = []
+
+    def kernel(k_pool, v_pool, li, k_rows, v_rows, phys, off):
+        calls.append(phys)
+        return paged_kv_write(k_pool, v_pool, li, k_rows, v_rows, phys, off)
+
+    monkeypatch.setattr(paged_cache, "kv_write_suits", lambda new, pool: True)
+    monkeypatch.setattr(paged_cache, "paged_kv_write", kernel)
+    got = jax.jit(lambda ch: write(ch))(cache)  # a function jit has not traced
+    assert len(calls) == (2 if c.get("eva") else 1)   # K and V in ONE call
+    assert type(got) is type(cache)
+    assert np.array_equal(bits(got.k), bits(want.k))
+    assert np.array_equal(bits(got.v), bits(want.v))
+    # the case writes what it says: a row a live position the table maps, one
+    # more where a position ends a chunk, in layer li alone
+    live = sum(0 <= p and (c.get("ring") or p < -(-h // bs) * bs)
+               for p, h in zip(pos, held))
+    ends = sum(p >= 0 and (p + 1) % EVA.chunk_size == 0 for p in pos) if c.get("eva") else 0
+    changed = (bits(got.v) != bits(v)).reshape(hkv, L, nb, bs, -1).any(axis=(0, -1))
+    assert changed.sum() == live + ends and changed[int(li)].sum() == live + ends
+    if name == "every_row_dropped":
+        assert np.array_equal(bits(got.k), bits(k))
+
+
+def test_the_step_decides_how_it_writes(monkeypatch):
+    """`kv_write_suits`: the decode kernel's predicate on the new rows and the
+    pool, and every row's tile of both pools in VMEM at once; no option."""
+    from picotron_tpu.ops import paged_attention as pa
+    pool = jnp.zeros((2, L, NB, 16, 128), jnp.bfloat16)
+    new = jnp.zeros((3, 1, 2, 128), jnp.bfloat16)
+    assert not pa.kv_write_suits(new, pool)  # the CPU compiles no kernel
+    monkeypatch.setattr(pa, "compiled_kernels_available", lambda: True)
+    assert pa.kv_write_suits(new, pool)
+    assert not pa.kv_write_suits(jnp.zeros((3, 5, 2, 128)), pool)        # a chunk
+    assert not pa.kv_write_suits(new[..., :64], pool[..., :64])          # head 64
+    assert not pa.kv_write_suits(new, pool[:, :, :, :8])       # half a bf16 tile
+    assert pa.kv_write_suits(new, pool[:, :, :, :8].astype(jnp.float32))
+    rows = pa.KV_WRITE_VMEM_BYTES // (2 * 2 * 16 * 128 * 2)    # tiles that fit
+    assert pa.kv_write_suits(jnp.zeros((rows, 1, 2, 128)), pool)
+    assert not pa.kv_write_suits(jnp.zeros((rows + 1, 1, 2, 128)), pool)
+
+
+def test_no_two_slots_hold_a_block(tiny):
+    """The premise of `paged_kv_write` (and of the scatter): no two rows of a
+    decode step write into one block, because a slot's blocks are its own from
+    admission to its last token. Held on the host's tables at every step of a
+    run with more requests than slots."""
+    cfg, params = tiny
+    rng = np.random.default_rng(3)
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=3, block_size=4, num_blocks=24, prefill_chunk=4,
+        max_model_len=32, decode_interval=2))
+    for n, m in ((5, 6), (9, 3), (3, 8), (7, 5), (11, 4)):
+        eng.submit(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+    steps = 0
+    while eng.sched.has_work():
+        eng.step()
+        steps += 1
+        (table,), ((_, unmapped),) = eng._tables, eng.cache.table_specs
+        mapped = table[table != unmapped]
+        assert len(set(mapped.tolist())) == mapped.size, table
+    eng.close()
+    assert steps > 5 and eng.pool.in_use == 0
