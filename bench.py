@@ -652,169 +652,6 @@ def run_serve_fleet(model: str, layers, *, fleet: int, slots: int,
     return row
 
 
-def make_burst_trace(slots: int, prompt_len: int, prefill_chunk: int,
-                     decode_interval: int, max_new: int, vocab: int,
-                     seed: int = 0) -> list:
-    """Deterministic long-prefill burst (everything arrives at t=0):
-    `slots` SHORT requests (tiny prompt, a decode budget sized to keep
-    the decode side busy for the whole long-prefill grind) followed by
-    `slots` LONG requests (full `prompt_len` prompt, small budget).
-
-    A colocated engine admits the shorts into every slot; the longs are
-    stuck in the queue until the shorts RETIRE (admission is coupled to
-    decode slots), and when they do, every slot flips to chunked prefill
-    at once — max consecutive decode-dispatch stalls ~= the long
-    prefill's ceil(prompt_len / prefill_chunk) ticks. A disaggregated
-    engine admits the longs into the PREFILL pool immediately, so their
-    prefill overlaps the shorts' decode and the handoff lands on an
-    already-warm decode pool — the stall streak collapses. That stall
-    drop is the bench headline."""
-    import numpy as np
-
-    rng = np.random.default_rng(seed)
-    prefill_ticks = -(-prompt_len // prefill_chunk)
-    short_len = max(prompt_len // 8, 2)
-    # outlast the long prefill by a few dispatches so the decode pool is
-    # never the reason the longs look stall-free
-    short_budget = min(decode_interval * (prefill_ticks + 4), max_new)
-    long_budget = max(max_new // 8, decode_interval)
-    out = []
-    for _ in range(slots):
-        prompt = rng.integers(0, vocab, size=short_len).tolist()
-        out.append((prompt, short_budget, 0.0))
-    for _ in range(slots):
-        prompt = rng.integers(0, vocab, size=prompt_len).tolist()
-        out.append((prompt, long_budget, 0.0))
-    return out
-
-
-def run_serve_disagg(model: str, layers, *, slots: int, block_size: int,
-                     num_blocks: int, prefill_chunk: int, prompt_len: int,
-                     max_new: int, n_requests: int, rate: float,
-                     decode_interval: int = 4, seed: int = 0,
-                     telemetry: str | None = None) -> dict:
-    """Disaggregated vs colocated serving (picotron_tpu/serve/disagg) on
-    the deterministic long-prefill burst trace, plus a sweep
-    artifact. One JSON line:
-
-    - headline: the drop in max consecutive decode-dispatch stall ticks
-      (colocated minus disagg) on the burst trace — the number
-      disaggregation exists to buy, and fully deterministic.
-    - `slo_curve`: per arrival rate (derived from --rate, 0 = the
-      saturation point only), TTFT/TPOT/queue-wait percentiles for both
-      engines on the SAME Poisson trace — the
-      disaggregated-vs-colocated SLO comparison.
-
-    Stall ticks, slot-steps and handoffs are structural —
-    identical on any host; only the secondary wall fields are timing
-    (see `wall_note`)."""
-    from picotron_tpu.analysis.cost_model import CostModel
-    from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
-    from picotron_tpu.models.llama import init_params
-    from picotron_tpu.serve import DisaggServeEngine, ServeEngine
-    from picotron_tpu.telemetry import JsonlSink, Telemetry
-
-    cap = prompt_len + max_new
-    preset = resolve_preset(model)
-    preset["max_position_embeddings"] = max(
-        preset.get("max_position_embeddings", 0), cap)
-    if layers:
-        preset["num_hidden_layers"] = layers
-    mcfg = ModelConfig(name=model, **preset)
-    params = jax.jit(
-        lambda k: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
-                               init_params(mcfg, k)))(jax.random.key(0))
-
-    def scfg(**kw):
-        base = dict(decode_slots=slots, block_size=block_size,
-                    num_blocks=num_blocks, prefill_chunk=prefill_chunk,
-                    max_model_len=cap, decode_interval=decode_interval)
-        base.update(kw)
-        return ServeConfig(**base)
-
-    def run(engine_cls, cfg, trace, tel=None):
-        eng = engine_cls(params, mcfg, cfg, telemetry=tel)
-        t0 = time.perf_counter()
-        eng.run(trace)
-        wall = time.perf_counter() - t0
-        summary = eng.summary
-        eng.close()
-        return summary, wall
-
-    # --- burst headline: stall ticks colocated vs disagg -------------
-    burst = make_burst_trace(slots, prompt_len, prefill_chunk,
-                             decode_interval, max_new, mcfg.vocab_size,
-                             seed)
-    # compile-warm both engines' programs on a 2-request mini-trace
-    for cls, cfg in ((ServeEngine, scfg()),
-                     (DisaggServeEngine, scfg(disagg=True))):
-        warm = cls(params, mcfg, cfg)
-        warm.run([(burst[0][0], 2), (burst[1][0], 2)])
-        warm.close()
-
-    tel = (Telemetry(sinks=[JsonlSink(telemetry)]) if telemetry else None)
-    colo, colo_wall = run(ServeEngine, scfg(), burst)
-    dis, dis_wall = run(DisaggServeEngine, scfg(disagg=True), burst, tel)
-    if tel is not None:
-        tel.close()
-
-    # --- SLO curve: both engines on the same Poisson trace -----------
-    base_rate = rate if rate > 0 else 0.0
-    rates = ([base_rate * f for f in (0.5, 1.0, 2.0)]
-             if base_rate > 0 else [0.0])
-    slo_curve = []
-    ms = lambda v: round(v * 1e3, 2) if v is not None else None  # noqa: E731
-    for r in rates:
-        trace = make_serve_trace(n_requests, r, prompt_len, max_new,
-                                 mcfg.vocab_size, seed)
-        point: dict = {"rate": round(r, 3), "requests": n_requests}
-        for tag, cls, cfg in (("colocated", ServeEngine, scfg()),
-                              ("disagg", DisaggServeEngine,
-                               scfg(disagg=True))):
-            s, _ = run(cls, cfg, trace)
-            point[tag] = {
-                "ttft_p50_ms": ms(s["ttft_p50_s"]),
-                "ttft_p95_ms": ms(s["ttft_p95_s"]),
-                "tpot_p50_ms": ms(s["tpot_p50_s"]),
-                "tpot_p95_ms": ms(s["tpot_p95_s"]),
-                "queue_wait_p95_ms": ms(s["queue_wait_p95_s"]),
-                "decode_stall_ticks_max": s["decode_stall_ticks_max"],
-            }
-        slo_curve.append(point)
-
-    handoff_s, handoff_bytes = CostModel("v5e").price_kv_handoff(
-        mcfg, scfg(disagg=True))
-    print(f"# {_WALL_NOTE}", file=sys.stderr)
-    return {
-        "metric": f"serve_disagg_{model.split('/')[-1]}"
-                  f"-{mcfg.num_hidden_layers}L",
-        # headline: deterministic stall-streak drop on the burst trace
-        "value": (colo["decode_stall_ticks_max"]
-                  - dis["decode_stall_ticks_max"]),
-        "unit": "decode_stall_ticks_drop",
-        "colocated_stall_ticks_max": colo["decode_stall_ticks_max"],
-        "disagg_stall_ticks_max": dis["decode_stall_ticks_max"],
-        "burst_requests": len(burst),
-        "prompt_len": prompt_len,
-        "max_new": max_new,
-        "slots": slots,
-        "prefill_slots": dis["prefill_slots"],
-        "handoffs": dis["handoffs"],
-        "handoff_blocks": dis["handoff_blocks"],
-        "handoff_s": dis["handoff_s"],
-        "predicted_handoff_ms_worstcase": round(handoff_s * 1e3, 3),
-        "predicted_handoff_bytes_worstcase": handoff_bytes,
-        "prefill_slot_occupancy": dis["prefill_slot_occupancy"],
-        "decode_compiles": dis["decode_compiles"],
-        "preemptions": dis["preemptions"],
-        "colocated_wall_s": round(colo_wall, 4),
-        "disagg_wall_s": round(dis_wall, 4),
-        "wall_note": _WALL_NOTE,
-        "slo_curve": slo_curve,
-        "device_kind": jax.devices()[0].device_kind,
-    }
-
-
 def run_pp_tick_sweep(model: str, layers, seq: int, mbs: int, *,
                       pp: int = 4, n_micros=(2, 4, 8, 16), steps: int = 4,
                       warmup: int = 1, interleave: int = 2) -> dict:
@@ -1331,12 +1168,6 @@ def main(argv=None) -> None:
                     help="--serve: timed wall-clock runs per side; the "
                          "reported wall is the median (the structural "
                          "decode_slot_steps headline needs one run)")
-    ap.add_argument("--disagg", action="store_true",
-                    help="--serve: disaggregated vs colocated engines "
-                         "(picotron_tpu/serve/disagg) — deterministic "
-                         "decode-stall drop on a long-prefill burst "
-                         "trace and a --rate SLO curve for both "
-                         "engines")
     ap.add_argument("--fleet", type=int, default=0,
                     help="--serve: run N engine replicas behind one "
                          "queue (picotron_tpu/serve/fleet) instead of "
@@ -1455,10 +1286,9 @@ def main(argv=None) -> None:
             ap.error("--serve needs --max-new-tokens >= 1 and "
                      "--requests >= 2")
         if args.fleet:
-            if args.disagg or args.tp > 1:
+            if args.tp > 1:
                 ap.error("--fleet places each replica on its own device; "
-                         "incompatible with --disagg/--tp in this bench "
-                         "mode")
+                         "incompatible with --tp in this bench mode")
             print(json.dumps(run_serve_fleet(
                 args.model, args.layers or 0, fleet=args.fleet,
                 slots=args.serve_slots, block_size=args.block_size,
@@ -1470,20 +1300,6 @@ def main(argv=None) -> None:
                 seed=args.serve_seed, temperature=args.serve_temperature,
                 deadline_ms=args.deadline_ms, chaos_spec=args.chaos,
                 tick_s=args.tick_s, telemetry=args.telemetry)))
-            return
-        if args.disagg:
-            if args.tp > 1:
-                ap.error("--disagg places each pool on its own device; "
-                         "incompatible with --tp (the mesh-sharded path "
-                         "colocates the pools on the mesh)")
-            print(json.dumps(run_serve_disagg(
-                args.model, args.layers or 0, slots=args.serve_slots,
-                block_size=args.block_size, num_blocks=args.num_blocks,
-                prefill_chunk=args.prefill_chunk,
-                prompt_len=args.prompt_len,
-                max_new=args.max_new_tokens, n_requests=args.requests,
-                rate=args.rate, decode_interval=args.decode_interval,
-                telemetry=args.telemetry)))
             return
         print(json.dumps(run_serve(
             args.model, args.layers or 0, slots=args.serve_slots,

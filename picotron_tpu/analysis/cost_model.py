@@ -401,41 +401,6 @@ class CostModel:
                            "secs": secs, "axis_guess": guess})
         return priced
 
-    def price_kv_handoff(self, model_cfg, serve_cfg=None, *,
-                         n_tokens: Optional[int] = None,
-                         hops: int = 1) -> tuple:
-        """(secs, bytes) for ONE prefill->decode KV-block handoff in the
-        disaggregated serving engine (serve/disagg.py): the K and V
-        blocks of one finished prefix cross the pool boundary as a
-        point-to-point `device_put` over `hops` ICI links (1 = adjacent
-        chips, the intended placement; a torus detour raises it).
-
-        Payload = 2 tensors x L x blocks x block_size x Hkv x Dh at the
-        serve compute dtype, with `blocks` rounded UP from `n_tokens`
-        (default: the full serve.max_model_len prefix — the conservative
-        per-request bound admission should budget). The transfer is
-        point-to-point, so it prices like a single ppermute hop:
-        nbytes * hops / link_bw + alpha * hops. Decode-side stall only
-        occurs if the handoff is scheduled synchronously with a decode
-        dispatch — the engine interleaves it between dispatches, so this
-        number is the budget the scheduler's handoff rate must stay
-        under, not a per-token tax."""
-        from picotron_tpu.config import ServeConfig
-
-        scfg = serve_cfg or ServeConfig()
-        max_len = (scfg.max_model_len
-                   or model_cfg.max_position_embeddings)
-        if n_tokens is None:
-            n_tokens = max_len
-        blocks = -(-n_tokens // scfg.block_size)
-        kv_bytes = _DTYPE_BYTES.get(model_cfg.dtype, 2)
-        nbytes = (2 * model_cfg.num_hidden_layers * blocks
-                  * scfg.block_size * model_cfg.num_key_value_heads
-                  * model_cfg.head_dim * kv_bytes)
-        secs = (nbytes * hops / self.gen.link_bandwidth
-                + self.calib.alpha_link_s * hops)
-        return secs, nbytes
-
     @staticmethod
     def _match_axes(op, sizes: dict) -> tuple:
         """Mesh axes a parsed op most plausibly spans."""

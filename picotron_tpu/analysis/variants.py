@@ -416,99 +416,6 @@ def prove_serve_programs(model_cfg, serve_cfg=None, *, params=None) -> \
     return rep
 
 
-def prove_disagg_programs(model_cfg, serve_cfg=None) -> Report:
-    """Static proof for the DISAGGREGATED engine's four device programs
-    (serve/disagg.py): the prefill-pool chunk program, the decode-pool
-    step program, and the two handoff programs (block gather on the
-    prefill placement, sentinel-drop scatter on the decode placement).
-
-    Same argument as prove_serve_programs, per pool: every abstract
-    shape is a pure function of (model_cfg, serve_cfg) — pool sizes,
-    slot counts, and the fixed [max_blocks] handoff index width are
-    config constants, while request identity, positions, block tables,
-    and the handoff's actual block ids are DATA. One signature per
-    program (the prefill-pool program: one per row count of
-    `prefill_rungs(prefill_slots)`, each compiled by the constructor)
-    => no pool compiles after construction, so a prefill burst cannot
-    trigger a decode-side recompile (nor vice versa)."""
-    import jax.numpy as jnp
-
-    from picotron_tpu.config import ServeConfig
-    from picotron_tpu.serve.engine import prefill_rungs
-    from picotron_tpu.serve.paged_cache import init_paged_cache
-    from picotron_tpu.serve.scheduler import blocks_for
-
-    scfg = serve_cfg or ServeConfig()
-    scfg.validate()
-    if model_cfg.num_experts:
-        raise ValueError(
-            "disaggregated serving rejects MoE models (the block "
-            "handoff has never run or been tested with an expert block)")
-    rep = Report()
-    max_len = scfg.max_model_len or model_cfg.max_position_embeddings
-    max_blocks = blocks_for(max_len, scfg.block_size)
-    s = scfg.decode_slots
-    p = scfg.prefill_slots or s
-    num_blocks = scfg.num_blocks or s * max_blocks
-    pnum_blocks = scfg.prefill_num_blocks or p * max_blocks
-
-    dcache = jax.eval_shape(lambda: init_paged_cache(
-        model_cfg, num_blocks, scfg.block_size, s, max_blocks))
-    pcache = jax.eval_shape(lambda: init_paged_cache(
-        model_cfg, pnum_blocks, scfg.block_size, p, max_blocks))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
-
-    decode_args = {
-        "k": sds(dcache.k), "v": sds(dcache.v),
-        "tables": i32(s, max_blocks), "toks": i32(s),
-        "positions": i32(s), "rids": i32(s), "tidx": i32(s),
-    }
-    prefill_args = {
-        "k": sds(pcache.k), "v": sds(pcache.v),
-        "tables": i32(p, max_blocks),
-        "chunk_ids": i32(p, scfg.prefill_chunk),
-        "start_pos": i32(p), "n_valid": i32(p), "rids": i32(p),
-        "tidx": i32(p),
-    }
-    # handoff: gather pulls [max_blocks] block rows from the prefill
-    # pool; scatter writes the staged buffer into the decode pool.
-    # Index vectors are padded to the constant max_blocks width exactly
-    # so a request's block COUNT stays data, not shape.
-    buf = jax.ShapeDtypeStruct(
-        tuple(pcache.k.shape[:2]) + (max_blocks,)
-        + tuple(pcache.k.shape[3:]),
-        pcache.k.dtype)
-    gather_args = {"k": sds(pcache.k), "v": sds(pcache.v),
-                   "idx": i32(max_blocks)}
-    scatter_args = {"k": sds(dcache.k), "v": sds(dcache.v),
-                    "buf_k": buf, "buf_v": buf, "idx": i32(max_blocks)}
-
-    sigs = {
-        "prefill_pool": signature_of(prefill_args),
-        "decode_pool": signature_of(decode_args),
-        "handoff_gather": signature_of(gather_args),
-        "handoff_scatter": signature_of(scatter_args),
-    }
-    rep.info[CHECK] = {
-        "entry": "serve_disagg",
-        "programs": len(sigs),
-        "signatures": {name: len(sig.leaves) for name, sig in sigs.items()},
-        "proven": True,
-        "prefill_slots": p, "decode_slots": s,
-        # the prefill-pool program's batch is compacted: its signature
-        # above is the top rung's, the others differ in the row count only
-        "prefill_rows": list(prefill_rungs(p)),
-    }
-    rep.add(CHECK, INFO, "serve_disagg",
-            f"compile-once proven for both pools + handoff: "
-            f"{len(sigs)} programs, one closed abstract signature each "
-            f"(prefill [R, {scfg.prefill_chunk}] for R in "
-            f"{list(prefill_rungs(p))}, decode [{s}], "
-            f"handoff idx [{max_blocks}])")
-    return rep
-
-
 # ---------------------------------------------------------------------------
 # The check (runner wiring)
 # ---------------------------------------------------------------------------
@@ -532,11 +439,5 @@ def audit_variants(cfg, *, low=None, menv=None) -> Report:
         info["serve"] = serve_rep.info.get(CHECK, {})
     except Exception as e:  # serve stack optional for exotic models
         info["serve"] = {"unavailable": f"{type(e).__name__}: {e}"}
-    try:
-        disagg_rep = prove_disagg_programs(cfg.model, cfg.serve)
-        rep.findings.extend(disagg_rep.findings)
-        info["serve_disagg"] = disagg_rep.info.get(CHECK, {})
-    except Exception as e:  # e.g. MoE models: disagg serving rejects them
-        info["serve_disagg"] = {"unavailable": f"{type(e).__name__}: {e}"}
     rep.info[CHECK] = info
     return rep

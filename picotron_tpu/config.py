@@ -260,7 +260,7 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
     # head j predicts byte t + 1 + j. Not built: training (no loss over the
     # 8 heads, no banded kernel with summary keys: ROADMAP M9),
     # self-speculative decoding from heads 1-7 (ROADMAP M8), tp / pp / cp /
-    # ep, the disaggregated engine, the fleet.
+    # ep, the fleet.
     "EvaByte/EvaByte": dict(
         vocab_size=320, hidden_size=4096, intermediate_size=11008,
         num_hidden_layers=32, num_attention_heads=32,
@@ -2106,24 +2106,6 @@ class ServeConfig:
     # scheduling.
     decode_interval: int = 4
 
-    # --- disaggregated serving (serve/disagg.py): prefill pool / decode
-    # pool as separately placed programs with paged-KV block handoff, so
-    # a burst of long prefills cannot stall decode dispatches ---
-    # Split prefill and decode into two pools (DisaggServeEngine).
-    disagg: bool = False
-    # Prefill-pool slot width (the prefill program's static batch shape).
-    # 0 = decode_slots.
-    prefill_slots: int = 0
-    # Physical blocks in the prefill pool. 0 = auto: prefill_slots *
-    # ceil(max_model_len / block_size) — every prefill slot can hold a
-    # full-length prompt without backpressure.
-    prefill_num_blocks: int = 0
-    # Device placement per pool: an index into jax.devices(). -1 = auto
-    # (prefill on the first device, decode on the last — distinct devices
-    # whenever the host has more than one, colocated otherwise).
-    prefill_device: int = -1
-    decode_device: int = -1
-
     # --- fleet serving (serve/fleet.py): a FleetSupervisor fronting N
     # engine replicas with health/failover/drain — the robustness layer
     # in front of the single-engine stack ---
@@ -2158,16 +2140,6 @@ class ServeConfig:
             raise ValueError(
                 f"serve.max_model_len must be >= 0 (0 = model limit), got "
                 f"{self.max_model_len}")
-        for name in ("prefill_slots", "prefill_num_blocks"):
-            if getattr(self, name) < 0:
-                raise ValueError(
-                    f"serve.{name} must be >= 0 (0 = auto), got "
-                    f"{getattr(self, name)}")
-        for name in ("prefill_device", "decode_device"):
-            if getattr(self, name) < -1:
-                raise ValueError(
-                    f"serve.{name} must be a device index or -1 (auto), "
-                    f"got {getattr(self, name)}")
         if self.fleet_size < 1:
             raise ValueError(
                 f"serve.fleet_size must be >= 1, got {self.fleet_size}")
@@ -2346,18 +2318,6 @@ class Config:
                 f"serve.max_model_len ({self.serve.max_model_len}) exceeds "
                 f"max_position_embeddings "
                 f"({self.model.max_position_embeddings})")
-        if self.model.num_experts and self.serve.disagg:
-            # ServeEngine serves experts (dropless at ep = 1: a token's
-            # experts depend on that token alone, whatever the chunking);
-            # the disaggregated engine's block handoff has never run an
-            # expert block, and nothing tests it with one. Its engine
-            # rejects MoE at construction; this catches the intent at
-            # config load.
-            raise ValueError(
-                "serve.disagg does not support MoE models "
-                "(model.num_experts > 0): nobody has run or tested the "
-                "disaggregated handoff with an expert block; serve experts "
-                "through the plain ServeEngine")
         if self.serve.fleet_size > 1 and self.model.num_experts:
             # the fleet's bit-identical failover re-dispatch is pinned by
             # test for dense models only
@@ -2673,8 +2633,8 @@ class Config:
         if m.recurrent and d.ep_size > 1:
             refuse(f"expert parallelism (ep_size={d.ep_size}: the mixer's "
                    f"leaves have no sharding rule)")
-        if sv.disagg or sv.fleet_size > 1:
-            refuse("serve.disagg / serve.fleet_size > 1 "
+        if sv.fleet_size > 1:
+            refuse("serve.fleet_size > 1 "
                    "(one pool, one table a slot, no hand-over of a state)")
 
     def _refuse_new_blocks(self) -> None:
@@ -2761,8 +2721,8 @@ class Config:
             refuse(f"expert parallelism (ep_size={d.ep_size})",
                    "the exchange across 'ep' routes by softmax gates over "
                    "every expert; a held share is served without exchange")
-        if sv.disagg or sv.fleet_size > 1:
-            refuse("serve.disagg / serve.fleet_size > 1",
+        if sv.fleet_size > 1:
+            refuse("serve.fleet_size > 1",
                    "one K/V pool, never run with this block")
 
     def to_json_dict(self) -> dict[str, Any]:
@@ -2892,6 +2852,11 @@ _RETIRED = {
     "serve": {
         "speculator": "off",
         "draft_len": 3,
+        "disagg": False,
+        "prefill_slots": 0,
+        "prefill_num_blocks": 0,
+        "prefill_device": -1,
+        "decode_device": -1,
     },
 }
 

@@ -8,36 +8,26 @@ path — the "millions of users, heavy traffic" half of the north star.
   fact of its format.
 - `scheduler`: FIFO admission into a fixed decode-slot batch, chunked
   prefill, youngest-first preemption with recompute, retirement — pure
-  host logic. `DisaggScheduler` splits the slot set in two with a
-  handoff boundary between the pools.
-- `engine`: the colocated driver — two jitted device programs (one
-  decode step, one prefill chunk; each compiled exactly once per
-  serving lifetime) plus telemetry (queue_wait/prefill/decode in the
-  GoodputLedger, TTFT/TPOT/per-token latency histograms,
-  serve_request/serve_summary JSONL).
-- `disagg`: the disaggregated driver — prefill and decode as separately
-  PLACED pools over their own block pools, paged-KV block handoff via
-  explicit `device_put` (the MPMD ring-buffer discipline), so prefill
-  bursts cannot stall decode dispatches.
+  host logic.
+- `engine`: the driver — two jitted device programs (one decode step, one
+  prefill chunk; each compiled exactly once per serving lifetime) plus
+  telemetry (queue_wait/prefill/decode in the GoodputLedger,
+  TTFT/TPOT/per-token latency histograms, serve_request/serve_summary
+  JSONL).
 - `fleet`: `FleetSupervisor` — N engine replicas behind one queue, with
   failover re-dispatch (bit-identical continuations), deadline load
   shedding, hang detection, and graceful drain.
 """
 
-from picotron_tpu.serve.disagg import DisaggServeEngine
 from picotron_tpu.serve.engine import ServeEngine
 from picotron_tpu.serve.fleet import FleetSupervisor
 from picotron_tpu.serve.paged_cache import (
     BlockPool, PagedKVCache, init_paged_cache,
 )
-from picotron_tpu.serve.scheduler import (
-    DisaggScheduler, Request, Scheduler, blocks_for,
-)
+from picotron_tpu.serve.scheduler import Request, Scheduler, blocks_for
 
 __all__ = [
     "BlockPool",
-    "DisaggScheduler",
-    "DisaggServeEngine",
     "FleetSupervisor",
     "PagedKVCache",
     "Request",

@@ -327,8 +327,7 @@ SLOW_STEP_WINDOW, SLOW_STEP_AFTER, SLOW_STEP_LOGS = 64, 16, 32
 
 
 # The leaves that enqueue work on the device, and those that wait for it.
-_ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch",
-                       "serve.handoff"))
+_ENQUEUES = frozenset(("serve.prefill.dispatch", "serve.decode.dispatch"))
 _DECODE_DISPATCH, _DECODE_WAIT = "serve.decode.dispatch", "serve.decode.wait"
 _WAITS = frozenset(("serve.prefill.wait", _DECODE_WAIT))
 # `dry_by`'s name for the seconds between two steps (the caller's), as
@@ -367,20 +366,19 @@ def step_account(leaves, t0: float, wall: float, in_flight=(), probes=(),
     `leaves` are the step's leaf spans in the order they ran, `(name,
     start, secs)` on the clock of `t0`, the step's start; `wall` is the
     step's seconds so far. The device has work from the start of a
-    `*.dispatch` (or `serve.handoff`) leaf (`_ENQUEUES`) until a `*.wait`
-    leaf (`_WAITS`) has fetched its outputs, or those of something
-    enqueued behind it. A `serve.prefill.wait` fetches the dispatch
-    enqueued last, so it clears all that is in flight. A
-    `serve.decode.wait` fetches the OLDEST decode dispatch nobody has
-    fetched: the engine runs one decode dispatch ahead, so a newer one is
-    usually enqueued behind it and stays in flight, and the emit, the next
-    admit and the next build are fed by it. Where a prefill wait has
-    cleared that dispatch already, the decode wait clears nothing and is a
-    leaf like any other. `in_flight` names the leaves that enqueued what
-    the steps before left in flight, oldest first (it holds from `since`
-    on); `"in_flight"` of the result is the same for the next step, and
-    `waits` lists each wait that cleared something as `(name, secs,
-    dispatches it cleared)`.
+    `*.dispatch` leaf (`_ENQUEUES`) until a `*.wait` leaf (`_WAITS`) has
+    fetched its outputs, or those of something enqueued behind it. A
+    `serve.prefill.wait` fetches the dispatch enqueued last, so it clears
+    all that is in flight. A `serve.decode.wait` fetches the OLDEST
+    decode dispatch nobody has fetched: the engine runs one decode
+    dispatch ahead, so a newer one is usually enqueued behind it and stays
+    in flight, and the emit, the next admit and the next build are fed by
+    it. Where a prefill wait has cleared that dispatch already, the decode
+    wait clears nothing and is a leaf like any other. `in_flight` names
+    the leaves that enqueued what the steps before left in flight, oldest
+    first (it holds from `since` on); `"in_flight"` of the result is the
+    same for the next step, and `waits` lists each wait that cleared
+    something as `(name, secs, dispatches it cleared)`.
 
     `probes` are `(stamp, ready)` in order: whether the NEWEST output
     enqueued had finished at `stamp` (one stream runs in order, so the
@@ -701,8 +699,6 @@ class ServeEngine:
         where, idx, st = got
         if where == "slot":
             self._sync_table(idx)
-        elif where == "pslot":  # disagg prefill side
-            self._sync_ptable(idx)
         self.stats["cancelled"] += 1
         self.telemetry.emit("serve_cancel", id=request_id, where=where,
                             tokens=len(st.generated))
@@ -722,16 +718,11 @@ class ServeEngine:
         tracer = self.telemetry.tracer
         return tracer.clock() if tracer is not None else time.perf_counter()
 
-    # whether the device is probed: one stream runs in order, so the newest
-    # output enqueued says whether all of it has run (serve/disagg.py: two
-    # pools, maybe on two devices, and no dry counts)
-    _PROBED = True
-
     def _enqueued(self, out) -> None:
         """`out` is the newest output enqueued on the device: what the
-        probes ask until one reads ready."""
-        if self._PROBED:
-            self._newest, self._newest_ready = out, False
+        probes ask until one reads ready (one stream runs in order, so the
+        newest output says whether all of it has run)."""
+        self._newest, self._newest_ready = out, False
 
     def _fetched(self, out) -> None:
         """The host has `out`: if nothing was enqueued behind it, nothing is
@@ -741,9 +732,9 @@ class ServeEngine:
 
     def _probe(self) -> int:
         """Has the device run everything it was given? 1: the newest output
-        enqueued is ready; 0: it is not; -1: the host has fetched it, or
-        this engine is not probed. One non-blocking `is_ready()`, stamped
-        for `step_account`, and none once a probe has read ready."""
+        enqueued is ready; 0: it is not; -1: the host has fetched it. One
+        non-blocking `is_ready()`, stamped for `step_account`, and none
+        once a probe has read ready."""
         if self._newest is None:
             return -1
         if not self._newest_ready:
@@ -822,20 +813,12 @@ class ServeEngine:
                          self.sched.slots[slot])
         self._decode_state = None  # roster/table changed: slow path next
 
-    # -- the prefill program's side of the engine: what DisaggServeEngine
-    # overrides to point the shared feed at its own prefill pool
-
-    def _prefill_pool(self):
-        """(slot states, the cache, its host table mirrors, upload
-        sharding) of the pool the prefill program writes."""
-        return self.sched.slots, self.cache, self._tables, self._rep_sh
-
-    _PREFILL_PHASE: dict = {}  # further keys of the `phase=prefill` event
+    # -- the prefill program's side of the engine
 
     def _retire_prefilled(self, slot: int, t: float) -> int:
         """A request whose first token already ends it (EOS, a budget of
-        one) leaves straight from the pool it was prefilled in. Returns
-        the blocks it gave back (0: it stays)."""
+        one) leaves as soon as it is prefilled. Returns the blocks it gave
+        back (0: it stays)."""
         if not self.sched.should_retire(slot, self.eos_token_id):
             return 0
         freed = self.sched.slots[slot].held_blocks
@@ -854,26 +837,26 @@ class ServeEngine:
             cache_cls=type(self.cache))
         return toks, logits
 
-    def _prefill_feed(self, pslots, rows: Optional[int] = None):
+    def _prefill_feed(self, slots, rows: Optional[int] = None):
         """The compacted prefill batch: row i carries the next chunk of
-        slot pslots[i] and that slot's table row; the batch is padded up
+        slot slots[i] and that slot's table row; the batch is padded up
         to `rows`, the rung `prefill_cover` gave these slots (None: the
         smallest that holds them). A pad row has n_valid 0 and an
         all-unmapped table row: its writes drop. Returns (device feed in
         `serve_prefill`'s argument order, n_valid [R] on the host, the
         rows whose prompt ends in this chunk)."""
-        states, cache, tables, sh = self._prefill_pool()
+        states, cache = self.sched.slots, self.cache
         c = self.scfg.prefill_chunk
-        r = rows or next(x for x in self.prefill_rungs if x >= len(pslots))
+        r = rows or next(x for x in self.prefill_rungs if x >= len(slots))
         trows = tuple(np.full((r, width), unmapped, np.int32)
                       for width, unmapped in cache.table_specs)
         ids = np.zeros((r, c), np.int32)
         start, nval, rids, tidx = np.zeros((4, r), np.int32)
         finals = []
-        for row, s in enumerate(pslots):
+        for row, s in enumerate(slots):
             st = states[s]
             chunk = st.prefill_ids[st.n_prefilled:st.n_prefilled + c]
-            for rows_of, table in zip(trows, tables):
+            for rows_of, table in zip(trows, self._tables):
                 rows_of[row] = table[s]
             ids[row, :len(chunk)] = chunk
             start[row] = st.n_prefilled
@@ -882,7 +865,8 @@ class ServeEngine:
             tidx[row] = len(st.generated)
             if st.n_prefilled + len(chunk) >= len(st.prefill_ids):
                 finals.append(row)
-        feed = jax.device_put((trows, ids, start, nval, rids, tidx), sh)
+        feed = jax.device_put((trows, ids, start, nval, rids, tidx),
+                              self._rep_sh)
         return feed, nval, finals
 
     def _warm_prefill(self) -> None:
@@ -1143,9 +1127,7 @@ class ServeEngine:
         # ---- one decode step over every slot with a live sequence
         decode_ran = self._decode_tick(now, reg)
         worked = worked or decode_ran
-        # max consecutive ticks with work in the system but no decode
-        # dispatch — the TTFT/TPOT SLO killer the disaggregated engine
-        # exists to eliminate (bench.py --serve --disagg compares this)
+        # max consecutive ticks with work in the system, no decode dispatch
         if decode_ran:
             self._stall_streak = 0
         elif self.sched.has_work():
@@ -1174,27 +1156,20 @@ class ServeEngine:
         its rung) with a span, a `seq` and counts of its own, `piece` of
         `pieces`; all are enqueued before any is waited for, the pools
         chained through the donated argument, so the device runs them back
-        to back. Works through `_prefill_pool` / `_run_prefill` /
-        `_retire_prefilled` and the scheduler's prefill interface, so the
-        disaggregated engine runs it verbatim against its prefill pool.
-        Every slot advances one chunk a tick whatever piece carries it,
-        and samples under its own (request id, token index) key, so the
-        served tokens are those of one dispatch over all the rows.
-        Returns whether a dispatch ran.
-
-        It keeps the lines it had before PR 56 (what it grew by is
-        `_emit_prefilled`, below `_collect_decode`): a decode kernel's
-        cache key may carry the lines of `_enqueue_decode`, which stands
-        below this (the comment above `step_account`)."""
-        pslots = self.sched.prefill_slots()
-        if not pslots:
+        to back. Every slot advances one chunk a tick whatever piece
+        carries it, and samples under its own (request id, token index)
+        key, so the served tokens are those of one dispatch over all the
+        rows. What the tick books is `_emit_prefilled`'s. Returns whether a
+        dispatch ran."""
+        slots = self.sched.prefill_slots()
+        if not slots:
             return False
-        states, cache = self._prefill_pool()[:2]
+        states, cache = self.sched.slots, self.cache
         chunk = self.scfg.prefill_chunk
         with self._span("serve.prefill.build"):
             pieces, at = [], 0
-            for rung, n in prefill_cover(len(pslots), self.prefill_rungs):
-                mine = pslots[at:at + n]  # oldest admitted first
+            for rung, n in prefill_cover(len(slots), self.prefill_rungs):
+                mine = slots[at:at + n]  # oldest admitted first
                 feed, nval, finals = self._prefill_feed(mine, rows=rung)
                 pieces.append({"slots": mine, "nval": nval, "feed": feed,
                                "finals": finals})
@@ -1263,11 +1238,8 @@ class ServeEngine:
         reading of the tokens, the next admit and the next build happen
         while it computes. With nothing in flight (the first dispatch
         after an empty system) the step enqueues and returns, and the next
-        step is ahead. Operates purely through the scheduler's decode
-        interface plus the decode-side device context (self.params/_kv/
-        cos/sin/base_key/_rep_sh), so the disaggregated engine reuses it
-        verbatim against its decode pool. Returns whether a dispatch was
-        enqueued or waited for."""
+        step is ahead. Returns whether a dispatch was enqueued or waited
+        for."""
         flying = self._flying
         self._flying = self._enqueue_decode(flying)
         if flying is not None:
@@ -1535,26 +1507,25 @@ class ServeEngine:
         computed as `prefill_rows_real` + `prefill_rows_padded`), and the
         first token of each prompt that ended, under `serve.prefill.emit`.
         `dt`: the seconds since the first piece's enqueue, less compiles."""
-        states = self._prefill_pool()[0]
-        pslots = [s for p in pieces for s in p["slots"]]
+        states = self.sched.slots
+        slots = [s for p in pieces for s in p["slots"]]
         # `waited`: whether `secs` is the device's time for the chunks or
         # only the enqueues
         self.telemetry.emit("phase", phase="prefill", category="prefill",
                             secs=dt,
                             tokens=sum(int(p["nval"].sum()) for p in pieces),
-                            ids=[states[s].req.id for s in pslots],
-                            waited=bool(n_finals), dispatches=len(pieces),
-                            **self._PREFILL_PHASE)
+                            ids=[states[s].req.id for s in slots],
+                            waited=bool(n_finals), dispatches=len(pieces))
         for p in pieces:
             for row, s in enumerate(p["slots"]):
                 self.sched.note_prefilled(s, int(p["nval"][row]))
         stats = self.stats
-        stats["prefill_chunks"] += len(pslots)
+        stats["prefill_chunks"] += len(slots)
         stats["prefill_ticks"] += 1
         stats["prefill_dispatches"] += len(pieces)
-        stats["prefill_rows_real"] += len(pslots)
+        stats["prefill_rows_real"] += len(slots)
         stats["prefill_rows_padded"] += (sum(len(p["nval"]) for p in pieces)
-                                         - len(pslots))
+                                         - len(slots))
         if not n_finals:
             return
         n_retired = n_freed = 0
@@ -1710,7 +1681,7 @@ class ServeEngine:
 # ---------------------------------------------------------------------------
 
 # `engine.stats` of the prefill ticks (`ServeEngine._emit_prefilled` counts
-# them; `_init_step_account`, which both engines pass, zeroes them)
+# them; `_init_step_account` zeroes them)
 PREFILL_COUNTS = ("prefill_ticks", "prefill_dispatches", "prefill_rows_real",
                   "prefill_rows_padded")
 

@@ -2,12 +2,11 @@
 robustness-first — engine death, hung dispatches, and overload bursts
 are routine, chaos-tested events, not crashes.
 
-`FleetSupervisor` owns N `ServeEngine` / `DisaggServeEngine` replicas,
-each pinned to its own device (round-robin over `jax.devices()` — on
-CPU the conftest's simulated devices, so the tests exercise REAL
-multi-engine placement) with its own KV block pool and its OWN copy of
-the params, but the SAME base sampling key. Requests flow through a
-fleet-global FIFO: arrivals route to the least-loaded live engine;
+`FleetSupervisor` owns N `ServeEngine` replicas, each pinned to its own
+device (round-robin over `jax.devices()` — on CPU the conftest's
+simulated devices, so the tests exercise REAL multi-engine placement)
+with its own KV block pool and its OWN copy of the params, but the SAME
+base sampling key. Requests flow through a fleet-global FIFO: arrivals route to the least-loaded live engine;
 everything after that is the single-engine machinery unchanged.
 
 Four robustness mechanisms, layered on the PR-7 scheduler invariants:
@@ -60,7 +59,6 @@ import jax
 from picotron_tpu.config import ModelConfig, ServeConfig
 from picotron_tpu.resilience import chaos, watchdog
 from picotron_tpu.resilience.watchdog import Watchdog
-from picotron_tpu.serve.disagg import DisaggServeEngine
 from picotron_tpu.serve.engine import ServeEngine
 from picotron_tpu.serve.scheduler import Request
 from picotron_tpu.telemetry import Telemetry
@@ -102,29 +100,17 @@ class FleetSupervisor:
         devices = jax.devices()
         self.engines: list = []
         for k in range(self.n):
-            if scfg.disagg:
-                import dataclasses
-                dev_d = (2 * k) % len(devices)
-                dev_p = (2 * k + 1) % len(devices)
-                ecfg = dataclasses.replace(
-                    scfg, decode_device=dev_d, prefill_device=dev_p)
-                eng = DisaggServeEngine(
-                    params, model_cfg, ecfg, eos_token_id=eos_token_id,
-                    temperature=temperature, top_k=top_k, seed=seed,
-                    telemetry=self.telemetry, engine_id=k)
-            else:
-                dev = devices[k % len(devices)]
-                # re-commit even already-committed params: a replica must
-                # hold its OWN copy on its OWN device or failover would
-                # discard state it shares with survivors
-                p_k = (params if mesh_sharded
-                       else jax.device_put(params, SingleDeviceSharding(dev)))
-                eng = ServeEngine(
-                    p_k, model_cfg, scfg, eos_token_id=eos_token_id,
-                    temperature=temperature, top_k=top_k, seed=seed,
-                    telemetry=self.telemetry,
-                    device=None if mesh_sharded else dev, engine_id=k)
-            self.engines.append(eng)
+            dev = devices[k % len(devices)]
+            # re-commit even already-committed params: a replica must
+            # hold its OWN copy on its OWN device or failover would
+            # discard state it shares with survivors
+            p_k = (params if mesh_sharded
+                   else jax.device_put(params, SingleDeviceSharding(dev)))
+            self.engines.append(ServeEngine(
+                p_k, model_cfg, scfg, eos_token_id=eos_token_id,
+                temperature=temperature, top_k=top_k, seed=seed,
+                telemetry=self.telemetry,
+                device=None if mesh_sharded else dev, engine_id=k))
 
         self.alive = [True] * self.n
         self.draining: dict = {}   # engine -> drain start (trace clock)
@@ -185,9 +171,7 @@ class FleetSupervisor:
 
     def _load(self, k: int) -> int:
         s = self.engines[k].sched
-        n = len(s.queue) + sum(x is not None for x in s.slots)
-        n += sum(x is not None for x in getattr(s, "pslots", ()))
-        return n
+        return len(s.queue) + sum(x is not None for x in s.slots)
 
     def _displace(self, k: int, free_blocks: bool) -> list:
         """Pull every in-flight request out of engine k, oldest-admitted
@@ -198,24 +182,17 @@ class FleetSupervisor:
         accounting the tests pin on SURVIVOR pools."""
         eng = self.engines[k]
         sched = eng.sched
-        resident = []  # (state, owning pool) — disagg pslot blocks live
-        #                in the prefill pool, decode-slot blocks in pool
+        resident = []
         for i, s in enumerate(sched.slots):
             if s is not None:
-                resident.append((s, eng.pool))
+                resident.append(s)
                 sched.slots[i] = None
-        pslots = getattr(sched, "pslots", None)
-        if pslots is not None:
-            for i, s in enumerate(pslots):
-                if s is not None:
-                    resident.append((s, eng.pool_p))
-                    pslots[i] = None
-        resident.sort(key=lambda sp: sp[0].admit_seq)
+        resident.sort(key=lambda s: s.admit_seq)
         if free_blocks:
-            for st, pool in resident:
+            for st in resident:
                 if st.blocks:
-                    pool.free(st.blocks)
-        sts = [sp[0] for sp in resident] + list(sched.queue)
+                    eng.pool.free(st.blocks)
+        sts = resident + list(sched.queue)
         sched.queue.clear()
         for st in sts:
             st.blocks = []
@@ -453,15 +430,9 @@ class FleetSupervisor:
         """Blocks still held across every LIVING pool after a drained
         trace — dead engines' pools were discarded wholesale and do not
         count (that is the failover contract). Must be zero."""
-        total = 0
-        for k, eng in enumerate(self.engines):
-            if not self.alive[k] and k not in self.drained:
-                continue  # died abruptly: pool discarded, not leaked
-            total += eng.pool.in_use
-            pool_p = getattr(eng, "pool_p", None)
-            if pool_p is not None:
-                total += pool_p.in_use
-        return total
+        # an engine that died abruptly: pool discarded, not leaked
+        return sum(eng.pool.in_use for k, eng in enumerate(self.engines)
+                   if self.alive[k] or k in self.drained)
 
     def _emit_summary(self, wall: float) -> None:
         reg = self.telemetry.registry

@@ -30,7 +30,7 @@ mapping is shared so in-process and post-hoc accounting agree.
 JSONL schema (one object per line; `ts` = time.time()):
 
   {"ts", "kind": "phase", "phase", "step", "secs", "category"}
-      # serve: phase queue_wait | prefill | decode | handoff with id / ids,
+      # serve: phase queue_wait | prefill | decode with id / ids,
       # tokens; prefill carries `waited` (whether `secs` includes the
       # host's wait for the device or only the enqueue); phase serve_host,
       # one an engine step with device work: `secs` the step's seconds

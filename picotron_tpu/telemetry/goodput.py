@@ -35,11 +35,6 @@ Every timed second of the run is booked to exactly one category:
                      (picotron_tpu/serve): the engine's two jitted
                      programs (both goodput — tokens leaving the system)
                      and time requests sat queued before admission.
-- ``handoff``      — disaggregated serving only (serve/disagg.py): the
-                     prefill->decode KV-block transfer across the pool
-                     boundary. Transport overhead, NOT goodput — the
-                     number the cost model's price_kv_handoff predicts
-                     and the decode pool must never wait on.
 - ``serve_host``   — serving only (serve/engine.py `step_account`): the
                      seconds of an engine step with work pending and
                      nothing enqueued on the device, between one
@@ -102,12 +97,11 @@ CATEGORIES = (
     "retry_backoff", "data_wait", "host_sync", "pp_bubble", "eval",
     "other",
     # serving (picotron_tpu/serve): device time in the two jitted
-    # programs (goodput), the admission-latency badput, the
-    # disaggregated engines' cross-pool KV transfer (badput: transport),
-    # queue seconds thrown away by deadline load shedding (badput), and
-    # an engine step's seconds with nothing enqueued on the device
-    # (badput: the host's share of the serving loop)
-    "prefill", "decode", "queue_wait", "handoff", "shed", "serve_host",
+    # programs (goodput), the admission-latency badput, queue seconds
+    # thrown away by deadline load shedding (badput), and an engine
+    # step's seconds with nothing enqueued on the device (badput: the
+    # host's share of the serving loop)
+    "prefill", "decode", "queue_wait", "shed", "serve_host",
 )
 
 

@@ -157,6 +157,11 @@ _RETIRED = {
     "distributed.hier_dp_reduce": ("auto", "on"),
     "serve.speculator": ("off", "ngram"),
     "serve.draft_len": (3, 2),
+    "serve.disagg": (False, True),
+    "serve.prefill_slots": (0, 4),
+    "serve.prefill_num_blocks": (0, 64),
+    "serve.prefill_device": (-1, 1),
+    "serve.decode_device": (-1, 0),
 }
 
 

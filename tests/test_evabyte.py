@@ -608,7 +608,6 @@ REFUSED = {
     "pp": dict(distributed=dict(pp_size=2)),
     "tp": dict(distributed=dict(tp_size=2)),
     "ep": dict(distributed=dict(ep_size=2)),
-    "disagg": dict(serve=dict(disagg=True)),
     "fleet": dict(serve=dict(fleet_size=2)),
 }
 
@@ -638,13 +637,3 @@ def test_each_new_feature_is_fenced_by_its_own_name(feature, over):
             Config(distributed=DistributedConfig(**dist), model=model,
                    training=TrainingConfig(seq_length=64)).validate()
     Config(model=model, training=TrainingConfig(seq_length=64)).validate()
-
-
-def test_the_disaggregated_engine_refuses_the_model_at_construction():
-    from picotron_tpu.serve.disagg import DisaggServeEngine
-
-    cfg = tiny()
-    params = init_params(cfg, jax.random.key(0))
-    scfg = dict(decode_slots=2, block_size=BS, prefill_chunk=8, max_model_len=64)
-    with pytest.raises(ValueError, match="attention_class 'eva'"):
-        DisaggServeEngine(params, cfg, ServeConfig(disagg=True, **scfg))

@@ -151,17 +151,17 @@ def _mpmd_cfg():
 
 def test_dryrun_trace_has_all_span_families_and_validates(tmp_path):
     """The acceptance pin: a 2-step CPU dryrun (real MPMD pp2 executor +
-    real disaggregated serve engine, driven through the facade) exports
+    real serve engine, driven through the facade) exports
     one Chrome-trace JSON carrying train phases, per-op stage-tick
     spans, the serve request lifecycle (queue_wait -> the engine step's
-    prefill / handoff / decode spans with request ids), and a resilience
+    prefill / decode spans with request ids), and a resilience
     instant — and
     `tools/trace_export.py --validate` accepts it (subprocess, the same
     gate a CI smoke would run)."""
     from picotron_tpu.mesh import MeshEnv
     from picotron_tpu.models.llama import init_params
     from picotron_tpu.parallel.api import init_sharded_state, make_train_step
-    from picotron_tpu.serve import DisaggServeEngine
+    from picotron_tpu.serve import ServeEngine
 
     trace_path = str(tmp_path / "trace.json")
     tel = Telemetry(sinks=[])
@@ -195,11 +195,11 @@ def test_dryrun_trace_has_all_span_families_and_validates(tmp_path):
         rng = np.random.default_rng(0)
         reqs = [(list(map(int, rng.integers(0, mcfg.vocab_size, size=n))), 3)
                 for n in (5, 7)]
-        eng = DisaggServeEngine(
+        eng = ServeEngine(
             params, mcfg,
             ServeConfig(decode_slots=2, block_size=4, num_blocks=16,
                         prefill_chunk=4, max_model_len=32,
-                        decode_interval=2, disagg=True),
+                        decode_interval=2),
             telemetry=tel)
         eng.run(reqs)
         eng.close()
@@ -225,10 +225,10 @@ def test_dryrun_trace_has_all_span_families_and_validates(tmp_path):
                if e["tid"] >= TID_PP_BASE)
     # serve request lifecycle, ids attached
     assert {"serve.queue_wait", "serve.step", "serve.prefill.dispatch",
-            "serve.handoff", "serve.decode.dispatch"} <= names_by_lane[TID_SERVE]
+            "serve.decode.dispatch"} <= names_by_lane[TID_SERVE]
     serve = [e for e in spans if e["tid"] == TID_SERVE]
     assert all("id" in e.get("args", {}) for e in serve
-               if e["name"] in ("serve.queue_wait", "serve.handoff"))
+               if e["name"] == "serve.queue_wait")
     assert all("ids" in e.get("args", {}) for e in serve
                if e["name"] in ("serve.prefill.dispatch",
                                 "serve.decode.dispatch"))

@@ -667,7 +667,7 @@ REFUSALS = [
     (dict(distributed=DistributedConfig(pp_size=2)), "pipeline parallelism"),
     (dict(distributed=DistributedConfig(ep_size=2)), "expert parallelism"),
     (dict(distributed=DistributedConfig(cp_size=2)), "context parallelism"),
-    (dict(serve=ServeConfig(disagg=True)), "serve.disagg"),
+    (dict(serve=ServeConfig(fleet_size=2)), "serve.fleet_size > 1"),
 ]
 
 

@@ -416,7 +416,6 @@ REFUSED = {
     "pp": dict(distributed=dict(pp_size=2)),
     "tp": dict(distributed=dict(tp_size=2)),
     "ep": dict(distributed=dict(ep_size=2)),
-    "disagg": dict(serve=dict(disagg=True)),
     "fleet": dict(serve=dict(fleet_size=2)),
 }
 

@@ -581,7 +581,7 @@ REFUSALS = [
     (dict(distributed=DistributedConfig(pp_size=2)), "pipeline parallelism"),
     (dict(distributed=DistributedConfig(ep_size=2)), "expert parallelism"),
     (dict(distributed=DistributedConfig(cp_size=2)), "context parallelism"),
-]  # (serve.disagg and a fleet refuse every model with experts before they ask for its layers)
+]  # (a fleet refuses every model with experts before it asks for its layers)
 
 
 @pytest.mark.parametrize("over,message", REFUSALS, ids=[m for _, m in REFUSALS])

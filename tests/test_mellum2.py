@@ -333,7 +333,7 @@ def test_dense_mlp_layers_are_refused_by_name():
                                             remat_policy="dots_attn"))),
     ("pipeline parallelism", dict(dist=dict(pp_size=2))),
     ("tensor parallelism", dict(dist=dict(tp_size=2))),
-    ("serve.disagg", dict(serve=dict(disagg=True))),
+    ("serve.fleet_size > 1", dict(serve=dict(fleet_size=2))),
 ])
 def test_window_layers_are_refused_by_name(what, kw):
     import dataclasses

@@ -369,7 +369,6 @@ REFUSALS = [
     (dict(distributed=dict(tp_size=2)), "tensor parallelism"),
     (dict(distributed=dict(pp_size=2)), "pipeline parallelism"),
     (dict(distributed=dict(ep_size=2)), "expert parallelism"),
-    (dict(serve=dict(disagg=True)), "serve.disagg"),
     (dict(serve=dict(fleet_size=2)), "fleet_size"),
 ]
 

@@ -591,14 +591,14 @@ def prefill_dispatches(tel):
             if e["ph"] == "X" and e["name"] == "serve.prefill.dispatch"]
 
 
-def traced_engine(tiny, cls=ServeEngine, **kw):
+def traced_engine(tiny, **kw):
     from picotron_tpu.telemetry import Telemetry
     from picotron_tpu.telemetry.flightdeck import SpanTracer
 
     cfg, params = tiny
     tel = Telemetry(sinks=[])
     tel.tracer = SpanTracer()
-    return cls(params, cfg, scfg(**kw), telemetry=tel), tel
+    return ServeEngine(params, cfg, scfg(**kw), telemetry=tel), tel
 
 
 def test_prefill_rungs_are_a_function_of_the_slot_count():
@@ -727,29 +727,17 @@ def test_compacted_prefill_parity_and_rung(tiny, requests5, offline_refs, n,
 
 
 @pytest.mark.parametrize("n", [5, 7])
-@pytest.mark.parametrize("disagg", [False, True], ids=["colocated", "disagg"])
-def test_a_tick_of_several_dispatches(tiny, requests5, offline_refs, n,
-                                      disagg):
+def test_a_tick_of_several_dispatches(tiny, requests5, offline_refs, n):
     """Five and seven prompts mid-prefill at once on the ladder (1, 4, 16),
     driven by hand: a tick's spans are its cover, numbered `piece` of
     `pieces` with consecutive `seq`, all enqueued before the tick's one
     wait (which carries the newest `seq`); every slot advances exactly one
     chunk a tick; the stats count ticks, dispatches and rows; nothing
-    compiles past the constructor; the tokens are the offline sampler's.
-    The disaggregated engine runs the same tick against its prefill pool."""
-    from picotron_tpu.serve.disagg import DisaggServeEngine
-
+    compiles past the constructor; the tokens are the offline sampler's."""
     reqs = (requests5 + requests5[:2])[:n]
     refs = (offline_refs + offline_refs[:2])[:n]
-    kw = dict(decode_slots=16, num_blocks=128)
-    if disagg:
-        eng, tel = traced_engine(tiny, DisaggServeEngine, disagg=True,
-                                 prefill_slots=16, prefill_num_blocks=128,
-                                 **kw)
-        states = eng.sched.pslots
-    else:
-        eng, tel = traced_engine(tiny, **kw)
-        states = eng.sched.slots
+    eng, tel = traced_engine(tiny, decode_slots=16, num_blocks=128)
+    states = eng.sched.slots
     assert eng.prefill_rungs == (1, 4, 16)
     compiles = (eng.stats["prefill_compiles"], eng.stats["decode_compiles"])
     for i, (p, m) in enumerate(reqs):
@@ -763,7 +751,7 @@ def test_a_tick_of_several_dispatches(tiny, requests5, offline_refs, n,
                   if st is not None and st.prefilling}
         eng.step(0.0)
         after = {st.req.id: st.n_prefilled
-                 for st in (*states, *eng.sched.slots) if st is not None}
+                 for st in states if st is not None}
         for rid, (done, total) in before.items():
             if rid in after:  # not retired at its first token
                 assert after[rid] == min(done + chunk, total), rid
@@ -847,12 +835,12 @@ def test_pad_rows_leak_nothing(tiny, requests5):
     for i in (1, 3, 4):  # 9, 7 and 11 tokens: 2 or 3 chunks
         eng.submit(*requests5[i])
     eng.step(0.0)
-    pslots = eng.sched.prefill_slots()
-    assert len(pslots) == 3
-    feed, nval, finals = eng._prefill_feed(pslots)
+    mids = eng.sched.prefill_slots()
+    assert len(mids) == 3
+    feed, nval, finals = eng._prefill_feed(mids)
     trows = np.asarray(feed[0][0])
     assert trows.shape == (4, eng.max_blocks) and finals == [1]
-    assert (trows[:3] == eng._tables[0][pslots]).all()
+    assert (trows[:3] == eng._tables[0][mids]).all()
     assert (trows[:3, 0] < eng.num_blocks).all()
     assert (trows[3:] == eng.num_blocks).all()  # unmapped: writes drop
     assert list(nval) == [4, 3, 4, 0]
@@ -1168,50 +1156,206 @@ def test_engine_serves_experts_with_generates_tokens():
 
 
 def _pangu(seed=3):
-    from picotron_tpu.config import resolve_preset
+    return _toy("debug-tiny-pangu-moe", seed)
 
-    cfg = ModelConfig(dtype="float32", **resolve_preset("debug-tiny-pangu-moe"))
+
+def _toy(preset, seed=3, **over):
+    """A family's tiny preset at a trained model's embedding scale, so that
+    the layers show in the logits."""
+    cfg = ModelConfig(dtype="float32", **{**resolve_preset(preset), **over})
+    cfg.validate()
     p = init_params(cfg, jax.random.key(seed))
     return cfg, dict(p, embedding=p["embedding"] * 0.1)
 
 
-def test_latent_engine_retire_cancel_shed_leak_no_block():
-    """The latent pool over a trace that retires, cancels (a resident and a
-    queued request) and sheds (a deadline passed in the queue): every block
-    is back, the survivors' tokens are `generate`'s, and a preempted request
-    (a pool too small for both residents' growth) finishes alike."""
-    cfg, params = _pangu()
+# One toy model a kind of `init_serve_cache`, two layers a layer kind.
+_GDN, _SSM, _KDA, _FULL, _SLIDE = ("linear_attention", "mamba", "kda",
+                                   "full_attention", "sliding_attention")
+CACHE_KINDS = {
+    "latent": (_pangu, "LatentPagedCache"),
+    "eva": (lambda: _toy("debug-tiny-evabyte"), "EvaPagedCache"),
+    "state_kv_gdn": (lambda: _toy(
+        "debug-tiny-qwen3-next", num_hidden_layers=4,
+        layer_types=(_GDN, _FULL) * 2), "HybridPagedCache"),
+    "state_tail_kv_mamba": (lambda: _toy(
+        "debug-tiny-jamba", num_hidden_layers=4,
+        layer_types=(_SSM, _FULL) * 2), "HybridPagedCache"),
+    "state_latent_kda": (lambda: _toy(
+        "debug-tiny-kimi-linear", num_hidden_layers=4,
+        layer_types=(_KDA, _FULL) * 2), "HybridLatentPagedCache"),
+    "window_full": (lambda: _toy(
+        "debug-tiny-mellum2", seed=5, num_hidden_layers=4,
+        layer_types=(_SLIDE, _FULL) * 2), "MixedPagedKVCache"),
+}
+
+
+@pytest.fixture(scope="module")
+def toys():
+    """kind -> (model config, weights), each built once. Every engine of a
+    kind is built with `TOY_SERVE` and so has the same shapes: the module
+    compiles a kind's two programs once."""
+    built = {}
+
+    def get(kind):
+        if kind not in built:
+            built[kind] = CACHE_KINDS[kind][0]()
+        return built[kind]
+
+    return get
+
+
+# 24 blocks hold two residents of 48 positions and not two of 49
+TOY_SERVE = ServeConfig(decode_slots=2, block_size=4, prefill_chunk=8,
+                        max_model_len=64, decode_interval=2, num_blocks=24)
+
+
+def _pools(eng):
+    return [p for p in (eng.pool, eng.wpool) if p is not None]
+
+
+@pytest.mark.parametrize("kind", [k for k in CACHE_KINDS if k != "window_full"])
+def test_engine_retire_cancel_shed_leak_no_block(toys, kind):
+    """Every kind of cache over a trace that retires, cancels (a resident
+    mid-prefill, a resident mid-decode and a queued request) and sheds (a
+    deadline passed in the queue): every block of every pool is back, and
+    the requests that then take the two slots the cancelled ones left start
+    from nothing of theirs: their tokens are `generate`'s, which knows no
+    slot. Nothing on the host clears a slot's state row or its blocks: the
+    program starts a row at position 0 from zeros."""
+    cfg, params = toys(kind)
     rng = np.random.default_rng(2)
+    # 0: cancelled mid-prefill; 1: mid-decode; 2: in the queue; 3, 4: retire
+    # from the slots of 0 and 1 (past EvaByte's window of 32); 5: shed
+    lens, new = (41, 9, 12, 37, 37, 5), 6
     prompts = [list(map(int, rng.integers(0, cfg.vocab_size, size=n)))
-               for n in (23, 9, 17, 30, 12, 5)]
-    want = {i: list(map(int, np.asarray(generate(
-        params, cfg, jnp.asarray([p]), 6))[0, len(p):])) for i, p in enumerate(prompts)}
-    eng = ServeEngine(params, cfg, ServeConfig(
-        decode_slots=2, block_size=4, prefill_chunk=8, max_model_len=48,
-        decode_interval=2, num_blocks=14))
-    assert [pool.shape for pool in eng._kv] == [(4, 14, 4, 128)]
+               for n in lens]
+    want = np.asarray(generate(params, cfg, jnp.asarray(prompts[3:5]), new))[:, 37:]
+    eng = ServeEngine(params, cfg, TOY_SERVE)
+    assert type(eng.cache).__name__ == CACHE_KINDS[kind][1]
     for i, p in enumerate(prompts):
-        eng.submit(p, 6, req_id=i, arrival=0.0,
+        eng.submit(p, new, req_id=i, arrival=0.0,
                    **({"deadline_ms": 10.0} if i == 5 else {}))
-    eng.step(0.0)
-    eng.step(0.0)
-    resident = [s.req.id for s in eng.sched.slots if s is not None]
-    queued = [s.req.id for s in eng.sched.queue if s.req.id != 5]
-    assert resident and queued
-    held = eng.pool.in_use
-    assert eng.cancel(resident[0]) and eng.pool.in_use < held
-    assert eng.cancel(queued[0])
+    slot_of = {}
+
+    def step(now):
+        eng.step(now)
+        slot_of.update({st.req.id: s for s, st in enumerate(eng.sched.slots)
+                        if st is not None})
+
+    while 1 not in slot_of or not eng.sched.slots[slot_of[1]].generated:
+        step(0.0)
+    first, second = (eng.sched.slots[slot_of[i]] for i in (0, 1))
+    assert first.req.id == 0 and first.prefilling and not first.generated
+    assert second.req.id == 1 and not second.prefilling
+    assert 0 < len(second.generated) < new
+    held = sum(p.in_use for p in _pools(eng))
+    assert eng.cancel(0) and eng.cancel(1) and eng.cancel(2)
+    assert sum(p.in_use for p in _pools(eng)) == 0 < held
     now = 1.0
     while eng.sched.has_work():
-        eng.step(now)
+        step(now)
         now += 0.1
-    assert eng.pool.in_use == 0
+    assert all(p.in_use == 0 for p in _pools(eng))
     assert [r["id"] for r in eng.shed_results] == [5]
+    assert eng.stats["cancelled"] == 3
     done = {r["id"]: r["tokens"] for r in eng.results}
-    assert set(done) == set(range(5)) - {resident[0], queued[0]}
-    for rid, toks in done.items():
-        assert toks == want[rid], rid
+    assert set(done) == {3, 4}
+    assert {slot_of[3], slot_of[4]} == {slot_of[0], slot_of[1]} == {0, 1}
+    for rid in (3, 4):
+        assert done[rid] == want[rid - 3].tolist(), rid
     eng.close()
+
+
+def _run_to_end(eng, requests, one_at_a_time=False):
+    """Tokens by request id: all submitted at once, or each alone."""
+    for i, (p, n) in enumerate(requests):
+        eng.submit(p, n, req_id=i)
+        while one_at_a_time and eng.sched.has_work():
+            eng.step(0.0)
+    while eng.sched.has_work():
+        eng.step(0.0)
+    eng.close()
+    return {r["id"]: r["tokens"] for r in eng.results}
+
+
+@pytest.mark.parametrize("kind", ["window_full", "latent"])
+def test_preemption_and_recompute_keep_the_tokens(toys, kind):
+    """A pool too small for both residents' growth preempts the younger
+    mid-decode and recomputes it, through a mixed window + full cache (its
+    ring of window blocks is given back and given again) and a latent one:
+    every request's tokens are those of the same pool serving it alone,
+    where nothing is ever preempted, and every block of every pool is
+    back."""
+    cfg, params = toys(kind)
+    rng = np.random.default_rng(6)
+    new = 28
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), new)
+                for n in (23, 27, 21)]
+    alone = ServeEngine(params, cfg, TOY_SERVE)
+    want = _run_to_end(alone, requests, one_at_a_time=True)
+    assert alone.sched.n_preempted == 0
+    eng = ServeEngine(params, cfg, TOY_SERVE)
+    assert type(eng.cache).__name__ == CACHE_KINDS[kind][1]
+    assert (eng.wpool is not None) == (kind == "window_full")
+    got = _run_to_end(eng, requests)
+    assert eng.sched.n_preempted > 0
+    assert got == want and all(len(t) == new for t in got.values())
+    for pool in _pools(eng):
+        assert pool.in_use == 0 and pool.free_blocks == pool.num_blocks
+
+
+def test_sampled_tokens_survive_preemption(tiny, requests5):
+    """At temperature 0.7 a request preempted mid-decode and recomputed
+    samples the tokens it would have sampled undisturbed: the key of a
+    token folds (request id, token index), not where or when it ran."""
+    cfg, params = tiny
+    runs = {}
+    for num_blocks in (24, 8):
+        eng = ServeEngine(params, cfg, scfg(num_blocks=num_blocks),
+                          temperature=0.7, seed=11)
+        runs[num_blocks] = _run_to_end(eng, requests5)
+        assert (eng.sched.n_preempted > 0) == (num_blocks == 8)
+        assert eng.pool.in_use == 0
+    assert runs[8] == runs[24]
+    greedy = _run_to_end(ServeEngine(params, cfg, scfg()), requests5)
+    assert runs[24] != greedy  # the temperature took
+
+
+# The boundary PR 46 drew: which cache a model is served from, and every fact
+# of its format, is `serve/paged_cache.py`'s; the engine asks the cache it was
+# given. Outside `new_cache`, which asks `init_serve_cache` once, neither
+# module names a cache class or asks a ModelConfig what kind of model it is.
+KIND_PREDICATES = {"mla", "eva", "gdn", "ssm", "kda", "recurrent",
+                   "layer_types"}
+
+
+@pytest.mark.parametrize("module", ["engine", "scheduler"])
+def test_the_engine_and_the_scheduler_name_no_cache_kind(module):
+    import ast
+
+    serve = os.path.join(os.path.dirname(__file__), "..", "picotron_tpu",
+                         "serve")
+
+    def parse(name):
+        with open(os.path.join(serve, f"{name}.py")) as f:
+            return ast.parse(f.read())
+
+    cache_classes = {n.name for n in ast.walk(parse("paged_cache"))
+                     if isinstance(n, ast.ClassDef) and n.name.endswith("Cache")}
+    assert {"PagedKVCache", "EvaPagedCache", "HybridPagedCache"} <= cache_classes
+    tree = parse(module)
+    tree.body = [n for n in tree.body  # the one place that picks a cache
+                 if not (isinstance(n, ast.FunctionDef) and n.name == "new_cache")]
+    leaks = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Name) and node.id in cache_classes:
+            leaks.append((node.lineno, node.id))
+        elif isinstance(node, ast.alias) and node.name in cache_classes:
+            leaks.append((node.lineno, node.name))
+        elif isinstance(node, ast.Attribute) and (
+                node.attr in KIND_PREDICATES or node.attr in cache_classes):
+            leaks.append((node.lineno, "." + node.attr))
+    assert not leaks, f"serve/{module}.py reaches around the cache: {leaks}"
 
 
 # ---------------------------------------------------------------------------
@@ -1236,7 +1380,7 @@ PD, DD = "serve.prefill.dispatch", "serve.decode.dispatch"
 ACCOUNT_CASES = {
     # name: (the enqueues in flight at the start, oldest first, leaves,
     #        tail ms, starved ms by leaf,
-    #        ms with work enqueued: from the start of a dispatch or handoff
+    #        ms with work enqueued: from the start of a dispatch
     #        to the end of the wait that clears it, added up by hand,
     #        the dispatches each wait cleared, in flight at the end)
     "decode_only": (
@@ -1284,10 +1428,11 @@ ACCOUNT_CASES = {
         1,
         {"serve.decode.build": 2, "unspanned": 1}, 1 + 1 + 2 + 3 + 9, [4],
         ()),
-    "handoff": (
+    # a prefill tick of two pieces, neither waited for
+    "two_pieces": (
         (),
-        [("serve.admit", 0, 1), ("serve.handoff", 0, 2),
-         ("serve.handoff", 0.5, 2), ("serve.decode.build", 0, 1),
+        [("serve.admit", 0, 1), ("serve.prefill.dispatch", 0, 2),
+         ("serve.prefill.dispatch", 0.5, 2), ("serve.decode.build", 0, 1),
          ("serve.decode.dispatch", 0, 1), ("serve.decode.wait", 0, 8),
          ("serve.decode.emit", 0, 2)],
         0,
@@ -1532,19 +1677,16 @@ class _Events:
         pass
 
 
-@pytest.mark.parametrize("disagg", [False, True])
-def test_serve_host_events_add_up_to_the_stats(tiny, requests5, disagg):
+def test_serve_host_events_add_up_to_the_stats(tiny, requests5):
     """Every step with device work emits one `phase=serve_host` event whose
     `secs` are the step's starved seconds: they sum to `stats["starved_s"]`
     and to the ledger's `serve_host`, and the summary carries the share."""
-    from picotron_tpu.serve import DisaggServeEngine
     from picotron_tpu.telemetry import Telemetry
 
     cfg, params = tiny
     cap = _Events()
     tel = Telemetry(sinks=[cap])
-    cls = DisaggServeEngine if disagg else ServeEngine
-    eng = cls(params, cfg, scfg(disagg=disagg), telemetry=tel)
+    eng = ServeEngine(params, cfg, scfg(), telemetry=tel)
     res = eng.run(requests5)
     assert len(res) == len(requests5)
     host = [e for e in cap.events
@@ -1571,11 +1713,7 @@ def test_serve_host_events_add_up_to_the_stats(tiny, requests5, disagg):
     assert st["period_s"] >= st["step_wall_s"]
     assert (st["empty_s"] + st["starved_s"] + st["caller_starved_s"]
             + st["dry_s"]) <= st["period_s"]
-    if disagg:  # its two pools are not probed (serve/disagg.py)
-        assert st["probes"] == 0 and st["dry_s"] == st["dry_slack_s"] == 0.0
-        assert eng.summary["device_dry_share"] == 0.0
-    else:
-        assert st["probes"] > 0
+    assert st["probes"] > 0
     assert st["step_wall_max_s"] == max(eng._walls)
     assert sum(eng._walls) == pytest.approx(st["step_wall_s"])
     assert st["slow_steps"] == 0 and "steps" not in st
@@ -1583,8 +1721,6 @@ def test_serve_host_events_add_up_to_the_stats(tiny, requests5, disagg):
     assert s["device_starved_share"] == pytest.approx(
         st["starved_s"] / st["step_wall_s"], abs=1e-4)
     assert 0.0 < s["step_wall_p50_s"] <= s["step_wall_max_s"]
-    if disagg:
-        assert st["handoffs"] > 0
     # the gauges nothing read are gone; the summary's own numbers stay
     assert not any(k.startswith("serve/") for k in
                    tel.registry.snapshot()["gauges"])

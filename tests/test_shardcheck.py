@@ -593,8 +593,7 @@ def test_shardflow_runs_gate():
         if base["status"] == "fatal":
             continue  # improvement: traces now where it could not before
         var = row["info"]["variants"]
-        for entry in ("train_step", "serve", "serve_disagg",
-                      "mpmd_stages"):
+        for entry in ("train_step", "serve", "mpmd_stages"):
             if (base.get(f"{entry}_proven")
                     and not var.get(entry, {}).get("proven")):
                 problems.append(f"{name}: {entry} no longer proven "

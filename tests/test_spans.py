@@ -15,7 +15,7 @@ import pytest
 
 from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
 from picotron_tpu.models.llama import init_params
-from picotron_tpu.serve import DisaggServeEngine, ServeEngine
+from picotron_tpu.serve import ServeEngine
 from picotron_tpu.serve.engine import prefill_rungs
 from picotron_tpu.telemetry import PhaseTimer, Telemetry, bus
 from picotron_tpu.telemetry.flightdeck import (
@@ -47,13 +47,13 @@ def model():
     return mcfg, init_params(mcfg, jax.random.key(0))
 
 
-def make_engine(model, tel, cls=ServeEngine, **kw):
+def make_engine(model, tel):
     mcfg, params = model
-    return cls(params, mcfg,
-               ServeConfig(decode_slots=SLOTS, block_size=4, num_blocks=16,
-                           prefill_chunk=CHUNK, max_model_len=32,
-                           decode_interval=2, **kw),
-               telemetry=tel)
+    return ServeEngine(
+        params, mcfg,
+        ServeConfig(decode_slots=SLOTS, block_size=4, num_blocks=16,
+                    prefill_chunk=CHUNK, max_model_len=32, decode_interval=2),
+        telemetry=tel)
 
 
 def host_annotations(trace_dir):
@@ -155,9 +155,14 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
                 + c["dry_us"]) <= c["period_us"]
     host = [e for e in events
             if e.get("kind") == "phase" and e.get("phase") == "serve_host"]
+    # one event a step, in the order the steps ran; both hold the same
+    # seconds in whole microseconds, the event's rounded (`Telemetry.emit`)
+    # and the span's cut off (`_account_step`), so they are equal or the
+    # event's is one more. Compared as integers: the rounded seconds times
+    # 1e6 is a float a hair off the integer on either side
     assert len(host) == len(steps)
-    assert all(abs(e["secs"] * 1e6 - c["starved_us"]) <= 1
-               for e, (*_, c) in zip(host, steps))
+    for e, (*_, c) in zip(host, steps):
+        assert 0 <= round(e["secs"] * 1e6) - c["starved_us"] <= 1
 
     # counts, at the boundary of the work they count
     # (the prefill batch is compacted: `capacity` is the rung's rows, not SLOTS)
@@ -214,17 +219,6 @@ def test_engine_spans_under_a_profile(model, tmp_path, ends_in_chunk):
             k: str(v) for k, v in counts.items()}
     waits = [e for e in spans if e["name"] == "serve.queue_wait"]
     assert sorted(e["args"]["id"] for e in waits) == [100 + i for i in range(len(prompts))]
-
-
-def test_disagg_engine_spans_include_the_handoff(model):
-    tel = Telemetry(sinks=[])
-    tel.tracer = SpanTracer()
-    eng = make_engine(model, tel, cls=DisaggServeEngine, disagg=True)
-    eng.run([(list(range(1, 6)), 3), (list(range(1, 8)), 3)])
-    eng.close()
-    names = {e["name"] for e in tel.tracer.to_json()["traceEvents"] if e["ph"] == "X"}
-    assert set(LEAVES) | {"serve.step", "serve.handoff", "serve.queue_wait"} <= names
-    tel.close()
 
 
 def test_span_without_profile_or_tracer_records_nothing(model):

@@ -35,6 +35,19 @@ def test_create_config_roundtrip(tmp_path):
     assert cfg.global_batch_size == 2 * 3 * 2
 
 
+def test_create_config_has_no_flag_for_a_second_engine(tmp_path, capsys):
+    """`--serve-disagg` went with the engine it selected: argparse refuses
+    it by name, and writes nothing."""
+    cc = load_tool("create_config")
+    with pytest.raises(SystemExit) as e:
+        cc.build_parser().parse_args([
+            "--exp-name", "x", "--out-dir", str(tmp_path),
+            "--model", "debug-tiny", "--serve-disagg"])
+    assert e.value.code == 2
+    assert "unrecognized arguments: --serve-disagg" in capsys.readouterr().err
+    assert not list(tmp_path.iterdir())
+
+
 def test_create_config_rejects_bad_layout(tmp_path):
     cc = load_tool("create_config")
     args = cc.build_parser().parse_args([
@@ -289,6 +302,43 @@ def test_extract_metrics_extras_skip_stable_suffixed_fields(tmp_path):
         "| MFU: 45.00% | tokens: 20K | mem: 1.0GB\n")
     out = process_file(str(log))
     assert "mean_tokens" not in out and "mean_mem" not in out
+
+
+def test_extract_metrics_serve_columns(tmp_path):
+    """A serving-only telemetry stream (no train steps) must still yield
+    a harvest row: serve_* TTFT/TPOT columns from the serve_summary
+    event."""
+    import jax
+    import numpy as np
+
+    from picotron_tpu.config import ModelConfig, ServeConfig, resolve_preset
+    from picotron_tpu.models.llama import init_params
+    from picotron_tpu.serve import ServeEngine
+    from picotron_tpu.telemetry import JsonlSink, Telemetry
+
+    cfg = ModelConfig(dtype="float32", **{
+        **resolve_preset("debug-tiny"), "max_position_embeddings": 64})
+    params = init_params(cfg, jax.random.key(0))
+    rng = np.random.default_rng(0)
+    requests = [(list(map(int, rng.integers(0, cfg.vocab_size, size=n))), m)
+                for n, m in ((5, 6), (9, 3), (3, 8))]
+    run_dir = tmp_path / "serve_run"
+    run_dir.mkdir()
+    path = str(run_dir / "telemetry.jsonl")
+    tel = Telemetry(sinks=[JsonlSink(path)])
+    eng = ServeEngine(params, cfg, ServeConfig(
+        decode_slots=3, block_size=4, num_blocks=24, prefill_chunk=4,
+        max_model_len=32, decode_interval=3), telemetry=tel)
+    eng.run(requests)
+    tel.close()
+
+    stats = load_tool("extract_metrics").process_telemetry(path)
+    assert stats is not None
+    assert stats["serve_requests"] == len(requests)
+    assert stats["serve_output_tokens"] == 6 + 3 + 8
+    assert stats["serve_ttft_p50_ms"] >= 0
+    assert stats["serve_tpot_p50_ms"] >= 0
+    assert "serve_decode_stall_ticks_max" in stats
 
 
 def test_telemetry_report_cli_markdown_smoke(tmp_path, capsys):

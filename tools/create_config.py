@@ -133,20 +133,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode steps scanned per dispatch (amortizes "
                         "host overhead; retirement latency quantizes "
                         "to it)")
-    p.add_argument("--serve-disagg", action="store_true",
-                   help="disaggregated serving: prefill and decode as "
-                        "separately placed pools with paged-KV block "
-                        "handoff (picotron_tpu/serve/disagg)")
-    p.add_argument("--serve-prefill-slots", type=int, default=None,
-                   help="prefill-pool slot count (0 = decode_slots)")
-    p.add_argument("--serve-prefill-num-blocks", type=int, default=None,
-                   help="prefill-pool KV blocks (0 = worst-case auto)")
-    p.add_argument("--serve-prefill-device", type=int, default=None,
-                   help="device index for the prefill pool (-1 = auto: "
-                        "device 1 when available)")
-    p.add_argument("--serve-decode-device", type=int, default=None,
-                   help="device index for the decode pool (-1 = auto: "
-                        "device 0)")
     # checkpoint / logging
     p.add_argument("--save-frequency", type=int, default=0)
     p.add_argument("--auto-resume", action="store_true",
@@ -243,11 +229,6 @@ def create_single_config(args) -> str:
         prefill_chunk=args.serve_prefill_chunk,
         max_model_len=args.serve_max_len,
         decode_interval=args.serve_decode_interval,
-        disagg=args.serve_disagg or None,
-        prefill_slots=args.serve_prefill_slots,
-        prefill_num_blocks=args.serve_prefill_num_blocks,
-        prefill_device=args.serve_prefill_device,
-        decode_device=args.serve_decode_device,
     ).items() if v is not None}
     if serve:
         raw["serve"] = serve

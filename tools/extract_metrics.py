@@ -110,7 +110,6 @@ _SERVE_FIELDS = (
     ("tpot_p50_s", "serve_tpot_p50_ms", 1e3),
     ("tpot_p95_s", "serve_tpot_p95_ms", 1e3),
     ("decode_stall_ticks_max", "serve_decode_stall_ticks_max", 1),
-    ("handoffs", "serve_handoffs", 1),
     # fleet serving (serve/fleet.py): overload + failover counters
     ("shed", "serve_shed", 1),
     ("redispatched", "serve_redispatch", 1),

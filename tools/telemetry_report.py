@@ -276,13 +276,6 @@ def serving_view(reqs: list[dict], summary: dict | None,
                 ("decode_compiles", "decode_compiles", 1),
                 ("preemptions", "preemptions", 1),
                 ("decode_stall_ticks_max", "decode_stall_ticks_max", 1),
-                # disaggregated engines only (serve/disagg.py)
-                ("prefill_slot_occupancy", "prefill_slot_occupancy", 1),
-                ("prefill_pool_peak_utilization",
-                 "prefill_pool_peak_utilization", 1),
-                ("handoffs", "handoffs", 1),
-                ("handoff_s", "handoff_s", 1),
-                ("handoff_blocks", "handoff_blocks", 1),
                 # fleet serving (serve/fleet.py)
                 ("fleet_size", "fleet_size", 1),
                 ("shed", "shed", 1),
@@ -512,13 +505,6 @@ def render(s: dict, markdown: bool = False) -> str:
                 f"{st['held_s']} s (limit {st['limit_s']}) of wall "
                 f"{st['wall_s']} s (starved {st['starved_s']} s), longest "
                 f"leaf {leaf} {ms} ms, blocks freed {st['blocks_freed']}")
-        if "handoffs" in sv or "prefill_slot_occupancy" in sv:
-            lines.append(
-                f"  disagg: prefill occupancy "
-                f"{pair('prefill_slot_occupancy')} (pool peak "
-                f"{pair('prefill_pool_peak_utilization')}) | handoffs "
-                f"{pair('handoffs')} ({pair('handoff_blocks')} blocks, "
-                f"{pair('handoff_s')} s)")
         if any(k in sv for k in ("fleet_size", "shed", "redispatched",
                                  "engines_dead", "drains")):
             lines.append(

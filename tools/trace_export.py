@@ -7,7 +7,7 @@ Two modes:
   per-host event file next to the checkpoints) into Chrome trace-event
   JSON loadable by Perfetto / chrome://tracing. Phase events become
   complete spans — train-loop phases on the train lane, serve request
-  phases (queue_wait/prefill/handoff/decode, with their request ids) on
+  phases (queue_wait/prefill/decode, with their request ids) on
   the serve lane — and resilience events (chaos, guard, rollback,
   preemption, watchdog, resize, recompile, sentinel alerts) become
   instants, so one timeline shows compute, comm phases, and faults
@@ -49,7 +49,7 @@ from picotron_tpu.telemetry.sinks import jsonl_segments  # noqa: E402
 
 _VALID_PH = frozenset("XBEiICMsnftPNODabevR")
 # The serve engine's `phase` events, drawn on the serve lane.
-_SERVE_PHASES = frozenset(("queue_wait", "prefill", "decode", "handoff"))
+_SERVE_PHASES = frozenset(("queue_wait", "prefill", "decode"))
 
 
 def resolve_jsonl(path: str) -> str:
