@@ -86,7 +86,11 @@ def causal_conv(x, tail, w, n_valid, bias=None):
     it; bias [C]: added before the SiLU (a Mamba mixer's; the Gated DeltaNet
     mixer's convolution has none). Returns (y [B, s, C] in x's dtype, the tail after the last real
     position [B, K - 1, C] in the tail's dtype: the old tail where
-    n_valid is 0)."""
+    n_valid is 0). At one position a row (a decode step) the new tail is
+    one select between the two cases, a token or none; a longer segment
+    takes K - 1 positions from each row's own n_valid by a slice a row
+    (64 slots' slices were 0.9 of a 10.1 ms Kimi-Linear step: PERF.md
+    section 6, PR 61)."""
     k = w.shape[-1]
     s = x.shape[1]
     full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
@@ -94,9 +98,14 @@ def causal_conv(x, tail, w, n_valid, bias=None):
     y = sum(full[:, j:j + s].astype(F32) * wf[:, j] for j in range(k))
     if bias is not None:
         y = y + bias.astype(F32)
-    new_tail = jax.vmap(
-        lambda f, n: lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(
-            full, n_valid)
+    if s == 1:
+        # one position a row: a token or none, the two tails one select
+        new_tail = jnp.where((n_valid >= 1)[:, None, None], full[:, 1:],
+                             full[:, :-1])
+    else:
+        new_tail = jax.vmap(
+            lambda f, n: lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(
+                full, n_valid)
     return jax.nn.silu(y).astype(x.dtype), new_tail.astype(tail.dtype)
 
 

@@ -123,7 +123,7 @@ from picotron_tpu.ops.gated_delta import (
     gated_delta_chunk_pooled, gated_delta_chunk_suits,
     gated_delta_kernel_suits, gated_delta_step_pooled, per_value_head,
 )
-from picotron_tpu.ops.kda import delta_rule
+from picotron_tpu.ops.kda import delta_rule, kda_chunk_pooled, kda_chunk_suits
 from picotron_tpu.ops.mla import (
     TILE_KEYS, absorb_queries, latent_attention, values_from_latent,
 )
@@ -1135,8 +1135,10 @@ class HybridPagedCache(NamedTuple):
         rows with a real position and a mapped slot alone; everything else
         gathers, runs the plain rule and scatters. g [B, s, Hv, d_k], a
         decay a CHANNEL (a Kimi Delta Attention mixer's): the decode step's
-        kernel takes it as it takes the other; a prefill chunk has no kernel
-        yet and gathers, runs `ops.kda.kda_chunked` and scatters."""
+        kernel takes it as it takes the other, and a prefill chunk has a
+        kernel of its own (`ops.kda.kda_chunk_pooled`, asked for by
+        `kda_chunk_suits`; `g.ndim` tells the two rules apart); what it
+        refuses gathers, runs `ops.kda.kda_chunked` and scatters."""
         where = (self.state, gi, self.stables[:, 0],
                  jnp.any(q_pos >= 0, axis=1), q_pos[:, 0] == 0)
         if gated_delta_kernel_suits(q.shape[1], self.state):
@@ -1147,6 +1149,10 @@ class HybridPagedCache(NamedTuple):
         if g.ndim == beta.ndim and gated_delta_chunk_suits(
                 q.shape[1], q.shape[2], self.state):
             o, state = gated_delta_chunk_pooled(q, k, v, g, beta, *where)
+            return o, self._replace(state=state)
+        if g.ndim == q.ndim and kda_chunk_suits(
+                q.shape[1], q.shape[2], self.state):
+            o, state = kda_chunk_pooled(q, k, v, g, beta, *where)
             return o, self._replace(state=state)
         o, state = delta_rule(q, k, v, g, beta, self.state_of(gi, q_pos))
         return o, self.put_state(gi, state, q_pos)
@@ -1306,7 +1312,8 @@ class HybridLatentPagedCache(NamedTuple):
     x channels] float32, `stables`; a start from zeros at position 0, rows
     without a real position write nothing, `recur` answers for the state:
     a decode step on a chip is ONE kernel over the pool in place,
-    `kda_step_pooled` in a trace). `generate._decode_layers` calls `write(li,
+    `kda_step_pooled` in a trace, and so is a prefill chunk,
+    `kda_chunk_pooled`). `generate._decode_layers` calls `write(li,
     ckr, q_pos, ki=)` / `attend(li, q_n, q_r, q_pos, kv_b, cfg, ki=)` on a
     full layer and `tail_of` / `recur` / `put_tail` on a mixer. A dispatch's
     span carries both halves' counts: `latent_blocks` / `latent_keys` over

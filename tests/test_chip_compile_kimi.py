@@ -42,8 +42,12 @@ def test_kimi_linear_serving_programs(topo, monkeypatch, program, rows):
     state's kernel 1 + 3 + 2 times, the latent kernel 1 + 1 times and the
     experts' grouped kernel 4 + 3 times, and gathers no row of state; a
     prefill chunk attends through the latent prefill kernel and runs the
-    chunked per-channel rule in jax.numpy (no kernel yet) without a
-    triangular solve; the scopes the cell's metrics read are there."""
+    chunked per-channel rule as one kernel a mixer over the state pool in
+    place (`kda_chunk_pooled`, 1 + 3 + 2 calls under `kda_chunk`: no row of
+    state gathered, no loop over a sub-chunk's blocks left in `jax.numpy`,
+    no triangular solve); a decode step's convolution takes its new tail by
+    a select, no `dynamic-slice` under `kda_conv`; the scopes the cell's
+    metrics read are there."""
     comp, cache, pools = lower_serve(topo, monkeypatch, KIMI, program, rows)
     text = comp.as_text()
     assert text.startswith(f"HloModule jit_{program}")
@@ -81,18 +85,30 @@ def test_kimi_linear_serving_programs(topo, monkeypatch, program, rows):
     grouped = [(n, op) for n, op in kernels if n.startswith("grouped_experts")]
     chunked = [(n, op) for n, op in kernels if n.startswith("latent_prefill_attention")]
     state = [(n, op) for n, op in kernels if n.startswith("kda_step_pooled")]
-    assert len(grouped) + len(latent) + len(chunked) + len(state) == len(kernels), kernels
+    rule = [(n, op) for n, op in kernels if n.startswith("kda_chunk_pooled")]
+    assert (len(grouped) + len(latent) + len(chunked) + len(state) + len(rule)
+            == len(kernels)), kernels
     assert len(grouped) == 7 and "ragged-dot" not in text
     assert all("moe_experts" in words(op) for _, op in grouped), grouped
     rows_of_state = f"f32[{rows or slots},32,128,128]"
     if program == "serve_decode":
         assert len(latent) == 2 and all("attn_latent" in words(op) for _, op in latent)
         assert len(state) == 6 and all({"kda", "kda_state"} <= words(op) for _, op in state)
-        assert rows_of_state not in text and not chunked
+        assert rows_of_state not in text and not chunked and not rule
+        # one position a row: the convolution's new tail is one select
+        assert not [n for n, op, line in ins if "kda_conv" in words(op)
+                    and "dynamic-slice(" in line]
     else:
         assert not latent and not state and len(chunked) == 2
         assert all({"paged_attention", "attn_latent"} <= words(op) for _, op in chunked)
+        assert len(rule) == 6 and all({"kda", "kda_chunk"} <= words(op) for _, op in rule)
         assert "triangular" not in text.lower()
+        # the chunked rule's `jax.numpy` form is gone: no row of state
+        # gathered and no exponential left under `kda_chunk` (the running
+        # sum of g, which the kernel is handed, and the tails' moves are)
+        assert rows_of_state not in text
+        assert not [line for n, op, line in ins if "kda_chunk" in words(op)
+                    and " exponential(" in line]
     ma = comp.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)

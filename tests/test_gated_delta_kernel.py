@@ -5,9 +5,13 @@ pool, through the Pallas interpreter on the CPU at the kernels' own widths
 form of the rule (a chunk: token by token, `gated_delta_scan`), on the rows
 that hold a token, and to the pool's own bits everywhere else. The compiled
 kernels inside the serve programs are held by tests/test_chip_compile.py, a
-serving engine that runs them by tests/test_qwen3_next.py."""
+serving engine that runs them by tests/test_qwen3_next.py. Beside them the
+per-channel rule's chunk kernel (`ops/kda.py kda_chunk_pooled`, Kimi-Linear's;
+its engine: tests/test_kimi_linear.py) and the convolution before the rule at
+one position a row."""
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,11 +20,12 @@ import pytest
 
 from picotron_tpu.ops import gated_delta as gd
 from picotron_tpu.ops.gated_delta import (
-    CHUNK_SUB, gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
+    CHUNK_SUB, causal_conv, gated_delta, gated_delta_chunk_pooled, gated_delta_chunk_suits,
     gated_delta_kernel_suits, gated_delta_scan, gated_delta_step,
     gated_delta_step_pooled, l2_normalise, per_value_head,
 )
-from picotron_tpu.serve.paged_cache import HybridPagedCache
+from picotron_tpu.ops.kda import kda_chunk_pooled, kda_chunk_suits, kda_chunked
+from picotron_tpu.serve.paged_cache import HybridLatentPagedCache, HybridPagedCache
 
 MIXERS, SLOTS, ROWS, D = 3, 5, 4, 128
 EPS = float(np.finfo(np.float32).eps)
@@ -270,3 +275,220 @@ def test_which_chunks_take_the_kernel_and_what_the_others_do(monkeypatch):
     np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
     np.testing.assert_array_equal(np.asarray(after.state),
                                   np.asarray(cache.put_state(1, state, pos).state))
+
+
+# ---------------------------------------------------------------------------
+# the per-channel rule's prefill chunk (Kimi Delta Attention, ops/kda.py)
+# ---------------------------------------------------------------------------
+
+
+def seeded_decays(b, s, h, dk, seed):
+    """g a channel as Kimi-Linear's seeded model draws it, on its
+    fastest-decaying channels (tests/test_kimi_linear.py): A at the top of
+    U(1, 16), the step's softplus around the top of [0.001, 0.1] and three
+    sigmas of the projection's noise above it."""
+    ks = jax.random.split(jax.random.key(seed), 2)
+    a = jax.random.uniform(ks[0], (h, 1), jnp.float32, 12.0, 16.0)
+    dt = jax.nn.softplus(jnp.log(jnp.expm1(0.1)) + jax.random.normal(ks[1], (b, s, h, dk)))
+    return -a * dt
+
+
+KDA_CHUNK_CASES = {
+    # name: (heads, positions, mixer of 3, rows' slots of 6 (6: unmapped), real
+    #        positions a row, fresh, the pool's scale)
+    "padded_idle_and_fresh_rows": (2, 128, 1, [3, 1, 0, 4], [128, 100, 0, 77],
+                                   [False, False, False, True], 1.0),
+    "an_unmapped_row_and_pad_rows": (2, 64, 0, [2, 6, 5, 5], [64, 64, 0, 0],
+                                     [False, False, False, False], 1.0),
+    "no_row_live": (2, 64, 1, [0, 1, 2, 3], [0, 0, 0, 0], [False, True, False, False], 1.0),
+    "position_0_over_a_nonzero_row": (2, 64, 1, [4, 1, 5, 5], [64, 9, 0, 0],
+                                      [True, False, False, False], 1.0),
+    "three_pairs_one_a_loop_step": (6, 64, 2, [1, 5, 3, 5], [64, 0, 40, 0],
+                                    [False, False, True, False], 1.0),
+    "two_pairs_side_by_side_three_sub_chunks": (4, 192, 2, [3, 0, 4, 5], [150, 192, 65, 0],
+                                                [False, True, False, False], 1.0),
+    "a_large_start_state": (2, 128, 0, [4, 0, 3, 1], [128, 100, 128, 1],
+                            [False, False, True, False], 10.0),
+}
+
+
+@pytest.mark.parametrize("case", KDA_CHUNK_CASES)
+def test_the_chunk_kernel_is_the_chunked_rule_on_the_live_rows_and_nothing_elsewhere(case):
+    """`kda_chunk_pooled` in the Pallas interpreter at the kernel's own widths
+    (d_k = d_v = 128), on the fastest-decaying channels of the seeded draw,
+    from a non-zero pool: o and the worked rows' state against the rule token
+    by token (`gated_delta_scan`), and no further from it than the chunked
+    `jax.numpy` form; a row without a real position and an unmapped one get
+    zeros for o; every other bit of the pool (pad, idle and unmapped rows,
+    the other mixers) as it was."""
+    heads, s, gi, rows, real, fresh, scale = KDA_CHUNK_CASES[case]
+    b, d, mixers, slots = 4, 128, 3, 6
+    rows, fresh, real = jnp.asarray(rows), jnp.asarray(fresh), jnp.asarray(real)
+    live = real > 0
+    work = np.asarray(live) & (np.asarray(rows) < slots)
+    ks = jax.random.split(jax.random.key(s + heads), 5)
+    q = l2_normalise(jax.random.normal(ks[0], (b, s, heads, d))) * d ** -0.5
+    k = l2_normalise(jax.random.normal(ks[1], (b, s, heads, d)))
+    v = jax.random.normal(ks[2], (b, s, heads, d))
+    g = seeded_decays(b, s, heads, d, gi + 1)
+    assert float(g.min()) < -4.0 and float(jnp.cumsum(g, axis=1).min()) < -88.0
+    held = jnp.arange(s)[None, :] < real[:, None]
+    g = jnp.where(held[..., None, None], g, 0.0)
+    beta = jnp.where(held[..., None], jax.nn.sigmoid(jax.random.normal(ks[3], (b, s, heads))), 0.0)
+    pool0 = scale * jax.random.normal(ks[4], (mixers, slots, heads, d, d))
+    o, pool = jax.jit(kda_chunk_pooled)(q, k, v, g, beta, pool0, jnp.asarray(gi), rows, live,
+                                        fresh)
+    start = jnp.where(fresh[:, None, None, None], 0.0, pool0[gi, jnp.minimum(rows, slots - 1)])
+    want_o, want = jax.jit(gated_delta_scan)(q, k, v, g, beta, start)
+    plain_o, plain = jax.jit(kda_chunked)(q, k, v, g, beta, start)
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(pool)).all()
+    want_o = np.where(work[:, None, None, None], np.asarray(want_o), 0.0)
+    o_scale, s_scale = np.abs(want_o).max() or 1.0, float(jnp.abs(want).max())
+    err_o = np.abs(np.asarray(o) - want_o).max() / o_scale
+    ref_o = np.abs(np.where(work[:, None, None, None], np.asarray(plain_o), 0.0)
+                   - want_o).max() / o_scale
+    assert err_o <= max(2e-6, 2 * ref_o), (err_o, ref_o)
+    assert not np.asarray(o)[~work].any()
+    got, pool0 = np.asarray(pool), np.asarray(pool0)
+    for row in np.flatnonzero(work):
+        err = np.abs(got[gi, int(rows[row])] - np.asarray(want[row])).max() / s_scale
+        ref = np.abs(np.asarray(plain[row]) - np.asarray(want[row])).max() / s_scale
+        assert err <= max(2e-6, 2 * ref), (row, err, ref)
+    assert work.any() == (np.abs(want_o).max() > 0.01)
+    # the chunk moved the worked rows and nothing else, not by a bit
+    moved = np.any(got != pool0, axis=(2, 3, 4))
+    worked = {int(rows[row]) for row in np.flatnonzero(work)}
+    assert moved.tolist() == [[m == gi and slot in worked for slot in range(slots)]
+                              for m in range(mixers)]
+
+
+KDA_POOL = (3, 6, 8, 128, 128)
+KDA_CHUNK_SUITS = {
+    # name: (positions, heads handed over, the pool's shape, its dtype, a
+    #        backend that compiles kernels, the answer)
+    "one_sub_chunk": (64, 8, KDA_POOL, jnp.float32, True, True),
+    "the_cells_chunk": (256, 32, (9, 64, 32, 128, 128), jnp.float32, True, True),
+    "a_decode_step": (1, 8, KDA_POOL, jnp.float32, True, False),
+    "half_a_sub_chunk_over": (96, 8, KDA_POOL, jnp.float32, True, False),
+    "an_odd_head": (64, 7, (3, 6, 7, 128, 128), jnp.float32, True, False),
+    "keys_a_key_head": (64, 4, KDA_POOL, jnp.float32, True, False),
+    "half_a_row_of_lanes": (64, 8, (3, 6, 8, 64, 128), jnp.float32, True, False),
+    "values_of_8": (64, 8, (3, 6, 8, 128, 8), jnp.float32, True, False),
+    "a_bfloat16_state": (64, 8, KDA_POOL, jnp.bfloat16, True, False),
+    "a_row_that_outgrows_vmem": (2048, 32, (9, 64, 32, 128, 128), jnp.float32, True, False),
+    "the_cpu": (64, 8, KDA_POOL, jnp.float32, False, False),
+}
+
+
+@pytest.mark.parametrize("case", KDA_CHUNK_SUITS)
+def test_which_chunks_take_the_kda_kernel(monkeypatch, case):
+    """`kda_chunk_suits`: whole sub-chunks of 64 positions over 128-lane
+    float32 heads in pairs, a row's q, k, G, v and o inside VMEM, on a backend
+    that compiles kernels; nothing else, and never a decode step."""
+    s, heads, shape, dtype, compiles, suits = KDA_CHUNK_SUITS[case]
+    fa = importlib.import_module("picotron_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "compiled_kernels_available", lambda: compiles)
+    assert kda_chunk_suits(s, heads, jax.ShapeDtypeStruct(shape, dtype)) is suits
+
+
+@pytest.mark.parametrize("suits", [False, True])
+def test_the_cache_hands_a_chunk_to_the_kernel_that_suits_it(monkeypatch, suits):
+    """`recur` with a decay a channel of the key: where `kda_chunk_suits` says
+    no (every CPU run) the cache gathers, runs `kda_chunked` and scatters, and
+    never calls the kernel; where it says yes the chunk goes through
+    `kda_chunk_pooled` over the pool in place (here the Pallas interpreter) and
+    comes out as the plain path's, the rows without a real position and the
+    unmapped row untouched. The scalar-gated rule's kernel is never asked for
+    such a chunk."""
+    from picotron_tpu.serve import paged_cache
+    b, s, h, d, mixers, slots = 4, 64, 2, 128, 2, 5
+    calls = []
+    sound = paged_cache.kda_chunk_pooled
+    monkeypatch.setattr(paged_cache, "kda_chunk_suits", lambda *a: suits)
+    monkeypatch.setattr(paged_cache, "kda_chunk_pooled",
+                        lambda *a, **k: calls.append(a[3].shape) or sound(*a, **k))
+
+    def refuse(*a, **k):
+        raise AssertionError("the scalar-gated chunk kernel was asked")
+
+    monkeypatch.setattr(paged_cache, "gated_delta_chunk_pooled", refuse)
+    ks = jax.random.split(jax.random.key(4), 6)
+    cache = HybridLatentPagedCache(
+        jnp.zeros((1, 4, 4, 8)), jax.random.normal(ks[5], (mixers, slots, h, d, d)),
+        jnp.ones((mixers, slots, 6)), jnp.full((b, 2), 4, jnp.int32),
+        jnp.asarray([[3], [slots], [0], [1]], jnp.int32))
+    real = jnp.asarray([64, 64, 20, 0])
+    held = jnp.arange(s)[None, :] < real[:, None]
+    pos = jnp.where(held, jnp.asarray([[7], [3], [0], [0]]) + jnp.arange(s)[None, :], -1)
+    q = l2_normalise(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+    k = l2_normalise(jax.random.normal(ks[1], (b, s, h, d)))
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = jnp.where(held[..., None, None], seeded_decays(b, s, h, d, 2), 0.0)
+    beta = jnp.where(held[..., None], jax.nn.sigmoid(jax.random.normal(ks[3], (b, s, h))), 0.0)
+    o, after = cache.recur(1, q, k, v, g, beta, pos)
+    want_o, state = kda_chunked(q, k, v, g, beta, cache.state_of(1, pos))
+    want = cache.put_state(1, state, pos).state
+    assert calls == ([(b, s, h, d)] if suits else [])
+    if suits:
+        mapped = np.asarray([True, False, True, False])[:, None, None, None]
+        np.testing.assert_allclose(o, np.where(mapped, want_o, 0.0), atol=2e-6)
+        np.testing.assert_allclose(after.state, want, atol=2e-5)
+        moved = np.any(np.asarray(after.state != cache.state), axis=(2, 3, 4))
+        assert moved.tolist() == [[False] * slots, [True, False, False, True, False]]
+    else:
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
+        np.testing.assert_array_equal(np.asarray(after.state), np.asarray(want))
+    assert after.tail is cache.tail and after.kv is cache.kv
+
+
+# ---------------------------------------------------------------------------
+# the convolution before the rule, at one position a row
+# ---------------------------------------------------------------------------
+
+
+def sliced_conv(x, tail, w, n_valid, bias=None):
+    """`causal_conv` as it took the new tail before PR 61, whatever the
+    segment's length: one `dynamic_slice` a row."""
+    k, s = w.shape[-1], x.shape[1]
+    full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    wf = w.astype(jnp.float32)
+    y = sum(full[:, j:j + s].astype(jnp.float32) * wf[:, j] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(
+        full, n_valid)
+    return jax.nn.silu(y).astype(x.dtype), new_tail.astype(tail.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("n_valid", [[0, 0, 0, 0], [1, 1, 1, 1], [1, 0, 0, 1]],
+                         ids=["no_token", "a_token_a_row", "some_idle"])
+def test_one_position_a_row_takes_its_new_tail_by_one_select(n_valid, bias, dtype):
+    """A decode step's convolution: the output and the tail after it are, bit
+    for bit, what one `dynamic_slice` a row gave (the old tail for a row
+    without a token, the tail moved up by the token for one with), with and
+    without a bias, and the traced step holds no `dynamic_slice`; a longer
+    segment keeps its slice a row."""
+    rows, kernel, c = 4, 4, 24
+    ks = jax.random.split(jax.random.key(sum(n_valid) + bias), 4)
+    x = jax.random.normal(ks[0], (rows, 1, c)).astype(dtype)
+    tail = jax.random.normal(ks[1], (rows, kernel - 1, c))
+    w = jax.random.normal(ks[2], (c, kernel))
+    b = jax.random.normal(ks[3], (c,)) if bias else None
+    n = jnp.asarray(n_valid, jnp.int32)
+    y, new = jax.jit(causal_conv)(x, tail, w, n, b)
+    want_y, want = jax.jit(sliced_conv)(x, tail, w, n, b)
+    assert y.dtype == x.dtype and new.dtype == tail.dtype and new.shape == tail.shape
+    np.testing.assert_array_equal(np.asarray(y, np.float32), np.asarray(want_y, np.float32))
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(want))
+    sliced = re.compile("dynamic_slice|gather")  # (a slice a row, under `vmap`: a gather)
+    assert not sliced.search(str(jax.make_jaxpr(causal_conv)(x, tail, w, n, b)))
+    # three positions a row: the slice stays, and so do its values
+    x3 = jnp.concatenate([x, x + 1, x - 1], axis=1)
+    n3 = jnp.asarray(n_valid, jnp.int32) * jnp.asarray([3, 2, 1, 3])
+    y3, new3 = causal_conv(x3, tail, w, n3, b)
+    want_y3, want3 = sliced_conv(x3, tail, w, n3, b)
+    np.testing.assert_array_equal(np.asarray(y3, np.float32), np.asarray(want_y3, np.float32))
+    np.testing.assert_array_equal(np.asarray(new3), np.asarray(want3))
+    assert sliced.search(str(jax.make_jaxpr(causal_conv)(x3, tail, w, n3, b)))

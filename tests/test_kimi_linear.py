@@ -36,6 +36,8 @@ from picotron_tpu.serve.paged_cache import (
     HybridLatentPagedCache, init_hybrid_latent_cache, init_serve_cache,
 )
 
+from test_gated_delta_kernel import seeded_decays  # (tests/ is on the path)
+
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 # loaded by its path: `benchmark/` is not put on sys.path, where its own
 # `tests` package would shadow this one
@@ -159,16 +161,6 @@ def test_training_refuses_the_mixer_by_name():
 # ---------------------------------------------------------------------------
 # (b) the rule: chunked against token by token, the kernel against the step
 # ---------------------------------------------------------------------------
-
-
-def seeded_decays(b, s, h, dk, seed):
-    """g as the seeded model draws it, on its fastest-decaying channels: A at
-    the top of U(1, 16), the step's softplus around the top of [0.001, 0.1]
-    and three sigmas of the projection's noise above it."""
-    ks = jax.random.split(jax.random.key(seed), 2)
-    a = jax.random.uniform(ks[0], (h, 1), jnp.float32, 12.0, 16.0)
-    dt = jax.nn.softplus(jnp.log(jnp.expm1(0.1)) + jax.random.normal(ks[1], (b, s, h, dk)))
-    return -a * dt
 
 
 @pytest.mark.parametrize("s,sub,block", [(150, 64, 16), (5, 64, 16), (37, 8, 4), (64, 16, 16)])
@@ -385,6 +377,46 @@ def test_a_decode_step_through_the_kernel_serves_what_the_plain_path_serves(monk
     assert [r["tokens"] for r in kernel_out] == [r["tokens"] for r in plain_out]
     np.testing.assert_allclose(kernel_pool, plain_pool, rtol=0, atol=1e-5)
     held_to_the_reference(params, cfg, requests, kernel_out)
+
+
+def test_prefill_chunks_through_the_kernel_serve_the_references_tokens(monkeypatch):
+    """A one-slot engine at widths the chunk kernel takes (one period, 2 heads
+    of 128 x 128, chunks of 64), a prompt of two chunks with a part chunk at
+    its end, then the slot's next request over the state its predecessor
+    left (a rung's pad rows: the test above): the prefill chunks go through
+    `kda_chunk_pooled` (the Pallas interpreter; `recur` is told the kernel
+    suits), and every served token is the reference's at its logit."""
+    from picotron_tpu.serve import paged_cache
+
+    cfg = tiny(num_hidden_layers=4, layer_types=(KDA, KDA, KDA, F), linear_key_head_dim=128,
+               linear_value_head_dim=128, linear_num_key_heads=2, linear_num_value_heads=2)
+    params = weights(cfg)
+    requests = some_requests(cfg, ((100, 3), (40, 2)), seed=3)
+    calls = []
+    sound = paged_cache.kda_chunk_pooled
+    monkeypatch.setattr(paged_cache, "kda_chunk_suits",
+                        lambda s, heads, pool: s > 1 and s % 64 == 0)
+    monkeypatch.setattr(paged_cache, "kda_chunk_pooled",
+                        lambda *a, **k: calls.append(a[3].shape) or sound(*a, **k))
+    jax.clear_caches()  # the engines of one process share their compiled programs
+    try:
+        eng = ServeEngine(params, cfg, ServeConfig(
+            decode_slots=1, block_size=16, prefill_chunk=64, max_model_len=128,
+            decode_interval=2))
+        eng._kv = jax.device_put(tuple(jnp.full(x.shape, 0.25 + i, x.dtype)
+                                       for i, x in enumerate(eng._kv)))
+        for i, (prompt, n) in enumerate(requests):
+            eng.submit(prompt, n, req_id=i)
+        while eng.sched.has_work():
+            eng.step(0.0)
+        eng.close()
+    finally:
+        jax.clear_caches()  # no later engine may meet the programs traced here
+    assert eng.pool.in_use == 0
+    # traced once a mixer and rung (the dense stack's one, the expert stack's
+    # two), with the decay a channel: [rows, 64, heads, d_k]
+    assert calls == [(1, 64, 2, 128)] * 3
+    held_to_the_reference(params, cfg, requests, sorted(eng.results, key=lambda r: r["id"]))
 
 
 def test_the_cache_pairs_a_state_pool_with_a_latent_pool():
