@@ -404,6 +404,47 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         moe_scoring="sigmoid", routed_scaling_factor=2.446,
         moe_selection_bias=True, router_aux_coef=0.0,
     ),
+    # NVIDIA-Nemotron-3-Super-120B-A12B
+    # (https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json,
+    # model_type nemotron_h). 88 layers that are ONE sublayer each behind one
+    # norm, x + f(norm(x)), a letter of hybrid_override_pattern a layer: 40
+    # `M`, a Mamba-2 mixer (ops/ssd.py: 128 heads of 64 channels, a float32
+    # state of 64 x 128 a head decayed by one scalar a head and step, B and C
+    # of 8 groups, a causal depthwise convolution of 4 with bias over [x | B
+    # | C], the output gated BEFORE a norm over each group's 1,024 channels);
+    # 8 `*`, an attention of 32 query heads over 2 K/V heads of 128 with no
+    # rotation and no position term of any kind; 40 `E`, LatentMoE: sigmoid
+    # scores over 512 experts, the 22 largest of score + bias, renormalised
+    # and scaled by 5, the experts NOT gated (relu(W1 l)^2 through W2) on a
+    # latent of 1,024 (W_dn, W_up around them), beside one shared expert of
+    # 5,376 on the full width; untied 131,072-row head. Built: forward(),
+    # generate() and ServeEngine, on one device. Assumed, with no key in
+    # config.json (benchmark/reference_nemotron_h.py repeats the list): the
+    # split order [z | x B C | dt], the gate before the grouped norm, no
+    # clamp on the step, A_log = log U(1, 16) a head and dt_bias the inverse
+    # softplus of a step log-uniform in [time_step_min, time_step_max] at
+    # init, D = 1, the shared expert on the full width and the routed ones
+    # on the latent (the published parameter count decides it), no drafting
+    # module (num_nextn_predict_layers 1: ROADMAP M8).
+    "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B": dict(
+        vocab_size=131072, hidden_size=4096, intermediate_size=2688,
+        num_hidden_layers=88, num_attention_heads=32, num_key_value_heads=2,
+        head_dim=128, max_position_embeddings=262144, rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        layer_types=tuple(
+            {"M": "mamba2", "*": "full_attention", "E": "experts"}[c]
+            for c in "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                     "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        rope_parameters=dict(full_attention=dict(rope_type="none")),
+        hidden_act="relu2",
+        mamba_num_heads=128, mamba_head_dim=64, n_groups=8,
+        ssm_state_size=128, mamba_d_conv=4, mamba_conv_bias=True,
+        num_experts=512, num_experts_per_token=22,
+        moe_intermediate_size=2688, moe_latent_size=1024, n_shared_experts=1,
+        moe_shared_expert_intermediate_size=5376, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=5.0,
+        moe_selection_bias=True, router_aux_coef=0.0,
+    ),
     # Tiny debug model for tests / CI
     "picotron-tpu/debug-tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -577,6 +618,29 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         moe_scoring="sigmoid", routed_scaling_factor=2.446,
         moe_selection_bias=True, router_aux_coef=0.0,
     ),
+    # Tiny Nemotron-H-shaped debug model: layers of one sublayer each, two
+    # periods of (*, E, M, E, M) and (*, E) left over (12 layers: 3
+    # attentions, 5 expert layers, 4 mixers); the mixer at 8 heads of 16
+    # channels in 2 groups with a state of 32 (a tail of 3 x 256 numbers);
+    # 16 non-gated experts 4 a token on a latent of 32 beside one shared
+    # expert of 48, sigmoid scores with a selection bias, scaled by 5.
+    # Served with block_size 4.
+    "picotron-tpu/debug-tiny-nemotron-h": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=12, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        layer_types=("full_attention", "experts", "mamba2", "experts",
+                     "mamba2") * 2 + ("full_attention", "experts"),
+        rope_parameters=dict(full_attention=dict(rope_type="none")),
+        hidden_act="relu2",
+        mamba_num_heads=8, mamba_head_dim=16, n_groups=2, ssm_state_size=32,
+        mamba_d_conv=4, mamba_conv_bias=True,
+        num_experts=16, num_experts_per_token=4, moe_intermediate_size=24,
+        moe_latent_size=32, n_shared_experts=1,
+        moe_shared_expert_intermediate_size=48, norm_topk_prob=True,
+        moe_scoring="sigmoid", routed_scaling_factor=5.0,
+        moe_selection_bias=True, router_aux_coef=0.0,
+    ),
 }
 
 # Aliases so shorthand names in configs resolve too.
@@ -621,6 +685,9 @@ _PRESET_ALIASES = {
     "debug-tiny-jamba": "picotron-tpu/debug-tiny-jamba",
     "Kimi-Linear-48B-A3B-Instruct": "moonshotai/Kimi-Linear-48B-A3B-Instruct",
     "debug-tiny-kimi-linear": "picotron-tpu/debug-tiny-kimi-linear",
+    "NVIDIA-Nemotron-3-Super-120B-A12B":
+        "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B",
+    "debug-tiny-nemotron-h": "picotron-tpu/debug-tiny-nemotron-h",
 }
 
 
@@ -641,11 +708,131 @@ def resolve_hf_name(name: str) -> str:
     return _PRESET_ALIASES.get(name, name)
 
 
+# Nemotron-H's config.json: what the reader below does with each key. A key
+# in neither set is REFUSED by name: `_filter_kwargs` ignores unknown keys on
+# load, which is right for a dumped config of an older run and wrong for a
+# published architecture, where a key this reader does not know (as
+# `moe_latent_size` once was) would silently build another model (experts on
+# the full width, 450 B parameters for 120 B).
+_NEMOTRON_H_READ = frozenset((
+    "vocab_size", "hidden_size", "num_hidden_layers",
+    "hybrid_override_pattern", "num_attention_heads", "num_key_value_heads",
+    "head_dim", "max_position_embeddings", "rope_theta", "layer_norm_epsilon",
+    "norm_eps", "tie_word_embeddings", "mamba_num_heads", "mamba_head_dim",
+    "n_groups", "ssm_state_size", "conv_kernel", "use_conv_bias", "expand",
+    "mlp_hidden_act", "mamba_hidden_act", "n_routed_experts",
+    "num_experts_per_tok", "moe_intermediate_size", "moe_latent_size",
+    "moe_shared_expert_intermediate_size", "n_shared_experts",
+    "norm_topk_prob", "routed_scaling_factor", "n_group", "topk_group",
+    "attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias",
+    "residual_in_fp32", "sliding_window", "intermediate_size"))
+# read by nothing: the SSD chunk (an implementation's own), the rotation keys
+# (the modelling code applies none: the attention is position-free), the
+# initialiser's ranges, kernel switches, the drafting module (not built:
+# ROADMAP M8) and a checkpoint's bookkeeping
+_NEMOTRON_H_UNUSED = frozenset((
+    "model_type", "chunk_size", "partial_rotary_factor", "num_logits_to_keep",
+    "rescale_prenorm_residual", "time_step_floor", "time_step_max",
+    "time_step_min", "time_step_limit", "use_mamba_kernels",
+    "moe_shared_expert_overlap", "mtp_hybrid_override_pattern",
+    "num_nextn_predict_layers", "initializer_range", "torch_dtype", "dtype",
+    "architectures", "transformers_version", "bos_token_id", "eos_token_id",
+    "pad_token_id", "use_cache", "attention_dropout", "hidden_dropout",
+    "auto_map", "rope_scaling", "mamba_ssm_cache_dtype"))
+
+
+def _nemotron_h_kwargs(hf: dict[str, Any]) -> dict[str, Any]:
+    """ModelConfig kwargs of a `nemotron_h` config.json: layers that are ONE
+    sublayer each, a letter of `hybrid_override_pattern` a layer (`M` a
+    Mamba-2 mixer, `*` a softmax attention without rotation, `E` a LatentMoE
+    expert block; `-`, a dense MLP layer, is not built), the mixer's and the
+    experts' sizes under the HF names. A key the reader does not know is
+    refused by name (the comment at `_NEMOTRON_H_READ`)."""
+    unknown = sorted(set(hf) - _NEMOTRON_H_READ - _NEMOTRON_H_UNUSED)
+    if unknown:
+        raise ValueError(
+            f"nemotron_h: config key(s) {unknown} are not known to this "
+            f"reader; an unknown key of a published architecture is refused, "
+            f"not ignored (it may change what a layer is)")
+    letters = {"M": SSD, "*": "full_attention", "E": MOE}
+    pattern = hf["hybrid_override_pattern"]
+    bad = sorted(set(pattern) - set(letters))
+    if bad:
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern holds {bad}; built are "
+            f"'M' (Mamba-2 mixer), '*' (attention) and 'E' (experts); a "
+            f"dense MLP layer ('-') is not")
+    if len(pattern) != hf["num_hidden_layers"]:
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern names {len(pattern)} "
+            f"layers, num_hidden_layers is {hf['num_hidden_layers']}")
+    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("use_bias", False), ("mamba_proj_bias", False),
+                      ("residual_in_fp32", False), ("sliding_window", None)):
+        if hf.get(key, want) != want:
+            raise ValueError(
+                f"nemotron_h with {key} = {hf[key]!r}: only {want!r} is built")
+    heads, p = int(hf["mamba_num_heads"]), int(hf["mamba_head_dim"])
+    if "expand" in hf and int(hf["expand"]) * hf["hidden_size"] != heads * p:
+        raise ValueError(
+            f"nemotron_h: expand x hidden_size ({hf['expand']} x "
+            f"{hf['hidden_size']}) is not mamba_num_heads x mamba_head_dim "
+            f"({heads} x {p})")
+    n_heads = hf["num_attention_heads"]
+    out: dict[str, Any] = {
+        "vocab_size": hf["vocab_size"], "hidden_size": hf["hidden_size"],
+        # a dense MLP layer's width: no layer of a built pattern reads it
+        "intermediate_size": hf.get("intermediate_size",
+                                    hf.get("moe_intermediate_size", 0)),
+        "num_hidden_layers": hf["num_hidden_layers"],
+        "num_attention_heads": n_heads,
+        "num_key_value_heads": hf.get("num_key_value_heads", n_heads),
+        "head_dim": hf.get("head_dim", hf["hidden_size"] // n_heads),
+        "max_position_embeddings": hf.get("max_position_embeddings", 2048),
+        "rope_theta": float(hf.get("rope_theta", 10000.0)),
+        "rms_norm_eps": float(hf.get("layer_norm_epsilon",
+                                     hf.get("norm_eps", 1e-5))),
+        "tie_word_embeddings": bool(hf.get("tie_word_embeddings", False)),
+        "layer_types": tuple(letters[c] for c in pattern),
+        # position-free attention: the mixers order the sequence
+        "rope_parameters": {"full_attention": {"rope_type": "none"}},
+        "hidden_act": "relu2",
+        "mamba_num_heads": heads, "mamba_head_dim": p,
+        "n_groups": int(hf["n_groups"]),
+        "ssm_state_size": int(hf["ssm_state_size"]),
+        "mamba_d_conv": int(hf["conv_kernel"]),
+        "mamba_conv_bias": bool(hf.get("use_conv_bias", True)),
+    }
+    if "E" in pattern:
+        shared = int(hf.get("n_shared_experts", 0))
+        if shared not in (0, 1):
+            raise ValueError(
+                f"nemotron_h with n_shared_experts = {shared}: one shared "
+                f"expert of moe_shared_expert_intermediate_size, or none")
+        out.update({
+            "num_experts": int(hf["n_routed_experts"]),
+            "num_experts_per_token": int(hf["num_experts_per_tok"]),
+            "moe_intermediate_size": int(hf["moe_intermediate_size"]),
+            "moe_latent_size": int(hf.get("moe_latent_size") or 0),
+            "n_shared_experts": shared,
+            "moe_shared_expert_intermediate_size": int(
+                hf["moe_shared_expert_intermediate_size"]) if shared else 0,
+            "norm_topk_prob": bool(hf.get("norm_topk_prob", True)),
+            "routed_scaling_factor": float(
+                hf.get("routed_scaling_factor", 1.0)),
+            "moe_scoring": "sigmoid", "moe_selection_bias": True,
+            "router_aux_coef": 0.0,
+        })
+    return out
+
+
 def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
     """ModelConfig kwargs from a local HF `config.json` — the OFFLINE
     equivalent of the reference's network AutoConfig fetch
     (ref: create_config.py:51-55): any Llama/Qwen2/Mixtral/OLMoE/Mellum/
-    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next/Jamba/Kimi-Linear-family model outside the preset registry
+    Pangu-Ultra-MoE/EXAONE-MoE/EvaByte/LongCat-Flash/Qwen3-Next/Jamba/Kimi-Linear/Nemotron-H-family model outside the preset registry
     resolves from its config file instead of hand-typed hyperparameters.
     Pass a path or an already-parsed dict."""
     if isinstance(path_or_dict, dict):
@@ -660,7 +847,7 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
         "longcat_flash" if "zero_expert_num" in hf else "llama")
     supported = ("llama", "mistral", "mixtral", "qwen2", "olmoe", "mellum",
                  "pangu_ultra_moe", "exaone_moe", "evabyte", "longcat_flash",
-                 "qwen3_next", "jamba", "kimi_linear")
+                 "qwen3_next", "jamba", "kimi_linear", "nemotron_h")
     if mtype not in supported:
         raise ValueError(
             f"model_type {mtype!r} is not a supported architecture family "
@@ -680,6 +867,8 @@ def model_config_from_hf_json(path_or_dict) -> dict[str, Any]:
                 f"dense Jamba)")
         hf = {k: v for k, v in hf.items()
               if k not in ("num_experts", "num_experts_per_tok")}
+    if mtype == "nemotron_h":
+        return _nemotron_h_kwargs(hf)
     if mtype == "longcat_flash":
         # its names for the depth, the two widths and the experts a token
         hf = {**hf, "num_hidden_layers": hf["num_layers"],
@@ -1122,14 +1311,24 @@ def parse_cp_mesh(spec: str) -> tuple[int, int]:
 GDN = "linear_attention"  # the kind of a layer that is a Gated DeltaNet mixer
 SSM = "mamba"  # the kind of a layer that is a Mamba-1 selective-scan mixer
 KDA = "kda"  # the kind of a layer that is a Kimi Delta Attention mixer
+# the kind of a layer that is a Mamba-2 (SSD) mixer ALONE, with no MLP behind
+# it (a model whose layers are one sublayer each: `ModelConfig.single_sublayer`)
+SSD = "mamba2"
+# the kind of a layer that is an expert block ALONE, with no mixer before it
+MOE = "experts"
 # the kinds whose mixer carries a state a SEQUENCE, not a row a position
-RECURRENT = (GDN, SSM, KDA)
+RECURRENT = (GDN, SSM, KDA, SSD)
 
 
 class Block(NamedTuple):
     """What one decoder block is made of: the one description that the
     training forward (`models.llama.decoder_layer`) and the cached decode
-    forward (`generate._decode_layers`) both read."""
+    forward (`generate._decode_layers`) both read. A block is a mixer
+    FOLLOWED BY an MLP, each behind a norm of its own, unless it is `alone`:
+    then a layer is ONE sublayer behind ONE norm, `x + f(norm(x))`, and the
+    layer's kind (`Stack.kinds`) says which: the softmax attention
+    ("full_attention"), a Mamba-2 mixer ("mamba2") or the experts
+    ("experts"); `attn` and `mlp` then say what those are made of."""
 
     # "gqa": q/k/v per head | "mla": latent attention | "eva": q/k/v per
     # head over the open window's keys and a summary a chunk of the rest.
@@ -1146,6 +1345,7 @@ class Block(NamedTuple):
     # the stack has a sublayer axis behind the layer axis)
     mlp: str
     sandwich: bool  # RMSNorms on the attention's and the MLP's outputs too
+    alone: bool = False  # a layer is ONE sublayer, named by its kind
 
     @property
     def attentions(self) -> int:
@@ -1234,7 +1434,8 @@ class ModelConfig:
     # (EXACT erf GELU — transformers' "gelu"), or "gelu_tanh" (the tanh
     # approximation — transformers' "gelu_pytorch_tanh"/"gelu_new",
     # the Gemma-style GeGLU) — widens the --from-hf-config long tail
-    # beyond pure-SwiGLU families.
+    # beyond pure-SwiGLU families. "relu2": NOT gated, down(relu(up x)^2),
+    # two matrices an MLP (the Nemotron-H experts; `mlp_gated`).
     hidden_act: str = "silu"
     dtype: str = "bfloat16"  # compute/activation dtype; master params are fp32
     # Attention implementation: "auto" picks flash on TPU / reference on CPU;
@@ -1387,6 +1588,25 @@ class ModelConfig:
     mamba_dt_rank: int = 0
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    # The Mamba-2 (SSD) mixer of a "mamba2" layer (ops/ssd.py), the published
+    # keys: heads of `mamba_head_dim` channels (d_inner = heads x head_dim:
+    # `ssd_inner`), each with a float32 state [head_dim, ssm_state_size]
+    # decayed by ONE scalar a head and step; B and C come a GROUP of heads
+    # (`n_groups`); the convolution's kernel and bias are `mamba_d_conv` /
+    # `mamba_conv_bias`, over [x | B | C] (`ssd_channels`).
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 0
+    ssm_state_size: int = 0
+    # LatentMoE: the routed experts read and write a latent of this width,
+    # between a down projection of the token and an up projection of their
+    # weighted sum (W_dn [hidden, latent], W_up [latent, hidden]); the router
+    # and the shared expert stay on the full width. 0: the experts are
+    # hidden_size wide.
+    moe_latent_size: int = 0
+    # The width of the one shared expert where the model publishes it (0:
+    # n_shared_experts x the routed experts' width).
+    moe_shared_expert_intermediate_size: int = 0
     # Accepted for reference compat (ref uses them to pick CUDA kernels).
     use_flash_attention: bool = True
     use_fused_adam: bool = True
@@ -1483,6 +1703,46 @@ class ModelConfig:
         return KDA in self.layer_kinds
 
     @property
+    def ssd(self) -> bool:
+        """Whether some layer is a Mamba-2 (SSD) mixer."""
+        return SSD in self.layer_kinds
+
+    @property
+    def single_sublayer(self) -> bool:
+        """Whether a layer is ONE sublayer behind one norm (a mixer alone or
+        an MLP alone: `Block.alone`), which a layer kind of "mamba2" or
+        "experts" says of the whole model."""
+        return SSD in self.layer_kinds or MOE in self.layer_kinds
+
+    @property
+    def mlp_gated(self) -> bool:
+        """Whether an MLP is gated, act(gate x) * (up x), three matrices; a
+        "relu2" MLP is down(relu(up x)^2), two."""
+        return self.hidden_act != "relu2"
+
+    @property
+    def ssd_inner(self) -> int:
+        """d_inner of a Mamba-2 mixer: heads x head_dim."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssd_channels(self) -> int:
+        """The channels a Mamba-2 mixer's convolution runs over: [x | B | C]."""
+        return self.ssd_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def expert_in_size(self) -> int:
+        """The width a routed expert reads and writes: the latent's, or the
+        residual stream's."""
+        return self.moe_latent_size or self.hidden_size
+
+    @property
+    def shared_ffn_size(self) -> int:
+        """The shared expert's width (0: none)."""
+        return (self.moe_shared_expert_intermediate_size
+                or self.n_shared_experts * self.expert_ffn_size)
+
+    @property
     def ssm_inner(self) -> int:
         """d_inner: the channels of a Mamba mixer, each with a state of
         mamba_d_state."""
@@ -1491,9 +1751,9 @@ class ModelConfig:
     @property
     def recurrent_layers(self) -> int:
         """The layers whose mixer carries a state a sequence (a Gated
-        DeltaNet, a Mamba or a Kimi Delta Attention mixer): a model with
-        some is cached in a state pool beside the attentions' K/V (or their
-        latents)."""
+        DeltaNet, a Mamba, a Kimi Delta Attention or a Mamba-2 mixer): a
+        model with some is cached in a state pool beside the attentions' K/V
+        (or their latents)."""
         return sum(k in RECURRENT for k in self.layer_kinds)
 
     @property
@@ -1524,9 +1784,11 @@ class ModelConfig:
     @property
     def attention_sublayers(self) -> int:
         """Attention sublayers of the model, one cache row each: the
-        leading axis of a latent cache (a recurrent mixer has none)."""
-        return sum(st.layers * st.block.attentions
-                   for st in self.stacks) - self.recurrent_layers
+        leading axis of a latent cache, or the layer axis of a hybrid's K/V
+        pool (a recurrent mixer has none, nor has a layer that is the
+        experts alone)."""
+        return (sum(st.layers * st.block.attentions for st in self.stacks)
+                - self.recurrent_layers - self.layer_kinds.count(MOE))
 
     @property
     def stacks(self) -> tuple:
@@ -1540,6 +1802,9 @@ class ModelConfig:
         attn = ("mla" if self.mla else "eva" if self.eva else "gqa")
         n, k = self.num_hidden_layers, self.first_k_dense_replace
         kinds = self.layer_kinds
+        if self.single_sublayer:
+            return (Stack("layers", n, Block(attn, "experts", False, True),
+                          kinds),)
         if not self.num_experts:
             return (Stack("layers", n,
                           Block(attn, "dense", self.sandwich_norm), kinds),)
@@ -1552,6 +1817,59 @@ class ModelConfig:
                          Block(attn, "dense", self.sandwich_norm),
                          kinds[:k]),) + out
         return out
+
+    def _validate_single_sublayer(self, sizes: tuple) -> None:
+        """A model whose layers are ONE sublayer each (kinds 'mamba2',
+        'experts', 'full_attention'): what is built beside them."""
+        kinds = set(self.layer_types)
+        if kinds - {"full_attention", SSD, MOE}:
+            raise ValueError(
+                f"layers that are one sublayer each are 'mamba2', 'experts' "
+                f"and 'full_attention': {sorted(kinds - {'full_attention', SSD, MOE})} "
+                f"beside them are not built")
+        if self.ssd:
+            if min(sizes) < 1 or self.mamba_d_conv < 2 or (
+                    self.mamba_num_heads % self.n_groups):
+                raise ValueError(
+                    f"layer_types holds mamba2 layers: mamba_num_heads, "
+                    f"mamba_head_dim, n_groups and ssm_state_size must be "
+                    f">= 1, mamba_d_conv >= 2 and the heads a whole number a "
+                    f"group, got {sizes}, mamba_d_conv {self.mamba_d_conv}")
+            if (self.mamba_d_conv - 1) * self.ssd_channels % 128:
+                raise ValueError(
+                    f"layer_types holds mamba2 layers: the convolution's "
+                    f"tail, (mamba_d_conv - 1) x (d_inner + 2 x n_groups x "
+                    f"ssm_state_size) = {(self.mamba_d_conv - 1) * self.ssd_channels} "
+                    f"numbers, must be whole rows of 128 lanes")
+        elif any(sizes):
+            raise ValueError(
+                "mamba_num_heads / mamba_head_dim / n_groups / ssm_state_size "
+                "are a mamba2 layer's: no layer of layer_types is one")
+        if (MOE in kinds) != bool(self.num_experts):
+            raise ValueError(
+                "layer_types holds 'experts' layers exactly when num_experts "
+                "> 0")
+        if (self.mla or self.eva or self.sandwich_norm or self.shortcut_moe
+                or self.first_k_dense_replace or self.zero_experts
+                or self.attention_bias or self.qk_norm
+                or self.attn_output_gate or self.norm_add_unit_offset
+                or self.fp32_skip_add or self.num_pred_heads > 1
+                or self.shared_expert_gate or self.mamba_proj_bias
+                or self.partial_rotary_factor != 1.0):
+            raise ValueError(
+                "layers that are one sublayer each are built with plain "
+                "q/k/v heads, one norm a layer and experts with at most one "
+                "ungated shared expert: latent attention, attention_class "
+                "'eva', sandwich_norm, shortcut_moe, first_k_dense_replace, "
+                "zero_experts, attention_bias, qk_norm, attn_output_gate, "
+                "norm_add_unit_offset, fp32_skip_add, num_pred_heads > 1, "
+                "shared_expert_gate, mamba_proj_bias and "
+                "partial_rotary_factor < 1 must be unset")
+        if self.moe_latent_size < 0 or (
+                self.moe_shared_expert_intermediate_size < 0):
+            raise ValueError(
+                "moe_latent_size and moe_shared_expert_intermediate_size "
+                "must be >= 0")
 
     def validate(self) -> None:
         if self.attn_impl not in ("auto", "flash", "reference", "ring",
@@ -1574,12 +1892,13 @@ class ModelConfig:
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"num_hidden_layers is {self.num_hidden_layers}")
             bad = set(self.layer_types) - {"full_attention",
-                                           "sliding_attention", *RECURRENT}
+                                           "sliding_attention", *RECURRENT,
+                                           MOE}
             if bad:
                 raise ValueError(
                     f"layer_types entries must be 'full_attention', "
-                    f"'sliding_attention', 'linear_attention', 'mamba' or "
-                    f"'kda', got {sorted(bad)}")
+                    f"'sliding_attention', 'linear_attention', 'mamba', "
+                    f"'kda', 'mamba2' or 'experts', got {sorted(bad)}")
             if "sliding_attention" in self.layer_types and (
                     not self.sliding_window or self.sliding_window < 1):
                 raise ValueError(
@@ -1669,11 +1988,24 @@ class ModelConfig:
                     "attention_bias, qk_norm, attn_output_gate, "
                     "norm_add_unit_offset, fp32_skip_add and num_pred_heads "
                     "> 1 must be unset")
-        elif any(sizes):
+        elif any(sizes) and not (self.ssd and sizes == (
+                0, self.mamba_d_conv, 0, 0)):
             raise ValueError(
                 "mamba_d_state / mamba_d_conv / mamba_expand / mamba_dt_rank "
                 "are a mamba layer's: set layer_types with them, or none of "
-                "them")
+                "them (a mamba2 layer reads mamba_d_conv alone)")
+        sizes = (self.mamba_num_heads, self.mamba_head_dim, self.n_groups,
+                 self.ssm_state_size)
+        if self.single_sublayer:
+            self._validate_single_sublayer(sizes)
+        elif any(sizes) or self.moe_latent_size or (
+                self.moe_shared_expert_intermediate_size):
+            raise ValueError(
+                "mamba_num_heads / mamba_head_dim / n_groups / ssm_state_size "
+                "/ moe_latent_size / moe_shared_expert_intermediate_size "
+                "describe a model whose layers are one sublayer each: set "
+                "layer_types of 'mamba2' / 'experts' / 'full_attention' with "
+                "them, or none of them")
         if not 0.0 < self.partial_rotary_factor <= 1.0 or (
                 self.rope_dim % 2):
             raise ValueError(
@@ -1707,15 +2039,21 @@ class ModelConfig:
                 f"vector) or 'head' (over each head), got {self.qk_norm!r}")
         if self.rope_parameters:
             # a recurrent mixer has no positions to rotate: no section
-            missing = (set(self.layer_kinds) - set(RECURRENT)
+            missing = (set(self.layer_kinds) - set(RECURRENT) - {MOE}
                        - set(dict(self.rope_parameters)))
             if missing:
                 raise ValueError(
                     f"rope_parameters has no section for {sorted(missing)}")
-        if self.hidden_act not in ("silu", "gelu", "gelu_tanh"):
+        if self.hidden_act not in ("silu", "gelu", "gelu_tanh", "relu2"):
             raise ValueError(
-                f"hidden_act must be 'silu', 'gelu', or 'gelu_tanh', got "
-                f"{self.hidden_act!r}")
+                f"hidden_act must be 'silu', 'gelu', 'gelu_tanh' or 'relu2', "
+                f"got {self.hidden_act!r}")
+        if not self.mlp_gated and not self.single_sublayer:
+            raise ValueError(
+                "hidden_act 'relu2' (a non-gated MLP of two matrices) is "
+                "built for the experts of a model whose layers are one "
+                "sublayer each (layer_types of 'mamba2' / 'experts' / "
+                "'full_attention'); a dense gated MLP has three")
         if self.mla:
             for key in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"):
                 if getattr(self, key) < 1:
@@ -2597,7 +2935,9 @@ class Config:
 
     def _refuse_window_layers(self) -> None:
         """Sliding-window layers, Gated DeltaNet mixers (linear_attention
-        layers) and Mamba mixers (mamba layers) run on the plain attention of
+        layers), Mamba mixers (mamba layers), Kimi Delta Attention mixers
+        (kda layers) and layers that are one sublayer each (mamba2 and
+        experts layers) run on the plain attention of
         `forward()`, on `generate()` and on `ServeEngine`. Every path
         that has no band, or that slices, shards or copies a stack whose
         layers are all alike, refuses the model by name (ROADMAP M4: the
@@ -2687,6 +3027,10 @@ class Config:
              m.attn_output_gate),
             ("a gated shared expert (shared_expert_gate)",
              m.shared_expert_gate),
+            ("layers that are one sublayer each (mamba2 / experts layers)",
+             m.single_sublayer),
+            ("a non-gated MLP (hidden_act 'relu2')", not m.mlp_gated),
+            ("experts on a latent (moe_latent_size)", m.moe_latent_size > 0),
         ) if on]
         if not what:
             return
@@ -2746,8 +3090,14 @@ def refuse_training(m: ModelConfig) -> None:
     history a layer, kept or recomputed: ROADMAP M9), nor has a Kimi Delta
     Attention mixer's chunked rule (its within-chunk decays are built block
     by block with a loop over the columns of each diagonal block, a form
-    written for the served prefill: ROADMAP M9 (a))."""
+    written for the served prefill: ROADMAP M9 (a)); a Mamba-2 mixer's
+    chunked rule is plain matrix products, but a model of them is layers of
+    one sublayer each with non-gated experts on a latent, for which no train
+    step, sharding rule or router loss was ever run (ROADMAP M9 (c))."""
     what = [name for name, on in (
+        ("mamba2 layers (layers of one sublayer each)", m.ssd),
+        ("experts layers (an expert block alone a layer)",
+         MOE in m.layer_kinds),
         ("kda layers (a delta rule with a decay a channel)", m.kda),
         ("mamba layers (a selective scan)", m.ssm),
         ("attention_class 'eva'", m.eva),
@@ -2760,7 +3110,8 @@ def refuse_training(m: ModelConfig) -> None:
         raise ValueError(
             f"model has {', '.join(what)}, which training does not "
             f"implement (no backward of the selective scan or of the "
-            f"per-channel delta rule at training shapes, no loss over "
+            f"per-channel delta rule at training shapes, no train step over "
+            f"layers of one sublayer each, no loss over "
             f"several prediction heads, no banded "
             f"attention kernel with summary keys and its backward, no router "
             f"loss over zero-compute experts and no update of a selection "
@@ -2831,6 +3182,14 @@ def resolved_cp_mesh(cfg: "Config") -> tuple[int, int]:
 
 
 def _filter_kwargs(cls: type, raw: dict[str, Any]) -> dict[str, Any]:
+    # Unknown keys are ignored on load: a dumped config of a newer or older
+    # run loads. The consequence for a READER of a published architecture is
+    # the opposite rule: `model_config_from_hf_json` hands this function only
+    # names it chose, so a key of the source it does not know never gets here
+    # to be dropped, and the one reader whose family keeps growing keys that
+    # change what a layer IS (`nemotron_h`: `_nemotron_h_kwargs`) refuses an
+    # unknown key by name, so that a config with `moe_latent_size` cannot
+    # silently build full-width experts.
     names = {f.name for f in dataclasses.fields(cls)}
     return {k: v for k, v in raw.items() if k in names}
 
@@ -2946,6 +3305,33 @@ def num_params(m: ModelConfig, active_only: bool = False,
     understate MFU by the head's share."""
     h, i, v, l = m.hidden_size, m.intermediate_size, m.vocab_size, m.num_hidden_layers
     kv = m.num_key_value_heads * m.head_dim
+    if m.single_sublayer:
+        # a layer is one sublayer and one norm: an attention (q, k, v, o), a
+        # Mamba-2 mixer ([z | x B C | dt], the convolution and its bias,
+        # dt_bias, A_log and D a head, the grouped norm, the output
+        # projection) or the experts (router and its bias, the latent's two
+        # projections, the routed experts of two matrices on the latent, the
+        # shared expert of two on the full width)
+        q = m.num_attention_heads * m.head_dim
+        di, c = m.ssd_inner, m.ssd_channels
+        mixer = (h * (di + c + m.mamba_num_heads) + c * m.mamba_d_conv
+                 + (c if m.mamba_conv_bias else 0) + 3 * m.mamba_num_heads
+                 + di + di * h)
+        mats = 3 if m.mlp_gated else 2
+        lat = m.expert_in_size
+        experts = (h * m.router_width
+                   + (m.router_width if m.moe_selection_bias else 0)
+                   + (2 * h * lat if m.moe_latent_size else 0)
+                   + (m.num_experts_per_token if active_only
+                      else m.num_experts) * mats * lat * m.expert_ffn_size
+                   + mats * h * m.shared_ffn_size)
+        kinds = m.layer_kinds
+        layers = (kinds.count("full_attention") * (2 * h * q + 2 * h * kv)
+                  + kinds.count(SSD) * mixer + kinds.count(MOE) * experts
+                  + l * h)
+        head = (h * v if (not m.tie_word_embeddings or include_tied_head)
+                else 0)
+        return v * h + layers + h + head
     dense_ffn = 3 * h * i  # gate/up/down
     if m.num_experts:
         e_ffn = 3 * h * m.expert_ffn_size  # gate/up/down per expert

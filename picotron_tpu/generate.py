@@ -56,12 +56,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from picotron_tpu.config import GDN, KDA, SSM, ModelConfig, pattern_of
+from picotron_tpu.config import (
+    GDN, KDA, MOE, SSD, SSM, ModelConfig, pattern_of,
+)
 from picotron_tpu.models.llama import (
     BRANCH, DEFAULT_CTX, _mlp_block, compute_dtype, conv_from_tail,
-    final_hidden,
+    expert_norm, final_hidden,
     gate_attention, gated_qkv_proj, gdn_mixer, holds, kda_mixer, kind_tables,
-    layer_window, mamba_mixer, mlp_act, model_rope_tables, norm_weight,
+    latent_in, latent_out, layer_window, mamba2_mixer, mamba_mixer, mlp_act,
+    model_rope_tables, norm_weight,
     own_leaf, qkv_proj, recurrent_start, residual_stream, rms_norm,
     served_head, shared_expert,
 )
@@ -73,6 +76,7 @@ from picotron_tpu.ops.mla import TILE_KEYS, latent_attention, mla_project
 from picotron_tpu.ops.moe import moe_mlp_served
 from picotron_tpu.ops.rope import apply_rope, rotate_half
 from picotron_tpu.ops.selective_scan import scan_segment
+from picotron_tpu.ops.ssd import ssd
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -299,6 +303,14 @@ class HybridCache(NamedTuple):
         return y, self._replace(
             state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
 
+    def ssd(self, gi, v, g, b, c, q_pos):
+        """`models.llama.mamba2_mixer`'s recurrence over the segment from
+        Mamba-2 mixer gi's state [B, H, P, N] (zeros at position 0) -> (y
+        [B, s, H, P], the cache with the state after it)."""
+        y, state = ssd(v, g, b, c, self._carried(self.state, gi, q_pos))
+        return y, self._replace(
+            state=lax.dynamic_update_index_in_dim(self.state, state, gi, 0))
+
 
 class HybridLatentCache(NamedTuple):
     """`HybridCache` for a model whose full layers are LATENT attentions
@@ -352,7 +364,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_length: int):
             return HybridLatentCache(jnp.zeros(
                 (cfg.attention_sublayers, batch, max_length,
                  cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt), *carried)
-        shape = (cfg.num_hidden_layers - n_rec, batch, max_length,
+        # (the layers that hold an attention: not the mixers, nor a layer
+        # that is the experts alone)
+        shape = (cfg.attention_sublayers, batch, max_length,
                  cfg.num_key_value_heads, cfg.head_dim)
         return HybridCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
                            *carried)
@@ -503,6 +517,15 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
                 lambda c, *xs: c.conv(ki, *xs, q_pos=q_pos),
                 lambda c, *xs: c.scan(ki, *xs, q_pos=q_pos), cache, live)
 
+    def mamba2(h, cache, lp, li, kind, ki):
+        """A Mamba-2 mixer, against the cache as `mamba` is: the convolution
+        and the recurrence asked of the cache (`conv`, `ssd`)."""
+        with scope("ssd_mixer"):
+            return mamba2_mixer(
+                h, lp, cfg,
+                lambda c, *xs: c.conv(ki, *xs, q_pos=q_pos),
+                lambda c, *xs: c.ssd(ki, *xs, q_pos=q_pos), cache, live)
+
     def eva(h, cache, lp, li, kind, ki):
         """EVA attention (ops/eva.py): K and V written a head as `gqa`
         writes them, and with them the summary of every chunk the segment
@@ -550,7 +573,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         """Norm -> one attention against cache row `li` -> its output."""
         h = rms_norm(x, norm_weight(lp["input_norm"], cfg),
                      cfg.rms_norm_eps).astype(dt)
-        mixer = {GDN: gdn, SSM: mamba, KDA: kda}.get(kind) or {
+        mixer = {GDN: gdn, SSM: mamba, KDA: kda, SSD: mamba2}.get(kind) or {
             "gqa": gqa, "mla": mla, "eva": eva}[block.attn]
         return mixer(h, cache, lp, li, kind, ki)
 
@@ -576,7 +599,12 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         if block.mlp == "shortcut":
             return shortcut_layer(x, cache, lp, banks, block, li, bank_li,
                                   kind, ki)
+        if block.alone and kind == MOE:  # the experts are the layer
+            out, touched = _moe_served_block(x, lp, banks, bank_li, cfg, live)
+            return x + out, cache, touched
         out, cache = attend(x, cache, lp, block, li, kind, ki)
+        if block.alone:  # a mixer is the layer
+            return x + out, cache, None
         if block.sandwich:
             out = rms_norm(out, lp["attn_out_norm"], cfg.rms_norm_eps)
         x = x + out
@@ -613,6 +641,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
         # iteration of every step (tests/test_chip_compile.py weights_written)
         layers = {n: w for n, w in stack.items() if n not in BANKS}
         banks = {n: stack.get(n) for n in BANKS}
+        alone = block.alone
 
         def one(carry, i, kind, ki):
             # layer i of the stack (its place in the stack's leaves and
@@ -622,17 +651,22 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
             def take(w, at):
                 return lax.dynamic_index_in_dim(w, at, 0, keepdims=False)
 
-            if rows == 1 and not cfg.recurrent:
+            bank = i  # the layer's place in the stack's expert banks
+            if rows == 1 and not cfg.recurrent and not alone:
                 at = i
                 lp = jax.tree.map(lambda w: take(w, i), layers)
             elif rows == 1:
                 # a mixer's leaves are stacked over the layers of its kind
                 # alone (`models.llama.holds`): the layer's ordinal among
-                # them in this stack; every other leaf is at `i`
+                # them in this stack; every other leaf is at `i` (in a stack
+                # of layers of one sublayer each every leaf but the norm is
+                # one kind's, the experts' banks too)
                 at = i
                 own = ki - before[kind]
-                lp = {n: take(w, own if own_leaf(n) else i)
-                      for n, w in layers.items() if holds(n, kind)}
+                lp = {n: take(w, own if own_leaf(n, alone) else i)
+                      for n, w in layers.items() if holds(n, kind, alone)}
+                if alone:
+                    bank = own
             else:
                 # each (attention, dense MLP) pair's own view of the layer
                 # (`models.llama.sublayer`), a pair's leaf [L, 2, ...] read
@@ -643,7 +677,7 @@ def _decode_layers(params, x, cache, q_pos, cfg: ModelConfig, cos, sin,
                 lp = tuple({n: (take(w, i) if n in BRANCH else
                                 take(w.reshape(-1, *w.shape[2:]), at + j))
                             for n, w in layers.items()} for j in range(rows))
-            x, cache, t = layer(x, cache, lp, banks, block, row + at, i,
+            x, cache, t = layer(x, cache, lp, banks, block, row + at, bank,
                                 kind, ki)
             return x, cache, touched if t is None else touched + t
 
@@ -694,15 +728,16 @@ def _served_experts(x, lp, banks, li, cfg: ModelConfig, live):
     routed nowhere. `banks`: the stack's whole banks of the experts held
     on this device, of which this is layer `li`. Returns (out, the counts
     `expert_counts` names)."""
-    h = rms_norm(x, norm_weight(lp["post_norm"], cfg), cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(expert_norm(lp), cfg), cfg.rms_norm_eps)
     out, counts = moe_mlp_served(
         h, lp["router"], *(banks[n] for n in BANKS),
         top_k=cfg.num_experts_per_token, act=mlp_act(cfg),
         norm_topk_prob=cfg.norm_topk_prob, live=live, layer=li,
         scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
         expert_first=cfg.expert_first, bias=lp.get("router_bias"),
-        zero=cfg.zero_experts)
-    if "shared_gate" in lp:
+        zero=cfg.zero_experts, latent=latent_in(h, lp))
+    out = latent_out(out, lp)
+    if "shared_up" in lp:
         out = out + shared_expert(h, lp, cfg)
     return out, counts
 

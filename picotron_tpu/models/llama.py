@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from picotron_tpu.config import (
-    GDN, KDA, RECURRENT, SSM, Block, ModelConfig, pattern_of, refuse_training,
+    GDN, KDA, MOE, RECURRENT, SSD, SSM, Block, ModelConfig, pattern_of,
+    refuse_training,
 )
 from picotron_tpu.ops.attention import sdpa_attention
 from picotron_tpu.ops.eva import chunk_summaries, eva_attention
@@ -50,6 +51,7 @@ from picotron_tpu.ops.mla import mla_project, up_weights
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.rope import apply_rope, rope_tables
 from picotron_tpu.ops.selective_scan import scan_segment, tail_shape
+from picotron_tpu.ops.ssd import ssd
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -74,7 +76,7 @@ def model_rope_tables(cfg, max_len=None):
         return rope_tables(n, cfg.rope_dim, cfg.rope_theta,
                            rope_scaling=cfg.rope_scaling_dict)
     pairs = {}
-    for kind in sorted(set(cfg.layer_kinds) - set(RECURRENT)):
+    for kind in sorted(set(cfg.layer_kinds) - set(RECURRENT) - {MOE}):
         theta, scaling = cfg.rope_law(kind)
         pairs[kind] = rope_tables(n, cfg.head_dim, theta,
                                   rope_scaling=scaling)
@@ -112,40 +114,59 @@ def by_period(layer_tree, period: int, whole: int):
 # no layer carries the other kind's matrices. These are the softmax
 # attention's leaves (a latent attention's among them); a Gated DeltaNet
 # mixer's are named `gdn_...`, a Mamba mixer's `ssm_...`, a Kimi Delta
-# Attention mixer's `kda_...`.
-OWN_PREFIX = {GDN: "gdn_", SSM: "ssm_", KDA: "kda_"}
+# Attention mixer's `kda_...`, a Mamba-2 mixer's `ssd_...`. In a stack whose
+# layers are ONE sublayer each (`one_each`: kinds "mamba2" / "experts" /
+# "full_attention") a layer holds its one sublayer's leaves and the one norm
+# in front of it, `input_norm`, which alone is stacked over every layer: the
+# experts' leaves (router, banks, latent projections, shared expert) are
+# stacked over the "experts" layers alone.
+OWN_PREFIX = {GDN: "gdn_", SSM: "ssm_", KDA: "kda_", SSD: "ssd_"}
 ATTENTION_LEAVES = ("q", "k", "v", "o", "q_norm", "k_norm", "b_q", "b_k",
                     "b_v", "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm",
                     "kv_b")
 
 
-def own_leaf(name: str) -> bool:
-    """Whether `name` is a leaf of one kind of mixer: the softmax
-    attention's or a recurrent mixer's."""
+def one_each(kinds) -> bool:
+    """Whether a run of layers of `kinds` is layers of one sublayer each
+    (`config.Block.alone`): a kind of "mamba2" or "experts" says so."""
+    return SSD in kinds or MOE in kinds
+
+
+def own_leaf(name: str, alone: bool = False) -> bool:
+    """Whether `name` is a leaf of one kind of layer, stacked over the
+    layers of that kind alone: the softmax attention's or a recurrent
+    mixer's, and in a stack of layers of one sublayer each (`alone`) every
+    leaf but the one norm."""
     return (name.startswith(tuple(OWN_PREFIX.values()))
-            or name in ATTENTION_LEAVES)
+            or name in ATTENTION_LEAVES or (alone and name != "input_norm"))
 
 
-def holds(name: str, kind: str) -> bool:
+def holds(name: str, kind: str, alone: bool = False) -> bool:
     """Whether a layer of `kind` holds the stack's leaf `name`. Every layer
-    holds every leaf of a stack without recurrent mixers."""
+    holds every leaf of a stack without recurrent mixers; in a stack of
+    layers of one sublayer each (`alone`) only an "experts" layer holds what
+    is neither a mixer's nor the norm."""
     for own, prefix in OWN_PREFIX.items():
         if name.startswith(prefix):
             return kind == own
-    return kind not in RECURRENT or name not in ATTENTION_LEAVES
+    if name in ATTENTION_LEAVES:
+        return kind not in RECURRENT and kind != MOE
+    return not alone or name == "input_norm" or kind == MOE
 
 
 def leaf_row(name: str, kinds: tuple, i: int) -> int:
     """The row of leaf `name` that layer `i` of a run of layers of `kinds`
     reads: the layers before it that hold the leaf (i, where all do)."""
-    return sum(holds(name, k) for k in kinds[:i])
+    alone = one_each(kinds)
+    return sum(holds(name, k, alone) for k in kinds[:i])
 
 
 def layer_leaves(stack, kinds: tuple, i: int):
     """Layer i's leaves out of a stack (or a period of one) of layers of
     `kinds`: each leaf it holds, at the leaf's row for it."""
+    alone = one_each(kinds)
     return {n: w[leaf_row(n, kinds, i)] for n, w in stack.items()
-            if holds(n, kinds[i])}
+            if holds(n, kinds[i], alone)}
 
 Params = dict[str, Any]
 
@@ -268,7 +289,13 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     n_gdn = tuple(kinds).count(GDN)
     n_ssm = tuple(kinds).count(SSM)
     n_kda = tuple(kinds).count(KDA)
-    na = nl - n_gdn - n_ssm - n_kda  # layers with a softmax attention
+    n_ssd = tuple(kinds).count(SSD)
+    n_moe = tuple(kinds).count(MOE)
+    # layers with a softmax attention
+    na = nl - n_gdn - n_ssm - n_kda - n_ssd - n_moe
+    # layers with an MLP: all of them, or the "experts" layers of a stack of
+    # layers of one sublayer each
+    ne = n_moe if block.alone else nl
 
     keys = jax.random.split(key, 14)
     # a layer of two (attention, dense MLP) pairs: every leaf of a pair has
@@ -282,10 +309,9 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
     def paired(k, fan_in, shape, n=nl):
         return stacked(k, fan_in, pair + shape, n)
 
-    layers = {
-        "input_norm": _norm_init(cfg, (nl,) + pair + (h,)),
-        "post_norm": _norm_init(cfg, (nl,) + pair + (h,)),
-    }
+    layers = {"input_norm": _norm_init(cfg, (nl,) + pair + (h,))}
+    if not block.alone:  # (a layer of one sublayer has one norm)
+        layers["post_norm"] = _norm_init(cfg, (nl,) + pair + (h,))
     if block.sandwich:
         # norms on the attention's and the MLP's outputs (`post_norm` is
         # the MLP's input norm, as everywhere)
@@ -317,7 +343,7 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "kv_b": paired(mk[3], rank, (rank, heads * (dn + dv)), na),
             "o": paired(keys[4], heads * dv, (heads * dv, h), na),
         })
-    elif block.attn != "mla":
+    elif block.attn != "mla" and na:
         # a gated attention's q holds each head's query, then its gate
         gated = 2 if cfg.attn_output_gate else 1
         layers.update({
@@ -412,6 +438,33 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
         if cfg.mamba_conv_bias:
             layers["ssm_conv_bias"] = stacked(
                 sk[2], cfg.mamba_d_conv, (di,), n_ssm)
+    if n_ssd:
+        di, c, hm = cfg.ssd_inner, cfg.ssd_channels, cfg.mamba_num_heads
+        mk = jax.random.split(keys[13], 6)
+        # a step's d = softplus(dt + dt_bias) around a draw log-uniform in
+        # [0.001, 0.1] (the Mamba-2 initialiser's time_step_min / max;
+        # dt_bias its inverse softplus) and A = -U(1, 16) a head: a step keeps
+        # exp(d A) of a head's state, 0.37 to 0.999 over the middle nine
+        # tenths of the heads, so a state carries a few to thousands of
+        # positions (a placeholder dt_bias makes a mixer without a memory:
+        # PERF.md section 6, PR 51); D = 1
+        step = jnp.exp(jax.random.uniform(mk[5], (n_ssd, hm), jnp.float32,
+                                          math.log(1e-3), math.log(1e-1)))
+        layers.update({
+            # [z | x B C | dt]: the gate, the convolved channels, the step
+            "ssd_in": stacked(mk[0], h, (h, di + c + hm), n_ssd),
+            "ssd_conv": stacked(mk[1], cfg.mamba_d_conv,
+                                (c, cfg.mamba_d_conv), n_ssd),
+            "ssd_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "ssd_A_log": jnp.log(jax.random.uniform(
+                mk[3], (n_ssd, hm), jnp.float32, 1.0, 16.0)),
+            "ssd_D": jnp.ones((n_ssd, hm), jnp.float32),
+            "ssd_norm": jnp.ones((n_ssd, di), jnp.float32),  # a plain weight
+            "ssd_out": stacked(mk[4], di, (di, h), n_ssd),
+        })
+        if cfg.mamba_conv_bias:
+            layers["ssd_conv_bias"] = stacked(
+                mk[2], cfg.mamba_d_conv, (c,), n_ssd)
     if block.attn == "eva":
         # EVA's pooling vectors, one a KV head: unit normal clamped to
         # [-1, 1] an element, so that a chunk's summary is far from its
@@ -440,31 +493,40 @@ def _init_stack(cfg: ModelConfig, block: Block, nl: int,
             "q_norm": jnp.ones((nl, q_out), jnp.float32),
             "k_norm": jnp.ones((nl, kv_out), jnp.float32),
         })
-    if block.mlp in ("experts", "shortcut"):
+    if block.mlp in ("experts", "shortcut") and ne:
         e, f = cfg.num_experts, cfg.expert_ffn_size
+        w = cfg.expert_in_size  # the experts' own width: the latent's, or h
         layers.update({
             # router (over every expert of the model, held here or not) +
             # per-layer banks of the experts held [L, E, ...] (ops/moe.py)
-            "router": stacked(keys[9], h, (h, cfg.router_width)),
-            "w_gate": stacked(keys[5], h, (e, h, f)),
-            "w_up": stacked(keys[6], h, (e, h, f)),
-            "w_down": stacked(keys[7], f, (e, f, h)),
+            "router": stacked(keys[9], h, (h, cfg.router_width), ne),
+            "w_up": stacked(keys[6], w, (e, w, f), ne),
+            "w_down": stacked(keys[7], f, (e, f, w), ne),
         })
-        if cfg.n_shared_experts:
-            fs = cfg.n_shared_experts * f
+        if cfg.mlp_gated:  # (a "relu2" expert is down(relu(up x)^2))
+            layers["w_gate"] = stacked(keys[5], w, (e, w, f), ne)
+        if cfg.moe_latent_size:
+            lk = jax.random.split(jax.random.fold_in(keys[9], 1), 2)
             layers.update({
-                "shared_gate": stacked(keys[10], h, (h, fs)),
-                "shared_up": stacked(keys[11], h, (h, fs)),
-                "shared_down": stacked(keys[12], fs, (fs, h)),
+                "latent_down": stacked(lk[0], h, (h, w), ne),
+                "latent_up": stacked(lk[1], w, (w, h), ne),
             })
+        if cfg.n_shared_experts:
+            fs = cfg.shared_ffn_size
+            layers.update({
+                "shared_up": stacked(keys[11], h, (h, fs), ne),
+                "shared_down": stacked(keys[12], fs, (fs, h), ne),
+            })
+            if cfg.mlp_gated:
+                layers["shared_gate"] = stacked(keys[10], h, (h, fs), ne)
             if cfg.shared_expert_gate:
                 layers["shared_out_gate"] = stacked(
                     jax.random.fold_in(keys[12], 1), h, (h,))
         if cfg.moe_selection_bias:
             # zeros, as a released checkpoint's buffer starts
-            layers["router_bias"] = jnp.zeros((nl, cfg.router_width),
+            layers["router_bias"] = jnp.zeros((ne, cfg.router_width),
                                               jnp.float32)
-    if block.mlp != "experts":
+    if block.mlp != "experts" and not block.alone:
         dk = jax.random.split(keys[10], 3) if pair else keys[5:8]
         layers.update({
             "gate": paired(dk[0], h, (h, i)),
@@ -942,7 +1004,96 @@ def recurrent_start(cfg: ModelConfig, rows: int):
     """(state, tail) of `rows` sequences before their first position, for
     the model's kind of recurrent mixer: what a cache's pools are shaped
     from."""
-    return (mamba_start if cfg.ssm else gdn_start)(cfg, rows)
+    return (mamba_start if cfg.ssm else mamba2_start if cfg.ssd
+            else gdn_start)(cfg, rows)
+
+
+def grouped_rms_norm(x, w, groups: int, eps: float):
+    """RMSNorm of x [..., C] over each of `groups` equal runs of its
+    channels, times the weight w [C]; float32."""
+    x = x.astype(jnp.float32)
+    g = x.reshape(*x.shape[:-1], groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(x.shape) * w.astype(jnp.float32)
+
+
+def mamba2_mixer(h, lp, cfg: ModelConfig, conv, recur, carry, live):
+    """A Mamba-2 mixer (ops/ssd.py) over a segment of every row. h [B, s,
+    hidden]: the normed block input; live [B, s]: the positions that hold a
+    token, a prefix of each row. What a sequence carries from segment to
+    segment (the matrix state a head and the convolution's tail) is the
+    caller's, `carry`, and so is every step that touches it, as
+    `mamba_mixer`'s are:
+
+    - `conv(carry, x, w, bias, n_valid, moves)`: `mamba_mixer`'s, over the
+      channels [x | B | C];
+    - `recur(carry, v, g, b, c)` (v [B, s, H, P] = d x; g [B, s, H] = d A
+      <= 0; b, c [B, s, G, N], a row a GROUP; float32, g = 0 and v = 0 at a
+      position without a token, which leaves the state as it was) runs the
+      rule over the segment from the state the rows carry and returns (y [B,
+      s, H, P] without the D x term, carry with the state after it).
+
+    `held_conv` / `held_ssd` over a (state, tail) pair where the caller holds
+    one (`_mamba2_block`); a cache's own `conv` / `ssd` where both live in
+    pools (`generate.HybridCache`, `serve.paged_cache.HybridPagedCache`).
+    [z | x B C | dt] come out of ONE projection; the step is d = softplus(dt +
+    dt_bias), not clamped; the output is W_out [groupnorm(y * silu(z)) * w]:
+    the gate BEFORE the norm, the RMS over each group's d_inner / n_groups
+    channels. Where it rounds: as `mamba_mixer` (the projections take the
+    compute dtype and give float32; everything between them is float32). The
+    recurrence and its state and tail traffic stand under `ssd_step` in a
+    decode step and `ssd_chunk` in a longer segment."""
+    dt_ = h.dtype
+    b_, s, _ = h.shape
+    hm, p, grp, n = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+                     cfg.ssm_state_size)
+    di, c = cfg.ssd_inner, cfg.ssd_channels
+    f32 = jnp.float32
+    zxd = jnp.matmul(h, lp["ssd_in"].astype(dt_), preferred_element_type=f32)
+    moves = "ssd_step" if s == 1 else "ssd_chunk"
+    with scope("ssd_conv"):
+        xbc, carry = conv(carry, zxd[..., di:di + c], lp["ssd_conv"],
+                          lp.get("ssd_conv_bias"), jnp.sum(live, axis=1), moves)
+    x = xbc[..., :di].reshape(b_, s, hm, p).astype(f32)
+    bb = xbc[..., di:di + grp * n].reshape(b_, s, grp, n).astype(f32)
+    cc = xbc[..., di + grp * n:].reshape(b_, s, grp, n).astype(f32)
+    step = jax.nn.softplus(zxd[..., di + c:] + lp["ssd_dt_bias"].astype(f32))
+    # a position without a token neither decays nor writes
+    step = jnp.where(live[..., None], step, 0.0)
+    with scope(moves):
+        y, carry = recur(carry, x * step[..., None],
+                         -jnp.exp(lp["ssd_A_log"].astype(f32)) * step, bb, cc)
+    y = y + lp["ssd_D"].astype(f32)[:, None] * x
+    y = y.reshape(b_, s, di) * jax.nn.silu(zxd[..., :di])
+    y = grouped_rms_norm(y, lp["ssd_norm"], grp, cfg.rms_norm_eps)
+    return y.astype(dt_) @ lp["ssd_out"].astype(dt_), carry
+
+
+def held_ssd(carry, v, g, b, c):
+    """`mamba2_mixer`'s recurrence over a (state, tail) pair the caller
+    holds: state [B, H, P, N]."""
+    y, state = ssd(v, g, b, c, carry[0])
+    return y, (state, carry[1])
+
+
+def mamba2_start(cfg: ModelConfig, rows: int):
+    """(state, tail) of `rows` sequences before their first position, both
+    float32: the state [rows, H, P, N], N along the lanes (ops/ssd.py), the
+    tail in rows of 128 lanes (`ops.selective_scan.tail_shape`)."""
+    return (jnp.zeros((rows, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                       cfg.ssm_state_size), jnp.float32),
+            jnp.zeros((rows,) + tail_shape(cfg.ssd_channels, cfg.mamba_d_conv),
+                      jnp.float32))
+
+
+@scope("ssd_mixer")
+def _mamba2_block(x, lp, cfg: ModelConfig):
+    """RMSNorm -> Mamba-2 mixer over whole sequences from a zero state."""
+    h = rms_norm(x, norm_weight(lp["input_norm"], cfg), cfg.rms_norm_eps)
+    out, _ = mamba2_mixer(h, lp, cfg, held_conv, held_ssd,
+                          mamba2_start(cfg, h.shape[0]),
+                          jnp.ones(h.shape[:2], bool))
+    return out
 
 
 @scope("ssm_mixer")
@@ -1055,10 +1206,22 @@ def mlp_act(cfg: ModelConfig):
     lineage, ref: model.py:184-186), exact-erf GeGLU ("gelu" — what
     transformers' ACT2FN "gelu" means), or tanh-approx GeGLU ("gelu_tanh",
     the Gemma-style variant) — shared by the dense MLP, the MoE expert
-    bank, and the decode path so they cannot diverge."""
+    bank, and the decode path so they cannot diverge. "relu2" is NOT a gated
+    activation: the MLP is down(relu(up x)^2), two matrices and no gate
+    branch (`cfg.mlp_gated` false; the expert banks then hold no `w_gate`
+    and the shared expert no `shared_gate`), and this is what stands
+    between them."""
     if cfg.hidden_act == "silu":
         return jax.nn.silu
+    if cfg.hidden_act == "relu2":
+        return relu2
     return _GELU[cfg.hidden_act == "gelu_tanh"]
+
+
+def relu2(x):
+    """relu(x)^2: one object, as `_GELU`'s are (a static argument of the
+    served expert block's jit)."""
+    return jnp.square(jax.nn.relu(x))
 
 
 # one object a variant: the activation is a static argument of the served
@@ -1082,21 +1245,49 @@ def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx):
 
 
 def shared_expert(h, lp, cfg: ModelConfig):
-    """The shared experts' gated MLP over the normed block input h: every
+    """The shared experts' MLP (gated, or of two matrices where the layer
+    has no `shared_gate`) over the normed block input h: every
     token passes through it, with gate 1 or, where the layer has
     `shared_out_gate`, sigmoid of that 1-wide projection of the token. One
     implementation for the training block and the cached decode paths."""
     dt = h.dtype
     with scope("moe_shared"):
-        gate = h @ lp["shared_gate"].astype(dt)
         up = h @ lp["shared_up"].astype(dt)
-        out = (mlp_act(cfg)(gate) * up) @ lp["shared_down"].astype(dt)
+        if "shared_gate" in lp:
+            up = mlp_act(cfg)(h @ lp["shared_gate"].astype(dt)) * up
+        else:  # not gated (`mlp_act`)
+            up = mlp_act(cfg)(up)
+        out = up @ lp["shared_down"].astype(dt)
         if "shared_out_gate" in lp:
             with scope("moe_shared_gate"):
                 out = out * jax.nn.sigmoid(
                     (h @ lp["shared_out_gate"].astype(dt)).astype(
                         jnp.float32))[..., None].astype(dt)
         return out
+
+
+def expert_norm(lp):
+    """The norm in front of a layer's experts: `post_norm` behind a mixer,
+    the layer's one norm where the experts are the layer."""
+    return lp["post_norm"] if "post_norm" in lp else lp["input_norm"]
+
+
+def latent_in(h, lp):
+    """LatentMoE: what the routed experts read, W_dn h (None where the
+    experts read the token itself). Under `moe_latent`, with `latent_out`."""
+    if "latent_down" not in lp:
+        return None
+    with scope("moe_latent"):
+        return h @ lp["latent_down"].astype(h.dtype)
+
+
+def latent_out(out, lp):
+    """LatentMoE: the routed experts' weighted sum back on the full width,
+    W_up (sum_i g_i E_i(l)); the sum itself where there is no latent."""
+    if "latent_up" not in lp:
+        return out
+    with scope("moe_latent"):
+        return out @ lp["latent_up"].astype(out.dtype)
 
 
 def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
@@ -1107,14 +1298,14 @@ def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
     fraction and the busiest expert's load over the mean."""
     from picotron_tpu.ops.moe import moe_mlp
 
-    h = rms_norm(x, norm_weight(lp["post_norm"], cfg), cfg.rms_norm_eps)
+    h = rms_norm(x, norm_weight(expert_norm(lp), cfg), cfg.rms_norm_eps)
     h = ctx.f(h)
     # every expert on this device (ep = 1): the dropless dispatch; across
     # 'ep' the all_to_all needs the capacity path's fixed shapes
     ep = (jax.lax.psum(1, ctx.moe_ep_axis)
           if ctx.moe_ep_axis is not None else 1)
     out, aux, drop, load = moe_mlp(
-        h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+        h, lp["router"], lp.get("w_gate"), lp["w_up"], lp["w_down"],
         num_experts=cfg.num_experts,
         top_k=cfg.num_experts_per_token,
         capacity_factor=cfg.capacity_factor if ep > 1 else None,
@@ -1127,8 +1318,10 @@ def _experts(x, lp, cfg: ModelConfig, ctx: ParallelCtx, is_real=1.0):
         scoring=cfg.moe_scoring, scale=cfg.routed_scaling_factor,
         expert_first=cfg.expert_first,
         bias=lp.get("router_bias"), zero=cfg.zero_experts,
+        latent=latent_in(h, lp),
     )
-    if "shared_gate" in lp:
+    out = latent_out(out, lp)
+    if "shared_up" in lp:
         out = out + shared_expert(h, lp, cfg)
     # Zero-padded PP layer slots (pad_layers_for_pp) must not contribute
     # router statistics: their all-zero router yields uniform logits whose
@@ -1180,6 +1373,14 @@ def decoder_layer(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
     block = block or cfg.stacks[-1].block
     if block.mlp == "shortcut":
         return _shortcut_layer(x, lp, cfg, ctx, cos, sin, is_real)
+    if block.alone:
+        # ONE sublayer behind one norm, named by the layer's kind
+        if kind == MOE:
+            out, aux = _moe_block(x, lp, cfg, ctx, is_real)
+            return x + out, aux
+        out = (_mamba2_block(x, lp, cfg) if kind == SSD
+               else _attention_block(x, lp, cfg, ctx, cos, sin, kind))
+        return x + out, jnp.zeros(3, jnp.float32)
     if kind == GDN:
         attn_out = _gdn_block(x, lp, cfg)
     elif kind == KDA:

@@ -117,20 +117,25 @@ def group_tiles(counts, tm: int, n_tiles: int):
             n_visits.astype(jnp.int32))
 
 
-def _kernel(tile_expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-            acc_ref, *, act, n_f: int):
+def _kernel(tile_expert_ref, meta_ref, x_ref, *refs, act, n_f: int):
     del tile_expert_ref  # read by the index maps
+    # three banks, or two where the experts are not gated
+    *w_refs, wd_ref, o_ref, acc_ref = refs
     t, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t <= meta_ref[1])  # a tile that holds rows (or tile 0)
     def _visit():
         x = x_ref[...]
         dt = x.dtype  # the compute dtype: a weight stored wider is cast here
-        g = jnp.dot(x, wg_ref[...].astype(dt),
+        g = jnp.dot(x, w_refs[0][...].astype(dt),
                     preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[...].astype(dt),
-                    preferred_element_type=jnp.float32)
-        y = jnp.dot((act(g) * u).astype(dt), wd_ref[...].astype(dt),
+        if len(w_refs) == 2:
+            u = jnp.dot(x, w_refs[1][...].astype(dt),
+                        preferred_element_type=jnp.float32)
+            mid = act(g) * u
+        else:  # not gated: the one bank's product is the activation's input
+            mid = act(g)
+        y = jnp.dot(mid.astype(dt), wd_ref[...].astype(dt),
                     preferred_element_type=jnp.float32)
         if n_f == 1:
             o_ref[...] = y.astype(o_ref.dtype)
@@ -160,21 +165,23 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_visits, layer, *,
     stacks; tile_expert [T] int32; n_visits []: only the first `n_visits`
     tiles are computed (tile 0 always is); layer: a scalar, traced or not.
     Returns [T * tm, H] in xs' dtype; tiles from `max(n_visits, 1)` on are
-    NOT written.
+    NOT written. `w_gate` None: experts that are not gated, `act(x @
+    w_up[l, e]) @ w_down[l, e]`, two banks read.
 
     `interpret=None` compiles the kernel on a TPU backend and runs the
     Pallas interpreter anywhere else (the CPU tests)."""
     if interpret is None:
         interpret = not compiled_kernels_available()
     m, h = xs.shape
-    n_layers, e, _, f = w_gate.shape
+    n_layers, e, _, f = w_up.shape
     n_tiles = tile_expert.shape[0]
-    if m != n_tiles * tm or w_gate.shape != (n_layers, e, h, f) or (
-            w_up.shape != w_gate.shape) or w_down.shape != (n_layers, e, f, h):
+    ins = (w_up,) if w_gate is None else (w_gate, w_up)
+    if m != n_tiles * tm or any(w.shape != (n_layers, e, h, f) for w in ins) or (
+            w_down.shape != (n_layers, e, f, h)):
         raise ValueError(f"rows {xs.shape} in {n_tiles} tiles of {tm} do not "
-                         f"match banks {w_gate.shape} / {w_up.shape} / "
+                         f"match banks {[w.shape for w in ins]} / "
                          f"{w_down.shape}")
-    tf = ffn_tile(h, f, jnp.dtype(w_gate.dtype).itemsize)
+    tf = ffn_tile(h, f, jnp.dtype(w_up.dtype).itemsize)
     n_f = f // tf
     last = jnp.maximum(jnp.asarray(n_visits, jnp.int32) - 1, 0)
     meta = jnp.stack([jnp.asarray(layer, jnp.int32), last])
@@ -196,8 +203,7 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_visits, layer, *,
             num_scalar_prefetch=2,  # the tiles' experts; (layer, last visit)
             grid=(n_tiles, n_f),
             in_specs=[pl.BlockSpec((tm, h), rows),
-                      pl.BlockSpec((None, None, h, tf), up),
-                      pl.BlockSpec((None, None, h, tf), up),
+                      *(pl.BlockSpec((None, None, h, tf), up) for _ in ins),
                       pl.BlockSpec((None, None, tf, h), down)],
             out_specs=pl.BlockSpec((tm, h), rows),
             scratch_shapes=[pltpu.VMEM((tm, h) if n_f > 1 else (8, 128),
@@ -208,4 +214,4 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_visits, layer, *,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="grouped_experts",
-    )(tile_expert, meta, xs, w_gate, w_up, w_down)
+    )(tile_expert, meta, xs, *ins, w_down)

@@ -274,7 +274,8 @@ _assignments_from_sorted.defvjp(_assignments_from_sorted_fwd,
 def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
     """Every assignment through its expert, no capacity: flat [N, H] ->
     [N, H] (gates applied, summed over k). Requires the whole bank
-    [E, H, F] / [E, F, H] on the device."""
+    [E, H, F] / [E, F, H] on the device. `w_gate` None: the experts are not
+    gated, down(act(up x)), two banks."""
     n, h = flat.shape
     k = r.expert_idx.shape[1]
     dt = flat.dtype
@@ -292,19 +293,22 @@ def _dropless_experts(flat, r: Routing, w_gate, w_up, w_down, act):
         # the experts' group sizes: all of `counts`, less the dead rows'
         # group where the router was told of them (route_topk `live`): the
         # rows past the last group are selected away below
-        sizes = r.counts[:w_gate.shape[0]]
-        g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
+        sizes = r.counts[:w_up.shape[0]]
         u = lax.ragged_dot(xs, w_up.astype(dt), sizes)
-        ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), sizes)
+        if w_gate is None:
+            ys = lax.ragged_dot(act(u), w_down.astype(dt), sizes)
+        else:
+            g = lax.ragged_dot(xs, w_gate.astype(dt), sizes)
+            ys = lax.ragged_dot(act(g) * u, w_down.astype(dt), sizes)
     with scope("moe_dispatch"):
         picked = _assignments_from_sorted(ys, row, inv)           # [N, k, H]
-        if r.counts.shape[0] > w_gate.shape[0]:
+        if r.counts.shape[0] > w_up.shape[0]:
             # dead assignments (picks of experts held elsewhere, rows
             # without a token) lie past the last group, where the chip's
             # grouped matmul leaves whatever was there, not zeros (54 x the
             # block's output at 64 of 512 held: PERF.md section 6, PR 51)
             picked = jnp.where(
-                (r.expert_idx < w_gate.shape[0])[..., None], picked, 0)
+                (r.expert_idx < w_up.shape[0])[..., None], picked, 0)
         out = jnp.sum(picked.astype(jnp.float32) * r.gate[..., None], axis=1)
     return out.astype(dt)
 
@@ -328,7 +332,7 @@ def _grouped_experts(flat, r: Routing, live, w_gate, w_up, w_down, act,
     live assignments' rows is never written and never read."""
     n, h = flat.shape
     k = r.expert_idx.shape[1]
-    e = w_gate.shape[1]
+    e = w_up.shape[1]
     dt = flat.dtype
     live = live[:, None] & (r.expert_idx < e)                      # [N, k]
     tm = row_tile(n * k, e)
@@ -373,7 +377,8 @@ def _split_over_a_mesh(w) -> bool:
 def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                    act, norm_topk_prob: bool, live, layer,
                    scoring: str = "softmax", scale: float = 1.0,
-                   expert_first: int = 0, bias=None, zero: int = 0):
+                   expert_first: int = 0, bias=None, zero: int = 0,
+                   latent=None):
     """The routed experts of the decode paths (`generate`, the serve
     programs): `moe_mlp`'s router and dropless mathematics, no loss terms,
     and `live` [B, S] saying which rows carry a token (idle slots and chunk
@@ -408,14 +413,21 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     the live rows' picks that landed on held experts; and all their picks
     (rows x k). With `zero`, counts [6]: then the live rows' picks of
     zero-compute experts, and the live rows none of whose picks read a
-    held bank (all of them zero-compute or held elsewhere)."""
-    b, s, h = x.shape
-    n, e = b * s, w_gate.shape[1]
+    held bank (all of them zero-compute or held elsewhere).
+
+    `latent` [B, S, W]: what the experts read in x's place (LatentMoE: the
+    router scores the token, the experts a W-wide projection of it, banks
+    [L, E, W, F] / [L, E, F, W]); the output is then W wide, and the caller's
+    to project back. `w_gate` None: the experts are not gated, two banks."""
+    b, s, _ = x.shape
+    n, e = b * s, w_up.shape[1]
+    into = x if latent is None else latent
+    h = into.shape[-1]
 
     def block(args):
-        flat, live = args
+        tokens, flat, live = args
         with scope("moe_router"):
-            logits = (flat.astype(jnp.float32)
+            logits = (tokens.astype(jnp.float32)
                       @ router_w.astype(jnp.float32))             # [N, R] fp32
             r = route_topk(logits, top_k, norm_topk_prob=norm_topk_prob,
                            live=live, scoring=scoring, scale=scale,
@@ -426,10 +438,11 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                 picks += [jnp.sum(r.zero_pick & live[:, None]),
                           jnp.sum(live & jnp.all(r.expert_idx >= e, axis=-1))]
             picks = jnp.stack(picks).astype(jnp.int32)
-        if _split_over_a_mesh(w_gate):
+        if _split_over_a_mesh(w_up):
             out = _dropless_experts(
-                flat, r, *(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-                           for w in (w_gate, w_up, w_down)), act)
+                flat, r, *(None if w is None else lax.dynamic_index_in_dim(
+                    w, layer, 0, keepdims=False)
+                    for w in (w_gate, w_up, w_down)), act)
             visits = touched  # the compiler's kernel walks a group once
         else:
             out, visits = _grouped_experts(flat, r, live, w_gate, w_up,
@@ -450,8 +463,9 @@ def moe_mlp_served(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         blocks *= 2
     # (unrolled, not a `lax.map`: loop-invariant banks that ride a while
     # loop's operands were copied whole by the compiler, 3 x 1.9 GB)
-    flat, live = x.reshape(blocks, n // blocks, h), live.reshape(blocks, -1)
-    outs = [block((flat[j], live[j])) for j in range(blocks)]
+    tokens = x.reshape(blocks, n // blocks, -1)
+    flat, live = into.reshape(blocks, n // blocks, h), live.reshape(blocks, -1)
+    outs = [block((tokens[j], flat[j], live[j])) for j in range(blocks)]
     return (jnp.concatenate([o for o, _ in outs]).reshape(b, s, h),
             sum(c for _, c in outs))
 
@@ -477,6 +491,7 @@ def moe_mlp(
     expert_first: int = 0,
     bias=None,
     zero: int = 0,
+    latent=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """MoE feed-forward. x: [B, S, H]; router_w: [H, E]; expert banks
     [E_local, H, F] / [E_local, F, H] (E_local = E/ep under expert
@@ -502,7 +517,9 @@ def moe_mlp(
     dispatch only, and a pick elsewhere adds nothing (`route_topk` held).
     `bias`, `zero`: a selection bias and zero-compute experts, as
     `moe_mlp_served` describes them (a held share in this sense: the
-    router is wider than the banks).
+    router is wider than the banks). `latent` [B, S, W] and `w_gate` None: as
+    `moe_mlp_served` describes them too (the experts read `latent`, the
+    router x; the output is W wide; dropless dispatch only).
 
     Recompute contract: every op here is a deterministic function of
     (x, weights) — fp32 router logits, top_k, the slot cumsum, the
@@ -518,7 +535,7 @@ def moe_mlp(
     n = b * s
     e = num_experts
     ep = lax.psum(1, ep_axis) if ep_axis is not None else 1
-    e_local = w_gate.shape[0]
+    e_local = w_up.shape[0]
     assert e_local * ep == e, (e_local, ep, e)
     flat = x.reshape(n, h)
     with scope("moe_router"):
@@ -536,6 +553,8 @@ def moe_mlp(
 
     if capacity_factor is None:
         assert ep == 1 and e_local == e, "dropless dispatch needs ep = 1"
+        if latent is not None:  # the experts' own input and width
+            flat = latent.reshape(n, -1)
         out = _dropless_experts(flat, r, w_gate, w_up, w_down, act)
         if zero:
             out = add_zero_experts(out, flat, r)
@@ -544,8 +563,10 @@ def moe_mlp(
         # (`counts` includes a held share's picks elsewhere: not drops)
         drop_frac = ((n * top_k - jnp.sum(r.counts)).astype(jnp.float32)
                      / (n * top_k))
-        return out.reshape(b, s, h), aux, drop_frac, load
+        return out.reshape(b, s, -1), aux, drop_frac, load
     assert not share, "a held share of the experts is dropless (ep = 1)"
+    assert latent is None and w_gate is not None, (
+        "experts on a latent, or without a gate, are dropless (ep = 1)")
 
     # Per-device capacity per expert, padded to a lane-friendly multiple.
     cap = int(capacity_factor * top_k * n / e) + 1

@@ -137,6 +137,9 @@ from picotron_tpu.ops.selective_scan import (
     selective_scan_chunk_pooled, selective_scan_step_pooled, ssm_chunk_suits,
     ssm_kernel_suits,
 )
+from picotron_tpu.ops.ssd import (
+    ssd, ssd_chunk_pooled, ssd_chunk_suits, ssd_kernel_suits, ssd_step_pooled,
+)
 from picotron_tpu.serve.scheduler import blocks_for
 from picotron_tpu.telemetry.scopes import scope
 
@@ -1196,6 +1199,27 @@ class HybridPagedCache(NamedTuple):
         y, state = scan_segment(u, dt, b, c, a, self.state_of(gi, q_pos))
         return y, self.put_state(gi, state, q_pos)
 
+    def ssd(self, gi, v, g, b, c, q_pos):
+        """`models.llama.mamba2_mixer`'s recurrence over the segment (v [B,
+        s, H, P]; g [B, s, H]; b, c [B, s, G, N]; q_pos [B, s]) from Mamba-2
+        mixer gi's state [H, P, N] of the rows' slots -> (y [B, s, H, P], the
+        cache with the state after it). A decode step and a prefill chunk
+        that their kernels suit update the pool in place, the rows with a
+        real position and a mapped slot alone (`ops.ssd.ssd_step_pooled`,
+        `ssd_chunk_pooled`); everything else gathers, runs the plain rule and
+        scatters. The convolution before it is `conv`, as a Mamba-1 mixer's."""
+        where = (self.state, gi, self.stables[:, 0],
+                 jnp.any(q_pos >= 0, axis=1), q_pos[:, 0] == 0)
+        if ssd_kernel_suits(v.shape[1], self.state):
+            y, state = ssd_step_pooled(v[:, 0], g[:, 0], b[:, 0], c[:, 0],
+                                       *where)
+            return y[:, None], self._replace(state=state)
+        if ssd_chunk_suits(v.shape[1], b.shape[2], self.state):
+            y, state = ssd_chunk_pooled(v, g, b, c, *where)
+            return y, self._replace(state=state)
+        y, state = ssd(v, g, b, c, self.state_of(gi, q_pos))
+        return y, self.put_state(gi, state, q_pos)
+
     # -- what the serving engine asks (see `PagedKVCache`)
 
     @classmethod
@@ -1257,9 +1281,9 @@ class HybridPagedCache(NamedTuple):
         rung holds: `chunk_rows_batch` ((row, mixer) pairs, a row with a
         request or a pad row) and `chunk_rows_idle` (those of them without
         a real position, which the chunk's kernel skips); for a model of
-        Mamba mixers `scan_tokens` too."""
+        Mamba or Mamba-2 mixers `scan_tokens` too."""
         counts = self._chunk_counts(spans, rows)
-        if cfg.ssm:
+        if cfg.ssm or cfg.ssd:
             # (position, mixer) pairs with a token: what a selective scan
             # runs over, one after the other, whatever the rung pads
             counts["scan_tokens"] = self.state.shape[0] * sum(
@@ -1286,7 +1310,9 @@ def init_hybrid_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     the model's own start state (`models.llama.recurrent_start`)."""
     n_rec = cfg.recurrent_layers
     dt = compute_dtype(cfg)
-    shape = (cfg.num_key_value_heads, cfg.num_hidden_layers - n_rec,
+    # (the layers that hold an attention: a layer's row is its ordinal among
+    # them, and neither a mixer nor a layer that is the experts alone has one)
+    shape = (cfg.num_key_value_heads, cfg.attention_sublayers,
              num_blocks, block_size, cfg.head_dim)
     state, tail = recurrent_start(cfg, num_slots)
     return HybridPagedCache(
@@ -1428,7 +1454,7 @@ def init_serve_cache(cfg: ModelConfig, scfg: ServeConfig, num_slots: int,
             raise ValueError(
                 "a model with linear_attention, kda or mamba layers is "
                 "served from one device: the state pool is not sharded "
-                "(tp = 1)")
+                "(tp = 1; mamba2 layers are held the same way)")
         init = init_hybrid_latent_cache if cfg.mla else init_hybrid_cache
         return init(cfg, num_blocks, bs, num_slots, max_blocks)
     if cfg.mla:  # one pool with no head axis, sized from the latent's width

@@ -63,6 +63,11 @@ SCOPES = (
     "kda_gate",         # inside kda: the two low-rank projections (the decay's, the output gate's) and the decay's softplus
     "kda_state",        # inside kda: every byte of recurrent state a DECODE step moves and the rule itself: on a chip one kernel over the state pool in place (the live rows' matrices alone), else the rows' gather, the rule and the scatter; the tail's gather and scatter in every case
     "kda_chunk",        # inside kda: every byte of recurrent state a longer segment moves (a prefill chunk's, forward()'s) and the chunked per-channel rule: the rows' states and tails out of the pools, the sub-chunks one after the other, the states and tails back
+    "ssd_mixer",        # a Mamba-2 mixer as a whole: the one projection to [z | x B C | dt], convolution, recurrence, D x, the gate, the grouped norm and the output projection
+    "ssd_conv",         # inside ssd_mixer: the causal depthwise convolution with bias over [x | B | C], and the SiLU
+    "ssd_step",         # inside ssd_mixer: every byte of recurrent state a DECODE step moves and the rule itself: on a chip one kernel over the state pool in place (the live rows' matrices alone) and one over the tail pool, else the rows' gather, the rule and the scatter
+    "ssd_chunk",        # inside ssd_mixer: every byte of recurrent state a longer segment moves (a prefill chunk's, forward()'s) and the chunked rule: on a chip one kernel over the state pool in place (the rows with a real position alone), else the rows' gather, the chunked form and the scatter; the tail's gather and scatter in every case
+    "moe_latent",       # inside mlp: LatentMoE's two projections around the routed experts, the token down to the latent and the experts' weighted sum back up
     "eva_summarise",    # EVA attention: the pooling of a chunk's keys and values into its summary row (ops/eva.py); the attention itself is under attention / paged_attention
 )
 

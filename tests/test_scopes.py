@@ -205,6 +205,15 @@ ONE_TABLE = {
     "kda": ("debug-tiny-kimi-linear",
             {"serve_prefill": SERVE | MOE | MLA | {"kda", "kda_conv", "kda_gate", "kda_chunk"},
              "serve_decode": SERVE | MOE | MLA | {"kda", "kda_conv", "kda_gate", "kda_state"}}, 8),
+    # layers of ONE sublayer each: Mamba-2 mixers over a state pool, unrotated
+    # attentions over the K/V pool and non-gated experts on a latent beside a
+    # shared expert: a prefill chunk's recurrence under `ssd_chunk`, a decode
+    # step's under `ssd_step`, the latent's two projections under `moe_latent`
+    "mamba2": ("debug-tiny-nemotron-h",
+               {"serve_prefill": SERVE | MOE | {"ssd_mixer", "ssd_conv", "ssd_chunk", "attn_full",
+                                                "moe_shared", "moe_latent"},
+                "serve_decode": SERVE | MOE | {"ssd_mixer", "ssd_conv", "ssd_step", "attn_full",
+                                               "moe_shared", "moe_latent"}}, 8),
 }
 
 
@@ -225,7 +234,11 @@ def test_latent_and_eva_model_serve_program_scopes(model, program):
     and the recurrence's scope of that program inside it (`ssm_*.serve.json`).
     A model of Kimi Delta Attention mixers and latent attentions: `kda` with
     `kda_conv`, `kda_gate` and the recurrence's scope of that program inside it
-    (`kda_*.serve.json`) beside the latent scopes."""
+    (`kda_*.serve.json`) beside the latent scopes. A model whose layers are one
+    sublayer each (Mamba-2 mixers, attentions, experts on a latent):
+    `ssd_mixer` with `ssd_conv` and the recurrence's scope of that program
+    inside it (`ssd_*.serve.json`), `moe_latent` (`moe_latent_ms.serve.json`)
+    beside the expert scopes."""
     preset, want, chunk = ONE_TABLE[model]
     want = want[program] if isinstance(want, dict) else want
     mcfg = ModelConfig(dtype="float32", **resolve_preset(preset))
