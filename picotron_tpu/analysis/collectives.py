@@ -41,6 +41,7 @@ from typing import Optional
 import jax
 
 from picotron_tpu.analysis.report import ERROR, INFO, Report
+from picotron_tpu.parallel.fused_bwd import resolved_grad_engine
 
 CHECK = "collectives"
 
@@ -66,22 +67,6 @@ _RE_HLO_IOTA = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _RE_HLO_PAIRS = re.compile(r"source_target_pairs=\{([^}]*)\}")
 _RE_HLO_SHAPE = re.compile(r"=\s*([a-z]+[0-9]+|pred)\[([0-9,]*)\]")
-
-
-def resolved_grad_engine(cfg) -> str:
-    """The grad engine the step actually compiles ('fused'/'ad'/'1f1b'),
-    resolving 'auto' exactly like parallel/api.py's _device_grads."""
-    from picotron_tpu.parallel.fused_bwd import fused_bwd_supported
-
-    if cfg.distributed.pp_size > 1:
-        return cfg.distributed.pp_engine
-    t = cfg.training
-    if (t.grad_engine == "fused"
-            or (t.grad_engine == "auto"
-                and t.gradient_accumulation_steps > 1
-                and fused_bwd_supported(cfg))):
-        return "fused"
-    return "ad"
 
 
 @dataclass(frozen=True)

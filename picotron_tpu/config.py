@@ -2244,17 +2244,23 @@ class TrainingConfig:
     # "dots_norms" additionally saves RMSNorm outputs (~2 activations/layer
     # more HBM, less backward recompute).
     remat_policy: str = "dots"
-    # Gradient engine for the non-pipeline microbatch loop: "ad"
-    # differentiates each microbatch and tree-adds into the fp32
-    # accumulator (one whole-tree temp write + one whole-tree add per
-    # microbatch — measured 26 ms of serialized roofline HBM traffic per
-    # microbatch at SmolLM-1.7B, PERF.md r5); "fused" runs the manual
-    # backward layer scan (parallel/fused_bwd.py) that accumulates each
-    # layer's dW in-scan, eliminating both passes. "auto" picks "fused"
-    # whenever it is supported (any single-pipeline-stage layout —
-    # dp/tp/SP/cp ring|ulysses/ep/MoE — under remat dots_attn; see the
-    # README eligibility matrix) and gradient accumulation is in play.
-    # Numerics match the AD engine (pinned by tests/test_fused_bwd.py).
+    # Gradient engine of the microbatch loop and of the 1F1B tick's
+    # backward unit: "ad" differentiates each microbatch (each tick's
+    # layer block) and tree-adds into the fp32 accumulator (one whole-tree
+    # temp write + one whole-tree add per microbatch — measured 26 ms of
+    # serialized roofline HBM traffic per microbatch at SmolLM-1.7B,
+    # PERF.md r5); "fused" runs the manual backward layer scan
+    # (parallel/fused_bwd.py) that accumulates each layer's dW in-scan,
+    # eliminating both passes. "auto" picks "fused" whenever it is
+    # supported and gradients accumulate: under remat dots_attn, any
+    # single-pipeline-stage layout (dp/tp/SP/cp ring|ulysses/ep/MoE) over
+    # more than one microbatch, and at pp > 1 the spmd executor's 1F1B
+    # engine over the same axes (PR 63; MoE only where the layers split
+    # evenly over the stages); AFAB and the MPMD executor run "ad". One
+    # predicate says
+    # which: parallel/fused_bwd.py resolved_grad_engine (see the README
+    # eligibility matrix). Numerics match the AD engine (pinned by
+    # tests/test_fused_bwd.py and tests/test_pp_engines.py).
     grad_engine: str = "auto"
 
 
@@ -2795,13 +2801,16 @@ class Config:
 
             if not fused_bwd_supported(self):
                 raise ValueError(
-                    "grad_engine='fused' requires a single pipeline stage "
-                    "(pp_size=1) and remat with remat_policy='dots_attn' "
-                    "— the save set the manual backward is derived from. "
+                    "grad_engine='fused' requires remat with "
+                    "remat_policy='dots_attn' — the save set the manual "
+                    "backward is derived from — and a layout that runs "
+                    "it: a single pipeline stage (pp_size=1, where "
                     "dp/tp/sequence_parallel/cp (ring and ulysses)/ep/MoE "
-                    "all compose (see the README grad-engine eligibility "
-                    "matrix); use 'auto' to fall back to the AD engine "
-                    "automatically")
+                    "all compose), or pp_engine='1f1b' under the spmd "
+                    "executor (MoE only where the layers split evenly "
+                    "over the stages; see the README grad-engine "
+                    "eligibility matrix); use 'auto' to fall back to the "
+                    "AD engine automatically")
         if t.optimizer_offload:
             # zero1 COMPOSES with offload (r5): the host master/moments
             # shard over the fused data axes, each process streams 1/dp

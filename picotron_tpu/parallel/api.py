@@ -327,7 +327,7 @@ def _device_grads(params, batch, cfg: Config):
         # dispatches to the pipeline engines the same way).
         from picotron_tpu.parallel.pp import (
             pipeline_1f1b_grads, pipeline_loss_sum_count,
-            sync_pp_replicated_grads, sync_sp_partial_grads,
+            sync_pp_replicated_grads,
         )
 
         if cfg.distributed.pp_engine == "1f1b":
@@ -344,8 +344,6 @@ def _device_grads(params, batch, cfg: Config):
             (nll_total, (count, dropw)), grads = jax.value_and_grad(
                 pp_nll, has_aux=True)(params)
         grads = sync_pp_replicated_grads(grads, param_specs(cfg))
-        if cfg.distributed.sequence_parallel:
-            grads = sync_sp_partial_grads(grads, params)
         grads = _data_axes_psum(grads, cfg)
         nll_total = lax.psum(nll_total, ("dp", "ep", "cp"))
         dropw = lax.psum(dropw, ("dp", "ep", "cp"))
@@ -353,14 +351,10 @@ def _device_grads(params, batch, cfg: Config):
         return _finish_grads(grads, nll_total, count, dropw, cfg)
 
     from picotron_tpu.parallel.fused_bwd import (
-        fused_bwd_supported, fused_micro_grads,
+        fused_micro_grads, resolved_grad_engine,
     )
 
-    t = cfg.training
-    use_fused = (t.grad_engine == "fused"
-                 or (t.grad_engine == "auto"
-                     and t.gradient_accumulation_steps > 1
-                     and fused_bwd_supported(cfg)))
+    use_fused = resolved_grad_engine(cfg) == "fused"
 
     def nll_sum(params, mb_ids, mb_tgt):
         total, count, extras = loss_sum_count(params, mb_ids, mb_tgt,

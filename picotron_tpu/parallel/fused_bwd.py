@@ -56,10 +56,15 @@ Per-axis structure (the north-star layouts; VERDICT r5):
   gradients flow with the same cotangent the AD engine sees; the capacity
   drop statistic rides the forward scan only (observability, no grad).
 
-Eligibility (see `fused_bwd_supported`): every single-pipeline-stage
-layout — dp/tp/SP/cp (ring and Ulysses)/ep/MoE — under remat with the
-dots_attn policy. Only pp > 1 and other remat policies keep the AD engine
-(the 1F1B engine is itself a manual-VJP schedule; see parallel/pp.py).
+Eligibility (see `fused_bwd_supported`; `resolved_grad_engine` is the one
+predicate every reader asks): every single-pipeline-stage layout —
+dp/tp/SP/cp (ring and Ulysses)/ep/MoE — under remat with the dots_attn
+policy, through `fused_micro_grads` in the microbatch loop; and since
+PR 63 the 1F1B pipeline engine's tick (parallel/pp.py), whose backward
+unit calls the same two scans (`layer_scans`) on its stage's slice and the
+same head block (`head_grads`) in the last stage's branch, over the same
+axes. AFAB, the MPMD executor, other remat policies and MoE over stages of
+unequal depth keep the AD engine.
 The reference gets in-place accumulation for free on every layout from
 per-rank autograd hooks (ref: bucket.py:25-31 — an imperative luxury an
 SPMD program has to earn back with scan structure); with the three axes
@@ -87,13 +92,18 @@ from picotron_tpu.telemetry.scopes import scope
 
 
 def fused_bwd_supported(cfg: Config) -> bool:
-    """True when the fused grad engine covers this config: any
-    single-pipeline-stage layout (dp/tp/SP/cp ring|ulysses|mesh/ep/MoE)
-    under remat with the dots_attn policy — the save set this engine's
-    manual backward is derived from. pp > 1 keeps the AD/1F1B engines (the
-    pipeline scan subsumes the microbatch loop), and other remat policies
-    keep the AD engine (their save sets differ from the manual backward's
-    recompute plan)."""
+    """True when the manual backward of this file covers the config: the
+    old block in one `layers` stack, under remat with the dots_attn policy
+    (the save set the manual backward is derived from), and a layout whose
+    program calls it. At pp = 1 that is every layout (dp/tp/SP/cp
+    ring|ulysses|mesh/ep/MoE: `fused_micro_grads` in the microbatch loop).
+    At pp > 1 it is the spmd executor's 1F1B engine, whose tick's backward
+    unit runs this file's two layer scans on its stage's slice
+    (parallel/pp.py `pipeline_1f1b_grads`), over the same axes; MoE only
+    where the layers split evenly over the stages (the scans do not mask a
+    padded slot's router statistics). tests/test_pp_engines.py holds each
+    against the AD tick leaf by leaf. AFAB differentiates through its scan
+    and the MPMD executor has its own per-stage programs: both stay AD."""
     d, t = cfg.distributed, cfg.training
     m = cfg.model
     # one kind of block in one `layers` stack: latent and EVA attention,
@@ -108,8 +118,30 @@ def fused_bwd_supported(cfg: Config) -> bool:
              and m.moe_scoring == "softmax"
              and m.routed_scaling_factor == 1.0
              and m.router_width == m.num_experts)
-    return (d.pp_size == 1 and plain
+    layout = d.pp_size == 1 or (
+        d.pp_engine == "1f1b" and cfg.pipeline.executor == "spmd"
+        and not (m.num_experts and m.num_hidden_layers % d.pp_size))
+    return (layout and plain
             and t.remat and t.remat_policy == "dots_attn")
+
+
+def resolved_grad_engine(cfg: Config) -> str:
+    """'fused' or 'ad': the backward unit the step's program holds, for the
+    step (parallel/api.py `_device_grads`), the 1F1B tick (parallel/pp.py),
+    `Config.validate` and the collectives audit
+    (analysis/collectives.py) alike. `training.grad_engine: ad` is the AD
+    engine everywhere; `fused` is refused by `Config.validate` where
+    `fused_bwd_supported` is false; `auto` takes the manual backward
+    wherever it is supported and gradients accumulate: under a pipeline
+    (the schedule IS the accumulation) or over more than one microbatch.
+    In a device trace the `dw_accum` scope is the sign that it engaged."""
+    t = cfg.training
+    if t.grad_engine == "ad" or not fused_bwd_supported(cfg):
+        return "ad"
+    if (t.grad_engine == "fused" or cfg.distributed.pp_size > 1
+            or t.gradient_accumulation_steps > 1):
+        return "fused"
+    return "ad"
 
 
 def _vary_like(x, ref):
@@ -273,16 +305,21 @@ def _attn_paths(cfg: Config, ctx: ParallelCtx, cos, sin):
     return attn_fwd, attn_bwd
 
 
-def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
-                      ctx: ParallelCtx):
-    """One microbatch: returns (g_acc', nll_sum, valid_count, dropw) with
-    grads accumulated into g_acc (layer leaves in-scan, non-layer leaves by
-    one small add). Per-device semantics — runs inside the train step's
-    shard_map body like the AD engine it replaces. Numerics match the AD
-    engine: per-layer dW emerges in the bf16 param dtype from the same
-    segment math before the fp32 accumulate. `dropw` is the token-weighted
-    MoE capacity-drop sum (aux[1] * count, the loss_sum_count convention;
-    0 for dense models)."""
+def layer_scans(cfg: Config, ctx: ParallelCtx, layers):
+    """(forward, backward): the two layer scans of the manual backward over
+    the stacked `layers` tree (a whole model's, or one pipeline stage's
+    slice), shared by `fused_micro_grads` and the 1F1B tick's backward unit
+    (parallel/pp.py).
+
+      forward(x0) -> (xL, saved, aux_layers): the layer block, saving per
+        layer exactly `dots_attn`'s set (the layer input, q/k/v and out
+        flat, the softmax statistics); aux_layers [L, 3] is each layer's
+        (router loss, drops, load), zeros for a dense model.
+      backward(saved, dxL, g_layers, count_f) -> (dx0, g_layers'): the
+        reverse scan; g_layers is the fp32 accumulator of the stack, and
+        each iteration adds one layer's dW into its slices (scope
+        `dw_accum`), so no gradient tree of the stack ever exists.
+        count_f, the microbatch's token count, weights MoE's aux fold."""
     m = cfg.model
     eps = m.rms_norm_eps
     hd = m.head_dim
@@ -303,7 +340,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
     # optional leaves of the q/k/v segment: Qwen2's biases, OLMoE's QK-norm
     # weights (qkv_proj branches on their presence)
     qkv_opt_keys = [k for k in ("b_q", "b_k", "b_v", "q_norm", "k_norm")
-                    if k in params["layers"]]
+                    if k in layers]
 
     moe_keys = (["router", "w_gate", "w_up", "w_down"] if moe
                 else ["gate", "up", "down"])
@@ -315,14 +352,6 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
     # the innermost name-stack element at the call, and the benchmark's
     # `flash_roofline.train` finds them under the name the layer scan's
     # body gives them (tests/test_chip_compile.py).
-
-    # ---------------- forward ----------------
-    with scope("embed"):
-        x0, vjp_embed = jax.vjp(
-            lambda e: (ctx.embed_lookup(e, ids)
-                       if ctx.embed_lookup is not None
-                       else e[ids]).astype(compute_dtype(m)),
-            params["embedding"])
 
     def fwd_body(x, lp):
         with scope("attention"):
@@ -341,11 +370,102 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
             aux = jnp.zeros(3, jnp.float32)
         return y, ((x, flat(q), flat(k), flat(v), outf, lse), aux)
 
-    xL, (saved, aux_layers) = lax.scan(fwd_body, x0, params["layers"])
-    aux_sum = jnp.sum(aux_layers, axis=0)  # [3]: (router loss, drops, load)
+    def forward(x0):
+        xL, (saved, aux_layers) = lax.scan(fwd_body, x0, layers)
+        return xL, saved, aux_layers
 
-    # ---------------- head + CE ----------------
-    nonlayer = {k: v for k, v in params.items() if k != "layers"}
+    def backward(saved, dxL, g_layers, count_f):
+        def bwd_body(carry, xs):
+            dy, gL = carry
+            (x, qf, kf, vf, outf, lse), lp, idx = xs
+
+            # MLP/MoE half: recompute a = x + o-proj (the dots_attn policy's
+            # recompute set), derive the block's grads by segment VJP. For
+            # MoE the routing recomputes deterministically and the aux-loss
+            # fold (aux * count) rides the segment so balance/z grads flow.
+            with scope("attention"):
+                a = x + ctx.g(outf @ lp["o"].astype(x.dtype))
+
+            if moe:
+                def seg_mlp(a_, *ws):
+                    lp2 = dict(lp)
+                    lp2.update(zip(["post_norm"] + moe_keys, ws))
+                    mo, aux2 = _moe_block(a_, lp2, m, ctx)
+                    return a_ + mo, aux2[0] * count_f
+
+                (_, fold_re), vjp_b = jax.vjp(
+                    seg_mlp, a, lp["post_norm"], *[lp[k] for k in moe_keys])
+                d_fold = _vary_like(jnp.ones((), jnp.float32), fold_re)
+                da, d_post, *d_ws = vjp_b((dy, d_fold))
+            else:
+                def seg_mlp(a_, *ws):
+                    lp2 = dict(lp)
+                    lp2.update(zip(["post_norm"] + moe_keys, ws))
+                    return a_ + _mlp_block(a_, lp2, m, ctx)
+
+                _, vjp_b = jax.vjp(
+                    seg_mlp, a, lp["post_norm"], *[lp[k] for k in moe_keys])
+                da, d_post, *d_ws = vjp_b(dy)
+
+            @scope("attention")
+            def seg_o(x_, outf_, wo):
+                return x_ + ctx.g(outf_ @ wo.astype(x_.dtype))
+
+            _, vjp_o = jax.vjp(seg_o, x, outf, lp["o"])
+            with scope("attention"):
+                dx1, doutf, d_o = vjp_o(da)
+
+            dqf, dkf, dvf = attn_bwd_flat(qf, kf, vf, outf, lse, doutf)
+
+            @scope("attention")
+            def seg_qkv(x_, w_in, wq, wk, wv, *bs):
+                lpq = dict(lp)
+                lpq.update(input_norm=w_in, q=wq, k=wk, v=wv,
+                           **dict(zip(qkv_opt_keys, bs)))
+                h1_ = rms_norm(x_, w_in, eps)
+                hf_ = ctx.f(h1_)
+                q_, k_, v_ = qkv_proj(hf_, lpq, hd, eps)
+                return flat(q_), flat(k_), flat(v_)
+
+            _, vjp_q = jax.vjp(seg_qkv, x, lp["input_norm"], lp["q"],
+                               lp["k"], lp["v"],
+                               *[lp[k] for k in qkv_opt_keys])
+            with scope("attention"):
+                dx2, d_in, d_q, d_k, d_v, *d_bs = vjp_q((dqf, dkf, dvf))
+
+            gl = dict(input_norm=d_in, q=d_q, k=d_k, v=d_v, o=d_o,
+                      post_norm=d_post,
+                      **dict(zip(moe_keys, d_ws)),
+                      **dict(zip(qkv_opt_keys, d_bs)))
+            assert set(gl) == set(lp), (sorted(gl), sorted(lp))
+
+            def acc(accl, g):
+                cur = lax.dynamic_index_in_dim(accl, idx, 0, keepdims=False)
+                return lax.dynamic_update_index_in_dim(
+                    accl, cur + g.astype(accl.dtype), idx, 0)
+
+            with scope("dw_accum"):
+                gL = jax.tree.map(acc, gL, gl)
+            return (dx1 + dx2, gL), None
+
+        n_layers = jax.tree.leaves(layers)[0].shape[0]
+        (dx0, g_layers), _ = lax.scan(
+            bwd_body, (dxL, g_layers),
+            (saved, layers, jnp.arange(n_layers)), reverse=True)
+        return dx0, g_layers
+
+    return forward, backward
+
+
+def head_grads(xL, nonlayer, tgt, cfg: Config, ctx: ParallelCtx,
+               weight=None):
+    """The final norm, the head and the cross entropy of one microbatch,
+    forward and backward in one place: (nll_sum, valid_count, dxL,
+    g_nonlayer), where g_nonlayer is the gradient of `weight` x nll_sum in
+    every leaf of `nonlayer` (the final norm and the head's matrix: the
+    embedding's when tied). `weight` None is 1; the 1F1B tick passes 0 on a
+    tick without a backward."""
+    eps = cfg.model.rms_norm_eps
 
     @scope("head_ce")
     def head_fn(x, nl):
@@ -360,11 +480,42 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         return total, count
 
     (total, vjp_head, count) = jax.vjp(head_fn, xL, nonlayer, has_aux=True)
-    one = _vary_like(jnp.ones((), jnp.float32), total)
+    cot = _vary_like(
+        jnp.ones((), jnp.float32) if weight is None else weight, total)
     with scope("head_ce"):
-        dxL, g_nonlayer = vjp_head(one)
+        dxL, g_nonlayer = vjp_head(cot)
+    return total, count, dxL, g_nonlayer
+
+
+def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
+                      ctx: ParallelCtx):
+    """One microbatch: returns (g_acc', nll_sum, valid_count, dropw) with
+    grads accumulated into g_acc (layer leaves in-scan, non-layer leaves by
+    one small add). Per-device semantics — runs inside the train step's
+    shard_map body like the AD engine it replaces. Numerics match the AD
+    engine: per-layer dW emerges in the bf16 param dtype from the same
+    segment math before the fp32 accumulate. `dropw` is the token-weighted
+    MoE capacity-drop sum (aux[1] * count, the loss_sum_count convention;
+    0 for dense models)."""
+    m = cfg.model
+    forward, backward = layer_scans(cfg, ctx, params["layers"])
+
+    # ---------------- forward ----------------
+    with scope("embed"):
+        x0, vjp_embed = jax.vjp(
+            lambda e: (ctx.embed_lookup(e, ids)
+                       if ctx.embed_lookup is not None
+                       else e[ids]).astype(compute_dtype(m)),
+            params["embedding"])
+
+    xL, saved, aux_layers = forward(x0)
+    aux_sum = jnp.sum(aux_layers, axis=0)  # [3]: (router loss, drops, load)
+
+    # ---------------- head + CE ----------------
+    nonlayer = {k: v for k, v in params.items() if k != "layers"}
+    total, count, dxL, g_nonlayer = head_grads(xL, nonlayer, tgt, cfg, ctx)
     count_f = count.astype(jnp.float32)
-    if moe:
+    if m.num_experts:
         # the loss_sum_count fold: reported total = nll + (sum_l aux_l)*count
         # — the router-loss gradient flows per layer through the backward
         # scan's segment VJPs with cotangent 1.0 on the folded scalar.
@@ -374,82 +525,7 @@ def fused_micro_grads(params, ids, tgt, g_acc, cfg: Config,
         dropw = total * jnp.zeros((2,), jnp.float32)
 
     # ---------------- backward layer scan ----------------
-    def bwd_body(carry, xs):
-        dy, gL = carry
-        (x, qf, kf, vf, outf, lse), lp, idx = xs
-
-        # MLP/MoE half: recompute a = x + o-proj (the dots_attn policy's
-        # recompute set), derive the block's grads by segment VJP. For MoE
-        # the routing recomputes deterministically and the aux-loss fold
-        # (aux * count) rides the segment so balance/z grads flow.
-        with scope("attention"):
-            a = x + ctx.g(outf @ lp["o"].astype(x.dtype))
-
-        if moe:
-            def seg_mlp(a_, *ws):
-                lp2 = dict(lp)
-                lp2.update(zip(["post_norm"] + moe_keys, ws))
-                mo, aux2 = _moe_block(a_, lp2, m, ctx)
-                return a_ + mo, aux2[0] * count_f
-
-            (_, fold_re), vjp_b = jax.vjp(
-                seg_mlp, a, lp["post_norm"], *[lp[k] for k in moe_keys])
-            d_fold = _vary_like(jnp.ones((), jnp.float32), fold_re)
-            da, d_post, *d_ws = vjp_b((dy, d_fold))
-        else:
-            def seg_mlp(a_, *ws):
-                lp2 = dict(lp)
-                lp2.update(zip(["post_norm"] + moe_keys, ws))
-                return a_ + _mlp_block(a_, lp2, m, ctx)
-
-            _, vjp_b = jax.vjp(
-                seg_mlp, a, lp["post_norm"], *[lp[k] for k in moe_keys])
-            da, d_post, *d_ws = vjp_b(dy)
-
-        @scope("attention")
-        def seg_o(x_, outf_, wo):
-            return x_ + ctx.g(outf_ @ wo.astype(x_.dtype))
-
-        _, vjp_o = jax.vjp(seg_o, x, outf, lp["o"])
-        with scope("attention"):
-            dx1, doutf, d_o = vjp_o(da)
-
-        dqf, dkf, dvf = attn_bwd_flat(qf, kf, vf, outf, lse, doutf)
-
-        @scope("attention")
-        def seg_qkv(x_, w_in, wq, wk, wv, *bs):
-            lpq = dict(lp)
-            lpq.update(input_norm=w_in, q=wq, k=wk, v=wv,
-                       **dict(zip(qkv_opt_keys, bs)))
-            h1_ = rms_norm(x_, w_in, eps)
-            hf_ = ctx.f(h1_)
-            q_, k_, v_ = qkv_proj(hf_, lpq, hd, eps)
-            return flat(q_), flat(k_), flat(v_)
-
-        _, vjp_q = jax.vjp(seg_qkv, x, lp["input_norm"], lp["q"], lp["k"],
-                           lp["v"], *[lp[k] for k in qkv_opt_keys])
-        with scope("attention"):
-            dx2, d_in, d_q, d_k, d_v, *d_bs = vjp_q((dqf, dkf, dvf))
-
-        gl = dict(input_norm=d_in, q=d_q, k=d_k, v=d_v, o=d_o,
-                  post_norm=d_post,
-                  **dict(zip(moe_keys, d_ws)),
-                  **dict(zip(qkv_opt_keys, d_bs)))
-        assert set(gl) == set(lp), (sorted(gl), sorted(lp))
-
-        def acc(accl, g):
-            cur = lax.dynamic_index_in_dim(accl, idx, 0, keepdims=False)
-            return lax.dynamic_update_index_in_dim(
-                accl, cur + g.astype(accl.dtype), idx, 0)
-
-        with scope("dw_accum"):
-            gL = jax.tree.map(acc, gL, gl)
-        return (dx1 + dx2, gL), None
-
-    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
-    (dx0, g_layers), _ = lax.scan(
-        bwd_body, (dxL, g_acc["layers"]),
-        (saved, params["layers"], jnp.arange(n_layers)), reverse=True)
+    dx0, g_layers = backward(saved, dxL, g_acc["layers"], count_f)
 
     # ---------------- embedding + non-layer accumulate ----------------
     with scope("embed"):

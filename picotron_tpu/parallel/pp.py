@@ -19,9 +19,10 @@ s applies its layer block to microbatch m, where stage 0 ingests `embed(m)`
 (masked-uniform) and the last stage scores m against the targets in a
 `lax.cond` branch by stage (the head matmul runs ONLY on the last stage —
 see _make_stage_fn, which also states what such a branch may hold). AFAB
-differentiates through the unit, scoring and all; 1F1B differentiates it in
-its backward unit and runs it a second time, WITHOUT the scoring
-(`score=False`), only where a later stage needs the output.
+differentiates through the unit, scoring and all; 1F1B runs it WITHOUT the
+scoring (`score=False`) as its forward unit, only where a later stage needs
+the output, and its backward unit is written out by hand from the same
+building blocks (pipeline_1f1b_grads).
 
 **"afab"** (all-forward-all-backward, ref: pipeline_parallel.py:77-118):
 one `lax.scan` over n_micro + pp - 1 ticks; at tick t stage s forwards
@@ -39,9 +40,13 @@ m + 2(pp-1) - s — each steady-state tick executes one active forward AND
 one active backward per stage, finishing in n_micro + 2(pp-1) ticks (see
 pipeline_1f1b_grads for the schedule/memory analysis). On the last stage the
 two are the same microbatch and the forward has no consumer, so that stage's
-tick is the backward unit alone (its vjp runs the forward once). Activation
+tick is the backward unit alone (which runs the forward once). Activation
 cotangents ride a reverse ppermute; parameter gradients accumulate in the
-scan carry; live boundary inputs sit in a min(n_micro, 2(pp-1))-slot ring,
+scan carry, each leaf written where its gradient is produced and on the
+stage that produces it (no per-tick gradient tree, no whole-tree add; the
+layer block's backward is parallel/fused_bwd.py's manual scans where
+`resolved_grad_engine` allows, jax.vjp of the block elsewhere);
+live boundary inputs sit in a min(n_micro, 2(pp-1))-slot ring,
 *independent of n_micro* (AFAB's live set grows with n_micro). 1f1b is the
 default engine: ~AFAB speed with O(pp) instead of O(n_micro) boundary-
 activation memory.
@@ -83,6 +88,9 @@ from picotron_tpu.models.llama import (
     model_rope_tables, remat_policy_for, run_layers,
 )
 from picotron_tpu.ops.losses import IGNORE_INDEX, cross_entropy_sum_count
+from picotron_tpu.parallel.fused_bwd import (
+    head_grads, layer_scans, resolved_grad_engine,
+)
 from picotron_tpu.telemetry.scopes import scope
 
 
@@ -141,10 +149,11 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
     axis_index('pp') with a psum over 'tp' in one branch only and a
     ppermute over 'pp' after it, on a (pp 2, tp 2) mesh): right values on
     four CPU devices and on the four-chip v5e host (PR 38, JAX 0.9.0). The
-    scoring cond's BACKWARD has held such a collective all along: the
-    compiled four-chip step has a tp all-reduce in each branch of
-    `transpose(jvp(head_ce))/cond`. 1F1B's forward unit relies on the rule
-    for a whole layer block (pipeline_1f1b_grads).
+    scoring cond's BACKWARD has held such a collective all along (until
+    PR 63 the compiled four-chip step had a tp all-reduce in each branch of
+    `transpose(jvp(head_ce))/cond`). 1F1B relies on the rule for a whole
+    layer block in its forward unit and for the whole head, forward and
+    backward, in its backward unit (pipeline_1f1b_grads).
 
     The scoring branch computes this tp shard's local softmax stats
     (vocab_parallel_ce_local_stats; zero FLOPs off the last stage) and the
@@ -357,8 +366,8 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
 
     Memory: stage s holds up to min(n_micro, 2(pp-1-s)) boundary *inputs*
     live — the ring holds only [mbs, S_local, H] stage inputs (the backward
-    unit recomputes the stage interior under jax.vjp, honoring the remat
-    policy), so the bound is 2x Megatron's per-stage pp-s activations but
+    unit recomputes the stage interior, honoring the remat policy), so the
+    bound is 2x Megatron's per-stage pp-s activations but
     counts only boundary tensors, negligible against weights at realistic
     shapes. The 2x is fundamental to full rate: microbatch m's grad returns
     to stage s exactly 2(pp-1-s) ticks after its forward (one stage per
@@ -376,24 +385,53 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
     (b == f) and the backward consumes the live x_buf directly, not the
     ring.
 
-    What a tick runs. The *backward unit* is `jax.vjp` of the whole stage
-    unit (layers, and on the last stage the scoring) at microbatch m_b: its
-    forward pass recomputes the stage from the saved boundary input, and its
-    primal outputs are where the engine reads the loss sum, the token count
-    and the MoE drop / load sums — every microbatch has exactly one backward
-    on every stage. The *forward unit* is the layer block alone at
-    microbatch m_f (`score=False`: no head, no merge collectives), and
-    exists to feed the next stage. On the last stage m_f == m_b and nothing
-    consumes y, so the unit sits in a `lax.cond` whose last-stage branch is
-    zeros: that stage computes each microbatch's forward once (a second
-    forward of layers and head there is a third of the tick of the stage
-    that sets the step). The forward unit's branch holds the layers' tp /
-    ep collectives and none over 'pp' — _make_stage_fn's branch rule — and
-    is not differentiated, so that docstring's two backward-branch rules do
-    not apply to it. With cp > 1
-    the layers may hold a ring of ppermutes, which the rule forbids: the
-    unit then runs masked-uniform on every stage (the last stage still
-    scores once).
+    What a tick runs. The *backward unit* takes microbatch m_b through the
+    stage a second time, forward then backward, and every weight gradient
+    lands in the fp32 accumulator `g_acc` WHERE IT IS PRODUCED, on the
+    stage that produces it (PR 63) — the tick builds no gradient tree and
+    adds no tree. Its four parts, each the same mathematics (dW in the
+    compute dtype, cast to fp32, added into the accumulator):
+
+    1. Ingest, a branch by stage: stage 0 looks the microbatch up, the
+       others take the saved boundary input.
+    2. The layer block. Where `resolved_grad_engine` says 'fused'
+       (parallel/fused_bwd.py: the old block under remat dots_attn, on
+       any dp/tp/SP/cp/ep layout) it is that file's two layer scans:
+       the forward with dots_attn's save set, then the reverse scan that
+       carries `g_acc["layers"]` and updates one layer's slices an
+       iteration (scope `dw_accum`), fed by the cotangent from the next
+       stage (the head's dx on the last). Everywhere else (`grad_engine:
+       ad`, another remat policy, MoE over stages of unequal depth) it is
+       `jax.vjp` of `run_layers` and one add over the stack's leaves.
+    3. The head, a branch by stage that only the last stage takes: final
+       norm, head matmul, CE (its tp merge too) AND their backward
+       (`fused_bwd.head_grads`), `acc + dW` for the norm and the head's
+       matrix inside the branch, so the compiler folds the add into the dW
+       matmul. Forward and backward share the branch, so nothing
+       differentiates THROUGH a cond: no neutral-branch residuals (AD made
+       the stage that never scores zero-fill a microbatch's fp32 logits
+       every tick), and _make_stage_fn's two backward-branch rules, which
+       AFAB still needs, do not arise. Its primal is where the engine reads
+       the loss sum — every microbatch has exactly one backward on every
+       stage; the token count and the MoE drop / load sums need no head.
+    4. The embedding's rows, a branch by stage: scattered onto
+       `g_acc["embedding"]` on stage 0. With a tied head the embedding is
+       touched twice, once in each branch.
+
+    A stage passes the accumulators it does not use through the other
+    branch untouched; tests/test_chip_compile.py holds that the compiled
+    tick neither copies nor fills nor adds a head- or embedding-shaped leaf
+    outside a branch. Every branch follows _make_stage_fn's rule: a tp
+    all-reduce may sit in it, nothing over 'pp', no ppermute.
+
+    The *forward unit* is the layer block alone at microbatch m_f
+    (`score=False`: no head, no merge collectives), and exists to feed the
+    next stage. On the last stage m_f == m_b and nothing consumes y, so the
+    unit sits in a `lax.cond` whose last-stage branch is zeros: that stage
+    computes each microbatch's forward once (a second forward of layers and
+    head there is a third of the tick of the stage that sets the step).
+    With cp > 1 the layers may hold a ring of ppermutes, which the rule
+    forbids: the unit then runs masked-uniform on every stage.
 
     Grads of pp-replicated params (embedding / final norm / head) come out
     nonzero only on the stage that uses them — pass through
@@ -411,6 +449,23 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
     stage_fn = _make_stage_fn(ids, tgt, m, ctx, cos, sin, s_idx, pp)
     fwd_perm = [(i, i + 1) for i in range(pp - 1)]
     bwd_perm = [(i + 1, i) for i in range(pp - 1)]
+
+    # The backward unit's layer block (docstring): the manual backward's two
+    # scans, or jax.vjp of run_layers and an add over the stack's leaves.
+    accumulate = resolved_grad_engine(cfg) == "fused"
+    if accumulate:
+        layers_fwd, layers_bwd = layer_scans(cfg, ctx, params["layers"])
+    # the leaves the head's branch reads and accumulates into: the final
+    # norm and the head's matrix (the embedding's, where tied)
+    head_keys = ("final_norm",
+                 "lm_head" if "lm_head" in params else "embedding")
+    data_pp = {"dp", "ep", "cp", "pp"}
+    on_boundary = set(_boundary_axes(ctx))
+
+    def into(acc, g):
+        """acc + g: a gradient in the compute dtype, cast to the fp32
+        accumulator's dtype and varying type, added."""
+        return acc + _cast_varying_like(g.astype(acc.dtype), acc)
 
     def tick(carry, t):
         ring, x_buf, g_buf, g_acc, nll_acc, cnt_acc, drop_acc = carry
@@ -433,16 +488,16 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         f_on = (df >= 0) & (df < n_micro)
         m_f = jnp.clip(df, 0, n_micro - 1)
 
-        def layers_fwd(p, xb):
+        def layers_fwd_unit(p, xb):
             return stage_fn(p, xb, m_f, f_on, score=False)
 
         if cfg.distributed.cp_size == 1:
             y = lax.cond(
                 s_idx == pp - 1,
                 lambda p, xb: _cast_varying_like(jnp.zeros_like(xb), xb),
-                layers_fwd, params, x_buf)
+                layers_fwd_unit, params, x_buf)
         else:
-            y = layers_fwd(params, x_buf)
+            y = layers_fwd_unit(params, x_buf)
         # Save this stage's *input* for the backward recompute. Guard the
         # store: on non-forward ticks m_f aliases a possibly-live slot.
         ring_new = lax.dynamic_update_index_in_dim(
@@ -452,33 +507,106 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
             y_send = lax.ppermute(y * f_on.astype(y.dtype), "pp", fwd_perm)
 
         # ---- backward unit: microbatch m_b retreats one stage ----
-        # Last stage: b(m) == f(m), the input is this tick's live x_buf.
+        mb_ids = lax.dynamic_index_in_dim(ids, m_b, 0, keepdims=False)
+        mb_tgt = lax.dynamic_index_in_dim(tgt, m_b, 0, keepdims=False)
+        count = jnp.sum(mb_tgt != IGNORE_INDEX)
+        # The loss's cotangent is 1 on EVERY stage that ran m_b (the CE
+        # counts on the last stage only, each stage's MoE aux term on its
+        # own) and 0 on a tick without a backward: g_buf is zeros then too,
+        # every gradient below is linear in the two, so nothing needs a mask.
+        g_nll = _vary_over(jnp.where(b_on, 1.0, 0.0), data_pp)
+
+        # Ingest, in a branch by stage: stage 0 looks the microbatch up
+        # (zero-masked off a backward tick: all bubble compute runs on
+        # zeros, which every op here keeps finite), every other stage takes
+        # its saved input — the last one this tick's live x_buf, since
+        # there b(m) == f(m).
+        def lookup(emb):
+            return embed({"embedding": emb}, mb_ids, m, ctx) \
+                * b_on.astype(dtype)
+
         x_saved = jnp.where(s_idx == pp - 1, x_buf, x_ring)
-        (_, contrib), vjp_fn, (cnt, dropw) = jax.vjp(
-            lambda p, xb: stage_fn(p, xb, m_b, b_on), params, x_saved,
-            has_aux=True)
-        # The loss is read where it is computed: the vjp's primal. Every
-        # microbatch has exactly one backward on every stage. contrib
-        # pre-masks the CE to the last stage (stage_fn); MoE aux
-        # contributions ride it on every stage, as does this stage's
-        # layers' capacity-drop observability sum.
+        x_in = lax.cond(
+            s_idx == 0,
+            lambda emb, xs: _vary_over(lookup(emb), on_boundary),
+            lambda emb, xs: xs, params["embedding"], x_saved)
+
+        # the layer block, forward
+        def weighted(aux):
+            # llama.loss_sum_count's folding rule: this stage's layers'
+            # (pre-weighted) router loss scaled by the token count, which
+            # joins the loss; and the same-scaled drop / load sums
+            return ((aux[0] * count,) if m.num_experts else ()), \
+                aux[1:] * count
+
+        if accumulate:
+            x_out, saved, aux_layers = layers_fwd(x_in)
+            fold, dropw = weighted(jnp.sum(aux_layers, axis=0))
+        else:
+            def block(lp, x):
+                y_, aux = run_layers(lp, x, m, ctx, cos, sin)
+                fold, dropw = weighted(aux)
+                return (y_,) + fold, dropw
+
+            (x_out, *fold), vjp_block, dropw = jax.vjp(
+                block, params["layers"], x_in, has_aux=True)
+
+        # The head, forward AND backward, in the branch the last stage
+        # takes: final norm, head matmul, CE (its tp merge too) and their
+        # gradients, the head's and the norm's landing in their
+        # accumulators there. Every other stage passes its accumulators
+        # through and hands the layer block the cotangent that arrived
+        # from the next stage.
+        def score(x, nl, acc):
+            total, _, dx, g_nl = head_grads(x, nl, mb_tgt, cfg, ctx, g_nll)
+            with scope("head_ce"):
+                acc = {k: into(acc[k], g_nl[k]) for k in acc}
+            return (_vary_over(total, data_pp),
+                    _vary_over(dx, on_boundary), acc)
+
+        def no_score(x, nl, acc):
+            return (_vary_over(jnp.zeros((), jnp.float32), data_pp),
+                    g_buf, acc)
+
+        contrib, dx_out, acc_head = lax.cond(
+            s_idx == pp - 1, score, no_score, x_out,
+            {k: params[k] for k in head_keys},
+            {k: g_acc[k] for k in head_keys})
+        g_acc = {**g_acc, **acc_head}
+
+        # the layer block, backward: each layer's dW into the accumulator
+        # inside the reverse scan, or AD's tree for the stack and one add
+        if fold:
+            contrib = contrib + fold[0]
+        if accumulate:
+            # (the scan's aux fold is `aux * weight` with cotangent 1: the
+            # token count, times the loss's cotangent on this tick)
+            dx_in, g_layers = layers_bwd(saved, dx_out, g_acc["layers"],
+                                         count * g_nll)
+        else:
+            g_lp, dx_in = vjp_block(
+                (dx_out,) + tuple(_cast_varying_like(g_nll, f) for f in fold))
+            g_layers = jax.tree.map(into, g_acc["layers"], g_lp)
+
+        # the embedding's rows, scattered onto its accumulator on stage 0
+        def lookup_bwd(acc, dx):
+            with scope("embed"):
+                _, vjp_lookup = jax.vjp(lookup, params["embedding"])
+                (g_emb,) = vjp_lookup(dx)
+                return into(acc, g_emb)
+
+        g_emb_acc = lax.cond(s_idx == 0, lookup_bwd, lambda acc, dx: acc,
+                             g_acc["embedding"], dx_in)
+        g_acc = {**g_acc, "layers": g_layers, "embedding": g_emb_acc}
+
+        # The loss is read where it is computed: the backward unit's
+        # primal. Every microbatch has exactly one backward on every stage.
         nll_acc = nll_acc + jnp.where(b_on, contrib, 0.0)
-        cnt_acc = cnt_acc + jnp.where(b_on & (s_idx == pp - 1), cnt, 0)
+        cnt_acc = cnt_acc + jnp.where(b_on & (s_idx == pp - 1), count, 0)
         drop_acc = drop_acc + jnp.where(b_on, dropw, 0.0)
-        # Cotangents: g_buf arrived from stage s+1 (zeros at the last stage
-        # by ppermute's edge semantics — its y has no downstream consumer);
-        # the contrib cotangent is 1 on EVERY stage that ran m_b — contrib
-        # masks the CE to the last stage internally, and the per-stage MoE
-        # aux term needs its gradient from every stage. On non-backward
-        # ticks both cotangents are zero, so the VJP outputs are zero and
-        # need no masking.
-        g_nll = _vary_over(jnp.where(b_on, 1.0, 0.0),
-                           {"dp", "ep", "cp", "pp"})
-        g_params, g_x = vjp_fn((g_buf, g_nll))
-        g_acc = jax.tree.map(
-            lambda a, g: jnp.add(a, _cast_varying_like(g, a)), g_acc, g_params)
+        # Cotangents ride the reverse ring: stage 0's has no receiver.
         with scope("pp_boundary"):
-            g_send = lax.ppermute(g_x, "pp", bwd_perm)
+            g_send = lax.ppermute(dx_in, "pp", bwd_perm)
 
         return (ring, y_send, g_send, g_acc, nll_acc, cnt_acc, drop_acc), None
 
@@ -490,20 +618,25 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
          jnp.zeros((2,), jnp.float32)),
         ("dp", "ep", "cp", "pp"), to="varying")
-    # Each grad-accumulator leaf varies over the data axes plus whatever its
-    # param already varies over (tp/pp shardings) — matching what the VJP
-    # emits each tick, so the scan carry type is stable. Under sequence
-    # parallelism the per-tick VJP grads of tp-replicated params (norms)
-    # are per-rank partials over this rank's seq shard, hence tp-varying;
-    # sync_sp_partial_grads completes them with a tp psum after the scan.
+    # Each grad-accumulator leaf varies over the data axes and 'pp' plus
+    # whatever its param already varies over (tp shardings) — what the
+    # backward unit produces each tick, so the scan carry type is stable.
+    # NOT over 'tp' for a tp-replicated param under sequence parallelism
+    # (the norms): the gradient of a tp-invariant param arrives complete,
+    # AD's pvary-transpose psum over tp having run inside the tick (the rule
+    # parallel/api.py _device_grads states for the data axes). Until PR 63
+    # these leaves were typed tp-varying here and a tp psum after the scan
+    # (`sync_sp_partial_grads`, gone) summed them a second time: every
+    # norm's gradient tp x too large under 1F1B + SP (AFAB and pp = 1 were
+    # right), which is what set 1F1B's grad_norm 1% off AFAB's (PERF.md
+    # section 7, PR 38 (f)).
     # fp32 accumulation regardless of the param dtype: with
-    # optimizer_offload the params (and hence the per-tick VJP grads) are
-    # bf16, and summing n_micro bf16 grads in bf16 would lose the low bits
-    # the fp32 master exists to keep (jnp.add promotes bf16 + fp32 -> fp32).
+    # optimizer_offload the params (and hence the per-tick dW) are bf16, and
+    # summing n_micro bf16 grads in bf16 would lose the low bits the fp32
+    # master exists to keep (jnp.add promotes bf16 + fp32 -> fp32).
     g_zero = jax.tree.map(
         lambda p: _vary_over(jnp.zeros(p.shape, jnp.float32),
-                             set(_boundary_axes(ctx))
-                             | set(compat.vma(p))),
+                             data_pp | set(compat.vma(p))),
         params)
     init = (bufs[0], bufs[1], bufs[2], g_zero, bufs[3], bufs[4], bufs[5])
     (_, _, _, grads, nll_sum, cnt, dropw), _ = lax.scan(
@@ -542,21 +675,3 @@ def sync_pp_replicated_grads(grads, specs):
 
     return jax.tree.map(fix, grads, specs,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-def sync_sp_partial_grads(grads, params):
-    """Under sequence parallelism, complete the grads of tp-replicated
-    params (the norm weights): each tp rank accumulated the partial over its
-    sequence shard (tp-varying leaf), and the psum assembles the full sum.
-    tp-sharded params (vma already contains 'tp') are genuine shards, not
-    partials — left untouched. No-op tree-wide when nothing is tp-varying
-    beyond its param (the automatic pvary-transpose psum already ran, e.g.
-    the AFAB jax.grad path)."""
-    # Which leaves are tp-PARTIAL (vs genuine tp shards) is read off the
-    # vma types.
-    def fix(g, p):
-        if "tp" in compat.vma(g) and "tp" not in compat.vma(p):
-            return lax.psum(g, "tp")
-        return g
-
-    return jax.tree.map(fix, grads, params)

@@ -57,12 +57,24 @@ BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
 # unit's forward together; in a branch and outside it they are two
 # `psum_invariant.N` each (+3 names each): 46 - 1 + 9 = 54 (46
 # psum_invariant, 4 all-reduce, 2 + 2 collective-permute). As instructions:
-# 16 all-reduces before, 19 now, of which the last stage runs 16.
-N_COLLECTIVES = 54
+# 16 all-reduces before, 19 then, of which the last stage ran 16.
+# PR 63 (the accumulating tick): 54 - 6 = 48 (40 psum_invariant, 4
+# all-reduce, 2 + 2 collective-permute); 17 all-reduce instructions. The
+# head's forward and backward sit in ONE branch by stage and nothing
+# differentiates through a cond, so the neutral branch's anchor and its
+# `bf16[]` psum are gone (-3); and at one layer a stage the manual backward's
+# re-run of the o-projection is the forward's, merged by the compiler with
+# its all-reduce, where remat's had its own (-3: at the cell's three layers a
+# stage the two scans are loops and each keeps its own).
+N_COLLECTIVES = 48
 # temp_size_in_bytes of the four-chip step at one layer a stage on the parent
 # of PR 38 (commit 977113c, this installation), whose tick held the forward
 # unit's fp32 [4096,76032] logits (1.25 GB) beside the backward unit's
 PARENT_FOUR_CHIP_TEMP_BYTES = 11_745_135_104
+# the same on the parent of PR 63 (commit defc834), whose tick built each
+# microbatch's whole gradient tree (the stage's layer stack, a zero-filled
+# head and embedding) before it added the tree into the accumulator
+PARENT_63_FOUR_CHIP_TEMP_BYTES = 10_661_128_192
 
 
 @pytest.fixture(scope="module")
@@ -158,52 +170,113 @@ def test_four_chip_step_keeps_its_collectives_names(topo, monkeypatch):
     assert sends and all("pp_boundary" in words(op) for op in sends)
     reduces = [op for n, op, _ in ins if n.startswith("psum_invariant")]
     assert any("tp_reduce" in words(op) for op in reduces)
-    # four kernel calls, all inside `attention` (no accepted metric finds
-    # them by name in this cell): the 1F1B forward unit's forward kernel, in
-    # the branch the last stage does not take; the backward unit's forward
-    # kernel (its `jax.vjp` runs the stage's forward: this one is NOT
-    # remat's); dq and dkv under the transpose. `dots_attn` saves the
-    # kernel's output, so remat adds no call of its own.
+    # four kernel calls (no accepted metric finds them by name in this
+    # cell): the 1F1B forward unit's forward kernel, inside `attention`, in
+    # the branch the last stage does not take; and the manual backward's
+    # three (PR 63: forward, dq, dkv of parallel/fused_bwd.py's two scans),
+    # outside every scope and named after the scan's body as in the
+    # one-chip cells: no `jvp`, no `transpose`, and remat adds no call.
     kernels = [op for _, op, line in ins if "tpu_custom_call" in line]
-    assert len(kernels) == 4 and all("attention" in words(op) for op in kernels)
+    assert len(kernels) == 4, kernels
+    unit = [op for op in kernels if "attention" in words(op)]
+    manual = [op for op in kernels if not words(op) & set(SCOPES)]
+    assert len(unit) == 1 and len(manual) == 3, kernels
+    for op in manual:
+        assert op.endswith("closed_call/pallas_call"), op
+        assert "jvp" not in op and "transpose" not in op, op
     found = set().union(*(words(op) for _, op, _ in ins)) & set(SCOPES)
     assert found >= {"embed", "attention", "mlp", "head_ce", "optimizer",
-                     "pp_boundary", "tp_reduce"}
+                     "pp_boundary", "tp_reduce", "dw_accum"}
 
 
 def test_four_chip_last_stage_runs_each_forward_once(topo, monkeypatch):
     """PR 38: the 1F1B tick's forward unit never scores, and its layer block
-    sits in a branch the last stage does not take (the backward unit's
-    `jax.vjp` runs that stage's forward of the same microbatch). Until then
-    the last stage, which sets the step, ran the layers' forward and the
-    head's forward twice a tick."""
+    sits in a branch the last stage does not take (the backward unit runs
+    that stage's forward of the same microbatch). Until then the last
+    stage, which sets the step, ran the layers' forward and the head's
+    forward twice a tick. PR 63: the backward unit's layer block is the
+    manual backward's two scans, and the head's forward and backward share
+    the one branch the last stage takes."""
     comp = compile_step(topo, monkeypatch, "qwen2-7b-6l-tp2pp2", layers=2)
     text = comp.as_text()
     ins = instructions(text)
     kernels = [op for _, op, line in ins if "tpu_custom_call" in line]
     in_branch = [op for op in kernels if "branch_0_fun" in words(op)]
     assert len(in_branch) == 1 and "jvp" not in in_branch[0], kernels
-    assert sum("transpose(jvp())" in op for op in kernels) == 2, kernels
     # one forward matmul of the head a tick (`[4096, 3584] x [3584, 76032]`),
-    # under the backward unit's vjp; until PR 38 the forward unit had its own
+    # in the last stage's branch; until PR 38 the forward unit had its own
     logits = [op for _, op, line in ins if " convolution(" in line
               and re.search(r"= bf16\[(1,)?4096,76032\]", line)]
     assert len(logits) == 1 and "jvp(head_ce)" in logits[0], logits
-    # the forward unit's layer block sits in a branch by stage; it holds the
-    # embedding's and the layers' tensor-parallel all-reduces and nothing
-    # that crosses stages (devices 0, 1 are stage 0; 2, 3 the last)
-    conds = [(line, branch_collectives(text, line)) for _, op, line in ins
+    assert "cond/branch_1_fun" in logits[0], logits
+    # four branches by stage a tick, none with a collective that crosses
+    # stages (devices 0, 1 are stage 0; 2, 3 the last): the forward unit's
+    # layer block (the embedding's and the layers' tensor-parallel
+    # all-reduces) against nothing; the backward unit's lookup (the
+    # embedding's) against the saved input; the head (the CE's merge, pmax
+    # and a tuple, and dx's) against the next stage's cotangent; the
+    # embedding's rows onto their accumulator (no collective)
+    conds = [branch_collectives(text, line) for _, op, line in ins
              if " conditional(" in line and op.endswith("closed_call/cond")]
-    assert len(conds) == 1, [line[:200] for line, _ in conds]
-    layers_branch, skip_branch = conds[0][1]
-    assert not skip_branch and len(layers_branch) == 3, conds[0][1]
-    for line in layers_branch:
+    assert sorted(sorted(map(len, c)) for c in conds) == [
+        [0, 0], [0, 1], [0, 3], [0, 3]], conds
+    for line in (line for c in conds for branch in c for line in branch):
         assert " all-reduce(" in line, line
         assert "replica_groups={{0,1},{2,3}}" in line, line
     temp = comp.memory_analysis().temp_size_in_bytes
     print(f"four-chip step: temp_size_in_bytes {temp:,} "
-          f"(parent {PARENT_FOUR_CHIP_TEMP_BYTES:,})")
+          f"(parent of PR 38 {PARENT_FOUR_CHIP_TEMP_BYTES:,}, "
+          f"of PR 63 {PARENT_63_FOUR_CHIP_TEMP_BYTES:,})")
     assert temp <= PARENT_FOUR_CHIP_TEMP_BYTES
+    # PR 63: under the parent's by at least the layer stack's gradient tree
+    # (one layer a stage here, float32, halved by tp 2), which no tick builds
+    m = load("configs", "qwen2-7b-6l-tp2pp2")["model"]
+    h, f = m["hidden_size"], m["intermediate_size"]
+    kv = m["num_key_value_heads"] * h // m["num_attention_heads"]
+    layer_tree = 4 * (3 * h * f + 2 * h * h + 2 * h * kv) // 2
+    assert temp <= PARENT_63_FOUR_CHIP_TEMP_BYTES - layer_tree, (temp, layer_tree)
+
+
+def test_four_chip_tick_touches_a_leaf_only_where_it_is_used(topo, monkeypatch):
+    """PR 63: the head's and the embedding's fp32 accumulators (1.09 GB
+    each a chip) are written by the operation that produces their gradient,
+    in the branch of the stage that produces it: the head's dW matmul with
+    the convert and the add in its epilogue, the embedding's rows scattered
+    onto the accumulator in place. Outside a branch by stage the tick holds
+    no instruction that makes a leaf of either shape: no add, no zero fill,
+    no copy (a branch that passes a leaf through must not copy it). Until
+    then every tick zero-filled both on every stage and read and wrote the
+    whole accumulator to add them (`select_add_fusion f32[3584,76032]` in
+    the parent's trace, 4.7 ms a tick on the stage that holds no head)."""
+    text = compiled_step(topo, monkeypatch, "qwen2-7b-6l-tp2pp2")
+    comps = computations(text)
+    ins = instructions(text)
+    c = load("configs", "qwen2-7b-6l-tp2pp2")
+    leaf = (c["model"]["vocab_size"] // c["distributed"]["tp_size"]
+            * c["model"]["hidden_size"])
+    in_loop = loop_computations(text, comps)
+    in_branch = reachable(comps, {
+        b for _, _, line in ins if " conditional(" in line
+        for b in called(line)})
+    moves_nothing = re.compile(
+        r" (parameter|get-tuple-element|tuple|bitcast|conditional|while)\(")
+
+    def makes_a_leaf(line):
+        return ("f32[" in line and leaf in result_sizes(line)
+                and not moves_nothing.search(line))
+
+    outside = [line.strip()[:200] for name in sorted(in_loop - in_branch)
+               if not name.startswith("fused_computation")
+               for line in comps[name] if makes_a_leaf(line)]
+    assert not outside, outside
+    inside = [line for name in sorted(in_loop & in_branch)
+              if not name.startswith("fused_computation")
+              for line in comps[name] if makes_a_leaf(line)]
+    assert len(inside) == 2 and all(" fusion(" in line for line in inside), inside
+    bodies = ["\n".join(comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)])
+              for line in inside]
+    assert sum(" convolution(" in b for b in bodies) == 1
+    assert sum(" scatter(" in b for b in bodies) == 1
 
 
 def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):

@@ -238,9 +238,15 @@ def test_auto_resolves_fused_only_when_supported():
         engine_cfg("auto", dist_kw={"dp_size": 2, "ep_size": 2},
                    model_kw={"num_experts": 4,
                              "num_experts_per_token": 2}))
-    # still AD-only: pp > 1, non-dots_attn remat, remat off
-    assert not fused_bwd_supported(
+    # pp > 1 (PR 63): the 1F1B tick's backward unit runs the two layer
+    # scans for a dense model without cp or SP (tests/test_pp_engines.py);
+    # AFAB differentiates through its scan and stays AD
+    assert fused_bwd_supported(
         engine_cfg("auto", dist_kw={"dp_size": 2, "pp_size": 2}))
+    assert not fused_bwd_supported(
+        engine_cfg("auto", dist_kw={"dp_size": 2, "pp_size": 2,
+                                    "pp_engine": "afab"}))
+    # still AD-only: non-dots_attn remat, remat off
     assert not fused_bwd_supported(
         engine_cfg("auto", remat_policy="dots"))
     assert not fused_bwd_supported(engine_cfg("auto", remat=False))
@@ -250,8 +256,8 @@ def test_fused_rejects_unsupported_config():
     with pytest.raises(ValueError, match="fused"):
         engine_cfg("fused", remat_policy="dots").validate()
     with pytest.raises(ValueError, match="fused"):
-        engine_cfg("fused",
-                   dist_kw={"dp_size": 2, "pp_size": 2}).validate()
+        engine_cfg("fused", dist_kw={"dp_size": 2, "pp_size": 2,
+                                     "pp_engine": "afab"}).validate()
 
 
 # ---------------------------------------------------------------------------

@@ -80,9 +80,11 @@ def lowered_train_step(engine: str, pp: int, moe: bool):
     ("fused", 1, {"dw_accum"}),
     ("ad", 1, set()),
     ("ad", 2, {"pp_boundary"}),
+    # PR 63: the 1F1B tick's backward unit runs the manual backward's scans
+    ("fused", 2, {"dw_accum", "pp_boundary"}),
     ("fused", 1, {"dw_accum"} | MOE),
     ("ad", 1, MOE),
-], ids=["fused", "ad", "ad-pp2", "fused-moe", "ad-moe"])
+], ids=["fused", "ad", "ad-pp2", "fused-pp2", "fused-moe", "ad-moe"])
 def test_train_step_scopes_and_module_name(engine, pp, extra):
     _, text = lowered_train_step(engine, pp, extra >= MOE)
     assert module_name(text) == "jit_train_step"
@@ -92,8 +94,9 @@ def test_train_step_scopes_and_module_name(engine, pp, extra):
 
 
 @pytest.mark.parametrize("engine,pp,moe", [
-    ("fused", 1, False), ("ad", 2, False), ("fused", 1, True),
-], ids=["fused", "1f1b", "fused-moe"])
+    ("fused", 1, False), ("ad", 2, False), ("fused", 2, False),
+    ("fused", 1, True),
+], ids=["fused", "1f1b", "1f1b-fused", "fused-moe"])
 def test_label_backward_is_under_head_ce(engine, pp, moe):
     """The label pick's backward (ops/losses.py) is a custom_vjp rule, traced
     where the engine applies the VJP and not where the forward's `head_ce`
