@@ -1,10 +1,11 @@
 """MPMD pipeline executor: per-stage jitted programs + a host-side schedule.
 
 The SPMD 1f1b engine (parallel/pp.py) runs the whole pipeline as ONE jitted
-lockstep scan: every device executes every tick's traced unit whether its
-schedule slot is active or not, so an IDLE tick costs a full forward+backward
-unit (PERF.md r4 measured 64.7 ms/tick with an implied bubble of 7.0 ticks at
-pp=4). This module is the fix from "Scaling Deep Learning Training with MPMD
+lockstep scan: every device steps through every tick, and until PR 65 ran
+every tick's traced units whether its schedule slot was active or not, so an
+IDLE tick cost a full forward+backward unit (PERF.md r4 measured 64.7 ms/tick
+with an implied bubble of 7.0 ticks at pp=4; since PR 65 a tick costs its
+slowest stage's live units, see `schedule_stats`). This module is the fix from "Scaling Deep Learning Training with MPMD
 Pipeline Parallelism" (arxiv 2412.14374): compile one program per pipeline
 stage (each tracing ONLY its own layer block) and drive them from a host-side
 schedule table — an idle tick dispatches nothing and costs ~0, which is what
@@ -75,7 +76,9 @@ from picotron_tpu.models.llama import (
 )
 from picotron_tpu.optimizer import make_optimizer
 from picotron_tpu.parallel.api import make_parallel_ctx
-from picotron_tpu.parallel.pp import _cast_varying_like, _vary_over
+from picotron_tpu.parallel.pp import (
+    _cast_varying_like, _vary_over, pp_1f1b_ticks, units_in_branches,
+)
 from picotron_tpu.parallel.sharding import batch_spec, param_shardings, param_specs
 from picotron_tpu.train_step import TrainState, guard_nonfinite
 
@@ -305,25 +308,47 @@ def lint_schedule(table: list, n_micro: int, pp: int,
 
 
 def schedule_stats(kind: str, n_micro: int, pp: int,
-                   interleave: int = 1) -> dict:
+                   interleave: int = 1, gated: bool = True) -> dict:
     """Tick accounting for a schedule, in full units (1 unit = one stage's
-    forward + backward for one microbatch — the SPMD scan's per-tick cost).
+    forward + backward for one microbatch — a steady SPMD tick's cost).
 
     kind="spmd" prices the lockstep scan twin closed-form: n + 2(pp-1)
-    ticks, EVERY tick a full unit on every device, so bubble = 2(pp-1)
-    units. MPMD schedules are priced off the simulated table: makespan
+    ticks of two unit slots a stage, the forward unit (a quarter of a full
+    unit) and the backward unit (three quarters: it runs the forward again,
+    and each backward half costs about a forward — the ZB-H1 assumption
+    below). `gated` (pp.units_in_branches: the 1F1B engine, no ring over cp)
+    says each unit runs only where its stage holds a microbatch for it, so a
+    tick costs its slowest stage's live units and the first and last pp-1
+    ticks are partial; otherwise EVERY tick is a full unit on every device
+    and bubble = 2(pp-1) units. `units_live` counts the slots that hold a
+    microbatch a later unit needs (the last stage's forward unit never
+    does: its backward unit runs that forward), `units_skipped` the slots
+    whose unit does not run (none ungated: the rest run on zeros).
+    MPMD schedules are priced off the simulated table: makespan
     ticks / ticks-per-unit, where a full unit spans 2v chunk-ops (3v under
-    the zb split, whose halves each cost ~a forward — the ZB-H1
-    assumption). busy is always n_micro units; the bubble is the rest.
+    the zb split, whose halves each cost ~a forward). busy is always
+    n_micro units; the bubble is the rest.
     """
     if kind == "spmd":
-        makespan = float(n_micro + 2 * (pp - 1))
+        ticks = pp_1f1b_ticks(n_micro, pp)
+        # [tick][stage] -> (forward unit live, backward unit live)
+        live = [[(s < pp - 1 and 0 <= t - s < n_micro,
+                  0 <= t - 2 * (pp - 1) + s < n_micro) for s in range(pp)]
+                for t in range(ticks)]
+        units_live = sum(f + b for row in live for f, b in row)
+        makespan = (sum(max(0.25 * f + 0.75 * b for f, b in row)
+                        for row in live)
+                    # (pp 1 has no fill or drain, and its one stage's tick is
+                    # the backward unit alone: the full unit of this account)
+                    if gated and pp > 1 else float(ticks))
+        bubble = makespan - n_micro
         return {
             "kind": kind, "n_micro": n_micro, "pp": pp, "interleave": 1,
-            "ticks": n_micro + 2 * (pp - 1), "makespan_units": makespan,
-            "busy_units": float(n_micro),
-            "bubble_units": float(2 * (pp - 1)),
-            "bubble_fraction": 2 * (pp - 1) / makespan if makespan else 0.0,
+            "ticks": ticks, "makespan_units": makespan,
+            "busy_units": float(n_micro), "bubble_units": bubble,
+            "bubble_fraction": bubble / makespan if makespan else 0.0,
+            "units_live": units_live,
+            "units_skipped": 2 * pp * ticks - units_live if gated else 0,
         }
     table = build_schedule(kind, n_micro, pp, interleave)
     v = interleave if kind == "interleaved" else 1
@@ -342,16 +367,17 @@ def schedule_stats(kind: str, n_micro: int, pp: int,
 def pipeline_bubble_fraction(cfg: Config) -> float:
     """Static schedule-derived idle fraction of a step for this config (0.0
     when pp == 1) — what telemetry books under the 'pp_bubble' goodput
-    category. For the SPMD executor this is the lockstep scan's full-price
-    accounting; for MPMD it comes off the simulated table."""
+    category. For the SPMD executor this is the lockstep scan's accounting
+    (partial fill and drain ticks where the engine gates its units, the
+    full price elsewhere); for MPMD it comes off the simulated table."""
     pp = cfg.distributed.pp_size
     if pp <= 1:
         return 0.0
     n = cfg.training.gradient_accumulation_steps
     kind = ("spmd" if cfg.pipeline.executor == "spmd"
             else cfg.pipeline.schedule)
-    return schedule_stats(kind, n, pp, cfg.pipeline.interleave)[
-        "bubble_fraction"]
+    return schedule_stats(kind, n, pp, cfg.pipeline.interleave,
+                          units_in_branches(cfg))["bubble_fraction"]
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,11 @@ m + 2(pp-1) - s — each steady-state tick executes one active forward AND
 one active backward per stage, finishing in n_micro + 2(pp-1) ticks (see
 pipeline_1f1b_grads for the schedule/memory analysis). On the last stage the
 two are the same microbatch and the forward has no consumer, so that stage's
-tick is the backward unit alone (which runs the forward once). Activation
+tick is the backward unit alone (which runs the forward once). In the first
+and last pp-1 ticks a stage holds a microbatch for one unit only, or for
+none, and runs only that: each unit sits in a `lax.cond` by whether its
+schedule slot is live (PR 65), so a tick costs its slowest stage's live
+units. Activation
 cotangents ride a reverse ppermute; parameter gradients accumulate in the
 scan carry, each leaf written where its gradient is produced and on the
 stage that produces it (no per-tick gradient tree, no whole-tree add; the
@@ -53,18 +57,19 @@ activation memory.
 
 **Why no Megatron interleaved (virtual-stage) schedule UNDER THIS
 EXECUTOR** (`pipeline.executor: spmd`, the default): with v chunks per
-device the pipeline deepens to V = v*pp virtual stages, and in a
-masked-uniform SPMD tick model every tick must trace each device's v
-forward + v backward units whether active or not — so fill/drain cost
-grows with V while per-tick cost grows with v, making interleaving
-STRICTLY worse here (efficiency n/(n + 2(V-1)) vs this schedule's
-n/(n + 2(pp-1))). Interleaving wins on per-rank imperative runtimes
-because idle warmup slots cost nothing; under jit they cost a full traced
-unit (PERF.md r4 measured ~one traced unit per idle tick). Gating the
-units with lax.cond (the head-scoring trick) cannot recover it either: a
-skipped unit still occupies its tick slot in the schedule. Under the scan
-the lever for bubble fraction is more microbatches (n), amortized at
-2(pp-1)/n.
+device the pipeline deepens to V = v*pp virtual stages, and the lockstep
+scan runs n + 2(V-1) ticks, each holding a device's v forward + v backward
+unit slots and ending in the two ppermutes every stage joins — so the
+number of fill/drain ticks grows with V while per-tick cost grows with v,
+making interleaving worse here (efficiency n/(n + 2(V-1)) vs this
+schedule's n/(n + 2(pp-1)) with every tick at full price). Interleaving
+wins on per-rank imperative runtimes because a rank moves on as soon as
+its own slot is done; here a tick lasts as long as its slowest stage's live
+units (PERF.md r4 measured ~one traced unit per idle tick when every slot
+ran; since PR 65 a slot that holds no microbatch is skipped, which makes
+the 2(pp-1) fill and drain ticks partial, not free: some stage is live in
+each). Under the scan the remaining lever for bubble fraction is more
+microbatches (n).
 
 `pipeline.executor: mpmd` (parallel/mpmd.py) is the executor where that
 premise does not hold: per-stage programs driven by a host-side schedule
@@ -133,7 +138,12 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
     (VERDICT r2 weak #2; the reference runs the head only on the last stage,
     ref: pipeline_parallel.py:53-63).
 
-    The rule for a branch taken by stage: NO COLLECTIVE OVER 'pp' IN IT,
+    The rule for a branch taken by stage — by a predicate on the stage
+    index and the scan counter alone, which every device of one stage
+    computes alike: `s_idx == pp - 1` and `s_idx == 0` here and in the 1F1B
+    backward unit, `(s_idx == pp - 1) | ~f_on` round the 1F1B forward unit
+    and `b_on` round its backward unit (f_on / b_on: the stage holds a
+    microbatch for the unit at this tick) — NO COLLECTIVE OVER 'pp' IN IT,
     AND NO PPERMUTE OVER ANY AXIS. A collective whose replica group spans
     devices that take different branches leaves the in-branch members
     waiting on peers that never arrive (observed as a rendezvous deadlock
@@ -152,8 +162,9 @@ def _make_stage_fn(ids, tgt, m, ctx: ParallelCtx, cos, sin, s_idx, pp):
     scoring cond's BACKWARD has held such a collective all along (until
     PR 63 the compiled four-chip step had a tp all-reduce in each branch of
     `transpose(jvp(head_ce))/cond`). 1F1B relies on the rule for a whole
-    layer block in its forward unit and for the whole head, forward and
-    backward, in its backward unit (pipeline_1f1b_grads).
+    layer block in its forward unit and for its whole backward unit (the
+    layer block forward and backward with their tp all-reduces, the head
+    and the lookup in branches of their own inside it): pipeline_1f1b_grads.
 
     The scoring branch computes this tp shard's local softmax stats
     (vocab_parallel_ce_local_stats; zero FLOPs off the last stage) and the
@@ -345,6 +356,18 @@ def pp_1f1b_ring_slots(n_micro: int, pp: int) -> int:
     return max(1, min(n_micro, 2 * (pp - 1)))
 
 
+def units_in_branches(cfg: Config) -> bool:
+    """Whether a pipeline tick's two units each sit in a branch taken only
+    where the stage holds a microbatch for the unit: the 1F1B engine's tick
+    (pipeline_1f1b_grads), unless cp > 1 — the layers then hold a ring of
+    ppermutes, which may not sit in a branch (_make_stage_fn's rule), and
+    both units run masked-uniform on every stage in every tick. AFAB is
+    differentiated through and keeps its units whole (a differentiated cond
+    makes both branches hand over residuals)."""
+    d = cfg.distributed
+    return d.pp_engine == "1f1b" and d.cp_size == 1
+
+
 def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
     """1F1B engine: (grads, nll_sum, valid_count, drop_weighted_sum),
     pipelined over 'pp'.
@@ -385,7 +408,26 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
     (b == f) and the backward consumes the live x_buf directly, not the
     ring.
 
-    What a tick runs. The *backward unit* takes microbatch m_b through the
+    What a tick runs: each of its two units only where the stage holds a
+    microbatch for it at this tick (PR 65), the two ppermutes always. The
+    forward unit's slot is live at stage s on ticks s .. s + n_micro - 1
+    (`f_on`), the backward unit's on ticks 2(pp-1) - s .. 2(pp-1) - s +
+    n_micro - 1 (`b_on`); each unit sits in a `lax.cond` on its predicate,
+    which depends on the stage index and the scan counter alone, so every
+    peer of a tp / ep / dp collective inside takes the same branch
+    (_make_stage_fn's rule). A fill tick (t < pp-1: no stage holds a
+    backward) therefore costs a forward unit, a drain tick (t >=
+    n_micro + pp-1: no forward left) a backward unit, and the ticks between
+    whatever the slowest stage's live units cost; a skipped unit added
+    exact zeros before (its cotangents were zero), so the sums are the
+    same. At pp 2 with 8 microbatches: tick 0 is stage 0's forward unit
+    alone and tick 9 its backward unit alone, where each cost a whole tick.
+    `units_in_branches` says where this holds: with cp > 1 the layers hold
+    a ring of ppermutes, which the rule forbids in a branch, and both units
+    run masked-uniform on every stage in every tick (on zeros off their
+    slots, as everywhere until PR 65).
+
+    The *backward unit* takes microbatch m_b through the
     stage a second time, forward then backward, and every weight gradient
     lands in the fp32 accumulator `g_acc` WHERE IT IS PRODUCED, on the
     stage that produces it (PR 63) — the tick builds no gradient tree and
@@ -419,19 +461,21 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
        touched twice, once in each branch.
 
     A stage passes the accumulators it does not use through the other
-    branch untouched; tests/test_chip_compile.py holds that the compiled
-    tick neither copies nor fills nor adds a head- or embedding-shaped leaf
-    outside a branch. Every branch follows _make_stage_fn's rule: a tp
-    all-reduce may sit in it, nothing over 'pp', no ppermute.
+    branch untouched, and a tick without a backward passes them all through
+    the unit's idle branch, which returns zeros for the loss term, the drop
+    sums and the cotangent it sends on; tests/test_chip_compile.py holds
+    that the compiled tick neither copies nor fills nor adds an
+    accumulator-shaped leaf outside a live branch. Every branch follows
+    _make_stage_fn's rule: a tp all-reduce may sit in it, nothing over
+    'pp', no ppermute.
 
     The *forward unit* is the layer block alone at microbatch m_f
     (`score=False`: no head, no merge collectives), and exists to feed the
     next stage. On the last stage m_f == m_b and nothing consumes y, so the
-    unit sits in a `lax.cond` whose last-stage branch is zeros: that stage
-    computes each microbatch's forward once (a second forward of layers and
-    head there is a third of the tick of the stage that sets the step).
-    With cp > 1 the layers may hold a ring of ppermutes, which the rule
-    forbids: the unit then runs masked-uniform on every stage.
+    unit's `lax.cond` takes its zeros branch there as well as off a forward
+    tick: that stage computes each microbatch's forward once (a second
+    forward of layers and head there is a third of the tick of the stage
+    that sets the step).
 
     Grads of pp-replicated params (embedding / final norm / head) come out
     nonzero only on the stage that uses them — pass through
@@ -461,6 +505,7 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
                  "lm_head" if "lm_head" in params else "embedding")
     data_pp = {"dp", "ep", "cp", "pp"}
     on_boundary = set(_boundary_axes(ctx))
+    gated = units_in_branches(cfg)
 
     def into(acc, g):
         """acc + g: a gradient in the compute dtype, cast to the fp32
@@ -479,9 +524,8 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
                                           keepdims=False)
 
         # ---- forward unit: microbatch m_f advances one stage ----
-        # Layers only, and nothing on the last stage, whose y has no
-        # consumer (docstring) — unless the layers hold a ring over cp,
-        # whose ppermutes may not sit in a branch by stage. Not
+        # Layers only, and only where a later stage will read the result:
+        # not on the last stage (docstring), not off a forward tick. Not
         # differentiated, so the pcast in the zeros branch transposes to
         # nothing.
         df = t - s_idx
@@ -491,9 +535,9 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         def layers_fwd_unit(p, xb):
             return stage_fn(p, xb, m_f, f_on, score=False)
 
-        if cfg.distributed.cp_size == 1:
+        if gated:
             y = lax.cond(
-                s_idx == pp - 1,
+                (s_idx == pp - 1) | ~f_on,
                 lambda p, xb: _cast_varying_like(jnp.zeros_like(xb), xb),
                 layers_fwd_unit, params, x_buf)
         else:
@@ -510,94 +554,121 @@ def pipeline_1f1b_grads(params, ids, tgt, cfg: Config, ctx: ParallelCtx):
         mb_ids = lax.dynamic_index_in_dim(ids, m_b, 0, keepdims=False)
         mb_tgt = lax.dynamic_index_in_dim(tgt, m_b, 0, keepdims=False)
         count = jnp.sum(mb_tgt != IGNORE_INDEX)
-        # The loss's cotangent is 1 on EVERY stage that ran m_b (the CE
-        # counts on the last stage only, each stage's MoE aux term on its
-        # own) and 0 on a tick without a backward: g_buf is zeros then too,
-        # every gradient below is linear in the two, so nothing needs a mask.
-        g_nll = _vary_over(jnp.where(b_on, 1.0, 0.0), data_pp)
 
-        # Ingest, in a branch by stage: stage 0 looks the microbatch up
-        # (zero-masked off a backward tick: all bubble compute runs on
-        # zeros, which every op here keeps finite), every other stage takes
-        # its saved input — the last one this tick's live x_buf, since
-        # there b(m) == f(m).
-        def lookup(emb):
-            return embed({"embedding": emb}, mb_ids, m, ctx) \
-                * b_on.astype(dtype)
+        def backward_unit(g_acc, x_saved, g_buf, live):
+            """-> (g_acc, contrib, dropw, dx_in). `live` says whether the
+            stage holds a backward this tick: True in the branch by b_on,
+            the traced b_on where the unit runs masked-uniform."""
+            # The loss's cotangent is 1 on EVERY stage that ran m_b (the CE
+            # counts on the last stage only, each stage's MoE aux term on
+            # its own). Masked-uniform it is 0 on a tick without a backward:
+            # g_buf is zeros then too, every gradient below is linear in the
+            # two, so nothing else needs a mask.
+            g_nll = _vary_over(jnp.where(live, 1.0, 0.0), data_pp)
+
+            # Ingest, in a branch by stage: stage 0 looks the microbatch up
+            # (masked-uniform: zeroed off a backward tick, so that all
+            # bubble compute runs on zeros, which every op here keeps
+            # finite), every other stage takes its saved input — the last
+            # one this tick's live x_buf, since there b(m) == f(m).
+            def lookup(emb):
+                x = embed({"embedding": emb}, mb_ids, m, ctx)
+                return x if live is True else x * live.astype(dtype)
+
+            x_in = lax.cond(
+                s_idx == 0,
+                lambda emb, xs: _vary_over(lookup(emb), on_boundary),
+                lambda emb, xs: xs, params["embedding"], x_saved)
+
+            # the layer block, forward
+            def weighted(aux):
+                # llama.loss_sum_count's folding rule: this stage's layers'
+                # (pre-weighted) router loss scaled by the token count,
+                # which joins the loss; and the same-scaled drop / load sums
+                return ((aux[0] * count,) if m.num_experts else ()), \
+                    aux[1:] * count
+
+            if accumulate:
+                x_out, saved, aux_layers = layers_fwd(x_in)
+                fold, dropw = weighted(jnp.sum(aux_layers, axis=0))
+            else:
+                def block(lp, x):
+                    y_, aux = run_layers(lp, x, m, ctx, cos, sin)
+                    fold, dropw = weighted(aux)
+                    return (y_,) + fold, dropw
+
+                (x_out, *fold), vjp_block, dropw = jax.vjp(
+                    block, params["layers"], x_in, has_aux=True)
+
+            # The head, forward AND backward, in the branch the last stage
+            # takes: final norm, head matmul, CE (its tp merge too) and
+            # their gradients, the head's and the norm's landing in their
+            # accumulators there. Every other stage passes its accumulators
+            # through and hands the layer block the cotangent that arrived
+            # from the next stage.
+            def score(x, nl, acc):
+                total, _, dx, g_nl = head_grads(x, nl, mb_tgt, cfg, ctx,
+                                                g_nll)
+                with scope("head_ce"):
+                    acc = {k: into(acc[k], g_nl[k]) for k in acc}
+                return (_vary_over(total, data_pp),
+                        _vary_over(dx, on_boundary), acc)
+
+            def no_score(x, nl, acc):
+                return (_vary_over(jnp.zeros((), jnp.float32), data_pp),
+                        g_buf, acc)
+
+            contrib, dx_out, acc_head = lax.cond(
+                s_idx == pp - 1, score, no_score, x_out,
+                {k: params[k] for k in head_keys},
+                {k: g_acc[k] for k in head_keys})
+            g_acc = {**g_acc, **acc_head}
+
+            # the layer block, backward: each layer's dW into the
+            # accumulator inside the reverse scan, or AD's tree for the
+            # stack and one add
+            if fold:
+                contrib = contrib + fold[0]
+            if accumulate:
+                # (the scan's aux fold is `aux * weight` with cotangent 1:
+                # the token count, times the loss's cotangent on this tick)
+                dx_in, g_layers = layers_bwd(saved, dx_out, g_acc["layers"],
+                                             count * g_nll)
+            else:
+                g_lp, dx_in = vjp_block(
+                    (dx_out,)
+                    + tuple(_cast_varying_like(g_nll, f) for f in fold))
+                g_layers = jax.tree.map(into, g_acc["layers"], g_lp)
+
+            # the embedding's rows, scattered onto its accumulator on
+            # stage 0
+            def lookup_bwd(acc, dx):
+                with scope("embed"):
+                    _, vjp_lookup = jax.vjp(lookup, params["embedding"])
+                    (g_emb,) = vjp_lookup(dx)
+                    return into(acc, g_emb)
+
+            g_emb_acc = lax.cond(s_idx == 0, lookup_bwd, lambda acc, dx: acc,
+                                 g_acc["embedding"], dx_in)
+            g_acc = {**g_acc, "layers": g_layers, "embedding": g_emb_acc}
+            return (g_acc, _vary_over(contrib, data_pp),
+                    _vary_over(dropw, data_pp), dx_in)
+
+        def idle_unit(g_acc, x_saved, g_buf):
+            """A tick without a backward: the accumulators as they came,
+            and zeros typed as backward_unit's other results."""
+            return (g_acc, _vary_over(jnp.zeros((), jnp.float32), data_pp),
+                    _vary_over(jnp.zeros((2,), jnp.float32), data_pp),
+                    _cast_varying_like(jnp.zeros_like(g_buf), g_buf))
 
         x_saved = jnp.where(s_idx == pp - 1, x_buf, x_ring)
-        x_in = lax.cond(
-            s_idx == 0,
-            lambda emb, xs: _vary_over(lookup(emb), on_boundary),
-            lambda emb, xs: xs, params["embedding"], x_saved)
-
-        # the layer block, forward
-        def weighted(aux):
-            # llama.loss_sum_count's folding rule: this stage's layers'
-            # (pre-weighted) router loss scaled by the token count, which
-            # joins the loss; and the same-scaled drop / load sums
-            return ((aux[0] * count,) if m.num_experts else ()), \
-                aux[1:] * count
-
-        if accumulate:
-            x_out, saved, aux_layers = layers_fwd(x_in)
-            fold, dropw = weighted(jnp.sum(aux_layers, axis=0))
+        if gated:
+            g_acc, contrib, dropw, dx_in = lax.cond(
+                b_on, lambda *a: backward_unit(*a, True), idle_unit,
+                g_acc, x_saved, g_buf)
         else:
-            def block(lp, x):
-                y_, aux = run_layers(lp, x, m, ctx, cos, sin)
-                fold, dropw = weighted(aux)
-                return (y_,) + fold, dropw
-
-            (x_out, *fold), vjp_block, dropw = jax.vjp(
-                block, params["layers"], x_in, has_aux=True)
-
-        # The head, forward AND backward, in the branch the last stage
-        # takes: final norm, head matmul, CE (its tp merge too) and their
-        # gradients, the head's and the norm's landing in their
-        # accumulators there. Every other stage passes its accumulators
-        # through and hands the layer block the cotangent that arrived
-        # from the next stage.
-        def score(x, nl, acc):
-            total, _, dx, g_nl = head_grads(x, nl, mb_tgt, cfg, ctx, g_nll)
-            with scope("head_ce"):
-                acc = {k: into(acc[k], g_nl[k]) for k in acc}
-            return (_vary_over(total, data_pp),
-                    _vary_over(dx, on_boundary), acc)
-
-        def no_score(x, nl, acc):
-            return (_vary_over(jnp.zeros((), jnp.float32), data_pp),
-                    g_buf, acc)
-
-        contrib, dx_out, acc_head = lax.cond(
-            s_idx == pp - 1, score, no_score, x_out,
-            {k: params[k] for k in head_keys},
-            {k: g_acc[k] for k in head_keys})
-        g_acc = {**g_acc, **acc_head}
-
-        # the layer block, backward: each layer's dW into the accumulator
-        # inside the reverse scan, or AD's tree for the stack and one add
-        if fold:
-            contrib = contrib + fold[0]
-        if accumulate:
-            # (the scan's aux fold is `aux * weight` with cotangent 1: the
-            # token count, times the loss's cotangent on this tick)
-            dx_in, g_layers = layers_bwd(saved, dx_out, g_acc["layers"],
-                                         count * g_nll)
-        else:
-            g_lp, dx_in = vjp_block(
-                (dx_out,) + tuple(_cast_varying_like(g_nll, f) for f in fold))
-            g_layers = jax.tree.map(into, g_acc["layers"], g_lp)
-
-        # the embedding's rows, scattered onto its accumulator on stage 0
-        def lookup_bwd(acc, dx):
-            with scope("embed"):
-                _, vjp_lookup = jax.vjp(lookup, params["embedding"])
-                (g_emb,) = vjp_lookup(dx)
-                return into(acc, g_emb)
-
-        g_emb_acc = lax.cond(s_idx == 0, lookup_bwd, lambda acc, dx: acc,
-                             g_acc["embedding"], dx_in)
-        g_acc = {**g_acc, "layers": g_layers, "embedding": g_emb_acc}
+            g_acc, contrib, dropw, dx_in = backward_unit(
+                g_acc, x_saved, g_buf, b_on)
 
         # The loss is read where it is computed: the backward unit's
         # primal. Every microbatch has exactly one backward on every stage.

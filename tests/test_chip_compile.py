@@ -66,6 +66,13 @@ BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
 # re-run of the o-projection is the forward's, merged by the compiler with
 # its all-reduce, where remat's had its own (-3: at the cell's three layers a
 # stage the two scans are loops and each keeps its own).
+# PR 65 (each unit of the tick in a branch by whether the stage holds a
+# microbatch for it): still 48, the same names. The backward unit's
+# collectives moved into its conditional's live branch with it (8 all-reduces
+# there, of which 1 + 3 sit in the lookup's and the head's branches by stage
+# inside it); the forward unit's 3 stay in the branch PR 38 made, whose
+# predicate widened; the two collective-permutes stay at the tick's top
+# level, once a tick on every stage.
 N_COLLECTIVES = 48
 # temp_size_in_bytes of the four-chip step at one layer a stage on the parent
 # of PR 38 (commit 977113c, this installation), whose tick held the forward
@@ -75,6 +82,13 @@ PARENT_FOUR_CHIP_TEMP_BYTES = 11_745_135_104
 # microbatch's whole gradient tree (the stage's layer stack, a zero-filled
 # head and embedding) before it added the tree into the accumulator
 PARENT_63_FOUR_CHIP_TEMP_BYTES = 10_661_128_192
+# the same at the cell's own depth (three layers a stage) on the parent of
+# PR 65 (commit 1d8b6d8), whose tick ran both units on every stage in every
+# tick. PR 65's reads 9,314,992,640, 0.77% more: the backward unit's
+# conditional keeps what it reads live from its start to its end (the two
+# RoPE tables, buffers of the boundary's size), where the parent's scheduler
+# let them share a slot with the unit's temporaries
+PARENT_65_FOUR_CHIP_TEMP_BYTES_AT_DEPTH = 9_243_948_032
 
 
 @pytest.fixture(scope="module")
@@ -196,7 +210,8 @@ def test_four_chip_last_stage_runs_each_forward_once(topo, monkeypatch):
     stage, which sets the step, ran the layers' forward and the head's
     forward twice a tick. PR 63: the backward unit's layer block is the
     manual backward's two scans, and the head's forward and backward share
-    the one branch the last stage takes."""
+    the one branch the last stage takes. PR 65: each unit sits in a branch
+    of its own, taken where the stage holds a microbatch for it."""
     comp = compile_step(topo, monkeypatch, "qwen2-7b-6l-tp2pp2", layers=2)
     text = comp.as_text()
     ins = instructions(text)
@@ -209,20 +224,34 @@ def test_four_chip_last_stage_runs_each_forward_once(topo, monkeypatch):
               and re.search(r"= bf16\[(1,)?4096,76032\]", line)]
     assert len(logits) == 1 and "jvp(head_ce)" in logits[0], logits
     assert "cond/branch_1_fun" in logits[0], logits
-    # four branches by stage a tick, none with a collective that crosses
-    # stages (devices 0, 1 are stage 0; 2, 3 the last): the forward unit's
-    # layer block (the embedding's and the layers' tensor-parallel
-    # all-reduces) against nothing; the backward unit's lookup (the
-    # embedding's) against the saved input; the head (the CE's merge, pmax
-    # and a tuple, and dx's) against the next stage's cotangent; the
-    # embedding's rows onto their accumulator (no collective)
-    conds = [branch_collectives(text, line) for _, op, line in ins
-             if " conditional(" in line and op.endswith("closed_call/cond")]
-    assert sorted(sorted(map(len, c)) for c in conds) == [
-        [0, 0], [0, 1], [0, 3], [0, 3]], conds
-    for line in (line for c in conds for branch in c for line in branch):
+    # PR 65: two conditionals at the tick's top level, one round each unit,
+    # taken where the stage holds a microbatch for the unit this tick. The
+    # forward unit's: nothing, against the layer block (the embedding's and
+    # the layers' tensor-parallel all-reduces). The backward unit's: nothing
+    # (the accumulators passed through), against the whole unit, 8
+    # all-reduces, of which 5 sit in the three branches by stage INSIDE it,
+    # as they were: the lookup (the embedding's) against the saved input;
+    # the head (the CE's merge, pmax and a tuple, and dx's) against the next
+    # stage's cotangent; the embedding's rows onto their accumulator (none).
+    # No branch holds a collective that crosses stages (devices 0, 1 are
+    # stage 0; 2, 3 the last), and no collective-permute at all.
+    def conds_at(op_suffix):
+        return [branch_collectives(text, line) for _, op, line in ins
+                if " conditional(" in line and op.endswith(op_suffix)]
+
+    def sizes(conds):
+        return sorted(sorted(map(len, c)) for c in conds)
+
+    assert sum(" conditional(" in line for _, _, line in ins) == 5
+    units = conds_at("closed_call/cond")
+    by_stage = conds_at("closed_call/cond/branch_1_fun/cond")
+    assert sizes(units) == [[0, 3], [0, 8]], units
+    assert sizes(by_stage) == [[0, 0], [0, 1], [0, 3]], by_stage
+    for line in (line for c in units + by_stage for branch in c for line in branch):
         assert " all-reduce(" in line, line
         assert "replica_groups={{0,1},{2,3}}" in line, line
+    sends = [op for n, op, _ in ins if n.startswith("collective-permute")]
+    assert len(sends) == 4 and not any("cond" in words(op) for op in sends), sends
     temp = comp.memory_analysis().temp_size_in_bytes
     print(f"four-chip step: temp_size_in_bytes {temp:,} "
           f"(parent of PR 38 {PARENT_FOUR_CHIP_TEMP_BYTES:,}, "
@@ -235,6 +264,21 @@ def test_four_chip_last_stage_runs_each_forward_once(topo, monkeypatch):
     kv = m["num_key_value_heads"] * h // m["num_attention_heads"]
     layer_tree = 4 * (3 * h * f + 2 * h * h + 2 * h * kv) // 2
     assert temp <= PARENT_63_FOUR_CHIP_TEMP_BYTES - layer_tree, (temp, layer_tree)
+
+
+_MOVES_NOTHING = re.compile(
+    r" (parameter|get-tuple-element|tuple|bitcast|conditional|while)\(")
+
+
+def makes_f32_of(comps: dict, names, sizes) -> list:
+    """The instructions of the computations `names` (fused bodies apart) whose
+    result is an fp32 array of one of `sizes` elements and a buffer of its
+    own: no parameter, tuple, bitcast, conditional or while."""
+    return [line.strip() for name in sorted(names)
+            if not name.startswith("fused_computation")
+            for line in comps[name]
+            if "f32[" in line and set(result_sizes(line)) & set(sizes)
+            and not _MOVES_NOTHING.search(line)]
 
 
 def test_four_chip_tick_touches_a_leaf_only_where_it_is_used(topo, monkeypatch):
@@ -258,25 +302,71 @@ def test_four_chip_tick_touches_a_leaf_only_where_it_is_used(topo, monkeypatch):
     in_branch = reachable(comps, {
         b for _, _, line in ins if " conditional(" in line
         for b in called(line)})
-    moves_nothing = re.compile(
-        r" (parameter|get-tuple-element|tuple|bitcast|conditional|while)\(")
-
-    def makes_a_leaf(line):
-        return ("f32[" in line and leaf in result_sizes(line)
-                and not moves_nothing.search(line))
-
-    outside = [line.strip()[:200] for name in sorted(in_loop - in_branch)
-               if not name.startswith("fused_computation")
-               for line in comps[name] if makes_a_leaf(line)]
-    assert not outside, outside
-    inside = [line for name in sorted(in_loop & in_branch)
-              if not name.startswith("fused_computation")
-              for line in comps[name] if makes_a_leaf(line)]
+    outside = makes_f32_of(comps, in_loop - in_branch, [leaf])
+    assert not outside, [line[:200] for line in outside]
+    inside = makes_f32_of(comps, in_loop & in_branch, [leaf])
     assert len(inside) == 2 and all(" fusion(" in line for line in inside), inside
     bodies = ["\n".join(comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)])
               for line in inside]
     assert sum(" convolution(" in b for b in bodies) == 1
     assert sum(" scatter(" in b for b in bodies) == 1
+
+
+def test_four_chip_idle_tick_passes_the_accumulators_through(topo, monkeypatch):
+    """PR 65, at the cell's own depth (three layers a stage, so the two
+    layer scans are loops): the backward unit's conditional hands the whole
+    fp32 accumulator tree (3.58 GB a chip) in and out. A copy of it round
+    the conditional, or in the idle branch, would cost 9 ms a tick. Every
+    instruction of the tick that makes a result shaped like an accumulator
+    leaf (the head's, the embedding's, a layer stack's) sits in a live
+    branch and is a fusion that updates the leaf where it lies: the reverse
+    scan's `dw_accum` dynamic-update-slices, the head's dW matmul with its
+    add, the embedding's scatter. None is a copy, none sits in a branch
+    that only passes the accumulators through, none outside a branch."""
+    comp = compile_step(topo, monkeypatch, "qwen2-7b-6l-tp2pp2", layers=None)
+    text = comp.as_text()
+    comps = computations(text)
+    ins = instructions(text)
+    d = load("configs", "qwen2-7b-6l-tp2pp2")["distributed"]
+    # a chip's share of each matrix: a stack's layers over pp, one other axis
+    # over tp. Four sizes: embedding = head, gate = up = down, q = o, k = v
+    leaves = {math.prod(a.shape) // d["tp_size"] // (d["pp_size"] if a.ndim == 3 else 1)
+              for a in jax.tree.leaves(abstract_params("qwen2-7b-6l-tp2pp2"))
+              if math.prod(a.shape) >= 2**20}
+    assert len(leaves) == 4, leaves
+    in_loop = loop_computations(text, comps)
+    conds = [line for _, _, line in ins if " conditional(" in line]
+    assert len(conds) == 5
+    def made(names):
+        return makes_f32_of(comps, names, leaves)
+
+    in_branch = reachable(comps, {b for line in conds for b in called(line)})
+    assert not made(in_loop - in_branch), [
+        line[:200] for line in made(in_loop - in_branch)]
+    live = made(in_loop & in_branch)
+    # seven stack leaves, the head's and the embedding's: once each
+    assert len(live) == 9 and all(" fusion(" in line for line in live), live
+    assert not any(re.match(r"%?copy", line) for line in live), live
+    # the backward unit's conditional is the one whose branches reach the
+    # reverse scan; its other branch, and whatever that calls, makes no leaf
+    unit = [line for line in conds
+            if any(made(reachable(comps, [b])) for b in called(line))
+            and "closed_call/cond\"" in line]
+    assert len(unit) == 1, unit
+    idle = [b for b in called(unit[0]) if not made(reachable(comps, [b]))]
+    assert len(idle) == 1, called(unit[0])
+    # (what it does make: the zero cotangent it sends on, one boundary
+    # activation, and scalars)
+    c = load("configs", "qwen2-7b-6l-tp2pp2")
+    boundary = (c["training"]["micro_batch_size"] * c["training"]["seq_length"]
+                * c["model"]["hidden_size"])
+    big = [line.strip()[:160] for line in comps[idle[0]]
+           if not _MOVES_NOTHING.search(line) and max(result_sizes(line), default=0) > boundary]
+    assert not big, big
+    temp = comp.memory_analysis().temp_size_in_bytes
+    print(f"four-chip step at depth: temp_size_in_bytes {temp:,} "
+          f"(parent of PR 65 {PARENT_65_FOUR_CHIP_TEMP_BYTES_AT_DEPTH:,})")
+    assert temp <= PARENT_65_FOUR_CHIP_TEMP_BYTES_AT_DEPTH * 1.01
 
 
 def test_olmoe_step_keeps_kernels_scopes_and_fits_one_chip(topo, monkeypatch):

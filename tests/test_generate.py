@@ -32,12 +32,16 @@ def teacher_forced_cache_logits(params, cfg, ids):
     b, n = ids.shape
     cos, sin = model_rope_tables(cfg)
     cache = init_cache(cfg, b, n)
+    @jax.jit  # one compile for the n positions: eagerly, each call's scans
+    def step(params, cache, tok, pos):  # compile anew (minutes over this file)
+        x = params["embedding"][tok].astype(compute_dtype(cfg))
+        x, cache = _decode_layers(params, x, cache, pos, cfg, cos, sin)
+        return _logits_last(params, x, cfg), cache
+
     outs = []
     for t in range(n):
-        x = params["embedding"][ids[:, t:t + 1]].astype(compute_dtype(cfg))
-        x, cache = _decode_layers(params, x, cache, jnp.array([t]), cfg,
-                                  cos, sin)
-        outs.append(_logits_last(params, x, cfg))
+        out, cache = step(params, cache, ids[:, t:t + 1], jnp.array([t]))
+        outs.append(out)
     return jnp.stack(outs, axis=1)  # [B, N, V]
 
 

@@ -123,7 +123,7 @@ def test_the_published_pattern_builds_and_its_kinds_match_layer_types():
     assert params["layers"]["q"].shape[0] == 47 and param_count(params) == num_params(cfg)
     ids = jax.random.randint(jax.random.key(0), (1, 20), 0, cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(forward(params, ids, cfg))[0]
+        got = np.asarray(jax.jit(lambda p, i: forward(p, i, cfg))(params, ids))[0]
     np.testing.assert_allclose(got, ref_logits(params, cfg, ids[0]), atol=5e-5)
     # the published sizes themselves validate, pattern and stacks and all
     full = ModelConfig(**resolve_preset("K-EXAONE-236B-A23B"))
@@ -170,7 +170,7 @@ def test_forward_matches_the_reference(share, depth):
     params = weights(cfg)
     ids = jax.random.randint(jax.random.key(2), (2, 40), 0, cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(forward(params, ids, cfg))
+        got = np.asarray(jax.jit(lambda p, i: forward(p, i, cfg))(params, ids))
     for b in range(2):
         want = ref_logits(params, cfg, ids[b])
         # float32 both sides at `highest`: what is left is the order of the sums
@@ -240,9 +240,10 @@ def test_ad_runs_through_both_stacks_and_the_layers_left_over(depth):
     cfg = tiny(**DEPTHS[depth])
     params = weights(cfg)
     ids = jax.random.randint(jax.random.key(1), (2, 24), 0, cfg.vocab_size)
-    plain = jax.grad(loss_fn)(params, ids, ids, cfg)
-    remat = jax.grad(loss_fn)(params, ids, ids, cfg,
-                             ParallelCtx(remat=True, remat_policy="dots"))
+    # (jitted: eagerly each layer scan compiles anew, a minute a depth)
+    plain = jax.jit(jax.grad(lambda p: loss_fn(p, ids, ids, cfg)))(params)
+    remat = jax.jit(jax.grad(lambda p: loss_fn(
+        p, ids, ids, cfg, ParallelCtx(remat=True, remat_policy="dots"))))(params)
     for st in cfg.stacks:
         per_layer = np.abs(np.asarray(plain[st.name]["q"])).max(axis=(1, 2))
         assert per_layer.shape == (st.layers,) and (per_layer > 0).all()

@@ -115,7 +115,7 @@ def test_forward_matches_the_reference(share):
     # longer than one sub-chunk of the chunked recurrence (64), and no multiple
     ids = jax.random.randint(jax.random.key(2), (2, 83), 0, cfg.vocab_size)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(forward(params, ids, cfg))
+        got = np.asarray(jax.jit(lambda p, i: forward(p, i, cfg))(params, ids))
     for b in range(2):
         want = ref_logits(params, cfg, ids[b])
         np.testing.assert_allclose(got[b], want, atol=5e-4)

@@ -130,16 +130,57 @@ def test_schedule_rejects_bad_args():
 
 def test_schedule_ranking_at_pp4():
     """The tick accounting the planner and bench report: at pp=4, n=8 the
-    spmd twin's full-price bubble (6 units) dominates 1f1b (3), interleaved
-    v=2 beats 1f1b (2.5), and the zero-bubble split beats both (1)."""
+    spmd twin's full-price bubble (6 units: what it costs where its units
+    run on every stage in every tick) dominates 1f1b (3), interleaved v=2
+    beats 1f1b (2.5), and the zero-bubble split beats both (1)."""
     n, pp = 8, 4
     b = {k: schedule_stats(k, n, pp, 2 if k == "interleaved" else 1)
-         ["bubble_units"] for k in ("spmd", "1f1b", "gpipe", "interleaved",
-                                    "zb")}
+         ["bubble_units"] for k in ("1f1b", "gpipe", "interleaved", "zb")}
+    b["spmd"] = schedule_stats("spmd", n, pp, gated=False)["bubble_units"]
     assert b["spmd"] == pytest.approx(6.0)
     assert b["1f1b"] == pytest.approx(3.0)
     assert b["interleaved"] < b["1f1b"]
     assert b["zb"] < b["interleaved"]
+
+
+@pytest.mark.parametrize("n,pp,live,makespan", [
+    # pp 2, 8 microbatches (the benchmark's four-chip cell): tick 0 is stage
+    # 0's forward unit alone (1/4), ticks 1, 8, 9 a backward unit (3/4), the
+    # six between full
+    (8, 2, 24, 0.25 + 3 * 0.75 + 6),
+    # pp 4: three forward-only ticks, tick 3 the last stage's first backward
+    # unit beside forward units, ticks 4-9 full (stage 2, then others, hold
+    # both), ticks 10-13 backward units alone
+    (8, 4, 56, 3 * 0.25 + 0.75 + 6 + 4 * 0.75),
+    # fewer microbatches than stages (the ring's smaller form): no stage
+    # ever holds both units. F at (t, s) for t - s in [0, n), s < pp - 1; B
+    # for t - 6 + s in [0, n):  t0 F0 | t1 F0 F1 | t2 F1 F2 | t3 F2 B3 |
+    # t4 B3 B2 | t5 B2 B1 | t6 B1 B0 | t7 B0
+    (2, 4, 14, 3 * 0.25 + 5 * 0.75),
+])
+def test_spmd_tick_is_priced_at_its_live_units(n, pp, live, makespan):
+    """PR 65: the SPMD 1F1B tick runs a unit only where its stage holds a
+    microbatch for it, so the account prices a tick at its slowest stage's
+    live units (forward unit 1/4, backward unit 3/4 of a full unit) and
+    counts the unit slots: 2 a stage a tick, of which n pp backward units
+    and n (pp - 1) forward units are live (the last stage's forward unit is
+    its backward unit's) and the rest are skipped."""
+    s = schedule_stats("spmd", n, pp)
+    ticks = n + 2 * (pp - 1)
+    assert s["ticks"] == ticks
+    assert s["units_live"] == live == n * pp + n * (pp - 1)
+    assert s["units_skipped"] == 2 * pp * ticks - live
+    assert s["makespan_units"] == pytest.approx(makespan)
+    assert s["busy_units"] == n
+    assert s["bubble_units"] == pytest.approx(makespan - n)
+    assert s["bubble_fraction"] == pytest.approx((makespan - n) / makespan)
+    # where the units run masked-uniform (a ring over cp; AFAB) every tick
+    # is a full unit on every device, as before PR 65
+    full = schedule_stats("spmd", n, pp, gated=False)
+    assert full["makespan_units"] == ticks and full["units_skipped"] == 0
+    assert full["units_live"] == live
+    assert full["bubble_units"] == pytest.approx(2 * (pp - 1))
+    assert s["bubble_units"] < full["bubble_units"]
 
 
 def test_pipeline_bubble_fraction_from_config():
@@ -152,12 +193,17 @@ def test_pipeline_bubble_fraction_from_config():
     flat = Config(distributed=DistributedConfig(), **base)
     assert pipeline_bubble_fraction(flat) == 0.0
     spmd = Config(distributed=DistributedConfig(pp_size=4), **base)
-    assert pipeline_bubble_fraction(spmd) == pytest.approx(6.0 / 14.0)
+    assert pipeline_bubble_fraction(spmd) == pytest.approx(2.5 / 10.5)
+    # the full price where the tick's units are not in branches: the layers'
+    # ring over cp, and AFAB (differentiated through, its units whole)
+    for whole in (dict(cp_size=2), dict(pp_engine="afab")):
+        cfg = Config(distributed=DistributedConfig(pp_size=4, **whole), **base)
+        assert pipeline_bubble_fraction(cfg) == pytest.approx(6.0 / 14.0)
     mpmd = Config(distributed=DistributedConfig(pp_size=4),
                   pipeline=PipelineConfig(executor="mpmd"), **base)
     assert pipeline_bubble_fraction(mpmd) == pytest.approx(
         schedule_stats("1f1b", 8, 4)["bubble_fraction"])
-    assert pipeline_bubble_fraction(mpmd) < pipeline_bubble_fraction(spmd)
+    assert pipeline_bubble_fraction(mpmd) < 6.0 / 14.0
 
 
 # ---------------------------------------------------------------------------

@@ -184,7 +184,7 @@ def test_forward_matches_the_reference(layers):
     cfg = tiny() if layers == 5 else tiny(**resolve_preset("debug-tiny-nemotron-h"))
     params = weights(cfg)
     ids = jax.random.randint(jax.random.key(2), (23,), 0, cfg.vocab_size)
-    got = np.asarray(forward(params, ids[None], cfg))[0]
+    got = np.asarray(jax.jit(lambda p, i: forward(p, i, cfg))(params, ids[None]))[0]
     np.testing.assert_allclose(got, ref_logits(params, cfg, ids), atol=2e-5)
 
 

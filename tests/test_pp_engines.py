@@ -146,6 +146,15 @@ def test_1f1b_loss_and_update_are_the_parents(name):
     np.testing.assert_allclose(abs_sum(state.params), want_sum, rtol=1e-6)
 
 
+def sub_jaxprs(eqn):
+    """The jaxprs an equation holds: a scan's body, a cond's branches."""
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
 def head_forwards_a_tick(cfg) -> int:
     """dot_generals whose result is one microbatch's logits ([tokens,
     vocab / tp]) in the body of the traced step's 1F1B scan: the head's
@@ -158,13 +167,6 @@ def head_forwards_a_tick(cfg) -> int:
     vocab = cfg.model.vocab_size // d.tp_size
     assert tokens != cfg.model.hidden_size  # or dW would read as logits
     ticks = t.gradient_accumulation_steps + 2 * (d.pp_size - 1)
-
-    def sub_jaxprs(eqn):
-        for v in eqn.params.values():
-            for x in v if isinstance(v, (tuple, list)) else (v,):
-                x = getattr(x, "jaxpr", x)
-                if hasattr(x, "eqns"):
-                    yield x
 
     def walk(jaxpr, in_scan):
         n = 0
@@ -255,6 +257,13 @@ TICK_LAYOUTS = {
     # the token count times the tick's cotangent; drops happen
     "pp2ep2-moe": dict(dk=dict(pp_size=2, ep_size=2), mk=MOE,
                        tr=dict(micro_batch_size=2)),
+    # PR 65, where most ticks are fill or drain and a unit's branch is idle
+    # more often than live: one microbatch (3 ticks, no stage ever holds
+    # both units), two at pp 2, and two at pp 4 (8 ticks, n_micro <
+    # 2(pp - 1): the ring's smaller form; "pp4" above is three); toy depths
+    "pp2-n1": dict(dk=dict(pp_size=2), mk=dict(num_hidden_layers=2), ga=1),
+    "pp2-n2": dict(dk=dict(pp_size=2), mk=dict(num_hidden_layers=2), ga=2),
+    "pp4-n2": dict(dk=dict(pp_size=4), ga=2),
 }
 # what it may not take: `auto` keeps the AD tick, and an explicit `fused` is
 # refused
@@ -294,7 +303,12 @@ def test_accumulating_tick_grads_match(name, against, rendezvous_timeout):
     fused engine to (1e-4 of the leaf's largest gradient, float32): the
     gradients the accumulating tick hands the optimizer are the AD tick's
     and AFAB's. A leaf left out of the accumulation (a stage that skipped
-    its embedding, a head added on no stage) reads as a whole leaf off."""
+    its embedding, a head added on no stage) reads as a whole leaf off.
+    Since PR 65 each unit of the tick sits in a branch by whether the stage
+    holds a microbatch for it (none with cp 2): a microbatch left out, or a
+    tick whose idle branch dropped an accumulator, moves the loss (the sum
+    over the token count, which also divides every gradient), the drop sums
+    or a leaf."""
     from picotron_tpu.parallel.fused_bwd import resolved_grad_engine
 
     layout = TICK_LAYOUTS[name]
@@ -314,10 +328,8 @@ def test_accumulating_tick_grads_match(name, against, rendezvous_timeout):
             err_msg=jax.tree_util.keystr(path))
 
 
-def tick_scopes(cfg) -> set:
-    """The declared scopes on the name stacks of the traced step."""
-    from tests.test_scopes import scopes_in
-
+def abstract_step(cfg):
+    """(jitted train step, abstract state, abstract batch) for cfg."""
     menv = MeshEnv.from_config(cfg)
     state = init_sharded_state(cfg, menv, jax.random.key(0), abstract=True)
     t = cfg.training
@@ -325,8 +337,15 @@ def tick_scopes(cfg) -> set:
         (t.gradient_accumulation_steps,
          t.micro_batch_size * cfg.distributed.dp_size, t.seq_length),
         np.int32, sharding=menv.batch_sharding())
-    return scopes_in(make_train_step(cfg, menv).lower(state, (b, b)).as_text(
-        debug_info=True))
+    return make_train_step(cfg, menv), state, (b, b)
+
+
+def tick_scopes(cfg) -> set:
+    """The declared scopes on the name stacks of the traced step."""
+    from tests.test_scopes import scopes_in
+
+    step, state, batch = abstract_step(cfg)
+    return scopes_in(step.lower(state, batch).as_text(debug_info=True))
 
 
 @pytest.mark.parametrize("name", sorted(AD_ONLY))
@@ -346,6 +365,53 @@ def test_uncovered_layout_keeps_the_ad_tick(name):
         assert "dw_accum" not in tick_scopes(cfg)
         assert "dw_accum" in tick_scopes(
             tick_cfg("1f1b", "auto", **TICK_LAYOUTS["pp2tp2"]))
+
+
+def traced_tick(cfg):
+    """The body of the traced step's 1F1B scan, a jaxpr."""
+    step, state, batch = abstract_step(cfg)
+    ticks = (cfg.training.gradient_accumulation_steps
+             + 2 * (cfg.distributed.pp_size - 1))
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan" and eqn.params["length"] == ticks:
+                return eqn.params["jaxpr"].jaxpr
+            for j in sub_jaxprs(eqn):
+                if (got := find(j)) is not None:
+                    return got
+
+    return find(jax.make_jaxpr(step)(state, batch).jaxpr)
+
+
+def count_in(jaxpr, primitive: str) -> int:
+    return sum((eqn.primitive.name == primitive)
+               + sum(count_in(j, primitive) for j in sub_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("name,units_in_branches", [("pp2tp2", True),
+                                                    ("pp2cp2", False)])
+def test_tick_holds_a_conditional_round_each_unit(name, units_in_branches):
+    """PR 65: the tick's top level is two conditionals, the forward unit
+    (one layer scan) by `(s_idx == pp - 1) | ~f_on` and the backward unit
+    (both layer scans, and the three branches by stage inside it) by `b_on`,
+    and the two ppermutes outside them. With cp 2 the layers hold a ring of
+    ppermutes, which may sit in no branch: the three layer scans stand at
+    the tick's top level beside the three branches by stage, as before."""
+    cfg = tick_cfg("1f1b", "auto", **TICK_LAYOUTS[name])
+    tick = traced_tick(cfg)
+    conds = [eqn for eqn in tick.eqns if eqn.primitive.name == "cond"]
+    inside = sorted(
+        tuple(sum(count_in(j, prim) for j in sub_jaxprs(eqn))
+              for prim in ("cond", "scan", "ppermute")) for eqn in conds)
+    scans = sum(eqn.primitive.name == "scan" for eqn in tick.eqns)
+    if units_in_branches:
+        assert inside == [(0, 1, 0), (3, 2, 0)] and scans == 0
+        assert count_in(tick, "ppermute") == 2
+    else:
+        assert inside == [(0, 0, 0)] * 3 and scans == 3
+        assert count_in(tick, "ppermute") > 2  # the ring's, in the scans
 
 
 def test_branch_by_stage_may_hold_a_tp_collective(rendezvous_timeout):
